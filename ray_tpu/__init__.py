@@ -162,6 +162,16 @@ def shutdown():
                 runtime.gcs_call("finish_job", job_id=runtime.job_id, rpc_timeout=2.0)
             except Exception:
                 pass
+            if _session and _session.get("procs"):
+                # our own node: it terminates its workers and answers
+                # when they are gone, so no worker outlives shutdown()
+                # holding a chip (a SIGTERMed nodelet leaves them to
+                # notice on their own, seconds later)
+                try:
+                    runtime.node_call(_session["node_addr"], "shutdown",
+                                      rpc_timeout=10.0)
+                except Exception:
+                    pass
             runtime.shutdown()
         if _session:
             for p in _session.get("procs", []):

@@ -36,7 +36,8 @@ from typing import Any, Dict, List, Optional, Tuple
 from ray_tpu.core.common import (Address, NodeInfo, ResourceSet, TaskSpec)
 from ray_tpu.core.config import Config
 from ray_tpu.core.ids import ActorID, JobID, NodeID, PlacementGroupID
-from ray_tpu.core.rpc import ClientPool, ConnectionLost, RemoteError, RpcServer
+from ray_tpu.core.rpc import (ClientPool, ConnectionLost, RemoteError,
+                              RpcServer, RpcTimeout)
 from ray_tpu.core.scheduling_policy import (HybridPolicy, SchedNode,
                                             SpreadPolicy, pack_bundles)
 
@@ -93,6 +94,9 @@ class GcsServer:
         self.heartbeat_seq: Dict[NodeID, int] = {}
         self.last_seen: Dict[NodeID, float] = {}
         self.actors: Dict[ActorID, ActorRecord] = {}
+        # actor in creation -> nodelet running the attempt (its worker is
+        # not known here until __init__ returns; rpc_kill_actor needs it)
+        self._creating: Dict[ActorID, Address] = {}
         self.named_actors: Dict[Tuple[str, str], ActorID] = {}
         self.jobs: Dict[JobID, dict] = {}
         self.kv: Dict[Tuple[str, bytes], bytes] = {}
@@ -195,11 +199,23 @@ class GcsServer:
         period = self.cfg.health_check_period_s
         timeout = period * self.cfg.health_check_failure_threshold
         while not self._stopping:
+            t0 = time.monotonic()
             await asyncio.sleep(period)
+            late = time.monotonic() - t0 - period
+            if late > period:
+                # this loop was held up itself: the heartbeats that came
+                # in meanwhile are queued behind it, so silence measured
+                # now is the GCS's own, not a node's
+                logger.warning("gcs event loop was held up for %.1f s; "
+                               "skipping one health check", late)
+                continue
             now = time.time()
             for nid, info in list(self.nodes.items()):
-                if info.alive and now - self.last_seen.get(nid, now) > timeout:
-                    await self._on_node_death(nid, "health check timeout")
+                silent = now - self.last_seen.get(nid, now)
+                if info.alive and silent > timeout:
+                    await self._on_node_death(
+                        nid, f"health check timeout (no heartbeat for "
+                             f"{silent:.1f} s, limit {timeout:.1f} s)")
             # watchdog sweep: beacons whose owner stopped reporting, and
             # straggler candidates that crossed k x p95 since last report
             try:
@@ -451,43 +467,81 @@ class GcsServer:
         # instead of leasing a second worker and running __init__ twice.
         # A restart bumps num_restarts and legitimately creates anew.
         idem = f"{rec.actor_id.hex()}:{rec.num_restarts}"
-        while not self._stopping:
-            target = await self._pick_for_spec(spec)
-            if target is None:
-                if time.time() > deadline:
-                    rec.state = DEAD
-                    rec.death_cause = "no feasible node for actor resources"
-                    await self._publish_actor(rec)
-                    return
-                await asyncio.sleep(0.2)
-                continue
-            nid = target["node_id"]
-            client = self.pool.get(tuple(target["addr"]))
-            try:
-                # Creation waits on a worker lease + __init__, so it gets
-                # its own bound rather than the default rpc deadline.
-                r = await client.call(
-                    "create_actor", spec=spec, idem=idem,
-                    timeout=self.cfg.worker_start_timeout_s
-                    + self.cfg.worker_lease_timeout_s + 10.0)
-            except (ConnectionLost, RemoteError, OSError) as e:
-                logger.warning("actor create on %s failed: %s", nid.hex()[:8], e)
-                await asyncio.sleep(0.2)
-                continue
-            if not r.get("ok"):
-                if r.get("retryable", True):
+        target = None
+        try:
+            while not self._stopping:
+                if rec.state == DEAD:
+                    return   # killed while it was pending
+                if target is None:
+                    target = await self._pick_for_spec(spec)
+                if target is None:
+                    if time.time() > deadline:
+                        rec.state = DEAD
+                        rec.death_cause = "no feasible node for actor resources"
+                        await self._publish_actor(rec)
+                        return
                     await asyncio.sleep(0.2)
                     continue
-                rec.state = DEAD
-                rec.death_cause = r.get("error", "creation failed")
+                nid = target["node_id"]
+                client = self.pool.get(tuple(target["addr"]))
+                self._creating[rec.actor_id] = tuple(target["addr"])
+                try:
+                    # One attempt is bounded by what the nodelet needs to
+                    # lease and start a worker. __init__ itself has no
+                    # deadline here (a serve replica loads and compiles a
+                    # model for minutes): whoever waits for the actor
+                    # bounds it and kills it (rpc_kill_actor reaches a
+                    # worker still in __init__).
+                    r = await client.call(
+                        "create_actor", spec=spec, idem=idem,
+                        timeout=self.cfg.worker_start_timeout_s
+                        + self.cfg.worker_lease_timeout_s + 10.0)
+                except RpcTimeout:
+                    # still in __init__, or the answer was lost: ask the
+                    # SAME node again — the token joins the creation in
+                    # flight there or replays its result. A dead node
+                    # surfaces as ConnectionLost through the keepalive.
+                    continue
+                except (ConnectionLost, RemoteError, OSError) as e:
+                    logger.warning("actor create on %s failed: %s",
+                                   nid.hex()[:8], e)
+                    target = None
+                    await asyncio.sleep(0.2)
+                    continue
+                if not r.get("ok"):
+                    if r.get("retryable", True):
+                        target = None
+                        await asyncio.sleep(0.2)
+                        continue
+                    rec.state = DEAD
+                    rec.death_cause = r.get("error", "creation failed")
+                    await self._publish_actor(rec)
+                    return
+                if rec.state == DEAD:
+                    # killed while the nodelet was still leasing a worker,
+                    # so the kill found none: stop what it then created
+                    await self._kill_on(client, r["worker_id"], rec.actor_id)
+                    return
+                rec.state = ALIVE
+                rec.address = tuple(r["worker_addr"])
+                rec.worker_id = r["worker_id"]
+                rec.node_id = nid
                 await self._publish_actor(rec)
                 return
-            rec.state = ALIVE
-            rec.address = tuple(r["worker_addr"])
-            rec.worker_id = r["worker_id"]
-            rec.node_id = nid
-            await self._publish_actor(rec)
-            return
+        finally:
+            self._creating.pop(rec.actor_id, None)
+
+    async def _kill_on(self, client, worker_id: bytes, actor_id: ActorID):
+        """Ask a nodelet to kill an actor's worker. With an empty
+        worker_id the nodelet finds the worker by actor_id (an actor still
+        in __init__ has no worker the GCS knows of); on a lane host only
+        that lane dies."""
+        try:
+            await client.call("kill_worker", worker_id=worker_id,
+                              actor_id=actor_id, reason="ray_tpu.kill",
+                              timeout=10.0)
+        except (ConnectionLost, RemoteError, OSError):
+            pass
 
     async def _pick_for_spec(self, spec: TaskSpec) -> Optional[dict]:
         if spec.scheduling.kind == "PLACEMENT_GROUP":
@@ -550,14 +604,16 @@ class GcsServer:
         if no_restart:
             rec.max_restarts = rec.num_restarts  # exhaust budget
         if rec.address is not None and rec.node_id in self.nodes:
-            client = self.pool.get(self.nodes[rec.node_id].nodelet_addr)
-            try:
-                # actor_id lets a lane-host nodelet kill ONLY this lane
-                await client.call("kill_worker", worker_id=rec.worker_id,
-                                  actor_id=actor_id, reason="ray_tpu.kill",
-                                  timeout=10.0)
-            except (ConnectionLost, RemoteError, OSError):
-                pass
+            await self._kill_on(
+                self.pool.get(self.nodes[rec.node_id].nodelet_addr),
+                rec.worker_id, actor_id)
+        elif actor_id in self._creating:
+            if no_restart:
+                # before the kill: the creation loop must find it when
+                # the attempt the kill breaks comes back
+                rec.state = DEAD
+            await self._kill_on(self.pool.get(self._creating[actor_id]),
+                                b"", actor_id)
         if no_restart:
             rec.state = DEAD
             rec.death_cause = "killed via ray_tpu.kill"

@@ -33,6 +33,7 @@ import time
 from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
 
+from ray_tpu.core import compile_cache
 from ray_tpu.core.common import Address, NodeInfo, ResourceSet, TaskSpec
 from ray_tpu.core.config import Config
 from ray_tpu.core.external_storage import FilesystemStorage
@@ -133,6 +134,8 @@ class Nodelet:
         # heartbeat as autoscaler-visible unmet demand (ref: the
         # raylet's infeasible queue feeding autoscaler state)
         self._infeasible: List[dict] = []
+        # worker processes sent SIGTERM and not yet reaped
+        self._dying: List[subprocess.Popen] = []
         # pg_id -> {bundle_index -> {"resources", "available", "committed"}}
         self.pg_bundles: Dict[PlacementGroupID, Dict[int, dict]] = {}
         self.pool = ClientPool()
@@ -219,9 +222,18 @@ class Nodelet:
     async def _heartbeat_loop(self):
         period = self.cfg.health_check_period_s / 2
         gcs = self.pool.get(self.gcs_addr)
+        last = time.monotonic()
         while not self._stopping:
             self._hb_seq += 1
             infeasible, self._infeasible = self._infeasible, []
+            now = time.monotonic()
+            if now - last > 4 * period:
+                # the GCS declares this node dead after a few missed
+                # beats: say on which side the time went
+                logger.warning("heartbeat %d starts %.1f s after the "
+                               "last one (period %.1f s)", self._hb_seq,
+                               now - last, period)
+            last = now
             try:
                 r = await gcs.call("heartbeat", node_id=self.node_id,
                                    seqno=self._hb_seq,
@@ -278,6 +290,7 @@ class Nodelet:
                             "buffers", n)
                 except Exception:
                     pass
+            self._dying = [p for p in self._dying if p.poll() is None]
             for w in list(self.workers.values()):
                 if w.state == "dead":
                     continue
@@ -355,6 +368,9 @@ class Nodelet:
         err = open(log_base + ".err", "ab")
         env = dict(os.environ)
         env["RAY_TPU_SESSION_DIR"] = self.session_dir
+        # workers are the processes that compile: all share one
+        # persistent XLA cache (see core/compile_cache.py)
+        compile_cache.env_defaults(env)
         if env_vars:
             # runtime-env-keyed pool: these must exist before the worker
             # interpreter imports anything (JAX_PLATFORMS, XLA_FLAGS, ...)
@@ -386,6 +402,9 @@ class Nodelet:
             w.proc.terminate()
         except Exception:
             pass
+        # terminated, not yet gone: the reap loop collects it, and
+        # rpc_shutdown waits for it (it may still hold a chip)
+        self._dying.append(w.proc)
         self._on_worker_dead(w)
         if was in ("leased", "actor"):
             # Deliberate kills of busy workers (OOM, shutdown, requested)
@@ -538,6 +557,12 @@ class Nodelet:
     async def rpc_kill_worker(self, worker_id: bytes, reason: str = "",
                               actor_id=None) -> dict:
         w = self.workers.get(worker_id)
+        if w is None and not worker_id and actor_id is not None:
+            # an actor still in __init__: only this nodelet knows which
+            # worker runs it
+            w = next((x for x in self.workers.values()
+                      if x.actor_id == actor_id or actor_id in x.lanes),
+                     None)
         if w is None:
             return {"ok": True}
         if actor_id is not None and w.lane_host:
@@ -854,8 +879,9 @@ class Nodelet:
             host.lanes[spec.actor_id] = spec.resources.copy()
         client = self.pool.get(tuple(host.addr))
         try:
-            res = await client.call("create_actor", spec=spec,
-                                    timeout=self.cfg.worker_start_timeout_s)
+            # timeout=None (reviewed): see _create_actor
+            res = await client.call(
+                "create_actor", spec=spec, timeout=None)  # raylint: disable=unbounded-rpc-call
         except ConnectionLost as e:
             # transport broke: the host process is gone/wedged — killing
             # it death-reports every lane for restart
@@ -863,8 +889,8 @@ class Nodelet:
             self._kill_worker(host, f"lane creation rpc failed: {e}")
             return {"ok": False, "retryable": True, "error": str(e)}
         except (RemoteError, OSError) as e:
-            # THIS lane's creation failed (ctor hang past the deadline,
-            # or a handler error); sibling lanes are healthy — tombstone
+            # THIS lane's creation failed (a handler error); sibling
+            # lanes are healthy — tombstone
             # the lane worker-side so a late-finishing ctor can't install
             # a zombie, and keep the host
             self._lane_rollback(host, spec.actor_id)
@@ -940,8 +966,14 @@ class Nodelet:
         w.actor_id = spec.actor_id
         client = self.pool.get(tuple(w.addr))
         try:
-            res = await client.call("create_actor", spec=spec,
-                                    timeout=self.cfg.worker_start_timeout_s)
+            # timeout=None (reviewed): __init__ is user code like a task
+            # — a serve replica loads and compiles a model for minutes —
+            # so it is bounded by liveness (worker death surfaces as
+            # ConnectionLost), not by the worker-start deadline. Whoever
+            # waits for the actor bounds it and kills it; the GCS routes
+            # that kill here by actor id (rpc_kill_worker).
+            res = await client.call(
+                "create_actor", spec=spec, timeout=None)  # raylint: disable=unbounded-rpc-call
         except (ConnectionLost, RemoteError, OSError) as e:
             self._kill_worker(w, f"actor creation rpc failed: {e}")
             return {"ok": False, "retryable": True, "error": str(e)}
@@ -1351,9 +1383,13 @@ class Nodelet:
         return {"ok": True}
 
     async def rpc_shutdown(self) -> dict:
+        """Stop this node: terminate every worker and answer only when
+        they are gone. A worker that outlives its node still holds its
+        chip, and the next process to open that chip fails on the lock."""
         self._stopping = True
         for w in list(self.workers.values()):
             self._kill_worker(w, "nodelet shutdown")
+        await asyncio.to_thread(_reap, list(self._dying), 5.0)
         if self.store is not None:
             self.store.xfer_serve_stop()
             # keep the segment mapped until os._exit: a live xfer thread
@@ -1361,6 +1397,18 @@ class Nodelet:
             self.store.close(destroy=True, unmap=False)
         asyncio.get_running_loop().call_later(0.05, lambda: os._exit(0))
         return {"ok": True}
+
+
+def _reap(procs, grace_s: float) -> None:
+    """Wait for terminated processes to exit; SIGKILL what remains after
+    grace_s (a worker stuck in a device call ignores SIGTERM)."""
+    deadline = time.time() + grace_s
+    for p in procs:
+        try:
+            p.wait(timeout=max(deadline - time.time(), 0.0))
+        except subprocess.TimeoutExpired:
+            p.kill()
+            p.wait()
 
 
 def main():

@@ -33,7 +33,6 @@ FaultPlan can drop/delay/duplicate/reorder/black-hole/reset any link.
 from __future__ import annotations
 
 import asyncio
-import concurrent.futures as _futures
 import itertools
 import pickle
 import struct
@@ -89,13 +88,13 @@ class ConnectionLost(RpcError):
     pass
 
 
-class RpcTimeout(RpcError, asyncio.TimeoutError, TimeoutError):
+class RpcTimeout(RpcError, TimeoutError):
     """Transport deadline expired with no response.
 
-    Subclasses BOTH timeout spellings (pre-3.11 asyncio.TimeoutError is
-    not the builtin) so every existing wait_for/OSError-family handler
-    keeps working — retry loops that treat OSError as "peer unreachable,
-    retry" absorb timeouts the same way. Distinct from ConnectionLost
+    Subclasses the builtin TimeoutError so every existing
+    wait_for/OSError-family handler keeps working — retry loops that
+    treat OSError as "peer unreachable, retry" absorb timeouts the same
+    way. Distinct from ConnectionLost
     because the link may be fine and the *peer* gray-failed — the health
     plane treats repeated RpcTimeouts as a peer-suspicion signal."""
 
@@ -523,7 +522,7 @@ class RpcClient:
             return await fut
         try:
             return await asyncio.wait_for(fut, timeout)
-        except (asyncio.TimeoutError, TimeoutError):
+        except TimeoutError:
             if fut.done() and not fut.cancelled():
                 # completed inside wait_for's cancellation window
                 return fut.result()
@@ -667,11 +666,7 @@ class EventLoopThread:
                 # documented contract (CancelledError is a BaseException —
                 # callers' `except Exception` handlers never see it)
                 raise ConnectionLost("runtime event loop stopped") from None
-            except (TimeoutError, _futures.TimeoutError):
-                # both spellings: before 3.11 concurrent.futures'
-                # TimeoutError is NOT the builtin, and fut.result raises
-                # the futures one — catching only the builtin turns every
-                # >0.5s coroutine into a spurious timeout
+            except TimeoutError:
                 if fut.done():
                     # Completed during the poll window: surface the real
                     # outcome (result, or the coroutine's own exception).

@@ -26,8 +26,6 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.parallel import _compat  # noqa: F401 — installs jax.shard_map
-
 
 @dataclass(frozen=True)
 class LlamaConfig:
@@ -283,7 +281,45 @@ def _attention_xla(q, k, v, causal: bool, q_offset=0, window=None):
     return out.reshape(B, S, H, D)
 
 
-def _attention(q, k, v, cfg: LlamaConfig, causal=True, q_offset=0):
+def _flash_sharded(q, k, v, window, mesh, rules):
+    """Flash attention under a mesh. GSPMD cannot partition a Mosaic
+    kernel, so the kernel runs per shard inside a shard_map: batch over
+    the rules' batch axes, heads over their heads axes (only when they
+    divide both H and KV, so each shard keeps whole GQA groups). The
+    map is manual over EVERY mesh axis — Mosaic's lowering refuses a
+    partial-manual context — so q/k/v are replicated over the axes the
+    spec does not name. With no batch or heads axis of size > 1 — one
+    chip — the kernel is called bare and the program is the one-chip
+    program."""
+    from ray_tpu.ops.flash_attention import flash_attention
+
+    kernel = functools.partial(flash_attention, causal=True, window=window)
+    if mesh is None or rules is None:
+        return kernel(q, k, v)
+    from ray_tpu.parallel.sharding import logical_to_mesh
+
+    def mesh_axes(logical):   # size>1 mesh axes of one logical axis
+        spec = logical_to_mesh((logical,), rules, mesh)
+        a = spec[0] if len(spec) else None
+        return () if a is None else (a,) if isinstance(a, str) else tuple(a)
+
+    batch, heads = mesh_axes("batch"), mesh_axes("heads")
+    n_head_shards = 1
+    for a in heads:
+        n_head_shards *= int(mesh.shape[a])
+    if q.shape[2] % n_head_shards or k.shape[2] % n_head_shards:
+        heads = ()
+    if not batch + heads:
+        return kernel(q, k, v)
+    from jax.sharding import PartitionSpec as P
+
+    spec = P(batch or None, None, heads or None, None)
+    return jax.shard_map(kernel, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
+
+
+def _attention(q, k, v, cfg: LlamaConfig, causal=True, q_offset=0,
+               mesh=None, rules=None):
     win = cfg.sliding_window
     if win is not None and cfg.attn_impl in ("ring", "ulysses"):
         # silently computing FULL attention here would train a different
@@ -297,9 +333,7 @@ def _attention(q, k, v, cfg: LlamaConfig, causal=True, q_offset=0):
     at_origin = isinstance(q_offset, int) and q_offset == 0
     if cfg.attn_impl == "flash" and causal and q.shape[1] >= 128 \
             and at_origin:
-        from ray_tpu.ops.flash_attention import flash_attention
-
-        return flash_attention(q, k, v, causal=True, window=win)
+        return _flash_sharded(q, k, v, win, mesh, rules)
     if cfg.attn_impl == "ring":
         from ray_tpu.ops.ring_attention import ring_attention
 
@@ -311,9 +345,11 @@ def _attention(q, k, v, cfg: LlamaConfig, causal=True, q_offset=0):
     return _attention_xla(q, k, v, causal, q_offset, window=win)
 
 
-def _layer(x, lp, cfg: LlamaConfig, cos, sin, cache=None, collect_kv=False):
+def _layer(x, lp, cfg: LlamaConfig, cos, sin, cache=None, collect_kv=False,
+           mesh=None, rules=None):
     """One transformer block. x: [B, S, D]. cache: (k, v, offset) or None.
-    collect_kv=True returns this layer's (k, v) for cache seeding."""
+    collect_kv=True returns this layer's (k, v) for cache seeding.
+    mesh+rules reach the flash kernel's shard_map (see _flash_sharded)."""
     B, S, D = x.shape
     H, KV, HD = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     dt = cfg.dtype
@@ -348,7 +384,7 @@ def _layer(x, lp, cfg: LlamaConfig, cos, sin, cache=None, collect_kv=False):
         attn = _attention(q, kk, vv, cfg, causal=True, q_offset=offset)
         new_cache = (ck, cv)
     else:
-        attn = _attention(q, k, v, cfg, causal=True)
+        attn = _attention(q, k, v, cfg, causal=True, mesh=mesh, rules=rules)
     attn = attn.reshape(B, S, H * HD)
     x = x + attn @ _dq(lp["wo"], dt)
 
@@ -402,7 +438,7 @@ def forward(params, tokens, cfg: LlamaConfig, pos_offset=0, mesh=None,
         sin = jax.lax.dynamic_slice_in_dim(sin_full, pos_offset, S, axis=0)
 
     def body(x, lp):
-        y, _ = _layer(x, lp, cfg, cos, sin)
+        y, _ = _layer(x, lp, cfg, cos, sin, mesh=mesh, rules=rules)
         return con(y), None
 
     if cfg.remat:
@@ -752,8 +788,7 @@ def prefill_paged_tail(params, tokens, tail_len, prefix_len, page_table,
     pages; page_table [B, maxP]. Writes the tail's KV into the pages and
     returns (logits at each row's final tail token [B, V], k_pools,
     v_pools). Cost O(T * (prefix+T)) instead of the full O((prefix+T)^2)
-    re-prefill — and ONE device call instead of T decode steps (which on
-    a remote-attach transport cost a round trip each)."""
+    re-prefill — and ONE device call instead of T decode steps."""
     dt = cfg.dtype
     B, T = tokens.shape
     H, KV, HD = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim

@@ -15,15 +15,25 @@ OBJSTORE_SO = os.path.join(_HERE, "libraytpu_objstore.so")
 
 
 def ensure_built() -> str:
-    """Compile the native library if missing or older than its sources."""
+    """Compile the native library if missing or older than its sources.
+    Other processes (test workers, every spawned worker) may be loading
+    the library meanwhile, so the build goes to a sibling name and is
+    renamed into place: a reader sees the old file or the new, never a
+    half-written one."""
     srcs = [os.path.join(_HERE, "objstore.cc"),
             os.path.join(_HERE, "xfer.cc")]
     if (not os.path.exists(OBJSTORE_SO)
             or os.path.getmtime(OBJSTORE_SO) < max(
                 os.path.getmtime(s) for s in srcs)):
-        subprocess.run(
-            ["make", "-C", _HERE, "all"],
-            check=True,
-            capture_output=True,
-        )
+        tmp = os.path.join(_HERE, f".libraytpu_objstore.{os.getpid()}.so")
+        try:
+            subprocess.run(
+                ["make", "-C", _HERE, f"OUT={tmp}", "all"],
+                check=True,
+                capture_output=True,
+            )
+            os.replace(tmp, OBJSTORE_SO)
+        finally:
+            if os.path.exists(tmp):
+                os.remove(tmp)
     return OBJSTORE_SO

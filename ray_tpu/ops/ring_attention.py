@@ -26,7 +26,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.parallel import _compat  # noqa: F401 — installs jax.shard_map
 
 NEG_INF = -1e30
 

@@ -28,8 +28,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.parallel import _compat  # noqa: F401 — installs jax.shard_map
-
 
 def _full_attention(q, k, v, causal: bool):
     """Reference einsum attention with GQA broadcast (the per-chip compute

@@ -24,8 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ray_tpu.parallel import _compat  # noqa: F401 — installs jax.shard_map
-
 
 def pipeline_trunk(stage_fn: Callable, mesh, num_microbatches: int,
                    schedule: str = "gpipe"):

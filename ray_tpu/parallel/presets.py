@@ -167,8 +167,6 @@ def sharded_jit(fn: Optional[Callable] = None, *,
         def wrapped(*args, **kwargs):
             import jax
 
-            from ray_tpu.parallel import _compat  # noqa: F401 (shims)
-
             if mesh is not None:
                 key, m = ("fixed", id(mesh)), mesh
             else:
@@ -184,8 +182,8 @@ def sharded_jit(fn: Optional[Callable] = None, *,
             if g is None:
                 body = f
                 if in_specs is not None:
-                    names = tuple(axis_names) if axis_names is not None \
-                        else tuple(m.axis_names)
+                    names = frozenset(axis_names if axis_names is not None
+                                      else m.axis_names)
                     body = jax.shard_map(f, mesh=m, in_specs=in_specs,
                                          out_specs=out_specs,
                                          axis_names=names)

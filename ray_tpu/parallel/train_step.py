@@ -40,9 +40,13 @@ def _replicated(mesh):
 def opt_state_shardings(opt_state_shapes, params_shapes, param_shardings, mesh):
     """Shard optimizer-state subtrees that mirror the param tree like the
     params (ZeRO), everything else replicated. Works for optax chains whose
-    states embed params-shaped pytrees (adam/adamw/sgd-momentum/...)."""
+    states embed params-shaped pytrees (adam/adamw/sgd-momentum/...). A
+    leaf of such a subtree whose shape is not its parameter's (adafactor's
+    factored rank-1 v_row/v_col, its (1,) placeholders) is replicated: the
+    parameter's spec does not apply to it."""
     params_treedef = jax.tree.structure(params_shapes)
     param_sh_flat = jax.tree.leaves(param_shardings)
+    param_shape_flat = [p.shape for p in jax.tree.leaves(params_shapes)]
 
     def rec(node):
         try:
@@ -50,7 +54,10 @@ def opt_state_shardings(opt_state_shapes, params_shapes, param_shardings, mesh):
         except Exception:
             td = None
         if td == params_treedef:
-            return jax.tree.unflatten(td, param_sh_flat)
+            return jax.tree.unflatten(td, [
+                sh if leaf.shape == shape else _replicated(mesh)
+                for leaf, shape, sh in zip(jax.tree.leaves(node),
+                                           param_shape_flat, param_sh_flat)])
         # descend through tuples/namedtuples/lists/dicts
         if isinstance(node, tuple) and type(node) is not tuple:  # namedtuple
             return type(node)(*(rec(c) for c in node))

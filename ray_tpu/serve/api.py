@@ -29,12 +29,17 @@ class Deployment:
     ray_actor_options: Optional[dict] = None
     init_args: tuple = ()
     init_kwargs: dict = field(default_factory=dict)
+    #: how long the controller waits for a new replica's first answer
+    #: before it kills it and tries again; a replica that loads a real
+    #: model needs more than the default
+    health_check_timeout_s: float = 30.0
 
     def bind(self, *args, **kwargs) -> "Application":
         d = Deployment(self.func_or_class, self.name, self.num_replicas,
                        self.max_concurrent_queries, self.user_config,
                        self.autoscaling_config, self.model_autoscaling_config,
-                       self.ray_actor_options, args, kwargs)
+                       self.ray_actor_options, args, kwargs,
+                       self.health_check_timeout_s)
         # Composition (ref: deployment_graph_build.py): nested bound
         # deployments in the init args join this application's deployment
         # list; serve.run turns them into handles at deploy time.
@@ -64,7 +69,10 @@ class Deployment:
                        kw.pop("autoscaling_config", self.autoscaling_config),
                        kw.pop("model_autoscaling_config",
                               self.model_autoscaling_config),
-                       kw.pop("ray_actor_options", self.ray_actor_options))
+                       kw.pop("ray_actor_options", self.ray_actor_options),
+                       health_check_timeout_s=kw.pop(
+                           "health_check_timeout_s",
+                           self.health_check_timeout_s))
         if kw:
             raise ValueError(f"unknown deployment options {sorted(kw)}")
         return d
@@ -119,12 +127,14 @@ def deployment(_func_or_class=None, *, name: Optional[str] = None,
                user_config: Any = None,
                autoscaling_config: Optional[dict] = None,
                model_autoscaling_config: Optional[dict] = None,
-               ray_actor_options: Optional[dict] = None):
+               ray_actor_options: Optional[dict] = None,
+               health_check_timeout_s: float = 30.0):
     def deco(obj):
         return Deployment(obj, name or getattr(obj, "__name__", "deployment"),
                           num_replicas, max_concurrent_queries, user_config,
                           autoscaling_config, model_autoscaling_config,
-                          ray_actor_options)
+                          ray_actor_options,
+                          health_check_timeout_s=health_check_timeout_s)
 
     if _func_or_class is not None:
         return deco(_func_or_class)
@@ -187,10 +197,17 @@ def run(app: Application, *, route_prefix: Optional[str] = None,
             "autoscaling_config": d.autoscaling_config,
             "model_autoscaling_config": d.model_autoscaling_config,
             "ray_actor_options": d.ray_actor_options,
+            "health_check_timeout_s": d.health_check_timeout_s,
         }
         ray_tpu.get(controller.deploy.remote(
             d.name, blob, _handleize(d.init_args), _handleize(d.init_kwargs),
             config))
+        st = ray_tpu.get(controller.list_deployments.remote())[d.name]
+        if st["num_replicas"] == 0 and st["last_error"]:
+            # nothing would serve it, and nothing else says why: a handle
+            # only ever reports "no replicas"
+            raise RuntimeError(f"deployment {d.name!r} came up with no "
+                               f"replica: {st['last_error']}")
     if route_prefix is not None:
         ray_tpu.get(controller.set_route.remote(route_prefix,
                                                 app.ingress.name))

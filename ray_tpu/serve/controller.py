@@ -337,7 +337,8 @@ class ServeController:
         out = {}
         for name, d in self.deployments.items():
             out[name] = {"num_replicas": len(d["replicas"]),
-                         "config": d["config"], "version": d["version"]}
+                         "config": d["config"], "version": d["version"],
+                         "last_error": d.get("last_error")}
         return out
 
     def report_load(self, name: str, reporter: str, load: float,
@@ -486,6 +487,10 @@ class ServeController:
                 continue
             replicas.append(h)
             names.append(rn)
+        if started and len(replicas) >= target:
+            with self._lock:
+                if name in self.deployments:
+                    self.deployments[name].pop("last_error", None)
         # Scale-down drains instead of killing: unpublish FIRST (the
         # table update + bump below pushes the shrunk set to every
         # router long-poll, so no new requests target the retiring

@@ -50,6 +50,9 @@ class _Request:
     #: sequence length = len(prompt) + len(generated) - overlap
     overlap: int = 0
     error: Optional[str] = None
+    #: status the serve layer answers with when `error` is set: 400 = the
+    #: request could not be served as asked, 500 = the engine failed
+    error_status: int = 400
     done_event: threading.Event = field(default_factory=threading.Event)
     # pulsed whenever generated grows (token-streaming consumers wait on it)
     progress: threading.Event = field(default_factory=threading.Event)
@@ -351,6 +354,50 @@ class LLMEngine:
         with self.lock:
             return bool(self.pending) or any(s is not None for s in self.slots)
 
+    def fail_all(self, error: str) -> int:
+        """Fail every queued, prefilling and decoding request with
+        `error` and free their slots and pages. For a step that raised:
+        its donated cache buffers may be gone, so nothing in flight can
+        finish, and retrying the same program would only raise again."""
+        with self.lock:
+            failed = self.pending + [r for r in self.slots if r is not None]
+            self.pending = []
+            self._prefilling = []
+            for r in failed:
+                self._free_slot(r)
+        for r in failed:
+            r.error, r.error_status = error, 500
+            r.done_event.set()
+            r.progress.set()
+        self.metrics["failed"] = self.metrics.get("failed", 0) + len(failed)
+        return len(failed)
+
+    def device_report(self) -> Dict[str, Any]:
+        """Where and how this engine runs: the device jax gave it, the
+        compute dtype it chose, and whether the fused decode block traces
+        to a Pallas call (paged layout on a chip) or to the jnp reference.
+        Traces the decode block once; not for polling."""
+        jax, jnp = self._jax, self._jnp
+        dev = jax.devices()[0]
+        if self.kv_layout == "paged":
+            fn, args = self._decode_n_paged, (
+                self.params, self._last, self.kp, self.vp, self._pt_dev,
+                self._len_dev, self._active_dev, self._temps_dev, self._key)
+        else:
+            fn, args = self._decode_n, (
+                self.params, self._last, self.cache, self._active_dev,
+                self._temps_dev, self._key)
+        # shapes only: the decode thread may be donating these buffers
+        shapes = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), args)
+        lowered = fn.lower(*shapes, n=8)
+        return {"platform": dev.platform, "kind": dev.device_kind,
+                "count": len(jax.devices()),
+                "dtype": str(jnp.dtype(self.cfg.dtype)),
+                "kv_layout": self.kv_layout,
+                "decode_has_pallas_call":
+                    "tpu_custom_call" in lowered.as_text()}
+
     # ---- engine step -------------------------------------------------------
 
     def _bucket(self, n: int) -> int:
@@ -648,16 +695,22 @@ class LLMEngine:
                     and r.generated[-1] == self.eos)
                 or self._seq_len(r) >= self.max_seq - 1):
             with self.lock:
-                if r.slot >= 0:
-                    if self.kv_layout == "paged":
-                        self.pool.release(r.slot)
-                        self._len_host[r.slot] = 0
-                        self._table_dirty = True
-                    self.slots[r.slot] = None
-                    r.slot = -1
-                    self._masks_dirty = True
+                self._free_slot(r)
             r.done_event.set()
             r.progress.set()
+
+    def _free_slot(self, r: _Request) -> None:
+        """Give back a finished or failed request's slot and pages
+        (caller holds self.lock)."""
+        if r.slot < 0:
+            return
+        if self.kv_layout == "paged":
+            self.pool.release(r.slot)
+            self._len_host[r.slot] = 0
+            self._table_dirty = True
+        self.slots[r.slot] = None
+        r.slot = -1
+        self._masks_dirty = True
 
     def _preempt_one(self) -> bool:
         """Paged pools exhausted mid-decode: evict the most recently
@@ -1084,34 +1137,67 @@ class LLMServer:
 
     def _loop(self):
         while not self._stop:
-            worked = False
+            idle_s = 0.01
             try:
-                for eng in self._engines():
-                    if eng.has_work():
-                        if not self._beacon.busy:
-                            self._beacon.arm(queue=self.queue_len())
-                        eng.step_n(self.decode_block)
-                        self._beacon.tick()
-                        worked = True
-                if self._retiring:
-                    # a retiree with no admitted work left has finished
-                    # its in-flight generations; drop it (engine GC
-                    # frees pages)
-                    with self._retire_lock:
-                        self._retiring = [e for e in self._retiring
-                                          if e.has_work()]
-            except Exception:
-                # one engine's bad step must not kill the decode thread
-                # — that would freeze every stream on the replica, not
-                # just the failing one
-                logger.exception("decode loop step failed; continuing")
-                time.sleep(0.05)
-                continue
+                worked = self._drive_engines()
+            except Exception as e:  # noqa: BLE001 — see below
+                # Anything but a step (the model table changing under the
+                # iteration, a beacon, an engine's bookkeeping): a thread
+                # that ended here would leave every stream of the replica
+                # hanging. Fail what is in flight with the error and wait
+                # for the next request instead of coming straight back.
+                logger.exception("decode loop iteration failed")
+                self._fail_engines(
+                    [self.engine, *self._models.values_snapshot(),
+                     *self._retiring],
+                    f"decode loop failed: {type(e).__name__}: {e}")
+                worked, idle_s = False, 1.0
             if not worked:
                 self._beacon.disarm()
-                self._wake.wait(timeout=0.01)
+                self._wake.wait(timeout=idle_s)
                 self._wake.clear()
         self._beacon.disarm()
+
+    def _drive_engines(self) -> bool:
+        """One pass of the decode loop: a fused block on every engine
+        that has work. Returns whether any had."""
+        worked = False
+        for eng in self._engines():
+            if eng.has_work():
+                if not self._beacon.busy:
+                    self._beacon.arm(queue=self.queue_len())
+                try:
+                    eng.step_n(self.decode_block)
+                except Exception as e:  # noqa: BLE001 — see below
+                    # A step that raised (a program the compiler or the
+                    # runtime refuses) raises again on retry: fail that
+                    # engine's requests with the error so clients see it,
+                    # and keep the thread for the other engines. With
+                    # nothing left in flight the loop sleeps; it cannot
+                    # spin on the failure.
+                    logger.exception("decode step failed")
+                    self._fail_engines(
+                        [eng], f"engine step failed: {type(e).__name__}: {e}")
+                self._beacon.tick()
+                worked = True
+        if self._retiring:
+            # a retiree with no admitted work left has finished its
+            # in-flight generations; drop it (engine GC frees pages)
+            with self._retire_lock:
+                self._retiring = [e for e in self._retiring
+                                  if e.has_work()]
+        return worked
+
+    @staticmethod
+    def _fail_engines(engines, error: str) -> None:
+        for eng in engines:
+            try:
+                n = eng.fail_all(error)
+            except Exception:  # noqa: BLE001 — the other engines still get theirs
+                logger.exception("could not fail an engine's requests")
+                continue
+            if n:
+                logger.error("failed %d request(s): %s", n, error)
 
     # ---- model multiplexing ------------------------------------------------
 
@@ -1249,7 +1335,8 @@ class LLMServer:
         if req.error:
             from ray_tpu.serve.http_proxy import Response
 
-            return Response({"error": req.error}, status_code=400)
+            return Response({"error": req.error},
+                            status_code=req.error_status)
         ttft = (req.first_token_time - req.submit_time
                 if req.first_token_time else None)
         return {"tokens": req.generated, "ttft_s": ttft}
@@ -1320,6 +1407,7 @@ class LLMServer:
         out = {"done": True, "n_tokens": cursor, "ttft_s": ttft}
         if req.error:
             out["error"] = req.error
+            out["status"] = req.error_status
         yield out
 
     # ---- disaggregated serving (serve/disagg.py) ---------------------------
@@ -1446,6 +1534,9 @@ class LLMServer:
             # unpin retained + in-flight page groups and withdraw our
             # global-directory entries before the controller kills us
             self._exporter.close()
+
+    def device_report(self) -> Dict[str, Any]:
+        return self.engine.device_report()
 
     def stats(self) -> Dict[str, Any]:
         m = dict(self.engine.metrics)

@@ -531,6 +531,11 @@ class SimLLMServer:
             self._exporter.close()
 
 
+#: readiness deadline of a replica that loads a real model (the serve
+#: default, 30 s, is less than a 2.7B model takes to reach the chip)
+_ENGINE_STARTUP_TIMEOUT_S = 600.0
+
+
 def build_llm_app(*, name: str = "llm_server",
                   num_replicas: int = 2,
                   router_policy: str = "affinity",
@@ -550,6 +555,14 @@ def build_llm_app(*, name: str = "llm_server",
     when use_sim=True (tests/bench). Returns the Application; deploy
     with serve.run(app, route_prefix=...).
 
+    Every real-engine replica opens a chip, and a chip belongs to one
+    process: each asks the scheduler for one chip, so a replica the node
+    has no free chip for waits in the scheduler instead of failing on
+    libtpu's lock inside the replica; and each gets
+    _ENGINE_STARTUP_TIMEOUT_S to load its model before the controller
+    gives up on it and kills it (nothing else bounds a replica's
+    __init__). SimLLMServer replicas hold no device and ask for none.
+
     disaggregated=True builds the two-pool topology instead
     (serve/disagg.py): `{name}_prefill` x prefill_replicas and
     `{name}_decode` x decode_replicas behind a DisaggRouter ingress.
@@ -558,10 +571,13 @@ def build_llm_app(*, name: str = "llm_server",
     autoscales independently (the router report_loads per pool)."""
     if use_sim:
         server_cls = SimLLMServer
+        engine_opts: dict = {}
     else:
         from ray_tpu.serve.llm import LLMServer
 
         server_cls = LLMServer
+        engine_opts = {"ray_actor_options": {"num_tpus": 1},
+                       "health_check_timeout_s": _ENGINE_STARTUP_TIMEOUT_S}
     if disaggregated:
         from ray_tpu.serve.disagg import DisaggRouter
 
@@ -571,11 +587,13 @@ def build_llm_app(*, name: str = "llm_server",
             else max(1, num_replicas - n_pf)
         prefill = serve_api.deployment(
             server_cls, name=f"{name}_prefill", num_replicas=n_pf,
-            autoscaling_config=prefill_autoscaling_config).bind(
+            autoscaling_config=prefill_autoscaling_config,
+            **engine_opts).bind(
             mode="prefill", **llm_kwargs)
         decode = serve_api.deployment(
             server_cls, name=f"{name}_decode", num_replicas=n_dec,
-            autoscaling_config=decode_autoscaling_config).bind(
+            autoscaling_config=decode_autoscaling_config,
+            **engine_opts).bind(
             mode="decode", **llm_kwargs)
         router = serve_api.deployment(
             DisaggRouter, name=f"{name}_router", num_replicas=1).bind(
@@ -585,8 +603,8 @@ def build_llm_app(*, name: str = "llm_server",
     llm = serve_api.deployment(
         server_cls, name=name, num_replicas=num_replicas,
         autoscaling_config=autoscaling_config,
-        model_autoscaling_config=model_autoscaling_config).bind(
-        **llm_kwargs)
+        model_autoscaling_config=model_autoscaling_config,
+        **engine_opts).bind(**llm_kwargs)
     rkw = dict(router_kwargs or {})
     if tenant_weights is not None:
         rkw.setdefault("tenant_weights", tenant_weights)

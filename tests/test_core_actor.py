@@ -3,6 +3,7 @@
 Reference test model: python/ray/tests/test_actor*.py.
 """
 
+import os
 import time
 
 import pytest
@@ -155,3 +156,76 @@ def test_actor_in_placement_context_gets_big_object(ray_start_regular):
     big = np.ones(400_000, dtype=np.float64)
     ref = ray_tpu.put(big)
     assert ray_tpu.get(h.load.remote(ref)) == 400_000.0
+
+
+@pytest.fixture
+def ray_short_start_deadline():
+    # worker start and lease bounds far below the actors' __init__ times
+    # below: one GCS creation attempt lasts 1 + 1 + 10 s
+    ray_tpu.init(num_cpus=4, _system_config={
+        "health_check_period_s": 0.2, "worker_start_timeout_s": 1.0,
+        "worker_lease_timeout_s": 1.0})
+    yield
+    ray_tpu.shutdown()
+
+
+def test_slow_init_is_not_bounded_by_worker_start(ray_short_start_deadline,
+                                                  tmp_path):
+    """An __init__ that outlasts the worker-start bound AND one GCS
+    creation attempt (a replica loading a model) runs once, to its end:
+    it is not killed and created again."""
+    log = tmp_path / "inits"
+
+    @ray_tpu.remote(num_cpus=1)
+    class Slow:
+        def __init__(self, path):
+            import os
+
+            with open(path, "a") as f:
+                f.write(f"{os.getpid()}\n")
+            time.sleep(14.0)
+
+        def ping(self):
+            return "pong"
+
+    a = Slow.remote(str(log))
+    assert ray_tpu.get(a.ping.remote(), timeout=60) == "pong"
+    assert len(log.read_text().split()) == 1, log.read_text()
+
+
+def test_kill_reaches_an_actor_still_in_init(ray_short_start_deadline,
+                                             tmp_path):
+    """The deadline on __init__ is its caller's: ray_tpu.kill on an actor
+    that has not come up stops the worker running it, frees what it held,
+    and the actor is dead — not created again."""
+    log = tmp_path / "pid"
+
+    @ray_tpu.remote(num_cpus=4)
+    class Hangs:
+        def __init__(self, path):
+            import os
+
+            with open(path, "a") as f:
+                f.write(f"{os.getpid()}\n")
+            time.sleep(600)
+
+        def ping(self):
+            return "pong"
+
+    a = Hangs.remote(str(log))
+    deadline = time.time() + 30
+    while not log.exists() and time.time() < deadline:
+        time.sleep(0.1)
+    pid = int(log.read_text().split()[0])
+    ray_tpu.kill(a)
+    with pytest.raises((ActorDiedError,
+                        ray_tpu.exceptions.ActorUnavailableError)):
+        ray_tpu.get(a.ping.remote(), timeout=30)
+    deadline = time.time() + 10
+    while os.path.exists(f"/proc/{pid}") and time.time() < deadline:
+        time.sleep(0.1)
+    assert not os.path.exists(f"/proc/{pid}"), "worker in __init__ survived"
+    # all four CPUs are free again, and nothing re-ran __init__
+    b = Counter.options(num_cpus=4).remote(1)
+    assert ray_tpu.get(b.value.remote(), timeout=30) == 1
+    assert len(log.read_text().split()) == 1

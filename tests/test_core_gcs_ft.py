@@ -176,3 +176,64 @@ def test_gcs_restart_actor_lost_during_downtime(ft_cluster):
         except Exception:
             time.sleep(0.5)
     assert pid2 != pid1                   # restarted on the new node
+
+
+def test_gcs_held_up_itself_does_not_declare_its_nodes_dead():
+    """Opening a TPU freezes the whole host for seconds (gcs and nodelet
+    held up at the same instant, PERF.md §6): when the GCS wakes, the
+    heartbeats of that time are still queued behind its health check. The
+    silence it would measure is its own; it must let them in first."""
+    import asyncio
+
+    from ray_tpu.core.common import NodeInfo, ResourceSet
+    from ray_tpu.core.config import Config
+    from ray_tpu.core.gcs import GcsServer
+    from ray_tpu.core.ids import NodeID
+    from ray_tpu.core.rpc import RpcClient
+
+    async def scenario():
+        cfg = Config()
+        cfg.health_check_period_s = 0.1
+        cfg.health_check_failure_threshold = 5      # 0.5 s of silence
+        gcs = GcsServer(cfg)
+        host, port = await gcs.start()
+        info = NodeInfo(NodeID.from_random(), ("127.0.0.1", 1),
+                        ResourceSet({"CPU": 1.0}))
+        client = RpcClient(host, port)
+        await client.call("register_node", info=info)
+        stop = False
+        # count the verdicts, not info.alive: the next heartbeat brings a
+        # node back, but by then its actors have been restarted elsewhere
+        deaths = []
+        on_node_death = gcs._on_node_death
+
+        async def counting(node_id, reason):
+            deaths.append(reason)
+            await on_node_death(node_id, reason)
+
+        gcs._on_node_death = counting
+
+        async def nodelet_heartbeats():
+            seq = 0
+            while not stop:
+                seq += 1
+                await client.call("heartbeat", node_id=info.node_id,
+                                  seqno=seq, available=info.resources_total)
+                await asyncio.sleep(0.05)
+
+        beats = asyncio.ensure_future(nodelet_heartbeats())
+        await asyncio.sleep(0.3)
+        time.sleep(0.8)            # the host freezes: every loop at once
+        await asyncio.sleep(0.4)
+        deaths_after_freeze = list(deaths)
+        stop = True
+        await beats
+        await asyncio.sleep(0.8)   # a node that really falls silent
+        gcs._stopping = True
+        await client.close()
+        await gcs.server.stop()
+        return deaths_after_freeze, deaths
+
+    deaths_after_freeze, deaths = asyncio.run(scenario())
+    assert deaths_after_freeze == []
+    assert len(deaths) == 1 and "no heartbeat for" in deaths[0]

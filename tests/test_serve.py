@@ -264,3 +264,41 @@ def test_multiplexed_model_loading(ray_start_regular):
         handle.options(multiplexed_model_id="m3").remote(1))
     assert out3["loads"] == ["m3", "m4", "m5", "m3"]
     serve.shutdown()
+
+
+@pytest.mark.parametrize("fault", ["raises", "hangs"])
+def test_run_says_why_a_deployment_has_no_replica(ray_start_regular,
+                                                  tmp_path, fault):
+    """A replica that cannot come up is the deployment's whole story:
+    serve.run raises with the cause instead of returning a handle that can
+    only ever say "no replicas". The readiness deadline is what bounds a
+    replica's __init__ — its worker is stopped, not left loading."""
+    import os
+
+    marker = tmp_path / "pid"
+
+    @serve.deployment(num_replicas=1, health_check_timeout_s=3.0,
+                      ray_actor_options={"num_cpus": 1})
+    class Broken:
+        def __init__(self, how, path):
+            with open(path, "w") as f:
+                f.write(str(os.getpid()))
+            if how == "raises":
+                raise ValueError("weights not found at /nowhere")
+            time.sleep(600)
+
+        def __call__(self, x):
+            return x
+
+    with pytest.raises(RuntimeError) as e:
+        serve.run(Broken.bind(fault, str(marker)))
+    assert "'Broken' came up with no replica" in str(e.value)
+    if fault == "raises":
+        assert "weights not found at /nowhere" in str(e.value)
+    assert serve.status()["deployments"]["Broken"]["last_error"]
+    pid = int(marker.read_text())
+    deadline = time.time() + 15
+    while os.path.exists(f"/proc/{pid}") and time.time() < deadline:
+        time.sleep(0.1)
+    assert not os.path.exists(f"/proc/{pid}"), "replica worker survived"
+    serve.shutdown()

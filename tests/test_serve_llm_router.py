@@ -200,3 +200,23 @@ def test_autoscale_up_then_drain_down(ray_start_regular):
         time.sleep(0.25)
     assert downs, "fleet never drained back down after load stopped"
     serve.shutdown()
+
+
+def test_real_engine_replicas_ask_for_a_chip_and_sim_ones_do_not():
+    """A chip belongs to one process: each real-engine replica must ask
+    the scheduler for one, so a replica without a free chip waits there
+    instead of failing on libtpu's lock; SimLLMServer holds no device."""
+    def asks(app):
+        return {d.name: (d.ray_actor_options or {}).get("num_tpus")
+                for d in app.deployments}
+
+    real = build_llm_app(use_sim=False, num_replicas=1, preset="tiny")
+    assert asks(real) == {"llm_server": 1, "llm_server_router": None}
+    llm = next(d for d in real.deployments if d.name == "llm_server")
+    # and more than the default 30 s to load a real model
+    assert llm.health_check_timeout_s > 30.0
+    disagg = build_llm_app(use_sim=False, disaggregated=True, preset="tiny")
+    assert asks(disagg) == {"llm_server_prefill": 1, "llm_server_decode": 1,
+                            "llm_server_router": None}
+    sim = build_llm_app(use_sim=True, num_replicas=2)
+    assert asks(sim) == {"llm_server": None, "llm_server_router": None}

@@ -352,3 +352,151 @@ def test_int8_quantized_engine_serves():
         out = _greedy(eng, list(range(2, 34)), 8)
         assert len(out) == 8 and all(0 <= t < 256 for t in out), (layout,
                                                                   out)
+
+
+# --- the Pallas kernels themselves, interpreted ----------------------------
+# Program code has no interpret branch (off the chip it returns the jnp
+# reference), so without these the kernels' first execution anywhere
+# would be on a chip.
+
+
+@pytest.fixture
+def interpreted_kernels(monkeypatch):
+    from ray_tpu.ops import paged_attention as pa
+
+    real = pa.pl.pallas_call
+    monkeypatch.setattr(pa.pl, "pallas_call",
+                        lambda *a, **k: real(*a, interpret=True, **k))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    return pa
+
+
+def _paged_case(groups):
+    S, KV, HD, ps, maxP = 4, 2, 128, 16, 4
+    rng = np.random.default_rng(0)
+    f = lambda *shape: jax.numpy.asarray(  # noqa: E731
+        rng.normal(size=shape), jax.numpy.float32)
+    NP = S * maxP + 1
+    table = jax.numpy.asarray(
+        1 + np.arange(S * maxP).reshape(S, maxP), jax.numpy.int32)
+    # one token, a page boundary + 1, an inactive slot, a full table
+    lengths = jax.numpy.asarray([1, ps + 1, 0, maxP * ps], jax.numpy.int32)
+    return (f(S, KV * groups, HD), f(S, KV, HD), f(S, KV, HD),
+            f(KV, NP, ps, HD), f(KV, NP, ps, HD), table, lengths)
+
+
+@pytest.mark.parametrize("groups", [1, 2])
+def test_paged_inplace_kernel_interpreted_matches_reference(
+        groups, interpreted_kernels):
+    pa = interpreted_kernels
+    q, kn, vn, kp, vp, table, lengths = _paged_case(groups)
+    o, k2, v2 = pa.paged_decode_attention_inplace(q, kn, vn, kp, vp, table,
+                                                  lengths)
+    ro, rk, rv = pa.paged_decode_attention_inplace_reference(
+        q, kn, vn, kp, vp, table, lengths)
+    live = np.asarray(lengths) > 0
+    np.testing.assert_allclose(np.asarray(o)[live], np.asarray(ro)[live],
+                               atol=1e-5)
+    # page 0 is the trash page: inactive slots may flush garbage there
+    np.testing.assert_array_equal(np.asarray(k2)[:, 1:],
+                                  np.asarray(rk)[:, 1:])
+    np.testing.assert_array_equal(np.asarray(v2)[:, 1:],
+                                  np.asarray(rv)[:, 1:])
+
+
+def test_paged_read_kernel_interpreted_matches_reference(interpreted_kernels):
+    pa = interpreted_kernels
+    q, _, _, kp, vp, table, lengths = _paged_case(2)
+    live = np.asarray(lengths) > 0
+    np.testing.assert_allclose(
+        np.asarray(pa.paged_attention(q, kp, vp, table, lengths))[live],
+        np.asarray(paged_attention_reference(q, kp, vp, table,
+                                             lengths))[live], atol=1e-5)
+
+
+# --- a step that raises ------------------------------------------------------
+
+
+def test_failing_step_fails_its_requests_and_does_not_spin():
+    """A decode program the device refuses raises on every retry: the
+    server must hand the error to the waiting clients (500, through the
+    stream too) within a deadline, free their slots, and go idle — not
+    log and retry for ever while the clients hang."""
+    import asyncio
+    import time
+
+    from ray_tpu.serve.llm import LLMServer
+
+    server = LLMServer(preset="tiny", kv_layout="paged", page_size=16)
+    calls = []
+
+    def refuse(n):
+        calls.append(n)
+        raise RuntimeError("XlaRuntimeError: program refused by the chip")
+
+    server.engine.step_n = refuse
+    body = {"prompt": list(range(20)), "max_new_tokens": 4}
+
+    async def drive():
+        out = await asyncio.wait_for(server(dict(body)), timeout=10)
+        frames = [f async for f in server.stream_request(dict(body))]
+        return out, frames
+
+    try:
+        out, frames = asyncio.run(drive())
+        assert out.status_code == 500
+        assert "program refused by the chip" in out.content["error"]
+        assert frames[-1]["done"] and frames[-1]["status"] == 500
+        assert "program refused by the chip" in frames[-1]["error"]
+        # one attempt per request, then idle: no retry loop
+        time.sleep(0.5)
+        assert len(calls) == 2, calls
+        assert not server.engine.has_work()
+        assert server.queue_len() == 0
+        stats = server.stats()
+        assert stats["failed"] == 2 and stats["active_slots"] == 0
+    finally:
+        server._stop = True
+        server._wake.set()
+
+
+def test_failing_loop_iteration_fails_requests_and_keeps_the_thread():
+    """The decode loop guards more than the step: an exception anywhere
+    in an iteration (here the engine's own has_work) must not end the
+    thread in silence — every stream of the replica would hang. The
+    requests in flight fail with the error, the thread lives, and the
+    loop waits for the next request instead of coming straight back."""
+    import asyncio
+    import time
+
+    from ray_tpu.serve.llm import LLMServer
+
+    server = LLMServer(preset="tiny", kv_layout="paged", page_size=16)
+    calls = []
+
+    def broken():
+        calls.append(time.time())
+        raise KeyError("model table changed under the loop")
+
+    server.engine.has_work = broken
+    body = {"prompt": list(range(20)), "max_new_tokens": 4}
+
+    async def drive():
+        out = await asyncio.wait_for(server(dict(body)), timeout=10)
+        frames = [f async for f in server.stream_request(dict(body))]
+        return out, frames
+
+    try:
+        out, frames = asyncio.run(drive())
+        assert out.status_code == 500
+        assert "model table changed under the loop" in out.content["error"]
+        assert frames[-1]["done"] and frames[-1]["status"] == 500
+        assert "decode loop failed: KeyError" in frames[-1]["error"]
+        time.sleep(0.5)
+        assert server._thread.is_alive()
+        assert len(calls) <= 6, len(calls)   # idle poll is 100 Hz
+        assert server.stats()["failed"] == 2
+    finally:
+        server._stop = True
+        server._wake.set()
+

@@ -41,7 +41,9 @@ def test_intermediate_reports_and_asha(ray_start_regular):
 
         for i in range(20):
             tune.report({"loss": 100.0 / config["q"] - i})
-            time.sleep(0.01)
+            # long enough that the four trials overlap although their
+            # workers start one after another on a loaded host
+            time.sleep(0.05)
         return {"final": True}
 
     sched = tune.ASHAScheduler(metric="loss", mode="min", max_t=20,
@@ -168,7 +170,9 @@ def test_pbt_exploit_transfers_checkpoint(ray_start_regular):
             score += lr
             tune.report({"score": score, "lr": lr},
                         checkpoint={"score": score})
-            time.sleep(0.01)
+            # as above: a trial that ends before the others have
+            # reported has nobody to exploit
+            time.sleep(0.05)
         return {"score": score}
 
     sched = tune.PopulationBasedTraining(
