@@ -8,12 +8,20 @@ where it is not, the cache is one fixed directory at the root of the
 checkout — never a temporary name, a pid or a time. Both knobs are
 environment defaults, read by jax when it is imported, so this module
 never imports jax and a parent that only spawns stays off the chip.
+
+It also counts what the process compiles (`listen`, `compile_count`,
+`compile_seconds`): the directory cannot, because jax never writes a
+program that compiled faster than the bar below.
 """
 
 from __future__ import annotations
 
 import os
+import sys
+import threading
 from typing import MutableMapping
+
+from ray_tpu.util import tracing
 
 DEFAULT_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(
@@ -37,3 +45,50 @@ def entry_count(path: str) -> int:
         return sum(1 for n in os.listdir(path) if not n.endswith("-atime"))
     except OSError:
         return 0
+
+
+# jax times every program it builds for a backend under this event, a
+# compile and a load from the persistent cache alike; a call with shapes
+# it has seen raises none. (jax._src.dispatch.BACKEND_COMPILE_EVENT)
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_lock = threading.Lock()
+_listening = False
+_count = 0
+_seconds = 0.0
+
+
+def _on_duration(event: str, seconds: float, **_kw) -> None:
+    global _count, _seconds
+    if event != _COMPILE_EVENT:
+        return
+    with _lock:
+        _count += 1
+        _seconds += seconds
+    tracing.instant("xla.compile", {"seconds": float(seconds)})
+
+
+def listen() -> bool:
+    """Start counting this process's compiles, once; call where jax is
+    known to be imported (session start, engine start). Does nothing, and
+    says so, where it is not: this module imports no jax."""
+    global _listening
+    if _listening:
+        return True
+    if "jax" not in sys.modules:
+        return False
+    import jax.monitoring
+
+    with _lock:
+        if not _listening:
+            jax.monitoring.register_event_duration_secs_listener(_on_duration)
+            _listening = True
+    return True
+
+
+def compile_count() -> int:
+    """Programs this process built for a backend since `listen()`."""
+    return _count
+
+
+def compile_seconds() -> float:
+    return _seconds

@@ -216,7 +216,7 @@ class Config:
     # --- health plane (observability/health.py) -----------------------------
     # Flight recorder: bounded per-process ring of recent task events /
     # spans / channel-frame metadata, dumped to a post-mortem JSON under
-    # flight_recorder_dir ("" -> /tmp/ray_tpu/flight) on stall detection,
+    # flight_recorder_dir ("" -> $RAY_TPU_TMPDIR/flight) on stall detection,
     # uncaught worker exception, or CollectiveError. 0 disables.
     flight_recorder_size: int = 2048
     flight_recorder_dir: str = ""

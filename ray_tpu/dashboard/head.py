@@ -244,7 +244,9 @@ class DashboardHead:
         # events from different processes flush independently and
         # interleave out of order in the GCS — fold by timestamp, or a
         # late-arriving PENDING overwrites a FINISHED forever
-        for ev in sorted((ev for ev in events if ev.get("kind") != "span"),
+        # (task state events only: spans and health instants, the
+        # `stall::` markers, share the store and carry no task_id)
+        for ev in sorted((ev for ev in events if ev.get("task_id")),
                          key=lambda ev: ev["ts"]):
             t = tasks.setdefault(ev["task_id"], {
                 "task_id": ev["task_id"], "name": ev.get("name"),

@@ -107,6 +107,8 @@ class TelemetryAgent:
                                      name="raytpu-telemetry")
                 self._thread = t
                 t.start()
+                # its own thread: this one blocks in RPCs for seconds
+                _health.watch_host_freezes()
 
     def _loop(self) -> None:
         while not self._stopped.is_set():

@@ -20,10 +20,11 @@ Dump triggers (all call FlightRecorder.dump(reason)):
     collective/group.py
   * an uncaught exception unwinds a worker task (core/worker.py)
 
-Dumps are JSON files under `cfg.flight_recorder_dir` (default
-/tmp/ray_tpu/flight), one per incident, rate-limited per reason prefix
-so a stall flagged every report interval produces one file, not one
-per interval. `cli blackbox` lists and renders them;
+Dumps are JSON files under `cfg.flight_recorder_dir` (default: `flight/`
+beside the session directories, `$RAY_TPU_TMPDIR/flight`, which is
+/tmp/ray_tpu/flight where that is not set), one per incident,
+rate-limited per reason prefix so a stall flagged every report interval
+produces one file, not one per interval. `cli blackbox` lists and renders them;
 `cli blackbox --chrome out.json` merges a dump into the chrome trace
 via observability/timeline.chrome_trace.
 """
@@ -40,11 +41,13 @@ from typing import Any, Dict, List, Optional
 # One dump per (reason prefix) per this many seconds — a stall that
 # stays stalled re-triggers on every telemetry reply otherwise.
 _DUMP_MIN_INTERVAL_S = 30.0
-_DEFAULT_DIR = "/tmp/ray_tpu/flight"
 
 
 def default_dir() -> str:
-    return _DEFAULT_DIR
+    """Beside the session directories (core/node.py `new_session_dir`):
+    whoever keeps those inside a checkout keeps the dumps there too."""
+    return os.path.join(
+        os.environ.get("RAY_TPU_TMPDIR", "/tmp/ray_tpu"), "flight")
 
 
 class FlightRecorder:
@@ -72,7 +75,7 @@ class FlightRecorder:
 
     def _dir(self) -> str:
         d = str(getattr(self._rt.cfg, "flight_recorder_dir", "") or "")
-        return d or _DEFAULT_DIR
+        return d or default_dir()
 
     def dump(self, reason: str, extra: Optional[Dict[str, Any]] = None,
              force: bool = False) -> Optional[str]:
@@ -125,7 +128,7 @@ class FlightRecorder:
 # --------------------------------------------------------------------------
 
 def list_dumps(directory: Optional[str] = None) -> List[str]:
-    d = directory or _DEFAULT_DIR
+    d = directory or default_dir()
     try:
         names = [n for n in os.listdir(d)
                  if n.startswith("flight-") and n.endswith(".json")]

@@ -37,6 +37,17 @@ Design here:
   RUNNING task older than `straggler_k` × p95 of >= `straggler_min_peers`
   completed peers raises a straggler event and a timeline instant.
 
+* **Which side stood still** — a beacon's deadline is tens of seconds
+  and cannot tell a frozen host from a device that does not answer. Two
+  clocks inside the process can: `FreezeWatcher` sleeps 0.1 s and notes
+  when it wakes more than a second late while a loop of the process is
+  under way (`stall::host_freeze`: the process, the host or the VM
+  stood still), and `WaitWatch` notes a device wait far above its
+  running median (`stall::device_wait`, with what had been
+  dispatched). Both late = the host froze; the wait long with the
+  watcher on time = the device or the runtime under jax did not
+  answer. Both record into the flight ring and dump it.
+
 This module is import-light (stdlib only at module scope) because the
 GCS imports it; `quantile_from_buckets` is pulled lazily inside the
 straggler check.
@@ -44,10 +55,14 @@ straggler check.
 
 from __future__ import annotations
 
+import logging
+import statistics
 import threading
 import time
 from collections import deque
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
+
+logger = logging.getLogger("ray_tpu.health")
 
 # Log-scale duration boundaries (seconds) for the per-task-name
 # completion histograms behind straggler p95 — same shape as the
@@ -141,6 +156,125 @@ def snapshot_beacons() -> List[dict]:
 def _reset_for_tests() -> None:
     with _beacons_lock:
         _beacons.clear()
+    _counters.update(host_freezes=0, host_freeze_s=0.0)
+
+
+# --------------------------------------------------------------------------
+# process side: which side stood still
+# --------------------------------------------------------------------------
+
+_counters: Dict[str, float] = {"host_freezes": 0, "host_freeze_s": 0.0}
+
+
+def counters() -> Dict[str, float]:
+    """Stalls this process has seen (`LLMServer.stats()` shows them)."""
+    return dict(_counters)
+
+
+def _stall(name: str, attrs: Dict[str, Any], reason: str) -> None:
+    """One stall: a WARNING line, an instant in the flight ring (and in
+    the profiler's trace, if one runs), and the ring dumped under the
+    recorder's rate limit. Never raises: it runs on hot paths."""
+    logger.warning("%s %s", name, attrs)
+    try:
+        from ray_tpu.core import runtime as _rt
+        from ray_tpu.util import tracing
+
+        tracing.instant(name, attrs, always=True)
+        rt = _rt.current_runtime_or_none()
+        if rt is not None:
+            rt.flight.dump(reason, extra=dict(
+                attrs, stall=name, counters=counters()))
+    except Exception:  # noqa: BLE001 - a diagnostic must not add a fault
+        logger.exception("could not record %s", name)
+
+
+class FreezeWatcher:
+    """Sleeps `PERIOD_S`; a wake-up more than `LATE_S` late by the
+    monotonic clock means that this process did not run for that long:
+    its host or VM froze, it was stopped, or every core was taken."""
+
+    PERIOD_S, LATE_S = 0.1, 1.0
+
+    def __init__(self, clock: Callable[[], float] = time.monotonic,
+                 sleep: Callable[[float], None] = time.sleep):
+        self._clock, self._sleep = clock, sleep
+        self._thread: Optional[threading.Thread] = None
+        self._start_lock = threading.Lock()
+
+    def run_once(self) -> Optional[float]:
+        """One sleep; returns how late it woke if that is a freeze."""
+        t0 = self._clock()
+        self._sleep(self.PERIOD_S)
+        late = self._clock() - t0 - self.PERIOD_S
+        if late <= self.LATE_S:
+            return None
+        _counters["host_freezes"] += 1
+        _counters["host_freeze_s"] += late
+        # A stall only where a loop of this process is under way: an
+        # armed beacon that has ticked. Opening a TPU freezes every
+        # process of its host for seconds at every job's start, before
+        # any loop's first step: routine, counted and logged, no more.
+        with _beacons_lock:
+            at_work = any(b.busy and b.count for b in _beacons.values())
+        if at_work:
+            _stall("stall::host_freeze", {"late_s": round(late, 3)},
+                   f"host_freeze:{late:.1f}s")
+        else:
+            logger.info("host froze %.3f s with no loop under way", late)
+        return late
+
+    def ensure_started(self) -> None:
+        with self._start_lock:
+            if self._thread is None:
+                self._thread = threading.Thread(
+                    target=self._loop, daemon=True,
+                    name="raytpu-freeze-watch")
+                self._thread.start()
+
+    def _loop(self) -> None:
+        while True:
+            self.run_once()
+
+
+_watcher = FreezeWatcher()
+
+
+def watch_host_freezes() -> None:
+    """Start this process's freeze watcher, once. The TelemetryAgent
+    calls it as its reporter starts, which every worker's does (the
+    reporter cannot stand in: it blocks in RPCs for seconds)."""
+    _watcher.ensure_started()
+
+
+class WaitWatch:
+    """Running median of one repeated wait for the device (a decode
+    block's fetch, the time from one `train.report` to the next). A wait
+    above `MIN_S` and `FACTOR` x the median is a stall."""
+
+    MIN_S, FACTOR, WARM = 2.0, 5.0, 4
+
+    def __init__(self, what: str):
+        self.what = what
+        self._recent: deque = deque(maxlen=64)
+
+    def observe(self, waited_s: float, **dispatched: Any) -> bool:
+        """`dispatched`: what the device had been given (scalars)."""
+        recent = self._recent
+        if waited_s <= self.MIN_S or len(recent) < self.WARM:
+            recent.append(waited_s)
+            return False
+        median = statistics.median(recent)
+        if waited_s <= self.FACTOR * median:
+            recent.append(waited_s)
+            return False
+        # a stall does not move the median
+        _stall("stall::device_wait",
+               {"waited_s": round(waited_s, 3), "what": self.what,
+                "median_s": round(median, 4),
+                "host_freezes": _counters["host_freezes"], **dispatched},
+               f"stall:device_wait:{self.what}")
+        return True
 
 
 # --------------------------------------------------------------------------

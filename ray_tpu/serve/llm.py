@@ -27,6 +27,10 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
+from ray_tpu.core import compile_cache as _compile_cache
+from ray_tpu.observability import health as _health
+from ray_tpu.util import tracing as _tracing
+
 logger = logging.getLogger("ray_tpu.serve.llm")
 
 
@@ -57,6 +61,9 @@ class _Request:
     # pulsed whenever generated grows (token-streaming consumers wait on it)
     progress: threading.Event = field(default_factory=threading.Event)
     submit_time: float = field(default_factory=time.time)
+    #: when it (re)joined the queue, if later than submit_time: a
+    #: preempted request's second wait is not counted from its submission
+    queued_time: Optional[float] = None
     first_token_time: Optional[float] = None
 
 
@@ -81,6 +88,7 @@ class LLMEngine:
         self._jax = jax
         self._jnp = jnp
         self._llama = llama
+        _compile_cache.listen()   # `compiles` in stats(), xla.compile
         if cfg is None:
             cfg = llama.PRESETS[preset]
             if jax.default_backend() != "tpu":
@@ -218,26 +226,37 @@ class LLMEngine:
         self._key = jax.random.PRNGKey(seed ^ 0x5eed)
         self._masks_dirty = True
 
+        # Every program is a named def: a device trace names a program
+        # after its function (`jit_serve_prefill`), and a lambda's name
+        # says nothing, so the device's time could not be split between
+        # admission and decoding.
         if kv_layout == "paged":
-            self._decode_paged = jax.jit(
-                lambda p, t, kp, vp, pt, ln, a: llama.decode_step_paged(
-                    p, t, kp, vp, pt, ln, cfg, active=a),
-                donate_argnums=(2, 3))
-            self._scatter = jax.jit(
-                lambda kp, vp, ks, vs, pt, sl, ln: llama.
-                scatter_prefill_pages(kp, vp, ks, vs, pt, sl, ln,
-                                      page_size),
-                donate_argnums=(0, 1))
+            def serve_decode_step_paged(p, t, kp, vp, pt, ln, a):
+                return llama.decode_step_paged(p, t, kp, vp, pt, ln, cfg,
+                                               active=a)
+
+            self._decode_paged = jax.jit(serve_decode_step_paged,
+                                         donate_argnums=(2, 3))
+
+            def serve_scatter_pages(kp, vp, ks, vs, pt, sl, ln):
+                return llama.scatter_prefill_pages(kp, vp, ks, vs, pt, sl,
+                                                   ln, page_size)
+
+            self._scatter = jax.jit(serve_scatter_pages,
+                                    donate_argnums=(0, 1))
+
             # chunked tail prefill against cached prefix pages: ONE
             # device call finishes a prefix-hit admission (token-by-token
             # draining costs a transport round trip per tail token)
-            self._prefill_tail = jax.jit(
-                lambda p, t, tl, pl, pt, kp, vp: llama.prefill_paged_tail(
-                    p, t, tl, pl, pt, kp, vp, cfg),
-                donate_argnums=(5, 6))
+            def serve_prefill_tail(p, t, tl, pl, pt, kp, vp):
+                return llama.prefill_paged_tail(p, t, tl, pl, pt, kp, vp,
+                                                cfg)
 
-            def _multi_paged(params, last, kp, vp, pt, ln, active, temps,
-                             key, n):
+            self._prefill_tail = jax.jit(serve_prefill_tail,
+                                         donate_argnums=(5, 6))
+
+            def serve_decode_block_paged(params, last, kp, vp, pt, ln,
+                                         active, temps, key, n):
                 def body(carry, _):
                     last, kp, vp, ln, key = carry
                     logits, kp, vp, ln = llama.decode_step_paged(
@@ -255,22 +274,32 @@ class LLMEngine:
                     body, (last, kp, vp, ln, key), None, length=n)
                 return toks, last, kp, vp, ln, key
 
-            self._decode_n_paged = jax.jit(_multi_paged, static_argnames="n",
-                                           donate_argnums=(2, 3))
+            self._decode_n_paged = jax.jit(
+                serve_decode_block_paged, static_argnames="n",
+                donate_argnums=(2, 3))
         else:
+            def serve_decode_step(p, t, c, a):
+                return llama.decode_step(p, t, c, cfg, active=a)
+
             self._decode = jax.jit(
-                lambda p, t, c, a: llama.decode_step(p, t, c, cfg, active=a),
+                serve_decode_step,
                 donate_argnums=(2,))  # cache aliases in place across calls
+
             # chunked-prefill twin for the contiguous layout: writes a
             # bounded token chunk into slot rows at their current fill
-            self._prefill_tail_contig = jax.jit(
-                lambda p, t, tl, pl, sl, c: llama.prefill_tail_contiguous(
-                    p, t, tl, pl, c, sl, cfg),
-                donate_argnums=(5,))
-        self._prefill = jax.jit(
-            lambda p, t, l: llama.prefill(p, t, l, cfg))  # noqa: E741
+            def serve_prefill_tail_contig(p, t, tl, pl, sl, c):
+                return llama.prefill_tail_contiguous(p, t, tl, pl, c, sl,
+                                                     cfg)
 
-        def _multi(params, last, cache, active, temps, key, n):
+            self._prefill_tail_contig = jax.jit(serve_prefill_tail_contig,
+                                                donate_argnums=(5,))
+
+        def serve_prefill(p, t, lens):
+            return llama.prefill(p, t, lens, cfg)
+
+        self._prefill = jax.jit(serve_prefill)
+
+        def serve_decode_block(params, last, cache, active, temps, key, n):
             # n fused decode steps with ON-DEVICE sampling: one host
             # round-trip per n tokens instead of per token (the per-step
             # logits fetch dominates decode latency on any transport)
@@ -289,8 +318,10 @@ class LLMEngine:
                 body, (last, cache, key), None, length=n)
             return toks, last, cache, key  # toks: [n, slots]
 
-        self._decode_n = jax.jit(_multi, static_argnames="n",
+        self._decode_n = jax.jit(serve_decode_block, static_argnames="n",
                                  donate_argnums=(2,))
+        # a fetch far above its running median is a stall (health.py)
+        self._fetch_watch = _health.WaitWatch("serve.decode_block.fetch")
 
         self.metrics = {"requests": 0, "tokens_generated": 0,
                         "ttft_sum": 0.0, "ttft_count": 0}
@@ -415,8 +446,6 @@ class LLMEngine:
         return 512
 
     def _admit(self):
-        import jax.numpy as jnp
-
         chunk = self._chunk_size()
         with self.lock:
             free = [i for i, s in enumerate(self.slots) if s is None]
@@ -461,9 +490,11 @@ class LLMEngine:
                                       chunked_new, admit)
                     self.pending.remove(r)
             self._prefilling.extend(chunked_new)
+            pending_after = len(self.pending)
+        self._note_admitted(chunked_new, admit)
         # advance every mid-prefill request (fresh prefix hits included)
         # by one bounded chunk — one device call for the whole set
-        self._prefill_round(chunk)
+        self._prefill_round(chunk, pending_after)
         if not admit:
             return
         P = self._bucket(max(len(r.prompt) for r in admit))
@@ -473,6 +504,29 @@ class LLMEngine:
             p = r.prompt[-P:]
             toks[i, :len(p)] = p
             lens[i] = len(p)
+        with _tracing.span("serve.prefill", {
+                "rows": len(admit), "batch_bucket": len(admit),
+                "length_bucket": P, "prompt_tokens": int(lens.sum()),
+                "pending_after": pending_after}):
+            self._prefill_plain(admit, toks, lens, P)
+
+    def _note_admitted(self, chunked_new: list, admit: list) -> None:
+        """Each request that just got its slot: how long it queued (from
+        its submission, or from its preemption), outside the lock."""
+        now = time.time()
+        # a plain admission has no fill yet; a chunked one starts at the
+        # tokens of the pages its prefix hit adopted (0 without a hit)
+        for r, hit in ([(r, r._filled) for r in chunked_new]
+                       + [(r, 0) for r in admit]):
+            wait = max(now - (r.queued_time or r.submit_time), 0.0)
+            _tracing.instant("serve.admitted", {
+                "queue_ms": wait * 1e3, "prompt_tokens": len(r.prompt),
+                "prefix_hit_tokens": hit})
+
+    def _prefill_plain(self, admit: list, toks, lens, P: int) -> None:
+        """One prefill program over the whole prompts of `admit`, their
+        KV written to the cache, their first tokens fetched."""
+        jnp = self._jnp
         logits, ks, vs = self._prefill(self.params, jnp.asarray(toks),
                                        jnp.asarray(lens))
         if self.kv_layout == "paged":
@@ -541,8 +595,6 @@ class LLMEngine:
             r.generated.append(int(first[i]))
             if r.first_token_time is None:
                 self._record_first_token(r, now)
-            self.metrics["tokens_generated"] += 1
-            self._m_tokens.inc()
             if (self.kv_layout == "paged" and self.prefix_caching
                     and r._filled < self.max_seq):
                 from ray_tpu.serve.paged_kv import page_chain_hashes
@@ -558,16 +610,22 @@ class LLMEngine:
                     list(r.prompt)[-r._filled:], self.pool.page_size))
             self._maybe_finish(r)
             r.progress.set()
+        self._count_tokens(len(pairs))
 
-    def _prefill_round(self, chunk: int):
+    def _count_tokens(self, n: int) -> None:
+        """One update per delivery, not one per token (a lock and a dict
+        update each in the cluster-visible counter)."""
+        if n:
+            self.metrics["tokens_generated"] += n
+            self._m_tokens.inc(n)
+
+    def _prefill_round(self, chunk: int, pending_after: int = 0):
         """One bounded prefill chunk for every mid-prefill request, in
         ONE device call (ref: vLLM chunked prefill scheduling — prefill
         advances between decode steps instead of monopolizing a round).
         Requests whose tail completes sample their first token here and
         join the next decode step; the rest stay masked out of decode
         and continue next round."""
-        import jax.numpy as jnp
-
         with self.lock:
             rows = list(self._prefilling)
         if not rows:
@@ -575,18 +633,32 @@ class LLMEngine:
         takes = [min(len(r._tail), chunk) for r in rows]
         Tb = self._bucket(max(takes))
         n = len(rows)
-        if self.kv_layout == "paged":
-            # pad the BATCH dim to a pow2 bucket: every distinct (n, T)
-            # shape is its own XLA program. Pad rows have tail_len 0, so
-            # their writes land in the trash page.
-            nb = 1
-            while nb < n:
-                nb *= 2
-        else:
+        with _tracing.span("serve.prefill", {
+                "rows": n, "batch_bucket": self._batch_bucket(n),
+                "length_bucket": Tb, "prompt_tokens": sum(takes),
+                "pending_after": pending_after}):
+            self._prefill_chunk(rows, takes, Tb)
+
+    def _batch_bucket(self, n: int) -> int:
+        """Rows of the chunked-prefill program that holds `n` requests."""
+        if self.kv_layout != "paged":
             # contiguous has no trash row a pad entry could safely
             # target, so the batch dim stays exact (bounded by
             # max_slots distinct programs)
-            nb = n
+            return n
+        # pad the BATCH dim to a pow2 bucket: every distinct (n, T)
+        # shape is its own XLA program. Pad rows have tail_len 0, so
+        # their writes land in the trash page.
+        nb = 1
+        while nb < n:
+            nb *= 2
+        return nb
+
+    def _prefill_chunk(self, rows: list, takes: list, Tb: int) -> None:
+        """The chunked-prefill program over one chunk of every row, and
+        the first tokens of the rows whose prompt it finished."""
+        jnp = self._jnp
+        n, nb = len(rows), self._batch_bucket(len(rows))
         toks = np.zeros((nb, Tb), np.int32)
         tl = np.zeros((nb,), np.int32)
         pl = np.zeros((nb,), np.int32)
@@ -745,6 +817,7 @@ class LLMEngine:
                     self._prefilling.remove(victim)
                 except ValueError:
                     pass
+            victim.queued_time = time.time()
             self.pending.insert(0, victim)
             self._table_dirty = True
             self._masks_dirty = True
@@ -829,12 +902,30 @@ class LLMEngine:
             self._masks_dirty = False
         return self._active_dev
 
-    def step(self) -> int:
+    def _live_context(self, active_reqs: list) -> int:
+        """Cached tokens the next decode step attends to, all slots."""
+        if self.kv_layout == "paged":
+            return int(sum(self._len_host[r.slot] for r in active_reqs))
+        return sum(self._seq_len(r) for r in active_reqs)
+
+    def _count_block(self, n: int, active: int, context: int,
+                     block_s: float, fetch_s: float) -> None:
+        """One decode block of `n` steps, the one-step block included."""
+        m = self.metrics
+        m["decode_block_s"] = m.get("decode_block_s", 0.0) + block_s
+        m["decode_blocks"] = m.get("decode_blocks", 0) + 1
+        self._m_decode_block.observe(block_s)
+        self._fetch_watch.observe(fetch_s, n=n, active=active,
+                                  context=context)
+
+    def step(self, n_asked: int = 1) -> int:
         """Admit + one decode step for all active slots. Returns number of
-        active requests after the step."""
+        active requests after the step. `n_asked`: the block `step_n`
+        wanted when it fell through to here (for the block's span)."""
         import jax.numpy as jnp
 
-        self._admit()
+        with _tracing.span("serve.admit"):
+            self._admit()
         with self.lock:
             active_reqs = [r for r in self.slots if self._decode_ready(r)]
             active_mask = np.array(
@@ -869,35 +960,53 @@ class LLMEngine:
                 occupied = sum(1 for s in self.slots if s is not None)
             if not active_reqs:
                 return occupied
-            # temps ride along so a later fused block never samples with
-            # a stale _temps_dev after this sync clears _masks_dirty
-            act = self._sync_paged_device_state(active_mask, np_temps)
-            logits, self.kp, self.vp, self._len_dev = self._decode_paged(
-                self.params, self._last, self.kp, self.vp, self._pt_dev,
-                self._len_dev, act)
-            self._len_host += active_mask
-        else:
-            logits, self.cache = self._decode(
-                self.params, self._last, self.cache, jnp.asarray(active_mask))
-        temps = [0.0] * self.max_slots
-        with self.lock:
-            for r in self.slots:
-                if r is not None:
-                    temps[r.slot] = r.temperature
-        toks = np.asarray(self._sample(logits, temps))
-        self._last = jnp.asarray(toks[:, None].astype(np.int32))
-        now = time.time()
-        for r in list(active_reqs):
-            if r.slot < 0:
-                continue
-            tok = int(toks[r.slot])
-            r.generated.append(tok)
-            if r.first_token_time is None:
-                self._record_first_token(r, now)
-            self.metrics["tokens_generated"] += 1
-            self._m_tokens.inc()
-            self._maybe_finish(r)
-            r.progress.set()
+        t_blk = time.perf_counter()
+        active, context = len(active_reqs), self._live_context(active_reqs)
+        with _tracing.span("serve.decode_block", {
+                "n": 1, "n_asked": n_asked, "active": active,
+                "max_slots": self.max_slots, "context": context}):
+            with _tracing.span("serve.decode_block.dispatch"):
+                if self.kv_layout == "paged":
+                    # temps ride along so a later fused block never
+                    # samples with a stale _temps_dev after this sync
+                    # clears _masks_dirty
+                    act = self._sync_paged_device_state(active_mask,
+                                                        np_temps)
+                    logits, self.kp, self.vp, self._len_dev = \
+                        self._decode_paged(
+                            self.params, self._last, self.kp, self.vp,
+                            self._pt_dev, self._len_dev, act)
+                    self._len_host += active_mask
+                else:
+                    logits, self.cache = self._decode(
+                        self.params, self._last, self.cache,
+                        jnp.asarray(active_mask))
+                temps = [0.0] * self.max_slots
+                with self.lock:
+                    for r in self.slots:
+                        if r is not None:
+                            temps[r.slot] = r.temperature
+                toks = self._sample(logits, temps)
+            with _tracing.span("serve.decode_block.fetch"):
+                t_fetch = time.perf_counter()
+                toks = np.asarray(toks)
+                t_done = time.perf_counter()
+        self._count_block(1, active, context, t_done - t_blk,
+                          t_done - t_fetch)
+        with _tracing.span("serve.deliver"):
+            self._last = jnp.asarray(toks[:, None].astype(np.int32))
+            now = time.time()
+            delivered = 0
+            for r in list(active_reqs):
+                if r.slot < 0:
+                    continue
+                r.generated.append(int(toks[r.slot]))
+                delivered += 1
+                if r.first_token_time is None:
+                    self._record_first_token(r, now)
+                self._maybe_finish(r)
+                r.progress.set()
+            self._count_tokens(delivered)
         with self.lock:
             return sum(1 for s in self.slots if s is not None)
 
@@ -910,9 +1019,10 @@ class LLMEngine:
         import jax
         import jax.numpy as jnp
 
-        t_adm = time.time()
-        self._admit()
-        adm = time.time() - t_adm
+        t_adm = time.perf_counter()   # not the wall clock: a stepped
+        with _tracing.span("serve.admit"):   # clock must not read as a stall
+            self._admit()
+        adm = time.perf_counter() - t_adm
         self.metrics["admit_s"] = self.metrics.get("admit_s", 0.0) + adm
         self._m_admit.inc(adm)
         with self.lock:
@@ -956,45 +1066,51 @@ class LLMEngine:
             if not active_reqs:
                 return occupied
         if n_eff <= 1:
-            return self.step()
-        t_blk = time.time()
-        if self.kv_layout == "paged":
-            act = self._sync_paged_device_state(active_mask, temps)
-            (toks, self._last, self.kp, self.vp, self._len_dev,
-             self._key) = self._decode_n_paged(
-                self.params, self._last, self.kp, self.vp, self._pt_dev,
-                self._len_dev, act, self._temps_dev, self._key, n_eff)
-            self._len_host += active_mask.astype(np.int64) * n_eff
-        else:
-            if self._masks_dirty:
-                self._active_dev = jnp.asarray(active_mask)
-                self._temps_dev = jnp.asarray(temps)
-                self._masks_dirty = False
-            toks, self._last, self.cache, self._key = self._decode_n(
-                self.params, self._last, self.cache,
-                self._active_dev, self._temps_dev, self._key, n_eff)
-        toks = np.asarray(toks)  # the block's single host fetch
-        now = time.time()
-        # per-block wall (dispatch + device + the one fetch): attributes
+            return self.step(n_asked=n)
+        t_blk = time.perf_counter()
+        active, context = len(active_reqs), self._live_context(active_reqs)
+        with _tracing.span("serve.decode_block", {
+                "n": n_eff, "n_asked": n, "active": active,
+                "max_slots": self.max_slots, "context": context}):
+            with _tracing.span("serve.decode_block.dispatch"):
+                if self.kv_layout == "paged":
+                    act = self._sync_paged_device_state(active_mask, temps)
+                    (toks, self._last, self.kp, self.vp, self._len_dev,
+                     self._key) = self._decode_n_paged(
+                        self.params, self._last, self.kp, self.vp,
+                        self._pt_dev, self._len_dev, act, self._temps_dev,
+                        self._key, n_eff)
+                    self._len_host += active_mask.astype(np.int64) * n_eff
+                else:
+                    if self._masks_dirty:
+                        self._active_dev = jnp.asarray(active_mask)
+                        self._temps_dev = jnp.asarray(temps)
+                        self._masks_dirty = False
+                    toks, self._last, self.cache, self._key = self._decode_n(
+                        self.params, self._last, self.cache,
+                        self._active_dev, self._temps_dev, self._key, n_eff)
+            with _tracing.span("serve.decode_block.fetch"):
+                t_fetch = time.perf_counter()
+                toks = np.asarray(toks)  # the block's single host fetch
+                t_done = time.perf_counter()
+        # per-block time (dispatch + device + the one fetch): attributes
         # serving throughput between engine time and transport weather
-        self.metrics["decode_block_s"] = \
-            self.metrics.get("decode_block_s", 0.0) + (now - t_blk)
-        self.metrics["decode_blocks"] = \
-            self.metrics.get("decode_blocks", 0) + 1
-        self.metrics["decode_block_tokens"] = \
-            self.metrics.get("decode_block_tokens", 0) + n_eff
-        self._m_decode_block.observe(now - t_blk)
-        for r in list(active_reqs):
-            for j in range(n_eff):
-                if r.slot < 0:
-                    break  # finished mid-block; surplus tokens dropped
-                r.generated.append(int(toks[j, r.slot]))
-                if r.first_token_time is None:   # defensive: admission
-                    self._record_first_token(r, now)  # normally did this
-                self.metrics["tokens_generated"] += 1
-                self._m_tokens.inc()
-                self._maybe_finish(r)
-            r.progress.set()
+        self._count_block(n_eff, active, context, t_done - t_blk,
+                          t_done - t_fetch)
+        with _tracing.span("serve.deliver"):
+            now = time.time()
+            delivered = 0
+            for r in list(active_reqs):
+                for j in range(n_eff):
+                    if r.slot < 0:
+                        break  # finished mid-block; surplus tokens dropped
+                    r.generated.append(int(toks[j, r.slot]))
+                    delivered += 1
+                    if r.first_token_time is None:   # defensive: admission
+                        self._record_first_token(r, now)  # normally did this
+                    self._maybe_finish(r)
+                r.progress.set()
+            self._count_tokens(delivered)
         with self.lock:
             return sum(1 for s in self.slots if s is not None)
 
@@ -1118,7 +1234,6 @@ class LLMServer:
         # admitted work, ticked per decode block — a wedged device step
         # (or a deadlocked engine lock) flags as a StallEvent instead of
         # silently freezing every in-flight stream
-        from ray_tpu.observability import health as _health
         self._beacon = _health.beacon("serve:decode", deadline_s=30.0)
         self._thread = threading.Thread(target=self._loop, daemon=True)
         self._thread.start()
@@ -1564,10 +1679,11 @@ class LLMServer:
                       for k, v in self._adopter.stats().items()})
         if m["ttft_count"]:
             m["mean_ttft_s"] = m["ttft_sum"] / m["ttft_count"]
-            p50 = self.engine._m_ttft.quantile(0.5)
-            if p50 is not None:
-                m["ttft_p50_s"] = p50
-                m["ttft_p99_s"] = self.engine._m_ttft.quantile(0.99)
+        # process-wide: programs built (none once every shape is warm)
+        # and times this process stood still (observability/health.py)
+        m["compiles"] = _compile_cache.compile_count()
+        m["compile_s"] = _compile_cache.compile_seconds()
+        m["host_freezes"] = _health.counters()["host_freezes"]
         if getattr(self.engine, "pool", None) is not None:
             m["prefix_cache"] = self.engine.pool.cache_stats()
         return m

@@ -12,8 +12,10 @@ import threading
 import time
 from typing import Any, Dict, List, Optional
 
+from ray_tpu.core import compile_cache as _compile_cache
 from ray_tpu.observability import health as _health
 from ray_tpu.train.checkpoint import Checkpoint, CheckpointManager
+from ray_tpu.util import tracing as _tracing
 
 # Step-loop progress beacon deadline: generous — "step" here means
 # report() cadence, and big-model steps plus a collective checkpoint
@@ -49,6 +51,10 @@ class TrainContext:
         self.ckpt_mgr = CheckpointManager(run_dir, num_to_keep)
         self.finished = False
         self._mesh = None
+        # one report to the next is one step of the user's loop, most of
+        # it a wait for the device: far above its median is a stall
+        self.step_watch = _health.WaitWatch("train.report interval")
+        self.last_report: Optional[float] = None   # perf_counter
 
 
 _ctx: Optional[TrainContext] = None
@@ -65,6 +71,7 @@ def _set_context(ctx: Optional[TrainContext]):
         _health.drop_beacon(f"train:r{_ctx.world_rank}")
     _ctx = ctx
     if ctx is not None:
+        _compile_cache.listen()        # if jax is imported by now
         # armed for the whole run: a rank that stops reporting past the
         # deadline (wedged collective, dead peer mid-allreduce) flags as
         # a StallEvent naming the rank. The run tag in the context lets
@@ -104,60 +111,84 @@ def report(metrics: Dict[str, Any], *, state: Any = None) -> None:
     rank that skips them hangs the gang. Single-process workers: rank 0's
     state is saved, other ranks' is ignored."""
     ctx = get_context()
+    attrs = {"has_state": state is not None}
+    if isinstance(metrics.get("step"), int):
+        attrs["step"] = metrics["step"]
+    with _tracing.span("train.report", attrs):
+        _report(ctx, metrics, state)
+
+
+def _report(ctx: TrainContext, metrics: Dict[str, Any], state: Any) -> None:
+    _compile_cache.listen()            # the loop has imported jax by now
+    now = time.perf_counter()
+    if ctx.last_report is not None:
+        ctx.step_watch.observe(now - ctx.last_report,
+                               step=metrics.get("step", len(ctx.reports)))
     entry = dict(metrics)
     entry["_ts"] = time.time()
     entry["_rank"] = ctx.world_rank
     ckpt_path = None
     if state is not None:
-        import jax
-
-        # Collective save: when the workers form one multi-host jax
-        # runtime, EVERY process must call from_state (orbax writes each
-        # process's addressable shards + a sync barrier). With independent
-        # single-process workers (process_count==1), rank 0 saves alone.
-        collective = jax.process_count() > 1
-        if ctx.world_rank == 0 or collective:
-            if collective:
-                import numpy as np
-                from jax.experimental import multihost_utils
-
-                # all ranks write into rank 0's checkpoint slot — a
-                # replacement rank with a fresh staging dir may disagree
-                # on the next index
-                idx = int(multihost_utils.broadcast_one_to_all(
-                    np.int32(ctx.ckpt_mgr._index)))
-                path = ctx.ckpt_mgr.new_dir(index=idx)
-            else:
-                path = ctx.ckpt_mgr.new_dir()
-            ck = Checkpoint.from_state(state, path)
-            if ctx.world_rank != 0 and collective:
-                # mirror this rank's shard files + evict per num_to_keep
-                # on this host; no marker, no remote eviction; synchronous
-                # so the barrier below really covers the upload
-                ctx.ckpt_mgr.register(path, primary=False)
-            if collective:
-                # the primary's completion marker must land after every
-                # rank's shard upload
-                multihost_utils.sync_global_devices("ray_tpu_ckpt_mirror")
-            if ctx.world_rank == 0:
-                # single-process mode mirrors on a background thread so
-                # the train loop isn't stalled for the upload
-                ctx.ckpt_mgr.register(path, primary=True,
-                                      sync=collective)
-                ctx.latest_checkpoint = ck
-                ckpt_path = ck.path
-                if ctx.ckpt_mgr.uri:
-                    import os as _os
-
-                    from ray_tpu.train import storage as _storage
-
-                    entry["_checkpoint_uri"] = _storage.join_uri(
-                        ctx.ckpt_mgr.uri, _os.path.basename(path))
+        with _tracing.span("train.checkpoint"):
+            ckpt_path = _save_checkpoint(ctx, state, entry)
     if ckpt_path:
         entry["_checkpoint"] = ckpt_path
     with ctx.report_lock:
         ctx.reports.append(entry)
     _health.beacon(f"train:r{ctx.world_rank}", _step_deadline(ctx)).tick()
+    ctx.last_report = time.perf_counter()   # a save is not a device wait
+
+
+def _save_checkpoint(ctx: TrainContext, state: Any, entry: dict
+                     ) -> Optional[str]:
+    """The collective (or rank 0's) save of `state`; returns the path
+    rank 0 registered, and puts the checkpoint's URI into `entry`."""
+    ckpt_path = None
+    import jax
+
+    # Collective save: when the workers form one multi-host jax
+    # runtime, EVERY process must call from_state (orbax writes each
+    # process's addressable shards + a sync barrier). With independent
+    # single-process workers (process_count==1), rank 0 saves alone.
+    collective = jax.process_count() > 1
+    if ctx.world_rank == 0 or collective:
+        if collective:
+            import numpy as np
+            from jax.experimental import multihost_utils
+
+            # all ranks write into rank 0's checkpoint slot — a
+            # replacement rank with a fresh staging dir may disagree
+            # on the next index
+            idx = int(multihost_utils.broadcast_one_to_all(
+                np.int32(ctx.ckpt_mgr._index)))
+            path = ctx.ckpt_mgr.new_dir(index=idx)
+        else:
+            path = ctx.ckpt_mgr.new_dir()
+        ck = Checkpoint.from_state(state, path)
+        if ctx.world_rank != 0 and collective:
+            # mirror this rank's shard files + evict per num_to_keep
+            # on this host; no marker, no remote eviction; synchronous
+            # so the barrier below really covers the upload
+            ctx.ckpt_mgr.register(path, primary=False)
+        if collective:
+            # the primary's completion marker must land after every
+            # rank's shard upload
+            multihost_utils.sync_global_devices("ray_tpu_ckpt_mirror")
+        if ctx.world_rank == 0:
+            # single-process mode mirrors on a background thread so
+            # the train loop isn't stalled for the upload
+            ctx.ckpt_mgr.register(path, primary=True,
+                                  sync=collective)
+            ctx.latest_checkpoint = ck
+            ckpt_path = ck.path
+            if ctx.ckpt_mgr.uri:
+                import os as _os
+
+                from ray_tpu.train import storage as _storage
+
+                entry["_checkpoint_uri"] = _storage.join_uri(
+                    ctx.ckpt_mgr.uri, _os.path.basename(path))
+    return ckpt_path
 
 
 def get_checkpoint() -> Optional[Checkpoint]:

@@ -13,6 +13,17 @@ riding the existing task-event channel to the GCS (task_event_buffer.h:199
 analog), so one store serves task states AND spans, and `ray_tpu.timeline()`
 / the CLI export both as one Chrome trace. Context propagation is a
 contextvar here + a `trace_ctx` field on TaskSpec there.
+
+One clock for host and device: a span is ALSO an event of jax's profiler.
+Where `jax` is already imported, `span` and `instant` run inside a
+`jax.profiler.TraceAnnotation` of the same name, whether or not tracing
+is enabled: while a profile runs, the event lands in the `/host:CPU`
+plane of the `.xplane.pb`, on the calling thread's line, with its scalar
+attributes as stats and its start on the axis of the device planes, so a
+device idle gap can be put down to the span that covers it
+(`benchmark/host_plane.py` reads them). With no profile running the
+annotation is inactive and records nothing. This module never imports
+jax itself: a parent that only spawns workers stays off the chip.
 """
 
 from __future__ import annotations
@@ -20,6 +31,7 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import os
+import sys
 import time
 from typing import Any, Dict, Optional
 
@@ -56,6 +68,22 @@ def current_context() -> Optional[Dict[str, str]]:
     return _ctx.get()
 
 
+def _annotation(name: str, attributes: Optional[Dict[str, Any]]):
+    """The profiler's half of a span: an unopened TraceAnnotation, or
+    None where jax is not imported (yet). It takes its attributes when it
+    opens, and only scalars: what a span learns at its end goes on a
+    child or on an instant recorded as it closes."""
+    jax = sys.modules.get("jax")
+    profiler = getattr(jax, "profiler", None)
+    if profiler is None:
+        return None
+    if not attributes:
+        return profiler.TraceAnnotation(name)
+    return profiler.TraceAnnotation(name, **{
+        k: v for k, v in attributes.items()
+        if isinstance(v, (bool, int, float, str))})
+
+
 def _record(span: Dict[str, Any]) -> None:
     try:
         from ray_tpu import _rt
@@ -72,10 +100,14 @@ def span(name: str, attributes: Optional[Dict[str, Any]] = None):
     Nested spans chain; spans created inside a task continue the
     submitting caller's trace (a live parent context counts as opt-in
     even when this process never called enable() — that's how worker
-    processes participate). No-op when tracing is off."""
+    processes participate). With tracing off nothing is recorded or
+    sent; the body still runs inside the profiler's annotation (module
+    docstring), which is inactive unless a profile is running."""
+    ann = _annotation(name, attributes) or contextlib.nullcontext()
     parent = _ctx.get()
     if not (is_enabled() or parent is not None):
-        yield None
+        with ann:
+            yield None
         return
     rec = {
         "kind": "span",
@@ -89,7 +121,8 @@ def span(name: str, attributes: Optional[Dict[str, Any]] = None):
     token = _ctx.set({"trace_id": rec["trace_id"],
                       "span_id": rec["span_id"]})
     try:
-        yield rec
+        with ann:
+            yield rec
     except BaseException as e:
         rec["attrs"]["error"] = repr(e)
         raise
@@ -97,6 +130,32 @@ def span(name: str, attributes: Optional[Dict[str, Any]] = None):
         _ctx.reset(token)
         rec["dur"] = time.time() - rec["ts"]
         _record(rec)
+
+
+def instant(name: str, attributes: Optional[Dict[str, Any]] = None, *,
+            always: bool = False) -> Optional[dict]:
+    """A zero-length span: a value with a time (one admitted request, one
+    compile, one stall). Same two halves and the same opt-in rule as
+    span(); `always` records it with tracing off too, for the rare event
+    a post-mortem must hold (a stall). `timeline.chrome_trace` renders
+    the record as an instant event."""
+    ann = _annotation(name, attributes)
+    if ann is not None:
+        with ann:
+            pass
+    parent = _ctx.get()
+    if not (always or is_enabled() or parent is not None):
+        return None
+    rec = {
+        "kind": "instant",
+        "name": name,
+        "trace_id": parent["trace_id"] if parent else None,
+        "parent_id": parent["span_id"] if parent else None,
+        "ts": time.time(),
+        "attrs": dict(attributes or {}),
+    }
+    _record(rec)
+    return rec
 
 
 def emit_span(name: str, ts: float, dur: float,

@@ -187,6 +187,9 @@ def test_task_summary_and_timeline(dash):
         return x * 2
 
     assert ray_tpu.get([work.remote(i) for i in range(3)]) == [0, 2, 4]
+    # a stall instant shares the store and has no task_id: not a task row
+    from ray_tpu.util import tracing
+    tracing.instant("stall::host_freeze", {"late_s": 3.0}, always=True)
     ray_tpu._rt.get_runtime().flush_task_events(wait=True)
 
     _, _, body = _get(dash + "/api/v0/task_summary")
