@@ -47,12 +47,20 @@ class LlamaConfig:
     # apply the band mask (the decode cache stays max_seq-sized; only
     # the attention is banded). Unsupported with ring/ulysses.
     sliding_window: Any = None
-    remat: bool = True               # jax.checkpoint each layer (HBM savings)
-    # What the per-layer checkpoint may keep: "none" (full recompute,
-    # maximum HBM savings) or "dots" (save matmul outputs, recompute only
-    # elementwise/norms — jax.checkpoint_policies
-    # .dots_with_no_batch_dims_saveable). "dots" trades a little HBM for
-    # skipping the matmul recompute in the backward.
+    # jax.checkpoint each layer: the backward keeps the layer's input and
+    # rebuilds the rest (HBM savings), but for what remat_policy names.
+    remat: bool = True
+    # What the per-layer checkpoint may keep beside the layer input.
+    # "none": only the flash kernel's output and log-sum-exp
+    # (ops/flash_attention.py FLASH_RESIDUALS) — the output is as large
+    # as the layer input itself, B x S x D x 2 bytes a layer in bf16, the
+    # log-sum-exp B x H x S x 4, and rebuilding them costs a second
+    # launch of the forward kernel over S^2; everything else is
+    # recomputed. Without the kernel in the layer (attn_impl other than
+    # "flash", under 128 tokens) "none" keeps nothing. "dots": those two
+    # and the matmul outputs (jax.checkpoint_policies
+    # .dots_with_no_batch_dims_saveable), recomputing only
+    # elementwise/norms: more HBM for no matmul recompute in the backward.
     remat_policy: str = "none"
     # Concatenate wq/wk/wv (and w_gate/w_up) into single wider matmuls at
     # apply time. Same params/checkpoints; at small d_model the wider N
@@ -225,15 +233,22 @@ def _embed(params, tokens, dt):
 
 
 def _checkpoint(body, cfg: "LlamaConfig"):
+    """Per-layer jax.checkpoint. Either policy keeps the flash kernel's
+    output and log-sum-exp (FLASH_RESIDUALS), so the backward kernels run
+    from them and the forward kernel runs once; a body without the
+    kernel holds no such name and nothing more is saved."""
+    from ray_tpu.ops.flash_attention import FLASH_RESIDUALS
+
+    policies = jax.checkpoint_policies
+    policy = policies.save_only_these_names(*FLASH_RESIDUALS)
     if cfg.remat_policy == "dots":
-        return jax.checkpoint(
-            body,
-            policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
-    if cfg.remat_policy != "none":
+        policy = policies.save_from_both_policies(
+            policies.dots_with_no_batch_dims_saveable, policy)
+    elif cfg.remat_policy != "none":
         raise ValueError(
             f"remat_policy must be 'none' or 'dots', got "
             f"{cfg.remat_policy!r}")
-    return jax.checkpoint(body)
+    return jax.checkpoint(body, policy=policy)
 
 
 def rms_norm(x, scale, eps):

@@ -14,6 +14,17 @@ BACKWARD_IMPL for debugging.
 GQA is handled in the kernel via the k/v index maps (kv_head = head // group)
 — no KV broadcast materialization.
 
+Across a checkpoint: the forward rule names the two residuals only the
+kernel can produce, FLASH_RESIDUALS = the output `o` ([B, S, H, D] in
+q.dtype: as large as the layer input a per-layer checkpoint already keeps)
+and the log-sum-exp `lse`, held compact as [B, H, S] f32 (the kernel writes
+it lane-broadcast over 128 lanes, twice the size of `o`; the backward
+broadcasts it back). A `jax.checkpoint` whose policy saves those names
+(models/llama.py::_checkpoint) runs the backward kernels from them; one
+that does not runs the forward kernel a second time, a launch over S^2,
+only to rebuild them. q, k and v carry no name: they are rebuilt from the
+layer input by their projections.
+
 Shapes: q [B, S, H, D], k/v [B, T, KV, D], output [B, S, H, D].
 """
 
@@ -24,10 +35,13 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+# checkpoint_name tags of what _flash_vjp_fwd hands the backward: (o, lse)
+FLASH_RESIDUALS = ("flash_out", "flash_lse")
 
 
 def _use_interpret() -> bool:
@@ -356,6 +370,8 @@ def _flash_pallas_bwd(res, g, *, causal: bool, block_q: int, block_k: int,
     """Full Pallas backward: two kernels (dQ; dK/dV), GQA group-sum on the
     dK/dV results (FlashAttention-2, Dao 2023)."""
     q, k, v, out, lse = res
+    # the residual is compact [B, H, S]; the kernels read 128-lane blocks
+    lse = jnp.broadcast_to(lse[..., None], lse.shape + (128,))
     B, S, H, D = q.shape
     T, KV = k.shape[1], k.shape[2]
     groups = H // KV
@@ -427,8 +443,7 @@ def _reference_chunked_bwd(res, g, *, causal: bool, chunk: int,
                            window: int = 0):
     """Recompute-based backward, chunked over the key axis to stay O(S*chunk)
     in memory. Uses the forward's lse so probabilities are exact."""
-    q, k, v, out, lse = res
-    lse = lse[..., 0]                                  # drop lane broadcast
+    q, k, v, out, lse = res                            # lse [B, H, S]
     B, S, H, D = q.shape
     T, KV = k.shape[1], k.shape[2]
     groups = H // KV
@@ -489,6 +504,9 @@ def _flash(q, k, v, causal, block_q, block_k, window):
 def _flash_vjp_fwd(q, k, v, causal, block_q, block_k, window):
     out, lse = _flash_fwd(q, k, v, causal=causal, block_q=block_q,
                           block_k=block_k, window=window)
+    out = checkpoint_name(out, FLASH_RESIDUALS[0])
+    # drop the lane broadcast: [B, H, S, 128] -> [B, H, S]
+    lse = checkpoint_name(lse[..., 0], FLASH_RESIDUALS[1])
     return out, (q, k, v, out, lse)
 
 

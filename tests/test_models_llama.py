@@ -228,6 +228,73 @@ def test_remat_policy_dots_grad_parity():
         np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6), g1, g2)
 
 
+def _pallas_calls(jaxpr):
+    """pallas_call equations of a jaxpr, sub-jaxprs (scan, checkpoint,
+    custom_vjp, shard_map bodies) included."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == "pallas_call"
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            n += _pallas_calls(sub)
+    return n
+
+
+def _flash_cfg(remat, policy="none"):
+    # S128 is the least length that takes the kernel (llama._attention)
+    return CFG.replace(attn_impl="flash", remat=remat, remat_policy=policy)
+
+
+def _flash_batch():
+    return {"tokens": jax.random.randint(jax.random.PRNGKey(12), (2, 129), 0,
+                                         CFG.vocab_size)}
+
+
+@pytest.mark.parametrize("policy", ["none", "dots"])
+def test_remat_keeps_flash_residuals_call_count(policy):
+    """The per-layer checkpoint keeps the kernel's output and log-sum-exp,
+    so the backward holds forward, dq and dkdv — the same three calls as
+    without remat — and not the forward a second time."""
+    params, batch = llama.init_params(jax.random.PRNGKey(11), CFG), \
+        _flash_batch()
+
+    def calls(cfg):
+        return _pallas_calls(jax.make_jaxpr(jax.grad(
+            lambda p: llama.loss_fn(p, batch, cfg)))(params).jaxpr)
+
+    assert calls(_flash_cfg(True, policy)) == 3
+    assert calls(_flash_cfg(False)) == 3
+
+
+@pytest.mark.parametrize("policy", ["none", "dots"])
+def test_remat_keeps_flash_residuals_grad_parity(policy):
+    """What the checkpoint saves never changes the math: loss and every
+    gradient leaf match the program without remat."""
+    params, batch = llama.init_params(jax.random.PRNGKey(11), CFG), \
+        _flash_batch()
+    l1, g1 = jax.value_and_grad(
+        lambda p: llama.loss_fn(p, batch, _flash_cfg(False)))(params)
+    l2, g2 = jax.value_and_grad(
+        lambda p: llama.loss_fn(p, batch, _flash_cfg(True, policy)))(params)
+    np.testing.assert_allclose(float(l1), float(l2), rtol=1e-6)
+    jax.tree.map(lambda a, b: np.testing.assert_allclose(
+        np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6), g1, g2)
+
+
+@pytest.mark.parametrize("policy", ["none", "dots"])
+def test_remat_keeps_flash_residuals_under_shard_map(policy):
+    """The same count where the kernel runs per shard under shard_map
+    (fsdp x tp on four virtual devices): the names survive the map."""
+    mesh = build_mesh(MeshSpec(fsdp=2, tp=2), devices=jax.devices()[:4])
+    rules = ShardingRules.fsdp_tp()
+    cfg = _flash_cfg(True, policy)
+    params, batch = llama.init_params(jax.random.PRNGKey(11), CFG), \
+        _flash_batch()
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p: llama.loss_fn(
+        p, batch, cfg, mesh=mesh, rules=rules)))(params).jaxpr
+    assert "shard_map" in str(jaxpr)
+    assert _pallas_calls(jaxpr) == 3
+
+
 def test_bf16_logits_flag():
     """f32_logits=False keeps logits in the compute dtype; loss still
     computes its reductions in f32 and matches the f32-logits loss."""

@@ -139,6 +139,37 @@ def test_pallas_bwd_matches_chunked_bwd():
                                    rtol=3e-3, atol=3e-3)
 
 
+def test_flash_residuals_compact_lse_feeds_both_backwards(monkeypatch):
+    """The forward rule hands the backward `lse` as [B, H, S], not the
+    kernel's 128-lane broadcast (which would be twice the size of `o`
+    where a checkpoint keeps it), and both backward implementations give
+    the same gradients from that one residual tuple."""
+    import sys
+
+    fa = sys.modules["ray_tpu.ops.flash_attention"]
+    B, S, H, KV, D = 1, 128, 4, 2, 32
+    q, k, v = _make(B=B, S=S, H=H, KV=KV, D=D)
+    out, res = fa._flash_vjp_fwd(q, k, v, True, 32, 32, 0)
+    assert [r.shape for r in res] == [
+        (B, S, H, D), (B, S, KV, D), (B, S, KV, D), (B, S, H, D), (B, H, S)]
+    assert res[4].dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(res[3]), np.asarray(out))
+    # lse is the row's log-sum-exp of the masked, scaled scores
+    s = jnp.einsum("bshd,bthd->bhst", q, jnp.repeat(k, H // KV, axis=2))
+    s = jnp.where(jnp.tril(jnp.ones((S, S), bool)), s * D ** -0.5, -jnp.inf)
+    np.testing.assert_allclose(np.asarray(res[4]), np.asarray(
+        jax.nn.logsumexp(s, axis=-1)), rtol=1e-4, atol=1e-4)
+
+    g = jax.random.normal(jax.random.PRNGKey(9), out.shape, out.dtype)
+    grads = {}
+    for impl in ("pallas", "chunked"):
+        monkeypatch.setattr(fa, "BACKWARD_IMPL", impl)
+        grads[impl] = fa._flash_vjp_bwd(True, 32, 32, 0, res, g)
+    for a, b in zip(grads["pallas"], grads["chunked"]):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=3e-3, atol=3e-3)
+
+
 def test_ulysses_matches_reference():
     from ray_tpu.ops.ulysses import ulysses_attention
 
