@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import sys
 from dataclasses import dataclass
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
@@ -232,15 +233,26 @@ def _embed(params, tokens, dt):
     return w.astype(dt)[tokens]
 
 
+def _family(cfg: "LlamaConfig"):
+    """The module that defines ``cfg``'s class: this one for the dense
+    model, ``models/moe.py`` for ``MoEConfig``. It supplies the layer's
+    feed-forward half (``feed_forward``), the names that half wants kept
+    across the layer checkpoint (``REMAT_SAVED``) and, where the
+    feed-forward returns statistics, ``finish_loss``."""
+    return sys.modules[type(cfg).__module__]
+
+
 def _checkpoint(body, cfg: "LlamaConfig"):
     """Per-layer jax.checkpoint. Either policy keeps the flash kernel's
     output and log-sum-exp (FLASH_RESIDUALS), so the backward kernels run
-    from them and the forward kernel runs once; a body without the
-    kernel holds no such name and nothing more is saved."""
+    from them and the forward kernel runs once, and what the family's
+    feed-forward names (REMAT_SAVED: an expert layer's routes); a body
+    that holds no such name saves nothing more."""
     from ray_tpu.ops.flash_attention import FLASH_RESIDUALS
 
     policies = jax.checkpoint_policies
-    policy = policies.save_only_these_names(*FLASH_RESIDUALS)
+    policy = policies.save_only_these_names(
+        *FLASH_RESIDUALS, *_family(cfg).REMAT_SAVED)
     if cfg.remat_policy == "dots":
         policy = policies.save_from_both_policies(
             policies.dots_with_no_batch_dims_saveable, policy)
@@ -360,16 +372,24 @@ def _attention(q, k, v, cfg: LlamaConfig, causal=True, q_offset=0,
     return _attention_xla(q, k, v, causal, q_offset, window=win)
 
 
-def _layer(x, lp, cfg: LlamaConfig, cos, sin, cache=None, collect_kv=False,
-           mesh=None, rules=None):
-    """One transformer block. x: [B, S, D]. cache: (k, v, offset) or None.
-    collect_kv=True returns this layer's (k, v) for cache seeding.
-    mesh+rules reach the flash kernel's shard_map (see _flash_sharded)."""
+def _attention_half(x, lp, cfg: LlamaConfig, cos, sin, cache=None, mesh=None,
+                    rules=None):
+    """The attention half of a block: x [B, S, D] -> (x + attention, k, v,
+    new_cache). cache: (k, v, offset) or None. With ``cfg.qk_norm`` (an
+    OLMoE block) q and k pass an RMS norm over the WHOLE projected vector,
+    one learned scale each (``q_norm``, ``k_norm``), before the split into
+    heads. mesh+rules reach the flash kernel's shard_map (_flash_sharded)."""
     B, S, D = x.shape
     H, KV, HD = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     dt = cfg.dtype
 
     h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+
+    def heads(y, n, norm=None):
+        if norm is not None and getattr(cfg, "qk_norm", False):
+            y = rms_norm(y, lp[norm], cfg.norm_eps)
+        return y.reshape(B, S, n, HD)
+
     if cfg.fused_matmuls:
         # One [D, (H+2KV)*HD] matmul instead of three: at small d_model the
         # MXU is launch/tile-bound, so widening N raises utilization.
@@ -377,13 +397,11 @@ def _layer(x, lp, cfg: LlamaConfig, cos, sin, cache=None, collect_kv=False,
                                 _dq(lp["wv"], dt)], axis=-1)
         qkv = h @ wqkv
         q, k, v = jnp.split(qkv, [H * HD, (H + KV) * HD], axis=-1)
-        q = q.reshape(B, S, H, HD)
-        k = k.reshape(B, S, KV, HD)
-        v = v.reshape(B, S, KV, HD)
+        q, k, v = heads(q, H, "q_norm"), heads(k, KV, "k_norm"), heads(v, KV)
     else:
-        q = (h @ _dq(lp["wq"], dt)).reshape(B, S, H, HD)
-        k = (h @ _dq(lp["wk"], dt)).reshape(B, S, KV, HD)
-        v = (h @ _dq(lp["wv"], dt)).reshape(B, S, KV, HD)
+        q = heads(h @ _dq(lp["wq"], dt), H, "q_norm")
+        k = heads(h @ _dq(lp["wk"], dt), KV, "k_norm")
+        v = heads(h @ _dq(lp["wv"], dt), KV)
     q = apply_rope(q, cos, sin)
     k = apply_rope(k, cos, sin)
 
@@ -401,9 +419,21 @@ def _layer(x, lp, cfg: LlamaConfig, cos, sin, cache=None, collect_kv=False,
     else:
         attn = _attention(q, k, v, cfg, causal=True, mesh=mesh, rules=rules)
     attn = attn.reshape(B, S, H * HD)
-    x = x + attn @ _dq(lp["wo"], dt)
+    return x + attn @ _dq(lp["wo"], dt), k, v, new_cache
 
-    h = rms_norm(x, lp["ffn_norm"], cfg.norm_eps)
+
+# checkpoint_name tags the dense feed-forward wants kept across the layer
+# checkpoint: none (see _family)
+REMAT_SAVED = ()
+
+
+def feed_forward(h, lp, cfg: LlamaConfig, mesh=None, rules=None):
+    """The dense feed-forward half of a block, a SwiGLU: normed h
+    [B, S, D] -> (its output [B, S, D], None). The second value is what a
+    family's feed-forward reports of itself layer by layer (an expert
+    layer's routing statistics, models/moe.py); the dense one has nothing
+    to report."""
+    dt = cfg.dtype
     if cfg.fused_matmuls:
         w_gu = jnp.concatenate([_dq(lp["w_gate"], dt),
                                 _dq(lp["w_up"], dt)], axis=-1)
@@ -413,10 +443,22 @@ def _layer(x, lp, cfg: LlamaConfig, cos, sin, cache=None, collect_kv=False,
     else:
         gate = jax.nn.silu(h @ _dq(lp["w_gate"], dt))
         up = h @ _dq(lp["w_up"], dt)
-    x = x + (gate * up) @ _dq(lp["w_down"], dt)
-    if collect_kv:
-        return x, (k, v)
-    return x, new_cache
+    return (gate * up) @ _dq(lp["w_down"], dt), None
+
+
+def _layer(x, lp, cfg: LlamaConfig, cos, sin, cache=None, collect_kv=False,
+           mesh=None, rules=None):
+    """One transformer block: the attention half, then the family's
+    feed-forward half (dense SwiGLU here, the expert layer for a
+    MoEConfig). x: [B, S, D]. Returns (x, kv, stats): kv is the updated
+    (k, v) cache slices when ``cache`` is given, this layer's (k, v) with
+    collect_kv=True (cache seeding), else None; stats is what the
+    feed-forward reports (None for the dense one)."""
+    x, k, v, new_cache = _attention_half(x, lp, cfg, cos, sin, cache=cache,
+                                         mesh=mesh, rules=rules)
+    h = rms_norm(x, lp["ffn_norm"], cfg.norm_eps)
+    y, stats = _family(cfg).feed_forward(h, lp, cfg, mesh=mesh, rules=rules)
+    return x + y, ((k, v) if collect_kv else new_cache), stats
 
 
 def _act_constraint(mesh, rules):
@@ -440,6 +482,13 @@ def forward(params, tokens, cfg: LlamaConfig, pos_offset=0, mesh=None,
     pos_offset shifts RoPE positions (sequence-parallel shards pass their
     global chunk offset). mesh+rules (optional) pin per-layer activation
     shardings (see _act_constraint)."""
+    return forward_with_stats(params, tokens, cfg, pos_offset, mesh, rules)[0]
+
+
+def forward_with_stats(params, tokens, cfg: LlamaConfig, pos_offset=0,
+                       mesh=None, rules=None):
+    """``forward`` and what every layer's feed-forward reported, stacked
+    over layers (None for the dense model): (logits, stats)."""
     dt = cfg.dtype
     B, S = tokens.shape
     con = _act_constraint(mesh, rules)
@@ -453,20 +502,24 @@ def forward(params, tokens, cfg: LlamaConfig, pos_offset=0, mesh=None,
         sin = jax.lax.dynamic_slice_in_dim(sin_full, pos_offset, S, axis=0)
 
     def body(x, lp):
-        y, _ = _layer(x, lp, cfg, cos, sin, mesh=mesh, rules=rules)
-        return con(y), None
+        y, _, stats = _layer(x, lp, cfg, cos, sin, mesh=mesh, rules=rules)
+        return con(y), stats
 
     if cfg.remat:
         body = _checkpoint(body, cfg)
     if cfg.scan_layers:
-        x, _ = jax.lax.scan(body, x, params["layers"])
+        x, stats = jax.lax.scan(body, x, params["layers"])
     else:
+        per_layer = []
         for i in range(cfg.n_layers):
             lp = jax.tree.map(lambda a: a[i], params["layers"])
-            x, _ = body(x, lp)
+            x, st = body(x, lp)
+            per_layer.append(st)
+        stats = None if per_layer[0] is None else jax.tree.map(
+            lambda *a: jnp.stack(a), *per_layer)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = x @ _dq(params["lm_head"], dt)
-    return logits.astype(jnp.float32) if cfg.f32_logits else logits
+    return (logits.astype(jnp.float32) if cfg.f32_logits else logits), stats
 
 
 def forward_sp(params, tokens, cfg: LlamaConfig, mesh):
@@ -510,8 +563,7 @@ def forward_pp(params, tokens, cfg: LlamaConfig, mesh, num_microbatches=None):
 
     def stage_fn(stage_layers, x):
         def body(x, lp):
-            y, _ = _layer(x, lp, cfg, cos, sin)
-            return y, None
+            return _layer(x, lp, cfg, cos, sin)[0], None
 
         if cfg.remat:
             body = _checkpoint(body, cfg)
@@ -530,7 +582,9 @@ def loss_fn(params, batch, cfg: LlamaConfig, mesh=None, rules=None):
     """Next-token cross-entropy. batch: {"tokens": [B, S+1]} or
     {"inputs": [B,S], "targets": [B,S], optional "mask": [B,S]}.
     mesh+rules pin activation shardings in the dense path (required for
-    HBM-tight FSDP configs; see _act_constraint)."""
+    HBM-tight FSDP configs; see _act_constraint). A scalar for the dense
+    model; for a family with ``finish_loss`` (models/moe.py) the pair
+    ``(loss, aux)`` that ``parallel.make_train_step`` takes."""
     if "tokens" in batch:
         inputs, targets = batch["tokens"][:, :-1], batch["tokens"][:, 1:]
         mask = batch.get("mask")
@@ -539,13 +593,19 @@ def loss_fn(params, batch, cfg: LlamaConfig, mesh=None, rules=None):
     else:
         inputs, targets = batch["inputs"], batch["targets"]
         mask = batch.get("mask")
+    stats = None
     if (cfg.attn_impl in ("ring", "ulysses") and mesh is not None
             and int(mesh.shape.get("sp", 1)) > 1):
         logits = forward_sp(params, inputs, cfg, mesh)
     elif mesh is not None and int(mesh.shape.get("pp", 1)) > 1:
         logits = forward_pp(params, inputs, cfg, mesh)
     else:
-        logits = forward(params, inputs, cfg, mesh=mesh, rules=rules)
+        logits, stats = forward_with_stats(params, inputs, cfg, mesh=mesh,
+                                           rules=rules)
+    finish = getattr(_family(cfg), "finish_loss", None)
+    if finish is not None and stats is None:
+        raise ValueError("a model whose loss needs its layers' statistics "
+                         "(router losses) does not train under sp or pp")
     # nll = logsumexp(logits) - logit[target]: same value/gradient as
     # log_softmax + gather but never materializes the [B, S, V] log_softmax
     # tensor (1 GB f32 at B=8 S=1024 V=32k — pure HBM traffic).
@@ -554,9 +614,12 @@ def loss_fn(params, batch, cfg: LlamaConfig, mesh=None, rules=None):
                              axis=-1)[..., 0].astype(jnp.float32)
     nll = lse - ll
     if mask is None:
-        return nll.mean()
-    mask = mask.astype(nll.dtype)
-    return (nll * mask).sum() / jnp.maximum(mask.sum(), 1.0)
+        loss = nll.mean()
+    else:
+        mask = mask.astype(nll.dtype)
+        loss = (nll * mask).sum() / jnp.maximum(mask.sum(), 1.0)
+    # an expert model adds its router losses and returns (loss, aux)
+    return loss if finish is None else finish(loss, stats, cfg)
 
 
 # --- inference (KV cache) ---------------------------------------------------
@@ -622,7 +685,7 @@ def prefill(params, tokens, lengths, cfg: LlamaConfig):
     cos, sin = _rope_tables(cfg.rope_theta, P, cfg.head_dim)
 
     def body(x, lp):
-        y, kv = _layer(x, lp, cfg, cos, sin, collect_kv=True)
+        y, kv, _ = _layer(x, lp, cfg, cos, sin, collect_kv=True)
         return y, kv
 
     x, (ks, vs) = jax.lax.scan(body, x, params["layers"])
@@ -1028,8 +1091,8 @@ def forward_with_cache(params, tokens, cache: KVCache, cfg: LlamaConfig,
     sin = jax.lax.dynamic_slice_in_dim(sin_full, offset, S, axis=0)
 
     def body(x, lp, ck, cv):
-        y, (nk_l, nv_l) = _layer(x, lp, cfg, cos, sin,
-                                 cache=(ck, cv, offset))
+        y, (nk_l, nv_l), _ = _layer(x, lp, cfg, cos, sin,
+                                    cache=(ck, cv, offset))
         return y, nk_l, nv_l
 
     x, nk, nv = _layer_scan_with_kv(body, x, cache.k, cache.v,

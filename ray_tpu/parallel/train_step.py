@@ -108,22 +108,28 @@ def make_train_state_init(init_params_fn: Callable, optimizer, mesh,
 
 def make_train_step(loss_fn: Callable, optimizer, mesh, rules: ShardingRules,
                     state_shardings, batch_shapes=None, donate: bool = True):
-    """loss_fn(params, batch) -> scalar. Returns jitted
-    step(state, batch) -> (state, metrics)."""
+    """loss_fn(params, batch) -> scalar loss, or -> (loss, aux) with
+    ``aux`` a dict of scalars that the step hands on (an expert model's
+    routing statistics, models/moe.py ``finish_loss``). Returns jitted
+    step(state, batch) -> (state, metrics); metrics holds ``loss``,
+    ``grad_norm``, ``step`` and every key of ``aux``; a scalar loss gives
+    the program it always gave."""
     batch_sh = (batch_sharding(mesh, rules, batch_shapes)
                 if batch_shapes is not None else None)
 
     def _step(state: TrainState, batch):
         def lf(p):
-            return loss_fn(p, batch)
+            out = loss_fn(p, batch)     # (loss, aux) or a scalar
+            return out if isinstance(out, tuple) else (out, {})
 
-        loss, grads = jax.value_and_grad(lf)(state.params)
+        (loss, aux), grads = jax.value_and_grad(lf, has_aux=True)(
+            state.params)
         updates, opt_state = optimizer.update(grads, state.opt_state,
                                               state.params)
         params = jax.tree.map(lambda p, u: p + u.astype(p.dtype),
                               state.params, updates)
         gnorm = optax_global_norm(grads)
-        metrics = {"loss": loss, "grad_norm": gnorm,
+        metrics = {**aux, "loss": loss, "grad_norm": gnorm,
                    "step": state.step + 1}
         return TrainState(params, opt_state, state.step + 1), metrics
 

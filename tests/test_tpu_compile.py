@@ -106,6 +106,32 @@ def test_flash_forward_backward_compiles(preset, one_chip, on_chip_branch):
     assert text.count("tpu_custom_call") >= 3, text[:2000]
 
 
+def test_grouped_matmul_compiles_at_olmoe_widths(one_chip, on_chip_branch):
+    """The expert layer's Mosaic calls at OLMoE-1B-7B's shapes (131,072
+    routed rows, 64 experts of 2048 x 1024) with the tiles
+    ops/grouped_matmul.py names: forward, input gradient, weight gradient."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops.grouped_matmul import grouped_matmul
+
+    rows, e, d, f = 131072, 64, 2048, 1024
+    x = _sds((rows, d), jnp.bfloat16, one_chip)
+    w = _sds((e, d, f), jnp.bfloat16, one_chip)
+    sizes = _sds((e,), jnp.int32, one_chip)
+
+    def loss(x, w, sizes):
+        return grouped_matmul(x, w, sizes, impl="pallas").astype(
+            jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        x, w, sizes).compile().as_text()
+    # input gradient (gmm with the matrices transposed) and weight gradient
+    # (tgmm); the forward's product is not needed for a sum's gradient
+    assert text.count("tpu_custom_call") >= 2, text[:2000]
+    assert "bf16[64,2048,1024]" in text
+
+
 # (slots, heads, kv_heads, head_dim, page_size, pages per slot)
 PAGED_WIDTHS = {
     "2b7": (8, 20, 20, 128, 64, 16),
