@@ -5,11 +5,32 @@ VMEM while K/V stream through in chunks with the online-softmax recurrence —
 O(S) memory instead of O(S^2), and the QK^T / PV matmuls hit the MXU at
 [block_q x head_dim] x [head_dim x block_k] granularity.
 
-Backward: full Pallas two-kernel backward (FlashAttention-2 style): a dQ
-pass gridded over q-blocks and a dK/dV pass gridded over k-blocks, both
+Backward: full Pallas two-kernel backward (FlashAttention-2 style), both
 recomputing probabilities from the saved log-sum-exp so nothing O(S^2) is
-ever materialized. A chunked-recompute JAX fallback remains selectable via
-BACKWARD_IMPL for debugging.
+ever materialized. The dQ pass keeps a q-block resident and loops over
+k-blocks. The dK/dV pass is its mirror image and has two block plans, told
+apart by the shape alone (`bwd_dkdv_plan`; the choice is the instant
+`flash.bwd_plan` of a trace):
+  resident  one instance per (b, h, k-block); q, dO, o and lse of the
+            whole head are blocks whose index is constant in the k axis,
+            so they are fetched once a head, and the kernel loops over the
+            q-blocks of its band. Taken where a head's query side, twice
+            (Mosaic double-buffers), and one step's temporaries fit a
+            quarter of the core's VMEM (S 8192 at D 128 on a v5e). HBM
+            bytes a head at S 4096, D 128, bf16, H == KV: 5 MiB of query
+            side + 2 of k and v + 2 of dk and dv = 9 MiB; k-blocks are
+            walked last to first, so the next head's 5 MiB arrive under
+            the head's longest instance, not its shortest.
+  stream    grid (b, h, k-block, q-block) accumulating into the f32 output
+            block: O(block) VMEM at any S. The query-side index maps are
+            clamped into the band as the forward's `kv_idx` clamps k and
+            v, so a step the mask skips fetches nothing: 0.625 MiB a step
+            that runs, 35 of 64 at S 4096 causal = 22 MiB + 6 (f32
+            results). Unclamped, as this grid was until PR 28, every step
+            fetched: 46 MiB a head.
+Both run the same accumulate step (`_dkdv_step`) in the same order, so
+their results agree to the last bit. A chunked-recompute JAX fallback
+remains selectable via BACKWARD_IMPL for debugging.
 
 GQA is handled in the kernel via the k/v index maps (kv_head = head // group)
 — no KV broadcast materialization.
@@ -31,6 +52,7 @@ Shapes: q [B, S, H, D], k/v [B, T, KV, D], output [B, S, H, D].
 from __future__ import annotations
 
 import functools
+import operator
 from typing import Optional, Tuple
 
 import jax
@@ -39,7 +61,10 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ray_tpu.util import tracing
+
 NEG_INF = -1e30
+_LSE_LANES = 128            # the kernels read and write lse lane-broadcast
 # checkpoint_name tags of what _flash_vjp_fwd hands the backward: (o, lse)
 FLASH_RESIDUALS = ("flash_out", "flash_lse")
 
@@ -308,15 +333,102 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, o_ref, lse_ref, dq_ref, *,
     dq_ref[0, 0] = dq.astype(dq_ref.dtype)
 
 
-def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, g_ref, o_ref, lse_ref,
-                     dk_ref, dv_ref, *, block_q: int, scale: float,
-                     causal: bool, window: int):
-    """Grid (b, h, k-block, q-block): the dk/dv output block is constant in
-    the (minor) q axis, so Mosaic keeps it resident and this accumulates
-    across sequential q steps — O(block) VMEM at any sequence length
-    (FlashAttention-2 backward, dK/dV pass). dK/dV land per-query-head;
-    the wrapper sums over GQA groups."""
-    block_k, D = k_ref.shape[2], k_ref.shape[3]
+def _dkdv_step(q, k, v, g, o, lse, qi, ki, *, block_q: int, block_k: int,
+               scale: float, causal: bool, window: int):
+    """What q-block `qi` adds to the dK and dV of k-block `ki`, both
+    [block_k, D] f32, from blocks already cast to f32 (lse [block_q, 1]).
+    The one accumulate step of both block plans below."""
+    delta = jnp.sum(o * g, axis=-1, keepdims=True)
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    if causal:
+        q_pos = qi * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, 1), 0)
+        k_pos = ki * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (1, block_k), 1)
+        keep = q_pos >= k_pos
+        if window > 0:
+            keep = keep & (q_pos - k_pos < window)
+        s = jnp.where(keep, s, NEG_INF)
+    p = jnp.exp(s - lse)                                       # [bq, bk]
+    dv = jax.lax.dot_general(p, g, (((0,), (0,)), ((), ())),
+                             preferred_element_type=jnp.float32)  # p^T @ g
+    dp = jax.lax.dot_general(g, v, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    ds = p * (dp - delta) * scale
+    dk = jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
+                             preferred_element_type=jnp.float32)  # ds^T @ q
+    return dk, dv
+
+
+def _index_ops(i):
+    """Floor division, min and max for a grid index: a traced scalar in a
+    kernel or an index map, a Python int where the plan and the tests walk
+    the grid on the host."""
+    if isinstance(i, int):
+        return operator.floordiv, min, max
+    return jax.lax.div, jax.lax.min, jax.lax.max
+
+
+def _q_band(ki, *, num_q: int, block_q: int, block_k: int, causal: bool,
+            window: int):
+    """[lo, hi): the q-blocks holding a row that the causal mask (and the
+    window) lets see k-block `ki`; every other q-block adds nothing to
+    that block's dK and dV."""
+    if not causal:
+        return 0, num_q
+    div, least, _ = _index_ops(ki)
+    lo = div(ki * block_k, block_q)
+    hi = num_q
+    if window > 0:
+        hi = least(num_q, div((ki + 1) * block_k + window + block_q - 1,
+                              block_q))
+    return lo, hi
+
+
+def _bwd_dkdv_resident_kernel(q_ref, k_ref, v_ref, g_ref, o_ref, lse_ref,
+                              dk_ref, dv_ref, *, block_q: int, scale: float,
+                              causal: bool, window: int):
+    """One instance per (b, h, k-block), the mirror image of the dQ pass:
+    q, dO, o and lse of the WHOLE head are the instance's blocks, with an
+    index constant in the k axis, so Mosaic fetches them once a head; the
+    kernel loops over the q-blocks of this k-block's band itself and
+    writes dK and dV once, in the result's dtype. dK/dV land
+    per-query-head; the wrapper sums over GQA groups."""
+    block_k = k_ref.shape[2]
+    num_q = q_ref.shape[2] // block_q
+    ki = pl.num_programs(2) - 1 - pl.program_id(2)
+    k = k_ref[0, 0].astype(jnp.float32)
+    v = v_ref[0, 0].astype(jnp.float32)
+
+    def body(qi, carry):
+        dk, dv = carry
+        rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+        dk_q, dv_q = _dkdv_step(
+            q_ref[0, 0, rows, :].astype(jnp.float32), k, v,
+            g_ref[0, 0, rows, :].astype(jnp.float32),
+            o_ref[0, 0, rows, :].astype(jnp.float32),
+            lse_ref[0, 0, rows, :][:, 0:1], qi, ki, block_q=block_q,
+            block_k=block_k, scale=scale, causal=causal, window=window)
+        return dk + dk_q, dv + dv_q
+
+    lo, hi = _q_band(ki, num_q=num_q, block_q=block_q, block_k=block_k,
+                     causal=causal, window=window)
+    zero = jnp.zeros(k.shape, jnp.float32)
+    dk, dv = jax.lax.fori_loop(lo, hi, body, (zero, zero))
+    dk_ref[0, 0] = dk.astype(dk_ref.dtype)
+    dv_ref[0, 0] = dv.astype(dv_ref.dtype)
+
+
+def _bwd_dkdv_stream_kernel(q_ref, k_ref, v_ref, g_ref, o_ref, lse_ref,
+                            dk_ref, dv_ref, *, block_q: int, num_q: int,
+                            scale: float, causal: bool, window: int):
+    """Grid (b, h, k-block, q-block): the f32 dk/dv output block is
+    constant in the (minor) q axis, so Mosaic keeps it resident and this
+    accumulates across sequential q steps: O(block) VMEM at any sequence
+    length. The query-side blocks arrive through index maps clamped into
+    the band (`_flash_bwd_dkdv`), so a step outside it fetches nothing."""
+    block_k = k_ref.shape[2]
     ki = pl.program_id(2)
     qi = pl.program_id(3)
 
@@ -325,44 +437,163 @@ def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, g_ref, o_ref, lse_ref,
         dk_ref[0, 0] = jnp.zeros_like(dk_ref[0, 0])
         dv_ref[0, 0] = jnp.zeros_like(dv_ref[0, 0])
 
-    # Causal: a q-block strictly above the diagonal contributes nothing.
-    run = True
-    if causal:
-        run = (qi + 1) * block_q > ki * block_k
-        if window > 0:
-            # windowed: q-blocks wholly past the window skip this k-block
-            run = run & (qi * block_q < (ki + 1) * block_k + window)
+    lo, hi = _q_band(ki, num_q=num_q, block_q=block_q,
+                     block_k=block_k, causal=causal, window=window)
 
-    @pl.when(run)
+    @pl.when((qi >= lo) & (qi < hi))
     def _accumulate():
-        k = k_ref[0, 0].astype(jnp.float32)
-        v = v_ref[0, 0].astype(jnp.float32)
-        q = q_ref[0, 0].astype(jnp.float32)
-        g = g_ref[0, 0].astype(jnp.float32)
-        o = o_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0][:, 0:1]
-        delta = jnp.sum(o * g, axis=-1, keepdims=True)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, 1), 0)
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (1, block_k), 1)
-            keep = q_pos >= k_pos
-            if window > 0:
-                keep = keep & (q_pos - k_pos < window)
-            s = jnp.where(keep, s, NEG_INF)
-        p = jnp.exp(s - lse)                                   # [bq, bk]
-        dv_ref[0, 0] += jax.lax.dot_general(
-            p, g, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)                # p^T @ g
-        dp = jax.lax.dot_general(g, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale
-        dk_ref[0, 0] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)                # ds^T @ q
+        dk_q, dv_q = _dkdv_step(
+            q_ref[0, 0].astype(jnp.float32), k_ref[0, 0].astype(jnp.float32),
+            v_ref[0, 0].astype(jnp.float32), g_ref[0, 0].astype(jnp.float32),
+            o_ref[0, 0].astype(jnp.float32), lse_ref[0, 0][:, 0:1], qi, ki,
+            block_q=block_q, block_k=block_k, scale=scale, causal=causal,
+            window=window)
+        dv_ref[0, 0] += dv_q
+        dk_ref[0, 0] += dk_q
+
+
+# physical VMEM of a v5e core: what a trace with no chip attached plans for
+# (interpret mode, a compile for a described device)
+_V5E_VMEM_BYTES = 128 * 2 ** 20
+
+
+def _vmem_bytes() -> int:
+    """VMEM of the core this trace is for."""
+    try:
+        return int(pltpu.get_tpu_info().vmem_capacity_bytes)
+    except ValueError:                     # no TPU is the default device
+        return _V5E_VMEM_BYTES
+
+
+def _q_block_index(ki, qi, *, num_q: int, block_q: int, block_k: int,
+                   causal: bool, window: int):
+    """The streaming plan's query-side block at grid step (ki, qi): qi
+    clamped into k-block ki's band, as the forward's `kv_idx` clamps k and
+    v. A step outside the band repeats the index of the band's near edge,
+    and Mosaic elides a fetch whose index repeats."""
+    lo, hi = _q_band(ki, num_q=num_q, block_q=block_q, block_k=block_k,
+                     causal=causal, window=window)
+    _, least, most = _index_ops(qi)
+    return most(least(qi, hi - 1), lo) if causal else qi
+
+
+def hbm_bytes_per_head(path: str, *, S: int, T: int, D: int, block_q: int,
+                       block_k: int, itemsize: int, out_itemsize: int,
+                       q_index=None) -> int:
+    """HBM bytes the dK/dV call moves for one (b, h): k and v read and dk
+    and dv written once, plus the query side (q, dO, o and the 128-lane
+    f32 lse): once on the resident plan; on the streaming plan one block
+    each time `q_index(ki, qi)` changes along the grid's walk."""
+    kv_bytes = 2 * T * D * (itemsize + out_itemsize)
+    row_bytes = 3 * D * itemsize + _LSE_LANES * 4
+    if path == "resident":
+        return S * row_bytes + kv_bytes
+    fetches, last = 0, None
+    for ki in range(T // block_k):
+        for qi in range(S // block_q):
+            index = q_index(ki, qi)
+            fetches += index != last
+            last = index
+    return fetches * block_q * row_bytes + kv_bytes
+
+
+def bwd_dkdv_plan(*, S: int, T: int, D: int, dtype, groups: int,
+                  block_q: int, block_k: int, causal: bool, window: int,
+                  vmem_bytes: int) -> dict:
+    """Which of the dK/dV call's two block plans a shape takes, and the
+    bytes that decide it (also the attributes of `flash.bwd_plan`).
+    resident: the query side of a whole head in VMEM, taken where its
+    blocks (double-buffered by Mosaic) and the f32 temporaries of one
+    accumulate step fit a quarter of the core's VMEM; stream otherwise.
+    The rest is XLA's: it holds operands of the fusions around the call
+    there, and a call's `vmem_limit_bytes` is taken out of that for as
+    long as the call is scheduled (at 96 MiB the four-chip step lost a
+    64 MiB operand of a weight-gradient fusion, 16 ms a step: PERF.md 6,
+    PR 28), so the limit asked for is the estimate and a quarter."""
+    itemsize = jnp.dtype(dtype).itemsize
+    row_bytes = 3 * D * itemsize + _LSE_LANES * 4
+    # a head's results leave in the inputs' dtype; a group's are summed in f32
+    out_dtype = jnp.dtype(dtype if groups == 1 else jnp.float32)
+    resident_bytes = (
+        2 * S * row_bytes
+        + 2 * 2 * block_k * D * (itemsize + out_dtype.itemsize)
+        # two [block_q, block_k] of s/p/dp/ds; q, dO, o; k, v, dk, dv
+        # (Mosaic planned 0.75-1.1 MiB under this at five shapes)
+        + 4 * (2 * block_q * block_k + (3 * block_q + 4 * block_k) * D))
+    path = "resident" if resident_bytes <= vmem_bytes // 4 else "stream"
+    if path == "stream":                   # accumulated in the output block
+        out_dtype = jnp.dtype(jnp.float32)
+    dims = dict(block_q=block_q, block_k=block_k)
+    return dict(
+        dims, path=path, S=S, window=window, resident_bytes=resident_bytes,
+        out_dtype=out_dtype, vmem_limit_bytes=resident_bytes * 5 // 4,
+        hbm_bytes_per_head=hbm_bytes_per_head(
+            path, S=S, T=T, D=D, itemsize=itemsize,
+            out_itemsize=out_dtype.itemsize, **dims,
+            q_index=functools.partial(
+                _q_block_index, num_q=S // block_q, causal=causal,
+                window=window, **dims)))
+
+
+def _flash_bwd_dkdv(qt, kt, vt, gt, ot, lse, *, causal: bool, block_q: int,
+                    block_k: int, window: int, vmem_bytes: int = None):
+    """The dK/dV call on [B, H|KV, S|T, D] operands (lse [B, H, S, 128]):
+    per-query-head dK and dV, [B, H, T, D]."""
+    B, H, S, D = qt.shape
+    KV, T = kt.shape[1], kt.shape[2]
+    groups = H // KV
+    num_q = S // block_q
+    plan = bwd_dkdv_plan(
+        S=S, T=T, D=D, dtype=kt.dtype, groups=groups,
+        block_q=block_q, block_k=block_k, causal=causal, window=window,
+        vmem_bytes=vmem_bytes or _vmem_bytes())
+    tracing.instant("flash.bwd_plan", {
+        k: plan[k] for k in ("path", "S", "block_q", "block_k", "window",
+                             "resident_bytes", "hbm_bytes_per_head")})
+    kernel_args = dict(block_q=block_q, scale=D ** -0.5, causal=causal,
+                       window=window)
+    if plan["path"] == "resident":
+        kernel = functools.partial(_bwd_dkdv_resident_kernel, **kernel_args)
+        grid = (B, H, T // block_k)
+        last_k = T // block_k - 1          # the kernel walks them last to first
+
+        def q_side(width):
+            return pl.BlockSpec((1, 1, S, width), lambda b, h, i: (b, h, 0, 0))
+
+        kv_blk = pl.BlockSpec((1, 1, block_k, D),
+                              lambda b, h, i: (b, h // groups, last_k - i, 0))
+        dkv_blk = pl.BlockSpec((1, 1, block_k, D),
+                               lambda b, h, i: (b, h, last_k - i, 0))
+        params = pltpu.CompilerParams(
+            vmem_limit_bytes=plan["vmem_limit_bytes"])
+    else:
+        kernel = functools.partial(_bwd_dkdv_stream_kernel, num_q=num_q,
+                                   **kernel_args)
+        grid = (B, H, T // block_k, num_q)
+
+        def q_index(b, h, i, j):
+            return (b, h, _q_block_index(
+                i, j, num_q=num_q, block_q=block_q, block_k=block_k,
+                causal=causal, window=window), 0)
+
+        def q_side(width):
+            return pl.BlockSpec((1, 1, block_q, width), q_index)
+
+        kv_blk = pl.BlockSpec((1, 1, block_k, D),
+                              lambda b, h, i, j: (b, h // groups, i, 0))
+        dkv_blk = pl.BlockSpec((1, 1, block_k, D),
+                               lambda b, h, i, j: (b, h, i, 0))
+        params = None
+    return pl.pallas_call(
+        kernel,
+        grid=grid,
+        in_specs=[q_side(D), kv_blk, kv_blk, q_side(D), q_side(D),
+                  q_side(_LSE_LANES)],
+        out_specs=[dkv_blk, dkv_blk],
+        out_shape=[jax.ShapeDtypeStruct((B, H, T, D), plan["out_dtype"])] * 2,
+        compiler_params=params,
+        interpret=_use_interpret(),
+    )(qt, kt, vt, gt, ot, lse)
 
 
 def _flash_pallas_bwd(res, g, *, causal: bool, block_q: int, block_k: int,
@@ -371,7 +602,7 @@ def _flash_pallas_bwd(res, g, *, causal: bool, block_q: int, block_k: int,
     dK/dV results (FlashAttention-2, Dao 2023)."""
     q, k, v, out, lse = res
     # the residual is compact [B, H, S]; the kernels read 128-lane blocks
-    lse = jnp.broadcast_to(lse[..., None], lse.shape + (128,))
+    lse = jnp.broadcast_to(lse[..., None], lse.shape + (_LSE_LANES,))
     B, S, H, D = q.shape
     T, KV = k.shape[1], k.shape[2]
     groups = H // KV
@@ -406,37 +637,15 @@ def _flash_pallas_bwd(res, g, *, causal: bool, block_q: int, block_k: int,
         interpret=_use_interpret(),
     )(qt, kt, vt, gt, ot, lse)
 
-    q_stream = pl.BlockSpec((1, 1, block_q, D),
-                            lambda b, h, i, j: (b, h, j, 0))
-    kv_blk = pl.BlockSpec((1, 1, block_k, D),
-                          lambda b, h, i, j, g_=groups: (b, h // g_, i, 0))
-    dkv_spec = pl.BlockSpec((1, 1, block_k, D),
-                            lambda b, h, i, j: (b, h, i, 0))
-    dk_h, dv_h = pl.pallas_call(
-        functools.partial(_bwd_dkdv_kernel, block_q=block_q, scale=scale,
-                          causal=causal, window=window),
-        grid=(B, H, T // block_k, S // block_q),
-        in_specs=[
-            q_stream,
-            kv_blk,
-            kv_blk,
-            q_stream,
-            q_stream,
-            pl.BlockSpec((1, 1, block_q, 128),
-                         lambda b, h, i, j: (b, h, j, 0)),
-        ],
-        out_specs=[dkv_spec, dkv_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, H, T, D), jnp.float32),
-            jax.ShapeDtypeStruct((B, H, T, D), jnp.float32),
-        ],
-        interpret=_use_interpret(),
-    )(qt, kt, vt, gt, ot, lse)
-
-    # GQA: sum per-query-head contributions into each kv head.
-    dk = dk_h.reshape(B, KV, groups, T, D).sum(2).transpose(0, 2, 1, 3)
-    dv = dv_h.reshape(B, KV, groups, T, D).sum(2).transpose(0, 2, 1, 3)
-    return dq.transpose(0, 2, 1, 3), dk.astype(k.dtype), dv.astype(v.dtype)
+    dk, dv = _flash_bwd_dkdv(qt, kt, vt, gt, ot, lse, causal=causal,
+                             block_q=block_q, block_k=block_k, window=window)
+    if groups > 1:
+        # GQA: sum per-query-head contributions into each kv head.
+        dk = dk.reshape(B, KV, groups, T, D).sum(2)
+        dv = dv.reshape(B, KV, groups, T, D).sum(2)
+    return (dq.transpose(0, 2, 1, 3),
+            dk.transpose(0, 2, 1, 3).astype(k.dtype),
+            dv.transpose(0, 2, 1, 3).astype(v.dtype))
 
 
 def _reference_chunked_bwd(res, g, *, causal: bool, chunk: int,

@@ -277,3 +277,175 @@ def test_flash_sliding_window_gradients():
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=5e-3, atol=1e-2)
+
+
+# -- the dK/dV call's two block plans (ops/flash_attention.py) ---------------
+
+def _fa():
+    import sys
+
+    return sys.modules["ray_tpu.ops.flash_attention"]
+
+
+def _dkdv(path, res, g, *, causal, window, block):
+    """The dK/dV call alone on the backward's residuals, one plan forced
+    through the VMEM size the plan is made for; returns [B, T, KV, D]."""
+    fa = _fa()
+    q, k, v, out, lse = res
+    B, S, H, D = q.shape
+    KV = k.shape[2]
+    t = lambda x: x.transpose(0, 2, 1, 3)  # noqa: E731
+    lse = jnp.broadcast_to(lse[..., None], lse.shape + (128,))
+    vmem = {"resident": 128 * 2 ** 20, "stream": 1024}[path]
+    plan = fa.bwd_dkdv_plan(
+        S=S, T=S, D=D, dtype=q.dtype, groups=H // KV,
+        block_q=block, block_k=block, causal=causal, window=window,
+        vmem_bytes=vmem)
+    assert plan["path"] == path
+    dk, dv = fa._flash_bwd_dkdv(
+        t(q), t(k), t(v), t(g), t(out), lse, causal=causal, block_q=block,
+        block_k=block, window=window, vmem_bytes=vmem)
+    return [x.astype(jnp.float32).reshape(B, KV, H // KV, S, D).sum(2)
+            .transpose(0, 2, 1, 3) for x in (dk, dv)]
+
+
+@pytest.mark.parametrize("blocks", [1, 4], ids=["S=block", "S=4blocks"])
+@pytest.mark.parametrize("heads", [(2, 2), (4, 2)], ids=["H=KV", "groups2"])
+@pytest.mark.parametrize("mask", ["causal", "noncausal", "window64"])
+@pytest.mark.parametrize("path", ["resident", "stream"])
+def test_dkdv_block_plans(path, mask, heads, blocks):
+    """Each plan against the chunked reference at the file's tolerance,
+    and against the other plan to 1e-6: the same float32 sums in the same
+    order, only the blocks arrive differently."""
+    fa = _fa()
+    block = 32
+    causal, window = mask != "noncausal", 64 if mask == "window64" else 0
+    q, k, v = _make(B=1, S=block * blocks, H=heads[0], KV=heads[1], D=32,
+                    seed=3)
+    _, res = fa._flash_vjp_fwd(q, k, v, causal, block, block, window)
+    g = jax.random.normal(jax.random.PRNGKey(9), q.shape, q.dtype)
+    got = _dkdv(path, res, g, causal=causal, window=window, block=block)
+    _, dk_ref, dv_ref = fa._reference_chunked_bwd(
+        res, g, causal=causal, chunk=block, window=window)
+    other = _dkdv({"resident": "stream", "stream": "resident"}[path], res, g,
+                  causal=causal, window=window, block=block)
+    for a, ref, b in zip(got, (dk_ref, dv_ref), other):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(ref),
+                                   rtol=3e-3, atol=3e-3)
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-6, atol=1e-6)
+
+
+# the parent's grid (PR 26): every grid step fetched query-side block qi,
+# float32 results
+PARENT_DKDV_PLAN = dict(path="stream", out_itemsize=4,
+                        q_index=lambda ki, qi: qi)
+
+
+def test_dkdv_plan_bytes_at_the_benchmark_shape():
+    """The benchmark's attention shape (S 4096, D 128, bf16, 512-blocks,
+    H == KV) takes the resident plan and moves under 10 MB a head where
+    the parent's grid moved 47; a small VMEM takes the streaming plan,
+    whose clamped maps fetch only the band."""
+    fa = _fa()
+    shape = dict(S=4096, T=4096, D=128, block_q=512, block_k=512)
+    mask = dict(causal=True, window=0)
+    plan = fa.bwd_dkdv_plan(dtype=jnp.bfloat16, groups=1, vmem_bytes=128 * 2 ** 20,
+                            **shape, **mask)
+    assert plan["path"] == "resident"
+    # Mosaic planned 14 MiB for this call (tests/test_tpu_compile.py holds
+    # that the limit suffices); a quarter of a v5e's VMEM is the budget
+    assert 14 * 2 ** 20 <= plan["resident_bytes"] <= 32 * 2 ** 20
+    assert plan["resident_bytes"] <= plan["vmem_limit_bytes"] <= 32 * 2 ** 20
+    assert plan["hbm_bytes_per_head"] == 9 * 2 ** 20 <= 10e6
+    parent = fa.hbm_bytes_per_head(itemsize=2, **shape, **PARENT_DKDV_PLAN)
+    assert parent == 46 * 2 ** 20 and 47e6 < parent < 49e6
+    # groups: float32 results for the group sum, 2 MB a head more
+    grouped = fa.bwd_dkdv_plan(dtype=jnp.bfloat16, groups=4,
+                               vmem_bytes=128 * 2 ** 20, **shape, **mask)
+    assert grouped["path"] == "resident"
+    assert grouped["hbm_bytes_per_head"] == 11 * 2 ** 20
+    # a core with 16 MiB of VMEM streams: 36 of the 64 steps run, and the
+    # last k-block's one step finds its block there from the step before
+    small = fa.bwd_dkdv_plan(dtype=jnp.bfloat16, groups=1, vmem_bytes=16 * 2 ** 20,
+                             **shape, **mask)
+    assert small["path"] == "stream"
+    assert small["hbm_bytes_per_head"] == (35 * 640 + 6 * 1024) * 1024
+    # S 8192 with Mistral's window still fits; its band is what streams
+    long = dict(shape, S=8192, T=8192)
+    assert fa.bwd_dkdv_plan(
+        dtype=jnp.bfloat16, groups=4, vmem_bytes=128 * 2 ** 20, causal=True,
+        window=4096, **long)["path"] == "resident"
+    banded = fa.bwd_dkdv_plan(
+        dtype=jnp.bfloat16, groups=4, vmem_bytes=16 * 2 ** 20, causal=True,
+        window=4096, **long)
+    assert banded["path"] == "stream"
+    assert banded["hbm_bytes_per_head"] < 0.5 * fa.hbm_bytes_per_head(
+        itemsize=2, **long, **PARENT_DKDV_PLAN)
+
+
+@pytest.mark.parametrize("window", [0, 64, 100], ids=["causal", "w64", "w100"])
+@pytest.mark.parametrize("block_q,block_k", [(32, 32), (32, 64), (64, 32)])
+def test_dkdv_stream_grid_fetches_only_its_band(window, block_q, block_k):
+    """Walk the streaming plan's grid on the host in the order Mosaic
+    does. On a step the kernel runs, the query-side index is the block
+    the kernel masks for (qi itself). On a step it skips, the index is
+    the previous step's (nothing is fetched) or, before a k-block's band
+    opens, the band's first block (fetched early, once): so over a head
+    the index changes once per step that runs, never for one skipped."""
+    fa = _fa()
+    S = 256
+    num_q, num_k = S // block_q, S // block_k
+    kw = dict(num_q=num_q, block_q=block_q, block_k=block_k, causal=True,
+              window=window)
+    prev, fetches, ran = None, 0, 0
+    for ki in range(num_k):
+        for qi in range(num_q):
+            # what the kernel's pl.when computes, from positions
+            rows = np.arange(qi * block_q, (qi + 1) * block_q)[:, None]
+            cols = np.arange(ki * block_k, (ki + 1) * block_k)[None, :]
+            keep = rows >= cols
+            if window:
+                # the band test is by blocks: the row at the window's
+                # edge, that sees nothing, still counts as in the band
+                keep &= rows - cols <= window
+            lo, hi = fa._q_band(ki, **kw)
+            assert (lo <= qi < hi) == bool(keep.any()), (ki, qi)
+            index = fa._q_block_index(ki, qi, **kw)
+            if lo <= qi < hi:
+                ran += 1
+                assert index == qi
+            else:
+                assert index == prev or index == lo, (ki, qi, index, prev)
+            fetches += index != prev
+            prev = index
+    assert fetches <= ran
+    # the same functions trace: an index map gets traced scalars
+    traced = jax.jit(lambda i, j: fa._q_block_index(i, j, **kw))
+    for ki, qi in [(0, 0), (num_k - 1, 0), (0, num_q - 1), (num_k // 2, 1)]:
+        assert int(traced(ki, qi)) == fa._q_block_index(ki, qi, **kw)
+
+
+def test_flash_bwd_plan_instant_once_a_trace(monkeypatch):
+    """The plan is chosen while the backward is traced, and says so once:
+    one `flash.bwd_plan` instant a compile, none when the compiled
+    program runs again."""
+    fa = _fa()
+    seen = []
+    monkeypatch.setattr(fa.tracing, "instant",
+                        lambda name, attrs=None, **kw: seen.append(
+                            (name, attrs)))
+    q, k, v = _make(B=1, S=128, H=2, KV=2, D=32)
+    grad = jax.jit(jax.grad(lambda *a: flash_attention(
+        *a, block_q=32, block_k=32).sum(), argnums=(0, 1, 2)))
+    jax.block_until_ready(grad(q, k, v))
+    jax.block_until_ready(grad(q, k, v))
+    assert [name for name, _ in seen] == ["flash.bwd_plan"]
+    attrs = seen[0][1]
+    assert attrs == {
+        "path": "resident", "S": 128, "block_q": 32, "block_k": 32,
+        "window": 0, "resident_bytes": attrs["resident_bytes"],
+        "hbm_bytes_per_head": fa.hbm_bytes_per_head(
+            "resident", S=128, T=128, D=32, block_q=32, block_k=32,
+            itemsize=4, out_itemsize=4)}
+    assert all(isinstance(x, (int, str)) for x in attrs.values())

@@ -83,6 +83,11 @@ FLASH_WIDTHS = {
     "2b7": (5, 1024, 20, 20, 128),
     "debug-125m": (8, 1024, 12, 12, 64),
     "1b": (4, 2048, 16, 8, 128),
+    # the benchmark's train cells: the dK/dV call's resident plan at the
+    # VMEM limit its estimate asks for (Mosaic planned 14 MiB of it here)
+    "deepseek-7b-s4096": (3, 4096, 32, 32, 128),
+    # the longest sequence the forward kernel holds, grouped: f32 results
+    "gqa-s8192": (1, 8192, 32, 8, 128),
 }
 
 
@@ -104,6 +109,55 @@ def test_flash_forward_backward_compiles(preset, one_chip, on_chip_branch):
         q, kv, kv).compile().as_text()
     # forward + dq + dkdv kernels
     assert text.count("tpu_custom_call") >= 3, text[:2000]
+
+
+def test_flash_calls_keep_their_face_in_the_trace(one_chip, on_chip_branch):
+    """The roofline readers tell the three flash calls by operands and
+    results alone (``benchmark/readers/kernel_roofline.py``: forward 3 -> 2,
+    dq 6 -> 1, dkdv 6 -> 2, q ``[B, H, S, HD]`` and k ``[B, KV, S, HD]``
+    first) and raise on any other Mosaic call in a train program. Both
+    block plans of the dK/dV call have to keep that face."""
+    import re
+    import sys
+
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark.readers import kernel_roofline
+    from ray_tpu.ops.flash_attention import flash_attention
+
+    fa = sys.modules["ray_tpu.ops.flash_attention"]
+    B, S, H, KV, D = 2, 1024, 4, 2, 128
+    q = _sds((B, S, H, D), jnp.bfloat16, one_chip)
+    kv = _sds((B, S, KV, D), jnp.bfloat16, one_chip)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, block_q=256,
+                               block_k=256).astype(jnp.float32).sum()
+
+    def calls():
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            q, kv, kv).compile().as_text()
+        return [ln for ln in text.splitlines()
+                if kernel_roofline.signature(ln) is not None]
+
+    faces = {}
+    for path, vmem in (("resident", 128 * 2 ** 20), ("stream", 2 ** 20)):
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(fa, "_vmem_bytes", lambda vmem=vmem: vmem)
+            lines = calls()
+        assert sorted(map(kernel_roofline.signature, lines)) == [
+            (1, 6), (2, 3), (2, 6)], lines
+        for ln in lines:
+            # compiled text names its operands without their shapes (a
+            # trace's op line has both); the layout constraints list them
+            shapes = re.findall(r"\[([\d,]+)\]", re.search(
+                r"operand_layout_constraints=\{(.*?\})\}", ln).group(1))
+            assert shapes[:2] == [f"{B},{H},{S},{D}", f"{B},{KV},{S},{D}"], ln
+        faces[path] = next(ln for ln in lines
+                           if kernel_roofline.signature(ln) == (2, 6))
+    # two plans, two programs: the streaming grid has one more axis
+    assert faces["resident"] != faces["stream"]
 
 
 def test_grouped_matmul_compiles_at_olmoe_widths(one_chip, on_chip_branch):
