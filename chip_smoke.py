@@ -6,8 +6,8 @@ a user calls, at the full width of the ``2b7`` preset (32 layers, random
 weights from ``--seed``), and checks what comes out:
 
   train      ray_tpu.init() -> JaxTrainer (one worker, one chip) -> the
-             loop builds 2b7 at bench.py's recipe (bf16 params, flash,
-             remat, adafactor, B5 x S1024) with make_train_state_init /
+             loop builds 2b7 (bf16 params, flash, remat, bf16 logits,
+             adafactor, B5 x S1024) with make_train_state_init /
              make_train_step and takes a few steps on one batch.
   serve      serve.run(build_llm_app(use_sim=False, num_replicas=1,
              preset="2b7", kv_layout="paged")) -> HTTP requests.
@@ -117,7 +117,7 @@ class _CacheEvents:
 
 
 def _build_train(size: dict, seed: int, mesh, rules, batch: int, on_tpu: bool):
-    """2b7 exactly as bench.py's headline recipe builds it. Returns
+    """2b7 in bf16 with flash, remat, bf16 logits and adafactor. Returns
     (state, compiled step, batch, what the compiler says of it, cfg)."""
     import jax
     import jax.numpy as jnp

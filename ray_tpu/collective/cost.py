@@ -13,9 +13,8 @@ this independently for dispatch. Rank 0 computes the choice and
 broadcasts it through the coordinator (api.GroupClient._agree); this
 module itself is pure and deterministic in its inputs.
 
-Priors were calibrated against BENCH_collective.json on the 1-vCPU dev
-box (the same one the acceptance sweep runs on); they only matter until
-the first few rounds warm the EWMAs.
+The priors are starting values only: they matter until the first few
+rounds warm the EWMAs.
 """
 
 from __future__ import annotations

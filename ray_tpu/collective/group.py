@@ -248,7 +248,7 @@ def payload_nbytes(obj) -> int:
 
 class TransferStats:
     """Per-rank byte accounting — the hook the bandwidth-optimality tests
-    and ``bench.py --bench collective`` assert against."""
+    assert against."""
 
     def __init__(self):
         self.bytes_sent = 0          # total payload bytes this rank pushed

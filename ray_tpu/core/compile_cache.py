@@ -2,7 +2,7 @@
 
 JAX's persistent compilation cache is keyed by its path, so a directory
 that moves never hits. One rule for every process of the program that
-compiles (spawned workers, bench.py, chip_smoke.py): where
+compiles (spawned workers, benchmark/run.py, chip_smoke.py): where
 JAX_COMPILATION_CACHE_DIR is set it is used and code sets no other;
 where it is not, the cache is one fixed directory at the root of the
 checkout — never a temporary name, a pid or a time. Both knobs are

@@ -261,7 +261,7 @@ class Config:
     # --- memory attribution plane (observability/memory.py) -----------------
     # Per-object ownership/pin/temperature records riding the batched
     # telemetry report; False strips the put/get hot-path hooks to bare
-    # dict probes (bench.py --bench memory measures the difference).
+    # dict probes.
     memory_attribution: bool = True
     # A record still pinned this long after its last owner ref died is a
     # leak suspect in memory_report() (ref: `ray memory` leak triage).

@@ -13,7 +13,7 @@ stops pulling, task issue stops within one budget window.
 
 Every run records a bounded trace of per-round operator states
 (in-flight, queued bytes) and publishes a summary via
-get_last_execution_stats() for tests and bench.py --bench data.
+get_last_execution_stats().
 """
 
 from __future__ import annotations

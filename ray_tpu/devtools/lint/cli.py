@@ -77,7 +77,7 @@ def main(argv: Optional[List[str]] = None) -> int:
 
     if args.as_json:
         print(json.dumps(report.to_dict(), indent=2, sort_keys=True))
-        # bench.py-style greppable one-liner; stderr keeps stdout pure JSON
+        # greppable one-liner; stderr keeps stdout pure JSON
         print(report.summary_line(), file=sys.stderr)
     else:
         for f in report.findings:

@@ -100,7 +100,7 @@ class LintReport:
         return out
 
     def summary_line(self) -> str:
-        # bench.py-style single greppable line for CI diffing
+        # single greppable line for CI diffing
         return (f"RAYLINT files={self.files_scanned} "
                 f"findings={len(self.unsuppressed)} "
                 f"suppressed={len(self.suppressed)} "

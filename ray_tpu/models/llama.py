@@ -49,24 +49,8 @@ class LlamaConfig:
     # the attention is banded). Unsupported with ring/ulysses.
     sliding_window: Any = None
     # jax.checkpoint each layer: the backward keeps the layer's input and
-    # rebuilds the rest (HBM savings), but for what remat_policy names.
+    # rebuilds the rest (HBM savings), but for what _checkpoint names.
     remat: bool = True
-    # What the per-layer checkpoint may keep beside the layer input.
-    # "none": only the flash kernel's output and log-sum-exp
-    # (ops/flash_attention.py FLASH_RESIDUALS) — the output is as large
-    # as the layer input itself, B x S x D x 2 bytes a layer in bf16, the
-    # log-sum-exp B x H x S x 4, and rebuilding them costs a second
-    # launch of the forward kernel over S^2; everything else is
-    # recomputed. Without the kernel in the layer (attn_impl other than
-    # "flash", under 128 tokens) "none" keeps nothing. "dots": those two
-    # and the matmul outputs (jax.checkpoint_policies
-    # .dots_with_no_batch_dims_saveable), recomputing only
-    # elementwise/norms: more HBM for no matmul recompute in the backward.
-    remat_policy: str = "none"
-    # Concatenate wq/wk/wv (and w_gate/w_up) into single wider matmuls at
-    # apply time. Same params/checkpoints; at small d_model the wider N
-    # dimension keeps the MXU tiles full.
-    fused_matmuls: bool = False
     # Emit [B, S, vocab] logits in f32 (safe default) or keep them in the
     # compute dtype. With the logsumexp-form CE below, bf16 logits with
     # f32-accumulated reductions (XLA fuses the upcast into the reduce)
@@ -76,14 +60,6 @@ class LlamaConfig:
     # the forward scan) or "1f1b" (explicitly-scheduled backward with an
     # O(M)-activation stash; parallel/pipeline.py).
     pp_schedule: str = "gpipe"
-    # Layer loop form. True = lax.scan over stacked layer params (compact
-    # HLO, fast compiles). False = unrolled Python loop slicing one layer
-    # at a time — with FSDP this keeps each layer's param all-gather and
-    # grad reduce-scatter adjacent to its use, so buffer liveness frees
-    # the gathered bf16 copy per layer instead of holding the whole
-    # model's (XLA can hoist a scan-carried all-gather out of the loop,
-    # which costs a full unsharded param copy in HBM at 7B+ scale).
-    scan_layers: bool = True
 
     @property
     def head_dim(self) -> int:
@@ -243,24 +219,19 @@ def _family(cfg: "LlamaConfig"):
 
 
 def _checkpoint(body, cfg: "LlamaConfig"):
-    """Per-layer jax.checkpoint. Either policy keeps the flash kernel's
-    output and log-sum-exp (FLASH_RESIDUALS), so the backward kernels run
-    from them and the forward kernel runs once, and what the family's
-    feed-forward names (REMAT_SAVED: an expert layer's routes); a body
-    that holds no such name saves nothing more."""
+    """Per-layer jax.checkpoint. Beside the layer's input it keeps the
+    flash kernel's output and log-sum-exp (FLASH_RESIDUALS: the output is
+    as large as the layer input, B x S x D x 2 bytes a layer in bf16, the
+    log-sum-exp B x H x S x 4), so the backward kernels run from them and
+    the forward kernel runs once, and what the family's feed-forward
+    names (REMAT_SAVED: an expert layer's routes); everything else is
+    recomputed. A body that holds no such name (attn_impl other than
+    "flash", under 128 tokens) saves nothing more."""
     from ray_tpu.ops.flash_attention import FLASH_RESIDUALS
 
-    policies = jax.checkpoint_policies
-    policy = policies.save_only_these_names(
-        *FLASH_RESIDUALS, *_family(cfg).REMAT_SAVED)
-    if cfg.remat_policy == "dots":
-        policy = policies.save_from_both_policies(
-            policies.dots_with_no_batch_dims_saveable, policy)
-    elif cfg.remat_policy != "none":
-        raise ValueError(
-            f"remat_policy must be 'none' or 'dots', got "
-            f"{cfg.remat_policy!r}")
-    return jax.checkpoint(body, policy=policy)
+    return jax.checkpoint(
+        body, policy=jax.checkpoint_policies.save_only_these_names(
+            *FLASH_RESIDUALS, *_family(cfg).REMAT_SAVED))
 
 
 def rms_norm(x, scale, eps):
@@ -390,18 +361,9 @@ def _attention_half(x, lp, cfg: LlamaConfig, cos, sin, cache=None, mesh=None,
             y = rms_norm(y, lp[norm], cfg.norm_eps)
         return y.reshape(B, S, n, HD)
 
-    if cfg.fused_matmuls:
-        # One [D, (H+2KV)*HD] matmul instead of three: at small d_model the
-        # MXU is launch/tile-bound, so widening N raises utilization.
-        wqkv = jnp.concatenate([_dq(lp["wq"], dt), _dq(lp["wk"], dt),
-                                _dq(lp["wv"], dt)], axis=-1)
-        qkv = h @ wqkv
-        q, k, v = jnp.split(qkv, [H * HD, (H + KV) * HD], axis=-1)
-        q, k, v = heads(q, H, "q_norm"), heads(k, KV, "k_norm"), heads(v, KV)
-    else:
-        q = heads(h @ _dq(lp["wq"], dt), H, "q_norm")
-        k = heads(h @ _dq(lp["wk"], dt), KV, "k_norm")
-        v = heads(h @ _dq(lp["wv"], dt), KV)
+    q = heads(h @ _dq(lp["wq"], dt), H, "q_norm")
+    k = heads(h @ _dq(lp["wk"], dt), KV, "k_norm")
+    v = heads(h @ _dq(lp["wv"], dt), KV)
     q = apply_rope(q, cos, sin)
     k = apply_rope(k, cos, sin)
 
@@ -434,15 +396,8 @@ def feed_forward(h, lp, cfg: LlamaConfig, mesh=None, rules=None):
     layer's routing statistics, models/moe.py); the dense one has nothing
     to report."""
     dt = cfg.dtype
-    if cfg.fused_matmuls:
-        w_gu = jnp.concatenate([_dq(lp["w_gate"], dt),
-                                _dq(lp["w_up"], dt)], axis=-1)
-        gu = h @ w_gu
-        gate, up = jnp.split(gu, 2, axis=-1)
-        gate = jax.nn.silu(gate)
-    else:
-        gate = jax.nn.silu(h @ _dq(lp["w_gate"], dt))
-        up = h @ _dq(lp["w_up"], dt)
+    gate = jax.nn.silu(h @ _dq(lp["w_gate"], dt))
+    up = h @ _dq(lp["w_up"], dt)
     return (gate * up) @ _dq(lp["w_down"], dt), None
 
 
@@ -507,16 +462,7 @@ def forward_with_stats(params, tokens, cfg: LlamaConfig, pos_offset=0,
 
     if cfg.remat:
         body = _checkpoint(body, cfg)
-    if cfg.scan_layers:
-        x, stats = jax.lax.scan(body, x, params["layers"])
-    else:
-        per_layer = []
-        for i in range(cfg.n_layers):
-            lp = jax.tree.map(lambda a: a[i], params["layers"])
-            x, st = body(x, lp)
-            per_layer.append(st)
-        stats = None if per_layer[0] is None else jax.tree.map(
-            lambda *a: jnp.stack(a), *per_layer)
+    x, stats = jax.lax.scan(body, x, params["layers"])
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = x @ _dq(params["lm_head"], dt)
     return (logits.astype(jnp.float32) if cfg.f32_logits else logits), stats

@@ -7,16 +7,17 @@ indices (its page table) instead of a contiguous [max_seq] stripe, so
 HBM scales with TOKENS IN USE, not worst-case-per-slot (the vLLM
 memory model, re-designed for XLA's static shapes).
 
-TPU kernel design: one grid instance per (slot, kv_head, page). The
-page table and per-slot lengths ride in as SCALAR-PREFETCH arguments
-(pltpu.PrefetchScalarGridSpec) so the k/v BlockSpec index_maps can
-point each grid step's DMA at that slot's next physical page — Mosaic
-fetches exactly the pages the slot owns, never materializing the
-gathered [slots, max_pages*page_size] view the way an XLA gather
-would. Out-of-range steps clamp their index (repeat DMA, elided) and
-skip compute via pl.when; online-softmax state (acc/m/l) lives in VMEM
-scratch across the page steps of one (slot, kv_head), exactly like
-ops/flash_attention.py's streaming kernel.
+TPU kernel design (paged_decode_attention_inplace, which also writes the
+current token's k/v into its slot's tip page): one grid instance per
+(slot, kv_head, page). The page table and per-slot lengths ride in as
+SCALAR-PREFETCH arguments (pltpu.PrefetchScalarGridSpec) so the k/v
+BlockSpec index_maps can point each grid step's DMA at that slot's next
+physical page — Mosaic fetches exactly the pages the slot owns, never
+materializing the gathered [slots, max_pages*page_size] view the way an
+XLA gather would. Out-of-range steps clamp their index (repeat DMA,
+elided) and skip compute via pl.when; online-softmax state (acc/m/l)
+lives in VMEM scratch across the page steps of one (slot, kv_head),
+exactly like ops/flash_attention.py's streaming kernel.
 
 Shapes: q [S, H, HD] (one new token per slot), pools [KV, NP, ps, HD]
 (kv-head major so the kernel's page block keeps (ps, HD) as its last two
@@ -56,49 +57,6 @@ def paged_attention_reference(q, k_pool, v_pool, page_table, lengths):
     # fully-masked rows (inactive slots) produce uniform p; output unused
     out = jnp.einsum("skgt,kstd->skgd", p, v.astype(jnp.float32))
     return out.reshape(S, H, HD).astype(q.dtype)
-
-
-def _kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref, acc, m_scr, l_scr,
-            *, page_size: int, max_pages: int, scale: float):
-    """Grid (S, KV, maxP). pt_ref/len_ref are scalar-prefetched."""
-    s = pl.program_id(0)
-    p = pl.program_id(2)
-
-    @pl.when(p == 0)
-    def _init():
-        acc[...] = jnp.zeros_like(acc)
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-
-    length = len_ref[s]
-    # number of pages this slot actually uses (0 for inactive slots)
-    n_pages = jax.lax.div(length + page_size - 1, page_size)
-
-    @pl.when(p < n_pages)
-    def _step():
-        q = q_ref[0, 0]                                # [G, HD]
-        k = k_ref[0, 0]                                # [ps, HD]
-        v = v_ref[0, 0]
-        st = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32) * scale
-        tok = p * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page_size), 1)
-        st = jnp.where(tok < length, st, NEG_INF)      # [G, ps]
-        m = m_scr[...][:, 0:1]
-        l = l_scr[...][:, 0:1]
-        m_new = jnp.maximum(m, jnp.max(st, axis=1, keepdims=True))
-        pr = jnp.exp(st - m_new)
-        alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + jnp.sum(pr, axis=1, keepdims=True)
-        acc[...] = acc[...] * alpha + jax.lax.dot(
-            pr.astype(v.dtype), v, preferred_element_type=jnp.float32)
-        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
-
-    @pl.when(p == max_pages - 1)
-    def _finish():
-        l = jnp.maximum(l_scr[...][:, 0:1], 1e-30)
-        o_ref[0, 0] = (acc[...] / l).astype(o_ref.dtype)
 
 
 def paged_decode_attention_inplace_reference(q, k_new, v_new, k_pool,
@@ -260,53 +218,3 @@ def paged_decode_attention_inplace(q, k_new, v_new, k_pool, v_pool,
         input_output_aliases={5: 1, 6: 2},
     )(page_table, lengths, qt, kn4, vn4, k_pool, v_pool)
     return o.reshape(S, H, HD), k_pool, v_pool
-
-
-def paged_attention(q, k_pool, v_pool, page_table, lengths):
-    """q [S, H, HD] -> [S, H, HD]. lengths must INCLUDE the current
-    token (its k/v already written to the pool). Inactive slots pass
-    length 0 and read back garbage that callers mask."""
-    S, H, HD = q.shape
-    KV, NP, ps, _ = k_pool.shape
-    maxP = page_table.shape[1]
-    G = H // KV
-    if jax.default_backend() != "tpu":
-        return paged_attention_reference(q, k_pool, v_pool, page_table,
-                                         lengths)
-
-    # [S, KV, G, HD] so one grid instance owns one (slot, kv head)
-    qt = q.reshape(S, KV, G, HD)
-
-    def q_idx(s, kv, p, pt, ln):
-        return (s, kv, 0, 0)
-
-    def kv_idx(s, kv, p, pt, ln):
-        # clamp into this slot's live pages: out-of-range steps repeat
-        # the previous index so Mosaic elides their DMA
-        length = ln[s]
-        n_pages = jax.lax.div(length + ps - 1, ps)
-        j = jax.lax.min(p, jax.lax.max(n_pages - 1, 0))
-        return (kv, pt[s, j], 0, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(S, KV, maxP),
-        in_specs=[
-            pl.BlockSpec((1, 1, G, HD), q_idx),
-            pl.BlockSpec((1, 1, ps, HD), kv_idx),
-            pl.BlockSpec((1, 1, ps, HD), kv_idx),
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, HD), q_idx),
-        scratch_shapes=[
-            pltpu.VMEM((G, HD), jnp.float32),
-            pltpu.VMEM((G, 128), jnp.float32),
-            pltpu.VMEM((G, 128), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        functools.partial(_kernel, page_size=ps, max_pages=maxP,
-                          scale=HD ** -0.5),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, KV, G, HD), q.dtype),
-    )(page_table, lengths, qt, k_pool, v_pool)
-    return out.reshape(S, H, HD)

@@ -49,7 +49,6 @@ class SimLLMServer:
                  group_pages: int = 4,
                  retained_groups: int = 512,
                  use_directory: bool = True,
-                 colocation_interference: float = 0.0,
                  multiplexed: bool = False,
                  max_models: Optional[int] = None,
                  model_load_s: float = 0.05,
@@ -70,13 +69,6 @@ class SimLLMServer:
         self.tokens_per_frame = max(int(tokens_per_frame), 1)
         self.prefix_caching = prefix_caching
         self.prefix_cache_pages = prefix_cache_pages
-        # co-location contention model (ref: DistServe §2): a prefill
-        # sharing the engine inflates every co-scheduled decode step by
-        # this factor per co-running prefill. A replica that runs only
-        # one phase (mode="prefill"/"decode") never pays it — the effect
-        # disaggregation removes.
-        self.colocation_interference = float(colocation_interference)
-        self._prefill_active = 0
         # LRU by insertion/touch order, like PagePool's reclaim of
         # refcount-0 cached pages: a replica whose routed working set
         # exceeds capacity THRASHES — the effect prefix affinity exists
@@ -117,7 +109,6 @@ class SimLLMServer:
             "prefills": 0, "prefill_tokens": 0,
             "global_prefix_hits": 0, "global_prefix_hit_tokens": 0,
             "decodes": 0, "handoffs_lost": 0,
-            "interference_stall_s": 0.0,
             # multiplex counters + the per-request context observations
             # the compiled-vs-legacy propagation test asserts on
             "model_loads": 0, "model_evictions": 0,
@@ -315,14 +306,8 @@ class SimLLMServer:
                 t0 = time.time()
                 # prefill cost scales with the UNCACHED prompt tail —
                 # this is the wall-clock effect prefix affinity buys
-                with self._lock:
-                    self._prefill_active += 1
-                try:
-                    await asyncio.sleep(
-                        self.prefill_s_per_token * (len(prompt) - matched))
-                finally:
-                    with self._lock:
-                        self._prefill_active -= 1
+                await asyncio.sleep(
+                    self.prefill_s_per_token * (len(prompt) - matched))
                 dt = time.time() - t0
                 with self._lock:
                     self.metrics["admit_s"] += dt
@@ -332,12 +317,7 @@ class SimLLMServer:
                 while i < max_new:
                     n = min(self.tokens_per_frame, max_new - i)
                     t1 = time.time()
-                    base = self.decode_s_per_token * n
-                    with self._lock:
-                        stall = base * self.colocation_interference \
-                            * self._prefill_active
-                        self.metrics["interference_stall_s"] += stall
-                    await asyncio.sleep(base + stall)
+                    await asyncio.sleep(self.decode_s_per_token * n)
                     with self._lock:
                         self.metrics["decode_block_s"] += time.time() - t1
                         self.metrics["tokens_generated"] += n

@@ -1,0 +1,74 @@
+"""Every train cell's program config still builds, here on the CPU.
+
+A cell's recipe (``benchmark/workloads/<cell>.json``, group ``train``) names
+fields of ``LlamaConfig`` / ``MoEConfig``; the harness hands them over in the
+worker that holds the chip (``benchmark/kinds/train.py``, ``train_moe.py``),
+so a field that the program loses while a recipe still names it would fail
+only there. ``benchmark/tests/`` is not on the driver's command; this is. It
+reads the benchmark's files and changes none.
+"""
+
+import glob
+import json
+import os
+import sys
+
+import pytest
+
+ROOT = os.path.join(os.path.dirname(os.path.abspath(__file__)), os.pardir)
+
+# the recipe keys each kind hands to its config builder
+# (benchmark/kinds/train.py, benchmark/kinds/train_moe.py: run())
+RECIPE_KEYS = {"train": ("attn_impl", "remat", "f32_logits"),
+               "train_moe": ("attn_impl", "gmm_impl", "remat", "f32_logits")}
+# published config.json key -> the program's field
+WIDTHS = {"hidden_size": "d_model", "num_hidden_layers": "n_layers",
+          "num_attention_heads": "n_heads",
+          "num_key_value_heads": "n_kv_heads", "intermediate_size": "d_ff",
+          "vocab_size": "vocab_size", "rope_theta": "rope_theta",
+          "rms_norm_eps": "norm_eps", "sliding_window": "sliding_window"}
+MOE_WIDTHS = {"num_experts": "n_experts", "num_experts_per_tok": "top_k",
+              "norm_topk_prob": "norm_topk"}
+
+
+def _train_cells():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        listed = [w["name"] for w in json.load(f)["workloads"]]
+    rehearsals = sorted(
+        os.path.basename(p)[:-5] for p in glob.glob(os.path.join(
+            ROOT, "benchmark", "workloads", "rehearse-train*.json")))
+    return listed + rehearsals
+
+
+@pytest.mark.parametrize("name", _train_cells())
+def test_cell_program_config_builds_at_its_published_widths(name):
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark import model, model_moe, resolve
+
+    cell = resolve.cell(name)
+    kind, conf, recipe = cell["kind"], cell["config"], cell["train"]
+    build = {"train": model.llama_config,
+             "train_moe": model_moe.moe_config}[kind]
+    passed = {k: recipe[k] for k in RECIPE_KEYS[kind] if k in recipe}
+    cfg = build(conf, **passed)
+
+    for key, field in {**WIDTHS,
+                       **(MOE_WIDTHS if kind == "train_moe" else {})}.items():
+        assert getattr(cfg, field) == conf[key], (name, key)
+    for key, value in passed.items():
+        assert getattr(cfg, key) == value, (name, key)
+    assert cfg.dtype == getattr(jnp, conf["run"]["dtype"])
+
+    # the loss the cell's step differentiates traces with this config at
+    # the cell's own batch: shapes only, nothing runs
+    mod = sys.modules[type(cfg).__module__]
+    mix = cell["mix"]
+    params = jax.eval_shape(
+        lambda: mod.init_params(jax.random.PRNGKey(0), cfg))
+    tokens = jax.ShapeDtypeStruct((mix["batch"], mix["seq"] + 1), jnp.int32)
+    out = jax.eval_shape(lambda p, t: mod.loss_fn(p, {"tokens": t}, cfg),
+                         params, tokens)
+    loss = out[0] if isinstance(out, tuple) else out
+    assert loss.shape == () and loss.dtype == jnp.float32

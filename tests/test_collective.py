@@ -271,26 +271,3 @@ def test_train_worker_group_host_collective(ray_start_regular):
         wg.destroy_host_collective("wg_col")
     finally:
         wg.shutdown()
-
-
-@pytest.mark.slow
-def test_collective_bench_smoke(ray_start_regular, tmp_path):
-    """`bench.py --bench collective` sweep writes the scoreboard file."""
-    import json
-    import sys
-
-    sys.path.insert(0, "/root/repo")
-    try:
-        from bench import run_collective_bench
-    finally:
-        sys.path.pop(0)
-
-    out = tmp_path / "BENCH_collective.json"
-    result = run_collective_bench(world_sizes=(2,), payload_mib=(0.0625,),
-                                  backends=("gather", "ring"), rounds=2,
-                                  out_path=str(out))
-    assert out.exists()
-    data = json.loads(out.read_text())
-    assert data["metric"] == "collective_allreduce_ring_best_mib_per_s"
-    cells = {c["backend"] for c in data["extra"]["sweep"] if "error" not in c}
-    assert {"gather", "ring"} <= cells, data["extra"]["sweep"]

@@ -416,38 +416,6 @@ def test_global_prefix_adoption_second_replica(ray_start_regular):
     b._exporter.close()
 
 
-@pytest.mark.slow
-def test_serve_disagg_bench_smoke(ray_start_8cpu, tmp_path):
-    """`bench.py --bench serve_disagg` writes the scoreboard file with
-    the acceptance block and honest transfer accounting."""
-    import json
-    import sys
-
-    sys.path.insert(0, "/root/repo")
-    try:
-        from bench import run_serve_disagg_bench
-    finally:
-        sys.path.pop(0)
-
-    out = tmp_path / "BENCH_serve_disagg.json"
-    result = run_serve_disagg_bench(concurrency=8, n_long=6, n_short=18,
-                                    repeats=1, out_path=str(out),
-                                    init_cluster=False)
-    assert out.exists()
-    data = json.loads(out.read_text())
-    assert data["metric"] == \
-        "serve_disagg_short_ttft_p99_speedup_vs_monolithic"
-    dz = data["extra"]["disaggregated"]
-    assert dz["handoffs"] >= 24 and dz["handoffs_lost"] == 0
-    # each page group's bytes crossed the store exactly once
-    assert dz["exactly_once_cluster_lifetime"], dz
-    assert set(data["extra"]["acceptance"]) == {
-        "disagg_beats_mono_decode_ttft_p99", "tok_per_s_within_10pct",
-        "global_hit_rate_above_local_0_61_baseline",
-        "page_bytes_cross_store_exactly_once"}
-    assert result["value"] is not None
-
-
 def test_spill_tier_counters_surface_in_state(ray_start_regular):
     """The nodelet's lifetime spill/restore counters ride node_stats into
     memory_summary() per node and fold into memory_report()'s

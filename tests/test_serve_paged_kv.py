@@ -354,9 +354,9 @@ def test_int8_quantized_engine_serves():
                                                                   out)
 
 
-# --- the Pallas kernels themselves, interpreted ----------------------------
+# --- the Pallas kernel itself, interpreted ----------------------------------
 # Program code has no interpret branch (off the chip it returns the jnp
-# reference), so without these the kernels' first execution anywhere
+# reference), so without this the kernel's first execution anywhere
 # would be on a chip.
 
 
@@ -402,16 +402,6 @@ def test_paged_inplace_kernel_interpreted_matches_reference(
                                   np.asarray(rk)[:, 1:])
     np.testing.assert_array_equal(np.asarray(v2)[:, 1:],
                                   np.asarray(rv)[:, 1:])
-
-
-def test_paged_read_kernel_interpreted_matches_reference(interpreted_kernels):
-    pa = interpreted_kernels
-    q, _, _, kp, vp, table, lengths = _paged_case(2)
-    live = np.asarray(lengths) > 0
-    np.testing.assert_allclose(
-        np.asarray(pa.paged_attention(q, kp, vp, table, lengths))[live],
-        np.asarray(paged_attention_reference(q, kp, vp, table,
-                                             lengths))[live], atol=1e-5)
 
 
 # --- a step that raises ------------------------------------------------------

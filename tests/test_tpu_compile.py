@@ -14,7 +14,6 @@ Kernels pick their chip branch from ``jax.default_backend()``, which is
 """
 
 import os
-import sys
 
 import pytest
 
@@ -78,7 +77,7 @@ def _with_shardings(shapes, shardings):
 
 
 # (batch, seq, heads, kv_heads, head_dim) of the attention call each
-# preset's train step makes at bench.py's shapes
+# preset's train step makes (2b7: chip_smoke.py's batch)
 FLASH_WIDTHS = {
     "2b7": (5, 1024, 20, 20, 128),
     "debug-125m": (8, 1024, 12, 12, 64),
@@ -212,9 +211,10 @@ def test_paged_decode_kernel_compiles(preset, one_chip, on_chip_branch):
     assert "tpu_custom_call" in text, text[:2000]
 
 
-def _lower_train_step(mesh, rules, batch, seq):
-    """The 2b7 train step at bench.py's recipe (bf16 params, flash, remat,
-    adafactor), lowered for ``mesh`` from shapes alone."""
+def _lower_train_step(mesh, rules, batch, seq, cfg=None, opt=None):
+    """A llama train step lowered for ``mesh`` from shapes alone: by
+    default 2b7 as chip_smoke.py trains it (bf16 params, flash, remat,
+    bf16 logits, adafactor)."""
     import jax
     import jax.numpy as jnp
     import optax
@@ -224,10 +224,12 @@ def _lower_train_step(mesh, rules, batch, seq):
                                              make_train_state_init,
                                              make_train_step)
 
-    cfg = llama.PRESETS["2b7"].replace(
-        dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, remat=True,
-        attn_impl="flash", f32_logits=False)
-    opt = optax.adafactor(3e-4)
+    if cfg is None:
+        cfg = llama.PRESETS["2b7"].replace(
+            dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, remat=True,
+            attn_impl="flash", f32_logits=False)
+    if opt is None:
+        opt = optax.adafactor(3e-4)
     init_fn, state_sh = make_train_state_init(
         lambda k: llama.init_params(k, cfg), opt, mesh, rules,
         llama.param_specs(cfg))
@@ -309,25 +311,27 @@ def test_2b7_engine_programs_compile(one_chip, on_chip_branch):
 
 
 def test_llama7b_fsdp_fits_v5e8_hbm(topo, no_persistent_cache):
-    """North-star HBM feasibility: the REAL 7B sharded train step against
-    a device-less v5e:2x4 (compile_case describes it; ``topo`` has shown
-    by then that this process can) — the compiler enforces the 16 GB
-    budget (a config that does not fit fails with RESOURCE_EXHAUSTED) and
-    reports per-device peak memory. BASELINE.md target 2."""
+    """HBM feasibility of BASELINE.md target 2: the 7B train step (f32
+    master weights, adamw with a bf16 first moment, XLA attention, B8 x
+    S2048) sharded ``fsdp=8`` over a described v5e:2x4 (``topo`` has shown
+    by then that this process can describe one). The compiler enforces
+    the 16 GB budget (a program that does not fit fails with
+    RESOURCE_EXHAUSTED) and reports the peak memory of a device."""
     import jax.numpy as jnp
+    import optax
+    from jax.experimental import topologies
 
-    rel = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                       os.pardir, "release")
-    sys.path.insert(0, rel)
-    try:
-        from model_scale_benchmark import compile_case
-    finally:
-        sys.path.pop(0)
-    r = compile_case(preset="7b", chip="v5e", mesh_axes={"fsdp": 8},
-                     rules_name="fsdp", batch=8, seq=2048,
-                     mu_dtype=jnp.bfloat16)
-    assert r["fits"], r
-    assert r["peak_hbm_gb"] <= 16.0, r
-    # the projection should land in the plausible band for 7B on v5e
-    assert 1000 < r["projected_tokens_per_sec_per_chip"] < 20000, r
-    assert r["params"] > 6.5e9
+    from ray_tpu.models import llama
+    from ray_tpu.parallel import MeshSpec, ShardingRules, build_mesh
+
+    v5e8 = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x4")
+    mesh = build_mesh(MeshSpec(fsdp=8), devices=v5e8.devices)
+    cfg = llama.PRESETS["7b"].replace(
+        dtype=jnp.bfloat16, remat=True, attn_impl="xla", f32_logits=False,
+        max_seq_len=2048)
+    assert llama.num_params(cfg) > 6.5e9
+    opt = optax.adamw(3e-4, weight_decay=0.01, mu_dtype=jnp.bfloat16)
+    compiled = _lower_train_step(mesh, ShardingRules.fsdp(), 8, 2048,
+                                 cfg=cfg, opt=opt).compile()
+    assert compiled.memory_analysis().peak_memory_in_bytes <= V5E_HBM
