@@ -27,6 +27,12 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.parallel.collective_matmul import (allgather_matmul,
+                                                gather_apply_scatter,
+                                                matmul_reduce_scatter,
+                                                overlap_plan)
+from ray_tpu.util import tracing
+
 
 @dataclass(frozen=True)
 class LlamaConfig:
@@ -294,14 +300,9 @@ def _flash_sharded(q, k, v, window, mesh, rules):
     kernel = functools.partial(flash_attention, causal=True, window=window)
     if mesh is None or rules is None:
         return kernel(q, k, v)
-    from ray_tpu.parallel.sharding import logical_to_mesh
+    from ray_tpu.parallel.sharding import mesh_axes
 
-    def mesh_axes(logical):   # size>1 mesh axes of one logical axis
-        spec = logical_to_mesh((logical,), rules, mesh)
-        a = spec[0] if len(spec) else None
-        return () if a is None else (a,) if isinstance(a, str) else tuple(a)
-
-    batch, heads = mesh_axes("batch"), mesh_axes("heads")
+    batch, heads = (mesh_axes(a, rules, mesh) for a in ("batch", "heads"))
     n_head_shards = 1
     for a in heads:
         n_head_shards *= int(mesh.shape[a])
@@ -344,12 +345,16 @@ def _attention(q, k, v, cfg: LlamaConfig, causal=True, q_offset=0,
 
 
 def _attention_half(x, lp, cfg: LlamaConfig, cos, sin, cache=None, mesh=None,
-                    rules=None):
+                    rules=None, tp=None):
     """The attention half of a block: x [B, S, D] -> (x + attention, k, v,
     new_cache). cache: (k, v, offset) or None. With ``cfg.qk_norm`` (an
     OLMoE block) q and k pass an RMS norm over the WHOLE projected vector,
     one learned scale each (``q_norm``, ``k_norm``), before the split into
-    heads. mesh+rules reach the flash kernel's shard_map (_flash_sharded)."""
+    heads. mesh+rules reach the flash kernel's shard_map (_flash_sharded).
+    With a plan ``tp`` (_tp_plan) x is sharded over its rows on the tensor
+    axis: q/k/v share one gather of them that runs under their matmuls,
+    and ``wo`` ends in a reduce-scatter that runs under its own
+    (parallel/collective_matmul.py)."""
     B, S, D = x.shape
     H, KV, HD = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     dt = cfg.dtype
@@ -361,11 +366,26 @@ def _attention_half(x, lp, cfg: LlamaConfig, cos, sin, cache=None, mesh=None,
             y = rms_norm(y, lp[norm], cfg.norm_eps)
         return y.reshape(B, S, n, HD)
 
-    q = heads(h @ _dq(lp["wq"], dt), H, "q_norm")
-    k = heads(h @ _dq(lp["wk"], dt), KV, "k_norm")
-    v = heads(h @ _dq(lp["wv"], dt), KV)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    if tp is None:
+        q = heads(h @ _dq(lp["wq"], dt), H, "q_norm")
+        k = heads(h @ _dq(lp["wk"], dt), KV, "k_norm")
+        v = heads(h @ _dq(lp["wv"], dt), KV)
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
+    else:
+        def in_heads(i, y, shard, cos, sin):
+            # one shard's rows of q (0), k (1) or v (2), as its matmul
+            # leaves them: split into heads and turned, then joined
+            y = y.reshape(*y.shape[:2], -1, HD)
+            if i == 2:
+                return y
+            at = lambda t: jax.lax.dynamic_slice_in_dim(       # noqa: E731
+                t, shard * y.shape[1], y.shape[1])
+            return apply_rope(y, at(cos), at(sin))
+
+        q, k, v = allgather_matmul(
+            h, [_dq(lp[w], dt) for w in ("wq", "wk", "wv")], tp,
+            then=in_heads, extras=(cos, sin))
 
     new_cache = None
     if cache is not None:
@@ -381,6 +401,9 @@ def _attention_half(x, lp, cfg: LlamaConfig, cos, sin, cache=None, mesh=None,
     else:
         attn = _attention(q, k, v, cfg, causal=True, mesh=mesh, rules=rules)
     attn = attn.reshape(B, S, H * HD)
+    if tp is not None:
+        return x + matmul_reduce_scatter(attn, _dq(lp["wo"], dt), tp), \
+            k, v, new_cache
     return x + attn @ _dq(lp["wo"], dt), k, v, new_cache
 
 
@@ -389,20 +412,26 @@ def _attention_half(x, lp, cfg: LlamaConfig, cos, sin, cache=None, mesh=None,
 REMAT_SAVED = ()
 
 
-def feed_forward(h, lp, cfg: LlamaConfig, mesh=None, rules=None):
+def feed_forward(h, lp, cfg: LlamaConfig, mesh=None, rules=None, tp=None):
     """The dense feed-forward half of a block, a SwiGLU: normed h
     [B, S, D] -> (its output [B, S, D], None). The second value is what a
     family's feed-forward reports of itself layer by layer (an expert
     layer's routing statistics, models/moe.py); the dense one has nothing
-    to report."""
+    to report. With a plan ``tp`` every shard's rows pass from the gather
+    through the SwiGLU to the reduce-scatter on their own."""
     dt = cfg.dtype
+    if tp is not None:
+        return gather_apply_scatter(
+            h, [_dq(lp["w_gate"], dt), _dq(lp["w_up"], dt)],
+            lambda gate, up: jax.nn.silu(gate) * up,
+            _dq(lp["w_down"], dt), tp), None
     gate = jax.nn.silu(h @ _dq(lp["w_gate"], dt))
     up = h @ _dq(lp["w_up"], dt)
     return (gate * up) @ _dq(lp["w_down"], dt), None
 
 
 def _layer(x, lp, cfg: LlamaConfig, cos, sin, cache=None, collect_kv=False,
-           mesh=None, rules=None):
+           mesh=None, rules=None, tp=None):
     """One transformer block: the attention half, then the family's
     feed-forward half (dense SwiGLU here, the expert layer for a
     MoEConfig). x: [B, S, D]. Returns (x, kv, stats): kv is the updated
@@ -410,24 +439,58 @@ def _layer(x, lp, cfg: LlamaConfig, cos, sin, cache=None, collect_kv=False,
     collect_kv=True (cache seeding), else None; stats is what the
     feed-forward reports (None for the dense one)."""
     x, k, v, new_cache = _attention_half(x, lp, cfg, cos, sin, cache=cache,
-                                         mesh=mesh, rules=rules)
+                                         mesh=mesh, rules=rules, tp=tp)
     h = rms_norm(x, lp["ffn_norm"], cfg.norm_eps)
-    y, stats = _family(cfg).feed_forward(h, lp, cfg, mesh=mesh, rules=rules)
+    y, stats = _family(cfg).feed_forward(h, lp, cfg, mesh=mesh, rules=rules,
+                                         tp=tp)
     return x + y, ((k, v) if collect_kv else new_cache), stats
 
 
-def _act_constraint(mesh, rules):
+def _tp_plan(cfg: LlamaConfig, mesh, rules, batch: int, seq: int):
+    """How this forward's tensor-parallel matmuls communicate, read from
+    what it is given: an ``OverlapPlan`` (parallel/collective_matmul.py:
+    the residual stream sharded over the sequence on the tensor axis,
+    half-row permutes under the matmuls) where the rules put ``heads`` and
+    ``mlp`` on one mesh axis of size n > 1 and n divides the sequence, the
+    heads, the KV heads (a shard's columns are split into WHOLE heads) and
+    the feed-forward's width; else None, and the program is the plain one.
+    A family with a feed-forward of its own (models/moe.py) stays plain
+    too: its expert layer is not row-parallel in this sense, and would be
+    handed rows it has to gather first."""
+    if _family(cfg).feed_forward is not feed_forward:
+        return None
+    return overlap_plan(mesh, rules, batch, seq,
+                        (cfg.n_heads, cfg.n_kv_heads, cfg.d_ff))
+
+
+def _say_tp_plan(tp, cfg: LlamaConfig, batch: int, seq: int):
+    """The instant ``tp.overlap_plan`` of a trace, once a traced forward
+    that was given a mesh and rules, after its layers are traced: which
+    path they took and, from the helpers' own calls, at how many sites."""
+    rows = seq // tp.shards if tp else seq
+    tracing.instant("tp.overlap_plan", {
+        "path": "overlap" if tp else "plain",
+        "shards": tp.shards if tp else 1,
+        # a layer's gathers (q/k/v; gate/up) and scatters (wo; w_down)
+        "sites": len(tp.sites) if tp else 0, "rows_per_step": rows,
+        "bytes_per_permute": batch // tp.batch_shards * rows * cfg.d_model
+        * jnp.dtype(cfg.dtype).itemsize if tp else 0})
+
+
+def _act_constraint(mesh, rules, tp=None):
     """Activation sharding constraint [batch, seq, embed] for the dense
     forward. Without it GSPMD is free to re-replicate intermediates — at
     7B the rematted attention backward materialized the FULL-batch
     [B, H, S, S] f32 scores on every device (8 GB/chip at B=16 S=2048),
     blowing v5e HBM; constraining the per-layer activation pins the
-    batch axis down and the whole backward stays batch-sharded."""
+    batch axis down and the whole backward stays batch-sharded. With a
+    plan ``tp`` the sequence lies over the tensor axis between blocks."""
     if mesh is None or rules is None:
         return lambda x: x
     from ray_tpu.parallel.sharding import named_sharding
 
-    sh = named_sharding(mesh, ("batch", "seq", None), rules)
+    sh = named_sharding(mesh, ("batch", "seq", None), rules) \
+        if tp is None else tp.rows_sharding()
     return lambda x: jax.lax.with_sharding_constraint(x, sh)
 
 
@@ -446,7 +509,8 @@ def forward_with_stats(params, tokens, cfg: LlamaConfig, pos_offset=0,
     over layers (None for the dense model): (logits, stats)."""
     dt = cfg.dtype
     B, S = tokens.shape
-    con = _act_constraint(mesh, rules)
+    tp = _tp_plan(cfg, mesh, rules, B, S)
+    con = _act_constraint(mesh, rules, tp)
     x = con(_embed(params, tokens, dt))
     if isinstance(pos_offset, int) and pos_offset == 0:
         cos, sin = _rope_tables(cfg.rope_theta, S, cfg.head_dim)
@@ -457,13 +521,18 @@ def forward_with_stats(params, tokens, cfg: LlamaConfig, pos_offset=0,
         sin = jax.lax.dynamic_slice_in_dim(sin_full, pos_offset, S, axis=0)
 
     def body(x, lp):
-        y, _, stats = _layer(x, lp, cfg, cos, sin, mesh=mesh, rules=rules)
+        y, _, stats = _layer(x, lp, cfg, cos, sin, mesh=mesh, rules=rules,
+                             tp=tp)
         return con(y), stats
 
     if cfg.remat:
         body = _checkpoint(body, cfg)
     x, stats = jax.lax.scan(body, x, params["layers"])
+    if mesh is not None and rules is not None:
+        _say_tp_plan(tp, cfg, B, S)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    if tp is not None:    # the head wants every row: one gather a step
+        x = jax.lax.with_sharding_constraint(x, tp.gathered_sharding())
     logits = x @ _dq(params["lm_head"], dt)
     return (logits.astype(jnp.float32) if cfg.f32_logits else logits), stats
 

@@ -210,9 +210,11 @@ def route(logits, cfg: MoEConfig):
     return weights, experts, probs
 
 
-def feed_forward(h, lp, cfg: MoEConfig, mesh=None, rules=None):
+def feed_forward(h, lp, cfg: MoEConfig, mesh=None, rules=None, tp=None):
     """The expert layer: normed h [B, S, D] -> (its output [B, S, D],
-    this layer's routing statistics for ``finish_loss``)."""
+    this layer's routing statistics for ``finish_loss``). It takes every
+    row of a sequence and no overlap plan (llama._tp_plan gives none)."""
+    assert tp is None, "the expert layer is not row-parallel"
     if cfg.gmm_impl == "pallas" and mesh is not None and mesh.size > 1:
         raise NotImplementedError(
             "gmm_impl='pallas' runs on one device: GSPMD cannot partition "

@@ -1,0 +1,243 @@
+"""Tensor-parallel matmuls that carry their own communication.
+
+A Megatron block under a tensor axis of n shards ends its row-parallel
+matmuls (``wo``, ``w_down``) in an all-reduce of the whole activation and
+starts its column-parallel ones (q/k/v, gate/up) from a replicated input.
+XLA emits that all-reduce as a blocking op between the matmul that makes
+its operand and the norm that reads its result: nothing else runs on the
+core meanwhile (PERF.md 5: 150 of them a step on the four-chip cell).
+
+Here the residual stream between the blocks is sharded over the SEQUENCE
+on the tensor axis (Megatron sequence parallelism), so the all-reduce
+splits into a reduce-scatter after the row-parallel matmul and an
+all-gather before the column-parallel one, and each of those is
+decomposed into n - 1 ``ppermute``s of one shard's rows around a ring,
+with the matmul of the rows already here running under the transfer of
+the next (the collective matmul of Wang et al., ASPLOS 2023). At n = 2
+each is ONE permute of half the rows.
+
+  allgather_matmul(h, ws)        h rows-sharded, each w column-sharded:
+                                 step t multiplies the rows of shard
+                                 i - t while they travel on to i + 1
+  matmul_reduce_scatter(a, w)    a column-sharded, w row-sharded: step t
+                                 multiplies the rows that belong to shard
+                                 i - 1 - t, adds what arrived, sends it
+                                 on; the own rows come last
+  gather_apply_scatter(...)      both around a row-wise function (the
+                                 SwiGLU): the gathered rows are never
+                                 joined
+
+All three are ``jax.shard_map``s manual over every mesh axis, as the
+flash kernel's is (models/llama.py ``_flash_sharded``) and for its
+reason, and are differentiated by jax's own transposition: the transpose
+of a gather-side permute is a scatter-side one, so the backward overlaps
+the same way with no backward code here, but for the two row
+permutations (``_join``, ``_split``), each of which names the other as
+its transpose. What was tried on the chip and lost: PERF.md 6, PR 30.
+
+Whether a forward takes them is read from what it is given
+(``overlap_plan``), never set: no config field, no environment variable.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+from dataclasses import dataclass, field
+from typing import Any, Callable, List, Optional, Sequence, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+from ray_tpu.parallel.sharding import ShardingRules, mesh_axes
+
+
+@dataclass(frozen=True)
+class OverlapPlan:
+    """Where the tensor axis lies. ``batch``: the mesh axes (of size > 1)
+    that shard the batch dimension, possibly none. ``sites``: the gathers
+    and scatters traced under this plan so far, one name each."""
+    mesh: Any
+    axis: str
+    shards: int
+    batch: Tuple[str, ...]
+    sites: List[str] = field(default_factory=list, compare=False, repr=False)
+
+    @property
+    def batch_shards(self) -> int:
+        return math.prod(int(self.mesh.shape[a]) for a in self.batch)
+
+    def rows(self) -> P:
+        """[batch, seq over the tensor axis, features]."""
+        return P(self.batch or None, self.axis, None)
+
+    def columns(self) -> P:
+        """[batch, seq, features over the tensor axis, ...]."""
+        return P(self.batch or None, None, self.axis)
+
+    def rows_sharding(self) -> NamedSharding:
+        return NamedSharding(self.mesh, self.rows())
+
+    def gathered_sharding(self) -> NamedSharding:
+        return NamedSharding(self.mesh, P(self.batch or None, None, None))
+
+
+def overlap_plan(mesh, rules: Optional[ShardingRules], batch: int, seq: int,
+                 units: Sequence[int]) -> Optional[OverlapPlan]:
+    """The plan for a forward over ``[batch, seq]`` tokens whose
+    tensor-parallel matmuls have ``units`` indivisible columns on their
+    sharded side (heads, not their widths: a shard holds whole heads), or
+    None where the plain ``h @ w`` is the program: no mesh or rules, no
+    ONE mesh axis of size n > 1 under both ``heads`` and ``mlp``, a
+    sequence the rules already shard (``seq`` on ``sp``), or rows, units
+    or batch that do not divide."""
+    if mesh is None or rules is None:
+        return None
+    heads, mlp = mesh_axes("heads", rules, mesh), mesh_axes("mlp", rules, mesh)
+    if heads != mlp or len(heads) != 1:
+        return None
+    axis = heads[0]
+    n = int(mesh.shape[axis])
+    plan = OverlapPlan(mesh, axis, n, mesh_axes("batch", rules, mesh))
+    if axis in plan.batch or mesh_axes("seq", rules, mesh) or seq % n \
+            or batch % plan.batch_shards or any(u % n for u in units):
+        return None
+    return plan
+
+
+def _ring(n: int):
+    return [(j, (j + 1) % n) for j in range(n)]
+
+
+def _travelling(x, plan: OverlapPlan):
+    """The rows of every shard, one a step, as they come round the ring:
+    step t yields the rows of shard i - t. The next step's rows are sent
+    on before this step's are handed out, so that what the caller does
+    with them runs under the transfer."""
+    n = plan.shards
+    for t in range(n):
+        nxt = jax.lax.ppermute(x, plan.axis, _ring(n)) if t + 1 < n else None
+        yield x
+        x = nxt
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
+def _join(parts, plan: OverlapPlan, first: int = 0):
+    """``parts[t]``: the rows of shard i - first - t, [b, s, ...] each ->
+    the whole sequence [b, n * s, ...] in its own order: block b of it is
+    the part that arrived at step i - first - b. Written as a choice
+    among the parts and not as an update of a buffer at a computed row:
+    the choice fuses into whatever reads the sequence next, the update is
+    a copy of every part."""
+    n = plan.shards
+    i = jax.lax.axis_index(plan.axis)
+    return jnp.concatenate(
+        [jax.lax.select_n((i + 2 * n - first - b) % n, *parts)
+         for b in range(n)], axis=1)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
+def _split(a, plan: OverlapPlan, first: int = 0):
+    """The inverse of ``_join``: [b, n * s, K] -> the rows of shard
+    i - first - t for t = 0..n-1. A permutation: its transpose is its
+    inverse, so the gradient is ``_join`` of the parts' gradients (jax's
+    own for n dynamic slices would be n padded copies and their sum)."""
+    n, s = plan.shards, a.shape[1] // plan.shards
+    i = jax.lax.axis_index(plan.axis)
+    return tuple(jax.lax.dynamic_slice_in_dim(
+        a, (i + 2 * n - first - t) % n * s, s, axis=1) for t in range(n))
+
+
+_split.defvjp(lambda a, plan, first: (_split(a, plan, first), None),
+              lambda plan, first, _, g: (_join(tuple(g), plan, first),))
+_join.defvjp(lambda parts, plan, first: (_join(parts, plan, first), None),
+             lambda plan, first, _, g: (_split(g, plan, first),))
+
+
+def _scattered(rows, w, plan: OverlapPlan):
+    """Reduce-scatter of ``rows[t] @ w`` around the ring. ``rows[t]``:
+    this shard's columns of the rows of shard i - 1 - t (t = n - 1: its
+    own). Each product is added to what arrived from shard i - 1 and sent
+    on to i + 1; the own rows' product is made while the last transfer
+    runs."""
+    n = plan.shards
+    acc = None
+    for t in range(n):
+        part = rows[t] @ w
+        if acc is not None:
+            # both sides of the sum stand in memory before it is taken:
+            # fused into the matmul's own output the sum would make the
+            # matmul wait for the transfer it is there to cover
+            part, acc = jax.lax.optimization_barrier((part, acc))
+            part = part + acc
+        acc = jax.lax.ppermute(part, plan.axis, _ring(n)) \
+            if t + 1 < n else part
+    return acc
+
+
+def allgather_matmul(h, ws: Sequence[Any], plan: OverlapPlan,
+                     then: Optional[Callable] = None, extras=()):
+    """``h`` [B, S, D], S over the tensor axis; each ``w`` [D, N], N over
+    it -> ``[h_all @ w for w in ws]``, each [B, S, N] with N over the
+    tensor axis. One gather serves all of ``ws`` (q/k/v; gate/up).
+    ``then(k, y, shard, *extras)`` is applied to the product ``y`` of
+    ``ws[k]`` with the rows of ``shard`` before they are joined (what is
+    row-wise after the matmul: the split into heads, the rotary), so that
+    the join is the last thing before the consumer and fuses into it;
+    ``extras`` reach it whole on every shard. It may split the last
+    dimension: [b, s, N] -> [b, s, H, d]."""
+    plan.sites.append("gather")
+
+    def shard(h, extras, *ws):
+        i, n = jax.lax.axis_index(plan.axis), plan.shards
+        parts = []
+        for t, rows in enumerate(_travelling(h, plan)):
+            ys = [rows @ w for w in ws]
+            if then is not None:
+                ys = [then(k, y, (i + n - t) % n, *extras)
+                      for k, y in enumerate(ys)]
+            parts.append(ys)
+        return tuple(_join(tuple(p[k] for p in parts), plan)
+                     for k in range(len(ws)))
+
+    return jax.shard_map(
+        shard, mesh=plan.mesh,
+        in_specs=(plan.rows(), P()) + (P(None, plan.axis),) * len(ws),
+        out_specs=(plan.columns(),) * len(ws), check_vma=False)(
+            h, tuple(extras), *ws)
+
+
+def matmul_reduce_scatter(a, w, plan: OverlapPlan):
+    """``a`` [B, S, K], K over the tensor axis; ``w`` [K, D], K over it ->
+    ``a @ w`` summed over the shards, [B, S, D] with S over the tensor
+    axis."""
+    plan.sites.append("scatter")
+
+    def shard(a, w):
+        return _scattered(_split(a, plan, 1), w, plan)
+
+    return jax.shard_map(
+        shard, mesh=plan.mesh, in_specs=(plan.columns(), P(plan.axis, None)),
+        out_specs=plan.rows(), check_vma=False)(a, w)
+
+
+def gather_apply_scatter(h, ws: Sequence[Any], fn: Callable, w_out,
+                         plan: OverlapPlan):
+    """``matmul_reduce_scatter(fn(*allgather_matmul(h, ws)), w_out)`` for
+    an ``fn`` that works row by row (the SwiGLU): every shard's rows go
+    from the gather through ``fn`` to the scatter on their own, so the
+    gathered [B, S, N] is never assembled. The own rows arrive first and
+    leave last: ``fn`` of them waits while the others pass."""
+    plan.sites.extend(("gather", "scatter"))
+
+    def shard(h, w_out, *ws):
+        mid = [fn(*[rows @ w for w in ws]) for rows in _travelling(h, plan)]
+        # mid[t]: the rows of shard i - t; the scatter wants i - 1, ..., i
+        return _scattered(mid[1:] + mid[:1], w_out, plan)
+
+    return jax.shard_map(
+        shard, mesh=plan.mesh,
+        in_specs=(plan.rows(), P(plan.axis, None))
+        + (P(None, plan.axis),) * len(ws),
+        out_specs=plan.rows(), check_vma=False)(h, w_out, *ws)

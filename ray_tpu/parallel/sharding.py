@@ -58,7 +58,20 @@ class ShardingRules:
 
     @classmethod
     def fsdp_tp(cls) -> "ShardingRules":
-        """+ Megatron tensor parallelism: head/mlp/vocab dims on tp."""
+        """+ Megatron tensor parallelism: head/mlp/vocab dims on tp.
+
+        ``seq`` stays unnamed here, and yet a dense llama forward under
+        these rules shards the SEQUENCE dimension of the residual stream
+        over tp between blocks (Megatron sequence parallelism): with
+        ``heads`` and ``mlp`` on one axis of n > 1 shards dividing the
+        sequence, ``models/llama.py`` ends its row-parallel matmuls in a
+        reduce-scatter and starts its column-parallel ones from an
+        all-gather, both as half-row permutes that run under the matmuls
+        (``parallel/collective_matmul.py``), instead of one blocking
+        all-reduce of the whole activation. Inside attention the sequence
+        is whole again and the heads are sharded. ``kv_heads`` is not on
+        tp: wk/wv and their optimizer state are replicated over it and
+        their gradients gathered once a step (ROADMAP.md S1)."""
         return cls.fsdp().with_(mlp="tp", heads="tp", vocab="tp")
 
     @classmethod
@@ -107,6 +120,13 @@ def logical_to_mesh(logical_spec: Tuple[Optional[str], ...],
     while out and out[-1] is None:
         out.pop()
     return PartitionSpec(*out)
+
+
+def mesh_axes(logical: str, rules: ShardingRules, mesh) -> Tuple[str, ...]:
+    """The mesh axes of size > 1 that one logical axis is sharded over."""
+    spec = logical_to_mesh((logical,), rules, mesh)
+    a = spec[0] if len(spec) else None
+    return () if a is None else (a,) if isinstance(a, str) else tuple(a)
 
 
 def named_sharding(mesh, logical_spec, rules: ShardingRules):

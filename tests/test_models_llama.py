@@ -339,3 +339,160 @@ def test_sharded_flash_adafactor_step_matches_one_device(rules_name,
     if rules_name != "dp":
         wq = state.params["layers"]["wq"]
         assert wq.addressable_shards[0].data.shape != wq.shape
+
+
+# --- the tensor axis's overlap plan (parallel/collective_matmul.py) ---------
+
+
+def _loss_and_grads(mod, cfg, batch, mesh=None, rules=None):
+    """(loss, gradients, jaxpr text) of ``mod.loss_fn`` on one set of
+    weights, placed by ``rules`` where a mesh is given."""
+    from ray_tpu.parallel import shard_params
+
+    params = mod.init_params(jax.random.PRNGKey(3), cfg)
+    if mesh is not None:
+        params = shard_params(mesh, params, mod.param_specs(cfg), rules)
+
+    def loss(p):
+        out = mod.loss_fn(p, batch, cfg, mesh=mesh, rules=rules)
+        return out[0] if isinstance(out, tuple) else out
+
+    value, grads = jax.jit(jax.value_and_grad(loss))(params)
+    return float(value), jax.device_get(grads), str(jax.make_jaxpr(loss)(params))
+
+
+def _moe_tiny():
+    from ray_tpu.models import moe
+
+    return moe, moe.PRESETS["tiny"].replace(dtype=jnp.float32)
+
+
+# (family and config, rules, tokens a row, whether the overlap plan engages)
+TP_PLAN_CASES = {
+    # S 128: the flash kernel under its shard_map between the two helpers
+    "flash-s128": (lambda: (llama, _flash_cfg(True, "mha")), "fsdp_tp", 129,
+                   True),
+    "xla-s32-gqa": (lambda: (llama, CFG.replace(remat=True)), "fsdp_tp", 33,
+                    True),
+    # 31 rows over 2 shards: the plain program, line for line
+    "rows-do-not-divide": (lambda: (llama, CFG), "fsdp_tp", 32, False),
+    # one KV head over 2 shards: its width divides, the head does not, so
+    # the flash shard_map keeps the heads whole and the plan stays out
+    "kv-heads-do-not-divide": (lambda: (llama, _flash_cfg(True).replace(
+        n_kv_heads=1)), "fsdp_tp", 129, False),
+    "xla-kv-heads-do-not-divide": (lambda: (llama, CFG.replace(
+        n_kv_heads=1)), "fsdp_tp", 33, False),
+    # a block whose feed-forward is an expert layer stays plain as a whole
+    "moe-under-ep": (_moe_tiny, "ep", 33, False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(TP_PLAN_CASES))
+def test_fsdp_tp_loss_and_gradients_match_one_device(case):
+    """``loss_fn`` and every gradient under MeshSpec(fsdp=2, tp=2) against
+    the single-device program, where the tensor-parallel matmuls overlap
+    their own communication and where the plan does not engage."""
+    family, rules_name, width, engages = TP_PLAN_CASES[case]
+    mod, cfg = family()
+    mesh = build_mesh(MeshSpec(fsdp=2, tp=2), devices=jax.devices()[:4])
+    rules = getattr(ShardingRules, rules_name)()
+    batch = {"tokens": jax.random.randint(jax.random.PRNGKey(12), (4, width),
+                                          0, cfg.vocab_size)}
+    want, want_g, _ = _loss_and_grads(mod, cfg, batch)
+    got, got_g, text = _loss_and_grads(mod, cfg, batch, mesh, rules)
+    assert ("ppermute" in text) == engages
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    jax.tree.map(lambda a, b: np.testing.assert_allclose(
+        np.asarray(a), np.asarray(b), rtol=2e-4, atol=1e-5), got_g, want_g)
+
+
+def test_tp_overlap_plan_instant_says_which_path():
+    """``tp.overlap_plan`` once a traced forward with a mesh: the
+    attributes a profile around a lowering shows."""
+    from ray_tpu.util import tracing
+
+    seen = []
+    mesh = build_mesh(MeshSpec(fsdp=2, tp=2), devices=jax.devices()[:4])
+    rules = ShardingRules.fsdp_tp()
+    params = jax.eval_shape(lambda: llama.init_params(jax.random.PRNGKey(0),
+                                                      CFG))
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(tracing, "instant",
+                   lambda name, attrs=None, **kw: seen.append((name, attrs)))
+        for width in (32, 31):
+            jax.eval_shape(lambda p, t: llama.forward(
+                p, t, CFG, mesh=mesh, rules=rules), params,
+                jax.ShapeDtypeStruct((4, width), jnp.int32))
+        jax.eval_shape(lambda p, t: llama.forward(p, t, CFG), params,
+                       jax.ShapeDtypeStruct((4, 32), jnp.int32))
+    plans = [a for n, a in seen if n == "tp.overlap_plan"]
+    assert plans == [
+        {"path": "overlap", "shards": 2, "sites": 4, "rows_per_step": 16,
+         # two rows of the batch a device x 16 rows x 64 wide x f32
+         "bytes_per_permute": 2 * 16 * CFG.d_model * 4},
+        {"path": "plain", "shards": 1, "sites": 0, "rows_per_step": 31,
+         "bytes_per_permute": 0}]
+
+
+def _primitives(jaxpr, into=None):
+    """How often each primitive stands in a jaxpr, its sub-jaxprs (scan
+    and checkpoint bodies, custom-derivative calls) included."""
+    import collections
+
+    into = collections.Counter() if into is None else into
+    for eqn in jaxpr.eqns:
+        into[eqn.primitive.name] += 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _primitives(sub, into)
+    return into
+
+
+# The one-device program is to stay the program it was before the overlap
+# plan (PR 29's tree). What holds under any jax: it names no mesh and moves
+# nothing between devices. Under the jax that traced them (JAXPRS_FROM), also
+# the sha256 (first 16 hex digits) of str(jax.make_jaxpr(value_and_grad(
+# loss))) with function addresses cut out, B2 x S128 tokens, as that tree
+# traced it; another jax prints another text, and the digests are then left
+# alone. A deliberate change to the model's forward changes them: trace the
+# parent and the change, compare the primitive counts the failure prints,
+# and replace them.
+JAXPRS_FROM = "0.9.0"
+ONE_DEVICE_JAXPRS = {
+    ("dense", 2, "flash", True, "bfloat16"): "146c43e811e92f05",
+    ("dense", 4, "flash", True, "bfloat16"): "463153eaf29cd165",
+    ("dense", 2, "xla", False, "float32"): "751bfcca654a5c93",
+    ("moe", 2, "flash", True, "bfloat16"): "95b57d24d3862885",
+    ("moe", 2, "xla", False, "float32"): "8d6c8419471c919a",
+}
+
+
+@pytest.mark.parametrize("case", sorted(ONE_DEVICE_JAXPRS, key=str))
+def test_one_device_jaxpr_is_the_program_before_the_overlap_plan(case):
+    import hashlib
+    import re
+
+    family, kv, attn, remat, dt = case
+    if family == "dense":
+        mod, cfg = llama, llama.PRESETS["tiny"].replace(n_kv_heads=kv)
+    else:
+        mod, cfg = _moe_tiny()
+    cfg = cfg.replace(attn_impl=attn, remat=remat, dtype=getattr(jnp, dt),
+                      max_seq_len=128)
+    params = jax.eval_shape(lambda: mod.init_params(jax.random.PRNGKey(0),
+                                                    cfg))
+    batch = {"tokens": jax.ShapeDtypeStruct((2, 129), jnp.int32)}
+
+    def loss(p, b):
+        out = mod.loss_fn(p, b, cfg)
+        return out[0] if isinstance(out, tuple) else out
+
+    closed = jax.make_jaxpr(jax.value_and_grad(loss))(params, batch)
+    counts = _primitives(closed.jaxpr)
+    across = {"ppermute", "shard_map", "sharding_constraint", "psum",
+              "all_gather", "all_to_all", "axis_index"} & set(counts)
+    assert not across, across
+    assert (counts["pallas_call"] > 0) == (attn == "flash")
+    if jax.__version__ == JAXPRS_FROM:
+        text = re.sub(r" at 0x[0-9a-f]+", "", str(closed))
+        assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
+            ONE_DEVICE_JAXPRS[case], sorted(counts.items())

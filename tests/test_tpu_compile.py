@@ -243,11 +243,55 @@ def _lower_train_step(mesh, rules, batch, seq, cfg=None, opt=None):
     return step.lower(state, batch_abs)
 
 
-def test_2b7_train_step_fits_one_chip(topo, on_chip_branch):
+_STEPS = {}       # compiled 2b7 steps, shared by the tests of one shape
+
+
+def _step_2b7(topo, chips, tp=2):
+    """The compiled 2b7 train step on one chip (B5 x S1024, dp) or on the
+    four of a v5e:2x2 (B8 x S1024, MeshSpec(fsdp=2, tp=2) or
+    MeshSpec(tp=4), fsdp_tp)."""
     from ray_tpu.parallel import MeshSpec, ShardingRules, build_mesh
 
-    mesh = build_mesh(MeshSpec(dp=-1), devices=topo.devices[:1])
-    compiled = _lower_train_step(mesh, ShardingRules.dp(), 5, 1024).compile()
+    if (chips, tp) not in _STEPS:
+        if chips == 1:
+            mesh = build_mesh(MeshSpec(dp=-1), devices=topo.devices[:1])
+            lowered = _lower_train_step(mesh, ShardingRules.dp(), 5, 1024)
+        else:
+            mesh = build_mesh(MeshSpec(fsdp=4 // tp, tp=tp),
+                              devices=topo.devices)
+            assert len({d.id for d in mesh.devices.flat}) == 4
+            lowered = _lower_train_step(mesh, ShardingRules.fsdp_tp(), 8,
+                                        1024)
+        _STEPS[chips, tp] = lowered.compile()
+    return _STEPS[chips, tp]
+
+
+def _while_bodies(text):
+    """The scheduled instructions of every ``while`` body of a compiled
+    module: {computation name: [line, ...]}."""
+    import re
+
+    names = set(re.findall(r"body=%?([\w.\-]+)", text))
+    bodies, cur = {}, None
+    for ln in text.splitlines():
+        m = re.match(r"^%?([\w.\-]+) \(.*\) -> .* \{$", ln)
+        if m:
+            cur = m.group(1) if m.group(1) in names else None
+            if cur:
+                bodies[cur] = []
+        elif ln.startswith("}"):
+            cur = None
+        elif cur:
+            bodies[cur].append(ln.strip())
+    return bodies
+
+
+COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
+               "collective-permute")
+
+
+def test_2b7_train_step_fits_one_chip(topo, on_chip_branch):
+    compiled = _step_2b7(topo, 1)
     mem = compiled.memory_analysis()
     need = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert need < V5E_HBM, mem
@@ -256,23 +300,85 @@ def test_2b7_train_step_fits_one_chip(topo, on_chip_branch):
     assert compiled.as_text().count("tpu_custom_call") == 3
 
 
+def test_one_chip_step_has_no_collective(topo, on_chip_branch):
+    """No tensor axis, no plan: the one-chip program talks to nobody."""
+    text = _step_2b7(topo, 1).as_text()
+    assert not [c for c in COLLECTIVES
+                if c + "(" in text or c + "-start(" in text]
+
+
 def test_2b7_fsdp_tp_flash_step_compiles_on_four_chips(topo, on_chip_branch):
     """The README's first example with the kernel the one-chip numbers
     rest on: GSPMD cannot partition a Mosaic call, so this compiles only
     while models/llama.py wraps it in a shard_map, and only while
     adafactor's rank-1 state gets a valid sharding."""
-    from ray_tpu.parallel import MeshSpec, ShardingRules, build_mesh
-
-    mesh = build_mesh(MeshSpec(fsdp=2, tp=2), devices=topo.devices)
-    assert len({d.id for d in mesh.devices.flat}) == 4
-    compiled = _lower_train_step(mesh, ShardingRules.fsdp_tp(), 8,
-                                 1024).compile()
+    compiled = _step_2b7(topo, 4)
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < V5E_HBM, mem
     text = compiled.as_text()
-    assert "tpu_custom_call" in text
+    assert text.count("tpu_custom_call") == 3
     assert "all-gather" in text and ("reduce-scatter" in text
                                      or "all-reduce" in text)
+
+
+def _loop_permutes(text, rows_shape):
+    """[(loop body, permute, matmul fusions between its start and its
+    done)] for every collective-permute of ``rows_shape`` in the layer
+    loops of a compiled step, having checked that the loops hold no
+    blocking all-reduce of an activation and that nothing joins or splits
+    the rows as an op of its own (a copy of every part)."""
+    import re
+
+    bodies = _while_bodies(text)
+    assert len(bodies) == 2, sorted(bodies)           # forward, backward
+    permutes = []
+    for name, lines in bodies.items():
+        alone = [ln for ln in lines if re.search(
+            r"= \w+\[\d+,\d+,\d+[\],]\S* (all-reduce|concatenate|select|"
+            r"dynamic-update-slice)\(", ln)]
+        assert not alone, (name, alone)
+        starts = {}
+        for at, ln in enumerate(lines):
+            head = ln.split(" = ")[0]
+            if " collective-permute-start(" in ln and rows_shape in ln:
+                starts[head] = at
+            done = re.search(r" collective-permute-done\((%[\w.\-]+)\)", ln)
+            if done and done.group(1) in starts:
+                under = lines[starts[done.group(1)] + 1:at]
+                permutes.append((name, head, sum(
+                    "convolution" in u.split(" = ")[0] or "kind=kOutput" in u
+                    for u in under)))
+    return permutes
+
+
+def test_four_chip_step_overlaps_its_tensor_parallel_traffic(topo,
+                                                             on_chip_branch):
+    """Under a tensor axis the layer loops hold no blocking all-reduce of
+    an activation: each became half-row collective-permutes
+    (parallel/collective_matmul.py), 4 a layer forward and 7 backward, and
+    the scheduler put a matmul fusion between every start and its done."""
+    # B8 / fsdp 2, S1024 / tp 2, D
+    permutes = _loop_permutes(_step_2b7(topo, 4).as_text(),
+                              "bf16[4,512,2560]")
+    assert len(permutes) == 11, permutes
+    assert all(matmuls >= 1 for _, _, matmuls in permutes), permutes
+
+
+def test_four_shard_ring_overlaps_most_of_its_traffic(topo, on_chip_branch):
+    """The ring of n - 1 permutes at tp=4, which no cell runs: three
+    quarter-row permutes for each of tp=2's one, no all-reduce, no join as
+    an op of its own, the kernels still there. What the scheduler leaves
+    bare is pinned as found: 3 of the 33 (PERF.md 7), the last step of the
+    feed-forward's gather and of its scatter forward (the own rows'
+    ``w_down`` product is hoisted above the transfer it was to cover) and
+    one gather of the backward."""
+    compiled = _step_2b7(topo, 4, tp=4)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 3
+    permutes = _loop_permutes(text, "bf16[8,256,2560]")   # B8, S1024 / 4, D
+    assert len(permutes) == 33, permutes
+    bare = [p for p in permutes if p[2] == 0]
+    assert len(bare) <= 3, bare
 
 
 def test_2b7_engine_programs_compile(one_chip, on_chip_branch):
