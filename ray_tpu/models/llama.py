@@ -66,6 +66,16 @@ class LlamaConfig:
     # the forward scan) or "1f1b" (explicitly-scheduled backward with an
     # O(M)-activation stash; parallel/pipeline.py).
     pp_schedule: str = "gpipe"
+    # What a family may state of its own (models/hybrid.py does); None is
+    # llama's: rows of the embedding as they are, a half-block added to x
+    # as it is, logits undivided, scores times head_dim ** -0.5. Read by
+    # forward_with_stats and the layer under it; the cached and paged
+    # paths know none of them and refuse such a config (init_cache).
+    embedding_multiplier: Any = None
+    residual_multiplier: Any = None
+    logits_scaling: Any = None
+    attn_scale: Any = None
+    rope: bool = True                # rotary position embedding
 
     @property
     def head_dim(self) -> int:
@@ -231,8 +241,9 @@ def _checkpoint(body, cfg: "LlamaConfig"):
     log-sum-exp B x H x S x 4), so the backward kernels run from them and
     the forward kernel runs once, and what the family's feed-forward
     names (REMAT_SAVED: an expert layer's routes); everything else is
-    recomputed. A body that holds no such name (attn_impl other than
-    "flash", under 128 tokens) saves nothing more."""
+    recomputed, a state-space mixer's scan too (ops/ssd.py). A body that
+    holds no such name (attn_impl other than "flash", under 128 tokens)
+    saves nothing more."""
     from ray_tpu.ops.flash_attention import FLASH_RESIDUALS
 
     return jax.checkpoint(
@@ -263,16 +274,18 @@ def apply_rope(x, cos, sin):
                            axis=-1).astype(x.dtype)
 
 
-def _attention_xla(q, k, v, causal: bool, q_offset=0, window=None):
+def _attention_xla(q, k, v, causal: bool, q_offset=0, window=None,
+                   scale=None):
     """Plain einsum attention; XLA fuses this well on TPU for moderate S.
-    q: [B, S, H, D], k/v: [B, T, KV, D] (GQA broadcast)."""
+    q: [B, S, H, D], k/v: [B, T, KV, D] (GQA broadcast). ``scale``
+    multiplies the scores where the model states its own (else D ** -0.5)."""
     B, S, H, D = q.shape
     T, KV = k.shape[1], k.shape[2]
     groups = H // KV
     q = q.reshape(B, S, KV, groups, D)
     scores = jnp.einsum("bskgd,btkd->bkgst", q, k,
                         preferred_element_type=jnp.float32)
-    scores = scores / (D ** 0.5)
+    scores = scores / (D ** 0.5) if scale is None else scores * scale
     if causal:
         qpos = jnp.arange(S)[:, None] + q_offset
         kpos = jnp.arange(T)[None, :]
@@ -285,7 +298,7 @@ def _attention_xla(q, k, v, causal: bool, q_offset=0, window=None):
     return out.reshape(B, S, H, D)
 
 
-def _flash_sharded(q, k, v, window, mesh, rules):
+def _flash_sharded(q, k, v, window, mesh, rules, scale=None):
     """Flash attention under a mesh. GSPMD cannot partition a Mosaic
     kernel, so the kernel runs per shard inside a shard_map: batch over
     the rules' batch axes, heads over their heads axes (only when they
@@ -297,7 +310,8 @@ def _flash_sharded(q, k, v, window, mesh, rules):
     program."""
     from ray_tpu.ops.flash_attention import flash_attention
 
-    kernel = functools.partial(flash_attention, causal=True, window=window)
+    kernel = functools.partial(flash_attention, causal=True, window=window,
+                               scale=scale)
     if mesh is None or rules is None:
         return kernel(q, k, v)
     from ray_tpu.parallel.sharding import mesh_axes
@@ -320,6 +334,10 @@ def _flash_sharded(q, k, v, window, mesh, rules):
 def _attention(q, k, v, cfg: LlamaConfig, causal=True, q_offset=0,
                mesh=None, rules=None):
     win = cfg.sliding_window
+    scale = cfg.attn_scale                      # None: head_dim ** -0.5
+    if scale is not None and cfg.attn_impl in ("ring", "ulysses"):
+        raise ValueError("a configured softmax scale is not supported with "
+                         "ring/ulysses attention")
     if win is not None and cfg.attn_impl in ("ring", "ulysses"):
         # silently computing FULL attention here would train a different
         # model than the config describes
@@ -332,7 +350,7 @@ def _attention(q, k, v, cfg: LlamaConfig, causal=True, q_offset=0,
     at_origin = isinstance(q_offset, int) and q_offset == 0
     if cfg.attn_impl == "flash" and causal and q.shape[1] >= 128 \
             and at_origin:
-        return _flash_sharded(q, k, v, win, mesh, rules)
+        return _flash_sharded(q, k, v, win, mesh, rules, scale)
     if cfg.attn_impl == "ring":
         from ray_tpu.ops.ring_attention import ring_attention
 
@@ -341,7 +359,7 @@ def _attention(q, k, v, cfg: LlamaConfig, causal=True, q_offset=0,
         from ray_tpu.ops.ulysses import ulysses_attention
 
         return ulysses_attention(q, k, v, axis_name="sp")
-    return _attention_xla(q, k, v, causal, q_offset, window=win)
+    return _attention_xla(q, k, v, causal, q_offset, window=win, scale=scale)
 
 
 def _attention_half(x, lp, cfg: LlamaConfig, cos, sin, cache=None, mesh=None,
@@ -350,7 +368,11 @@ def _attention_half(x, lp, cfg: LlamaConfig, cos, sin, cache=None, mesh=None,
     new_cache). cache: (k, v, offset) or None. With ``cfg.qk_norm`` (an
     OLMoE block) q and k pass an RMS norm over the WHOLE projected vector,
     one learned scale each (``q_norm``, ``k_norm``), before the split into
-    heads. mesh+rules reach the flash kernel's shard_map (_flash_sharded).
+    heads. Without rotary tables (``cos`` None: a model with no position
+    embedding) q and k go to the kernel as they are; a config's
+    ``attn_scale`` replaces the softmax's head_dim ** -0.5 and its
+    ``residual_multiplier`` scales what is added to x.
+    mesh+rules reach the flash kernel's shard_map (_flash_sharded).
     With a plan ``tp`` (_tp_plan) x is sharded over its rows on the tensor
     axis: q/k/v share one gather of them that runs under their matmuls,
     and ``wo`` ends in a reduce-scatter that runs under its own
@@ -370,8 +392,9 @@ def _attention_half(x, lp, cfg: LlamaConfig, cos, sin, cache=None, mesh=None,
         q = heads(h @ _dq(lp["wq"], dt), H, "q_norm")
         k = heads(h @ _dq(lp["wk"], dt), KV, "k_norm")
         v = heads(h @ _dq(lp["wv"], dt), KV)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
+        if cos is not None:
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
     else:
         def in_heads(i, y, shard, cos, sin):
             # one shard's rows of q (0), k (1) or v (2), as its matmul
@@ -402,9 +425,15 @@ def _attention_half(x, lp, cfg: LlamaConfig, cos, sin, cache=None, mesh=None,
         attn = _attention(q, k, v, cfg, causal=True, mesh=mesh, rules=rules)
     attn = attn.reshape(B, S, H * HD)
     if tp is not None:
-        return x + matmul_reduce_scatter(attn, _dq(lp["wo"], dt), tp), \
-            k, v, new_cache
-    return x + attn @ _dq(lp["wo"], dt), k, v, new_cache
+        return _residual(x, matmul_reduce_scatter(attn, _dq(lp["wo"], dt),
+                                                  tp), cfg), k, v, new_cache
+    return _residual(x, attn @ _dq(lp["wo"], dt), cfg), k, v, new_cache
+
+
+def _residual(x, y, cfg: LlamaConfig):
+    """x + y, y scaled where the config has a ``residual_multiplier``."""
+    by = cfg.residual_multiplier
+    return x + y if by is None else x + (y * by).astype(x.dtype)
 
 
 # checkpoint_name tags the dense feed-forward wants kept across the layer
@@ -431,19 +460,26 @@ def feed_forward(h, lp, cfg: LlamaConfig, mesh=None, rules=None, tp=None):
 
 
 def _layer(x, lp, cfg: LlamaConfig, cos, sin, cache=None, collect_kv=False,
-           mesh=None, rules=None, tp=None):
+           mesh=None, rules=None, tp=None, kind=None):
     """One transformer block: the attention half, then the family's
     feed-forward half (dense SwiGLU here, the expert layer for a
     MoEConfig). x: [B, S, D]. Returns (x, kv, stats): kv is the updated
     (k, v) cache slices when ``cache`` is given, this layer's (k, v) with
     collect_kv=True (cache seeding), else None; stats is what the
-    feed-forward reports (None for the dense one)."""
-    x, k, v, new_cache = _attention_half(x, lp, cfg, cos, sin, cache=cache,
-                                         mesh=mesh, rules=rules, tp=tp)
+    feed-forward reports (None for the dense one). ``kind``: None or
+    "attention" for the attention half; any other kind of layer takes its
+    first half from the family's ``mixer_half`` (x, lp, cfg, kind -> x)."""
+    if kind in (None, "attention"):
+        x, k, v, new_cache = _attention_half(
+            x, lp, cfg, cos, sin, cache=cache, mesh=mesh, rules=rules, tp=tp)
+    else:
+        assert cache is None and not collect_kv, kind
+        x, k, v, new_cache = _family(cfg).mixer_half(
+            x, lp, cfg, kind, mesh=mesh), None, None, None
     h = rms_norm(x, lp["ffn_norm"], cfg.norm_eps)
     y, stats = _family(cfg).feed_forward(h, lp, cfg, mesh=mesh, rules=rules,
                                          tp=tp)
-    return x + y, ((k, v) if collect_kv else new_cache), stats
+    return _residual(x, y, cfg), ((k, v) if collect_kv else new_cache), stats
 
 
 def _tp_plan(cfg: LlamaConfig, mesh, rules, batch: int, seq: int):
@@ -477,6 +513,16 @@ def _say_tp_plan(tp, cfg: LlamaConfig, batch: int, seq: int):
         * jnp.dtype(cfg.dtype).itemsize if tp else 0})
 
 
+def _say_layer_plan(runs, bodies: int):
+    """The instant ``hybrid.layer_plan`` of a trace, once a traced forward
+    of a model whose layers are a list of runs: how many kinds of layer,
+    how many runs of adjacent layers of one kind (one scan each) and how
+    many bodies were built for them (one a kind)."""
+    tracing.instant("hybrid.layer_plan", {
+        "kinds": len({k for k, _ in runs}), "runs": len(runs),
+        "bodies": bodies, "layers": sum(n for _, n in runs)})
+
+
 def _act_constraint(mesh, rules, tp=None):
     """Activation sharding constraint [batch, seq, embed] for the dense
     forward. Without it GSPMD is free to re-replicate intermediates — at
@@ -506,13 +552,27 @@ def forward(params, tokens, cfg: LlamaConfig, pos_offset=0, mesh=None,
 def forward_with_stats(params, tokens, cfg: LlamaConfig, pos_offset=0,
                        mesh=None, rules=None):
     """``forward`` and what every layer's feed-forward reported, stacked
-    over layers (None for the dense model): (logits, stats)."""
+    over layers (None for the dense model): (logits, stats).
+
+    ``params["layers"]`` is one stack of identical layers, scanned by one
+    body, or, for a family with layers of several kinds (``layer_runs``:
+    models/hybrid.py), a list of stacks, one a run of adjacent layers of
+    one kind: each run is scanned by its kind's body, traced from ONE
+    function a kind whatever the depth. Such a family may also scale the
+    embedding (``embedding_multiplier``), do without rotary tables
+    (``rope`` False), tie the head to the embedding (no ``lm_head``) and
+    divide the logits (``logits_scaling``)."""
     dt = cfg.dtype
     B, S = tokens.shape
     tp = _tp_plan(cfg, mesh, rules, B, S)
     con = _act_constraint(mesh, rules, tp)
-    x = con(_embed(params, tokens, dt))
-    if isinstance(pos_offset, int) and pos_offset == 0:
+    x = _embed(params, tokens, dt)
+    if cfg.embedding_multiplier is not None:
+        x = (x * cfg.embedding_multiplier).astype(dt)
+    x = con(x)
+    if not cfg.rope:
+        cos = sin = None
+    elif isinstance(pos_offset, int) and pos_offset == 0:
         cos, sin = _rope_tables(cfg.rope_theta, S, cfg.head_dim)
     else:
         cos_full, sin_full = _rope_tables(cfg.rope_theta, cfg.max_seq_len,
@@ -520,20 +580,37 @@ def forward_with_stats(params, tokens, cfg: LlamaConfig, pos_offset=0,
         cos = jax.lax.dynamic_slice_in_dim(cos_full, pos_offset, S, axis=0)
         sin = jax.lax.dynamic_slice_in_dim(sin_full, pos_offset, S, axis=0)
 
-    def body(x, lp):
-        y, _, stats = _layer(x, lp, cfg, cos, sin, mesh=mesh, rules=rules,
-                             tp=tp)
-        return con(y), stats
+    @functools.cache
+    def body_of(kind):
+        def body(x, lp):
+            y, _, stats = _layer(x, lp, cfg, cos, sin, mesh=mesh, rules=rules,
+                                 tp=tp, kind=kind)
+            return con(y), stats
 
-    if cfg.remat:
-        body = _checkpoint(body, cfg)
-    x, stats = jax.lax.scan(body, x, params["layers"])
+        return _checkpoint(body, cfg) if cfg.remat else body
+
+    if isinstance(params["layers"], dict):
+        x, stats = jax.lax.scan(body_of(None), x, params["layers"])
+    else:
+        runs = _family(cfg).layer_runs(cfg)
+        assert len(runs) == len(params["layers"]), (runs, len(params["layers"]))
+        stats = []
+        for (kind, _), stack in zip(runs, params["layers"]):
+            x, s = jax.lax.scan(body_of(kind), x, stack)
+            stats.append(s)
+        stats = jax.tree.map(lambda *s: jnp.concatenate(s), *stats)
+        _say_layer_plan(runs, body_of.cache_info().currsize)
     if mesh is not None and rules is not None:
         _say_tp_plan(tp, cfg, B, S)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     if tp is not None:    # the head wants every row: one gather a step
         x = jax.lax.with_sharding_constraint(x, tp.gathered_sharding())
-    logits = x @ _dq(params["lm_head"], dt)
+    if "lm_head" in params:
+        logits = x @ _dq(params["lm_head"], dt)
+    else:                                   # tied to the embedding
+        logits = jnp.einsum("bsd,vd->bsv", x, _dq(params["embed"], dt))
+    if cfg.logits_scaling is not None:
+        logits = logits / cfg.logits_scaling
     return (logits.astype(jnp.float32) if cfg.f32_logits else logits), stats
 
 
@@ -646,8 +723,21 @@ class KVCache(NamedTuple):
     length: jax.Array   # [B] int32 — per-sequence filled length
 
 
+def _refuse_stated(cfg: LlamaConfig):
+    """The cached and paged paths embed, rotate, scale and add as llama
+    does: a config that states otherwise would be served as another model."""
+    stated = [f for f in ("embedding_multiplier", "residual_multiplier",
+                          "logits_scaling", "attn_scale")
+              if getattr(cfg, f) is not None] + ([] if cfg.rope else ["rope"])
+    if stated:
+        raise NotImplementedError(
+            f"a KV cache for a config that states {', '.join(stated)}: the "
+            "cached paths apply none of them (forward_with_stats does)")
+
+
 def init_cache(cfg: LlamaConfig, batch: int, max_seq: Optional[int] = None,
                dtype=None) -> KVCache:
+    _refuse_stated(cfg)
     S = max_seq or cfg.max_seq_len
     shape = (cfg.n_layers, batch, S, cfg.n_kv_heads, cfg.head_dim)
     dt = dtype or cfg.dtype
@@ -794,6 +884,7 @@ def init_paged_cache(cfg: LlamaConfig, num_pages: int, page_size: int,
     """Paged KV pools [L, KV, num_pages, page_size, HD] (SURVEY §7.9 /
     ops/paged_attention.py layout; page 0 is the trash page inactive
     slots write into). HBM scales with pages, not slots*max_seq."""
+    _refuse_stated(cfg)
     dt = dtype or cfg.dtype
     shape = (cfg.n_layers, cfg.n_kv_heads, num_pages, page_size,
              cfg.head_dim)

@@ -30,6 +30,21 @@ in expert order, gate, up and their SwiGLU, and not the down projection
 
 On a mesh that shards ``experts`` (``ShardingRules.ep()``) the "xla" path
 runs under GSPMD; the "pallas" path refuses any mesh of several devices.
+
+A chip's share of the experts (``experts_held``: how many, and the first):
+the router, ``route`` and the statistics stay ``n_experts`` wide; the
+weights' first axis, the group sizes and the rows gathered are the held
+experts' only (``_held_experts``, ``_held_combine``: a second way through
+the layer, because its rows are a data-dependent FEW of the T*K: the
+assignments sorted by held expert with the absent last, computed a pass of
+twice the even share at a time and, in a layer that got more, in further
+short passes; ``_dispatch`` and ``_down_combine`` gather all T*K rows and
+serve the layer that holds every expert). An assignment to an absent
+expert contributes nothing and is no dropped row: its part of the result
+is another chip's; every assignment to a held expert is computed.
+``shared_d_ff`` adds a dense SwiGLU that every token passes beside the
+routed experts, whole on every chip. With every expert held and no shared
+width the program is the one described above.
 """
 
 from __future__ import annotations
@@ -37,14 +52,14 @@ from __future__ import annotations
 import dataclasses
 import functools
 from dataclasses import dataclass
-from typing import Any, Dict
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.models import llama as _ll
-from ray_tpu.ops.grouped_matmul import grouped_matmul
+from ray_tpu.ops.grouped_matmul import GMM_TILING, grouped_matmul
 
 
 @dataclass(frozen=True)
@@ -61,6 +76,15 @@ class MoEConfig(_ll.LlamaConfig):
     router_aux_weight: float = 0.01     # load balancing
     router_z_weight: float = 0.001      # mean squared logsumexp
     gmm_impl: str = "xla"               # "xla" | "pallas"
+    # (count, first): the experts whose weights live here, of the
+    # n_experts the router scores; None: all of them
+    experts_held: Optional[Tuple[int, int]] = None
+    # width of a SwiGLU every token passes beside its experts; 0: none
+    shared_d_ff: int = 0
+
+    @property
+    def n_held(self) -> int:
+        return self.experts_held[0] if self.experts_held else self.n_experts
 
     def replace(self, **kw) -> "MoEConfig":
         return dataclasses.replace(self, **kw)
@@ -95,6 +119,10 @@ def param_specs(cfg: MoEConfig) -> Dict[str, Any]:
     lay["we_gate"] = L + ("experts", "embed", "expert_mlp")
     lay["we_up"] = L + ("experts", "embed", "expert_mlp")
     lay["we_down"] = L + ("experts", "expert_mlp", "embed")
+    if cfg.shared_d_ff:
+        lay["ws_gate"] = L + ("embed", "mlp")
+        lay["ws_up"] = L + ("embed", "mlp")
+        lay["ws_down"] = L + ("mlp", "embed")
     spec["layers"] = lay
     return spec
 
@@ -103,6 +131,7 @@ def init_params(key, cfg: MoEConfig) -> Dict[str, Any]:
     params = _ll.init_params(key, cfg.replace(d_ff=1))   # no dense SwiGLU
     pd = cfg.param_dtype
     L, D, F, E = cfg.n_layers, cfg.d_model, cfg.d_ff, cfg.n_experts
+    H, Fs = cfg.n_held, cfg.shared_d_ff
     ks = jax.random.split(jax.random.fold_in(key, 1), 4)
     lay = dict(params["layers"])
     for w in ("w_gate", "w_up", "w_down"):
@@ -111,9 +140,14 @@ def init_params(key, cfg: MoEConfig) -> Dict[str, Any]:
         lay["q_norm"] = jnp.ones((L, cfg.n_heads * cfg.head_dim), pd)
         lay["k_norm"] = jnp.ones((L, cfg.n_kv_heads * cfg.head_dim), pd)
     lay["router"] = jax.random.normal(ks[0], (L, D, E), pd) * 0.02
-    lay["we_gate"] = jax.random.normal(ks[1], (L, E, D, F), pd) * D ** -0.5
-    lay["we_up"] = jax.random.normal(ks[2], (L, E, D, F), pd) * D ** -0.5
-    lay["we_down"] = jax.random.normal(ks[3], (L, E, F, D), pd) * F ** -0.5
+    lay["we_gate"] = jax.random.normal(ks[1], (L, H, D, F), pd) * D ** -0.5
+    lay["we_up"] = jax.random.normal(ks[2], (L, H, D, F), pd) * D ** -0.5
+    lay["we_down"] = jax.random.normal(ks[3], (L, H, F, D), pd) * F ** -0.5
+    if Fs:
+        ks = jax.random.split(jax.random.fold_in(key, 2), 3)
+        lay["ws_gate"] = jax.random.normal(ks[0], (L, D, Fs), pd) * D ** -0.5
+        lay["ws_up"] = jax.random.normal(ks[1], (L, D, Fs), pd) * D ** -0.5
+        lay["ws_down"] = jax.random.normal(ks[2], (L, Fs, D), pd) * Fs ** -0.5
     params["layers"] = lay
     return params
 
@@ -122,7 +156,7 @@ def num_params(cfg: MoEConfig) -> int:
     D, E, F = cfg.d_model, cfg.n_experts, cfg.d_ff
     qk = (cfg.n_heads + cfg.n_kv_heads) * cfg.head_dim if cfg.qk_norm else 0
     return _ll.num_params(cfg.replace(d_ff=0)) + cfg.n_layers * (
-        qk + D * E + 3 * E * D * F)
+        qk + D * E + 3 * cfg.n_held * D * F + 3 * D * cfg.shared_d_ff)
 
 
 def _rows(tokens, k, order):
@@ -210,6 +244,167 @@ def route(logits, cfg: MoEConfig):
     return weights, experts, probs
 
 
+# Rows computed AT A TIME for a share of the experts: this many times what
+# the held experts get of T*K assignments when every expert is as likely
+# (shapes are static; the grouped matmul skips the tiles no group covers,
+# the gathers do not). A layer whose held experts got more runs further
+# passes, a quarter as long, over the rest (``_held_combine``), so no
+# assignment is ever dropped and the step takes as long as the router
+# made it. With random weights a layer's share of 9 experts in 72 read
+# 0.044 to 0.242 over 370 layers (even: 0.125; PERF.md 6, PR 32): twice
+# the even share is the least that leaves the further passes to the tail.
+HELD_PASS = 2
+
+
+def _count(ids, n: int):
+    """How many of ``ids`` [M] are each of 0..n-1, int32 [n]: a compare
+    and a sum (a scatter-add of M ones walks them one by one on a TPU)."""
+    return jnp.sum(ids[:, None] == jnp.arange(n, dtype=ids.dtype), axis=0,
+                   dtype=jnp.int32)
+
+
+def held_rows(cfg: MoEConfig, tokens: int) -> int:
+    """Rows of one pass over the held experts' assignments of ``tokens``
+    tokens: whole row tiles of the grouped matmul, at most every
+    assignment."""
+    rows = -(-tokens * cfg.top_k * cfg.n_held * HELD_PASS // cfg.n_experts)
+    tile = GMM_TILING[0]
+    return min(-(-rows // tile) * tile, tokens * cfg.top_k)
+
+
+def _held_slots(lo, rows: int, ranked, counts):
+    """The slots of one pass over the assignments to held experts, ``rows``
+    of them from the ``lo``-th on in the held experts' order (ranked
+    [T*K]: the assignments sorted by held expert, the absent last; counts
+    [held]) -> (order [rows], the assignment in each slot; live [rows, 1],
+    false beyond the last held one; sizes [held] for the grouped matmul:
+    the part of each expert's run that falls into the pass)."""
+    start = jnp.cumsum(counts) - counts          # an expert's run in ranked
+    slot = lo + jnp.arange(rows, dtype=jnp.int32)
+    sizes = jnp.maximum(jnp.minimum(start + counts, lo + rows)
+                        - jnp.maximum(start, lo), 0)
+    return (ranked[jnp.minimum(slot, ranked.shape[0] - 1)],
+            (slot < counts.sum())[:, None], sizes)
+
+
+def _held_swiglu(xs, w_rows, live, sizes, we, cfg: MoEConfig):
+    """The rows of a pass through their experts: xs [rows, D] (dead rows
+    0), w_rows [rows] their routing weights, we the (gate, up, down)
+    weights [held, ...] -> [rows, D], dead rows 0."""
+    # the kernel leaves the rows no group covers unwritten: cleared, or
+    # what is there meets a gradient of 0 and may be no number
+    mm = lambda a, w: jnp.where(live, grouped_matmul(           # noqa: E731
+        a, w, sizes, impl=cfg.gmm_impl), 0)
+    # a row's weight multiplies it where it is narrow, before the down
+    # projection (linear in its rows)
+    h = jax.nn.silu(mm(xs, we[0])) * mm(xs, we[1])
+    return mm((h.astype(jnp.float32) * w_rows[:, None]).astype(xs.dtype),
+              we[2])
+
+
+def _held_rows(lo, rows: int, x, weights, ranked, counts, k: int):
+    """A pass's rows (``_held_slots``): (token [rows], order, live, sizes,
+    the tokens' rows of x with the dead ones 0, the rows' weights)."""
+    order, live, sizes = _held_slots(lo, rows, ranked, counts)
+    token = order // k
+    return (token, order, live, sizes, jnp.where(live, x[token], 0),
+            weights.reshape(-1)[order])
+
+
+def _passes(counts, skip: int, rows: int):
+    """How many passes of ``rows`` the assignments to held experts beyond
+    the first ``skip`` need: none in all but a layer of the tail."""
+    return jnp.maximum(-(-(counts.sum() - skip) // rows), 0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _held_combine(cfg, rows, short, x, weights, ranked, counts, we):
+    """The held experts' part of the layer's output [T, D]: x [T, D],
+    weights [T, K], ranked [T*K] (the assignments sorted by held expert,
+    the absent last), counts [held], we the (gate, up, down) weights.
+
+    The first ``rows`` assignments to held experts are one pass, and all
+    of them in all but a layer of the tail; where a layer's counts ask for
+    more, the rest follow ``short`` rows at a time in a loop whose length
+    the data decide, so no assignment is dropped whatever the router does.
+    Such a loop has no gradient of jax's own, hence the custom VJP: the
+    first pass's backward is jax's (of ``_held_swiglu``, kept from the
+    forward), each further pass runs again under its own, and all of them
+    add the rows' gradients into the accumulators the first pass made, so
+    the loops hold one short pass at a time and nothing more that is T
+    rows wide. The
+    sum back into token order is a scatter-add of the rows (a gather T*K
+    rows wide would move several times as much)."""
+    return _held_combine_fwd(cfg, rows, short, x, weights, ranked, counts,
+                             we)[0]
+
+
+def _held_combine_fwd(cfg, rows, short, x, weights, ranked, counts, we):
+    K = cfg.top_k
+    token, order, live, sizes, xs, w_rows = _held_rows(
+        0, rows, x, weights, ranked, counts, K)
+    ys, back = jax.vjp(lambda xs, w_rows, we: _held_swiglu(
+        xs, w_rows, live, sizes, we, cfg), xs, w_rows, we)
+
+    def one(p, y):
+        token, _, live, sizes, xs, w_rows = _held_rows(
+            rows + p * short, short, x, weights, ranked, counts, K)
+        return y.at[token].add(_held_swiglu(xs, w_rows, live, sizes, we, cfg))
+
+    y = jax.lax.fori_loop(0, _passes(counts, rows, short), one,
+                          jnp.zeros(x.shape, jnp.float32).at[token].add(ys))
+    return y.astype(x.dtype), (back, token, order, live, x, weights, ranked,
+                               counts, we)
+
+
+def _held_combine_bwd(cfg, rows, short, res, dy):
+    back, token, order, live, x, weights, ranked, counts, we = res
+    K = cfg.top_k
+
+    def add(acc, token, order, live, d_xs, d_rows, d_we):
+        return (acc[0].at[token].add(jnp.where(live, d_xs, 0)),
+                acc[1].at[order].add(jnp.where(live[:, 0], d_rows, 0)),
+                d_we if acc[2] is None else jax.tree.map(jnp.add, acc[2],
+                                                         d_we))
+
+    def one(p, acc):
+        token, order, live, sizes, xs, w_rows = _held_rows(
+            rows + p * short, short, x, weights, ranked, counts, K)
+        _, again = jax.vjp(lambda xs, w_rows, we: _held_swiglu(
+            xs, w_rows, live, sizes, we, cfg), xs, w_rows, we)
+        return add(acc, token, order, live, *again(dy[token]))
+
+    d_x, d_weights, d_we = jax.lax.fori_loop(
+        0, _passes(counts, rows, short), one, add(
+            (jnp.zeros_like(x), jnp.zeros(weights.size, weights.dtype), None),
+            token, order, live, *back(dy[token])))
+    return d_x, d_weights.reshape(weights.shape), None, None, d_we
+
+
+_held_combine.defvjp(_held_combine_fwd, _held_combine_bwd)
+
+
+def _held_experts(x, weights, experts, lp, cfg: MoEConfig):
+    """The held experts' part of the layer's output: x [T, D], weights and
+    experts [T, K] (over all n_experts) -> (y [T, D], counts of the held
+    experts [held], how many passes beyond the first they took). The
+    assignments are sorted by held expert, the absent last, and the held
+    ones computed ``held_rows`` at a time, then a quarter as many
+    (``_held_combine``)."""
+    T, K, dt = x.shape[0], cfg.top_k, cfg.dtype
+    held, first = cfg.experts_held
+    local = experts.reshape(T * K) - first
+    local = jnp.where((local >= 0) & (local < held), local, held)
+    ranked = jnp.argsort(local, stable=True).astype(jnp.int32)
+    weights, ranked, counts = checkpoint_name(
+        (weights, ranked, _count(local, held)), REMAT_SAVED[0])
+    rows, tile = held_rows(cfg, T), GMM_TILING[0]
+    short = max(rows // 4 // tile * tile, min(tile, rows))
+    we = tuple(_ll._dq(lp[w], dt) for w in ("we_gate", "we_up", "we_down"))
+    return (_held_combine(cfg, rows, short, x, weights, ranked, counts, we),
+            counts, _passes(counts, rows, short))
+
+
 def feed_forward(h, lp, cfg: MoEConfig, mesh=None, rules=None, tp=None):
     """The expert layer: normed h [B, S, D] -> (its output [B, S, D],
     this layer's routing statistics for ``finish_loss``). It takes every
@@ -229,6 +424,11 @@ def feed_forward(h, lp, cfg: MoEConfig, mesh=None, rules=None, tp=None):
                      preferred_element_type=jnp.float32)            # [T, E]
     weights, experts, probs = route(logits, cfg)
     flat = experts.reshape(T * K)
+    if cfg.experts_held is not None:
+        y, held, more = _held_experts(x, weights, experts, lp, cfg)
+        stats = {"counts": _count(flat, E), "held_counts": held,
+                 "more_passes": more}
+        return _finish(y, stats, x, lp, cfg, logits, experts, probs, (B, S, D))
     order = jnp.argsort(flat, stable=True).astype(jnp.int32)   # row -> slot
     back = jnp.zeros_like(order).at[order].set(
         jnp.arange(T * K, dtype=jnp.int32))                    # slot -> row
@@ -241,11 +441,22 @@ def feed_forward(h, lp, cfg: MoEConfig, mesh=None, rules=None, tp=None):
                                   impl=cfg.gmm_impl)
     y = _down_combine(cfg.gmm_impl, jax.nn.silu(mm("we_gate")) * mm("we_up"),
                       _ll._dq(lp["we_down"], dt), weights, order, back, sizes)
-    stats = {"counts": sizes,
-             "prob_sum": probs.sum(axis=0),
+    return _finish(y, {"counts": sizes}, x, lp, cfg, logits, experts, probs,
+                   (B, S, D))
+
+
+def _finish(y, stats, x, lp, cfg: MoEConfig, logits, experts, probs, shape):
+    """The routed experts' y [T, D] plus the shared SwiGLU of x, where the
+    config has one, in the layer's shape, and the layer's statistics."""
+    stats = {**stats, "prob_sum": probs.sum(axis=0),
              "z_sum": jnp.square(jax.nn.logsumexp(logits, axis=-1)).sum(),
              "experts": experts}
-    return y.reshape(B, S, D), stats
+    if cfg.shared_d_ff:
+        dt = cfg.dtype
+        gate = jax.nn.silu(x @ _ll._dq(lp["ws_gate"], dt))
+        y = y + (gate * (x @ _ll._dq(lp["ws_up"], dt))) @ _ll._dq(
+            lp["ws_down"], dt)
+    return y.reshape(shape), stats
 
 
 def finish_loss(loss, stats, cfg: MoEConfig):
@@ -261,6 +472,20 @@ def finish_loss(loss, stats, cfg: MoEConfig):
     aux = E * jnp.sum(share * stats["prob_sum"].sum(axis=0) / rows)
     z = stats["z_sum"].sum() / rows
     per_layer = rows // counts.shape[0] * K                    # T x K
+    if cfg.experts_held is not None:
+        held = stats["held_counts"].astype(jnp.float32)        # [L, held]
+        return loss + cfg.router_aux_weight * aux + cfg.router_z_weight * z, {
+            "moe_aux_loss": aux, "moe_z_loss": z,
+            # the largest held expert over the held experts' mean, worst layer
+            "moe_load_max_over_mean": jnp.max(
+                held.max(axis=1) * held.shape[1]
+                / jnp.maximum(held.sum(axis=1), 1.0)),
+            "moe_held_rows_share": held.sum() / (counts.shape[0] * per_layer),
+            # passes beyond the first that the held experts' rows took
+            "moe_held_more_passes":
+                stats["more_passes"].sum().astype(jnp.float32),
+            # every assignment to a held expert is computed (_held_experts)
+            "moe_dropped": jnp.zeros((), jnp.int32)}
     return (loss + cfg.router_aux_weight * aux + cfg.router_z_weight * z, {
         "moe_aux_loss": aux, "moe_z_loss": z,
         "moe_load_max_over_mean":

@@ -13,6 +13,7 @@ _FAMILIES = {
     "llama": "ray_tpu.models.llama",
     "gpt2": "ray_tpu.models.gpt2",
     "moe": "ray_tpu.models.moe",
+    "hybrid": "ray_tpu.models.hybrid",
     "vit": "ray_tpu.models.vit",
 }
 

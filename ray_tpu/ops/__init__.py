@@ -6,6 +6,8 @@
   KV blocks rotate around the ICI ring via ppermute while each chip keeps
   its queries resident (SURVEY.md §5.7: absent in the reference; first-class
   here).
+- ssd: the chunked state-space scan of a Mamba-2 mixer, forward and
+  backward, its decay block in VMEM and its state carried between chunks.
 
 Kernels run under `interpret=True` automatically on CPU (tests); compiled
 Mosaic on TPU.
@@ -13,5 +15,6 @@ Mosaic on TPU.
 
 from ray_tpu.ops.flash_attention import flash_attention
 from ray_tpu.ops.ring_attention import ring_attention
+from ray_tpu.ops.ssd import ssd_scan
 
-__all__ = ["flash_attention", "ring_attention"]
+__all__ = ["flash_attention", "ring_attention", "ssd_scan"]

@@ -1,0 +1,421 @@
+"""Chunked state-space scan (SSD, the Mamba-2 mixer's recurrence) for TPU
+in Pallas, with its backward.
+
+For every head h with a [P, N] state s (P the head's width, N the state
+size), step by step over a sequence:
+
+    s_t = exp(dt_t A) s_{t-1} + dt_t x_t B_t^T        y_t = s_t C_t
+
+(x_t [P], B_t and C_t [N], shared by all heads: one group; dt_t > 0 and
+A < 0 a head; the skip ``D x_t`` is the caller's, an elementwise term).
+Written in chunks of Q steps (arXiv:2405.21060, section 6), with
+``cum_t`` the running sum of ``dt A`` inside a chunk and ``u = dt x``:
+
+    y_t   = sum_{s <= t} (C_t . B_s) exp(cum_t - cum_s) u_s     (in the chunk)
+            + exp(cum_t) h_in C_t                        (what came before it)
+    h_out = exp(cum_last) h_in + sum_s exp(cum_last - cum_s) u_s B_s^T
+
+Two paths, chosen by the caller the way ``attn_impl`` chooses "xla" or
+"flash":
+
+- ``"xla"``: the same chunks as plain einsums, the [Q, Q] decay block of
+  every head and chunk an array in memory (H x Q floats a token: 2.1 GB a
+  layer at 16,384 tokens, H 128, Q 256). The CPU tests, a mesh, small
+  sizes. Gradients are jax's own.
+- ``"pallas"``: one Mosaic call forward and one backward, grid (batch,
+  head block, chunk). The decay block lives in VMEM as flash's scores do;
+  the chunks of a sequence are walked in order (the backward last to
+  first) and the state between them ([heads, P, N] float32) is carried in
+  VMEM scratch, so the only state that reaches HBM is what the backward
+  needs: each chunk's incoming state ``h_in`` ([B, chunks, H x P, N]
+  float32, 268 MB a layer at B2 x S8192), written by the forward rule and
+  read once. A Mosaic call cannot be partitioned by GSPMD; the caller
+  refuses a mesh of several devices. Interpret mode off the TPU.
+
+The kernel's layout. x arrives as it leaves the mixer's convolution,
+[B, S, H x P], and is never transposed: a lane tile of 128 holds TWO
+heads of 64, so the kernel walks a block's heads in pairs. Each head of
+a pair has its own [Q, Q] block ``M = (C B^T) . L``; ``M_a @ u`` and
+``M_b @ u`` are both taken over the pair's 128 lanes (the MXU is 128
+wide either way) and the halves chosen by lane. Everything whose
+contraction runs over the steps or over the state (the chunk's end state,
+the term of ``h_in``, and their gradients) is one matmul a pair with no
+waste. ``cum`` is needed along both axes of the block: it arrives twice,
+[B, S, H] (steps on sublanes: a head's column is a masked lane reduction)
+and [B, H, S] (steps on lanes: a head's row is a sublane slice), and its
+gradient leaves in both layouts, summed by the caller. Matmul operands
+are bfloat16 (x, B, C arrive so), products accumulate in float32, the
+decay and every sum over it are float32.
+
+Across a layer checkpoint nothing of the scan is kept, so nothing of it
+is named for ``llama._checkpoint``: its output is as large as two layer inputs and the states as
+four, so the backward's recomputation of the layer runs the forward call
+again (the numbers: PERF.md section 6, PR 32).
+
+S must be a multiple of the chunk (pad upstream).
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ray_tpu.util import tracing
+
+NEG_INF = -1e30
+HEADS_PER_BLOCK = 16       # heads one kernel instance walks, in pairs
+
+
+def _use_interpret() -> bool:
+    return jax.default_backend() != "tpu"
+
+
+# --- the plain path ---------------------------------------------------------
+
+
+def _chunks(a, q):
+    """[B, S, ...] -> [B, S / q, q, ...]."""
+    return a.reshape(a.shape[0], a.shape[1] // q, q, *a.shape[2:])
+
+
+def _scan_xla(u, bm, cm, cum):
+    """u [B, C, Q, H, P], bm and cm [B, C, Q, N], cum [B, C, Q, H] (all
+    float32) -> y [B, C, Q, H, P]."""
+    q = u.shape[2]
+    g = jnp.einsum("bctn,bcsn->bcts", cm, bm)
+    seg = cum[:, :, :, None, :] - cum[:, :, None, :, :]      # [B, C, t, s, H]
+    seen = jnp.tril(jnp.ones((q, q), bool))[None, None, :, :, None]
+    decay = jnp.exp(jnp.where(seen, seg, NEG_INF))
+    y = jnp.einsum("bcts,bctsh,bcshp->bcthp", g, decay, u)
+    to_end = jnp.exp(cum[:, :, -1:, :] - cum)                # [B, C, Q, H]
+    local = jnp.einsum("bcsh,bcshp,bcsn->bchpn", to_end, u, bm)
+    whole = jnp.exp(cum[:, :, -1, :])                        # [B, C, H]
+
+    def step(h, inp):
+        s, d = inp
+        return h * d[..., None, None] + s, h
+
+    _, h_in = jax.lax.scan(step, jnp.zeros_like(local[:, 0]), (
+        jnp.moveaxis(local, 1, 0), jnp.moveaxis(whole, 1, 0)))
+    h_in = jnp.moveaxis(h_in, 0, 1)                          # [B, C, H, P, N]
+    return y + jnp.einsum("bctn,bchpn,bcth->bcthp", cm, h_in, jnp.exp(cum))
+
+
+# --- the kernels ------------------------------------------------------------
+
+
+def _lane_is_first(width: int, half: int):
+    """[1, width] bool: the lanes of a pair's first head."""
+    return jax.lax.broadcasted_iota(jnp.int32, (1, width), 1) < half
+
+
+def _column(tile, head):
+    """Column ``head`` of tile [Q, H] as [Q, 1] (a masked lane reduction:
+    ``head`` may be a traced scalar)."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, tile.shape[1]), 1)
+    return jnp.sum(jnp.where(lane == head, tile, 0.0), axis=1, keepdims=True)
+
+
+def _last(row):
+    """The chunk's last entry of row [1, Q] as [1, 1] (a masked lane
+    reduction: Mosaic does not broadcast a slice taken at lane Q - 1)."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, row.shape, 1)
+    return jnp.sum(jnp.where(lane == row.shape[1] - 1, row, 0.0), axis=1,
+                   keepdims=True)
+
+
+def _decay_block(col, row, seen):
+    """L[t, s] = exp(cum_t - cum_s) for s <= t, else 0: [Q, Q] float32."""
+    return jnp.exp(jnp.where(seen, col - row, NEG_INF))
+
+
+_NT = (((1,), (1,)), ((), ()))     # a @ b^T
+_TN = (((0,), (0,)), ((), ()))     # a^T @ b
+
+
+def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
+    return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _fwd_kernel(u_ref, b_ref, c_ref, col_ref, row_ref, y_ref, hin_ref, h_scr,
+                *, heads: int, width: int):
+    """One instance per (batch, head block, chunk), chunks in order.
+    u_ref [1, Q, heads x P]; b_ref, c_ref [1, Q, N]; col_ref [1, Q, H];
+    row_ref [1, heads, Q]; y_ref as u_ref; hin_ref [1, 1, heads x P, N]
+    float32; h_scr [heads x P, N] float32, the state entering the chunk."""
+    q = u_ref.shape[1]
+    two = 2 * width
+    mm = u_ref.dtype
+
+    @pl.when(pl.program_id(2) == 0)
+    def _start():
+        h_scr[...] = jnp.zeros_like(h_scr)
+
+    bm, cm = b_ref[0], c_ref[0]
+    g = _dot(cm, bm, _NT)                                    # [Q, Q]
+    seen = jax.lax.broadcasted_iota(jnp.int32, (q, 1), 0) \
+        >= jax.lax.broadcasted_iota(jnp.int32, (1, q), 1)
+    first = _lane_is_first(two, width)
+    first_rows = jax.lax.broadcasted_iota(jnp.int32, (two, 1), 0) < width
+    cols = col_ref[0]
+    head0 = pl.program_id(1) * heads
+    hin_ref[0, 0] = h_scr[...]
+    for pair in range(heads // 2):
+        lanes = slice(pair * two, (pair + 1) * two)
+        u2 = u_ref[0, :, lanes]                              # [Q, 2P]
+        h2 = h_scr[lanes, :]                                 # [2P, N]
+        col = [_column(cols, head0 + 2 * pair + i) for i in (0, 1)]
+        row = [row_ref[0, 2 * pair + i:2 * pair + i + 1, :] for i in (0, 1)]
+        ys = [_dot((g * _decay_block(col[i], row[i], seen)).astype(mm), u2)
+              for i in (0, 1)]
+        y = jnp.where(first, ys[0], ys[1])
+        y = y + _dot(cm, h2.astype(mm), _NT) * jnp.where(
+            first, jnp.exp(col[0]), jnp.exp(col[1]))
+        y_ref[0, :, lanes] = y.astype(y_ref.dtype)
+        last = [_last(r) for r in row]                        # [1, 1]
+        to_end = jnp.where(first, jnp.exp(last[0] - col[0]),
+                           jnp.exp(last[1] - col[1]))        # [Q, 2P]
+        local = _dot((u2.astype(jnp.float32) * to_end).astype(mm), bm, _TN)
+        h_scr[lanes, :] = h2 * jnp.where(
+            first_rows, jnp.exp(last[0]), jnp.exp(last[1])) + local
+
+
+def _bwd_kernel(u_ref, b_ref, c_ref, col_ref, row_ref, hin_ref, dy_ref,
+                du_ref, db_ref, dc_ref, dcol_ref, drow_ref, dh_scr,
+                *, heads: int, width: int):
+    """The mirror image, chunks last to first; dh_scr [heads x P, N] is
+    the gradient of the state LEAVING the chunk. db_ref, dc_ref
+    [1, 1, Q, N] float32 are this head block's part (the caller sums the
+    blocks); dcol_ref [1, 1, Q, H] float32 holds this block's heads in
+    their own lanes and zeros elsewhere; drow_ref [1, heads, Q]."""
+    q = u_ref.shape[1]
+    two = 2 * width
+    mm = u_ref.dtype
+    f32 = jnp.float32
+
+    @pl.when(pl.program_id(2) == 0)
+    def _start():
+        dh_scr[...] = jnp.zeros_like(dh_scr)
+
+    bm, cm = b_ref[0], c_ref[0]
+    g = _dot(cm, bm, _NT)
+    seen = jax.lax.broadcasted_iota(jnp.int32, (q, 1), 0) \
+        >= jax.lax.broadcasted_iota(jnp.int32, (1, q), 1)
+    is_last = jax.lax.broadcasted_iota(jnp.int32, (q, 1), 0) == q - 1
+    first = _lane_is_first(two, width)
+    first_rows = jax.lax.broadcasted_iota(jnp.int32, (two, 1), 0) < width
+    cols = col_ref[0]
+    all_lanes = jax.lax.broadcasted_iota(jnp.int32, (1, cols.shape[1]), 1)
+    head0 = pl.program_id(1) * heads
+    dg = jnp.zeros((q, q), f32)
+    db = jnp.zeros(bm.shape, f32)
+    dc = jnp.zeros(cm.shape, f32)
+    dcols = jnp.zeros(cols.shape, f32)
+
+    def half(i, a):                  # lanes of head i of the pair, else 0
+        return jnp.where(first if i == 0 else ~first, a, 0.0)
+
+    for pair in range(heads // 2):
+        lanes = slice(pair * two, (pair + 1) * two)
+        u2 = u_ref[0, :, lanes]
+        dy2 = dy_ref[0, :, lanes]
+        h2 = hin_ref[0, 0, lanes, :]                         # [2P, N] f32
+        dh2 = dh_scr[lanes, :]
+        col = [_column(cols, head0 + 2 * pair + i) for i in (0, 1)]
+        row = [row_ref[0, 2 * pair + i:2 * pair + i + 1, :] for i in (0, 1)]
+        last = [_last(r) for r in row]
+        u2f, dy2f = u2.astype(f32), dy2.astype(f32)
+        dus, dcol = [], []
+        for i in (0, 1):
+            decay = _decay_block(col[i], row[i], seen)
+            m = g * decay
+            dm = _dot(half(i, dy2f).astype(mm), u2, _NT)     # [Q(t), Q(s)]
+            w = dm * m
+            dg = dg + dm * decay
+            dcol.append(jnp.sum(w, axis=1, keepdims=True))
+            drow_ref[0, 2 * pair + i:2 * pair + i + 1, :] = \
+                -jnp.sum(w, axis=0, keepdims=True)
+            dus.append(_dot(m.astype(mm), dy2, _TN))         # M^T dy
+        # what came before the chunk: y_t has exp(cum_t) h_in C_t
+        grow = jnp.where(first, jnp.exp(col[0]), jnp.exp(col[1]))
+        dye = dy2f * grow                                    # [Q, 2P]
+        came = _dot(cm, h2.astype(mm), _NT)                  # [Q, 2P]
+        dc = dc + _dot(dye.astype(mm), h2.astype(mm))
+        dh_in = _dot(dye.astype(mm), cm, _TN)                # [2P, N]
+        # the chunk's end state: h_out has exp(last - cum_s) u_s B_s^T
+        to_end = jnp.where(first, jnp.exp(last[0] - col[0]),
+                           jnp.exp(last[1] - col[1]))
+        sent = _dot(bm, dh2.astype(mm), _NT) * to_end        # [Q, 2P]
+        db = db + _dot((u2f * to_end).astype(mm), dh2.astype(mm))
+        du_ref[0, :, lanes] = (jnp.where(first, dus[0], dus[1])
+                               + sent).astype(du_ref.dtype)
+        whole = jnp.where(first_rows, jnp.exp(last[0]), jnp.exp(last[1]))
+        kept = dh2 * h2 * whole                              # [2P, N]
+        for i in (0, 1):
+            through = jnp.sum(half(i, u2f * sent), axis=1, keepdims=True)
+            d_last = jnp.sum(through) + jnp.sum(jnp.where(
+                first_rows if i == 0 else ~first_rows, kept, 0.0))
+            d = dcol[i] + jnp.sum(half(i, dye * came), axis=1,
+                                  keepdims=True) - through
+            d = d + jnp.where(is_last, d_last, 0.0)
+            dcols = jnp.where(all_lanes == head0 + 2 * pair + i, d, dcols)
+        dh_scr[lanes, :] = dh_in + dh2 * whole
+    dc_ref[0, 0] = dc + _dot(dg.astype(mm), bm)
+    db_ref[0, 0] = db + _dot(dg.astype(mm), cm, _TN)
+    dcol_ref[0, 0] = dcols
+
+
+# --- the block plan and the calls -------------------------------------------
+
+
+def plan(*, S: int, H: int, P: int, N: int, chunk: int, dtype,
+         impl: str) -> dict:
+    """The scan's block plan (also the attributes of ``ssd.plan``): how
+    many heads an instance walks, the VMEM one instance of the backward
+    call holds (its blocks twice, Mosaic double-buffers, the state scratch
+    and the [Q, Q] float32 temporaries of a head) and the HBM bytes the
+    two calls move for one head and sequence."""
+    heads = min(HEADS_PER_BLOCK, H)
+    while H % heads:
+        heads -= 1
+    item = jnp.dtype(dtype).itemsize
+    q = chunk
+    blocks = (3 * q * heads * P * item                  # u, dy, du
+              + 2 * q * N * item + 2 * q * N * 4        # B, C; dB, dC
+              + 2 * q * H * 4 + 2 * heads * q * 4       # cum and its gradient
+              + heads * P * N * 4)                      # h_in
+    vmem = 2 * blocks + heads * P * N * 4 + 8 * q * q * 4
+    # a head's rows of u and y, forward; u, dy and du, backward; h_in
+    # written and read
+    hbm = S * P * item * 5 + 2 * (S // q) * P * N * 4
+    return {"S": S, "chunk": q, "heads_per_block": heads, "path": impl,
+            "vmem_bytes": vmem if impl == "pallas" else 0,
+            "hbm_bytes_per_head": hbm if impl == "pallas" else 0}
+
+
+def _specs(B, S, H, P, N, q, heads):
+    wide = pl.BlockSpec((1, q, heads * P), lambda b, h, c: (b, c, h))
+    shared = pl.BlockSpec((1, q, N), lambda b, h, c: (b, c, 0))
+    col = pl.BlockSpec((1, q, H), lambda b, h, c: (b, c, 0))
+    row = pl.BlockSpec((1, heads, q), lambda b, h, c: (b, h, c))
+    state = pl.BlockSpec((1, 1, heads * P, N), lambda b, h, c: (b, c, h, 0))
+    return wide, shared, col, row, state
+
+
+def _backwards(spec, last):
+    """The same block, its chunk index walked last to first."""
+    index = spec.index_map
+
+    def flipped(b, h, c):
+        return index(b, h, last - c)
+
+    return pl.BlockSpec(spec.block_shape, flipped)
+
+
+def _forward_call(u, bm, cm, col, row, *, chunk: int, heads: int, width: int):
+    B, S, HP = u.shape
+    H, N = col.shape[2], bm.shape[2]
+    wide, shared, colspec, rowspec, state = _specs(B, S, H, width, N, chunk,
+                                                   heads)
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, heads=heads, width=width),
+        grid=(B, H // heads, S // chunk),
+        in_specs=[wide, shared, shared, colspec, rowspec],
+        out_specs=[wide, state],
+        out_shape=[jax.ShapeDtypeStruct(u.shape, u.dtype),
+                   jax.ShapeDtypeStruct((B, S // chunk, HP, N), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((heads * width, N), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=_use_interpret(),
+    )(u, bm, cm, col, row)
+
+
+def _backward_call(u, bm, cm, col, row, h_in, dy, *, chunk: int, heads: int,
+                   width: int):
+    B, S, HP = u.shape
+    H, N = col.shape[2], bm.shape[2]
+    blocks, n_chunks = H // heads, S // chunk
+    rev = functools.partial(_backwards, last=n_chunks - 1)
+    wide, shared, colspec, rowspec, state = map(
+        rev, _specs(B, S, H, width, N, chunk, heads))
+    part = rev(pl.BlockSpec((1, 1, chunk, N), lambda b, h, c: (b, h, c, 0)))
+    dcol = rev(pl.BlockSpec((1, 1, chunk, H), lambda b, h, c: (b, h, c, 0)))
+    f32 = jnp.float32
+    du, db, dc, dcols, drow = pl.pallas_call(
+        functools.partial(_bwd_kernel, heads=heads, width=width),
+        grid=(B, blocks, n_chunks),
+        in_specs=[wide, shared, shared, colspec, rowspec, state, wide],
+        out_specs=[wide, part, part, dcol, rowspec],
+        out_shape=[jax.ShapeDtypeStruct(u.shape, u.dtype),
+                   jax.ShapeDtypeStruct((B, blocks, S, N), f32),
+                   jax.ShapeDtypeStruct((B, blocks, S, N), f32),
+                   jax.ShapeDtypeStruct((B, blocks, S, H), f32),
+                   jax.ShapeDtypeStruct(row.shape, f32)],
+        scratch_shapes=[pltpu.VMEM((heads * width, N), f32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=_use_interpret(),
+    )(u, bm, cm, col, row, h_in, dy)
+    # a block's heads stand in their own lanes of dcols, zeros elsewhere
+    return (du, db.sum(axis=1).astype(bm.dtype),
+            dc.sum(axis=1).astype(cm.dtype), dcols.sum(axis=1), drow)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _scan_pallas(u, bm, cm, col, row, chunk, heads, width):
+    return _forward_call(u, bm, cm, col, row, chunk=chunk, heads=heads,
+                         width=width)[0]
+
+
+def _scan_pallas_fwd(u, bm, cm, col, row, chunk, heads, width):
+    y, h_in = _forward_call(u, bm, cm, col, row, chunk=chunk, heads=heads,
+                            width=width)
+    return y, (u, bm, cm, col, row, h_in)
+
+
+def _scan_pallas_bwd(chunk, heads, width, res, dy):
+    return _backward_call(*res, dy.astype(res[0].dtype), chunk=chunk,
+                          heads=heads, width=width)
+
+
+_scan_pallas.defvjp(_scan_pallas_fwd, _scan_pallas_bwd)
+
+
+def ssd_scan(x, dt, a, bm, cm, *, chunk: int = 256, impl: str = "xla"):
+    """x [B, S, H, P], dt [B, S, H] (positive: after its softplus), a [H]
+    (negative), bm and cm [B, S, N] -> y [B, S, H, P] in x's type, the
+    recurrence of the module docstring from a zero state, without the
+    skip term. Differentiable in all five on both paths."""
+    B, S, H, P = x.shape
+    if S % chunk:
+        raise ValueError(f"ssd_scan: {S} steps are no multiple of the chunk "
+                         f"{chunk}; pad upstream")
+    if impl not in ("xla", "pallas"):
+        raise ValueError(f"ssd_scan impl must be 'xla' or 'pallas', got "
+                         f"{impl!r}")
+    p = plan(S=S, H=H, P=P, N=bm.shape[-1], chunk=chunk, dtype=x.dtype,
+             impl=impl)
+    tracing.instant("ssd.plan", p)
+    f32 = jnp.float32
+    step = dt.astype(f32) * a.astype(f32)                    # [B, S, H] < 0
+    cum = jnp.cumsum(_chunks(step, chunk), axis=2)           # [B, C, Q, H]
+    u = (x.astype(f32) * dt.astype(f32)[..., None]).astype(x.dtype)
+    if impl == "xla":
+        y = _scan_xla(_chunks(u, chunk).astype(f32),
+                      _chunks(bm, chunk).astype(f32),
+                      _chunks(cm, chunk).astype(f32), cum)
+        return y.reshape(B, S, H, P).astype(x.dtype)
+    heads = p["heads_per_block"]
+    if heads % 2:
+        raise ValueError(f"ssd_scan: the kernel walks heads in pairs, {H} "
+                         "heads give a block of an odd number")
+    col = cum.reshape(B, S, H)
+    y = _scan_pallas(u.reshape(B, S, H * P), bm.astype(x.dtype),
+                     cm.astype(x.dtype), col, col.transpose(0, 2, 1), chunk,
+                     heads, P)
+    return y.reshape(B, S, H, P)

@@ -20,7 +20,9 @@ ROOT = os.path.join(os.path.dirname(os.path.abspath(__file__)), os.pardir)
 # the recipe keys each kind hands to its config builder
 # (benchmark/kinds/train.py, benchmark/kinds/train_moe.py: run())
 RECIPE_KEYS = {"train": ("attn_impl", "remat", "f32_logits"),
-               "train_moe": ("attn_impl", "gmm_impl", "remat", "f32_logits")}
+               "train_moe": ("attn_impl", "gmm_impl", "remat", "f32_logits"),
+               "train_hybrid": ("attn_impl", "gmm_impl", "ssd_impl", "remat",
+                                "f32_logits")}
 # published config.json key -> the program's field
 WIDTHS = {"hidden_size": "d_model", "num_hidden_layers": "n_layers",
           "num_attention_heads": "n_heads",
@@ -45,18 +47,27 @@ def test_cell_program_config_builds_at_its_published_widths(name):
     import jax
     import jax.numpy as jnp
 
-    from benchmark import model, model_moe, resolve
+    from benchmark import model, model_granite, model_moe, resolve
 
     cell = resolve.cell(name)
     kind, conf, recipe = cell["kind"], cell["config"], cell["train"]
     build = {"train": model.llama_config,
-             "train_moe": model_moe.moe_config}[kind]
+             "train_moe": model_moe.moe_config,
+             "train_hybrid": model_granite.hybrid_config}[kind]
     passed = {k: recipe[k] for k in RECIPE_KEYS[kind] if k in recipe}
     cfg = build(conf, **passed)
 
-    for key, field in {**WIDTHS,
-                       **(MOE_WIDTHS if kind == "train_moe" else {})}.items():
+    widths = {"train": WIDTHS, "train_moe": {**WIDTHS, **MOE_WIDTHS},
+              "train_hybrid": model_granite.HF_TO_FIELD}[kind]
+    for key, field in widths.items():
         assert getattr(cfg, field) == conf[key], (name, key)
+    if kind == "train_hybrid":
+        # the router's width and the experts held are the deployment's
+        dep = conf["deployment"]
+        assert cfg.n_experts == dep["router_experts"]
+        assert cfg.experts_held == (conf["num_local_experts"],
+                                    dep["experts_first"])
+        assert cfg.kinds == tuple(conf["layer_types"][:cfg.n_layers])
     for key, value in passed.items():
         assert getattr(cfg, key) == value, (name, key)
     assert cfg.dtype == getattr(jnp, conf["run"]["dtype"])

@@ -1,0 +1,379 @@
+"""The state-space / attention hybrid (models/hybrid.py) against its plain
+reference (models/reference_granite.py) on seeded weights, a chip's share
+of the experts against the uncut layer, and what the shared code
+(llama.py, moe.py, flash) was given for it."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.models import hybrid, llama, moe, reference_granite
+from ray_tpu.models import registry
+
+
+def ref_cfg(cfg) -> dict:
+    d = dataclasses.asdict(cfg)
+    d["layer_types"] = cfg.kinds
+    return d
+
+
+def tiny(**kw):
+    return hybrid.PRESETS["tiny"].replace(dtype=jnp.float32, mamba_chunk=32,
+                                          **kw)
+
+
+def make(cfg, seed=0, batch=2, seq=96):
+    params = hybrid.init_params(jax.random.PRNGKey(seed), cfg)
+    # zero biases and unit norms hide a wrong index: draw them
+    key = iter(jax.random.split(jax.random.PRNGKey(seed + 1), 64))
+    for lay in params["layers"]:
+        for name in ("conv_b", "d_skip", "gate_norm", "mix_norm", "attn_norm",
+                     "ffn_norm"):
+            if name in lay:
+                lay[name] = lay[name] + 0.3 * jax.random.normal(
+                    next(key), lay[name].shape)
+    tokens = jax.random.randint(jax.random.PRNGKey(seed + 2),
+                                (batch, seq + 1), 0, cfg.vocab_size, "int32")
+    return params, tokens
+
+
+def test_layer_runs_and_parameter_tree():
+    cfg = tiny()
+    assert hybrid.layer_runs(cfg) == [("mamba", 2), ("attention", 1),
+                                      ("mamba", 1)]
+    full = cfg.replace(n_layers=40, layer_types=(
+        ("mamba",) * 5 + ("attention",) + ("mamba",) * 4) * 4)
+    assert [n for _, n in hybrid.layer_runs(full)] \
+        == [5, 1, 9, 1, 9, 1, 9, 1, 4]
+    params, _ = make(cfg)
+    assert [sorted(lay)[:2] for lay in params["layers"]] == [
+        ["a_log", "conv_b"], ["attn_norm", "ffn_norm"], ["a_log", "conv_b"]]
+    assert hybrid.num_params(cfg) == sum(
+        x.size for x in jax.tree.leaves(params))
+    spec = hybrid.param_specs(cfg)
+    is_axes = lambda x: isinstance(x, tuple)     # noqa: E731
+    assert jax.tree.structure(spec, is_leaf=is_axes) \
+        == jax.tree.structure(params)
+    for axes, w in zip(jax.tree.leaves(spec, is_leaf=is_axes),
+                       jax.tree.leaves(params)):
+        assert len(axes) == w.ndim, (axes, w.shape)
+    with pytest.raises(ValueError, match="layer types"):
+        hybrid.layer_runs(cfg.replace(n_layers=5))
+    assert registry.get("hybrid", "tiny")[1] is hybrid
+
+
+def test_the_cells_count_of_parameters():
+    """ISSUE 32's arithmetic: one period, 9 of 72 experts, an eighth of the
+    vocabulary: 2.055 B parameters."""
+    cfg = hybrid.HybridConfig(
+        vocab_size=12544, d_model=4096, n_layers=10, n_heads=32, n_kv_heads=8,
+        d_ff=768, n_experts=72, top_k=10, experts_held=(9, 0),
+        shared_d_ff=1536, mamba_heads=128, mamba_head_dim=64,
+        mamba_state=128, mamba_conv=4, mamba_chunk=256,
+        layer_types=("mamba",) * 5 + ("attention",) + ("mamba",) * 4)
+    assert cfg.head_dim == 128
+    mamba = 4096 * 16768 + 8192 * 4096 + 5 * 8448 + 3 * 128 + 8192
+    attention = 2 * 4096 * 4096 + 2 * 4096 * 1024
+    rest = 2 * 4096 + 3 * 4096 * 1536 + 4096 * 72 + 9 * 3 * 4096 * 768
+    assert hybrid.num_params(cfg) == 9 * mamba + attention + 10 * rest \
+        + 12544 * 4096 + 4096
+    assert round(hybrid.num_params(cfg) / 1e9, 3) == 2.055
+
+
+@pytest.mark.parametrize("held", [None, (4, 2), (2, 4)],
+                         ids=["all", "share", "over_one_pass"])
+def test_model_against_the_plain_reference(held):
+    over = held == (2, 4)
+    cfg = tiny(experts_held=held, remat=True, n_experts=16 if over else 8)
+    params, tokens = make(cfg, seq=256 if over else 96)
+    if over:    # routers that send half the tokens to both held experts
+        for lay in params["layers"]:
+            lay["router"] = lay["router"].at[:, :, 4:6].add(
+                jax.random.normal(jax.random.PRNGKey(7),
+                                  lay["router"].shape[:2])[..., None])
+    logits, stats = hybrid.forward_with_stats(params, tokens[:, :-1], cfg)
+    want = [reference_granite.forward(params, t[:-1], ref_cfg(cfg))
+            for t in tokens]
+    np.testing.assert_allclose(logits, jnp.stack([w[0] for w in want]),
+                               rtol=2e-4, atol=2e-4)
+    own = jnp.stack([w[1]["experts"] for w in want], axis=1)   # [L, B, S, K]
+    got = stats["experts"].reshape(own.shape)
+    assert bool(jnp.all(jnp.sort(got, -1) == jnp.sort(own, -1)))
+    (loss, aux), grads = jax.value_and_grad(
+        lambda p: hybrid.loss_fn(p, {"tokens": tokens}, cfg),
+        has_aux=True)(params)
+    (ref_loss, terms), ref_grads = jax.value_and_grad(
+        lambda p: reference_granite.loss(p, tokens, ref_cfg(cfg)),
+        has_aux=True)(params)
+    assert abs(float(loss) - float(ref_loss)) < 2e-5
+    assert abs(float(aux["moe_aux_loss"]) - float(terms["aux"])) < 1e-5
+    assert abs(float(aux["moe_z_loss"]) - float(terms["z"])) < 1e-4
+    assert float(aux["moe_dropped"]) == 0
+    if held is not None:
+        assert (float(aux["moe_held_more_passes"]) > 0) == over
+        rec = reference_granite.token_losses(params, tokens, ref_cfg(cfg))[1]
+        t_k = tokens[:, :-1].size * cfg.top_k
+        assert float(aux["moe_held_rows_share"]) == pytest.approx(
+            float(rec["held_rows"].sum()) / (cfg.n_layers * t_k))
+    flat = lambda t: jax.tree.leaves_with_path(t)   # noqa: E731
+    for (path, g), (_, w) in zip(flat(grads), flat(ref_grads)):
+        scale = float(jnp.max(jnp.abs(w))) + 1e-9
+        np.testing.assert_allclose(g, w, rtol=2e-3, atol=2e-3 * scale,
+                                   err_msg=jax.tree_util.keystr(path))
+
+
+def test_kernel_paths_give_the_plain_paths_program():
+    """flash, the Mosaic grouped matmul and the scan kernel, in interpret
+    mode, against the "xla" paths of the same model."""
+    cfg = tiny(experts_held=(4, 0))
+    params, tokens = make(cfg, seq=128)
+    batch = {"tokens": tokens}
+    fast = cfg.replace(attn_impl="flash", gmm_impl="pallas",
+                       ssd_impl="pallas")
+    want, w_grads = jax.value_and_grad(
+        lambda p: hybrid.loss_fn(p, batch, cfg)[0])(params)
+    got, g_grads = jax.value_and_grad(
+        lambda p: hybrid.loss_fn(p, batch, fast)[0])(params)
+    assert abs(float(got) - float(want)) < 1e-5
+    for g, w in zip(jax.tree.leaves(g_grads), jax.tree.leaves(w_grads)):
+        np.testing.assert_allclose(g, w, rtol=2e-3, atol=2e-3 * float(
+            jnp.max(jnp.abs(w)) + 1e-9))
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda p: hybrid.loss_fn(p, batch, fast)[0]))(params)
+    assert str(jaxpr).count("pallas_call") >= 3
+
+
+def test_the_shares_add_up_to_the_uncut_layer():
+    """The guide's share test: over 8 chips of 2 experts each, the routed
+    parts that the shares compute, with the shared expert (computed alike
+    on every chip) counted once, are the uncut reference's layer output."""
+    cfg = tiny(n_experts=16, top_k=4)
+    params, _ = make(cfg)
+    lp = jax.tree.map(lambda w: w[0], params["layers"][0])
+    t, d = 192, cfg.d_model
+    h = jax.random.normal(jax.random.PRNGKey(5), (1, t, d))
+    whole, rec = reference_granite._experts(h[0], lp, ref_cfg(cfg), None)
+    x = h[0]
+    shared = (jax.nn.silu(x @ lp["ws_gate"]) * (x @ lp["ws_up"])) \
+        @ lp["ws_down"]
+    total, rows = shared, 0
+    for share in range(8):
+        first = 2 * share
+        part = cfg.replace(experts_held=(2, first))
+        mine = {k: (w[first:first + 2] if k.startswith("we_") else w)
+                for k, w in lp.items()}
+        y, stats = moe.feed_forward(h, mine, part)
+        assert int(stats["more_passes"]) == 0
+        np.testing.assert_array_equal(stats["counts"], rec["counts"])
+        rows += int(stats["held_counts"].sum())
+        total = total + (y[0] - shared)
+        # and the reference, given the same share, gives this chip's part
+        same, _ = reference_granite._experts(x, mine, ref_cfg(part), None)
+        np.testing.assert_allclose(y[0], same, rtol=2e-4, atol=2e-5)
+    assert rows == t * cfg.top_k          # every assignment on one chip
+    np.testing.assert_allclose(total, whole, rtol=2e-4, atol=2e-5)
+
+
+def _layer_and_reference(cfg, lp, h):
+    """The program's expert layer and the reference's on the same input:
+    ((y, gradients of sum(y * probe) by h and the weights) of each, the
+    program's statistics)."""
+    probe = jax.random.normal(jax.random.PRNGKey(9), h.shape)
+
+    def mine(h, lp):
+        y, stats = moe.feed_forward(h, lp, cfg)
+        return jnp.sum(y * probe), (y, stats)
+
+    def plain(h, lp):
+        y, _ = reference_granite._experts(h[0], lp, ref_cfg(cfg), None)
+        return jnp.sum(y * probe[0]), y
+
+    (_, (y, stats)), got = jax.value_and_grad(mine, (0, 1), has_aux=True)(h, lp)
+    (_, want_y), want = jax.value_and_grad(plain, (0, 1), has_aux=True)(h, lp)
+    return (y[0], got), (want_y, want), stats
+
+
+def test_a_layer_over_one_pass_takes_further_passes_and_drops_nothing():
+    """A router that sends every token to the held experts: one pass holds
+    HELD_PASS times the even share, the rest follows in a second, and the
+    layer and its gradients are still the reference's."""
+    cfg = tiny(n_experts=16, top_k=2, experts_held=(2, 4), shared_d_ff=0)
+    params, _ = make(cfg)
+    lp = jax.tree.map(lambda w: w[0], params["layers"][0])
+    t = 256
+    h = jnp.abs(jax.random.normal(jax.random.PRNGKey(5), (1, t, cfg.d_model)))
+    lp["router"] = 0.01 * lp["router"] + jnp.zeros_like(
+        lp["router"]).at[:, 4:6].set(1.0)
+    rows = moe.held_rows(cfg, t)
+    assert rows == 256 and rows < t * cfg.top_k      # 2 x the even 64, a tile
+    (y, got), (want_y, want), stats = _layer_and_reference(cfg, lp, h)
+    assert int(stats["held_counts"].sum()) == t * 2
+    assert int(stats["more_passes"]) == 1
+    np.testing.assert_allclose(y, want_y, rtol=2e-4, atol=2e-5)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(g, w, rtol=2e-3, atol=2e-4 * float(
+            jnp.max(jnp.abs(w)) + 1e-9))
+    loss, aux = moe.finish_loss(0.0, jax.tree.map(lambda s: s[None], stats),
+                                cfg)
+    assert float(aux["moe_dropped"]) == 0
+    assert float(aux["moe_held_more_passes"]) == 1.0
+    assert float(aux["moe_held_rows_share"]) == 1.0
+    # at the cell's sizes: 16,384 tokens, 10 of 72, 9 held
+    cell = cfg.replace(n_experts=72, top_k=10, experts_held=(9, 0))
+    assert moe.held_rows(cell, 16384) == 40960
+
+
+def test_layer_plan_instant_and_the_mesh_refusal(monkeypatch):
+    from ray_tpu.util import tracing
+
+    seen = []
+    monkeypatch.setattr(tracing, "instant",
+                        lambda name, attrs=None, **kw: seen.append(
+                            (name, attrs)))
+    cfg = tiny()
+    params, tokens = make(cfg)
+    jax.make_jaxpr(lambda p: hybrid.forward(p, tokens[:, :-1], cfg))(params)
+    plans = [a for n, a in seen if n == "hybrid.layer_plan"]
+    assert plans == [{"kinds": 2, "runs": 3, "bodies": 2, "layers": 4}]
+    # two runs of mamba layers, ONE trace of their body
+    assert [a["path"] for n, a in seen if n == "ssd.plan"] == ["xla"]
+
+    class Mesh:
+        size, shape = 4, {"dp": 4}
+
+    with pytest.raises(NotImplementedError, match="runs on one device"):
+        hybrid.mixer_half(jnp.zeros((1, 32, cfg.d_model)), {},
+                          cfg.replace(ssd_impl="pallas"), "mamba", mesh=Mesh())
+
+
+def test_flash_with_a_stated_scale_against_xla():
+    """H 4 over KV 2, scale 1/16 where head_dim ** -0.5 is 1/4: values and
+    gradients of the kernel against ``_attention_xla``."""
+    from ray_tpu.ops.flash_attention import flash_attention
+
+    ks = jax.random.split(jax.random.PRNGKey(3), 4)
+    q = jax.random.normal(ks[0], (2, 256, 4, 16))
+    k = jax.random.normal(ks[1], (2, 256, 2, 16))
+    v = jax.random.normal(ks[2], (2, 256, 2, 16))
+    probe = jax.random.normal(ks[3], q.shape)
+    scale = 1.0 / 16
+
+    def through(fn):
+        return jax.value_and_grad(lambda *a: jnp.sum(fn(*a) * probe),
+                                  argnums=(0, 1, 2))(q, k, v)
+
+    want, w_grads = through(lambda q, k, v: llama._attention_xla(
+        q, k, v, True, scale=scale))
+    got, g_grads = through(lambda q, k, v: flash_attention(
+        q, k, v, block_q=64, block_k=64, scale=scale))
+    default, _ = through(lambda q, k, v: flash_attention(
+        q, k, v, block_q=64, block_k=64))
+    assert abs(float(got) - float(want)) < 1e-3 * abs(float(want))
+    assert abs(float(default) - float(want)) > 1e-2 * abs(float(want))
+    for g, w in zip(g_grads, w_grads):
+        np.testing.assert_allclose(g, w, rtol=2e-3, atol=2e-3)
+    # the model's attention layer reaches the kernel with the scale
+    cfg = tiny(attn_impl="flash")
+    out = llama._attention(q, k, v, cfg.replace(attn_scale=scale))
+    np.testing.assert_allclose(out, llama._attention_xla(
+        q, k, v, True, scale=scale), rtol=2e-3, atol=2e-3)
+
+
+def test_plans_read_back_from_a_profile_around_a_lowering(tmp_path):
+    """``ssd.plan`` and ``hybrid.layer_plan`` are events of jax's profiler
+    (util/tracing.py): a profile taken around a lowering holds them with
+    their attributes, tracing on or off."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    cfg = tiny(ssd_impl="pallas")
+    params, tokens = make(cfg)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        jax.jit(lambda p: hybrid.loss_fn(p, {"tokens": tokens}, cfg)[0]
+                ).lower(params)
+    finally:
+        jax.profiler.stop_trace()
+    path = sorted(glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb")))[-1]
+    events = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name in ("ssd.plan", "hybrid.layer_plan"):
+                        events.setdefault(e.name, []).append(dict(e.stats))
+    assert events["hybrid.layer_plan"] == [
+        {"kinds": 2, "runs": 3, "bodies": 2, "layers": 4}]
+    plan = events["ssd.plan"][0]
+    assert plan["path"] == "pallas" and plan["chunk"] == 32
+    assert plan["S"] == 96 and plan["heads_per_block"] == 8
+    assert plan["vmem_bytes"] > 0 and plan["hbm_bytes_per_head"] > 0
+
+
+@pytest.mark.parametrize("lean", [-0.05, 0.04, 0.1, 0.5])
+def test_held_passes_hold_every_assignment_once(lean):
+    """At a size of several row tiles an expert, with a router that leans
+    away from the held experts, a little towards them (one pass nearly
+    full), further (an expert's run straddles the first pass's end) and
+    wholly (the first pass and four a quarter as long): every assignment to a held expert stands
+    in one live slot of one pass, in its expert's group; the passes' groups
+    add up to the counts; the layer and its gradients are the reference's."""
+    held, k, t = 2, 2, 2048
+    cfg = tiny(n_experts=8, top_k=k, experts_held=(held, 3), shared_d_ff=0)
+    rows = moe.held_rows(cfg, t)
+    assert rows == 2048 and rows % 256 == 0          # 2 x the even 1024
+    params, _ = make(cfg)
+    lp = jax.tree.map(lambda w: w[0], params["layers"][0])
+    h = jnp.abs(jax.random.normal(jax.random.PRNGKey(5), (1, t, cfg.d_model)))
+    lp["router"] = lp["router"].at[:, 3:5].add(lean * 0.05)
+    (y, got), (want_y, want), stats = _layer_and_reference(cfg, lp, h)
+    counts = np.asarray(stats["held_counts"])
+    n = counts.sum()
+    assert int(stats["more_passes"]) == -(-max(n - rows, 0) // 512)
+    assert {-0.05: n < rows // 2, 0.04: rows // 2 < n <= rows,
+            0.1: rows < n < 2 * rows, 0.5: n == 2 * rows}[lean], counts
+    np.testing.assert_allclose(y, want_y, rtol=2e-4, atol=2e-5)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(g, w, rtol=2e-3, atol=2e-4 * float(
+            jnp.max(jnp.abs(w)) + 1e-9))
+    # the slots, pass by pass, as _held_pass lays them out
+    experts = np.asarray(stats["experts"]).reshape(t * k)
+    local = np.where((experts >= 3) & (experts < 3 + held), experts - 3, held)
+    ranked = np.argsort(local, kind="stable")
+    start = np.cumsum(counts) - counts
+    seen, total = [], np.zeros(held, np.int64)
+    small = 512                              # a further pass: a quarter
+    passes = [(0, rows)] + [(lo, small) for lo in range(rows, t * k, small)]
+    for lo, size in passes:
+        slot = lo + np.arange(size)
+        live = slot < counts.sum()
+        sizes = np.maximum(np.minimum(start + counts, lo + size)
+                           - np.maximum(start, lo), 0)
+        assert sizes.sum() == live.sum()
+        group = np.searchsorted(np.cumsum(sizes), np.arange(size),
+                                side="right")
+        order = ranked[np.minimum(slot, t * k - 1)]
+        assert (local[order[live]] == group[live]).all()
+        seen.extend(order[live])
+        total += sizes
+    assert (total == counts).all()
+    assert sorted(seen) == sorted(np.flatnonzero(local < held))
+
+
+def test_the_cached_paths_refuse_a_config_that_states_its_own_scales():
+    """prefill and decode embed, rotate, scale and add as llama does: a
+    cache for a config that states otherwise is refused, llama's is not."""
+    with pytest.raises(NotImplementedError, match="residual_multiplier"):
+        llama.init_cache(tiny(), 1)
+    with pytest.raises(NotImplementedError, match="rope"):
+        llama.init_paged_cache(
+            llama.PRESETS["tiny"].replace(rope=False), 4, 16)
+    assert llama.init_cache(llama.PRESETS["tiny"], 1).k.shape[0] == 2

@@ -164,7 +164,7 @@ def test_flash_residuals_compact_lse_feeds_both_backwards(monkeypatch):
     grads = {}
     for impl in ("pallas", "chunked"):
         monkeypatch.setattr(fa, "BACKWARD_IMPL", impl)
-        grads[impl] = fa._flash_vjp_bwd(True, 32, 32, 0, res, g)
+        grads[impl] = fa._flash_vjp_bwd(True, 32, 32, 0, None, res, g)
     for a, b in zip(grads["pallas"], grads["chunked"]):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=3e-3, atol=3e-3)

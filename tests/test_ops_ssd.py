@@ -1,0 +1,137 @@
+"""The chunked state-space scan (ops/ssd.py) against the recurrence
+written token by token in float32: values and gradients, the kernel in
+interpret mode and the einsum path."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.ops import ssd
+
+
+def recurrence(x, dt, a, bm, cm):
+    """s_t = exp(dt_t a) s_{t-1} + dt_t x_t B_t^T, y_t = s_t C_t, one
+    step at a time. x [B, S, H, P], dt [B, S, H], a [H], bm, cm [B, S, N]."""
+    def step(s, inp):
+        xt, dtt, bt, ct = inp
+        s = s * jnp.exp(dtt * a)[..., None, None] \
+            + jnp.einsum("bh,bhp,bn->bhpn", dtt, xt, bt)
+        return s, jnp.einsum("bhpn,bn->bhp", s, ct)
+
+    B, _, H, P = x.shape
+    s0 = jnp.zeros((B, H, P, bm.shape[-1]), jnp.float32)
+    _, y = jax.lax.scan(step, s0, tuple(
+        jnp.moveaxis(t, 1, 0) for t in (x, dt, bm, cm)))
+    return jnp.moveaxis(y, 0, 1)
+
+
+def inputs(seed, B, S, H, P, N):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    x = jax.random.normal(ks[0], (B, S, H, P), jnp.float32)
+    # steps and rates as a mixer makes them: dt in (0.001, 0.7), a in -(1, 16)
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (B, S, H)) * 2 - 2)
+    a = -jnp.exp(jax.random.uniform(ks[2], (H,), minval=0.0,
+                                    maxval=jnp.log(16.0)))
+    bm = jax.random.normal(ks[3], (B, S, N), jnp.float32) * N ** -0.25
+    cm = jax.random.normal(ks[4], (B, S, N), jnp.float32) * N ** -0.25
+    return x, dt, a, bm, cm
+
+
+# several chunks; heads fewer than the kernel's block of 16 (one head
+# block, short), and more than one block (32 heads: two)
+SHAPES = {"one short head block": (2, 96, 4, 16, 8, 32),
+          "two head blocks, width 64": (1, 64, 32, 64, 16, 32),
+          "a chunk of 128": (1, 384, 2, 8, 8, 128)}
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("shape", sorted(SHAPES))
+def test_scan_values_against_the_recurrence(shape, impl):
+    B, S, H, P, N, chunk = SHAPES[shape]
+    args = inputs(0, B, S, H, P, N)
+    want = recurrence(*args)
+    got = ssd.ssd_scan(*args, chunk=chunk, impl=impl)
+    assert got.shape == (B, S, H, P) and got.dtype == jnp.float32
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("shape", sorted(SHAPES)[:2])
+def test_scan_gradients_against_the_recurrence(shape, impl):
+    B, S, H, P, N, chunk = SHAPES[shape]
+    args = inputs(1, B, S, H, P, N)
+    probe = jax.random.normal(jax.random.PRNGKey(7), (B, S, H, P))
+
+    def through(fn):
+        return jax.grad(lambda *a: jnp.sum(fn(*a) * probe),
+                        argnums=(0, 1, 2, 3, 4))(*args)
+
+    want = through(recurrence)
+    got = through(lambda *a: ssd.ssd_scan(*a, chunk=chunk, impl=impl))
+    for name, g, w in zip(("x", "dt", "a", "B", "C"), got, want):
+        scale = float(jnp.max(jnp.abs(w)))
+        np.testing.assert_allclose(g, w, rtol=1e-3, atol=1e-3 * scale,
+                                   err_msg=name)
+
+
+def test_long_decay_neither_overflows_nor_vanishes():
+    """exp(cum_t - cum_s) is taken of the DIFFERENCE: a chunk whose
+    running sum passes -700 (exp underflows in float32, its inverse
+    overflows) still gives the recurrence's values and finite gradients."""
+    B, S, H, P, N, chunk = 1, 256, 2, 8, 8, 128
+    x, dt, a, bm, cm = inputs(2, B, S, H, P, N)
+    dt = jnp.full_like(dt, 0.7)
+    a = jnp.array([-16.0, -1.0])            # cum reaches -1,433 in a chunk
+    want = recurrence(x, dt, a, bm, cm)
+    for impl in ("xla", "pallas"):
+        got = ssd.ssd_scan(x, dt, a, bm, cm, chunk=chunk, impl=impl)
+        np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+        g = jax.grad(lambda dt: jnp.sum(ssd.ssd_scan(
+            x, dt, a, bm, cm, chunk=chunk, impl=impl)))(dt)
+        assert bool(jnp.all(jnp.isfinite(g)))
+
+
+def test_bf16_inputs_give_bf16_out_and_stay_near():
+    B, S, H, P, N, chunk = 1, 128, 4, 64, 32, 64
+    x, dt, a, bm, cm = inputs(3, B, S, H, P, N)
+    want = recurrence(x, dt, a, bm, cm)
+    bf = jnp.bfloat16
+    for impl in ("xla", "pallas"):
+        got = ssd.ssd_scan(x.astype(bf), dt, a, bm.astype(bf), cm.astype(bf),
+                           chunk=chunk, impl=impl)
+        assert got.dtype == bf
+        err = jnp.abs(got.astype(jnp.float32) - want)
+        assert float(err.mean()) < 0.02 * float(jnp.abs(want).mean())
+
+
+def test_refusals_and_plan():
+    args = inputs(0, 1, 96, 4, 16, 8)
+    with pytest.raises(ValueError, match="no multiple of the chunk"):
+        ssd.ssd_scan(*args, chunk=64)
+    with pytest.raises(ValueError, match="'xla' or 'pallas'"):
+        ssd.ssd_scan(*args, chunk=32, impl="cuda")
+    p = ssd.plan(S=8192, H=128, P=64, N=128, chunk=256, dtype=jnp.bfloat16,
+                 impl="pallas")
+    assert p["heads_per_block"] == 16 and p["path"] == "pallas"
+    assert p["vmem_bytes"] < 16 * 2 ** 20     # Mosaic's default scoped limit
+    # u, y; u, dy, du of 8192 rows of 64 in bf16 and 32 states in and out
+    assert p["hbm_bytes_per_head"] == 8192 * 64 * 2 * 5 + 2 * 32 * 64 * 128 * 4
+    assert ssd.plan(S=96, H=4, P=16, N=8, chunk=32, dtype=jnp.float32,
+                    impl="xla")["vmem_bytes"] == 0
+
+
+def test_ssd_plan_instant_once_a_trace(monkeypatch):
+    from ray_tpu.util import tracing
+
+    seen = []
+    monkeypatch.setattr(tracing, "instant",
+                        lambda name, attrs=None, **kw: seen.append(
+                            (name, attrs)))
+    args = inputs(0, 1, 96, 4, 16, 8)
+    fn = jax.jit(lambda *a: ssd.ssd_scan(*a, chunk=32, impl="pallas"))
+    fn(*args)
+    fn(*args)                                # compiled: no second trace
+    plans = [a for n, a in seen if n == "ssd.plan"]   # a compile is one too
+    assert len(plans) == 1
+    assert plans[0]["chunk"] == 32 and plans[0]["heads_per_block"] == 4

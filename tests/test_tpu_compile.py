@@ -185,6 +185,51 @@ def test_grouped_matmul_compiles_at_olmoe_widths(one_chip, on_chip_branch):
     assert "bf16[64,2048,1024]" in text
 
 
+def test_ssd_scan_compiles_at_granite_widths(one_chip, on_chip_branch):
+    """The state-space scan's two Mosaic calls at Granite-4.0-H-Small's
+    shapes (B2 x S8192, 128 heads of 64, state 128, chunks of 256): the
+    forward with the states it hands the backward, and the backward."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops.ssd import ssd_scan
+
+    B, S, H, P, N = 2, 8192, 128, 64, 128
+    bf, f32 = jnp.bfloat16, jnp.float32
+    args = (_sds((B, S, H, P), bf, one_chip), _sds((B, S, H), f32, one_chip),
+            _sds((H,), f32, one_chip), _sds((B, S, N), bf, one_chip),
+            _sds((B, S, N), bf, one_chip))
+
+    def loss(*a):
+        return ssd_scan(*a, chunk=256, impl="pallas").astype(f32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        *args).compile().as_text()
+    assert text.count("tpu_custom_call") == 2, text[:2000]
+    assert "f32[2,32,8192,128]" in text          # the chunks' incoming states
+
+
+def test_flash_compiles_with_a_stated_scale_and_grouped_heads(
+        one_chip, on_chip_branch):
+    """The attention layer of the hybrid cell: 32 heads over 8 KV heads at
+    S 8192, softmax scale 1/128 in place of 128 ** -0.5."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops.flash_attention import flash_attention
+
+    q = _sds((2, 8192, 32, 128), jnp.bfloat16, one_chip)
+    k = _sds((2, 8192, 8, 128), jnp.bfloat16, one_chip)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, scale=0.0078125).astype(
+            jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, k, k).compile().as_text()
+    assert text.count("tpu_custom_call") == 3, text[:2000]
+
+
 # (slots, heads, kv_heads, head_dim, page_size, pages per slot)
 PAGED_WIDTHS = {
     "2b7": (8, 20, 20, 128, 64, 16),
