@@ -37,11 +37,26 @@ The recurrence is ``ops/ssd.py``: "xla" (plain einsums; the CPU, a mesh)
 or "pallas" (the Mosaic calls), chosen by ``ssd_impl`` as ``attn_impl``
 and ``gmm_impl`` choose theirs; the kernel path refuses a mesh of several
 devices.
+
+What lies between the two projections is memory-bound row work, and its
+gradient is written by hand (``_conv_silu``, ``_gated_norm`` here,
+``_prologue`` in ``ops/ssd.py``; instant ``mixer.plan``), whatever
+``ssd_impl`` says. A rule keeps only arrays the forward already has, in
+the activations' type (the convolution's input; x, y, z; the steps),
+computes every float32 value again inside the pass that needs it, and
+hands each cotangent on in the type its consumer takes, so that no float32
+[B, S, H P] array stands in memory between two fusions (jax's transposition
+left five). Across the layer checkpoint the rules keep NOTHING: the
+backward's replay of the layer makes their residuals again. The results
+of a pass go through ``optimization_barrier``: XLA otherwise moves a
+pass's arithmetic into its consumers' fusions, twice where there are two
+(tests/test_tpu_compile.py holds the compiled layer to the account).
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 from dataclasses import dataclass
 from typing import Any, Dict, List, Tuple
 
@@ -51,6 +66,7 @@ import jax.numpy as jnp
 from ray_tpu.models import llama as _ll
 from ray_tpu.models import moe as _moe
 from ray_tpu.ops.ssd import ssd_scan
+from ray_tpu.util import tracing
 
 
 @dataclass(frozen=True)
@@ -211,6 +227,134 @@ def _causal_conv(x, w, b):
     return out
 
 
+def _again(x):
+    """x in float32, as a value of its own: x + 0 with a zero the compiler
+    cannot see through. Two passes that compute the same float32 values
+    from the same array would else share them, by way of memory."""
+    return x.astype(jnp.float32) + jax.lax.optimization_barrier(
+        jnp.zeros((), jnp.float32))
+
+
+@jax.custom_vjp
+def _conv_silu(x, w, b):
+    """silu(conv(x) + b) in x's type: x [B, S, C], w [taps, C], b [C]."""
+    return jax.lax.optimization_barrier(
+        jax.nn.silu(_causal_conv(x, w, b)).astype(x.dtype))
+
+
+def _conv_silu_fwd(x, w, b):
+    return _conv_silu(x, w, b), (x, w, b)
+
+
+def _conv_silu_bwd(res, g):
+    """The pre-activation again from x; ``dpre`` ONCE, in x's type (the
+    type jax's transposition gave each tap's product). ``dpre`` padded at
+    the END and read at one shift a tap serves both dx, its transposed
+    convolution, and dw[j] = sum over the rows of dpre[t + taps - 1 - j]
+    x[t]; dw and db are float32 sums over every row."""
+    x, w, b = res
+    f32 = jnp.float32
+    taps, s = w.shape[0], x.shape[1]
+    pre = _causal_conv(x, w, b)
+    sig = jax.nn.sigmoid(pre)
+    dpre = (g.astype(f32) * sig * (1.0 + pre * (1.0 - sig))).astype(x.dtype)
+    ahead = jnp.pad(dpre, ((0, 0), (0, taps - 1), (0, 0)))
+    dx, dw = 0.0, []
+    for j in range(taps):
+        shifted = ahead[:, taps - 1 - j:taps - 1 - j + s].astype(f32)
+        dx = dx + shifted * w[j].astype(f32)
+        dw.append(jnp.sum(shifted * x.astype(f32), axis=(0, 1)))
+    db = jnp.sum(dpre.astype(f32), axis=(0, 1))
+    return (dx.astype(x.dtype), jnp.stack(dw).astype(w.dtype),
+            db.astype(b.dtype))
+
+
+_conv_silu.defvjp(_conv_silu_fwd, _conv_silu_bwd)
+
+
+def _lanes(d_skip, inner: int):
+    """d_skip [H] -> [H P] float32: a head's D on each of its lanes."""
+    return jnp.repeat(d_skip.astype(jnp.float32), inner // d_skip.shape[0])
+
+
+def _gated(y, xs, z, d_skip):
+    """(y + D xs) silu(z), float32: y, xs, z [B, S, H P], d_skip [H]."""
+    f32 = jnp.float32
+    y = y.astype(f32) + xs.astype(f32) * _lanes(d_skip, y.shape[-1])
+    return y * jax.nn.silu(z.astype(f32))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _gated_norm(y, xs, z, d_skip, gate_norm, eps):
+    """The skip, the gate and the RMS norm over all H P:
+    rms_norm((y + D xs) silu(z)) gate_norm, [B, S, H P] in y's type."""
+    v = _gated(y, xs, z, d_skip)
+    r = jax.lax.rsqrt(jnp.mean(v * v, axis=-1, keepdims=True) + eps)
+    return jax.lax.optimization_barrier(
+        (v * r).astype(y.dtype) * gate_norm.astype(y.dtype))
+
+
+def _gated_norm_fwd(y, xs, z, d_skip, gate_norm, eps):
+    return (_gated_norm(y, xs, z, d_skip, gate_norm, eps),
+            (y, xs, z, d_skip, gate_norm))
+
+
+def _gated_norm_bwd(eps, res, g):
+    """Two passes over the rows. The first leaves a row's two scalars:
+    r = rsqrt(mean v^2 + eps) and m = mean(dn v), [B, S, 1] float32. The
+    second computes v again (from values ``_again`` sets apart: shared with
+    the first pass they would be written out in float32) and writes dy,
+    dxs, dz in their own types and the sums d d_skip [H], d gate_norm
+    [H P], float32 over every row: with n = v r, dv = r (dn - n m r)."""
+    y, xs, z, d_skip, gate_norm = res
+    f32, dt_ = jnp.float32, y.dtype
+    scale = gate_norm.astype(f32)
+    v = _gated(y, xs, z, d_skip)
+    r = jax.lax.rsqrt(jnp.mean(v * v, axis=-1, keepdims=True) + eps)
+    m = jnp.mean(g.astype(f32) * scale * v, axis=-1, keepdims=True)
+    y, xs, z, g = (_again(t) for t in (y, xs, z, g))
+    skip = _lanes(d_skip, y.shape[-1])
+    y = y + xs * skip
+    gate = jax.nn.sigmoid(z)
+    n = y * (z * gate) * r
+    dv = r * (g * scale - n * (m * r))
+    dy = dv * (z * gate)
+    dz = dv * y * gate * (1.0 + z * (1.0 - gate))
+    d_gate = jnp.sum(g * n.astype(dt_).astype(f32), axis=(0, 1))
+    d_d = jnp.sum(dy * xs, axis=(0, 1)).reshape(d_skip.shape[0], -1).sum(-1)
+    # results of THIS pass, not terms of their consumers' fusions
+    return jax.lax.optimization_barrier(
+        (dy.astype(dt_), (dy * skip).astype(dt_), dz.astype(dt_),
+         d_d.astype(d_skip.dtype), d_gate.astype(gate_norm.dtype)))
+
+
+_gated_norm.defvjp(_gated_norm_fwd, _gated_norm_bwd)
+
+
+def plan(cfg: HybridConfig, B: int, S: int) -> dict:
+    """The rules' own account of a mixer's elementwise passes (also the
+    attributes of ``mixer.plan``): every operand of a pass read once and
+    every result written once, in bytes, for one forward and for the
+    backward; and what the rules keep beyond the layer's input: nothing
+    under the layer checkpoint (the forward runs again), else the
+    convolution's input, x, y and z in the activations' type and the
+    steps."""
+    rows, item = B * S, jnp.dtype(cfg.dtype).itemsize
+    inner, conv_dim, _ = _mamba_sizes(cfg)
+    wide, conv = rows * inner * item, rows * conv_dim * item
+    steps = rows * cfg.mamba_heads * 4
+    return {
+        "path": "rules", "rows": rows,
+        "residual_bytes": 0 if cfg.remat else conv + 3 * wide + steps,
+        # xBC -> its activation; x, dt -> u and the sums twice; y, x, z ->
+        # a row's scalar, the three again -> the normed rows
+        "hbm_bytes_fwd": 2 * conv + (2 * wide + 3 * steps) + 7 * wide,
+        # g, y, x, z -> a row's scalars; the four again -> dy, dx, dz;
+        # du, x -> d dt (with the sums' gradients), du, dt -> dx;
+        # x, g -> dpre; dpre, x -> dx, d conv_w
+        "hbm_bytes_bwd": 11 * wide + (4 * wide + 4 * steps) + 6 * conv}
+
+
 def mixer_half(x, lp, cfg: HybridConfig, kind: str, mesh=None):
     """The Mamba-2 half of a block: x [B, S, D] -> x + its mixer's output
     (the module docstring has the equations)."""
@@ -224,22 +368,23 @@ def mixer_half(x, lp, cfg: HybridConfig, kind: str, mesh=None):
     H, P, N = cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_state
     inner, conv_dim, _ = _mamba_sizes(cfg)
     dt_, f32 = cfg.dtype, jnp.float32
+    tracing.instant("mixer.plan", plan(cfg, B, S))
     u = _ll.rms_norm(x, lp["mix_norm"], cfg.norm_eps)
     zxbcdt = u @ _ll._dq(lp["in_proj"], dt_)
-    z, xbc, step = (zxbcdt[..., :inner], zxbcdt[..., inner:inner + conv_dim],
-                    zxbcdt[..., inner + conv_dim:])
-    xbc = jax.nn.silu(_causal_conv(xbc, lp["conv_w"], lp["conv_b"])
-                      ).astype(dt_)
-    xs = xbc[..., :inner].reshape(B, S, H, P)
-    bm, cm = xbc[..., inner:inner + N], xbc[..., inner + N:]
+    z, step = zxbcdt[..., :inner], zxbcdt[..., inner + conv_dim:]
+    # the heads' channels and B | C, convolved apart: x is an array of its
+    # own in the passes below (a slice of the joint one splits them in two)
+    xs = _conv_silu(zxbcdt[..., inner:2 * inner], lp["conv_w"][:, :inner],
+                    lp["conv_b"][:inner])
+    bc = _conv_silu(zxbcdt[..., 2 * inner:inner + conv_dim],
+                    lp["conv_w"][:, inner:], lp["conv_b"][inner:])
+    bm, cm = bc[..., :N], bc[..., N:]
     step = jax.nn.softplus(step.astype(f32) + lp["dt_bias"].astype(f32))
-    y = ssd_scan(xs, step, -jnp.exp(lp["a_log"].astype(f32)), bm, cm,
+    y = ssd_scan(xs.reshape(B, S, H, P), step,
+                 -jnp.exp(lp["a_log"].astype(f32)), bm, cm,
                  chunk=min(cfg.mamba_chunk, S), impl=cfg.ssd_impl)
-    y = y.astype(f32) + xs.astype(f32) * lp["d_skip"].astype(f32)[:, None]
-    y = y.reshape(B, S, inner) * jax.nn.silu(z.astype(f32))
-    y = (y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True)
-                           + cfg.norm_eps)).astype(dt_) \
-        * lp["gate_norm"].astype(dt_)
+    y = _gated_norm(y.reshape(B, S, inner), xs, z, lp["d_skip"],
+                    lp["gate_norm"], cfg.norm_eps)
     return _ll._residual(x, y @ _ll._dq(lp["out_proj"], dt_), cfg)
 
 

@@ -50,7 +50,11 @@ decay and every sum over it are float32.
 Across a layer checkpoint nothing of the scan is kept, so nothing of it
 is named for ``llama._checkpoint``: its output is as large as two layer inputs and the states as
 four, so the backward's recomputation of the layer runs the forward call
-again (the numbers: PERF.md section 6, PR 32).
+again (the numbers: PERF.md section 6, PR 32). What both paths take in
+place of x, dt and A (``_prologue``: u = dt x, the running sums in two
+layouts) has a gradient rule of its own that keeps x, dt and A and no
+more; a head's dt reaches its P lanes, and ``du x`` is summed over them,
+as a product with a 0/1 matrix (``_over_lanes``, ``_per_head``).
 
 S must be a multiple of the chunk (pad upstream).
 """
@@ -386,6 +390,72 @@ def _scan_pallas_bwd(chunk, heads, width, res, dy):
 _scan_pallas.defvjp(_scan_pallas_fwd, _scan_pallas_bwd)
 
 
+def _spread(heads: int, width: int):
+    """[H, H x width] float32, 1 where the lane is the head's."""
+    return jnp.repeat(jnp.eye(heads, dtype=jnp.float32), width, axis=1)
+
+
+def _over_lanes(per_head, width: int):
+    """[B, S, H] float32 -> [B, S, H x width]: a head's value on each of
+    its lanes. A product with a 0/1 matrix, not a broadcast and a reshape:
+    the TPU's tiles hold 128 lanes, two heads of 64, and XLA writes such a
+    broadcast out as a float32 array of its own and copies it into the
+    rows' layout."""
+    return jnp.einsum("bsh,hl->bsl", per_head,
+                      _spread(per_head.shape[-1], width),
+                      precision=jax.lax.Precision.HIGHEST)
+
+
+def _per_head(wide, heads: int):
+    """[B, S, H x width] float32 -> [B, S, H]: the sum over a head's lanes,
+    the transpose of ``_over_lanes`` and the same product."""
+    return jnp.einsum("bsl,hl->bsh", wide,
+                      _spread(heads, wide.shape[-1] // heads),
+                      precision=jax.lax.Precision.HIGHEST)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _prologue(x, dt, a, chunk):
+    """What both paths take in place of x [B, S, H x P], dt [B, S, H] and
+    a [H]: u = dt x in x's shape and type, and the running sum of dt a
+    inside each chunk in the two layouts the kernel reads, col [B, S, H]
+    and row [B, H, S], float32. Its gradient is written out below, not
+    transposed by jax: the product ``du x`` is summed over a head's lanes
+    in the pass that makes it."""
+    f32 = jnp.float32
+    step = dt.astype(f32) * a.astype(f32)                    # [B, S, H] < 0
+    col = jnp.cumsum(_chunks(step, chunk), axis=2).reshape(step.shape)
+    width = x.shape[-1] // dt.shape[-1]
+    u = (x.astype(f32) * _over_lanes(dt.astype(f32), width)).astype(x.dtype)
+    return u, col, col.transpose(0, 2, 1)
+
+
+def _prologue_fwd(x, dt, a, chunk):
+    return _prologue(x, dt, a, chunk), (x, dt, a)
+
+
+def _prologue_bwd(chunk, res, grads):
+    x, dt, a = res
+    du, dcol, drow = grads
+    f32 = jnp.float32
+    heads = dt.shape[-1]
+    # dt over the lanes again, not the forward's product kept in float32
+    wide = _over_lanes(jax.lax.optimization_barrier(dt.astype(f32)),
+                       x.shape[-1] // heads)
+    dx = (du.astype(f32) * wide).astype(x.dtype)
+    dcum = _chunks(dcol + drow.transpose(0, 2, 1), chunk)
+    # the transpose of a running sum: the running sum from the chunk's end
+    dstep = jnp.flip(jnp.cumsum(jnp.flip(dcum, 2), axis=2), 2).reshape(
+        dt.shape)
+    ddt = _per_head(du.astype(f32) * x.astype(f32), heads) \
+        + dstep * a.astype(f32)
+    da = jnp.sum(dstep * dt.astype(f32), axis=(0, 1))
+    return dx, ddt.astype(dt.dtype), da.astype(a.dtype)
+
+
+_prologue.defvjp(_prologue_fwd, _prologue_bwd)
+
+
 def ssd_scan(x, dt, a, bm, cm, *, chunk: int = 256, impl: str = "xla"):
     """x [B, S, H, P], dt [B, S, H] (positive: after its softplus), a [H]
     (negative), bm and cm [B, S, N] -> y [B, S, H, P] in x's type, the
@@ -402,20 +472,16 @@ def ssd_scan(x, dt, a, bm, cm, *, chunk: int = 256, impl: str = "xla"):
              impl=impl)
     tracing.instant("ssd.plan", p)
     f32 = jnp.float32
-    step = dt.astype(f32) * a.astype(f32)                    # [B, S, H] < 0
-    cum = jnp.cumsum(_chunks(step, chunk), axis=2)           # [B, C, Q, H]
-    u = (x.astype(f32) * dt.astype(f32)[..., None]).astype(x.dtype)
+    u, col, row = _prologue(x.reshape(B, S, H * P), dt, a, chunk)
     if impl == "xla":
-        y = _scan_xla(_chunks(u, chunk).astype(f32),
+        y = _scan_xla(_chunks(u.reshape(x.shape), chunk).astype(f32),
                       _chunks(bm, chunk).astype(f32),
-                      _chunks(cm, chunk).astype(f32), cum)
+                      _chunks(cm, chunk).astype(f32), _chunks(col, chunk))
         return y.reshape(B, S, H, P).astype(x.dtype)
     heads = p["heads_per_block"]
     if heads % 2:
         raise ValueError(f"ssd_scan: the kernel walks heads in pairs, {H} "
                          "heads give a block of an odd number")
-    col = cum.reshape(B, S, H)
-    y = _scan_pallas(u.reshape(B, S, H * P), bm.astype(x.dtype),
-                     cm.astype(x.dtype), col, col.transpose(0, 2, 1), chunk,
-                     heads, P)
+    y = _scan_pallas(u, bm.astype(x.dtype), cm.astype(x.dtype), col, row,
+                     chunk, heads, P)
     return y.reshape(B, S, H, P)

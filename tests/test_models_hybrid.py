@@ -125,6 +125,117 @@ def test_model_against_the_plain_reference(held):
                                    err_msg=jax.tree_util.keystr(path))
 
 
+def plain_mixer(x, lp, cfg, kind="mamba", mesh=None):
+    """``hybrid.mixer_half`` as the module docstring's equations, with the
+    program's casts to ``cfg.dtype`` and the recurrence one step at a time,
+    for jax to differentiate: what the hand-written rules are held to."""
+    f32, dt_ = jnp.float32, cfg.dtype
+    B, S, _ = x.shape
+    H, P, N = cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_state
+    inner = H * P
+    w = lambda name: lp[name].astype(dt_)                     # noqa: E731
+    u = llama.rms_norm(x, lp["mix_norm"], cfg.norm_eps)
+    zxbcdt = u @ w("in_proj")
+    z, xbc, dt = jnp.split(zxbcdt, [inner, 2 * inner + 2 * N], axis=-1)
+    taps = lp["conv_w"].shape[0]
+    padded = jnp.pad(xbc.astype(f32), ((0, 0), (taps - 1, 0), (0, 0)))
+    pre = lp["conv_b"].astype(f32) + sum(
+        lp["conv_w"][j].astype(f32) * padded[:, j:j + S] for j in range(taps))
+    xbc = jax.nn.silu(pre).astype(dt_)
+    xs, bm, cm = jnp.split(xbc, [inner, inner + N], axis=-1)
+    xs = xs.reshape(B, S, H, P)
+    dt = jax.nn.softplus(dt.astype(f32) + lp["dt_bias"].astype(f32))
+    a = -jnp.exp(lp["a_log"].astype(f32))
+
+    def step(s, at):
+        x_t, b_t, c_t, dt_t = at              # [B, H, P], [B, N] x 2, [B, H]
+        u_t = (x_t.astype(f32) * dt_t[..., None]).astype(dt_).astype(f32)
+        s = jnp.exp(dt_t * a)[..., None, None] * s \
+            + u_t[..., None] * b_t.astype(f32)[:, None, None, :]
+        return s, jnp.einsum("bhpn,bn->bhp", s, c_t.astype(f32))
+
+    _, y = jax.lax.scan(step, jnp.zeros((B, H, P, N), f32), tuple(
+        jnp.moveaxis(t, 1, 0) for t in (xs, bm, cm, dt)))
+    y = jnp.moveaxis(y, 0, 1).astype(dt_).astype(f32) \
+        + xs.astype(f32) * lp["d_skip"].astype(f32)[:, None]
+    y = y.reshape(B, S, inner) * jax.nn.silu(z.astype(f32))
+    y = (y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True)
+                           + cfg.norm_eps)).astype(dt_) * w("gate_norm")
+    return llama._residual(x, y @ w("out_proj"), cfg)
+
+
+MIXER_WEIGHTS = ("mix_norm", "in_proj", "conv_w", "conv_b", "dt_bias",
+                 "a_log", "d_skip", "gate_norm", "out_proj")
+RULE_CASES = [(impl, chunks, batch, taps, "float32")
+              for impl in ("xla", "pallas") for chunks in (1, 3)
+              for batch in (1, 3) for taps in (4, 2)] \
+    + [("xla", 3, 3, 4, "bfloat16"), ("pallas", 3, 1, 4, "bfloat16")]
+
+
+@pytest.mark.parametrize(
+    "impl,chunks,batch,taps,dtype", RULE_CASES,
+    ids=["-".join(map(str, c)) for c in RULE_CASES])
+def test_the_mixers_gradient_rules_against_jax_on_the_plain_formulas(
+        impl, chunks, batch, taps, dtype):
+    """The three rules (``_conv_silu``, ``_gated_norm``, ``ssd._prologue``)
+    through ``mixer_half``: the input's gradient and each of the nine
+    weights', against jax's own of ``plain_mixer`` in float32, to 1e-5 of
+    a gradient's largest entry. With bfloat16 activations the same float32
+    gradients are the yardstick (jax's own bfloat16 ones add up a weight's
+    gradient IN bfloat16, the norm's scale outside the rules too) and the
+    limit is the rounding of the activations, three hundredths."""
+    cfg = tiny(ssd_impl=impl, mamba_conv=taps)
+    params, _ = make(cfg, seed=chunks + batch)
+    lp = {k: params["layers"][0][k][0] for k in MIXER_WEIGHTS}
+    assert float(jnp.abs(lp["conv_b"]).min()) > 0
+    assert float(jnp.abs(lp["d_skip"] - 1).min()) > 0
+    keys = jax.random.split(jax.random.PRNGKey(taps), 2)
+    shape = (batch, chunks * cfg.mamba_chunk, cfg.d_model)
+    x = jax.random.normal(keys[0], shape).astype(dtype).astype(jnp.float32)
+    probe = jax.random.normal(keys[1], shape)
+
+    def through(mixer, cfg):
+        def loss(x, lp):
+            return jnp.sum(mixer(x.astype(cfg.dtype), lp, cfg, "mamba"
+                                 ).astype(jnp.float32) * probe)
+        return jax.value_and_grad(loss, argnums=(0, 1))(x, lp)
+
+    got, (g_x, g_lp) = through(hybrid.mixer_half,
+                               cfg.replace(dtype=jnp.dtype(dtype)))
+    want, (w_x, w_lp) = through(plain_mixer, cfg)
+    exact = dtype == "float32"
+    assert abs(float(got) - float(want)) < (1e-4 if exact else 0.05) \
+        * (1 + abs(float(want)))
+    for name, g, w in [("x", g_x, w_x)] + [(k, g_lp[k], w_lp[k])
+                                            for k in MIXER_WEIGHTS]:
+        assert g.dtype == w.dtype and g.shape == w.shape, name
+        scale = float(jnp.max(jnp.abs(w)))
+        assert scale > 0, name
+        tol = 1e-5 if exact else 3e-2
+        np.testing.assert_allclose(g, w, rtol=tol, atol=tol * scale,
+                                   err_msg=name)
+
+
+def test_the_model_under_the_layer_checkpoint_with_rules_and_without(
+        monkeypatch):
+    """The whole ``tiny`` model's loss gradient, every layer under
+    ``llama._checkpoint``: the rules against the plain formulas patched in
+    for ``mixer_half``."""
+    cfg = tiny(remat=True)
+    params, tokens = make(cfg)
+    grad = lambda: jax.value_and_grad(                        # noqa: E731
+        lambda p: hybrid.loss_fn(p, {"tokens": tokens}, cfg)[0])(params)
+    got, g_grads = grad()
+    monkeypatch.setattr(hybrid, "mixer_half", plain_mixer)
+    want, w_grads = grad()
+    assert abs(float(got) - float(want)) < 1e-5
+    flat = lambda t: jax.tree.leaves_with_path(t)   # noqa: E731
+    for (path, g), (_, w) in zip(flat(g_grads), flat(w_grads)):
+        np.testing.assert_allclose(
+            g, w, rtol=2e-3, atol=2e-3 * float(jnp.max(jnp.abs(w)) + 1e-9),
+            err_msg=jax.tree_util.keystr(path))
+
+
 def test_kernel_paths_give_the_plain_paths_program():
     """flash, the Mosaic grouped matmul and the scan kernel, in interpret
     mode, against the "xla" paths of the same model."""
@@ -283,7 +394,7 @@ def test_flash_with_a_stated_scale_against_xla():
 
 
 def test_plans_read_back_from_a_profile_around_a_lowering(tmp_path):
-    """``ssd.plan`` and ``hybrid.layer_plan`` are events of jax's profiler
+    """``ssd.plan``, ``mixer.plan`` and ``hybrid.layer_plan`` are events of jax's profiler
     (util/tracing.py): a profile taken around a lowering holds them with
     their attributes, tracing on or off."""
     import glob
@@ -308,7 +419,8 @@ def test_plans_read_back_from_a_profile_around_a_lowering(tmp_path):
         if plane.name == "/host:CPU":
             for line in plane.lines:
                 for e in line.events:
-                    if e.name in ("ssd.plan", "hybrid.layer_plan"):
+                    if e.name in ("ssd.plan", "hybrid.layer_plan",
+                                  "mixer.plan"):
                         events.setdefault(e.name, []).append(dict(e.stats))
     assert events["hybrid.layer_plan"] == [
         {"kinds": 2, "runs": 3, "bodies": 2, "layers": 4}]
@@ -316,6 +428,15 @@ def test_plans_read_back_from_a_profile_around_a_lowering(tmp_path):
     assert plan["path"] == "pallas" and plan["chunk"] == 32
     assert plan["S"] == 96 and plan["heads_per_block"] == 8
     assert plan["vmem_bytes"] > 0 and plan["hbm_bytes_per_head"] > 0
+    # once a traced mixer body, beside ssd.plan: the rules' own account at
+    # B2 x S96, inner 128, 160 channels convolved, 8 heads, float32
+    wide, conv, steps = 192 * 128 * 4, 192 * 160 * 4, 192 * 8 * 4
+    assert events["mixer.plan"] == [{
+        "path": "rules", "rows": 192, "residual_bytes": 0,
+        "hbm_bytes_fwd": 2 * conv + 9 * wide + 3 * steps,
+        "hbm_bytes_bwd": 15 * wide + 6 * conv + 4 * steps}]
+    assert hybrid.plan(cfg.replace(remat=False), 2, 96)["residual_bytes"] \
+        == conv + 3 * wide + steps
 
 
 @pytest.mark.parametrize("lean", [-0.05, 0.04, 0.1, 0.5])

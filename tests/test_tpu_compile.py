@@ -209,6 +209,128 @@ def test_ssd_scan_compiles_at_granite_widths(one_chip, on_chip_branch):
     assert "f32[2,32,8192,128]" in text          # the chunks' incoming states
 
 
+def _array_bytes(types: str) -> int:
+    """Bytes of every array type named in ``types`` (one, or a tuple)."""
+    import math
+    import re
+
+    item = {"bf16": 2, "f32": 4, "s32": 4, "u32": 4, "pred": 1}
+    return sum(item[t] * math.prod(int(d) for d in dims.split(",") if d)
+               for t, dims in re.findall(r"\b(bf16|f32|s32|u32|pred)"
+                                         r"\[([\d,]*)\]", types))
+
+
+def _entry_ops(text):
+    """[(opcode, result type, bytes read, bytes written, is a matmul)] of
+    the entry computation of a compiled module. A fusion reads each
+    operand once, one that its body only slices at the slices' size; a
+    fusion whose body holds a convolution or whose kind is kOutput is a
+    matmul fusion. Any other op is listed with its result's bytes both
+    ways (a copy, a slice, a broadcast, a convert left outside every
+    fusion is a pass over memory of its own)."""
+    import re
+
+    comps, cur = {}, None
+    for ln in text.splitlines():
+        m = re.match(r"^(ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", ln)
+        if m:
+            cur = comps["ENTRY" if m.group(1) else m.group(2)] = []
+        elif ln.startswith("}"):
+            cur = None
+        elif cur is not None:
+            cur.append(ln.strip())
+    ops = []
+    for ln in comps["ENTRY"]:
+        m = re.match(r"^(?:ROOT )?%?[\w.\-]+ = (.*?) ([\w\-]+)\(", ln)
+        if not m:
+            continue
+        result, opcode = m.groups()
+        if opcode != "fusion":
+            ops.append((opcode, result, _array_bytes(result),
+                        _array_bytes(result), False))
+            continue
+        body = comps[re.search(r"calls=%?([\w.\-]+)", ln).group(1)]
+        read = 0
+        for b in body:
+            pm = re.match(r"^%?([\w.\-]+) = (\S+) parameter\(", b)
+            if not pm:
+                continue
+            users = [u.split(" = ", 1)[1] for u in body if re.search(
+                rf"[(, ]%?{re.escape(pm.group(1))}[,)]",
+                u.split(" = ", 1)[-1])]
+            sliced = [u for u in users
+                      if re.match(r"\S+ (dynamic-)?slice\(", u)]
+            read += sum(_array_bytes(u.split(" ")[0]) for u in sliced) \
+                if users and len(sliced) == len(users) \
+                else _array_bytes(pm.group(2))
+        matmul = "kind=kOutput" in ln or any(" convolution(" in b
+                                             for b in body)
+        ops.append(("fusion", result, read, _array_bytes(result), matmul))
+    return ops
+
+
+def test_a_mixers_passes_at_granite_widths(one_chip, on_chip_branch):
+    """One mixer's forward, its replay under ``jax.checkpoint`` and its
+    backward at the Granite cell's widths (B2 x S8192), compiled for the
+    chip: what tells a later refactor that it brought a pass back. The
+    Mosaic calls are the scan's and nobody else's (forward twice 5 -> 2,
+    backward 7 -> 5: ``readers/granite_kernel_roofline.py`` raises on any
+    other). No op leaves a float32 array of the rows' size behind. The
+    non-matmul fusions move 7.85 GB (15.4 at the parent of PR 33, whose
+    gradient jax transposed), under the rules' own account; ops outside
+    every fusion 0.54 GB, two slices of x out of the projection's output
+    (4.1: two broadcasts of dt over a head's lanes, a relayout of ``du x``
+    before its sum over them)."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark.readers import kernel_roofline
+    from ray_tpu.models import hybrid
+
+    bf = jnp.bfloat16
+    cfg = hybrid.HybridConfig(
+        vocab_size=12544, d_model=4096, n_layers=1, n_heads=32, n_kv_heads=8,
+        d_ff=768, n_experts=72, top_k=10, experts_held=(9, 0),
+        shared_d_ff=1536, mamba_heads=128, mamba_head_dim=64,
+        mamba_state=128, mamba_conv=4, mamba_chunk=256, ssd_impl="pallas",
+        layer_types=("mamba",), dtype=bf, param_dtype=bf,
+        residual_multiplier=0.22)
+    B, S = 2, 8192
+    stack = jax.eval_shape(
+        lambda: hybrid.init_params(jax.random.PRNGKey(0), cfg))["layers"][0]
+    lp = {k: _sds(stack[k].shape[1:], bf, one_chip) for k in (
+        "mix_norm", "in_proj", "conv_w", "conv_b", "dt_bias", "a_log",
+        "d_skip", "gate_norm", "out_proj")}
+
+    def loss(x, lp):
+        y = jax.checkpoint(lambda x, lp: hybrid.mixer_half(
+            x, lp, cfg, "mamba"))(x, lp)
+        return jnp.sum(y.astype(jnp.float32) ** 2)   # wants the forward too
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        _sds((B, S, cfg.d_model), bf, one_chip), lp).compile().as_text()
+    calls = sorted(kernel_roofline.signature(ln) for ln in text.splitlines()
+                   if kernel_roofline.signature(ln) is not None)
+    assert calls == [(2, 5), (2, 5), (5, 7)], calls
+    ops = _entry_ops(text)
+    rows = re.compile(rf"f32\[{B},{S},({cfg.mamba_inner}|"
+                      rf"{cfg.mamba_inner + 2 * cfg.mamba_state})\]")
+    wide = [(op, result[:200]) for op, result, *_ in ops if rows.search(result)]
+    assert not wide, wide
+    passes = sum(r + w for op, _, r, w, matmul in ops
+                 if op == "fusion" and not matmul)
+    plan = hybrid.plan(cfg, B, S)
+    assert passes < 8.2e9, passes
+    assert passes < 2 * plan["hbm_bytes_fwd"] + plan["hbm_bytes_bwd"] \
+        < 12.5e9, plan
+    alone = sum(w for op, _, _, w, _ in ops if w > 50e6 and op in (
+        "copy", "slice", "broadcast", "convert", "transpose", "pad",
+        "concatenate"))
+    assert alone < 1.0e9, alone
+
+
 def test_flash_compiles_with_a_stated_scale_and_grouped_heads(
         one_chip, on_chip_branch):
     """The attention layer of the hybrid cell: 32 heads over 8 KV heads at
