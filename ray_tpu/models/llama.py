@@ -81,6 +81,12 @@ class LlamaConfig:
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
 
+    @property
+    def rope_dim(self) -> int:
+        """How many of a head's dims the rotary tables turn: all of them,
+        but where a family states fewer (models/latent.py)."""
+        return self.head_dim
+
     def replace(self, **kw) -> "LlamaConfig":
         return dataclasses.replace(self, **kw)
 
@@ -230,7 +236,10 @@ def _family(cfg: "LlamaConfig"):
     model, ``models/moe.py`` for ``MoEConfig``. It supplies the layer's
     feed-forward half (``feed_forward``), the names that half wants kept
     across the layer checkpoint (``REMAT_SAVED``) and, where the
-    feed-forward returns statistics, ``finish_loss``."""
+    feed-forward returns statistics, ``finish_loss``; where its attention
+    is not three projections of the hidden state, the attention half too
+    (``attention_half``); where it predicts further tokens than the next,
+    ``further_losses``."""
     return sys.modules[type(cfg).__module__]
 
 
@@ -441,13 +450,15 @@ def _residual(x, y, cfg: LlamaConfig):
 REMAT_SAVED = ()
 
 
-def feed_forward(h, lp, cfg: LlamaConfig, mesh=None, rules=None, tp=None):
+def feed_forward(h, lp, cfg: LlamaConfig, mesh=None, rules=None, tp=None,
+                 kind=None):
     """The dense feed-forward half of a block, a SwiGLU: normed h
     [B, S, D] -> (its output [B, S, D], None). The second value is what a
     family's feed-forward reports of itself layer by layer (an expert
     layer's routing statistics, models/moe.py); the dense one has nothing
     to report. With a plan ``tp`` every shard's rows pass from the gather
-    through the SwiGLU to the reduce-scatter on their own."""
+    through the SwiGLU to the reduce-scatter on their own. ``kind`` is the
+    layer's, for a family whose feed-forward differs by it."""
     dt = cfg.dtype
     if tp is not None:
         return gather_apply_scatter(
@@ -468,8 +479,15 @@ def _layer(x, lp, cfg: LlamaConfig, cos, sin, cache=None, collect_kv=False,
     collect_kv=True (cache seeding), else None; stats is what the
     feed-forward reports (None for the dense one). ``kind``: None or
     "attention" for the attention half; any other kind of layer takes its
-    first half from the family's ``mixer_half`` (x, lp, cfg, kind -> x)."""
-    if kind in (None, "attention"):
+    first half from the family's ``mixer_half`` (x, lp, cfg, kind -> x). A
+    family with an ``attention_half`` of its own (x, lp, cfg, cos, sin ->
+    x) supplies every layer's. ``kind`` goes on to the feed-forward."""
+    own = getattr(_family(cfg), "attention_half", None)
+    if own is not None:
+        assert cache is None and not collect_kv and tp is None, kind
+        x, k, v, new_cache = own(x, lp, cfg, cos, sin, mesh=mesh,
+                                 rules=rules), None, None, None
+    elif kind in (None, "attention"):
         x, k, v, new_cache = _attention_half(
             x, lp, cfg, cos, sin, cache=cache, mesh=mesh, rules=rules, tp=tp)
     else:
@@ -478,7 +496,7 @@ def _layer(x, lp, cfg: LlamaConfig, cos, sin, cache=None, collect_kv=False,
             x, lp, cfg, kind, mesh=mesh), None, None, None
     h = rms_norm(x, lp["ffn_norm"], cfg.norm_eps)
     y, stats = _family(cfg).feed_forward(h, lp, cfg, mesh=mesh, rules=rules,
-                                         tp=tp)
+                                         tp=tp, kind=kind)
     return _residual(x, y, cfg), ((k, v) if collect_kv else new_cache), stats
 
 
@@ -552,7 +570,30 @@ def forward(params, tokens, cfg: LlamaConfig, pos_offset=0, mesh=None,
 def forward_with_stats(params, tokens, cfg: LlamaConfig, pos_offset=0,
                        mesh=None, rules=None):
     """``forward`` and what every layer's feed-forward reported, stacked
-    over layers (None for the dense model): (logits, stats).
+    over layers (None for the dense model): (logits, stats)."""
+    return _forward(params, tokens, cfg, pos_offset, mesh, rules)[:2]
+
+
+def _logits(params, x, cfg: LlamaConfig):
+    """The head over normed x [B, S, D]: the ``lm_head`` or, tied, the
+    embedding; divided where the config says; float32 or as computed."""
+    dt = cfg.dtype
+    if "lm_head" in params:
+        logits = x @ _dq(params["lm_head"], dt)
+    else:                                   # tied to the embedding
+        logits = jnp.einsum("bsd,vd->bsv", x, _dq(params["embed"], dt))
+    if cfg.logits_scaling is not None:
+        logits = logits / cfg.logits_scaling
+    return logits.astype(jnp.float32) if cfg.f32_logits else logits
+
+
+def _forward(params, tokens, cfg: LlamaConfig, pos_offset=0, mesh=None,
+             rules=None):
+    """``forward_with_stats`` and what a further pass over the same
+    sequences needs (``loss_fn``, a family's ``further_losses``): (logits,
+    stats, the residual stream BEFORE the final norm, ``run``: (kind, x,
+    stack) -> (x, stats), the scan of a stack of layers of one kind by the
+    body, the tables and the shardings this forward used).
 
     ``params["layers"]`` is one stack of identical layers, scanned by one
     body, or, for a family with layers of several kinds (``layer_runs``:
@@ -573,10 +614,10 @@ def forward_with_stats(params, tokens, cfg: LlamaConfig, pos_offset=0,
     if not cfg.rope:
         cos = sin = None
     elif isinstance(pos_offset, int) and pos_offset == 0:
-        cos, sin = _rope_tables(cfg.rope_theta, S, cfg.head_dim)
+        cos, sin = _rope_tables(cfg.rope_theta, S, cfg.rope_dim)
     else:
         cos_full, sin_full = _rope_tables(cfg.rope_theta, cfg.max_seq_len,
-                                          cfg.head_dim)
+                                          cfg.rope_dim)
         cos = jax.lax.dynamic_slice_in_dim(cos_full, pos_offset, S, axis=0)
         sin = jax.lax.dynamic_slice_in_dim(sin_full, pos_offset, S, axis=0)
 
@@ -589,29 +630,28 @@ def forward_with_stats(params, tokens, cfg: LlamaConfig, pos_offset=0,
 
         return _checkpoint(body, cfg) if cfg.remat else body
 
+    def run(kind, x, stack):
+        return jax.lax.scan(body_of(kind), x, stack)
+
     if isinstance(params["layers"], dict):
-        x, stats = jax.lax.scan(body_of(None), x, params["layers"])
+        x, stats = run(None, x, params["layers"])
     else:
         runs = _family(cfg).layer_runs(cfg)
         assert len(runs) == len(params["layers"]), (runs, len(params["layers"]))
         stats = []
         for (kind, _), stack in zip(runs, params["layers"]):
-            x, s = jax.lax.scan(body_of(kind), x, stack)
-            stats.append(s)
+            x, s = run(kind, x, stack)
+            if s is not None:       # a run of dense layers reports nothing
+                stats.append(s)
         stats = jax.tree.map(lambda *s: jnp.concatenate(s), *stats)
         _say_layer_plan(runs, body_of.cache_info().currsize)
     if mesh is not None and rules is not None:
         _say_tp_plan(tp, cfg, B, S)
+    hidden = x
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     if tp is not None:    # the head wants every row: one gather a step
         x = jax.lax.with_sharding_constraint(x, tp.gathered_sharding())
-    if "lm_head" in params:
-        logits = x @ _dq(params["lm_head"], dt)
-    else:                                   # tied to the embedding
-        logits = jnp.einsum("bsd,vd->bsv", x, _dq(params["embed"], dt))
-    if cfg.logits_scaling is not None:
-        logits = logits / cfg.logits_scaling
-    return (logits.astype(jnp.float32) if cfg.f32_logits else logits), stats
+    return _logits(params, x, cfg), stats, hidden, run
 
 
 def forward_sp(params, tokens, cfg: LlamaConfig, mesh):
@@ -676,8 +716,19 @@ def loss_fn(params, batch, cfg: LlamaConfig, mesh=None, rules=None):
     mesh+rules pin activation shardings in the dense path (required for
     HBM-tight FSDP configs; see _act_constraint). A scalar for the dense
     model; for a family with ``finish_loss`` (models/moe.py) the pair
-    ``(loss, aux)`` that ``parallel.make_train_step`` takes."""
-    if "tokens" in batch:
+    ``(loss, aux)`` that ``parallel.make_train_step`` takes. A family with
+    ``further_losses`` (multi-token prediction, models/latent.py) takes
+    {"tokens": [B, S+1+n]}: n more ids a sequence, the targets of its
+    further passes over the same S positions."""
+    further = getattr(_family(cfg), "further_losses", None)
+    if further is not None:
+        if set(batch) != {"tokens"}:
+            raise ValueError("a model that predicts further tokens takes "
+                             "{'tokens': [B, S + 1 + n]} and no mask")
+        S = batch["tokens"].shape[1] - 1 - cfg.n_mtp
+        inputs, targets = batch["tokens"][:, :S], batch["tokens"][:, 1:S + 1]
+        mask = None
+    elif "tokens" in batch:
         inputs, targets = batch["tokens"][:, :-1], batch["tokens"][:, 1:]
         mask = batch.get("mask")
         if mask is not None:
@@ -685,33 +736,45 @@ def loss_fn(params, batch, cfg: LlamaConfig, mesh=None, rules=None):
     else:
         inputs, targets = batch["inputs"], batch["targets"]
         mask = batch.get("mask")
-    stats = None
+    stats = hidden = run = None
     if (cfg.attn_impl in ("ring", "ulysses") and mesh is not None
             and int(mesh.shape.get("sp", 1)) > 1):
         logits = forward_sp(params, inputs, cfg, mesh)
     elif mesh is not None and int(mesh.shape.get("pp", 1)) > 1:
         logits = forward_pp(params, inputs, cfg, mesh)
     else:
-        logits, stats = forward_with_stats(params, inputs, cfg, mesh=mesh,
-                                           rules=rules)
+        logits, stats, hidden, run = _forward(params, inputs, cfg, mesh=mesh,
+                                              rules=rules)
     finish = getattr(_family(cfg), "finish_loss", None)
     if finish is not None and stats is None:
         raise ValueError("a model whose loss needs its layers' statistics "
                          "(router losses) does not train under sp or pp")
-    # nll = logsumexp(logits) - logit[target]: same value/gradient as
-    # log_softmax + gather but never materializes the [B, S, V] log_softmax
-    # tensor (1 GB f32 at B=8 S=1024 V=32k — pure HBM traffic).
+    loss = cross_entropy(logits, targets, mask)
+    if further is not None:
+        # the further passes' losses and their layers' statistics join stats
+        stats = further(params, batch["tokens"], hidden, stats, cfg, run)
+    # an expert model adds its router losses and returns (loss, aux)
+    return loss if finish is None else finish(loss, stats, cfg)
+
+
+def token_losses(logits, targets):
+    """Every position's cross-entropy, float32 [B, S].
+    nll = logsumexp(logits) - logit[target]: same value/gradient as
+    log_softmax + gather but never materializes the [B, S, V] log_softmax
+    tensor (1 GB f32 at B=8 S=1024 V=32k — pure HBM traffic)."""
     lse = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
     ll = jnp.take_along_axis(logits, targets[..., None],
                              axis=-1)[..., 0].astype(jnp.float32)
-    nll = lse - ll
+    return lse - ll
+
+
+def cross_entropy(logits, targets, mask=None):
+    """The mean of ``token_losses``, over the positions ``mask`` keeps."""
+    nll = token_losses(logits, targets)
     if mask is None:
-        loss = nll.mean()
-    else:
-        mask = mask.astype(nll.dtype)
-        loss = (nll * mask).sum() / jnp.maximum(mask.sum(), 1.0)
-    # an expert model adds its router losses and returns (loss, aux)
-    return loss if finish is None else finish(loss, stats, cfg)
+        return nll.mean()
+    mask = mask.astype(nll.dtype)
+    return (nll * mask).sum() / jnp.maximum(mask.sum(), 1.0)
 
 
 # --- inference (KV cache) ---------------------------------------------------
@@ -729,6 +792,8 @@ def _refuse_stated(cfg: LlamaConfig):
     stated = [f for f in ("embedding_multiplier", "residual_multiplier",
                           "logits_scaling", "attn_scale")
               if getattr(cfg, f) is not None] + ([] if cfg.rope else ["rope"])
+    if getattr(_family(cfg), "attention_half", None) is not None:
+        stated.append("an attention half of its own")
     if stated:
         raise NotImplementedError(
             f"a KV cache for a config that states {', '.join(stated)}: the "

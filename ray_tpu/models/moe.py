@@ -81,6 +81,14 @@ class MoEConfig(_ll.LlamaConfig):
     experts_held: Optional[Tuple[int, int]] = None
     # width of a SwiGLU every token passes beside its experts; 0: none
     shared_d_ff: int = 0
+    # what the router makes of its logits: "softmax" over all experts, the
+    # K largest kept; or "sigmoid" (DeepSeek-V3's): the K experts with the
+    # largest score PLUS a per-expert bias that no gradient reaches (the
+    # leaf ``router_bias``, moved by a rule after each step:
+    # models/latent.py ``post_update``), weighted by their scores WITHOUT it
+    router_score: str = "softmax"
+    # multiplies the K weights (DeepSeek's ``routed_scaling_factor``)
+    route_scale: float = 1.0
 
     @property
     def n_held(self) -> int:
@@ -116,6 +124,8 @@ def param_specs(cfg: MoEConfig) -> Dict[str, Any]:
         lay["q_norm"] = L + ("heads",)
         lay["k_norm"] = L + ("kv_heads",)
     lay["router"] = L + ("embed", "experts")
+    if cfg.router_score == "sigmoid":
+        lay["router_bias"] = L + ("experts",)
     lay["we_gate"] = L + ("experts", "embed", "expert_mlp")
     lay["we_up"] = L + ("experts", "embed", "expert_mlp")
     lay["we_down"] = L + ("experts", "expert_mlp", "embed")
@@ -140,6 +150,8 @@ def init_params(key, cfg: MoEConfig) -> Dict[str, Any]:
         lay["q_norm"] = jnp.ones((L, cfg.n_heads * cfg.head_dim), pd)
         lay["k_norm"] = jnp.ones((L, cfg.n_kv_heads * cfg.head_dim), pd)
     lay["router"] = jax.random.normal(ks[0], (L, D, E), pd) * 0.02
+    if cfg.router_score == "sigmoid":       # float32 whatever the weights are
+        lay["router_bias"] = jnp.zeros((L, E), jnp.float32)
     lay["we_gate"] = jax.random.normal(ks[1], (L, H, D, F), pd) * D ** -0.5
     lay["we_up"] = jax.random.normal(ks[2], (L, H, D, F), pd) * D ** -0.5
     lay["we_down"] = jax.random.normal(ks[3], (L, H, F, D), pd) * F ** -0.5
@@ -155,8 +167,9 @@ def init_params(key, cfg: MoEConfig) -> Dict[str, Any]:
 def num_params(cfg: MoEConfig) -> int:
     D, E, F = cfg.d_model, cfg.n_experts, cfg.d_ff
     qk = (cfg.n_heads + cfg.n_kv_heads) * cfg.head_dim if cfg.qk_norm else 0
+    bias = E if cfg.router_score == "sigmoid" else 0
     return _ll.num_params(cfg.replace(d_ff=0)) + cfg.n_layers * (
-        qk + D * E + 3 * cfg.n_held * D * F + 3 * D * cfg.shared_d_ff)
+        qk + D * E + bias + 3 * cfg.n_held * D * F + 3 * D * cfg.shared_d_ff)
 
 
 def _rows(tokens, k, order):
@@ -234,13 +247,26 @@ def _down_combine_bwd(impl, res, dy):
 _down_combine.defvjp(_down_combine_fwd, _down_combine_bwd)
 
 
-def route(logits, cfg: MoEConfig):
-    """Router logits [T, E] float32 -> (weights [T, K] float32, experts
-    [T, K] int32, probabilities [T, E])."""
-    probs = jax.nn.softmax(logits, axis=-1)
-    weights, experts = jax.lax.top_k(probs, cfg.top_k)
+def route(logits, cfg: MoEConfig, bias=None):
+    """Router logits [T, E] float32 (and, for the sigmoid router, its bias
+    [E] float32) -> (weights [T, K] float32, experts [T, K] int32, every
+    expert's score [T, E]: softmax probabilities, or sigmoids)."""
+    if cfg.router_score == "sigmoid":
+        probs = jax.nn.sigmoid(logits)
+        # the bias chooses and does not weigh; nothing is learned through it
+        _, experts = jax.lax.top_k(
+            probs + jax.lax.stop_gradient(bias.astype(jnp.float32)),
+            cfg.top_k)
+        weights = jnp.take_along_axis(probs, experts, axis=-1)
+    elif cfg.router_score == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+        weights, experts = jax.lax.top_k(probs, cfg.top_k)
+    else:
+        raise ValueError(f"unknown router_score {cfg.router_score!r}")
     if cfg.norm_topk:
         weights = weights / weights.sum(axis=-1, keepdims=True)
+    if cfg.route_scale != 1.0:
+        weights = weights * cfg.route_scale
     return weights, experts, probs
 
 
@@ -405,10 +431,12 @@ def _held_experts(x, weights, experts, lp, cfg: MoEConfig):
             counts, _passes(counts, rows, short))
 
 
-def feed_forward(h, lp, cfg: MoEConfig, mesh=None, rules=None, tp=None):
+def feed_forward(h, lp, cfg: MoEConfig, mesh=None, rules=None, tp=None,
+                 kind=None):
     """The expert layer: normed h [B, S, D] -> (its output [B, S, D],
     this layer's routing statistics for ``finish_loss``). It takes every
-    row of a sequence and no overlap plan (llama._tp_plan gives none)."""
+    row of a sequence and no overlap plan (llama._tp_plan gives none);
+    every kind of layer has the same."""
     assert tp is None, "the expert layer is not row-parallel"
     if cfg.gmm_impl == "pallas" and mesh is not None and mesh.size > 1:
         raise NotImplementedError(
@@ -422,7 +450,7 @@ def feed_forward(h, lp, cfg: MoEConfig, mesh=None, rules=None, tp=None):
     x = h.reshape(T, D)
     logits = jnp.dot(x, _ll._dq(lp["router"], dt),
                      preferred_element_type=jnp.float32)            # [T, E]
-    weights, experts, probs = route(logits, cfg)
+    weights, experts, probs = route(logits, cfg, lp.get("router_bias"))
     flat = experts.reshape(T * K)
     if cfg.experts_held is not None:
         y, held, more = _held_experts(x, weights, experts, lp, cfg)
@@ -448,15 +476,50 @@ def feed_forward(h, lp, cfg: MoEConfig, mesh=None, rules=None, tp=None):
 def _finish(y, stats, x, lp, cfg: MoEConfig, logits, experts, probs, shape):
     """The routed experts' y [T, D] plus the shared SwiGLU of x, where the
     config has one, in the layer's shape, and the layer's statistics."""
-    stats = {**stats, "prob_sum": probs.sum(axis=0),
-             "z_sum": jnp.square(jax.nn.logsumexp(logits, axis=-1)).sum(),
-             "experts": experts}
+    if cfg.router_score == "sigmoid":
+        stats = {**stats, "experts": experts,
+                 "balance": _sequence_balance(probs, experts, cfg, shape[0])}
+    else:
+        stats = {**stats, "prob_sum": probs.sum(axis=0),
+                 "z_sum": jnp.square(jax.nn.logsumexp(logits, axis=-1)).sum(),
+                 "experts": experts}
     if cfg.shared_d_ff:
         dt = cfg.dtype
         gate = jax.nn.silu(x @ _ll._dq(lp["ws_gate"], dt))
         y = y + (gate * (x @ _ll._dq(lp["ws_up"], dt))) @ _ll._dq(
             lp["ws_down"], dt)
     return y.reshape(shape), stats
+
+
+def _sequence_balance(scores, experts, cfg: MoEConfig, batch: int):
+    """DeepSeek-V3's sequence-wise balance loss of one layer, averaged over
+    the batch's sequences: for a sequence of S tokens sum_i f_i P_i, f_i =
+    E / (K S) x its assignments to expert i, P_i the mean over its tokens
+    of s_i / sum_j s_j. scores [T, E], experts [T, K], T = batch x S."""
+    E, K = cfg.n_experts, cfg.top_k
+    S = scores.shape[0] // batch
+    share = (scores / scores.sum(axis=-1, keepdims=True)).reshape(
+        batch, S, E).mean(axis=1)                                  # P [B, E]
+    counts = jax.vmap(lambda ids: _count(ids, E))(
+        experts.reshape(batch, S * K))                             # [B, E]
+    return jnp.mean(jnp.sum(counts.astype(jnp.float32) * (E / (K * S))
+                            * share, axis=-1))
+
+
+def held_aux(held, stats, per_layer: int):
+    """What a step reports of a chip's share of the experts: held [L, held]
+    the held experts' counts in float32, per_layer = T x K assignments."""
+    return {
+        # the largest held expert over the held experts' mean, worst layer
+        "moe_load_max_over_mean": jnp.max(
+            held.max(axis=1) * held.shape[1]
+            / jnp.maximum(held.sum(axis=1), 1.0)),
+        "moe_held_rows_share": held.sum() / (held.shape[0] * per_layer),
+        # passes beyond the first that the held experts' rows took
+        "moe_held_more_passes":
+            stats["more_passes"].sum().astype(jnp.float32),
+        # every assignment to a held expert is computed (_held_experts)
+        "moe_dropped": jnp.zeros((), jnp.int32)}
 
 
 def finish_loss(loss, stats, cfg: MoEConfig):
@@ -476,16 +539,7 @@ def finish_loss(loss, stats, cfg: MoEConfig):
         held = stats["held_counts"].astype(jnp.float32)        # [L, held]
         return loss + cfg.router_aux_weight * aux + cfg.router_z_weight * z, {
             "moe_aux_loss": aux, "moe_z_loss": z,
-            # the largest held expert over the held experts' mean, worst layer
-            "moe_load_max_over_mean": jnp.max(
-                held.max(axis=1) * held.shape[1]
-                / jnp.maximum(held.sum(axis=1), 1.0)),
-            "moe_held_rows_share": held.sum() / (counts.shape[0] * per_layer),
-            # passes beyond the first that the held experts' rows took
-            "moe_held_more_passes":
-                stats["more_passes"].sum().astype(jnp.float32),
-            # every assignment to a held expert is computed (_held_experts)
-            "moe_dropped": jnp.zeros((), jnp.int32)}
+            **held_aux(held, stats, per_layer)}
     return (loss + cfg.router_aux_weight * aux + cfg.router_z_weight * z, {
         "moe_aux_loss": aux, "moe_z_loss": z,
         "moe_load_max_over_mean":

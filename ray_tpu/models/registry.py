@@ -14,6 +14,7 @@ _FAMILIES = {
     "gpt2": "ray_tpu.models.gpt2",
     "moe": "ray_tpu.models.moe",
     "hybrid": "ray_tpu.models.hybrid",
+    "latent": "ray_tpu.models.latent",
     "vit": "ray_tpu.models.vit",
 }
 

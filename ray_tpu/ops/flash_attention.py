@@ -3,14 +3,23 @@
 Forward: one kernel instance per (batch, head, q-block); the q-block stays in
 VMEM while K/V stream through in chunks with the online-softmax recurrence —
 O(S) memory instead of O(S^2), and the QK^T / PV matmuls hit the MXU at
-[block_q x head_dim] x [head_dim x block_k] granularity.
+[block_q x head_dim] x [head_dim x block_k] granularity. Two kernels, told
+apart by the shape alone (`kv_plan`; the instant `flash.fwd_plan`): `loop`
+holds a head's whole K and V as blocks and walks them itself, where those
+blocks, double-buffered, and a step's temporaries fit the 16 MiB a Mosaic
+call gets that asks for no more (S 8192 at D 128); `stream` has the
+k-blocks on a grid axis, O(block) VMEM at any S and D (S 8192 at D 256:
+the loop's blocks alone are 16 MiB), and serves every windowed call.
 
 Backward: full Pallas two-kernel backward (FlashAttention-2 style), both
 recomputing probabilities from the saved log-sum-exp so nothing O(S^2) is
 ever materialized. The dQ pass keeps a q-block resident and loops over
-k-blocks. The dK/dV pass is its mirror image and has two block plans, told
-apart by the shape alone (`bwd_dkdv_plan`; the choice is the instant
-`flash.bwd_plan` of a trace):
+k-blocks: of a head's whole K and V held as blocks (`loop`, chosen by the
+forward's bytes) or with the k-blocks on a grid axis, clamped into the
+band, dq accumulated in the float32 output block (`stream`); both run
+`_dq_step` in the same order and agree to the last bit. The dK/dV pass is
+its mirror image and has two block plans, told apart by the shape alone
+(`bwd_dkdv_plan`; the choices are the instant `flash.bwd_plan` of a trace):
   resident  one instance per (b, h, k-block); q, dO, o and lse of the
             whole head are blocks whose index is constant in the k axis,
             so they are fetched once a head, and the kernel loops over the
@@ -228,14 +237,49 @@ def _fwd_kernel_stream(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr,
         lse_ref[0, 0] = m_scr[...] + jnp.log(l)
 
 
+# VMEM a Mosaic call gets on a v5e core when it asks for no limit of its own
+_SCOPED_VMEM_BYTES = 16 * 2 ** 20
+
+
+def kv_plan(*, S: int, T: int, D: int, dtype, block_q: int,
+            block_k: int) -> dict:
+    """Which kernel a call that walks k-blocks for a resident q-block takes
+    (the forward, the dQ pass), and the bytes that decide it (also the
+    attributes of `flash.fwd_plan`). loop: K and V of a whole head are
+    blocks of the call, fetched once a head; taken where those blocks,
+    double-buffered by Mosaic (`kv_block_bytes`), with the q-side blocks
+    and the f32 temporaries of one step fit the VMEM a call gets without
+    asking (the compiler refused S 8192 x D 256 at 17.5 MiB of 16); stream
+    otherwise. Asking for more is not the way: a call's limit is taken out
+    of XLA's own fast memory for as long as the call is scheduled
+    (`bwd_dkdv_plan`)."""
+    itemsize = jnp.dtype(dtype).itemsize
+    kv_block_bytes = 2 * 2 * T * D * itemsize
+    loop_bytes = (
+        kv_block_bytes
+        # q, dO, o, the result and the 128-lane lse, double-buffered
+        + 2 * block_q * (4 * D * itemsize + _LSE_LANES * 4)
+        # two [block_q, block_k] of s and p; the accumulator and a k-block
+        + 4 * (2 * block_q * block_k + (block_q + block_k) * D))
+    return dict(path="loop" if loop_bytes <= _SCOPED_VMEM_BYTES else "stream",
+                S=S, D=D, kv_block_bytes=kv_block_bytes,
+                loop_bytes=loop_bytes)
+
+
 def _flash_fwd(q, k, v, *, causal: bool, block_q: int, block_k: int,
                scale: float, window: int = 0):
-    if window <= 0:
+    B, S, H, D = q.shape
+    plan = kv_plan(S=S, T=k.shape[1], D=D, dtype=k.dtype,
+                   block_q=min(block_q, S), block_k=min(block_k, k.shape[1]))
+    if window > 0:
+        plan["path"] = "stream"     # never fetches k-blocks out of the band
+    tracing.instant("flash.fwd_plan", {
+        n: plan[n] for n in ("path", "S", "D", "kv_block_bytes")})
+    if plan["path"] == "loop":
         # plain causal/full: the q-block loop kernel has 1/num_k the
         # grid steps — faster where per-step overhead dominates
         return _flash_fwd_loop(q, k, v, causal=causal, block_q=block_q,
                                block_k=block_k, scale=scale)
-    B, S, H, D = q.shape
     T, KV = k.shape[1], k.shape[2]
     groups = H // KV
     # layout: [B, H, S, D] per-instance slices
@@ -291,13 +335,48 @@ def _flash_fwd(q, k, v, *, causal: bool, block_q: int, block_k: int,
     return out.transpose(0, 2, 1, 3), lse
 
 
+def _dq_step(q, k, v, g, lse, delta, q_pos, ki, *, block_k: int, scale: float,
+             causal: bool, window: int):
+    """What k-block `ki` adds to the dQ of a q-block, [block_q, D] f32,
+    from blocks already cast to f32 (lse, delta and the rows' positions
+    q_pos [block_q, 1]). The one accumulate step of both dQ kernels."""
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    if causal:
+        k_pos = ki * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (1, block_k), 1)
+        keep = q_pos >= k_pos
+        if window > 0:
+            keep = keep & (q_pos - k_pos < window)
+        s = jnp.where(keep, s, NEG_INF)
+    p = jnp.exp(s - lse)
+    dp = jax.lax.dot_general(g, v, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    ds = p * (dp - delta) * scale
+    return jax.lax.dot(ds, k, preferred_element_type=jnp.float32)
+
+
+def _k_band(qi, *, num_k: int, block_q: int, block_k: int, causal: bool,
+            window: int):
+    """[lo, hi): the k-blocks that hold a key a row of q-block `qi` sees
+    under the causal mask (and the window)."""
+    if not causal:
+        return 0, num_k
+    div, _, most = _index_ops(qi)
+    hi = div((qi + 1) * block_q + block_k - 1, block_k)
+    lo = 0
+    if window > 0:
+        lo = most(0, div(qi * block_q - window + 1, block_k))
+    return lo, hi
+
+
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, o_ref, lse_ref, dq_ref, *,
                    block_k: int, scale: float, causal: bool, window: int):
-    """One instance per (b, h, q-block): stream K/V, accumulate dQ
+    """One instance per (b, h, q-block): K/V of the whole head are its
+    blocks, dQ accumulates over the k-blocks of the band
     (FlashAttention-2 backward, dQ pass). delta = rowsum(o * dO) is
     computed in-kernel from the resident blocks."""
     block_q, D = q_ref.shape[2], q_ref.shape[3]
-    T = k_ref.shape[2]
     qi = pl.program_id(2)
     q = q_ref[0, 0].astype(jnp.float32)
     g = g_ref[0, 0].astype(jnp.float32)
@@ -309,32 +388,48 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, o_ref, lse_ref, dq_ref, *,
     def body(ki, dq):
         k = k_ref[0, 0, pl.ds(ki * block_k, block_k), :].astype(jnp.float32)
         v = v_ref[0, 0, pl.ds(ki * block_k, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if causal:
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (1, block_k), 1)
-            keep = q_pos >= k_pos
-            if window > 0:
-                keep = keep & (q_pos - k_pos < window)
-            s = jnp.where(keep, s, NEG_INF)
-        p = jnp.exp(s - lse)
-        dp = jax.lax.dot_general(g, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale
-        return dq + jax.lax.dot(ds, k, preferred_element_type=jnp.float32)
+        return dq + _dq_step(q, k, v, g, lse, delta, q_pos, ki,
+                             block_k=block_k, scale=scale, causal=causal,
+                             window=window)
 
-    start_k = 0
-    if causal:
-        num_k = jax.lax.div((qi + 1) * block_q + block_k - 1, block_k)
-        if window > 0:
-            start_k = jax.lax.max(
-                0, jax.lax.div(qi * block_q - window + 1, block_k))
-    else:
-        num_k = T // block_k
-    dq = jax.lax.fori_loop(start_k, num_k,
-                           body, jnp.zeros((block_q, D), jnp.float32))
+    lo, hi = _k_band(qi, num_k=k_ref.shape[2] // block_k, block_q=block_q,
+                     block_k=block_k, causal=causal, window=window)
+    dq = jax.lax.fori_loop(lo, hi, body, jnp.zeros((block_q, D), jnp.float32))
     dq_ref[0, 0] = dq.astype(dq_ref.dtype)
+
+
+def _bwd_dq_stream_kernel(q_ref, k_ref, v_ref, g_ref, o_ref, lse_ref, dq_ref,
+                          *, num_k: int, scale: float, causal: bool,
+                          window: int):
+    """Grid (b, h, q-block, k-block): the f32 dq output block is constant
+    in the (minor) k axis, so Mosaic keeps it resident and this accumulates
+    across sequential k steps, in the loop kernel's order: O(block) VMEM at
+    any sequence length and head width. K and V arrive one block a step
+    through an index map clamped into the band (`_flash_bwd_dq`), so a step
+    outside it fetches nothing."""
+    block_q, block_k = q_ref.shape[2], k_ref.shape[2]
+    qi = pl.program_id(2)
+    ki = pl.program_id(3)
+
+    @pl.when(ki == 0)
+    def _zero():
+        dq_ref[0, 0] = jnp.zeros_like(dq_ref[0, 0])
+
+    lo, hi = _k_band(qi, num_k=num_k, block_q=block_q, block_k=block_k,
+                     causal=causal, window=window)
+
+    @pl.when((ki >= lo) & (ki < hi))
+    def _accumulate():
+        g = g_ref[0, 0].astype(jnp.float32)
+        delta = jnp.sum(o_ref[0, 0].astype(jnp.float32) * g, axis=-1,
+                        keepdims=True)
+        q_pos = qi * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, 1), 0)
+        dq_ref[0, 0] += _dq_step(
+            q_ref[0, 0].astype(jnp.float32), k_ref[0, 0].astype(jnp.float32),
+            v_ref[0, 0].astype(jnp.float32), g, lse_ref[0, 0][:, 0:1], delta,
+            q_pos, ki, block_k=block_k, scale=scale, causal=causal,
+            window=window)
 
 
 def _dkdv_step(q, k, v, g, o, lse, qi, ki, *, block_q: int, block_k: int,
@@ -541,7 +636,7 @@ def bwd_dkdv_plan(*, S: int, T: int, D: int, dtype, groups: int,
 
 def _flash_bwd_dkdv(qt, kt, vt, gt, ot, lse, *, causal: bool, block_q: int,
                     block_k: int, window: int, scale: float = None,
-                    vmem_bytes: int = None):
+                    vmem_bytes: int = None, dq_path: str = None):
     """The dK/dV call on [B, H|KV, S|T, D] operands (lse [B, H, S, 128]):
     per-query-head dK and dV, [B, H, T, D]."""
     B, H, S, D = qt.shape
@@ -553,8 +648,9 @@ def _flash_bwd_dkdv(qt, kt, vt, gt, ot, lse, *, causal: bool, block_q: int,
         block_q=block_q, block_k=block_k, causal=causal, window=window,
         vmem_bytes=vmem_bytes or _vmem_bytes())
     tracing.instant("flash.bwd_plan", {
-        k: plan[k] for k in ("path", "S", "block_q", "block_k", "window",
-                             "resident_bytes", "hbm_bytes_per_head")})
+        **{k: plan[k] for k in ("path", "S", "block_q", "block_k", "window",
+                                "resident_bytes", "hbm_bytes_per_head")},
+        **({"dq_path": dq_path} if dq_path else {})})
     kernel_args = dict(block_q=block_q, scale=_scale(scale, D),
                        causal=causal, window=window)
     if plan["path"] == "resident":
@@ -601,6 +697,60 @@ def _flash_bwd_dkdv(qt, kt, vt, gt, ot, lse, *, causal: bool, block_q: int,
     )(qt, kt, vt, gt, ot, lse)
 
 
+def _flash_bwd_dq(qt, kt, vt, gt, ot, lse, *, path: str, causal: bool,
+                  block_q: int, block_k: int, window: int, scale: float):
+    """The dQ call on [B, H|KV, S|T, D] operands (lse [B, H, S, 128]), by
+    the plan `kv_plan` chose: [B, H, S, D] in q's dtype."""
+    B, H, S, D = qt.shape
+    T, groups = kt.shape[2], H // kt.shape[1]
+    if path == "loop":
+        q_blk = pl.BlockSpec((1, 1, block_q, D), lambda b, h, i: (b, h, i, 0))
+        kv_spec = pl.BlockSpec((1, 1, T, D),
+                               lambda b, h, i, g_=groups: (b, h // g_, 0, 0))
+        return pl.pallas_call(
+            functools.partial(_bwd_dq_kernel, block_k=block_k, scale=scale,
+                              causal=causal, window=window),
+            grid=(B, H, S // block_q),
+            in_specs=[
+                q_blk,
+                kv_spec,
+                kv_spec,
+                q_blk,
+                q_blk,
+                pl.BlockSpec((1, 1, block_q, 128),
+                             lambda b, h, i: (b, h, i, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, 1, block_q, D),
+                                   lambda b, h, i: (b, h, i, 0)),
+            out_shape=jax.ShapeDtypeStruct((B, H, S, D), qt.dtype),
+            interpret=_use_interpret(),
+        )(qt, kt, vt, gt, ot, lse)
+    num_k = T // block_k
+
+    def kv_idx(b, h, qi, ki):
+        lo, hi = _k_band(qi, num_k=num_k, block_q=block_q, block_k=block_k,
+                         causal=causal, window=window)
+        if causal:      # a step outside the band repeats its near edge
+            ki = jax.lax.max(jax.lax.min(ki, hi - 1), lo)
+        return (b, h // groups, ki, 0)
+
+    def q_side(width):
+        return pl.BlockSpec((1, 1, block_q, width),
+                            lambda b, h, qi, ki: (b, h, qi, 0))
+
+    kv_blk = pl.BlockSpec((1, 1, block_k, D), kv_idx)
+    return pl.pallas_call(
+        functools.partial(_bwd_dq_stream_kernel, num_k=num_k, scale=scale,
+                          causal=causal, window=window),
+        grid=(B, H, S // block_q, num_k),
+        in_specs=[q_side(D), kv_blk, kv_blk, q_side(D), q_side(D),
+                  q_side(_LSE_LANES)],
+        out_specs=q_side(D),
+        out_shape=jax.ShapeDtypeStruct((B, H, S, D), jnp.float32),
+        interpret=_use_interpret(),
+    )(qt, kt, vt, gt, ot, lse).astype(qt.dtype)
+
+
 def _flash_pallas_bwd(res, g, *, causal: bool, block_q: int, block_k: int,
                       scale: float, window: int = 0):
     """Full Pallas backward: two kernels (dQ; dK/dV), GQA group-sum on the
@@ -620,30 +770,14 @@ def _flash_pallas_bwd(res, g, *, causal: bool, block_q: int, block_k: int,
     gt = g.transpose(0, 2, 1, 3)
     ot = out.transpose(0, 2, 1, 3)
 
-    q_blk = pl.BlockSpec((1, 1, block_q, D), lambda b, h, i: (b, h, i, 0))
-    kv_spec = pl.BlockSpec((1, 1, T, D),
-                           lambda b, h, i, g_=groups: (b, h // g_, 0, 0))
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, block_k=block_k, scale=scale,
-                          causal=causal, window=window),
-        grid=(B, H, S // block_q),
-        in_specs=[
-            q_blk,
-            kv_spec,
-            kv_spec,
-            q_blk,
-            q_blk,
-            pl.BlockSpec((1, 1, block_q, 128), lambda b, h, i: (b, h, i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, block_q, D),
-                               lambda b, h, i: (b, h, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
-        interpret=_use_interpret(),
-    )(qt, kt, vt, gt, ot, lse)
-
+    dq_path = kv_plan(S=S, T=T, D=D, dtype=k.dtype, block_q=block_q,
+                      block_k=block_k)["path"]
+    dq = _flash_bwd_dq(qt, kt, vt, gt, ot, lse, path=dq_path, causal=causal,
+                       block_q=block_q, block_k=block_k, window=window,
+                       scale=scale)
     dk, dv = _flash_bwd_dkdv(qt, kt, vt, gt, ot, lse, causal=causal,
                              block_q=block_q, block_k=block_k, window=window,
-                             scale=scale)
+                             scale=scale, dq_path=dq_path)
     if groups > 1:
         # GQA: sum per-query-head contributions into each kv head.
         dk = dk.reshape(B, KV, groups, T, D).sum(2)

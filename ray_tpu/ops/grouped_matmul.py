@@ -28,7 +28,14 @@ and up to 7.7 with tk 512; ``tgmm`` at (256, 1024, 1024),
 4.03 ms (136 TFLOP/s), its larger tiles run out of VMEM. XLA's
 ``ragged_dot`` takes 5.13-5.55 ms forward and 12.2 ms for both gradients
 of one matrix. A tile size is cut to the dimension where the dimension is
-smaller; M must be a multiple of tm.
+smaller; M must be a multiple of tm. Two rules for widths the sweep did not
+see (``_fit``; GLM-4.7-Flash's [2048, 1536]): a K or N that is no whole
+number of tiles is split into equal tiles of whole lanes (1536 under 1024:
+two of 768; megablox would run a second, half-empty tile), and ``gmm``'s N
+tile is halved while its tiles (both operands double-buffered, the result
+and its float32 accumulator) pass the 15 MiB the sweep held to (2048 x
+1536 whole is 16.7 MiB, and Mosaic refuses it). Every shape of the sweep
+keeps its tiles.
 """
 
 from __future__ import annotations
@@ -47,13 +54,30 @@ def _use_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _fit(tiling, m, k, n):
+_TILE_VMEM_BYTES = 15 * 2 ** 20
+
+
+def _even(tile: int, dim: int) -> int:
+    """The tile for ``dim``: ``tile`` cut to it, or, where ``dim`` is no
+    whole number of those, ``dim`` in as many equal tiles of whole lanes."""
+    tile = min(tile, dim)
+    parts = -(-dim // tile)
+    even = dim // parts
+    return even if dim % parts == 0 and even % 128 == 0 else tile
+
+
+def _fit(tiling, m, k, n, itemsize: int = 2, halve_n: bool = False):
     tm, tk, tn = tiling
     tm = min(tm, m)
     if m % tm:
         raise ValueError(f"grouped matmul: {m} rows are no multiple of the "
                          f"row tile {tm}")
-    return tm, min(tk, k), min(tn, n)
+    tk, tn = _even(tk, k), _even(tn, n)
+    while halve_n and tn % 256 == 0 and (
+            2 * (tm * tk + tk * tn) * itemsize
+            + tm * tn * (itemsize + 4)) > _TILE_VMEM_BYTES:
+        tn //= 2
+    return tm, tk, tn
 
 
 def _backend():
@@ -69,7 +93,8 @@ def _gmm(lhs, rhs, group_sizes, transpose_rhs=False):
     n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
     return _backend().gmm(
         lhs, rhs, group_sizes, preferred_element_type=lhs.dtype,
-        tiling=_fit(GMM_TILING, lhs.shape[0], lhs.shape[1], n),
+        tiling=_fit(GMM_TILING, lhs.shape[0], lhs.shape[1], n,
+                    lhs.dtype.itemsize, halve_n=True),
         transpose_rhs=transpose_rhs, interpret=_use_interpret())
 
 

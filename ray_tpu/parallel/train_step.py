@@ -106,14 +106,39 @@ def make_train_state_init(init_params_fn: Callable, optimizer, mesh,
     return init_fn, state_sh
 
 
+def hold_out(optimizer, names: Tuple[str, ...]):
+    """``optimizer`` over every leaf but those whose key in their dict is
+    one of ``names``: those get an update of zero and no optimizer state,
+    whatever their gradient. For state that a rule of the model's moves
+    (``make_train_step``'s ``post_update``) and no gradient reaches."""
+    import optax
+
+    def labels(params):
+        return jax.tree_util.tree_map_with_path(
+            lambda path, _: "rule" if getattr(path[-1], "key", None) in names
+            else "optimizer", params)
+
+    return optax.multi_transform(
+        {"optimizer": optimizer, "rule": optax.set_to_zero()}, labels)
+
+
 def make_train_step(loss_fn: Callable, optimizer, mesh, rules: ShardingRules,
-                    state_shardings, batch_shapes=None, donate: bool = True):
+                    state_shardings, batch_shapes=None, donate: bool = True,
+                    post_update: Optional[Callable] = None):
     """loss_fn(params, batch) -> scalar loss, or -> (loss, aux) with
     ``aux`` a dict of scalars that the step hands on (an expert model's
     routing statistics, models/moe.py ``finish_loss``). Returns jitted
     step(state, batch) -> (state, metrics); metrics holds ``loss``,
     ``grad_norm``, ``step`` and every key of ``aux``; a scalar loss gives
-    the program it always gave."""
+    the program it always gave.
+
+    ``post_update(params, aux) -> (params, aux)`` is a rule of the model's
+    that changes parameters no gradient reaches from what the step's
+    ``aux`` carries (a router's bias from the step's counts,
+    models/latent.py): it runs after the optimizer's update, inside the
+    same jitted step, and takes out of ``aux`` what is no scalar to hand
+    on. The optimizer is told to leave those leaves alone (``hold_out``).
+    Without a rule the program is the one it was."""
     batch_sh = (batch_sharding(mesh, rules, batch_shapes)
                 if batch_shapes is not None else None)
 
@@ -128,6 +153,8 @@ def make_train_step(loss_fn: Callable, optimizer, mesh, rules: ShardingRules,
                                               state.params)
         params = jax.tree.map(lambda p, u: p + u.astype(p.dtype),
                               state.params, updates)
+        if post_update is not None:
+            params, aux = post_update(params, aux)
         gnorm = optax_global_norm(grads)
         metrics = {**aux, "loss": loss, "grad_norm": gnorm,
                    "step": state.step + 1}

@@ -22,6 +22,8 @@ ROOT = os.path.join(os.path.dirname(os.path.abspath(__file__)), os.pardir)
 RECIPE_KEYS = {"train": ("attn_impl", "remat", "f32_logits"),
                "train_moe": ("attn_impl", "gmm_impl", "remat", "f32_logits"),
                "train_hybrid": ("attn_impl", "gmm_impl", "ssd_impl", "remat",
+                                "f32_logits"),
+               "train_latent": ("attn_impl", "gmm_impl", "remat",
                                 "f32_logits")}
 # published config.json key -> the program's field
 WIDTHS = {"hidden_size": "d_model", "num_hidden_layers": "n_layers",
@@ -47,18 +49,20 @@ def test_cell_program_config_builds_at_its_published_widths(name):
     import jax
     import jax.numpy as jnp
 
-    from benchmark import model, model_granite, model_moe, resolve
+    from benchmark import model, model_glm, model_granite, model_moe, resolve
 
     cell = resolve.cell(name)
     kind, conf, recipe = cell["kind"], cell["config"], cell["train"]
     build = {"train": model.llama_config,
              "train_moe": model_moe.moe_config,
-             "train_hybrid": model_granite.hybrid_config}[kind]
+             "train_hybrid": model_granite.hybrid_config,
+             "train_latent": model_glm.latent_config}[kind]
     passed = {k: recipe[k] for k in RECIPE_KEYS[kind] if k in recipe}
     cfg = build(conf, **passed)
 
     widths = {"train": WIDTHS, "train_moe": {**WIDTHS, **MOE_WIDTHS},
-              "train_hybrid": model_granite.HF_TO_FIELD}[kind]
+              "train_hybrid": model_granite.HF_TO_FIELD,
+              "train_latent": model_glm.HF_TO_FIELD}[kind]
     for key, field in widths.items():
         assert getattr(cfg, field) == conf[key], (name, key)
     if kind == "train_hybrid":
@@ -68,6 +72,21 @@ def test_cell_program_config_builds_at_its_published_widths(name):
         assert cfg.experts_held == (conf["num_local_experts"],
                                     dep["experts_first"])
         assert cfg.kinds == tuple(conf["layer_types"][:cfg.n_layers])
+    if kind == "train_latent":
+        # the latents' ranks, the three head widths, the leading dense
+        # layers and the prediction modules are the published keys' (the
+        # map above); the router's width and the experts held the
+        # deployment's
+        assert {"q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+                "qk_rope_head_dim", "v_head_dim", "first_k_dense_replace",
+                "num_nextn_predict_layers"} <= set(widths)
+        dep = conf["deployment"]
+        assert cfg.n_experts == dep["router_experts"]
+        assert cfg.experts_held == (conf["n_routed_experts"],
+                                    dep["experts_first"])
+        assert cfg.head_dim == cfg.qk_nope_dim + cfg.qk_rope_dim == cfg.v_dim
+        assert cfg.router_score == "sigmoid"
+        assert cfg.shared_d_ff == conf["n_shared_experts"] * cfg.d_ff
     for key, value in passed.items():
         assert getattr(cfg, key) == value, (name, key)
     assert cfg.dtype == getattr(jnp, conf["run"]["dtype"])
@@ -78,7 +97,10 @@ def test_cell_program_config_builds_at_its_published_widths(name):
     mix = cell["mix"]
     params = jax.eval_shape(
         lambda: mod.init_params(jax.random.PRNGKey(0), cfg))
-    tokens = jax.ShapeDtypeStruct((mix["batch"], mix["seq"] + 1), jnp.int32)
+    # a model that predicts further tokens takes as many more ids
+    more = 1 + getattr(cfg, "n_mtp", 0)
+    tokens = jax.ShapeDtypeStruct((mix["batch"], mix["seq"] + more),
+                                  jnp.int32)
     out = jax.eval_shape(lambda p, t: mod.loss_fn(p, {"tokens": t}, cfg),
                          params, tokens)
     loss = out[0] if isinstance(out, tuple) else out

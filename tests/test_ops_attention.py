@@ -428,8 +428,8 @@ def test_dkdv_stream_grid_fetches_only_its_band(window, block_q, block_k):
 
 def test_flash_bwd_plan_instant_once_a_trace(monkeypatch):
     """The plan is chosen while the backward is traced, and says so once:
-    one `flash.bwd_plan` instant a compile, none when the compiled
-    program runs again."""
+    one `flash.fwd_plan` and one `flash.bwd_plan` instant a compile, none
+    when the compiled program runs again."""
     fa = _fa()
     seen = []
     monkeypatch.setattr(fa.tracing, "instant",
@@ -440,12 +440,17 @@ def test_flash_bwd_plan_instant_once_a_trace(monkeypatch):
         *a, block_q=32, block_k=32).sum(), argnums=(0, 1, 2)))
     jax.block_until_ready(grad(q, k, v))
     jax.block_until_ready(grad(q, k, v))
-    assert [name for name, _ in seen] == ["flash.bwd_plan"]
-    attrs = seen[0][1]
+    # the forward's kernel is chosen where the forward is traced, and says
+    # so beside it (`flash.fwd_plan`); the backward's instant names the dQ
+    # call's plan too
+    assert [name for name, _ in seen] == ["flash.fwd_plan", "flash.bwd_plan"]
+    assert seen[0][1] == {"path": "loop", "S": 128, "D": 32,
+                          "kv_block_bytes": 2 * 2 * 128 * 32 * 4}
+    attrs = seen[1][1]
     assert attrs == {
         "path": "resident", "S": 128, "block_q": 32, "block_k": 32,
         "window": 0, "resident_bytes": attrs["resident_bytes"],
         "hbm_bytes_per_head": fa.hbm_bytes_per_head(
             "resident", S=128, T=128, D=32, block_q=32, block_k=32,
-            itemsize=4, out_itemsize=4)}
+            itemsize=4, out_itemsize=4), "dq_path": "loop"}
     assert all(isinstance(x, (int, str)) for x in attrs.values())

@@ -159,30 +159,40 @@ def test_flash_calls_keep_their_face_in_the_trace(one_chip, on_chip_branch):
     assert faces["resident"] != faces["stream"]
 
 
-def test_grouped_matmul_compiles_at_olmoe_widths(one_chip, on_chip_branch):
-    """The expert layer's Mosaic calls at OLMoE-1B-7B's shapes (131,072
-    routed rows, 64 experts of 2048 x 1024) with the tiles
+# (rows, experts, model width, one expert's width) of a cell's grouped
+# matmuls: OLMoE-1B-7B's 131,072 routed rows over 64 experts of 2048 x 1024;
+# GLM-4.7-Flash's one pass of 16,384 rows over the 8 experts held, 2048 x
+# 1536 (no whole number of the N tiles; whole, the forward's tiles pass
+# the VMEM a call gets: ``ops/grouped_matmul.py`` ``_fit``)
+GMM_WIDTHS = {"olmoe": (131072, 64, 2048, 1024),
+              "glm": (16384, 8, 2048, 1536)}
+
+
+@pytest.mark.parametrize("model", sorted(GMM_WIDTHS))
+def test_grouped_matmul_compiles_at_the_cells_widths(model, one_chip,
+                                                     on_chip_branch):
+    """The expert layer's Mosaic calls with the tiles
     ops/grouped_matmul.py names: forward, input gradient, weight gradient."""
     import jax
     import jax.numpy as jnp
 
     from ray_tpu.ops.grouped_matmul import grouped_matmul
 
-    rows, e, d, f = 131072, 64, 2048, 1024
+    rows, e, d, f = GMM_WIDTHS[model]
     x = _sds((rows, d), jnp.bfloat16, one_chip)
     w = _sds((e, d, f), jnp.bfloat16, one_chip)
     sizes = _sds((e,), jnp.int32, one_chip)
 
     def loss(x, w, sizes):
-        return grouped_matmul(x, w, sizes, impl="pallas").astype(
-            jnp.float32).sum()
+        y = grouped_matmul(x, w, sizes, impl="pallas").astype(jnp.float32)
+        return jnp.sum(y * y)               # wants the forward's product too
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
         x, w, sizes).compile().as_text()
-    # input gradient (gmm with the matrices transposed) and weight gradient
-    # (tgmm); the forward's product is not needed for a sum's gradient
-    assert text.count("tpu_custom_call") >= 2, text[:2000]
-    assert "bf16[64,2048,1024]" in text
+    # forward, input gradient (gmm with the matrices transposed) and
+    # weight gradient (tgmm)
+    assert text.count("tpu_custom_call") >= 3, text[:2000]
+    assert f"bf16[{e},{d},{f}]" in text
 
 
 def test_ssd_scan_compiles_at_granite_widths(one_chip, on_chip_branch):
@@ -329,6 +339,72 @@ def test_a_mixers_passes_at_granite_widths(one_chip, on_chip_branch):
         "copy", "slice", "broadcast", "convert", "transpose", "pad",
         "concatenate"))
     assert alone < 1.0e9, alone
+
+
+def test_latent_attention_block_at_glm_widths_streams_and_fits(
+        one_chip, on_chip_branch, monkeypatch):
+    """One latent-attention half at the GLM-4.7-Flash cell's widths (B2 x
+    S8192, 20 heads of 192 + 64 over latents of 768 and 512), forward,
+    replay under ``jax.checkpoint`` and backward, compiled for the chip:
+    all three flash calls take their streaming plan by the bytes alone
+    (``flash.fwd_plan``, ``flash.bwd_plan``), Mosaic accepts them in the
+    VMEM a call gets without asking, and the calls keep the face the
+    readers know them by (``benchmark/readers/glm_kernel_roofline.py``).
+    The loop kernel at this shape is what the compiler refuses."""
+    import sys
+
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark.readers import kernel_roofline
+    from ray_tpu.models import latent, llama
+
+    fa = sys.modules["ray_tpu.ops.flash_attention"]
+    seen = []
+    monkeypatch.setattr(fa.tracing, "instant",
+                        lambda name, attrs=None, **kw: seen.append(
+                            (name, attrs)))
+    bf = jnp.bfloat16
+    cfg = latent.LatentConfig(
+        vocab_size=19360, d_model=2048, n_layers=2, n_heads=20, n_kv_heads=20,
+        d_ff=1536, n_experts=64, top_k=4, experts_held=(8, 0),
+        shared_d_ff=1536, q_rank=768, kv_rank=512, qk_nope_dim=192,
+        qk_rope_dim=64, v_dim=256, dense_d_ff=10240, rope_theta=1e6,
+        attn_impl="flash", dtype=bf, param_dtype=bf)
+    B, S = 2, 8192
+    stack = jax.eval_shape(
+        lambda: latent.init_params(jax.random.PRNGKey(0), cfg))["layers"][0]
+    lp = {k: _sds(stack[k].shape[1:], bf, one_chip) for k in (
+        "attn_norm", "wq_a", "q_a_norm", "wq_b", "wkv_a", "kv_a_norm",
+        "wkv_b", "wo")}
+    cos, sin = llama._rope_tables(cfg.rope_theta, S, cfg.rope_dim)
+
+    def loss(x, lp):
+        y = jax.checkpoint(lambda x, lp: latent.attention_half(
+            x, lp, cfg, cos, sin))(x, lp)
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    x = _sds((B, S, cfg.d_model), bf, one_chip)
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        x, lp).compile().as_text()
+    calls = sorted(kernel_roofline.signature(ln) for ln in text.splitlines()
+                   if kernel_roofline.signature(ln) is not None)
+    assert calls == [(1, 6), (2, 3), (2, 3), (2, 6)], calls
+    plans = {}
+    for name, attrs in seen:
+        plans.setdefault(name, []).append(attrs)
+    assert {a["path"] for a in plans["flash.fwd_plan"]} == {"stream"}
+    assert plans["flash.fwd_plan"][0]["kv_block_bytes"] == 16 * 2 ** 20
+    back = plans["flash.bwd_plan"][0]
+    assert back["path"] == "stream" and back["dq_path"] == "stream"
+    assert back["resident_bytes"] > fa._vmem_bytes() // 4
+    assert plans["mla.plan"][0]["k_bytes"] == B * S * 20 * 256 * 2
+    # what the bytes say, the compiler says: the loop kernel does not fit
+    monkeypatch.setattr(fa, "_SCOPED_VMEM_BYTES", 2 ** 40)
+    q = _sds((B, S, 20, 256), bf, one_chip)
+    with pytest.raises(Exception, match="(?i)vmem|memory|exceed"):
+        jax.jit(lambda q, k, v: fa.flash_attention(q, k, v)).lower(
+            q, q, q).compile()
 
 
 def test_flash_compiles_with_a_stated_scale_and_grouped_heads(
