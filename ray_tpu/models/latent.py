@@ -28,10 +28,30 @@ The attention half, for h = rms_norm(x) [B, S, D], H heads:
 ``k_r`` is ONE vector a token, shared by all heads; the rotary turns
 interleaved pairs (2i, 2i+1) of the ``qk_rope_dim`` lanes. This is the
 EXPANDED form, the one training and prefill run: K goes to the kernel as
-[B, S, H, qk_nope_dim + qk_rope_dim] with the shared rotary key written
+[B, H, S, qk_nope_dim + qk_rope_dim] with the shared rotary key written
 into every head's last lanes (instant ``mla.plan``). The kernel takes q, k
 and v of one width, so ``v_dim`` has to equal ``qk_nope_dim +
 qk_rope_dim`` (GLM-4.7-Flash: 192 + 64 = 256).
+
+The stored weights keep the published order of columns: ``wq_b`` a head's
+[q_nope | q_rope] H times, the rotary lanes as interleaved pairs; ``wkv_a``
+[c_kv | k_r], pairs likewise; ``wkv_b`` a head's [k_nope | v] H times.
+What the projections WRITE is what the kernel reads, [B, H, S, .] with a
+head's lanes minor, because their columns are gathered inside the traced
+step (a few MB a layer; the weights' gradients come back through the
+gather in the stored order) and no row is rolled, split, padded or joined
+afterwards:
+
+- the rotary is linear, rope(x) = x cos + (x P) sin with P the signed swap
+  of a pair's lanes, and x = c W gives x P = c (W P): a second projection
+  of ``wq_b``'s rotary columns, swapped and signed (``_swapped``; H x
+  qk_rope_dim more columns), and of ``wkv_a``'s (qk_rope_dim more). q is
+  its product times (1 | cos) plus the swapped product times (0 | sin),
+  the product's own epilogue;
+- k comes from ``wkv_b``'s k_nope columns with zero columns under a head's
+  rotary lanes, and the turned key is added to every head in that
+  product's epilogue; v from ``wkv_b``'s v columns, as its product wrote
+  it; ``wo`` contracts heads and lanes of the kernel's output in place.
 
 Layers are a list of runs (``layer_runs``): ``n_dense`` leading layers
 whose feed-forward is a SwiGLU of ``dense_d_ff``, then sparse layers
@@ -217,53 +237,86 @@ def num_params(cfg: LatentConfig) -> int:
             + cfg.n_mtp * (sparse + 2 * D * D + 3 * D))
 
 
-def _rope_pairs(x, cos, sin):
-    """Rotary over interleaved pairs: x [B, S, N, R], cos/sin [S, R/2];
-    lanes (2i, 2i+1) turn by angle i. Written with the pair's other lane
-    rolled in beside it, so that no array is ever [.., R/2, 2] wide."""
-    f32 = jnp.float32
-    xf = x.astype(f32)
-    even = jnp.arange(x.shape[-1]) % 2 == 0
-    other = jnp.where(even, jnp.roll(xf, -1, axis=-1),
-                      jnp.roll(xf, 1, axis=-1))
-    c = jnp.repeat(cos, 2, axis=-1)[None, :, None, :]
-    s = jnp.repeat(sin, 2, axis=-1)[None, :, None, :]
-    return (xf * c + other * jnp.where(even, -s, s)).astype(x.dtype)
+def _swapped(w):
+    """w P for the rotary's signed swap P of interleaved pairs: columns
+    (2i, 2i+1) of the last axis become (-w[2i+1], w[2i]), so that with x =
+    c w the rotary of x is x * cos + (c (w P)) * sin, pair by pair."""
+    pairs = w.reshape(*w.shape[:-1], -1, 2)
+    return jnp.stack([-pairs[..., 1], pairs[..., 0]],
+                     axis=-1).reshape(w.shape)
+
+
+def _rotary_tables(cos, sin, dn: int):
+    """cos/sin [S, R/2] -> float32 [S, dn + R] each, (1 | cos) and (0 |
+    sin) with an angle's value on both lanes of its pair: a head's lanes
+    times the first plus its swapped lanes times the second is the head
+    with its last R lanes turned and its first ``dn`` as they were."""
+    c, s = (jnp.repeat(t.astype(jnp.float32), 2, axis=-1) for t in (cos, sin))
+    return (jnp.pad(c, ((0, 0), (dn, 0)), constant_values=1.0),
+            jnp.pad(s, ((0, 0), (dn, 0))))
 
 
 def plan(cfg: LatentConfig, B: int, S: int) -> dict:
     """What a traced attention half says of itself (instant ``mla.plan``):
-    the sizes, the form it runs in and the bytes of K as the kernel gets
-    it, the shared rotary key written out for every head."""
-    return {"S": S, "heads": cfg.n_heads, "qk_nope": cfg.qk_nope_dim,
-            "qk_rope": cfg.qk_rope_dim, "v_dim": cfg.v_dim,
-            "q_rank": cfg.q_rank, "kv_rank": cfg.kv_rank, "form": "expanded",
-            "k_bytes": B * S * cfg.n_heads * cfg.head_dim
-            * jnp.dtype(cfg.dtype).itemsize}
+    the sizes, the form it runs in (``form``: K expanded, the shared rotary
+    key written out for every head, ``k_bytes`` of it; ``rope``: by a
+    second projection of swapped columns; ``kv``: K and V each from its
+    own columns of ``wkv_b``), the columns the projections compute beyond
+    the stored ones (``extra_columns`` swapped, ``zero_columns`` under K's
+    rotary lanes) and the form's own account of its row passes, every
+    operand read once and every result written once, the products' other
+    operands left out: forward, q from its two products and k from its
+    product and the turned key (v is its product's result); backward, the
+    kernel's float32 dq, dk and dv each read once for the bf16 gradient
+    rows of the products (dq's twice over the rotary lanes, dk's with its
+    sum over heads)."""
+    H, R, e = cfg.n_heads, cfg.qk_rope_dim, jnp.dtype(cfg.dtype).itemsize
+    rows, heads = B * S, B * S * cfg.n_heads * cfg.head_dim
+    return {"S": S, "heads": H, "qk_nope": cfg.qk_nope_dim, "qk_rope": R,
+            "v_dim": cfg.v_dim, "q_rank": cfg.q_rank, "kv_rank": cfg.kv_rank,
+            "form": "expanded", "k_bytes": heads * e, "rope": "projected",
+            "kv": "split_weights", "extra_columns": (H + 1) * R,
+            "zero_columns": H * R,
+            "hbm_bytes_fwd": e * (4 * heads + rows * R * (H + 4)),
+            "hbm_bytes_bwd": 3 * 4 * heads + e * (3 * heads
+                                                  + rows * R * (H + 1))}
 
 
 def attention_half(x, lp, cfg: LatentConfig, cos, sin, mesh=None, rules=None):
     """The latent-attention half of a block: x [B, S, D] -> x + its
-    attention's output (the module docstring has the equations)."""
+    attention's output (the module docstring has the equations and how the
+    stored columns are arranged for the kernel)."""
     B, S, _ = x.shape
     H, dn, dv, dt = cfg.n_heads, cfg.qk_nope_dim, cfg.v_dim, cfg.dtype
+    R, rk, f32 = cfg.qk_rope_dim, cfg.kv_rank, jnp.float32
     tracing.instant("mla.plan", plan(cfg, B, S))
     w = lambda name: _ll._dq(lp[name], dt)                     # noqa: E731
+    wq_b = w("wq_b").reshape(cfg.q_rank, H, dn + R)
+    wq_s = _swapped(wq_b[..., dn:])                            # [q_rank, H, R]
+    wkv_a = w("wkv_a")
+    wkv_a = jnp.concatenate([wkv_a, _swapped(wkv_a[:, rk:])], axis=-1)
+    wkv_b = w("wkv_b").reshape(rk, H, dn + dv)
+    wk = jnp.pad(wkv_b[..., :dn], ((0, 0), (0, 0), (0, R)))
+    one, turn = _rotary_tables(cos, sin, dn)                   # [S, dn + R]
     h = _ll.rms_norm(x, lp["attn_norm"], cfg.norm_eps)
     c_q = _ll.rms_norm(h @ w("wq_a"), lp["q_a_norm"], cfg.norm_eps)
-    q = (c_q @ w("wq_b")).reshape(B, S, H, cfg.head_dim)
-    c_kv = h @ w("wkv_a")
-    k_r = c_kv[..., None, cfg.kv_rank:]                        # [B, S, 1, R]
-    c_kv = _ll.rms_norm(c_kv[..., :cfg.kv_rank], lp["kv_a_norm"],
-                        cfg.norm_eps)
-    kv = (c_kv @ w("wkv_b")).reshape(B, S, H, dn + dv)
-    q = jnp.concatenate([q[..., :dn], _rope_pairs(q[..., dn:], cos, sin)],
-                        axis=-1)
-    k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(
-        _rope_pairs(k_r, cos, sin), (B, S, H, cfg.qk_rope_dim))], axis=-1)
-    out = _ll._attention(q, k, kv[..., dn:], cfg, causal=True, mesh=mesh,
-                         rules=rules)
-    return _ll._residual(x, out.reshape(B, S, H * dv) @ w("wo"), cfg)
+    q = jnp.einsum("bsr,rhd->bhsd", c_q, wq_b)
+    q_s = jnp.pad(jnp.einsum("bsr,rhd->bhsd", c_q, wq_s),
+                  ((0, 0), (0, 0), (0, 0), (dn, 0)))
+    q = (q.astype(f32) * one + q_s.astype(f32) * turn).astype(dt)
+    c_kv = h @ wkv_a                                  # [c_kv | k_r | k_r P]
+    # the ONE rotary key a token, by the tables' rotary lanes (cos | sin)
+    k_r = (c_kv[..., rk:rk + R].astype(f32) * one[:, dn:]
+           + c_kv[..., rk + R:].astype(f32) * turn[:, dn:]).astype(dt)
+    c_kv = _ll.rms_norm(c_kv[..., :rk], lp["kv_a_norm"], cfg.norm_eps)
+    k = jnp.einsum("bsr,rhd->bhsd", c_kv, wk) \
+        + jnp.pad(k_r, ((0, 0), (0, 0), (dn, 0)))[:, None]
+    v = jnp.einsum("bsr,rhd->bhsd", c_kv, wkv_b[..., dn:])
+    # the kernel's own transposes, so XLA writes no copy for them
+    q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
+    out = _ll._attention(q, k, v, cfg, causal=True, mesh=mesh, rules=rules)
+    return _ll._residual(
+        x, jnp.einsum("bshd,hde->bse", out, w("wo").reshape(H, dv, -1)), cfg)
 
 
 def feed_forward(h, lp, cfg: LatentConfig, mesh=None, rules=None, tp=None,

@@ -244,12 +244,104 @@ def test_latent_attention_through_flash_at_a_head_of_256(fwd_dq, dkdv,
     assert plans["mla.plan"] == {
         "S": S, "heads": 2, "qk_nope": 192, "qk_rope": 64, "v_dim": 256,
         "q_rank": 32, "kv_rank": 16, "form": "expanded",
-        "k_bytes": S * 2 * 256 * 4}
+        "k_bytes": S * 2 * 256 * 4, "rope": "projected",
+        "kv": "split_weights", "extra_columns": 3 * 64,
+        "zero_columns": 2 * 64,
+        # q from its two products, k from its product and the key; the
+        # kernel's float32 dq, dk, dv read once each
+        "hbm_bytes_fwd": 4 * (4 * S * 2 * 256 + S * 64 * 6),
+        "hbm_bytes_bwd": (12 + 12) * S * 2 * 256 + 4 * S * 64 * 3}
     assert plans["flash.fwd_plan"] == {
         "path": fwd_dq, "S": S, "D": 256,
         "kv_block_bytes": 2 * 2 * S * 256 * 4}
     assert plans["flash.bwd_plan"]["path"] == dkdv
     assert plans["flash.bwd_plan"]["dq_path"] == fwd_dq
+
+
+def _published_half(x, lp, cfg, cos, sin):
+    """The attention half in the published form (transformers'
+    ``deepseek_v3`` block, as ``models/latent.py`` ran it until the
+    projections were arranged for the kernel): q and k built head by head,
+    the rotary as a turn of interleaved pairs, K and V sliced out of
+    ``c_kv wkv_b``."""
+    B, S, _ = x.shape
+    H, dn, dv, R = cfg.n_heads, cfg.qk_nope_dim, cfg.v_dim, cfg.qk_rope_dim
+    w = lambda name: lp[name].astype(cfg.dtype)                # noqa: E731
+
+    def turn(t):                   # [B, S, N, R]: lanes (2i, 2i+1) by angle i
+        pairs = t.astype(jnp.float32).reshape(*t.shape[:-1], R // 2, 2)
+        c, s = cos[None, :, None, :], sin[None, :, None, :]
+        a, b = pairs[..., 0], pairs[..., 1]
+        return jnp.stack([a * c - b * s, b * c + a * s],
+                         axis=-1).reshape(t.shape).astype(t.dtype)
+
+    h = llama.rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+    c_q = llama.rms_norm(h @ w("wq_a"), lp["q_a_norm"], cfg.norm_eps)
+    q = (c_q @ w("wq_b")).reshape(B, S, H, dn + R)
+    c_kv = h @ w("wkv_a")
+    k_r = turn(c_kv[..., None, cfg.kv_rank:])
+    c_kv = llama.rms_norm(c_kv[..., :cfg.kv_rank], lp["kv_a_norm"],
+                          cfg.norm_eps)
+    kv = (c_kv @ w("wkv_b")).reshape(B, S, H, dn + dv)
+    q = jnp.concatenate([q[..., :dn], turn(q[..., dn:])], axis=-1)
+    k = jnp.concatenate([kv[..., :dn],
+                         jnp.broadcast_to(k_r, (B, S, H, R))], axis=-1)
+    out = llama._attention_xla(q, k, kv[..., dn:], causal=True)
+    return x + out.reshape(B, S, H * dv) @ w("wo")
+
+
+HALF_WIDTHS = {
+    "tiny": {},
+    "3-heads-of-24+8": dict(n_heads=3, n_kv_heads=3, qk_nope_dim=24,
+                            qk_rope_dim=8, v_dim=32),
+    "rope-12": dict(n_heads=4, n_kv_heads=4, qk_nope_dim=20, qk_rope_dim=12,
+                    v_dim=32, q_rank=24, kv_rank=20),
+}
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-5), ("bfloat16", 2e-2)])
+@pytest.mark.parametrize("widths", sorted(HALF_WIDTHS))
+def test_the_attention_half_is_the_published_form(widths, dtype, tol):
+    """The projections arranged for the kernel (the rotary by a second
+    projection of swapped columns, K and V from their own columns of
+    ``wkv_b``) against the published form above: the output and the
+    gradients of x and of all seven weights, from the STORED weights in
+    their published order."""
+    dt = jnp.dtype(dtype)
+    cfg = latent.PRESETS["tiny"].replace(
+        dtype=dt, param_dtype=dt, attn_impl="xla", **HALF_WIDTHS[widths])
+    lp = {k: v[0] for k, v in latent._mla_params(
+        jax.random.PRNGKey(3), cfg, 1).items()}
+    lp["attn_norm"] = jnp.ones((cfg.d_model,), dt)
+    for i, name in enumerate(("attn_norm", "q_a_norm", "kv_a_norm")):
+        lp[name] = lp[name] + 0.3 * jax.random.normal(
+            jax.random.PRNGKey(10 + i), lp[name].shape, dt)
+    B, S = 2, 48
+    x = jax.random.normal(jax.random.PRNGKey(5), (B, S, cfg.d_model), dt)
+    probe = jax.random.normal(jax.random.PRNGKey(6), x.shape, jnp.float32)
+    cos, sin = llama._rope_tables(cfg.rope_theta, S, cfg.rope_dim)
+
+    def run(half):
+        def f(x, lp):
+            y = half(x, lp, cfg, cos, sin)
+            return jnp.sum(y.astype(jnp.float32) * probe), y
+        with jax.default_matmul_precision("highest"):
+            return jax.jit(jax.value_and_grad(f, (0, 1), has_aux=True))(x, lp)
+
+    (_, y), (gx, glp) = run(latent.attention_half)
+    (_, want_y), (want_gx, want_glp) = run(_published_half)
+    assert y.dtype == dt and set(glp) == set(want_glp) and len(glp) == 8
+
+    def close(a, b, name):
+        a, b = (np.asarray(t, np.float32) for t in (a, b))
+        assert np.abs(a - b).max() <= tol * np.abs(b).max(), (
+            name, np.abs(a - b).max(), np.abs(b).max())
+
+    close(y, want_y, "y")
+    close(gx, want_gx, "x")
+    for name in want_glp:
+        assert glp[name].shape == lp[name].shape, name
+        close(glp[name], want_glp[name], name)
 
 
 def _explicit(q, k, v):
@@ -536,7 +628,10 @@ def test_plans_read_back_from_a_profile_around_a_lowering(tmp_path,
     assert events["mla.plan"][0] == {
         "S": 128, "heads": 2, "qk_nope": 192, "qk_rope": 64, "v_dim": 256,
         "q_rank": 32, "kv_rank": 16, "form": "expanded",
-        "k_bytes": 2 * 128 * 2 * 256 * 4}
+        "k_bytes": 2 * 128 * 2 * 256 * 4, "rope": "projected",
+        "kv": "split_weights", "extra_columns": 192, "zero_columns": 128,
+        "hbm_bytes_fwd": 4 * (4 * 256 * 2 * 256 + 256 * 64 * 6),
+        "hbm_bytes_bwd": 24 * 256 * 2 * 256 + 4 * 256 * 64 * 3}
     assert events["flash.fwd_plan"][0] == {
         "path": "stream", "S": 128, "D": 256,
         "kv_block_bytes": 2 * 2 * 128 * 256 * 4}
