@@ -334,16 +334,25 @@ def _ahead(params, tokens, hidden, stats, cfg: LatentConfig, run):
     model's own embedding and head; the layers' statistics with the
     module's block joined on, one expert layer more)."""
     m, S = params["mtp"], tokens.shape[1] - 2
-    ahead = _ll._embed(params, tokens[:, 1:S + 1], cfg.dtype)
-    g = jnp.concatenate(
-        [_ll.rms_norm(hidden, m["h_norm"], cfg.norm_eps),
-         _ll.rms_norm(ahead, m["e_norm"], cfg.norm_eps)], axis=-1) \
-        @ _ll._dq(m["eh_proj"], cfg.dtype)
-    x, more = run("sparse", g, m["block"])
-    logits = _ll._logits(
-        params, _ll.rms_norm(x, m["final_norm"], cfg.norm_eps), cfg)
-    return logits, jax.tree.map(lambda a, b: jnp.concatenate([a, b]), stats,
-                                more)
+    # the module's parts under ``mtp``, in the main model's scopes: its
+    # embedding, its block (``run`` opens ``layers``), ``eh_proj`` and its
+    # head with the heads and losses
+    with jax.named_scope("mtp"):
+        with jax.named_scope("embed"):
+            ahead = _ll._embed(params, tokens[:, 1:S + 1], cfg.dtype)
+        with jax.named_scope("head_loss"):
+            g = jnp.concatenate(
+                [_ll.rms_norm(hidden, m["h_norm"], cfg.norm_eps),
+                 _ll.rms_norm(ahead, m["e_norm"], cfg.norm_eps)], axis=-1) \
+                @ _ll._dq(m["eh_proj"], cfg.dtype)
+        x, more = run("sparse", g, m["block"])
+        with jax.named_scope("head_loss"):
+            logits = _ll._logits(
+                params, _ll.rms_norm(x, m["final_norm"], cfg.norm_eps), cfg)
+        with jax.named_scope("layers"):
+            stats = jax.tree.map(lambda a, b: jnp.concatenate([a, b]), stats,
+                                 more)
+    return logits, stats
 
 
 def further_losses(params, tokens, hidden, stats, cfg: LatentConfig, run):
@@ -356,8 +365,9 @@ def further_losses(params, tokens, hidden, stats, cfg: LatentConfig, run):
         return stats
     logits, stats = _ahead(params, tokens, hidden, stats, cfg, run)
     S = tokens.shape[1] - 2
-    return {**stats, "mtp_loss": _ll.cross_entropy(logits,
-                                                   tokens[:, 2:S + 2])}
+    with jax.named_scope("mtp"), jax.named_scope("head_loss"):
+        return {**stats, "mtp_loss": _ll.cross_entropy(logits,
+                                                       tokens[:, 2:S + 2])}
 
 
 def token_losses(params, tokens, cfg: LatentConfig, mesh=None, rules=None):

@@ -14,6 +14,11 @@ Design notes (TPU):
 - attention dispatch: "xla" (fused by Mosaic/XLA), "flash" (our Pallas
   kernel, ops/flash_attention.py), "ring" (sequence-parallel ring attention,
   ops/ring_attention.py) — chosen by RuntimeFlags, not model code.
+- every op says which part of the model issued it: the forward opens
+  ``jax.named_scope``s (``embed``, ``layers``, a layer's ``attention`` or
+  ``mixer`` and ``feed_forward``, ``head_loss``; PERF.md 3 has the list).
+  They are HLO metadata and cost nothing at run time; a chip trace carries
+  them, benchmark/op_scopes.py reads them back.
 """
 
 from __future__ import annotations
@@ -485,19 +490,25 @@ def _layer(x, lp, cfg: LlamaConfig, cos, sin, cache=None, collect_kv=False,
     own = getattr(_family(cfg), "attention_half", None)
     if own is not None:
         assert cache is None and not collect_kv and tp is None, kind
-        x, k, v, new_cache = own(x, lp, cfg, cos, sin, mesh=mesh,
-                                 rules=rules), None, None, None
+        with jax.named_scope("attention"):
+            x, k, v, new_cache = own(x, lp, cfg, cos, sin, mesh=mesh,
+                                     rules=rules), None, None, None
     elif kind in (None, "attention"):
-        x, k, v, new_cache = _attention_half(
-            x, lp, cfg, cos, sin, cache=cache, mesh=mesh, rules=rules, tp=tp)
+        with jax.named_scope("attention"):
+            x, k, v, new_cache = _attention_half(
+                x, lp, cfg, cos, sin, cache=cache, mesh=mesh, rules=rules,
+                tp=tp)
     else:
         assert cache is None and not collect_kv, kind
-        x, k, v, new_cache = _family(cfg).mixer_half(
-            x, lp, cfg, kind, mesh=mesh), None, None, None
-    h = rms_norm(x, lp["ffn_norm"], cfg.norm_eps)
-    y, stats = _family(cfg).feed_forward(h, lp, cfg, mesh=mesh, rules=rules,
-                                         tp=tp, kind=kind)
-    return _residual(x, y, cfg), ((k, v) if collect_kv else new_cache), stats
+        with jax.named_scope("mixer"):
+            x, k, v, new_cache = _family(cfg).mixer_half(
+                x, lp, cfg, kind, mesh=mesh), None, None, None
+    with jax.named_scope("feed_forward"):
+        h = rms_norm(x, lp["ffn_norm"], cfg.norm_eps)
+        y, stats = _family(cfg).feed_forward(h, lp, cfg, mesh=mesh,
+                                             rules=rules, tp=tp, kind=kind)
+        x = _residual(x, y, cfg)
+    return x, ((k, v) if collect_kv else new_cache), stats
 
 
 def _tp_plan(cfg: LlamaConfig, mesh, rules, batch: int, seq: int):
@@ -607,19 +618,23 @@ def _forward(params, tokens, cfg: LlamaConfig, pos_offset=0, mesh=None,
     B, S = tokens.shape
     tp = _tp_plan(cfg, mesh, rules, B, S)
     con = _act_constraint(mesh, rules, tp)
-    x = _embed(params, tokens, dt)
-    if cfg.embedding_multiplier is not None:
-        x = (x * cfg.embedding_multiplier).astype(dt)
+    with jax.named_scope("embed"):
+        x = _embed(params, tokens, dt)
+        if cfg.embedding_multiplier is not None:
+            x = (x * cfg.embedding_multiplier).astype(dt)
     x = con(x)
-    if not cfg.rope:
-        cos = sin = None
-    elif isinstance(pos_offset, int) and pos_offset == 0:
-        cos, sin = _rope_tables(cfg.rope_theta, S, cfg.rope_dim)
-    else:
-        cos_full, sin_full = _rope_tables(cfg.rope_theta, cfg.max_seq_len,
-                                          cfg.rope_dim)
-        cos = jax.lax.dynamic_slice_in_dim(cos_full, pos_offset, S, axis=0)
-        sin = jax.lax.dynamic_slice_in_dim(sin_full, pos_offset, S, axis=0)
+    with jax.named_scope("attention"):      # the tables are its rotary's
+        if not cfg.rope:
+            cos = sin = None
+        elif isinstance(pos_offset, int) and pos_offset == 0:
+            cos, sin = _rope_tables(cfg.rope_theta, S, cfg.rope_dim)
+        else:
+            cos_full, sin_full = _rope_tables(
+                cfg.rope_theta, cfg.max_seq_len, cfg.rope_dim)
+            cos = jax.lax.dynamic_slice_in_dim(cos_full, pos_offset, S,
+                                               axis=0)
+            sin = jax.lax.dynamic_slice_in_dim(sin_full, pos_offset, S,
+                                               axis=0)
 
     @functools.cache
     def body_of(kind):
@@ -631,7 +646,10 @@ def _forward(params, tokens, cfg: LlamaConfig, pos_offset=0, mesh=None,
         return _checkpoint(body, cfg) if cfg.remat else body
 
     def run(kind, x, stack):
-        return jax.lax.scan(body_of(kind), x, stack)
+        # what lies under ``layers`` and in none of a layer's halves is
+        # the loop's own: the scan's stacks, its carries, ``con``
+        with jax.named_scope("layers"):
+            return jax.lax.scan(body_of(kind), x, stack)
 
     if isinstance(params["layers"], dict):
         x, stats = run(None, x, params["layers"])
@@ -643,15 +661,18 @@ def _forward(params, tokens, cfg: LlamaConfig, pos_offset=0, mesh=None,
             x, s = run(kind, x, stack)
             if s is not None:       # a run of dense layers reports nothing
                 stats.append(s)
-        stats = jax.tree.map(lambda *s: jnp.concatenate(s), *stats)
+        with jax.named_scope("layers"):
+            stats = jax.tree.map(lambda *s: jnp.concatenate(s), *stats)
         _say_layer_plan(runs, body_of.cache_info().currsize)
     if mesh is not None and rules is not None:
         _say_tp_plan(tp, cfg, B, S)
     hidden = x
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    if tp is not None:    # the head wants every row: one gather a step
-        x = jax.lax.with_sharding_constraint(x, tp.gathered_sharding())
-    return _logits(params, x, cfg), stats, hidden, run
+    with jax.named_scope("head_loss"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        if tp is not None:    # the head wants every row: one gather a step
+            x = jax.lax.with_sharding_constraint(x, tp.gathered_sharding())
+        logits = _logits(params, x, cfg)
+    return logits, stats, hidden, run
 
 
 def forward_sp(params, tokens, cfg: LlamaConfig, mesh):
@@ -749,12 +770,16 @@ def loss_fn(params, batch, cfg: LlamaConfig, mesh=None, rules=None):
     if finish is not None and stats is None:
         raise ValueError("a model whose loss needs its layers' statistics "
                          "(router losses) does not train under sp or pp")
-    loss = cross_entropy(logits, targets, mask)
+    with jax.named_scope("head_loss"):
+        loss = cross_entropy(logits, targets, mask)
     if further is not None:
         # the further passes' losses and their layers' statistics join stats
         stats = further(params, batch["tokens"], hidden, stats, cfg, run)
+    if finish is None:
+        return loss
     # an expert model adds its router losses and returns (loss, aux)
-    return loss if finish is None else finish(loss, stats, cfg)
+    with jax.named_scope("head_loss"):
+        return finish(loss, stats, cfg)
 
 
 def token_losses(logits, targets):

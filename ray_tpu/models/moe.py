@@ -367,15 +367,20 @@ def _held_combine(cfg, rows, short, x, weights, ranked, counts, we):
 
 def _held_combine_fwd(cfg, rows, short, x, weights, ranked, counts, we):
     K = cfg.top_k
-    token, order, live, sizes, xs, w_rows = _held_rows(
-        0, rows, x, weights, ranked, counts, K)
-    ys, back = jax.vjp(lambda xs, w_rows, we: _held_swiglu(
-        xs, w_rows, live, sizes, we, cfg), xs, w_rows, we)
+    with jax.named_scope("dispatch"):
+        token, order, live, sizes, xs, w_rows = _held_rows(
+            0, rows, x, weights, ranked, counts, K)
+    with jax.named_scope("experts"):
+        ys, back = jax.vjp(lambda xs, w_rows, we: _held_swiglu(
+            xs, w_rows, live, sizes, we, cfg), xs, w_rows, we)
 
     def one(p, y):
-        token, _, live, sizes, xs, w_rows = _held_rows(
-            rows + p * short, short, x, weights, ranked, counts, K)
-        return y.at[token].add(_held_swiglu(xs, w_rows, live, sizes, we, cfg))
+        with jax.named_scope("dispatch"):
+            token, _, live, sizes, xs, w_rows = _held_rows(
+                rows + p * short, short, x, weights, ranked, counts, K)
+        with jax.named_scope("experts"):
+            ys = _held_swiglu(xs, w_rows, live, sizes, we, cfg)
+        return y.at[token].add(ys)
 
     y = jax.lax.fori_loop(0, _passes(counts, rows, short), one,
                           jnp.zeros(x.shape, jnp.float32).at[token].add(ys))
@@ -394,16 +399,24 @@ def _held_combine_bwd(cfg, rows, short, res, dy):
                                                          d_we))
 
     def one(p, acc):
-        token, order, live, sizes, xs, w_rows = _held_rows(
-            rows + p * short, short, x, weights, ranked, counts, K)
-        _, again = jax.vjp(lambda xs, w_rows, we: _held_swiglu(
-            xs, w_rows, live, sizes, we, cfg), xs, w_rows, we)
-        return add(acc, token, order, live, *again(dy[token]))
+        with jax.named_scope("dispatch"):
+            token, order, live, sizes, xs, w_rows = _held_rows(
+                rows + p * short, short, x, weights, ranked, counts, K)
+        with jax.named_scope("experts"):
+            _, again = jax.vjp(lambda xs, w_rows, we: _held_swiglu(
+                xs, w_rows, live, sizes, we, cfg), xs, w_rows, we)
+        return add(acc, token, order, live, *rows_back(again, token))
 
+    def rows_back(vjp, token):
+        with jax.named_scope("dispatch"):
+            dys = dy[token]
+        with jax.named_scope("experts"):
+            return vjp(dys)
+
+    more = _passes(counts, rows, short)
+    zeros = (jnp.zeros_like(x), jnp.zeros(weights.size, weights.dtype), None)
     d_x, d_weights, d_we = jax.lax.fori_loop(
-        0, _passes(counts, rows, short), one, add(
-            (jnp.zeros_like(x), jnp.zeros(weights.size, weights.dtype), None),
-            token, order, live, *back(dy[token])))
+        0, more, one, add(zeros, token, order, live, *rows_back(back, token)))
     return d_x, d_weights.reshape(weights.shape), None, None, d_we
 
 
@@ -419,16 +432,23 @@ def _held_experts(x, weights, experts, lp, cfg: MoEConfig):
     (``_held_combine``)."""
     T, K, dt = x.shape[0], cfg.top_k, cfg.dtype
     held, first = cfg.experts_held
-    local = experts.reshape(T * K) - first
-    local = jnp.where((local >= 0) & (local < held), local, held)
-    ranked = jnp.argsort(local, stable=True).astype(jnp.int32)
-    weights, ranked, counts = checkpoint_name(
-        (weights, ranked, _count(local, held)), REMAT_SAVED[0])
+    with jax.named_scope("dispatch"):
+        local = experts.reshape(T * K) - first
+        local = jnp.where((local >= 0) & (local < held), local, held)
+        ranked = jnp.argsort(local, stable=True).astype(jnp.int32)
+    with jax.named_scope("router"):
+        counts = _count(local, held)
+    weights, ranked, counts = checkpoint_name((weights, ranked, counts),
+                                              REMAT_SAVED[0])
     rows, tile = held_rows(cfg, T), GMM_TILING[0]
     short = max(rows // 4 // tile * tile, min(tile, rows))
-    we = tuple(_ll._dq(lp[w], dt) for w in ("we_gate", "we_up", "we_down"))
-    return (_held_combine(cfg, rows, short, x, weights, ranked, counts, we),
-            counts, _passes(counts, rows, short))
+    with jax.named_scope("experts"):
+        we = tuple(_ll._dq(lp[w], dt)
+                   for w in ("we_gate", "we_up", "we_down"))
+    # the pass's own scopes lie inside (``_held_combine_fwd``, ``_bwd``)
+    with jax.named_scope("combine"):
+        y = _held_combine(cfg, rows, short, x, weights, ranked, counts, we)
+        return y, counts, _passes(counts, rows, short)
 
 
 def feed_forward(h, lp, cfg: MoEConfig, mesh=None, rules=None, tp=None,
@@ -448,27 +468,37 @@ def feed_forward(h, lp, cfg: MoEConfig, mesh=None, rules=None, tp=None,
     E, K = cfg.n_experts, cfg.top_k
     T, dt = B * S, cfg.dtype
     x = h.reshape(T, D)
-    logits = jnp.dot(x, _ll._dq(lp["router"], dt),
-                     preferred_element_type=jnp.float32)            # [T, E]
-    weights, experts, probs = route(logits, cfg, lp.get("router_bias"))
-    flat = experts.reshape(T * K)
+    # the layer's sub-scopes (PERF.md 3): router, dispatch, experts,
+    # combine, shared
+    with jax.named_scope("router"):
+        logits = jnp.dot(x, _ll._dq(lp["router"], dt),
+                         preferred_element_type=jnp.float32)        # [T, E]
+        weights, experts, probs = route(logits, cfg, lp.get("router_bias"))
+        flat = experts.reshape(T * K)
     if cfg.experts_held is not None:
         y, held, more = _held_experts(x, weights, experts, lp, cfg)
-        stats = {"counts": _count(flat, E), "held_counts": held,
-                 "more_passes": more}
+        with jax.named_scope("router"):
+            stats = {"counts": _count(flat, E), "held_counts": held,
+                     "more_passes": more}
         return _finish(y, stats, x, lp, cfg, logits, experts, probs, (B, S, D))
-    order = jnp.argsort(flat, stable=True).astype(jnp.int32)   # row -> slot
-    back = jnp.zeros_like(order).at[order].set(
-        jnp.arange(T * K, dtype=jnp.int32))                    # slot -> row
-    sizes = jnp.zeros((E,), jnp.int32).at[flat].add(1)
+    with jax.named_scope("dispatch"):
+        order = jnp.argsort(flat, stable=True).astype(jnp.int32)  # row -> slot
+        back = jnp.zeros_like(order).at[order].set(
+            jnp.arange(T * K, dtype=jnp.int32))                   # slot -> row
+    with jax.named_scope("router"):
+        sizes = jnp.zeros((E,), jnp.int32).at[flat].add(1)
     weights, experts, order, back, sizes = checkpoint_name(
         (weights, experts, order, back, sizes), REMAT_SAVED[0])
 
-    xs = _dispatch(K, x, order, back)            # rows in expert order
+    with jax.named_scope("dispatch"):
+        xs = _dispatch(K, x, order, back)        # rows in expert order
     mm = lambda w: grouped_matmul(xs, _ll._dq(lp[w], dt), sizes,   # noqa: E731
                                   impl=cfg.gmm_impl)
-    y = _down_combine(cfg.gmm_impl, jax.nn.silu(mm("we_gate")) * mm("we_up"),
-                      _ll._dq(lp["we_down"], dt), weights, order, back, sizes)
+    with jax.named_scope("experts"):
+        h = jax.nn.silu(mm("we_gate")) * mm("we_up")
+    with jax.named_scope("combine"):
+        y = _down_combine(cfg.gmm_impl, h, _ll._dq(lp["we_down"], dt),
+                          weights, order, back, sizes)
     return _finish(y, {"counts": sizes}, x, lp, cfg, logits, experts, probs,
                    (B, S, D))
 
@@ -476,18 +506,20 @@ def feed_forward(h, lp, cfg: MoEConfig, mesh=None, rules=None, tp=None,
 def _finish(y, stats, x, lp, cfg: MoEConfig, logits, experts, probs, shape):
     """The routed experts' y [T, D] plus the shared SwiGLU of x, where the
     config has one, in the layer's shape, and the layer's statistics."""
-    if cfg.router_score == "sigmoid":
-        stats = {**stats, "experts": experts,
-                 "balance": _sequence_balance(probs, experts, cfg, shape[0])}
-    else:
-        stats = {**stats, "prob_sum": probs.sum(axis=0),
-                 "z_sum": jnp.square(jax.nn.logsumexp(logits, axis=-1)).sum(),
-                 "experts": experts}
+    with jax.named_scope("router"):
+        if cfg.router_score == "sigmoid":
+            stats = {**stats, "experts": experts, "balance":
+                     _sequence_balance(probs, experts, cfg, shape[0])}
+        else:
+            stats = {**stats, "prob_sum": probs.sum(axis=0), "z_sum":
+                     jnp.square(jax.nn.logsumexp(logits, axis=-1)).sum(),
+                     "experts": experts}
     if cfg.shared_d_ff:
         dt = cfg.dtype
-        gate = jax.nn.silu(x @ _ll._dq(lp["ws_gate"], dt))
-        y = y + (gate * (x @ _ll._dq(lp["ws_up"], dt))) @ _ll._dq(
-            lp["ws_down"], dt)
+        with jax.named_scope("shared"):
+            gate = jax.nn.silu(x @ _ll._dq(lp["ws_gate"], dt))
+            y = y + (gate * (x @ _ll._dq(lp["ws_up"], dt))) @ _ll._dq(
+                lp["ws_down"], dt)
     return y.reshape(shape), stats
 
 

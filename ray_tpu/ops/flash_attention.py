@@ -148,7 +148,7 @@ def _flash_fwd_loop(q, k, v, *, causal: bool, block_q: int, block_k: int,
     block_k = min(block_k, T)
     grid = (B, H, S // block_q)
 
-    out, lse = pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(_fwd_kernel_loop, block_k=block_k, scale=scale,
                           causal=causal),
         grid=grid,
@@ -169,7 +169,9 @@ def _flash_fwd_loop(q, k, v, *, causal: bool, block_q: int, block_k: int,
             jax.ShapeDtypeStruct((B, H, S, 128), jnp.float32),
         ],
         interpret=_use_interpret(),
-    )(qt, kt, vt)
+    )
+    with jax.named_scope("flash.fwd.loop"):     # flash.fwd_plan's path
+        out, lse = call(qt, kt, vt)
     return out.transpose(0, 2, 1, 3), lse
 
 
@@ -304,7 +306,7 @@ def _flash_fwd(q, k, v, *, causal: bool, block_q: int, block_k: int,
                 j = jax.lax.max(j, lo)
         return (b, h // g, j, 0)
 
-    out, lse = pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(_fwd_kernel_stream, block_q=block_q,
                           block_k=block_k, scale=scale, causal=causal,
                           window=window, num_k=num_k),
@@ -331,7 +333,9 @@ def _flash_fwd(q, k, v, *, causal: bool, block_q: int, block_k: int,
             pltpu.VMEM((block_q, 128), jnp.float32),   # running sum
         ],
         interpret=_use_interpret(),
-    )(qt, kt, vt)
+    )
+    with jax.named_scope("flash.fwd.stream"):
+        out, lse = call(qt, kt, vt)
     return out.transpose(0, 2, 1, 3), lse
 
 
@@ -685,7 +689,7 @@ def _flash_bwd_dkdv(qt, kt, vt, gt, ot, lse, *, causal: bool, block_q: int,
         dkv_blk = pl.BlockSpec((1, 1, block_k, D),
                                lambda b, h, i, j: (b, h, i, 0))
         params = None
-    return pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[q_side(D), kv_blk, kv_blk, q_side(D), q_side(D),
@@ -694,7 +698,9 @@ def _flash_bwd_dkdv(qt, kt, vt, gt, ot, lse, *, causal: bool, block_q: int,
         out_shape=[jax.ShapeDtypeStruct((B, H, T, D), plan["out_dtype"])] * 2,
         compiler_params=params,
         interpret=_use_interpret(),
-    )(qt, kt, vt, gt, ot, lse)
+    )
+    with jax.named_scope(f"flash.dkdv.{plan['path']}"):
+        return call(qt, kt, vt, gt, ot, lse)
 
 
 def _flash_bwd_dq(qt, kt, vt, gt, ot, lse, *, path: str, causal: bool,
@@ -707,7 +713,7 @@ def _flash_bwd_dq(qt, kt, vt, gt, ot, lse, *, path: str, causal: bool,
         q_blk = pl.BlockSpec((1, 1, block_q, D), lambda b, h, i: (b, h, i, 0))
         kv_spec = pl.BlockSpec((1, 1, T, D),
                                lambda b, h, i, g_=groups: (b, h // g_, 0, 0))
-        return pl.pallas_call(
+        call = pl.pallas_call(
             functools.partial(_bwd_dq_kernel, block_k=block_k, scale=scale,
                               causal=causal, window=window),
             grid=(B, H, S // block_q),
@@ -724,7 +730,9 @@ def _flash_bwd_dq(qt, kt, vt, gt, ot, lse, *, path: str, causal: bool,
                                    lambda b, h, i: (b, h, i, 0)),
             out_shape=jax.ShapeDtypeStruct((B, H, S, D), qt.dtype),
             interpret=_use_interpret(),
-        )(qt, kt, vt, gt, ot, lse)
+        )
+        with jax.named_scope("flash.dq.loop"):      # flash.bwd_plan's dq_path
+            return call(qt, kt, vt, gt, ot, lse)
     num_k = T // block_k
 
     def kv_idx(b, h, qi, ki):
@@ -739,7 +747,7 @@ def _flash_bwd_dq(qt, kt, vt, gt, ot, lse, *, path: str, causal: bool,
                             lambda b, h, qi, ki: (b, h, qi, 0))
 
     kv_blk = pl.BlockSpec((1, 1, block_k, D), kv_idx)
-    return pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(_bwd_dq_stream_kernel, num_k=num_k, scale=scale,
                           causal=causal, window=window),
         grid=(B, H, S // block_q, num_k),
@@ -748,7 +756,9 @@ def _flash_bwd_dq(qt, kt, vt, gt, ot, lse, *, path: str, causal: bool,
         out_specs=q_side(D),
         out_shape=jax.ShapeDtypeStruct((B, H, S, D), jnp.float32),
         interpret=_use_interpret(),
-    )(qt, kt, vt, gt, ot, lse).astype(qt.dtype)
+    )
+    with jax.named_scope("flash.dq.stream"):
+        return call(qt, kt, vt, gt, ot, lse).astype(qt.dtype)
 
 
 def _flash_pallas_bwd(res, g, *, causal: bool, block_q: int, block_k: int,

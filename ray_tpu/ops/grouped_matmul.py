@@ -91,11 +91,12 @@ def _backend():
 
 def _gmm(lhs, rhs, group_sizes, transpose_rhs=False):
     n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
-    return _backend().gmm(
-        lhs, rhs, group_sizes, preferred_element_type=lhs.dtype,
-        tiling=_fit(GMM_TILING, lhs.shape[0], lhs.shape[1], n,
-                    lhs.dtype.itemsize, halve_n=True),
-        transpose_rhs=transpose_rhs, interpret=_use_interpret())
+    with jax.named_scope("gmm.pallas"):
+        return _backend().gmm(
+            lhs, rhs, group_sizes, preferred_element_type=lhs.dtype,
+            tiling=_fit(GMM_TILING, lhs.shape[0], lhs.shape[1], n,
+                        lhs.dtype.itemsize, halve_n=True),
+            transpose_rhs=transpose_rhs, interpret=_use_interpret())
 
 
 @jax.custom_vjp
@@ -113,10 +114,12 @@ def _pallas_bwd(res, g):
     d_lhs = _gmm(g, rhs, group_sizes, transpose_rhs=True)
     # tgmm takes lhs as [K, M] and transposes it back itself: XLA cancels
     # the pair, the kernel reads lhs as it lies
-    d_rhs = _backend().tgmm(
-        lhs.swapaxes(0, 1), g, group_sizes, preferred_element_type=rhs.dtype,
-        tiling=_fit(TGMM_TILING, lhs.shape[0], lhs.shape[1], g.shape[1]),
-        num_actual_groups=rhs.shape[0], interpret=_use_interpret())
+    with jax.named_scope("tgmm.pallas"):
+        d_rhs = _backend().tgmm(
+            lhs.swapaxes(0, 1), g, group_sizes,
+            preferred_element_type=rhs.dtype,
+            tiling=_fit(TGMM_TILING, lhs.shape[0], lhs.shape[1], g.shape[1]),
+            num_actual_groups=rhs.shape[0], interpret=_use_interpret())
     return d_lhs, d_rhs, None
 
 
@@ -132,6 +135,7 @@ def grouped_matmul(lhs, rhs, group_sizes, impl: str = "xla"):
     if impl != "xla":
         raise ValueError(f"grouped_matmul impl must be 'xla' or 'pallas', "
                          f"got {impl!r}")
-    return jax.lax.ragged_dot(
-        lhs, rhs, group_sizes.astype(jnp.int32),
-        preferred_element_type=jnp.float32).astype(lhs.dtype)
+    with jax.named_scope("gmm.xla"):    # jax transposes it itself
+        return jax.lax.ragged_dot(
+            lhs, rhs, group_sizes.astype(jnp.int32),
+            preferred_element_type=jnp.float32).astype(lhs.dtype)

@@ -325,7 +325,7 @@ def _forward_call(u, bm, cm, col, row, *, chunk: int, heads: int, width: int):
     H, N = col.shape[2], bm.shape[2]
     wide, shared, colspec, rowspec, state = _specs(B, S, H, width, N, chunk,
                                                    heads)
-    return pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(_fwd_kernel, heads=heads, width=width),
         grid=(B, H // heads, S // chunk),
         in_specs=[wide, shared, shared, colspec, rowspec],
@@ -336,7 +336,9 @@ def _forward_call(u, bm, cm, col, row, *, chunk: int, heads: int, width: int):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_use_interpret(),
-    )(u, bm, cm, col, row)
+    )
+    with jax.named_scope("ssd.fwd.pallas"):         # ssd.plan's path
+        return call(u, bm, cm, col, row)
 
 
 def _backward_call(u, bm, cm, col, row, h_in, dy, *, chunk: int, heads: int,
@@ -350,7 +352,7 @@ def _backward_call(u, bm, cm, col, row, h_in, dy, *, chunk: int, heads: int,
     part = rev(pl.BlockSpec((1, 1, chunk, N), lambda b, h, c: (b, h, c, 0)))
     dcol = rev(pl.BlockSpec((1, 1, chunk, H), lambda b, h, c: (b, h, c, 0)))
     f32 = jnp.float32
-    du, db, dc, dcols, drow = pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(_bwd_kernel, heads=heads, width=width),
         grid=(B, blocks, n_chunks),
         in_specs=[wide, shared, shared, colspec, rowspec, state, wide],
@@ -364,7 +366,9 @@ def _backward_call(u, bm, cm, col, row, h_in, dy, *, chunk: int, heads: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_use_interpret(),
-    )(u, bm, cm, col, row, h_in, dy)
+    )
+    with jax.named_scope("ssd.bwd.pallas"):
+        du, db, dc, dcols, drow = call(u, bm, cm, col, row, h_in, dy)
     # a block's heads stand in their own lanes of dcols, zeros elsewhere
     return (du, db.sum(axis=1).astype(bm.dtype),
             dc.sum(axis=1).astype(cm.dtype), dcols.sum(axis=1), drow)
@@ -474,9 +478,10 @@ def ssd_scan(x, dt, a, bm, cm, *, chunk: int = 256, impl: str = "xla"):
     f32 = jnp.float32
     u, col, row = _prologue(x.reshape(B, S, H * P), dt, a, chunk)
     if impl == "xla":
-        y = _scan_xla(_chunks(u.reshape(x.shape), chunk).astype(f32),
-                      _chunks(bm, chunk).astype(f32),
-                      _chunks(cm, chunk).astype(f32), _chunks(col, chunk))
+        with jax.named_scope("ssd.fwd.xla"):    # jax transposes it itself
+            y = _scan_xla(_chunks(u.reshape(x.shape), chunk).astype(f32),
+                          _chunks(bm, chunk).astype(f32),
+                          _chunks(cm, chunk).astype(f32), _chunks(col, chunk))
         return y.reshape(B, S, H, P).astype(x.dtype)
     heads = p["heads_per_block"]
     if heads % 2:

@@ -52,6 +52,12 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ray_tpu.parallel.sharding import ShardingRules, mesh_axes
 
+# the named scope round each helper's shard_map: ``tp.`` and the ``path``
+# that the instant ``tp.overlap_plan`` reports when these run (the plain
+# program calls none of them), so a trace's permutes and half-row matmuls
+# say which plan issued them
+SCOPE = "tp.overlap"
+
 
 @dataclass(frozen=True)
 class OverlapPlan:
@@ -201,11 +207,12 @@ def allgather_matmul(h, ws: Sequence[Any], plan: OverlapPlan,
         return tuple(_join(tuple(p[k] for p in parts), plan)
                      for k in range(len(ws)))
 
-    return jax.shard_map(
-        shard, mesh=plan.mesh,
-        in_specs=(plan.rows(), P()) + (P(None, plan.axis),) * len(ws),
-        out_specs=(plan.columns(),) * len(ws), check_vma=False)(
-            h, tuple(extras), *ws)
+    with jax.named_scope(SCOPE):
+        return jax.shard_map(
+            shard, mesh=plan.mesh,
+            in_specs=(plan.rows(), P()) + (P(None, plan.axis),) * len(ws),
+            out_specs=(plan.columns(),) * len(ws), check_vma=False)(
+                h, tuple(extras), *ws)
 
 
 def matmul_reduce_scatter(a, w, plan: OverlapPlan):
@@ -217,9 +224,11 @@ def matmul_reduce_scatter(a, w, plan: OverlapPlan):
     def shard(a, w):
         return _scattered(_split(a, plan, 1), w, plan)
 
-    return jax.shard_map(
-        shard, mesh=plan.mesh, in_specs=(plan.columns(), P(plan.axis, None)),
-        out_specs=plan.rows(), check_vma=False)(a, w)
+    with jax.named_scope(SCOPE):
+        return jax.shard_map(
+            shard, mesh=plan.mesh,
+            in_specs=(plan.columns(), P(plan.axis, None)),
+            out_specs=plan.rows(), check_vma=False)(a, w)
 
 
 def gather_apply_scatter(h, ws: Sequence[Any], fn: Callable, w_out,
@@ -236,8 +245,9 @@ def gather_apply_scatter(h, ws: Sequence[Any], fn: Callable, w_out,
         # mid[t]: the rows of shard i - t; the scatter wants i - 1, ..., i
         return _scattered(mid[1:] + mid[:1], w_out, plan)
 
-    return jax.shard_map(
-        shard, mesh=plan.mesh,
-        in_specs=(plan.rows(), P(plan.axis, None))
-        + (P(None, plan.axis),) * len(ws),
-        out_specs=plan.rows(), check_vma=False)(h, w_out, *ws)
+    with jax.named_scope(SCOPE):
+        return jax.shard_map(
+            shard, mesh=plan.mesh,
+            in_specs=(plan.rows(), P(plan.axis, None))
+            + (P(None, plan.axis),) * len(ws),
+            out_specs=plan.rows(), check_vma=False)(h, w_out, *ws)

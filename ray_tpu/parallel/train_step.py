@@ -138,7 +138,12 @@ def make_train_step(loss_fn: Callable, optimizer, mesh, rules: ShardingRules,
     models/latent.py): it runs after the optimizer's update, inside the
     same jitted step, and takes out of ``aux`` what is no scalar to hand
     on. The optimizer is told to leave those leaves alone (``hold_out``).
-    Without a rule the program is the one it was."""
+    Without a rule the program is the one it was.
+
+    Everything after the gradient lies in the named scope ``optimizer``
+    (the rule in ``optimizer/rule``, the norm in ``optimizer/grad_norm``):
+    with the model's own scopes (models/llama.py) every device op of the
+    step says which part issued it."""
     batch_sh = (batch_sharding(mesh, rules, batch_shapes)
                 if batch_shapes is not None else None)
 
@@ -149,13 +154,16 @@ def make_train_step(loss_fn: Callable, optimizer, mesh, rules: ShardingRules,
 
         (loss, aux), grads = jax.value_and_grad(lf, has_aux=True)(
             state.params)
-        updates, opt_state = optimizer.update(grads, state.opt_state,
-                                              state.params)
-        params = jax.tree.map(lambda p, u: p + u.astype(p.dtype),
-                              state.params, updates)
-        if post_update is not None:
-            params, aux = post_update(params, aux)
-        gnorm = optax_global_norm(grads)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(grads, state.opt_state,
+                                                  state.params)
+            params = jax.tree.map(lambda p, u: p + u.astype(p.dtype),
+                                  state.params, updates)
+            if post_update is not None:
+                with jax.named_scope("rule"):
+                    params, aux = post_update(params, aux)
+            with jax.named_scope("grad_norm"):
+                gnorm = optax_global_norm(grads)
         metrics = {**aux, "loss": loss, "grad_norm": gnorm,
                    "step": state.step + 1}
         return TrainState(params, opt_state, state.step + 1), metrics

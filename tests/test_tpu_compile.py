@@ -230,14 +230,9 @@ def _array_bytes(types: str) -> int:
                                          r"\[([\d,]*)\]", types))
 
 
-def _entry_ops(text):
-    """[(opcode, result type, bytes read, bytes written, is a matmul)] of
-    the entry computation of a compiled module. A fusion reads each
-    operand once, one that its body only slices at the slices' size; a
-    fusion whose body holds a convolution or whose kind is kOutput is a
-    matmul fusion. Any other op is listed with its result's bytes both
-    ways (a copy, a slice, a broadcast, a convert left outside every
-    fusion is a pass over memory of its own)."""
+def _computations(text):
+    """{name: [instruction lines]} of a compiled module's computations,
+    the entry under ``ENTRY``."""
     import re
 
     comps, cur = {}, None
@@ -249,6 +244,20 @@ def _entry_ops(text):
             cur = None
         elif cur is not None:
             cur.append(ln.strip())
+    return comps
+
+
+def _entry_ops(text):
+    """[(opcode, result type, bytes read, bytes written, is a matmul)] of
+    the entry computation of a compiled module. A fusion reads each
+    operand once, one that its body only slices at the slices' size; a
+    fusion whose body holds a convolution or whose kind is kOutput is a
+    matmul fusion. Any other op is listed with its result's bytes both
+    ways (a copy, a slice, a broadcast, a convert left outside every
+    fusion is a pass over memory of its own)."""
+    import re
+
+    comps = _computations(text)
     ops = []
     for ln in comps["ENTRY"]:
         m = re.match(r"^(?:ROOT )?%?[\w.\-]+ = (.*?) ([\w\-]+)\(", ln)
@@ -377,9 +386,12 @@ def _glm_attention_block(one_chip):
         "wkv_b", "wo")}
     cos, sin = llama._rope_tables(cfg.rope_theta, S, cfg.rope_dim)
 
+    def half(x, lp):
+        with jax.named_scope("attention"):      # as llama._layer opens it
+            return latent.attention_half(x, lp, cfg, cos, sin)
+
     def loss(x, lp):
-        y = jax.checkpoint(lambda x, lp: latent.attention_half(
-            x, lp, cfg, cos, sin))(x, lp)
+        y = jax.checkpoint(half)(x, lp)
         return jnp.sum(y.astype(jnp.float32) ** 2)
 
     try:
@@ -467,6 +479,46 @@ def test_a_latent_attention_halfs_passes_at_glm_widths(one_chip,
         "copy", "slice", "broadcast", "pad", "concatenate", "convert",
         "transpose"))
     assert passes + alone < 2.5e9 < 0.6 * 12.76e9, (passes, alone)
+
+
+def test_latent_block_keeps_its_scopes_through_the_chips_compiler(
+        one_chip, on_chip_branch):
+    """The same compiled block, read as a chip trace's labels are
+    (``benchmark/op_scopes.py``; an instruction's ``op_name`` is the
+    ``tf_op`` of its events): the TPU compiler's fusion and layout passes
+    leave the scope ``attention`` on every fusion that holds a convolution
+    and on every Mosaic call, each call in the kernel-call scope of the
+    plan it took, and forward, replay and backward are all there. What
+    carries no ``op_name`` at all is the compiler's own (copies, bitcasts,
+    tuple plumbing): its share of the entry's instructions is printed."""
+    import re
+
+    from benchmark import op_scopes
+
+    comps = _computations(_glm_attention_block(one_chip)[3])
+    entry = [ln for ln in comps["ENTRY"] if " = " in ln]
+    matmuls, calls, passes, bare = 0, {}, set(), 0
+    for ln in entry:
+        name = re.search(r'op_name="([^"]*)"', ln)
+        parts = op_scopes.elements(name.group(1) if name else "")
+        bare += name is None
+        body = re.search(r" fusion\(.*calls=%?([\w.\-]+)", ln)
+        if body and any(" convolution(" in b for b in comps[body.group(1)]):
+            matmuls += 1
+            assert op_scopes.bucket(parts) == "attention", ln[:300]
+            passes.add(op_scopes.which_pass(parts))
+        if 'custom_call_target="tpu_custom_call"' in ln:
+            assert op_scopes.bucket(parts) == "attention", ln[:300]
+            calls[op_scopes.kernel_scope(parts)] = \
+                calls.get(op_scopes.kernel_scope(parts), 0) + 1
+    assert matmuls >= 20 and passes == {"forward", "replay", "backward"}, (
+        matmuls, passes)
+    # the replay makes the forward call again here: nothing of this
+    # block's checkpoint keeps ``o`` and ``lse`` by name
+    assert calls == {"flash.fwd.stream": 2, "flash.dq.stream": 1,
+                     "flash.dkdv.stream": 1}, calls
+    print(f"latent block: {bare} of {len(entry)} entry instructions carry "
+          f"no op_name ({100.0 * bare / len(entry):.1f}%)")
 
 
 def test_flash_compiles_with_a_stated_scale_and_grouped_heads(
