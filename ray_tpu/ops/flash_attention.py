@@ -1,23 +1,40 @@
 """Fused causal attention (flash-style) for TPU in Pallas.
 
-Forward: one kernel instance per (batch, head, q-block); the q-block stays in
-VMEM while K/V stream through in chunks with the online-softmax recurrence —
-O(S) memory instead of O(S^2), and the QK^T / PV matmuls hit the MXU at
-[block_q x head_dim] x [head_dim x block_k] granularity. Two kernels, told
-apart by the shape alone (`kv_plan`; the instant `flash.fwd_plan`): `loop`
-holds a head's whole K and V as blocks and walks them itself, where those
-blocks, double-buffered, and a step's temporaries fit the 16 MiB a Mosaic
-call gets that asks for no more (S 8192 at D 128); `stream` has the
-k-blocks on a grid axis, O(block) VMEM at any S and D (S 8192 at D 256:
-the loop's blocks alone are 16 MiB), and serves every windowed call.
+Forward: one kernel (`_fwd_kernel`), grid (batch, head, q-block, span): the
+q-block stays in VMEM while K/V arrive a SPAN of k-blocks a grid step and
+the kernel walks the span's blocks of the causal/window band itself with
+the online-softmax recurrence -- O(S) memory instead of O(S^2), and the
+QK^T / PV matmuls hit the MXU at [block_q x head_dim] x [head_dim x
+block_k] granularity. What a grid step holds is chosen by the shape alone
+(`kv_plan`; the instant `flash.fwd_plan`):
+  loop    one span: a head's whole K and V are blocks of the call, fetched
+          once a head, where those blocks, double-buffered, and a step's
+          temporaries fit the 16 MiB a Mosaic call gets that asks for no
+          more (S 8192 at D 128). The sums are the loop's carry, one block
+          an iteration.
+  stream  several spans, through an index map clamped into the band (a
+          span outside it fetches nothing): the longest span that fits (S
+          8192 at D 256, where the loop's blocks alone are 16 MiB: 4,096
+          keys, two grid steps a q-block where one block a step made
+          sixteen; a grid step costs about a microsecond whatever it
+          does), the sums in VMEM scratch, read and written once a step of
+          the walk, and TWO k-blocks a step of the walk where their
+          temporaries fit: both blocks' `q k^T` are issued before the
+          first block's softmax, two chains in one straight-line body.
+          Serves every windowed call.
+Each block makes its own online-softmax update in rising order on every
+plan, so all of them agree to the last bit (on the chip: with the kernels
+of one block a grid step they replaced, PERF.md 6, PR 37).
 
 Backward: full Pallas two-kernel backward (FlashAttention-2 style), both
 recomputing probabilities from the saved log-sum-exp so nothing O(S^2) is
-ever materialized. The dQ pass keeps a q-block resident and loops over
-k-blocks: of a head's whole K and V held as blocks (`loop`, chosen by the
-forward's bytes) or with the k-blocks on a grid axis, clamped into the
-band, dq accumulated in the float32 output block (`stream`); both run
-`_dq_step` in the same order and agree to the last bit. The dK/dV pass is
+ever materialized. The dQ pass (`_dq_kernel`) is the forward's walk: a
+q-block resident, K and V a span a grid step by the forward's plan
+(`loop`: dq is the loop's carry, written once in q's dtype; `stream`: dq
+accumulated in the float32 output block, which stays resident across a
+q-block's spans), two k-blocks a step of the walk where their
+temporaries fit beside the span (their `q k^T` and `g v^T` first); every
+plan sums in the same order. The dK/dV pass is
 its mirror image and has two block plans, told apart by the shape alone
 (`bwd_dkdv_plan`; the choices are the instant `flash.bwd_plan` of a trace):
   resident  one instance per (b, h, k-block); q, dO, o and lse of the
@@ -87,277 +104,33 @@ def _scale(scale, head_dim: int) -> float:
     return head_dim ** -0.5 if scale is None else float(scale)
 
 
-def _fwd_kernel_loop(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
-                     scale: float, causal: bool):
-    """Full-K/V-resident variant: one grid instance per q-block streams
-    k-blocks in a fori_loop. Fewer grid steps than the ki-minor kernel —
-    faster at short/medium S where per-step overhead dominates; the
-    ki-minor streaming kernel wins for windowed long-S (it never fetches
-    out-of-band K/V)."""
-    # q_ref: [1, 1, block_q, D]; k_ref/v_ref: [1, 1, T, D]
-    block_q, D = q_ref.shape[2], q_ref.shape[3]
-    T = k_ref.shape[2]
-    qi = pl.program_id(2)
-    # operands keep the input dtype (bf16 MXU rate); f32 accumulation
-    q = q_ref[0, 0]
-
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
-
-    def body(ki, carry):
-        o, m, l = carry
-        k = k_ref[0, 0, pl.ds(ki * block_k, block_k), :]
-        v = v_ref[0, 0, pl.ds(ki * block_k, block_k), :]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if causal:
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + jnp.sum(p, axis=1, keepdims=True)
-        o_new = o * alpha + jax.lax.dot(p.astype(v.dtype), v,
-                                        preferred_element_type=jnp.float32)
-        return o_new, m_new, l_new
-
-    o0 = jnp.zeros((block_q, D), jnp.float32)
-    m0 = jnp.full((block_q, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((block_q, 1), jnp.float32)
-    if causal:
-        # only k-blocks at or before this q-block contribute
-        num_k = jax.lax.div((qi + 1) * block_q + block_k - 1, block_k)
-    else:
-        num_k = T // block_k
-    o, m, l = jax.lax.fori_loop(0, num_k, body, (o0, m0, l0))
-    l = jnp.maximum(l, 1e-30)
-    o_ref[0, 0] = (o / l).astype(o_ref.dtype)
-    # Lane-broadcast (Mosaic wants last-dim 128 blocks; official TPU flash
-    # kernel stores l/m the same way).
-    lse_ref[0, 0] = jnp.broadcast_to(m + jnp.log(l), (block_q, 128))
-
-
-def _flash_fwd_loop(q, k, v, *, causal: bool, block_q: int, block_k: int,
-                    scale: float):
-    B, S, H, D = q.shape
-    T, KV = k.shape[1], k.shape[2]
-    groups = H // KV
-    qt = q.transpose(0, 2, 1, 3)
-    kt = k.transpose(0, 2, 1, 3)
-    vt = v.transpose(0, 2, 1, 3)
-    block_q = min(block_q, S)
-    block_k = min(block_k, T)
-    grid = (B, H, S // block_q)
-
-    call = pl.pallas_call(
-        functools.partial(_fwd_kernel_loop, block_k=block_k, scale=scale,
-                          causal=causal),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, D),
-                         lambda b, h, i: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, T, D),
-                         lambda b, h, i, g=groups: (b, h // g, 0, 0)),
-            pl.BlockSpec((1, 1, T, D),
-                         lambda b, h, i, g=groups: (b, h // g, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, block_q, D), lambda b, h, i: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, block_q, 128), lambda b, h, i: (b, h, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
-            jax.ShapeDtypeStruct((B, H, S, 128), jnp.float32),
-        ],
-        interpret=_use_interpret(),
-    )
-    with jax.named_scope("flash.fwd.loop"):     # flash.fwd_plan's path
-        out, lse = call(qt, kt, vt)
-    return out.transpose(0, 2, 1, 3), lse
-
-
-def _fwd_kernel_stream(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr,
-                       l_scr, *, block_q: int, block_k: int, scale: float,
-                       causal: bool, window: int, num_k: int):
-    """ki-minor streaming variant: grid (B, H, q-blocks, k-blocks).
-    K/V arrive one block per step through a CLAMPED index_map, so blocks
-    outside the causal/window band are never fetched (Mosaic elides the
-    DMA when the block index repeats) — O(S*W) HBM traffic for sliding
-    windows instead of O(S*T). acc/m/l live in VMEM scratch across the
-    ki steps of one q-block (same structure as the official TPU flash
-    kernel); the last ki step normalizes and writes o/lse."""
-    qi = pl.program_id(2)
-    ki = pl.program_id(3)
-
-    @pl.when(ki == 0)
-    def _init():
-        acc[...] = jnp.zeros_like(acc)
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-
-    run = True
-    if causal:
-        run = qi * block_q + block_q > ki * block_k
-        if window > 0:
-            run = run & (qi * block_q < (ki + 1) * block_k + window)
-
-    @pl.when(run)
-    def _step():
-        # operands stay in the input dtype (bf16 on TPU: 8x the f32 MXU
-        # rate); the MXU accumulates in f32 via preferred_element_type —
-        # an f32 cast here made the whole kernel f32-matmul-bound
-        q = q_ref[0, 0]
-        k = k_ref[0, 0]
-        v = v_ref[0, 0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, 1), 0)
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (1, block_k), 1)
-            keep = q_pos >= k_pos
-            if window > 0:
-                keep = keep & (q_pos - k_pos < window)
-            s = jnp.where(keep, s, NEG_INF)
-        m = m_scr[...][:, 0:1]
-        l = l_scr[...][:, 0:1]
-        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + jnp.sum(p, axis=1, keepdims=True)
-        # p joins v's dtype for the second MXU pass (f32 accumulation);
-        # standard flash practice, same as the official TPU kernel
-        acc[...] = acc[...] * alpha + jax.lax.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
-
-    @pl.when(ki == num_k - 1)
-    def _finish():
-        l = jnp.maximum(l_scr[...][:, 0:1], 1e-30)
-        o_ref[0, 0] = (acc[...] / l).astype(o_ref.dtype)
-        lse_ref[0, 0] = m_scr[...] + jnp.log(l)
-
-
-# VMEM a Mosaic call gets on a v5e core when it asks for no limit of its own
-_SCOPED_VMEM_BYTES = 16 * 2 ** 20
-
-
-def kv_plan(*, S: int, T: int, D: int, dtype, block_q: int,
-            block_k: int) -> dict:
-    """Which kernel a call that walks k-blocks for a resident q-block takes
-    (the forward, the dQ pass), and the bytes that decide it (also the
-    attributes of `flash.fwd_plan`). loop: K and V of a whole head are
-    blocks of the call, fetched once a head; taken where those blocks,
-    double-buffered by Mosaic (`kv_block_bytes`), with the q-side blocks
-    and the f32 temporaries of one step fit the VMEM a call gets without
-    asking (the compiler refused S 8192 x D 256 at 17.5 MiB of 16); stream
-    otherwise. Asking for more is not the way: a call's limit is taken out
-    of XLA's own fast memory for as long as the call is scheduled
-    (`bwd_dkdv_plan`)."""
-    itemsize = jnp.dtype(dtype).itemsize
-    kv_block_bytes = 2 * 2 * T * D * itemsize
-    loop_bytes = (
-        kv_block_bytes
-        # q, dO, o, the result and the 128-lane lse, double-buffered
-        + 2 * block_q * (4 * D * itemsize + _LSE_LANES * 4)
-        # two [block_q, block_k] of s and p; the accumulator and a k-block
-        + 4 * (2 * block_q * block_k + (block_q + block_k) * D))
-    return dict(path="loop" if loop_bytes <= _SCOPED_VMEM_BYTES else "stream",
-                S=S, D=D, kv_block_bytes=kv_block_bytes,
-                loop_bytes=loop_bytes)
-
-
-def _flash_fwd(q, k, v, *, causal: bool, block_q: int, block_k: int,
-               scale: float, window: int = 0):
-    B, S, H, D = q.shape
-    plan = kv_plan(S=S, T=k.shape[1], D=D, dtype=k.dtype,
-                   block_q=min(block_q, S), block_k=min(block_k, k.shape[1]))
+def _mask(s, q_pos, ki, *, block_k: int, window: int):
+    """Scores [block_q, block_k] of k-block `ki` with NEG_INF where a row
+    (positions q_pos [block_q, 1]) does not see the key."""
+    k_pos = ki * block_k + jax.lax.broadcasted_iota(
+        jnp.int32, (1, block_k), 1)
+    keep = q_pos >= k_pos
     if window > 0:
-        plan["path"] = "stream"     # never fetches k-blocks out of the band
-    tracing.instant("flash.fwd_plan", {
-        n: plan[n] for n in ("path", "S", "D", "kv_block_bytes")})
-    if plan["path"] == "loop":
-        # plain causal/full: the q-block loop kernel has 1/num_k the
-        # grid steps — faster where per-step overhead dominates
-        return _flash_fwd_loop(q, k, v, causal=causal, block_q=block_q,
-                               block_k=block_k, scale=scale)
-    T, KV = k.shape[1], k.shape[2]
-    groups = H // KV
-    # layout: [B, H, S, D] per-instance slices
-    qt = q.transpose(0, 2, 1, 3)
-    kt = k.transpose(0, 2, 1, 3)
-    vt = v.transpose(0, 2, 1, 3)
-    block_q = min(block_q, S)
-    block_k = min(block_k, T)
-    num_k = T // block_k
-    grid = (B, H, S // block_q, num_k)
-
-    def kv_idx(b, h, qi, ki, g=groups):
-        # clamp into the band: out-of-band steps repeat a neighboring
-        # index, so Mosaic elides their K/V DMA entirely
-        j = ki
-        if causal:
-            hi = jax.lax.div(qi * block_q + block_q - 1, block_k)
-            j = jax.lax.min(j, hi)
-            if window > 0:
-                lo = jax.lax.max(
-                    0, jax.lax.div(qi * block_q - window + 1, block_k))
-                j = jax.lax.max(j, lo)
-        return (b, h // g, j, 0)
-
-    call = pl.pallas_call(
-        functools.partial(_fwd_kernel_stream, block_q=block_q,
-                          block_k=block_k, scale=scale, causal=causal,
-                          window=window, num_k=num_k),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, D),
-                         lambda b, h, qi, ki: (b, h, qi, 0)),
-            pl.BlockSpec((1, 1, block_k, D), kv_idx),
-            pl.BlockSpec((1, 1, block_k, D), kv_idx),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, block_q, D),
-                         lambda b, h, qi, ki: (b, h, qi, 0)),
-            pl.BlockSpec((1, 1, block_q, 128),
-                         lambda b, h, qi, ki: (b, h, qi, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
-            jax.ShapeDtypeStruct((B, H, S, 128), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, D), jnp.float32),     # acc
-            pltpu.VMEM((block_q, 128), jnp.float32),   # running max
-            pltpu.VMEM((block_q, 128), jnp.float32),   # running sum
-        ],
-        interpret=_use_interpret(),
-    )
-    with jax.named_scope("flash.fwd.stream"):
-        out, lse = call(qt, kt, vt)
-    return out.transpose(0, 2, 1, 3), lse
+        keep = keep & (q_pos - k_pos < window)
+    return jnp.where(keep, s, NEG_INF)
 
 
-def _dq_step(q, k, v, g, lse, delta, q_pos, ki, *, block_k: int, scale: float,
-             causal: bool, window: int):
-    """What k-block `ki` adds to the dQ of a q-block, [block_q, D] f32,
-    from blocks already cast to f32 (lse, delta and the rows' positions
-    q_pos [block_q, 1]). The one accumulate step of both dQ kernels."""
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    if causal:
-        k_pos = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_k), 1)
-        keep = q_pos >= k_pos
-        if window > 0:
-            keep = keep & (q_pos - k_pos < window)
-        s = jnp.where(keep, s, NEG_INF)
-    p = jnp.exp(s - lse)
-    dp = jax.lax.dot_general(g, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    ds = p * (dp - delta) * scale
-    return jax.lax.dot(ds, k, preferred_element_type=jnp.float32)
+def _walk(lo, hi, carry, step, in_flight: int):
+    """`step(carry, ki, n)` over the k-blocks [lo, hi) in rising order:
+    `n = in_flight` blocks a call while that many are left, then one a
+    call. `step` issues the products that need nothing of the sums for
+    all its n blocks before the first block's vector work (independent
+    chains in one straight-line body, for the scheduler to interleave),
+    then updates the sums block by block in order: they are those of a
+    walk one block at a time, to the last bit."""
+    n = in_flight
+    groups = jax.lax.div(hi - lo, n)
+    carry = jax.lax.fori_loop(
+        0, groups, lambda i, c: step(c, lo + n * i, n), carry)
+    if n > 1:
+        carry = jax.lax.fori_loop(
+            lo + n * groups, hi, lambda ki, c: step(c, ki, 1), carry)
+    return carry
 
 
 def _k_band(qi, *, num_k: int, block_q: int, block_k: int, causal: bool,
@@ -374,66 +147,349 @@ def _k_band(qi, *, num_k: int, block_q: int, block_k: int, causal: bool,
     return lo, hi
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, o_ref, lse_ref, dq_ref, *,
-                   block_k: int, scale: float, causal: bool, window: int):
-    """One instance per (b, h, q-block): K/V of the whole head are its
-    blocks, dQ accumulates over the k-blocks of the band
-    (FlashAttention-2 backward, dQ pass). delta = rowsum(o * dO) is
-    computed in-kernel from the resident blocks."""
-    block_q, D = q_ref.shape[2], q_ref.shape[3]
-    qi = pl.program_id(2)
-    q = q_ref[0, 0].astype(jnp.float32)
-    g = g_ref[0, 0].astype(jnp.float32)
-    o = o_ref[0, 0].astype(jnp.float32)
-    lse = lse_ref[0, 0][:, 0:1]
-    delta = jnp.sum(o * g, axis=-1, keepdims=True)
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
-
-    def body(ki, dq):
-        k = k_ref[0, 0, pl.ds(ki * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, 0, pl.ds(ki * block_k, block_k), :].astype(jnp.float32)
-        return dq + _dq_step(q, k, v, g, lse, delta, q_pos, ki,
-                             block_k=block_k, scale=scale, causal=causal,
-                             window=window)
-
-    lo, hi = _k_band(qi, num_k=k_ref.shape[2] // block_k, block_q=block_q,
-                     block_k=block_k, causal=causal, window=window)
-    dq = jax.lax.fori_loop(lo, hi, body, jnp.zeros((block_q, D), jnp.float32))
-    dq_ref[0, 0] = dq.astype(dq_ref.dtype)
-
-
-def _bwd_dq_stream_kernel(q_ref, k_ref, v_ref, g_ref, o_ref, lse_ref, dq_ref,
-                          *, num_k: int, scale: float, causal: bool,
-                          window: int):
-    """Grid (b, h, q-block, k-block): the f32 dq output block is constant
-    in the (minor) k axis, so Mosaic keeps it resident and this accumulates
-    across sequential k steps, in the loop kernel's order: O(block) VMEM at
-    any sequence length and head width. K and V arrive one block a step
-    through an index map clamped into the band (`_flash_bwd_dq`), so a step
-    outside it fetches nothing."""
-    block_q, block_k = q_ref.shape[2], k_ref.shape[2]
-    qi = pl.program_id(2)
-    ki = pl.program_id(3)
-
-    @pl.when(ki == 0)
-    def _zero():
-        dq_ref[0, 0] = jnp.zeros_like(dq_ref[0, 0])
-
+def _span_band(qi, si, *, span: int, num_k: int, block_q: int, block_k: int,
+               causal: bool, window: int):
+    """[lo, hi), traced: the k-blocks of q-block `qi`'s band that grid
+    step `si` holds, at `span` blocks a step."""
     lo, hi = _k_band(qi, num_k=num_k, block_q=block_q, block_k=block_k,
                      causal=causal, window=window)
+    return (jax.lax.max(jnp.int32(lo), si * span),
+            jax.lax.min(jnp.int32(hi), (si + 1) * span))
 
-    @pl.when((ki >= lo) & (ki < hi))
-    def _accumulate():
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, block_k: int,
+                num_k: int, scale: float, causal: bool, window: int,
+                in_flight: int):
+    """Grid (b, h, q-block, span): the q-block stays, K and V arrive a
+    span of k-blocks a grid step through an index map clamped into the
+    band (`_k_span_index`: a span outside it repeats its neighbour's index
+    and Mosaic, which elides a fetch whose index repeats, fetches nothing:
+    O(S*W) HBM traffic for sliding windows instead of O(S*T)), and the
+    kernel walks the span's blocks of the band itself (`_walk`).
+    One span (the `loop` plan: a head's whole K and V): the accumulator,
+    the running max and the running sum are the loop's carry, one block an
+    iteration. Several (`stream`): the three live in VMEM scratch across
+    the grid steps of a q-block (same structure as the official TPU flash
+    kernel) and a step of the walk reads and writes them once, not once a
+    block; the last grid step normalizes and writes o and lse."""
+    block_q, D = q_ref.shape[2], q_ref.shape[3]
+    span = k_ref.shape[2] // block_k
+    spans = num_k // span
+    qi, si = pl.program_id(2), pl.program_id(3)
+    lo, hi = _span_band(qi, si, span=span, num_k=num_k, block_q=block_q,
+                        block_k=block_k, causal=causal, window=window)
+
+    def keep(o, m, l):
+        acc, m_scr, l_scr = scratch
+        acc[...] = o
+        m_scr[...] = jnp.broadcast_to(m, m_scr.shape)
+        l_scr[...] = jnp.broadcast_to(l, l_scr.shape)
+
+    def kept():
+        acc, m_scr, l_scr = scratch
+        return acc[...], m_scr[...][:, 0:1], l_scr[...][:, 0:1]
+
+    def walk(carry):
+        # operands stay in the input dtype (bf16 on TPU: 8x the f32 MXU
+        # rate); the MXU accumulates in f32 via preferred_element_type --
+        # an f32 cast here made the whole kernel f32-matmul-bound
+        q = q_ref[0, 0]
+        q_pos = qi * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, 1), 0)
+
+        def block(ref, ki):
+            rows = pl.multiple_of((ki - si * span) * block_k, block_k)
+            return ref[0, 0, pl.ds(rows, block_k), :]
+
+        def step(carry, ki, n):
+            kv = [(block(k_ref, ki + j), block(v_ref, ki + j))
+                  for j in range(n)]
+            scores = [jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale for k, _ in kv]
+            o, m, l = carry if spans == 1 else kept()
+            for j, ((_, v), s) in enumerate(zip(kv, scores)):
+                if causal:
+                    s = _mask(s, q_pos, ki + j, block_k=block_k,
+                              window=window)
+                m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+                p = jnp.exp(s - m_new)
+                alpha = jnp.exp(m - m_new)
+                l = l * alpha + jnp.sum(p, axis=1, keepdims=True)
+                # p joins v's dtype for the second MXU pass (f32
+                # accumulation); standard flash practice, same as the
+                # official TPU kernel
+                o = o * alpha + jax.lax.dot(
+                    p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+                m = m_new
+            if spans == 1:
+                return o, m, l
+            keep(o, m, l)
+            return carry
+
+        return _walk(lo, hi, carry, step, in_flight)
+
+    def finish(o, m, l):
+        l = jnp.maximum(l, 1e-30)
+        o_ref[0, 0] = (o / l).astype(o_ref.dtype)
+        # Lane-broadcast (Mosaic wants last-dim 128 blocks; official TPU
+        # flash kernel stores l/m the same way).
+        lse_ref[0, 0] = jnp.broadcast_to(m + jnp.log(l),
+                                         (block_q, _LSE_LANES))
+
+    zero = (jnp.zeros((block_q, D), jnp.float32),
+            jnp.full((block_q, 1), NEG_INF, jnp.float32),
+            jnp.zeros((block_q, 1), jnp.float32))
+    if spans == 1:
+        finish(*walk(zero))
+        return
+
+    @pl.when(si == 0)
+    def _init():
+        keep(*zero)
+
+    @pl.when(lo < hi)
+    def _span():
+        walk(0)
+
+    @pl.when(si == spans - 1)
+    def _finish():
+        finish(*kept())
+
+
+# VMEM a Mosaic call gets on a v5e core when it asks for no limit of its own
+_SCOPED_VMEM_BYTES = 16 * 2 ** 20
+
+
+def kv_plan(*, S: int, T: int, D: int, dtype, block_q: int, block_k: int,
+            window: int = 0, call: str = "fwd") -> dict:
+    """How a call that walks k-blocks for a resident q-block (`call`: the
+    forward `fwd`, the dQ pass `dq`) holds K and V, and the bytes that
+    decide it (the attributes of `flash.fwd_plan`; the dQ call's are in
+    `flash.bwd_plan`).
+
+    path  loop: K and V of a whole head are blocks of the call, fetched
+          once a head; taken where those blocks, double-buffered by
+          Mosaic (`kv_block_bytes`), with the q-side blocks and the f32
+          temporaries of one block's step (`loop_bytes`) fit the VMEM a
+          call gets without asking (the compiler refused S 8192 x D 256
+          at 17.5 MiB of 16); stream otherwise, and for a windowed
+          forward. Asking for more is not the way: a call's limit is
+          taken out of XLA's own fast memory for as long as the call is
+          scheduled (`bwd_dkdv_plan`).
+    span  keys of K and V a grid step holds: T on the loop plan; on the
+          stream plan the most k-blocks (a divisor of their number, no
+          more than cover a window) whose step fits (`walk_bytes`). A grid
+          step costs about a microsecond whatever it does (S 8192, D 256
+          on a v5e: 256 steps a head of one block 14.56 ms a forward
+          call, 32 of eight 11.33; PERF.md 6, PR 37), so the longest span
+          comes first.
+    in_flight  k-blocks a step of the walk takes (`_walk`): 2 where the
+          span leaves a second block's temporaries room, else 1; 1 in the
+          loop plan's forward, whose carry of three arrays makes the
+          second loop (the odd block's) cost more than the pairs win
+          (S 4096, D 128: 5.62 ms a call against 5.57).
+
+    `walk_bytes` is above what Mosaic planned at every shape compiled for
+    a v5e (the least `vmem_limit_bytes` it accepted, to a quarter MiB, PR
+    37: D 128 and 256, spans of 1 to 16 blocks, by 0.2 to 1.9 MiB)."""
+    itemsize = jnp.dtype(dtype).itemsize
+    kv_block_bytes = 2 * 2 * T * D * itemsize
+    # q, dO, o, the result and the 128-lane lse, double-buffered
+    q_side_bytes = 2 * block_q * (4 * D * itemsize + _LSE_LANES * 4)
+    loop_bytes = (
+        kv_block_bytes + q_side_bytes
+        # two [block_q, block_k] of s and p; the accumulator and a k-block
+        + 4 * (2 * block_q * block_k + (block_q + block_k) * D))
+    path = "stream" if loop_bytes > _SCOPED_VMEM_BYTES or (
+        window > 0 and call == "fwd") else "loop"
+
+    def walk_bytes(blocks: int, in_flight: int) -> int:
+        scores = block_q * block_k
+        if call == "fwd":
+            # q, o and the 128-lane lse, double-buffered
+            q_side = 2 * block_q * (2 * D * itemsize + _LSE_LANES * 4)
+            # a block in flight: s in f32 and p in the operands' dtype,
+            # and the accumulator as it leaves it; the accumulator as it
+            # was, twice; the accumulator, running max and sum kept
+            # (scratch or carry)
+            step = (in_flight * scores * 6 + (2 + in_flight) * block_q * D * 4
+                    + 4 * block_q * (D + 2 * _LSE_LANES))
+        else:
+            # q, dO, o, the lse and the result (f32 where spans add up)
+            q_side = 2 * block_q * (
+                3 * D * itemsize + _LSE_LANES * 4
+                + D * (4 if path == "stream" else itemsize))
+            # a block in flight: s and dp, and k and v cast to f32; q and
+            # dO cast to f32, and the sum
+            step = (in_flight * (scores * 8 + 2 * block_k * D * 4)
+                    + 3 * block_q * D * 4)
+        return 2 * 2 * blocks * block_k * D * itemsize + q_side + step
+
+    num_k = T // block_k
+    most = num_k if window == 0 else min(num_k, -(-window // block_k))
+    spans = [num_k] if path == "loop" else [
+        n for n in range(most, 0, -1) if num_k % n == 0]
+    pairs = (1,) if (path, call) == ("loop", "fwd") else (2, 1)
+    blocks, in_flight = next(
+        ((n, f) for n in spans for f in pairs
+         if f <= n and walk_bytes(n, f) <= _SCOPED_VMEM_BYTES),
+        (spans[-1], 1))
+    return dict(path=path, S=S, D=D, kv_block_bytes=kv_block_bytes,
+                loop_bytes=loop_bytes, span=blocks * block_k,
+                in_flight=in_flight,
+                walk_bytes=walk_bytes(blocks, in_flight))
+
+
+def _k_span_index(qi, si, *, span: int, num_k: int, block_q: int,
+                  block_k: int, causal: bool, window: int):
+    """The span of K and V at grid step (qi, si) of a call that holds a
+    q-block and steps over spans of `span` k-blocks: si clamped into the
+    spans that hold a block of q-block qi's band, so a span outside it
+    repeats the index of the band's near edge and Mosaic, which elides a
+    fetch whose index repeats, fetches nothing."""
+    if not causal or span == num_k:
+        return si
+    lo, hi = _k_band(qi, num_k=num_k, block_q=block_q, block_k=block_k,
+                     causal=causal, window=window)
+    div, least, most = _index_ops(qi)
+    return most(least(si, div(hi - 1, span)), div(lo, span))
+
+
+def _walk_specs(*, groups: int, span: int, num_k: int, block_q: int,
+                block_k: int, D: int, causal: bool, window: int):
+    """Block specs of a call on grid (b, h, q-block, span): `q_side(width)`
+    for an operand or result that follows the q-block, and the spec of K
+    and V, `span` k-blocks a grid step by `_k_span_index`."""
+    def q_side(width):
+        return pl.BlockSpec((1, 1, block_q, width),
+                            lambda b, h, qi, si: (b, h, qi, 0))
+
+    def kv_idx(b, h, qi, si):
+        return (b, h // groups, _k_span_index(
+            qi, si, span=span, num_k=num_k, block_q=block_q,
+            block_k=block_k, causal=causal, window=window), 0)
+
+    return q_side, pl.BlockSpec((1, 1, span * block_k, D), kv_idx)
+
+
+def _flash_fwd(q, k, v, *, causal: bool, block_q: int, block_k: int,
+               scale: float, window: int = 0, plan: dict = None):
+    """The forward call by `kv_plan`'s plan (or the one given): o
+    [B, S, H, D] in q's dtype and lse [B, H, S, 128] f32."""
+    B, S, H, D = q.shape
+    T, KV = k.shape[1], k.shape[2]
+    groups = H // KV
+    block_q = min(block_q, S)
+    block_k = min(block_k, T)
+    if plan is None:
+        plan = kv_plan(S=S, T=T, D=D, dtype=k.dtype, block_q=block_q,
+                       block_k=block_k, window=window)
+        tracing.instant("flash.fwd_plan", {n: plan[n] for n in (
+            "path", "S", "D", "kv_block_bytes", "span", "in_flight")})
+    # layout: [B, H, S, D] per-instance slices
+    qt = q.transpose(0, 2, 1, 3)
+    kt = k.transpose(0, 2, 1, 3)
+    vt = v.transpose(0, 2, 1, 3)
+    num_k = T // block_k
+    span = plan["span"] // block_k
+    q_side, kv_blk = _walk_specs(
+        groups=groups, span=span, num_k=num_k, block_q=block_q,
+        block_k=block_k, D=D, causal=causal, window=window)
+    call = pl.pallas_call(
+        functools.partial(_fwd_kernel, block_k=block_k, num_k=num_k,
+                          scale=scale, causal=causal, window=window,
+                          in_flight=plan["in_flight"]),
+        grid=(B, H, S // block_q, num_k // span),
+        in_specs=[q_side(D), kv_blk, kv_blk],
+        out_specs=[q_side(D), q_side(_LSE_LANES)],
+        out_shape=[
+            jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
+            jax.ShapeDtypeStruct((B, H, S, _LSE_LANES), jnp.float32),
+        ],
+        scratch_shapes=[] if span == num_k else [
+            pltpu.VMEM((block_q, D), jnp.float32),            # acc
+            pltpu.VMEM((block_q, _LSE_LANES), jnp.float32),   # running max
+            pltpu.VMEM((block_q, _LSE_LANES), jnp.float32),   # running sum
+        ],
+        interpret=_use_interpret(),
+    )
+    with jax.named_scope(f"flash.fwd.{plan['path']}"):  # flash.fwd_plan's path
+        out, lse = call(qt, kt, vt)
+    return out.transpose(0, 2, 1, 3), lse
+
+
+def _dq_kernel(q_ref, k_ref, v_ref, g_ref, o_ref, lse_ref, dq_ref, *,
+               block_k: int, num_k: int, scale: float, causal: bool,
+               window: int, in_flight: int):
+    """Grid (b, h, q-block, span), the forward's walk (`_fwd_kernel`) for
+    dQ (FlashAttention-2 backward, dQ pass): K and V arrive a span of
+    k-blocks a grid step, and dQ accumulates over the span's blocks of the
+    band, `in_flight` at a time (a block's `q k^T` and `g v^T` need
+    nothing of the sum). delta = rowsum(o * dO) is computed in-kernel,
+    once a grid step. One span (`loop`): the sum is the loop's carry and
+    dQ is written once, in q's dtype. Several (`stream`): the f32 dq
+    output block is constant in the (minor) span axis, so Mosaic keeps it
+    resident and a step of the walk adds to it, in the loop plan's
+    order."""
+    block_q, D = q_ref.shape[2], q_ref.shape[3]
+    span = k_ref.shape[2] // block_k
+    streamed = span != num_k
+    qi, si = pl.program_id(2), pl.program_id(3)
+    lo, hi = _span_band(qi, si, span=span, num_k=num_k, block_q=block_q,
+                        block_k=block_k, causal=causal, window=window)
+
+    def walk(carry):
+        q = q_ref[0, 0].astype(jnp.float32)
         g = g_ref[0, 0].astype(jnp.float32)
+        lse = lse_ref[0, 0][:, 0:1]
         delta = jnp.sum(o_ref[0, 0].astype(jnp.float32) * g, axis=-1,
                         keepdims=True)
         q_pos = qi * block_q + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, 1), 0)
-        dq_ref[0, 0] += _dq_step(
-            q_ref[0, 0].astype(jnp.float32), k_ref[0, 0].astype(jnp.float32),
-            v_ref[0, 0].astype(jnp.float32), g, lse_ref[0, 0][:, 0:1], delta,
-            q_pos, ki, block_k=block_k, scale=scale, causal=causal,
-            window=window)
+
+        def block(ref, ki):
+            rows = pl.multiple_of((ki - si * span) * block_k, block_k)
+            return ref[0, 0, pl.ds(rows, block_k), :].astype(jnp.float32)
+
+        def step(carry, ki, n):
+            kv = [(block(k_ref, ki + j), block(v_ref, ki + j))
+                  for j in range(n)]
+            products = [
+                (jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+                 * scale,
+                 jax.lax.dot_general(g, v, (((1,), (1,)), ((), ())),
+                                     preferred_element_type=jnp.float32))
+                for k, v in kv]
+            dq = dq_ref[0, 0] if streamed else carry
+            for j, ((k, _), (s, dp)) in enumerate(zip(kv, products)):
+                if causal:
+                    s = _mask(s, q_pos, ki + j, block_k=block_k,
+                              window=window)
+                p = jnp.exp(s - lse)
+                ds = p * (dp - delta) * scale
+                dq = dq + jax.lax.dot(ds, k,
+                                      preferred_element_type=jnp.float32)
+            if not streamed:
+                return dq
+            dq_ref[0, 0] = dq
+            return carry
+
+        return _walk(lo, hi, carry, step, in_flight)
+
+    if not streamed:
+        dq_ref[0, 0] = walk(jnp.zeros((block_q, D), jnp.float32)).astype(
+            dq_ref.dtype)
+        return
+
+    @pl.when(si == 0)
+    def _zero():
+        dq_ref[0, 0] = jnp.zeros_like(dq_ref[0, 0])
+
+    @pl.when(lo < hi)
+    def _span():
+        walk(0)
 
 
 def _dkdv_step(q, k, v, g, o, lse, qi, ki, *, block_q: int, block_k: int,
@@ -640,7 +696,7 @@ def bwd_dkdv_plan(*, S: int, T: int, D: int, dtype, groups: int,
 
 def _flash_bwd_dkdv(qt, kt, vt, gt, ot, lse, *, causal: bool, block_q: int,
                     block_k: int, window: int, scale: float = None,
-                    vmem_bytes: int = None, dq_path: str = None):
+                    vmem_bytes: int = None, dq_plan: dict = None):
     """The dK/dV call on [B, H|KV, S|T, D] operands (lse [B, H, S, 128]):
     per-query-head dK and dV, [B, H, T, D]."""
     B, H, S, D = qt.shape
@@ -654,7 +710,8 @@ def _flash_bwd_dkdv(qt, kt, vt, gt, ot, lse, *, causal: bool, block_q: int,
     tracing.instant("flash.bwd_plan", {
         **{k: plan[k] for k in ("path", "S", "block_q", "block_k", "window",
                                 "resident_bytes", "hbm_bytes_per_head")},
-        **({"dq_path": dq_path} if dq_path else {})})
+        **({"dq_" + k: dq_plan[k] for k in ("path", "span", "in_flight")}
+           if dq_plan else {})})
     kernel_args = dict(block_q=block_q, scale=_scale(scale, D),
                        causal=causal, window=window)
     if plan["path"] == "resident":
@@ -703,61 +760,32 @@ def _flash_bwd_dkdv(qt, kt, vt, gt, ot, lse, *, causal: bool, block_q: int,
         return call(qt, kt, vt, gt, ot, lse)
 
 
-def _flash_bwd_dq(qt, kt, vt, gt, ot, lse, *, path: str, causal: bool,
+def _flash_bwd_dq(qt, kt, vt, gt, ot, lse, *, plan: dict, causal: bool,
                   block_q: int, block_k: int, window: int, scale: float):
     """The dQ call on [B, H|KV, S|T, D] operands (lse [B, H, S, 128]), by
-    the plan `kv_plan` chose: [B, H, S, D] in q's dtype."""
+    the plan `kv_plan` made for it (`path`, `span`, `in_flight`):
+    [B, H, S, D] in q's dtype."""
     B, H, S, D = qt.shape
     T, groups = kt.shape[2], H // kt.shape[1]
-    if path == "loop":
-        q_blk = pl.BlockSpec((1, 1, block_q, D), lambda b, h, i: (b, h, i, 0))
-        kv_spec = pl.BlockSpec((1, 1, T, D),
-                               lambda b, h, i, g_=groups: (b, h // g_, 0, 0))
-        call = pl.pallas_call(
-            functools.partial(_bwd_dq_kernel, block_k=block_k, scale=scale,
-                              causal=causal, window=window),
-            grid=(B, H, S // block_q),
-            in_specs=[
-                q_blk,
-                kv_spec,
-                kv_spec,
-                q_blk,
-                q_blk,
-                pl.BlockSpec((1, 1, block_q, 128),
-                             lambda b, h, i: (b, h, i, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, 1, block_q, D),
-                                   lambda b, h, i: (b, h, i, 0)),
-            out_shape=jax.ShapeDtypeStruct((B, H, S, D), qt.dtype),
-            interpret=_use_interpret(),
-        )
-        with jax.named_scope("flash.dq.loop"):      # flash.bwd_plan's dq_path
-            return call(qt, kt, vt, gt, ot, lse)
     num_k = T // block_k
-
-    def kv_idx(b, h, qi, ki):
-        lo, hi = _k_band(qi, num_k=num_k, block_q=block_q, block_k=block_k,
-                         causal=causal, window=window)
-        if causal:      # a step outside the band repeats its near edge
-            ki = jax.lax.max(jax.lax.min(ki, hi - 1), lo)
-        return (b, h // groups, ki, 0)
-
-    def q_side(width):
-        return pl.BlockSpec((1, 1, block_q, width),
-                            lambda b, h, qi, ki: (b, h, qi, 0))
-
-    kv_blk = pl.BlockSpec((1, 1, block_k, D), kv_idx)
+    span = plan["span"] // block_k
+    q_side, kv_blk = _walk_specs(
+        groups=groups, span=span, num_k=num_k, block_q=block_q,
+        block_k=block_k, D=D, causal=causal, window=window)
     call = pl.pallas_call(
-        functools.partial(_bwd_dq_stream_kernel, num_k=num_k, scale=scale,
-                          causal=causal, window=window),
-        grid=(B, H, S // block_q, num_k),
+        functools.partial(_dq_kernel, block_k=block_k, num_k=num_k,
+                          scale=scale, causal=causal, window=window,
+                          in_flight=plan["in_flight"]),
+        grid=(B, H, S // block_q, num_k // span),
         in_specs=[q_side(D), kv_blk, kv_blk, q_side(D), q_side(D),
                   q_side(_LSE_LANES)],
         out_specs=q_side(D),
-        out_shape=jax.ShapeDtypeStruct((B, H, S, D), jnp.float32),
+        # several spans add up in the output block, in float32
+        out_shape=jax.ShapeDtypeStruct(
+            (B, H, S, D), qt.dtype if span == num_k else jnp.float32),
         interpret=_use_interpret(),
     )
-    with jax.named_scope("flash.dq.stream"):
+    with jax.named_scope(f"flash.dq.{plan['path']}"):  # flash.bwd_plan's dq_path
         return call(qt, kt, vt, gt, ot, lse).astype(qt.dtype)
 
 
@@ -780,14 +808,14 @@ def _flash_pallas_bwd(res, g, *, causal: bool, block_q: int, block_k: int,
     gt = g.transpose(0, 2, 1, 3)
     ot = out.transpose(0, 2, 1, 3)
 
-    dq_path = kv_plan(S=S, T=T, D=D, dtype=k.dtype, block_q=block_q,
-                      block_k=block_k)["path"]
-    dq = _flash_bwd_dq(qt, kt, vt, gt, ot, lse, path=dq_path, causal=causal,
+    dq_plan = kv_plan(S=S, T=T, D=D, dtype=k.dtype, block_q=block_q,
+                      block_k=block_k, window=window, call="dq")
+    dq = _flash_bwd_dq(qt, kt, vt, gt, ot, lse, plan=dq_plan, causal=causal,
                        block_q=block_q, block_k=block_k, window=window,
                        scale=scale)
     dk, dv = _flash_bwd_dkdv(qt, kt, vt, gt, ot, lse, causal=causal,
                              block_q=block_q, block_k=block_k, window=window,
-                             scale=scale, dq_path=dq_path)
+                             scale=scale, dq_plan=dq_plan)
     if groups > 1:
         # GQA: sum per-query-head contributions into each kv head.
         dk = dk.reshape(B, KV, groups, T, D).sum(2)

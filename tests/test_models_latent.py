@@ -251,11 +251,17 @@ def test_latent_attention_through_flash_at_a_head_of_256(fwd_dq, dkdv,
         # kernel's float32 dq, dk, dv read once each
         "hbm_bytes_fwd": 4 * (4 * S * 2 * 256 + S * 64 * 6),
         "hbm_bytes_bwd": (12 + 12) * S * 2 * 256 + 4 * S * 64 * 3}
+    # four blocks of 32 keys. loop: one span, the forward one block at a
+    # time, the dQ call two; stream with no byte to spend (`_force`): one
+    # block a grid step, the parent's walk
     assert plans["flash.fwd_plan"] == {
         "path": fwd_dq, "S": S, "D": 256,
-        "kv_block_bytes": 2 * 2 * S * 256 * 4}
-    assert plans["flash.bwd_plan"]["path"] == dkdv
-    assert plans["flash.bwd_plan"]["dq_path"] == fwd_dq
+        "kv_block_bytes": 2 * 2 * S * 256 * 4,
+        "span": {"loop": S, "stream": 32}[fwd_dq], "in_flight": 1}
+    back = plans["flash.bwd_plan"]
+    assert back["path"] == dkdv
+    assert (back["dq_path"], back["dq_span"], back["dq_in_flight"]) == {
+        "loop": ("loop", S, 2), "stream": ("stream", 32, 1)}[fwd_dq]
 
 
 def _published_half(x, lp, cfg, cos, sin):
@@ -363,20 +369,21 @@ def test_the_block_plans_agree_at_a_head_of_256(mask):
     key = jax.random.split(jax.random.PRNGKey(11), 4)
     q, k, v, g = (jax.random.normal(kk, (B, S, H, D)) * 0.5 for kk in key)
     kw = dict(causal=True, block_q=block, block_k=block, scale=D ** -0.5)
-    with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(fa, "_SCOPED_VMEM_BYTES", 0)
-        streamed, lse_s = fa._flash_fwd(q, k, v, window=window, **kw)
+    # spans of two of the four k-blocks, walked two at a time
+    stream = dict(path="stream", span=2 * block, in_flight=2)
+    loop = dict(path="loop", span=S, in_flight=2)
+    streamed, lse_s = fa._flash_fwd(q, k, v, window=window, plan=stream, **kw)
     if not window:
-        looped, lse_l = fa._flash_fwd_loop(q, k, v, **kw)
+        looped, lse_l = fa._flash_fwd(q, k, v, plan=loop, **kw)
         np.testing.assert_allclose(looped, streamed, rtol=1e-6, atol=1e-6)
         np.testing.assert_allclose(lse_l, lse_s, rtol=1e-6, atol=1e-6)
         np.testing.assert_allclose(streamed, _explicit(q, k, v), rtol=2e-5,
                                    atol=2e-5)
     t = lambda x: x.transpose(0, 2, 1, 3)                      # noqa: E731
     args = (t(q), t(k), t(v), t(g), t(streamed), lse_s)   # lse [B, H, S, 128]
-    dq = {path: fa._flash_bwd_dq(*args, path=path, causal=True, block_q=block,
-                                 block_k=block, window=window, scale=D ** -0.5)
-          for path in ("loop", "stream")}
+    dq = {plan["path"]: fa._flash_bwd_dq(
+        *args, plan=plan, causal=True, block_q=block, block_k=block,
+        window=window, scale=D ** -0.5) for plan in (loop, stream)}
     np.testing.assert_array_equal(dq["loop"], dq["stream"])
     dkdv = {path: fa._flash_bwd_dkdv(
         *args, causal=True, block_q=block, block_k=block, window=window,
@@ -399,6 +406,13 @@ def test_the_plans_at_the_cells_shape_and_at_the_other_cells():
     glm = fa.kv_plan(S=8192, T=8192, D=256, dtype=bf, block_q=512,
                      block_k=512)
     assert glm["path"] == "stream" and glm["kv_block_bytes"] == 16 * 2 ** 20
+    # half a head's keys a grid step (two spans a q-block where the
+    # parent's grid had sixteen steps), two k-blocks a step of the walk;
+    # the dQ call's four score-sized temporaries a block leave room for one
+    assert (glm["span"], glm["in_flight"]) == (4096, 2)
+    dq = fa.kv_plan(S=8192, T=8192, D=256, dtype=bf, block_q=512,
+                    block_k=512, call="dq")
+    assert (dq["path"], dq["span"], dq["in_flight"]) == ("stream", 4096, 1)
     assert fa.bwd_dkdv_plan(
         S=8192, T=8192, D=256, dtype=bf, groups=1, block_q=512, block_k=512,
         causal=True, window=0, vmem_bytes=fa._V5E_VMEM_BYTES
@@ -634,7 +648,8 @@ def test_plans_read_back_from_a_profile_around_a_lowering(tmp_path,
         "hbm_bytes_bwd": 24 * 256 * 2 * 256 + 4 * 256 * 64 * 3}
     assert events["flash.fwd_plan"][0] == {
         "path": "stream", "S": 128, "D": 256,
-        "kv_block_bytes": 2 * 2 * 128 * 256 * 4}
+        "kv_block_bytes": 2 * 2 * 128 * 256 * 4, "span": 32, "in_flight": 1}
     back = events["flash.bwd_plan"][0]
     assert back["path"] == "stream" and back["dq_path"] == "stream"
+    assert (back["dq_span"], back["dq_in_flight"]) == (32, 1)
     assert back["S"] == 128 and back["block_q"] == 32

@@ -426,6 +426,254 @@ def test_dkdv_stream_grid_fetches_only_its_band(window, block_q, block_k):
         assert int(traced(ki, qi)) == fa._q_block_index(ki, qi, **kw)
 
 
+# -- the forward's and the dQ call's walk over k-blocks ----------------------
+
+def _one_block_walk(call, q, k, v, g=None, out=None, lse=None, *, causal,
+                    window, block):
+    """The walk the kernels replaced, written out: grid (b, h, q-block,
+    k-block), ONE k-block a grid step, the sums in VMEM scratch (forward)
+    or in the float32 output block (dQ), every step masked. Operands
+    [B, H|KV, S, D]; returns (o, lse [B, H, S, 128]) or dq."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, H, S, D = q.shape
+    groups, num_k, scale = H // k.shape[1], S // block, D ** -0.5
+    neg = _fa().NEG_INF
+
+    def scores(q_blk, k_blk, qi, ki):
+        s = jax.lax.dot_general(q_blk, k_blk, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        if causal:
+            q_pos = qi * block + jax.lax.broadcasted_iota(
+                jnp.int32, (block, 1), 0)
+            k_pos = ki * block + jax.lax.broadcasted_iota(
+                jnp.int32, (1, block), 1)
+            keep = q_pos >= k_pos
+            if window:
+                keep = keep & (q_pos - k_pos < window)
+            s = jnp.where(keep, s, neg)
+        return s
+
+    def in_band(qi, ki):
+        if not causal:
+            return True
+        run = qi * block + block > ki * block
+        if window:
+            run = run & (qi * block < (ki + 1) * block + window)
+        return run
+
+    def fwd(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr):
+        qi, ki = pl.program_id(2), pl.program_id(3)
+
+        @pl.when(ki == 0)
+        def _():
+            acc[...] = jnp.zeros_like(acc)
+            m_scr[...] = jnp.full_like(m_scr, neg)
+            l_scr[...] = jnp.zeros_like(l_scr)
+
+        @pl.when(in_band(qi, ki))
+        def _():
+            s = scores(q_ref[0, 0], k_ref[0, 0], qi, ki)
+            m, l = m_scr[...][:, 0:1], l_scr[...][:, 0:1]
+            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m - m_new)
+            l_new = l * alpha + jnp.sum(p, axis=1, keepdims=True)
+            acc[...] = acc[...] * alpha + jax.lax.dot(
+                p.astype(v_ref.dtype), v_ref[0, 0],
+                preferred_element_type=jnp.float32)
+            m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+            l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+
+        @pl.when(ki == num_k - 1)
+        def _():
+            l = jnp.maximum(l_scr[...][:, 0:1], 1e-30)
+            o_ref[0, 0] = (acc[...] / l).astype(o_ref.dtype)
+            lse_ref[0, 0] = m_scr[...] + jnp.log(l)
+
+    def dq(q_ref, k_ref, v_ref, g_ref, o_ref, lse_ref, dq_ref):
+        qi, ki = pl.program_id(2), pl.program_id(3)
+
+        @pl.when(ki == 0)
+        def _():
+            dq_ref[0, 0] = jnp.zeros_like(dq_ref[0, 0])
+
+        @pl.when(in_band(qi, ki))
+        def _():
+            f32 = lambda ref: ref[0, 0].astype(jnp.float32)  # noqa: E731
+            delta = jnp.sum(f32(o_ref) * f32(g_ref), axis=-1, keepdims=True)
+            p = jnp.exp(scores(f32(q_ref), f32(k_ref), qi, ki)
+                        - lse_ref[0, 0][:, 0:1])
+            dp = jax.lax.dot_general(
+                f32(g_ref), f32(v_ref), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dq_ref[0, 0] += jax.lax.dot(
+                p * (dp - delta) * scale, f32(k_ref),
+                preferred_element_type=jnp.float32)
+
+    def rows(width):
+        return pl.BlockSpec((1, 1, block, width),
+                            lambda b, h, i, j: (b, h, i, 0))
+
+    keys = pl.BlockSpec((1, 1, block, D),
+                        lambda b, h, i, j: (b, h // groups, j, 0))
+    grid = (B, H, S // block, num_k)
+    if call == "fwd":
+        return pl.pallas_call(
+            fwd, grid=grid, in_specs=[rows(D), keys, keys],
+            out_specs=[rows(D), rows(128)],
+            out_shape=[jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
+                       jax.ShapeDtypeStruct((B, H, S, 128), jnp.float32)],
+            scratch_shapes=[pltpu.VMEM((block, D), jnp.float32),
+                            pltpu.VMEM((block, 128), jnp.float32),
+                            pltpu.VMEM((block, 128), jnp.float32)],
+            interpret=True)(q, k, v)
+    return pl.pallas_call(
+        dq, grid=grid,
+        in_specs=[rows(D), keys, keys, rows(D), rows(D), rows(128)],
+        out_specs=rows(D),
+        out_shape=jax.ShapeDtypeStruct((B, H, S, D), jnp.float32),
+        interpret=True)(q, k, v, g, out, lse).astype(q.dtype)
+
+
+@pytest.mark.parametrize("blocks", [1, 3, 8],
+                         ids=["S=block", "S=3blocks", "S=8blocks"])
+@pytest.mark.parametrize("heads", [(2, 2), (4, 2)], ids=["H=KV", "groups2"])
+@pytest.mark.parametrize("mask", ["causal", "noncausal", "window64"])
+@pytest.mark.parametrize("path", ["loop", "stream"])
+@pytest.mark.parametrize("call", ["fwd", "dq"])
+def test_the_walk_of_k_blocks_is_the_one_block_walk_to_the_last_bit(
+        call, path, mask, heads, blocks):
+    """The forward and the dQ call, on the loop plan (one span: a head's
+    whole K and V) and the stream plan (spans of half the k-blocks, of one
+    where their number is odd), two k-blocks a step of the walk wherever a
+    span holds two: bit-equal to a walk of one k-block a grid step, the
+    parent's, written out above. Three blocks walk a pair and an odd one;
+    a window's band starts inside a span."""
+    fa = _fa()
+    block = 32
+    causal, window = mask != "noncausal", 64 if mask == "window64" else 0
+    S = block * blocks
+    # D 64: the scale is a power of two. XLA's CPU backend contracts
+    # `s * scale - m` into one rounding where both land in one fusion,
+    # which depends on the program around them; with an exact product the
+    # two roundings are one, and interpret mode is bit-equal as the chip
+    # is (PERF.md 6, PR 37: every plan against the parent's kernels)
+    q, k, v = _make(B=1, S=S, H=heads[0], KV=heads[1], D=64, seed=5)
+    g = jax.random.normal(jax.random.PRNGKey(6), q.shape, q.dtype)
+    span = blocks if path == "loop" else max(
+        n for n in range(1, blocks // 2 + 1) if blocks % n == 0) \
+        if blocks > 1 else 1
+    plan = dict(path=path, span=span * block, in_flight=min(2, span))
+    kw = dict(causal=causal, block_q=block, block_k=block, window=window,
+              scale=64 ** -0.5)
+    t = lambda x: x.transpose(0, 2, 1, 3)  # noqa: E731
+    want_o, want_lse = _one_block_walk(
+        "fwd", t(q), t(k), t(v), causal=causal, window=window, block=block)
+    if call == "fwd":
+        got_o, got_lse = fa._flash_fwd(q, k, v, plan=plan, **kw)
+        np.testing.assert_array_equal(t(got_o), want_o)
+        np.testing.assert_array_equal(got_lse, want_lse)
+        return
+    args = (t(q), t(k), t(v), t(g), want_o, want_lse)
+    want = _one_block_walk("dq", *args, causal=causal, window=window,
+                           block=block)
+    np.testing.assert_array_equal(
+        fa._flash_bwd_dq(*args, plan=plan, **kw), want)
+
+
+@pytest.mark.parametrize("window", [0, 64, 100], ids=["causal", "w64", "w100"])
+@pytest.mark.parametrize("span", [1, 2, 4])
+@pytest.mark.parametrize("block_q,block_k", [(32, 32), (32, 64), (64, 32)])
+def test_kv_span_grid_fetches_only_its_band(window, span, block_q, block_k):
+    """Walk the grid of a call that holds a q-block and steps over spans
+    of k-blocks, on the host, in the order Mosaic does. On a step whose
+    span holds a block of the band, the index of K and V is that span
+    (si itself). On any other step it is the previous step's (nothing is
+    fetched) or, before a q-block's band opens, the band's first span
+    (fetched early, once): over a head the index changes at most once per
+    step that runs, never for a span outside the band."""
+    fa = _fa()
+    S = 256
+    num_q, num_k = S // block_q, S // block_k
+    kw = dict(num_k=num_k, block_q=block_q, block_k=block_k, causal=True,
+              window=window)
+    prev, fetches, ran = None, 0, 0
+    for qi in range(num_q):
+        for si in range(num_k // span):
+            # what the kernel's pl.when computes, from positions
+            rows = np.arange(qi * block_q, (qi + 1) * block_q)[:, None]
+            cols = np.arange(si * span * block_k,
+                             (si + 1) * span * block_k)[None, :]
+            keep = rows >= cols
+            if window:
+                # the band test is by blocks: the row at the window's
+                # edge, that sees nothing, still counts as in the band
+                keep &= rows - cols <= window + block_k - 1
+            lo, hi = fa._k_band(qi, **kw)
+            runs = max(lo, si * span) < min(hi, (si + 1) * span)
+            if not window:
+                assert runs == bool(keep.any()), (qi, si)
+            else:
+                assert runs or not (rows - cols < window)[keep].any()
+            index = fa._k_span_index(qi, si, span=span, **kw)
+            if runs:
+                ran += 1
+                assert index == si
+            else:
+                assert index == prev or index == lo // span, (
+                    qi, si, index, prev)
+            fetches += index != prev
+            prev = index
+    assert fetches <= ran
+    # the same function traces: an index map gets traced scalars
+    traced = jax.jit(lambda i, j: fa._k_span_index(i, j, span=span, **kw))
+    for qi, si in [(0, 0), (num_q - 1, 0), (0, num_k // span - 1),
+                   (num_q // 2, 1 % (num_k // span))]:
+        assert int(traced(qi, si)) == fa._k_span_index(qi, si, span=span, **kw)
+
+
+def test_kv_plan_takes_the_longest_span_that_fits_then_a_second_block():
+    """`kv_plan` by bytes alone: the loop plan holds a head's whole K and
+    V and its forward walks one block at a time, its dQ call two; the
+    stream plan takes the longest span whose step fits the 16 MiB a call
+    gets, and a second block in flight where that span leaves room; a
+    window caps the span at the blocks that cover it."""
+    fa = _fa()
+    bf = jnp.bfloat16
+    shape = dict(dtype=bf, block_q=512, block_k=512)
+    l8 = {c: fa.kv_plan(S=4096, T=4096, D=128, call=c, **shape)
+          for c in ("fwd", "dq")}
+    assert [(p["path"], p["span"], p["in_flight"]) for p in l8.values()] == [
+        ("loop", 4096, 1), ("loop", 4096, 2)]
+    glm = {c: fa.kv_plan(S=8192, T=8192, D=256, call=c, **shape)
+           for c in ("fwd", "dq")}
+    assert [(p["path"], p["span"], p["in_flight"]) for p in glm.values()] == [
+        ("stream", 4096, 2), ("stream", 4096, 1)]
+    for plan in list(l8.values()) + list(glm.values()):
+        assert plan["walk_bytes"] <= fa._SCOPED_VMEM_BYTES
+    # a MiB less: half the span, where the dQ call's pair fits again; at
+    # 12 MiB the longer span with one block still comes before the pair
+    with pytest.MonkeyPatch.context() as mp:
+        for budget, want in ((15, (2048, 2)), (12, (2048, 1))):
+            mp.setattr(fa, "_SCOPED_VMEM_BYTES", budget * 2 ** 20)
+            small = fa.kv_plan(S=8192, T=8192, D=256, call="dq", **shape)
+            assert (small["span"], small["in_flight"]) == want, budget
+        # nothing fits: one block a grid step, one at a time (the parent's)
+        mp.setattr(fa, "_SCOPED_VMEM_BYTES", 0)
+        assert [fa.kv_plan(S=8192, T=8192, D=256, call=c, **shape)[n]
+                for c in ("fwd", "dq") for n in ("span", "in_flight")] == [
+            512, 1, 512, 1]
+    # a windowed forward streams whatever fits, in spans no longer than
+    # the blocks that cover the window; the dQ call keeps its plan
+    banded = fa.kv_plan(S=8192, T=8192, D=128, window=1024, **shape)
+    assert (banded["path"], banded["span"], banded["in_flight"]) == (
+        "stream", 1024, 2)
+    assert fa.kv_plan(S=8192, T=8192, D=128, window=1024, call="dq",
+                      **shape)["path"] == "loop"
+
+
 def test_flash_bwd_plan_instant_once_a_trace(monkeypatch):
     """The plan is chosen while the backward is traced, and says so once:
     one `flash.fwd_plan` and one `flash.bwd_plan` instant a compile, none
@@ -444,13 +692,17 @@ def test_flash_bwd_plan_instant_once_a_trace(monkeypatch):
     # so beside it (`flash.fwd_plan`); the backward's instant names the dQ
     # call's plan too
     assert [name for name, _ in seen] == ["flash.fwd_plan", "flash.bwd_plan"]
+    # four blocks of 32 keys: the loop plan holds all of them, and its
+    # forward walks them one at a time, its dQ call two at a time
     assert seen[0][1] == {"path": "loop", "S": 128, "D": 32,
-                          "kv_block_bytes": 2 * 2 * 128 * 32 * 4}
+                          "kv_block_bytes": 2 * 2 * 128 * 32 * 4,
+                          "span": 128, "in_flight": 1}
     attrs = seen[1][1]
     assert attrs == {
         "path": "resident", "S": 128, "block_q": 32, "block_k": 32,
         "window": 0, "resident_bytes": attrs["resident_bytes"],
         "hbm_bytes_per_head": fa.hbm_bytes_per_head(
             "resident", S=128, T=128, D=32, block_q=32, block_k=32,
-            itemsize=4, out_itemsize=4), "dq_path": "loop"}
+            itemsize=4, out_itemsize=4), "dq_path": "loop", "dq_span": 128,
+        "dq_in_flight": 2}
     assert all(isinstance(x, (int, str)) for x in attrs.values())

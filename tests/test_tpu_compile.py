@@ -159,6 +159,105 @@ def test_flash_calls_keep_their_face_in_the_trace(one_chip, on_chip_branch):
     assert faces["resident"] != faces["stream"]
 
 
+def test_flash_calls_keep_their_face_on_the_stream_plans(one_chip,
+                                                         on_chip_branch):
+    """The same face at the GLM-4.7-Flash cell's attention shape (S 8192,
+    D 256), where all three calls stream by the bytes alone
+    (``benchmark/readers/glm_kernel_roofline.py`` tells them as the dense
+    reader does): forward 3 -> 2, dq 6 -> 1 and still float32 (the spans
+    of a q-block add up in the output block), dkdv 6 -> 2, q and k first;
+    each call is named after the scope of its plan."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark.readers import kernel_roofline
+    from ray_tpu.ops.flash_attention import flash_attention
+
+    B, S, H, D = 1, 8192, 2, 256
+    q = _sds((B, S, H, D), jnp.bfloat16, one_chip)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, q, q).compile().as_text()
+    lines = {kernel_roofline.signature(ln): ln for ln in text.splitlines()
+             if kernel_roofline.signature(ln) is not None}
+    assert sorted(lines) == [(1, 6), (2, 3), (2, 6)], sorted(lines)
+    for sig, scope in (((2, 3), "flash.fwd.stream"), ((1, 6), "flash.dq.stream"),
+                       ((2, 6), "flash.dkdv.stream")):
+        ln = lines[sig]
+        shapes = re.findall(r"\[([\d,]+)\]", re.search(
+            r"operand_layout_constraints=\{(.*?\})\}", ln).group(1))
+        assert shapes[:2] == [f"{B},{H},{S},{D}"] * 2, ln[:400]
+        assert f"({scope})" in re.search(r'op_name="([^"]*)"', ln).group(1)
+    assert re.search(rf" = f32\[{B},{H},{S},{D}\]", lines[(1, 6)]), \
+        lines[(1, 6)][:300]
+
+
+# (batch, seq, heads, kv_heads, head_dim, stated scale) of the attention
+# call one device makes in each of the benchmark's five cells
+CELL_FLASH = {
+    "train-deepseek7b-l8": (3, 4096, 32, 32, 128, None),
+    "train-deepseek7b-fsdp2tp2": (2, 4096, 16, 16, 128, None),
+    "train-olmoe1b7b-s4096-b4": (4, 4096, 16, 16, 128, None),
+    "train-granite4hs-ep8-s8192-b2": (2, 8192, 32, 8, 128, 0.0078125),
+    "train-glm47flash-ep8-s8192-b2": (2, 8192, 20, 20, 256, None),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_FLASH))
+def test_the_cells_flash_walks_fit_the_vmem_a_call_gets(cell, one_chip,
+                                                       on_chip_branch):
+    """The forward and the dQ call of every cell compile for the chip in
+    the 16 MiB a Mosaic call gets that asks for no more (``kv_plan``'s
+    span and blocks in flight are chosen against it; a call's
+    ``vmem_limit_bytes`` is taken out of XLA's fast memory): their scoped
+    VMEM in the compiled text is the default. Only the resident dK/dV
+    plan asks (``bwd_dkdv_plan``)."""
+    import re
+    import sys
+
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark.readers import kernel_roofline
+    from ray_tpu.ops.flash_attention import flash_attention
+
+    fa = sys.modules["ray_tpu.ops.flash_attention"]
+    B, S, H, KV, D, scale = CELL_FLASH[cell]
+    q = _sds((B, S, H, D), jnp.bfloat16, one_chip)
+    kv = _sds((B, S, KV, D), jnp.bfloat16, one_chip)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, scale=scale).astype(
+            jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile().as_text()
+    scoped = {}
+    for ln in text.splitlines():
+        sig = kernel_roofline.signature(ln)
+        if sig is not None:
+            size = re.search(
+                r'"scoped_memory_configs":\[\{[^}]*"size":"(\d+)"', ln)
+            # no call of the program asks: the list is empty in all
+            scoped[kernel_roofline.FLASH[sig]] = int(
+                size.group(1)) if size else fa._SCOPED_VMEM_BYTES
+    assert sorted(scoped) == ["dkdv", "dq", "fwd"], scoped
+    assert scoped["fwd"] == scoped["dq"] == fa._SCOPED_VMEM_BYTES, scoped
+    streams = D == 256
+    assert (scoped["dkdv"] > fa._SCOPED_VMEM_BYTES) == (not streams), scoped
+    plans = {c: fa.kv_plan(S=S, T=S, D=D, dtype=jnp.bfloat16, block_q=512,
+                           block_k=512, call=c) for c in ("fwd", "dq")}
+    assert [(p["path"], p["span"], p["in_flight"])
+            for p in plans.values()] == (
+        [("stream", 4096, 2), ("stream", 4096, 1)] if streams
+        else [("loop", S, 1), ("loop", S, 2)]), plans
+
+
 # (rows, experts, model width, one expert's width) of a cell's grouped
 # matmuls: OLMoE-1B-7B's 131,072 routed rows over 64 experts of 2048 x 1024;
 # GLM-4.7-Flash's one pass of 16,384 rows over the 8 experts held, 2048 x
@@ -426,8 +525,11 @@ def test_latent_attention_block_at_glm_widths_streams_and_fits(
     assert calls == [(1, 6), (2, 3), (2, 3), (2, 6)], calls
     assert {a["path"] for a in plans["flash.fwd_plan"]} == {"stream"}
     assert plans["flash.fwd_plan"][0]["kv_block_bytes"] == 16 * 2 ** 20
+    assert [plans["flash.fwd_plan"][0][n] for n in ("span", "in_flight")] == [
+        4096, 2]
     back = plans["flash.bwd_plan"][0]
     assert back["path"] == "stream" and back["dq_path"] == "stream"
+    assert (back["dq_span"], back["dq_in_flight"]) == (4096, 1)
     assert back["resident_bytes"] > fa._vmem_bytes() // 4
     assert plans["mla.plan"][0]["k_bytes"] == B * S * 20 * 256 * 2
     # what the bytes say, the compiler says: the loop kernel does not fit
