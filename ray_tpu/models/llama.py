@@ -23,8 +23,10 @@ Design notes (TPU):
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
+import math
 import sys
 from dataclasses import dataclass
 from typing import Any, Dict, NamedTuple, Optional, Tuple
@@ -37,6 +39,29 @@ from ray_tpu.parallel.collective_matmul import (allgather_matmul,
                                                 matmul_reduce_scatter,
                                                 overlap_plan)
 from ray_tpu.util import tracing
+
+
+@dataclass(frozen=True)
+class Yarn:
+    """YaRN's stretch of the rotary frequencies (arXiv:2309.00071, as
+    transformers' ``_compute_yarn_parameters`` has it): lanes that turn
+    more than ``beta_fast`` times over the ``original`` context keep their
+    frequency, lanes that turn less than ``beta_slow`` times have it
+    divided by ``factor``, a linear ramp between; cos and sin are
+    multiplied by ``attention_factor`` (None: 0.1 ln(factor) + 1)."""
+    factor: float
+    original: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: Optional[float] = None
+
+
+@dataclass(frozen=True)
+class AttentionKind:
+    """What the attention layers of one kind have of their own."""
+    window: Optional[int] = None        # None: every earlier key
+    rope_theta: Optional[float] = None  # None: the config's
+    yarn: Optional[Yarn] = None         # None: plain tables
 
 
 @dataclass(frozen=True)
@@ -81,10 +106,19 @@ class LlamaConfig:
     logits_scaling: Any = None
     attn_scale: Any = None
     rope: bool = True                # rotary position embedding
+    # a head's width where the model states one (Mellum2: 32 heads of 128
+    # over a hidden size of 2304); None: d_model // n_heads
+    head_width: Any = None
+    # attention layers of several kinds in one model, by name: ((name,
+    # AttentionKind), ...). A family that lists its layers' kinds
+    # (models/moe.py ``layer_kinds``) gives each layer its kind's window
+    # and rotary tables; with none named every layer is of the one kind
+    # ``sliding_window`` and ``rope_theta`` describe.
+    attn_kinds: Tuple[Tuple[str, "AttentionKind"], ...] = ()
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        return self.head_width or self.d_model // self.n_heads
 
     @property
     def rope_dim(self) -> int:
@@ -270,13 +304,62 @@ def rms_norm(x, scale, eps):
     return (x * jax.lax.rsqrt(var + eps)).astype(x.dtype) * scale.astype(x.dtype)
 
 
+def yarn_range(theta: float, head_dim: int, yarn: Yarn) -> Tuple[int, int]:
+    """(low, high): the lanes between which YaRN's ramp runs. dim(r) is
+    the lane that turns r times over the original context."""
+    def dim(r):
+        return head_dim * math.log(yarn.original / (2 * math.pi * r)) \
+            / (2 * math.log(theta))
+
+    return (max(math.floor(dim(yarn.beta_fast)), 0),
+            min(math.ceil(dim(yarn.beta_slow)), head_dim - 1))
+
+
+def rope_inv_freq(theta: float, head_dim: int, yarn: Optional[Yarn] = None):
+    """The rotary lanes' frequencies, float32 [head_dim / 2]: theta ^
+    (-2i / head_dim), under YaRN blended with the same divided by
+    ``factor`` along the ramp of ``yarn_range``."""
+    turns = theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
+                      / head_dim)
+    if yarn is None:
+        return 1.0 / turns
+    low, high = yarn_range(theta, head_dim, yarn)
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 0.001), 0.0, 1.0)
+    return 1.0 / (yarn.factor * turns) * ramp + 1.0 / turns * (1.0 - ramp)
+
+
 @functools.partial(jax.jit, static_argnums=(1, 2), inline=True)
 def _rope_tables(theta: float, seq_len: int, head_dim: int):
-    freqs = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
-                             / head_dim))
+    freqs = rope_inv_freq(theta, head_dim)
     t = jnp.arange(seq_len, dtype=jnp.float32)
     angles = jnp.outer(t, freqs)                     # [S, HD/2]
     return jnp.cos(angles), jnp.sin(angles)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3), inline=True)
+def _yarn_tables(theta: float, seq_len: int, head_dim: int, yarn: Yarn):
+    """``_rope_tables`` for a kind of layer under YaRN: the stretched
+    frequencies, cos and sin both times the attention factor."""
+    freqs = rope_inv_freq(theta, head_dim, yarn)
+    angles = jnp.outer(jnp.arange(seq_len, dtype=jnp.float32), freqs)
+    by = yarn.attention_factor or 0.1 * math.log(yarn.factor) + 1.0
+    return jnp.cos(angles) * by, jnp.sin(angles) * by
+
+
+def _kind_tables(cfg: "LlamaConfig", of: AttentionKind, seq_len: int):
+    """cos and sin [seq_len, rope_dim / 2] of the layers of one kind."""
+    theta = cfg.rope_theta if of.rope_theta is None else of.rope_theta
+    if of.yarn is None:
+        return _rope_tables(theta, seq_len, cfg.rope_dim)
+    return _yarn_tables(float(theta), seq_len, cfg.rope_dim, of.yarn)
+
+
+def attention_kind(cfg: LlamaConfig, kind=None) -> AttentionKind:
+    """The window and the rotary tables of the layers of ``kind``: a kind
+    the config names (``attn_kinds``), else the config's one kind."""
+    return dict(cfg.attn_kinds).get(
+        kind, AttentionKind(window=cfg.sliding_window))
 
 
 def apply_rope(x, cos, sin):
@@ -346,8 +429,8 @@ def _flash_sharded(q, k, v, window, mesh, rules, scale=None):
 
 
 def _attention(q, k, v, cfg: LlamaConfig, causal=True, q_offset=0,
-               mesh=None, rules=None):
-    win = cfg.sliding_window
+               mesh=None, rules=None, kind=None):
+    win = attention_kind(cfg, kind).window
     scale = cfg.attn_scale                      # None: head_dim ** -0.5
     if scale is not None and cfg.attn_impl in ("ring", "ulysses"):
         raise ValueError("a configured softmax scale is not supported with "
@@ -377,9 +460,11 @@ def _attention(q, k, v, cfg: LlamaConfig, causal=True, q_offset=0,
 
 
 def _attention_half(x, lp, cfg: LlamaConfig, cos, sin, cache=None, mesh=None,
-                    rules=None, tp=None):
+                    rules=None, tp=None, kind=None):
     """The attention half of a block: x [B, S, D] -> (x + attention, k, v,
-    new_cache). cache: (k, v, offset) or None. With ``cfg.qk_norm`` (an
+    new_cache). cache: (k, v, offset) or None. ``kind`` names the layer's
+    kind where the config has several (``attention_kind``: its window;
+    ``cos`` and ``sin`` are its tables). With ``cfg.qk_norm`` (an
     OLMoE block) q and k pass an RMS norm over the WHOLE projected vector,
     one learned scale each (``q_norm``, ``k_norm``), before the split into
     heads. Without rotary tables (``cos`` None: a model with no position
@@ -433,10 +518,12 @@ def _attention_half(x, lp, cfg: LlamaConfig, cos, sin, cache=None, mesh=None,
                                           (0, offset, 0, 0))
         kk, vv = ck.astype(dt), cv.astype(dt)
         # mask out cache slots beyond offset+S via causal offset
-        attn = _attention(q, kk, vv, cfg, causal=True, q_offset=offset)
+        attn = _attention(q, kk, vv, cfg, causal=True, q_offset=offset,
+                          kind=kind)
         new_cache = (ck, cv)
     else:
-        attn = _attention(q, k, v, cfg, causal=True, mesh=mesh, rules=rules)
+        attn = _attention(q, k, v, cfg, causal=True, mesh=mesh, rules=rules,
+                          kind=kind)
     attn = attn.reshape(B, S, H * HD)
     if tp is not None:
         return _residual(x, matmul_reduce_scatter(attn, _dq(lp["wo"], dt),
@@ -475,6 +562,13 @@ def feed_forward(h, lp, cfg: LlamaConfig, mesh=None, rules=None, tp=None,
     return (gate * up) @ _dq(lp["w_down"], dt), None
 
 
+def _takes_attention_half(cfg: LlamaConfig, kind) -> bool:
+    """Whether a layer of ``kind`` runs ``_attention_half``: the one kind
+    of a model that names none, a hybrid's "attention", a named kind."""
+    return (getattr(_family(cfg), "attention_half", None) is None
+            and (kind in (None, "attention") or kind in dict(cfg.attn_kinds)))
+
+
 def _layer(x, lp, cfg: LlamaConfig, cos, sin, cache=None, collect_kv=False,
            mesh=None, rules=None, tp=None, kind=None):
     """One transformer block: the attention half, then the family's
@@ -483,21 +577,27 @@ def _layer(x, lp, cfg: LlamaConfig, cos, sin, cache=None, collect_kv=False,
     (k, v) cache slices when ``cache`` is given, this layer's (k, v) with
     collect_kv=True (cache seeding), else None; stats is what the
     feed-forward reports (None for the dense one). ``kind``: None or
-    "attention" for the attention half; any other kind of layer takes its
-    first half from the family's ``mixer_half`` (x, lp, cfg, kind -> x). A
-    family with an ``attention_half`` of its own (x, lp, cfg, cos, sin ->
-    x) supplies every layer's. ``kind`` goes on to the feed-forward."""
+    "attention" for the attention half, or a kind of attention layer the
+    config names (``attn_kinds``: the same half with that kind's window,
+    ``cos`` and ``sin`` its tables, under a scope of the kind's name); any
+    other kind of layer takes its first half from the family's
+    ``mixer_half`` (x, lp, cfg, kind -> x). A family with an
+    ``attention_half`` of its own (x, lp, cfg, cos, sin -> x) supplies
+    every layer's. ``kind`` goes on to the feed-forward."""
     own = getattr(_family(cfg), "attention_half", None)
+    named = kind in dict(cfg.attn_kinds)
     if own is not None:
         assert cache is None and not collect_kv and tp is None, kind
         with jax.named_scope("attention"):
             x, k, v, new_cache = own(x, lp, cfg, cos, sin, mesh=mesh,
                                      rules=rules), None, None, None
-    elif kind in (None, "attention"):
-        with jax.named_scope("attention"):
+    elif _takes_attention_half(cfg, kind):
+        # a trace tells the kinds apart by the inner scope, with no shape
+        with jax.named_scope("attention"), \
+                jax.named_scope(kind) if named else contextlib.nullcontext():
             x, k, v, new_cache = _attention_half(
                 x, lp, cfg, cos, sin, cache=cache, mesh=mesh, rules=rules,
-                tp=tp)
+                tp=tp, kind=kind)
     else:
         assert cache is None and not collect_kv, kind
         with jax.named_scope("mixer"):
@@ -550,6 +650,19 @@ def _say_layer_plan(runs, bodies: int):
     tracing.instant("hybrid.layer_plan", {
         "kinds": len({k for k, _ in runs}), "runs": len(runs),
         "bodies": bodies, "layers": sum(n for _, n in runs)})
+
+
+def _say_kind_plan(cfg: LlamaConfig, kind, of: AttentionKind, seq: int):
+    """The instant ``attn.kind_plan`` of a trace, once a kind of attention
+    layer and traced forward: the kind's window (0: none) and rotary
+    tables, and the heads they serve."""
+    tracing.instant("attn.kind_plan", {
+        "kind": kind or "attention", "window": of.window or 0,
+        "rope": ("none" if not cfg.rope else
+                 "yarn" if of.yarn is not None else "default"),
+        "factor": of.yarn.factor if of.yarn is not None else 1.0,
+        "S": seq, "heads": cfg.n_heads, "kv_heads": cfg.n_kv_heads,
+        "head_dim": cfg.head_dim})
 
 
 def _act_constraint(mesh, rules, tp=None):
@@ -623,21 +736,25 @@ def _forward(params, tokens, cfg: LlamaConfig, pos_offset=0, mesh=None,
         if cfg.embedding_multiplier is not None:
             x = (x * cfg.embedding_multiplier).astype(dt)
     x = con(x)
-    with jax.named_scope("attention"):      # the tables are its rotary's
+
+    def tables_of(kind):
+        # called once a kind: ``body_of`` is cached
+        of = attention_kind(cfg, kind)
+        if _takes_attention_half(cfg, kind):
+            _say_kind_plan(cfg, kind, of, S)
         if not cfg.rope:
-            cos = sin = None
-        elif isinstance(pos_offset, int) and pos_offset == 0:
-            cos, sin = _rope_tables(cfg.rope_theta, S, cfg.rope_dim)
-        else:
-            cos_full, sin_full = _rope_tables(
-                cfg.rope_theta, cfg.max_seq_len, cfg.rope_dim)
-            cos = jax.lax.dynamic_slice_in_dim(cos_full, pos_offset, S,
-                                               axis=0)
-            sin = jax.lax.dynamic_slice_in_dim(sin_full, pos_offset, S,
-                                               axis=0)
+            return None, None
+        with jax.named_scope("attention"):  # the tables are its rotary's
+            if isinstance(pos_offset, int) and pos_offset == 0:
+                return _kind_tables(cfg, of, S)
+            return tuple(
+                jax.lax.dynamic_slice_in_dim(t, pos_offset, S, axis=0)
+                for t in _kind_tables(cfg, of, cfg.max_seq_len))
 
     @functools.cache
     def body_of(kind):
+        cos, sin = tables_of(kind)
+
         def body(x, lp):
             y, _, stats = _layer(x, lp, cfg, cos, sin, mesh=mesh, rules=rules,
                                  tp=tp, kind=kind)
@@ -819,6 +936,8 @@ def _refuse_stated(cfg: LlamaConfig):
               if getattr(cfg, f) is not None] + ([] if cfg.rope else ["rope"])
     if getattr(_family(cfg), "attention_half", None) is not None:
         stated.append("an attention half of its own")
+    if cfg.attn_kinds:
+        stated.append("attention layers of several kinds")
     if stated:
         raise NotImplementedError(
             f"a KV cache for a config that states {', '.join(stated)}: the "

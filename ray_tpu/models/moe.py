@@ -31,6 +31,14 @@ in expert order, gate, up and their SwiGLU, and not the down projection
 On a mesh that shards ``experts`` (``ShardingRules.ep()``) the "xla" path
 runs under GSPMD; the "pallas" path refuses any mesh of several devices.
 
+Attention layers of several kinds (``layer_kinds`` names each layer's,
+``attn_kinds`` what a kind has of its own: Mellum2's window layers with
+plain rotary tables beside full layers under YaRN): ``params["layers"]`` is
+then a LIST of stacks, one a run of adjacent layers of one kind
+(``layer_runs``), each run one ``lax.scan``, every run of a kind scanned by
+the same traced body; every layer is the same block but for its kind's
+window and tables (``llama._attention_half``).
+
 A chip's share of the experts (``experts_held``: how many, and the first):
 the router, ``route`` and the statistics stay ``n_experts`` wide; the
 weights' first axis, the group sizes and the rows gathered are the held
@@ -51,8 +59,9 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import itertools
 from dataclasses import dataclass
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -89,6 +98,10 @@ class MoEConfig(_ll.LlamaConfig):
     router_score: str = "softmax"
     # multiplies the K weights (DeepSeek's ``routed_scaling_factor``)
     route_scale: float = 1.0
+    # one name a layer where the attention layers are of several kinds
+    # (``attn_kinds``: Mellum2's three window layers to one full layer);
+    # () = every layer of the config's one kind, one stack
+    layer_kinds: Tuple[str, ...] = ()
 
     @property
     def n_held(self) -> int:
@@ -108,13 +121,62 @@ PRESETS: Dict[str, MoEConfig] = {
                              n_heads=16, n_kv_heads=16, d_ff=1024,
                              max_seq_len=4096, n_experts=64, top_k=8,
                              qk_norm=True),
+    # JetBrains/Mellum2-12B-A2.5B-Instruct config.json: 28 layers, three
+    # sliding-window layers of 1,024 (plain rotary tables) to one full
+    # layer (YaRN x16 over 8,192), 32 query heads over 4 KV heads of a
+    # STATED 128 (hidden 2304), 64 experts of 896, 8 a token renormalised
+    "mellum2-12b-a2.5b": MoEConfig(
+        vocab_size=98304, d_model=2304, n_layers=28, n_heads=32,
+        n_kv_heads=4, head_width=128, d_ff=896, max_seq_len=131072,
+        n_experts=64, top_k=8, norm_topk=True, norm_eps=1e-6,
+        rope_theta=500000.0, router_z_weight=0.0,
+        layer_kinds=("window", "window", "window", "full") * 7,
+        attn_kinds=(
+            ("window", _ll.AttentionKind(window=1024)),
+            ("full", _ll.AttentionKind(yarn=_ll.Yarn(
+                factor=16.0, original=8192, beta_fast=32.0, beta_slow=1.0,
+                attention_factor=1.2772588722239782))))),
+    # the same block at the CPU tests' size: two periods, a window shorter
+    # than the tests' sequences, a head width no quotient of the hidden size
+    "tiny-mellum": MoEConfig(
+        vocab_size=256, d_model=48, n_layers=8, n_heads=8, n_kv_heads=1,
+        head_width=16, d_ff=32, max_seq_len=256, n_experts=8, top_k=2,
+        norm_topk=True, norm_eps=1e-6, rope_theta=10000.0,
+        router_z_weight=0.0,
+        layer_kinds=("window", "window", "window", "full") * 2,
+        attn_kinds=(
+            ("window", _ll.AttentionKind(window=24)),
+            ("full", _ll.AttentionKind(yarn=_ll.Yarn(
+                factor=4.0, original=32, beta_fast=4.0, beta_slow=0.5))))),
 }
 
 # what the layer checkpoint keeps of an expert layer (llama._checkpoint)
 REMAT_SAVED = ("moe_route",)
 
 
+def layer_runs(cfg: MoEConfig) -> List[Tuple[str, int]]:
+    """[(kind, how many adjacent layers of it), ...] in the layers' order,
+    for a config that names its layers' kinds: one stack and one scan a
+    run, one traced body a kind (llama._forward)."""
+    named, known = set(cfg.layer_kinds), set(dict(cfg.attn_kinds))
+    if len(cfg.layer_kinds) != cfg.n_layers or named - known:
+        raise ValueError(
+            f"{len(cfg.layer_kinds)} layer kinds {sorted(named)} for "
+            f"{cfg.n_layers} layers of kinds {sorted(known)}")
+    return [(kind, len(list(run)))
+            for kind, run in itertools.groupby(cfg.layer_kinds)]
+
+
+def _run_configs(cfg: MoEConfig) -> List[MoEConfig]:
+    """The config of each run's stack: that many layers of one kind."""
+    return [cfg.replace(n_layers=n, layer_kinds=())
+            for _, n in layer_runs(cfg)]
+
+
 def param_specs(cfg: MoEConfig) -> Dict[str, Any]:
+    if cfg.layer_kinds:         # a list of stacks, one a run (layer_runs)
+        runs = [param_specs(run) for run in _run_configs(cfg)]
+        return {**runs[0], "layers": [r["layers"] for r in runs]}
     spec = _ll.param_specs(cfg)
     L = ("layers",)
     lay = dict(spec["layers"])
@@ -138,6 +200,12 @@ def param_specs(cfg: MoEConfig) -> Dict[str, Any]:
 
 
 def init_params(key, cfg: MoEConfig) -> Dict[str, Any]:
+    if cfg.layer_kinds:         # a list of stacks, one a run (layer_runs)
+        runs = [init_params(jax.random.fold_in(key, 100 + i),
+                            run.replace(vocab_size=1))["layers"]
+                for i, run in enumerate(_run_configs(cfg))]
+        return {**init_params(key, cfg.replace(n_layers=0, layer_kinds=())),
+                "layers": runs}
     params = _ll.init_params(key, cfg.replace(d_ff=1))   # no dense SwiGLU
     pd = cfg.param_dtype
     L, D, F, E = cfg.n_layers, cfg.d_model, cfg.d_ff, cfg.n_experts

@@ -24,7 +24,9 @@ RECIPE_KEYS = {"train": ("attn_impl", "remat", "f32_logits"),
                "train_hybrid": ("attn_impl", "gmm_impl", "ssd_impl", "remat",
                                 "f32_logits"),
                "train_latent": ("attn_impl", "gmm_impl", "remat",
-                                "f32_logits")}
+                                "f32_logits"),
+               "train_mixed": ("attn_impl", "gmm_impl", "remat",
+                               "f32_logits")}
 # published config.json key -> the program's field
 WIDTHS = {"hidden_size": "d_model", "num_hidden_layers": "n_layers",
           "num_attention_heads": "n_heads",
@@ -49,20 +51,23 @@ def test_cell_program_config_builds_at_its_published_widths(name):
     import jax
     import jax.numpy as jnp
 
-    from benchmark import model, model_glm, model_granite, model_moe, resolve
+    from benchmark import (model, model_glm, model_granite, model_mellum,
+                           model_moe, resolve)
 
     cell = resolve.cell(name)
     kind, conf, recipe = cell["kind"], cell["config"], cell["train"]
     build = {"train": model.llama_config,
              "train_moe": model_moe.moe_config,
              "train_hybrid": model_granite.hybrid_config,
-             "train_latent": model_glm.latent_config}[kind]
+             "train_latent": model_glm.latent_config,
+             "train_mixed": model_mellum.moe_config}[kind]
     passed = {k: recipe[k] for k in RECIPE_KEYS[kind] if k in recipe}
     cfg = build(conf, **passed)
 
     widths = {"train": WIDTHS, "train_moe": {**WIDTHS, **MOE_WIDTHS},
               "train_hybrid": model_granite.HF_TO_FIELD,
-              "train_latent": model_glm.HF_TO_FIELD}[kind]
+              "train_latent": model_glm.HF_TO_FIELD,
+              "train_mixed": model_mellum.HF_TO_FIELD}[kind]
     for key, field in widths.items():
         assert getattr(cfg, field) == conf[key], (name, key)
     if kind == "train_hybrid":
@@ -87,6 +92,25 @@ def test_cell_program_config_builds_at_its_published_widths(name):
         assert cfg.head_dim == cfg.qk_nope_dim + cfg.qk_rope_dim == cfg.v_dim
         assert cfg.router_score == "sigmoid"
         assert cfg.shared_d_ff == conf["n_shared_experts"] * cfg.d_ff
+    if kind == "train_mixed":
+        # the stated head width, the kinds of layer with their windows and
+        # tables are the published keys'; the router's width and the
+        # experts held the deployment's
+        dep = conf["deployment"]
+        assert cfg.n_experts == dep["router_experts"]
+        assert cfg.experts_held == (conf["num_experts"],
+                                    dep["experts_first"])
+        assert cfg.head_dim == conf["head_dim"]
+        kinds = {"sliding_attention": "window", "full_attention": "full"}
+        assert cfg.layer_kinds == tuple(
+            kinds[t] for t in conf["layer_types"][:cfg.n_layers])
+        of = dict(cfg.attn_kinds)
+        assert of["window"].window == conf["sliding_window"]
+        assert of["window"].yarn is None and of["full"].window is None
+        rope = conf["rope_parameters"]["full_attention"]
+        assert (of["full"].yarn.factor, of["full"].yarn.original) == (
+            rope["factor"], rope["original_max_position_embeddings"])
+        assert of["full"].rope_theta == rope["rope_theta"]
     for key, value in passed.items():
         assert getattr(cfg, key) == value, (name, key)
     assert cfg.dtype == getattr(jnp, conf["run"]["dtype"])

@@ -279,6 +279,41 @@ def test_flash_sliding_window_gradients():
                                    rtol=5e-3, atol=1e-2)
 
 
+@pytest.mark.parametrize("window", [100, None], ids=["w100", "full"])
+@pytest.mark.parametrize("what", ["fwd", "dq", "dkdv"])
+def test_flash_at_eight_query_heads_a_kv_head_against_xla(what, window):
+    """Mellum2's grouping (32 query heads over 4 KV heads: 8 a group) at a
+    window that is no multiple of the block, and with none: the banded
+    forward, dQ and dK/dV calls against the model's own einsum attention
+    (``llama._attention_xla``) under the same mask."""
+    from ray_tpu.models.llama import _attention_xla
+
+    q, k, v = _make(B=1, S=256, H=8, KV=1, D=32, seed=11)
+    g = jax.random.normal(jax.random.PRNGKey(12), q.shape, q.dtype)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, window=window, block_q=64,
+                               block_k=64)
+
+    def xla(q, k, v):
+        return _attention_xla(q, k, v, True, window=window)
+
+    if what == "fwd":
+        got, want = flash(q, k, v), xla(q, k, v)
+    else:
+        argnums = (0,) if what == "dq" else (1, 2)
+        got, want = (jax.grad(lambda *a: (f(*a) * g).sum(), argnums)(q, k, v)
+                     for f in (flash, xla))
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=5e-3, atol=1e-2)
+    if window is not None and what == "fwd":
+        # one key more or fewer is another result: the band's edge counts
+        off = flash_attention(q, k, v, window=window - 1, block_q=64,
+                              block_k=64)
+        assert float(jnp.max(jnp.abs(off - got))) > 1e-3
+
+
 # -- the dK/dV call's two block plans (ops/flash_attention.py) ---------------
 
 def _fa():
@@ -309,9 +344,17 @@ def _dkdv(path, res, g, *, causal, window, block):
             .transpose(0, 2, 1, 3) for x in (dk, dv)]
 
 
+def _window(mask: str) -> int:
+    """``window64`` -> 64, ``window100`` (no multiple of the tests' block
+    of 32: the band's edge cuts a block) -> 100, else 0."""
+    return int(mask[6:]) if mask.startswith("window") else 0
+
+
 @pytest.mark.parametrize("blocks", [1, 4], ids=["S=block", "S=4blocks"])
-@pytest.mark.parametrize("heads", [(2, 2), (4, 2)], ids=["H=KV", "groups2"])
-@pytest.mark.parametrize("mask", ["causal", "noncausal", "window64"])
+@pytest.mark.parametrize("heads", [(2, 2), (4, 2), (8, 1)],
+                         ids=["H=KV", "groups2", "groups8"])
+@pytest.mark.parametrize("mask", ["causal", "noncausal", "window64",
+                                  "window100"])
 @pytest.mark.parametrize("path", ["resident", "stream"])
 def test_dkdv_block_plans(path, mask, heads, blocks):
     """Each plan against the chunked reference at the file's tolerance,
@@ -319,7 +362,7 @@ def test_dkdv_block_plans(path, mask, heads, blocks):
     order, only the blocks arrive differently."""
     fa = _fa()
     block = 32
-    causal, window = mask != "noncausal", 64 if mask == "window64" else 0
+    causal, window = mask != "noncausal", _window(mask)
     q, k, v = _make(B=1, S=block * blocks, H=heads[0], KV=heads[1], D=32,
                     seed=3)
     _, res = fa._flash_vjp_fwd(q, k, v, causal, block, block, window)
@@ -540,7 +583,8 @@ def _one_block_walk(call, q, k, v, g=None, out=None, lse=None, *, causal,
 @pytest.mark.parametrize("blocks", [1, 3, 8],
                          ids=["S=block", "S=3blocks", "S=8blocks"])
 @pytest.mark.parametrize("heads", [(2, 2), (4, 2)], ids=["H=KV", "groups2"])
-@pytest.mark.parametrize("mask", ["causal", "noncausal", "window64"])
+@pytest.mark.parametrize("mask", ["causal", "noncausal", "window64",
+                                  "window100"])
 @pytest.mark.parametrize("path", ["loop", "stream"])
 @pytest.mark.parametrize("call", ["fwd", "dq"])
 def test_the_walk_of_k_blocks_is_the_one_block_walk_to_the_last_bit(
@@ -553,7 +597,7 @@ def test_the_walk_of_k_blocks_is_the_one_block_walk_to_the_last_bit(
     a window's band starts inside a span."""
     fa = _fa()
     block = 32
-    causal, window = mask != "noncausal", 64 if mask == "window64" else 0
+    causal, window = mask != "noncausal", _window(mask)
     S = block * blocks
     # D 64: the scale is a power of two. XLA's CPU backend contracts
     # `s * scale - m` into one rounding where both land in one fusion,

@@ -205,6 +205,9 @@ CELL_FLASH = {
     "train-olmoe1b7b-s4096-b4": (4, 4096, 16, 16, 128, None),
     "train-granite4hs-ep8-s8192-b2": (2, 8192, 32, 8, 128, 0.0078125),
     "train-glm47flash-ep8-s8192-b2": (2, 8192, 20, 20, 256, None),
+    # Mellum2's two kinds of layer (a seventh entry: the window)
+    "train-mellum2-ep4-s16384-b1/full": (1, 16384, 32, 4, 128, None),
+    "train-mellum2-ep4-s16384-b1/window": (1, 16384, 32, 4, 128, None, 1024),
 }
 
 
@@ -227,12 +230,13 @@ def test_the_cells_flash_walks_fit_the_vmem_a_call_gets(cell, one_chip,
     from ray_tpu.ops.flash_attention import flash_attention
 
     fa = sys.modules["ray_tpu.ops.flash_attention"]
-    B, S, H, KV, D, scale = CELL_FLASH[cell]
+    B, S, H, KV, D, scale, *window = CELL_FLASH[cell]
+    window = window[0] if window else None
     q = _sds((B, S, H, D), jnp.bfloat16, one_chip)
     kv = _sds((B, S, KV, D), jnp.bfloat16, one_chip)
 
     def loss(q, k, v):
-        return flash_attention(q, k, v, scale=scale).astype(
+        return flash_attention(q, k, v, scale=scale, window=window).astype(
             jnp.float32).sum()
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
@@ -248,14 +252,20 @@ def test_the_cells_flash_walks_fit_the_vmem_a_call_gets(cell, one_chip,
                 size.group(1)) if size else fa._SCOPED_VMEM_BYTES
     assert sorted(scoped) == ["dkdv", "dq", "fwd"], scoped
     assert scoped["fwd"] == scoped["dq"] == fa._SCOPED_VMEM_BYTES, scoped
-    streams = D == 256
+    # K and V of a head are 16 MiB double-buffered at S 8192 x D 256 and
+    # at S 16384 x D 128: both stream, and so does every windowed call
+    streams = S * D == 8192 * 256
     assert (scoped["dkdv"] > fa._SCOPED_VMEM_BYTES) == (not streams), scoped
     plans = {c: fa.kv_plan(S=S, T=S, D=D, dtype=jnp.bfloat16, block_q=512,
-                           block_k=512, call=c) for c in ("fwd", "dq")}
+                           block_k=512, window=window or 0, call=c)
+             for c in ("fwd", "dq")}
+    want = [("loop", S, 1), ("loop", S, 2)]
+    if streams:         # half a head's keys a grid step; a window: its own
+        span = window or S // 2
+        want = [("stream", span, 2),
+                ("stream", span, 1 if D == 256 else 2)]
     assert [(p["path"], p["span"], p["in_flight"])
-            for p in plans.values()] == (
-        [("stream", 4096, 2), ("stream", 4096, 1)] if streams
-        else [("loop", S, 1), ("loop", S, 2)]), plans
+            for p in plans.values()] == want, plans
 
 
 # (rows, experts, model width, one expert's width) of a cell's grouped
