@@ -16,12 +16,21 @@ block_k] granularity. What a grid step holds is chosen by the shape alone
           span outside it fetches nothing): the longest span that fits (S
           8192 at D 256, where the loop's blocks alone are 16 MiB: 4,096
           keys, two grid steps a q-block where one block a step made
-          sixteen; a grid step costs about a microsecond whatever it
-          does), the sums in VMEM scratch, read and written once a step of
+          sixteen; a grid step that works costs about a microsecond
+          beside its blocks), the sums in VMEM scratch, once a step of
           the walk, and TWO k-blocks a step of the walk where their
           temporaries fit: both blocks' `q k^T` are issued before the
           first block's softmax, two chains in one straight-line body.
-          Serves every windowed call.
+  band    a window that ends every q-block's band before T does: the
+          longest band is the span (S 16384, window 1024, blocks of 512:
+          3 k-blocks of T's 32), fetched at the k-block the band starts
+          at, so the grid is as long as the band: ONE step a q-block and
+          none that finds nothing to do (a grid of T's 16 spans with the
+          index clamped into the band ran 14 empty steps of 16, 0.10 us
+          each in the forward, 0.26 in the dQ call with its six
+          operands), the sums in the loop's carry as on the loop plan,
+          the band's blocks all in flight. A band too long to hold
+          streams.
 Each block makes its own online-softmax update in rising order on every
 plan, so all of them agree to the last bit (on the chip: with the kernels
 of one block a grid step they replaced, PERF.md 6, PR 37).
@@ -30,9 +39,9 @@ Backward: full Pallas two-kernel backward (FlashAttention-2 style), both
 recomputing probabilities from the saved log-sum-exp so nothing O(S^2) is
 ever materialized. The dQ pass (`_dq_kernel`) is the forward's walk: a
 q-block resident, K and V a span a grid step by the forward's plan
-(`loop`: dq is the loop's carry, written once in q's dtype; `stream`: dq
-accumulated in the float32 output block, which stays resident across a
-q-block's spans), two k-blocks a step of the walk where their
+(`loop`, `band`: dq is the loop's carry, written once in q's dtype;
+`stream`: dq accumulated in the float32 output block, which stays resident
+across a q-block's spans), two k-blocks a step of the walk where their
 temporaries fit beside the span (their `q k^T` and `g v^T` first); every
 plan sums in the same order. The dK/dV pass is
 its mirror image and has two block plans, told apart by the shape alone
@@ -54,6 +63,13 @@ its mirror image and has two block plans, told apart by the shape alone
             that runs, 35 of 64 at S 4096 causal = 22 MiB + 6 (f32
             results). Unclamped, as this grid was until PR 28, every step
             fetched: 46 MiB a head.
+  band      the stream plan where a window ends every k-block's band
+            before S does: the q axis is as long as the longest band (S
+            16384, window 1024: 3 q-blocks of S's 32) and counts from the
+            band's first q-block, so a k-block's 29 steps outside its band
+            are gone (15.03 -> 7.04 ms a call; PERF.md 6, PR 39). The
+            causal grid without a window keeps its empty triangle: a band
+            as long as S has no shorter axis.
 Both run the same accumulate step (`_dkdv_step`) in the same order, so
 their results agree to the last bit. A chunked-recompute JAX fallback
 remains selectable via BACKWARD_IMPL for debugging.
@@ -147,37 +163,75 @@ def _k_band(qi, *, num_k: int, block_q: int, block_k: int, causal: bool,
     return lo, hi
 
 
-def _span_band(qi, si, *, span: int, num_k: int, block_q: int, block_k: int,
-               causal: bool, window: int):
-    """[lo, hi), traced: the k-blocks of q-block `qi`'s band that grid
-    step `si` holds, at `span` blocks a step."""
-    lo, hi = _k_band(qi, num_k=num_k, block_q=block_q, block_k=block_k,
-                     causal=causal, window=window)
+def _span_steps(*, num_q: int, span: int, num_k: int, block_q: int,
+                block_k: int, causal: bool, window: int) -> Tuple[int, int]:
+    """(steps, band_steps) of a head's walk at `span` k-blocks a grid
+    step: the length of the span axis, and how many of the head's num_q x
+    steps grid steps hold a block of a band. A span that is shorter than T
+    and holds the longest band of any q-block is a banded call's: ONE step
+    a q-block, the span fetched where the band starts (`_span_band`).
+    Every other span is one of T's num_k / span, and the axis counts all
+    of them."""
+    mask = dict(num_k=num_k, block_q=block_q, block_k=block_k, causal=causal,
+                window=window)
+    bands = [_k_band(qi, **mask) for qi in range(num_q)]
+    if max(hi - lo for lo, hi in bands) <= span < num_k:
+        return 1, num_q
+    return num_k // span, sum((hi - 1) // span - lo // span + 1
+                              for lo, hi in bands)
+
+
+def _band_start(qi, *, span: int, num_k: int, block_q: int, block_k: int,
+                causal: bool, window: int):
+    """The first k-block of a banded call's one span of q-block `qi`
+    (`_span_steps`): the band's first, or as late as T lets a span
+    start."""
+    lo, _ = _k_band(qi, num_k=num_k, block_q=block_q, block_k=block_k,
+                    causal=causal, window=window)
+    _, least, _ = _index_ops(qi)
+    return least(lo, num_k - span)
+
+
+def _span_band(qi, si, *, span: int, steps: int, num_k: int, block_q: int,
+               block_k: int, causal: bool, window: int):
+    """(lo, hi, first), traced: the k-blocks [lo, hi) of q-block `qi`'s
+    band that grid step `si` holds, at `span` blocks a step, and the
+    span's first k-block (a function, for the kernel to call where it
+    slices a block): all of the band in a banded call's one step
+    (`_span_steps`), else what the si-th of T's spans holds of it."""
+    mask = dict(num_k=num_k, block_q=block_q, block_k=block_k, causal=causal,
+                window=window)
+    lo, hi = _k_band(qi, **mask)
+    if steps == 1 and span < num_k:
+        start = _band_start(qi, span=span, **mask)
+        return lo, hi, lambda: start
     return (jax.lax.max(jnp.int32(lo), si * span),
-            jax.lax.min(jnp.int32(hi), (si + 1) * span))
+            jax.lax.min(jnp.int32(hi), (si + 1) * span), lambda: si * span)
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, block_k: int,
-                num_k: int, scale: float, causal: bool, window: int,
-                in_flight: int):
+                num_k: int, steps: int, scale: float, causal: bool,
+                window: int, in_flight: int):
     """Grid (b, h, q-block, span): the q-block stays, K and V arrive a
     span of k-blocks a grid step through an index map clamped into the
     band (`_k_span_index`: a span outside it repeats its neighbour's index
     and Mosaic, which elides a fetch whose index repeats, fetches nothing:
     O(S*W) HBM traffic for sliding windows instead of O(S*T)), and the
     kernel walks the span's blocks of the band itself (`_walk`).
-    One span (the `loop` plan: a head's whole K and V): the accumulator,
-    the running max and the running sum are the loop's carry, one block an
-    iteration. Several (`stream`): the three live in VMEM scratch across
-    the grid steps of a q-block (same structure as the official TPU flash
-    kernel) and a step of the walk reads and writes them once, not once a
-    block; the last grid step normalizes and writes o and lse."""
+    One grid step a q-block (`steps` 1: the `loop` plan's span is a head's
+    whole K and V, the `band` plan's the q-block's whole band, fetched
+    where it starts): the accumulator, the running max and the running
+    sum are the loop's carry. Several (`stream`): the three live in VMEM
+    scratch across the grid steps of a q-block (same structure as the
+    official TPU flash kernel) and a step of the walk reads and writes
+    them once, not once a block; the last grid step normalizes and writes
+    o and lse."""
     block_q, D = q_ref.shape[2], q_ref.shape[3]
     span = k_ref.shape[2] // block_k
-    spans = num_k // span
     qi, si = pl.program_id(2), pl.program_id(3)
-    lo, hi = _span_band(qi, si, span=span, num_k=num_k, block_q=block_q,
-                        block_k=block_k, causal=causal, window=window)
+    lo, hi, first = _span_band(
+        qi, si, span=span, steps=steps, num_k=num_k, block_q=block_q,
+        block_k=block_k, causal=causal, window=window)
 
     def keep(o, m, l):
         acc, m_scr, l_scr = scratch
@@ -198,7 +252,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, block_k: int,
             jnp.int32, (block_q, 1), 0)
 
         def block(ref, ki):
-            rows = pl.multiple_of((ki - si * span) * block_k, block_k)
+            rows = pl.multiple_of((ki - first()) * block_k, block_k)
             return ref[0, 0, pl.ds(rows, block_k), :]
 
         def step(carry, ki, n):
@@ -207,7 +261,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, block_k: int,
             scores = [jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale for k, _ in kv]
-            o, m, l = carry if spans == 1 else kept()
+            o, m, l = carry if steps == 1 else kept()
             for j, ((_, v), s) in enumerate(zip(kv, scores)):
                 if causal:
                     s = _mask(s, q_pos, ki + j, block_k=block_k,
@@ -222,7 +276,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, block_k: int,
                 o = o * alpha + jax.lax.dot(
                     p.astype(v.dtype), v, preferred_element_type=jnp.float32)
                 m = m_new
-            if spans == 1:
+            if steps == 1:
                 return o, m, l
             keep(o, m, l)
             return carry
@@ -240,7 +294,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, block_k: int,
     zero = (jnp.zeros((block_q, D), jnp.float32),
             jnp.full((block_q, 1), NEG_INF, jnp.float32),
             jnp.zeros((block_q, 1), jnp.float32))
-    if spans == 1:
+    if steps == 1:
         finish(*walk(zero))
         return
 
@@ -252,7 +306,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, block_k: int,
     def _span():
         walk(0)
 
-    @pl.when(si == spans - 1)
+    @pl.when(si == steps - 1)
     def _finish():
         finish(*kept())
 
@@ -262,7 +316,7 @@ _SCOPED_VMEM_BYTES = 16 * 2 ** 20
 
 
 def kv_plan(*, S: int, T: int, D: int, dtype, block_q: int, block_k: int,
-            window: int = 0, call: str = "fwd") -> dict:
+            window: int = 0, call: str = "fwd", causal: bool = True) -> dict:
     """How a call that walks k-blocks for a resident q-block (`call`: the
     forward `fwd`, the dQ pass `dq`) holds K and V, and the bytes that
     decide it (the attributes of `flash.fwd_plan`; the dQ call's are in
@@ -274,25 +328,42 @@ def kv_plan(*, S: int, T: int, D: int, dtype, block_q: int, block_k: int,
           temporaries of one block's step (`loop_bytes`) fit the VMEM a
           call gets without asking (the compiler refused S 8192 x D 256
           at 17.5 MiB of 16); stream otherwise, and for a windowed
-          forward. Asking for more is not the way: a call's limit is
-          taken out of XLA's own fast memory for as long as the call is
-          scheduled (`bwd_dkdv_plan`).
-    span  keys of K and V a grid step holds: T on the loop plan; on the
-          stream plan the most k-blocks (a divisor of their number, no
-          more than cover a window) whose step fits (`walk_bytes`). A grid
-          step costs about a microsecond whatever it does (S 8192, D 256
-          on a v5e: 256 steps a head of one block 14.56 ms a forward
-          call, 32 of eight 11.33; PERF.md 6, PR 37), so the longest span
-          comes first.
+          forward: spans of T through an index map clamped into the
+          band; band: where such a call's window ends every q-block's
+          band before T does and the longest band fits as ONE span (S
+          16384, window 1024, blocks of 512: 3 k-blocks), the span axis
+          is one step and the span is fetched where the band starts, at
+          any k-block (an offset by elements): no grid step finds
+          nothing to do, and the sums never leave the loop's carry (the
+          banded forward 9.76 -> 5.77 ms a call, dQ 9.57 -> 4.48: PERF.md
+          6, PR 39). Asking for more VMEM is not the way: a call's limit
+          is taken out of XLA's own fast memory for as long as the call
+          is scheduled (`bwd_dkdv_plan`).
+    span  keys of K and V a grid step holds: T on the loop plan, the
+          longest band on the band plan; on the stream plan the most
+          k-blocks (a divisor of their number, no more than cover a
+          window) whose step fits (`walk_bytes`). A grid step that works
+          costs about a microsecond beside its blocks (S 8192, D 256 on a
+          v5e: 256 steps a head of one block 14.56 ms a forward call, 32
+          of eight 11.33; PERF.md 6, PR 37), so the longest span comes
+          first.
     in_flight  k-blocks a step of the walk takes (`_walk`): 2 where the
           span leaves a second block's temporaries room, else 1; 1 in the
           loop plan's forward, whose carry of three arrays makes the
           second loop (the odd block's) cost more than the pairs win
-          (S 4096, D 128: 5.62 ms a call against 5.57).
+          (S 4096, D 128: 5.62 ms a call against 5.57); on the band plan
+          the whole band where its temporaries fit, one straight-line
+          body a q-block (3 at the shape above: forward 5.77 ms against
+          5.90 at 1 and 6.15 at 2, dQ 4.48 against 4.76 and 4.68).
+    steps, band_steps  of one head: the span axis's length, and how many
+          of the head's S / block_q x steps grid steps hold a block of a
+          band (`_span_steps`).
 
     `walk_bytes` is above what Mosaic planned at every shape compiled for
     a v5e (the least `vmem_limit_bytes` it accepted, to a quarter MiB, PR
-    37: D 128 and 256, spans of 1 to 16 blocks, by 0.2 to 1.9 MiB)."""
+    37: D 128 and 256, spans of 1 to 16 blocks, by 0.2 to 1.9 MiB; PR 39,
+    the band plan: bands of 3, 5 and 9 blocks, 1 to 5 in flight, the dQ
+    call by 0.25 to 4 MiB, the forward by 2 and more)."""
     itemsize = jnp.dtype(dtype).itemsize
     kv_block_bytes = 2 * 2 * T * D * itemsize
     # q, dO, o, the result and the 128-lane lse, double-buffered
@@ -321,33 +392,56 @@ def kv_plan(*, S: int, T: int, D: int, dtype, block_q: int, block_k: int,
                 3 * D * itemsize + _LSE_LANES * 4
                 + D * (4 if path == "stream" else itemsize))
             # a block in flight: s and dp, and k and v cast to f32; q and
-            # dO cast to f32, and the sum
+            # dO cast to f32, and the sum; three more of its size on the
+            # band plan, where Mosaic planned 0.75 to 1.25 MiB over the
+            # rest at D 256 (at 3 in flight 17.0 MiB of 16)
             step = (in_flight * (scores * 8 + 2 * block_k * D * 4)
-                    + 3 * block_q * D * 4)
+                    + (6 if path == "band" else 3) * block_q * D * 4)
         return 2 * 2 * blocks * block_k * D * itemsize + q_side + step
 
-    num_k = T // block_k
+    num_q, num_k = S // block_q, T // block_k
+    mask = dict(num_k=num_k, block_q=block_q, block_k=block_k, causal=causal,
+                window=window)
+    band = max(hi - lo for lo, hi in (
+        _k_band(qi, **mask) for qi in range(num_q)))
+    if path == "stream" and band < num_k:
+        path = "band"                      # `walk_bytes` reads it
+        if walk_bytes(band, 1) > _SCOPED_VMEM_BYTES:
+            path = "stream"
     most = num_k if window == 0 else min(num_k, -(-window // block_k))
-    spans = [num_k] if path == "loop" else [
+    spans = {"loop": [num_k], "band": [band]}.get(path) or [
         n for n in range(most, 0, -1) if num_k % n == 0]
-    pairs = (1,) if (path, call) == ("loop", "fwd") else (2, 1)
+    pairs = (1,) if call == "fwd" and path != "stream" else (2, 1)
+    if path == "band":
+        pairs = (band,) + pairs
     blocks, in_flight = next(
         ((n, f) for n in spans for f in pairs
          if f <= n and walk_bytes(n, f) <= _SCOPED_VMEM_BYTES),
         (spans[-1], 1))
+    steps, band_steps = _span_steps(num_q=num_q, span=blocks, **mask)
     return dict(path=path, S=S, D=D, kv_block_bytes=kv_block_bytes,
                 loop_bytes=loop_bytes, span=blocks * block_k,
                 in_flight=in_flight,
-                walk_bytes=walk_bytes(blocks, in_flight))
+                walk_bytes=walk_bytes(blocks, in_flight), steps=steps,
+                band_steps=band_steps)
+
+
+def _grid_steps(plan: dict, heads: int, blocks: int, prefix: str = "") -> dict:
+    """What a plan instant says of its call's grid of `heads` x `blocks`
+    x the plan's `steps`: `grid_steps`, all of it, and `band_steps`, the
+    steps that hold a block of a band (their quotient is the share of grid
+    steps that work)."""
+    return {prefix + "grid_steps": heads * blocks * plan["steps"],
+            prefix + "band_steps": heads * plan["band_steps"]}
 
 
 def _k_span_index(qi, si, *, span: int, num_k: int, block_q: int,
                   block_k: int, causal: bool, window: int):
     """The span of K and V at grid step (qi, si) of a call that holds a
-    q-block and steps over spans of `span` k-blocks: si clamped into the
-    spans that hold a block of q-block qi's band, so a span outside it
-    repeats the index of the band's near edge and Mosaic, which elides a
-    fetch whose index repeats, fetches nothing."""
+    q-block and steps over all of T's spans of `span` k-blocks: si clamped
+    into the spans that hold a block of q-block qi's band, so a span
+    outside it repeats the index of the band's near edge and Mosaic, which
+    elides a fetch whose index repeats, fetches nothing."""
     if not causal or span == num_k:
         return si
     lo, hi = _k_band(qi, num_k=num_k, block_q=block_q, block_k=block_k,
@@ -356,20 +450,31 @@ def _k_span_index(qi, si, *, span: int, num_k: int, block_q: int,
     return most(least(si, div(hi - 1, span)), div(lo, span))
 
 
-def _walk_specs(*, groups: int, span: int, num_k: int, block_q: int,
-                block_k: int, D: int, causal: bool, window: int):
+def _walk_specs(*, groups: int, span: int, steps: int, num_k: int,
+                block_q: int, block_k: int, D: int, causal: bool, window: int):
     """Block specs of a call on grid (b, h, q-block, span): `q_side(width)`
     for an operand or result that follows the q-block, and the spec of K
-    and V, `span` k-blocks a grid step by `_k_span_index`."""
+    and V, `span` k-blocks a grid step: by `_k_span_index` among T's
+    spans, or, a banded call's one span (`_span_steps`), by the element
+    its band starts at (a band starts at any k-block, not at a multiple of
+    the span)."""
+    mask = dict(span=span, num_k=num_k, block_q=block_q, block_k=block_k,
+                causal=causal, window=window)
+
     def q_side(width):
         return pl.BlockSpec((1, 1, block_q, width),
                             lambda b, h, qi, si: (b, h, qi, 0))
 
     def kv_idx(b, h, qi, si):
-        return (b, h // groups, _k_span_index(
-            qi, si, span=span, num_k=num_k, block_q=block_q,
-            block_k=block_k, causal=causal, window=window), 0)
+        return (b, h // groups, _k_span_index(qi, si, **mask), 0)
 
+    def kv_at(b, h, qi, si):
+        return (b, h // groups, _band_start(qi, **mask) * block_k, 0)
+
+    if steps == 1 and span < num_k:
+        # Mosaic takes offsets by elements in all of a block's axes or none
+        return q_side, pl.BlockSpec(tuple(
+            pl.Element(n) for n in (1, 1, span * block_k, D)), kv_at)
     return q_side, pl.BlockSpec((1, 1, span * block_k, D), kv_idx)
 
 
@@ -384,30 +489,34 @@ def _flash_fwd(q, k, v, *, causal: bool, block_q: int, block_k: int,
     block_k = min(block_k, T)
     if plan is None:
         plan = kv_plan(S=S, T=T, D=D, dtype=k.dtype, block_q=block_q,
-                       block_k=block_k, window=window)
-        tracing.instant("flash.fwd_plan", {n: plan[n] for n in (
-            "path", "S", "D", "kv_block_bytes", "span", "in_flight")})
+                       block_k=block_k, window=window, causal=causal)
+        tracing.instant("flash.fwd_plan", {
+            **{n: plan[n] for n in ("path", "S", "D", "kv_block_bytes",
+                                    "span", "in_flight")},
+            **_grid_steps(plan, B * H, S // block_q)})
     # layout: [B, H, S, D] per-instance slices
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
     num_k = T // block_k
     span = plan["span"] // block_k
-    q_side, kv_blk = _walk_specs(
-        groups=groups, span=span, num_k=num_k, block_q=block_q,
-        block_k=block_k, D=D, causal=causal, window=window)
+    mask = dict(num_k=num_k, block_q=block_q, block_k=block_k, causal=causal,
+                window=window)
+    steps, _ = _span_steps(num_q=S // block_q, span=span, **mask)
+    q_side, kv_blk = _walk_specs(groups=groups, span=span, steps=steps, D=D,
+                                 **mask)
     call = pl.pallas_call(
         functools.partial(_fwd_kernel, block_k=block_k, num_k=num_k,
-                          scale=scale, causal=causal, window=window,
-                          in_flight=plan["in_flight"]),
-        grid=(B, H, S // block_q, num_k // span),
+                          steps=steps, scale=scale, causal=causal,
+                          window=window, in_flight=plan["in_flight"]),
+        grid=(B, H, S // block_q, steps),
         in_specs=[q_side(D), kv_blk, kv_blk],
         out_specs=[q_side(D), q_side(_LSE_LANES)],
         out_shape=[
             jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
             jax.ShapeDtypeStruct((B, H, S, _LSE_LANES), jnp.float32),
         ],
-        scratch_shapes=[] if span == num_k else [
+        scratch_shapes=[] if steps == 1 else [
             pltpu.VMEM((block_q, D), jnp.float32),            # acc
             pltpu.VMEM((block_q, _LSE_LANES), jnp.float32),   # running max
             pltpu.VMEM((block_q, _LSE_LANES), jnp.float32),   # running sum
@@ -420,24 +529,25 @@ def _flash_fwd(q, k, v, *, causal: bool, block_q: int, block_k: int,
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, g_ref, o_ref, lse_ref, dq_ref, *,
-               block_k: int, num_k: int, scale: float, causal: bool,
-               window: int, in_flight: int):
+               block_k: int, num_k: int, steps: int, scale: float,
+               causal: bool, window: int, in_flight: int):
     """Grid (b, h, q-block, span), the forward's walk (`_fwd_kernel`) for
     dQ (FlashAttention-2 backward, dQ pass): K and V arrive a span of
     k-blocks a grid step, and dQ accumulates over the span's blocks of the
     band, `in_flight` at a time (a block's `q k^T` and `g v^T` need
     nothing of the sum). delta = rowsum(o * dO) is computed in-kernel,
-    once a grid step. One span (`loop`): the sum is the loop's carry and
-    dQ is written once, in q's dtype. Several (`stream`): the f32 dq
-    output block is constant in the (minor) span axis, so Mosaic keeps it
-    resident and a step of the walk adds to it, in the loop plan's
-    order."""
+    once a grid step. One grid step a q-block (`loop`, `band`): the sum
+    is the loop's carry and dQ is written once, in q's dtype. Several
+    (`stream`): the f32 dq output block is constant in the (minor) span
+    axis, so Mosaic keeps it resident and a step of the walk adds to it,
+    in the loop plan's order."""
     block_q, D = q_ref.shape[2], q_ref.shape[3]
     span = k_ref.shape[2] // block_k
-    streamed = span != num_k
+    streamed = steps > 1
     qi, si = pl.program_id(2), pl.program_id(3)
-    lo, hi = _span_band(qi, si, span=span, num_k=num_k, block_q=block_q,
-                        block_k=block_k, causal=causal, window=window)
+    lo, hi, first = _span_band(
+        qi, si, span=span, steps=steps, num_k=num_k, block_q=block_q,
+        block_k=block_k, causal=causal, window=window)
 
     def walk(carry):
         q = q_ref[0, 0].astype(jnp.float32)
@@ -449,7 +559,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, g_ref, o_ref, lse_ref, dq_ref, *,
             jnp.int32, (block_q, 1), 0)
 
         def block(ref, ki):
-            rows = pl.multiple_of((ki - si * span) * block_k, block_k)
+            rows = pl.multiple_of((ki - first()) * block_k, block_k)
             return ref[0, 0, pl.ds(rows, block_k), :].astype(jnp.float32)
 
         def step(carry, ki, n):
@@ -581,12 +691,16 @@ def _bwd_dkdv_resident_kernel(q_ref, k_ref, v_ref, g_ref, o_ref, lse_ref,
 
 def _bwd_dkdv_stream_kernel(q_ref, k_ref, v_ref, g_ref, o_ref, lse_ref,
                             dk_ref, dv_ref, *, block_q: int, num_q: int,
-                            scale: float, causal: bool, window: int):
+                            steps: int, scale: float, causal: bool,
+                            window: int):
     """Grid (b, h, k-block, q-block): the f32 dk/dv output block is
     constant in the (minor) q axis, so Mosaic keeps it resident and this
     accumulates across sequential q steps: O(block) VMEM at any sequence
-    length. The query-side blocks arrive through index maps clamped into
-    the band (`_flash_bwd_dkdv`), so a step outside it fetches nothing."""
+    length. The q axis has `steps` steps: all of S's q-blocks, or, where a
+    window ends every band sooner (`band`), as many as the longest band
+    holds, counted from the band's first q-block. The query-side blocks
+    arrive through index maps clamped into the band (`_flash_bwd_dkdv`),
+    so a step outside it fetches nothing."""
     block_k = k_ref.shape[2]
     ki = pl.program_id(2)
     qi = pl.program_id(3)
@@ -598,6 +712,8 @@ def _bwd_dkdv_stream_kernel(q_ref, k_ref, v_ref, g_ref, o_ref, lse_ref,
 
     lo, hi = _q_band(ki, num_q=num_q, block_q=block_q,
                      block_k=block_k, causal=causal, window=window)
+    if steps < num_q:                      # the axis counts from the band
+        qi = qi + lo
 
     @pl.when((qi >= lo) & (qi < hi))
     def _accumulate():
@@ -624,32 +740,50 @@ def _vmem_bytes() -> int:
         return _V5E_VMEM_BYTES
 
 
-def _q_block_index(ki, qi, *, num_q: int, block_q: int, block_k: int,
-                   causal: bool, window: int):
-    """The streaming plan's query-side block at grid step (ki, qi): qi
-    clamped into k-block ki's band, as the forward's `kv_idx` clamps k and
-    v. A step outside the band repeats the index of the band's near edge,
-    and Mosaic elides a fetch whose index repeats."""
+def _q_steps(*, num_q: int, num_k: int, block_q: int, block_k: int,
+             causal: bool, window: int) -> Tuple[int, int]:
+    """(steps, band_steps) of a head's streamed dK/dV walk: the length of
+    the q axis, which is the most q-blocks the band of any k-block holds
+    (all of S's unless a window ends the bands early), and how many of a
+    head's num_k x steps grid steps hold a q-block of a band."""
+    bands = [_q_band(ki, num_q=num_q, block_q=block_q, block_k=block_k,
+                     causal=causal, window=window) for ki in range(num_k)]
+    return max(hi - lo for lo, hi in bands), sum(hi - lo for lo, hi in bands)
+
+
+def _q_block_index(ki, qi, *, steps: int, num_q: int, block_q: int,
+                   block_k: int, causal: bool, window: int):
+    """The streamed dK/dV call's query-side block at grid step (ki, qi) of
+    a q axis `steps` long: one as long as S's counts from q-block 0, one
+    shorter (a banded call) from the first q-block of k-block ki's band;
+    either way clamped into the band, as the forward's `kv_idx` clamps k
+    and v. A step outside the band repeats the index of the band's near
+    edge, and Mosaic elides a fetch whose index repeats."""
+    if not causal:
+        return qi
     lo, hi = _q_band(ki, num_q=num_q, block_q=block_q, block_k=block_k,
                      causal=causal, window=window)
     _, least, most = _index_ops(qi)
-    return most(least(qi, hi - 1), lo) if causal else qi
+    if steps < num_q:
+        return least(lo + qi, hi - 1)
+    return most(least(qi, hi - 1), lo)
 
 
 def hbm_bytes_per_head(path: str, *, S: int, T: int, D: int, block_q: int,
                        block_k: int, itemsize: int, out_itemsize: int,
-                       q_index=None) -> int:
+                       q_index=None, steps: int = None) -> int:
     """HBM bytes the dK/dV call moves for one (b, h): k and v read and dk
     and dv written once, plus the query side (q, dO, o and the 128-lane
-    f32 lse): once on the resident plan; on the streaming plan one block
-    each time `q_index(ki, qi)` changes along the grid's walk."""
+    f32 lse): once on the resident plan; on the streaming plans one block
+    each time `q_index(ki, qi)` changes along the walk of the grid, whose
+    q axis is `steps` long (all of S's q-blocks if not given)."""
     kv_bytes = 2 * T * D * (itemsize + out_itemsize)
     row_bytes = 3 * D * itemsize + _LSE_LANES * 4
     if path == "resident":
         return S * row_bytes + kv_bytes
     fetches, last = 0, None
     for ki in range(T // block_k):
-        for qi in range(S // block_q):
+        for qi in range(steps or S // block_q):
             index = q_index(ki, qi)
             fetches += index != last
             last = index
@@ -663,12 +797,18 @@ def bwd_dkdv_plan(*, S: int, T: int, D: int, dtype, groups: int,
     bytes that decide it (also the attributes of `flash.bwd_plan`).
     resident: the query side of a whole head in VMEM, taken where its
     blocks (double-buffered by Mosaic) and the f32 temporaries of one
-    accumulate step fit a quarter of the core's VMEM; stream otherwise.
-    The rest is XLA's: it holds operands of the fusions around the call
-    there, and a call's `vmem_limit_bytes` is taken out of that for as
-    long as the call is scheduled (at 96 MiB the four-chip step lost a
-    64 MiB operand of a weight-gradient fusion, 16 ms a step: PERF.md 6,
-    PR 28), so the limit asked for is the estimate and a quarter."""
+    accumulate step fit a quarter of the core's VMEM; stream otherwise,
+    on grid (b, h, k-block, q-block); band: a streamed call whose window
+    ends every k-block's band before S's last q-block, so that its q axis
+    is as long as the longest band (`steps`; S 16384, window 1024: 3
+    q-blocks a k-block where S has 32) and counts from the band's first
+    q-block. `band_steps`: how many of a head's grid steps hold a q-block
+    of a band. The rest of VMEM is XLA's: it holds operands of the fusions
+    around the call there, and a call's `vmem_limit_bytes` is taken out of
+    that for as long as the call is scheduled (at 96 MiB the four-chip
+    step lost a 64 MiB operand of a weight-gradient fusion, 16 ms a step:
+    PERF.md 6, PR 28), so the limit asked for is the estimate and a
+    quarter."""
     itemsize = jnp.dtype(dtype).itemsize
     row_bytes = 3 * D * itemsize + _LSE_LANES * 4
     # a head's results leave in the inputs' dtype; a group's are summed in f32
@@ -679,19 +819,21 @@ def bwd_dkdv_plan(*, S: int, T: int, D: int, dtype, groups: int,
         # two [block_q, block_k] of s/p/dp/ds; q, dO, o; k, v, dk, dv
         # (Mosaic planned 0.75-1.1 MiB under this at five shapes)
         + 4 * (2 * block_q * block_k + (3 * block_q + 4 * block_k) * D))
-    path = "resident" if resident_bytes <= vmem_bytes // 4 else "stream"
-    if path == "stream":                   # accumulated in the output block
-        out_dtype = jnp.dtype(jnp.float32)
     dims = dict(block_q=block_q, block_k=block_k)
+    mask = dict(dims, num_q=S // block_q, causal=causal, window=window)
+    path, steps, band_steps = "resident", 1, T // block_k
+    if resident_bytes > vmem_bytes // 4:
+        steps, band_steps = _q_steps(num_k=T // block_k, **mask)
+        path = "band" if steps < S // block_q else "stream"
+        out_dtype = jnp.dtype(jnp.float32)  # accumulated in the output block
     return dict(
         dims, path=path, S=S, window=window, resident_bytes=resident_bytes,
         out_dtype=out_dtype, vmem_limit_bytes=resident_bytes * 5 // 4,
+        steps=steps, band_steps=band_steps,
         hbm_bytes_per_head=hbm_bytes_per_head(
             path, S=S, T=T, D=D, itemsize=itemsize,
-            out_itemsize=out_dtype.itemsize, **dims,
-            q_index=functools.partial(
-                _q_block_index, num_q=S // block_q, causal=causal,
-                window=window, **dims)))
+            out_itemsize=out_dtype.itemsize, **dims, steps=steps,
+            q_index=functools.partial(_q_block_index, steps=steps, **mask)))
 
 
 def _flash_bwd_dkdv(qt, kt, vt, gt, ot, lse, *, causal: bool, block_q: int,
@@ -710,8 +852,10 @@ def _flash_bwd_dkdv(qt, kt, vt, gt, ot, lse, *, causal: bool, block_q: int,
     tracing.instant("flash.bwd_plan", {
         **{k: plan[k] for k in ("path", "S", "block_q", "block_k", "window",
                                 "resident_bytes", "hbm_bytes_per_head")},
+        **_grid_steps(plan, B * H, T // block_k),
         **({"dq_" + k: dq_plan[k] for k in ("path", "span", "in_flight")}
-           if dq_plan else {})})
+           if dq_plan else {}),
+        **(_grid_steps(dq_plan, B * H, num_q, "dq_") if dq_plan else {})})
     kernel_args = dict(block_q=block_q, scale=_scale(scale, D),
                        causal=causal, window=window)
     if plan["path"] == "resident":
@@ -729,14 +873,15 @@ def _flash_bwd_dkdv(qt, kt, vt, gt, ot, lse, *, causal: bool, block_q: int,
         params = pltpu.CompilerParams(
             vmem_limit_bytes=plan["vmem_limit_bytes"])
     else:
+        steps = plan["steps"]
         kernel = functools.partial(_bwd_dkdv_stream_kernel, num_q=num_q,
-                                   **kernel_args)
-        grid = (B, H, T // block_k, num_q)
+                                   steps=steps, **kernel_args)
+        grid = (B, H, T // block_k, steps)
 
         def q_index(b, h, i, j):
             return (b, h, _q_block_index(
-                i, j, num_q=num_q, block_q=block_q, block_k=block_k,
-                causal=causal, window=window), 0)
+                i, j, steps=steps, num_q=num_q, block_q=block_q,
+                block_k=block_k, causal=causal, window=window), 0)
 
         def q_side(width):
             return pl.BlockSpec((1, 1, block_q, width), q_index)
@@ -769,20 +914,22 @@ def _flash_bwd_dq(qt, kt, vt, gt, ot, lse, *, plan: dict, causal: bool,
     T, groups = kt.shape[2], H // kt.shape[1]
     num_k = T // block_k
     span = plan["span"] // block_k
-    q_side, kv_blk = _walk_specs(
-        groups=groups, span=span, num_k=num_k, block_q=block_q,
-        block_k=block_k, D=D, causal=causal, window=window)
+    mask = dict(num_k=num_k, block_q=block_q, block_k=block_k, causal=causal,
+                window=window)
+    steps, _ = _span_steps(num_q=S // block_q, span=span, **mask)
+    q_side, kv_blk = _walk_specs(groups=groups, span=span, steps=steps, D=D,
+                                 **mask)
     call = pl.pallas_call(
         functools.partial(_dq_kernel, block_k=block_k, num_k=num_k,
-                          scale=scale, causal=causal, window=window,
-                          in_flight=plan["in_flight"]),
-        grid=(B, H, S // block_q, num_k // span),
+                          steps=steps, scale=scale, causal=causal,
+                          window=window, in_flight=plan["in_flight"]),
+        grid=(B, H, S // block_q, steps),
         in_specs=[q_side(D), kv_blk, kv_blk, q_side(D), q_side(D),
                   q_side(_LSE_LANES)],
         out_specs=q_side(D),
-        # several spans add up in the output block, in float32
+        # several grid steps add up in the output block, in float32
         out_shape=jax.ShapeDtypeStruct(
-            (B, H, S, D), qt.dtype if span == num_k else jnp.float32),
+            (B, H, S, D), qt.dtype if steps == 1 else jnp.float32),
         interpret=_use_interpret(),
     )
     with jax.named_scope(f"flash.dq.{plan['path']}"):  # flash.bwd_plan's dq_path
@@ -809,7 +956,8 @@ def _flash_pallas_bwd(res, g, *, causal: bool, block_q: int, block_k: int,
     ot = out.transpose(0, 2, 1, 3)
 
     dq_plan = kv_plan(S=S, T=T, D=D, dtype=k.dtype, block_q=block_q,
-                      block_k=block_k, window=window, call="dq")
+                      block_k=block_k, window=window, call="dq",
+                      causal=causal)
     dq = _flash_bwd_dq(qt, kt, vt, gt, ot, lse, plan=dq_plan, causal=causal,
                        block_q=block_q, block_k=block_k, window=window,
                        scale=scale)

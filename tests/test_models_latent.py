@@ -253,15 +253,22 @@ def test_latent_attention_through_flash_at_a_head_of_256(fwd_dq, dkdv,
         "hbm_bytes_bwd": (12 + 12) * S * 2 * 256 + 4 * S * 64 * 3}
     # four blocks of 32 keys. loop: one span, the forward one block at a
     # time, the dQ call two; stream with no byte to spend (`_force`): one
-    # block a grid step, the parent's walk
+    # block a grid step, the parent's walk. The grids of the two heads: a
+    # step a q-block, or one for each of T's four blocks, of which the
+    # causal triangle's ten a head hold a block of a band
+    steps = {"loop": (8, 8), "stream": (32, 20)}
     assert plans["flash.fwd_plan"] == {
         "path": fwd_dq, "S": S, "D": 256,
         "kv_block_bytes": 2 * 2 * S * 256 * 4,
-        "span": {"loop": S, "stream": 32}[fwd_dq], "in_flight": 1}
+        "span": {"loop": S, "stream": 32}[fwd_dq], "in_flight": 1,
+        "grid_steps": steps[fwd_dq][0], "band_steps": steps[fwd_dq][1]}
     back = plans["flash.bwd_plan"]
     assert back["path"] == dkdv
     assert (back["dq_path"], back["dq_span"], back["dq_in_flight"]) == {
         "loop": ("loop", S, 2), "stream": ("stream", 32, 1)}[fwd_dq]
+    assert (back["dq_grid_steps"], back["dq_band_steps"]) == steps[fwd_dq]
+    assert (back["grid_steps"], back["band_steps"]) == {
+        "resident": (8, 8), "stream": (32, 20)}[dkdv]
 
 
 def _published_half(x, lp, cfg, cos, sin):
@@ -648,7 +655,9 @@ def test_plans_read_back_from_a_profile_around_a_lowering(tmp_path,
         "hbm_bytes_bwd": 24 * 256 * 2 * 256 + 4 * 256 * 64 * 3}
     assert events["flash.fwd_plan"][0] == {
         "path": "stream", "S": 128, "D": 256,
-        "kv_block_bytes": 2 * 2 * 128 * 256 * 4, "span": 32, "in_flight": 1}
+        "kv_block_bytes": 2 * 2 * 128 * 256 * 4, "span": 32, "in_flight": 1,
+        # two sequences of two heads: 4 x 4 steps each, 10 in the triangle
+        "grid_steps": 64, "band_steps": 40}
     back = events["flash.bwd_plan"][0]
     assert back["path"] == "stream" and back["dq_path"] == "stream"
     assert (back["dq_span"], back["dq_in_flight"]) == (32, 1)

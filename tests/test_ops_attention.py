@@ -1,6 +1,8 @@
 """Kernel correctness: flash attention (pallas, interpret on CPU) and ring
 attention (8-device CPU mesh) vs the reference einsum implementation."""
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -115,25 +117,36 @@ def test_noncausal_flash_gradients():
                                    rtol=5e-3, atol=5e-3)
 
 
-def test_pallas_bwd_matches_chunked_bwd():
+@pytest.mark.parametrize("heads,window", [
+    ((4, 2), None), ((8, 1), 64), ((8, 1), 100), ((8, 1), 20), ((8, 1), 256),
+    ((8, 1), 300)], ids=["groups2-full", "groups8-w64", "groups8-w100",
+                         "groups8-w20", "groups8-wS", "groups8-w300"])
+def test_pallas_bwd_matches_chunked_bwd(heads, window, monkeypatch):
+    """The two Pallas calls of the backward against the chunked recompute
+    (``_reference_chunked_bwd``) from the same residuals; with 8 query
+    heads a KV head at windows of two blocks, of no multiple of a block,
+    of under a block, of S and over, with no VMEM to speak of, so that
+    the dQ call holds a q-block's whole band in one grid step and the
+    dK/dV call steps over a k-block's band alone wherever the window ends
+    the bands before S does."""
     import sys
 
     fa = sys.modules["ray_tpu.ops.flash_attention"]
-
-    q, k, v = _make(B=1, S=128, H=4, KV=2, D=32)
+    if window:
+        monkeypatch.setattr(fa, "_SCOPED_VMEM_BYTES", 200_000)
+        monkeypatch.setattr(fa, "_vmem_bytes", lambda: 1024)
+    q, k, v = _make(B=1, S=256 if window else 128, H=heads[0], KV=heads[1],
+                    D=32)
 
     def grads():
         return jax.grad(lambda *a: flash_attention(
-            *a, block_q=32, block_k=32).sum(), argnums=(0, 1, 2))(q, k, v)
+            *a, block_q=32, block_k=32, window=window).sum(),
+            argnums=(0, 1, 2))(q, k, v)
 
-    old = fa.BACKWARD_IMPL
-    try:
-        fa.BACKWARD_IMPL = "pallas"
-        gp = grads()
-        fa.BACKWARD_IMPL = "chunked"
-        gc = grads()
-    finally:
-        fa.BACKWARD_IMPL = old
+    monkeypatch.setattr(fa, "BACKWARD_IMPL", "pallas")
+    gp = grads()
+    monkeypatch.setattr(fa, "BACKWARD_IMPL", "chunked")
+    gc = grads()
     for a, b in zip(gp, gc):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=3e-3, atol=3e-3)
@@ -279,21 +292,43 @@ def test_flash_sliding_window_gradients():
                                    rtol=5e-3, atol=1e-2)
 
 
-@pytest.mark.parametrize("window", [100, None], ids=["w100", "full"])
+@pytest.mark.parametrize("window", [100, None, 64, 40, 256, 300],
+                         ids=["w100", "full", "w64", "w40", "wS", "w300"])
 @pytest.mark.parametrize("what", ["fwd", "dq", "dkdv"])
-def test_flash_at_eight_query_heads_a_kv_head_against_xla(what, window):
-    """Mellum2's grouping (32 query heads over 4 KV heads: 8 a group) at a
-    window that is no multiple of the block, and with none: the banded
-    forward, dQ and dK/dV calls against the model's own einsum attention
-    (``llama._attention_xla``) under the same mask."""
+@pytest.mark.parametrize("plans", ["by_bytes", "streamed"])
+def test_flash_at_eight_query_heads_a_kv_head_against_xla(plans, what, window,
+                                                          monkeypatch):
+    """Mellum2's grouping (32 query heads over 4 KV heads: 8 a group) at
+    windows of a block, of no multiple of it, of under a block, of S and
+    over, and with none: the banded forward, dQ and dK/dV calls against
+    the model's own einsum attention (``llama._attention_xla``) under the
+    same mask. ``by_bytes``: the plans these small shapes get (a windowed
+    forward holds its band or streams, the dQ call loops, the dK/dV call
+    is resident); ``streamed``: no VMEM to speak of, so no call holds a
+    head: in blocks of 32 the forward and the dQ call hold a q-block's
+    whole band in one grid step and the dK/dV call steps over a k-block's
+    band alone wherever the window ends the bands before T does, and all
+    three step over T where it does not."""
     from ray_tpu.models.llama import _attention_xla
 
+    fa = _fa()
+    block = 64
+    if plans == "streamed":
+        # room for a band of three k-blocks all in flight, for one of five
+        # with one (forward) or two (dQ), for half of T's eight, not for T
+        block = 32
+        monkeypatch.setattr(fa, "_SCOPED_VMEM_BYTES", 205_000)
+        monkeypatch.setattr(fa, "_vmem_bytes", lambda: 1024)
+        seen = {}
+        monkeypatch.setattr(fa.tracing, "instant",
+                            lambda name, attrs=None, **kw: seen.update(
+                                {name: attrs}))
     q, k, v = _make(B=1, S=256, H=8, KV=1, D=32, seed=11)
     g = jax.random.normal(jax.random.PRNGKey(12), q.shape, q.dtype)
 
     def flash(q, k, v):
-        return flash_attention(q, k, v, window=window, block_q=64,
-                               block_k=64)
+        return flash_attention(q, k, v, window=window, block_q=block,
+                               block_k=block)
 
     def xla(q, k, v):
         return _attention_xla(q, k, v, True, window=window)
@@ -307,11 +342,38 @@ def test_flash_at_eight_query_heads_a_kv_head_against_xla(what, window):
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=5e-3, atol=1e-2)
-    if window is not None and what == "fwd":
+    if window is not None and window <= 256 and what == "fwd":
         # one key more or fewer is another result: the band's edge counts
-        off = flash_attention(q, k, v, window=window - 1, block_q=64,
-                              block_k=64)
+        off = flash_attention(q, k, v, window=window - 1, block_q=block,
+                              block_k=block)
         assert float(jnp.max(jnp.abs(off - got))) > 1e-3
+    if plans == "by_bytes":
+        return
+    # the calls took the plans meant: a window of 40 to 100 keys (bands
+    # of 3 to 5 of the 8 blocks) ends every band early
+    banded = window in (40, 64, 100)
+    path = "band" if banded else "stream"
+    band = {40: 96, 64: 96, 100: 160}.get(window)
+    fwd = seen["flash.fwd_plan"]
+    assert (fwd["path"], fwd["span"], fwd["in_flight"]) == (
+        (path, band, 1 if window == 100 else 3) if banded
+        else (path, 128, 2)), fwd
+    # 8 heads x 8 blocks x the axis: one step a q-block, or T's two spans
+    grids = [(fwd["grid_steps"], fwd["band_steps"], 1 if banded else 2)]
+    if what != "fwd":
+        back = seen["flash.bwd_plan"]
+        assert (back["path"], back["dq_path"], back["dq_span"],
+                back["dq_in_flight"]) == (
+            (path, path, band, 2 if window == 100 else 3) if banded
+            else (path, path, 128, 2)), back
+        # the dK/dV call's q axis: the longest band of a k-block, or S's 8
+        grids += [(back["dq_grid_steps"], back["dq_band_steps"],
+                   1 if banded else 2),
+                  (back["grid_steps"], back["band_steps"],
+                   {40: 3, 64: 3, 100: 5}.get(window, 8))]
+    for steps, work, axis in grids:
+        assert 0 < work <= steps == 8 * 8 * axis, (steps, work, axis, seen)
+        assert not banded or work > 0.7 * steps, (steps, work)
 
 
 # -- the dK/dV call's two block plans (ops/flash_attention.py) ---------------
@@ -336,7 +398,9 @@ def _dkdv(path, res, g, *, causal, window, block):
         S=S, T=S, D=D, dtype=q.dtype, groups=H // KV,
         block_q=block, block_k=block, causal=causal, window=window,
         vmem_bytes=vmem)
-    assert plan["path"] == path
+    # a streamed call whose window ends the bands early is named `band`
+    assert plan["path"] in {"resident": ("resident",),
+                            "stream": ("stream", "band")}[path]
     dk, dv = fa._flash_bwd_dkdv(
         t(q), t(k), t(v), t(g), t(out), lse, causal=causal, block_q=block,
         block_k=block, window=window, vmem_bytes=vmem)
@@ -422,51 +486,84 @@ def test_dkdv_plan_bytes_at_the_benchmark_shape():
     banded = fa.bwd_dkdv_plan(
         dtype=jnp.bfloat16, groups=4, vmem_bytes=16 * 2 ** 20, causal=True,
         window=4096, **long)
-    assert banded["path"] == "stream"
+    # 9 q-blocks see a k-block: the q axis is 9 long where S has 16
+    assert (banded["path"], banded["steps"]) == ("band", 9)
     assert banded["hbm_bytes_per_head"] < 0.5 * fa.hbm_bytes_per_head(
         itemsize=2, **long, **PARENT_DKDV_PLAN)
 
 
-@pytest.mark.parametrize("window", [0, 64, 100], ids=["causal", "w64", "w100"])
+# windows of a block or two, of no multiple of a block, of under a block,
+# of S and over; 0 is none
+WINDOWS = dict(argvalues=[0, 64, 100, 20, 256, 300],
+               ids=["causal", "w64", "w100", "w20", "wS", "w300"])
+
+
+@pytest.mark.parametrize("window", **WINDOWS)
 @pytest.mark.parametrize("block_q,block_k", [(32, 32), (32, 64), (64, 32)])
 def test_dkdv_stream_grid_fetches_only_its_band(window, block_q, block_k):
-    """Walk the streaming plan's grid on the host in the order Mosaic
-    does. On a step the kernel runs, the query-side index is the block
-    the kernel masks for (qi itself). On a step it skips, the index is
-    the previous step's (nothing is fetched) or, before a k-block's band
-    opens, the band's first block (fetched early, once): so over a head
-    the index changes once per step that runs, never for one skipped."""
+    """Walk the streamed dK/dV call's grid on the host in the order Mosaic
+    does. The q axis is as long as the longest band of a k-block and no
+    longer (all of S's q-blocks without a window, or with one that reaches
+    the sequence's end from the first k-block); where it is shorter than
+    S's it counts from the band's first q-block. Every q-block of every
+    band gets exactly one step, in rising order; on a step the kernel
+    runs, the query-side index is the block the kernel masks for. On a
+    step it skips, the index is the previous step's (nothing is fetched)
+    or, before a k-block's band opens, the band's first block (fetched
+    early, once): so over a head the index changes once per step that
+    runs, never for one skipped, and never leaves [0, num_q)."""
     fa = _fa()
     S = 256
     num_q, num_k = S // block_q, S // block_k
     kw = dict(num_q=num_q, block_q=block_q, block_k=block_k, causal=True,
               window=window)
+    steps, band_steps = fa._q_steps(num_k=num_k, **kw)
+    bands = [fa._q_band(ki, **kw) for ki in range(num_k)]
+    assert steps == max(hi - lo for lo, hi in bands) <= num_q
+    banded = steps < num_q
+    # the bands end early only under a window that cannot reach the end
+    assert banded == (0 < window <= S - block_q - block_k + 1), steps
     prev, fetches, ran = None, 0, 0
-    for ki in range(num_k):
-        for qi in range(num_q):
+    for ki, (lo, hi) in enumerate(bands):
+        visited = []
+        for j in range(steps):
+            # the kernel's: the axis counts from the band where shorter
+            qi = j + lo if banded else j
             # what the kernel's pl.when computes, from positions
             rows = np.arange(qi * block_q, (qi + 1) * block_q)[:, None]
             cols = np.arange(ki * block_k, (ki + 1) * block_k)[None, :]
-            keep = rows >= cols
+            keep = (rows >= cols) & (rows < S)
             if window:
                 # the band test is by blocks: the row at the window's
                 # edge, that sees nothing, still counts as in the band
                 keep &= rows - cols <= window
-            lo, hi = fa._q_band(ki, **kw)
             assert (lo <= qi < hi) == bool(keep.any()), (ki, qi)
-            index = fa._q_block_index(ki, qi, **kw)
+            index = fa._q_block_index(ki, j, steps=steps, **kw)
+            assert 0 <= index < num_q, (ki, j, index)
             if lo <= qi < hi:
-                ran += 1
+                visited.append(qi)
                 assert index == qi
             else:
                 assert index == prev or index == lo, (ki, qi, index, prev)
+            if not banded:          # the parent's grid and indices
+                assert index == max(min(j, hi - 1), lo)
             fetches += index != prev
             prev = index
-    assert fetches <= ran
+        assert visited == list(range(lo, hi)), (ki, visited)
+        ran += len(visited)
+    assert fetches <= ran == band_steps
+    # the plan walks the same grid for its bytes
+    assert fa.hbm_bytes_per_head(
+        "stream", S=S, T=S, D=32, block_q=block_q, block_k=block_k,
+        itemsize=4, out_itemsize=4, steps=steps,
+        q_index=functools.partial(fa._q_block_index, steps=steps, **kw)
+    ) == fetches * block_q * (3 * 32 * 4 + 512) + 2 * S * 32 * 8
     # the same functions trace: an index map gets traced scalars
-    traced = jax.jit(lambda i, j: fa._q_block_index(i, j, **kw))
-    for ki, qi in [(0, 0), (num_k - 1, 0), (0, num_q - 1), (num_k // 2, 1)]:
-        assert int(traced(ki, qi)) == fa._q_block_index(ki, qi, **kw)
+    traced = jax.jit(lambda i, j: fa._q_block_index(i, j, steps=steps, **kw))
+    for ki, j in [(0, 0), (num_k - 1, 0), (0, steps - 1),
+                  (num_k // 2, 1 % steps)]:
+        assert int(traced(ki, j)) == fa._q_block_index(ki, j, steps=steps,
+                                                       **kw)
 
 
 # -- the forward's and the dQ call's walk over k-blocks ----------------------
@@ -627,55 +724,86 @@ def test_the_walk_of_k_blocks_is_the_one_block_walk_to_the_last_bit(
         fa._flash_bwd_dq(*args, plan=plan, **kw), want)
 
 
-@pytest.mark.parametrize("window", [0, 64, 100], ids=["causal", "w64", "w100"])
-@pytest.mark.parametrize("span", [1, 2, 4])
+@pytest.mark.parametrize("window", **WINDOWS)
+@pytest.mark.parametrize("span", [1, 2, 3, 4])
 @pytest.mark.parametrize("block_q,block_k", [(32, 32), (32, 64), (64, 32)])
 def test_kv_span_grid_fetches_only_its_band(window, span, block_q, block_k):
     """Walk the grid of a call that holds a q-block and steps over spans
-    of k-blocks, on the host, in the order Mosaic does. On a step whose
-    span holds a block of the band, the index of K and V is that span
-    (si itself). On any other step it is the previous step's (nothing is
-    fetched) or, before a q-block's band opens, the band's first span
-    (fetched early, once): over a head the index changes at most once per
-    step that runs, never for a span outside the band."""
+    of k-blocks, on the host, in the order Mosaic does. A span shorter
+    than T that holds the longest band is a banded call's: ONE step a
+    q-block, the span fetched at the k-block the band starts at (or as
+    late as T lets a span start), every block of the band in it. Any
+    other span is one of T's and the axis counts all of them, as the
+    parent's did: on a step whose span holds a block of the band the
+    index of K and V is that span; on any other step it is the previous
+    step's (nothing is fetched) or, before a q-block's band opens, the
+    band's first span (fetched early, once). Either way every block of
+    every band is walked exactly once, in rising order, the index changes
+    at most once per step that runs, and nothing outside T is indexed."""
     fa = _fa()
     S = 256
     num_q, num_k = S // block_q, S // block_k
     kw = dict(num_k=num_k, block_q=block_q, block_k=block_k, causal=True,
               window=window)
+    bands = [fa._k_band(qi, **kw) for qi in range(num_q)]
+    banded = max(hi - lo for lo, hi in bands) <= span < num_k
+    if not window or window >= S:
+        assert not banded
+    if num_k % span and not banded:
+        pytest.skip("no plan cuts T into spans that do not divide it")
+    steps, band_steps = fa._span_steps(num_q=num_q, span=span, **kw)
+    assert steps == (1 if banded else num_k // span)
     prev, fetches, ran = None, 0, 0
-    for qi in range(num_q):
-        for si in range(num_k // span):
-            # what the kernel's pl.when computes, from positions
-            rows = np.arange(qi * block_q, (qi + 1) * block_q)[:, None]
-            cols = np.arange(si * span * block_k,
-                             (si + 1) * span * block_k)[None, :]
-            keep = rows >= cols
-            if window:
-                # the band test is by blocks: the row at the window's
-                # edge, that sees nothing, still counts as in the band
-                keep &= rows - cols <= window + block_k - 1
-            lo, hi = fa._k_band(qi, **kw)
-            runs = max(lo, si * span) < min(hi, (si + 1) * span)
-            if not window:
-                assert runs == bool(keep.any()), (qi, si)
+    for qi, (lo, hi) in enumerate(bands):
+        first, last = lo // span, (hi - 1) // span
+        walked = []
+        for si in range(steps):
+            if banded:
+                index = fa._band_start(qi, span=span, **kw)
+                assert index == min(lo, num_k - span) >= 0
+                assert hi <= index + span    # the whole band is in the span
+                mine = range(lo, hi)
             else:
-                assert runs or not (rows - cols < window)[keep].any()
-            index = fa._k_span_index(qi, si, span=span, **kw)
-            if runs:
-                ran += 1
-                assert index == si
-            else:
-                assert index == prev or index == lo // span, (
-                    qi, si, index, prev)
+                # what the kernel's pl.when computes, from positions
+                rows = np.arange(qi * block_q, (qi + 1) * block_q)[:, None]
+                cols = np.arange(si * span * block_k,
+                                 (si + 1) * span * block_k)[None, :]
+                keep = rows >= cols
+                if window:
+                    # the band test is by blocks: the row at the window's
+                    # edge, that sees nothing, still counts as in the band
+                    keep &= rows - cols <= window + block_k - 1
+                mine = range(max(lo, si * span), min(hi, (si + 1) * span))
+                if not window:
+                    assert (len(mine) > 0) == bool(keep.any()), (qi, si)
+                else:
+                    assert mine or not (rows - cols < window)[keep].any()
+                # the parent's grid and indices
+                index = fa._k_span_index(qi, si, span=span, **kw)
+                assert index == max(min(si, last), first) < num_k // span
+                if mine:
+                    assert index == si
+                else:
+                    assert index in (prev, first), (qi, si, index, prev)
+            # the kernel's own bounds of the step's walk, and where it
+            # slices the span's first block from
+            at, to, start = fa._span_band(qi, si, span=span, steps=steps, **kw)
+            assert list(range(int(at), int(to))) == list(mine)
+            assert start() == (index if banded else si * span)
+            walked += mine
+            ran += len(mine) > 0
             fetches += index != prev
             prev = index
-    assert fetches <= ran
-    # the same function traces: an index map gets traced scalars
-    traced = jax.jit(lambda i, j: fa._k_span_index(i, j, span=span, **kw))
-    for qi, si in [(0, 0), (num_q - 1, 0), (0, num_k // span - 1),
-                   (num_q // 2, 1 % (num_k // span))]:
-        assert int(traced(qi, si)) == fa._k_span_index(qi, si, span=span, **kw)
+        assert walked == list(range(lo, hi)), (qi, walked)
+    assert fetches <= ran == band_steps
+    # the same functions trace: an index map gets traced scalars
+    index_of = functools.partial(fa._band_start, span=span, **kw) if banded \
+        else lambda qi, si: fa._k_span_index(qi, si, span=span, **kw)
+    traced = jax.jit(index_of)
+    for qi, si in [(0, 0), (num_q - 1, 0), (0, steps - 1),
+                   (num_q // 2, 1 % steps)]:
+        args = (qi,) if banded else (qi, si)
+        assert int(traced(*args)) == index_of(*args)
 
 
 def test_kv_plan_takes_the_longest_span_that_fits_then_a_second_block():
@@ -683,7 +811,8 @@ def test_kv_plan_takes_the_longest_span_that_fits_then_a_second_block():
     V and its forward walks one block at a time, its dQ call two; the
     stream plan takes the longest span whose step fits the 16 MiB a call
     gets, and a second block in flight where that span leaves room; a
-    window caps the span at the blocks that cover it."""
+    window that ends the bands before T makes the longest band the span,
+    all its blocks in flight where they fit, one grid step a q-block."""
     fa = _fa()
     bf = jnp.bfloat16
     shape = dict(dtype=bf, block_q=512, block_k=512)
@@ -709,11 +838,27 @@ def test_kv_plan_takes_the_longest_span_that_fits_then_a_second_block():
         assert [fa.kv_plan(S=8192, T=8192, D=256, call=c, **shape)[n]
                 for c in ("fwd", "dq") for n in ("span", "in_flight")] == [
             512, 1, 512, 1]
-    # a windowed forward streams whatever fits, in spans no longer than
-    # the blocks that cover the window; the dQ call keeps its plan
+    # a windowed forward holds its longest band where that fits; the dQ
+    # call keeps the loop plan where a head's K and V fit
     banded = fa.kv_plan(S=8192, T=8192, D=128, window=1024, **shape)
     assert (banded["path"], banded["span"], banded["in_flight"]) == (
-        "stream", 1024, 2)
+        "band", 1536, 3)
+    # a q-block's three k-blocks are ONE span, fetched where they start:
+    # one grid step a q-block, and every one of the 16 works
+    assert (banded["steps"], banded["band_steps"]) == (1, 16)
+    # at the Mellum2 cell's S the dQ call cannot hold a head either
+    assert [fa.kv_plan(S=16384, T=16384, D=128, window=1024, call=c,
+                       **shape)[n] for c in ("fwd", "dq")
+            for n in ("path", "span", "in_flight", "steps", "band_steps")
+            ] == ["band", 1536, 3, 1, 32] * 2
+    # a window as long as S ends no band early: T in one span, or the
+    # head's K and V as the loop plan holds them
+    assert [fa.kv_plan(S=8192, T=8192, D=128, window=w, **shape)[n]
+            for w in (8192, 0) for n in ("path", "steps", "band_steps")] == [
+        "stream", 1, 16, "loop", 1, 16]
+    # a band too long to hold streams over T as it did (33 blocks of 64)
+    long = fa.kv_plan(S=32768, T=32768, D=128, window=16384, **shape)
+    assert (long["path"], long["span"], long["steps"]) == ("stream", 8192, 4)
     assert fa.kv_plan(S=8192, T=8192, D=128, window=1024, call="dq",
                       **shape)["path"] == "loop"
 
@@ -738,15 +883,18 @@ def test_flash_bwd_plan_instant_once_a_trace(monkeypatch):
     assert [name for name, _ in seen] == ["flash.fwd_plan", "flash.bwd_plan"]
     # four blocks of 32 keys: the loop plan holds all of them, and its
     # forward walks them one at a time, its dQ call two at a time
+    # (a grid of 2 heads x 4 q-blocks, every step at work)
     assert seen[0][1] == {"path": "loop", "S": 128, "D": 32,
                           "kv_block_bytes": 2 * 2 * 128 * 32 * 4,
-                          "span": 128, "in_flight": 1}
+                          "span": 128, "in_flight": 1, "grid_steps": 8,
+                          "band_steps": 8}
     attrs = seen[1][1]
     assert attrs == {
         "path": "resident", "S": 128, "block_q": 32, "block_k": 32,
         "window": 0, "resident_bytes": attrs["resident_bytes"],
         "hbm_bytes_per_head": fa.hbm_bytes_per_head(
             "resident", S=128, T=128, D=32, block_q=32, block_k=32,
-            itemsize=4, out_itemsize=4), "dq_path": "loop", "dq_span": 128,
-        "dq_in_flight": 2}
+            itemsize=4, out_itemsize=4), "grid_steps": 8, "band_steps": 8,
+        "dq_path": "loop", "dq_span": 128, "dq_in_flight": 2,
+        "dq_grid_steps": 8, "dq_band_steps": 8}
     assert all(isinstance(x, (int, str)) for x in attrs.values())

@@ -219,7 +219,11 @@ def test_the_cells_flash_walks_fit_the_vmem_a_call_gets(cell, one_chip,
     span and blocks in flight are chosen against it; a call's
     ``vmem_limit_bytes`` is taken out of XLA's fast memory): their scoped
     VMEM in the compiled text is the default. Only the resident dK/dV
-    plan asks (``bwd_dkdv_plan``)."""
+    plan asks (``bwd_dkdv_plan``). The Mellum2 cell's window layers take
+    the banded plans: a q-block's whole band of three k-blocks in one grid
+    step, fetched where it starts, and a dK/dV q axis of a k-block's three
+    q-blocks; its full layers and the GLM cell keep the streaming plans to
+    the letter."""
     import re
     import sys
 
@@ -260,12 +264,73 @@ def test_the_cells_flash_walks_fit_the_vmem_a_call_gets(cell, one_chip,
                            block_k=512, window=window or 0, call=c)
              for c in ("fwd", "dq")}
     want = [("loop", S, 1), ("loop", S, 2)]
-    if streams:         # half a head's keys a grid step; a window: its own
-        span = window or S // 2
-        want = [("stream", span, 2),
-                ("stream", span, 1 if D == 256 else 2)]
+    grids = [(S // 512, S // 512)] * 2      # a head's grid steps, at work
+    if streams:         # half a head's keys a grid step, two a q-block
+        want = [("stream", S // 2, 2),
+                ("stream", S // 2, 1 if D == 256 else 2)]
+        grids = [(2 * S // 512, 3 * S // 1024)] * 2
+    if window:          # a q-block's band of three k-blocks in one step
+        want = [("band", window + 512, 3)] * 2
+        grids = [(S // 512, S // 512)] * 2
     assert [(p["path"], p["span"], p["in_flight"])
             for p in plans.values()] == want, plans
+    assert [(S // 512 * p["steps"], p["band_steps"])
+            for p in plans.values()] == grids, plans
+    dkdv = fa.bwd_dkdv_plan(
+        S=S, T=S, D=D, dtype=jnp.bfloat16, groups=H // KV, block_q=512,
+        block_k=512, causal=True, window=window or 0,
+        vmem_bytes=fa._V5E_VMEM_BYTES)
+    # (path, a head's grid steps, those at work): the causal triangle, or
+    # three q-blocks a k-block and the sequence's end
+    assert (dkdv["path"], S // 512 * dkdv["steps"], dkdv["band_steps"]) == (
+        ("band", 96, 93) if window else
+        ("stream", (S // 512) ** 2, S // 512 * (S // 512 + 1) // 2)
+        if streams else ("resident", S // 512, S // 512)), dkdv
+    # the compiled calls carry the scope of the plan they took
+    for call, plan in (("fwd", plans["fwd"]), ("dq", plans["dq"]),
+                       ("dkdv", dkdv)):
+        assert f"flash.{call}.{plan['path']}" in text, (call, plan["path"])
+
+
+@pytest.mark.parametrize("window,fwd,dq", [
+    (1024, ("band", 1536, 3), ("band", 1536, 2)),
+    (2048, ("band", 2560, 1), ("band", 2560, 1)),
+    (4096, ("band", 4608, 1), ("stream", 4096, 1))],
+    ids=["w1024", "w2048", "w4096"])
+def test_banded_calls_at_a_head_of_256_fit_the_vmem_a_call_gets(
+        window, fwd, dq, one_chip, on_chip_branch):
+    """No cell has a window at a head of 256 (Gemma-2's shape), where a
+    block in flight and the dQ call's sum are twice a head of 128's: the
+    three calls compile in the 16 MiB a call gets on the plans ``kv_plan``
+    takes there (the band's three blocks all in flight passed it by 1 MiB
+    in the dQ call, and a band of nine with one: two in flight, and T's
+    spans for the nine)."""
+    import sys
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops.flash_attention import flash_attention
+
+    fa = sys.modules["ray_tpu.ops.flash_attention"]
+    S, D = 8192, 256
+    plans = [fa.kv_plan(S=S, T=S, D=D, dtype=jnp.bfloat16, block_q=512,
+                        block_k=512, window=window, call=c)
+             for c in ("fwd", "dq")]
+    assert [(p["path"], p["span"], p["in_flight"]) for p in plans] == [
+        fwd, dq], plans
+    assert all(p["walk_bytes"] <= fa._SCOPED_VMEM_BYTES for p in plans)
+    q = _sds((1, S, 2, D), jnp.bfloat16, one_chip)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, window=window).astype(
+            jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, q, q).compile().as_text()
+    for scope in (f"flash.fwd.{fwd[0]}", f"flash.dq.{dq[0]}",
+                  "flash.dkdv.band"):
+        assert scope in text, scope
 
 
 # (rows, experts, model width, one expert's width) of a cell's grouped
