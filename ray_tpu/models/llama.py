@@ -62,6 +62,12 @@ class AttentionKind:
     window: Optional[int] = None        # None: every earlier key
     rope_theta: Optional[float] = None  # None: the config's
     yarn: Optional[Yarn] = None         # None: plain tables
+    # False: layers of this kind turn no tables (Command A+'s full layers:
+    # q and k go to the kernel as projected) though the model has rotary
+    rope: bool = True
+    # which lanes of a head one angle turns: "halves" (i, i + head_dim / 2)
+    # (transformers' rotate_half) or "neighbours" (2i, 2i + 1) (GPT-J's)
+    pairs: str = "halves"
 
 
 @dataclass(frozen=True)
@@ -115,6 +121,11 @@ class LlamaConfig:
     # and rotary tables; with none named every layer is of the one kind
     # ``sliding_window`` and ``rope_theta`` describe.
     attn_kinds: Tuple[Tuple[str, "AttentionKind"], ...] = ()
+    # "rms", or "layer": the mean subtracted first, a scale and no bias
+    norm: str = "rms"
+    # one norm a layer feeds both halves and the layer is x + attention(n)
+    # + feed_forward(n) (Cohere's use_parallel_block; no ``ffn_norm``)
+    parallel_block: bool = False
 
     @property
     def head_dim(self) -> int:
@@ -304,6 +315,23 @@ def rms_norm(x, scale, eps):
     return (x * jax.lax.rsqrt(var + eps)).astype(x.dtype) * scale.astype(x.dtype)
 
 
+def layer_norm(x, scale, eps):
+    """A LayerNorm with a scale and no bias, its moments in float32."""
+    f = x.astype(jnp.float32)
+    f = f - jnp.mean(f, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(f), axis=-1, keepdims=True)
+    return (f * jax.lax.rsqrt(var + eps)).astype(x.dtype) * scale.astype(x.dtype)
+
+
+def _norm(x, scale, cfg: "LlamaConfig"):
+    """The model's norm over the hidden state (``cfg.norm``)."""
+    if cfg.norm == "rms":
+        return rms_norm(x, scale, cfg.norm_eps)
+    if cfg.norm != "layer":
+        raise ValueError(f"unknown norm {cfg.norm!r}")
+    return layer_norm(x, scale, cfg.norm_eps)
+
+
 def yarn_range(theta: float, head_dim: int, yarn: Yarn) -> Tuple[int, int]:
     """(low, high): the lanes between which YaRN's ramp runs. dim(r) is
     the lane that turns r times over the original context."""
@@ -348,11 +376,19 @@ def _yarn_tables(theta: float, seq_len: int, head_dim: int, yarn: Yarn):
 
 
 def _kind_tables(cfg: "LlamaConfig", of: AttentionKind, seq_len: int):
-    """cos and sin [seq_len, rope_dim / 2] of the layers of one kind."""
+    """cos and sin of the layers of one kind: [seq_len, rope_dim / 2], or,
+    where neighbouring lanes are paired, [seq_len, rope_dim] with a pair's
+    angle on both its lanes (``apply_rope``)."""
     theta = cfg.rope_theta if of.rope_theta is None else of.rope_theta
     if of.yarn is None:
-        return _rope_tables(theta, seq_len, cfg.rope_dim)
-    return _yarn_tables(float(theta), seq_len, cfg.rope_dim, of.yarn)
+        tables = _rope_tables(theta, seq_len, cfg.rope_dim)
+    else:
+        tables = _yarn_tables(float(theta), seq_len, cfg.rope_dim, of.yarn)
+    if of.pairs == "halves":
+        return tables
+    if of.pairs != "neighbours":
+        raise ValueError(f"unknown rotary pairing {of.pairs!r}")
+    return tuple(jnp.repeat(t, 2, axis=-1) for t in tables)
 
 
 def attention_kind(cfg: LlamaConfig, kind=None) -> AttentionKind:
@@ -363,7 +399,18 @@ def attention_kind(cfg: LlamaConfig, kind=None) -> AttentionKind:
 
 
 def apply_rope(x, cos, sin):
-    """x: [B, S, N, HD]; cos/sin: [S, HD/2] (already offset for decode)."""
+    """x: [B, S, N, HD]; cos/sin: [S, HD/2] (already offset for decode),
+    lanes (i, i + HD/2) turned together; or [S, HD] (``_kind_tables`` for
+    a kind that pairs neighbours): lanes (2i, 2i + 1) turned together."""
+    if cos.shape[-1] == x.shape[-1]:
+        # the pair's other lane by two shifts along the lanes and a select
+        # (a reshape to [.., HD/2, 2] would put 2 on the lane axis)
+        f = x.astype(jnp.float32)
+        even = jnp.arange(x.shape[-1]) % 2 == 0
+        other = jnp.where(even, -jnp.roll(f, -1, axis=-1),
+                          jnp.roll(f, 1, axis=-1))
+        return (f * cos[None, :, None, :]
+                + other * sin[None, :, None, :]).astype(x.dtype)
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     c = cos[None, :, None, :]
     s = sin[None, :, None, :]
@@ -460,10 +507,13 @@ def _attention(q, k, v, cfg: LlamaConfig, causal=True, q_offset=0,
 
 
 def _attention_half(x, lp, cfg: LlamaConfig, cos, sin, cache=None, mesh=None,
-                    rules=None, tp=None, kind=None):
+                    rules=None, tp=None, kind=None, normed=None):
     """The attention half of a block: x [B, S, D] -> (x + attention, k, v,
-    new_cache). cache: (k, v, offset) or None. ``kind`` names the layer's
-    kind where the config has several (``attention_kind``: its window;
+    new_cache). cache: (k, v, offset) or None. A parallel block hands its
+    one normed input as ``normed``: the half then norms nothing and adds
+    nothing, its first result is the attention's output alone. ``kind``
+    names the layer's kind where the config has several
+    (``attention_kind``: its window;
     ``cos`` and ``sin`` are its tables). With ``cfg.qk_norm`` (an
     OLMoE block) q and k pass an RMS norm over the WHOLE projected vector,
     one learned scale each (``q_norm``, ``k_norm``), before the split into
@@ -480,7 +530,7 @@ def _attention_half(x, lp, cfg: LlamaConfig, cos, sin, cache=None, mesh=None,
     H, KV, HD = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     dt = cfg.dtype
 
-    h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+    h = _norm(x, lp["attn_norm"], cfg) if normed is None else normed
 
     def heads(y, n, norm=None):
         if norm is not None and getattr(cfg, "qk_norm", False):
@@ -526,9 +576,12 @@ def _attention_half(x, lp, cfg: LlamaConfig, cos, sin, cache=None, mesh=None,
                           kind=kind)
     attn = attn.reshape(B, S, H * HD)
     if tp is not None:
-        return _residual(x, matmul_reduce_scatter(attn, _dq(lp["wo"], dt),
-                                                  tp), cfg), k, v, new_cache
-    return _residual(x, attn @ _dq(lp["wo"], dt), cfg), k, v, new_cache
+        out = matmul_reduce_scatter(attn, _dq(lp["wo"], dt), tp)
+    else:
+        out = attn @ _dq(lp["wo"], dt)
+    if normed is not None:
+        return out, k, v, new_cache
+    return _residual(x, out, cfg), k, v, new_cache
 
 
 def _residual(x, y, cfg: LlamaConfig):
@@ -583,7 +636,12 @@ def _layer(x, lp, cfg: LlamaConfig, cos, sin, cache=None, collect_kv=False,
     other kind of layer takes its first half from the family's
     ``mixer_half`` (x, lp, cfg, kind -> x). A family with an
     ``attention_half`` of its own (x, lp, cfg, cos, sin -> x) supplies
-    every layer's. ``kind`` goes on to the feed-forward."""
+    every layer's. ``kind`` goes on to the feed-forward. A config with
+    ``parallel_block`` runs the same two halves side by side from one norm
+    (``_parallel_layer``)."""
+    if cfg.parallel_block:
+        return _parallel_layer(x, lp, cfg, cos, sin, cache, collect_kv, mesh,
+                               rules, tp, kind)
     own = getattr(_family(cfg), "attention_half", None)
     named = kind in dict(cfg.attn_kinds)
     if own is not None:
@@ -604,10 +662,36 @@ def _layer(x, lp, cfg: LlamaConfig, cos, sin, cache=None, collect_kv=False,
             x, k, v, new_cache = _family(cfg).mixer_half(
                 x, lp, cfg, kind, mesh=mesh), None, None, None
     with jax.named_scope("feed_forward"):
-        h = rms_norm(x, lp["ffn_norm"], cfg.norm_eps)
+        h = _norm(x, lp["ffn_norm"], cfg)
         y, stats = _family(cfg).feed_forward(h, lp, cfg, mesh=mesh,
                                              rules=rules, tp=tp, kind=kind)
         x = _residual(x, y, cfg)
+    return x, ((k, v) if collect_kv else new_cache), stats
+
+
+def _parallel_layer(x, lp, cfg: LlamaConfig, cos, sin, cache, collect_kv,
+                    mesh, rules, tp, kind):
+    """``_layer`` for a parallel block (``cfg.parallel_block``): ONE norm
+    (``attn_norm``) feeds the attention half and the family's feed-forward
+    side by side, neither reads the other's result, and the layer is x +
+    attention(n) + feed_forward(n). The norm and the sum are the block's
+    own (scope ``block``: in a trace under ``layers`` and in neither half);
+    the two halves are the serial block's code under its scopes."""
+    if not _takes_attention_half(cfg, kind):
+        raise NotImplementedError(
+            f"a parallel block whose first half is no attention ({kind!r})")
+    with jax.named_scope("block"):
+        n = _norm(x, lp["attn_norm"], cfg)
+    with jax.named_scope("attention"), jax.named_scope(kind) \
+            if kind in dict(cfg.attn_kinds) else contextlib.nullcontext():
+        a, k, v, new_cache = _attention_half(
+            x, lp, cfg, cos, sin, cache=cache, mesh=mesh, rules=rules, tp=tp,
+            kind=kind, normed=n)
+    with jax.named_scope("feed_forward"):
+        y, stats = _family(cfg).feed_forward(n, lp, cfg, mesh=mesh,
+                                             rules=rules, tp=tp, kind=kind)
+    with jax.named_scope("block"):
+        x = _residual(_residual(x, a, cfg), y, cfg)
     return x, ((k, v) if collect_kv else new_cache), stats
 
 
@@ -658,11 +742,25 @@ def _say_kind_plan(cfg: LlamaConfig, kind, of: AttentionKind, seq: int):
     tables, and the heads they serve."""
     tracing.instant("attn.kind_plan", {
         "kind": kind or "attention", "window": of.window or 0,
-        "rope": ("none" if not cfg.rope else
-                 "yarn" if of.yarn is not None else "default"),
+        "rope": ("none" if not (cfg.rope and of.rope) else
+                 "yarn" if of.yarn is not None else
+                 "gptj" if of.pairs == "neighbours" else "default"),
+        "groups": cfg.n_heads // cfg.n_kv_heads,
         "factor": of.yarn.factor if of.yarn is not None else 1.0,
         "S": seq, "heads": cfg.n_heads, "kv_heads": cfg.n_kv_heads,
         "head_dim": cfg.head_dim})
+
+
+def _say_block_plan(cfg: LlamaConfig):
+    """The instant ``block.plan`` of a trace, once a traced forward of a
+    model with a parallel block: how the layer is put together, and what
+    its feed-forward's shared experts are (0: a family without)."""
+    tracing.instant("block.plan", {
+        "residual": "parallel", "norm": cfg.norm,
+        "shared_experts": getattr(cfg, "n_shared", 0)
+        if getattr(cfg, "shared_d_ff", 0) else 0,
+        "shared_combine": getattr(cfg, "shared_combine", "sum"),
+        "shared_width": getattr(cfg, "shared_d_ff", 0)})
 
 
 def _act_constraint(mesh, rules, tp=None):
@@ -742,7 +840,7 @@ def _forward(params, tokens, cfg: LlamaConfig, pos_offset=0, mesh=None,
         of = attention_kind(cfg, kind)
         if _takes_attention_half(cfg, kind):
             _say_kind_plan(cfg, kind, of, S)
-        if not cfg.rope:
+        if not (cfg.rope and of.rope):
             return None, None
         with jax.named_scope("attention"):  # the tables are its rotary's
             if isinstance(pos_offset, int) and pos_offset == 0:
@@ -783,9 +881,11 @@ def _forward(params, tokens, cfg: LlamaConfig, pos_offset=0, mesh=None,
         _say_layer_plan(runs, body_of.cache_info().currsize)
     if mesh is not None and rules is not None:
         _say_tp_plan(tp, cfg, B, S)
+    if cfg.parallel_block:
+        _say_block_plan(cfg)
     hidden = x
     with jax.named_scope("head_loss"):
-        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        x = _norm(x, params["final_norm"], cfg)
         if tp is not None:    # the head wants every row: one gather a step
             x = jax.lax.with_sharding_constraint(x, tp.gathered_sharding())
         logits = _logits(params, x, cfg)
@@ -938,6 +1038,10 @@ def _refuse_stated(cfg: LlamaConfig):
         stated.append("an attention half of its own")
     if cfg.attn_kinds:
         stated.append("attention layers of several kinds")
+    if cfg.parallel_block:
+        stated.append("a parallel block")
+    if cfg.norm != "rms":
+        stated.append(f"a {cfg.norm} norm")
     if stated:
         raise NotImplementedError(
             f"a KV cache for a config that states {', '.join(stated)}: the "

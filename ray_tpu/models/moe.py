@@ -90,18 +90,40 @@ class MoEConfig(_ll.LlamaConfig):
     experts_held: Optional[Tuple[int, int]] = None
     # width of a SwiGLU every token passes beside its experts; 0: none
     shared_d_ff: int = 0
+    # how many such shared experts, each ``shared_d_ff`` wide, and what is
+    # made of their outputs: their "sum" or their "average" (Command A+:
+    # four, averaged). Their weights lie side by side in ``ws_gate``,
+    # ``ws_up`` [D, n x width] and ``ws_down`` [n x width, D]: n SwiGLUs
+    # summed ARE one of n times the width
+    n_shared: int = 1
+    shared_combine: str = "sum"
     # what the router makes of its logits: "softmax" over all experts, the
     # K largest kept; or "sigmoid" (DeepSeek-V3's): the K experts with the
     # largest score PLUS a per-expert bias that no gradient reaches (the
     # leaf ``router_bias``, moved by a rule after each step:
     # models/latent.py ``post_update``), weighted by their scores WITHOUT it
     router_score: str = "softmax"
+    # False: a sigmoid router with no bias leaf (Command A+'s: the K
+    # largest scores as they are)
+    router_bias: bool = True
     # multiplies the K weights (DeepSeek's ``routed_scaling_factor``)
     route_scale: float = 1.0
     # one name a layer where the attention layers are of several kinds
     # (``attn_kinds``: Mellum2's three window layers to one full layer);
     # () = every layer of the config's one kind, one stack
     layer_kinds: Tuple[str, ...] = ()
+    # the head is the embedding (no ``lm_head`` leaf)
+    tied_head: bool = False
+    # the most layers one stack of ``layer_kinds``' runs holds; 0: a whole
+    # run of adjacent layers of one kind. The optimizer's float32
+    # temporaries are as large as the largest stacked leaf: at Command A+'s
+    # widths three layers' experts in one stack are 1.6 GB a temporary
+    run_layers: int = 0
+
+    @property
+    def shared_width(self) -> int:
+        """All shared experts' widths together: ``ws_gate``'s columns."""
+        return self.n_shared * self.shared_d_ff
 
     @property
     def n_held(self) -> int:
@@ -148,6 +170,39 @@ PRESETS: Dict[str, MoEConfig] = {
             ("window", _ll.AttentionKind(window=24)),
             ("full", _ll.AttentionKind(yarn=_ll.Yarn(
                 factor=4.0, original=32, beta_fast=4.0, beta_slow=0.5))))),
+    # CohereLabs/command-a-plus-05-2026 config.json (``cohere2_moe``): 32
+    # parallel blocks (one LayerNorm, attention and experts side by side),
+    # three sliding-window layers of 4,096 with GPT-J rotary to one full
+    # layer with no position embedding, 128 query heads over 8 KV heads of
+    # 128, 128 experts of 4,096, 8 a token by sigmoid score renormalised,
+    # four shared experts averaged, tied embedding, no router loss
+    "command-a-plus": MoEConfig(
+        vocab_size=262144, d_model=4096, n_layers=32, n_heads=128,
+        n_kv_heads=8, head_width=128, d_ff=4096, max_seq_len=200000,
+        n_experts=128, top_k=8, norm_topk=True, norm_eps=1e-5,
+        rope_theta=50000.0, router_score="sigmoid", router_bias=False,
+        router_aux_weight=0.0, router_z_weight=0.0, shared_d_ff=4096,
+        n_shared=4, shared_combine="average", norm="layer",
+        parallel_block=True, tied_head=True,
+        layer_kinds=("window", "window", "window", "full") * 8,
+        attn_kinds=(
+            ("window", _ll.AttentionKind(window=4096, pairs="neighbours")),
+            ("full", _ll.AttentionKind(rope=False)))),
+    # the same block at the CPU tests' size: two periods, a window shorter
+    # than the tests' sequences, 4 query heads a KV head, 2 of 8 experts
+    # held here, two shared experts
+    "tiny-commanda": MoEConfig(
+        vocab_size=256, d_model=64, n_layers=8, n_heads=8, n_kv_heads=2,
+        head_width=16, d_ff=32, max_seq_len=256, n_experts=8, top_k=2,
+        norm_topk=True, norm_eps=1e-5, rope_theta=10000.0,
+        router_score="sigmoid", router_bias=False, router_aux_weight=0.0,
+        router_z_weight=0.0, shared_d_ff=32, n_shared=2,
+        shared_combine="average", norm="layer", parallel_block=True,
+        tied_head=True, experts_held=(2, 0),
+        layer_kinds=("window", "window", "window", "full") * 2,
+        attn_kinds=(
+            ("window", _ll.AttentionKind(window=24, pairs="neighbours")),
+            ("full", _ll.AttentionKind(rope=False)))),
 }
 
 # what the layer checkpoint keeps of an expert layer (llama._checkpoint)
@@ -163,8 +218,11 @@ def layer_runs(cfg: MoEConfig) -> List[Tuple[str, int]]:
         raise ValueError(
             f"{len(cfg.layer_kinds)} layer kinds {sorted(named)} for "
             f"{cfg.n_layers} layers of kinds {sorted(known)}")
-    return [(kind, len(list(run)))
+    runs = [(kind, len(list(run)))
             for kind, run in itertools.groupby(cfg.layer_kinds)]
+    most = cfg.run_layers or cfg.n_layers
+    return [(kind, min(most, n - at)) for kind, n in runs
+            for at in range(0, n, most)]
 
 
 def _run_configs(cfg: MoEConfig) -> List[MoEConfig]:
@@ -180,13 +238,15 @@ def param_specs(cfg: MoEConfig) -> Dict[str, Any]:
     spec = _ll.param_specs(cfg)
     L = ("layers",)
     lay = dict(spec["layers"])
-    for w in ("w_gate", "w_up", "w_down"):
+    for w in ("w_gate", "w_up", "w_down") + _absent(cfg):
         del lay[w]
+    if cfg.tied_head:
+        del spec["lm_head"]
     if cfg.qk_norm:
         lay["q_norm"] = L + ("heads",)
         lay["k_norm"] = L + ("kv_heads",)
     lay["router"] = L + ("embed", "experts")
-    if cfg.router_score == "sigmoid":
+    if _has_bias(cfg):
         lay["router_bias"] = L + ("experts",)
     lay["we_gate"] = L + ("experts", "embed", "expert_mlp")
     lay["we_up"] = L + ("experts", "embed", "expert_mlp")
@@ -199,6 +259,15 @@ def param_specs(cfg: MoEConfig) -> Dict[str, Any]:
     return spec
 
 
+def _absent(cfg: MoEConfig) -> Tuple[str, ...]:
+    """Leaves of the dense layer that this config's layer has not."""
+    return ("ffn_norm",) if cfg.parallel_block else ()
+
+
+def _has_bias(cfg: MoEConfig) -> bool:
+    return cfg.router_score == "sigmoid" and cfg.router_bias
+
+
 def init_params(key, cfg: MoEConfig) -> Dict[str, Any]:
     if cfg.layer_kinds:         # a list of stacks, one a run (layer_runs)
         runs = [init_params(jax.random.fold_in(key, 100 + i),
@@ -209,16 +278,18 @@ def init_params(key, cfg: MoEConfig) -> Dict[str, Any]:
     params = _ll.init_params(key, cfg.replace(d_ff=1))   # no dense SwiGLU
     pd = cfg.param_dtype
     L, D, F, E = cfg.n_layers, cfg.d_model, cfg.d_ff, cfg.n_experts
-    H, Fs = cfg.n_held, cfg.shared_d_ff
+    H, Fs = cfg.n_held, cfg.shared_width
     ks = jax.random.split(jax.random.fold_in(key, 1), 4)
     lay = dict(params["layers"])
-    for w in ("w_gate", "w_up", "w_down"):
+    for w in ("w_gate", "w_up", "w_down") + _absent(cfg):
         del lay[w]
+    if cfg.tied_head:
+        del params["lm_head"]
     if cfg.qk_norm:
         lay["q_norm"] = jnp.ones((L, cfg.n_heads * cfg.head_dim), pd)
         lay["k_norm"] = jnp.ones((L, cfg.n_kv_heads * cfg.head_dim), pd)
     lay["router"] = jax.random.normal(ks[0], (L, D, E), pd) * 0.02
-    if cfg.router_score == "sigmoid":       # float32 whatever the weights are
+    if _has_bias(cfg):                      # float32 whatever the weights are
         lay["router_bias"] = jnp.zeros((L, E), jnp.float32)
     lay["we_gate"] = jax.random.normal(ks[1], (L, H, D, F), pd) * D ** -0.5
     lay["we_up"] = jax.random.normal(ks[2], (L, H, D, F), pd) * D ** -0.5
@@ -227,7 +298,8 @@ def init_params(key, cfg: MoEConfig) -> Dict[str, Any]:
         ks = jax.random.split(jax.random.fold_in(key, 2), 3)
         lay["ws_gate"] = jax.random.normal(ks[0], (L, D, Fs), pd) * D ** -0.5
         lay["ws_up"] = jax.random.normal(ks[1], (L, D, Fs), pd) * D ** -0.5
-        lay["ws_down"] = jax.random.normal(ks[2], (L, Fs, D), pd) * Fs ** -0.5
+        lay["ws_down"] = jax.random.normal(ks[2], (L, Fs, D), pd) \
+            * cfg.shared_d_ff ** -0.5     # the fan-in of ONE shared expert
     params["layers"] = lay
     return params
 
@@ -235,9 +307,10 @@ def init_params(key, cfg: MoEConfig) -> Dict[str, Any]:
 def num_params(cfg: MoEConfig) -> int:
     D, E, F = cfg.d_model, cfg.n_experts, cfg.d_ff
     qk = (cfg.n_heads + cfg.n_kv_heads) * cfg.head_dim if cfg.qk_norm else 0
-    bias = E if cfg.router_score == "sigmoid" else 0
+    bias = E if _has_bias(cfg) else 0
     return _ll.num_params(cfg.replace(d_ff=0)) + cfg.n_layers * (
-        qk + D * E + bias + 3 * cfg.n_held * D * F + 3 * D * cfg.shared_d_ff)
+        qk + D * E + bias + 3 * cfg.n_held * D * F + 3 * D * cfg.shared_width
+        - D * len(_absent(cfg))) - D * cfg.vocab_size * cfg.tied_head
 
 
 def _rows(tokens, k, order):
@@ -321,11 +394,15 @@ def route(logits, cfg: MoEConfig, bias=None):
     expert's score [T, E]: softmax probabilities, or sigmoids)."""
     if cfg.router_score == "sigmoid":
         probs = jax.nn.sigmoid(logits)
-        # the bias chooses and does not weigh; nothing is learned through it
-        _, experts = jax.lax.top_k(
-            probs + jax.lax.stop_gradient(bias.astype(jnp.float32)),
-            cfg.top_k)
-        weights = jnp.take_along_axis(probs, experts, axis=-1)
+        if bias is None:        # a router without one (``router_bias``)
+            weights, experts = jax.lax.top_k(probs, cfg.top_k)
+        else:
+            # the bias chooses and does not weigh; nothing is learned
+            # through it
+            _, experts = jax.lax.top_k(
+                probs + jax.lax.stop_gradient(bias.astype(jnp.float32)),
+                cfg.top_k)
+            weights = jnp.take_along_axis(probs, experts, axis=-1)
     elif cfg.router_score == "softmax":
         probs = jax.nn.softmax(logits, axis=-1)
         weights, experts = jax.lax.top_k(probs, cfg.top_k)
@@ -586,8 +663,14 @@ def _finish(y, stats, x, lp, cfg: MoEConfig, logits, experts, probs, shape):
         dt = cfg.dtype
         with jax.named_scope("shared"):
             gate = jax.nn.silu(x @ _ll._dq(lp["ws_gate"], dt))
-            y = y + (gate * (x @ _ll._dq(lp["ws_up"], dt))) @ _ll._dq(
+            shared = (gate * (x @ _ll._dq(lp["ws_up"], dt))) @ _ll._dq(
                 lp["ws_down"], dt)
+            if cfg.shared_combine not in ("sum", "average"):
+                raise ValueError(
+                    f"unknown shared_combine {cfg.shared_combine!r}")
+            if cfg.shared_combine == "average":
+                shared = shared * (1.0 / cfg.n_shared)
+            y = y + shared
     return y.reshape(shape), stats
 
 
@@ -631,9 +714,12 @@ def finish_loss(loss, stats, cfg: MoEConfig):
     E, K = cfg.n_experts, cfg.top_k
     counts = stats["counts"]                                   # [L, E]
     rows = counts.shape[0] * stats["experts"].shape[1]         # L x T
-    share = counts.sum(axis=0).astype(jnp.float32) / rows
-    aux = E * jnp.sum(share * stats["prob_sum"].sum(axis=0) / rows)
-    z = stats["z_sum"].sum() / rows
+    if "balance" in stats:      # a sigmoid router: the sequence-wise loss
+        aux, z = stats["balance"].mean(), jnp.zeros((), jnp.float32)
+    else:
+        share = counts.sum(axis=0).astype(jnp.float32) / rows
+        aux = E * jnp.sum(share * stats["prob_sum"].sum(axis=0) / rows)
+        z = stats["z_sum"].sum() / rows
     per_layer = rows // counts.shape[0] * K                    # T x K
     if cfg.experts_held is not None:
         held = stats["held_counts"].astype(jnp.float32)        # [L, held]

@@ -13,6 +13,8 @@ _FAMILIES = {
     "llama": "ray_tpu.models.llama",
     "gpt2": "ray_tpu.models.gpt2",
     "moe": "ray_tpu.models.moe",
+    # Cohere's model_type: moe.py presets "command-a-plus", "tiny-commanda"
+    "cohere2_moe": "ray_tpu.models.moe",
     "hybrid": "ray_tpu.models.hybrid",
     "latent": "ray_tpu.models.latent",
     "vit": "ray_tpu.models.vit",

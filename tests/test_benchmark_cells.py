@@ -26,7 +26,9 @@ RECIPE_KEYS = {"train": ("attn_impl", "remat", "f32_logits"),
                "train_latent": ("attn_impl", "gmm_impl", "remat",
                                 "f32_logits"),
                "train_mixed": ("attn_impl", "gmm_impl", "remat",
-                               "f32_logits")}
+                               "f32_logits"),
+               "train_parallel": ("attn_impl", "gmm_impl", "remat",
+                                  "f32_logits")}
 # published config.json key -> the program's field
 WIDTHS = {"hidden_size": "d_model", "num_hidden_layers": "n_layers",
           "num_attention_heads": "n_heads",
@@ -51,8 +53,8 @@ def test_cell_program_config_builds_at_its_published_widths(name):
     import jax
     import jax.numpy as jnp
 
-    from benchmark import (model, model_glm, model_granite, model_mellum,
-                           model_moe, resolve)
+    from benchmark import (model, model_commanda, model_glm, model_granite,
+                           model_mellum, model_moe, resolve)
 
     cell = resolve.cell(name)
     kind, conf, recipe = cell["kind"], cell["config"], cell["train"]
@@ -60,16 +62,45 @@ def test_cell_program_config_builds_at_its_published_widths(name):
              "train_moe": model_moe.moe_config,
              "train_hybrid": model_granite.hybrid_config,
              "train_latent": model_glm.latent_config,
-             "train_mixed": model_mellum.moe_config}[kind]
+             "train_mixed": model_mellum.moe_config,
+             "train_parallel": model_commanda.moe_config}[kind]
     passed = {k: recipe[k] for k in RECIPE_KEYS[kind] if k in recipe}
     cfg = build(conf, **passed)
 
     widths = {"train": WIDTHS, "train_moe": {**WIDTHS, **MOE_WIDTHS},
               "train_hybrid": model_granite.HF_TO_FIELD,
               "train_latent": model_glm.HF_TO_FIELD,
-              "train_mixed": model_mellum.HF_TO_FIELD}[kind]
+              "train_mixed": model_mellum.HF_TO_FIELD,
+              "train_parallel": {
+                  k: f for k, f in model_commanda.HF_TO_FIELD.items()
+                  if f != "logit_scale"}}[kind]
     for key, field in widths.items():
         assert getattr(cfg, field) == conf[key], (name, key)
+    if kind == "train_parallel":
+        # the block, the norm, the router and the shared experts are the
+        # published keys'; the router's width and the experts and heads
+        # held the deployment's
+        dep = conf["deployment"]
+        assert cfg.parallel_block and cfg.norm == "layer" and cfg.tied_head
+        assert cfg.n_experts == dep["router_experts"]
+        assert cfg.experts_held == (conf["num_experts"],
+                                    dep["experts_first"])
+        assert (cfg.n_heads, cfg.n_kv_heads) == (dep["heads_held"],
+                                                 dep["kv_heads_held"])
+        assert cfg.head_dim == conf["head_dim"]
+        assert (cfg.n_shared, cfg.shared_d_ff, cfg.shared_combine) == (
+            conf["num_shared_experts"], conf["intermediate_size"], "average")
+        assert (cfg.router_score, cfg.router_bias, cfg.norm_topk) == (
+            "sigmoid", False, True)
+        assert cfg.router_aux_weight == cfg.router_z_weight == 0.0
+        kinds = {"sliding_attention": "window", "full_attention": "full"}
+        assert cfg.layer_kinds == tuple(
+            kinds[t] for t in conf["layer_types"][:cfg.n_layers])
+        of = dict(cfg.attn_kinds)
+        assert of["window"].window == conf["sliding_window"]
+        assert of["window"].pairs == "neighbours" and of["window"].rope
+        assert of["window"].rope_theta == conf["rope_theta"]
+        assert of["full"].window is None and not of["full"].rope
     if kind == "train_hybrid":
         # the router's width and the experts held are the deployment's
         dep = conf["deployment"]

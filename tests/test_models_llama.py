@@ -367,6 +367,14 @@ def _moe_tiny():
     return moe, moe.PRESETS["tiny"].replace(dtype=jnp.float32)
 
 
+def _parallel_tiny():
+    from ray_tpu.models import moe
+
+    return moe, moe.PRESETS["tiny-commanda"].replace(
+        dtype=jnp.float32, experts_held=None, n_layers=4,
+        layer_kinds=("window", "window", "window", "full"))
+
+
 # (family and config, rules, tokens a row, whether the overlap plan engages)
 TP_PLAN_CASES = {
     # S 128: the flash kernel under its shard_map between the two helpers
@@ -384,6 +392,10 @@ TP_PLAN_CASES = {
         n_kv_heads=1)), "fsdp_tp", 33, False),
     # a block whose feed-forward is an expert layer stays plain as a whole
     "moe-under-ep": (_moe_tiny, "ep", 33, False),
+    # a parallel block (one LayerNorm, attention and experts side by side,
+    # layers of two kinds in two stacks) stays plain too, and its sharded
+    # program is the one-device one
+    "parallel-block-under-ep": (_parallel_tiny, "ep", 33, False),
 }
 
 

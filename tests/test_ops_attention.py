@@ -898,3 +898,42 @@ def test_flash_bwd_plan_instant_once_a_trace(monkeypatch):
         "dq_path": "loop", "dq_span": 128, "dq_in_flight": 2,
         "dq_grid_steps": 8, "dq_band_steps": 8}
     assert all(isinstance(x, (int, str)) for x in attrs.values())
+
+
+@pytest.mark.parametrize("window", [None, 100, 192],
+                         ids=["full", "w100", "w192"])
+@pytest.mark.parametrize("what", ["fwd", "dq", "dkdv"])
+def test_flash_at_sixteen_query_heads_a_kv_head_against_xla(what, window):
+    """Command A+'s grouping (128 query heads over 8 KV heads: 16 a group;
+    a chip's 32 over 2) banded and full: forward, dQ and dK/dV (whose
+    per-query-head float32 results are summed over the 16) against the
+    model's own einsum attention under the same mask, two KV heads so
+    that a query head reading the wrong one shows."""
+    from ray_tpu.models.llama import _attention_xla
+
+    q, k, v = _make(B=1, S=256, H=32, KV=2, D=32, seed=21)
+    g = jax.random.normal(jax.random.PRNGKey(22), q.shape, q.dtype)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, window=window, block_q=64, block_k=64)
+
+    def xla(q, k, v):
+        return _attention_xla(q, k, v, True, window=window)
+
+    if what == "fwd":
+        got, want = flash(q, k, v), xla(q, k, v)
+    else:
+        argnums = (0,) if what == "dq" else (1, 2)
+        got, want = (jax.grad(lambda *a: (f(*a) * g).sum(), argnums)(q, k, v)
+                     for f in (flash, xla))
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=5e-3, atol=2e-2)
+    if what == "fwd":
+        # heads 0-15 read KV head 0 and 16-31 KV head 1: with the KV heads
+        # swapped the two halves of the query heads swap results
+        swapped = flash(jnp.concatenate([q[:, :, 16:], q[:, :, :16]], 2),
+                        k[:, :, ::-1], v[:, :, ::-1])
+        np.testing.assert_allclose(
+            np.asarray(swapped[:, :, :16]), np.asarray(got[:, :, 16:]),
+            rtol=1e-5, atol=1e-5)

@@ -208,7 +208,16 @@ CELL_FLASH = {
     # Mellum2's two kinds of layer (a seventh entry: the window)
     "train-mellum2-ep4-s16384-b1/full": (1, 16384, 32, 4, 128, None),
     "train-mellum2-ep4-s16384-b1/window": (1, 16384, 32, 4, 128, None, 1024),
+    # Command A+'s two kinds: 16 query heads a KV head, a window of 4,096
+    "train-commandaplus-ep16-s8192-b1/full": (1, 8192, 32, 2, 128, None),
+    "train-commandaplus-ep16-s8192-b1/window": (1, 8192, 32, 2, 128, None,
+                                                4096),
 }
+# where a window is so wide that only the forward is banded: (path, span,
+# in flight) of the forward and of dQ; K and V of a head are 2 MiB each at
+# S 8,192 x D 128, so dQ loops over the whole head and skips in the kernel,
+# and the dK/dV call is resident
+WIDE_WINDOW = {4096: [("band", 4608, 1), ("loop", 8192, 2)]}
 
 
 @pytest.mark.parametrize("cell", sorted(CELL_FLASH))
@@ -270,7 +279,7 @@ def test_the_cells_flash_walks_fit_the_vmem_a_call_gets(cell, one_chip,
                 ("stream", S // 2, 1 if D == 256 else 2)]
         grids = [(2 * S // 512, 3 * S // 1024)] * 2
     if window:          # a q-block's band of three k-blocks in one step
-        want = [("band", window + 512, 3)] * 2
+        want = WIDE_WINDOW.get(window, [("band", window + 512, 3)] * 2)
         grids = [(S // 512, S // 512)] * 2
     assert [(p["path"], p["span"], p["in_flight"])
             for p in plans.values()] == want, plans
@@ -283,7 +292,7 @@ def test_the_cells_flash_walks_fit_the_vmem_a_call_gets(cell, one_chip,
     # (path, a head's grid steps, those at work): the causal triangle, or
     # three q-blocks a k-block and the sequence's end
     assert (dkdv["path"], S // 512 * dkdv["steps"], dkdv["band_steps"]) == (
-        ("band", 96, 93) if window else
+        ("band", 96, 93) if window and window not in WIDE_WINDOW else
         ("stream", (S // 512) ** 2, S // 512 * (S // 512 + 1) // 2)
         if streams else ("resident", S // 512, S // 512)), dkdv
     # the compiled calls carry the scope of the plan they took
@@ -339,7 +348,10 @@ def test_banded_calls_at_a_head_of_256_fit_the_vmem_a_call_gets(
 # 1536 (no whole number of the N tiles; whole, the forward's tiles pass
 # the VMEM a call gets: ``ops/grouped_matmul.py`` ``_fit``)
 GMM_WIDTHS = {"olmoe": (131072, 64, 2048, 1024),
-              "glm": (16384, 8, 2048, 1536)}
+              "glm": (16384, 8, 2048, 1536),
+              # Command A+'s one pass of 8,192 rows over the 8 experts held,
+              # 4096 x 4096: two K tiles AND several N tiles in one call
+              "commanda": (8192, 8, 4096, 4096)}
 
 
 @pytest.mark.parametrize("model", sorted(GMM_WIDTHS))
