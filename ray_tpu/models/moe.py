@@ -418,12 +418,14 @@ def route(logits, cfg: MoEConfig, bias=None):
 # Rows computed AT A TIME for a share of the experts: this many times what
 # the held experts get of T*K assignments when every expert is as likely
 # (shapes are static; the grouped matmul skips the tiles no group covers,
-# the gathers do not). A layer whose held experts got more runs further
-# passes, a quarter as long, over the rest (``_held_combine``), so no
-# assignment is ever dropped and the step takes as long as the router
-# made it. With random weights a layer's share of 9 experts in 72 read
-# 0.044 to 0.242 over 370 layers (even: 0.125; PERF.md 6, PR 32): twice
-# the even share is the least that leaves the further passes to the tail.
+# the gather of the rows walks the live ones a chunk at a time,
+# ``held_chunk``; the scatter-adds walk the pass). A layer whose held
+# experts got more runs further passes, a quarter as long, over the rest
+# (``_held_combine``), so no assignment is ever dropped and the step takes
+# as long as the router made it. With random weights a layer's share of 9
+# experts in 72 read 0.044 to 0.242 over 370 layers (even: 0.125; PERF.md
+# 6, PR 32): twice the even share is the least that leaves the further
+# passes to the tail.
 HELD_PASS = 2
 
 
@@ -441,6 +443,53 @@ def held_rows(cfg: MoEConfig, tokens: int) -> int:
     rows = -(-tokens * cfg.top_k * cfg.n_held * HELD_PASS // cfg.n_experts)
     tile = GMM_TILING[0]
     return min(-(-rows // tile) * tile, tokens * cfg.top_k)
+
+
+# The first pass's gather moves a sixteenth of the pass at a time and no
+# chunk beyond the last live row: experts placed by load leave a pass half
+# empty. Under HELD_CHUNK_ROWS a chunk the loop saves nothing (one layer
+# alone on the chip, PERF.md 6, PR 42: 16,384 rows in chunks of 1,024
+# -0.05 ms, 8,192 in chunks of 512 +0.73 ms), and the pass stays whole.
+HELD_CHUNKS = 16
+HELD_CHUNK_ROWS = 2048
+
+
+def held_chunk(rows: int) -> int:
+    """Rows of one chunk of a pass of ``rows``: a sixteenth of it where
+    that is whole row tiles of the grouped matmul and HELD_CHUNK_ROWS or
+    more, else the pass itself, one chunk."""
+    chunk, left = divmod(rows, HELD_CHUNKS)
+    if left or chunk % GMM_TILING[0] or chunk < HELD_CHUNK_ROWS:
+        return rows
+    return chunk
+
+
+def _chunks(counts, rows: int):
+    """How many chunks of a first pass of ``rows`` its gather walks: those
+    that hold a live row; the one chunk of a pass that stays whole."""
+    chunk = held_chunk(rows)
+    if chunk == rows:
+        return jnp.ones((), jnp.int32)
+    return -(-jnp.minimum(counts.sum(), rows) // chunk)
+
+
+def _gather_rows(x, token, live, n=None):
+    """``where(live, x[token], 0)`` [rows, D]; given ``n``, over the first
+    ``n`` chunks of the rows alone (all the live ones: ``_chunks``), in a
+    loop whose length the data decide: the rest stays 0 and is never read
+    or written. A pass of one chunk is gathered whole."""
+    chunk = held_chunk(token.shape[0])
+    if n is None or chunk == token.shape[0]:
+        return jnp.where(live, x[token], 0)
+
+    def one(c, xs):
+        rows = jnp.where(
+            jax.lax.dynamic_slice_in_dim(live, c * chunk, chunk),
+            x[jax.lax.dynamic_slice_in_dim(token, c * chunk, chunk)], 0)
+        return jax.lax.dynamic_update_slice_in_dim(xs, rows, c * chunk, 0)
+
+    return jax.lax.fori_loop(
+        0, n, one, jnp.zeros((token.shape[0],) + x.shape[1:], x.dtype))
 
 
 def _held_slots(lo, rows: int, ranked, counts):
@@ -473,12 +522,15 @@ def _held_swiglu(xs, w_rows, live, sizes, we, cfg: MoEConfig):
               we[2])
 
 
-def _held_rows(lo, rows: int, x, weights, ranked, counts, k: int):
+def _held_rows(lo, rows: int, x, weights, ranked, counts, k: int,
+               chunks=None):
     """A pass's rows (``_held_slots``): (token [rows], order, live, sizes,
-    the tokens' rows of x with the dead ones 0, the rows' weights)."""
+    the tokens' rows of x with the dead ones 0, the rows' weights). With
+    ``chunks``, only so many chunks of the rows are gathered: the first
+    pass's live ones (``_gather_rows``)."""
     order, live, sizes = _held_slots(lo, rows, ranked, counts)
     token = order // k
-    return (token, order, live, sizes, jnp.where(live, x[token], 0),
+    return (token, order, live, sizes, _gather_rows(x, token, live, chunks),
             weights.reshape(-1)[order])
 
 
@@ -505,7 +557,20 @@ def _held_combine(cfg, rows, short, x, weights, ranked, counts, we):
     the loops hold one short pass at a time and nothing more that is T
     rows wide. The
     sum back into token order is a scatter-add of the rows (a gather T*K
-    rows wide would move several times as much)."""
+    rows wide would move several times as much).
+
+    The first pass's shapes are static and its live rows a prefix of it,
+    half of it where experts are placed by load: the gather of x into
+    expert order, forward and in a checkpoint's replay, goes a chunk of
+    rows at a time (``held_chunk``) and stops at the last live row, as the
+    grouped matmuls do. dy's gather and the three scatter-adds walk the
+    whole pass: XLA's scatter-add in chunks is slower a row than whole,
+    dy's gather as a loop beside the replayed one makes the compiler order
+    every update after the last backward (the plan +3 to +4 GB), and one
+    op over the live prefix, its static length chosen by a ``lax.switch``,
+    halves the scatter-adds but makes the step program half as large
+    again: it loads 12 s longer and the compiler rematerializes other
+    layers' work to fit it (PERF.md 6, PR 42)."""
     return _held_combine_fwd(cfg, rows, short, x, weights, ranked, counts,
                              we)[0]
 
@@ -514,7 +579,7 @@ def _held_combine_fwd(cfg, rows, short, x, weights, ranked, counts, we):
     K = cfg.top_k
     with jax.named_scope("dispatch"):
         token, order, live, sizes, xs, w_rows = _held_rows(
-            0, rows, x, weights, ranked, counts, K)
+            0, rows, x, weights, ranked, counts, K, _chunks(counts, rows))
     with jax.named_scope("experts"):
         ys, back = jax.vjp(lambda xs, w_rows, we: _held_swiglu(
             xs, w_rows, live, sizes, we, cfg), xs, w_rows, we)
@@ -570,11 +635,12 @@ _held_combine.defvjp(_held_combine_fwd, _held_combine_bwd)
 
 def _held_experts(x, weights, experts, lp, cfg: MoEConfig):
     """The held experts' part of the layer's output: x [T, D], weights and
-    experts [T, K] (over all n_experts) -> (y [T, D], counts of the held
-    experts [held], how many passes beyond the first they took). The
-    assignments are sorted by held expert, the absent last, and the held
-    ones computed ``held_rows`` at a time, then a quarter as many
-    (``_held_combine``)."""
+    experts [T, K] (over all n_experts) -> (y [T, D], the layer's
+    statistics: the counts of the held experts [held], how many passes
+    beyond the first they took, the share of the first pass's rows that
+    its gather walked). The assignments are sorted by held expert, the
+    absent last, and the held ones computed ``held_rows`` at a time, then
+    a quarter as many (``_held_combine``)."""
     T, K, dt = x.shape[0], cfg.top_k, cfg.dtype
     held, first = cfg.experts_held
     with jax.named_scope("dispatch"):
@@ -593,7 +659,10 @@ def _held_experts(x, weights, experts, lp, cfg: MoEConfig):
     # the pass's own scopes lie inside (``_held_combine_fwd``, ``_bwd``)
     with jax.named_scope("combine"):
         y = _held_combine(cfg, rows, short, x, weights, ranked, counts, we)
-        return y, counts, _passes(counts, rows, short)
+    return y, {"held_counts": counts,
+               "more_passes": _passes(counts, rows, short),
+               "walked_share": _chunks(counts, rows) * (held_chunk(rows)
+                                                        / rows)}
 
 
 def feed_forward(h, lp, cfg: MoEConfig, mesh=None, rules=None, tp=None,
@@ -621,10 +690,9 @@ def feed_forward(h, lp, cfg: MoEConfig, mesh=None, rules=None, tp=None,
         weights, experts, probs = route(logits, cfg, lp.get("router_bias"))
         flat = experts.reshape(T * K)
     if cfg.experts_held is not None:
-        y, held, more = _held_experts(x, weights, experts, lp, cfg)
+        y, stats = _held_experts(x, weights, experts, lp, cfg)
         with jax.named_scope("router"):
-            stats = {"counts": _count(flat, E), "held_counts": held,
-                     "more_passes": more}
+            stats = {"counts": _count(flat, E), **stats}
         return _finish(y, stats, x, lp, cfg, logits, experts, probs, (B, S, D))
     with jax.named_scope("dispatch"):
         order = jnp.argsort(flat, stable=True).astype(jnp.int32)  # row -> slot
@@ -701,6 +769,9 @@ def held_aux(held, stats, per_layer: int):
         # passes beyond the first that the held experts' rows took
         "moe_held_more_passes":
             stats["more_passes"].sum().astype(jnp.float32),
+        # rows the first passes' gathers walked over the rows of those
+        # passes (whole chunks, ``held_chunk``; 1.0: the passes whole)
+        "moe_held_walked_share": stats["walked_share"].mean(),
         # every assignment to a held expert is computed (_held_experts)
         "moe_dropped": jnp.zeros((), jnp.int32)}
 

@@ -489,6 +489,160 @@ def test_held_passes_hold_every_assignment_once(lean):
     assert sorted(seen) == sorted(np.flatnonzero(local < held))
 
 
+# a pass of 4,096 rows over 8,192 assignments, in sixteen chunks of one
+# row tile (the rule's threshold lowered to a size a test can afford)
+_WALK = dict(held=2, first=3, k=2, t=4096, rows=4096, chunk=256, short=1024)
+
+
+@pytest.fixture
+def small_chunks(monkeypatch):
+    monkeypatch.setattr(moe, "HELD_CHUNK_ROWS", _WALK["chunk"])
+
+
+def _held_layer(live: int, dtype):
+    """A layer's inputs with exactly ``live`` assignments to the held
+    experts, scattered over the tokens: (cfg, x, weights, experts, lp)."""
+    w = _WALK
+    cfg = tiny(n_experts=8, top_k=w["k"], experts_held=(w["held"], w["first"]),
+               shared_d_ff=0).replace(dtype=dtype)
+    assert moe.held_rows(cfg, w["t"]) == w["rows"]
+    assert moe.held_chunk(w["rows"]) == w["chunk"]
+    key = jax.random.split(jax.random.PRNGKey(live), 6)
+    n = w["t"] * w["k"]
+    held = w["first"] + jnp.arange(n) % w["held"]
+    away = (w["first"] + w["held"] + jnp.arange(n) % 3) % 8
+    experts = jnp.where(jax.random.permutation(key[0], n) < live, held,
+                        away).astype(jnp.int32).reshape(w["t"], w["k"])
+    d, f = cfg.d_model, cfg.d_ff
+    lp = {"we_gate": jax.random.normal(key[1], (w["held"], d, f)) / d ** .5,
+          "we_up": jax.random.normal(key[2], (w["held"], d, f)) / d ** .5,
+          "we_down": jax.random.normal(key[3], (w["held"], f, d)) / f ** .5}
+    x = jax.random.normal(key[4], (w["t"], d)).astype(dtype)
+    weights = jax.nn.softmax(jax.random.normal(key[5], (w["t"], w["k"])))
+    return cfg, x, weights, experts, jax.tree.map(
+        lambda a: a.astype(dtype), lp)
+
+
+def _whole_pass(x, weights, experts, lp, cfg):
+    """The held experts' part by the plain formulas: every assignment in
+    one pass, ``x[token]`` into expert order and ``.at[token].add`` back,
+    jax's own gradient."""
+    k, (held, first) = cfg.top_k, cfg.experts_held
+    local = experts.reshape(-1) - first
+    local = jnp.where((local >= 0) & (local < held), local, held)
+    order = jnp.argsort(local, stable=True)
+    counts = jnp.sum(local[:, None] == jnp.arange(held), axis=0)
+    live = (jnp.arange(order.size) < counts.sum())[:, None]
+    token = order // k
+    ys = moe._held_swiglu(
+        jnp.where(live, x[token], 0), weights.reshape(-1)[order], live,
+        counts, tuple(lp[n] for n in ("we_gate", "we_up", "we_down")), cfg)
+    return jnp.zeros(x.shape, jnp.float32).at[token].add(ys).astype(x.dtype)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("live", [0, 1, 255, 256, 257, 4096, 4096 + 1500])
+def test_chunked_walks_equal_the_whole_pass(live, dtype, small_chunks,
+                                            monkeypatch):
+    """The first pass's gather a chunk at a time, over the chunks that hold
+    a live row, against the plain formulas: the layer, d_x, d_weights and
+    the three weights' gradients, under a checkpoint too; and to the bit
+    against the same walk in ONE chunk (the pass as it was walked
+    before)."""
+    cfg, x, weights, experts, lp = _held_layer(live, jnp.dtype(dtype))
+    probe = jax.random.normal(jax.random.PRNGKey(9), x.shape)
+
+    def through(layer):
+        def loss(x, weights, lp):
+            y = layer(x, weights, experts, lp, cfg)
+            return jnp.sum(y.astype(jnp.float32) * probe), y
+        return jax.value_and_grad(loss, (0, 1, 2), has_aux=True)
+
+    mine = lambda *a: moe._held_experts(*a)[0]              # noqa: E731
+    (_, y), got = through(mine)(x, weights, lp)
+    (_, kept_y), kept = through(jax.checkpoint(mine, static_argnums=4))(
+        x, weights, lp)
+    (_, want_y), want = through(_whole_pass)(x, weights, lp)
+    stats = moe._held_experts(x, weights, experts, lp, cfg)[1]
+    w = _WALK
+    assert int(stats["held_counts"].sum()) == live
+    assert int(stats["more_passes"]) == -(-max(live - w["rows"], 0)
+                                          // w["short"])
+    assert float(stats["walked_share"]) == \
+        -(-min(live, w["rows"]) // w["chunk"]) * w["chunk"] / w["rows"]
+    tol = {"float32": 2e-5, "bfloat16": 2e-2}[dtype]
+    for g, k, r in zip(jax.tree.leaves((y, got)),
+                       jax.tree.leaves((kept_y, kept)),
+                       jax.tree.leaves((want_y, want))):
+        assert g.dtype == r.dtype
+        g, k, r = (np.asarray(a, np.float32) for a in (g, k, r))
+        np.testing.assert_array_equal(g, k)
+        np.testing.assert_allclose(g, r, rtol=tol,
+                                   atol=tol * (np.abs(r).max() + 1e-9))
+    monkeypatch.setattr(moe, "held_chunk", lambda rows: rows)
+    (_, one_y), one = through(mine)(x, weights, lp)
+    for g, o in zip(jax.tree.leaves((y, got)), jax.tree.leaves((one_y, one))):
+        np.testing.assert_array_equal(np.asarray(g, np.float32),
+                                      np.asarray(o, np.float32))
+
+
+def test_walked_share_is_whole_chunks_and_reaches_the_report_span(
+        small_chunks, monkeypatch):
+    """``moe_held_walked_share``: the layers' mean of the chunks their
+    first passes' gathers covered over the pass; a float of the step's
+    metrics, so an attribute of the ``train.report`` span."""
+    import contextlib
+
+    from ray_tpu.train import session
+
+    w = _WALK
+    shares = []
+    for live in (0, 700, 2049, 5000):
+        cfg, x, weights, experts, lp = _held_layer(live, jnp.float32)
+        shares.append(moe._held_experts(x, weights, experts, lp, cfg)[1])
+        assert float(shares[-1]["walked_share"]) == {
+            0: 0.0, 700: 0.1875, 2049: 0.5625, 5000: 1.0}[live]
+    stats = jax.tree.map(lambda *a: jnp.stack(a), *shares)
+    aux = moe.held_aux(stats["held_counts"].astype(jnp.float32), stats,
+                       w["t"] * w["k"])
+    assert float(aux["moe_held_walked_share"]) == 0.4375
+    assert float(aux["moe_held_more_passes"]) == 1.0
+    # the step's metrics as a train loop reports them
+    cfg = tiny(experts_held=(4, 2))
+    params, tokens = make(cfg)
+    _, metrics = hybrid.loss_fn(params, {"tokens": tokens}, cfg)
+    assert float(metrics["moe_held_walked_share"]) == 1.0   # passes whole
+    seen = {}
+    monkeypatch.setattr(session, "get_context", lambda: None)
+    monkeypatch.setattr(session, "_report", lambda *a: None)
+    monkeypatch.setattr(
+        session._tracing, "span",
+        lambda name, attrs: seen.update({name: attrs})
+        or contextlib.nullcontext())
+    session.report({"step": 1, **{k: float(v) for k, v in metrics.items()
+                                  if k.startswith("moe_")}})
+    assert seen["train.report"]["moe_held_walked_share"] == float(
+        metrics["moe_held_walked_share"])
+
+
+@pytest.mark.parametrize("rows,chunk", [
+    (40960, 2560), (65536, 4096),                     # Granite, Mellum2
+    (16384, 16384), (8192, 8192),     # GLM, Command A+: under the threshold
+    (32768, 2048), (32768 - 16, 32768 - 16), (32768 + 16 * 256, 2304),
+    (16 * 2047, 16 * 2047),                          # no whole row tiles
+    (40960 + 256, 40960 + 256), (300, 300), (256, 256)])
+def test_the_chunk_rule(rows, chunk):
+    """A chunk is a sixteenth of the pass where that is whole row tiles of
+    the grouped matmul and 2,048 rows or more; a pass too small for that,
+    or not sixteen whole tiles, is one chunk."""
+    from ray_tpu.ops.grouped_matmul import GMM_TILING
+
+    got = moe.held_chunk(rows)
+    assert got == chunk and rows % got == 0
+    assert got == rows or (got % GMM_TILING[0] == 0 and got >= 2048
+                           and rows == 16 * got)
+
+
 def test_the_cached_paths_refuse_a_config_that_states_its_own_scales():
     """prefill and decode embed, rotate, scale and add as llama does: a
     cache for a config that states otherwise is refused, llama's is not."""

@@ -573,7 +573,8 @@ def test_the_bias_rule_over_three_steps_and_the_optimizer_leaves_it_alone():
         "loss", "grad_norm", "step", "moe_main_loss", "moe_mtp_loss",
         "moe_aux_loss", "moe_bias_abs_max", "moe_bias_moved",
         "moe_load_max_over_mean", "moe_held_rows_share",
-        "moe_held_more_passes", "moe_dropped"}       # the counts are used up
+        "moe_held_more_passes", "moe_held_walked_share",
+        "moe_dropped"}                               # the counts are used up
     assert all(v.shape == () for v in m.values())
 
 

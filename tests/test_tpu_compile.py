@@ -536,6 +536,58 @@ def test_a_mixers_passes_at_granite_widths(one_chip, on_chip_branch):
     assert alone < 1.0e9, alone
 
 
+def test_the_held_experts_walks_at_granite_widths(one_chip, on_chip_branch):
+    """One expert layer holding 9 of 72 experts, forward, replay under
+    ``jax.checkpoint`` and backward at the Granite cell's widths (16,384
+    tokens of 4,096, a pass of 40,960 rows in 16 chunks), compiled for the
+    chip: the gather of x into expert order is a loop whose length the
+    data decide, once forward and once in the replay, and its body holds
+    no copy of the pass's buffer or of x (the buffer is updated in place);
+    the Mosaic calls are the 22 the whole-pass gather had (16 ``gmm``, 6
+    ``tgmm``, the further passes' among them); the layer's temporary bytes
+    are no more than with the gather whole (1,350,498,304 at the parent of
+    PR 42)."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import moe
+
+    bf = jnp.bfloat16
+    T, D, F, E, K, held = 16384, 4096, 768, 72, 10, 9
+    cfg = moe.MoEConfig(
+        vocab_size=256, d_model=D, n_layers=1, n_heads=8, n_kv_heads=8,
+        d_ff=F, n_experts=E, top_k=K, experts_held=(held, 0), shared_d_ff=0,
+        dtype=bf, param_dtype=bf, gmm_impl="pallas")
+    rows = moe.held_rows(cfg, T)
+    chunk = moe.held_chunk(rows)
+    assert (rows, chunk) == (40960, 2560)
+    lp = {"router": _sds((D, E), bf, one_chip),
+          "we_gate": _sds((held, D, F), bf, one_chip),
+          "we_up": _sds((held, D, F), bf, one_chip),
+          "we_down": _sds((held, F, D), bf, one_chip)}
+
+    def loss(lp, x):
+        y = jax.checkpoint(lambda lp, x: moe.feed_forward(x, lp, cfg)[0])(
+            lp, x)
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        lp, _sds((1, T, D), bf, one_chip)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes <= 1_350_498_304
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 22
+    walks = {name: body for name, body in _while_bodies(text).items()
+             if any(re.search(rf"\[{chunk},{D}\]", ln) for ln in body)
+             and not any("tpu_custom_call" in ln for ln in body)}
+    assert len(walks) == 2, sorted(walks)
+    big = re.compile(rf"= \S*\[({rows}|{T}),{D}\]\S* copy\(")
+    copies = [ln[:160] for body in walks.values() for ln in body
+              if big.search(ln)]
+    assert not copies, copies
+
+
 _GLM_BLOCK = {}    # the compiled attention half, shared by its two tests
 
 
