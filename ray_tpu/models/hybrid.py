@@ -113,6 +113,9 @@ _ATTENTION_ONLY = ("attn_norm", "wq", "wk", "wv", "wo")
 # residuals (llama._checkpoint): the expert layer's routes; of a mixer
 # nothing, its scan runs again
 REMAT_SAVED = _moe.REMAT_SAVED
+remat_saved_bytes = _moe.remat_saved_bytes
+remat_offers = _moe.remat_offers
+expert_rows = _moe.expert_rows
 
 
 def layer_runs(cfg: HybridConfig) -> List[Tuple[str, int]]:

@@ -125,6 +125,22 @@ PRESETS: Dict[str, LatentConfig] = {
 }
 
 REMAT_SAVED = _moe.REMAT_SAVED
+expert_rows = _moe.expert_rows
+
+
+def remat_saved_bytes(cfg: "LatentConfig", kind, rows: int) -> int:
+    return 0 if kind == "dense" else _moe.remat_saved_bytes(cfg, kind, rows)
+
+
+def remat_offers(cfg: "LatentConfig", kind, rows: int):
+    return () if kind == "dense" else _moe.remat_offers(cfg, kind, rows)
+
+
+def further_stacks(params, cfg: "LatentConfig"):
+    """The prediction module's block, for what counts a step's layers
+    (llama._stacks): a further pass over the same rows."""
+    return [("sparse", cfg.n_mtp, params["mtp"]["block"])] if cfg.n_mtp \
+        else []
 # leaves that no gradient reaches and ``post_update`` moves: the optimizer
 # is told to leave them alone (parallel.train_step.hold_out)
 RULE_LEAVES = ("router_bias",)
@@ -376,8 +392,8 @@ def token_losses(params, tokens, cfg: LatentConfig, mesh=None, rules=None):
     against the one after, both float32 [B, S], the expert layers'
     statistics stacked, the module's block last)."""
     S = tokens.shape[1] - 2
-    logits, stats, hidden, run = _ll._forward(params, tokens[:, :S], cfg,
-                                              mesh=mesh, rules=rules)
+    logits, stats, hidden, run, _ = _ll._forward(params, tokens[:, :S], cfg,
+                                                 mesh=mesh, rules=rules)
     main = _ll.token_losses(logits, tokens[:, 1:S + 1])
     logits, stats = _ahead(params, tokens, hidden, stats, cfg, run)
     return main, _ll.token_losses(logits, tokens[:, 2:S + 2]), stats

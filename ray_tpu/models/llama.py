@@ -33,11 +33,13 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.parallel.collective_matmul import (allgather_matmul,
                                                 gather_apply_scatter,
                                                 matmul_reduce_scatter,
                                                 overlap_plan)
+from ray_tpu.parallel.train_step import state_bytes, step_memory
 from ray_tpu.util import tracing
 
 
@@ -285,7 +287,9 @@ def _family(cfg: "LlamaConfig"):
     """The module that defines ``cfg``'s class: this one for the dense
     model, ``models/moe.py`` for ``MoEConfig``. It supplies the layer's
     feed-forward half (``feed_forward``), the names that half wants kept
-    across the layer checkpoint (``REMAT_SAVED``) and, where the
+    across the layer checkpoint (``REMAT_SAVED``, and what they weigh:
+    ``remat_saved_bytes``), the names it offers beyond them where the
+    step's memory has room (``remat_offers``) and, where the
     feed-forward returns statistics, ``finish_loss``; where its attention
     is not three projections of the hidden state, the attention half too
     (``attention_half``); where it predicts further tokens than the next,
@@ -293,21 +297,182 @@ def _family(cfg: "LlamaConfig"):
     return sys.modules[type(cfg).__module__]
 
 
-def _checkpoint(body, cfg: "LlamaConfig"):
+def _checkpoint(body, cfg: "LlamaConfig", kept: Tuple[str, ...] = ()):
     """Per-layer jax.checkpoint. Beside the layer's input it keeps the
     flash kernel's output and log-sum-exp (FLASH_RESIDUALS: the output is
     as large as the layer input, B x S x D x 2 bytes a layer in bf16, the
     log-sum-exp B x H x S x 4), so the backward kernels run from them and
-    the forward kernel runs once, and what the family's feed-forward
-    names (REMAT_SAVED: an expert layer's routes); everything else is
-    recomputed, a state-space mixer's scan too (ops/ssd.py). A body that
-    holds no such name (attn_impl other than "flash", under 128 tokens)
-    saves nothing more."""
+    the forward kernel runs once, what the family's feed-forward
+    names (REMAT_SAVED: an expert layer's routes) and ``kept``: the names
+    of those the layer offers that the step's memory has room for
+    (``remat_plan``: q, k and v as the attention call takes them, a shared
+    SwiGLU's gate and up); everything else is recomputed, a state-space
+    mixer's scan too (ops/ssd.py). A body that holds no such name
+    (attn_impl other than "flash", under 128 tokens) saves nothing more."""
     from ray_tpu.ops.flash_attention import FLASH_RESIDUALS
 
     return jax.checkpoint(
         body, policy=jax.checkpoint_policies.save_only_these_names(
-            *FLASH_RESIDUALS, *_family(cfg).REMAT_SAVED))
+            *FLASH_RESIDUALS, *_family(cfg).REMAT_SAVED, *kept))
+
+
+# checkpoint_name tags of what the attention half offers the layer
+# checkpoint where the step's memory has room: q, k and v as the attention
+# call takes them (after the q/k norm and the rotary)
+ATTN_OFFERED = ("attn_q", "attn_k", "attn_v")
+
+# The share of a device's memory that a plan with kept names leaves free:
+# ``estimate + KEPT_COST x kept`` stays under 85% of the limit, 14.37e9 of
+# the 16,909,336,064 a v5e chip states (of 16 GiB). The largest step that
+# has run there planned 15.82e9, at 15.38e9 XLA rematerialized on its own
+# (38 ``.remat`` instructions, +166 ms a step; PERF.md 6, PR 42), and the
+# estimate may read 0.5e9 under a plan: 15% keeps all three apart.
+REMAT_FREE = 0.15
+# One layer's backward, in bytes a byte of its products (every matrix of
+# the layer times its rows: a product and its gradient), and in bytes a lane
+# of what stands beside them in float32 and twice over: the rows gathered
+# into expert order and back, forward and backward, and the query heads
+# round the attention call (q under its rotary, dq, the output's gradient).
+# The update of a leaf under adafactor holds four float32 temporaries as
+# large as the leaf (3.4 and 3.8 read on the l8 and OLMoE plans, whose peak
+# it is). A kept byte has cost up to 1.58 bytes of plan (the Mellum2 step,
+# whose scans stack three layers' residuals: +2.55e9 for 1.61e9 of q) and
+# as little as 0.68 (Command A+'s: the replay's own buffers go): it is
+# counted at 1.5. All four from the one-chip plans compiled for a described
+# v5e (PERF.md 4 and 6, PR 43).
+LAYER_BACKWARD = 2.0
+LANE_BYTES = 18
+UPDATE_BYTES = 16
+KEPT_COST = 1.5
+
+
+class RematPlan(NamedTuple):
+    """What the layer checkpoint keeps beyond the parent's list, and why."""
+    kept: Tuple[str, ...]   # of the offered names, in the order offered
+    kept_bytes: int         # over all layers
+    estimate: int           # the step's bytes without them; 0: none made
+    limit: int              # the device's; 0: it states none
+    # "room" | "no room" | "no step" | "no limit" | "mesh"
+    why: str
+
+
+def _stacks(params, cfg: "LlamaConfig"):
+    """([(kind, layers, stack), ...], passes): the stacks of layers a
+    step's forward scans, those of a family's further passes over the same
+    rows last (``further_stacks``), and how many passes that makes."""
+    family = _family(cfg)
+    if isinstance(params["layers"], dict):
+        stacks = [(None, cfg.n_layers, params["layers"])]
+    else:
+        stacks = [(kind, n, stack) for (kind, n), stack in zip(
+            family.layer_runs(cfg), params["layers"])]
+    further = getattr(family, "further_stacks", lambda params, cfg: [])(
+        params, cfg)
+    return stacks + further, 1 + len(further)
+
+
+def _offers(cfg: "LlamaConfig", kind, batch: int, seq: int):
+    """((name, bytes), ...) a layer of ``kind`` offers the checkpoint, the
+    dearest replay a byte first: 28 ms a GB for q, k and v on the l8 step,
+    22 for the shared SwiGLU's gate and up on Command A+'s (PERF.md 6)."""
+    family, rows = _family(cfg), batch * seq
+    head = rows * cfg.head_dim * jnp.dtype(cfg.dtype).itemsize
+    attention = tuple(zip(ATTN_OFFERED, (
+        head * cfg.n_heads, head * cfg.n_kv_heads, head * cfg.n_kv_heads))
+    ) if _takes_attention_half(cfg, kind) else ()
+    return attention + tuple(family.remat_offers(cfg, kind, rows))
+
+
+def _step_estimate(cfg: "LlamaConfig", params, stacks, passes: int,
+                   rows: int, state: int) -> int:
+    """The bytes one device holds at the peak of a train step over
+    ``params`` (``_stacks``: its stacks of layers and passes) and ``rows``
+    tokens with the parent's list kept, from shapes alone: the state
+    (the step's own count) plus the larger of
+    - the update: the gradients that wait for it (a stack's update runs
+      when its backward scan ends, so the largest stack's and those of the
+      leaves outside the stacks) and adafactor's float32 temporaries over
+      the largest leaf;
+    - a layer's backward: those gradients, what every layer keeps (its
+      input, the flash call's ``o`` and ``lse``, the family's REMAT_SAVED),
+      the logits of the passes before a further pass's, and one layer's
+      products, gradients and float32 forms;
+    - the head: what every layer keeps, the logits and their gradient."""
+    family, item = _family(cfg), jnp.dtype(cfg.dtype).itemsize
+    expert_rows = getattr(family, "expert_rows", lambda cfg, rows: 0)(
+        cfg, rows)
+
+    def products(stack):
+        # a layer's matrices [L, in, out] times the rows, an expert's
+        # [L, E, in, out] times the rows its experts get; beside them the
+        # lanes of the rows in expert order and of the query heads
+        total = 0
+        for w in jax.tree.leaves(stack):
+            if w.ndim == 3:
+                total += rows * w.shape[2] * item
+            elif w.ndim == 4:
+                total += expert_rows * w.shape[3] * item
+        return LAYER_BACKWARD * total + LANE_BYTES * (
+            expert_rows * cfg.d_model + rows * cfg.n_heads * cfg.head_dim)
+
+    saved = sum(n * (rows * (cfg.d_model + cfg.n_heads * cfg.head_dim) * item
+                     + rows * cfg.n_heads * 4
+                     + family.remat_saved_bytes(cfg, kind, rows))
+                for kind, n, _ in stacks)
+    in_stacks = [state_bytes(stack) for _, _, stack in stacks]
+    outside = state_bytes(params) - sum(in_stacks)
+    waiting = outside + max(in_stacks)
+    logits = 2 * rows * cfg.vocab_size * (4 if cfg.f32_logits else item)
+    largest = max(x.size for x in jax.tree.leaves(params))
+    return int(state + max(
+        waiting + UPDATE_BYTES * largest,
+        waiting + saved + (passes - 1) * logits
+        + max(products(stack) for _, _, stack in stacks),
+        saved + passes * logits + outside))
+
+
+def remat_plan(cfg: "LlamaConfig", params, batch: int, seq: int, memory,
+               mesh=None) -> RematPlan:
+    """Which of the names the layers offer the layer checkpoint keeps in a
+    step over ``params`` (arrays or shapes) and [batch, seq] tokens: a pure
+    function of shapes and of ``memory`` (parallel.train_step.StepMemory:
+    the device's limit and the state's bytes as the step counts them; None
+    outside a train step). Names are kept in the order offered while
+    ``estimate + KEPT_COST x kept`` stays under the limit less its free
+    share (REMAT_FREE); a name that does not fit is passed over for the
+    next. With no limit (the CPU) or no step nothing more is kept than the
+    parent's list; under a mesh of several devices neither: the
+    activations' share of a device is not counted here."""
+    if memory is None or not memory.limit:
+        return RematPlan((), 0, 0, 0, "no step" if memory is None
+                         else "no limit")
+    if mesh is not None and mesh.size > 1:
+        return RematPlan((), 0, 0, memory.limit, "mesh")
+    stacks, passes = _stacks(params, cfg)
+    offered: Dict[str, int] = dict.fromkeys(ATTN_OFFERED, 0)
+    for kind, n, _ in stacks:
+        for name, nbytes in _offers(cfg, kind, batch, seq):
+            offered[name] = offered.get(name, 0) + n * nbytes
+    estimate = _step_estimate(cfg, params, stacks, passes, batch * seq,
+                              memory.state)
+    ceiling = memory.limit * (1 - REMAT_FREE)
+    kept, total = [], 0
+    for name, nbytes in offered.items():
+        if nbytes and estimate + KEPT_COST * (total + nbytes) <= ceiling:
+            kept.append(name)
+            total += nbytes
+    return RematPlan(tuple(kept), total, estimate, memory.limit,
+                     "room" if kept else "no room")
+
+
+def _say_remat_plan(plan: RematPlan):
+    """The instant ``remat.plan`` of a trace, once a traced forward under
+    the layer checkpoint: the names kept beyond the parent's list, their
+    bytes, the estimate they were added to and the limit."""
+    tracing.instant("remat.plan", {
+        "kept": ",".join(plan.kept), "kept_bytes": plan.kept_bytes,
+        "estimate": plan.estimate, "limit": plan.limit,
+        "ceiling": int(plan.limit * (1 - REMAT_FREE)), "why": plan.why})
 
 
 def rms_norm(x, scale, eps):
@@ -544,6 +709,10 @@ def _attention_half(x, lp, cfg: LlamaConfig, cos, sin, cache=None, mesh=None,
         if cos is not None:
             q = apply_rope(q, cos, sin)
             k = apply_rope(k, cos, sin)
+        # as the attention call takes them: kept across the layer
+        # checkpoint where the step's memory has room (``remat_plan``)
+        q, k, v = (checkpoint_name(t, name)
+                   for t, name in zip((q, k, v), ATTN_OFFERED))
     else:
         def in_heads(i, y, shard, cos, sin):
             # one shard's rows of q (0), k (1) or v (2), as its matmul
@@ -591,8 +760,16 @@ def _residual(x, y, cfg: LlamaConfig):
 
 
 # checkpoint_name tags the dense feed-forward wants kept across the layer
-# checkpoint: none (see _family)
+# checkpoint: none (see _family), and none offered beyond them
 REMAT_SAVED = ()
+
+
+def remat_saved_bytes(cfg: "LlamaConfig", kind, rows: int) -> int:
+    return 0
+
+
+def remat_offers(cfg: "LlamaConfig", kind, rows: int):
+    return ()
 
 
 def feed_forward(h, lp, cfg: LlamaConfig, mesh=None, rules=None, tp=None,
@@ -849,6 +1026,11 @@ def _forward(params, tokens, cfg: LlamaConfig, pos_offset=0, mesh=None,
                 jax.lax.dynamic_slice_in_dim(t, pos_offset, S, axis=0)
                 for t in _kind_tables(cfg, of, cfg.max_seq_len))
 
+    plan = None
+    if cfg.remat:
+        plan = remat_plan(cfg, params, B, S, step_memory(), mesh)
+        _say_remat_plan(plan)
+
     @functools.cache
     def body_of(kind):
         cos, sin = tables_of(kind)
@@ -858,7 +1040,7 @@ def _forward(params, tokens, cfg: LlamaConfig, pos_offset=0, mesh=None,
                                  tp=tp, kind=kind)
             return con(y), stats
 
-        return _checkpoint(body, cfg) if cfg.remat else body
+        return _checkpoint(body, cfg, plan.kept) if cfg.remat else body
 
     def run(kind, x, stack):
         # what lies under ``layers`` and in none of a layer's halves is
@@ -889,7 +1071,7 @@ def _forward(params, tokens, cfg: LlamaConfig, pos_offset=0, mesh=None,
         if tp is not None:    # the head wants every row: one gather a step
             x = jax.lax.with_sharding_constraint(x, tp.gathered_sharding())
         logits = _logits(params, x, cfg)
-    return logits, stats, hidden, run
+    return logits, stats, hidden, run, plan
 
 
 def forward_sp(params, tokens, cfg: LlamaConfig, mesh):
@@ -974,15 +1156,15 @@ def loss_fn(params, batch, cfg: LlamaConfig, mesh=None, rules=None):
     else:
         inputs, targets = batch["inputs"], batch["targets"]
         mask = batch.get("mask")
-    stats = hidden = run = None
+    stats = hidden = run = plan = None
     if (cfg.attn_impl in ("ring", "ulysses") and mesh is not None
             and int(mesh.shape.get("sp", 1)) > 1):
         logits = forward_sp(params, inputs, cfg, mesh)
     elif mesh is not None and int(mesh.shape.get("pp", 1)) > 1:
         logits = forward_pp(params, inputs, cfg, mesh)
     else:
-        logits, stats, hidden, run = _forward(params, inputs, cfg, mesh=mesh,
-                                              rules=rules)
+        logits, stats, hidden, run, plan = _forward(
+            params, inputs, cfg, mesh=mesh, rules=rules)
     finish = getattr(_family(cfg), "finish_loss", None)
     if finish is not None and stats is None:
         raise ValueError("a model whose loss needs its layers' statistics "
@@ -994,9 +1176,12 @@ def loss_fn(params, batch, cfg: LlamaConfig, mesh=None, rules=None):
         stats = further(params, batch["tokens"], hidden, stats, cfg, run)
     if finish is None:
         return loss
-    # an expert model adds its router losses and returns (loss, aux)
+    # an expert model adds its router losses and returns (loss, aux); the
+    # bytes its layer checkpoint keeps beyond the parent's list go with them
     with jax.named_scope("head_loss"):
-        return finish(loss, stats, cfg)
+        loss, aux = finish(loss, stats, cfg)
+    kept = plan.kept_bytes if plan is not None else 0
+    return loss, {**aux, "moe_remat_kept_gb": jnp.float32(kept / 1e9)}
 
 
 def token_losses(logits, targets):

@@ -26,7 +26,10 @@ Across the layer checkpoint the routes are kept (REMAT_SAVED: experts,
 weights, sort order and its inverse, group sizes: 36 bytes a token and
 layer at K 8); of what is T*K rows wide the backward recomputes the rows
 in expert order, gate, up and their SwiGLU, and not the down projection
-(``_down_combine``; the numbers: PERF.md section 6, PR 26).
+(``_down_combine``; the numbers: PERF.md section 6, PR 26). Where the step's
+memory has room (``llama.remat_plan``) the shared SwiGLU's two products of x
+are kept as well (SHARED_OFFERED, ``remat_offers``: what a layer offers
+and what each name weighs), and the replay runs neither a second time.
 
 On a mesh that shards ``experts`` (``ShardingRules.ep()``) the "xla" path
 runs under GSPMD; the "pallas" path refuses any mesh of several devices.
@@ -207,6 +210,30 @@ PRESETS: Dict[str, MoEConfig] = {
 
 # what the layer checkpoint keeps of an expert layer (llama._checkpoint)
 REMAT_SAVED = ("moe_route",)
+# what it keeps besides where the step's memory has room (llama.remat_plan):
+# the shared SwiGLU's two products of x, before the activation. silu(gate)
+# x up is not offered: it is elementwise work from the two
+SHARED_OFFERED = ("shared_gate", "shared_up")
+
+
+def remat_saved_bytes(cfg: "MoEConfig", kind, tokens: int) -> int:
+    """Bytes of a layer's REMAT_SAVED: the routes of ``tokens`` tokens
+    (``feed_forward``: weights, experts, order and its inverse, 4 bytes an
+    assignment each; for a share of the experts weights and ``ranked``)."""
+    return tokens * cfg.top_k * (8 if cfg.experts_held else 16)
+
+
+def expert_rows(cfg: "MoEConfig", tokens: int) -> int:
+    """Rows of the arrays in expert order: every assignment, or a pass of
+    the held experts' (``held_rows``)."""
+    return held_rows(cfg, tokens) if cfg.experts_held else tokens * cfg.top_k
+
+
+def remat_offers(cfg: "MoEConfig", kind, tokens: int):
+    """((name, bytes a layer), ...): what a layer's feed-forward offers the
+    layer checkpoint beyond REMAT_SAVED, dearest replay a byte first."""
+    each = tokens * cfg.shared_width * jnp.dtype(cfg.dtype).itemsize
+    return tuple((name, each) for name in SHARED_OFFERED) if each else ()
 
 
 def layer_runs(cfg: MoEConfig) -> List[Tuple[str, int]]:
@@ -730,9 +757,10 @@ def _finish(y, stats, x, lp, cfg: MoEConfig, logits, experts, probs, shape):
     if cfg.shared_d_ff:
         dt = cfg.dtype
         with jax.named_scope("shared"):
-            gate = jax.nn.silu(x @ _ll._dq(lp["ws_gate"], dt))
-            shared = (gate * (x @ _ll._dq(lp["ws_up"], dt))) @ _ll._dq(
-                lp["ws_down"], dt)
+            gate, up = (checkpoint_name(x @ _ll._dq(lp[w], dt), name)
+                        for w, name in zip(("ws_gate", "ws_up"),
+                                           SHARED_OFFERED))
+            shared = (jax.nn.silu(gate) * up) @ _ll._dq(lp["ws_down"], dt)
             if cfg.shared_combine not in ("sum", "average"):
                 raise ValueError(
                     f"unknown shared_combine {cfg.shared_combine!r}")

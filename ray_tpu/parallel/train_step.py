@@ -16,7 +16,10 @@ shardings; XLA emits reduce-scatter/all-gather/psum over ICI:
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import functools
+import math
 from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import jax
@@ -29,6 +32,58 @@ class TrainState(NamedTuple):
     params: Any
     opt_state: Any
     step: jax.Array
+
+
+class StepMemory(NamedTuple):
+    """What a train step knows of a device's memory while its loss is
+    traced, for a model that decides from the bytes left what its layer
+    checkpoint keeps (models/llama.py ``remat_plan``): shapes and the
+    device's own limit, never what the process happens to hold."""
+    limit: int      # the device's ``bytes_limit``; 0 where it states none
+    state: int      # a device's share of the parameters and optimizer state
+
+
+_step_memory: contextvars.ContextVar[Optional[StepMemory]] = \
+    contextvars.ContextVar("ray_tpu_step_memory", default=None)
+
+
+def step_memory() -> Optional[StepMemory]:
+    """The memory of the train step whose loss is being traced; None
+    outside one (an evaluation, a forward alone)."""
+    return _step_memory.get()
+
+
+@contextlib.contextmanager
+def _bound(memory: StepMemory):
+    token = _step_memory.set(memory)
+    try:
+        yield
+    finally:
+        _step_memory.reset(token)
+
+
+def device_bytes_limit(mesh) -> int:
+    """The memory one of this process's devices of ``mesh`` says it has
+    (16,909,336,064 of a v5e chip's 16 GiB); 0 from a backend that says
+    nothing (the CPU's) and from a device this process cannot ask (a
+    described topology's, compiled for and not attached)."""
+    try:
+        stats = (mesh.local_devices or [mesh.devices.flat[0]])[
+            0].memory_stats()
+    except jax.errors.JaxRuntimeError:
+        return 0
+    return int((stats or {}).get("bytes_limit", 0))
+
+
+def state_bytes(state, shardings=None) -> int:
+    """A device's share of the bytes of ``state`` (arrays or shapes) under
+    ``shardings`` (a matching tree; None: all of every leaf)."""
+    leaves = jax.tree.leaves(state)
+    shapes = [x.shape for x in leaves] if shardings is None else [
+        sh.shard_shape(x.shape)
+        for x, sh in zip(leaves, jax.tree.leaves(shardings))]
+    return sum(math.prod(shape) * jnp.dtype(x.dtype).itemsize
+               for x, shape in zip(leaves, shapes))
 
 
 def _replicated(mesh):
@@ -143,13 +198,22 @@ def make_train_step(loss_fn: Callable, optimizer, mesh, rules: ShardingRules,
     Everything after the gradient lies in the named scope ``optimizer``
     (the rule in ``optimizer/rule``, the norm in ``optimizer/grad_norm``):
     with the model's own scopes (models/llama.py) every device op of the
-    step says which part issued it."""
+    step says which part issued it.
+
+    While the loss is traced the step's memory is bound (``step_memory``:
+    the device's limit and a device's share of ``state``, parameters AND
+    the optimizer's leaves), so the model knows the optimizer's bytes
+    without guessing the optimizer."""
     batch_sh = (batch_sharding(mesh, rules, batch_shapes)
                 if batch_shapes is not None else None)
 
     def _step(state: TrainState, batch):
+        memory = StepMemory(device_bytes_limit(mesh),
+                            state_bytes(state, state_shardings))
+
         def lf(p):
-            out = loss_fn(p, batch)     # (loss, aux) or a scalar
+            with _bound(memory):
+                out = loss_fn(p, batch)     # (loss, aux) or a scalar
             return out if isinstance(out, tuple) else (out, {})
 
         (loss, aux), grads = jax.value_and_grad(lf, has_aux=True)(

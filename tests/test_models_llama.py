@@ -470,14 +470,18 @@ def _primitives(jaxpr, into=None):
 # and replace them. (PR 37 replaced the three `flash` ones: the text holds
 # the kernels' bodies, and the forward and dQ kernels changed; outside the
 # three pallas_calls the primitive counts are the parent's, and the two
-# `xla` digests stood.)
+# `xla` digests stood. PR 43 replaced all five: `_attention_half` names q, k
+# and v for the layer checkpoint whatever the attention call, three `name`
+# equations a traced body, 2 -> 8, 0 -> 3, 7 -> 13 and 5 -> 8 in all; every
+# other primitive's count is the parent's, and with no limit nothing more is
+# kept, so the equations lower to nothing.)
 JAXPRS_FROM = "0.9.0"
 ONE_DEVICE_JAXPRS = {
-    ("dense", 2, "flash", True, "bfloat16"): "81d161390b1d3028",
-    ("dense", 4, "flash", True, "bfloat16"): "42858d9f9fec1bbf",
-    ("dense", 2, "xla", False, "float32"): "751bfcca654a5c93",
-    ("moe", 2, "flash", True, "bfloat16"): "b57a396ff26024ef",
-    ("moe", 2, "xla", False, "float32"): "8d6c8419471c919a",
+    ("dense", 2, "flash", True, "bfloat16"): "3b12ce20f8e1059f",
+    ("dense", 4, "flash", True, "bfloat16"): "4ab56e75b0de7f09",
+    ("dense", 2, "xla", False, "float32"): "d99f3888a36ad995",
+    ("moe", 2, "flash", True, "bfloat16"): "3700376b7b68b19e",
+    ("moe", 2, "xla", False, "float32"): "1981221167aeb9de",
 }
 
 

@@ -291,8 +291,10 @@ def test_train_step_hands_on_the_routing_statistics():
                            rules, state_sh)
     _, m = step(init_fn(jax.random.PRNGKey(0)), batch)
     assert set(m) == {"loss", "grad_norm", "step", "moe_aux_loss",
-                      "moe_z_loss", "moe_load_max_over_mean", "moe_dropped"}
+                      "moe_z_loss", "moe_load_max_over_mean", "moe_dropped",
+                      "moe_remat_kept_gb"}
     assert int(m["moe_dropped"]) == 0
+    assert float(m["moe_remat_kept_gb"]) == 0.0     # CFG has no checkpoint
     assert float(m["moe_load_max_over_mean"]) >= 1.0
     dcfg = llama.PRESETS["tiny"].replace(dtype=jnp.float32)
     init_fn, state_sh = make_train_state_init(

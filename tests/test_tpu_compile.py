@@ -1039,3 +1039,102 @@ def test_llama7b_fsdp_fits_v5e8_hbm(topo, no_persistent_cache):
     compiled = _lower_train_step(mesh, ShardingRules.fsdp(), 8, 2048,
                                  cfg=cfg, opt=opt).compile()
     assert compiled.memory_analysis().peak_memory_in_bytes <= V5E_HBM
+
+
+# cell -> (config module of the benchmark, its function, family): the two
+# cells whose whole step is compiled here, from the cell's own files
+_CELL_STEPS = {
+    "train-commandaplus-ep16-s8192-b1": ("model_commanda", "moe_config",
+                                         "moe"),
+    "train-granite4hs-ep8-s8192-b2": ("model_granite", "hybrid_config",
+                                      "hybrid"),
+}
+
+
+def _compile_cell_step(name, topo, monkeypatch):
+    """A one-chip cell's train step by its recipe, compiled for a described
+    v5e chip that states a v5e's limit, 16,909,336,064 (here no device
+    states one):
+    (compiled, plan bytes, the ``remat.plan`` instant's attributes)."""
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    from benchmark import resolve
+    from ray_tpu.parallel import (MeshSpec, ShardingRules, build_mesh,
+                                  train_step)
+    from ray_tpu.util import tracing
+
+    cell = resolve.cell(name)
+    recipe, mix = cell["train"], cell["mix"]
+    module, make, family = _CELL_STEPS[name]
+    cfg = getattr(importlib.import_module(f"benchmark.{module}"), make)(
+        cell["config"], **{k: recipe[k] for k in (
+            "attn_impl", "gmm_impl", "ssd_impl", "remat", "f32_logits")
+            if k in recipe})
+    fam = importlib.import_module(f"ray_tpu.models.{family}")
+    said = []
+    instant = tracing.instant
+    monkeypatch.setattr(train_step, "device_bytes_limit",
+                        lambda mesh: 16_909_336_064)
+    monkeypatch.setattr(tracing, "instant", lambda n, attrs=None, **kw: (
+        said.append((n, attrs)), instant(n, attrs, **kw))[1])
+    mesh = build_mesh(MeshSpec(**recipe["mesh"]), devices=topo.devices[:1])
+    rules, opt = getattr(ShardingRules, recipe["rules"])(), optax.adafactor(
+        recipe["lr"])
+    init_fn, state_sh = train_step.make_train_state_init(
+        lambda k: fam.init_params(k, cfg), opt, mesh, rules,
+        fam.param_specs(cfg))
+    state = _with_shardings(
+        jax.eval_shape(init_fn, jax.random.PRNGKey(0)), state_sh)
+    shape = {"tokens": jax.ShapeDtypeStruct(
+        (mix["batch"], mix["seq"] + 1), jnp.int32)}
+    batch = _with_shardings(shape,
+                            train_step.batch_sharding(mesh, rules, shape))
+    compiled = train_step.make_train_step(
+        lambda p, b: fam.loss_fn(p, b, cfg, mesh=mesh, rules=rules), opt,
+        mesh, rules, state_sh, batch_shapes=shape).lower(
+            state, batch).compile()
+    mem = compiled.memory_analysis()
+    return compiled, int(
+        mem.argument_size_in_bytes + mem.temp_size_in_bytes
+        + mem.output_size_in_bytes - mem.alias_size_in_bytes), [
+            a for n, a in said if n == "remat.plan"]
+
+
+def test_command_a_plus_step_keeps_its_names_and_fits(topo, on_chip_branch,
+                                                      monkeypatch):
+    """The Command A+ cell's step with the names its plan keeps (all five:
+    q, k, v, the shared SwiGLU's gate and up; 2.45e9 bytes over four
+    layers): the plan stays under 12.0e9 (9,282,964,480 with none kept;
+    10,938,735,616 when this was written), XLA rematerializes nothing of
+    its own, and no checkpoint body computes a shared product again (the
+    parent's held 8: gate and up, a layer)."""
+    compiled, plan, said = _compile_cell_step(
+        "train-commandaplus-ep16-s8192-b1", topo, monkeypatch)
+    assert [(p["kept"], p["kept_bytes"], p["why"]) for p in said] == [
+        ("attn_q,attn_k,attn_v,shared_gate,shared_up", 2_449_473_536,
+         "room")]
+    assert 9.3e9 < plan <= 12.0e9, plan
+    text = compiled.as_text()
+    assert text.count(".remat") == 0
+    assert text.count("tpu_custom_call") == 100
+    replayed = [ln for ln in text.splitlines() if "rematted_computation/"
+                "feed_forward/shared/dot_general" in ln]
+    assert not replayed, replayed[:2]
+    assert "checkpoint/feed_forward/shared/dot_general" in text
+
+
+def test_granite_step_keeps_the_parents_list(topo, on_chip_branch,
+                                             monkeypatch):
+    """The Granite cell's step has no room: the plan keeps nothing more,
+    the program plans what the parent's did (15,310,881,280 bytes at PR
+    42) and XLA rematerializes nothing of its own."""
+    compiled, plan, said = _compile_cell_step(
+        "train-granite4hs-ep8-s8192-b2", topo, monkeypatch)
+    assert [(p["kept"], p["kept_bytes"], p["why"]) for p in said] == [
+        ("", 0, "no room")]
+    assert abs(plan - 15_310_881_280) < 1e6, plan
+    assert compiled.as_text().count(".remat") == 0
