@@ -108,7 +108,7 @@ class LlamaConfig:
     # llama's: rows of the embedding as they are, a half-block added to x
     # as it is, logits undivided, scores times head_dim ** -0.5. Read by
     # forward_with_stats and the layer under it; the cached and paged
-    # paths know none of them and refuse such a config (init_cache).
+    # forwards (models/cached.py) refuse such a config (init_cache).
     embedding_multiplier: Any = None
     residual_multiplier: Any = None
     logits_scaling: Any = None
@@ -671,19 +671,27 @@ def _attention(q, k, v, cfg: LlamaConfig, causal=True, q_offset=0,
     return _attention_xla(q, k, v, causal, q_offset, window=win, scale=scale)
 
 
-def _attention_half(x, lp, cfg: LlamaConfig, cos, sin, cache=None, mesh=None,
-                    rules=None, tp=None, kind=None, normed=None):
-    """The attention half of a block: x [B, S, D] -> (x + attention, k, v,
-    new_cache). cache: (k, v, offset) or None. A parallel block hands its
-    one normed input as ``normed``: the half then norms nothing and adds
-    nothing, its first result is the attention's output alone. ``kind``
-    names the layer's kind where the config has several
-    (``attention_kind``: its window;
-    ``cos`` and ``sin`` are its tables). With ``cfg.qk_norm`` (an
-    OLMoE block) q and k pass an RMS norm over the WHOLE projected vector,
-    one learned scale each (``q_norm``, ``k_norm``), before the split into
-    heads. Without rotary tables (``cos`` None: a model with no position
-    embedding) q and k go to the kernel as they are; a config's
+def _project(h, lp, cfg: LlamaConfig, w: str, n: int, norm=None):
+    """One of a block's three projections of its normed input h [B, S, D],
+    split into its n heads: [B, S, n, HD]. With ``cfg.qk_norm`` (an OLMoE
+    block) q and k pass an RMS norm over the WHOLE projected vector, one
+    learned scale each (``norm``: its name), before the split."""
+    y = h @ _dq(lp[w], cfg.dtype)
+    if norm is not None and getattr(cfg, "qk_norm", False):
+        y = rms_norm(y, lp[norm], cfg.norm_eps)
+    return y.reshape(*h.shape[:2], n, cfg.head_dim)
+
+
+def _attention_half(x, lp, cfg: LlamaConfig, cos, sin, mesh=None, rules=None,
+                    tp=None, kind=None, normed=None):
+    """The attention half of a block: x [B, S, D] -> x + attention. A
+    parallel block hands its one normed input as ``normed``: the half then
+    norms nothing and adds nothing, its result is the attention's output
+    alone. ``kind`` names the layer's kind where the config has several
+    (``attention_kind``: its window; ``cos`` and ``sin`` are its tables).
+    q and k are normed before the split where the config says
+    (``_project``). Without rotary tables (``cos`` None: a model with no
+    position embedding) q and k go to the kernel as they are; a config's
     ``attn_scale`` replaces the softmax's head_dim ** -0.5 and its
     ``residual_multiplier`` scales what is added to x.
     mesh+rules reach the flash kernel's shard_map (_flash_sharded).
@@ -697,15 +705,10 @@ def _attention_half(x, lp, cfg: LlamaConfig, cos, sin, cache=None, mesh=None,
 
     h = _norm(x, lp["attn_norm"], cfg) if normed is None else normed
 
-    def heads(y, n, norm=None):
-        if norm is not None and getattr(cfg, "qk_norm", False):
-            y = rms_norm(y, lp[norm], cfg.norm_eps)
-        return y.reshape(B, S, n, HD)
-
     if tp is None:
-        q = heads(h @ _dq(lp["wq"], dt), H, "q_norm")
-        k = heads(h @ _dq(lp["wk"], dt), KV, "k_norm")
-        v = heads(h @ _dq(lp["wv"], dt), KV)
+        q = _project(h, lp, cfg, "wq", H, "q_norm")
+        k = _project(h, lp, cfg, "wk", KV, "k_norm")
+        v = _project(h, lp, cfg, "wv", KV)
         if cos is not None:
             q = apply_rope(q, cos, sin)
             k = apply_rope(k, cos, sin)
@@ -728,29 +731,14 @@ def _attention_half(x, lp, cfg: LlamaConfig, cos, sin, cache=None, mesh=None,
             h, [_dq(lp[w], dt) for w in ("wq", "wk", "wv")], tp,
             then=in_heads, extras=(cos, sin))
 
-    new_cache = None
-    if cache is not None:
-        ck, cv, offset = cache
-        ck = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype),
-                                          (0, offset, 0, 0))
-        cv = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype),
-                                          (0, offset, 0, 0))
-        kk, vv = ck.astype(dt), cv.astype(dt)
-        # mask out cache slots beyond offset+S via causal offset
-        attn = _attention(q, kk, vv, cfg, causal=True, q_offset=offset,
-                          kind=kind)
-        new_cache = (ck, cv)
-    else:
-        attn = _attention(q, k, v, cfg, causal=True, mesh=mesh, rules=rules,
-                          kind=kind)
+    attn = _attention(q, k, v, cfg, causal=True, mesh=mesh, rules=rules,
+                      kind=kind)
     attn = attn.reshape(B, S, H * HD)
     if tp is not None:
         out = matmul_reduce_scatter(attn, _dq(lp["wo"], dt), tp)
     else:
         out = attn @ _dq(lp["wo"], dt)
-    if normed is not None:
-        return out, k, v, new_cache
-    return _residual(x, out, cfg), k, v, new_cache
+    return out if normed is not None else _residual(x, out, cfg)
 
 
 def _residual(x, y, cfg: LlamaConfig):
@@ -799,13 +787,11 @@ def _takes_attention_half(cfg: LlamaConfig, kind) -> bool:
             and (kind in (None, "attention") or kind in dict(cfg.attn_kinds)))
 
 
-def _layer(x, lp, cfg: LlamaConfig, cos, sin, cache=None, collect_kv=False,
-           mesh=None, rules=None, tp=None, kind=None):
+def _layer(x, lp, cfg: LlamaConfig, cos, sin, mesh=None, rules=None, tp=None,
+           kind=None):
     """One transformer block: the attention half, then the family's
     feed-forward half (dense SwiGLU here, the expert layer for a
-    MoEConfig). x: [B, S, D]. Returns (x, kv, stats): kv is the updated
-    (k, v) cache slices when ``cache`` is given, this layer's (k, v) with
-    collect_kv=True (cache seeding), else None; stats is what the
+    MoEConfig). x: [B, S, D]. Returns (x, stats): stats is what the
     feed-forward reports (None for the dense one). ``kind``: None or
     "attention" for the attention half, or a kind of attention layer the
     config names (``attn_kinds``: the same half with that kind's window,
@@ -817,37 +803,31 @@ def _layer(x, lp, cfg: LlamaConfig, cos, sin, cache=None, collect_kv=False,
     ``parallel_block`` runs the same two halves side by side from one norm
     (``_parallel_layer``)."""
     if cfg.parallel_block:
-        return _parallel_layer(x, lp, cfg, cos, sin, cache, collect_kv, mesh,
-                               rules, tp, kind)
+        return _parallel_layer(x, lp, cfg, cos, sin, mesh, rules, tp, kind)
     own = getattr(_family(cfg), "attention_half", None)
     named = kind in dict(cfg.attn_kinds)
     if own is not None:
-        assert cache is None and not collect_kv and tp is None, kind
+        assert tp is None, kind
         with jax.named_scope("attention"):
-            x, k, v, new_cache = own(x, lp, cfg, cos, sin, mesh=mesh,
-                                     rules=rules), None, None, None
+            x = own(x, lp, cfg, cos, sin, mesh=mesh, rules=rules)
     elif _takes_attention_half(cfg, kind):
         # a trace tells the kinds apart by the inner scope, with no shape
         with jax.named_scope("attention"), \
                 jax.named_scope(kind) if named else contextlib.nullcontext():
-            x, k, v, new_cache = _attention_half(
-                x, lp, cfg, cos, sin, cache=cache, mesh=mesh, rules=rules,
-                tp=tp, kind=kind)
+            x = _attention_half(x, lp, cfg, cos, sin, mesh=mesh, rules=rules,
+                                tp=tp, kind=kind)
     else:
-        assert cache is None and not collect_kv, kind
         with jax.named_scope("mixer"):
-            x, k, v, new_cache = _family(cfg).mixer_half(
-                x, lp, cfg, kind, mesh=mesh), None, None, None
+            x = _family(cfg).mixer_half(x, lp, cfg, kind, mesh=mesh)
     with jax.named_scope("feed_forward"):
         h = _norm(x, lp["ffn_norm"], cfg)
         y, stats = _family(cfg).feed_forward(h, lp, cfg, mesh=mesh,
                                              rules=rules, tp=tp, kind=kind)
         x = _residual(x, y, cfg)
-    return x, ((k, v) if collect_kv else new_cache), stats
+    return x, stats
 
 
-def _parallel_layer(x, lp, cfg: LlamaConfig, cos, sin, cache, collect_kv,
-                    mesh, rules, tp, kind):
+def _parallel_layer(x, lp, cfg: LlamaConfig, cos, sin, mesh, rules, tp, kind):
     """``_layer`` for a parallel block (``cfg.parallel_block``): ONE norm
     (``attn_norm``) feeds the attention half and the family's feed-forward
     side by side, neither reads the other's result, and the layer is x +
@@ -861,15 +841,14 @@ def _parallel_layer(x, lp, cfg: LlamaConfig, cos, sin, cache, collect_kv,
         n = _norm(x, lp["attn_norm"], cfg)
     with jax.named_scope("attention"), jax.named_scope(kind) \
             if kind in dict(cfg.attn_kinds) else contextlib.nullcontext():
-        a, k, v, new_cache = _attention_half(
-            x, lp, cfg, cos, sin, cache=cache, mesh=mesh, rules=rules, tp=tp,
-            kind=kind, normed=n)
+        a = _attention_half(x, lp, cfg, cos, sin, mesh=mesh, rules=rules,
+                            tp=tp, kind=kind, normed=n)
     with jax.named_scope("feed_forward"):
         y, stats = _family(cfg).feed_forward(n, lp, cfg, mesh=mesh,
                                              rules=rules, tp=tp, kind=kind)
     with jax.named_scope("block"):
         x = _residual(_residual(x, a, cfg), y, cfg)
-    return x, ((k, v) if collect_kv else new_cache), stats
+    return x, stats
 
 
 def _tp_plan(cfg: LlamaConfig, mesh, rules, batch: int, seq: int):
@@ -1036,8 +1015,8 @@ def _forward(params, tokens, cfg: LlamaConfig, pos_offset=0, mesh=None,
         cos, sin = tables_of(kind)
 
         def body(x, lp):
-            y, _, stats = _layer(x, lp, cfg, cos, sin, mesh=mesh, rules=rules,
-                                 tp=tp, kind=kind)
+            y, stats = _layer(x, lp, cfg, cos, sin, mesh=mesh, rules=rules,
+                              tp=tp, kind=kind)
             return con(y), stats
 
         return _checkpoint(body, cfg, plan.kept) if cfg.remat else body
@@ -1125,9 +1104,7 @@ def forward_pp(params, tokens, cfg: LlamaConfig, mesh, num_microbatches=None):
     stacked = stack_stages(params["layers"], pp)
     trunk = pipeline_trunk(stage_fn, mesh, M, schedule=cfg.pp_schedule)
     x = trunk(stacked, x)
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = x @ _dq(params["lm_head"], dt)
-    return logits.astype(jnp.float32) if cfg.f32_logits else logits
+    return _logits(params, _norm(x, params["final_norm"], cfg), cfg)
 
 
 def loss_fn(params, batch, cfg: LlamaConfig, mesh=None, rules=None):
@@ -1202,505 +1179,3 @@ def cross_entropy(logits, targets, mask=None):
         return nll.mean()
     mask = mask.astype(nll.dtype)
     return (nll * mask).sum() / jnp.maximum(mask.sum(), 1.0)
-
-
-# --- inference (KV cache) ---------------------------------------------------
-
-
-class KVCache(NamedTuple):
-    k: jax.Array        # [L, B, max_seq, KV, HD]
-    v: jax.Array
-    length: jax.Array   # [B] int32 — per-sequence filled length
-
-
-def _refuse_stated(cfg: LlamaConfig):
-    """The cached and paged paths embed, rotate, scale and add as llama
-    does: a config that states otherwise would be served as another model."""
-    stated = [f for f in ("embedding_multiplier", "residual_multiplier",
-                          "logits_scaling", "attn_scale")
-              if getattr(cfg, f) is not None] + ([] if cfg.rope else ["rope"])
-    if getattr(_family(cfg), "attention_half", None) is not None:
-        stated.append("an attention half of its own")
-    if cfg.attn_kinds:
-        stated.append("attention layers of several kinds")
-    if cfg.parallel_block:
-        stated.append("a parallel block")
-    if cfg.norm != "rms":
-        stated.append(f"a {cfg.norm} norm")
-    if stated:
-        raise NotImplementedError(
-            f"a KV cache for a config that states {', '.join(stated)}: the "
-            "cached paths apply none of them (forward_with_stats does)")
-
-
-def init_cache(cfg: LlamaConfig, batch: int, max_seq: Optional[int] = None,
-               dtype=None) -> KVCache:
-    _refuse_stated(cfg)
-    S = max_seq or cfg.max_seq_len
-    shape = (cfg.n_layers, batch, S, cfg.n_kv_heads, cfg.head_dim)
-    dt = dtype or cfg.dtype
-    return KVCache(jnp.zeros(shape, dt), jnp.zeros(shape, dt),
-                   jnp.zeros((batch,), jnp.int32))
-
-
-def cache_specs(cfg: LlamaConfig):
-    return KVCache(("layers", None, None, "kv_heads", "head_dim"),
-                   ("layers", None, None, "kv_heads", "head_dim"),
-                   (None,))
-
-
-def _layer_scan_with_kv(body, x, a_all, b_all, layers):
-    """lax.scan over stacked per-layer inputs with two stacked KV
-    buffers ([L, ...]) kept in the CARRY, each layer's slice read and
-    written back in place via dynamic_(index|update_index)_in_dim.
-
-    This is the memory shape every cached forward uses: passing the
-    buffers as scan xs with restacked ys makes XLA materialize a second
-    full-size copy (and the layout-assignment copies that follow), which
-    at 2.7B+ pools/caches is multiple GB of HBM temp — enough that the
-    decode program alone exceeded the 16 GB chip before this form.
-
-    body(x, layer_xs, a_slice, b_slice) -> (x, new_a_slice, new_b_slice)
-    """
-    def wrap(carry, lx):
-        x, a_all, b_all, li = carry
-        a = jax.lax.dynamic_index_in_dim(a_all, li, 0, keepdims=False)
-        b = jax.lax.dynamic_index_in_dim(b_all, li, 0, keepdims=False)
-        x, a, b = body(x, lx, a, b)
-        a_all = jax.lax.dynamic_update_index_in_dim(a_all, a, li, 0)
-        b_all = jax.lax.dynamic_update_index_in_dim(b_all, b, li, 0)
-        return (x, a_all, b_all, li + 1), None
-
-    (x, a_all, b_all, _), _ = jax.lax.scan(
-        wrap, (x, a_all, b_all, jnp.int32(0)), layers)
-    return x, a_all, b_all
-
-
-def prefill(params, tokens, lengths, cfg: LlamaConfig):
-    """Batched prefill for the continuous-batching engine. tokens [n, P]
-    right-padded; lengths [n] true lengths. Returns (logits_at_last [n, V],
-    k_layers [L, n, P, KV, HD], v_layers). Pad positions produce garbage
-    k/v but are never attended later (decode masks kpos < length and new
-    tokens overwrite pad slots)."""
-    dt = cfg.dtype
-    B, P = tokens.shape
-    x = _embed(params, tokens, dt)
-    cos, sin = _rope_tables(cfg.rope_theta, P, cfg.head_dim)
-
-    def body(x, lp):
-        y, kv, _ = _layer(x, lp, cfg, cos, sin, collect_kv=True)
-        return y, kv
-
-    x, (ks, vs) = jax.lax.scan(body, x, params["layers"])
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    # logits at each row's final REAL position
-    idx = jnp.clip(lengths - 1, 0, P - 1)
-    last = jnp.take_along_axis(x, idx[:, None, None], axis=1)[:, 0]
-    logits = last @ _dq(params["lm_head"], dt)
-    return logits.astype(jnp.float32), ks, vs
-
-
-def decode_step(params, tokens, cache: KVCache, cfg: LlamaConfig,
-                active=None) -> Tuple[jax.Array, KVCache]:
-    """One continuous-batching decode step with PER-ROW positions.
-    tokens [B, 1]; cache.length [B] gives each row's write position; rows
-    where active==0 keep their cache untouched. Returns (logits [B, V],
-    updated cache)."""
-    dt = cfg.dtype
-    B = tokens.shape[0]
-    H, KV, HD = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    pos = cache.length                                    # [B]
-    if active is None:
-        active = jnp.ones((B,), jnp.int32)
-
-    cos_full, sin_full = _rope_tables(cfg.rope_theta, cfg.max_seq_len,
-                                      cfg.head_dim)
-    cos = cos_full[pos][:, None, :]                       # [B, 1, HD/2]
-    sin = sin_full[pos][:, None, :]
-
-    def rope1(x):  # x: [B, 1, N, HD] with per-row tables
-        x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-        c = cos[:, :, None, :]
-        s = sin[:, :, None, :]
-        return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s],
-                               axis=-1).astype(x.dtype)
-
-    x = _embed(params, tokens, dt)                # [B, 1, D]
-    S = cache.k.shape[2]
-    kpos = jnp.arange(S)[None, :]                         # [1, S]
-    attn_mask = (kpos <= pos[:, None]) & (active[:, None] > 0)  # [B, S]
-    if cfg.sliding_window is not None:
-        # banded decode matches banded training: only the last W cached
-        # keys are visible (cache layout unchanged)
-        attn_mask = attn_mask & (pos[:, None] - kpos < cfg.sliding_window)
-
-    def body(x, lp, ck, cv):
-        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        q = rope1((h @ _dq(lp["wq"], dt)).reshape(B, 1, H, HD))
-        k = rope1((h @ _dq(lp["wk"], dt)).reshape(B, 1, KV, HD))
-        v = (h @ _dq(lp["wv"], dt)).reshape(B, 1, KV, HD)
-        # Unconditional one-position write per row; inactive rows write
-        # back the value already there. A vmapped lax.cond would lower to
-        # SELECTs over the whole [S, KV, HD] cache per row (both branches
-        # materialized) — this form touches O(KV*HD) per row instead.
-        def write_at(c, new, p, a):
-            old = jax.lax.dynamic_slice(c, (p, 0, 0), new.shape)
-            val = jnp.where(a > 0, new, old)
-            return jax.lax.dynamic_update_slice(c, val, (p, 0, 0))
-
-        upd = jax.vmap(write_at)(ck, k.astype(ck.dtype)[:, 0][:, None],
-                                 pos, active)
-        vpd = jax.vmap(write_at)(cv, v.astype(cv.dtype)[:, 0][:, None],
-                                 pos, active)
-        kk = upd.astype(dt)                                # [B, S, KV, HD]
-        vv = vpd.astype(dt)
-        # scores: q [B,1,H,HD] x kk [B,S,KV,HD], GQA groups
-        G = H // KV
-        q5 = q.reshape(B, 1, KV, G, HD)
-        s = jnp.einsum("bqkgd,bskd->bkgqs", q5, kk,
-                       preferred_element_type=jnp.float32) / (HD ** 0.5)
-        s = jnp.where(attn_mask[:, None, None, None, :], s, -1e30)
-        p = jax.nn.softmax(s, axis=-1).astype(dt)
-        o = jnp.einsum("bkgqs,bskd->bqkgd", p, vv).reshape(B, 1, H * HD)
-        x = x + o @ _dq(lp["wo"], dt)
-        h = rms_norm(x, lp["ffn_norm"], cfg.norm_eps)
-        gate = jax.nn.silu(h @ _dq(lp["w_gate"], dt))
-        up = h @ _dq(lp["w_up"], dt)
-        x = x + (gate * up) @ _dq(lp["w_down"], dt)
-        return x, upd, vpd
-
-    x, nk, nv = _layer_scan_with_kv(body, x, cache.k, cache.v,
-                                    params["layers"])
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = (x[:, 0] @ _dq(params["lm_head"], dt)).astype(jnp.float32)
-    new_len = cache.length + active
-    return logits, KVCache(nk, nv, new_len)
-
-
-def init_paged_cache(cfg: LlamaConfig, num_pages: int, page_size: int,
-                     dtype=None):
-    """Paged KV pools [L, KV, num_pages, page_size, HD] (SURVEY §7.9 /
-    ops/paged_attention.py layout; page 0 is the trash page inactive
-    slots write into). HBM scales with pages, not slots*max_seq."""
-    _refuse_stated(cfg)
-    dt = dtype or cfg.dtype
-    shape = (cfg.n_layers, cfg.n_kv_heads, num_pages, page_size,
-             cfg.head_dim)
-    return jnp.zeros(shape, dt), jnp.zeros(shape, dt)
-
-
-def decode_step_paged(params, tokens, k_pools, v_pools, page_table,
-                      lengths, cfg: LlamaConfig, active=None
-                      ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
-    """One continuous-batching decode step over a PAGED KV cache.
-    tokens [S, 1]; k_pools/v_pools [L, KV, NP, ps, HD]; page_table
-    [S, maxP]; lengths [S] = tokens already stored per slot. Returns
-    (logits [S, V], new k_pools, new v_pools, new lengths). Rows with
-    active==0 skip the KV write entirely and keep length (only the
-    kernel's unwritten-window flush may touch the reserved trash page
-    0). Write+attend is ops/paged_attention.py's fused Pallas kernel
-    (XLA scatter+gather reference off-TPU)."""
-    from ray_tpu.ops.paged_attention import paged_decode_attention_inplace
-
-    if cfg.sliding_window is not None:
-        raise ValueError("paged decode does not support sliding_window")
-    dt = cfg.dtype
-    S = tokens.shape[0]
-    H, KV, HD = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    ps = k_pools.shape[3]
-    if active is None:
-        active = jnp.ones((S,), jnp.int32)
-    pos = lengths                                          # write position
-    cos_full, sin_full = _rope_tables(cfg.rope_theta, cfg.max_seq_len,
-                                      cfg.head_dim)
-    cos = cos_full[pos][:, None, :]
-    sin = sin_full[pos][:, None, :]
-
-    def rope1(x):  # [S, 1, N, HD] with per-row tables
-        x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-        c = cos[:, :, None, :]
-        s = sin[:, :, None, :]
-        return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s],
-                               axis=-1).astype(x.dtype)
-
-    # the fused kernel derives each slot's tip page/offset from attn_len;
-    # inactive rows (attn_len 0) skip the write entirely
-    attn_len = jnp.where(active > 0, pos + 1, 0)
-
-    x = _embed(params, tokens, dt)                 # [S, 1, D]
-
-    # Pools ride the scan CARRY; the new token's k/v write happens INSIDE
-    # the fused Pallas kernel through pool-aliased outputs (see
-    # ops/paged_attention.py paged_decode_attention_inplace). The earlier
-    # forms — pools-as-xs with restacked ys, or an XLA scatter per layer —
-    # each materialized extra full-pool copies (the scatter's KV-minor
-    # layout preference alone cost two +3 GB layout copies at 2.7B, and
-    # the decode program exceeded the 16 GB chip).
-    def body(x, lp, kp, vp):
-        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        q = rope1((h @ _dq(lp["wq"], dt)).reshape(S, 1, H, HD))
-        k = rope1((h @ _dq(lp["wk"], dt)).reshape(S, 1, KV, HD))
-        v = (h @ _dq(lp["wv"], dt)).reshape(S, 1, KV, HD)
-        o, kp, vp = paged_decode_attention_inplace(
-            q[:, 0].astype(dt), k[:, 0].astype(kp.dtype),
-            v[:, 0].astype(vp.dtype), kp, vp, page_table, attn_len)
-        # fully-masked (inactive) rows return garbage — zero them
-        o = jnp.where((active > 0)[:, None, None], o, 0.0)
-        x = x + o.reshape(S, 1, H * HD) @ _dq(lp["wo"], dt)
-        h = rms_norm(x, lp["ffn_norm"], cfg.norm_eps)
-        gate = jax.nn.silu(h @ _dq(lp["w_gate"], dt))
-        up = h @ _dq(lp["w_up"], dt)
-        x = x + (gate * up) @ _dq(lp["w_down"], dt)
-        return x, kp, vp
-
-    x, nk, nv = _layer_scan_with_kv(body, x, k_pools, v_pools,
-                                    params["layers"])
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = (x[:, 0] @ _dq(params["lm_head"], dt)).astype(jnp.float32)
-    return logits, nk, nv, lengths + active
-
-
-def prefill_paged_tail(params, tokens, tail_len, prefix_len, page_table,
-                       k_pools, v_pools, cfg: LlamaConfig
-                       ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Chunked prefill of a prompt TAIL against existing paged prefix KV
-    (the compute half of automatic prefix caching — ref: vLLM's chunked
-    prefill with prefix blocks). tokens [B, T] right-padded tail tokens;
-    tail_len [B] true tail lengths; prefix_len [B] tokens already in the
-    pages; page_table [B, maxP]. Writes the tail's KV into the pages and
-    returns (logits at each row's final tail token [B, V], k_pools,
-    v_pools). Cost O(T * (prefix+T)) instead of the full O((prefix+T)^2)
-    re-prefill — and ONE device call instead of T decode steps."""
-    dt = cfg.dtype
-    B, T = tokens.shape
-    H, KV, HD = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    ps = k_pools.shape[3]
-    maxP = page_table.shape[1]
-    S_view = maxP * ps
-    grp = H // KV
-
-    # absolute positions of the tail tokens, per row
-    qpos = prefix_len[:, None] + jnp.arange(T)[None, :]          # [B, T]
-    valid = (jnp.arange(T)[None, :] < tail_len[:, None])         # [B, T]
-    cos_full, sin_full = _rope_tables(cfg.rope_theta, cfg.max_seq_len,
-                                      cfg.head_dim)
-    safe_pos = jnp.minimum(qpos, cfg.max_seq_len - 1)
-    cos = cos_full[safe_pos]                                     # [B, T, HD/2]
-    sin = sin_full[safe_pos]
-
-    def rope(x):   # [B, T, N, HD]
-        x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-        c = cos[:, :, None, :]
-        s = sin[:, :, None, :]
-        return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s],
-                               axis=-1).astype(x.dtype)
-
-    # physical write targets; padded rows land in trash page 0
-    page_ids = jnp.take_along_axis(page_table, qpos // ps, axis=1)  # [B, T]
-    page_ids = jnp.where(valid, page_ids, 0)
-    offsets = qpos % ps
-    pid_f = page_ids.reshape(-1)
-    off_f = offsets.reshape(-1)
-
-    # attention mask over the gathered page view [B, S_view]: causal
-    # against absolute key position, bounded by each row's total length
-    kv_pos = jnp.arange(S_view)[None, :]                         # [1, S_view]
-    total = (prefix_len + tail_len)[:, None]
-    base_mask = kv_pos < total                                   # [B, S_view]
-    causal = kv_pos[:, None, :] <= qpos[:, :, None]              # [B, T, S_view]
-    mask = base_mask[:, None, :] & causal                        # [B, T, S_view]
-
-    x = _embed(params, tokens, dt)                       # [B, T, D]
-
-    def body(x, lp, kp, vp):
-        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        q = rope((h @ _dq(lp["wq"], dt)).reshape(B, T, H, HD))
-        k = rope((h @ _dq(lp["wk"], dt)).reshape(B, T, KV, HD))
-        v = (h @ _dq(lp["wv"], dt)).reshape(B, T, KV, HD)
-        # write tail KV FIRST: the gathered view then covers prefix+tail
-        # and one causal mask handles both
-        k_f = k.reshape(B * T, KV, HD).transpose(1, 0, 2)
-        v_f = v.reshape(B * T, KV, HD).transpose(1, 0, 2)
-        kp = kp.at[:, pid_f, off_f, :].set(k_f.astype(kp.dtype))
-        vp = vp.at[:, pid_f, off_f, :].set(v_f.astype(vp.dtype))
-        # gather each row's pages into a contiguous [S_view] key space
-        kg = jnp.take(kp, page_table, axis=1)         # [KV, B, maxP, ps, HD]
-        vg = jnp.take(vp, page_table, axis=1)
-        kg = kg.transpose(1, 0, 2, 3, 4).reshape(B, KV, S_view, HD)
-        vg = vg.transpose(1, 0, 2, 3, 4).reshape(B, KV, S_view, HD)
-        kg = jnp.repeat(kg, grp, axis=1)              # GQA -> [B, H, S, HD]
-        vg = jnp.repeat(vg, grp, axis=1)
-        qh = q.transpose(0, 2, 1, 3)                  # [B, H, T, HD]
-        scores = jnp.einsum("bhtd,bhsd->bhts", qh.astype(jnp.float32),
-                            kg.astype(jnp.float32)) / (HD ** 0.5)
-        scores = jnp.where(mask[:, None, :, :], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1)
-        o = jnp.einsum("bhts,bhsd->bhtd", probs,
-                       vg.astype(jnp.float32)).astype(dt)
-        o = o.transpose(0, 2, 1, 3).reshape(B, T, H * HD)
-        x = x + o @ _dq(lp["wo"], dt)
-        h = rms_norm(x, lp["ffn_norm"], cfg.norm_eps)
-        gate = jax.nn.silu(h @ _dq(lp["w_gate"], dt))
-        up = h @ _dq(lp["w_up"], dt)
-        x = x + (gate * up) @ _dq(lp["w_down"], dt)
-        return x, kp, vp
-
-    x, nk, nv = _layer_scan_with_kv(body, x, k_pools, v_pools,
-                                    params["layers"])
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    idx = jnp.clip(tail_len - 1, 0, T - 1)
-    last = jnp.take_along_axis(x, idx[:, None, None], axis=1)[:, 0]
-    logits = (last @ _dq(params["lm_head"], dt)).astype(jnp.float32)
-    return logits, nk, nv
-
-
-def prefill_tail_contiguous(params, tokens, tail_len, prefix_len,
-                            cache: KVCache, slot_ids, cfg: LlamaConfig
-                            ) -> Tuple[jax.Array, KVCache]:
-    """Chunked prefill of a prompt segment into CONTIGUOUS cache rows —
-    the contiguous-layout twin of prefill_paged_tail, so both KV layouts
-    share the chunked-prefill admission path (ref: vLLM chunked prefill;
-    the reference has no native engine, its serve layer delegates to user
-    code). tokens [B, T] right-padded; tail_len [B] true chunk lengths;
-    prefix_len [B] tokens already in each row; slot_ids [B] DISTINCT cache
-    rows (duplicates would make scatter order undefined). Writes the
-    chunk's KV at positions prefix..prefix+tail of each slot row, attends
-    causally over the row's full filled length, and returns (logits at
-    each row's final chunk token [B, V], cache with length[slot] advanced
-    to prefix+tail for rows with tail_len>0). Cost O(T * S) attention per
-    chunk instead of the O(S^2) full re-prefill."""
-    dt = cfg.dtype
-    B, T = tokens.shape
-    H, KV, HD = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    S = cache.k.shape[2]
-    grp = H // KV
-
-    qpos = prefix_len[:, None] + jnp.arange(T)[None, :]          # [B, T]
-    valid = jnp.arange(T)[None, :] < tail_len[:, None]           # [B, T]
-    safe_q = jnp.minimum(qpos, S - 1)
-    cos_full, sin_full = _rope_tables(cfg.rope_theta, cfg.max_seq_len,
-                                      cfg.head_dim)
-    safe_pos = jnp.minimum(qpos, cfg.max_seq_len - 1)
-    cos = cos_full[safe_pos]                                     # [B, T, HD/2]
-    sin = sin_full[safe_pos]
-
-    def rope(x):   # [B, T, N, HD]
-        x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-        c = cos[:, :, None, :]
-        s = sin[:, :, None, :]
-        return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s],
-                               axis=-1).astype(x.dtype)
-
-    kv_pos = jnp.arange(S)[None, :]                              # [1, S]
-    total = (prefix_len + tail_len)[:, None]
-    mask = (kv_pos < total)[:, None, :] & \
-        (kv_pos[:, None, :] <= qpos[:, :, None])                 # [B, T, S]
-    if cfg.sliding_window is not None:
-        mask = mask & (qpos[:, :, None] - kv_pos[:, None, :]
-                       < cfg.sliding_window)
-
-    x = _embed(params, tokens, dt)                       # [B, T, D]
-
-    def body(x, lp, ck, cv):
-        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        q = rope((h @ _dq(lp["wq"], dt)).reshape(B, T, H, HD))
-        k = rope((h @ _dq(lp["wk"], dt)).reshape(B, T, KV, HD))
-        v = (h @ _dq(lp["wv"], dt)).reshape(B, T, KV, HD)
-        # masked scatter: pad positions write back what is already there
-        # (their safe_q indices all clamp to S-1, and last-write order is
-        # undefined for duplicates — writing the old value makes any
-        # order a no-op)
-        old_k = ck[slot_ids[:, None], safe_q]                    # [B, T, KV, HD]
-        old_v = cv[slot_ids[:, None], safe_q]
-        kw = jnp.where(valid[..., None, None], k.astype(ck.dtype), old_k)
-        vw = jnp.where(valid[..., None, None], v.astype(cv.dtype), old_v)
-        ck = ck.at[slot_ids[:, None], safe_q].set(kw)
-        cv = cv.at[slot_ids[:, None], safe_q].set(vw)
-        kg = jnp.repeat(ck[slot_ids].transpose(0, 2, 1, 3), grp, axis=1)
-        vg = jnp.repeat(cv[slot_ids].transpose(0, 2, 1, 3), grp, axis=1)
-        qh = q.transpose(0, 2, 1, 3)                             # [B, H, T, HD]
-        scores = jnp.einsum("bhtd,bhsd->bhts", qh.astype(jnp.float32),
-                            kg.astype(jnp.float32)) / (HD ** 0.5)
-        scores = jnp.where(mask[:, None, :, :], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1)
-        o = jnp.einsum("bhts,bhsd->bhtd", probs,
-                       vg.astype(jnp.float32)).astype(dt)
-        o = o.transpose(0, 2, 1, 3).reshape(B, T, H * HD)
-        x = x + o @ _dq(lp["wo"], dt)
-        h = rms_norm(x, lp["ffn_norm"], cfg.norm_eps)
-        gate = jax.nn.silu(h @ _dq(lp["w_gate"], dt))
-        up = h @ _dq(lp["w_up"], dt)
-        x = x + (gate * up) @ _dq(lp["w_down"], dt)
-        return x, ck, cv
-
-    x, nk, nv = _layer_scan_with_kv(body, x, cache.k, cache.v,
-                                    params["layers"])
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    idx = jnp.clip(tail_len - 1, 0, T - 1)
-    last = jnp.take_along_axis(x, idx[:, None, None], axis=1)[:, 0]
-    logits = (last @ _dq(params["lm_head"], dt)).astype(jnp.float32)
-    old_len = cache.length[slot_ids]
-    new_len = jnp.where(tail_len > 0,
-                        (prefix_len + tail_len).astype(old_len.dtype),
-                        old_len)
-    length = cache.length.at[slot_ids].set(new_len)
-    return logits, KVCache(nk, nv, length)
-
-
-def scatter_prefill_pages(k_pools, v_pools, ks, vs, page_table, slots,
-                          lengths, page_size: int):
-    """Write prefill k/v into the pools. ks/vs [L, n, P, KV, HD] (from
-    llama.prefill), slots [n] slot ids, lengths [n] true lengths;
-    positions past a row's length go to trash page 0. Returns updated
-    pools."""
-    L, n, P, KV, HD = ks.shape
-    ps = page_size
-    pos = jnp.arange(P)[None, :]                           # [1, P]
-    chunk = pos // ps                                      # [1, P]
-    pages = jnp.take_along_axis(
-        page_table[slots], jnp.broadcast_to(chunk, (n, P)), axis=1)
-    pages = jnp.where(pos < lengths[:, None], pages, 0)    # [n, P]
-    offs = jnp.broadcast_to(pos % ps, (n, P))
-    pages_f = pages.reshape(-1)
-    offs_f = offs.reshape(-1)
-
-    # Scatter one LAYER at a time with the pools as scan carry: a
-    # whole-pool scatter forces a full pool-sized layout copy in the
-    # compiled program (+2.7 GB transient at 2.7B; see
-    # _layer_scan_with_kv) — per-layer, the transient is 1/L of that.
-    def body(x, inp, kp, vp):
-        k_l, v_l = inp                                 # [n, P, KV, HD]
-        k_f = k_l.transpose(2, 0, 1, 3).reshape(KV, n * P, HD)
-        v_f = v_l.transpose(2, 0, 1, 3).reshape(KV, n * P, HD)
-        kp = kp.at[:, pages_f, offs_f, :].set(k_f.astype(kp.dtype))
-        vp = vp.at[:, pages_f, offs_f, :].set(v_f.astype(vp.dtype))
-        return x, kp, vp
-
-    _, k_pools, v_pools = _layer_scan_with_kv(
-        body, jnp.int32(0), k_pools, v_pools, (ks, vs))
-    return k_pools, v_pools
-
-
-def forward_with_cache(params, tokens, cache: KVCache, cfg: LlamaConfig,
-                       offset) -> Tuple[jax.Array, KVCache]:
-    """Run [B, S] tokens at position `offset` (scalar — uniform across batch
-    for the bucketed serving path), filling the cache. Returns last-position
-    logits [B, vocab] and the updated cache."""
-    dt = cfg.dtype
-    B, S = tokens.shape
-    x = _embed(params, tokens, dt)
-    cos_full, sin_full = _rope_tables(cfg.rope_theta, cfg.max_seq_len,
-                                     cfg.head_dim)
-    cos = jax.lax.dynamic_slice_in_dim(cos_full, offset, S, axis=0)
-    sin = jax.lax.dynamic_slice_in_dim(sin_full, offset, S, axis=0)
-
-    def body(x, lp, ck, cv):
-        y, (nk_l, nv_l), _ = _layer(x, lp, cfg, cos, sin,
-                                    cache=(ck, cv, offset))
-        return y, nk_l, nv_l
-
-    x, nk, nv = _layer_scan_with_kv(body, x, cache.k, cache.v,
-                                    params["layers"])
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = x[:, -1, :] @ _dq(params["lm_head"], dt)
-    return logits.astype(jnp.float32), KVCache(nk, nv, cache.length + S)

@@ -83,7 +83,7 @@ class LLMEngine:
         import jax
         import jax.numpy as jnp
 
-        from ray_tpu.models import llama
+        from ray_tpu.models import cached, llama
 
         self._jax = jax
         self._jnp = jnp
@@ -165,7 +165,7 @@ class LLMEngine:
             # default pool = the HBM a contiguous cache would commit
             # (+ trash page); the paged win is packing MORE slots into it
             num_pages = num_pages or max_slots * maxP + 1
-            self.kp, self.vp = llama.init_paged_cache(cfg, num_pages,
+            self.kp, self.vp = cached.init_paged_cache(cfg, num_pages,
                                                       page_size)
 
             def _nb(x):
@@ -199,7 +199,7 @@ class LLMEngine:
             self._table_dirty = False
             self.cache = None
         else:
-            self.cache = llama.init_cache(cfg, max_slots,
+            self.cache = cached.init_cache(cfg, max_slots,
                                           max_seq=self.max_seq)
         self.slots: List[Optional[_Request]] = [None] * max_slots
         self.lock = threading.Lock()
@@ -232,14 +232,14 @@ class LLMEngine:
         # admission and decoding.
         if kv_layout == "paged":
             def serve_decode_step_paged(p, t, kp, vp, pt, ln, a):
-                return llama.decode_step_paged(p, t, kp, vp, pt, ln, cfg,
+                return cached.decode_step_paged(p, t, kp, vp, pt, ln, cfg,
                                                active=a)
 
             self._decode_paged = jax.jit(serve_decode_step_paged,
                                          donate_argnums=(2, 3))
 
             def serve_scatter_pages(kp, vp, ks, vs, pt, sl, ln):
-                return llama.scatter_prefill_pages(kp, vp, ks, vs, pt, sl,
+                return cached.scatter_prefill_pages(kp, vp, ks, vs, pt, sl,
                                                    ln, page_size)
 
             self._scatter = jax.jit(serve_scatter_pages,
@@ -249,7 +249,7 @@ class LLMEngine:
             # device call finishes a prefix-hit admission (token-by-token
             # draining costs a transport round trip per tail token)
             def serve_prefill_tail(p, t, tl, pl, pt, kp, vp):
-                return llama.prefill_paged_tail(p, t, tl, pl, pt, kp, vp,
+                return cached.prefill_paged_tail(p, t, tl, pl, pt, kp, vp,
                                                 cfg)
 
             self._prefill_tail = jax.jit(serve_prefill_tail,
@@ -259,7 +259,7 @@ class LLMEngine:
                                          active, temps, key, n):
                 def body(carry, _):
                     last, kp, vp, ln, key = carry
-                    logits, kp, vp, ln = llama.decode_step_paged(
+                    logits, kp, vp, ln = cached.decode_step_paged(
                         params, last, kp, vp, pt, ln, cfg, active=active)
                     key, sub = jax.random.split(key)
                     greedy = jnp.argmax(logits, axis=-1)
@@ -279,7 +279,7 @@ class LLMEngine:
                 donate_argnums=(2, 3))
         else:
             def serve_decode_step(p, t, c, a):
-                return llama.decode_step(p, t, c, cfg, active=a)
+                return cached.decode_step(p, t, c, cfg, active=a)
 
             self._decode = jax.jit(
                 serve_decode_step,
@@ -288,14 +288,14 @@ class LLMEngine:
             # chunked-prefill twin for the contiguous layout: writes a
             # bounded token chunk into slot rows at their current fill
             def serve_prefill_tail_contig(p, t, tl, pl, sl, c):
-                return llama.prefill_tail_contiguous(p, t, tl, pl, c, sl,
+                return cached.prefill_tail_contiguous(p, t, tl, pl, c, sl,
                                                      cfg)
 
             self._prefill_tail_contig = jax.jit(serve_prefill_tail_contig,
                                                 donate_argnums=(5,))
 
         def serve_prefill(p, t, lens):
-            return llama.prefill(p, t, lens, cfg)
+            return cached.prefill(p, t, lens, cfg)
 
         self._prefill = jax.jit(serve_prefill)
 
@@ -305,7 +305,7 @@ class LLMEngine:
             # logits fetch dominates decode latency on any transport)
             def body(carry, _):
                 last, cache, key = carry
-                logits, cache = llama.decode_step(params, last, cache, cfg,
+                logits, cache = cached.decode_step(params, last, cache, cfg,
                                                   active=active)
                 key, sub = jax.random.split(key)
                 greedy = jnp.argmax(logits, axis=-1)
@@ -550,7 +550,7 @@ class LLMEngine:
             length = self.cache.length.at[slots].set(jnp.asarray(lens))
             for i, r in enumerate(admit):
                 r._filled = int(lens[i])
-            from ray_tpu.models.llama import KVCache
+            from ray_tpu.models.cached import KVCache
 
             self.cache = KVCache(k, v, length)
         self._masks_dirty = True

@@ -1,7 +1,7 @@
 """Host-side page allocator for the paged KV cache.
 
 The device side (pools + kernel) is ops/paged_attention.py +
-llama.decode_step_paged; this is the bookkeeping half: a free list of
+models/cached.py decode_step_paged; this is the bookkeeping half: a free list of
 physical pages and the per-slot page tables (ref: vLLM's BlockAllocator
 / BlockTable split, re-shaped so the device arrays stay static — the
 table is a dense [slots, max_pages] int32 the engine re-uploads only

@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import llama, moe, reference_commanda, registry
+from ray_tpu.models import cached, llama, moe, reference_commanda, registry
 from ray_tpu.util import tracing
 
 
@@ -258,7 +258,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
             mine = dict(lp, wq=lp["wq"][:, q_cols], wk=lp["wk"][:, kv_cols],
                         wv=lp["wv"][:, kv_cols], wo=lp["wo"][q_cols])
             a = llama._attention_half(x, mine, share, cos, sin, kind=kind,
-                                      normed=n)[0]
+                                      normed=n)
             total = total + a[0]
         rows = 0
         for first in (0, 2, 4, 6):              # an expert share
@@ -350,9 +350,9 @@ def test_the_router_without_a_bias():
 def test_the_cached_paths_refuse_a_parallel_block():
     cfg = tiny()
     with pytest.raises(NotImplementedError, match="parallel block"):
-        llama.init_cache(cfg, batch=1)
+        cached.init_cache(cfg, batch=1)
     dense = llama.PRESETS["tiny"]
     with pytest.raises(NotImplementedError, match="layer norm"):
-        llama._refuse_stated(dense.replace(norm="layer"))
+        cached._refuse_stated(dense.replace(norm="layer"))
     with pytest.raises(NotImplementedError, match="parallel block"):
-        llama.init_paged_cache(dense.replace(parallel_block=True), 4, 16)
+        cached.init_paged_cache(dense.replace(parallel_block=True), 4, 16)

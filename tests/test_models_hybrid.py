@@ -10,7 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import hybrid, llama, moe, reference_granite
+from ray_tpu.models import cached, hybrid, llama, moe, reference_granite
 from ray_tpu.models import registry
 
 
@@ -647,8 +647,8 @@ def test_the_cached_paths_refuse_a_config_that_states_its_own_scales():
     """prefill and decode embed, rotate, scale and add as llama does: a
     cache for a config that states otherwise is refused, llama's is not."""
     with pytest.raises(NotImplementedError, match="residual_multiplier"):
-        llama.init_cache(tiny(), 1)
+        cached.init_cache(tiny(), 1)
     with pytest.raises(NotImplementedError, match="rope"):
-        llama.init_paged_cache(
+        cached.init_paged_cache(
             llama.PRESETS["tiny"].replace(rope=False), 4, 16)
-    assert llama.init_cache(llama.PRESETS["tiny"], 1).k.shape[0] == 2
+    assert cached.init_cache(llama.PRESETS["tiny"], 1).k.shape[0] == 2

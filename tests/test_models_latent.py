@@ -17,7 +17,8 @@ import numpy as np
 import optax
 import pytest
 
-from ray_tpu.models import latent, llama, moe, reference_glm, registry
+from ray_tpu.models import (cached, latent, llama, moe, reference_glm,
+                            registry)
 from ray_tpu.parallel import MeshSpec, ShardingRules, build_mesh
 from ray_tpu.parallel.train_step import (hold_out, make_train_state_init,
                                          make_train_step)
@@ -181,7 +182,7 @@ def test_the_loss_takes_two_ids_more_and_no_mask():
     assert aux["router_counts"].shape == (2, 8)
     # the cached paths know three projections of the hidden state only
     with pytest.raises(NotImplementedError, match="attention half"):
-        llama._refuse_stated(cfg)
+        cached._refuse_stated(cfg)
 
 
 # --- latent attention through the flash kernels at a head of 256 -----------

@@ -7,7 +7,7 @@ jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 import optax  # noqa: E402
 
-from ray_tpu.models import llama  # noqa: E402
+from ray_tpu.models import cached, llama  # noqa: E402
 from ray_tpu.parallel import (MeshSpec, ShardingRules, build_mesh)  # noqa: E402
 from ray_tpu.parallel.train_step import (make_train_state_init,  # noqa: E402
                                          make_train_step)
@@ -41,14 +41,14 @@ def test_kv_cache_matches_forward():
                                 CFG.vocab_size)
     full = llama.forward(params, tokens, CFG)
 
-    cache = llama.init_cache(CFG, B, max_seq=32)
+    cache = cached.init_cache(CFG, B, max_seq=32)
     # prefill first 8, then decode one at a time
-    logits, cache = llama.forward_with_cache(params, tokens[:, :8], cache,
+    logits, cache = cached.forward_with_cache(params, tokens[:, :8], cache,
                                              CFG, 0)
     np.testing.assert_allclose(np.asarray(logits), np.asarray(full[:, 7]),
                                rtol=2e-4, atol=2e-4)
     for i in range(8, S):
-        logits, cache = llama.forward_with_cache(params, tokens[:, i:i + 1],
+        logits, cache = cached.forward_with_cache(params, tokens[:, i:i + 1],
                                                  cache, CFG, i)
         np.testing.assert_allclose(np.asarray(logits), np.asarray(full[:, i]),
                                    rtol=2e-4, atol=2e-4)
@@ -178,10 +178,10 @@ def test_sliding_window_decode_and_guards():
                                             dtype=jnp.float32,
                                             sliding_window=W)
         params = llama.init_params(jax.random.PRNGKey(0), cfg)
-        cache = llama.init_cache(cfg, batch=1, max_seq=24)
+        cache = cached.init_cache(cfg, batch=1, max_seq=24)
         outs = []
         for t in range(24):
-            lg, cache = llama.decode_step(params, toks[:, t:t + 1],
+            lg, cache = cached.decode_step(params, toks[:, t:t + 1],
                                           cache, cfg)
             outs.append(lg)
         return jnp.stack(outs, 1)

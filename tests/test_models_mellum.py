@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import llama, moe, reference_mellum, registry
+from ray_tpu.models import cached, llama, moe, reference_mellum, registry
 from ray_tpu.util import tracing
 
 
@@ -297,6 +297,6 @@ def test_a_dense_config_with_a_window_is_one_kind_as_before():
 def test_the_cached_paths_refuse_layers_of_several_kinds():
     cfg = tiny()
     with pytest.raises(NotImplementedError, match="several kinds"):
-        llama.init_cache(cfg, batch=1)
+        cached.init_cache(cfg, batch=1)
     with pytest.raises(NotImplementedError, match="several kinds"):
-        llama._refuse_stated(cfg)
+        cached._refuse_stated(cfg)
