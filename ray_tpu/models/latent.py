@@ -53,6 +53,35 @@ afterwards:
   product's epilogue; v from ``wkv_b``'s v columns, as its product wrote
   it; ``wo`` contracts heads and lanes of the kernel's output in place.
 
+A config with an indexer (``index_heads`` > 0: DeepSeek Sparse Attention,
+arXiv:2512.02556, as GLM-5.2 runs it) attends over a learned set. A FULL
+layer (``index_full[l]``) scores every earlier key with ``index_heads``
+small heads of ``index_dim`` lanes and keeps each query's ``index_topk``
+best (sg = stop-gradient; the rotary on the FIRST ``qk_rope_dim`` lanes of
+both, interleaved pairs, the attention's own tables):
+
+    qI = sg(c_q) W_Iq -> index_heads x [index_dim]
+    kI = layer_norm(sg(h) W_Ik)                  (scale and bias)
+    w  = sg(h) W_Iw * index_heads^(-1/2) * index_dim^(-1/2)
+    I[t, s] = sum_j w[t, j] relu(qI[t, j] . kI[s])      s <= t, float32
+    Sel_t = the min(index_topk, t + 1) keys of largest I[t, .], equal
+            scores to the smaller s                     (exact: ``_select``)
+
+and every layer's softmax runs over ``Sel_t`` alone
+(``ops/sparse_attention.py``). A SHARED layer has no ``W_I*`` and attends
+over the set of the nearest full layer before it: the set is the value the
+attention half takes and returns (``carried``: 0/1 ``[B, S, S]`` int8,
+causal already), ``llama._forward`` carries it from layer to layer and the
+layer checkpoint keeps it (``index_set``), so a replay never selects
+again. The indexer learns from the attention it steers and from nothing
+else: with P[t, s] = sg(mean_h p[h, t, s]) on Sel_t,
+
+    LI = mean_t sum_{s in Sel_t} P[t, s] (log P[t, s]
+                                          - log softmax_{Sel_t}(I[t, .])[s])
+
+joins the loss with ``index_loss_weight``; ``W_I*`` get gradient from LI
+only and every other leaf from the rest only.
+
 Layers are a list of runs (``layer_runs``): ``n_dense`` leading layers
 whose feed-forward is a SwiGLU of ``dense_d_ff``, then sparse layers
 (``d_ff`` the width of ONE expert). The prediction module
@@ -64,11 +93,14 @@ the model's embedding and head, trained on the token after the next.
 from __future__ import annotations
 
 import dataclasses
+import itertools
+import math
 from dataclasses import dataclass
 from typing import Any, Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.models import llama as _ll
 from ray_tpu.models import moe as _moe
@@ -94,6 +126,22 @@ class LatentConfig(_moe.MoEConfig):
     mtp_weight: float = 0.3             # of the second cross-entropy
     router_aux_weight: float = 0.0001   # of the sequence-wise balance loss
     router_z_weight: float = 0.0        # the sigmoid router has no z-loss
+    # the learned selection (module docstring); 0 heads: none, every query
+    # attends to every earlier key and the program is the one without
+    index_heads: int = 0
+    index_dim: int = 128
+    index_topk: int = 2048
+    # a layer's own: True scores and selects (it has ``W_I*``), False
+    # attends over the set of the full layer before it. A tuple a layer and
+    # no formula, because the source states a list (``indexer_types``)
+    index_full: Tuple[bool, ...] = ()
+    index_loss_weight: float = 1.0
+    # a full layer reports its set itself too (``index_set`` [B, S, S]
+    # int8 among its statistics) and every layer the fingerprint of the
+    # set it attended over (``index_attended``, ``set_fingerprint``): for
+    # a check of the sets and of their way through the layers, not for a
+    # step
+    index_report_sets: bool = False
 
     def __post_init__(self):
         if self.v_dim != self.qk_nope_dim + self.qk_rope_dim:
@@ -104,6 +152,18 @@ class LatentConfig(_moe.MoEConfig):
         if self.n_mtp not in (0, 1) or not 0 <= self.n_dense < self.n_layers:
             raise ValueError(f"n_mtp {self.n_mtp}, n_dense {self.n_dense} of "
                              f"{self.n_layers} layers")
+        if self.index_heads:
+            full = self.index_full
+            if len(full) != self.n_layers:
+                raise ValueError(f"index_full {full} for {self.n_layers} "
+                                 "layers: one entry a layer")
+            if self.n_mtp:
+                raise NotImplementedError(
+                    "a prediction module over a learned set: no key of the "
+                    "source says whose set its block attends over")
+            if self.index_dim < self.qk_rope_dim:
+                raise ValueError(f"index_dim {self.index_dim} under the "
+                                 f"rotary's {self.qk_rope_dim} lanes")
 
     @property
     def head_dim(self) -> int:
@@ -122,18 +182,58 @@ PRESETS: Dict[str, LatentConfig] = {
         vocab_size=256, d_model=64, n_layers=3, n_heads=4, n_kv_heads=4,
         d_ff=32, max_seq_len=128, n_experts=8, top_k=2, shared_d_ff=32,
         route_scale=1.8),
+    # the learned selection at toy sizes: a dense full layer, a period of
+    # three shared and one full sparse layers, one shared layer more
+    "tiny-glm52": LatentConfig(
+        vocab_size=256, d_model=64, n_layers=6, n_heads=2, n_kv_heads=2,
+        d_ff=32, max_seq_len=256, n_experts=8, top_k=2, shared_d_ff=32,
+        experts_held=(2, 2), route_scale=1.8, n_mtp=0, index_heads=2,
+        index_dim=16, index_topk=8,
+        index_full=(True, False, False, False, True, False)),
+    # GLM-5.2 (zai-org/GLM-5.2, glm_moe_dsa) at its published widths, one
+    # of 32 chips' share of the source's layers 2-6: 32 of 64 heads, 8 of
+    # 256 experts, an eighth of the vocabulary, no prediction module
+    # (benchmark/configs/glm-5.2-ep32-l5.json has the cut and its reasons)
+    "glm-5.2-ep32-l5": LatentConfig(
+        vocab_size=19360, d_model=6144, n_layers=5, n_heads=32,
+        n_kv_heads=32, d_ff=2048, dense_d_ff=12288, shared_d_ff=2048,
+        max_seq_len=1048576, rope_theta=8e6, norm_eps=1e-5, q_rank=2048,
+        kv_rank=512, qk_nope_dim=192, qk_rope_dim=64, v_dim=256,
+        n_experts=256, top_k=8, experts_held=(8, 0), route_scale=2.5,
+        n_dense=1, n_mtp=0, run_layers=1, index_heads=32, index_dim=128,
+        index_topk=2048, index_full=(True, False, False, False, True),
+        dtype=jnp.bfloat16, param_dtype=jnp.bfloat16),
 }
 
-REMAT_SAVED = _moe.REMAT_SAVED
+# beside the expert layer's routes, the set a full layer chose: the replay
+# of a layer never scores and selects again
+INDEX_SET = "index_set"
+REMAT_SAVED = _moe.REMAT_SAVED + (INDEX_SET,)
 expert_rows = _moe.expert_rows
+# rows of queries whose index scores are alive at once ([rows, keys]
+# float32), and of those whose per-head products are ([heads, rows, keys])
+SCORE_BLOCK_ROWS = 2048
+SCORE_CHUNK_ROWS = 256
+
+
+def _ffn(kind: str) -> str:
+    """A layer kind's feed-forward: "dense" | "sparse" (the kind itself
+    for a config without an indexer, "sparse.full" and the like with)."""
+    return kind.split(".")[0]
+
+
+def _selects(kind: str) -> bool:
+    return kind.endswith(".full")
 
 
 def remat_saved_bytes(cfg: "LatentConfig", kind, rows: int) -> int:
-    return 0 if kind == "dense" else _moe.remat_saved_bytes(cfg, kind, rows)
+    own = rows * min(rows, cfg.max_seq_len) if _selects(kind) else 0
+    return own + (0 if _ffn(kind) == "dense"
+                  else _moe.remat_saved_bytes(cfg, kind, rows))
 
 
 def remat_offers(cfg: "LatentConfig", kind, rows: int):
-    return () if kind == "dense" else _moe.remat_offers(cfg, kind, rows)
+    return () if _ffn(kind) == "dense" else _moe.remat_offers(cfg, kind, rows)
 
 
 def further_stacks(params, cfg: "LatentConfig"):
@@ -148,9 +248,37 @@ _PROJECTIONS = ("wq", "wk", "wv", "wo")     # llama's, which MLA replaces
 
 
 def layer_runs(cfg: LatentConfig) -> List[Tuple[str, int]]:
-    """[(kind, how many adjacent layers of it), ...] in the layers' order."""
-    runs = [("dense", cfg.n_dense), ("sparse", cfg.n_layers - cfg.n_dense)]
-    return [r for r in runs if r[1]]
+    """[(kind, how many adjacent layers of it), ...] in the layers' order.
+    A kind is the layer's feed-forward, "dense" | "sparse", and with an
+    indexer also whether the layer selects: "dense.full", "sparse.shared",
+    "sparse.full" (only full layers have indexer leaves, so the stacks'
+    trees differ); a run holds ``run_layers`` layers at most (0: all)."""
+    ffn = ["dense"] * cfg.n_dense + ["sparse"] * (cfg.n_layers - cfg.n_dense)
+    if not cfg.index_heads:
+        return [(k, len(list(g))) for k, g in itertools.groupby(ffn)]
+    if not cfg.index_full[0]:
+        raise ValueError("the first layer has no set to attend over: "
+                         f"index_full {cfg.index_full}")
+    kinds = [f"{f}.{'full' if own else 'shared'}"
+             for f, own in zip(ffn, cfg.index_full)]
+    most = cfg.run_layers or cfg.n_layers
+    return [(kind, min(most, n - at))
+            for kind, n in ((k, len(list(g)))
+                            for k, g in itertools.groupby(kinds))
+            for at in range(0, n, most)]
+
+
+def carried_init(cfg: LatentConfig, B: int, S: int):
+    """What ``llama._forward`` hands the first layer's attention half and
+    carries on from it: the set (nobody's yet), or None without an
+    indexer, and then nothing is carried."""
+    return jnp.zeros((B, S, S), jnp.int8) if cfg.index_heads else None
+
+
+def hands_on(cfg: LatentConfig, kind) -> bool:
+    """Whether a layer of ``kind`` replaces the carried set (a full
+    layer); a shared layer only reads it."""
+    return _selects(kind)
 
 
 def _mla_specs():
@@ -178,31 +306,60 @@ def _mla_params(key, cfg: LatentConfig, n: int):
             "wo": dense(ks[4], (H * cfg.v_dim, D))}
 
 
+def _index_specs():
+    L = ("layers",)
+    return {"wi_q": L + (None, None), "wi_k": L + ("embed", None),
+            "wi_k_norm": L + (None,), "wi_k_bias": L + (None,),
+            "wi_w": L + ("embed", None)}
+
+
+def _index_params(key, cfg: LatentConfig, n: int):
+    """A full layer's indexer: projections normal over the square root of
+    their fan-in, the key norm's scale 1 and bias 0."""
+    pd, D, ID = cfg.param_dtype, cfg.d_model, cfg.index_dim
+    ks = jax.random.split(key, 3)
+
+    def dense(k, shape):
+        return jax.random.normal(k, (n,) + shape, pd) * shape[0] ** -0.5
+
+    return {"wi_q": dense(ks[0], (cfg.q_rank, cfg.index_heads * ID)),
+            "wi_k": dense(ks[1], (D, ID)),
+            "wi_k_norm": jnp.ones((n, ID), pd),
+            "wi_k_bias": jnp.zeros((n, ID), pd),
+            "wi_w": dense(ks[2], (D, cfg.index_heads))}
+
+
 def _stacks(cfg: LatentConfig):
     """(kind, the config that makes a stack of that many layers of the
     kind): the runs, then the prediction module's one sparse block."""
-    out = [(kind, cfg.replace(n_layers=n, n_dense=0))
+    out = [(kind, cfg.replace(n_layers=n, n_dense=0,
+                              index_full=(_selects(kind),) * n))
            for kind, n in layer_runs(cfg)]
-    return out + [("sparse", cfg.replace(n_layers=1, n_dense=0))] * cfg.n_mtp
+    if cfg.n_mtp:
+        out.append(("sparse", cfg.replace(n_layers=1, n_dense=0)))
+    return out
 
 
 def _stack_specs(kind: str, run: LatentConfig):
-    base = _ll if kind == "dense" else _moe
+    base = _ll if _ffn(kind) == "dense" else _moe
     lay = dict(base.param_specs(run)["layers"])
     for w in _PROJECTIONS:
         del lay[w]
-    return {**lay, **_mla_specs()}
+    return {**lay, **_mla_specs(), **(_index_specs() if _selects(kind)
+                                      else {})}
 
 
 def _stack_params(key, kind: str, run: LatentConfig):
-    if kind == "dense":
+    if _ffn(kind) == "dense":
         lay = _ll.init_params(key, run.replace(d_ff=run.dense_d_ff,
                                                vocab_size=1))["layers"]
     else:
         lay = _moe.init_params(key, run.replace(vocab_size=1))["layers"]
     lay = {k: v for k, v in lay.items() if k not in _PROJECTIONS}
+    own = _index_params(jax.random.fold_in(key, 11), run, run.n_layers) \
+        if _selects(kind) else {}
     return {**lay, **_mla_params(jax.random.fold_in(key, 7), run,
-                                 run.n_layers)}
+                                 run.n_layers), **own}
 
 
 def param_specs(cfg: LatentConfig) -> Dict[str, Any]:
@@ -248,9 +405,12 @@ def num_params(cfg: LatentConfig) -> int:
     sparse = (mla + 2 * D + D * cfg.n_experts + cfg.n_experts
               + 3 * cfg.n_held * D * cfg.d_ff + 3 * D * cfg.shared_d_ff)
     dense = mla + 2 * D + 3 * D * cfg.dense_d_ff
+    index = sum(cfg.index_full) * (
+        cfg.q_rank * cfg.index_heads * cfg.index_dim + D * cfg.index_dim
+        + 2 * cfg.index_dim + D * cfg.index_heads) if cfg.index_heads else 0
     return (2 * cfg.vocab_size * D + D + cfg.n_dense * dense
             + (cfg.n_layers - cfg.n_dense) * sparse
-            + cfg.n_mtp * (sparse + 2 * D * D + 3 * D))
+            + cfg.n_mtp * (sparse + 2 * D * D + 3 * D) + index)
 
 
 def _swapped(w):
@@ -288,7 +448,8 @@ def plan(cfg: LatentConfig, B: int, S: int) -> dict:
     sum over heads)."""
     H, R, e = cfg.n_heads, cfg.qk_rope_dim, jnp.dtype(cfg.dtype).itemsize
     rows, heads = B * S, B * S * cfg.n_heads * cfg.head_dim
-    return {"S": S, "heads": H, "qk_nope": cfg.qk_nope_dim, "qk_rope": R,
+    return {"S": S, "heads": H, "heads_held": H, "qk_nope": cfg.qk_nope_dim,
+            "qk_rope": R,
             "v_dim": cfg.v_dim, "q_rank": cfg.q_rank, "kv_rank": cfg.kv_rank,
             "form": "expanded", "k_bytes": heads * e, "rope": "projected",
             "kv": "split_weights", "extra_columns": (H + 1) * R,
@@ -298,10 +459,187 @@ def plan(cfg: LatentConfig, B: int, S: int) -> dict:
                                                   + rows * R * (H + 1))}
 
 
-def attention_half(x, lp, cfg: LatentConfig, cos, sin, mesh=None, rules=None):
-    """The latent-attention half of a block: x [B, S, D] -> x + its
-    attention's output (the module docstring has the equations and how the
-    stored columns are arranged for the kernel)."""
+def index_plan(cfg: LatentConfig, B: int, S: int, kind: str) -> dict:
+    """What a traced attention half over a learned set says of itself
+    (instant ``dsa.plan``): the sizes, whether the layer selects (``kind``
+    full) or reads (shared), the form of the sparse attention (``mask``:
+    a membership test a pair, nothing gathered), the pairs the sets hold
+    beside the causal ones, the rows of queries whose scores are alive at
+    once and the set's bytes."""
+    k = min(cfg.index_topk, S)
+    return {"S": S, "topk": cfg.index_topk, "index_heads": cfg.index_heads,
+            "index_dim": cfg.index_dim,
+            "kind": "full" if _selects(kind) else "shared", "form": "mask",
+            "selected_pairs": B * (k * (k + 1) // 2 + (S - k) * k),
+            "causal_pairs": B * S * (S + 1) // 2,
+            "score_block_rows": min(S, SCORE_BLOCK_ROWS),
+            "set_bytes": B * S * S, "gathered_bytes_fwd": 0}
+
+
+def _index_rotary(x, cos, sin):
+    """The rotary on the FIRST lanes of x [..., S, (heads,) index_dim]:
+    interleaved pairs of as many lanes as the tables turn (cos, sin
+    [S, R / 2]), the lanes after them as they were."""
+    R = 2 * cos.shape[-1]
+    c, s = (jnp.repeat(t.astype(jnp.float32), 2, axis=-1) for t in (cos, sin))
+    pad = ((0, 0), (0, x.shape[-1] - R))
+    c, s = jnp.pad(c, pad, constant_values=1.0), jnp.pad(s, pad)
+    if x.ndim == 4:                                    # [B, S, heads, lanes]
+        c, s = c[:, None], s[:, None]
+    turned = jnp.pad(_swapped(x[..., :R]), [(0, 0)] * (x.ndim - 1) + [pad[1]])
+    return (x.astype(jnp.float32) * c
+            + turned.astype(jnp.float32) * s).astype(x.dtype)
+
+
+def _indexer(h, c_q, lp, cfg: LatentConfig, cos, sin):
+    """A full layer's index queries, keys and head weights from the normed
+    input h [B, S, D] and the query latent c_q [B, S, q_rank], both
+    detached: (qI [B, S, heads, index_dim], kI [B, S, index_dim], w
+    [B, S, heads] float32)."""
+    B, S, _ = h.shape
+    IH, ID, dt = cfg.index_heads, cfg.index_dim, cfg.dtype
+    h, c_q = jax.lax.stop_gradient(h), jax.lax.stop_gradient(c_q)
+    w = lambda name: _ll._dq(lp[name], dt)                     # noqa: E731
+    qI = (c_q @ w("wi_q")).reshape(B, S, IH, ID)
+    kI = _ll.layer_norm(h @ w("wi_k"), lp["wi_k_norm"], cfg.norm_eps) \
+        + lp["wi_k_bias"].astype(dt)
+    weight = (h @ w("wi_w")).astype(jnp.float32) * (IH ** -0.5 * ID ** -0.5)
+    return _index_rotary(qI, cos, sin), _index_rotary(kI, cos, sin), weight
+
+
+def index_scores(qI, weight, kI):
+    """I[t, s] = sum_j w[t, j] relu(qI[t, j] . kI[s]) for the queries qI
+    [R, heads, lanes] (weights [R, heads] float32) over the keys kI [T,
+    lanes] -> [R, T] float32, ``SCORE_CHUNK_ROWS`` queries' per-head
+    products alive at once, forward and (recomputed) backward."""
+    R, IH, ID = qI.shape
+    c = math.gcd(R, SCORE_CHUNK_ROWS)
+
+    @jax.checkpoint
+    def chunk(qw):
+        q, w = qw
+        products = jnp.einsum("cjd,td->cjt", q, kI,
+                              preferred_element_type=jnp.float32)
+        return jnp.sum(jax.nn.relu(products) * w[:, :, None], axis=1)
+
+    out = jax.lax.map(chunk, (qI.reshape(R // c, c, IH, ID),
+                              weight.reshape(R // c, c, IH)))
+    return out.reshape(R, kI.shape[0])
+
+
+def _sortable(x):
+    """float32 -> uint32 that orders as the floats do (-0.0 as 0.0)."""
+    u = jax.lax.bitcast_convert_type(x + 0.0, jnp.uint32)
+    return jnp.where(u >> 31 == 1, ~u, u | jnp.uint32(0x80000000))
+
+
+def select(scores, first_row: int, topk: int):
+    """The exact selection: scores [R, T] float32 of the queries at
+    positions ``first_row + r`` over the keys 0..T-1 -> keep [R, T] bool,
+    for each query the min(topk, t + 1) causal keys of largest score,
+    equal scores to the smaller s. No sort: the topk-th largest score of a
+    row is found bit by bit (32 counting passes over the row's keys as
+    ordered integers), the keys above it are kept and, of those equal to
+    it, the first that fill the set (log2 T counting passes more)."""
+    R, T = scores.shape
+    t = first_row + jnp.arange(R)[:, None]
+    causal = jnp.arange(T)[None, :] <= t
+    key = jnp.where(causal, _sortable(scores), jnp.uint32(0))
+
+    def bit(i, prefix):
+        cand = prefix | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        enough = jnp.sum(key >= cand[:, None], axis=1) >= topk
+        return jnp.where(enough, cand, prefix)
+
+    kth = jax.lax.fori_loop(0, 32, bit, jnp.zeros((R,), jnp.uint32))[:, None]
+    above = key > kth
+    room = topk - jnp.sum(above, axis=1)
+    equal = causal & (key == kth)
+    at = jnp.arange(T, dtype=jnp.int32)[None, :]
+    bits = max(T - 1, 1).bit_length()
+
+    # of the keys equal to the topk-th score the first ``room``: the
+    # position of the room-th of them, bit by bit too (a cumulative sum
+    # over the row is a dozen passes of the compiler's own, which a trace
+    # bills to nobody)
+    def place(i, last):
+        cand = last | (jnp.int32(1) << (bits - 1 - i))
+        before = jnp.sum(equal & (at < cand[:, None]), axis=1)
+        return jnp.where(before < room, cand, last)
+
+    last = jax.lax.fori_loop(0, bits, place, jnp.zeros((R,), jnp.int32))
+    return causal & (above | (equal & (at <= last[:, None])))
+
+
+def _score_blocks(S: int):
+    """[(first row, rows, keys), ...]: the queries in blocks of
+    ``SCORE_BLOCK_ROWS`` and the keys each block's rows may see."""
+    rows = min(S, SCORE_BLOCK_ROWS)
+    while S % rows:
+        rows //= 2
+    return [(at, rows, at + rows) for at in range(0, S, rows)]
+
+
+def _select_set(qI, weight, kI, topk: int):
+    """One sequence's set [S, S] int8 from its index queries, weights and
+    keys, a block of queries at a time."""
+    S = qI.shape[0]
+    out = []
+    for at, rows, keys in _score_blocks(S):
+        with jax.named_scope("indexer"):
+            scores = index_scores(qI[at:at + rows], weight[at:at + rows],
+                                  kI[:keys])
+        with jax.named_scope("select"):
+            keep = select(scores, at, topk).astype(jnp.int8)
+            out.append(jnp.pad(keep, ((0, 0), (0, S - keys))))
+    with jax.named_scope("select"):
+        return jnp.concatenate(out, axis=0)
+
+
+def _index_loss(qI, weight, kI, probs, keep):
+    """One sequence's LI (module docstring): the indexer's scores against
+    the attention's head-mean probabilities probs [S, S] float32 on the
+    set keep [S, S] int8, a block of queries at a time, each block's
+    scores recomputed in the backward."""
+    S = qI.shape[0]
+
+    @jax.checkpoint
+    def block(q, w, k, p, on):
+        on = on != 0
+        scores = jnp.where(on, index_scores(q, w, k), -1e30)
+        top = jnp.max(scores, axis=1, keepdims=True)
+        norm = top + jnp.log(jnp.sum(
+            jnp.where(on, jnp.exp(scores - top), 0.0), axis=1,
+            keepdims=True))
+        p = jnp.where(on, p, 0.0)
+        return jnp.sum(jnp.where(
+            p > 0, p * (jnp.log(jnp.maximum(p, 1e-37)) - (scores - norm)),
+            0.0))
+
+    # the scope OUTSIDE the checkpoint, so that a trace reads
+    # ``attention/index_loss`` as adjacent parts: the term's own pass over
+    # the scores (and its backward's) is the term's cost, not the
+    # selection's
+    with jax.named_scope("index_loss"):
+        total = sum(
+            block(qI[at:at + rows], weight[at:at + rows], kI[:keys],
+                  probs[at:at + rows, :keys], keep[at:at + rows, :keys])
+            for at, rows, keys in _score_blocks(S))
+    return total / S
+
+
+def attention_half(x, lp, cfg: LatentConfig, cos, sin, mesh=None, rules=None,
+                   carried=None, kind=None):
+    """The latent-attention half of a block: x [B, S, D] -> (x + its
+    attention's output, ``carried``, what the half reports of itself), the
+    module docstring has the equations and how the stored columns are
+    arranged for the kernel. ``carried`` is the set the layer attends over
+    (None for a config without an indexer: causal attention over every
+    earlier key, nothing reported). A full layer (``kind``) scores, selects
+    and hands its own set on, and reports its ``index_loss`` (LI), the
+    share of the causal pairs it selected and the share of its set that
+    the set it was handed holds (first sequence); a shared layer attends
+    over the set it was handed and hands it on."""
     B, S, _ = x.shape
     H, dn, dv, dt = cfg.n_heads, cfg.qk_nope_dim, cfg.v_dim, cfg.dtype
     R, rk, f32 = cfg.qk_rope_dim, cfg.kv_rank, jnp.float32
@@ -330,15 +668,65 @@ def attention_half(x, lp, cfg: LatentConfig, cos, sin, mesh=None, rules=None):
     v = jnp.einsum("bsr,rhd->bhsd", c_kv, wkv_b[..., dn:])
     # the kernel's own transposes, so XLA writes no copy for them
     q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
-    out = _ll._attention(q, k, v, cfg, causal=True, mesh=mesh, rules=rules)
+    said = None
+    if carried is None:
+        out = _ll._attention(q, k, v, cfg, causal=True, mesh=mesh,
+                             rules=rules)
+    else:
+        out, carried, said = _attend_set(q, k, v, h, c_q, lp, cfg, cos, sin,
+                                         carried, kind)
     return _ll._residual(
-        x, jnp.einsum("bshd,hde->bse", out, w("wo").reshape(H, dv, -1)), cfg)
+        x, jnp.einsum("bshd,hde->bse", out, w("wo").reshape(H, dv, -1)),
+        cfg), carried, said
+
+
+def set_fingerprint(keep):
+    """A set's fingerprint, uint32 a sequence: the sum over its pairs (t,
+    s) of ``(40503 t + 9973 s) mod 2^16``, mod 2^32. keep [B, S, S]."""
+    at = jnp.arange(keep.shape[-1], dtype=jnp.uint32)
+    weight = (at[:, None] * 40503 + at[None, :] * 9973) & 0xFFFF
+    return jnp.sum(keep.astype(jnp.uint32) * weight, axis=(1, 2))
+
+
+def _attend_set(q, k, v, h, c_q, lp, cfg: LatentConfig, cos, sin, carried,
+                kind):
+    """Attention over the learned set (q, k, v [B, S, H, .] as the dense
+    call takes them): a full layer's scores, selection and LI round the
+    sparse call, a shared layer's sparse call over ``carried``."""
+    from ray_tpu.ops.sparse_attention import sparse_attention
+
+    B, S = q.shape[:2]
+    tracing.instant("dsa.plan", index_plan(cfg, B, S, kind))
+    scale = cfg.attn_scale                      # None: head_dim ** -0.5
+    if not _selects(kind):
+        with jax.named_scope("sparse"):
+            out = sparse_attention(q, k, v, carried, scale=scale)
+        return out, carried, {"index_attended": set_fingerprint(carried)[0]} \
+            if cfg.index_report_sets else None
+    with jax.named_scope("indexer"):
+        qI, kI, weight = _indexer(h, c_q, lp, cfg, cos, sin)
+    own = jax.lax.stop_gradient(jax.vmap(
+        lambda a, b, c: _select_set(a, b, c, cfg.index_topk))(qI, weight, kI))
+    own = checkpoint_name(own, INDEX_SET)
+    with jax.named_scope("sparse"):
+        out, probs = sparse_attention(q, k, v, own, scale=scale,
+                                      with_probs=True)
+    loss = jnp.mean(jax.vmap(_index_loss)(qI, weight, kI, probs, own))
+    with jax.named_scope("select"):
+        pairs = jnp.sum(own[0].astype(jnp.int32))
+        said = {"index_loss": loss,
+                "index_selected": pairs / (S * (S + 1) / 2),
+                "index_overlap": jnp.sum(
+                    (own[0] & carried[0]).astype(jnp.int32)) / pairs}
+        if cfg.index_report_sets:
+            said.update(index_set=own, index_attended=set_fingerprint(own)[0])
+    return out, own, said
 
 
 def feed_forward(h, lp, cfg: LatentConfig, mesh=None, rules=None, tp=None,
                  kind=None):
     """A dense layer's SwiGLU or a sparse layer's experts, by ``kind``."""
-    half = _ll if kind == "dense" else _moe
+    half = _ll if _ffn(kind) == "dense" else _moe
     return half.feed_forward(h, lp, cfg, mesh=mesh, rules=rules, tp=tp)
 
 
@@ -400,7 +788,8 @@ def token_losses(params, tokens, cfg: LatentConfig, mesh=None, rules=None):
 
 
 def finish_loss(loss, stats, cfg: LatentConfig):
-    """loss = L_main + mtp_weight L_mtp + router_aux_weight L_balance, from
+    """loss = L_main + mtp_weight L_mtp + router_aux_weight L_balance (+
+    index_loss_weight x the sum of the full layers' LI), from
     the expert layers' stacked statistics (the module's block among them)
     -> (loss, aux). ``aux`` carries the step's counts over all experts
     (``router_counts`` [layers, E]) for ``post_update``."""
@@ -414,8 +803,17 @@ def finish_loss(loss, stats, cfg: LatentConfig):
             stats["experts"].shape[1] * cfg.top_k))
     else:
         aux["moe_dropped"] = jnp.zeros((), jnp.int32)
-    return (loss + cfg.mtp_weight * mtp + cfg.router_aux_weight * balance,
-            aux)
+    loss = loss + cfg.mtp_weight * mtp + cfg.router_aux_weight * balance
+    if "index_loss" in stats:
+        # the full layers' LI, in the layers' order; the last full layer's
+        # overlap with the set it was handed (the first was handed nobody's)
+        each = stats["index_loss"]
+        aux.update({f"index_loss_{i}": each[i] for i in range(each.shape[0])})
+        aux.update(index_loss=each.sum(),
+                   index_selected_share=stats["index_selected"].mean(),
+                   index_overlap=stats["index_overlap"][-1])
+        loss = loss + cfg.index_loss_weight * each.sum()
+    return loss, aux
 
 
 def post_update(params, aux, cfg: LatentConfig):

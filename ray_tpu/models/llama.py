@@ -293,7 +293,11 @@ def _family(cfg: "LlamaConfig"):
     feed-forward returns statistics, ``finish_loss``; where its attention
     is not three projections of the hidden state, the attention half too
     (``attention_half``); where it predicts further tokens than the next,
-    ``further_losses``."""
+    ``further_losses``; where its attention half hands a value on to the
+    layers after it (a learned selection's set), ``carried_init`` (cfg,
+    batch, seq -> the value before the first layer, None: nothing is
+    carried) and ``hands_on`` (cfg, kind -> whether a layer of the kind
+    replaces the value or only reads it)."""
     return sys.modules[type(cfg).__module__]
 
 
@@ -788,28 +792,34 @@ def _takes_attention_half(cfg: LlamaConfig, kind) -> bool:
 
 
 def _layer(x, lp, cfg: LlamaConfig, cos, sin, mesh=None, rules=None, tp=None,
-           kind=None):
+           kind=None, carried=None):
     """One transformer block: the attention half, then the family's
     feed-forward half (dense SwiGLU here, the expert layer for a
-    MoEConfig). x: [B, S, D]. Returns (x, stats): stats is what the
-    feed-forward reports (None for the dense one). ``kind``: None or
+    MoEConfig). x: [B, S, D]. Returns (x, stats, carried): stats is what
+    the halves report (None where neither does: the dense one), ``carried``
+    what a family's attention half hands on to the next layer (``_family``;
+    None in, None out). ``kind``: None or
     "attention" for the attention half, or a kind of attention layer the
     config names (``attn_kinds``: the same half with that kind's window,
     ``cos`` and ``sin`` its tables, under a scope of the kind's name); any
     other kind of layer takes its first half from the family's
     ``mixer_half`` (x, lp, cfg, kind -> x). A family with an
     ``attention_half`` of its own (x, lp, cfg, cos, sin -> x) supplies
-    every layer's. ``kind`` goes on to the feed-forward. A config with
+    every layer's: (x, lp, cfg, cos, sin, carried, kind -> x, carried,
+    what it reports). ``kind`` goes on to the feed-forward. A config with
     ``parallel_block`` runs the same two halves side by side from one norm
     (``_parallel_layer``)."""
     if cfg.parallel_block:
-        return _parallel_layer(x, lp, cfg, cos, sin, mesh, rules, tp, kind)
+        return _parallel_layer(x, lp, cfg, cos, sin, mesh, rules, tp,
+                               kind) + (carried,)
     own = getattr(_family(cfg), "attention_half", None)
     named = kind in dict(cfg.attn_kinds)
+    said = None
     if own is not None:
         assert tp is None, kind
         with jax.named_scope("attention"):
-            x = own(x, lp, cfg, cos, sin, mesh=mesh, rules=rules)
+            x, carried, said = own(x, lp, cfg, cos, sin, mesh=mesh,
+                                   rules=rules, carried=carried, kind=kind)
     elif _takes_attention_half(cfg, kind):
         # a trace tells the kinds apart by the inner scope, with no shape
         with jax.named_scope("attention"), \
@@ -824,7 +834,9 @@ def _layer(x, lp, cfg: LlamaConfig, cos, sin, mesh=None, rules=None, tp=None,
         y, stats = _family(cfg).feed_forward(h, lp, cfg, mesh=mesh,
                                              rules=rules, tp=tp, kind=kind)
         x = _residual(x, y, cfg)
-    return x, stats
+    if said is not None:
+        stats = {**(stats or {}), **said}
+    return x, stats, carried
 
 
 def _parallel_layer(x, lp, cfg: LlamaConfig, cos, sin, mesh, rules, tp, kind):
@@ -936,6 +948,18 @@ def _act_constraint(mesh, rules, tp=None):
     return lambda x: jax.lax.with_sharding_constraint(x, sh)
 
 
+def _join_stats(runs: list):
+    """The runs' stacked statistics joined in the layers' order. Runs that
+    report the same things are concatenated leaf by leaf; where they
+    differ (a run of layers that report a term another run's lack), each
+    name over the runs that report it."""
+    if all(jax.tree.structure(s) == jax.tree.structure(runs[0])
+           for s in runs):
+        return jax.tree.map(lambda *s: jnp.concatenate(s), *runs)
+    return {name: jnp.concatenate([s[name] for s in runs if name in s])
+            for name in sorted(set().union(*runs))}
+
+
 def forward(params, tokens, cfg: LlamaConfig, pos_offset=0, mesh=None,
             rules=None):
     """Teacher-forced logits. tokens: [B, S] int32 -> [B, S, vocab] f32.
@@ -972,6 +996,11 @@ def _forward(params, tokens, cfg: LlamaConfig, pos_offset=0, mesh=None,
     stats, the residual stream BEFORE the final norm, ``run``: (kind, x,
     stack) -> (x, stats), the scan of a stack of layers of one kind by the
     body, the tables and the shardings this forward used).
+
+    What a family's attention half hands on to the layers after it
+    (``_family``: ``carried_init``, ``hands_on``) travels beside x through
+    the runs and across the layer checkpoint, by one mechanism for any
+    family; a family that hands nothing on carries x alone.
 
     ``params["layers"]`` is one stack of identical layers, scanned by one
     body, or, for a family with layers of several kinds (``layer_runs``:
@@ -1010,35 +1039,79 @@ def _forward(params, tokens, cfg: LlamaConfig, pos_offset=0, mesh=None,
         plan = remat_plan(cfg, params, B, S, step_memory(), mesh)
         _say_remat_plan(plan)
 
+    family = _family(cfg)
+    # what the attention halves hand from layer to layer beside x (None:
+    # nothing, and the scans carry x alone)
+    held = getattr(family, "carried_init", lambda *_: None)(cfg, B, S)
+
     @functools.cache
     def body_of(kind):
         cos, sin = tables_of(kind)
 
-        def body(x, lp):
-            y, stats = _layer(x, lp, cfg, cos, sin, mesh=mesh, rules=rules,
-                              tp=tp, kind=kind)
-            return con(y), stats
+        def body(x, held, lp):
+            y, stats, held = _layer(x, lp, cfg, cos, sin, mesh=mesh,
+                                    rules=rules, tp=tp, kind=kind,
+                                    carried=held)
+            return con(y), held, stats
 
         return _checkpoint(body, cfg, plan.kept) if cfg.remat else body
 
-    def run(kind, x, stack):
+    @functools.cache
+    def step_of(kind, carries: bool):
+        """The scan's step over a kind's body, traced once a kind: with
+        nothing handed on (x alone is the carry) or with the handed value
+        carried beside x."""
+        body = body_of(kind)
+
+        def alone(x, lp):
+            y, _, stats = body(x, None, lp)
+            return y, stats
+
+        def beside(carry, lp):
+            y, handed, stats = body(*carry, lp)
+            return (y, handed), stats
+
+        return beside if carries else alone
+
+    def run_with(kind, x, held, stack):
+        """The scan of a stack of layers of one kind: (x, held, stats). A
+        kind that replaces the handed value carries it beside x; one that
+        only reads it holds it as a constant of the loop, so the scan
+        stacks no copy a layer for the backward."""
         # what lies under ``layers`` and in none of a layer's halves is
         # the loop's own: the scan's stacks, its carries, ``con``
         with jax.named_scope("layers"):
-            return jax.lax.scan(body_of(kind), x, stack)
+            if held is None:
+                x, stats = jax.lax.scan(step_of(kind, False), x, stack)
+            elif family.hands_on(cfg, kind):
+                (x, held), stats = jax.lax.scan(step_of(kind, True),
+                                                (x, held), stack)
+            else:
+                body = body_of(kind)
+
+                def reads(x, lp):
+                    y, _, stats = body(x, held, lp)
+                    return y, stats
+
+                x, stats = jax.lax.scan(reads, x, stack)
+            return x, held, stats
+
+    def run(kind, x, stack):
+        x, _, stats = run_with(kind, x, None, stack)
+        return x, stats
 
     if isinstance(params["layers"], dict):
-        x, stats = run(None, x, params["layers"])
+        x, held, stats = run_with(None, x, held, params["layers"])
     else:
-        runs = _family(cfg).layer_runs(cfg)
+        runs = family.layer_runs(cfg)
         assert len(runs) == len(params["layers"]), (runs, len(params["layers"]))
         stats = []
         for (kind, _), stack in zip(runs, params["layers"]):
-            x, s = run(kind, x, stack)
+            x, held, s = run_with(kind, x, held, stack)
             if s is not None:       # a run of dense layers reports nothing
                 stats.append(s)
         with jax.named_scope("layers"):
-            stats = jax.tree.map(lambda *s: jnp.concatenate(s), *stats)
+            stats = _join_stats(stats)
         _say_layer_plan(runs, body_of.cache_info().currsize)
     if mesh is not None and rules is not None:
         _say_tp_plan(tp, cfg, B, S)

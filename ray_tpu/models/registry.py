@@ -17,6 +17,8 @@ _FAMILIES = {
     "cohere2_moe": "ray_tpu.models.moe",
     "hybrid": "ray_tpu.models.hybrid",
     "latent": "ray_tpu.models.latent",
+    # GLM-5.2's model_type: latent.py presets "glm-5.2-ep32-l5", "tiny-glm52"
+    "glm_moe_dsa": "ray_tpu.models.latent",
     "vit": "ray_tpu.models.vit",
 }
 

@@ -114,9 +114,10 @@ def report(metrics: Dict[str, Any], *, state: Any = None) -> None:
     attrs = {"has_state": state is not None}
     if isinstance(metrics.get("step"), int):
         attrs["step"] = metrics["step"]
-    # an expert model's routing statistics (models/moe.py finish_loss)
+    # an expert model's routing statistics (models/moe.py finish_loss) and
+    # a learned selection's (models/latent.py finish_loss)
     attrs.update({k: v for k, v in metrics.items()
-                  if k.startswith("moe_") and isinstance(v, float)})
+                  if k.startswith(("moe_", "index_")) and isinstance(v, float)})
     with _tracing.span("train.report", attrs):
         _report(ctx, metrics, state)
 

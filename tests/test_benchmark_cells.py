@@ -28,7 +28,9 @@ RECIPE_KEYS = {"train": ("attn_impl", "remat", "f32_logits"),
                "train_mixed": ("attn_impl", "gmm_impl", "remat",
                                "f32_logits"),
                "train_parallel": ("attn_impl", "gmm_impl", "remat",
-                                  "f32_logits")}
+                                  "f32_logits"),
+               "train_sparse": ("attn_impl", "gmm_impl", "remat",
+                                "f32_logits")}
 # published config.json key -> the program's field
 WIDTHS = {"hidden_size": "d_model", "num_hidden_layers": "n_layers",
           "num_attention_heads": "n_heads",
@@ -53,8 +55,8 @@ def test_cell_program_config_builds_at_its_published_widths(name):
     import jax
     import jax.numpy as jnp
 
-    from benchmark import (model, model_commanda, model_glm, model_granite,
-                           model_mellum, model_moe, resolve)
+    from benchmark import (model, model_commanda, model_glm, model_glm52,
+                           model_granite, model_mellum, model_moe, resolve)
 
     cell = resolve.cell(name)
     kind, conf, recipe = cell["kind"], cell["config"], cell["train"]
@@ -63,7 +65,8 @@ def test_cell_program_config_builds_at_its_published_widths(name):
              "train_hybrid": model_granite.hybrid_config,
              "train_latent": model_glm.latent_config,
              "train_mixed": model_mellum.moe_config,
-             "train_parallel": model_commanda.moe_config}[kind]
+             "train_parallel": model_commanda.moe_config,
+             "train_sparse": model_glm52.latent_config}[kind]
     passed = {k: recipe[k] for k in RECIPE_KEYS[kind] if k in recipe}
     cfg = build(conf, **passed)
 
@@ -73,7 +76,8 @@ def test_cell_program_config_builds_at_its_published_widths(name):
               "train_mixed": model_mellum.HF_TO_FIELD,
               "train_parallel": {
                   k: f for k, f in model_commanda.HF_TO_FIELD.items()
-                  if f != "logit_scale"}}[kind]
+                  if f != "logit_scale"},
+              "train_sparse": model_glm52.HF_TO_FIELD}[kind]
     for key, field in widths.items():
         assert getattr(cfg, field) == conf[key], (name, key)
     if kind == "train_parallel":
@@ -108,6 +112,25 @@ def test_cell_program_config_builds_at_its_published_widths(name):
         assert cfg.experts_held == (conf["num_local_experts"],
                                     dep["experts_first"])
         assert cfg.kinds == tuple(conf["layer_types"][:cfg.n_layers])
+    if kind == "train_sparse":
+        # the latents' ranks, the head widths and the indexer's sizes are
+        # the published keys' (the map above); which layers select, the
+        # router's width and the experts and heads held the file's lists
+        # and its deployment
+        assert {"q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+                "qk_rope_head_dim", "v_head_dim", "index_n_heads",
+                "index_head_dim", "index_topk"} <= set(widths)
+        dep = conf["deployment"]
+        assert cfg.n_experts == dep["router_experts"]
+        assert cfg.experts_held == (conf["n_routed_experts"],
+                                    dep["experts_first"])
+        assert cfg.n_heads == dep["heads_held"]
+        assert cfg.head_dim == cfg.qk_nope_dim + cfg.qk_rope_dim == cfg.v_dim
+        assert cfg.index_full == tuple(
+            t == "full" for t in conf["indexer_types"])
+        assert cfg.rope_theta == conf["rope_parameters"]["rope_theta"]
+        assert cfg.n_mtp == 0 and cfg.router_score == "sigmoid"
+        assert cfg.shared_d_ff == conf["n_shared_experts"] * cfg.d_ff
     if kind == "train_latent":
         # the latents' ranks, the three head widths, the leading dense
         # layers and the prediction modules are the published keys' (the

@@ -223,7 +223,7 @@ def test_latent_attention_through_flash_at_a_head_of_256(fwd_dq, dkdv,
     cos, sin = llama._rope_tables(cfg.rope_theta, S, cfg.rope_dim)
 
     def mine(x, lp):
-        y = latent.attention_half(x, lp, cfg, cos, sin)
+        y = latent.attention_half(x, lp, cfg, cos, sin)[0]
         return jnp.sum(y * probe), y
 
     def plain(x, lp):
@@ -243,7 +243,8 @@ def test_latent_attention_through_flash_at_a_head_of_256(fwd_dq, dkdv,
     np.testing.assert_allclose(got[0], want[0], rtol=2e-3, atol=2e-4)
     plans = {n: a for n, a in seen}
     assert plans["mla.plan"] == {
-        "S": S, "heads": 2, "qk_nope": 192, "qk_rope": 64, "v_dim": 256,
+        "S": S, "heads": 2, "heads_held": 2, "qk_nope": 192, "qk_rope": 64,
+        "v_dim": 256,
         "q_rank": 32, "kv_rank": 16, "form": "expanded",
         "k_bytes": S * 2 * 256 * 4, "rope": "projected",
         "kv": "split_weights", "extra_columns": 3 * 64,
@@ -342,7 +343,10 @@ def test_the_attention_half_is_the_published_form(widths, dtype, tol):
         with jax.default_matmul_precision("highest"):
             return jax.jit(jax.value_and_grad(f, (0, 1), has_aux=True))(x, lp)
 
-    (_, y), (gx, glp) = run(latent.attention_half)
+    # the half also returns what it hands on and reports: nothing here
+    (_, y), (gx, glp) = run(
+        lambda *a: latent.attention_half(*a)[0])
+    assert latent.attention_half(x, lp, cfg, cos, sin)[1:] == (None, None)
     (_, want_y), (want_gx, want_glp) = run(_published_half)
     assert y.dtype == dt and set(glp) == set(want_glp) and len(glp) == 8
 
@@ -649,7 +653,8 @@ def test_plans_read_back_from_a_profile_around_a_lowering(tmp_path,
                                   "flash.bwd_plan"):
                         events.setdefault(e.name, []).append(dict(e.stats))
     assert events["mla.plan"][0] == {
-        "S": 128, "heads": 2, "qk_nope": 192, "qk_rope": 64, "v_dim": 256,
+        "S": 128, "heads": 2, "heads_held": 2, "qk_nope": 192, "qk_rope": 64,
+        "v_dim": 256,
         "q_rank": 32, "kv_rank": 16, "form": "expanded",
         "k_bytes": 2 * 128 * 2 * 256 * 4, "rope": "projected",
         "kv": "split_weights", "extra_columns": 192, "zero_columns": 128,

@@ -515,3 +515,46 @@ def test_one_device_jaxpr_is_the_program_before_the_overlap_plan(case):
         text = re.sub(r" at 0x[0-9a-f]+", "", str(closed))
         assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
             ONE_DEVICE_JAXPRS[case], sorted(counts.items())
+
+
+@pytest.mark.parametrize("remat", [True, False])
+def test_nothing_handed_on_leaves_the_scan_with_x_alone(remat):
+    """A family whose attention half hands nothing on to the next layer
+    (every family but one with a learned selection) carries x alone
+    through the layer scan, as before the mechanism was there: one carry,
+    and no handed value among the scan's constants."""
+    import jax
+
+    from ray_tpu.models import llama
+
+    cfg = llama.PRESETS["debug-125m"].replace(
+        n_layers=2, d_model=64, n_heads=2, n_kv_heads=2, d_ff=128,
+        vocab_size=256, max_seq_len=64, remat=remat)
+    params = jax.eval_shape(lambda k: llama.init_params(k, cfg),
+                            jax.random.PRNGKey(0))
+    tokens = jax.ShapeDtypeStruct((2, 33), "int32")
+    jaxpr = jax.make_jaxpr(lambda p, t: llama.loss_fn(p, {"tokens": t}, cfg))(
+        params, tokens)
+    scans = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "scan"]
+    assert len(scans) == 1
+    assert scans[0].params["num_carry"] == 1
+    assert not [v for v in scans[0].invars if str(v.aval.dtype) == "int8"]
+
+
+def test_join_stats_by_leaf_and_by_name():
+    """Runs that report the same things are joined leaf by leaf, as they
+    always were; runs that differ (a run whose layers report a term that
+    another run's lack) name by name over the runs that have it."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.models import llama
+
+    a = {"x": jnp.arange(2.0), "y": jnp.ones((2, 3))}
+    b = {"x": jnp.arange(3.0), "y": jnp.zeros((3, 3))}
+    same = llama._join_stats([a, b])
+    assert same["x"].shape == (5,) and same["y"].shape == (5, 3)
+    mixed = llama._join_stats([{"z": jnp.ones(1)}, a, {**b, "z": jnp.zeros(2)}])
+    assert sorted(mixed) == ["x", "y", "z"]
+    np.testing.assert_array_equal(mixed["z"], [1.0, 0.0, 0.0])
+    np.testing.assert_array_equal(mixed["x"], [0.0, 1.0, 0.0, 1.0, 2.0])

@@ -165,6 +165,51 @@ def test_grouped_matmul_pallas_at_several_k_and_n_tiles(sizes, monkeypatch):
             assert float(jnp.abs(grads[1][i]).max()) == 0.0
 
 
+@pytest.mark.parametrize("sizes", [[16, 16, 16, 16], [5, 0, 40, 19]])
+def test_grouped_matmul_pallas_at_three_k_tiles(sizes, monkeypatch):
+    """GLM-5.2's experts are [6144, 2048]: ``gmm`` walks THREE K tiles of
+    2,048 in one call (two is the most of any other cell). The same here
+    at tiles of 128: K 384 is three tiles, N 256 two, against
+    ``ragged_dot``: values and both gradients, uneven and empty groups."""
+    from ray_tpu.ops import grouped_matmul as gm
+
+    monkeypatch.setattr(gm, "GMM_TILING", (16, 128, 128))
+    monkeypatch.setattr(gm, "TGMM_TILING", (16, 128, 128))
+    m, k, n, e = 64, 384, 256, 4
+    assert gm._fit(gm.GMM_TILING, m, k, n, 4, halve_n=True) == (16, 128, 128)
+    lhs = jax.random.normal(jax.random.PRNGKey(0), (m, k))
+    rhs = jax.random.normal(jax.random.PRNGKey(1), (e, k, n)) * k ** -0.5
+    gs = jnp.asarray(sizes, jnp.int32)
+
+    def f(impl):
+        def loss(a, w):
+            out = grouped_matmul(a, w, gs, impl=impl)
+            return (out * jnp.cos(out)).sum(), out
+        (_, out), grads = jax.value_and_grad(loss, argnums=(0, 1),
+                                             has_aux=True)(lhs, rhs)
+        return out, grads
+
+    want, want_g = f("xla")
+    out, grads = f("pallas")
+    np.testing.assert_allclose(out, want, rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(grads[0], want_g[0], rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(grads[1], want_g[1], rtol=1e-4, atol=1e-4)
+    for i, s in enumerate(sizes):
+        if s == 0:
+            assert float(jnp.abs(grads[1][i]).max()) == 0.0
+
+
+def test_the_tiles_at_glm52s_expert_widths():
+    """[R, 6144] x [8, 6144, 2048] in bf16: what ``_fit`` gives ``gmm`` and
+    ``tgmm`` there: three K tiles of 2,048."""
+    from ray_tpu.ops import grouped_matmul as gm
+
+    tm, tk, tn = gm._fit(gm.GMM_TILING, 16384, 6144, 2048, 2, halve_n=True)
+    assert 6144 % tk == 0 and 6144 // tk == 3, (tm, tk, tn)
+    tm, tk, tn = gm._fit(gm.TGMM_TILING, 16384, 6144, 2048)
+    assert 6144 % tk == 0 and 2048 % tn == 0, (tm, tk, tn)
+
+
 def test_the_tiles_at_command_a_pluss_expert_widths():
     """[R, 4096] x [8, 4096, 4096] in bf16: ``gmm`` takes two K tiles of
     2,048 and halves N to 1,024 (whole, 2 x (256 x 2048 + 2048 x 2048) x 2

@@ -312,15 +312,18 @@ def test_the_manifest_lists_the_expert_cells_for_remat_kept_gb():
     with open(os.path.join(ROOT, "benchmark", "layer_metrics",
                            "remat_kept_gb.json")) as f:
         spec = json.load(f)
-    entry = manifest["per_layer"][-1]
-    assert entry["name"] == "remat_kept_gb"
+    entry = next(m for m in manifest["per_layer"]
+                 if m["name"] == "remat_kept_gb")
     assert (entry["unit"], entry["better"], entry["moves"]) == (
         "GB", "higher", "train_tok_s_chip")
     kinds = {w["name"]: json.load(open(os.path.join(
         ROOT, "benchmark", "workloads", w["name"] + ".json")))["kind"]
         for w in manifest["workloads"]}
-    assert entry["workloads"] == [n for n, k in kinds.items()
-                                  if k in spec["kinds"]]
-    assert len(entry["workloads"]) == 5
+    # the five expert cells of the kinds the metric's file names, then
+    # (appended, as a manifest grows) the cells of later kinds whose
+    # ``train.report`` span carries the same attribute
+    named = [n for n, k in kinds.items() if k in spec["kinds"]]
+    assert entry["workloads"][:len(named)] == named and len(named) == 5
+    assert set(entry["workloads"][5:]) <= {"train-glm52-ep32-s16384-b1"}
     assert (spec["reader"], spec["span"], spec["attr"]) == (
         "host_span", "train.report", "moe_remat_kept_gb")

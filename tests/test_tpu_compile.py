@@ -351,7 +351,11 @@ GMM_WIDTHS = {"olmoe": (131072, 64, 2048, 1024),
               "glm": (16384, 8, 2048, 1536),
               # Command A+'s one pass of 8,192 rows over the 8 experts held,
               # 4096 x 4096: two K tiles AND several N tiles in one call
-              "commanda": (8192, 8, 4096, 4096)}
+              "commanda": (8192, 8, 4096, 4096),
+              # GLM-5.2's one pass of 16,384 rows over the 8 experts held,
+              # 6144 x 2048: THREE K tiles of 2,048, an expert's matrix
+              # 24 MiB
+              "glm52": (16384, 8, 6144, 2048)}
 
 
 @pytest.mark.parametrize("model", sorted(GMM_WIDTHS))
@@ -626,7 +630,7 @@ def _glm_attention_block(one_chip):
 
     def half(x, lp):
         with jax.named_scope("attention"):      # as llama._layer opens it
-            return latent.attention_half(x, lp, cfg, cos, sin)
+            return latent.attention_half(x, lp, cfg, cos, sin)[0]
 
     def loss(x, lp):
         y = jax.checkpoint(half)(x, lp)
@@ -1044,6 +1048,7 @@ def test_llama7b_fsdp_fits_v5e8_hbm(topo, no_persistent_cache):
 # cell -> (config module of the benchmark, its function, family): the two
 # cells whose whole step is compiled here, from the cell's own files
 _CELL_STEPS = {
+    "train-glm52-ep32-s16384-b1": ("model_glm52", "latent_config", "latent"),
     "train-commandaplus-ep16-s8192-b1": ("model_commanda", "moe_config",
                                          "moe"),
     "train-granite4hs-ep8-s8192-b2": ("model_granite", "hybrid_config",
@@ -1138,3 +1143,93 @@ def test_granite_step_keeps_the_parents_list(topo, on_chip_branch,
         ("", 0, "no room")]
     assert abs(plan - 15_310_881_280) < 1e6, plan
     assert compiled.as_text().count(".remat") == 0
+
+
+# --- GLM-5.2: attention over a learned set (ops/sparse_attention.py) -------
+GLM52_ATTENTION = (1, 16384, 32, 256)      # the cell's B, S, heads held, D
+
+
+def test_sparse_attention_calls_compile_at_the_glm52_cells_shape(
+        one_chip, on_chip_branch):
+    """The four Mosaic calls of the attention over a set (forward, the
+    head-mean probabilities, dQ, dK/dV) lower for a v5e at the cell's
+    shape, sets of 2,048 as a 0/1 int8 square, within the 16 MiB a call
+    gets that asks for no more; the plans' fields are what the trace and
+    the roofline reader go by."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import sparse_attention as sa
+
+    B, S, H, D = GLM52_ATTENTION
+    q = _sds((B, S, H, D), jnp.bfloat16, one_chip)
+    keep = _sds((B, S, S), jnp.int8, one_chip)
+
+    def loss(q, k, v, keep):
+        o, p = sa.sparse_attention(q, k, v, keep, with_probs=True)
+        return o.astype(jnp.float32).sum(), p
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                      has_aux=True)).lower(
+        q, q, q, keep).compile().as_text()
+    assert text.count("tpu_custom_call") == 4, text[:2000]
+    for scope in ("sparse.fwd.mask", "sparse.probs.mask", "sparse.dq.mask",
+                  "sparse.dkdv.mask"):
+        assert scope in text, scope
+    for call in ("fwd", "probs", "dq", "dkdv"):
+        plan = sa.plan(B=B, H=H, S=S, T=S, D=D, dtype=jnp.bfloat16,
+                       call=call)
+        assert {"path", "call", "block_q", "block_k", "vmem_bytes",
+                "grid_steps", "live_steps"} <= set(plan)
+        assert plan["vmem_bytes"] <= 16 * 2 ** 20, plan
+
+
+def test_index_score_blocks_and_the_selection_fit_at_the_cells_shape(
+        one_chip, no_persistent_cache):
+    """One block of the indexer's scores and its exact selection at the
+    cell's shape (2,048 queries' 32 heads of 128 over all 16,384 keys):
+    the per-head products of 256 queries are alive at once, not the
+    block's (4.3e9 bytes), and no sort is in the program."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import latent
+
+    rows, S, IH, ID, topk = 2048, 16384, 32, 128, 2048
+
+    def block(qI, w, kI):
+        return latent.select(latent.index_scores(qI, w, kI), S - rows,
+                             topk)
+
+    compiled = jax.jit(block).lower(
+        _sds((rows, IH, ID), jnp.bfloat16, one_chip),
+        _sds((rows, IH), jnp.float32, one_chip),
+        _sds((S, ID), jnp.bfloat16, one_chip)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1.5e9, mem.temp_size_in_bytes
+    text = compiled.as_text()
+    assert " sort(" not in text and "topk" not in text.lower()
+
+
+def test_glm52_step_fits_with_its_set_kept_and_selects_once(
+        topo, on_chip_branch, monkeypatch):
+    """The GLM-5.2 cell's step at 32 heads held, a stack a layer: the plan
+    stays under 15.8e9 bytes (13,276,408,320 when this was written; whole
+    runs as stacks planned 17,861,688,832, over the chip), the layer
+    checkpoint keeps each full layer's set so that no replay selects
+    again (the selection's counting loop is in the program twice, once a
+    full layer, not four times), and every kind of Mosaic call is there."""
+    compiled, plan, said = _compile_cell_step(
+        "train-glm52-ep32-s16384-b1", topo, monkeypatch)
+    assert [(p["kept"], p["why"]) for p in said] == [("", "no room")]
+    assert plan <= 15.8e9, plan
+    text = compiled.as_text()
+    # (LI is computed a sequence at a time: its scope stands under vmap)
+    for scope in ("sparse.fwd.mask", "sparse.probs.mask", "sparse.dq.mask",
+                  "sparse.dkdv.mask", "attention/indexer",
+                  "attention/select", "attention/vmap(index_loss)"):
+        assert scope in text, scope
+    replayed = [ln for ln in text.splitlines()
+                if "rematted_computation/attention/select" in ln
+                and "while" in ln]
+    assert not replayed, replayed[:2]
