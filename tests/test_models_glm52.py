@@ -27,6 +27,24 @@ def _load(kind: str, name: str) -> dict:
         return json.load(f)
 
 
+@pytest.fixture(autouse=True)
+def few_mappings():
+    """Every test of this file starts from an empty executable cache.
+    ``_followed`` runs the model eagerly: each call compiles its four
+    scans and its eager ops anew (a fresh jaxpr misses jit's cache), and
+    every loaded CPU executable holds memory mappings: the seven
+    wrong-model cases alone took a process from 175 to 9,450 of them, and
+    ``jax.clear_caches()`` back to 693. Under the driver's six workers a
+    worker comes to this file with the mappings of the files it ran
+    before; at the kernel's 65,530 (``vm.max_map_count``) XLA's next
+    compile-and-load (or load from the persistent cache) dies of a
+    segmentation fault, which xdist reports as the case it was running:
+    ``[P not normalised]``, the file's last eager case, in the driver's
+    run of PR 45's tree and in mine of this one."""
+    jax.clear_caches()
+    yield
+
+
 @pytest.fixture(scope="module")
 def tiny():
     import sys

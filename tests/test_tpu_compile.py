@@ -1179,8 +1179,9 @@ def test_sparse_attention_calls_compile_at_the_glm52_cells_shape(
     for call in ("fwd", "probs", "dq", "dkdv"):
         plan = sa.plan(B=B, H=H, S=S, T=S, D=D, dtype=jnp.bfloat16,
                        call=call)
-        assert {"path", "call", "block_q", "block_k", "vmem_bytes",
-                "grid_steps", "live_steps"} <= set(plan)
+        assert {"path", "call", "block_q", "block_k", "span", "in_flight",
+                "vmem_bytes", "grid_steps", "live_steps"} <= set(plan)
+        assert plan["span"] > 1, plan     # a span of blocks a grid step
         assert plan["vmem_bytes"] <= 16 * 2 ** 20, plan
 
 
