@@ -80,7 +80,18 @@ else: with P[t, s] = sg(mean_h p[h, t, s]) on Sel_t,
                                           - log softmax_{Sel_t}(I[t, .])[s])
 
 joins the loss with ``index_loss_weight``; ``W_I*`` get gradient from LI
-only and every other leaf from the rest only.
+only and every other leaf from the rest only. LI's cotangent is a constant
+of the step, so LI makes its gradient where it makes its value
+(``_index_loss``, a ``jax.custom_vjp``): the forward rule computes a block
+of queries' scores once, from them the block's terms and d LI / d I =
+(softmax_Sel(I) sum_s P - P) / S on the set, goes straight back through
+``index_scores`` to d qI, d w and d kI and names them (``INDEX_GRADS``);
+the backward rule only scales them by the cotangent that arrives. The layer
+checkpoint keeps those names beside the set, as it keeps the sparse call's
+``o`` and ``lse``: the replay of a full layer finds them saved, so P and
+LI's scores are computed once a full layer and step and never in a replay
+(the indexer's projections are: their weights' gradients are made in the
+backward from the kept three). An evaluation computes LI alone.
 
 Layers are a list of runs (``layer_runs``): ``n_dense`` leading layers
 whose feed-forward is a SwiGLU of ``dense_d_ff``, then sparse layers
@@ -205,10 +216,13 @@ PRESETS: Dict[str, LatentConfig] = {
         dtype=jnp.bfloat16, param_dtype=jnp.bfloat16),
 }
 
-# beside the expert layer's routes, the set a full layer chose: the replay
-# of a layer never scores and selects again
+# beside the expert layer's routes, the set a full layer chose (the replay
+# of a layer never scores and selects again) and LI's gradients to the index
+# queries, head weights and keys, made where LI is (``_index_loss``: the
+# replay computes neither the head-mean probabilities nor LI's scores)
 INDEX_SET = "index_set"
-REMAT_SAVED = _moe.REMAT_SAVED + (INDEX_SET,)
+INDEX_GRADS = ("index_grad_q", "index_grad_w", "index_grad_k")
+REMAT_SAVED = _moe.REMAT_SAVED + (INDEX_SET,) + INDEX_GRADS
 expert_rows = _moe.expert_rows
 # rows of queries whose index scores are alive at once ([rows, keys]
 # float32), and of those whose per-head products are ([heads, rows, keys])
@@ -226,8 +240,18 @@ def _selects(kind: str) -> bool:
     return kind.endswith(".full")
 
 
+def index_grad_bytes(cfg: "LatentConfig", rows: int) -> int:
+    """Bytes of a full layer's ``INDEX_GRADS`` over ``rows`` tokens: the
+    index queries' and keys' in the activations' dtype, the head weights'
+    in float32."""
+    item = jnp.dtype(cfg.dtype).itemsize
+    return rows * ((cfg.index_heads + 1) * cfg.index_dim * item
+                   + cfg.index_heads * 4)
+
+
 def remat_saved_bytes(cfg: "LatentConfig", kind, rows: int) -> int:
-    own = rows * min(rows, cfg.max_seq_len) if _selects(kind) else 0
+    own = rows * min(rows, cfg.max_seq_len) + index_grad_bytes(cfg, rows) \
+        if _selects(kind) else 0
     return own + (0 if _ffn(kind) == "dense"
                   else _moe.remat_saved_bytes(cfg, kind, rows))
 
@@ -465,15 +489,22 @@ def index_plan(cfg: LatentConfig, B: int, S: int, kind: str) -> dict:
     full) or reads (shared), the form of the sparse attention (``mask``:
     a membership test a pair, nothing gathered), the pairs the sets hold
     beside the causal ones, the rows of queries whose scores are alive at
-    once and the set's bytes."""
+    once, the set's bytes, and where LI's gradient is made
+    (``index_grad`` "forward": with its value, in every full layer of
+    every step) and what the layer checkpoint keeps for it
+    (``index_grad_kept_bytes``: ``INDEX_GRADS``; 0 in a shared layer)."""
     k = min(cfg.index_topk, S)
+    full = _selects(kind)
     return {"S": S, "topk": cfg.index_topk, "index_heads": cfg.index_heads,
             "index_dim": cfg.index_dim,
-            "kind": "full" if _selects(kind) else "shared", "form": "mask",
+            "kind": "full" if full else "shared", "form": "mask",
             "selected_pairs": B * (k * (k + 1) // 2 + (S - k) * k),
             "causal_pairs": B * S * (S + 1) // 2,
             "score_block_rows": min(S, SCORE_BLOCK_ROWS),
-            "set_bytes": B * S * S, "gathered_bytes_fwd": 0}
+            "set_bytes": B * S * S, "gathered_bytes_fwd": 0,
+            "index_grad": "forward",
+            "index_grad_kept_bytes": index_grad_bytes(cfg, B * S) if full
+            else 0}
 
 
 def _index_rotary(x, cos, sin):
@@ -596,36 +627,92 @@ def _select_set(qI, weight, kI, topk: int):
         return jnp.concatenate(out, axis=0)
 
 
+def _block_terms(scores, p, on):
+    """A block of queries' share of LI's sum from its index scores [rows,
+    keys] float32, the attention's probabilities p and its set ``on``
+    (int8): (the sum of P (log P - log softmax_Sel(I)) over the block's
+    pairs, d of it / d scores = softmax_Sel(I) x sum_s P - P on the set
+    and 0 off it)."""
+    on = on != 0
+    scores = jnp.where(on, scores, -1e30)
+    top = jnp.max(scores, axis=1, keepdims=True)
+    norm = top + jnp.log(jnp.sum(
+        jnp.where(on, jnp.exp(scores - top), 0.0), axis=1, keepdims=True))
+    p = jnp.where(on, p, 0.0)
+    total = jnp.sum(jnp.where(
+        p > 0, p * (jnp.log(jnp.maximum(p, 1e-37)) - (scores - norm)), 0.0))
+    p = jnp.where(p > 0, p, 0.0)
+    soft = jnp.where(on, jnp.exp(scores - norm), 0.0)
+    return total, soft * jnp.sum(p, axis=1, keepdims=True) - p
+
+
+def _index_blocks(qI, weight, kI, probs, keep):
+    """(keys, the block's queries, weights, keys, probabilities and set)
+    for each of ``_score_blocks``."""
+    for at, rows, keys in _score_blocks(qI.shape[0]):
+        yield keys, (qI[at:at + rows], weight[at:at + rows], kI[:keys],
+                     probs[at:at + rows, :keys], keep[at:at + rows, :keys])
+
+
+@jax.custom_vjp
 def _index_loss(qI, weight, kI, probs, keep):
     """One sequence's LI (module docstring): the indexer's scores against
     the attention's head-mean probabilities probs [S, S] float32 on the
-    set keep [S, S] int8, a block of queries at a time, each block's
-    scores recomputed in the backward."""
-    S = qI.shape[0]
-
-    @jax.checkpoint
-    def block(q, w, k, p, on):
-        on = on != 0
-        scores = jnp.where(on, index_scores(q, w, k), -1e30)
-        top = jnp.max(scores, axis=1, keepdims=True)
-        norm = top + jnp.log(jnp.sum(
-            jnp.where(on, jnp.exp(scores - top), 0.0), axis=1,
-            keepdims=True))
-        p = jnp.where(on, p, 0.0)
-        return jnp.sum(jnp.where(
-            p > 0, p * (jnp.log(jnp.maximum(p, 1e-37)) - (scores - norm)),
-            0.0))
-
-    # the scope OUTSIDE the checkpoint, so that a trace reads
+    set keep [S, S] int8, a block of queries at a time. Differentiated, it
+    makes its gradient where it makes its value (``_index_loss_fwd``);
+    this is the value alone, an evaluation's."""
+    # the scope INSIDE the function, so that a trace reads
     # ``attention/index_loss`` as adjacent parts: the term's own pass over
-    # the scores (and its backward's) is the term's cost, not the
+    # the scores (and its gradient's) is the term's cost, not the
     # selection's
     with jax.named_scope("index_loss"):
-        total = sum(
-            block(qI[at:at + rows], weight[at:at + rows], kI[:keys],
-                  probs[at:at + rows, :keys], keep[at:at + rows, :keys])
-            for at, rows, keys in _score_blocks(S))
-    return total / S
+        total = sum(_block_terms(index_scores(q, w, k), p, on)[0]
+                    for _, (q, w, k, p, on) in _index_blocks(
+                        qI, weight, kI, probs, keep))
+    return total / qI.shape[0]
+
+
+def _index_loss_fwd(qI, weight, kI, probs, keep):
+    """LI and, for a cotangent of one, its gradients to qI, weight and kI
+    (``INDEX_GRADS``, the names the layer checkpoint keeps): LI's
+    cotangent is a constant of the step, so nothing waits for the
+    backward, and the replay of the layer finds nothing of LI to do. A
+    block's scores are computed once; from them its terms and d LI / d
+    scores; that goes straight back through ``index_scores`` (whose
+    chunks recompute their per-head products: a row's normaliser needs
+    all its keys before any gradient exists, so two passes over a row's
+    keys are inherent)."""
+    S = qI.shape[0]
+    with jax.named_scope("index_loss"):
+        total, dq, dw, dk = 0.0, [], [], jnp.zeros_like(kI)
+        for keys, (q, w, k, p, on) in _index_blocks(qI, weight, kI, probs,
+                                                    keep):
+            # one block at a time, and none before P is there: what a
+            # block reads waits for the block before it. Left alone, the
+            # scheduler computes every block's scores before the sparse
+            # call and holds them across it (the cell's step planned
+            # 14.09e9 bytes for 13.25e9: PERF.md 6, PR 47)
+            q, w, k, p, on, dk = jax.lax.optimization_barrier(
+                (q, w, k, p, on, dk))
+            scores, back = jax.vjp(index_scores, q, w, k)
+            terms, g = _block_terms(scores, p, on)
+            total = total + terms
+            a, b, c = back(g / S)
+            dq.append(a)
+            dw.append(b)
+            dk = dk.at[:keys].add(c)
+        grads = tuple(checkpoint_name(x, name) for x, name in zip(
+            (jnp.concatenate(dq), jnp.concatenate(dw), dk), INDEX_GRADS))
+    return total / S, grads
+
+
+def _index_loss_bwd(grads, g):
+    with jax.named_scope("index_loss"):
+        return (*((g * x.astype(jnp.float32)).astype(x.dtype)
+                  for x in grads), None, None)
+
+
+_index_loss.defvjp(_index_loss_fwd, _index_loss_bwd)
 
 
 def attention_half(x, lp, cfg: LatentConfig, cos, sin, mesh=None, rules=None,

