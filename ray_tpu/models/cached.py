@@ -42,6 +42,8 @@ def _refuse_stated(cfg: LlamaConfig):
         stated.append("attention layers of several kinds")
     if cfg.parallel_block:
         stated.append("a parallel block")
+    if getattr(cfg, "one_half", False):
+        stated.append("blocks that hold one half each")
     if cfg.norm != "rms":
         stated.append(f"a {cfg.norm} norm")
     if stated:

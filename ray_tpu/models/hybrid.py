@@ -16,6 +16,18 @@ router losses ``moe.finish_loss``; embedding, the loop over the layers,
 remat and its policy, the head (tied to the embedding here) and the
 cross-entropy are ``llama.forward_with_stats`` and ``llama.loss_fn``.
 
+A second member of the family, Nemotron-H's block (``nemotron_h``;
+Nemotron 3 Nano 30B-A3B), holds ONE half (``one_half``): a block is a
+mixer ("mamba"), an attention layer ("attention") or an expert layer
+("experts") alone, x + half(rms_norm(x)), and the three kinds' parameter
+trees share nothing; its mixer's B and C come in ``mamba_groups`` groups of
+adjacent heads (head h reads group h // (H / G)), the convolution runs
+over H P + 2 G N channels and the gated norm over each group's lanes
+apart; its experts are two matrices and a squared ReLU (``expert_act``),
+routed by sigmoid scores with a bias that ``moe.post_update`` moves; its
+head is a leaf of its own (``tied_head`` False). Its published pattern has
+no two adjacent blocks of a kind: every run is one block.
+
 Layers of two kinds cannot be one stack: ``params["layers"]`` is a LIST of
 stacks, one a run of adjacent layers of one kind (``layer_runs``; the
 published pattern is [5 mamba, attention, 4 mamba] four times over: runs
@@ -27,10 +39,11 @@ The mixer, for u = rms_norm(x) [B, S, D], H heads of width P, state N:
 
     [z | xBC | dt] = u @ in_proj          (H P | H P + 2 N | H, no bias)
     xBC = silu(conv(xBC) + b)             depthwise, causal, ``mamba_conv`` taps
-    x [H, P], B [N], C [N] = split(xBC)   one group: B and C shared by all heads
+    x [H, P], B [G, N], C [G, N] = split(xBC)   G groups (Granite: one)
     dt = softplus(dt + dt_bias); A = -exp(a_log)             float32, a head
     s_t = exp(dt_t A) s_{t-1} + dt_t x_t B_t^T; y_t = s_t C_t + D x_t
-    y = rms_norm(y * silu(z)) * gate_norm                    over all H P
+                                          (head h with its group's B, C)
+    y = rms_norm(y * silu(z)) * gate_norm       over each group's H P / G
     out = y @ out_proj
 
 The recurrence is ``ops/ssd.py``: "xla" (plain einsums; the CPU, a mesh)
@@ -65,7 +78,7 @@ import jax.numpy as jnp
 
 from ray_tpu.models import llama as _ll
 from ray_tpu.models import moe as _moe
-from ray_tpu.ops.ssd import ssd_scan
+from ray_tpu.ops.ssd import _over_lanes, _per_head, ssd_scan
 from ray_tpu.util import tracing
 
 
@@ -73,17 +86,25 @@ from ray_tpu.util import tracing
 class HybridConfig(_moe.MoEConfig):
     """``n_heads``, ``n_kv_heads`` are the attention layers'; ``d_ff`` is
     the width of ONE routed expert."""
-    # one kind a layer, "mamba" or "attention"; () = every layer "mamba"
+    # one kind a layer, "mamba" or "attention" (each followed by its
+    # expert layer) or, with ``one_half``, "experts" too; () = every layer
+    # "mamba"
     layer_types: Tuple[str, ...] = ()
+    # a block is ONE of mixer, attention and expert layer, with one norm
+    # and one residual add (Nemotron-H), not a first half and its experts
+    one_half: bool = False
     mamba_heads: int = 8
     mamba_head_dim: int = 16
     mamba_state: int = 16
+    # groups of adjacent heads that share a B and a C, and a gated norm
+    mamba_groups: int = 1
     mamba_conv: int = 4
     mamba_chunk: int = 64
     ssd_impl: str = "xla"               # "xla" | "pallas"
     # (the three multipliers and ``attn_scale`` are LlamaConfig's fields)
     rope: bool = False                  # no position embedding
     norm_topk: bool = True              # softmax over the K largest logits
+    tied_head: bool = True              # False: an ``lm_head`` leaf
 
     @property
     def mamba_inner(self) -> int:
@@ -104,18 +125,50 @@ PRESETS: Dict[str, HybridConfig] = {
         layer_types=("mamba", "mamba", "attention", "mamba"),
         embedding_multiplier=12.0, residual_multiplier=0.22,
         logits_scaling=16.0, attn_scale=1.0 / 16),
+    # Nemotron-H's block at the CPU tests' size: the pattern MEM*EMEM*E
+    # ([M E] [M *] ... no two adjacent alike), 4 mixer heads of 16 in 2
+    # groups, 4 query heads over 2 KV heads, 2 of 8 experts held, an
+    # expert width that is no multiple of a tile
+    "tiny-nemotron": HybridConfig(
+        vocab_size=256, d_model=48, n_layers=10, n_heads=4, n_kv_heads=2,
+        head_width=16, d_ff=24, max_seq_len=128, n_experts=8, top_k=2,
+        shared_d_ff=40, experts_held=(2, 0), one_half=True,
+        tied_head=False, expert_act="relu2",
+        router_score="sigmoid", route_scale=2.5, router_aux_weight=0.0001,
+        router_z_weight=0.0, mamba_heads=4, mamba_head_dim=16,
+        mamba_state=16, mamba_groups=2, mamba_chunk=8,
+        layer_types=tuple({"M": "mamba", "E": "experts", "*": "attention"}[c]
+                          for c in "MEM*EMEM*E")),
 }
 
 # what a mamba layer has not of the expert family's tree: the attention half
 _ATTENTION_ONLY = ("attn_norm", "wq", "wk", "wv", "wo")
+_KINDS = ("mamba", "attention", "experts")
 
 # what the layer checkpoint keeps beside the layer's input and flash's
 # residuals (llama._checkpoint): the expert layer's routes; of a mixer
 # nothing, its scan runs again
 REMAT_SAVED = _moe.REMAT_SAVED
-remat_saved_bytes = _moe.remat_saved_bytes
-remat_offers = _moe.remat_offers
 expert_rows = _moe.expert_rows
+post_update = _moe.post_update
+RULE_LEAVES = _moe.RULE_LEAVES
+
+
+def halves(cfg: HybridConfig, kind) -> Tuple[bool, bool]:
+    """(whether a block of ``kind`` runs a first half: its mixer or its
+    attention; whether it runs the expert layer), for ``llama._layer``."""
+    return (kind != "experts", kind == "experts") if cfg.one_half \
+        else (True, True)
+
+
+def remat_saved_bytes(cfg: HybridConfig, kind, tokens: int) -> int:
+    return _moe.remat_saved_bytes(cfg, kind, tokens) \
+        if halves(cfg, kind)[1] else 0
+
+
+def remat_offers(cfg: HybridConfig, kind, tokens: int):
+    return _moe.remat_offers(cfg, kind, tokens) if halves(cfg, kind)[1] \
+        else ()
 
 
 def layer_runs(cfg: HybridConfig) -> List[Tuple[str, int]]:
@@ -125,7 +178,7 @@ def layer_runs(cfg: HybridConfig) -> List[Tuple[str, int]]:
                          "layers")
     runs: List[Tuple[str, int]] = []
     for kind in cfg.kinds:
-        if kind not in ("mamba", "attention"):
+        if kind not in _KINDS[:3 if cfg.one_half else 2]:
             raise ValueError(f"unknown layer type {kind!r}")
         if runs and runs[-1][0] == kind:
             runs[-1] = (kind, runs[-1][1] + 1)
@@ -139,28 +192,39 @@ def _run_configs(cfg: HybridConfig):
 
 
 def _mamba_sizes(cfg: HybridConfig):
-    inner, n = cfg.mamba_inner, cfg.mamba_state
+    """(the heads' lanes H P, the convolution's channels H P + 2 G N, the
+    in-projection's columns z | xBC | dt)."""
+    inner, n = cfg.mamba_inner, cfg.mamba_state * cfg.mamba_groups
     return inner, inner + 2 * n, 2 * inner + 2 * n + cfg.mamba_heads
+
+
+def _of_kind(lay: dict, kind: str, cfg: HybridConfig, mixer: dict) -> dict:
+    """A stack of ``kind``'s leaves from the expert family's ``lay`` (an
+    attention half and an expert layer) and the ``mixer``'s: both halves
+    of a block, or with ``one_half`` the kind's own alone."""
+    first, second = halves(cfg, kind)
+    expert = {k: v for k, v in lay.items() if k not in _ATTENTION_ONLY}
+    out = dict(expert) if second else {}
+    if first:
+        out.update(mixer if kind == "mamba" else
+                   {k: lay[k] for k in _ATTENTION_ONLY})
+    return out
 
 
 def param_specs(cfg: HybridConfig) -> Dict[str, Any]:
     L = ("layers",)
-    runs = []
-    for kind, run in _run_configs(cfg):
-        lay = _moe.param_specs(run)["layers"]
-        if kind == "mamba":
-            for w in _ATTENTION_ONLY:
-                del lay[w]
-            lay.update({
-                "mix_norm": L + ("embed_nr",),
-                "in_proj": L + ("embed", "mlp"),
-                "conv_w": L + (None, "mlp"), "conv_b": L + ("mlp",),
-                "dt_bias": L + (None,), "a_log": L + (None,),
-                "d_skip": L + (None,), "gate_norm": L + ("mlp",),
-                "out_proj": L + ("mlp", "embed")})
-        runs.append(lay)
+    mixer = {
+        "mix_norm": L + ("embed_nr",),
+        "in_proj": L + ("embed", "mlp"),
+        "conv_w": L + (None, "mlp"), "conv_b": L + ("mlp",),
+        "dt_bias": L + (None,), "a_log": L + (None,),
+        "d_skip": L + (None,), "gate_norm": L + ("mlp",),
+        "out_proj": L + ("mlp", "embed")}
+    runs = [_of_kind(_moe.param_specs(run.replace(tied_head=False))["layers"],
+                     kind, cfg, mixer) for kind, run in _run_configs(cfg)]
+    head = {} if cfg.tied_head else {"lm_head": ("embed", "vocab")}
     return {"embed": ("vocab", "embed"), "layers": runs,
-            "final_norm": ("embed_nr",)}
+            "final_norm": ("embed_nr",), **head}
 
 
 def init_params(key, cfg: HybridConfig) -> Dict[str, Any]:
@@ -171,36 +235,40 @@ def init_params(key, cfg: HybridConfig) -> Dict[str, Any]:
     pd = cfg.param_dtype
     D, H = cfg.d_model, cfg.mamba_heads
     inner, conv_dim, proj = _mamba_sizes(cfg)
-    runs = []
-    for i, (kind, run) in enumerate(_run_configs(cfg)):
+
+    def stack(kind, run, i):
         k = jax.random.fold_in(key, 100 + i)
-        lay = _moe.init_params(k, run.replace(vocab_size=1))["layers"]
-        if kind == "mamba":
-            for w in _ATTENTION_ONLY:
-                del lay[w]
-            n = run.n_layers
-            ks = jax.random.split(jax.random.fold_in(k, 3), 5)
-            step = jnp.exp(jax.random.uniform(
-                ks[2], (n, H), minval=jnp.log(1e-3), maxval=jnp.log(1e-1)))
-            lay.update({
-                "mix_norm": jnp.ones((n, D), pd),
-                "in_proj": jax.random.normal(ks[0], (n, D, proj), pd)
-                * D ** -0.5,
-                "conv_w": jax.random.normal(
-                    ks[1], (n, cfg.mamba_conv, conv_dim), pd)
-                * cfg.mamba_conv ** -0.5,
-                "conv_b": jnp.zeros((n, conv_dim), pd),
-                "dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(pd),
-                "a_log": jnp.log(jax.random.uniform(
-                    ks[3], (n, H), minval=1.0, maxval=16.0)).astype(pd),
-                "d_skip": jnp.ones((n, H), pd),
-                "gate_norm": jnp.ones((n, inner), pd),
-                "out_proj": jax.random.normal(ks[4], (n, inner, D), pd)
-                * inner ** -0.5})
-        runs.append(lay)
+        lay = _moe.init_params(k, run.replace(
+            vocab_size=1, tied_head=False))["layers"]
+        if kind != "mamba":
+            return _of_kind(lay, kind, cfg, {})
+        n = run.n_layers
+        ks = jax.random.split(jax.random.fold_in(k, 3), 5)
+        step = jnp.exp(jax.random.uniform(
+            ks[2], (n, H), minval=jnp.log(1e-3), maxval=jnp.log(1e-1)))
+        return _of_kind(lay, kind, cfg, {
+            "mix_norm": jnp.ones((n, D), pd),
+            "in_proj": jax.random.normal(ks[0], (n, D, proj), pd)
+            * D ** -0.5,
+            "conv_w": jax.random.normal(
+                ks[1], (n, cfg.mamba_conv, conv_dim), pd)
+            * cfg.mamba_conv ** -0.5,
+            "conv_b": jnp.zeros((n, conv_dim), pd),
+            "dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(pd),
+            "a_log": jnp.log(jax.random.uniform(
+                ks[3], (n, H), minval=1.0, maxval=16.0)).astype(pd),
+            "d_skip": jnp.ones((n, H), pd),
+            "gate_norm": jnp.ones((n, inner), pd),
+            "out_proj": jax.random.normal(ks[4], (n, inner, D), pd)
+            * inner ** -0.5})
+
+    head = {} if cfg.tied_head else {"lm_head": jax.random.normal(
+        jax.random.fold_in(key, 1), (D, cfg.vocab_size), pd) * D ** -0.5}
     return {"embed": jax.random.normal(jax.random.fold_in(key, 0),
                                        (cfg.vocab_size, D), pd) * 0.02,
-            "layers": runs, "final_norm": jnp.ones((D,), pd)}
+            "layers": [stack(kind, run, i) for i, (kind, run) in enumerate(
+                _run_configs(cfg))],
+            "final_norm": jnp.ones((D,), pd), **head}
 
 
 def num_params(cfg: HybridConfig) -> int:
@@ -210,11 +278,16 @@ def num_params(cfg: HybridConfig) -> int:
                  + 2 * D * cfg.n_kv_heads * cfg.head_dim)
     mamba = (D * proj + (cfg.mamba_conv + 1) * conv_dim + 3 * H + inner
              + inner * D)
-    experts = (D * cfg.n_experts + 3 * cfg.n_held * D * cfg.d_ff
-               + 3 * D * cfg.shared_d_ff)
-    return cfg.vocab_size * D + D + sum(
-        2 * D + experts + (mamba if kind == "mamba" else attention)
-        for kind in cfg.kinds)
+    each = len(_moe._matrices(cfg)) + 1            # matrices an expert
+    experts = (D * cfg.n_experts + each * cfg.n_held * D * cfg.d_ff
+               + each * D * cfg.shared_d_ff
+               + (cfg.n_experts if _moe._has_bias(cfg) else 0))
+    total = cfg.vocab_size * D * (1 if cfg.tied_head else 2) + D
+    for kind in cfg.kinds:
+        first, second = halves(cfg, kind)
+        total += (D + (mamba if kind == "mamba" else attention)) * first \
+            + (D + experts) * second
+    return total
 
 
 def _causal_conv(x, w, b):
@@ -287,24 +360,39 @@ def _gated(y, xs, z, d_skip):
     return y * jax.nn.silu(z.astype(f32))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _gated_norm(y, xs, z, d_skip, gate_norm, eps):
-    """The skip, the gate and the RMS norm over all H P:
-    rms_norm((y + D xs) silu(z)) gate_norm, [B, S, H P] in y's type."""
+def _group_mean(a, groups: int):
+    """The mean of a [B, S, H P] float32 over each group's lanes, on every
+    lane of the group ([B, S, 1] for the one group: it broadcasts). By
+    group it is two products with a 0/1 matrix, as a head's step reaches
+    its lanes in ``ops/ssd.py``: a reshape to [B, S, G, lanes] and a
+    broadcast back are written out as float32 arrays of their own (three
+    relayout copies of 268 MB a mixer block at the cell's shape)."""
+    if groups == 1:
+        return jnp.mean(a, axis=-1, keepdims=True)
+    lanes = a.shape[-1] // groups
+    return _over_lanes(_per_head(a, groups) * (1.0 / lanes), lanes)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _gated_norm(y, xs, z, d_skip, gate_norm, eps, groups=1):
+    """The skip, the gate and the RMS norm over all H P, or over each of
+    ``groups`` groups' lanes apart: rms_norm((y + D xs) silu(z))
+    gate_norm, [B, S, H P] in y's type."""
     v = _gated(y, xs, z, d_skip)
-    r = jax.lax.rsqrt(jnp.mean(v * v, axis=-1, keepdims=True) + eps)
+    r = jax.lax.rsqrt(_group_mean(v * v, groups) + eps)
     return jax.lax.optimization_barrier(
         (v * r).astype(y.dtype) * gate_norm.astype(y.dtype))
 
 
-def _gated_norm_fwd(y, xs, z, d_skip, gate_norm, eps):
-    return (_gated_norm(y, xs, z, d_skip, gate_norm, eps),
+def _gated_norm_fwd(y, xs, z, d_skip, gate_norm, eps, groups):
+    return (_gated_norm(y, xs, z, d_skip, gate_norm, eps, groups),
             (y, xs, z, d_skip, gate_norm))
 
 
-def _gated_norm_bwd(eps, res, g):
+def _gated_norm_bwd(eps, groups, res, g):
     """Two passes over the rows. The first leaves a row's two scalars:
     r = rsqrt(mean v^2 + eps) and m = mean(dn v), [B, S, 1] float32. The
+    (a group's two, on the group's lanes, where the norm is by group). The
     second computes v again (from values ``_again`` sets apart: shared with
     the first pass they would be written out in float32) and writes dy,
     dxs, dz in their own types and the sums d d_skip [H], d gate_norm
@@ -313,8 +401,8 @@ def _gated_norm_bwd(eps, res, g):
     f32, dt_ = jnp.float32, y.dtype
     scale = gate_norm.astype(f32)
     v = _gated(y, xs, z, d_skip)
-    r = jax.lax.rsqrt(jnp.mean(v * v, axis=-1, keepdims=True) + eps)
-    m = jnp.mean(g.astype(f32) * scale * v, axis=-1, keepdims=True)
+    r = jax.lax.rsqrt(_group_mean(v * v, groups) + eps)
+    m = _group_mean(g.astype(f32) * scale * v, groups)
     y, xs, z, g = (_again(t) for t in (y, xs, z, g))
     skip = _lanes(d_skip, y.shape[-1])
     y = y + xs * skip
@@ -347,7 +435,9 @@ def plan(cfg: HybridConfig, B: int, S: int) -> dict:
     wide, conv = rows * inner * item, rows * conv_dim * item
     steps = rows * cfg.mamba_heads * 4
     return {
-        "path": "rules", "rows": rows,
+        "path": "rules", "rows": rows, "groups": cfg.mamba_groups,
+        "group_lanes": inner // cfg.mamba_groups,
+        "chunk": min(cfg.mamba_chunk, S), "channels": conv_dim,
         "residual_bytes": 0 if cfg.remat else conv + 3 * wide + steps,
         # xBC -> its activation; x, dt -> u and the sums twice; y, x, z ->
         # a row's scalar, the three again -> the normed rows
@@ -369,6 +459,7 @@ def mixer_half(x, lp, cfg: HybridConfig, kind: str, mesh=None):
             f"(mesh {dict(mesh.shape)}); use ssd_impl='xla' on a mesh")
     B, S, _ = x.shape
     H, P, N = cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_state
+    G = cfg.mamba_groups
     inner, conv_dim, _ = _mamba_sizes(cfg)
     dt_, f32 = cfg.dtype, jnp.float32
     tracing.instant("mixer.plan", plan(cfg, B, S))
@@ -381,13 +472,15 @@ def mixer_half(x, lp, cfg: HybridConfig, kind: str, mesh=None):
                     lp["conv_b"][:inner])
     bc = _conv_silu(zxbcdt[..., 2 * inner:inner + conv_dim],
                     lp["conv_w"][:, inner:], lp["conv_b"][inner:])
-    bm, cm = bc[..., :N], bc[..., N:]
+    bm, cm = bc[..., :G * N], bc[..., G * N:]
+    if G > 1:                       # a group's B and C: [B, S, G, N]
+        bm, cm = bm.reshape(B, S, G, N), cm.reshape(B, S, G, N)
     step = jax.nn.softplus(step.astype(f32) + lp["dt_bias"].astype(f32))
     y = ssd_scan(xs.reshape(B, S, H, P), step,
                  -jnp.exp(lp["a_log"].astype(f32)), bm, cm,
                  chunk=min(cfg.mamba_chunk, S), impl=cfg.ssd_impl)
     y = _gated_norm(y.reshape(B, S, inner), xs, z, lp["d_skip"],
-                    lp["gate_norm"], cfg.norm_eps)
+                    lp["gate_norm"], cfg.norm_eps, G)
     return _ll._residual(x, y @ _ll._dq(lp["out_proj"], dt_), cfg)
 
 

@@ -131,8 +131,6 @@ class LatentConfig(_moe.MoEConfig):
     dense_d_ff: int = 128
     router_score: str = "sigmoid"
     norm_topk: bool = True
-    # what one step moves a router's bias by (``post_update``)
-    bias_rate: float = 0.001
     n_mtp: int = 1                      # prediction modules: 0 or 1
     mtp_weight: float = 0.3             # of the second cross-entropy
     router_aux_weight: float = 0.0001   # of the sequence-wise balance loss
@@ -265,9 +263,7 @@ def further_stacks(params, cfg: "LatentConfig"):
     (llama._stacks): a further pass over the same rows."""
     return [("sparse", cfg.n_mtp, params["mtp"]["block"])] if cfg.n_mtp \
         else []
-# leaves that no gradient reaches and ``post_update`` moves: the optimizer
-# is told to leave them alone (parallel.train_step.hold_out)
-RULE_LEAVES = ("router_bias",)
+RULE_LEAVES = _moe.RULE_LEAVES
 _PROJECTIONS = ("wq", "wk", "wv", "wo")     # llama's, which MLA replaces
 
 
@@ -903,37 +899,8 @@ def finish_loss(loss, stats, cfg: LatentConfig):
     return loss, aux
 
 
-def post_update(params, aux, cfg: LatentConfig):
-    """The rule no gradient carries (DeepSeek-V3 2.1.2): after a step,
-    every router's bias moves by ``bias_rate`` towards balance, b += u x
-    sign(mean(c) - c) from the step's counts c of ALL experts, a layer.
-    (params, aux) -> (params, aux): ``router_counts`` (the statistics'
-    order: the runs' expert layers, then the prediction module's block) is
-    used up; ``moe_bias_abs_max`` and ``moe_bias_moved`` (how many biases
-    the step moved) are the rule's report."""
-    aux = dict(aux)
-    counts = aux.pop("router_counts").astype(jnp.float32)      # [layers, E]
-    step = cfg.bias_rate * jnp.sign(
-        counts.mean(axis=1, keepdims=True) - counts)
-    at, biases = 0, []
-
-    def moved(stack):
-        nonlocal at
-        if "router_bias" not in stack:
-            return stack
-        n = stack["router_bias"].shape[0]
-        biases.append(stack["router_bias"] + step[at:at + n])
-        at += n
-        return {**stack, "router_bias": biases[-1]}
-
-    params = dict(params, layers=[moved(s) for s in params["layers"]])
-    if "mtp" in params:
-        params["mtp"] = dict(params["mtp"],
-                             block=moved(params["mtp"]["block"]))
-    assert at == counts.shape[0], (at, counts.shape)
-    aux["moe_bias_abs_max"] = jnp.max(jnp.abs(jnp.concatenate(biases)))
-    aux["moe_bias_moved"] = jnp.count_nonzero(step).astype(jnp.float32)
-    return params, aux
+# the router's bias rule, where the hybrid family finds it too
+post_update = _moe.post_update
 
 
 forward = _ll.forward

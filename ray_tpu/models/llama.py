@@ -808,32 +808,39 @@ def _layer(x, lp, cfg: LlamaConfig, cos, sin, mesh=None, rules=None, tp=None,
     every layer's: (x, lp, cfg, cos, sin, carried, kind -> x, carried,
     what it reports). ``kind`` goes on to the feed-forward. A config with
     ``parallel_block`` runs the same two halves side by side from one norm
-    (``_parallel_layer``)."""
+    (``_parallel_layer``). A family whose blocks hold ONE half says which
+    (``halves``: cfg, kind -> (a first half, the feed-forward)); a block
+    without the feed-forward reports nothing."""
     if cfg.parallel_block:
         return _parallel_layer(x, lp, cfg, cos, sin, mesh, rules, tp,
                                kind) + (carried,)
     own = getattr(_family(cfg), "attention_half", None)
     named = kind in dict(cfg.attn_kinds)
     said = None
-    if own is not None:
+    first, second = getattr(_family(cfg), "halves",
+                            lambda cfg, kind: (True, True))(cfg, kind)
+    # ``first`` False: a block that is its feed-forward alone
+    if first and own is not None:
         assert tp is None, kind
         with jax.named_scope("attention"):
             x, carried, said = own(x, lp, cfg, cos, sin, mesh=mesh,
                                    rules=rules, carried=carried, kind=kind)
-    elif _takes_attention_half(cfg, kind):
+    elif first and _takes_attention_half(cfg, kind):
         # a trace tells the kinds apart by the inner scope, with no shape
         with jax.named_scope("attention"), \
                 jax.named_scope(kind) if named else contextlib.nullcontext():
             x = _attention_half(x, lp, cfg, cos, sin, mesh=mesh, rules=rules,
                                 tp=tp, kind=kind)
-    else:
+    elif first:
         with jax.named_scope("mixer"):
             x = _family(cfg).mixer_half(x, lp, cfg, kind, mesh=mesh)
-    with jax.named_scope("feed_forward"):
-        h = _norm(x, lp["ffn_norm"], cfg)
-        y, stats = _family(cfg).feed_forward(h, lp, cfg, mesh=mesh,
-                                             rules=rules, tp=tp, kind=kind)
-        x = _residual(x, y, cfg)
+    stats = None
+    if second:
+        with jax.named_scope("feed_forward"):
+            h = _norm(x, lp["ffn_norm"], cfg)
+            y, stats = _family(cfg).feed_forward(
+                h, lp, cfg, mesh=mesh, rules=rules, tp=tp, kind=kind)
+            x = _residual(x, y, cfg)
     if said is not None:
         stats = {**(stats or {}), **said}
     return x, stats, carried
@@ -897,11 +904,13 @@ def _say_tp_plan(tp, cfg: LlamaConfig, batch: int, seq: int):
 def _say_layer_plan(runs, bodies: int):
     """The instant ``hybrid.layer_plan`` of a trace, once a traced forward
     of a model whose layers are a list of runs: how many kinds of layer,
-    how many runs of adjacent layers of one kind (one scan each) and how
-    many bodies were built for them (one a kind)."""
+    how many runs of adjacent layers of one kind (one scan each), how
+    many bodies were built for them (one a kind) and the runs themselves,
+    "kind xN, ..." in the layers' order."""
     tracing.instant("hybrid.layer_plan", {
         "kinds": len({k for k, _ in runs}), "runs": len(runs),
-        "bodies": bodies, "layers": sum(n for _, n in runs)})
+        "bodies": bodies, "layers": sum(n for _, n in runs),
+        "pattern": ", ".join(f"{k} x{n}" for k, n in runs)})
 
 
 def _say_kind_plan(cfg: LlamaConfig, kind, of: AttentionKind, seq: int):

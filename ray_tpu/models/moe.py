@@ -71,7 +71,8 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.models import llama as _ll
-from ray_tpu.ops.grouped_matmul import GMM_TILING, grouped_matmul
+from ray_tpu.ops.grouped_matmul import GMM_TILING, grouped_matmul, tiles
+from ray_tpu.util import tracing
 
 
 @dataclass(frozen=True)
@@ -111,6 +112,13 @@ class MoEConfig(_ll.LlamaConfig):
     router_bias: bool = True
     # multiplies the K weights (DeepSeek's ``routed_scaling_factor``)
     route_scale: float = 1.0
+    # what one step moves a biased router's bias by (``post_update``)
+    bias_rate: float = 0.001
+    # what an expert, routed or shared, is: "swiglu", silu(x W_gate) x
+    # (x W_up) then W_down, three matrices; or "relu2" (Nemotron-H's),
+    # relu(x W_up)^2 then W_down, two: the tree has no ``we_gate`` and no
+    # ``ws_gate``
+    expert_act: str = "swiglu"
     # one name a layer where the attention layers are of several kinds
     # (``attn_kinds``: Mellum2's three window layers to one full layer);
     # () = every layer of the config's one kind, one stack
@@ -214,6 +222,28 @@ REMAT_SAVED = ("moe_route",)
 # the shared SwiGLU's two products of x, before the activation. silu(gate)
 # x up is not offered: it is elementwise work from the two
 SHARED_OFFERED = ("shared_gate", "shared_up")
+# leaves that no gradient reaches and ``post_update`` moves: the optimizer
+# is told to leave them alone (parallel.train_step.hold_out)
+RULE_LEAVES = ("router_bias",)
+
+
+def _matrices(cfg: "MoEConfig") -> Tuple[str, ...]:
+    """An expert's products of x before its activation, by the names of
+    their leaves' endings and checkpoint tags: gate and up, or up alone."""
+    if cfg.expert_act == "swiglu":
+        return ("gate", "up")
+    if cfg.expert_act == "relu2":
+        return ("up",)
+    raise ValueError(f"unknown expert_act {cfg.expert_act!r}")
+
+
+def _activation(cfg: "MoEConfig", product):
+    """The expert's activation of its products of x: ``product`` gives x's
+    product with the matrix of a name of ``_matrices``, when asked."""
+    if cfg.expert_act == "swiglu":
+        return jax.nn.silu(product("gate")) * product("up")
+    (up,) = _matrices(cfg)
+    return jnp.square(jax.nn.relu(product(up)))
 
 
 def remat_saved_bytes(cfg: "MoEConfig", kind, tokens: int) -> int:
@@ -233,7 +263,8 @@ def remat_offers(cfg: "MoEConfig", kind, tokens: int):
     """((name, bytes a layer), ...): what a layer's feed-forward offers the
     layer checkpoint beyond REMAT_SAVED, dearest replay a byte first."""
     each = tokens * cfg.shared_width * jnp.dtype(cfg.dtype).itemsize
-    return tuple((name, each) for name in SHARED_OFFERED) if each else ()
+    return tuple(("shared_" + m, each) for m in _matrices(cfg)) if each \
+        else ()
 
 
 def layer_runs(cfg: MoEConfig) -> List[Tuple[str, int]]:
@@ -275,12 +306,12 @@ def param_specs(cfg: MoEConfig) -> Dict[str, Any]:
     lay["router"] = L + ("embed", "experts")
     if _has_bias(cfg):
         lay["router_bias"] = L + ("experts",)
-    lay["we_gate"] = L + ("experts", "embed", "expert_mlp")
-    lay["we_up"] = L + ("experts", "embed", "expert_mlp")
+    for m in _matrices(cfg):
+        lay["we_" + m] = L + ("experts", "embed", "expert_mlp")
     lay["we_down"] = L + ("experts", "expert_mlp", "embed")
     if cfg.shared_d_ff:
-        lay["ws_gate"] = L + ("embed", "mlp")
-        lay["ws_up"] = L + ("embed", "mlp")
+        for m in _matrices(cfg):
+            lay["ws_" + m] = L + ("embed", "mlp")
         lay["ws_down"] = L + ("mlp", "embed")
     spec["layers"] = lay
     return spec
@@ -318,13 +349,16 @@ def init_params(key, cfg: MoEConfig) -> Dict[str, Any]:
     lay["router"] = jax.random.normal(ks[0], (L, D, E), pd) * 0.02
     if _has_bias(cfg):                      # float32 whatever the weights are
         lay["router_bias"] = jnp.zeros((L, E), jnp.float32)
-    lay["we_gate"] = jax.random.normal(ks[1], (L, H, D, F), pd) * D ** -0.5
-    lay["we_up"] = jax.random.normal(ks[2], (L, H, D, F), pd) * D ** -0.5
+    keys = {"gate": 1, "up": 2}
+    for m in _matrices(cfg):
+        lay["we_" + m] = jax.random.normal(
+            ks[keys[m]], (L, H, D, F), pd) * D ** -0.5
     lay["we_down"] = jax.random.normal(ks[3], (L, H, F, D), pd) * F ** -0.5
     if Fs:
         ks = jax.random.split(jax.random.fold_in(key, 2), 3)
-        lay["ws_gate"] = jax.random.normal(ks[0], (L, D, Fs), pd) * D ** -0.5
-        lay["ws_up"] = jax.random.normal(ks[1], (L, D, Fs), pd) * D ** -0.5
+        for m in _matrices(cfg):
+            lay["ws_" + m] = jax.random.normal(
+                ks[keys[m] - 1], (L, D, Fs), pd) * D ** -0.5
         lay["ws_down"] = jax.random.normal(ks[2], (L, Fs, D), pd) \
             * cfg.shared_d_ff ** -0.5     # the fan-in of ONE shared expert
     params["layers"] = lay
@@ -336,7 +370,8 @@ def num_params(cfg: MoEConfig) -> int:
     qk = (cfg.n_heads + cfg.n_kv_heads) * cfg.head_dim if cfg.qk_norm else 0
     bias = E if _has_bias(cfg) else 0
     return _ll.num_params(cfg.replace(d_ff=0)) + cfg.n_layers * (
-        qk + D * E + bias + 3 * cfg.n_held * D * F + 3 * D * cfg.shared_width
+        qk + D * E + bias + (len(_matrices(cfg)) + 1) * (
+            cfg.n_held * D * F + D * cfg.shared_width)
         - D * len(_absent(cfg))) - D * cfg.vocab_size * cfg.tied_head
 
 
@@ -537,16 +572,18 @@ def _held_slots(lo, rows: int, ranked, counts):
 def _held_swiglu(xs, w_rows, live, sizes, we, cfg: MoEConfig):
     """The rows of a pass through their experts: xs [rows, D] (dead rows
     0), w_rows [rows] their routing weights, we the (gate, up, down)
-    weights [held, ...] -> [rows, D], dead rows 0."""
+    weights [held, ...], or (up, down) (``expert_act``) -> [rows, D],
+    dead rows 0."""
     # the kernel leaves the rows no group covers unwritten: cleared, or
     # what is there meets a gradient of 0 and may be no number
     mm = lambda a, w: jnp.where(live, grouped_matmul(           # noqa: E731
         a, w, sizes, impl=cfg.gmm_impl), 0)
     # a row's weight multiplies it where it is narrow, before the down
     # projection (linear in its rows)
-    h = jax.nn.silu(mm(xs, we[0])) * mm(xs, we[1])
+    of = dict(zip(_matrices(cfg), we))
+    h = _activation(cfg, lambda m: mm(xs, of[m]))
     return mm((h.astype(jnp.float32) * w_rows[:, None]).astype(xs.dtype),
-              we[2])
+              we[-1])
 
 
 def _held_rows(lo, rows: int, x, weights, ranked, counts, k: int,
@@ -681,8 +718,8 @@ def _held_experts(x, weights, experts, lp, cfg: MoEConfig):
     rows, tile = held_rows(cfg, T), GMM_TILING[0]
     short = max(rows // 4 // tile * tile, min(tile, rows))
     with jax.named_scope("experts"):
-        we = tuple(_ll._dq(lp[w], dt)
-                   for w in ("we_gate", "we_up", "we_down"))
+        we = tuple(_ll._dq(lp["we_" + m], dt)
+                   for m in _matrices(cfg) + ("down",))
     # the pass's own scopes lie inside (``_held_combine_fwd``, ``_bwd``)
     with jax.named_scope("combine"):
         y = _held_combine(cfg, rows, short, x, weights, ranked, counts, we)
@@ -690,6 +727,28 @@ def _held_experts(x, weights, experts, lp, cfg: MoEConfig):
                "more_passes": _passes(counts, rows, short),
                "walked_share": _chunks(counts, rows) * (held_chunk(rows)
                                                         / rows)}
+
+
+def expert_plan(cfg: MoEConfig, tokens: int) -> dict:
+    """What an expert layer's experts are and how their grouped matmuls
+    are tiled (also the attributes of ``moe.expert_plan``, once a traced
+    body): the activation and its matrices (3: gate, up, down; 2: up,
+    down), the widths as stored (``padded_width`` 0: the config's own),
+    the experts held, the rows of a pass, and the (tm, tk, tn) the Mosaic
+    calls take for the up and the down product and for their weight
+    gradients (``ops/grouped_matmul.py`` ``tiles``; "" on the xla path)."""
+    rows, item = expert_rows(cfg, tokens), jnp.dtype(cfg.dtype).itemsize
+    said = {"act": cfg.expert_act, "matrices": len(_matrices(cfg)) + 1,
+            "width": cfg.d_ff, "shared_width": cfg.shared_width,
+            "padded_width": 0, "held": cfg.n_held, "rows": rows,
+            "path": cfg.gmm_impl}
+    for name, (k, n) in (("up", (cfg.d_model, cfg.d_ff)),
+                         ("down", (cfg.d_ff, cfg.d_model))):
+        gmm, tgmm = tiles(rows, k, n, item) if cfg.gmm_impl == "pallas" \
+            else ((), ())
+        said["gmm_" + name] = "x".join(map(str, gmm))
+        said["tgmm_" + name] = "x".join(map(str, tgmm))
+    return said
 
 
 def feed_forward(h, lp, cfg: MoEConfig, mesh=None, rules=None, tp=None,
@@ -709,6 +768,7 @@ def feed_forward(h, lp, cfg: MoEConfig, mesh=None, rules=None, tp=None,
     E, K = cfg.n_experts, cfg.top_k
     T, dt = B * S, cfg.dtype
     x = h.reshape(T, D)
+    tracing.instant("moe.expert_plan", expert_plan(cfg, T))
     # the layer's sub-scopes (PERF.md 3): router, dispatch, experts,
     # combine, shared
     with jax.named_scope("router"):
@@ -735,7 +795,7 @@ def feed_forward(h, lp, cfg: MoEConfig, mesh=None, rules=None, tp=None,
     mm = lambda w: grouped_matmul(xs, _ll._dq(lp[w], dt), sizes,   # noqa: E731
                                   impl=cfg.gmm_impl)
     with jax.named_scope("experts"):
-        h = jax.nn.silu(mm("we_gate")) * mm("we_up")
+        h = _activation(cfg, lambda m: mm("we_" + m))
     with jax.named_scope("combine"):
         y = _down_combine(cfg.gmm_impl, h, _ll._dq(lp["we_down"], dt),
                           weights, order, back, sizes)
@@ -757,10 +817,11 @@ def _finish(y, stats, x, lp, cfg: MoEConfig, logits, experts, probs, shape):
     if cfg.shared_d_ff:
         dt = cfg.dtype
         with jax.named_scope("shared"):
-            gate, up = (checkpoint_name(x @ _ll._dq(lp[w], dt), name)
-                        for w, name in zip(("ws_gate", "ws_up"),
-                                           SHARED_OFFERED))
-            shared = (jax.nn.silu(gate) * up) @ _ll._dq(lp["ws_down"], dt)
+            products = {m: checkpoint_name(x @ _ll._dq(lp["ws_" + m], dt),
+                                           "shared_" + m)
+                        for m in _matrices(cfg)}
+            shared = _activation(cfg, products.__getitem__) \
+                @ _ll._dq(lp["ws_down"], dt)
             if cfg.shared_combine not in ("sum", "average"):
                 raise ValueError(
                     f"unknown shared_combine {cfg.shared_combine!r}")
@@ -820,16 +881,54 @@ def finish_loss(loss, stats, cfg: MoEConfig):
         aux = E * jnp.sum(share * stats["prob_sum"].sum(axis=0) / rows)
         z = stats["z_sum"].sum() / rows
     per_layer = rows // counts.shape[0] * K                    # T x K
+    # a biased router's counts over all experts, for ``post_update``
+    ruled = {"router_counts": counts} if _has_bias(cfg) else {}
     if cfg.experts_held is not None:
         held = stats["held_counts"].astype(jnp.float32)        # [L, held]
         return loss + cfg.router_aux_weight * aux + cfg.router_z_weight * z, {
-            "moe_aux_loss": aux, "moe_z_loss": z,
+            "moe_aux_loss": aux, "moe_z_loss": z, **ruled,
             **held_aux(held, stats, per_layer)}
     return (loss + cfg.router_aux_weight * aux + cfg.router_z_weight * z, {
-        "moe_aux_loss": aux, "moe_z_loss": z,
+        "moe_aux_loss": aux, "moe_z_loss": z, **ruled,
         "moe_load_max_over_mean":
             counts.max().astype(jnp.float32) * E / per_layer,
         "moe_dropped": (per_layer - counts.sum(axis=1)).sum()})
+
+
+def post_update(params, aux, cfg: MoEConfig):
+    """The rule no gradient carries (DeepSeek-V3 2.1.2): after a step,
+    every router's bias moves by ``bias_rate`` towards balance, b += u x
+    sign(mean(c) - c) from the step's counts c of ALL experts, a layer.
+    (params, aux) -> (params, aux): ``router_counts`` (the statistics'
+    order: the runs' expert layers in the layers' order, then a prediction
+    module's block) is used up; ``moe_bias_abs_max`` and ``moe_bias_moved``
+    (how many biases the step moved) are the rule's report. A config whose routers have no bias reports no counts and has no rule:
+    params and aux go back as they came."""
+    if "router_counts" not in aux:
+        return params, aux
+    aux = dict(aux)
+    counts = aux.pop("router_counts").astype(jnp.float32)      # [layers, E]
+    step = cfg.bias_rate * jnp.sign(
+        counts.mean(axis=1, keepdims=True) - counts)
+    at, biases = 0, []
+
+    def moved(stack):
+        nonlocal at
+        if "router_bias" not in stack:
+            return stack
+        n = stack["router_bias"].shape[0]
+        biases.append(stack["router_bias"] + step[at:at + n])
+        at += n
+        return {**stack, "router_bias": biases[-1]}
+
+    params = dict(params, layers=[moved(s) for s in params["layers"]])
+    if "mtp" in params:
+        params["mtp"] = dict(params["mtp"],
+                             block=moved(params["mtp"]["block"]))
+    assert at == counts.shape[0], (at, counts.shape)
+    aux["moe_bias_abs_max"] = jnp.max(jnp.abs(jnp.concatenate(biases)))
+    aux["moe_bias_moved"] = jnp.count_nonzero(step).astype(jnp.float32)
+    return params, aux
 
 
 forward = _ll.forward

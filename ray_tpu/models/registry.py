@@ -16,6 +16,9 @@ _FAMILIES = {
     # Cohere's model_type: moe.py presets "command-a-plus", "tiny-commanda"
     "cohere2_moe": "ray_tpu.models.moe",
     "hybrid": "ray_tpu.models.hybrid",
+    # Nemotron-H's model_type: hybrid.py preset "tiny-nemotron" (blocks of
+    # one half, grouped mixers, two-matrix squared-ReLU experts)
+    "nemotron_h": "ray_tpu.models.hybrid",
     "latent": "ray_tpu.models.latent",
     # GLM-5.2's model_type: latent.py presets "glm-5.2-ep32-l5", "tiny-glm52"
     "glm_moe_dsa": "ray_tpu.models.latent",

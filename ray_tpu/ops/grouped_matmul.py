@@ -35,7 +35,12 @@ two of 768; megablox would run a second, half-empty tile), and ``gmm``'s N
 tile is halved while its tiles (both operands double-buffered, the result
 and its float32 accumulator) pass the 15 MiB the sweep held to (2048 x
 1536 whole is 16.7 MiB, and Mosaic refuses it). Every shape of the sweep
-keeps its tiles.
+keeps its tiles. Nemotron 3 Nano's [2688, 1856]: 2,688 = 21 x 128 goes in
+three tiles of 896, 1,856 = 29 x 64 whole in ``gmm`` (under its 2,048) and
+in ``tgmm`` as 1,024 and a ragged 832
+(``benchmark/tools/nemotron_gmm_forms.py`` times these, the parent's
+fallback (2,048 of the 2,688 with a ragged 640: Mosaic refuses it, out of
+VMEM) and weights stored at 1,920; PERF.md 6, PR 48).
 """
 
 from __future__ import annotations
@@ -59,11 +64,19 @@ _TILE_VMEM_BYTES = 15 * 2 ** 20
 
 def _even(tile: int, dim: int) -> int:
     """The tile for ``dim``: ``tile`` cut to it, or, where ``dim`` is no
-    whole number of those, ``dim`` in as many equal tiles of whole lanes."""
+    whole number of those, ``dim`` in equal tiles of whole lanes: as few
+    as cover it or, where those are no whole lanes, up to twice as many
+    (2,688 under 2,048: not two of 1,344 but three of 896). A ``dim`` that
+    no tile of whole lanes divides (1,856 = 14.5 x 128) keeps ``tile`` and
+    a ragged last one, which the kernels mask (``tgmm`` with 1,856 whole
+    is 8-13% faster alone and 0.34 MiB over the scoped VMEM inside the
+    step program: PERF.md 6, PR 48)."""
     tile = min(tile, dim)
     parts = -(-dim // tile)
-    even = dim // parts
-    return even if dim % parts == 0 and even % 128 == 0 else tile
+    for n in range(parts, 2 * parts + 1):
+        if dim % n == 0 and dim // n % 128 == 0:
+            return dim // n
+    return tile
 
 
 def _fit(tiling, m, k, n, itemsize: int = 2, halve_n: bool = False):
@@ -78,6 +91,13 @@ def _fit(tiling, m, k, n, itemsize: int = 2, halve_n: bool = False):
             + tm * tn * (itemsize + 4)) > _TILE_VMEM_BYTES:
         tn //= 2
     return tm, tk, tn
+
+
+def tiles(m: int, k: int, n: int, itemsize: int = 2):
+    """((tm, tk, tn) of ``gmm`` for [m, k] x [E, k, n], the same of
+    ``tgmm`` for its weight gradient): what the calls below take."""
+    return (_fit(GMM_TILING, m, k, n, itemsize, halve_n=True),
+            _fit(TGMM_TILING, m, k, n))
 
 
 def _backend():

@@ -6,8 +6,10 @@ size), step by step over a sequence:
 
     s_t = exp(dt_t A) s_{t-1} + dt_t x_t B_t^T        y_t = s_t C_t
 
-(x_t [P], B_t and C_t [N], shared by all heads: one group; dt_t > 0 and
-A < 0 a head; the skip ``D x_t`` is the caller's, an elementwise term).
+(x_t [P]; B_t and C_t [N] a GROUP of adjacent heads, head h of H reading
+group h // (H / G): one group shared by all heads as Granite has it, eight
+of eight heads as Nemotron 3 Nano has it; dt_t > 0 and A < 0 a head; the
+skip ``D x_t`` is the caller's, an elementwise term).
 Written in chunks of Q steps (arXiv:2405.21060, section 6), with
 ``cum_t`` the running sum of ``dt A`` inside a chunk and ``u = dt x``:
 
@@ -29,8 +31,13 @@ Two paths, chosen by the caller the way ``attn_impl`` chooses "xla" or
   VMEM scratch, so the only state that reaches HBM is what the backward
   needs: each chunk's incoming state ``h_in`` ([B, chunks, H x P, N]
   float32, 268 MB a layer at B2 x S8192), written by the forward rule and
-  read once. A Mosaic call cannot be partitioned by GSPMD; the caller
-  refuses a mesh of several devices. Interpret mode off the TPU.
+  read once. A head block lies inside ONE group (at most HEADS_PER_BLOCK
+  heads, a divisor of the group's), so an instance reads its group's B and
+  C block and computes ``C B^T`` once for all its heads; the gradients of
+  B and C leave a block apart and the caller sums each group's blocks. One
+  group is the case G = 1 of the same call. A Mosaic call cannot be
+  partitioned by GSPMD; the caller refuses a mesh of several devices.
+  Interpret mode off the TPU.
 
 The kernel's layout. x arrives as it leaves the mixer's convolution,
 [B, S, H x P], and is never transposed: a lane tile of 128 holds TWO
@@ -277,14 +284,17 @@ def _bwd_kernel(u_ref, b_ref, c_ref, col_ref, row_ref, hin_ref, dy_ref,
 
 
 def plan(*, S: int, H: int, P: int, N: int, chunk: int, dtype,
-         impl: str) -> dict:
+         impl: str, G: int = 1) -> dict:
     """The scan's block plan (also the attributes of ``ssd.plan``): how
     many heads an instance walks, the VMEM one instance of the backward
     call holds (its blocks twice, Mosaic double-buffers, the state scratch
     and the [Q, Q] float32 temporaries of a head) and the HBM bytes the
-    two calls move for one head and sequence."""
-    heads = min(HEADS_PER_BLOCK, H)
-    while H % heads:
+    two calls move for one head and sequence. A block's heads are of one
+    of the G groups."""
+    if H % G:
+        raise ValueError(f"ssd_scan: {H} heads in {G} groups")
+    heads = min(HEADS_PER_BLOCK, H // G)
+    while (H // G) % heads:
         heads -= 1
     item = jnp.dtype(dtype).itemsize
     q = chunk
@@ -297,13 +307,19 @@ def plan(*, S: int, H: int, P: int, N: int, chunk: int, dtype,
     # written and read
     hbm = S * P * item * 5 + 2 * (S // q) * P * N * 4
     return {"S": S, "chunk": q, "heads_per_block": heads, "path": impl,
+            "groups": G, "heads_per_group": H // G,
             "vmem_bytes": vmem if impl == "pallas" else 0,
             "hbm_bytes_per_head": hbm if impl == "pallas" else 0}
 
 
-def _specs(B, S, H, P, N, q, heads):
+def _specs(B, S, H, P, N, q, heads, groups):
+    """B and C arrive [B, S, G x N]: a head block reads its group's N
+    lanes (block ``h // blocks a group``; the one group's is block 0)."""
     wide = pl.BlockSpec((1, q, heads * P), lambda b, h, c: (b, c, h))
-    shared = pl.BlockSpec((1, q, N), lambda b, h, c: (b, c, 0))
+    per_group = H // groups // heads
+    shared = pl.BlockSpec((1, q, N), (lambda b, h, c: (b, c, 0))
+                          if groups == 1 else
+                          (lambda b, h, c: (b, c, h // per_group)))
     col = pl.BlockSpec((1, q, H), lambda b, h, c: (b, c, 0))
     row = pl.BlockSpec((1, heads, q), lambda b, h, c: (b, h, c))
     state = pl.BlockSpec((1, 1, heads * P, N), lambda b, h, c: (b, c, h, 0))
@@ -320,11 +336,12 @@ def _backwards(spec, last):
     return pl.BlockSpec(spec.block_shape, flipped)
 
 
-def _forward_call(u, bm, cm, col, row, *, chunk: int, heads: int, width: int):
+def _forward_call(u, bm, cm, col, row, *, chunk: int, heads: int, width: int,
+                  groups: int):
     B, S, HP = u.shape
-    H, N = col.shape[2], bm.shape[2]
+    H, N = col.shape[2], bm.shape[2] // groups
     wide, shared, colspec, rowspec, state = _specs(B, S, H, width, N, chunk,
-                                                   heads)
+                                                   heads, groups)
     call = pl.pallas_call(
         functools.partial(_fwd_kernel, heads=heads, width=width),
         grid=(B, H // heads, S // chunk),
@@ -342,13 +359,13 @@ def _forward_call(u, bm, cm, col, row, *, chunk: int, heads: int, width: int):
 
 
 def _backward_call(u, bm, cm, col, row, h_in, dy, *, chunk: int, heads: int,
-                   width: int):
+                   width: int, groups: int):
     B, S, HP = u.shape
-    H, N = col.shape[2], bm.shape[2]
+    H, N = col.shape[2], bm.shape[2] // groups
     blocks, n_chunks = H // heads, S // chunk
     rev = functools.partial(_backwards, last=n_chunks - 1)
     wide, shared, colspec, rowspec, state = map(
-        rev, _specs(B, S, H, width, N, chunk, heads))
+        rev, _specs(B, S, H, width, N, chunk, heads, groups))
     part = rev(pl.BlockSpec((1, 1, chunk, N), lambda b, h, c: (b, h, c, 0)))
     dcol = rev(pl.BlockSpec((1, 1, chunk, H), lambda b, h, c: (b, h, c, 0)))
     f32 = jnp.float32
@@ -370,25 +387,36 @@ def _backward_call(u, bm, cm, col, row, h_in, dy, *, chunk: int, heads: int,
     with jax.named_scope("ssd.bwd.pallas"):
         du, db, dc, dcols, drow = call(u, bm, cm, col, row, h_in, dy)
     # a block's heads stand in their own lanes of dcols, zeros elsewhere
-    return (du, db.sum(axis=1).astype(bm.dtype),
-            dc.sum(axis=1).astype(cm.dtype), dcols.sum(axis=1), drow)
+    return (du, _over_blocks(db, groups).astype(bm.dtype),
+            _over_blocks(dc, groups).astype(cm.dtype), dcols.sum(axis=1),
+            drow)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def _scan_pallas(u, bm, cm, col, row, chunk, heads, width):
+def _over_blocks(parts, groups: int):
+    """The head blocks' parts of dB or dC [B, blocks, S, N] summed over
+    each group's blocks -> [B, S, G x N]."""
+    if groups == 1:
+        return parts.sum(axis=1)
+    B, blocks, S, N = parts.shape
+    return parts.reshape(B, groups, blocks // groups, S, N).sum(
+        axis=2).transpose(0, 2, 1, 3).reshape(B, S, groups * N)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _scan_pallas(u, bm, cm, col, row, chunk, heads, width, groups):
     return _forward_call(u, bm, cm, col, row, chunk=chunk, heads=heads,
-                         width=width)[0]
+                         width=width, groups=groups)[0]
 
 
-def _scan_pallas_fwd(u, bm, cm, col, row, chunk, heads, width):
+def _scan_pallas_fwd(u, bm, cm, col, row, chunk, heads, width, groups):
     y, h_in = _forward_call(u, bm, cm, col, row, chunk=chunk, heads=heads,
-                            width=width)
+                            width=width, groups=groups)
     return y, (u, bm, cm, col, row, h_in)
 
 
-def _scan_pallas_bwd(chunk, heads, width, res, dy):
+def _scan_pallas_bwd(chunk, heads, width, groups, res, dy):
     return _backward_call(*res, dy.astype(res[0].dtype), chunk=chunk,
-                          heads=heads, width=width)
+                          heads=heads, width=width, groups=groups)
 
 
 _scan_pallas.defvjp(_scan_pallas_fwd, _scan_pallas_bwd)
@@ -462,10 +490,14 @@ _prologue.defvjp(_prologue_fwd, _prologue_bwd)
 
 def ssd_scan(x, dt, a, bm, cm, *, chunk: int = 256, impl: str = "xla"):
     """x [B, S, H, P], dt [B, S, H] (positive: after its softplus), a [H]
-    (negative), bm and cm [B, S, N] -> y [B, S, H, P] in x's type, the
-    recurrence of the module docstring from a zero state, without the
-    skip term. Differentiable in all five on both paths."""
+    (negative), bm and cm [B, S, N] (one group) or [B, S, G, N] -> y
+    [B, S, H, P] in x's type, the recurrence of the module docstring from
+    a zero state, without the skip term. Differentiable in all five on
+    both paths."""
     B, S, H, P = x.shape
+    G = 1 if bm.ndim == 3 else bm.shape[2]
+    if bm.ndim == 4 and G == 1:         # one group, stated: the same call
+        bm, cm = bm[:, :, 0], cm[:, :, 0]
     if S % chunk:
         raise ValueError(f"ssd_scan: {S} steps are no multiple of the chunk "
                          f"{chunk}; pad upstream")
@@ -473,20 +505,29 @@ def ssd_scan(x, dt, a, bm, cm, *, chunk: int = 256, impl: str = "xla"):
         raise ValueError(f"ssd_scan impl must be 'xla' or 'pallas', got "
                          f"{impl!r}")
     p = plan(S=S, H=H, P=P, N=bm.shape[-1], chunk=chunk, dtype=x.dtype,
-             impl=impl)
+             impl=impl, G=G)
     tracing.instant("ssd.plan", p)
     f32 = jnp.float32
     u, col, row = _prologue(x.reshape(B, S, H * P), dt, a, chunk)
     if impl == "xla":
         with jax.named_scope("ssd.fwd.xla"):    # jax transposes it itself
-            y = _scan_xla(_chunks(u.reshape(x.shape), chunk).astype(f32),
-                          _chunks(bm, chunk).astype(f32),
-                          _chunks(cm, chunk).astype(f32), _chunks(col, chunk))
+            args = (_chunks(u.reshape(x.shape), chunk).astype(f32),
+                    _chunks(bm, chunk).astype(f32),
+                    _chunks(cm, chunk).astype(f32), _chunks(col, chunk))
+            if bm.ndim == 3:
+                y = _scan_xla(*args)
+            else:       # a group's heads with the group's B and C
+                u5, bg, cg, cum = args
+                by_group = lambda a: a.reshape(              # noqa: E731
+                    *a.shape[:3], G, H // G, *a.shape[4:])
+                y = jax.vmap(_scan_xla, in_axes=3, out_axes=3)(
+                    by_group(u5), bg, cg, by_group(cum))
         return y.reshape(B, S, H, P).astype(x.dtype)
     heads = p["heads_per_block"]
     if heads % 2:
         raise ValueError(f"ssd_scan: the kernel walks heads in pairs, {H} "
                          "heads give a block of an odd number")
-    y = _scan_pallas(u, bm.astype(x.dtype), cm.astype(x.dtype), col, row,
-                     chunk, heads, P)
+    flat = lambda a: a.astype(x.dtype) if a.ndim == 3 else \
+        a.astype(x.dtype).reshape(B, S, -1)                  # noqa: E731
+    y = _scan_pallas(u, flat(bm), flat(cm), col, row, chunk, heads, P, G)
     return y.reshape(B, S, H, P)

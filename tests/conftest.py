@@ -8,6 +8,7 @@
 """
 
 import os
+import sys
 
 # Must happen before anything imports jax (including transitively).
 os.environ["JAX_PLATFORMS"] = "cpu"
@@ -40,6 +41,24 @@ def pytest_configure(config):
     config.addinivalue_line(
         "markers",
         "slow: excluded from tier-1 (`-m 'not slow'`) — sweeps, soak runs")
+
+
+@pytest.fixture(scope="module", autouse=True)
+def few_mappings():
+    """A worker leaves every test file with an empty executable cache.
+    Every loaded CPU executable holds memory mappings, a file that runs a
+    model eagerly loads thousands of them (``tests/test_models_nemotron.py``
+    left 18,470 in one process, ``jax.clear_caches()`` 733), and an xdist
+    worker carries them from file to file: at the kernel's 65,530
+    (``vm.max_map_count``) XLA's next load dies of a segmentation fault in
+    ``deserialize_executable``, which xdist reports as whatever case the
+    worker was running (ROADMAP.md D31; three whole runs of PR 48's tree
+    lost a worker so, in two files). What a later file needs again comes
+    from the persistent compile cache."""
+    yield
+    jax = sys.modules.get("jax")
+    if jax is not None:
+        jax.clear_caches()
 
 
 @pytest.fixture(scope="function")

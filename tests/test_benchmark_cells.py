@@ -30,7 +30,9 @@ RECIPE_KEYS = {"train": ("attn_impl", "remat", "f32_logits"),
                "train_parallel": ("attn_impl", "gmm_impl", "remat",
                                   "f32_logits"),
                "train_sparse": ("attn_impl", "gmm_impl", "remat",
-                                "f32_logits")}
+                                "f32_logits"),
+               "train_alternating": ("attn_impl", "gmm_impl", "ssd_impl",
+                                     "remat", "f32_logits")}
 # published config.json key -> the program's field
 WIDTHS = {"hidden_size": "d_model", "num_hidden_layers": "n_layers",
           "num_attention_heads": "n_heads",
@@ -56,7 +58,8 @@ def test_cell_program_config_builds_at_its_published_widths(name):
     import jax.numpy as jnp
 
     from benchmark import (model, model_commanda, model_glm, model_glm52,
-                           model_granite, model_mellum, model_moe, resolve)
+                           model_granite, model_mellum, model_moe,
+                           model_nemotron, resolve)
 
     cell = resolve.cell(name)
     kind, conf, recipe = cell["kind"], cell["config"], cell["train"]
@@ -66,7 +69,8 @@ def test_cell_program_config_builds_at_its_published_widths(name):
              "train_latent": model_glm.latent_config,
              "train_mixed": model_mellum.moe_config,
              "train_parallel": model_commanda.moe_config,
-             "train_sparse": model_glm52.latent_config}[kind]
+             "train_sparse": model_glm52.latent_config,
+             "train_alternating": model_nemotron.hybrid_config}[kind]
     passed = {k: recipe[k] for k in RECIPE_KEYS[kind] if k in recipe}
     cfg = build(conf, **passed)
 
@@ -77,7 +81,8 @@ def test_cell_program_config_builds_at_its_published_widths(name):
               "train_parallel": {
                   k: f for k, f in model_commanda.HF_TO_FIELD.items()
                   if f != "logit_scale"},
-              "train_sparse": model_glm52.HF_TO_FIELD}[kind]
+              "train_sparse": model_glm52.HF_TO_FIELD,
+              "train_alternating": model_nemotron.HF_TO_FIELD}[kind]
     for key, field in widths.items():
         assert getattr(cfg, field) == conf[key], (name, key)
     if kind == "train_parallel":
@@ -112,6 +117,31 @@ def test_cell_program_config_builds_at_its_published_widths(name):
         assert cfg.experts_held == (conf["num_local_experts"],
                                     dep["experts_first"])
         assert cfg.kinds == tuple(conf["layer_types"][:cfg.n_layers])
+    if kind == "train_alternating":
+        # the mixer's heads, groups, state, taps and chunk, the stated
+        # head width and both expert widths are the published keys' (the
+        # map above); the pattern's letters the blocks, ONE half each; the
+        # router's width and the experts held the deployment's
+        assert {"mamba_num_heads", "mamba_head_dim", "ssm_state_size",
+                "n_groups", "conv_kernel", "chunk_size", "head_dim",
+                "moe_intermediate_size",
+                "moe_shared_expert_intermediate_size",
+                "routed_scaling_factor"} <= set(widths)
+        dep = conf["deployment"]
+        assert cfg.n_experts == dep["router_experts"]
+        assert cfg.experts_held == (conf["n_routed_experts"],
+                                    dep["experts_first"])
+        assert cfg.kinds == tuple(model_nemotron.LETTERS[c] for c in
+                                  conf["hybrid_override_pattern"])
+        assert cfg.one_half and not cfg.tied_head
+        # no two adjacent blocks of a kind: a run a block
+        from ray_tpu.models import hybrid
+        assert hybrid.layer_runs(cfg) == [(k, 1) for k in cfg.kinds]
+        assert (cfg.expert_act, cfg.router_score, cfg.norm_topk) == (
+            "relu2", "sigmoid", True)
+        assert not cfg.rope and cfg.head_dim == conf["head_dim"]
+        assert cfg.mamba_inner == conf["mamba_num_heads"] \
+            * conf["mamba_head_dim"]
     if kind == "train_sparse":
         # the latents' ranks, the head widths and the indexer's sizes are
         # the published keys' (the map above); which layers select, the

@@ -319,7 +319,8 @@ def test_one_layer_one_attention_half_and_the_plans(monkeypatch):
     assert by["window"]["window"] == 24 and by["full"]["window"] == 0
     assert by["full"]["groups"] == 4 and by["full"]["kv_heads"] == 2
     layer = [a for n, a in said if n == "hybrid.layer_plan"]
-    assert layer == [{"kinds": 2, "runs": 4, "bodies": 2, "layers": 8}]
+    assert layer == [{"kinds": 2, "runs": 4, "bodies": 2, "layers": 8,
+                      "pattern": "window x3, full x1, window x3, full x1"}]
     # a serial model says no block plan and hands no normed input
     seen.clear(), said.clear()
     serial = moe.PRESETS["tiny-mellum"].replace(dtype=jnp.float32)

@@ -348,7 +348,8 @@ def test_layer_plan_instant_and_the_mesh_refusal(monkeypatch):
     params, tokens = make(cfg)
     jax.make_jaxpr(lambda p: hybrid.forward(p, tokens[:, :-1], cfg))(params)
     plans = [a for n, a in seen if n == "hybrid.layer_plan"]
-    assert plans == [{"kinds": 2, "runs": 3, "bodies": 2, "layers": 4}]
+    assert plans == [{"kinds": 2, "runs": 3, "bodies": 2, "layers": 4,
+                      "pattern": "mamba x2, attention x1, mamba x1"}]
     # two runs of mamba layers, ONE trace of their body
     assert [a["path"] for n, a in seen if n == "ssd.plan"] == ["xla"]
 
@@ -422,8 +423,11 @@ def test_plans_read_back_from_a_profile_around_a_lowering(tmp_path):
                     if e.name in ("ssd.plan", "hybrid.layer_plan",
                                   "mixer.plan"):
                         events.setdefault(e.name, []).append(dict(e.stats))
-    assert events["hybrid.layer_plan"] == [
+    plan = events["hybrid.layer_plan"]
+    assert [{k: p[k] for k in ("kinds", "runs", "bodies", "layers")}
+            for p in plan] == [
         {"kinds": 2, "runs": 3, "bodies": 2, "layers": 4}]
+    assert plan[0]["pattern"].startswith("mamba x")
     plan = events["ssd.plan"][0]
     assert plan["path"] == "pallas" and plan["chunk"] == 32
     assert plan["S"] == 96 and plan["heads_per_block"] == 8
@@ -432,7 +436,8 @@ def test_plans_read_back_from_a_profile_around_a_lowering(tmp_path):
     # B2 x S96, inner 128, 160 channels convolved, 8 heads, float32
     wide, conv, steps = 192 * 128 * 4, 192 * 160 * 4, 192 * 8 * 4
     assert events["mixer.plan"] == [{
-        "path": "rules", "rows": 192, "residual_bytes": 0,
+        "path": "rules", "rows": 192, "groups": 1, "group_lanes": 128,
+        "chunk": 32, "channels": 160, "residual_bytes": 0,
         "hbm_bytes_fwd": 2 * conv + 9 * wide + 3 * steps,
         "hbm_bytes_bwd": 15 * wide + 6 * conv + 4 * steps}]
     assert hybrid.plan(cfg.replace(remat=False), 2, 96)["residual_bytes"] \
@@ -652,3 +657,51 @@ def test_the_cached_paths_refuse_a_config_that_states_its_own_scales():
         cached.init_paged_cache(
             llama.PRESETS["tiny"].replace(rope=False), 4, 16)
     assert cached.init_cache(llama.PRESETS["tiny"], 1).k.shape[0] == 2
+
+
+LETTERS = {"M": "mamba", "E": "experts", "*": "attention"}
+
+
+@pytest.mark.parametrize("pattern,want", [
+    ("MEMEM*EMEMEM*EMEMEM*", " ".join(f"[{c}]x1" for c in
+                                      "MEMEM*EMEMEM*EMEMEM*")),
+    ("MEMEM*EMEMEM*", " ".join(f"[{c}]x1" for c in "MEMEM*EMEMEM*")),
+    ("MEM*EMEM*E", " ".join(f"[{c}]x1" for c in "MEM*EMEM*E")),
+    ("MMMMM*MMMM", "[M]x5 [*]x1 [M]x4"),
+    ("MMEE", "[M]x2 [E]x2"),
+    ("MEEEM**", "[M]x1 [E]x3 [M]x1 [*]x2")])
+def test_runs_of_blocks_that_hold_one_half(pattern, want):
+    """With ``one_half`` a run is adjacent blocks of one of THREE kinds (a
+    published pattern with no two alike: a run a block); the parameter tree
+    holds one stack a run, as long as the run, of the kind's own leaves."""
+    cfg = hybrid.PRESETS["tiny-nemotron"].replace(
+        n_layers=len(pattern), layer_types=tuple(LETTERS[c] for c in pattern))
+    back = {v: k for k, v in LETTERS.items()}
+    runs = hybrid.layer_runs(cfg)
+    assert " ".join(f"[{back[kind]}]x{n}" for kind, n in runs) == want
+    shapes = jax.eval_shape(lambda: hybrid.init_params(
+        jax.random.PRNGKey(0), cfg))["layers"]
+    own = {"mamba": "in_proj", "attention": "wq", "experts": "router"}
+    for (kind, n), run in zip(runs, shapes):
+        assert all(leaf.shape[0] == n for leaf in jax.tree.leaves(run))
+        assert [k for k in own.values() if k in run] == [own[kind]]
+
+
+def test_one_group_and_two_halves_are_the_program_granite_has():
+    """``mamba_groups`` 1 and ``one_half`` False are the defaults:
+    Granite's tree, runs and mixer are what they were."""
+    cfg = tiny()
+    assert (cfg.mamba_groups, cfg.one_half, cfg.tied_head,
+            cfg.expert_act) == (1, False, True, "swiglu")
+    assert hybrid.halves(cfg, "mamba") == hybrid.halves(cfg, "attention") \
+        == (True, True)
+    params, _ = make(cfg)
+    assert "lm_head" not in params
+    assert all("we_gate" in run and "ffn_norm" in run
+               for run in params["layers"])
+    with pytest.raises(ValueError, match="unknown layer type"):
+        hybrid.layer_runs(cfg.replace(layer_types=(
+            "mamba", "experts", "attention", "mamba")))
+    plan = hybrid.plan(cfg, 2, 96)
+    assert (plan["groups"], plan["group_lanes"], plan["channels"]) \
+        == (1, cfg.mamba_inner, cfg.mamba_inner + 2 * cfg.mamba_state)

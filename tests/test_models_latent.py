@@ -614,7 +614,8 @@ def test_layer_plan_says_two_kinds_in_two_runs(monkeypatch):
     jax.make_jaxpr(lambda p: latent.loss_fn(p, {"tokens": tokens}, cfg)[0])(
         params)
     assert [a for n, a in seen if n == "hybrid.layer_plan"] == [
-        {"kinds": 2, "runs": 2, "bodies": 2, "layers": 3}]
+        {"kinds": 2, "runs": 2, "bodies": 2, "layers": 3,
+         "pattern": "dense x1, sparse x2"}]
     # the dense body, the sparse body: the module's block is scanned by
     # the sparse body, not by a third
     assert [a["form"] for n, a in seen if n == "mla.plan"] == ["expanded"] * 2

@@ -278,7 +278,8 @@ def test_one_attention_half_two_scopes_and_the_kind_plan(monkeypatch):
     assert by["full"]["factor"] == 4.0 and by["full"]["head_dim"] == 16
     assert by["full"]["heads"] == 8 and by["full"]["kv_heads"] == 1
     layer = [a for n, a in said if n == "hybrid.layer_plan"]
-    assert layer == [{"kinds": 2, "runs": 4, "bodies": 2, "layers": 8}]
+    assert layer == [{"kinds": 2, "runs": 4, "bodies": 2, "layers": 8,
+                      "pattern": "window x3, full x1, window x3, full x1"}]
 
 
 def test_a_dense_config_with_a_window_is_one_kind_as_before():

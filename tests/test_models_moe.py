@@ -199,6 +199,88 @@ def test_grouped_matmul_pallas_at_three_k_tiles(sizes, monkeypatch):
             assert float(jnp.abs(grads[1][i]).max()) == 0.0
 
 
+@pytest.mark.parametrize("k,n", [(2688, 1856), (1856, 2688)])
+def test_grouped_matmul_pallas_at_a_width_of_no_whole_lanes(k, n):
+    """Nemotron 3 Nano's experts are [2688, 1856] and [1856, 2688]: 1,856 =
+    29 x 64 is no whole number of lanes (whole as ``gmm``'s N or K, 1,024
+    and a ragged 832 in ``tgmm``); 2,688 = 21 x 128 goes in three tiles of
+    896. The real widths and the real tiles against ``ragged_dot``: values
+    and both gradients, uneven and empty groups."""
+    from ray_tpu.ops import grouped_matmul as gm
+
+    m, e = 64, 3
+    tiles = gm._fit(gm.GMM_TILING, m, k, n, 4, halve_n=True)
+    assert tiles[1:] == ((896, 1856) if k == 2688 else (1856, 896)), tiles
+    assert gm._fit(gm.TGMM_TILING, m, k, n)[1:] == (
+        (896, 1024) if k == 2688 else (1024, 896))
+    lhs = jax.random.normal(jax.random.PRNGKey(0), (m, k))
+    rhs = jax.random.normal(jax.random.PRNGKey(1), (e, k, n)) * k ** -0.5
+    gs = jnp.asarray([37, 0, 27], jnp.int32)
+
+    def f(impl):
+        def loss(a, w):
+            out = grouped_matmul(a, w, gs, impl=impl)
+            return (out * jnp.cos(out)).sum(), out
+        (_, out), grads = jax.value_and_grad(loss, argnums=(0, 1),
+                                             has_aux=True)(lhs, rhs)
+        return out, grads
+
+    want, want_g = f("xla")
+    out, grads = f("pallas")
+    np.testing.assert_allclose(out, want, rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(grads[0], want_g[0], rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(grads[1], want_g[1], rtol=1e-4, atol=1e-4)
+    assert float(jnp.abs(grads[1][1]).max()) == 0.0
+
+
+def test_the_tiles_of_the_shapes_the_sweeps_saw_stand():
+    """``_even`` looks further for equal tiles of whole lanes only where
+    the fewest are none: every width a cell had keeps its tiles."""
+    from ray_tpu.ops import grouped_matmul as gm
+
+    assert [gm._even(t, d) for t, d in (
+        (2048, 2048), (1024, 1536), (2048, 4096), (1024, 768), (2048, 2304),
+        (1024, 2304), (2048, 6144), (1024, 896), (2048, 2688), (1024, 2688),
+        (2048, 1856), (1024, 1856))] == [
+        2048, 768, 2048, 768, 1152, 768, 2048, 896, 896, 896, 1856, 1024]
+
+
+@pytest.mark.parametrize("held", [None, (2, 2)])
+def test_two_matrix_experts_with_a_squared_relu(held):
+    """``expert_act`` "relu2": the tree has no gate, the count says so,
+    and both ways through the layer (every expert held: ``_dispatch``; a
+    share: ``_held_experts``) give down(relu(up(x))^2) of the held
+    experts, weighted, plus the shared one; gradients reach every leaf."""
+    cfg = moe.PRESETS["tiny"].replace(
+        expert_act="relu2", shared_d_ff=40, experts_held=held,
+        dtype=jnp.float32, param_dtype=jnp.float32, router_z_weight=0.0)
+    params = moe.init_params(jax.random.PRNGKey(0), cfg)
+    lay = params["layers"]
+    assert "we_gate" not in lay and "ws_gate" not in lay
+    assert lay["we_up"].shape == (2, cfg.n_held, 64, 96)
+    assert sum(x.size for x in jax.tree.leaves(params)) \
+        == moe.num_params(cfg)
+    assert set(moe.param_specs(cfg)["layers"]) == set(lay)
+    assert moe.remat_offers(cfg, None, 10) == (("shared_up", 10 * 40 * 4),)
+    lp = jax.tree.map(lambda w: w[0], lay)
+    x = jax.random.normal(jax.random.PRNGKey(1), (1, 32, 64))
+    with jax.default_matmul_precision("highest"):
+        y, stats = moe.feed_forward(x, lp, cfg)
+        logits = x[0] @ lp["router"]
+        weights, experts, _ = moe.route(logits, cfg)
+        want = jnp.square(jax.nn.relu(x[0] @ lp["ws_up"])) @ lp["ws_down"]
+        first = held[1] if held else 0
+        for e in range(cfg.n_held):
+            w = jnp.sum(jnp.where(experts == e + first, weights, 0.0), -1)
+            want = want + w[:, None] * (jnp.square(jax.nn.relu(
+                x[0] @ lp["we_up"][e])) @ lp["we_down"][e])
+        grads = jax.grad(lambda lp: jnp.sum(
+            moe.feed_forward(x, lp, cfg)[0] ** 2))(lp)
+    np.testing.assert_allclose(y[0], want, rtol=2e-4, atol=2e-5)
+    assert all(float(jnp.abs(grads[w]).max()) > 0 for w in (
+        "router", "we_up", "we_down", "ws_up", "ws_down"))
+
+
 def test_the_tiles_at_glm52s_expert_widths():
     """[R, 6144] x [8, 6144, 2048] in bf16: what ``_fit`` gives ``gmm`` and
     ``tgmm`` there: three K tiles of 2,048."""

@@ -75,6 +75,75 @@ def test_scan_gradients_against_the_recurrence(shape, impl):
                                    err_msg=name)
 
 
+def grouped(fn, groups):
+    """``fn`` (the recurrence) a group of adjacent heads at a time: x
+    [B, S, H, P], bm and cm [B, S, G, N]."""
+    def run(x, dt, a, bm, cm):
+        per = x.shape[2] // groups
+        return jnp.concatenate([
+            fn(x[:, :, g * per:(g + 1) * per], dt[..., g * per:(g + 1) * per],
+               a[g * per:(g + 1) * per], bm[:, :, g], cm[:, :, g])
+            for g in range(groups)], axis=2)
+    return run
+
+
+def group_inputs(seed, B, S, H, P, N, groups):
+    x, dt, a, _, _ = inputs(seed, B, S, H, P, N)
+    k = jax.random.split(jax.random.PRNGKey(seed + 100), 2)
+    bm, cm = (jax.random.normal(kk, (B, S, groups, N), jnp.float32)
+              * N ** -0.25 for kk in k)
+    return x, dt, a, bm, cm
+
+
+# (B, S, H, P, N, chunk, G): a block's heads are of one group. 16 heads in
+# 8 groups: blocks of a pair; 32 in 2: one block of 16 a group; 16 in 1
+# given as [B, S, 1, N]
+GROUPED = {"8 groups of a pair": (1, 64, 16, 16, 8, 32, 8),
+           "2 groups of 16": (2, 64, 32, 64, 16, 32, 2),
+           "1 group, stated": (1, 96, 16, 16, 8, 32, 1)}
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("shape", sorted(GROUPED))
+def test_grouped_scan_against_the_recurrence_a_group(shape, impl):
+    """B and C a group of heads: values and every gradient against the
+    token-by-token recurrence run a group at a time."""
+    B, S, H, P, N, chunk, G = GROUPED[shape]
+    args = group_inputs(3, B, S, H, P, N, G)
+    probe = jax.random.normal(jax.random.PRNGKey(7), (B, S, H, P))
+
+    def through(fn):
+        return jax.value_and_grad(lambda *a: jnp.sum(fn(*a) * probe),
+                                  argnums=(0, 1, 2, 3, 4))(*args)
+
+    np.testing.assert_allclose(
+        ssd.ssd_scan(*args, chunk=chunk, impl=impl),
+        grouped(recurrence, G)(*args), rtol=2e-4, atol=2e-4)
+    (_, want), (_, got) = through(grouped(recurrence, G)), through(
+        lambda *a: ssd.ssd_scan(*a, chunk=chunk, impl=impl))
+    for name, g, w in zip(("x", "dt", "a", "B", "C"), got, want):
+        assert g.shape == w.shape, name
+        scale = float(jnp.max(jnp.abs(w)))
+        np.testing.assert_allclose(g, w, rtol=1e-3, atol=1e-3 * scale,
+                                   err_msg=name)
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_one_group_stated_is_the_one_group_call_bit_for_bit(impl):
+    """[B, S, 1, N] and [B, S, N] are one program's values: the grouped
+    call at G = 1 is the call Granite makes."""
+    args = inputs(4, 1, 96, 16, 16, 8)
+    stated = args[:3] + tuple(t[:, :, None] for t in args[3:])
+    run = lambda a: jax.value_and_grad(                      # noqa: E731
+        lambda *a: jnp.sum(ssd.ssd_scan(*a, chunk=32, impl=impl) ** 2),
+        argnums=(0, 1, 2, 3, 4))(*a)
+    (v0, g0), (v1, g1) = run(args), run(stated)
+    assert float(v0) == float(v1)
+    for a, b in zip(g0, g1):
+        np.testing.assert_array_equal(np.asarray(a).ravel(),
+                                      np.asarray(b).ravel())
+
+
 def test_long_decay_neither_overflows_nor_vanishes():
     """exp(cum_t - cum_s) is taken of the DIFFERENCE: a chunk whose
     running sum passes -700 (exp underflows in float32, its inverse
@@ -119,6 +188,16 @@ def test_refusals_and_plan():
     assert p["hbm_bytes_per_head"] == 8192 * 64 * 2 * 5 + 2 * 32 * 64 * 128 * 4
     assert ssd.plan(S=96, H=4, P=16, N=8, chunk=32, dtype=jnp.float32,
                     impl="xla")["vmem_bytes"] == 0
+    assert (p["groups"], p["heads_per_group"]) == (1, 128)
+    # Nemotron 3 Nano's: 64 heads in 8 groups, a block is a group's 8 heads
+    p = ssd.plan(S=8192, H=64, P=64, N=128, chunk=128, dtype=jnp.bfloat16,
+                 impl="pallas", G=8)
+    assert (p["heads_per_block"], p["groups"], p["heads_per_group"]) \
+        == (8, 8, 8)
+    assert p["vmem_bytes"] < 16 * 2 ** 20
+    with pytest.raises(ValueError, match="groups"):
+        ssd.plan(S=96, H=6, P=16, N=8, chunk=32, dtype=jnp.float32,
+                 impl="xla", G=4)
 
 
 def test_ssd_plan_instant_once_a_trace(monkeypatch):

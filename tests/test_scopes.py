@@ -28,7 +28,10 @@ CASES = {"dense": ("rehearse-train", "model.llama_config", "llama"),
          "tiny-granite": ("rehearse-train-hybrid",
                           "model_granite.hybrid_config", "hybrid"),
          "tiny-glm": ("rehearse-train-latent", "model_glm.latent_config",
-                      "latent")}
+                      "latent"),
+         "tiny-nemotron": ("rehearse-train-alternating",
+                           "model_nemotron.hybrid_config", "hybrid")}
+MIXERS = ("tiny-granite", "tiny-nemotron")    # families with a mixer half
 PLANS = ("flash.fwd_plan", "flash.bwd_plan", "ssd.plan", "tp.overlap_plan")
 _LINE = re.compile(r"^\s*(?:ROOT )?%?[\w.\-]+ = .*? ([\w\-]+)\(")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
@@ -122,9 +125,9 @@ def test_no_matmul_and_no_kernel_call_outside_a_scope(case, tmp_path,
     assert not outside, outside
     found = {op_scopes.bucket(p) for p in paths}
     want = {"embed", "attention", "feed_forward", "head_loss", "optimizer",
-            "layer_loop"} | ({"mixer"} if case == "tiny-granite" else set())
+            "layer_loop"} | ({"mixer"} if case in MIXERS else set())
     assert want <= found, (want - found, found)
-    assert ("mixer" in found) == (case == "tiny-granite")
+    assert ("mixer" in found) == (case in MIXERS)
     # the layer checkpoint's replay, the backward and the forward are told
     # apart, and each half is seen in all three
     assert recipe["remat"]
@@ -136,8 +139,8 @@ def test_no_matmul_and_no_kernel_call_outside_a_scope(case, tmp_path,
     inside = {op_scopes.sub_scope(p, op_scopes.OPTIMIZER_SCOPES)
               for p in paths if op_scopes.bucket(p) == "optimizer"}
     assert inside == {None, "grad_norm"} | (
-        {"rule"} if case == "tiny-glm" else set()), inside
-    if case in ("tiny-olmoe", "tiny-granite", "tiny-glm"):
+        {"rule"} if case in ("tiny-glm", "tiny-nemotron") else set()), inside
+    if case in ("tiny-olmoe", "tiny-granite", "tiny-glm", "tiny-nemotron"):
         subs = {op_scopes.sub_scope(p) for p in paths
                 if op_scopes.bucket(p) == "feed_forward"}
         shared = {"shared"} if getattr(cfg, "shared_d_ff", 0) else set()

@@ -355,7 +355,13 @@ GMM_WIDTHS = {"olmoe": (131072, 64, 2048, 1024),
               # GLM-5.2's one pass of 16,384 rows over the 8 experts held,
               # 6144 x 2048: THREE K tiles of 2,048, an expert's matrix
               # 24 MiB
-              "glm52": (16384, 8, 6144, 2048)}
+              "glm52": (16384, 8, 6144, 2048),
+              # Nemotron 3 Nano's one pass of 24,576 rows over the 16
+              # experts held, up [2688, 1856] and down [1856, 2688]: 1,856
+              # is no whole number of lanes (whole, or 1,024 + a ragged
+              # 832), 2,688 three tiles of 896
+              "nemotron up": (24576, 16, 2688, 1856),
+              "nemotron down": (24576, 16, 1856, 2688)}
 
 
 @pytest.mark.parametrize("model", sorted(GMM_WIDTHS))
@@ -407,6 +413,38 @@ def test_ssd_scan_compiles_at_granite_widths(one_chip, on_chip_branch):
         *args).compile().as_text()
     assert text.count("tpu_custom_call") == 2, text[:2000]
     assert "f32[2,32,8192,128]" in text          # the chunks' incoming states
+
+
+def test_ssd_scan_compiles_at_nemotron_widths_in_groups(one_chip,
+                                                        on_chip_branch):
+    """The scan's two Mosaic calls at Nemotron 3 Nano's shapes (B2 x S8192,
+    64 heads of 64 in 8 groups of B and C, state 128, chunks of 128): a
+    head block is a group's 8 heads and reads its group's 128 lanes of
+    [B, S, 1024]; the gradients of B and C leave a block apart."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import ssd
+
+    B, S, H, P, N, G = 2, 8192, 64, 64, 128, 8
+    bf, f32 = jnp.bfloat16, jnp.float32
+    args = (_sds((B, S, H, P), bf, one_chip), _sds((B, S, H), f32, one_chip),
+            _sds((H,), f32, one_chip), _sds((B, S, G, N), bf, one_chip),
+            _sds((B, S, G, N), bf, one_chip))
+
+    def loss(*a):
+        return ssd.ssd_scan(*a, chunk=128, impl="pallas").astype(f32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        *args).compile().as_text()
+    assert text.count("tpu_custom_call") == 2, text[:2000]
+    assert "f32[2,64,4096,128]" in text          # the chunks' incoming states
+    assert "f32[2,8,8192,128]" in text           # dB, dC a head block
+    plan = ssd.plan(S=S, H=H, P=P, N=N, chunk=128, dtype=bf, impl="pallas",
+                    G=G)
+    assert (plan["heads_per_block"], plan["groups"],
+            plan["heads_per_group"]) == (8, 8, 8)
+    assert plan["vmem_bytes"] < 16 * 2 ** 20
 
 
 def _array_bytes(types: str) -> int:
@@ -1053,6 +1091,8 @@ _CELL_STEPS = {
                                          "moe"),
     "train-granite4hs-ep8-s8192-b2": ("model_granite", "hybrid_config",
                                       "hybrid"),
+    "train-nemotron3nano-ep8-s8192-b2": ("model_nemotron", "hybrid_config",
+                                         "hybrid"),
 }
 
 
@@ -1143,6 +1183,23 @@ def test_granite_step_keeps_the_parents_list(topo, on_chip_branch,
         ("", 0, "no room")]
     assert abs(plan - 15_310_881_280) < 1e6, plan
     assert compiled.as_text().count(".remat") == 0
+
+
+def test_nemotron_step_plans_under_the_figure_its_file_states(
+        topo, on_chip_branch, monkeypatch):
+    """The Nemotron 3 Nano cell's step (20 one-half blocks, each a run of
+    its own): the plan stays under the 10.7e9 the configuration's file
+    states (9,837,784,064 when this was written; a scan over a repeated
+    sequence of kinds planned 18,102,409,216 for the same blocks, over the
+    chip, and was not built) and XLA rematerializes nothing of its own."""
+    compiled, plan, _ = _compile_cell_step(
+        "train-nemotron3nano-ep8-s8192-b2", topo, monkeypatch)
+    assert 9.0e9 < plan < 10.7e9, plan
+    text = compiled.as_text()
+    assert text.count(".remat") == 0
+    # a mixer block's scan forward, again under the checkpoint, backward;
+    # an attention block's three flash calls; the grouped matmuls
+    assert text.count("tpu_custom_call") >= 100
 
 
 # --- GLM-5.2: attention over a learned set (ops/sparse_attention.py) -------
