@@ -19,7 +19,8 @@ spans. A span past the diagonal is fetched and computed by nobody (the
 index maps clamp into the causal band and Mosaic elides a fetch whose
 index repeats). A span whole inside the band is written out as
 straight-line code, the span on the diagonal walks in a loop
-(``_span_walk``). Nothing is gathered and nothing O(S T) is written but
+(``flash_attention._span_walk``, which the dense stream calls use too).
+Nothing is gathered and nothing O(S T) is written but
 the set itself. The backward is FlashAttention-2's two passes with the
 same test: dQ by the forward's walk, dK/dV by its mirror image (a k-block
 stays, q, dO, o, lse and the set's ROWS arrive a span of q-blocks a grid
@@ -71,7 +72,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops.flash_attention import (_SCOPED_VMEM_BYTES, FLASH_RESIDUALS,
-                                         NEG_INF, _use_interpret, _walk)
+                                         NEG_INF, _span_walk, _use_interpret)
 from ray_tpu.util import tracing
 
 _LANES = 128                # lse travels lane-broadcast, as the flash calls'
@@ -206,46 +207,6 @@ def _scores(q, k, keep, scale: float):
 
 def _member(keep):
     return keep.astype(jnp.int32) != 0
-
-
-def _at(i, first, size: int, span: int):
-    """Where block ``i`` starts in a span of ``span`` blocks of ``size``
-    that starts at block ``first``."""
-    return 0 if span == 1 else pl.multiple_of((i - first) * size, size)
-
-
-# the most blocks of a span a kernel's body is written out for
-_UNROLL_MOST = 8
-
-
-def _span_walk(lo, hi, first, size: int, span: int, in_flight: int, body):
-    """``body(offsets)`` over the blocks [lo, hi) of a span of ``span``
-    blocks of ``size`` that starts at block ``first``, ``in_flight`` a
-    call in rising order. A span that lies whole inside the causal band
-    (all but the one that holds the diagonal) is written out, its
-    offsets constants of the program: straight-line code the scheduler
-    runs a block's products under its neighbour's vector work (the
-    forward at spans of 4, 2 in flight: 39.05 ms a call through the loop
-    alone, 35.5 written out; PERF.md 6, PR 46); the span on the diagonal,
-    and any span longer than ``_UNROLL_MOST`` blocks, walks in a loop
-    (``flash_attention._walk``)."""
-    def step(carry, i, n):
-        body([_at(i + j, first, size, span) for j in range(n)])
-        return carry
-
-    if span == 1 or span > _UNROLL_MOST:
-        _walk(lo, hi, 0, step, in_flight)
-        return
-    whole = (lo == first) & (hi == first + span)
-
-    @pl.when(whole)
-    def _whole():
-        for j in range(0, span, in_flight):
-            body([(j + i) * size for i in range(min(in_flight, span - j))])
-
-    @pl.when(jnp.logical_not(whole))
-    def _part():
-        _walk(lo, hi, 0, step, in_flight)
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, keep_ref, o_ref, lse_ref, acc, m_scr,

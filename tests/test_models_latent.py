@@ -263,14 +263,20 @@ def test_latent_attention_through_flash_at_a_head_of_256(fwd_dq, dkdv,
         "path": fwd_dq, "S": S, "D": 256,
         "kv_block_bytes": 2 * 2 * S * 256 * 4,
         "span": {"loop": S, "stream": 32}[fwd_dq], "in_flight": 1,
-        "grid_steps": steps[fwd_dq][0], "band_steps": steps[fwd_dq][1]}
+        "grid_steps": steps[fwd_dq][0], "band_steps": steps[fwd_dq][1],
+        "whole_steps": 0}           # a span of one block is not written out
     back = plans["flash.bwd_plan"]
     assert back["path"] == dkdv
     assert (back["dq_path"], back["dq_span"], back["dq_in_flight"]) == {
         "loop": ("loop", S, 2), "stream": ("stream", 32, 1)}[fwd_dq]
     assert (back["dq_grid_steps"], back["dq_band_steps"]) == steps[fwd_dq]
+    # the streamed dK/dV call holds what the same bytes leave it: all four
+    # q-blocks a grid step, two in flight, or one
     assert (back["grid_steps"], back["band_steps"]) == {
-        "resident": (8, 8), "stream": (32, 20)}[dkdv]
+        "resident": (8, 8), "stream": steps[fwd_dq]}[dkdv]
+    assert (back["span"], back["in_flight"]) == (
+        (S, 1) if dkdv == "resident" else
+        {"loop": (S, 2), "stream": (32, 1)}[fwd_dq])
 
 
 def _published_half(x, lp, cfg, cos, sin):
@@ -665,7 +671,7 @@ def test_plans_read_back_from_a_profile_around_a_lowering(tmp_path,
         "path": "stream", "S": 128, "D": 256,
         "kv_block_bytes": 2 * 2 * 128 * 256 * 4, "span": 32, "in_flight": 1,
         # two sequences of two heads: 4 x 4 steps each, 10 in the triangle
-        "grid_steps": 64, "band_steps": 40}
+        "grid_steps": 64, "band_steps": 40, "whole_steps": 0}
     back = events["flash.bwd_plan"][0]
     assert back["path"] == "stream" and back["dq_path"] == "stream"
     assert (back["dq_span"], back["dq_in_flight"]) == (32, 1)

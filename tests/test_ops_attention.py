@@ -308,7 +308,8 @@ def test_flash_at_eight_query_heads_a_kv_head_against_xla(plans, what, window,
     head: in blocks of 32 the forward and the dQ call hold a q-block's
     whole band in one grid step and the dK/dV call steps over a k-block's
     band alone wherever the window ends the bands before T does, and all
-    three step over T where it does not."""
+    three step over T (the dK/dV call over S, two q-blocks a grid step)
+    where it does not."""
     from ray_tpu.models.llama import _attention_xla
 
     fa = _fa()
@@ -366,11 +367,14 @@ def test_flash_at_eight_query_heads_a_kv_head_against_xla(plans, what, window,
                 back["dq_in_flight"]) == (
             (path, path, band, 2 if window == 100 else 3) if banded
             else (path, path, 128, 2)), back
-        # the dK/dV call's q axis: the longest band of a k-block, or S's 8
+        # the dK/dV call's q axis: the longest band of a k-block, one
+        # q-block a step, or S's 8 q-blocks in spans of two
+        assert (back["span"], back["in_flight"]) == (
+            (32, 1) if banded else (64, 1)), back
         grids += [(back["dq_grid_steps"], back["dq_band_steps"],
                    1 if banded else 2),
                   (back["grid_steps"], back["band_steps"],
-                   {40: 3, 64: 3, 100: 5}.get(window, 8))]
+                   {40: 3, 64: 3, 100: 5}.get(window, 4))]
     for steps, work, axis in grids:
         assert 0 < work <= steps == 8 * 8 * axis, (steps, work, axis, seen)
         assert not banded or work > 0.7 * steps, (steps, work)
@@ -393,6 +397,10 @@ def _dkdv(path, res, g, *, causal, window, block):
     KV = k.shape[2]
     t = lambda x: x.transpose(0, 2, 1, 3)  # noqa: E731
     lse = jnp.broadcast_to(lse[..., None], lse.shape + (128,))
+    # `stream4x2`: the streamed call at 4 q-blocks a grid step, 2 in flight
+    path, _, walk = path.partition("stream")
+    path, walk = path or "stream", tuple(map(int, walk.split("x"))) if walk \
+        else None
     vmem = {"resident": 128 * 2 ** 20, "stream": 1024}[path]
     plan = fa.bwd_dkdv_plan(
         S=S, T=S, D=D, dtype=q.dtype, groups=H // KV,
@@ -403,7 +411,7 @@ def _dkdv(path, res, g, *, causal, window, block):
                             "stream": ("stream", "band")}[path]
     dk, dv = fa._flash_bwd_dkdv(
         t(q), t(k), t(v), t(g), t(out), lse, causal=causal, block_q=block,
-        block_k=block, window=window, vmem_bytes=vmem)
+        block_k=block, window=window, vmem_bytes=vmem, walk=walk)
     return [x.astype(jnp.float32).reshape(B, KV, H // KV, S, D).sum(2)
             .transpose(0, 2, 1, 3) for x in (dk, dv)]
 
@@ -414,16 +422,24 @@ def _window(mask: str) -> int:
     return int(mask[6:]) if mask.startswith("window") else 0
 
 
-@pytest.mark.parametrize("blocks", [1, 4], ids=["S=block", "S=4blocks"])
+# the streamed call also at 2 q-blocks a grid step, both in flight, at 8
+# blocks: whole, diagonal and empty spans all occur
+# (test_the_walk_of_k_blocks_... holds every form to the last bit)
+DKDV_PLANS = [(path, blocks) for path in ("resident", "stream")
+              for blocks in (1, 4)] + [("stream2x2", 8)]
+
+
 @pytest.mark.parametrize("heads", [(2, 2), (4, 2), (8, 1)],
                          ids=["H=KV", "groups2", "groups8"])
 @pytest.mark.parametrize("mask", ["causal", "noncausal", "window64",
                                   "window100"])
-@pytest.mark.parametrize("path", ["resident", "stream"])
+@pytest.mark.parametrize("path,blocks", DKDV_PLANS,
+                         ids=[f"{p}-S={b}blocks" for p, b in DKDV_PLANS])
 def test_dkdv_block_plans(path, mask, heads, blocks):
     """Each plan against the chunked reference at the file's tolerance,
     and against the other plan to 1e-6: the same float32 sums in the same
-    order, only the blocks arrive differently."""
+    order, only the blocks arrive differently (a window that ends the
+    bands early keeps the streamed call on its one q-block a step)."""
     fa = _fa()
     block = 32
     causal, window = mask != "noncausal", _window(mask)
@@ -434,7 +450,7 @@ def test_dkdv_block_plans(path, mask, heads, blocks):
     got = _dkdv(path, res, g, causal=causal, window=window, block=block)
     _, dk_ref, dv_ref = fa._reference_chunked_bwd(
         res, g, causal=causal, chunk=block, window=window)
-    other = _dkdv({"resident": "stream", "stream": "resident"}[path], res, g,
+    other = _dkdv("stream" if path == "resident" else "resident", res, g,
                   causal=causal, window=window, block=block)
     for a, ref, b in zip(got, (dk_ref, dv_ref), other):
         np.testing.assert_allclose(np.asarray(a), np.asarray(ref),
@@ -472,12 +488,55 @@ def test_dkdv_plan_bytes_at_the_benchmark_shape():
                                vmem_bytes=128 * 2 ** 20, **shape, **mask)
     assert grouped["path"] == "resident"
     assert grouped["hbm_bytes_per_head"] == 11 * 2 ** 20
-    # a core with 16 MiB of VMEM streams: 36 of the 64 steps run, and the
-    # last k-block's one step finds its block there from the step before
+    # a core with 16 MiB of VMEM streams, all 8 q-blocks ONE span of the
+    # call (15 MiB counted): the query side is fetched once a head, as the
+    # resident plan fetches it (at one q-block a grid step, until PR 49,
+    # 35 fetches of 640 KiB and float32 results: 28 MiB a head)
     small = fa.bwd_dkdv_plan(dtype=jnp.bfloat16, groups=1, vmem_bytes=16 * 2 ** 20,
                              **shape, **mask)
-    assert small["path"] == "stream"
-    assert small["hbm_bytes_per_head"] == (35 * 640 + 6 * 1024) * 1024
+    assert (small["path"], small["span"], small["in_flight"], small["steps"],
+            small["band_steps"]) == ("stream", 4096, 1, 1, 8)
+    assert small["walk_bytes"] == 15 * 2 ** 20
+    assert small["hbm_bytes_per_head"] == 9 * 2 ** 20
+    assert fa.hbm_bytes_per_head(
+        "stream", itemsize=2, out_itemsize=4, **shape,
+        q_index=functools.partial(
+            fa._q_block_index, steps=8, num_q=8, block_q=512, block_k=512,
+            **mask)) == (35 * 640 + 6 * 1024) * 1024
+    # the two cells whose full layers stream (bwd_dkdv_plan's docstring
+    # has what Mosaic planned beside each count): GLM-4.7-Flash, a head
+    # of 256, H == KV: 4 q-blocks a grid step, one in flight, bf16 results,
+    # 16 k-blocks x 4 spans of which 40 hold a block of a band (256 of
+    # which 136 at one q-block a step); Mellum2's full layers, 8 query
+    # heads a KV head: spans of 8, float32 results for the group's sum
+    glm = fa.bwd_dkdv_plan(S=8192, T=8192, D=256, dtype=jnp.bfloat16, groups=1,
+                           block_q=512, block_k=512, vmem_bytes=128 * 2 ** 20,
+                           **mask)
+    assert [glm[n] for n in ("path", "span", "in_flight", "walk_bytes",
+                             "steps", "band_steps", "out_dtype")] == [
+        "stream", 2048, 1, 16 * 2 ** 20, 4, 40, jnp.bfloat16]
+    mellum = fa.bwd_dkdv_plan(S=16384, T=16384, D=128, dtype=jnp.bfloat16,
+                              groups=8, block_q=512, block_k=512,
+                              vmem_bytes=128 * 2 ** 20, **mask)
+    assert [mellum[n] for n in ("path", "span", "in_flight", "walk_bytes",
+                                "steps", "band_steps", "out_dtype")] == [
+        "stream", 4096, 1, 15.5 * 2 ** 20, 4, 80, jnp.float32]
+    for plan in (small, glm, mellum):
+        assert plan["walk_bytes"] <= fa._SCOPED_VMEM_BYTES
+    # a MiB less and the GLM call holds 2 q-blocks a grid step
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(fa, "_SCOPED_VMEM_BYTES", 15 * 2 ** 20)
+        less = fa.bwd_dkdv_plan(
+            S=8192, T=8192, D=256, dtype=jnp.bfloat16, groups=1, block_q=512,
+            block_k=512, vmem_bytes=128 * 2 ** 20, **mask)
+        assert (less["span"], less["in_flight"], less["steps"],
+                less["band_steps"]) == (1024, 1, 8, 72)
+        mp.setattr(fa, "_SCOPED_VMEM_BYTES", 0)   # nothing fits: one, one
+        assert [fa.bwd_dkdv_plan(
+            S=8192, T=8192, D=256, dtype=jnp.bfloat16, groups=1, block_q=512,
+            block_k=512, vmem_bytes=128 * 2 ** 20, **mask)[n]
+            for n in ("span", "in_flight", "steps", "band_steps")] == [
+            512, 1, 16, 136]
     # S 8192 with Mistral's window still fits; its band is what streams
     long = dict(shape, S=8192, T=8192)
     assert fa.bwd_dkdv_plan(
@@ -499,71 +558,82 @@ WINDOWS = dict(argvalues=[0, 64, 100, 20, 256, 300],
 
 
 @pytest.mark.parametrize("window", **WINDOWS)
+@pytest.mark.parametrize("span", [1, 2, 4])
 @pytest.mark.parametrize("block_q,block_k", [(32, 32), (32, 64), (64, 32)])
-def test_dkdv_stream_grid_fetches_only_its_band(window, block_q, block_k):
+def test_dkdv_stream_grid_fetches_only_its_band(window, span, block_q,
+                                                block_k):
     """Walk the streamed dK/dV call's grid on the host in the order Mosaic
-    does. The q axis is as long as the longest band of a k-block and no
-    longer (all of S's q-blocks without a window, or with one that reaches
-    the sequence's end from the first k-block); where it is shorter than
-    S's it counts from the band's first q-block. Every q-block of every
-    band gets exactly one step, in rising order; on a step the kernel
-    runs, the query-side index is the block the kernel masks for. On a
-    step it skips, the index is the previous step's (nothing is fetched)
-    or, before a k-block's band opens, the band's first block (fetched
-    early, once): so over a head the index changes once per step that
-    runs, never for one skipped, and never leaves [0, num_q)."""
+    does. At one q-block a grid step the q axis is as long as the longest
+    band of a k-block and no longer (all of S's q-blocks without a window,
+    or with one that reaches the sequence's end from the first k-block);
+    where it is shorter than S's it counts from the band's first q-block.
+    At a span of q-blocks a step it counts S's spans. Every q-block of
+    every band is walked exactly once, in rising order, by the bounds the
+    kernel computes; on a step the kernel runs, the query-side index is
+    the block (the span) the kernel masks for. On a step it skips, the
+    index is the previous step's (nothing is fetched) or, before a
+    k-block's band opens, the band's first block (fetched early, once):
+    so over a head the index changes once per step that runs, never for
+    one skipped, and never leaves the axis."""
     fa = _fa()
     S = 256
     num_q, num_k = S // block_q, S // block_k
     kw = dict(num_q=num_q, block_q=block_q, block_k=block_k, causal=True,
               window=window)
-    steps, band_steps = fa._q_steps(num_k=num_k, **kw)
+    steps, band_steps = fa._q_steps(num_k=num_k, span=span, **kw)
     bands = [fa._q_band(ki, **kw) for ki in range(num_k)]
-    assert steps == max(hi - lo for lo, hi in bands) <= num_q
-    banded = steps < num_q
+    if span == 1:
+        assert steps == max(hi - lo for lo, hi in bands) <= num_q
+    else:
+        assert steps == num_q // span
+    banded = steps * span < num_q
     # the bands end early only under a window that cannot reach the end
-    assert banded == (0 < window <= S - block_q - block_k + 1), steps
+    assert banded == (span == 1
+                      and 0 < window <= S - block_q - block_k + 1), steps
+    index_of = functools.partial(fa._q_block_index, steps=steps, span=span,
+                                 **kw)
     prev, fetches, ran = None, 0, 0
     for ki, (lo, hi) in enumerate(bands):
         visited = []
         for j in range(steps):
-            # the kernel's: the axis counts from the band where shorter
-            qi = j + lo if banded else j
-            # what the kernel's pl.when computes, from positions
-            rows = np.arange(qi * block_q, (qi + 1) * block_q)[:, None]
-            cols = np.arange(ki * block_k, (ki + 1) * block_k)[None, :]
-            keep = (rows >= cols) & (rows < S)
-            if window:
-                # the band test is by blocks: the row at the window's
-                # edge, that sees nothing, still counts as in the band
-                keep &= rows - cols <= window
-            assert (lo <= qi < hi) == bool(keep.any()), (ki, qi)
-            index = fa._q_block_index(ki, j, steps=steps, **kw)
-            assert 0 <= index < num_q, (ki, j, index)
-            if lo <= qi < hi:
-                visited.append(qi)
-                assert index == qi
+            # the kernel's: the axis counts from the band where shorter,
+            # and a step holds the q-blocks of its span that the band has
+            first = j + lo if banded else j * span
+            mine = range(max(lo, first), min(hi, first + span))
+            for qi in range(first, first + span):
+                # what the kernel's pl.when computes, from positions
+                rows = np.arange(qi * block_q, (qi + 1) * block_q)[:, None]
+                cols = np.arange(ki * block_k, (ki + 1) * block_k)[None, :]
+                keep = (rows >= cols) & (rows < S)
+                if window:
+                    # the band test is by blocks: the row at the window's
+                    # edge, that sees nothing, still counts as in the band
+                    keep &= rows - cols <= window
+                assert (qi in mine) == bool(keep.any()), (ki, qi)
+            index = index_of(ki, j)
+            assert 0 <= index < num_q // span, (ki, j, index)
+            if mine:
+                visited += mine
+                assert index == first // span
             else:
-                assert index == prev or index == lo, (ki, qi, index, prev)
-            if not banded:          # the parent's grid and indices
-                assert index == max(min(j, hi - 1), lo)
+                assert index in (prev, lo // span), (ki, j, index, prev)
+            if not banded:          # the parent's grid and indices, by spans
+                assert index == max(min(j, (hi - 1) // span), lo // span)
             fetches += index != prev
+            ran += len(mine) > 0
             prev = index
         assert visited == list(range(lo, hi)), (ki, visited)
-        ran += len(visited)
     assert fetches <= ran == band_steps
     # the plan walks the same grid for its bytes
     assert fa.hbm_bytes_per_head(
         "stream", S=S, T=S, D=32, block_q=block_q, block_k=block_k,
-        itemsize=4, out_itemsize=4, steps=steps,
-        q_index=functools.partial(fa._q_block_index, steps=steps, **kw)
-    ) == fetches * block_q * (3 * 32 * 4 + 512) + 2 * S * 32 * 8
+        itemsize=4, out_itemsize=4, steps=steps, span=span, q_index=index_of
+    ) == fetches * span * block_q * (3 * 32 * 4 + 512) + 2 * S * 32 * 8
     # the same functions trace: an index map gets traced scalars
-    traced = jax.jit(lambda i, j: fa._q_block_index(i, j, steps=steps, **kw))
+    traced = jax.jit(index_of)
     for ki, j in [(0, 0), (num_k - 1, 0), (0, steps - 1),
                   (num_k // 2, 1 % steps)]:
-        assert int(traced(ki, j)) == fa._q_block_index(ki, j, steps=steps,
-                                                       **kw)
+        assert int(traced(ki, j)) == index_of(ki, j)
 
 
 # -- the forward's and the dQ call's walk over k-blocks ----------------------
@@ -572,8 +642,10 @@ def _one_block_walk(call, q, k, v, g=None, out=None, lse=None, *, causal,
                     window, block):
     """The walk the kernels replaced, written out: grid (b, h, q-block,
     k-block), ONE k-block a grid step, the sums in VMEM scratch (forward)
-    or in the float32 output block (dQ), every step masked. Operands
-    [B, H|KV, S, D]; returns (o, lse [B, H, S, 128]) or dq."""
+    or in the float32 output block (dQ), every step masked; for dK/dV its
+    mirror image, grid (b, h, k-block, q-block), ONE q-block a grid step,
+    both sums in their float32 output blocks. Operands [B, H|KV, S, D];
+    returns (o, lse [B, H, S, 128]), dq or (dk, dv) a query head."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -652,6 +724,45 @@ def _one_block_walk(call, q, k, v, g=None, out=None, lse=None, *, causal,
                 p * (dp - delta) * scale, f32(k_ref),
                 preferred_element_type=jnp.float32)
 
+    def dkdv(q_ref, k_ref, v_ref, g_ref, o_ref, lse_ref, dk_ref, dv_ref):
+        ki, qi = pl.program_id(2), pl.program_id(3)
+
+        @pl.when(qi == 0)
+        def _():
+            dk_ref[0, 0] = jnp.zeros_like(dk_ref[0, 0])
+            dv_ref[0, 0] = jnp.zeros_like(dv_ref[0, 0])
+
+        @pl.when(in_band(qi, ki))
+        def _():
+            f32 = lambda ref: ref[0, 0].astype(jnp.float32)  # noqa: E731
+            delta = jnp.sum(f32(o_ref) * f32(g_ref), axis=-1, keepdims=True)
+            p = jnp.exp(scores(f32(q_ref), f32(k_ref), qi, ki)
+                        - lse_ref[0, 0][:, 0:1])
+            dv_ref[0, 0] += jax.lax.dot_general(
+                p, f32(g_ref), (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dp = jax.lax.dot_general(
+                f32(g_ref), f32(v_ref), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dk_ref[0, 0] += jax.lax.dot_general(
+                p * (dp - delta) * scale, f32(q_ref),
+                (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+
+    if call == "dkdv":
+        def rows(width):
+            return pl.BlockSpec((1, 1, block, width),
+                                lambda b, h, i, j: (b, h, j, 0))
+
+        keys = pl.BlockSpec((1, 1, block, D),
+                            lambda b, h, i, j: (b, h // groups, i, 0))
+        sums = pl.BlockSpec((1, 1, block, D), lambda b, h, i, j: (b, h, i, 0))
+        return pl.pallas_call(
+            dkdv, grid=(B, H, num_k, S // block),
+            in_specs=[rows(D), keys, keys, rows(D), rows(D), rows(128)],
+            out_specs=[sums, sums],
+            out_shape=[jax.ShapeDtypeStruct((B, H, S, D), jnp.float32)] * 2,
+            interpret=True)(q, k, v, g, out, lse)
+
     def rows(width):
         return pl.BlockSpec((1, 1, block, width),
                             lambda b, h, i, j: (b, h, i, 0))
@@ -677,21 +788,42 @@ def _one_block_walk(call, q, k, v, g=None, out=None, lse=None, *, causal,
         interpret=True)(q, k, v, g, out, lse).astype(q.dtype)
 
 
-@pytest.mark.parametrize("blocks", [1, 3, 8],
-                         ids=["S=block", "S=3blocks", "S=8blocks"])
-@pytest.mark.parametrize("heads", [(2, 2), (4, 2)], ids=["H=KV", "groups2"])
-@pytest.mark.parametrize("mask", ["causal", "noncausal", "window64",
-                                  "window100"])
-@pytest.mark.parametrize("path", ["loop", "stream"])
-@pytest.mark.parametrize("call", ["fwd", "dq"])
+# (call, plan, blocks of S, mask, heads). The forward and the dQ call on the
+# loop plan (one span: a head's whole K and V) and the stream plan at spans of
+# half the blocks, under every mask; the dK/dV call on its resident and
+# stream plans at 8 blocks; and all three on the stream plan at `<blocks a
+# grid step>x<blocks in flight>` with whole spans written out (`l`: walked in
+# a loop), at 8 and 16 blocks, where whole, diagonal and empty spans all occur
+MASKS = ("causal", "noncausal", "window64", "window100")
+WALKS = [(call, plan, blocks, mask, heads) for call in ("fwd", "dq")
+         for plan in ("loop", "stream") for blocks in (1, 3, 8)
+         for mask in MASKS for heads in ((2, 2), (4, 2))] + [
+    ("dkdv", plan, 8, mask, (2, 2)) for plan in ("loop", "stream")
+    for mask in MASKS] + [
+    (call, plan, blocks, mask, heads) for call in ("fwd", "dq", "dkdv")
+    for plan, blocks in (("stream2x1", 8), ("stream2x2", 8), ("stream4x1", 8),
+                         ("stream4x2l", 8), ("stream4x2", 16),
+                         ("stream8x2", 16), ("stream8x1", 16))
+    for mask, heads in (("causal", (4, 2)), ("window100", (2, 2)))]
+_ONE_BLOCK_WALKS = {}      # (call, blocks, mask, heads) -> what it returned
+
+
+@pytest.mark.parametrize("call,path,blocks,mask,heads", WALKS, ids=[
+    f"{c}-{p}-S={b}blocks-{m}-{'H=KV' if h == (2, 2) else 'groups2'}"
+    for c, p, b, m, h in WALKS])
 def test_the_walk_of_k_blocks_is_the_one_block_walk_to_the_last_bit(
         call, path, mask, heads, blocks):
     """The forward and the dQ call, on the loop plan (one span: a head's
     whole K and V) and the stream plan (spans of half the k-blocks, of one
     where their number is odd), two k-blocks a step of the walk wherever a
-    span holds two: bit-equal to a walk of one k-block a grid step, the
-    parent's, written out above. Three blocks walk a pair and an odd one;
-    a window's band starts inside a span."""
+    span holds two, a span that lies whole inside the band written out
+    with the sums read from scratch once at its top: bit-equal to a walk
+    of one k-block a grid step, the parent's, written out above. Three
+    blocks walk a pair and an odd one; a window's band starts inside a
+    span. The dK/dV call, resident and streamed at every span of q-blocks
+    and number in flight, likewise to a walk of one q-block a grid step
+    (a window that ends the bands early keeps it on the band plan's one
+    q-block a step, whatever walk is asked for)."""
     fa = _fa()
     block = 32
     causal, window = mask != "noncausal", _window(mask)
@@ -707,21 +839,39 @@ def test_the_walk_of_k_blocks_is_the_one_block_walk_to_the_last_bit(
         n for n in range(1, blocks // 2 + 1) if blocks % n == 0) \
         if blocks > 1 else 1
     plan = dict(path=path, span=span * block, in_flight=min(2, span))
+    form = path.partition("stream")[2]
+    if form:
+        span, in_flight = map(int, form.rstrip("l").split("x"))
+        plan = dict(path="stream", span=span * block, in_flight=in_flight,
+                    written=not form.endswith("l"))
     kw = dict(causal=causal, block_q=block, block_k=block, window=window,
               scale=64 ** -0.5)
     t = lambda x: x.transpose(0, 2, 1, 3)  # noqa: E731
-    want_o, want_lse = _one_block_walk(
-        "fwd", t(q), t(k), t(v), causal=causal, window=window, block=block)
+
+    def one_block_walk(call, *args):
+        key = (call, blocks, mask, heads)
+        if key not in _ONE_BLOCK_WALKS:     # the same inputs in every case
+            _ONE_BLOCK_WALKS[key] = _one_block_walk(
+                call, *args, causal=causal, window=window, block=block)
+        return _ONE_BLOCK_WALKS[key]
+
+    want_o, want_lse = one_block_walk("fwd", t(q), t(k), t(v))
     if call == "fwd":
         got_o, got_lse = fa._flash_fwd(q, k, v, plan=plan, **kw)
         np.testing.assert_array_equal(t(got_o), want_o)
         np.testing.assert_array_equal(got_lse, want_lse)
         return
     args = (t(q), t(k), t(v), t(g), want_o, want_lse)
-    want = _one_block_walk("dq", *args, causal=causal, window=window,
-                           block=block)
-    np.testing.assert_array_equal(
-        fa._flash_bwd_dq(*args, plan=plan, **kw), want)
+    want = one_block_walk(call, *args)
+    if call == "dq":
+        np.testing.assert_array_equal(
+            fa._flash_bwd_dq(*args, plan=plan, **kw), want)
+        return
+    got = fa._flash_bwd_dkdv(
+        *args, **kw, vmem_bytes=128 * 2 ** 20 if path == "loop" else 1024,
+        walk=(span, plan["in_flight"]))
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(a, b)
 
 
 @pytest.mark.parametrize("window", **WINDOWS)
@@ -751,9 +901,10 @@ def test_kv_span_grid_fetches_only_its_band(window, span, block_q, block_k):
         assert not banded
     if num_k % span and not banded:
         pytest.skip("no plan cuts T into spans that do not divide it")
-    steps, band_steps = fa._span_steps(num_q=num_q, span=span, **kw)
+    steps, band_steps, whole_steps = fa._span_steps(num_q=num_q, span=span,
+                                                    **kw)
     assert steps == (1 if banded else num_k // span)
-    prev, fetches, ran = None, 0, 0
+    prev, fetches, ran, whole = None, 0, 0, 0
     for qi, (lo, hi) in enumerate(bands):
         first, last = lo // span, (hi - 1) // span
         walked = []
@@ -792,10 +943,15 @@ def test_kv_span_grid_fetches_only_its_band(window, span, block_q, block_k):
             assert start() == (index if banded else si * span)
             walked += mine
             ran += len(mine) > 0
+            # a span all of whose blocks are the band's is written out
+            whole += len(mine) == span > 1 and steps > 1
             fetches += index != prev
             prev = index
         assert walked == list(range(lo, hi)), (qi, walked)
     assert fetches <= ran == band_steps
+    assert whole == whole_steps <= band_steps
+    assert fa._span_steps(num_q=num_q, span=span, written=False,
+                          **kw) == (steps, band_steps, 0)
     # the same functions trace: an index map gets traced scalars
     index_of = functools.partial(fa._band_start, span=span, **kw) if banded \
         else lambda qi, si: fa._k_span_index(qi, si, span=span, **kw)
@@ -824,7 +980,26 @@ def test_kv_plan_takes_the_longest_span_that_fits_then_a_second_block():
            for c in ("fwd", "dq")}
     assert [(p["path"], p["span"], p["in_flight"]) for p in glm.values()] == [
         ("stream", 4096, 2), ("stream", 4096, 1)]
-    for plan in list(l8.values()) + list(glm.values()):
+    # a span that lies whole inside the band is written out where that
+    # fits: the dQ call's eight blocks do, one in flight (16.0 MiB counted,
+    # 16.0 planned: kv_plan's docstring), 10 of a head's 24 working steps;
+    # the forward's do not (21.5 counted, 19.25 planned) and walk in the
+    # loop, as one span of the loop plan does
+    assert [(p["written"], p["whole_steps"], p["band_steps"])
+            for p in list(glm.values()) + list(l8.values())] == [
+        (False, 0, 24), (True, 10, 24), (False, 0, 8), (False, 0, 8)]
+    assert glm["dq"]["walk_bytes"] == 16 * 2 ** 20
+    # the Mellum2 cell's full layers (S 16,384, D 128): the forward's span
+    # of 16 blocks is too long to write out and is cut to 8, which are
+    # (31.9 -> 30.6 ms a call; the dQ call lost 6% by the same cut and
+    # keeps its 16 in the loop: PERF.md 6, PR 49)
+    mellum = {c: fa.kv_plan(S=16384, T=16384, D=128, call=c, **shape)
+              for c in ("fwd", "dq")}
+    assert [(p["path"], p["span"], p["in_flight"], p["written"], p["steps"],
+             p["band_steps"], p["whole_steps"]) for p in mellum.values()] == [
+        ("stream", 4096, 2, True, 4, 80, 52),
+        ("stream", 8192, 2, False, 2, 48, 0)]
+    for plan in [*l8.values(), *glm.values(), *mellum.values()]:
         assert plan["walk_bytes"] <= fa._SCOPED_VMEM_BYTES
     # a MiB less: half the span, where the dQ call's pair fits again; at
     # 12 MiB the longer span with one block still comes before the pair
@@ -856,9 +1031,11 @@ def test_kv_plan_takes_the_longest_span_that_fits_then_a_second_block():
     assert [fa.kv_plan(S=8192, T=8192, D=128, window=w, **shape)[n]
             for w in (8192, 0) for n in ("path", "steps", "band_steps")] == [
         "stream", 1, 16, "loop", 1, 16]
-    # a band too long to hold streams over T as it did (33 blocks of 64)
+    # a band too long to hold streams over T as it did (33 blocks of 64),
+    # the forward in spans of 8 blocks, written out, for 16 in a loop
     long = fa.kv_plan(S=32768, T=32768, D=128, window=16384, **shape)
-    assert (long["path"], long["span"], long["steps"]) == ("stream", 8192, 4)
+    assert (long["path"], long["span"], long["steps"], long["written"]) == (
+        "stream", 4096, 8, True)
     assert fa.kv_plan(S=8192, T=8192, D=128, window=1024, call="dq",
                       **shape)["path"] == "loop"
 
@@ -887,17 +1064,41 @@ def test_flash_bwd_plan_instant_once_a_trace(monkeypatch):
     assert seen[0][1] == {"path": "loop", "S": 128, "D": 32,
                           "kv_block_bytes": 2 * 2 * 128 * 32 * 4,
                           "span": 128, "in_flight": 1, "grid_steps": 8,
-                          "band_steps": 8}
+                          "band_steps": 8, "whole_steps": 0}
+    # the dK/dV call's span, in queries (a resident call holds all of S),
+    # the q-blocks in flight and the bytes counted for a streamed call's
+    # step; `whole_steps`: no span is written out on the loop plan
     attrs = seen[1][1]
     assert attrs == {
         "path": "resident", "S": 128, "block_q": 32, "block_k": 32,
         "window": 0, "resident_bytes": attrs["resident_bytes"],
         "hbm_bytes_per_head": fa.hbm_bytes_per_head(
             "resident", S=128, T=128, D=32, block_q=32, block_k=32,
-            itemsize=4, out_itemsize=4), "grid_steps": 8, "band_steps": 8,
+            itemsize=4, out_itemsize=4), "span": 128, "in_flight": 1,
+        "walk_bytes": attrs["walk_bytes"], "grid_steps": 8, "band_steps": 8,
         "dq_path": "loop", "dq_span": 128, "dq_in_flight": 2,
-        "dq_grid_steps": 8, "dq_band_steps": 8}
+        "dq_grid_steps": 8, "dq_band_steps": 8, "dq_whole_steps": 0}
     assert all(isinstance(x, (int, str)) for x in attrs.values())
+    # streamed (eight blocks of 32 and a budget of 200,000 bytes: four
+    # k-blocks a grid step, written out, two q-blocks in the dK/dV call):
+    # the instants count their grids in spans, and the steps whose span
+    # is written out
+    seen.clear()
+    monkeypatch.setattr(fa, "_SCOPED_VMEM_BYTES", 200_000)
+    monkeypatch.setattr(fa, "_vmem_bytes", lambda: 1024)
+    q, k, v = _make(B=1, S=256, H=2, KV=2, D=32)
+    jax.block_until_ready(jax.jit(jax.grad(lambda *a: flash_attention(
+        *a, block_q=32, block_k=32).sum(), argnums=(0, 1, 2)))(q, k, v))
+    fwd, back = seen[0][1], seen[1][1]
+    assert [fwd[n] for n in ("path", "span", "in_flight", "grid_steps",
+                             "band_steps", "whole_steps")] == [
+        "stream", 128, 2, 32, 24, 12]
+    assert [back[n] for n in ("path", "span", "in_flight", "grid_steps",
+                              "band_steps", "dq_path", "dq_span",
+                              "dq_in_flight", "dq_grid_steps",
+                              "dq_band_steps", "dq_whole_steps")] == [
+        "stream", 64, 1, 64, 40, "stream", 128, 2, 32, 24, 12]
+    assert back["walk_bytes"] == 188_416
 
 
 @pytest.mark.parametrize("window", [None, 100, 192],

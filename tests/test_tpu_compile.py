@@ -164,8 +164,10 @@ def test_flash_calls_keep_their_face_on_the_stream_plans(one_chip,
     """The same face at the GLM-4.7-Flash cell's attention shape (S 8192,
     D 256), where all three calls stream by the bytes alone
     (``benchmark/readers/glm_kernel_roofline.py`` tells them as the dense
-    reader does): forward 3 -> 2, dq 6 -> 1 and still float32 (the spans
-    of a q-block add up in the output block), dkdv 6 -> 2, q and k first;
+    reader does): forward 3 -> 2, dq 6 -> 1, dkdv 6 -> 2, q and k first,
+    and since PR 49 every result in its own dtype (the spans add up in
+    float32 VMEM scratch and the call writes once: dq, and with H == KV
+    dk and dv, are bf16 where they were float32 and cast afterwards);
     each call is named after the scope of its plan."""
     import re
 
@@ -193,8 +195,12 @@ def test_flash_calls_keep_their_face_on_the_stream_plans(one_chip,
             r"operand_layout_constraints=\{(.*?\})\}", ln).group(1))
         assert shapes[:2] == [f"{B},{H},{S},{D}"] * 2, ln[:400]
         assert f"({scope})" in re.search(r'op_name="([^"]*)"', ln).group(1)
-    assert re.search(rf" = f32\[{B},{H},{S},{D}\]", lines[(1, 6)]), \
+    assert re.search(rf" = bf16\[{B},{H},{S},{D}\]", lines[(1, 6)]), \
         lines[(1, 6)][:300]
+    assert re.search(rf" = \(bf16\[{B},{H},{S},{D}\]\S*, "
+                     rf"bf16\[{B},{H},{S},{D}\]", lines[(2, 6)]), \
+        lines[(2, 6)][:300]
+    assert " f32[" not in lines[(2, 6)].split("custom-call(")[0]
 
 
 # (batch, seq, heads, kv_heads, head_dim, stated scale) of the attention
@@ -231,8 +237,8 @@ def test_the_cells_flash_walks_fit_the_vmem_a_call_gets(cell, one_chip,
     plan asks (``bwd_dkdv_plan``). The Mellum2 cell's window layers take
     the banded plans: a q-block's whole band of three k-blocks in one grid
     step, fetched where it starts, and a dK/dV q axis of a k-block's three
-    q-blocks; its full layers and the GLM cell keep the streaming plans to
-    the letter."""
+    q-blocks; its full layers and the GLM cell stream, a span of blocks a
+    grid step in all three calls (PR 49: the dK/dV call too)."""
     import re
     import sys
 
@@ -274,27 +280,43 @@ def test_the_cells_flash_walks_fit_the_vmem_a_call_gets(cell, one_chip,
              for c in ("fwd", "dq")}
     want = [("loop", S, 1), ("loop", S, 2)]
     grids = [(S // 512, S // 512)] * 2      # a head's grid steps, at work
+    written = [False, False]                # whole spans straight-line
     if streams:         # half a head's keys a grid step, two a q-block
         want = [("stream", S // 2, 2),
                 ("stream", S // 2, 1 if D == 256 else 2)]
         grids = [(2 * S // 512, 3 * S // 1024)] * 2
+        # what is written out: the dQ call's spans of 8 blocks at a head
+        # of 256 (the forward's ask for 19 MiB that way), the forward's at
+        # a head of 128, cut to 8 blocks for it (4 spans a q-block)
+        written = [D == 128, D == 256]
+        if D == 128:
+            want[0], grids[0] = ("stream", S // 4, 2), (4 * S // 512, 80)
     if window:          # a q-block's band of three k-blocks in one step
         want = WIDE_WINDOW.get(window, [("band", window + 512, 3)] * 2)
         grids = [(S // 512, S // 512)] * 2
+        written = [False, False]
     assert [(p["path"], p["span"], p["in_flight"])
             for p in plans.values()] == want, plans
     assert [(S // 512 * p["steps"], p["band_steps"])
             for p in plans.values()] == grids, plans
+    assert [p["written"] for p in plans.values()] == written, plans
+    assert [p["whole_steps"] > 0 for p in plans.values()] == written, plans
     dkdv = fa.bwd_dkdv_plan(
         S=S, T=S, D=D, dtype=jnp.bfloat16, groups=H // KV, block_q=512,
         block_k=512, causal=True, window=window or 0,
         vmem_bytes=fa._V5E_VMEM_BYTES)
-    # (path, a head's grid steps, those at work): the causal triangle, or
-    # three q-blocks a k-block and the sequence's end
+    # (path, a head's grid steps, those at work): the causal triangle in
+    # spans of 4 q-blocks (a head of 256) or 8 (of 128) a k-block, or three
+    # q-blocks a k-block and the sequence's end
     assert (dkdv["path"], S // 512 * dkdv["steps"], dkdv["band_steps"]) == (
         ("band", 96, 93) if window and window not in WIDE_WINDOW else
-        ("stream", (S // 512) ** 2, S // 512 * (S // 512 + 1) // 2)
-        if streams else ("resident", S // 512, S // 512)), dkdv
+        ("stream", 64, 40) if streams and D == 256 else
+        ("stream", 128, 80) if streams else
+        ("resident", S // 512, S // 512)), dkdv
+    if dkdv["path"] == "stream":
+        assert (dkdv["span"], dkdv["in_flight"]) == (
+            (2048, 1) if D == 256 else (4096, 1)), dkdv
+        assert dkdv["walk_bytes"] <= fa._SCOPED_VMEM_BYTES
     # the compiled calls carry the scope of the plan they took
     for call, plan in (("fwd", plans["fwd"]), ("dq", plans["dq"]),
                        ("dkdv", dkdv)):
@@ -340,6 +362,77 @@ def test_banded_calls_at_a_head_of_256_fit_the_vmem_a_call_gets(
     for scope in (f"flash.fwd.{fwd[0]}", f"flash.dq.{dq[0]}",
                   "flash.dkdv.band"):
         assert scope in text, scope
+
+
+# The flash calls of the cells that do NOT stream, and the four calls of the
+# attention over a set at the GLM-5.2 cell's shape: sha256[:16] of the jaxpr
+# of the call and its gradient (the kernels' bodies are in it), taken at PR
+# 49's PARENT (274de6d, jax JAXPRS_FROM). PR 49 rebuilt the stream plans of
+# ``ops/flash_attention.py`` and moved ``_span_walk`` there; whoever changes
+# those files next and means to leave a plan alone finds out here, without
+# unpacking a parent (the recipe PR 39 and PR 46 ran by hand). A digest that
+# moves with a change that MEANS to change the plan is replaced, and says so.
+# (batch, seq, heads, kv heads, head width, stated scale, window)
+JAXPRS_FROM = "0.9.0"
+PARENT_FLASH_JAXPRS = {
+    "train-deepseek7b-l8": (
+        (3, 4096, 32, 32, 128, None, None), "a29ac8cac53c77ad"),
+    "train-deepseek7b-fsdp2tp2": (
+        (2, 4096, 16, 16, 128, None, None), "3dae55e1e888278a"),
+    "train-olmoe1b7b-s4096-b4": (
+        (4, 4096, 16, 16, 128, None, None), "79bbd65e4a7c0ae1"),
+    "train-granite4hs-ep8-s8192-b2": (
+        (2, 8192, 32, 8, 128, 0.0078125, None), "dae59988254d481e"),
+    "train-nemotron3nano-ep8-s8192-b2": (
+        (2, 8192, 32, 2, 128, None, None), "549b8bf55f644cd8"),
+    "train-mellum2-ep4-s16384-b1/window": (
+        (1, 16384, 32, 4, 128, None, 1024), "f0738639ded60b83"),
+    "train-commandaplus-ep16-s8192-b1/full": (
+        (1, 8192, 32, 2, 128, None, None), "9ac5c76f7f7ff904"),
+    "train-commandaplus-ep16-s8192-b1/window": (
+        (1, 8192, 32, 2, 128, None, 4096), "c64410a606bd06ce"),
+    "train-glm52-ep32-s16384-b1/sparse": (None, "b598c4e3fb200c19"),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(PARENT_FLASH_JAXPRS))
+def test_the_plans_pr49_left_alone_trace_to_its_parents_programs(
+        cell, on_chip_branch):
+    """loop / resident / band at the seven other cells' shapes, and the
+    sparse forward, head-mean probabilities, dQ and dK/dV: to the
+    character."""
+    import hashlib
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import sparse_attention as sa
+    from ray_tpu.ops.flash_attention import flash_attention
+
+    shape, want = PARENT_FLASH_JAXPRS[cell]
+    if shape is None:
+        B, S, H, D = GLM52_ATTENTION
+        q = jax.ShapeDtypeStruct((B, S, H, D), jnp.bfloat16)
+        args = (q, q, q, jax.ShapeDtypeStruct((B, S, S), jnp.int8))
+
+        def loss(q, k, v, keep):
+            o, p = sa.sparse_attention(q, k, v, keep, with_probs=True)
+            return o.astype(jnp.float32).sum() + p.sum()
+    else:
+        B, S, H, KV, D, scale, window = shape
+        kv = jax.ShapeDtypeStruct((B, S, KV, D), jnp.bfloat16)
+        args = (jax.ShapeDtypeStruct((B, S, H, D), jnp.bfloat16), kv, kv)
+
+        def loss(q, k, v):
+            return flash_attention(q, k, v, scale=scale, window=window
+                                   ).astype(jnp.float32).sum()
+
+    closed = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(*args)
+    assert "pallas_call" in str(closed)
+    if jax.__version__ == JAXPRS_FROM:
+        text = re.sub(r" at 0x[0-9a-f]+", "", str(closed))
+        assert hashlib.sha256(text.encode()).hexdigest()[:16] == want
 
 
 # (rows, experts, model width, one expert's width) of a cell's grouped
