@@ -82,12 +82,16 @@ def init(address: Optional[str] = None, *,
             if ignore_reinit_error:
                 return dict(_session or {})
             raise RuntimeError("ray_tpu.init() already called")
+        # span `core.init` and a child a phase, stamped as each ends and
+        # recorded once the runtime is up to carry them
+        phases = [("", time.time())]
         cfg = Config.load(_system_config)
         procs = []
         if address is None:
             session_dir = new_session_dir()
             gcs_proc, gcs_addr = start_gcs(session_dir, cfg)
             procs.append(gcs_proc)
+            phases.append(("core.init.gcs", time.time()))
             res = dict(resources or {})
             res.setdefault("CPU", float(num_cpus if num_cpus is not None
                                         else (os.cpu_count() or 1)))
@@ -99,6 +103,8 @@ def init(address: Optional[str] = None, *,
             nodelet_proc, nodelet_addr, node_id_hex, store_name = start_nodelet(
                 session_dir, cfg, gcs_addr, resources=res)
             procs.append(nodelet_proc)
+            phases.append(("core.init.nodelet", time.time()))
+            said = {"nodes": 1, "num_cpus": res["CPU"]}
         else:
             session_dir = os.environ.get("RAY_TPU_SESSION_DIR", new_session_dir())
             h, p = address.rsplit(":", 1)
@@ -122,6 +128,9 @@ def init(address: Optional[str] = None, *,
             nodelet_addr = alive[0].nodelet_addr
             store_name = alive[0].store_name
             node_id_hex = alive[0].node_id.hex()
+            phases.append(("core.init.connect", time.time()))
+            said = {"nodes": len(alive), "num_cpus": sum(
+                n.resources_total.quantities.get("CPU", 0.0) for n in alive)}
 
         job_id = JobID.from_random()
         runtime = _rt.Runtime(cfg, gcs_addr, nodelet_addr, store_name, job_id,
@@ -139,6 +148,13 @@ def init(address: Optional[str] = None, *,
                          meta={"namespace": namespace, "pid": os.getpid()})
         if cfg.log_to_driver:
             runtime.subscribe_logs()
+        from ray_tpu.util import tracing    # ray_tpu.util imports ray_tpu
+
+        phases.append(("core.init.runtime", time.time()))
+        tracing.emit_span("core.init", phases[0][1],
+                          phases[-1][1] - phases[0][1], said, always=True)
+        for (_, t0), (phase, t1) in zip(phases, phases[1:]):
+            tracing.emit_span(phase, t0, t1 - t0, always=True)
         _session = {
             "address": f"{gcs_addr[0]}:{gcs_addr[1]}",
             "session_dir": session_dir,
@@ -362,17 +378,21 @@ def internal_stats() -> Dict[str, dict]:
     return out
 
 
-def timeline(limit: int = 1000, chrome: bool = False) -> List[dict]:
+def timeline(limit: int = 1000, chrome: bool = False,
+             spans_only: bool = False) -> List[dict]:
     """Recent task state transitions and tracing spans from the GCS
     task-event store (ref: `ray timeline` scripts.py:1835). Flushes the
     local TelemetryAgent first, so spans recorded just before the call
     are visible (read-your-writes). `chrome=True` returns the merged
     Chrome trace with per-worker lanes instead of raw events
     (observability/timeline.py) — json.dump it and load in
-    chrome://tracing."""
+    chrome://tracing. `spans_only` leaves the task states out: the
+    newest `limit` spans and instants (what `JaxTrainer.fit` writes as
+    the job's `timeline.json`)."""
     rt = _rt.get_runtime()
     rt.flush_task_events(wait=True)
-    events = rt.gcs_call("list_task_events", limit=limit)
+    events = rt.gcs_call("list_task_events", limit=limit,
+                         spans_only=spans_only)
     if chrome:
         from ray_tpu.observability import chrome_trace
 

@@ -26,6 +26,7 @@ just state as of the last snapshot point.
 from __future__ import annotations
 
 import asyncio
+import itertools
 import logging
 import os
 import pickle
@@ -112,6 +113,11 @@ class GcsServer:
         # reordered/duplicated stale gang-demand reports)
         self._gang_demand_seq: Dict[str, int] = {}
         self.task_events: deque = deque(maxlen=cfg.task_event_buffer_size)
+        # what `tracing` recorded (spans, instants), apart from the task
+        # states: a driver that polls its workers writes eight states a
+        # second, and what a job kept of its set-up must still be there
+        # when the job ends, hours later
+        self.span_events: deque = deque(maxlen=cfg.task_event_buffer_size)
         # per-edge EWMA latency/bandwidth fed by batched telemetry
         # reports (in-memory: telemetry, re-learned after failover)
         from ray_tpu.observability.edges import EdgeModel
@@ -879,10 +885,14 @@ class GcsServer:
 
     async def rpc_add_task_events(self, events: List[dict]) -> dict:
         # ref: gcs_task_manager.h bounded task-event store for observability.
-        self.task_events.extend(events)
-        for ev in events:
-            self.health.observe_task_event(ev)
+        self._store_events(events)
         return {"ok": True}
+
+    def _store_events(self, events: List[dict]) -> None:
+        for ev in events:
+            (self.span_events if ev.get("kind") in ("span", "instant")
+             else self.task_events).append(ev)
+            self.health.observe_task_event(ev)
 
     async def rpc_telemetry_report(self, report: dict) -> dict:
         """One batched report from a process's TelemetryAgent (ref:
@@ -897,11 +907,7 @@ class GcsServer:
 
         from ray_tpu.util.metrics import merge_payload
 
-        events = report.get("events") or []
-        if events:
-            self.task_events.extend(events)
-            for ev in events:
-                self.health.observe_task_event(ev)
+        self._store_events(report.get("events") or [])
         stalled: List[str] = []
         beacons = report.get("beacons")
         if beacons:
@@ -951,7 +957,7 @@ class GcsServer:
                            ev.get("kind"), ev.get("component"),
                            ev.get("worker"), ev.get("age_s", 0.0),
                            ev.get("context"))
-            self.task_events.append({
+            self.span_events.append({
                 "kind": "instant",
                 "name": f"{ev.get('kind')}::{ev.get('component')}",
                 "ts": ev.get("ts"), "worker": ev.get("worker"),
@@ -1083,15 +1089,21 @@ class GcsServer:
         return self.edge_model.stats()
 
     async def rpc_list_task_events(self, limit: int = 1000,
-                                   job_id: Optional[JobID] = None) -> List[dict]:
-        out = []
-        for ev in reversed(self.task_events):
-            if job_id is not None and ev.get("job_id") != job_id:
-                continue
-            out.append(ev)
-            if len(out) >= limit:
-                break
-        return out
+                                   job_id: Optional[JobID] = None,
+                                   spans_only: bool = False) -> List[dict]:
+        """Newest first, `limit` in all. `spans_only`: what `tracing`
+        recorded (spans and instants) and no task state, for a job's
+        timeline."""
+        stores = ((self.span_events,) if spans_only
+                  else (self.span_events, self.task_events))
+        out: List[dict] = []
+        for store in stores:
+            out.extend(itertools.islice(
+                (ev for ev in reversed(store)
+                 if job_id is None or ev.get("job_id") == job_id), limit))
+        if len(stores) > 1:
+            out.sort(key=lambda ev: ev.get("ts") or 0.0, reverse=True)
+        return out[:limit]
 
     # ----------------------------------------------------------------- pubsub
 

@@ -462,7 +462,7 @@ def mixer_half(x, lp, cfg: HybridConfig, kind: str, mesh=None):
     G = cfg.mamba_groups
     inner, conv_dim, _ = _mamba_sizes(cfg)
     dt_, f32 = cfg.dtype, jnp.float32
-    tracing.instant("mixer.plan", plan(cfg, B, S))
+    tracing.plan("mixer.plan", plan(cfg, B, S))
     u = _ll.rms_norm(x, lp["mix_norm"], cfg.norm_eps)
     zxbcdt = u @ _ll._dq(lp["in_proj"], dt_)
     z, step = zxbcdt[..., :inner], zxbcdt[..., inner + conv_dim:]

@@ -726,7 +726,7 @@ def attention_half(x, lp, cfg: LatentConfig, cos, sin, mesh=None, rules=None,
     B, S, _ = x.shape
     H, dn, dv, dt = cfg.n_heads, cfg.qk_nope_dim, cfg.v_dim, cfg.dtype
     R, rk, f32 = cfg.qk_rope_dim, cfg.kv_rank, jnp.float32
-    tracing.instant("mla.plan", plan(cfg, B, S))
+    tracing.plan("mla.plan", plan(cfg, B, S))
     w = lambda name: _ll._dq(lp[name], dt)                     # noqa: E731
     wq_b = w("wq_b").reshape(cfg.q_rank, H, dn + R)
     wq_s = _swapped(wq_b[..., dn:])                            # [q_rank, H, R]
@@ -779,7 +779,7 @@ def _attend_set(q, k, v, h, c_q, lp, cfg: LatentConfig, cos, sin, carried,
     from ray_tpu.ops.sparse_attention import sparse_attention
 
     B, S = q.shape[:2]
-    tracing.instant("dsa.plan", index_plan(cfg, B, S, kind))
+    tracing.plan("dsa.plan", index_plan(cfg, B, S, kind))
     scale = cfg.attn_scale                      # None: head_dim ** -0.5
     if not _selects(kind):
         with jax.named_scope("sparse"):

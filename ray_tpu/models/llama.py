@@ -473,7 +473,7 @@ def _say_remat_plan(plan: RematPlan):
     """The instant ``remat.plan`` of a trace, once a traced forward under
     the layer checkpoint: the names kept beyond the parent's list, their
     bytes, the estimate they were added to and the limit."""
-    tracing.instant("remat.plan", {
+    tracing.plan("remat.plan", {
         "kept": ",".join(plan.kept), "kept_bytes": plan.kept_bytes,
         "estimate": plan.estimate, "limit": plan.limit,
         "ceiling": int(plan.limit * (1 - REMAT_FREE)), "why": plan.why})
@@ -892,7 +892,7 @@ def _say_tp_plan(tp, cfg: LlamaConfig, batch: int, seq: int):
     that was given a mesh and rules, after its layers are traced: which
     path they took and, from the helpers' own calls, at how many sites."""
     rows = seq // tp.shards if tp else seq
-    tracing.instant("tp.overlap_plan", {
+    tracing.plan("tp.overlap_plan", {
         "path": "overlap" if tp else "plain",
         "shards": tp.shards if tp else 1,
         # a layer's gathers (q/k/v; gate/up) and scatters (wo; w_down)
@@ -907,7 +907,7 @@ def _say_layer_plan(runs, bodies: int):
     how many runs of adjacent layers of one kind (one scan each), how
     many bodies were built for them (one a kind) and the runs themselves,
     "kind xN, ..." in the layers' order."""
-    tracing.instant("hybrid.layer_plan", {
+    tracing.plan("hybrid.layer_plan", {
         "kinds": len({k for k, _ in runs}), "runs": len(runs),
         "bodies": bodies, "layers": sum(n for _, n in runs),
         "pattern": ", ".join(f"{k} x{n}" for k, n in runs)})
@@ -917,7 +917,7 @@ def _say_kind_plan(cfg: LlamaConfig, kind, of: AttentionKind, seq: int):
     """The instant ``attn.kind_plan`` of a trace, once a kind of attention
     layer and traced forward: the kind's window (0: none) and rotary
     tables, and the heads they serve."""
-    tracing.instant("attn.kind_plan", {
+    tracing.plan("attn.kind_plan", {
         "kind": kind or "attention", "window": of.window or 0,
         "rope": ("none" if not (cfg.rope and of.rope) else
                  "yarn" if of.yarn is not None else
@@ -932,7 +932,7 @@ def _say_block_plan(cfg: LlamaConfig):
     """The instant ``block.plan`` of a trace, once a traced forward of a
     model with a parallel block: how the layer is put together, and what
     its feed-forward's shared experts are (0: a family without)."""
-    tracing.instant("block.plan", {
+    tracing.plan("block.plan", {
         "residual": "parallel", "norm": cfg.norm,
         "shared_experts": getattr(cfg, "n_shared", 0)
         if getattr(cfg, "shared_d_ff", 0) else 0,

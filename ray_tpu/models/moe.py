@@ -768,7 +768,7 @@ def feed_forward(h, lp, cfg: MoEConfig, mesh=None, rules=None, tp=None,
     E, K = cfg.n_experts, cfg.top_k
     T, dt = B * S, cfg.dtype
     x = h.reshape(T, D)
-    tracing.instant("moe.expert_plan", expert_plan(cfg, T))
+    tracing.plan("moe.expert_plan", expert_plan(cfg, T))
     # the layer's sub-scopes (PERF.md 3): router, dispatch, experts,
     # combine, shared
     with jax.named_scope("router"):

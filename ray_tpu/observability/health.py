@@ -176,15 +176,24 @@ def _stall(name: str, attrs: Dict[str, Any], reason: str) -> None:
     the profiler's trace, if one runs), and the ring dumped under the
     recorder's rate limit. Never raises: it runs on hot paths."""
     logger.warning("%s %s", name, attrs)
+    _record_instant(name, attrs)
     try:
         from ray_tpu.core import runtime as _rt
-        from ray_tpu.util import tracing
 
-        tracing.instant(name, attrs, always=True)
         rt = _rt.current_runtime_or_none()
         if rt is not None:
             rt.flight.dump(reason, extra=dict(
                 attrs, stall=name, counters=counters()))
+    except Exception:  # noqa: BLE001 - a diagnostic must not add a fault
+        logger.exception("could not dump for %s", name)
+
+
+def _record_instant(name: str, attrs: Dict[str, Any]) -> None:
+    """The instant, kept with tracing off. Never raises."""
+    try:
+        from ray_tpu.util import tracing
+
+        tracing.instant(name, attrs, always=True)
     except Exception:  # noqa: BLE001 - a diagnostic must not add a fault
         logger.exception("could not record %s", name)
 
@@ -214,14 +223,17 @@ class FreezeWatcher:
         # A stall only where a loop of this process is under way: an
         # armed beacon that has ticked. Opening a TPU freezes every
         # process of its host for seconds at every job's start, before
-        # any loop's first step: routine, counted and logged, no more.
+        # any loop's first step: routine, counted, logged, and on the
+        # job's timeline (`armed` false) as seconds of set-up that were
+        # the machine's; no warning and no dump.
         with _beacons_lock:
             at_work = any(b.busy and b.count for b in _beacons.values())
+        attrs = {"late_s": round(late, 3), "armed": at_work}
         if at_work:
-            _stall("stall::host_freeze", {"late_s": round(late, 3)},
-                   f"host_freeze:{late:.1f}s")
+            _stall("stall::host_freeze", attrs, f"host_freeze:{late:.1f}s")
         else:
             logger.info("host froze %.3f s with no loop under way", late)
+            _record_instant("stall::host_freeze", attrs)
         return late
 
     def ensure_started(self) -> None:

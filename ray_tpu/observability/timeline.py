@@ -63,18 +63,25 @@ def chrome_trace(events: List[dict]) -> List[dict]:
     lanes = _Lanes()
     out: List[dict] = []
     running: Dict[str, dict] = {}  # task_id -> RUNNING event
-    for ev in sorted(events, key=lambda e: e.get("ts", 0.0)):
+    # of two that start together, the longer first: the one that holds
+    # the other
+    for ev in sorted(events, key=lambda e: (e.get("ts", 0.0),
+                                            -float(e.get("dur") or 0.0))):
         if ev.get("kind") == "span":
             pid = lanes.pid(ev.get("worker"))
-            track = f"trace:{str(ev.get('trace_id', ''))[:8]}"
+            # a span kept with tracing off belongs to no trace: a
+            # process's once-a-job records share the track "job"
+            trace_id = ev.get("trace_id")
+            track = f"trace:{str(trace_id)[:8]}" if trace_id else "job"
             out.append({
                 "name": ev.get("name", "span"), "cat": "span", "ph": "X",
                 "pid": pid, "tid": lanes.tid(pid, track),
                 "ts": ev.get("ts", 0.0) * 1e6,
                 "dur": max(float(ev.get("dur", 0.0)), 1e-6) * 1e6,
-                "args": _jsonable({"trace_id": ev.get("trace_id"),
+                "args": _jsonable({"trace_id": trace_id,
                                     "span_id": ev.get("span_id"),
                                     "parent_id": ev.get("parent_id"),
+                                    "worker": ev.get("worker"),
                                     "attrs": ev.get("attrs", {})}),
             })
             continue
@@ -92,7 +99,7 @@ def chrome_trace(events: List[dict]) -> List[dict]:
                 "pid": pid, "tid": lanes.tid(pid, track),
                 "ts": ev.get("ts", 0.0) * 1e6, "s": "p",
                 "args": _jsonable({k: v for k, v in ev.items()
-                                   if k not in ("kind", "ts", "worker")}),
+                                   if k not in ("kind", "ts")}),
             })
             continue
         state = ev.get("state")

@@ -658,7 +658,7 @@ def _flash_fwd(q, k, v, *, causal: bool, block_q: int, block_k: int,
     if plan is None:
         plan = kv_plan(S=S, T=T, D=D, dtype=k.dtype, block_q=block_q,
                        block_k=block_k, window=window, causal=causal)
-        tracing.instant("flash.fwd_plan", {
+        tracing.plan("flash.fwd_plan", {
             **{n: plan[n] for n in ("path", "S", "D", "kv_block_bytes",
                                     "span", "in_flight")},
             **_grid_steps(plan, B * H, S // block_q)})
@@ -1152,7 +1152,7 @@ def _flash_bwd_dkdv(qt, kt, vt, gt, ot, lse, *, causal: bool, block_q: int,
         S=S, T=T, D=D, dtype=kt.dtype, groups=groups,
         block_q=block_q, block_k=block_k, causal=causal, window=window,
         vmem_bytes=vmem_bytes or _vmem_bytes())
-    tracing.instant("flash.bwd_plan", {
+    tracing.plan("flash.bwd_plan", {
         **{k: plan[k] for k in ("path", "S", "block_q", "block_k", "window",
                                 "resident_bytes", "hbm_bytes_per_head",
                                 "span", "in_flight", "walk_bytes")},

@@ -400,7 +400,7 @@ def _q_walk_specs(bq: int, bk: int, D: int, span: int):
 def _say(name: str, call: str, qt, kt, **more) -> dict:
     B, H, S, D = qt.shape
     said = plan(B=B, H=H, S=S, T=kt.shape[2], D=D, dtype=qt.dtype, call=call)
-    tracing.instant(name, {**said, **more})
+    tracing.plan(name, {**said, **more})
     return said
 
 

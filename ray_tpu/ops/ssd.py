@@ -506,7 +506,7 @@ def ssd_scan(x, dt, a, bm, cm, *, chunk: int = 256, impl: str = "xla"):
                          f"{impl!r}")
     p = plan(S=S, H=H, P=P, N=bm.shape[-1], chunk=chunk, dtype=x.dtype,
              impl=impl, G=G)
-    tracing.instant("ssd.plan", p)
+    tracing.plan("ssd.plan", p)
     f32 = jnp.float32
     u, col, row = _prologue(x.reshape(B, S, H * P), dt, a, chunk)
     if impl == "xla":

@@ -125,9 +125,14 @@ def report(metrics: Dict[str, Any], *, state: Any = None) -> None:
 def _report(ctx: TrainContext, metrics: Dict[str, Any], state: Any) -> None:
     _compile_cache.listen()            # the loop has imported jax by now
     now = time.perf_counter()
+    step = metrics.get("step", len(ctx.reports))
     if ctx.last_report is not None:
-        ctx.step_watch.observe(now - ctx.last_report,
-                               step=metrics.get("step", len(ctx.reports)))
+        ctx.step_watch.observe(now - ctx.last_report, step=step)
+    else:
+        # where set-up ends on the job's timeline: kept, once a context
+        _tracing.instant("train.first_report", {
+            "step": step if isinstance(step, int) else len(ctx.reports)},
+            always=True)
     entry = dict(metrics)
     entry["_ts"] = time.time()
     entry["_rank"] = ctx.world_rank

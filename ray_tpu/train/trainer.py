@@ -9,6 +9,8 @@ backend is always JAX, and parallelism comes from ScalingConfig.mesh/rules.
 
 from __future__ import annotations
 
+import json
+import logging
 import os
 import time
 from dataclasses import dataclass, field
@@ -21,6 +23,13 @@ from ray_tpu.train.backend import TensorflowBackend, TorchBackend
 from ray_tpu.train.checkpoint import Checkpoint
 from ray_tpu.train.config import RunConfig, ScalingConfig
 from ray_tpu.train.worker_group import WorkerGroup
+from ray_tpu.util import tracing
+
+logger = logging.getLogger(__name__)
+
+# the most spans and instants a job's timeline holds (the newest): the
+# kept records of a job are a few hundred, with tracing on a span a step
+TIMELINE_RECORDS = 20000
 
 
 @dataclass
@@ -33,6 +42,10 @@ class Result:
     # (run_tag, world size per generation, remediation events); None for
     # fixed-size runs — see ray_tpu/train/elastic.py
     elastic: Optional[Dict[str, Any]] = None
+    # the job's timeline (`<run_dir>/timeline.json`, a Chrome trace):
+    # what was kept of set-up and, with tracing on, every span; None
+    # where it could not be written
+    timeline_path: Optional[str] = None
 
     @property
     def ok(self) -> bool:
@@ -82,10 +95,20 @@ class JaxTrainer:
 
             return ElasticCoordinator(self).fit()
         run_dir = self._run_dir()
+        result = Result()
+        try:
+            with tracing.span("train.fit", {
+                    "workers": self.scaling.num_workers,
+                    "chips_per_worker": self.scaling.chips_per_worker or 0},
+                    always=True):
+                return self._fit_retrying(run_dir, result)
+        finally:
+            result.timeline_path = _write_timeline(run_dir)
+
+    def _fit_retrying(self, run_dir: str, result: Result) -> Result:
         max_failures = self.run_config.failure_config.max_failures
         attempt = 0
         checkpoint = self.resume_from
-        result = Result()
         while True:
             try:
                 return self._fit_once(run_dir, checkpoint, result)
@@ -102,35 +125,51 @@ class JaxTrainer:
                     result.error = f"worker group failed: {e}"
                     return result
 
+    def _start_group(self, run_dir: str,
+                     checkpoint: Optional[Checkpoint]) -> WorkerGroup:
+        """The gang, reserved, spawned and set up: span
+        `train.group_start`, closed when every `setup()` has returned."""
+        with tracing.span("train.group_start", {
+                "workers": self.scaling.num_workers}, always=True):
+            group = WorkerGroup(self.scaling.num_workers,
+                                self.scaling.worker_resources())
+            try:
+                self._setup_group(group, run_dir, checkpoint)
+            except BaseException:
+                group.shutdown()
+                raise
+            return group
+
+    def _setup_group(self, group: WorkerGroup, run_dir: str,
+                     checkpoint: Optional[Checkpoint]) -> None:
+        # dataset shards: one DataIterator per rank (ref: session.py:901)
+        shards: List[Dict[str, Any]] = _split_datasets(
+            self.datasets, self.scaling.num_workers)
+        coordinator = None
+        if self.scaling.num_workers > 1 or self.backend.needs_coordinator:
+            if getattr(self.backend, "needs_worker_addresses", False):
+                # TF_CONFIG-style backends need the FULL cluster spec:
+                # one reserved host:port per rank (each worker holds
+                # its reservation until its own setup() releases it)
+                infos = ray_tpu.get(
+                    [w.host_info.remote() for w in group.workers])
+                self.backend.worker_addresses = [
+                    f"{i['hostname']}:{i['free_port']}" for i in infos]
+                coordinator = self.backend.worker_addresses[0]
+            else:
+                info = ray_tpu.get(group.workers[0].host_info.remote())
+                coordinator = f"{info['hostname']}:{info['free_port']}"
+        ray_tpu.get([
+            w.setup.remote(self.config, run_dir, self.scaling, checkpoint,
+                           shards[i], coordinator,
+                           self.run_config.checkpoint_config.num_to_keep,
+                           self.backend)
+            for i, w in enumerate(group.workers)])
+
     def _fit_once(self, run_dir: str, checkpoint: Optional[Checkpoint],
                   result: Result) -> Result:
-        group = WorkerGroup(self.scaling.num_workers,
-                            self.scaling.worker_resources())
+        group = self._start_group(run_dir, checkpoint)
         try:
-            # dataset shards: one DataIterator per rank (ref: session.py:901)
-            shards: List[Dict[str, Any]] = _split_datasets(
-                self.datasets, self.scaling.num_workers)
-            coordinator = None
-            if self.scaling.num_workers > 1 or self.backend.needs_coordinator:
-                if getattr(self.backend, "needs_worker_addresses", False):
-                    # TF_CONFIG-style backends need the FULL cluster spec:
-                    # one reserved host:port per rank (each worker holds
-                    # its reservation until its own setup() releases it)
-                    infos = ray_tpu.get(
-                        [w.host_info.remote() for w in group.workers])
-                    self.backend.worker_addresses = [
-                        f"{i['hostname']}:{i['free_port']}" for i in infos]
-                    coordinator = self.backend.worker_addresses[0]
-                else:
-                    info = ray_tpu.get(group.workers[0].host_info.remote())
-                    coordinator = f"{info['hostname']}:{info['free_port']}"
-            setup_refs = [
-                w.setup.remote(self.config, run_dir, self.scaling, checkpoint,
-                               shards[i], coordinator,
-                               self.run_config.checkpoint_config.num_to_keep,
-                               self.backend)
-                for i, w in enumerate(group.workers)]
-            ray_tpu.get(setup_refs)
             run_refs = [w.run.remote(self.loop, self.config)
                         for w in group.workers]
             seen = 0
@@ -214,6 +253,28 @@ class TensorflowTrainer(JaxTrainer):
     tf.distribute.MultiWorkerMirroredStrategy unchanged."""
 
     backend_cls = TensorflowBackend
+
+
+def _write_timeline(run_dir: str) -> Optional[str]:
+    """The job's spans and instants as a Chrome trace beside the
+    checkpoints, by the channel they ride anyway (the workers flushed
+    theirs as their loops ended). Returns where it is, or None: a job
+    does not fail for its timeline."""
+    try:
+        data = json.dumps(ray_tpu.timeline(
+            limit=TIMELINE_RECORDS, chrome=True, spans_only=True))
+        if storage.is_uri(run_dir):
+            uri = storage.join_uri(run_dir, "timeline.json")
+            fs, path = storage.get_fs_and_path(uri)
+            fs.pipe_file(path, data.encode())
+            return uri
+        path = os.path.join(run_dir, "timeline.json")
+        with open(path, "w") as f:
+            f.write(data)
+        return path
+    except Exception:   # noqa: BLE001 - the run's result stands without it
+        logger.exception("could not write the job's timeline to %s", run_dir)
+        return None
 
 
 def _latest_checkpoint(run_dir: str) -> Optional[Checkpoint]:

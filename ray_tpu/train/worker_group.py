@@ -9,13 +9,18 @@ the in-step collectives need no framework plumbing at all.
 
 from __future__ import annotations
 
+import logging
 import os
 from typing import Any, Callable, Dict, List, Optional
 
 import ray_tpu
+from ray_tpu.core import compile_cache
 from ray_tpu.train.session import TrainContext, _set_context
+from ray_tpu.util import tracing
 from ray_tpu.util import (PlacementGroupSchedulingStrategy, placement_group,
                           remove_placement_group)
+
+logger = logging.getLogger(__name__)
 
 
 @ray_tpu.remote
@@ -37,6 +42,14 @@ class TrainWorker:
               datasets, coordinator: Optional[str] = None,
               num_to_keep=None, backend=None,
               elastic_meta: Optional[dict] = None) -> bool:
+        with tracing.span("train.worker_setup", {"rank": self.rank},
+                          always=True):
+            return self._setup(config, run_dir, scaling, checkpoint,
+                               datasets, coordinator, num_to_keep, backend,
+                               elastic_meta)
+
+    def _setup(self, config, run_dir, scaling, checkpoint, datasets,
+               coordinator, num_to_keep, backend, elastic_meta) -> bool:
         # Collective bootstrap is a pluggable Backend hook
         # (ref: backend_executor.py Backend.on_start); default JaxBackend.
         from ray_tpu.train.backend import JaxBackend
@@ -59,7 +72,11 @@ class TrainWorker:
 
     def run(self, loop_fn: Callable, config: dict) -> Any:
         try:
-            self.result = loop_fn(config) if _accepts_arg(loop_fn) else loop_fn()
+            self._open_chips()
+            with tracing.span("train.loop", {"rank": self.rank},
+                              always=True):
+                self.result = (loop_fn(config) if _accepts_arg(loop_fn)
+                               else loop_fn())
             return self.result
         except BaseException as e:
             import traceback
@@ -78,6 +95,38 @@ class TrainWorker:
                 self.backend.on_worker_shutdown()
             except Exception:
                 pass
+            self._flush_telemetry()
+
+    def _open_chips(self) -> None:
+        """Where this worker was given chips: the backend's opening, which
+        freezes the host for seconds, under the span `train.chips_open`
+        and not under whatever line of the user's loop first touches a
+        device (that call then returns at once). A worker without chips
+        opens nothing here: its loop may still have to configure jax
+        (platform, device count) before a backend exists."""
+        scaling = self.ctx.scaling if self.ctx is not None else None
+        if not (scaling is not None and scaling.use_tpu
+                and scaling.chips_per_worker):
+            return
+        with tracing.span("train.chips_open", always=True) as said:
+            import jax
+
+            devices = jax.devices()
+            said["attrs"].update(platform=devices[0].platform,
+                                 kind=devices[0].device_kind,
+                                 count=len(devices))
+        compile_cache.listen()      # before the loop's first program
+
+    def _flush_telemetry(self) -> None:
+        """The loop's last spans reach the GCS before the driver reads
+        the job's timeline (and before this worker is killed)."""
+        try:
+            from ray_tpu.core import runtime as _rt
+
+            _rt.get_runtime().flush_task_events(wait=True)
+        except Exception:   # noqa: BLE001 - the loop's result stands
+            logger.exception("rank %d could not flush its telemetry",
+                             self.rank)
 
     def poll(self, after: int) -> dict:
         ctx = self.ctx

@@ -24,6 +24,16 @@ device idle gap can be put down to the span that covers it
 (`benchmark/host_plane.py` reads them). With no profile running the
 annotation is inactive and records nothing. This module never imports
 jax itself: a parent that only spawns workers stays off the chip.
+
+What happens once a job is KEPT with tracing off (`always=True`, `plan`):
+the cluster's start, the worker group's, a worker's set-up and loop, the
+chips' opening, the first report, every compile, trace and lowering of
+0.05 s or more, the plans a step was lowered with, a freeze of the host.
+They ride the same channel to the same store, and `JaxTrainer.fit` writes
+them out as it ends (`<run_dir>/timeline.json`, a Chrome trace), so what
+the program did before any profiler started outlives the cluster. What a
+step or a request does is never kept: with tracing off the hot paths
+record and send nothing.
 """
 
 from __future__ import annotations
@@ -94,32 +104,50 @@ def _record(span: Dict[str, Any]) -> None:
     rt.record_span(span)
 
 
+def _span_record(name: str, attributes: Optional[Dict[str, Any]],
+                 parent: Optional[Dict[str, str]], chained: bool,
+                 ts: float) -> Dict[str, Any]:
+    """A span's record as it opens. A kept span of a process with
+    tracing off (`chained` false) belongs to no trace."""
+    return {
+        "kind": "span",
+        "name": name,
+        "trace_id": (parent["trace_id"] if parent
+                     else _new_id(16) if chained else None),
+        "span_id": _new_id(8),
+        "parent_id": parent["span_id"] if parent else None,
+        "ts": ts,
+        "attrs": dict(attributes or {}),
+    }
+
+
 @contextlib.contextmanager
-def span(name: str, attributes: Optional[Dict[str, Any]] = None):
+def span(name: str, attributes: Optional[Dict[str, Any]] = None, *,
+         always: bool = False):
     """User-facing span (ref: custom spans via util/debug profiling).
     Nested spans chain; spans created inside a task continue the
     submitting caller's trace (a live parent context counts as opt-in
     even when this process never called enable() — that's how worker
     processes participate). With tracing off nothing is recorded or
     sent; the body still runs inside the profiler's annotation (module
-    docstring), which is inactive unless a profile is running."""
+    docstring), which is inactive unless a profile is running.
+
+    `always` keeps the span with tracing off too, for what happens once
+    a job (the cluster's start, a worker's set-up, the loop as a whole):
+    never for what a step or a request does. A kept span recorded with
+    tracing off is no context: it has no trace and nothing chains under
+    it, so a kept `train.loop` does not opt every `train.report` inside
+    it in. With tracing on it chains as any span."""
     ann = _annotation(name, attributes) or contextlib.nullcontext()
     parent = _ctx.get()
-    if not (is_enabled() or parent is not None):
+    chained = is_enabled() or parent is not None
+    if not (chained or always):
         with ann:
             yield None
         return
-    rec = {
-        "kind": "span",
-        "name": name,
-        "trace_id": parent["trace_id"] if parent else _new_id(16),
-        "span_id": _new_id(8),
-        "parent_id": parent["span_id"] if parent else None,
-        "ts": time.time(),
-        "attrs": dict(attributes or {}),
-    }
+    rec = _span_record(name, attributes, parent, chained, time.time())
     token = _ctx.set({"trace_id": rec["trace_id"],
-                      "span_id": rec["span_id"]})
+                      "span_id": rec["span_id"]}) if chained else None
     try:
         with ann:
             yield rec
@@ -127,7 +155,8 @@ def span(name: str, attributes: Optional[Dict[str, Any]] = None):
         rec["attrs"]["error"] = repr(e)
         raise
     finally:
-        _ctx.reset(token)
+        if token is not None:
+            _ctx.reset(token)
         rec["dur"] = time.time() - rec["ts"]
         _record(rec)
 
@@ -137,8 +166,9 @@ def instant(name: str, attributes: Optional[Dict[str, Any]] = None, *,
     """A zero-length span: a value with a time (one admitted request, one
     compile, one stall). Same two halves and the same opt-in rule as
     span(); `always` records it with tracing off too, for the rare event
-    a post-mortem must hold (a stall). `timeline.chrome_trace` renders
-    the record as an instant event."""
+    a post-mortem or a job's timeline must hold (a stall, a compile of
+    seconds). `timeline.chrome_trace` renders the record as an instant
+    event."""
     ann = _annotation(name, attributes)
     if ann is not None:
         with ann:
@@ -158,25 +188,29 @@ def instant(name: str, attributes: Optional[Dict[str, Any]] = None, *,
     return rec
 
 
+def plan(name: str, attributes: Dict[str, Any]) -> Optional[dict]:
+    """What a kernel or a model chose while a program is traced (a
+    `*.plan` / `*_plan` instant): once a traced body, so kept with
+    tracing off, and on the job's timeline though the lowering ran
+    before any profile."""
+    return instant(name, attributes, always=True)
+
+
 def emit_span(name: str, ts: float, dur: float,
-              attributes: Optional[Dict[str, Any]] = None) -> Optional[dict]:
+              attributes: Optional[Dict[str, Any]] = None, *,
+              always: bool = False) -> Optional[dict]:
     """Record a synthetic complete span for a phase measured elsewhere
-    (streaming-executor op lifetimes, replayed timings). Same opt-in
-    rule as span(): a live parent context counts as opt-in, and the
-    span chains under it."""
+    (streaming-executor op lifetimes, replayed timings, jax's own clock
+    round a trace or a lowering). Same opt-in rule as span(): a live
+    parent context counts as opt-in, and the span chains under it;
+    `always` keeps it with tracing off, under span()'s once-a-job
+    rule."""
     parent = _ctx.get()
-    if not (is_enabled() or parent is not None):
+    chained = is_enabled() or parent is not None
+    if not (chained or always):
         return None
-    rec = {
-        "kind": "span",
-        "name": name,
-        "trace_id": parent["trace_id"] if parent else _new_id(16),
-        "span_id": _new_id(8),
-        "parent_id": parent["span_id"] if parent else None,
-        "ts": float(ts),
-        "dur": float(dur),
-        "attrs": dict(attributes or {}),
-    }
+    rec = _span_record(name, attributes, parent, chained, float(ts))
+    rec["dur"] = float(dur)
     _record(rec)
     return rec
 

@@ -312,7 +312,9 @@ def test_a_freeze_before_any_loop_runs_is_counted_and_no_stall(
         recorder, caplog):
     """The opening of a TPU freezes its host at every job's start: with
     no beacon armed, or one armed that has not ticked yet (the train
-    worker before its first report), nothing is recorded or dumped."""
+    worker before its first report), nothing warns and nothing is dumped;
+    the instant says `armed` false (the job's timeline counts it as
+    set-up that was the machine's)."""
     clock = _Clock(12.0)
     w = health.FreezeWatcher(clock=clock, sleep=clock.sleep)
     with caplog.at_level("INFO", logger="ray_tpu.health"):
@@ -322,14 +324,30 @@ def test_a_freeze_before_any_loop_runs_is_counted_and_no_stall(
         assert w.run_once() == pytest.approx(12.0)
     assert health.counters()["host_freezes"] == 2
     assert health.counters()["host_freeze_s"] == pytest.approx(24.0)
-    assert recorder.spans == [] and recorder.flight.dumps_written == 0
+    assert [(s["name"], s["attrs"]) for s in recorder.spans] == [
+        ("stall::host_freeze", {"late_s": 12.0, "armed": False})] * 2
+    assert recorder.flight.dumps_written == 0
     assert [r.levelname for r in caplog.records] == ["INFO", "INFO"]
     # under way: a stall, dumped under the recorder's rate limit
     loop.tick()
     w.run_once()
     w.run_once()
-    assert [s["name"] for s in recorder.spans] == ["stall::host_freeze"] * 2
+    assert [s["attrs"]["armed"] for s in recorder.spans] == [
+        False, False, True, True]
     assert recorder.flight.dumps_written == 1
+
+
+def test_an_unarmed_freeze_is_kept_with_tracing_off(recorder):
+    """`FreezeWatcher.run_once` with no armed beacon and tracing off: the
+    chip's opening is on the job's timeline as `stall::host_freeze`."""
+    tracing.disable()
+    clock = _Clock(6.5)
+    late = health.FreezeWatcher(clock=clock, sleep=clock.sleep).run_once()
+    assert late == pytest.approx(6.5)
+    said, = recorder.spans
+    assert (said["kind"], said["name"]) == ("instant", "stall::host_freeze")
+    assert said["attrs"] == {"late_s": 6.5, "armed": False}
+    assert recorder.flight.dumps_written == 0
 
 
 def test_dumps_go_beside_the_session_directories(monkeypatch, tmp_path):
