@@ -883,7 +883,7 @@ def finish_loss(loss, stats, cfg: LatentConfig):
     if cfg.experts_held is not None:
         aux.update(_moe.held_aux(
             stats["held_counts"].astype(jnp.float32), stats,
-            stats["experts"].shape[1] * cfg.top_k))
+            stats["experts"].shape[1] * cfg.top_k, cfg.n_experts))
     else:
         aux["moe_dropped"] = jnp.zeros((), jnp.int32)
     loss = loss + cfg.mtp_weight * mtp + cfg.router_aux_weight * balance

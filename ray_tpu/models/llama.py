@@ -339,11 +339,12 @@ REMAT_FREE = 0.15
 # round the attention call (q under its rotary, dq, the output's gradient).
 # The update of a leaf under adafactor holds four float32 temporaries as
 # large as the leaf (3.4 and 3.8 read on the l8 and OLMoE plans, whose peak
-# it is). A kept byte has cost up to 1.58 bytes of plan (the Mellum2 step,
-# whose scans stack three layers' residuals: +2.55e9 for 1.61e9 of q) and
+# it is). A kept byte has cost up to 1.64 bytes of plan (the Mellum2 step,
+# whose scans stack three layers' residuals: +3.30e9 for 2.01e9 of q, k and
+# v at passes of 49,152 rows, PR 51; +2.55e9 for 1.61e9 of q at 65,536) and
 # as little as 0.68 (Command A+'s: the replay's own buffers go): it is
 # counted at 1.5. All four from the one-chip plans compiled for a described
-# v5e (PERF.md 4 and 6, PR 43).
+# v5e (PERF.md 4 and 6, PR 43 and 51).
 LAYER_BACKWARD = 2.0
 LANE_BYTES = 18
 UPDATE_BYTES = 16

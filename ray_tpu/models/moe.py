@@ -47,10 +47,11 @@ the router, ``route`` and the statistics stay ``n_experts`` wide; the
 weights' first axis, the group sizes and the rows gathered are the held
 experts' only (``_held_experts``, ``_held_combine``: a second way through
 the layer, because its rows are a data-dependent FEW of the T*K: the
-assignments sorted by held expert with the absent last, computed a pass of
-twice the even share at a time and, in a layer that got more, in further
-short passes; ``_dispatch`` and ``_down_combine`` gather all T*K rows and
-serve the layer that holds every expert). An assignment to an absent
+assignments sorted by held expert with the absent last, computed in a
+first pass of 3/2 of the even share (HELD_PASS: experts placed by load)
+and, in a layer that got more, in further short passes; ``_dispatch`` and
+``_down_combine`` gather all T*K rows and serve the layer that holds every
+expert). An assignment to an absent
 expert contributes nothing and is no dropped row: its part of the result
 is another chip's; every assignment to a held expert is computed.
 ``shared_d_ff`` adds a dense SwiGLU that every token passes beside the
@@ -477,18 +478,25 @@ def route(logits, cfg: MoEConfig, bias=None):
     return weights, experts, probs
 
 
-# Rows computed AT A TIME for a share of the experts: this many times what
-# the held experts get of T*K assignments when every expert is as likely
-# (shapes are static; the grouped matmul skips the tiles no group covers,
-# the gather of the rows walks the live ones a chunk at a time,
-# ``held_chunk``; the scatter-adds walk the pass). A layer whose held
-# experts got more runs further passes, a quarter as long, over the rest
-# (``_held_combine``), so no assignment is ever dropped and the step takes
-# as long as the router made it. With random weights a layer's share of 9
-# experts in 72 read 0.044 to 0.242 over 370 layers (even: 0.125; PERF.md
-# 6, PR 32): twice the even share is the least that leaves the further
-# passes to the tail.
-HELD_PASS = 2
+# Rows computed AT A TIME for a share of the experts: this margin (a
+# numerator over a denominator) over what the held experts get of T*K
+# assignments when every expert is as likely. It presumes experts PLACED BY
+# LOAD, as expert-parallel deployments place them and as every cell that
+# holds a share does ("placement": "balanced"): a step's MEAN held share
+# then sits at the even one, and a pass twice the even share was half dead
+# rows that every scatter-add, dy's gather and every [rows, F] and
+# [rows, D] form still walked (shapes are static; only the grouped matmul
+# skips the tiles no group covers, and the gather of x the chunks beyond
+# the last live row, ``held_chunk``). Single layers spread wider than the
+# mean, because the routers train on after the placement: over a 40 s
+# window 0 to 22% of layer-steps passed 5/4 of the even share in the six
+# cells and 0 to 5% passed 3/2 (PERF.md 6, PR 51), so 3/2 it is. A layer
+# whose held experts got more runs further passes, a quarter as long,
+# over the tail (``_held_combine``), so no assignment is ever dropped and
+# the step takes as long as the router made it: experts held as they come
+# (a layer's share of 9 in 72 read 0.044 to 0.242 with random weights; PR
+# 32) pay in further passes what the first one saves.
+HELD_PASS = (3, 2)
 
 
 def _count(ids, n: int):
@@ -499,19 +507,22 @@ def _count(ids, n: int):
 
 
 def held_rows(cfg: MoEConfig, tokens: int) -> int:
-    """Rows of one pass over the held experts' assignments of ``tokens``
-    tokens: whole row tiles of the grouped matmul, at most every
-    assignment."""
-    rows = -(-tokens * cfg.top_k * cfg.n_held * HELD_PASS // cfg.n_experts)
+    """Rows of the first pass over the held experts' assignments of
+    ``tokens`` tokens: HELD_PASS times their even share in whole row tiles
+    of the grouped matmul, at most every assignment."""
+    over, even = HELD_PASS
+    rows = -(-tokens * cfg.top_k * cfg.n_held * over
+             // (cfg.n_experts * even))
     tile = GMM_TILING[0]
     return min(-(-rows // tile) * tile, tokens * cfg.top_k)
 
 
 # The first pass's gather moves a sixteenth of the pass at a time and no
-# chunk beyond the last live row: experts placed by load leave a pass half
-# empty. Under HELD_CHUNK_ROWS a chunk the loop saves nothing (one layer
-# alone on the chip, PERF.md 6, PR 42: 16,384 rows in chunks of 1,024
-# -0.05 ms, 8,192 in chunks of 512 +0.73 ms), and the pass stays whole.
+# chunk beyond the last live row: experts placed by load leave the last
+# third of a pass empty (half of it before PR 51, when this was sized).
+# Under HELD_CHUNK_ROWS a chunk the loop saves nothing (one layer alone on
+# the chip, PERF.md 6, PR 42: 16,384 rows in chunks of 1,024 -0.05 ms,
+# 8,192 in chunks of 512 +0.73 ms), and the pass stays whole.
 HELD_CHUNKS = 16
 HELD_CHUNK_ROWS = 2048
 
@@ -610,10 +621,13 @@ def _held_combine(cfg, rows, short, x, weights, ranked, counts, we):
     weights [T, K], ranked [T*K] (the assignments sorted by held expert,
     the absent last), counts [held], we the (gate, up, down) weights.
 
-    The first ``rows`` assignments to held experts are one pass, and all
+    The first ``rows`` assignments to held experts are one pass (HELD_PASS
+    over their even share: it presumes experts placed by load), and all
     of them in all but a layer of the tail; where a layer's counts ask for
-    more, the rest follow ``short`` rows at a time in a loop whose length
-    the data decide, so no assignment is dropped whatever the router does.
+    more, the tail follows ``short`` rows at a time in a loop whose length
+    the data decide, so no assignment is dropped whatever the router does,
+    and experts not placed by load pay in further passes what the first
+    one saves.
     Such a loop has no gradient of jax's own, hence the custom VJP: the
     first pass's backward is jax's (of ``_held_swiglu``, kept from the
     forward), each further pass runs again under its own, and all of them
@@ -624,17 +638,18 @@ def _held_combine(cfg, rows, short, x, weights, ranked, counts, we):
     rows wide would move several times as much).
 
     The first pass's shapes are static and its live rows a prefix of it,
-    half of it where experts are placed by load: the gather of x into
-    expert order, forward and in a checkpoint's replay, goes a chunk of
-    rows at a time (``held_chunk``) and stops at the last live row, as the
-    grouped matmuls do. dy's gather and the three scatter-adds walk the
-    whole pass: XLA's scatter-add in chunks is slower a row than whole,
-    dy's gather as a loop beside the replayed one makes the compiler order
-    every update after the last backward (the plan +3 to +4 GB), and one
-    op over the live prefix, its static length chosen by a ``lax.switch``,
-    halves the scatter-adds but makes the step program half as large
-    again: it loads 12 s longer and the compiler rematerializes other
-    layers' work to fit it (PERF.md 6, PR 42)."""
+    about two thirds of it where experts are placed by load: the gather
+    of x into expert order, forward and in a checkpoint's replay, goes a
+    chunk of rows at a time where the pass gives chunks (``held_chunk``)
+    and stops at the last live row, as the grouped matmuls do. dy's gather
+    and the three scatter-adds walk the whole pass, so the pass is no
+    longer than the margin asks (PERF.md 6, PR 51): XLA's scatter-add in
+    chunks is slower a row than whole, dy's gather as a loop beside the
+    replayed one makes the compiler order every update after the last
+    backward (the plan +3 to +4 GB), and one op over the live prefix, its
+    static length chosen by a ``lax.switch``, makes the step program half
+    as large again: it loads 12 s longer and the compiler rematerializes
+    other layers' work to fit it (PERF.md 6, PR 42)."""
     return _held_combine_fwd(cfg, rows, short, x, weights, ranked, counts,
                              we)[0]
 
@@ -701,10 +716,10 @@ def _held_experts(x, weights, experts, lp, cfg: MoEConfig):
     """The held experts' part of the layer's output: x [T, D], weights and
     experts [T, K] (over all n_experts) -> (y [T, D], the layer's
     statistics: the counts of the held experts [held], how many passes
-    beyond the first they took, the share of the first pass's rows that
-    its gather walked). The assignments are sorted by held expert, the
-    absent last, and the held ones computed ``held_rows`` at a time, then
-    a quarter as many (``_held_combine``)."""
+    beyond the first they took, the shares of the first pass's rows that
+    its gather walked and that are live). The assignments are sorted by
+    held expert, the absent last, and the held ones computed ``held_rows``
+    at a time, then a quarter as many (``_held_combine``)."""
     T, K, dt = x.shape[0], cfg.top_k, cfg.dtype
     held, first = cfg.experts_held
     with jax.named_scope("dispatch"):
@@ -726,7 +741,8 @@ def _held_experts(x, weights, experts, lp, cfg: MoEConfig):
     return y, {"held_counts": counts,
                "more_passes": _passes(counts, rows, short),
                "walked_share": _chunks(counts, rows) * (held_chunk(rows)
-                                                        / rows)}
+                                                        / rows),
+               "live_share": jnp.minimum(counts.sum(), rows) / rows}
 
 
 def expert_plan(cfg: MoEConfig, tokens: int) -> dict:
@@ -846,9 +862,10 @@ def _sequence_balance(scores, experts, cfg: MoEConfig, batch: int):
                             * share, axis=-1))
 
 
-def held_aux(held, stats, per_layer: int):
+def held_aux(held, stats, per_layer: int, n_experts: int):
     """What a step reports of a chip's share of the experts: held [L, held]
-    the held experts' counts in float32, per_layer = T x K assignments."""
+    the held experts' counts in float32, per_layer = T x K assignments to
+    all ``n_experts``."""
     return {
         # the largest held expert over the held experts' mean, worst layer
         "moe_load_max_over_mean": jnp.max(
@@ -861,6 +878,15 @@ def held_aux(held, stats, per_layer: int):
         # rows the first passes' gathers walked over the rows of those
         # passes (whole chunks, ``held_chunk``; 1.0: the passes whole)
         "moe_held_walked_share": stats["walked_share"].mean(),
+        # live rows of the first passes over their rows, mean of the layers
+        "moe_held_pass_live_share": stats["live_share"].mean(),
+        # the fullest layer's held share over the even share (HELD_PASS is
+        # the margin a first pass has over it)
+        "moe_held_share_max_over_even": jnp.max(held.sum(axis=1)) * (
+            n_experts / (held.shape[1] * per_layer)),
+        # the share of the step's expert layers that took a further pass
+        "moe_held_further_pass_share":
+            (stats["more_passes"] > 0).mean(dtype=jnp.float32),
         # every assignment to a held expert is computed (_held_experts)
         "moe_dropped": jnp.zeros((), jnp.int32)}
 
@@ -887,7 +913,7 @@ def finish_loss(loss, stats, cfg: MoEConfig):
         held = stats["held_counts"].astype(jnp.float32)        # [L, held]
         return loss + cfg.router_aux_weight * aux + cfg.router_z_weight * z, {
             "moe_aux_loss": aux, "moe_z_loss": z, **ruled,
-            **held_aux(held, stats, per_layer)}
+            **held_aux(held, stats, per_layer, E)}
     return (loss + cfg.router_aux_weight * aux + cfg.router_z_weight * z, {
         "moe_aux_loss": aux, "moe_z_loss": z, **ruled,
         "moe_load_max_over_mean":

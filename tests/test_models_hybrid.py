@@ -307,22 +307,59 @@ def _layer_and_reference(cfg, lp, h):
     return (y[0], got), (want_y, want), stats
 
 
-def test_a_layer_over_one_pass_takes_further_passes_and_drops_nothing():
-    """A router that sends every token to the held experts: one pass holds
-    HELD_PASS times the even share, the rest follows in a second, and the
-    layer and its gradients are still the reference's."""
-    cfg = tiny(n_experts=16, top_k=2, experts_held=(2, 4), shared_d_ff=0)
+# the six held-expert cells: (tokens, top_k, held, experts) -> the rows of
+# a first pass (BENCHMARK.json: Granite, Mellum2, Nemotron 3 Nano,
+# GLM-4.7-Flash, Command A+, GLM-5.2)
+_CELL_PASSES = {
+    "granite": ((16384, 10, 9, 72), 30720),
+    "mellum2": ((16384, 8, 16, 64), 49152),
+    "nemotron": ((16384, 6, 16, 128), 18432),
+    "glm47flash": ((16384, 4, 8, 64), 12288),
+    "commandaplus": ((8192, 8, 8, 128), 6144),
+    "glm52": ((16384, 8, 8, 256), 6144)}
+
+
+@pytest.mark.parametrize("cell", list(_CELL_PASSES))
+def test_a_first_pass_at_the_cells_sizes(cell):
+    """3/2 of the held experts' even share of T x K, in whole row tiles."""
+    (t, k, held, experts), rows = _CELL_PASSES[cell]
+    cfg = tiny(n_experts=experts, top_k=k, experts_held=(held, 0))
+    assert moe.held_rows(cfg, t) == rows and rows % 256 == 0
+    assert moe.expert_rows(cfg, t) == rows
+    assert moe.expert_rows(cfg.replace(experts_held=None), t) == t * k
+
+
+@pytest.mark.parametrize("times,more", [(1.0, 0), (1.3, 0), (1.6, 1),
+                                        (2.5, 4)],
+                         ids=["even", "1.3x", "1.6x", "2.5x"])
+def test_a_layer_over_one_pass_takes_further_passes_and_drops_nothing(
+        times, more):
+    """A router that gives the held experts ``times`` their even share of
+    the assignments: the first pass holds 3/2 of it (HELD_PASS), the tail
+    follows in short passes (none, none, one, four), the layer and
+    its gradients are still the reference's, and the step's counters read
+    what a count by hand gives."""
+    held, k, t = 2, 2, 2048
+    cfg = tiny(n_experts=8, top_k=k, experts_held=(held, 3), shared_d_ff=0)
     params, _ = make(cfg)
     lp = jax.tree.map(lambda w: w[0], params["layers"][0])
-    t = 256
+    even = t * k * held // cfg.n_experts
+    rows, short = moe.held_rows(cfg, t), 256
+    assert (even, rows) == (1024, 1536) and rows < t * k
+    # the router reads a token's first 8 lanes: ``chosen`` tokens give both
+    # their assignments to the held experts, the others none
+    chosen = round(times * even) // k
+    n = chosen * k
     h = jnp.abs(jax.random.normal(jax.random.PRNGKey(5), (1, t, cfg.d_model)))
-    lp["router"] = 0.01 * lp["router"] + jnp.zeros_like(
-        lp["router"]).at[:, 4:6].set(1.0)
-    rows = moe.held_rows(cfg, t)
-    assert rows == 256 and rows < t * cfg.top_k      # 2 x the even 64, a tile
+    mine = (jax.random.permutation(jax.random.PRNGKey(6), t) < chosen)
+    lanes = jnp.where(mine[:, None], 4.0, -4.0) * (
+        (jnp.arange(8) >= 3) & (jnp.arange(8) < 3 + held))
+    h = h.at[0, :, :8].set(lanes + 0.1 * h[0, :, :8])
+    lp["router"] = jnp.zeros_like(lp["router"]).at[:8].set(jnp.eye(
+        8, lp["router"].shape[1]))
     (y, got), (want_y, want), stats = _layer_and_reference(cfg, lp, h)
-    assert int(stats["held_counts"].sum()) == t * 2
-    assert int(stats["more_passes"]) == 1
+    assert int(stats["held_counts"].sum()) == n
+    assert int(stats["more_passes"]) == more == -(-max(n - rows, 0) // short)
     np.testing.assert_allclose(y, want_y, rtol=2e-4, atol=2e-5)
     for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(g, w, rtol=2e-3, atol=2e-4 * float(
@@ -330,11 +367,36 @@ def test_a_layer_over_one_pass_takes_further_passes_and_drops_nothing():
     loss, aux = moe.finish_loss(0.0, jax.tree.map(lambda s: s[None], stats),
                                 cfg)
     assert float(aux["moe_dropped"]) == 0
-    assert float(aux["moe_held_more_passes"]) == 1.0
-    assert float(aux["moe_held_rows_share"]) == 1.0
-    # at the cell's sizes: 16,384 tokens, 10 of 72, 9 held
-    cell = cfg.replace(n_experts=72, top_k=10, experts_held=(9, 0))
-    assert moe.held_rows(cell, 16384) == 40960
+    assert float(aux["moe_held_more_passes"]) == more
+    assert float(aux["moe_held_rows_share"]) == n / (t * k)
+    assert float(aux["moe_held_pass_live_share"]) == pytest.approx(
+        min(n, rows) / rows, rel=1e-6)
+    assert float(aux["moe_held_share_max_over_even"]) == pytest.approx(
+        n / even, rel=1e-6)
+    assert float(aux["moe_held_further_pass_share"]) == float(more > 0)
+
+
+def test_the_pass_counters_over_several_layers(small_chunks):
+    """``held_aux`` over a stack of layers: the live share is the layers'
+    mean, the fullest layer's share is over the even one, and the share of
+    layers that took a further pass counts layers, not passes."""
+    w = _WALK
+    rows, even = w["rows"], w["t"] * w["k"] * w["held"] // 8
+    lives = (0, even, rows, rows + 1, 8000)
+    layers = []
+    for live in lives:
+        cfg, *inputs = _held_layer(live, jnp.float32)
+        layers.append(moe._held_experts(*inputs, cfg)[1])
+    stats = jax.tree.map(lambda *a: jnp.stack(a), *layers)
+    aux = moe.held_aux(stats["held_counts"].astype(jnp.float32), stats,
+                       w["t"] * w["k"], 8)
+    assert float(aux["moe_held_pass_live_share"]) == pytest.approx(
+        sum(min(n, rows) / rows for n in lives) / len(lives))
+    assert float(aux["moe_held_share_max_over_even"]) == pytest.approx(
+        8000 / even)
+    assert float(aux["moe_held_further_pass_share"]) == pytest.approx(2 / 5)
+    assert float(aux["moe_held_more_passes"]) == 1 + -(-(8000 - rows)
+                                                       // w["short"])
 
 
 def test_layer_plan_instant_and_the_mesh_refusal(monkeypatch):
@@ -444,18 +506,19 @@ def test_plans_read_back_from_a_profile_around_a_lowering(tmp_path):
         == conv + 3 * wide + steps
 
 
-@pytest.mark.parametrize("lean", [-0.05, 0.04, 0.1, 0.5])
+@pytest.mark.parametrize("lean", [-0.05, 0.0, 0.01, 0.1, 0.5])
 def test_held_passes_hold_every_assignment_once(lean):
     """At a size of several row tiles an expert, with a router that leans
-    away from the held experts, a little towards them (one pass nearly
-    full), further (an expert's run straddles the first pass's end) and
-    wholly (the first pass and four a quarter as long): every assignment to a held expert stands
-    in one live slot of one pass, in its expert's group; the passes' groups
-    add up to the counts; the layer and its gradients are the reference's."""
+    away from the held experts, not at all (one pass nearly full), a little
+    towards them (an expert's run straddles the first pass's end), further
+    (the first pass and five a sixth as long) and wholly (ten): every
+    assignment to a held expert stands in one live slot of one pass, in its
+    expert's group; the passes' groups add up to the counts; the layer and
+    its gradients are the reference's."""
     held, k, t = 2, 2, 2048
     cfg = tiny(n_experts=8, top_k=k, experts_held=(held, 3), shared_d_ff=0)
     rows = moe.held_rows(cfg, t)
-    assert rows == 2048 and rows % 256 == 0          # 2 x the even 1024
+    assert rows == 1536 and rows % 256 == 0      # 3/2 of the even 1024
     params, _ = make(cfg)
     lp = jax.tree.map(lambda w: w[0], params["layers"][0])
     h = jnp.abs(jax.random.normal(jax.random.PRNGKey(5), (1, t, cfg.d_model)))
@@ -463,9 +526,11 @@ def test_held_passes_hold_every_assignment_once(lean):
     (y, got), (want_y, want), stats = _layer_and_reference(cfg, lp, h)
     counts = np.asarray(stats["held_counts"])
     n = counts.sum()
-    assert int(stats["more_passes"]) == -(-max(n - rows, 0) // 512)
-    assert {-0.05: n < rows // 2, 0.04: rows // 2 < n <= rows,
-            0.1: rows < n < 2 * rows, 0.5: n == 2 * rows}[lean], counts
+    small = 256                # a further pass: a quarter, in row tiles
+    assert int(stats["more_passes"]) == -(-max(n - rows, 0) // small)
+    assert {-0.05: n < rows // 2, 0.0: rows - small < n <= rows,
+            0.01: rows < n < rows + small, 0.1: rows + small < n < t * k,
+            0.5: n == t * k}[lean], counts
     np.testing.assert_allclose(y, want_y, rtol=2e-4, atol=2e-5)
     for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(g, w, rtol=2e-3, atol=2e-4 * float(
@@ -476,7 +541,6 @@ def test_held_passes_hold_every_assignment_once(lean):
     ranked = np.argsort(local, kind="stable")
     start = np.cumsum(counts) - counts
     seen, total = [], np.zeros(held, np.int64)
-    small = 512                              # a further pass: a quarter
     passes = [(0, rows)] + [(lo, small) for lo in range(rows, t * k, small)]
     for lo, size in passes:
         slot = lo + np.arange(size)
@@ -494,9 +558,10 @@ def test_held_passes_hold_every_assignment_once(lean):
     assert sorted(seen) == sorted(np.flatnonzero(local < held))
 
 
-# a pass of 4,096 rows over 8,192 assignments, in sixteen chunks of one
-# row tile (the rule's threshold lowered to a size a test can afford)
-_WALK = dict(held=2, first=3, k=2, t=4096, rows=4096, chunk=256, short=1024)
+# a pass of 4,096 rows over 10,800 assignments (3/2 of the even 2,700, in
+# whole row tiles), in sixteen chunks of one row tile (the rule's threshold
+# lowered to a size a test can afford)
+_WALK = dict(held=2, first=3, k=2, t=5400, rows=4096, chunk=256, short=1024)
 
 
 @pytest.fixture
@@ -609,7 +674,7 @@ def test_walked_share_is_whole_chunks_and_reaches_the_report_span(
             0: 0.0, 700: 0.1875, 2049: 0.5625, 5000: 1.0}[live]
     stats = jax.tree.map(lambda *a: jnp.stack(a), *shares)
     aux = moe.held_aux(stats["held_counts"].astype(jnp.float32), stats,
-                       w["t"] * w["k"])
+                       w["t"] * w["k"], 8)
     assert float(aux["moe_held_walked_share"]) == 0.4375
     assert float(aux["moe_held_more_passes"]) == 1.0
     # the step's metrics as a train loop reports them
@@ -631,8 +696,11 @@ def test_walked_share_is_whole_chunks_and_reaches_the_report_span(
 
 
 @pytest.mark.parametrize("rows,chunk", [
-    (40960, 2560), (65536, 4096),                     # Granite, Mellum2
-    (16384, 16384), (8192, 8192),     # GLM, Command A+: under the threshold
+    (49152, 3072), (65536, 4096),         # Mellum2, and as it was at 2x
+    (30720, 30720), (18432, 18432),       # Granite, Nemotron: a sixteenth
+    (12288, 12288), (6144, 6144),         # is under the threshold; GLM,
+    (16384, 16384), (8192, 8192),         # Command A+; those two at 2x
+    (40960, 2560),                        # Granite at 2x
     (32768, 2048), (32768 - 16, 32768 - 16), (32768 + 16 * 256, 2304),
     (16 * 2047, 16 * 2047),                          # no whole row tiles
     (40960 + 256, 40960 + 256), (300, 300), (256, 256)])

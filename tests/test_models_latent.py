@@ -585,6 +585,8 @@ def test_the_bias_rule_over_three_steps_and_the_optimizer_leaves_it_alone():
         "moe_aux_loss", "moe_bias_abs_max", "moe_bias_moved",
         "moe_load_max_over_mean", "moe_held_rows_share",
         "moe_held_more_passes", "moe_held_walked_share",
+        "moe_held_pass_live_share", "moe_held_share_max_over_even",
+        "moe_held_further_pass_share",
         "moe_dropped", "moe_remat_kept_gb"}          # the counts are used up
     assert all(v.shape == () for v in m.values())
 

@@ -351,5 +351,5 @@ def test_plan_instants_carry_the_groups_the_runs_and_the_experts(monkeypatch):
         d_model=2688, d_ff=1856, n_experts=128, top_k=6,
         experts_held=(16, 0), dtype=jnp.bfloat16), 16384)
     assert (wide["rows"], wide["gmm_up"], wide["gmm_down"], wide["tgmm_up"],
-            wide["tgmm_down"]) == (24576, "256x896x1856", "256x1856x896",
+            wide["tgmm_down"]) == (18432, "256x896x1856", "256x1856x896",
                                    "256x896x1024", "256x1024x896")
