@@ -678,13 +678,19 @@ def _attention(q, k, v, cfg: LlamaConfig, causal=True, q_offset=0,
 
 def _project(h, lp, cfg: LlamaConfig, w: str, n: int, norm=None):
     """One of a block's three projections of its normed input h [B, S, D],
-    split into its n heads: [B, S, n, HD]. With ``cfg.qk_norm`` (an OLMoE
-    block) q and k pass an RMS norm over the WHOLE projected vector, one
-    learned scale each (``norm``: its name), before the split."""
+    split into its n heads: [B, S, n, HD]. q and k (``norm``: the name of
+    their learned scale) pass the RMS norm the config asks for:
+    ``cfg.qk_norm`` (an OLMoE block), one over the WHOLE projected vector
+    before the split, a scale of n x HD; ``cfg.qk_head_norm`` (a MiniCPM
+    block: models/sala.py), one over each HEAD after it, a scale of HD
+    shared by the heads; neither field or both False, none."""
     y = h @ _dq(lp[w], cfg.dtype)
     if norm is not None and getattr(cfg, "qk_norm", False):
         y = rms_norm(y, lp[norm], cfg.norm_eps)
-    return y.reshape(*h.shape[:2], n, cfg.head_dim)
+    y = y.reshape(*h.shape[:2], n, cfg.head_dim)
+    if norm is not None and getattr(cfg, "qk_head_norm", False):
+        y = rms_norm(y, lp[norm], cfg.norm_eps)
+    return y
 
 
 def _attention_half(x, lp, cfg: LlamaConfig, cos, sin, mesh=None, rules=None,

@@ -22,6 +22,9 @@ _FAMILIES = {
     "latent": "ray_tpu.models.latent",
     # GLM-5.2's model_type: latent.py presets "glm-5.2-ep32-l5", "tiny-glm52"
     "glm_moe_dsa": "ray_tpu.models.latent",
+    # MiniCPM-SALA's model_type: sala.py preset "tiny" (block-set sparse
+    # attention layers beside Lightning linear-attention layers)
+    "minicpm_sala": "ray_tpu.models.sala",
     "vit": "ray_tpu.models.vit",
 }
 

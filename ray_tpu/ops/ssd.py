@@ -54,6 +54,17 @@ gradient leaves in both layouts, summed by the caller. Matmul operands
 are bfloat16 (x, B, C arrive so), products accumulate in float32, the
 decay and every sum over it are float32.
 
+A head as WIDE as a lane tile with keys of its own (P = 128 and G = H: a
+Lightning linear-attention layer, arXiv:2401.04658, with x = v, B = k,
+C = q / sqrt(P) and N = P) takes calls of its own (``_fwd_kernel_wide``,
+``_bwd_kernel_wide``): one head a lane tile and no halves chosen by lane,
+``C B^T`` a head, B and C arriving [B, S, H x N] a block of whole heads
+(``WIDE_HEADS_PER_BLOCK``), dB and dC leaving in the same shape with nothing
+to sum. Its decay is a CONSTANT a head (``dt`` None: every step is 1, so
+``cum_t = a (t + 1)`` inside a chunk): the [H] rates travel in SMEM, the
+kernel makes ``exp(a (t - s))`` from two iotas, and no [B, S, H] array, no
+second layout and no gradient of a step or a rate exists on either path.
+
 Across a layer checkpoint nothing of the scan is kept, so nothing of it
 is named for ``llama._checkpoint``: its output is as large as two layer inputs and the states as
 four, so the backward's recomputation of the layer runs the forward call
@@ -79,6 +90,7 @@ from ray_tpu.util import tracing
 
 NEG_INF = -1e30
 HEADS_PER_BLOCK = 16       # heads one kernel instance walks, in pairs
+WIDE_HEADS_PER_BLOCK = 4   # heads of a lane tile each, with B and C of their own
 
 
 def _use_interpret() -> bool:
@@ -280,34 +292,129 @@ def _bwd_kernel(u_ref, b_ref, c_ref, col_ref, row_ref, hin_ref, dy_ref,
     dcol_ref[0, 0] = dcols
 
 
+# --- a head as wide as a lane tile, keys of its own, a constant decay -------
+
+
+def _steady(rate, q: int):
+    """What a constant ``rate`` a step (a scalar of SMEM, negative) makes
+    of a chunk of q steps, float32: the decay block L[t, s] = exp(rate (t
+    - s)) for s <= t, else 0 [q, q]; exp(cum_t) = exp(rate (t + 1)) and
+    exp(cum_last - cum_t) = exp(rate (q - 1 - t)) [q, 1]; exp(cum_last) =
+    exp(rate q) [1, 1]."""
+    f32 = jnp.float32
+    t = jax.lax.broadcasted_iota(jnp.int32, (q, 1), 0)
+    s = jax.lax.broadcasted_iota(jnp.int32, (1, q), 1)
+    decay = jnp.exp(jnp.where(t >= s, rate * (t - s).astype(f32), NEG_INF))
+    at = t.astype(f32)
+    return (decay, jnp.exp(rate * (at + 1.0)), jnp.exp(rate * (q - 1.0 - at)),
+            jnp.exp(rate * jnp.full((1, 1), q, f32)))
+
+
+def _fwd_kernel_wide(a_ref, u_ref, b_ref, c_ref, y_ref, hin_ref, h_scr, *,
+                     heads: int, width: int):
+    """``_fwd_kernel`` for heads of a lane tile each: a_ref [H] float32 in
+    SMEM; u_ref [1, Q, heads x P]; b_ref, c_ref [1, Q, heads x N], a head's
+    own N lanes; the rest as there. One head a step of the loop, every
+    product a matmul of whole tiles."""
+    q, n = u_ref.shape[1], b_ref.shape[2] // heads
+    mm = u_ref.dtype
+
+    @pl.when(pl.program_id(2) == 0)
+    def _start():
+        h_scr[...] = jnp.zeros_like(h_scr)
+
+    head0 = pl.program_id(1) * heads
+    hin_ref[0, 0] = h_scr[...]
+    for i in range(heads):
+        lanes, own = (slice(i * width, (i + 1) * width),
+                      slice(i * n, (i + 1) * n))
+        u, bm, cm = u_ref[0, :, lanes], b_ref[0, :, own], c_ref[0, :, own]
+        h = h_scr[lanes, :]                                  # [P, N]
+        decay, grow, to_end, whole = _steady(a_ref[head0 + i], q)
+        y = _dot((_dot(cm, bm, _NT) * decay).astype(mm), u) \
+            + _dot(cm, h.astype(mm), _NT) * grow
+        y_ref[0, :, lanes] = y.astype(y_ref.dtype)
+        local = _dot((u.astype(jnp.float32) * to_end).astype(mm), bm, _TN)
+        h_scr[lanes, :] = h * whole + local
+
+
+def _bwd_kernel_wide(a_ref, u_ref, b_ref, c_ref, hin_ref, dy_ref, du_ref,
+                     db_ref, dc_ref, dh_scr, *, heads: int, width: int):
+    """``_bwd_kernel`` for heads of a lane tile each: db_ref and dc_ref
+    [1, Q, heads x N] in B's type are each head's own, nothing is left to
+    sum; the rate is no argument of the program's loss, so nothing of
+    ``cum`` is differentiated."""
+    q, n = u_ref.shape[1], b_ref.shape[2] // heads
+    mm = u_ref.dtype
+    f32 = jnp.float32
+
+    @pl.when(pl.program_id(2) == 0)
+    def _start():
+        dh_scr[...] = jnp.zeros_like(dh_scr)
+
+    head0 = pl.program_id(1) * heads
+    for i in range(heads):
+        lanes, own = (slice(i * width, (i + 1) * width),
+                      slice(i * n, (i + 1) * n))
+        u, dy = u_ref[0, :, lanes], dy_ref[0, :, lanes]
+        bm, cm = b_ref[0, :, own], c_ref[0, :, own]
+        h = hin_ref[0, 0, lanes, :].astype(mm)               # [P, N]
+        dh = dh_scr[lanes, :]
+        decay, grow, to_end, whole = _steady(a_ref[head0 + i], q)
+        m = _dot(cm, bm, _NT) * decay
+        dg = (_dot(dy, u, _NT) * decay).astype(mm)           # [Q(t), Q(s)]
+        dye = (dy.astype(f32) * grow).astype(mm)
+        ue = (u.astype(f32) * to_end).astype(mm)
+        sent = _dot(bm, dh.astype(mm), _NT) * to_end         # [Q, P]
+        du_ref[0, :, lanes] = (_dot(m.astype(mm), dy, _TN)
+                               + sent).astype(du_ref.dtype)
+        dc_ref[0, :, own] = (_dot(dye, h) + _dot(dg, bm)).astype(dc_ref.dtype)
+        db_ref[0, :, own] = (_dot(ue, dh.astype(mm))
+                             + _dot(dg, cm, _TN)).astype(db_ref.dtype)
+        dh_scr[lanes, :] = _dot(dye, cm, _TN) + dh * whole
+
+
 # --- the block plan and the calls -------------------------------------------
 
 
 def plan(*, S: int, H: int, P: int, N: int, chunk: int, dtype,
-         impl: str, G: int = 1) -> dict:
+         impl: str, G: int = 1, steady: bool = False) -> dict:
     """The scan's block plan (also the attributes of ``ssd.plan``): how
     many heads an instance walks, the VMEM one instance of the backward
     call holds (its blocks twice, Mosaic double-buffers, the state scratch
     and the [Q, Q] float32 temporaries of a head) and the HBM bytes the
     two calls move for one head and sequence. A block's heads are of one
-    of the G groups."""
+    of the G groups; ``steady`` (a constant decay a head, no steps) with
+    a group a head takes the wide calls (``layout`` "wide": whole heads
+    with their own B and C, on the chip a head whole lane tiles; else
+    "pairs")."""
     if H % G:
         raise ValueError(f"ssd_scan: {H} heads in {G} groups")
-    heads = min(HEADS_PER_BLOCK, H // G)
-    while (H // G) % heads:
+    wide = steady and G == H
+    heads = min(WIDE_HEADS_PER_BLOCK if wide else HEADS_PER_BLOCK,
+                H if wide else H // G)
+    while (H if wide else H // G) % heads:
         heads -= 1
     item = jnp.dtype(dtype).itemsize
     q = chunk
-    blocks = (3 * q * heads * P * item                  # u, dy, du
-              + 2 * q * N * item + 2 * q * N * 4        # B, C; dB, dC
-              + 2 * q * H * 4 + 2 * heads * q * 4       # cum and its gradient
-              + heads * P * N * 4)                      # h_in
+    if wide:
+        blocks = (3 * q * heads * P * item              # u, dy, du
+                  + 4 * q * heads * N * item            # B, C; dB, dC
+                  + heads * P * N * 4)                  # h_in
+        hbm = S * (5 * P + 6 * N) * item + 2 * (S // q) * P * N * 4
+    else:
+        blocks = (3 * q * heads * P * item              # u, dy, du
+                  + 2 * q * N * item + 2 * q * N * 4    # B, C; dB, dC
+                  + 2 * q * H * 4 + 2 * heads * q * 4   # cum and its gradient
+                  + heads * P * N * 4)                  # h_in
+        # a head's rows of u and y, forward; u, dy and du, backward; h_in
+        # written and read
+        hbm = S * P * item * 5 + 2 * (S // q) * P * N * 4
     vmem = 2 * blocks + heads * P * N * 4 + 8 * q * q * 4
-    # a head's rows of u and y, forward; u, dy and du, backward; h_in
-    # written and read
-    hbm = S * P * item * 5 + 2 * (S // q) * P * N * 4
     return {"S": S, "chunk": q, "heads_per_block": heads, "path": impl,
             "groups": G, "heads_per_group": H // G,
+            "layout": "wide" if wide else "pairs",
+            "decay": "steady" if steady else "stepped",
             "vmem_bytes": vmem if impl == "pallas" else 0,
             "hbm_bytes_per_head": hbm if impl == "pallas" else 0}
 
@@ -422,6 +529,84 @@ def _scan_pallas_bwd(chunk, heads, width, groups, res, dy):
 _scan_pallas.defvjp(_scan_pallas_fwd, _scan_pallas_bwd)
 
 
+def _wide_specs(P, N, q, heads):
+    """The wide calls' blocks: the [H] rates whole in SMEM, ``heads`` whole
+    heads of u (and y, dy, du) and of B and C (and dB, dC), their states."""
+    rates = pl.BlockSpec(memory_space=pltpu.SMEM)
+    wide = pl.BlockSpec((1, q, heads * P), lambda b, h, c: (b, c, h))
+    own = pl.BlockSpec((1, q, heads * N), lambda b, h, c: (b, c, h))
+    state = pl.BlockSpec((1, 1, heads * P, N), lambda b, h, c: (b, c, h, 0))
+    return rates, wide, own, state
+
+
+def _forward_call_wide(u, bm, cm, a, *, chunk: int, heads: int, width: int):
+    B, S, HP = u.shape
+    H = HP // width
+    N = bm.shape[2] // H
+    rates, wide, own, state = _wide_specs(width, N, chunk, heads)
+    call = pl.pallas_call(
+        functools.partial(_fwd_kernel_wide, heads=heads, width=width),
+        grid=(B, H // heads, S // chunk),
+        in_specs=[rates, wide, own, own],
+        out_specs=[wide, state],
+        out_shape=[jax.ShapeDtypeStruct(u.shape, u.dtype),
+                   jax.ShapeDtypeStruct((B, S // chunk, HP, N), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((heads * width, N), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=_use_interpret(),
+    )
+    with jax.named_scope("ssd.fwd.pallas"):         # ssd.plan's path
+        return call(a, u, bm, cm)
+
+
+def _backward_call_wide(u, bm, cm, a, h_in, dy, *, chunk: int, heads: int,
+                        width: int):
+    B, S, HP = u.shape
+    H = HP // width
+    N = bm.shape[2] // H
+    rates, *rest = _wide_specs(width, N, chunk, heads)
+    wide, own, state = map(
+        functools.partial(_backwards, last=S // chunk - 1), rest)
+    call = pl.pallas_call(
+        functools.partial(_bwd_kernel_wide, heads=heads, width=width),
+        grid=(B, H // heads, S // chunk),
+        in_specs=[rates, wide, own, own, state, wide],
+        out_specs=[wide, own, own],
+        out_shape=[jax.ShapeDtypeStruct(u.shape, u.dtype),
+                   jax.ShapeDtypeStruct(bm.shape, bm.dtype),
+                   jax.ShapeDtypeStruct(cm.shape, cm.dtype)],
+        scratch_shapes=[pltpu.VMEM((heads * width, N), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=_use_interpret(),
+    )
+    with jax.named_scope("ssd.bwd.pallas"):
+        return call(a, u, bm, cm, h_in, dy)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _scan_wide(u, bm, cm, a, chunk, heads, width):
+    return _forward_call_wide(u, bm, cm, a, chunk=chunk, heads=heads,
+                              width=width)[0]
+
+
+def _scan_wide_fwd(u, bm, cm, a, chunk, heads, width):
+    y, h_in = _forward_call_wide(u, bm, cm, a, chunk=chunk, heads=heads,
+                                 width=width)
+    return y, (u, bm, cm, a, h_in)
+
+
+def _scan_wide_bwd(chunk, heads, width, res, dy):
+    u, bm, cm, a, h_in = res
+    du, db, dc = _backward_call_wide(u, bm, cm, a, h_in, dy.astype(u.dtype),
+                                     chunk=chunk, heads=heads, width=width)
+    return du, db, dc, jnp.zeros_like(a)        # the rate is a constant
+
+
+_scan_wide.defvjp(_scan_wide_fwd, _scan_wide_bwd)
+
+
 def _spread(heads: int, width: int):
     """[H, H x width] float32, 1 where the lane is the head's."""
     return jnp.repeat(jnp.eye(heads, dtype=jnp.float32), width, axis=1)
@@ -493,7 +678,14 @@ def ssd_scan(x, dt, a, bm, cm, *, chunk: int = 256, impl: str = "xla"):
     (negative), bm and cm [B, S, N] (one group) or [B, S, G, N] -> y
     [B, S, H, P] in x's type, the recurrence of the module docstring from
     a zero state, without the skip term. Differentiable in all five on
-    both paths."""
+    both paths. ``dt`` None: every step is 1 and ``a`` a constant of the
+    model, a head's decay ``exp(a)`` a step (differentiable in x, bm and
+    cm; nothing [B, S, H] is made).
+
+    The Pallas path takes two layouts (``plan``): heads of 64 with steps,
+    an even number of them a group (in pairs, a lane tile a pair), and
+    heads of a multiple of 128 with a B and a C each and a constant decay
+    (``dt`` None, G == H)."""
     B, S, H, P = x.shape
     G = 1 if bm.ndim == 3 else bm.shape[2]
     if bm.ndim == 4 and G == 1:         # one group, stated: the same call
@@ -505,10 +697,28 @@ def ssd_scan(x, dt, a, bm, cm, *, chunk: int = 256, impl: str = "xla"):
         raise ValueError(f"ssd_scan impl must be 'xla' or 'pallas', got "
                          f"{impl!r}")
     p = plan(S=S, H=H, P=P, N=bm.shape[-1], chunk=chunk, dtype=x.dtype,
-             impl=impl, G=G)
+             impl=impl, G=G, steady=dt is None)
     tracing.plan("ssd.plan", p)
     f32 = jnp.float32
-    u, col, row = _prologue(x.reshape(B, S, H * P), dt, a, chunk)
+    takes = ("the kernel takes heads of 64 with steps, an even number of "
+             "them a group (a block of 2 to 16 heads of one group), or heads "
+             "of a multiple of 128 with a B and a C each (G == H) and a "
+             "constant decay (dt None)")
+    if dt is None:
+        a = jax.lax.stop_gradient(a.astype(f32))
+        if impl == "pallas":
+            if p["layout"] != "wide" or (P % 128 and not _use_interpret()):
+                raise ValueError(f"ssd_scan: a constant decay at {H} heads "
+                                 f"of {P} in {G} groups; {takes}")
+            flat = lambda t: t.astype(x.dtype).reshape(B, S, -1)  # noqa: E731
+            y = _scan_wide(flat(x), flat(bm), flat(cm), a, chunk,
+                           p["heads_per_block"], P)
+            return y.reshape(B, S, H, P)
+        u = x.reshape(B, S, H * P)
+        inside = (jnp.arange(S) % chunk + 1).astype(f32)  # steps into a chunk
+        col = jnp.broadcast_to(inside[None, :, None] * a, (B, S, H))
+    else:
+        u, col, row = _prologue(x.reshape(B, S, H * P), dt, a, chunk)
     if impl == "xla":
         with jax.named_scope("ssd.fwd.xla"):    # jax transposes it itself
             args = (_chunks(u.reshape(x.shape), chunk).astype(f32),
@@ -525,8 +735,8 @@ def ssd_scan(x, dt, a, bm, cm, *, chunk: int = 256, impl: str = "xla"):
         return y.reshape(B, S, H, P).astype(x.dtype)
     heads = p["heads_per_block"]
     if heads % 2:
-        raise ValueError(f"ssd_scan: the kernel walks heads in pairs, {H} "
-                         "heads give a block of an odd number")
+        raise ValueError(f"ssd_scan: {H} heads of {P} in {G} groups give a "
+                         f"block of {heads}; {takes}")
     flat = lambda a: a.astype(x.dtype) if a.ndim == 3 else \
         a.astype(x.dtype).reshape(B, S, -1)                  # noqa: E731
     y = _scan_pallas(u, flat(bm), flat(cm), col, row, chunk, heads, P, G)

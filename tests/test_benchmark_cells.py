@@ -32,7 +32,9 @@ RECIPE_KEYS = {"train": ("attn_impl", "remat", "f32_logits"),
                "train_sparse": ("attn_impl", "gmm_impl", "remat",
                                 "f32_logits"),
                "train_alternating": ("attn_impl", "gmm_impl", "ssd_impl",
-                                     "remat", "f32_logits")}
+                                     "remat", "f32_logits"),
+               "train_blockset": ("attn_impl", "ssd_impl", "remat",
+                                  "f32_logits")}
 # published config.json key -> the program's field
 WIDTHS = {"hidden_size": "d_model", "num_hidden_layers": "n_layers",
           "num_attention_heads": "n_heads",
@@ -59,7 +61,7 @@ def test_cell_program_config_builds_at_its_published_widths(name):
 
     from benchmark import (model, model_commanda, model_glm, model_glm52,
                            model_granite, model_mellum, model_moe,
-                           model_nemotron, resolve)
+                           model_nemotron, model_sala, resolve)
 
     cell = resolve.cell(name)
     kind, conf, recipe = cell["kind"], cell["config"], cell["train"]
@@ -70,7 +72,8 @@ def test_cell_program_config_builds_at_its_published_widths(name):
              "train_mixed": model_mellum.moe_config,
              "train_parallel": model_commanda.moe_config,
              "train_sparse": model_glm52.latent_config,
-             "train_alternating": model_nemotron.hybrid_config}[kind]
+             "train_alternating": model_nemotron.hybrid_config,
+             "train_blockset": model_sala.sala_config}[kind]
     passed = {k: recipe[k] for k in RECIPE_KEYS[kind] if k in recipe}
     cfg = build(conf, **passed)
 
@@ -82,7 +85,8 @@ def test_cell_program_config_builds_at_its_published_widths(name):
                   k: f for k, f in model_commanda.HF_TO_FIELD.items()
                   if f != "logit_scale"},
               "train_sparse": model_glm52.HF_TO_FIELD,
-              "train_alternating": model_nemotron.HF_TO_FIELD}[kind]
+              "train_alternating": model_nemotron.HF_TO_FIELD,
+              "train_blockset": model_sala.HF_TO_FIELD}[kind]
     for key, field in widths.items():
         assert getattr(cfg, field) == conf[key], (name, key)
     if kind == "train_parallel":
@@ -142,6 +146,28 @@ def test_cell_program_config_builds_at_its_published_widths(name):
         assert not cfg.rope and cfg.head_dim == conf["head_dim"]
         assert cfg.mamba_inner == conf["mamba_num_heads"] \
             * conf["mamba_head_dim"]
+    if kind == "train_blockset":
+        # the heads, the stated head width, the SwiGLU's width and the
+        # vocabulary are the published keys' (the map above); the kinds of
+        # layer `mixer_types`', the multipliers MiniCPM's three over the
+        # PUBLISHED depth, the selection's sizes the file's `sparse_config`
+        assert {"head_dim", "lightning_nh", "intermediate_size",
+                "scale_emb"} <= set(widths)
+        assert cfg.kinds == tuple(model_sala.MIXERS[t]
+                                  for t in conf["mixer_types"])
+        assert cfg.head_dim == conf["head_dim"] == conf["lightning_head_dim"]
+        assert cfg.residual_multiplier == conf["scale_depth"] \
+            / conf["published"]["num_hidden_layers"] ** 0.5
+        assert cfg.logits_scaling == conf["hidden_size"] \
+            / conf["dim_model_base"]
+        sparse = conf["sparse_config"]
+        assert (cfg.sparse_kernel, cfg.sparse_stride, cfg.sparse_block,
+                cfg.sparse_topk, cfg.sparse_init_blocks, cfg.sparse_window,
+                cfg.dense_len) == tuple(sparse[k] for k in (
+                    "kernel_size", "kernel_stride", "block_size", "topk",
+                    "init_blocks", "window_size", "dense_len"))
+        from ray_tpu.models import sala
+        assert cfg.qk_head_norm and "lm_head" in sala.param_specs(cfg)
     if kind == "train_sparse":
         # the latents' ranks, the head widths and the indexer's sizes are
         # the published keys' (the map above); which layers select, the

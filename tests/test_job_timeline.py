@@ -386,6 +386,9 @@ FAMILIES = {
     "hybrid": ("tiny-granite", "model_granite", "hybrid_config", "hybrid", {
         "ssd.plan", "mixer.plan", "hybrid.layer_plan", "flash.fwd_plan",
         "flash.bwd_plan"}),
+    "blockset": ("tiny-sala", "model_sala", "sala_config", "sala", {
+        "sala.select_plan", "sparse.fwd_plan", "sparse.bwd_plan", "ssd.plan",
+        "hybrid.layer_plan", "remat.plan"}),
 }
 
 

@@ -1208,6 +1208,7 @@ _CELL_STEPS = {
     "train-mellum2-ep4-s16384-b1": ("model_mellum", "moe_config", "moe"),
     "train-nemotron3nano-ep8-s8192-b2": ("model_nemotron", "hybrid_config",
                                          "hybrid"),
+    "train-minicpmsala-l4-s16384-b1": ("model_sala", "sala_config", "sala"),
 }
 
 
@@ -1447,3 +1448,102 @@ def test_glm52_step_fits_with_its_set_kept_and_selects_once(
                          or "cjd,td->cjt" in ln
                          or "rematted_computation/attention/vmap" in ln], \
         late[:2]
+
+
+# --- MiniCPM-SALA: attention over a set of blocks, the wide scan ------------
+SALA_ATTENTION = (1, 16384, 32, 2, 128)    # the cell's B, S, H, KV, D
+
+
+def test_block_set_calls_compile_at_the_sala_cells_shape(one_chip,
+                                                         on_chip_branch):
+    """The three Mosaic calls of attention over a set of blocks a KV group
+    (forward, dQ, dK/dV with the group's 16 heads innermost) lower for a
+    v5e at the cell's shape, the set [1, 2, 16384, 256] int8, within the
+    16 MiB a call gets that asks for no more."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import sparse_attention as sa
+
+    B, S, H, KV, D = SALA_ATTENTION
+    q = _sds((B, S, H, D), jnp.bfloat16, one_chip)
+    k = _sds((B, S, KV, D), jnp.bfloat16, one_chip)
+    sel = _sds((B, KV, S, S // sa.SET_BLOCK), jnp.int8, one_chip)
+
+    def loss(q, k, v, sel):
+        return sa.block_sparse_attention(q, k, v, sel).astype(
+            jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, k, k, sel).compile().as_text()
+    assert text.count("tpu_custom_call") == 3, text[:2000]
+    for scope in ("sparse.fwd.blocks", "sparse.dq.blocks",
+                  "sparse.dkdv.blocks"):
+        assert scope in text, scope
+    for call in ("fwd", "dq", "dkdv"):
+        plan = sa.plan(B=B, H=H, S=S, T=S, D=D, dtype=jnp.bfloat16,
+                       call=call, blocks=S // sa.SET_BLOCK, group=H // KV)
+        assert plan["path"] == "blocks" and plan["span"] > 1, plan
+        assert plan["vmem_bytes"] <= 16 * 2 ** 20, plan
+
+
+@pytest.mark.parametrize("chunk", [128, 256])
+def test_ssd_scan_compiles_at_lightning_widths(chunk, one_chip,
+                                               on_chip_branch):
+    """The wide scan (32 heads of 128 with keys of their own, a constant
+    decay a head, the rates in SMEM) lowers for a v5e at the cell's shape:
+    two Mosaic calls, no [B, S, H] array of steps among their operands."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import ssd
+
+    B, S, H, _, P = SALA_ATTENTION
+    x = _sds((B, S, H, P), jnp.bfloat16, one_chip)
+    a = _sds((H,), jnp.float32, one_chip)
+
+    def loss(x, bm, cm, a):
+        return ssd.ssd_scan(x, None, a, bm, cm, chunk=chunk,
+                            impl="pallas").astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x, a).compile().as_text()
+    assert text.count("tpu_custom_call") == 2, text[:2000]
+    assert f"f32[{B},{S},{H}]" not in text
+    plan = ssd.plan(S=S, H=H, P=P, N=P, chunk=chunk, dtype=jnp.bfloat16,
+                    impl="pallas", G=H, steady=True)
+    assert plan["layout"] == "wide" and plan["heads_per_block"] == 4, plan
+    assert plan["vmem_bytes"] <= 16 * 2 ** 20, plan
+
+
+def test_sala_step_fits_with_its_set_kept_and_selects_once(
+        topo, on_chip_branch, monkeypatch):
+    """The MiniCPM-SALA cell's step (one sparse layer, three lightning
+    layers, the whole vocabulary): the plan stays under the 15.8e9 the
+    issue's rule allows, XLA rematerializes nothing of its own, the layer
+    checkpoint keeps the sparse layer's set so that the replay selects
+    nothing (the selection's top-k is in the program once), and every kind
+    of Mosaic call is there."""
+    compiled, plan, said = _compile_cell_step(
+        "train-minicpmsala-l4-s16384-b1", topo, monkeypatch)
+    assert [(p["kept"], p["why"]) for p in said] == [("", "no room")]
+    assert plan <= 15.8e9, plan
+    text = compiled.as_text()
+    assert text.count(".remat") == 0
+    for scope in ("sparse.fwd.blocks", "sparse.dq.blocks",
+                  "sparse.dkdv.blocks", "ssd.fwd.pallas", "ssd.bwd.pallas",
+                  "attention/sparse/block_select",
+                  "attention/lightning/scan"):
+        assert scope in text, scope
+    lines = text.splitlines()
+    replayed = [ln for ln in lines
+                if "rematted_computation/attention/sparse/block_select" in ln]
+    assert not replayed, replayed[:2]
+    calls = {scope: sum("tpu_custom_call" in ln and scope in ln
+                        for ln in lines)
+             for scope in ("sparse.fwd.blocks", "ssd.fwd.pallas",
+                           "ssd.bwd.pallas")}
+    # the sparse forward once (o and lse are kept); the scan's forward in
+    # the forward and again in the replay, one body a run of three layers
+    assert calls == {"sparse.fwd.blocks": 1, "ssd.fwd.pallas": 2,
+                     "ssd.bwd.pallas": 1}, calls
