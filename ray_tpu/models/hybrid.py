@@ -32,8 +32,10 @@ Layers of two kinds cannot be one stack: ``params["layers"]`` is a LIST of
 stacks, one a run of adjacent layers of one kind (``layer_runs``; the
 published pattern is [5 mamba, attention, 4 mamba] four times over: runs
 of 5, 1, 9, 1, 9, 1, 9, 1, 4), each run one ``lax.scan``; every run of a
-kind is scanned by the same function, traced under the per-layer
-checkpoint of ``llama._checkpoint`` (instant ``hybrid.layer_plan``).
+kind that keeps the same names across the layer checkpoint
+(``llama.remat_plan``: the step's memory decides run by run) is scanned by
+the same function, traced under ``llama._checkpoint`` (instant
+``hybrid.layer_plan``).
 
 The mixer, for u = rms_norm(x) [B, S, D], H heads of width P, state N:
 
@@ -60,7 +62,8 @@ computes every float32 value again inside the pass that needs it, and
 hands each cotangent on in the type its consumer takes, so that no float32
 [B, S, H P] array stands in memory between two fusions (jax's transposition
 left five). Across the layer checkpoint the rules keep NOTHING: the
-backward's replay of the layer makes their residuals again. The results
+backward's replay of the layer makes their residuals again (from the
+in-projection's product where the plan kept it, ``MIX_OFFERED``). The results
 of a pass go through ``optimization_barrier``: XLA otherwise moves a
 pass's arithmetic into its consumers' fusions, twice where there are two
 (tests/test_tpu_compile.py holds the compiled layer to the account).
@@ -75,6 +78,7 @@ from typing import Any, Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.models import llama as _ll
 from ray_tpu.models import moe as _moe
@@ -147,8 +151,12 @@ _KINDS = ("mamba", "attention", "experts")
 
 # what the layer checkpoint keeps beside the layer's input and flash's
 # residuals (llama._checkpoint): the expert layer's routes; of a mixer
-# nothing, its scan runs again
+# nothing: its scan runs again, and so does its in-projection unless the
+# step's memory has room for the product (MIX_OFFERED, after the shared
+# expert's: 15 ms of replay a GB against 22, PERF.md 6)
 REMAT_SAVED = _moe.REMAT_SAVED
+MIX_OFFERED = "mix_proj"
+REMAT_OFFERED = _moe.SHARED_OFFERED + (MIX_OFFERED,)
 expert_rows = _moe.expert_rows
 post_update = _moe.post_update
 RULE_LEAVES = _moe.RULE_LEAVES
@@ -167,8 +175,38 @@ def remat_saved_bytes(cfg: HybridConfig, kind, tokens: int) -> int:
 
 
 def remat_offers(cfg: HybridConfig, kind, tokens: int):
-    return _moe.remat_offers(cfg, kind, tokens) if halves(cfg, kind)[1] \
+    """What a block of ``kind`` offers the layer checkpoint: its expert
+    layer's shared products, then a mixer's ``u @ in_proj`` [tokens, z |
+    xBC | dt]."""
+    shared = _moe.remat_offers(cfg, kind, tokens) if halves(cfg, kind)[1] \
         else ()
+    if kind != "mamba":
+        return shared
+    return shared + ((MIX_OFFERED, tokens * _mamba_sizes(cfg)[2]
+                      * jnp.dtype(cfg.dtype).itemsize),)
+
+
+def mixer_backward_bytes(cfg: HybridConfig, kind, tokens: int) -> int:
+    """Bytes a mixer's backward holds beside its matrices' products and
+    their gradients (``llama._step_estimate`` counts those from the
+    leaves): what the replay leaves for the rules (``plan``'s residuals with
+    nothing checkpointed: the convolution's input, x, y and z, the steps),
+    the scan's state at every chunk's start (float32 [chunks, H, P, N]) and
+    one float32 pass over the convolution's channels and one over the gated
+    norm's lanes."""
+    inner, conv_dim, _ = _mamba_sizes(cfg)
+    chunks = -(-tokens // cfg.mamba_chunk)
+    return (_rule_residual_bytes(cfg, tokens)
+            + chunks * inner * cfg.mamba_state * 4
+            + tokens * (conv_dim + inner) * 4)
+
+
+def _rule_residual_bytes(cfg: HybridConfig, tokens: int) -> int:
+    """What the mixer's hand-written rules keep of a forward: the
+    convolution's input, x, y and z in the activations' type, the steps."""
+    inner, conv_dim, _ = _mamba_sizes(cfg)
+    return tokens * ((conv_dim + 3 * inner) * jnp.dtype(cfg.dtype).itemsize
+                     + cfg.mamba_heads * 4)
 
 
 def layer_runs(cfg: HybridConfig) -> List[Tuple[str, int]]:
@@ -438,7 +476,7 @@ def plan(cfg: HybridConfig, B: int, S: int) -> dict:
         "path": "rules", "rows": rows, "groups": cfg.mamba_groups,
         "group_lanes": inner // cfg.mamba_groups,
         "chunk": min(cfg.mamba_chunk, S), "channels": conv_dim,
-        "residual_bytes": 0 if cfg.remat else conv + 3 * wide + steps,
+        "residual_bytes": 0 if cfg.remat else _rule_residual_bytes(cfg, rows),
         # xBC -> its activation; x, dt -> u and the sums twice; y, x, z ->
         # a row's scalar, the three again -> the normed rows
         "hbm_bytes_fwd": 2 * conv + (2 * wide + 3 * steps) + 7 * wide,
@@ -464,7 +502,8 @@ def mixer_half(x, lp, cfg: HybridConfig, kind: str, mesh=None):
     dt_, f32 = cfg.dtype, jnp.float32
     tracing.plan("mixer.plan", plan(cfg, B, S))
     u = _ll.rms_norm(x, lp["mix_norm"], cfg.norm_eps)
-    zxbcdt = u @ _ll._dq(lp["in_proj"], dt_)
+    # kept across the layer checkpoint where the step's memory has room
+    zxbcdt = checkpoint_name(u @ _ll._dq(lp["in_proj"], dt_), MIX_OFFERED)
     z, step = zxbcdt[..., :inner], zxbcdt[..., inner + conv_dim:]
     # the heads' channels and B | C, convolved apart: x is an array of its
     # own in the passes below (a slice of the joint one splits them in two)

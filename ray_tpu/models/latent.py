@@ -221,6 +221,7 @@ PRESETS: Dict[str, LatentConfig] = {
 INDEX_SET = "index_set"
 INDEX_GRADS = ("index_grad_q", "index_grad_w", "index_grad_k")
 REMAT_SAVED = _moe.REMAT_SAVED + (INDEX_SET,) + INDEX_GRADS
+REMAT_OFFERED = _moe.REMAT_OFFERED      # (a dense layer offers nothing)
 expert_rows = _moe.expert_rows
 # rows of queries whose index scores are alive at once ([rows, keys]
 # float32), and of those whose per-head products are ([heads, rows, keys])

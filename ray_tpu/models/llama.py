@@ -288,9 +288,15 @@ def _family(cfg: "LlamaConfig"):
     model, ``models/moe.py`` for ``MoEConfig``. It supplies the layer's
     feed-forward half (``feed_forward``), the names that half wants kept
     across the layer checkpoint (``REMAT_SAVED``, and what they weigh:
-    ``remat_saved_bytes``), the names it offers beyond them where the
-    step's memory has room (``remat_offers``) and, where the
-    feed-forward returns statistics, ``finish_loss``; where its attention
+    ``remat_saved_bytes``), the names its layers offer beyond them where
+    the step's memory has room (``REMAT_OFFERED``: every such name, the
+    dearest replay a byte first; ``remat_offers``: a layer's, by its kind:
+    a dense SwiGLU's gate and up, a shared expert's, a mixer's
+    in-projection) and, where the feed-forward returns statistics,
+    ``finish_loss``; where a layer's first half is no attention, that half
+    (``mixer_half``) and what its backward holds beside its matrices'
+    products (``mixer_backward_bytes``, for the step's estimate); where its
+    blocks hold ONE half, which (``halves``); where its attention
     is not three projections of the hidden state, the attention half too
     (``attention_half``); where it predicts further tokens than the next,
     ``further_losses``; where its attention half hands a value on to the
@@ -308,11 +314,13 @@ def _checkpoint(body, cfg: "LlamaConfig", kept: Tuple[str, ...] = ()):
     log-sum-exp B x H x S x 4), so the backward kernels run from them and
     the forward kernel runs once, what the family's feed-forward
     names (REMAT_SAVED: an expert layer's routes) and ``kept``: the names
-    of those the layer offers that the step's memory has room for
-    (``remat_plan``: q, k and v as the attention call takes them, a shared
-    SwiGLU's gate and up); everything else is recomputed, a state-space
-    mixer's scan too (ops/ssd.py). A body that holds no such name
-    (attn_impl other than "flash", under 128 tokens) saves nothing more."""
+    of those the layer offers that the step's memory has room for in THIS
+    run of layers (``remat_plan``: q, k and v as the attention call takes
+    them, a dense or a shared feed-forward's products of x before the
+    activation, a state-space mixer's in-projection); everything else is
+    recomputed, the mixer's scan too (ops/ssd.py). A body that holds no
+    such name (attn_impl other than "flash", under 128 tokens) saves
+    nothing more."""
     from ray_tpu.ops.flash_attention import FLASH_RESIDUALS
 
     return jax.checkpoint(
@@ -342,9 +350,12 @@ REMAT_FREE = 0.15
 # it is). A kept byte has cost up to 1.64 bytes of plan (the Mellum2 step,
 # whose scans stack three layers' residuals: +3.30e9 for 2.01e9 of q, k and
 # v at passes of 49,152 rows, PR 51; +2.55e9 for 1.61e9 of q at 65,536) and
-# as little as 0.68 (Command A+'s: the replay's own buffers go): it is
-# counted at 1.5. All four from the one-chip plans compiled for a described
-# v5e (PERF.md 4 and 6, PR 43 and 51).
+# as little as 0.61 in a run of ONE layer, which stacks nothing (Nemotron's
+# q, k and v; Command A+'s five names 0.68: the replay's own buffers go;
+# but 1.02 for Nemotron's shared and in-projection products, +2.71e9 for
+# 2.66e9, and 1.00 for MiniCPM-SALA's gate and up, PR 53): it is counted at
+# 1.5 whatever the run's length. All four from the one-chip plans compiled
+# for a described v5e (PERF.md 4 and 6, PR 43, 51 and 53).
 LAYER_BACKWARD = 2.0
 LANE_BYTES = 18
 UPDATE_BYTES = 16
@@ -353,12 +364,18 @@ KEPT_COST = 1.5
 
 class RematPlan(NamedTuple):
     """What the layer checkpoint keeps beyond the parent's list, and why."""
-    kept: Tuple[str, ...]   # of the offered names, in the order offered
-    kept_bytes: int         # over all layers
+    # the names each run of layers keeps, one tuple a stack in ``_stacks``'
+    # order, each in the order offered; (): no run keeps any
+    kept: Tuple[Tuple[str, ...], ...]
+    kept_bytes: int         # over all runs and layers
     estimate: int           # the step's bytes without them; 0: none made
     limit: int              # the device's; 0: it states none
     # "room" | "no room" | "no step" | "no limit" | "mesh"
     why: str
+
+    def of(self, run: int) -> Tuple[str, ...]:
+        """The names run ``run`` keeps."""
+        return self.kept[run] if self.kept else ()
 
 
 def _stacks(params, cfg: "LlamaConfig"):
@@ -376,10 +393,35 @@ def _stacks(params, cfg: "LlamaConfig"):
     return stacks + further, 1 + len(further)
 
 
+def _halves(cfg: "LlamaConfig", kind):
+    """What a block of ``kind`` holds, as ``_layer`` runs it: (its first
+    half: "attention" (``_attention_half`` or the family's own: a call of
+    an attention kernel, whose ``o`` and ``lse`` the checkpoint keeps),
+    "mixer" (the family's ``mixer_half``) or None (a block that is its
+    feed-forward alone); whether it runs the feed-forward half). A family
+    whose blocks hold ONE half says which (``halves``)."""
+    family = _family(cfg)
+    first, second = getattr(family, "halves",
+                            lambda cfg, kind: (True, True))(cfg, kind)
+    if not first:
+        return None, second
+    attends = getattr(family, "attention_half", None) is not None \
+        or _takes_attention_half(cfg, kind)
+    return "attention" if attends else "mixer", second
+
+
+def _offered(cfg: "LlamaConfig") -> Tuple[str, ...]:
+    """Every name a layer of ``cfg``'s family may offer, in the order the
+    plan takes them: the dearest replay a byte first."""
+    return ATTN_OFFERED + tuple(_family(cfg).REMAT_OFFERED)
+
+
 def _offers(cfg: "LlamaConfig", kind, batch: int, seq: int):
     """((name, bytes), ...) a layer of ``kind`` offers the checkpoint, the
     dearest replay a byte first: 28 ms a GB for q, k and v on the l8 step,
-    22 for the shared SwiGLU's gate and up on Command A+'s (PERF.md 6)."""
+    23 for a dense SwiGLU's gate and up (MiniCPM-SALA's), 22 for the shared
+    SwiGLU's on Command A+'s, 15 for a mixer's in-projection (Nemotron's)
+    (PERF.md 6)."""
     family, rows = _family(cfg), batch * seq
     head = rows * cfg.head_dim * jnp.dtype(cfg.dtype).itemsize
     attention = tuple(zip(ATTN_OFFERED, (
@@ -399,18 +441,24 @@ def _step_estimate(cfg: "LlamaConfig", params, stacks, passes: int,
       leaves outside the stacks) and adafactor's float32 temporaries over
       the largest leaf;
     - a layer's backward: those gradients, what every layer keeps (its
-      input, the flash call's ``o`` and ``lse``, the family's REMAT_SAVED),
-      the logits of the passes before a further pass's, and one layer's
-      products, gradients and float32 forms;
-    - the head: what every layer keeps, the logits and their gradient."""
+      input, the family's REMAT_SAVED and, where its first half is an
+      attention call, flash's ``o`` and ``lse``), the logits of the passes
+      before a further pass's, and one layer's products, gradients and
+      float32 forms;
+    - the head: what every layer keeps, the logits and their gradient.
+    A block is counted for what its kind holds (``_halves``)."""
     family, item = _family(cfg), jnp.dtype(cfg.dtype).itemsize
     expert_rows = getattr(family, "expert_rows", lambda cfg, rows: 0)(
         cfg, rows)
+    heads = rows * cfg.n_heads * cfg.head_dim
 
-    def products(stack):
+    def products(kind, stack):
         # a layer's matrices [L, in, out] times the rows, an expert's
         # [L, E, in, out] times the rows its experts get; beside them the
-        # lanes of the rows in expert order and of the query heads
+        # lanes of the rows in expert order (a block with a feed-forward
+        # half) and of the query heads (one with an attention half), or
+        # what the family says a mixer's backward holds
+        first, second = _halves(cfg, kind)
         total = 0
         for w in jax.tree.leaves(stack):
             if w.ndim == 3:
@@ -418,12 +466,18 @@ def _step_estimate(cfg: "LlamaConfig", params, stacks, passes: int,
             elif w.ndim == 4:
                 total += expert_rows * w.shape[3] * item
         return LAYER_BACKWARD * total + LANE_BYTES * (
-            expert_rows * cfg.d_model + rows * cfg.n_heads * cfg.head_dim)
+            expert_rows * cfg.d_model * second
+            + heads * (first == "attention")) + (
+                family.mixer_backward_bytes(cfg, kind, rows)
+                if first == "mixer" else 0)
 
-    saved = sum(n * (rows * (cfg.d_model + cfg.n_heads * cfg.head_dim) * item
-                     + rows * cfg.n_heads * 4
-                     + family.remat_saved_bytes(cfg, kind, rows))
-                for kind, n, _ in stacks)
+    def keeps(kind):
+        flash = heads * item + rows * cfg.n_heads * 4 \
+            if _halves(cfg, kind)[0] == "attention" else 0
+        return rows * cfg.d_model * item + flash \
+            + family.remat_saved_bytes(cfg, kind, rows)
+
+    saved = sum(n * keeps(kind) for kind, n, _ in stacks)
     in_stacks = [state_bytes(stack) for _, _, stack in stacks]
     outside = state_bytes(params) - sum(in_stacks)
     waiting = outside + max(in_stacks)
@@ -432,50 +486,61 @@ def _step_estimate(cfg: "LlamaConfig", params, stacks, passes: int,
     return int(state + max(
         waiting + UPDATE_BYTES * largest,
         waiting + saved + (passes - 1) * logits
-        + max(products(stack) for _, _, stack in stacks),
+        + max(products(kind, stack) for kind, _, stack in stacks),
         saved + passes * logits + outside))
 
 
 def remat_plan(cfg: "LlamaConfig", params, batch: int, seq: int, memory,
                mesh=None) -> RematPlan:
-    """Which of the names the layers offer the layer checkpoint keeps in a
-    step over ``params`` (arrays or shapes) and [batch, seq] tokens: a pure
-    function of shapes and of ``memory`` (parallel.train_step.StepMemory:
-    the device's limit and the state's bytes as the step counts them; None
-    outside a train step). Names are kept in the order offered while
-    ``estimate + KEPT_COST x kept`` stays under the limit less its free
-    share (REMAT_FREE); a name that does not fit is passed over for the
-    next. With no limit (the CPU) or no step nothing more is kept than the
-    parent's list; under a mesh of several devices neither: the
-    activations' share of a device is not counted here."""
+    """Which of the names its layers offer the layer checkpoint keeps in
+    each run of layers of a step over ``params`` (arrays or shapes) and
+    [batch, seq] tokens: a pure function of shapes and of ``memory``
+    (parallel.train_step.StepMemory: the device's limit and the state's
+    bytes as the step counts them; None outside a train step). The run is
+    the unit: names are taken in the order offered (q, k and v, then the
+    family's ``REMAT_OFFERED``), each name in the runs that offer it,
+    earliest run first, while ``estimate + KEPT_COST x kept`` stays under
+    the limit less its free share (REMAT_FREE); a name that does not fit a
+    run is passed over for the next run and the next name. With no limit
+    (the CPU) or no step nothing more is kept than the parent's list; under
+    a mesh of several devices neither: the activations' share of a device
+    is not counted here."""
     if memory is None or not memory.limit:
         return RematPlan((), 0, 0, 0, "no step" if memory is None
                          else "no limit")
     if mesh is not None and mesh.size > 1:
         return RematPlan((), 0, 0, memory.limit, "mesh")
     stacks, passes = _stacks(params, cfg)
-    offered: Dict[str, int] = dict.fromkeys(ATTN_OFFERED, 0)
-    for kind, n, _ in stacks:
-        for name, nbytes in _offers(cfg, kind, batch, seq):
-            offered[name] = offered.get(name, 0) + n * nbytes
+    offers = [dict(_offers(cfg, kind, batch, seq)) for kind, _, _ in stacks]
     estimate = _step_estimate(cfg, params, stacks, passes, batch * seq,
                               memory.state)
     ceiling = memory.limit * (1 - REMAT_FREE)
-    kept, total = [], 0
-    for name, nbytes in offered.items():
-        if nbytes and estimate + KEPT_COST * (total + nbytes) <= ceiling:
-            kept.append(name)
-            total += nbytes
-    return RematPlan(tuple(kept), total, estimate, memory.limit,
-                     "room" if kept else "no room")
+    kept, total = [[] for _ in stacks], 0
+    for name in _offered(cfg):
+        for run, (_, n, _) in enumerate(stacks):
+            nbytes = n * offers[run].get(name, 0)
+            if nbytes and estimate + KEPT_COST * (total + nbytes) <= ceiling:
+                kept[run].append(name)
+                total += nbytes
+    if not total:
+        return RematPlan((), 0, estimate, memory.limit, "no room")
+    return RematPlan(tuple(map(tuple, kept)), total, estimate, memory.limit,
+                     "room")
 
 
-def _say_remat_plan(plan: RematPlan):
+def _say_remat_plan(plan: RematPlan, cfg: "LlamaConfig"):
     """The instant ``remat.plan`` of a trace, once a traced forward under
-    the layer checkpoint: the names kept beyond the parent's list, their
+    the layer checkpoint: the names kept beyond the parent's list by run
+    (``kept``: every name some run keeps, in the order offered; ``runs``:
+    "name xN, ..." with N the runs that keep it; ``by_run``: the runs'
+    names in the layers' order, "+" between a run's, "-" for none), their
     bytes, the estimate they were added to and the limit."""
+    names = [n for n in _offered(cfg) if any(n in run for run in plan.kept)]
     tracing.plan("remat.plan", {
-        "kept": ",".join(plan.kept), "kept_bytes": plan.kept_bytes,
+        "kept": ",".join(names), "kept_bytes": plan.kept_bytes,
+        "runs": ", ".join(f"{n} x{sum(n in run for run in plan.kept)}"
+                          for n in names),
+        "by_run": ",".join("+".join(run) or "-" for run in plan.kept),
         "estimate": plan.estimate, "limit": plan.limit,
         "ceiling": int(plan.limit * (1 - REMAT_FREE)), "why": plan.why})
 
@@ -759,8 +824,12 @@ def _residual(x, y, cfg: LlamaConfig):
 
 
 # checkpoint_name tags the dense feed-forward wants kept across the layer
-# checkpoint: none (see _family), and none offered beyond them
+# checkpoint: none (see _family); and what it offers where the step's memory
+# has room (``remat_plan``): the SwiGLU's two products of x, before the
+# activation, as ``moe.SHARED_OFFERED`` for a shared expert
 REMAT_SAVED = ()
+FFN_OFFERED = ("ffn_gate", "ffn_up")
+REMAT_OFFERED = FFN_OFFERED
 
 
 def remat_saved_bytes(cfg: "LlamaConfig", kind, rows: int) -> int:
@@ -768,7 +837,10 @@ def remat_saved_bytes(cfg: "LlamaConfig", kind, rows: int) -> int:
 
 
 def remat_offers(cfg: "LlamaConfig", kind, rows: int):
-    return ()
+    """((name, bytes a layer), ...): gate's and up's products of ``rows``
+    tokens, [rows, d_ff] each."""
+    each = rows * cfg.d_ff * jnp.dtype(cfg.dtype).itemsize
+    return tuple((name, each) for name in FFN_OFFERED)
 
 
 def feed_forward(h, lp, cfg: LlamaConfig, mesh=None, rules=None, tp=None,
@@ -786,9 +858,10 @@ def feed_forward(h, lp, cfg: LlamaConfig, mesh=None, rules=None, tp=None,
             h, [_dq(lp["w_gate"], dt), _dq(lp["w_up"], dt)],
             lambda gate, up: jax.nn.silu(gate) * up,
             _dq(lp["w_down"], dt), tp), None
-    gate = jax.nn.silu(h @ _dq(lp["w_gate"], dt))
-    up = h @ _dq(lp["w_up"], dt)
-    return (gate * up) @ _dq(lp["w_down"], dt), None
+    # kept across the layer checkpoint where the step's memory has room
+    gate, up = (checkpoint_name(h @ _dq(lp[w], dt), name) for w, name in zip(
+        ("w_gate", "w_up"), FFN_OFFERED))
+    return (jax.nn.silu(gate) * up) @ _dq(lp["w_down"], dt), None
 
 
 def _takes_attention_half(cfg: LlamaConfig, kind) -> bool:
@@ -824,21 +897,20 @@ def _layer(x, lp, cfg: LlamaConfig, cos, sin, mesh=None, rules=None, tp=None,
     own = getattr(_family(cfg), "attention_half", None)
     named = kind in dict(cfg.attn_kinds)
     said = None
-    first, second = getattr(_family(cfg), "halves",
-                            lambda cfg, kind: (True, True))(cfg, kind)
-    # ``first`` False: a block that is its feed-forward alone
-    if first and own is not None:
+    # ``first`` None: a block that is its feed-forward alone
+    first, second = _halves(cfg, kind)
+    if first == "attention" and own is not None:
         assert tp is None, kind
         with jax.named_scope("attention"):
             x, carried, said = own(x, lp, cfg, cos, sin, mesh=mesh,
                                    rules=rules, carried=carried, kind=kind)
-    elif first and _takes_attention_half(cfg, kind):
+    elif first == "attention":
         # a trace tells the kinds apart by the inner scope, with no shape
         with jax.named_scope("attention"), \
                 jax.named_scope(kind) if named else contextlib.nullcontext():
             x = _attention_half(x, lp, cfg, cos, sin, mesh=mesh, rules=rules,
                                 tp=tp, kind=kind)
-    elif first:
+    elif first == "mixer":
         with jax.named_scope("mixer"):
             x = _family(cfg).mixer_half(x, lp, cfg, kind, mesh=mesh)
     stats = None
@@ -912,7 +984,8 @@ def _say_layer_plan(runs, bodies: int):
     """The instant ``hybrid.layer_plan`` of a trace, once a traced forward
     of a model whose layers are a list of runs: how many kinds of layer,
     how many runs of adjacent layers of one kind (one scan each), how
-    many bodies were built for them (one a kind) and the runs themselves,
+    many bodies were built for them (one a kind and set of names its runs
+    keep across the layer checkpoint) and the runs themselves,
     "kind xN, ..." in the layers' order."""
     tracing.plan("hybrid.layer_plan", {
         "kinds": len({k for k, _ in runs}), "runs": len(runs),
@@ -1010,8 +1083,9 @@ def _forward(params, tokens, cfg: LlamaConfig, pos_offset=0, mesh=None,
     """``forward_with_stats`` and what a further pass over the same
     sequences needs (``loss_fn``, a family's ``further_losses``): (logits,
     stats, the residual stream BEFORE the final norm, ``run``: (kind, x,
-    stack) -> (x, stats), the scan of a stack of layers of one kind by the
-    body, the tables and the shardings this forward used).
+    stack, which of the family's ``further_stacks`` it is: 0) -> (x,
+    stats), the scan of a stack of layers of one kind by the body, the
+    tables and the shardings this forward used, the plan).
 
     What a family's attention half hands on to the layers after it
     (``_family``: ``carried_init``, ``hands_on``) travels beside x through
@@ -1022,7 +1096,8 @@ def _forward(params, tokens, cfg: LlamaConfig, pos_offset=0, mesh=None,
     body, or, for a family with layers of several kinds (``layer_runs``:
     models/hybrid.py), a list of stacks, one a run of adjacent layers of
     one kind: each run is scanned by its kind's body, traced from ONE
-    function a kind whatever the depth. Such a family may also scale the
+    function a kind and set of names its runs keep (``remat_plan``)
+    whatever the depth. Such a family may also scale the
     embedding (``embedding_multiplier``), do without rotary tables
     (``rope`` False), tie the head to the embedding (no ``lm_head``) and
     divide the logits (``logits_scaling``)."""
@@ -1036,8 +1111,9 @@ def _forward(params, tokens, cfg: LlamaConfig, pos_offset=0, mesh=None,
             x = (x * cfg.embedding_multiplier).astype(dt)
     x = con(x)
 
+    @functools.cache
     def tables_of(kind):
-        # called once a kind: ``body_of`` is cached
+        # once a kind, whatever its runs keep
         of = attention_kind(cfg, kind)
         if _takes_attention_half(cfg, kind):
             _say_kind_plan(cfg, kind, of, S)
@@ -1053,7 +1129,7 @@ def _forward(params, tokens, cfg: LlamaConfig, pos_offset=0, mesh=None,
     plan = None
     if cfg.remat:
         plan = remat_plan(cfg, params, B, S, step_memory(), mesh)
-        _say_remat_plan(plan)
+        _say_remat_plan(plan, cfg)
 
     family = _family(cfg)
     # what the attention halves hand from layer to layer beside x (None:
@@ -1061,7 +1137,10 @@ def _forward(params, tokens, cfg: LlamaConfig, pos_offset=0, mesh=None,
     held = getattr(family, "carried_init", lambda *_: None)(cfg, B, S)
 
     @functools.cache
-    def body_of(kind):
+    def body_of(kind, kept):
+        """A kind's layer under the checkpoint that keeps ``kept`` beyond
+        the parent's list: two runs of a kind that keep the same names
+        share one traced body."""
         cos, sin = tables_of(kind)
 
         def body(x, held, lp):
@@ -1070,14 +1149,14 @@ def _forward(params, tokens, cfg: LlamaConfig, pos_offset=0, mesh=None,
                                     carried=held)
             return con(y), held, stats
 
-        return _checkpoint(body, cfg, plan.kept) if cfg.remat else body
+        return _checkpoint(body, cfg, kept) if cfg.remat else body
 
     @functools.cache
-    def step_of(kind, carries: bool):
-        """The scan's step over a kind's body, traced once a kind: with
-        nothing handed on (x alone is the carry) or with the handed value
-        carried beside x."""
-        body = body_of(kind)
+    def step_of(kind, kept, carries: bool):
+        """The scan's step over a kind's body, traced once a kind and
+        names kept: with nothing handed on (x alone is the carry) or with
+        the handed value carried beside x."""
+        body = body_of(kind, kept)
 
         def alone(x, lp):
             y, _, stats = body(x, None, lp)
@@ -1089,21 +1168,23 @@ def _forward(params, tokens, cfg: LlamaConfig, pos_offset=0, mesh=None,
 
         return beside if carries else alone
 
-    def run_with(kind, x, held, stack):
-        """The scan of a stack of layers of one kind: (x, held, stats). A
-        kind that replaces the handed value carries it beside x; one that
-        only reads it holds it as a constant of the loop, so the scan
-        stacks no copy a layer for the backward."""
+    def run_with(kind, x, held, stack, at):
+        """The scan of a stack of layers of one kind, the ``at``-th of
+        ``_stacks`` (what it keeps is the plan's for that run): (x, held,
+        stats). A kind that replaces the handed value carries it beside x;
+        one that only reads it holds it as a constant of the loop, so the
+        scan stacks no copy a layer for the backward."""
+        kept = plan.of(at) if plan is not None else ()
         # what lies under ``layers`` and in none of a layer's halves is
         # the loop's own: the scan's stacks, its carries, ``con``
         with jax.named_scope("layers"):
             if held is None:
-                x, stats = jax.lax.scan(step_of(kind, False), x, stack)
+                x, stats = jax.lax.scan(step_of(kind, kept, False), x, stack)
             elif family.hands_on(cfg, kind):
-                (x, held), stats = jax.lax.scan(step_of(kind, True),
+                (x, held), stats = jax.lax.scan(step_of(kind, kept, True),
                                                 (x, held), stack)
             else:
-                body = body_of(kind)
+                body = body_of(kind, kept)
 
                 def reads(x, lp):
                     y, _, stats = body(x, held, lp)
@@ -1112,18 +1193,21 @@ def _forward(params, tokens, cfg: LlamaConfig, pos_offset=0, mesh=None,
                 x, stats = jax.lax.scan(reads, x, stack)
             return x, held, stats
 
-    def run(kind, x, stack):
-        x, _, stats = run_with(kind, x, None, stack)
+    main = 1 if isinstance(params["layers"], dict) else len(params["layers"])
+
+    def run(kind, x, stack, further: int = 0):
+        # a further pass's stack: the ``further``-th after the main runs
+        x, _, stats = run_with(kind, x, None, stack, main + further)
         return x, stats
 
     if isinstance(params["layers"], dict):
-        x, held, stats = run_with(None, x, held, params["layers"])
+        x, held, stats = run_with(None, x, held, params["layers"], 0)
     else:
         runs = family.layer_runs(cfg)
-        assert len(runs) == len(params["layers"]), (runs, len(params["layers"]))
+        assert len(runs) == main, (runs, main)
         stats = []
-        for (kind, _), stack in zip(runs, params["layers"]):
-            x, held, s = run_with(kind, x, held, stack)
+        for at, ((kind, _), stack) in enumerate(zip(runs, params["layers"])):
+            x, held, s = run_with(kind, x, held, stack, at)
             if s is not None:       # a run of dense layers reports nothing
                 stats.append(s)
         with jax.named_scope("layers"):

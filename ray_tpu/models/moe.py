@@ -28,8 +28,9 @@ layer at K 8); of what is T*K rows wide the backward recomputes the rows
 in expert order, gate, up and their SwiGLU, and not the down projection
 (``_down_combine``; the numbers: PERF.md section 6, PR 26). Where the step's
 memory has room (``llama.remat_plan``) the shared SwiGLU's two products of x
-are kept as well (SHARED_OFFERED, ``remat_offers``: what a layer offers
-and what each name weighs), and the replay runs neither a second time.
+are kept as well, run of layers by run (SHARED_OFFERED, ``remat_offers``:
+what a layer offers and what each name weighs), and the replay of a run
+that keeps them runs neither a second time.
 
 On a mesh that shards ``experts`` (``ShardingRules.ep()``) the "xla" path
 runs under GSPMD; the "pallas" path refuses any mesh of several devices.
@@ -38,8 +39,9 @@ Attention layers of several kinds (``layer_kinds`` names each layer's,
 ``attn_kinds`` what a kind has of its own: Mellum2's window layers with
 plain rotary tables beside full layers under YaRN): ``params["layers"]`` is
 then a LIST of stacks, one a run of adjacent layers of one kind
-(``layer_runs``), each run one ``lax.scan``, every run of a kind scanned by
-the same traced body; every layer is the same block but for its kind's
+(``layer_runs``), each run one ``lax.scan``, every run of a kind that keeps
+the same names scanned by the same traced body; every layer is the same
+block but for its kind's
 window and tables (``llama._attention_half``).
 
 A chip's share of the experts (``experts_held``: how many, and the first):
@@ -223,6 +225,7 @@ REMAT_SAVED = ("moe_route",)
 # the shared SwiGLU's two products of x, before the activation. silu(gate)
 # x up is not offered: it is elementwise work from the two
 SHARED_OFFERED = ("shared_gate", "shared_up")
+REMAT_OFFERED = SHARED_OFFERED
 # leaves that no gradient reaches and ``post_update`` moves: the optimizer
 # is told to leave them alone (parallel.train_step.hold_out)
 RULE_LEAVES = ("router_bias",)

@@ -88,6 +88,9 @@ KINDS = ("sparse", "lightning")
 # checkpoint (llama._checkpoint) so that the replay selects nothing
 BLOCK_SET = "block_set"
 REMAT_SAVED = (BLOCK_SET,)
+# beyond it, where the step's memory has room (llama.remat_plan): the dense
+# SwiGLU's gate and up, of either kind of layer
+REMAT_OFFERED = _ll.REMAT_OFFERED
 # queries a block of the selection scores at once
 SELECT_ROWS = 1024
 # steps a chunk of the lightning layers' scan (the sequence where it is
@@ -184,8 +187,7 @@ def remat_saved_bytes(cfg: SalaConfig, kind, rows: int) -> int:
         if kind == "sparse" and rows > cfg.dense_len else 0
 
 
-def remat_offers(cfg: SalaConfig, kind, rows: int):
-    return ()
+remat_offers = _ll.remat_offers
 
 
 def param_specs(cfg: SalaConfig) -> Dict[str, Any]:

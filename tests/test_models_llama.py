@@ -474,12 +474,15 @@ def _primitives(jaxpr, into=None):
 # and v for the layer checkpoint whatever the attention call, three `name`
 # equations a traced body, 2 -> 8, 0 -> 3, 7 -> 13 and 5 -> 8 in all; every
 # other primitive's count is the parent's, and with no limit nothing more is
-# kept, so the equations lower to nothing.)
+# kept, so the equations lower to nothing. PR 53 replaced the three dense
+# ones: ``feed_forward`` names gate's and up's products, two ``name``
+# equations a traced body, 8 -> 12, 8 -> 12 and 3 -> 5; every other
+# primitive's count is the parent's and the two ``moe`` digests stood.)
 JAXPRS_FROM = "0.9.0"
 ONE_DEVICE_JAXPRS = {
-    ("dense", 2, "flash", True, "bfloat16"): "3b12ce20f8e1059f",
-    ("dense", 4, "flash", True, "bfloat16"): "4ab56e75b0de7f09",
-    ("dense", 2, "xla", False, "float32"): "d99f3888a36ad995",
+    ("dense", 2, "flash", True, "bfloat16"): "3d9cb84650f54cfb",
+    ("dense", 4, "flash", True, "bfloat16"): "c35af5bbf0866773",
+    ("dense", 2, "xla", False, "float32"): "d7b97d42f0c9a0f5",
     ("moe", 2, "flash", True, "bfloat16"): "3700376b7b68b19e",
     ("moe", 2, "xla", False, "float32"): "1981221167aeb9de",
 }

@@ -13,21 +13,33 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import latent, llama, moe
+from ray_tpu.models import hybrid, latent, llama, moe, sala
 from ray_tpu.parallel import train_step
 
 ROOT = os.path.join(os.path.dirname(os.path.abspath(__file__)), os.pardir)
 V5E = 16_909_336_064        # a v5e chip's ``bytes_limit`` (of 16 GiB)
 ALL = llama.ATTN_OFFERED + moe.SHARED_OFFERED
+MIX = (hybrid.MIX_OFFERED,)
 
 # cell -> (the plan its step compiled to with the parent's list kept, bytes
 # on a device: at the parent of PR 43, PERF.md 4, and for the cells that
 # hold a share of their experts since their first pass is 3/2 of the even
-# share, PR 51; the names its plan keeps on a v5e chip)
+# share, PR 51; the names each of its runs keeps on a v5e chip, (): no run
+# any)
 CELLS = {
-    "train-commandaplus-ep16-s8192-b1": (9_280_733_184, ALL),
-    "train-mellum2-ep4-s16384-b1": (11_246_880_768, llama.ATTN_OFFERED),
-    "train-nemotron3nano-ep8-s8192-b2": (9_556_182_016, llama.ATTN_OFFERED),
+    "train-commandaplus-ep16-s8192-b1": (9_280_733_184, (ALL,) * 4),
+    "train-mellum2-ep4-s16384-b1": (11_246_880_768,
+                                    (llama.ATTN_OFFERED,) * 6),
+    # MEMEM*EMEMEM*EMEMEM*: q, k and v in the three attention blocks, the
+    # shared expert's up product in all eight expert blocks, the
+    # in-projection's product in the first five of the nine mixers
+    "train-nemotron3nano-ep8-s8192-b2": (9_556_182_016, tuple(
+        {"M": MIX, "m": (), "E": moe.SHARED_OFFERED[1:],
+         "*": llama.ATTN_OFFERED}[c] for c in "MEMEM*EMEMEm*EmEmEm*")),
+    # (PR 52's step: the sparse layer, a run of its own, keeps its SwiGLU's
+    # gate and up; the three lightning layers' stack has no room for them)
+    "train-minicpmsala-l4-s16384-b1": (12_696_442_368,
+                                       (llama.FFN_OFFERED, ())),
     "train-deepseek7b-fsdp2tp2": (14_306_706_432, ()),
     "train-glm47flash-ep8-s8192-b2": (14_375_225_344, ()),
     "train-granite4hs-ep8-s8192-b2": (14_993_509_376, ()),
@@ -45,6 +57,7 @@ _KINDS = {     # kind of cell -> (config module, its function, family)
     "train_parallel": ("model_commanda", "moe_config", "moe"),
     "train_sparse": ("model_glm52", "latent_config", "latent"),
     "train_alternating": ("model_nemotron", "hybrid_config", "hybrid"),
+    "train_blockset": ("model_sala", "sala_config", "sala"),
 }
 
 
@@ -104,11 +117,11 @@ def test_the_estimate_reads_no_more_than_half_a_gb_under_a_recorded_plan(
     # the dense one, 12,288 wide beside 32 heads of 256 at 18 bytes a lane,
     # and the compiled peak stands at a sparse layer's backward: the cell
     # keeps nothing it could have room for, PERF.md 7)
-    # the Nemotron step 2.73e9 over (every block a run of its own: a
-    # block's gradients meet the optimizer as they are made and the plan
-    # holds no stack of them, which the count's "largest stack" allows for)
-    room = {"train-glm52-ep32-s16384-b1": 3.7e9,
-            "train-nemotron3nano-ep8-s8192-b2": 2.9e9}.get(name, 1.5e9)
+    # (the Nemotron step read 2.73e9 over until PR 53: seventeen of its
+    # twenty blocks, mixers and expert blocks of one half, were counted
+    # flash's ``o`` and ``lse`` and the query heads' float32 lanes, which
+    # only an attention block holds; counted by kind it reads 0.14e9 under)
+    room = {"train-glm52-ep32-s16384-b1": 3.7e9}.get(name, 1.5e9)
     assert plan.estimate <= CELLS[name][0] + room, plan
 
 
@@ -130,6 +143,12 @@ def test_a_cell_keeps_the_names_it_has_room_for(name, cell_plans):
         # gate and up of four layers of 8,192 x 16,384, q and k, v of 32
         # and 2 heads of 128
         assert alone.kept_bytes == 4 * 8192 * 2 * (2 * 16384 + 36 * 128)
+    if name == "train-nemotron3nano-ep8-s8192-b2":
+        # 16,384 rows: q, k, v of 32 and 2 heads of 128 three times, a
+        # shared expert's 3,712 eight times, 10,304 columns of z | xBC | dt
+        # five times
+        assert alone.kept_bytes == 16384 * 2 * (
+            3 * 36 * 128 + 8 * 3712 + 5 * 10304)
 
 
 @pytest.mark.parametrize("name", sorted(CELLS))
@@ -141,18 +160,26 @@ def test_the_plan_is_monotone_in_the_limit(name, cell_plans):
     for less, more in zip(sweep, sweep[1:]):
         assert less.kept_bytes <= more.kept_bytes, (less, more)
         assert less.estimate == more.estimate       # shapes alone
-    assert sweep[0].kept == ()         # 10.3e9: under every estimate here
-    offered = {"train-deepseek7b-l8": llama.ATTN_OFFERED,
-               "train-deepseek7b-fsdp2tp2": llama.ATTN_OFFERED,
+    # 10.1e9, 8.62e9 of it for a plan: under every estimate here
+    assert sweep[0].kept == ()
+    dense = llama.ATTN_OFFERED + llama.FFN_OFFERED
+    offered = {"train-deepseek7b-l8": dense,
+               "train-deepseek7b-fsdp2tp2": dense,
                "train-olmoe1b7b-s4096-b4": llama.ATTN_OFFERED,
                "train-mellum2-ep4-s16384-b1": llama.ATTN_OFFERED,
-               # the latent half offers nothing; a mixer layer nothing
+               # the latent half offers nothing, nor a dense latent layer
                "train-glm47flash-ep8-s8192-b2": moe.SHARED_OFFERED,
                "train-glm52-ep32-s16384-b1": moe.SHARED_OFFERED,
+               # a family's own attention halves offer no q, k, v
+               "train-minicpmsala-l4-s16384-b1": llama.FFN_OFFERED,
+               "train-granite4hs-ep8-s8192-b2": ALL + MIX,
                # two-matrix experts: a shared expert has no gate
                "train-nemotron3nano-ep8-s8192-b2":
-                   llama.ATTN_OFFERED + moe.SHARED_OFFERED[1:]}
-    assert sweep[-1].kept == offered.get(name, ALL)
+                   llama.ATTN_OFFERED + moe.SHARED_OFFERED[1:] + MIX}
+    # with eight chips' memory every run keeps every name it offers
+    kept = {n for run in sweep[-1].kept for n in run}
+    assert kept == set(offered.get(name, ALL))
+    assert len({run for run in sweep[-1].kept if run}) <= 3, sweep[-1]
 
 
 @pytest.mark.parametrize("kind,index_heads,grads", [
@@ -183,14 +210,52 @@ def test_a_full_layer_counts_the_index_gradients_it_keeps(kind, index_heads,
             "index_grad_kept_bytes"] == kept
 
 
-def _tiny(preset):
-    cfg = moe.PRESETS[preset].replace(dtype=jnp.float32, remat=True,
-                                      n_shared=3 if "commanda" in preset
-                                      else 1)
-    params = moe.init_params(jax.random.PRNGKey(3), cfg)
+def _tiny(preset, family=moe):
+    more = {"n_shared": 3 if "commanda" in preset else 1} \
+        if family is moe else {}
+    cfg = family.PRESETS[preset].replace(dtype=jnp.float32, remat=True,
+                                         **more)
+    params = family.init_params(jax.random.PRNGKey(3), cfg)
     tokens = jax.random.randint(jax.random.PRNGKey(4), (2, 65), 0,
                                 cfg.vocab_size)
     return cfg, params, {"tokens": tokens}
+
+
+# (family, preset) -> the names its layers offer and the widths of one
+# token's kept products over all layers, in lanes (None: from the config,
+# a layer's q, k, v and shared gate and up): the hybrids' blocks by
+# kind (tiny: MM*M, each with experts and a shared SwiGLU of 48; tiny-nemotron:
+# MEM*EMEM*E, a shared expert of 40 and no gate), the dense model's two
+# layers of 128, SALA's five
+_mix = {"tiny": 2 * 128 + 2 * 16 + 8, "tiny-nemotron": 2 * 64 + 2 * 32 + 4}
+OFFERING = {
+    (moe, "tiny-commanda"): (ALL, None), (moe, "tiny-mellum"): (
+        llama.ATTN_OFFERED, None),
+    (hybrid, "tiny"): (ALL + MIX, 4 * 16 + 2 * 2 * 16 + 4 * 2 * 48
+                       + 3 * _mix["tiny"]),
+    (hybrid, "tiny-nemotron"): (
+        llama.ATTN_OFFERED + moe.SHARED_OFFERED[1:] + MIX,
+        2 * (4 * 16 + 2 * 2 * 16) + 4 * 40 + 4 * _mix["tiny-nemotron"]),
+    (llama, "tiny"): (llama.ATTN_OFFERED + llama.FFN_OFFERED,
+                      2 * (4 * 16 + 2 * 2 * 16 + 2 * 128)),
+    (sala, "tiny"): (llama.FFN_OFFERED, 5 * 2 * 128),
+}
+
+
+def _ids(v):
+    return getattr(v, "__name__", v if isinstance(v, str) else "").split(
+        ".")[-1]
+
+
+def _pair(out):
+    """(loss, aux) of a family's ``loss_fn`` (aux None for a scalar)."""
+    return out if isinstance(out, tuple) else (out, None)
+
+
+def _loss(family, cfg, batch):
+    """params -> the scalar loss (a family with router losses returns
+    (loss, aux))."""
+    return lambda p: _pair(family.loss_fn(p, batch, cfg))[0]
 
 
 def _memory(limit):
@@ -206,25 +271,130 @@ def test_nothing_more_is_kept_with_no_limit(memory, why):
     assert plan == llama.RematPlan((), 0, 0, 0, why)
 
 
-@pytest.mark.parametrize("preset", ["tiny-commanda", "tiny-mellum"])
-def test_values_and_gradients_are_bit_equal_with_every_name_kept(preset):
-    """Kept residuals are the arrays the replay would have made."""
-    cfg, params, batch = _tiny(preset)
+@pytest.mark.parametrize("family,preset", list(OFFERING), ids=_ids)
+def test_values_and_gradients_are_bit_equal_with_every_name_kept(family,
+                                                                 preset):
+    """Kept residuals are the arrays the replay would have made: a tag is
+    the identity unless the policy names it."""
+    cfg, params, batch = _tiny(preset, family)
+    names, lanes = OFFERING[family, preset]
+    if lanes is None:       # q, k, v and, with a shared SwiGLU, gate and up
+        lanes = cfg.n_layers * (
+            (cfg.n_heads + 2 * cfg.n_kv_heads) * cfg.head_dim
+            + (2 * cfg.shared_width if "commanda" in preset else 0))
+    # op by op for the two expert presets: one compiled program against
+    # another, XLA's CPU fusions round tiny-commanda's float32 loss an ulp
+    # apart (5.5726175 | 5.572618); the others are bit-equal compiled too
+    how = jax.disable_jit if family is moe else contextlib.nullcontext
     got = {}
-    # op by op: one compiled program against another, XLA's CPU fusions
-    # round tiny-commanda's float32 loss an ulp apart (5.5726175 | 5.572618)
     for limit in (0, 10**15):
-        with _memory(limit), jax.disable_jit():
-            (loss, aux), grads = jax.value_and_grad(
-                lambda p: moe.loss_fn(p, batch, cfg), has_aux=True)(params)
-        got[limit] = (loss, grads, float(aux["moe_remat_kept_gb"]))
-    widths = (cfg.n_heads + 2 * cfg.n_kv_heads) * cfg.head_dim + (
-        2 * cfg.shared_width if "commanda" in preset else 0)
-    assert got[0][2] == 0.0 and got[10**15][2] == pytest.approx(
-        cfg.n_layers * 2 * 64 * widths * 4 / 1e9)
+        with _memory(limit), how():
+            plan = llama.remat_plan(cfg, params, 2, 64,
+                                    train_step.step_memory())
+            (loss, aux), grads = jax.jit(jax.value_and_grad(
+                lambda p: _pair(family.loss_fn(p, batch, cfg)),
+                has_aux=True))(params)
+        got[limit] = (loss, grads, plan, aux)
+    assert got[0][2].kept == () and got[0][2].why == "no limit"
+    full = got[10**15][2]
+    assert {n for run in full.kept for n in run} == set(names), full
+    assert full.kept_bytes == 2 * 64 * lanes * 4
+    if got[0][3] is not None:       # an expert family reports the bytes
+        assert float(got[0][3]["moe_remat_kept_gb"]) == 0.0
+        assert float(got[10**15][3]["moe_remat_kept_gb"]) == pytest.approx(
+            full.kept_bytes / 1e9)
     assert np.array_equal(np.asarray(got[0][0]), np.asarray(got[10**15][0]))
     jax.tree.map(lambda a, b: np.testing.assert_array_equal(
         np.asarray(a), np.asarray(b)), got[0][1], got[10**15][1])
+
+
+def _limits(cfg, params):
+    """Limits from under the estimate to over everything offered, a step
+    an offer of a run: [(limit, plan), ...]."""
+    def plan(limit):
+        return llama.remat_plan(cfg, params, 2, 64,
+                                train_step.StepMemory(int(limit), 0))
+
+    full = plan(10**15)
+    each = sorted({n * b for (kind, n, _), run in zip(
+        llama._stacks(params, cfg)[0], full.kept)
+        for name, b in llama._offers(cfg, kind, 2, 64) if name in run})
+    floor = full.estimate / (1 - llama.REMAT_FREE)
+    step = llama.KEPT_COST * each[0] / (1 - llama.REMAT_FREE) / 2
+    top = floor + 2 * llama.KEPT_COST * full.kept_bytes
+    return [(x, plan(x)) for x in np.arange(floor - step, top, step)], full
+
+
+@pytest.mark.parametrize("family,preset", list(OFFERING), ids=_ids)
+def test_the_plan_is_monotone_in_the_limit_by_run(family, preset):
+    """Run by run: more memory never keeps fewer bytes, what is kept
+    always fits under the ceiling, nothing at the estimate and everything
+    offered in the end."""
+    cfg, params, _ = _tiny(preset, family)
+    sweep, full = _limits(cfg, params)
+    assert sweep[0][1].kept == () and sweep[0][1].why == "no room"
+    assert sweep[-1][1] == full._replace(limit=sweep[-1][1].limit)
+    seen = set()
+    for (_, less), (limit, more) in zip(sweep, sweep[1:]):
+        assert less.kept_bytes <= more.kept_bytes, (less, more)
+        assert less.estimate == more.estimate
+        assert more.estimate + llama.KEPT_COST * more.kept_bytes <= \
+            limit * (1 - llama.REMAT_FREE) + 1
+        assert len(more.kept) in (0, len(full.kept))
+        seen.add(more.kept)
+    # a step an offer: the plans differ by a run's name at a time
+    assert len(seen) >= sum(map(len, full.kept)) // 2, len(seen)
+
+
+@pytest.mark.parametrize("family,preset,name", [
+    (hybrid, "tiny-nemotron", hybrid.MIX_OFFERED),
+    (hybrid, "tiny-nemotron", "shared_up"),
+    (hybrid, "tiny", hybrid.MIX_OFFERED),
+    (sala, "tiny", "ffn_up")], ids=_ids)
+def test_a_run_keeps_a_name_another_run_of_its_kind_does_not(family, preset,
+                                                             name):
+    """The run is the unit: under a handed limit an earlier run keeps
+    ``name`` and a later run of the same kind does not, the forward traces
+    one body a kind and set of names, and the step's values and gradients
+    are those of the program that keeps nothing."""
+    cfg, params, batch = _tiny(preset, family)
+    runs = family.layer_runs(cfg)
+    sweep, full = _limits(cfg, params)
+
+    def split(plan):
+        has = [name in run for run in plan.kept]
+        return [(a, b) for a, (ka, _) in enumerate(runs)
+                for b, (kb, _) in enumerate(runs)
+                if a < b and ka == kb and has[a] and not has[b]
+                and name in full.kept[b]] if plan.kept else []
+
+    limit, plan = next((x, p) for x, p in sweep if split(p))
+    first, later = split(plan)[0]
+    assert name in plan.of(first) and name not in plan.of(later)
+    said = []
+    from ray_tpu.util import tracing
+
+    instant = tracing.instant
+    tracing.instant = lambda n, attrs=None, **kw: said.append((n, attrs))
+    try:
+        with _memory(int(limit)):
+            loss, grads = jax.jit(jax.value_and_grad(
+                _loss(family, cfg, batch)))(params)
+    finally:
+        tracing.instant = instant
+    layer = [a for n, a in said if n == "hybrid.layer_plan"][0]
+    assert layer["bodies"] == len({(k, plan.of(at))
+                                   for at, (k, _) in enumerate(runs)})
+    assert layer["bodies"] > layer["kinds"]
+    remat = [a for n, a in said if n == "remat.plan"][0]
+    assert remat["by_run"].split(",")[first].split("+").count(name) == 1
+    assert name not in remat["by_run"].split(",")[later].split("+")
+    assert remat["kept_bytes"] == plan.kept_bytes
+    base_loss, base = jax.jit(jax.value_and_grad(
+        _loss(family, cfg, batch)))(params)
+    assert np.array_equal(np.asarray(loss), np.asarray(base_loss))
+    jax.tree.map(lambda a, b: np.testing.assert_array_equal(
+        np.asarray(a), np.asarray(b)), grads, base)
 
 
 def _replays(jaxpr, shapes, into=None):
@@ -255,26 +425,35 @@ def _all(jaxpr):
             yield from _all(sub)
 
 
-@pytest.mark.parametrize("preset", ["tiny-commanda", "tiny-mellum"])
-def test_the_kept_program_computes_no_kept_product_twice(preset):
+@pytest.mark.parametrize("family,preset,weights", [
+    (moe, "tiny-commanda", ("wq", "wk", "wv", "ws_gate", "ws_up")),
+    (moe, "tiny-mellum", ("wq", "wk", "wv")),
+    (hybrid, "tiny-nemotron", ("wq", "wk", "wv", "ws_up", "in_proj")),
+    (hybrid, "tiny", ("ws_gate", "ws_up", "in_proj")),
+    (llama, "tiny", ("w_gate", "w_up")),
+    (sala, "tiny", ("w_gate", "w_up"))], ids=_ids)
+def test_the_kept_program_computes_no_kept_product_twice(family, preset,
+                                                         weights):
     """With the names kept the backward's checkpoint bodies hold no second
-    ``dot_general`` of ``wq``, ``wk``, ``wv``, ``ws_gate`` or ``ws_up``;
-    with none kept each traced body replays every one of them."""
-    cfg, params, batch = _tiny(preset)
-    layer = params["layers"][0]
-    weights = ["wq", "wk", "wv"] + (["ws_gate", "ws_up"]
-                                    if "commanda" in preset else [])
-    shapes = {layer[w].shape[1:] for w in weights}
-    assert not shapes & {layer[w].shape[1:] for w in layer
+    ``dot_general`` of ``wq``, ``wk``, ``wv``, ``ws_gate``, ``ws_up``, a
+    mixer's ``in_proj`` or a dense SwiGLU's ``w_gate`` and ``w_up``; with
+    none kept each run's body replays every one of them it holds."""
+    cfg, params, batch = _tiny(preset, family)
+    stacks = params["layers"] if isinstance(params["layers"], list) \
+        else [params["layers"]]
+    shapes = {st[w].shape[1:] for st in stacks for w in weights if w in st}
+    assert not shapes & {st[w].shape[1:] for st in stacks for w in st
                          if w not in weights}, "a test of distinct shapes"
     count = {}
     for limit in (0, 10**15):
         with _memory(limit):
-            closed = jax.make_jaxpr(jax.grad(
-                lambda p: moe.loss_fn(p, batch, cfg)[0]))(params)
+            closed = jax.make_jaxpr(jax.grad(_loss(family, cfg, batch)))(
+                params)
         count[limit] = len(_replays(closed.jaxpr, shapes))
-    bodies = len(params["layers"])           # one backward scan a stack
-    assert count == {0: bodies * len(weights), 10**15: 0}
+    # one backward scan a stack
+    held = sum(w in st for st in stacks for w in weights)
+    assert held >= len(weights)
+    assert count == {0: held, 10**15: 0}
 
 
 def test_the_remat_plan_instant_carries_its_fields(monkeypatch):
@@ -293,12 +472,16 @@ def test_the_remat_plan_instant_carries_its_fields(monkeypatch):
                    shapes)              # no checkpoint, no plan to say
     plans = [a for n, a in seen if n == "remat.plan"]
     assert [p["why"] for p in plans] == ["no step", "no limit", "room"]
-    assert plans[0] == {"kept": "", "kept_bytes": 0, "estimate": 0,
-                        "limit": 0, "ceiling": 0, "why": "no step"}
+    assert plans[0] == {"kept": "", "kept_bytes": 0, "runs": "",
+                        "by_run": "", "estimate": 0, "limit": 0,
+                        "ceiling": 0, "why": "no step"}
     last = plans[2]
     assert last["kept"] == ",".join(ALL)
-    assert set(last) == {"kept", "kept_bytes", "estimate", "limit",
-                         "ceiling", "why"}
+    # four runs of layers (window, full, window, full): each keeps all five
+    assert last["runs"] == ", ".join(f"{n} x4" for n in ALL)
+    assert last["by_run"] == ",".join(["+".join(ALL)] * 4)
+    assert set(last) == {"kept", "kept_bytes", "runs", "by_run", "estimate",
+                         "limit", "ceiling", "why"}
     assert last["limit"] == 10**15 and last["ceiling"] == int(
         10**15 * (1 - llama.REMAT_FREE))
     assert 0 < last["kept_bytes"] < last["estimate"] < last["ceiling"]
