@@ -44,6 +44,11 @@ def _refuse_stated(cfg: LlamaConfig):
         stated.append("a parallel block")
     if getattr(cfg, "one_half", False):
         stated.append("blocks that hold one half each")
+    if "conv" in getattr(cfg, "layer_types", ()):
+        stated.append("short-convolution layers (their state, the last "
+                      "taps - 1 rows, has no place in a KV cache)")
+    if getattr(cfg, "n_dense", 0) and hasattr(cfg, "conv_taps"):
+        stated.append("leading dense layers among expert layers")
     if cfg.norm != "rms":
         stated.append(f"a {cfg.norm} norm")
     if stated:

@@ -28,10 +28,33 @@ routed by sigmoid scores with a bias that ``moe.post_update`` moves; its
 head is a leaf of its own (``tied_head`` False). Its published pattern has
 no two adjacent blocks of a kind: every run is one block.
 
+A third member, LFM2's block (``lfm2_moe``; LFM2-8B-A1B), has a first half
+of a third kind, "conv": a gated short convolution,
+
+    [B | C | u] = rms_norm(x) @ in_proj   (3 D columns, no bias)
+    c[t] = sum_j conv_w[j] (B u)[t - taps + 1 + j]   depthwise, causal,
+                                          ``conv_taps`` taps, no bias, NO
+                                          activation
+    out = (C c) @ out_proj
+
+with no state but the last ``conv_taps`` - 1 rows: no scan, no decay, no
+heads. Its attention layers norm each head of q and of k
+(``qk_head_norm``: ``llama._project``) before the rotary; its first
+``n_dense`` layers' feed-forward is a dense SwiGLU of ``dense_d_ff``
+(``llama.feed_forward`` under the scope ``dense``) with no router, no
+routes kept and no part in ``post_update``; the others route by sigmoid
+scores with a bias. A dense layer's kind is its first half's with
+".dense" after it ("conv.dense"): the trees differ, so it is a run of its
+own. What lies between the operator's two projections is ONE pass
+(``_gated_conv``), its gradient written by hand as the Mamba-2 mixer's
+passes are.
+
 Layers of two kinds cannot be one stack: ``params["layers"]`` is a LIST of
 stacks, one a run of adjacent layers of one kind (``layer_runs``; the
 published pattern is [5 mamba, attention, 4 mamba] four times over: runs
-of 5, 1, 9, 1, 9, 1, 9, 1, 4), each run one ``lax.scan``; every run of a
+of 5, 1, 9, 1, 9, 1, 9, 1, 4; ``run_layers`` layers a stack at most where
+it is set: a stack of ONE layer is no loop, and its gradients meet the
+optimizer as the backward makes them), each run one ``lax.scan``; every run of a
 kind that keeps the same names across the layer checkpoint
 (``llama.remat_plan``: the step's memory decides run by run) is scanned by
 the same function, traced under ``llama._checkpoint`` (instant
@@ -86,13 +109,25 @@ from ray_tpu.ops.ssd import _over_lanes, _per_head, ssd_scan
 from ray_tpu.util import tracing
 
 
+_DENSE = ".dense"       # ends the kind of a layer with a dense feed-forward
+
+
+def _first(kind: str) -> str:
+    """A kind's first half: "mamba" | "conv" | "attention" | "experts"."""
+    return kind.split(".")[0]
+
+
+def _dense(kind: str) -> bool:
+    return kind.endswith(_DENSE)
+
+
 @dataclass(frozen=True)
 class HybridConfig(_moe.MoEConfig):
     """``n_heads``, ``n_kv_heads`` are the attention layers'; ``d_ff`` is
     the width of ONE routed expert."""
-    # one kind a layer, "mamba" or "attention" (each followed by its
-    # expert layer) or, with ``one_half``, "experts" too; () = every layer
-    # "mamba"
+    # one kind a layer: "mamba", "conv" or "attention" (each followed by
+    # its feed-forward) or, with ``one_half``, "experts" too; () = every
+    # layer "mamba"
     layer_types: Tuple[str, ...] = ()
     # a block is ONE of mixer, attention and expert layer, with one norm
     # and one residual add (Nemotron-H), not a first half and its experts
@@ -105,6 +140,14 @@ class HybridConfig(_moe.MoEConfig):
     mamba_conv: int = 4
     mamba_chunk: int = 64
     ssd_impl: str = "xla"               # "xla" | "pallas"
+    # taps of a "conv" layer's depthwise convolution (LFM2's conv_L_cache;
+    # ``mamba_conv`` is the Mamba-2 mixer's, with its bias and its SiLU)
+    conv_taps: int = 3
+    # leading layers whose feed-forward is a dense SwiGLU of ``dense_d_ff``
+    n_dense: int = 0
+    dense_d_ff: int = 0
+    # an RMS norm over each head of q and of k (``llama._project``)
+    qk_head_norm: bool = False
     # (the three multipliers and ``attn_scale`` are LlamaConfig's fields)
     rope: bool = False                  # no position embedding
     norm_topk: bool = True              # softmax over the K largest logits
@@ -116,7 +159,11 @@ class HybridConfig(_moe.MoEConfig):
 
     @property
     def kinds(self) -> Tuple[str, ...]:
-        return self.layer_types or ("mamba",) * self.n_layers
+        """A kind a layer: its first half's, ".dense" after it where its
+        feed-forward is the dense SwiGLU."""
+        types = self.layer_types or ("mamba",) * self.n_layers
+        return tuple(t + _DENSE if i < self.n_dense else t
+                     for i, t in enumerate(types))
 
     def replace(self, **kw) -> "HybridConfig":
         return dataclasses.replace(self, **kw)
@@ -143,20 +190,40 @@ PRESETS: Dict[str, HybridConfig] = {
         mamba_state=16, mamba_groups=2, mamba_chunk=8,
         layer_types=tuple({"M": "mamba", "E": "experts", "*": "attention"}[c]
                           for c in "MEM*EMEM*E")),
+    # LFM2's block at the CPU tests' size: two periods of conv, conv,
+    # attention, conv behind a dense first layer, 4 query heads over 2 KV
+    # heads of 24 (the hidden size over the heads is 16), 2 of 8 experts
+    # held, 3 a token by sigmoid score with a bias, no shared expert
+    "tiny-lfm2": HybridConfig(
+        vocab_size=256, d_model=64, n_layers=8, n_heads=4, n_kv_heads=2,
+        head_width=24, d_ff=32, max_seq_len=256, n_experts=8, top_k=3,
+        shared_d_ff=0, experts_held=(2, 0), n_dense=1, dense_d_ff=96,
+        conv_taps=3, qk_head_norm=True, rope=True, rope_theta=1000000.0,
+        norm_eps=1e-5, router_score="sigmoid", route_scale=1.0,
+        router_aux_weight=0.0001, router_z_weight=0.0,
+        layer_types=("conv", "conv", "attention", "conv") * 2),
 }
 
-# what a mamba layer has not of the expert family's tree: the attention half
-_ATTENTION_ONLY = ("attn_norm", "wq", "wk", "wv", "wo")
-_KINDS = ("mamba", "attention", "experts")
+# what a mixer's layer has not of the expert family's tree: the attention
+# half (the heads' norms with ``qk_head_norm``)
+_ATTENTION = ("attn_norm", "wq", "wk", "wv", "wo", "q_norm", "k_norm")
+# a first half's kinds; "experts", a block of its own, with ``one_half``
+_KINDS = ("mamba", "conv", "attention", "experts")
+# a mixer's leaves, by kind: the Mamba-2 mixer's; the short convolution's
+_MIXER_LEAVES = {
+    "mamba": ("mix_norm", "in_proj", "conv_w", "conv_b", "dt_bias", "a_log",
+              "d_skip", "gate_norm", "out_proj"),
+    "conv": ("mix_norm", "in_proj", "conv_w", "out_proj")}
 
 # what the layer checkpoint keeps beside the layer's input and flash's
 # residuals (llama._checkpoint): the expert layer's routes; of a mixer
 # nothing: its scan runs again, and so does its in-projection unless the
-# step's memory has room for the product (MIX_OFFERED, after the shared
-# expert's: 15 ms of replay a GB against 22, PERF.md 6)
+# step's memory has room for the product (MIX_OFFERED, after a dense
+# SwiGLU's and the shared expert's: 15 ms of replay a GB against 23 and
+# 22, PERF.md 6)
 REMAT_SAVED = _moe.REMAT_SAVED
 MIX_OFFERED = "mix_proj"
-REMAT_OFFERED = _moe.SHARED_OFFERED + (MIX_OFFERED,)
+REMAT_OFFERED = _ll.FFN_OFFERED + _moe.SHARED_OFFERED + (MIX_OFFERED,)
 expert_rows = _moe.expert_rows
 post_update = _moe.post_update
 RULE_LEAVES = _moe.RULE_LEAVES
@@ -164,26 +231,39 @@ RULE_LEAVES = _moe.RULE_LEAVES
 
 def halves(cfg: HybridConfig, kind) -> Tuple[bool, bool]:
     """(whether a block of ``kind`` runs a first half: its mixer or its
-    attention; whether it runs the expert layer), for ``llama._layer``."""
+    attention; whether it runs the feed-forward), for ``llama._layer``."""
     return (kind != "experts", kind == "experts") if cfg.one_half \
         else (True, True)
 
 
+def routes(cfg: HybridConfig, kind) -> bool:
+    """Whether a block of ``kind`` runs an EXPERT layer (its feed-forward,
+    and not the dense SwiGLU): it has routes to keep and rows in expert
+    order (``llama._step_estimate``)."""
+    return halves(cfg, kind)[1] and not _dense(kind)
+
+
 def remat_saved_bytes(cfg: HybridConfig, kind, tokens: int) -> int:
     return _moe.remat_saved_bytes(cfg, kind, tokens) \
-        if halves(cfg, kind)[1] else 0
+        if routes(cfg, kind) else 0
 
 
 def remat_offers(cfg: HybridConfig, kind, tokens: int):
-    """What a block of ``kind`` offers the layer checkpoint: its expert
-    layer's shared products, then a mixer's ``u @ in_proj`` [tokens, z |
-    xBC | dt]."""
-    shared = _moe.remat_offers(cfg, kind, tokens) if halves(cfg, kind)[1] \
-        else ()
-    if kind != "mamba":
-        return shared
-    return shared + ((MIX_OFFERED, tokens * _mamba_sizes(cfg)[2]
-                      * jnp.dtype(cfg.dtype).itemsize),)
+    """What a block of ``kind`` offers the layer checkpoint: its dense
+    SwiGLU's gate and up or its expert layer's shared products, then a
+    mixer's ``u @ in_proj`` [tokens, z | xBC | dt] or [tokens, B | C | u]."""
+    item = jnp.dtype(cfg.dtype).itemsize
+    if _dense(kind):
+        ffn = tuple((name, tokens * cfg.dense_d_ff * item)
+                    for name in _ll.FFN_OFFERED)
+    else:
+        ffn = _moe.remat_offers(cfg, kind, tokens) \
+            if halves(cfg, kind)[1] else ()
+    columns = {"mamba": _mamba_sizes(cfg)[2],
+               "conv": 3 * cfg.d_model}.get(_first(kind))
+    if columns is None:
+        return ffn
+    return ffn + ((MIX_OFFERED, tokens * columns * item),)
 
 
 def mixer_backward_bytes(cfg: HybridConfig, kind, tokens: int) -> int:
@@ -193,7 +273,10 @@ def mixer_backward_bytes(cfg: HybridConfig, kind, tokens: int) -> int:
     nothing checkpointed: the convolution's input, x, y and z, the steps),
     the scan's state at every chunk's start (float32 [chunks, H, P, N]) and
     one float32 pass over the convolution's channels and one over the gated
-    norm's lanes."""
+    norm's lanes. A short convolution's: its rule's residual ([tokens,
+    B | C | u] in the activations' type) and one float32 pass over it."""
+    if _first(kind) == "conv":
+        return tokens * 3 * cfg.d_model * (jnp.dtype(cfg.dtype).itemsize + 4)
     inner, conv_dim, _ = _mamba_sizes(cfg)
     chunks = -(-tokens // cfg.mamba_chunk)
     return (_rule_residual_bytes(cfg, tokens)
@@ -214,15 +297,42 @@ def layer_runs(cfg: HybridConfig) -> List[Tuple[str, int]]:
     if len(cfg.kinds) != cfg.n_layers:
         raise ValueError(f"{len(cfg.kinds)} layer types for {cfg.n_layers} "
                          "layers")
+    if cfg.n_dense and (cfg.one_half or not cfg.dense_d_ff):
+        raise ValueError(
+            f"{cfg.n_dense} dense layers of width {cfg.dense_d_ff}"
+            + (" in a model of one-half blocks" if cfg.one_half else ""))
     runs: List[Tuple[str, int]] = []
     for kind in cfg.kinds:
-        if kind not in _KINDS[:3 if cfg.one_half else 2]:
+        if _first(kind) not in _KINDS[:4 if cfg.one_half else 3]:
             raise ValueError(f"unknown layer type {kind!r}")
-        if runs and runs[-1][0] == kind:
+        # ``run_layers``: the most layers one stack holds (0: a whole run)
+        if runs and runs[-1][0] == kind \
+                and runs[-1][1] != (cfg.run_layers or cfg.n_layers):
             runs[-1] = (kind, runs[-1][1] + 1)
         else:
             runs.append((kind, 1))
     return runs
+
+
+def layer_plan_says(cfg: HybridConfig, runs, plan) -> dict:
+    """What ``hybrid.layer_plan`` says of a model with short convolutions
+    or leading dense layers beside its runs: the dense layers, the taps,
+    and what the layer checkpoint kept of each kind's runs beyond the
+    parent's list ("kind: names xN/M": in N of the kind's M runs). Nothing
+    for the family's other members."""
+    if not cfg.n_dense and "conv" not in cfg.layer_types:
+        return {}
+    kept = {}
+    for at, (kind, _) in enumerate(runs):
+        names = plan.of(at) if plan is not None else ()
+        mine = kept.setdefault(kind, [(), 0, 0])
+        mine[0] = mine[0] or names
+        mine[1] += bool(names)
+        mine[2] += 1
+    return {"dense_layers": cfg.n_dense, "dense_width": cfg.dense_d_ff,
+            "taps": cfg.conv_taps,
+            "kept": ", ".join(f"{k}: {'+'.join(n) or '-'} x{some}/{of}"
+                              for k, (n, some, of) in kept.items())}
 
 
 def _run_configs(cfg: HybridConfig):
@@ -236,16 +346,23 @@ def _mamba_sizes(cfg: HybridConfig):
     return inner, inner + 2 * n, 2 * inner + 2 * n + cfg.mamba_heads
 
 
-def _of_kind(lay: dict, kind: str, cfg: HybridConfig, mixer: dict) -> dict:
+def _of_kind(lay: dict, kind: str, cfg: HybridConfig, mixer: dict,
+             dense: dict = None) -> dict:
     """A stack of ``kind``'s leaves from the expert family's ``lay`` (an
-    attention half and an expert layer) and the ``mixer``'s: both halves
-    of a block, or with ``one_half`` the kind's own alone."""
+    attention half and an expert layer), the ``mixer``'s (every kind's:
+    the kind's own are taken) and, for a layer with a dense feed-forward,
+    the dense family's ``dense``: both halves of a block, or with
+    ``one_half`` the kind's own alone."""
     first, second = halves(cfg, kind)
-    expert = {k: v for k, v in lay.items() if k not in _ATTENTION_ONLY}
-    out = dict(expert) if second else {}
-    if first:
-        out.update(mixer if kind == "mamba" else
-                   {k: lay[k] for k in _ATTENTION_ONLY})
+    out = {}
+    if second and _dense(kind):
+        out = {k: dense[k] for k in ("ffn_norm", "w_gate", "w_up", "w_down")}
+    elif second:
+        out = {k: v for k, v in lay.items() if k not in _ATTENTION}
+    if first and _first(kind) == "attention":
+        out.update({k: lay[k] for k in _ATTENTION if k in lay})
+    elif first:
+        out.update({k: mixer[k] for k in _MIXER_LEAVES[_first(kind)]})
     return out
 
 
@@ -258,47 +375,75 @@ def param_specs(cfg: HybridConfig) -> Dict[str, Any]:
         "dt_bias": L + (None,), "a_log": L + (None,),
         "d_skip": L + (None,), "gate_norm": L + ("mlp",),
         "out_proj": L + ("mlp", "embed")}
-    runs = [_of_kind(_moe.param_specs(run.replace(tied_head=False))["layers"],
-                     kind, cfg, mixer) for kind, run in _run_configs(cfg)]
+    # a scale of ONE head's width, shared by the heads (models/sala.py's)
+    heads = {"q_norm": L + (None,), "k_norm": L + (None,)} \
+        if cfg.qk_head_norm else {}
+    runs = [_of_kind({**_moe.param_specs(
+        run.replace(tied_head=False))["layers"], **heads}, kind, cfg, mixer,
+        _ll.param_specs(run)["layers"]) for kind, run in _run_configs(cfg)]
     head = {} if cfg.tied_head else {"lm_head": ("embed", "vocab")}
     return {"embed": ("vocab", "embed"), "layers": runs,
             "final_norm": ("embed_nr",), **head}
 
 
+def _mamba_params(k, cfg: HybridConfig, n: int) -> dict:
+    """``n`` Mamba-2 mixers' leaves as Mamba-2 sets them: ``dt_bias`` the
+    inverse softplus of steps log-uniform in [0.001, 0.1], ``a_log`` the
+    log of rates uniform in [1, 16], ``d_skip`` 1."""
+    pd, D, H = cfg.param_dtype, cfg.d_model, cfg.mamba_heads
+    inner, conv_dim, proj = _mamba_sizes(cfg)
+    ks = jax.random.split(jax.random.fold_in(k, 3), 5)
+    step = jnp.exp(jax.random.uniform(
+        ks[2], (n, H), minval=jnp.log(1e-3), maxval=jnp.log(1e-1)))
+    return {
+        "mix_norm": jnp.ones((n, D), pd),
+        "in_proj": jax.random.normal(ks[0], (n, D, proj), pd) * D ** -0.5,
+        "conv_w": jax.random.normal(
+            ks[1], (n, cfg.mamba_conv, conv_dim), pd)
+        * cfg.mamba_conv ** -0.5,
+        "conv_b": jnp.zeros((n, conv_dim), pd),
+        "dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(pd),
+        "a_log": jnp.log(jax.random.uniform(
+            ks[3], (n, H), minval=1.0, maxval=16.0)).astype(pd),
+        "d_skip": jnp.ones((n, H), pd),
+        "gate_norm": jnp.ones((n, inner), pd),
+        "out_proj": jax.random.normal(ks[4], (n, inner, D), pd)
+        * inner ** -0.5}
+
+
+def _conv_params(k, cfg: HybridConfig, n: int) -> dict:
+    """``n`` short convolutions' leaves: the two projections normal over
+    the square root of their fan-in, the taps over the square root of
+    their number."""
+    pd, D = cfg.param_dtype, cfg.d_model
+    ks = jax.random.split(jax.random.fold_in(k, 3), 3)
+    return {
+        "mix_norm": jnp.ones((n, D), pd),
+        "in_proj": jax.random.normal(ks[0], (n, D, 3 * D), pd) * D ** -0.5,
+        "conv_w": jax.random.normal(ks[1], (n, cfg.conv_taps, D), pd)
+        * cfg.conv_taps ** -0.5,
+        "out_proj": jax.random.normal(ks[2], (n, D, D), pd) * D ** -0.5}
+
+
 def init_params(key, cfg: HybridConfig) -> Dict[str, Any]:
     """Norms 1, projections normal over the square root of their fan-in,
-    the mixer's own as Mamba-2 sets them: ``dt_bias`` the inverse softplus
-    of steps log-uniform in [0.001, 0.1], ``a_log`` the log of rates
-    uniform in [1, 16], ``d_skip`` 1."""
-    pd = cfg.param_dtype
-    D, H = cfg.d_model, cfg.mamba_heads
-    inner, conv_dim, proj = _mamba_sizes(cfg)
+    a mixer's own by its kind (``_mamba_params``, ``_conv_params``), every
+    router bias 0 in float32."""
+    pd, D = cfg.param_dtype, cfg.d_model
 
     def stack(kind, run, i):
-        k = jax.random.fold_in(key, 100 + i)
+        k, n = jax.random.fold_in(key, 100 + i), run.n_layers
         lay = _moe.init_params(k, run.replace(
             vocab_size=1, tied_head=False))["layers"]
-        if kind != "mamba":
-            return _of_kind(lay, kind, cfg, {})
-        n = run.n_layers
-        ks = jax.random.split(jax.random.fold_in(k, 3), 5)
-        step = jnp.exp(jax.random.uniform(
-            ks[2], (n, H), minval=jnp.log(1e-3), maxval=jnp.log(1e-1)))
-        return _of_kind(lay, kind, cfg, {
-            "mix_norm": jnp.ones((n, D), pd),
-            "in_proj": jax.random.normal(ks[0], (n, D, proj), pd)
-            * D ** -0.5,
-            "conv_w": jax.random.normal(
-                ks[1], (n, cfg.mamba_conv, conv_dim), pd)
-            * cfg.mamba_conv ** -0.5,
-            "conv_b": jnp.zeros((n, conv_dim), pd),
-            "dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(pd),
-            "a_log": jnp.log(jax.random.uniform(
-                ks[3], (n, H), minval=1.0, maxval=16.0)).astype(pd),
-            "d_skip": jnp.ones((n, H), pd),
-            "gate_norm": jnp.ones((n, inner), pd),
-            "out_proj": jax.random.normal(ks[4], (n, inner, D), pd)
-            * inner ** -0.5})
+        if cfg.qk_head_norm:
+            lay = {**lay, "q_norm": jnp.ones((n, cfg.head_dim), pd),
+                   "k_norm": jnp.ones((n, cfg.head_dim), pd)}
+        mixer = {"mamba": _mamba_params, "conv": _conv_params}.get(
+            _first(kind), lambda *_: {})(k, cfg, n)
+        dense = _ll.init_params(jax.random.fold_in(k, 5), run.replace(
+            vocab_size=1, d_ff=cfg.dense_d_ff))["layers"] \
+            if _dense(kind) else None
+        return _of_kind(lay, kind, cfg, mixer, dense)
 
     head = {} if cfg.tied_head else {"lm_head": jax.random.normal(
         jax.random.fold_in(key, 1), (D, cfg.vocab_size), pd) * D ** -0.5}
@@ -312,19 +457,23 @@ def init_params(key, cfg: HybridConfig) -> Dict[str, Any]:
 def num_params(cfg: HybridConfig) -> int:
     D, H = cfg.d_model, cfg.mamba_heads
     inner, conv_dim, proj = _mamba_sizes(cfg)
-    attention = (D * cfg.n_heads * cfg.head_dim * 2
-                 + 2 * D * cfg.n_kv_heads * cfg.head_dim)
-    mamba = (D * proj + (cfg.mamba_conv + 1) * conv_dim + 3 * H + inner
-             + inner * D)
+    first = {
+        "attention": (D * cfg.n_heads * cfg.head_dim * 2
+                      + 2 * D * cfg.n_kv_heads * cfg.head_dim
+                      + 2 * cfg.head_dim * cfg.qk_head_norm),
+        "mamba": (D * proj + (cfg.mamba_conv + 1) * conv_dim + 3 * H + inner
+                  + inner * D),
+        "conv": 3 * D * D + cfg.conv_taps * D + D * D,
+        "experts": 0}       # a block that is its feed-forward alone
     each = len(_moe._matrices(cfg)) + 1            # matrices an expert
     experts = (D * cfg.n_experts + each * cfg.n_held * D * cfg.d_ff
-               + each * D * cfg.shared_d_ff
+               + each * D * cfg.shared_width
                + (cfg.n_experts if _moe._has_bias(cfg) else 0))
     total = cfg.vocab_size * D * (1 if cfg.tied_head else 2) + D
     for kind in cfg.kinds:
-        first, second = halves(cfg, kind)
-        total += (D + (mamba if kind == "mamba" else attention)) * first \
-            + (D + experts) * second
+        one, two = halves(cfg, kind)
+        ffn = 3 * D * cfg.dense_d_ff if _dense(kind) else experts
+        total += (D + first[_first(kind)]) * one + (D + ffn) * two
     return total
 
 
@@ -460,6 +609,103 @@ def _gated_norm_bwd(eps, groups, res, g):
 _gated_norm.defvjp(_gated_norm_fwd, _gated_norm_bwd)
 
 
+def _gate_conv(bcu, w):
+    """(c, v) float32: v = B u [B, S, D] and its causal convolution c[t] =
+    sum_j w[j] v[t - taps + 1 + j], from bcu [B, S, B | C | u] and the taps
+    w [taps, D]. B and u are padded in their own type and multiplied at
+    each shift: no float32 array but c and v is made."""
+    f32 = jnp.float32
+    taps, (s, D) = w.shape[0], (bcu.shape[1], w.shape[1])
+    front = ((0, 0), (taps - 1, 0), (0, 0))
+    gate, u = jnp.pad(bcu[..., :D], front), jnp.pad(bcu[..., 2 * D:], front)
+    c = 0.0
+    for j in range(taps):       # the last shift is v itself
+        v = gate[:, j:j + s].astype(f32) * u[:, j:j + s].astype(f32)
+        c = c + v * w[j].astype(f32)
+    return c, v
+
+
+@jax.custom_vjp
+def _gated_conv(bcu, w):
+    """The short convolution between its two projections, ONE pass over
+    [B, S, 3 D] -> [B, S, D] in bcu's type with float32 sums: C conv(B u),
+    no bias, no activation."""
+    D = w.shape[1]
+    c, _ = _gate_conv(bcu, w)
+    return jax.lax.optimization_barrier(
+        (bcu[..., D:2 * D].astype(jnp.float32) * c).astype(bcu.dtype))
+
+
+def _gated_conv_fwd(bcu, w):
+    return _gated_conv(bcu, w), (bcu, w)
+
+
+def _gated_conv_bwd(res, g):
+    """c and v again from bcu. dC = g c; dc = g C ONCE, in bcu's type,
+    padded at the END and read at one shift a tap for both dv, its
+    transposed convolution, and dw[j] = sum over the rows of
+    dc[t + taps - 1 - j] v[t] (``_conv_silu_bwd``'s walk); dB = dv u,
+    du = dv B. The three gradients leave as ONE [B, S, 3 D] array in
+    bcu's type, which is what the in-projection's two gradient products
+    read; dw is a float32 sum over every row."""
+    bcu, w = res
+    f32 = jnp.float32
+    taps, (s, D) = w.shape[0], (bcu.shape[1], w.shape[1])
+    c, v = _gate_conv(bcu, w)
+    gate = bcu[..., D:2 * D].astype(f32)
+    dc = (g.astype(f32) * gate).astype(bcu.dtype)
+    ahead = jnp.pad(dc, ((0, 0), (0, taps - 1), (0, 0)))
+    dv, dw = 0.0, []
+    for j in range(taps):
+        shifted = ahead[:, taps - 1 - j:taps - 1 - j + s].astype(f32)
+        dv = dv + shifted * w[j].astype(f32)
+        dw.append(jnp.sum(shifted * v, axis=(0, 1)))
+    d_bcu = jnp.concatenate([
+        (dv * bcu[..., 2 * D:].astype(f32)).astype(bcu.dtype),
+        (g.astype(f32) * c).astype(bcu.dtype),
+        (dv * bcu[..., :D].astype(f32)).astype(bcu.dtype)], axis=-1)
+    # results of THIS pass, not terms of their consumers' fusions
+    return jax.lax.optimization_barrier(
+        (d_bcu, jnp.stack(dw).astype(w.dtype)))
+
+
+_gated_conv.defvjp(_gated_conv_fwd, _gated_conv_bwd)
+
+
+def conv_plan(cfg: HybridConfig, B: int, S: int) -> dict:
+    """The short convolution's own account of its one elementwise pass
+    (the attributes of ``mixer.plan`` for a "conv" layer): every operand
+    read once and every result written once, in bytes, forward (B, C, u ->
+    y) and backward (B, C, u, g -> dB, dC, du; the taps' gradient is
+    [taps, D])."""
+    rows, item = B * S, jnp.dtype(cfg.dtype).itemsize
+    wide = rows * cfg.d_model * item
+    return {"path": "rules", "kind": "conv", "rows": rows,
+            "taps": cfg.conv_taps, "channels": cfg.d_model,
+            "residual_bytes": 0 if cfg.remat else 3 * wide,
+            "hbm_bytes_fwd": 4 * wide, "hbm_bytes_bwd": 7 * wide}
+
+
+def short_conv_half(x, lp, cfg: HybridConfig):
+    """The gated short convolution half of a block: x [B, S, D] -> x + its
+    operator's output (the module docstring has the equations)."""
+    B, S, _ = x.shape
+    dt_ = cfg.dtype
+    tracing.plan("mixer.plan", conv_plan(cfg, B, S))
+    with jax.named_scope("short_conv"):
+        u = _ll.rms_norm(x, lp["mix_norm"], cfg.norm_eps)
+        with jax.named_scope("in_proj"):
+            # kept across the layer checkpoint where the step's memory has
+            # room
+            bcu = checkpoint_name(u @ _ll._dq(lp["in_proj"], dt_),
+                                  MIX_OFFERED)
+        with jax.named_scope("gated_conv"):
+            y = _gated_conv(bcu, lp["conv_w"])
+        with jax.named_scope("out_proj"):
+            y = y @ _ll._dq(lp["out_proj"], dt_)
+        return _ll._residual(x, y, cfg)
+
+
 def plan(cfg: HybridConfig, B: int, S: int) -> dict:
     """The rules' own account of a mixer's elementwise passes (also the
     attributes of ``mixer.plan``): every operand of a pass read once and
@@ -487,9 +733,12 @@ def plan(cfg: HybridConfig, B: int, S: int) -> dict:
 
 
 def mixer_half(x, lp, cfg: HybridConfig, kind: str, mesh=None):
-    """The Mamba-2 half of a block: x [B, S, D] -> x + its mixer's output
-    (the module docstring has the equations)."""
-    assert kind == "mamba", kind
+    """A block's mixer by its kind: the Mamba-2 half, x [B, S, D] -> x +
+    its mixer's output (the module docstring has the equations), or the
+    gated short convolution (``short_conv_half``)."""
+    if _first(kind) == "conv":
+        return short_conv_half(x, lp, cfg)
+    assert _first(kind) == "mamba", kind
     if cfg.ssd_impl == "pallas" and mesh is not None and mesh.size > 1:
         raise NotImplementedError(
             "ssd_impl='pallas' runs on one device: GSPMD cannot partition "
@@ -523,7 +772,18 @@ def mixer_half(x, lp, cfg: HybridConfig, kind: str, mesh=None):
     return _ll._residual(x, y @ _ll._dq(lp["out_proj"], dt_), cfg)
 
 
-feed_forward = _moe.feed_forward
+def feed_forward(h, lp, cfg: HybridConfig, mesh=None, rules=None, tp=None,
+                 kind=None):
+    """A layer's feed-forward by its kind: the expert layer, or for a
+    leading dense layer the SwiGLU of ``dense_d_ff`` (scope ``dense``),
+    which reports nothing."""
+    if kind is not None and _dense(kind):
+        with jax.named_scope("dense"):
+            return _ll.feed_forward(h, lp, cfg, mesh=mesh, rules=rules, tp=tp)
+    return _moe.feed_forward(h, lp, cfg, mesh=mesh, rules=rules, tp=tp,
+                             kind=kind)
+
+
 finish_loss = _moe.finish_loss
 forward = _ll.forward
 forward_with_stats = _ll.forward_with_stats

@@ -446,8 +446,11 @@ def _step_estimate(cfg: "LlamaConfig", params, stacks, passes: int,
       before a further pass's, and one layer's products, gradients and
       float32 forms;
     - the head: what every layer keeps, the logits and their gradient.
-    A block is counted for what its kind holds (``_halves``)."""
+    A block is counted for what its kind holds (``_halves``; a family
+    whose feed-forward is no expert layer in every block says in which it
+    is: ``routes``)."""
     family, item = _family(cfg), jnp.dtype(cfg.dtype).itemsize
+    routes = getattr(family, "routes", lambda cfg, kind: True)
     expert_rows = getattr(family, "expert_rows", lambda cfg, rows: 0)(
         cfg, rows)
     heads = rows * cfg.n_heads * cfg.head_dim
@@ -466,7 +469,7 @@ def _step_estimate(cfg: "LlamaConfig", params, stacks, passes: int,
             elif w.ndim == 4:
                 total += expert_rows * w.shape[3] * item
         return LAYER_BACKWARD * total + LANE_BYTES * (
-            expert_rows * cfg.d_model * second
+            expert_rows * cfg.d_model * (second and routes(cfg, kind))
             + heads * (first == "attention")) + (
                 family.mixer_backward_bytes(cfg, kind, rows)
                 if first == "mixer" else 0)
@@ -866,9 +869,11 @@ def feed_forward(h, lp, cfg: LlamaConfig, mesh=None, rules=None, tp=None,
 
 def _takes_attention_half(cfg: LlamaConfig, kind) -> bool:
     """Whether a layer of ``kind`` runs ``_attention_half``: the one kind
-    of a model that names none, a hybrid's "attention", a named kind."""
+    of a model that names none, a hybrid's "attention" (what follows a "."
+    is its feed-forward's: "attention.dense"), a named kind."""
     return (getattr(_family(cfg), "attention_half", None) is None
-            and (kind in (None, "attention") or kind in dict(cfg.attn_kinds)))
+            and (kind is None or kind.split(".")[0] == "attention"
+                 or kind in dict(cfg.attn_kinds)))
 
 
 def _layer(x, lp, cfg: LlamaConfig, cos, sin, mesh=None, rules=None, tp=None,
@@ -980,17 +985,18 @@ def _say_tp_plan(tp, cfg: LlamaConfig, batch: int, seq: int):
         * jnp.dtype(cfg.dtype).itemsize if tp else 0})
 
 
-def _say_layer_plan(runs, bodies: int):
+def _say_layer_plan(runs, bodies: int, more: Optional[dict] = None):
     """The instant ``hybrid.layer_plan`` of a trace, once a traced forward
     of a model whose layers are a list of runs: how many kinds of layer,
     how many runs of adjacent layers of one kind (one scan each), how
     many bodies were built for them (one a kind and set of names its runs
     keep across the layer checkpoint) and the runs themselves,
-    "kind xN, ..." in the layers' order."""
+    "kind xN, ..." in the layers' order; and what the family says
+    ``more`` of its layers (``layer_plan_says``)."""
     tracing.plan("hybrid.layer_plan", {
         "kinds": len({k for k, _ in runs}), "runs": len(runs),
         "bodies": bodies, "layers": sum(n for _, n in runs),
-        "pattern": ", ".join(f"{k} x{n}" for k, n in runs)})
+        "pattern": ", ".join(f"{k} x{n}" for k, n in runs), **(more or {})})
 
 
 def _say_kind_plan(cfg: LlamaConfig, kind, of: AttentionKind, seq: int):
@@ -1212,7 +1218,8 @@ def _forward(params, tokens, cfg: LlamaConfig, pos_offset=0, mesh=None,
                 stats.append(s)
         with jax.named_scope("layers"):
             stats = _join_stats(stats)
-        _say_layer_plan(runs, body_of.cache_info().currsize)
+        _say_layer_plan(runs, body_of.cache_info().currsize, getattr(
+            family, "layer_plan_says", lambda *_: None)(cfg, runs, plan))
     if mesh is not None and rules is not None:
         _say_tp_plan(tp, cfg, B, S)
     if cfg.parallel_block:

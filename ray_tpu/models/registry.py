@@ -19,6 +19,10 @@ _FAMILIES = {
     # Nemotron-H's model_type: hybrid.py preset "tiny-nemotron" (blocks of
     # one half, grouped mixers, two-matrix squared-ReLU experts)
     "nemotron_h": "ray_tpu.models.hybrid",
+    # LFM2's model_type: hybrid.py preset "tiny-lfm2" (gated short
+    # convolutions beside attention with a norm a head, leading dense
+    # layers, sigmoid-routed experts with no shared one)
+    "lfm2_moe": "ray_tpu.models.hybrid",
     "latent": "ray_tpu.models.latent",
     # GLM-5.2's model_type: latent.py presets "glm-5.2-ep32-l5", "tiny-glm52"
     "glm_moe_dsa": "ray_tpu.models.latent",

@@ -109,6 +109,21 @@ that does not runs the forward kernel a second time, a launch over S^2,
 only to rebuild them. q, k and v carry no name: they are rebuilt from the
 layer input by their projections.
 
+At a head of 64 (D 64, half a lane tile; the LFM2 cell, 32 query heads over
+8 KV heads, S 16384): a [rows, 64] block is a whole tile of 128 lanes in
+VMEM, so the plans count VMEM at `_vmem_lanes(D)` and HBM at D itself, and
+all three calls take the stream plans of the same S at D 128 (forward spans
+of 8 blocks written out, dQ 16 in the loop, dK/dV 8 q-blocks a grid step);
+counted at 64 the dK/dV call took the resident plan and Mosaic refused its
+105 MiB of 103.5. QK^T contracts over half the MXU's depth and PV writes
+half its width, and a score costs the VPU what it costs at 128: a call
+takes what it takes at D 128 (forward 30.6 ms, forward with backward 78.5;
+the Mellum2 full layers' 30.6 and 81 at 32 over 4 heads of 128), 24.9% of
+the roofline reckoned at 64. Padding q, k and v to 128 lanes in the wrapper
+gave the same bits and 30.1 | 79.4 ms: no gain, twice the HBM rows; the
+heads go to the kernels as they are (`benchmark/tools/lfm2_flash_forms.py`,
+my chip run, PR 54; PERF.md 6).
+
 Shapes: q [B, S, H, D], k/v [B, T, KV, D], output [B, S, H, D].
 """
 
@@ -130,6 +145,19 @@ NEG_INF = -1e30
 _LSE_LANES = 128            # the kernels read and write lse lane-broadcast
 # checkpoint_name tags of what _flash_vjp_fwd hands the backward: (o, lse)
 FLASH_RESIDUALS = ("flash_out", "flash_lse")
+
+
+def _vmem_lanes(head_dim: int) -> int:
+    """The lanes a [rows, head_dim] block takes in VMEM: whole tiles of
+    128. At a head of 64, half a tile, every block and temporary of the
+    kernels is as large there as at 128 (the plans count VMEM at this
+    width, HBM at the head's own: the resident dK/dV plan counted at 64
+    asked Mosaic for 105 MiB of the 103.5 it may, S 16384). Under 64 no
+    model runs on the chip: the CPU tests' toy heads of 16 and 32, in
+    interpret mode, are counted as they are."""
+    if head_dim < 64:
+        return head_dim
+    return -(-head_dim // _LSE_LANES) * _LSE_LANES
 
 
 def _use_interpret() -> bool:
@@ -511,6 +539,7 @@ def kv_plan(*, S: int, T: int, D: int, dtype, block_q: int, block_k: int,
     8 and 2: 14.25 | 11.25, dQ 8 and 2: 11.75 | 10.5, 16 and 2 in the
     loop: 15.5 | 14.25)."""
     itemsize = jnp.dtype(dtype).itemsize
+    head_dim, D = D, _vmem_lanes(D)         # every count below is of VMEM
     kv_block_bytes = 2 * 2 * T * D * itemsize
     # q, dO, o, the result and the 128-lane lse, double-buffered
     q_side_bytes = 2 * block_q * (4 * D * itemsize + _LSE_LANES * 4)
@@ -582,7 +611,7 @@ def kv_plan(*, S: int, T: int, D: int, dtype, block_q: int, block_k: int,
                    else (False,)) if fits(n, f, w)), (spans[-1], 1, False))
     steps, band_steps, whole_steps = _span_steps(
         num_q=num_q, span=blocks, written=written, **mask)
-    return dict(path=path, S=S, D=D, kv_block_bytes=kv_block_bytes,
+    return dict(path=path, S=S, D=head_dim, kv_block_bytes=kv_block_bytes,
                 loop_bytes=loop_bytes, span=blocks * block_k,
                 in_flight=in_flight, written=written,
                 walk_bytes=walk_bytes(blocks, in_flight, written),
@@ -1086,6 +1115,7 @@ def bwd_dkdv_plan(*, S: int, T: int, D: int, dtype, groups: int,
     D 128): the order is kept from the sparse mirror and decides nothing
     at either cell."""
     itemsize = jnp.dtype(dtype).itemsize
+    head_dim, D = D, _vmem_lanes(D)         # VMEM's counts; HBM's at head_dim
     row_bytes = 3 * D * itemsize + _LSE_LANES * 4
     # a head's results leave in the inputs' dtype; a group's are summed in f32
     out_dtype = jnp.dtype(dtype if groups == 1 else jnp.float32)
@@ -1131,7 +1161,7 @@ def bwd_dkdv_plan(*, S: int, T: int, D: int, dtype, groups: int,
         walk_bytes=walk_bytes(span, in_flight), steps=steps,
         band_steps=band_steps,
         hbm_bytes_per_head=hbm_bytes_per_head(
-            path, S=S, T=T, D=D, itemsize=itemsize,
+            path, S=S, T=T, D=head_dim, itemsize=itemsize,
             out_itemsize=out_dtype.itemsize, **dims, steps=steps, span=span,
             q_index=functools.partial(_q_block_index, steps=steps, span=span,
                                       **mask)))

@@ -34,7 +34,9 @@ RECIPE_KEYS = {"train": ("attn_impl", "remat", "f32_logits"),
                "train_alternating": ("attn_impl", "gmm_impl", "ssd_impl",
                                      "remat", "f32_logits"),
                "train_blockset": ("attn_impl", "ssd_impl", "remat",
-                                  "f32_logits")}
+                                  "f32_logits"),
+               "train_shortconv": ("attn_impl", "gmm_impl", "remat",
+                                   "f32_logits")}
 # published config.json key -> the program's field
 WIDTHS = {"hidden_size": "d_model", "num_hidden_layers": "n_layers",
           "num_attention_heads": "n_heads",
@@ -60,8 +62,8 @@ def test_cell_program_config_builds_at_its_published_widths(name):
     import jax.numpy as jnp
 
     from benchmark import (model, model_commanda, model_glm, model_glm52,
-                           model_granite, model_mellum, model_moe,
-                           model_nemotron, model_sala, resolve)
+                           model_granite, model_lfm2, model_mellum,
+                           model_moe, model_nemotron, model_sala, resolve)
 
     cell = resolve.cell(name)
     kind, conf, recipe = cell["kind"], cell["config"], cell["train"]
@@ -73,7 +75,8 @@ def test_cell_program_config_builds_at_its_published_widths(name):
              "train_parallel": model_commanda.moe_config,
              "train_sparse": model_glm52.latent_config,
              "train_alternating": model_nemotron.hybrid_config,
-             "train_blockset": model_sala.sala_config}[kind]
+             "train_blockset": model_sala.sala_config,
+             "train_shortconv": model_lfm2.hybrid_config}[kind]
     passed = {k: recipe[k] for k in RECIPE_KEYS[kind] if k in recipe}
     cfg = build(conf, **passed)
 
@@ -86,7 +89,8 @@ def test_cell_program_config_builds_at_its_published_widths(name):
                   if f != "logit_scale"},
               "train_sparse": model_glm52.HF_TO_FIELD,
               "train_alternating": model_nemotron.HF_TO_FIELD,
-              "train_blockset": model_sala.HF_TO_FIELD}[kind]
+              "train_blockset": model_sala.HF_TO_FIELD,
+              "train_shortconv": model_lfm2.HF_TO_FIELD}[kind]
     for key, field in widths.items():
         assert getattr(cfg, field) == conf[key], (name, key)
     if kind == "train_parallel":
@@ -146,6 +150,32 @@ def test_cell_program_config_builds_at_its_published_widths(name):
         assert not cfg.rope and cfg.head_dim == conf["head_dim"]
         assert cfg.mamba_inner == conf["mamba_num_heads"] \
             * conf["mamba_head_dim"]
+    if kind == "train_shortconv":
+        # the taps, the dense layers' count and width, both feed-forward
+        # widths, the rotary's theta and the scale are the published keys'
+        # (the map above); every layer's operator `layer_types`', the head
+        # width the hidden size over the heads; the router's width and the
+        # experts held the deployment's
+        assert {"conv_L_cache", "num_dense_layers", "intermediate_size",
+                "moe_intermediate_size", "rope_theta",
+                "routed_scaling_factor"} <= set(widths)
+        dep = conf["deployment"]
+        assert cfg.n_experts == dep["router_experts"]
+        assert cfg.experts_held == (conf["num_experts"], dep["experts_first"])
+        dense = conf["num_dense_layers"]
+        assert cfg.kinds == tuple(
+            model_lfm2.OPERATORS[t] + (".dense" if i < dense else "")
+            for i, t in enumerate(conf["layer_types"]))
+        assert cfg.head_dim == conf["hidden_size"] \
+            // conf["num_attention_heads"]
+        assert cfg.rope and cfg.qk_head_norm and cfg.tied_head
+        assert not cfg.one_half and cfg.shared_d_ff == 0
+        assert (cfg.expert_act, cfg.router_score, cfg.norm_topk) == (
+            "swiglu", "sigmoid", True)
+        from ray_tpu.models import hybrid
+        assert "lm_head" not in hybrid.param_specs(cfg)
+        assert sum(n for _, n in hybrid.layer_runs(cfg)) \
+            == conf["num_hidden_layers"]
     if kind == "train_blockset":
         # the heads, the stated head width, the SwiGLU's width and the
         # vocabulary are the published keys' (the map above); the kinds of
