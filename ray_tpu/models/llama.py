@@ -334,11 +334,12 @@ def _checkpoint(body, cfg: "LlamaConfig", kept: Tuple[str, ...] = ()):
 ATTN_OFFERED = ("attn_q", "attn_k", "attn_v")
 
 # The share of a device's memory that a plan with kept names leaves free:
-# ``estimate + KEPT_COST x kept`` stays under 85% of the limit, 14.37e9 of
-# the 16,909,336,064 a v5e chip states (of 16 GiB). The largest step that
-# has run there planned 15.82e9, at 15.38e9 XLA rematerialized on its own
-# (38 ``.remat`` instructions, +166 ms a step; PERF.md 6, PR 42), and the
-# estimate may read 0.5e9 under a plan: 15% keeps all three apart.
+# the estimate plus what is kept, each byte at its run's cost (below), stays
+# under 85% of the limit, 14.37e9 of the 16,909,336,064 a v5e chip states
+# (of 16 GiB). The largest step that has run there planned 15.82e9, at
+# 15.38e9 XLA rematerialized on its own (38 ``.remat`` instructions, +166
+# ms a step; PERF.md 6, PR 42), and the estimate may read 0.5e9 under a
+# plan (the LFM2 step's: it plans 14.76e9): 15% keeps all three apart.
 REMAT_FREE = 0.15
 # One layer's backward, in bytes a byte of its products (every matrix of
 # the layer times its rows: a product and its gradient), and in bytes a lane
@@ -347,19 +348,36 @@ REMAT_FREE = 0.15
 # round the attention call (q under its rotary, dq, the output's gradient).
 # The update of a leaf under adafactor holds four float32 temporaries as
 # large as the leaf (3.4 and 3.8 read on the l8 and OLMoE plans, whose peak
-# it is). A kept byte has cost up to 1.64 bytes of plan (the Mellum2 step,
-# whose scans stack three layers' residuals: +3.30e9 for 2.01e9 of q, k and
-# v at passes of 49,152 rows, PR 51; +2.55e9 for 1.61e9 of q at 65,536) and
-# as little as 0.61 in a run of ONE layer, which stacks nothing (Nemotron's
-# q, k and v; Command A+'s five names 0.68: the replay's own buffers go;
-# but 1.02 for Nemotron's shared and in-projection products, +2.71e9 for
-# 2.66e9, and 1.00 for MiniCPM-SALA's gate and up, PR 53): it is counted at
-# 1.5 whatever the run's length. All four from the one-chip plans compiled
-# for a described v5e (PERF.md 4 and 6, PR 43, 51 and 53).
+# it is). All three from the one-chip plans compiled for a described v5e
+# (PERF.md 4 and 6).
 LAYER_BACKWARD = 2.0
 LANE_BYTES = 18
 UPDATE_BYTES = 16
-KEPT_COST = 1.5
+# What a kept byte is charged, in bytes of plan, by the length of the run
+# that keeps it (``_stacks``' ``n``; read from the one-chip plans compiled
+# for a described v5e, PERF.md 6, PR 43, 51, 53 and 55). A run of ONE layer
+# stacks nothing: the kept product stands once and the replay's own buffer
+# for it goes. Its plans read 0.61 (Nemotron's q, k and v), 0.68 (Command
+# A+'s five names), 0.72 (Nemotron's last four in-projection products),
+# 0.999 (seven of LFM2's: +1.408e9 for 1.409e9), 1.00 (MiniCPM-SALA's gate
+# and up) and 1.02 (Nemotron's shared products and first five
+# in-projections: +2.71e9 for 2.66e9): 1.0 holds the most any read to 2%.
+# A run that scans two layers or more stacks every layer's residuals for
+# its backward scan, and a kept byte has cost 1.58 to 1.64 there (the
+# Mellum2 step, nine of whose twelve layers lie in stacks of three: +3.30e9
+# for 2.01e9 of q, k and v at passes of 49,152 rows, PR 51; +2.55e9 for
+# 1.61e9 of q at 65,536, PR 43); why it is half as much again is unread.
+# A family's further pass (``further_stacks``) is charged by ITS OWN
+# stack's length: ``run`` scans it like any other run, and no compiled plan
+# reads otherwise (the two GLM steps, the only ones with such a pass, have
+# no room and keep nothing; the Mellum2 step has no such pass).
+KEPT_COST_ONE = 1.0
+KEPT_COST_STACK = 1.5
+
+
+def kept_cost(n: int) -> float:
+    """Bytes of plan a byte kept in a run of ``n`` layers is charged."""
+    return KEPT_COST_ONE if n == 1 else KEPT_COST_STACK
 
 
 class RematPlan(NamedTuple):
@@ -372,6 +390,8 @@ class RematPlan(NamedTuple):
     limit: int              # the device's; 0: it states none
     # "room" | "no room" | "no step" | "no limit" | "mesh"
     why: str
+    # what the rule charged for ``kept_bytes``: each run's at ``kept_cost``
+    charged: int = 0
 
     def of(self, run: int) -> Tuple[str, ...]:
         """The names run ``run`` keeps."""
@@ -502,9 +522,11 @@ def remat_plan(cfg: "LlamaConfig", params, batch: int, seq: int, memory,
     bytes as the step counts them; None outside a train step). The run is
     the unit: names are taken in the order offered (q, k and v, then the
     family's ``REMAT_OFFERED``), each name in the runs that offer it,
-    earliest run first, while ``estimate + KEPT_COST x kept`` stays under
-    the limit less its free share (REMAT_FREE); a name that does not fit a
-    run is passed over for the next run and the next name. With no limit
+    earliest run first, while the estimate plus what is kept, each run's
+    bytes charged by the run's length (``kept_cost``: 1.0 a byte in a run
+    of one layer, 1.5 in a stack), stays under the limit less its free
+    share (REMAT_FREE); a name that does not fit a run is passed over for
+    the next run and the next name. With no limit
     (the CPU) or no step nothing more is kept than the parent's list; under
     a mesh of several devices neither: the activations' share of a device
     is not counted here."""
@@ -518,17 +540,19 @@ def remat_plan(cfg: "LlamaConfig", params, batch: int, seq: int, memory,
     estimate = _step_estimate(cfg, params, stacks, passes, batch * seq,
                               memory.state)
     ceiling = memory.limit * (1 - REMAT_FREE)
-    kept, total = [[] for _ in stacks], 0
+    kept, total, charged = [[] for _ in stacks], 0, 0.0
     for name in _offered(cfg):
         for run, (_, n, _) in enumerate(stacks):
             nbytes = n * offers[run].get(name, 0)
-            if nbytes and estimate + KEPT_COST * (total + nbytes) <= ceiling:
+            cost = kept_cost(n) * nbytes
+            if nbytes and estimate + charged + cost <= ceiling:
                 kept[run].append(name)
                 total += nbytes
+                charged += cost
     if not total:
         return RematPlan((), 0, estimate, memory.limit, "no room")
     return RematPlan(tuple(map(tuple, kept)), total, estimate, memory.limit,
-                     "room")
+                     "room", int(charged))
 
 
 def _say_remat_plan(plan: RematPlan, cfg: "LlamaConfig"):
@@ -537,10 +561,13 @@ def _say_remat_plan(plan: RematPlan, cfg: "LlamaConfig"):
     (``kept``: every name some run keeps, in the order offered; ``runs``:
     "name xN, ..." with N the runs that keep it; ``by_run``: the runs'
     names in the layers' order, "+" between a run's, "-" for none), their
-    bytes, the estimate they were added to and the limit."""
+    bytes, what the rule charged for them (``charged``: a run's bytes at
+    ``kept_cost``; the compiled plan's growth is the chip's answer), the
+    estimate they were added to and the limit."""
     names = [n for n in _offered(cfg) if any(n in run for run in plan.kept)]
     tracing.plan("remat.plan", {
         "kept": ",".join(names), "kept_bytes": plan.kept_bytes,
+        "charged": plan.charged,
         "runs": ", ".join(f"{n} x{sum(n in run for run in plan.kept)}"
                           for n in names),
         "by_run": ",".join("+".join(run) or "-" for run in plan.kept),

@@ -32,10 +32,19 @@ CELLS = {
                                     (llama.ATTN_OFFERED,) * 6),
     # MEMEM*EMEMEM*EMEMEM*: q, k and v in the three attention blocks, the
     # shared expert's up product in all eight expert blocks, the
-    # in-projection's product in the first five of the nine mixers
+    # in-projection's product in all nine mixers (the first five until a
+    # run of one layer was charged 1.0 a kept byte, PR 55)
     "train-nemotron3nano-ep8-s8192-b2": (9_556_182_016, tuple(
-        {"M": MIX, "m": (), "E": moe.SHARED_OFFERED[1:],
-         "*": llama.ATTN_OFFERED}[c] for c in "MEMEM*EMEMEm*EmEmEm*")),
+        {"M": MIX, "E": moe.SHARED_OFFERED[1:],
+         "*": llama.ATTN_OFFERED}[c] for c in "MEMEM*EMEMEM*EMEMEM*")),
+    # DD*ccc*ccc*ccc*ccc*cc*cc, 24 runs of one layer (the plan of PR 54's
+    # step, 13,349,467,136 with 2,952,790,016 of names kept, less those):
+    # gate, up and the in-projection's product in both dense layers, q, k
+    # and v in all six attention layers, the in-projection's product in the
+    # first twelve of the sixteen sparse convolution layers (five at 1.5)
+    "train-lfm2-ep4-s16384-b1": (13_349_467_136 - 2_952_790_016, tuple(
+        {"D": llama.FFN_OFFERED + MIX, "c": MIX, "-": (),
+         "*": llama.ATTN_OFFERED}[c] for c in "DD*ccc*ccc*ccc*ccc*--*--")),
     # (PR 52's step: the sparse layer, a run of its own, keeps its SwiGLU's
     # gate and up; the three lightning layers' stack has no room for them)
     "train-minicpmsala-l4-s16384-b1": (12_696_442_368,
@@ -58,14 +67,25 @@ _KINDS = {     # kind of cell -> (config module, its function, family)
     "train_sparse": ("model_glm52", "latent_config", "latent"),
     "train_alternating": ("model_nemotron", "hybrid_config", "hybrid"),
     "train_blockset": ("model_sala", "sala_config", "sala"),
+    "train_shortconv": ("model_lfm2", "hybrid_config", "hybrid"),
 }
+
+
+def _charge(cfg, params, plan, batch, seq):
+    """The sum the rule makes: every run's kept bytes at the cost of the
+    run's length (1.0 a byte in a run of one layer, 1.5 in a stack)."""
+    total = 0
+    for (kind, n, _), run in zip(llama._stacks(params, cfg)[0], plan.kept):
+        offers = dict(llama._offers(cfg, kind, batch, seq))
+        total += llama.kept_cost(n) * n * sum(offers[name] for name in run)
+    return total
 
 
 @pytest.fixture(scope="module")
 def cell_plans():
     """name -> (RematPlan on one v5e chip's limit, the same under the
-    cell's own mesh, the limits' sweep): from the cell's files and
-    ``jax.eval_shape``, nothing allocated."""
+    cell's own mesh, the limits' sweep, what the rule charges a plan): from
+    the cell's files and ``jax.eval_shape``, nothing allocated."""
     import importlib
 
     import optax
@@ -99,7 +119,8 @@ def cell_plans():
                                     train_step.StepMemory(limit, held), mesh)
 
         return (plan(V5E), plan(V5E, mesh),
-                [plan(int(V5E * x)) for x in (0.6, 0.9, 1, 1.05, 1.2, 2, 8)])
+                [plan(int(V5E * x)) for x in (0.6, 0.9, 1, 1.05, 1.2, 2, 8)],
+                lambda p: _charge(cfg, state.params, p, mix["batch"], seq))
 
     return {name: one(name) for name in CELLS}
 
@@ -127,7 +148,7 @@ def test_the_estimate_reads_no_more_than_half_a_gb_under_a_recorded_plan(
 
 @pytest.mark.parametrize("name", sorted(CELLS))
 def test_a_cell_keeps_the_names_it_has_room_for(name, cell_plans):
-    alone, meshed, _ = cell_plans[name]
+    alone, meshed, _, charge = cell_plans[name]
     want = CELLS[name][1]
     assert meshed.kept == want, meshed
     assert meshed.limit == V5E
@@ -136,9 +157,13 @@ def test_a_cell_keeps_the_names_it_has_room_for(name, cell_plans):
         return
     assert meshed == alone          # a mesh of one device is none
     assert alone.why == ("room" if want else "no room")
+    assert alone.charged == charge(alone)
     if want:
-        assert alone.estimate + llama.KEPT_COST * alone.kept_bytes <= \
-            V5E * (1 - llama.REMAT_FREE)
+        assert alone.estimate + alone.charged <= V5E * (1 - llama.REMAT_FREE)
+        # every run of one layer at 1.0, Mellum2's three stacks of three
+        # window layers (9 of its 12 layers) at 1.5
+        assert alone.charged == alone.kept_bytes * (
+            11 / 8 if "mellum2" in name else 1)
     if name == "train-commandaplus-ep16-s8192-b1":
         # gate and up of four layers of 8,192 x 16,384, q and k, v of 32
         # and 2 heads of 128
@@ -146,9 +171,19 @@ def test_a_cell_keeps_the_names_it_has_room_for(name, cell_plans):
     if name == "train-nemotron3nano-ep8-s8192-b2":
         # 16,384 rows: q, k, v of 32 and 2 heads of 128 three times, a
         # shared expert's 3,712 eight times, 10,304 columns of z | xBC | dt
-        # five times
+        # nine times
         assert alone.kept_bytes == 16384 * 2 * (
-            3 * 36 * 128 + 8 * 3712 + 5 * 10304)
+            3 * 36 * 128 + 8 * 3712 + 9 * 10304)
+    if name == "train-lfm2-ep4-s16384-b1":
+        # 16,384 rows: gate and up of 7,168 twice, q, k, v of 32 and 8 heads
+        # of 64 six times, 6,144 columns of B | C | u fourteen times (the
+        # fifteenth, 4.56e9 with the estimate's 9.91e9, does not fit)
+        assert alone.kept_bytes == 16384 * 2 * (
+            2 * 2 * 7168 + 6 * 48 * 64 + 14 * 6144) == 4_362_076_160
+    if name == "train-mellum2-ep4-s16384-b1":
+        assert alone.kept_bytes == 2_013_265_920        # as at PR 51
+    if name == "train-minicpmsala-l4-s16384-b1":
+        assert alone.kept_bytes == 2 * 16384 * 16384 * 2    # as at PR 53
 
 
 @pytest.mark.parametrize("name", sorted(CELLS))
@@ -159,6 +194,7 @@ def test_the_plan_is_monotone_in_the_limit(name, cell_plans):
     # at 17.8e9)
     for less, more in zip(sweep, sweep[1:]):
         assert less.kept_bytes <= more.kept_bytes, (less, more)
+        assert less.charged <= more.charged, (less, more)
         assert less.estimate == more.estimate       # shapes alone
     # 10.1e9, 8.62e9 of it for a plan: under every estimate here
     assert sweep[0].kept == ()
@@ -173,6 +209,9 @@ def test_the_plan_is_monotone_in_the_limit(name, cell_plans):
                # a family's own attention halves offer no q, k, v
                "train-minicpmsala-l4-s16384-b1": llama.FFN_OFFERED,
                "train-granite4hs-ep8-s8192-b2": ALL + MIX,
+               # dense layers' gate and up; no shared expert
+               "train-lfm2-ep4-s16384-b1":
+                   llama.ATTN_OFFERED + llama.FFN_OFFERED + MIX,
                # two-matrix experts: a shared expert has no gate
                "train-nemotron3nano-ep8-s8192-b2":
                    llama.ATTN_OFFERED + moe.SHARED_OFFERED[1:] + MIX}
@@ -320,25 +359,32 @@ def _limits(cfg, params):
         llama._stacks(params, cfg)[0], full.kept)
         for name, b in llama._offers(cfg, kind, 2, 64) if name in run})
     floor = full.estimate / (1 - llama.REMAT_FREE)
-    step = llama.KEPT_COST * each[0] / (1 - llama.REMAT_FREE) / 2
-    top = floor + 2 * llama.KEPT_COST * full.kept_bytes
+    step = llama.KEPT_COST_ONE * each[0] / (1 - llama.REMAT_FREE) / 2
+    top = floor + 2 * llama.KEPT_COST_STACK * full.kept_bytes
     return [(x, plan(x)) for x in np.arange(floor - step, top, step)], full
 
 
 @pytest.mark.parametrize("family,preset", list(OFFERING), ids=_ids)
 def test_the_plan_is_monotone_in_the_limit_by_run(family, preset):
-    """Run by run: more memory never keeps fewer bytes, what is kept
-    always fits under the ceiling, nothing at the estimate and everything
-    offered in the end."""
+    """Run by run: more memory is never charged less (what the rule fills
+    is the charge: in bytes a stack's name at 1.5, taken where it first
+    fits, may stand in the way of a larger name of a run of one at 1.0,
+    tiny-commanda's k of three window layers before k and v of two full
+    ones), what is kept always fits under the ceiling, nothing at the
+    estimate and everything offered in the end."""
     cfg, params, _ = _tiny(preset, family)
     sweep, full = _limits(cfg, params)
     assert sweep[0][1].kept == () and sweep[0][1].why == "no room"
     assert sweep[-1][1] == full._replace(limit=sweep[-1][1].limit)
     seen = set()
+    one_cost = len({n for _, n, _ in llama._stacks(params, cfg)[0]}) == 1
     for (_, less), (limit, more) in zip(sweep, sweep[1:]):
-        assert less.kept_bytes <= more.kept_bytes, (less, more)
+        assert less.charged <= more.charged, (less, more)
+        if one_cost:        # then bytes and charge are one order
+            assert less.kept_bytes <= more.kept_bytes, (less, more)
         assert less.estimate == more.estimate
-        assert more.estimate + llama.KEPT_COST * more.kept_bytes <= \
+        assert more.charged == _charge(cfg, params, more, 2, 64)
+        assert more.estimate + more.charged <= \
             limit * (1 - llama.REMAT_FREE) + 1
         assert len(more.kept) in (0, len(full.kept))
         seen.add(more.kept)
@@ -390,6 +436,8 @@ def test_a_run_keeps_a_name_another_run_of_its_kind_does_not(family, preset,
     assert remat["by_run"].split(",")[first].split("+").count(name) == 1
     assert name not in remat["by_run"].split(",")[later].split("+")
     assert remat["kept_bytes"] == plan.kept_bytes
+    assert remat["charged"] == plan.charged == _charge(cfg, params, plan,
+                                                       2, 64)
     base_loss, base = jax.jit(jax.value_and_grad(
         _loss(family, cfg, batch)))(params)
     assert np.array_equal(np.asarray(loss), np.asarray(base_loss))
@@ -456,6 +504,35 @@ def test_the_kept_program_computes_no_kept_product_twice(family, preset,
     assert count == {0: held, 10**15: 0}
 
 
+@pytest.mark.parametrize("name", ALL)
+def test_a_kept_byte_is_charged_by_the_length_of_its_run(name):
+    """Two configs that differ in ``run_layers`` alone (every layer a run
+    of its own against window, window, window | full twice over) keep the
+    same bytes of ``name`` in the same eight layers and are charged 1.0 a
+    byte in the runs of one against 1.5 in the stacks of three."""
+    base = _tiny("tiny-commanda")[0]
+    charged = {}
+    for most, lengths in ((1, [1] * 8), (3, [3, 1, 3, 1])):
+        cfg = base.replace(run_layers=most)
+        params = jax.eval_shape(
+            lambda cfg=cfg: moe.init_params(jax.random.PRNGKey(3), cfg))
+        assert [n for _, n in moe.layer_runs(cfg)] == lengths
+        plan = llama.remat_plan(cfg, params, 2, 64,
+                                train_step.StepMemory(10**15, 0))
+        assert all(name in run for run in plan.kept), plan
+        assert plan.charged == _charge(cfg, params, plan, 2, 64)
+        a_layer = dict(llama._offers(cfg, "window", 2, 64))[name]
+        assert dict(llama._offers(cfg, "full", 2, 64))[name] == a_layer
+        # every run keeps every name, each at the run's one rate: of the
+        # whole charge, this name's is the share of its bytes
+        charged[most] = plan.charged * 8 * a_layer / plan.kept_bytes
+    assert charged[1] == 8 * a_layer * llama.KEPT_COST_ONE
+    assert charged[3] == 2 * a_layer * llama.KEPT_COST_ONE \
+        + 6 * a_layer * llama.KEPT_COST_STACK
+    assert (llama.KEPT_COST_ONE, llama.KEPT_COST_STACK) == (1.0, 1.5)
+    assert [llama.kept_cost(n) for n in (1, 2, 3, 30)] == [1.0, 1.5, 1.5, 1.5]
+
+
 def test_the_remat_plan_instant_carries_its_fields(monkeypatch):
     from ray_tpu.util import tracing
 
@@ -472,19 +549,22 @@ def test_the_remat_plan_instant_carries_its_fields(monkeypatch):
                    shapes)              # no checkpoint, no plan to say
     plans = [a for n, a in seen if n == "remat.plan"]
     assert [p["why"] for p in plans] == ["no step", "no limit", "room"]
-    assert plans[0] == {"kept": "", "kept_bytes": 0, "runs": "",
-                        "by_run": "", "estimate": 0, "limit": 0,
+    assert plans[0] == {"kept": "", "kept_bytes": 0, "charged": 0,
+                        "runs": "", "by_run": "", "estimate": 0, "limit": 0,
                         "ceiling": 0, "why": "no step"}
     last = plans[2]
     assert last["kept"] == ",".join(ALL)
     # four runs of layers (window, full, window, full): each keeps all five
     assert last["runs"] == ", ".join(f"{n} x4" for n in ALL)
     assert last["by_run"] == ",".join(["+".join(ALL)] * 4)
-    assert set(last) == {"kept", "kept_bytes", "runs", "by_run", "estimate",
-                         "limit", "ceiling", "why"}
+    assert set(last) == {"kept", "kept_bytes", "charged", "runs", "by_run",
+                         "estimate", "limit", "ceiling", "why"}
     assert last["limit"] == 10**15 and last["ceiling"] == int(
         10**15 * (1 - llama.REMAT_FREE))
     assert 0 < last["kept_bytes"] < last["estimate"] < last["ceiling"]
+    # window, window, window, full, twice over: six of the eight layers lie
+    # in a stack of three (1.5 a kept byte), two in a run of one (1.0)
+    assert last["charged"] == last["kept_bytes"] * 11 // 8
 
 
 def test_the_step_hands_the_model_its_state_bytes_and_the_devices_limit(
