@@ -36,7 +36,7 @@ def _refuse_stated(cfg: LlamaConfig):
     stated = [f for f in ("embedding_multiplier", "residual_multiplier",
                           "logits_scaling", "attn_scale")
               if getattr(cfg, f) is not None] + ([] if cfg.rope else ["rope"])
-    if getattr(_family(cfg), "attention_half", None) is not None:
+    if _family(cfg).attention_half is not None:
         stated.append("an attention half of its own")
     if cfg.attn_kinds:
         stated.append("attention layers of several kinds")

@@ -56,8 +56,8 @@ of 5, 1, 9, 1, 9, 1, 9, 1, 4; ``run_layers`` layers a stack at most where
 it is set: a stack of ONE layer is no loop, and its gradients meet the
 optimizer as the backward makes them), each run one ``lax.scan``; every run of a
 kind that keeps the same names across the layer checkpoint
-(``llama.remat_plan``: the step's memory decides run by run) is scanned by
-the same function, traced under ``llama._checkpoint`` (instant
+(``remat.remat_plan``: the step's memory decides run by run) is scanned by
+the same function, traced under ``remat._checkpoint`` (instant
 ``hybrid.layer_plan``).
 
 The mixer, for u = rms_norm(x) [B, S, D], H heads of width P, state N:
@@ -89,7 +89,7 @@ backward's replay of the layer makes their residuals again (from the
 in-projection's product where the plan kept it, ``MIX_OFFERED``). The results
 of a pass go through ``optimization_barrier``: XLA otherwise moves a
 pass's arithmetic into its consumers' fusions, twice where there are two
-(tests/test_tpu_compile.py holds the compiled layer to the account).
+(tests/test_tpu_compile_dense.py holds the compiled layer to the account).
 """
 
 from __future__ import annotations
@@ -216,15 +216,13 @@ _MIXER_LEAVES = {
     "conv": ("mix_norm", "in_proj", "conv_w", "out_proj")}
 
 # what the layer checkpoint keeps beside the layer's input and flash's
-# residuals (llama._checkpoint): the expert layer's routes; of a mixer
+# residuals (remat._checkpoint): the expert layer's routes; of a mixer
 # nothing: its scan runs again, and so does its in-projection unless the
 # step's memory has room for the product (MIX_OFFERED, after a dense
 # SwiGLU's and the shared expert's: 15 ms of replay a GB against 23 and
 # 22, PERF.md 6)
-REMAT_SAVED = _moe.REMAT_SAVED
 MIX_OFFERED = "mix_proj"
 REMAT_OFFERED = _ll.FFN_OFFERED + _moe.SHARED_OFFERED + (MIX_OFFERED,)
-expert_rows = _moe.expert_rows
 post_update = _moe.post_update
 RULE_LEAVES = _moe.RULE_LEAVES
 
@@ -239,7 +237,7 @@ def halves(cfg: HybridConfig, kind) -> Tuple[bool, bool]:
 def routes(cfg: HybridConfig, kind) -> bool:
     """Whether a block of ``kind`` runs an EXPERT layer (its feed-forward,
     and not the dense SwiGLU): it has routes to keep and rows in expert
-    order (``llama._step_estimate``)."""
+    order (``remat._step_estimate``)."""
     return halves(cfg, kind)[1] and not _dense(kind)
 
 
@@ -268,7 +266,7 @@ def remat_offers(cfg: HybridConfig, kind, tokens: int):
 
 def mixer_backward_bytes(cfg: HybridConfig, kind, tokens: int) -> int:
     """Bytes a mixer's backward holds beside its matrices' products and
-    their gradients (``llama._step_estimate`` counts those from the
+    their gradients (``remat._step_estimate`` counts those from the
     leaves): what the replay leaves for the rules (``plan``'s residuals with
     nothing checkpointed: the convolution's input, x, y and z, the steps),
     the scan's state at every chunk's start (float32 [chunks, H, P, N]) and
@@ -784,7 +782,15 @@ def feed_forward(h, lp, cfg: HybridConfig, mesh=None, rules=None, tp=None,
                              kind=kind)
 
 
-finish_loss = _moe.finish_loss
 forward = _ll.forward
 forward_with_stats = _ll.forward_with_stats
 loss_fn = _ll.loss_fn
+
+# what the hybrids supply to the shared layer: the routes kept, the rows in
+# expert order and the router losses are the expert model's
+FAMILY = _moe.FAMILY.replace(
+    "hybrid", feed_forward=feed_forward, remat_offered=REMAT_OFFERED,
+    remat_saved_bytes=remat_saved_bytes, remat_offers=remat_offers,
+    layer_runs=layer_runs, mixer_half=mixer_half,
+    mixer_backward_bytes=mixer_backward_bytes, halves=halves, routes=routes,
+    layer_plan_says=layer_plan_says)

@@ -221,8 +221,6 @@ PRESETS: Dict[str, LatentConfig] = {
 INDEX_SET = "index_set"
 INDEX_GRADS = ("index_grad_q", "index_grad_w", "index_grad_k")
 REMAT_SAVED = _moe.REMAT_SAVED + (INDEX_SET,) + INDEX_GRADS
-REMAT_OFFERED = _moe.REMAT_OFFERED      # (a dense layer offers nothing)
-expert_rows = _moe.expert_rows
 # rows of queries whose index scores are alive at once ([rows, keys]
 # float32), and of those whose per-head products are ([heads, rows, keys])
 SCORE_BLOCK_ROWS = 2048
@@ -261,7 +259,7 @@ def remat_offers(cfg: "LatentConfig", kind, rows: int):
 
 def further_stacks(params, cfg: "LatentConfig"):
     """The prediction module's block, for what counts a step's layers
-    (llama._stacks): a further pass over the same rows."""
+    (remat._stacks): a further pass over the same rows."""
     return [("sparse", cfg.n_mtp, params["mtp"]["block"])] if cfg.n_mtp \
         else []
 RULE_LEAVES = _moe.RULE_LEAVES
@@ -907,3 +905,14 @@ post_update = _moe.post_update
 forward = _ll.forward
 forward_with_stats = _ll.forward_with_stats
 loss_fn = _ll.loss_fn
+
+# what the latent-attention models supply to the shared layer: the names
+# offered (a dense layer offers none of them) and the rows in expert order
+# are the expert model's
+FAMILY = _moe.FAMILY.replace(
+    "latent", feed_forward=feed_forward, remat_saved=REMAT_SAVED,
+    remat_saved_bytes=remat_saved_bytes, remat_offers=remat_offers,
+    layer_runs=layer_runs, attention_half=attention_half,
+    further_losses=further_losses, finish_loss=finish_loss,
+    carried_init=carried_init, hands_on=hands_on,
+    further_stacks=further_stacks)

@@ -27,19 +27,20 @@ import contextlib
 import dataclasses
 import functools
 import math
-import sys
 from dataclasses import dataclass
-from typing import Any, Dict, NamedTuple, Optional, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from ray_tpu.models import remat
+from ray_tpu.models.family import (Family, _family, _halves,
+                                   _takes_attention_half)
 from ray_tpu.parallel.collective_matmul import (allgather_matmul,
                                                 gather_apply_scatter,
                                                 matmul_reduce_scatter,
                                                 overlap_plan)
-from ray_tpu.parallel.train_step import state_bytes, step_memory
 from ray_tpu.util import tracing
 
 
@@ -93,7 +94,7 @@ class LlamaConfig:
     # the attention is banded). Unsupported with ring/ulysses.
     sliding_window: Any = None
     # jax.checkpoint each layer: the backward keeps the layer's input and
-    # rebuilds the rest (HBM savings), but for what _checkpoint names.
+    # rebuilds the rest (HBM savings), but for what remat._checkpoint names.
     remat: bool = True
     # Emit [B, S, vocab] logits in f32 (safe default) or keep them in the
     # compute dtype. With the logsumexp-form CE below, bf16 logits with
@@ -281,298 +282,6 @@ def _embed(params, tokens, dt):
     if isinstance(w, dict) and "q8" in w:
         return w["q8"][tokens].astype(dt) * w["s8"].astype(dt)
     return w.astype(dt)[tokens]
-
-
-def _family(cfg: "LlamaConfig"):
-    """The module that defines ``cfg``'s class: this one for the dense
-    model, ``models/moe.py`` for ``MoEConfig``. It supplies the layer's
-    feed-forward half (``feed_forward``), the names that half wants kept
-    across the layer checkpoint (``REMAT_SAVED``, and what they weigh:
-    ``remat_saved_bytes``), the names its layers offer beyond them where
-    the step's memory has room (``REMAT_OFFERED``: every such name, the
-    dearest replay a byte first; ``remat_offers``: a layer's, by its kind:
-    a dense SwiGLU's gate and up, a shared expert's, a mixer's
-    in-projection) and, where the feed-forward returns statistics,
-    ``finish_loss``; where a layer's first half is no attention, that half
-    (``mixer_half``) and what its backward holds beside its matrices'
-    products (``mixer_backward_bytes``, for the step's estimate); where its
-    blocks hold ONE half, which (``halves``); where its attention
-    is not three projections of the hidden state, the attention half too
-    (``attention_half``); where it predicts further tokens than the next,
-    ``further_losses``; where its attention half hands a value on to the
-    layers after it (a learned selection's set), ``carried_init`` (cfg,
-    batch, seq -> the value before the first layer, None: nothing is
-    carried) and ``hands_on`` (cfg, kind -> whether a layer of the kind
-    replaces the value or only reads it)."""
-    return sys.modules[type(cfg).__module__]
-
-
-def _checkpoint(body, cfg: "LlamaConfig", kept: Tuple[str, ...] = ()):
-    """Per-layer jax.checkpoint. Beside the layer's input it keeps the
-    flash kernel's output and log-sum-exp (FLASH_RESIDUALS: the output is
-    as large as the layer input, B x S x D x 2 bytes a layer in bf16, the
-    log-sum-exp B x H x S x 4), so the backward kernels run from them and
-    the forward kernel runs once, what the family's feed-forward
-    names (REMAT_SAVED: an expert layer's routes) and ``kept``: the names
-    of those the layer offers that the step's memory has room for in THIS
-    run of layers (``remat_plan``: q, k and v as the attention call takes
-    them, a dense or a shared feed-forward's products of x before the
-    activation, a state-space mixer's in-projection); everything else is
-    recomputed, the mixer's scan too (ops/ssd.py). A body that holds no
-    such name (attn_impl other than "flash", under 128 tokens) saves
-    nothing more."""
-    from ray_tpu.ops.flash_attention import FLASH_RESIDUALS
-
-    return jax.checkpoint(
-        body, policy=jax.checkpoint_policies.save_only_these_names(
-            *FLASH_RESIDUALS, *_family(cfg).REMAT_SAVED, *kept))
-
-
-# checkpoint_name tags of what the attention half offers the layer
-# checkpoint where the step's memory has room: q, k and v as the attention
-# call takes them (after the q/k norm and the rotary)
-ATTN_OFFERED = ("attn_q", "attn_k", "attn_v")
-
-# The share of a device's memory that a plan with kept names leaves free:
-# the estimate plus what is kept, each byte at its run's cost (below), stays
-# under 85% of the limit, 14.37e9 of the 16,909,336,064 a v5e chip states
-# (of 16 GiB). The largest step that has run there planned 15.82e9, at
-# 15.38e9 XLA rematerialized on its own (38 ``.remat`` instructions, +166
-# ms a step; PERF.md 6, PR 42), and the estimate may read 0.5e9 under a
-# plan (the LFM2 step's: it plans 14.76e9): 15% keeps all three apart.
-REMAT_FREE = 0.15
-# One layer's backward, in bytes a byte of its products (every matrix of
-# the layer times its rows: a product and its gradient), and in bytes a lane
-# of what stands beside them in float32 and twice over: the rows gathered
-# into expert order and back, forward and backward, and the query heads
-# round the attention call (q under its rotary, dq, the output's gradient).
-# The update of a leaf under adafactor holds four float32 temporaries as
-# large as the leaf (3.4 and 3.8 read on the l8 and OLMoE plans, whose peak
-# it is). All three from the one-chip plans compiled for a described v5e
-# (PERF.md 4 and 6).
-LAYER_BACKWARD = 2.0
-LANE_BYTES = 18
-UPDATE_BYTES = 16
-# What a kept byte is charged, in bytes of plan, by the length of the run
-# that keeps it (``_stacks``' ``n``; read from the one-chip plans compiled
-# for a described v5e, PERF.md 6, PR 43, 51, 53 and 55). A run of ONE layer
-# stacks nothing: the kept product stands once and the replay's own buffer
-# for it goes. Its plans read 0.61 (Nemotron's q, k and v), 0.68 (Command
-# A+'s five names), 0.72 (Nemotron's last four in-projection products),
-# 0.999 (seven of LFM2's: +1.408e9 for 1.409e9), 1.00 (MiniCPM-SALA's gate
-# and up) and 1.02 (Nemotron's shared products and first five
-# in-projections: +2.71e9 for 2.66e9): 1.0 holds the most any read to 2%.
-# A run that scans two layers or more stacks every layer's residuals for
-# its backward scan, and a kept byte has cost 1.58 to 1.64 there (the
-# Mellum2 step, nine of whose twelve layers lie in stacks of three: +3.30e9
-# for 2.01e9 of q, k and v at passes of 49,152 rows, PR 51; +2.55e9 for
-# 1.61e9 of q at 65,536, PR 43); why it is half as much again is unread.
-# A family's further pass (``further_stacks``) is charged by ITS OWN
-# stack's length: ``run`` scans it like any other run, and no compiled plan
-# reads otherwise (the two GLM steps, the only ones with such a pass, have
-# no room and keep nothing; the Mellum2 step has no such pass).
-KEPT_COST_ONE = 1.0
-KEPT_COST_STACK = 1.5
-
-
-def kept_cost(n: int) -> float:
-    """Bytes of plan a byte kept in a run of ``n`` layers is charged."""
-    return KEPT_COST_ONE if n == 1 else KEPT_COST_STACK
-
-
-class RematPlan(NamedTuple):
-    """What the layer checkpoint keeps beyond the parent's list, and why."""
-    # the names each run of layers keeps, one tuple a stack in ``_stacks``'
-    # order, each in the order offered; (): no run keeps any
-    kept: Tuple[Tuple[str, ...], ...]
-    kept_bytes: int         # over all runs and layers
-    estimate: int           # the step's bytes without them; 0: none made
-    limit: int              # the device's; 0: it states none
-    # "room" | "no room" | "no step" | "no limit" | "mesh"
-    why: str
-    # what the rule charged for ``kept_bytes``: each run's at ``kept_cost``
-    charged: int = 0
-
-    def of(self, run: int) -> Tuple[str, ...]:
-        """The names run ``run`` keeps."""
-        return self.kept[run] if self.kept else ()
-
-
-def _stacks(params, cfg: "LlamaConfig"):
-    """([(kind, layers, stack), ...], passes): the stacks of layers a
-    step's forward scans, those of a family's further passes over the same
-    rows last (``further_stacks``), and how many passes that makes."""
-    family = _family(cfg)
-    if isinstance(params["layers"], dict):
-        stacks = [(None, cfg.n_layers, params["layers"])]
-    else:
-        stacks = [(kind, n, stack) for (kind, n), stack in zip(
-            family.layer_runs(cfg), params["layers"])]
-    further = getattr(family, "further_stacks", lambda params, cfg: [])(
-        params, cfg)
-    return stacks + further, 1 + len(further)
-
-
-def _halves(cfg: "LlamaConfig", kind):
-    """What a block of ``kind`` holds, as ``_layer`` runs it: (its first
-    half: "attention" (``_attention_half`` or the family's own: a call of
-    an attention kernel, whose ``o`` and ``lse`` the checkpoint keeps),
-    "mixer" (the family's ``mixer_half``) or None (a block that is its
-    feed-forward alone); whether it runs the feed-forward half). A family
-    whose blocks hold ONE half says which (``halves``)."""
-    family = _family(cfg)
-    first, second = getattr(family, "halves",
-                            lambda cfg, kind: (True, True))(cfg, kind)
-    if not first:
-        return None, second
-    attends = getattr(family, "attention_half", None) is not None \
-        or _takes_attention_half(cfg, kind)
-    return "attention" if attends else "mixer", second
-
-
-def _offered(cfg: "LlamaConfig") -> Tuple[str, ...]:
-    """Every name a layer of ``cfg``'s family may offer, in the order the
-    plan takes them: the dearest replay a byte first."""
-    return ATTN_OFFERED + tuple(_family(cfg).REMAT_OFFERED)
-
-
-def _offers(cfg: "LlamaConfig", kind, batch: int, seq: int):
-    """((name, bytes), ...) a layer of ``kind`` offers the checkpoint, the
-    dearest replay a byte first: 28 ms a GB for q, k and v on the l8 step,
-    23 for a dense SwiGLU's gate and up (MiniCPM-SALA's), 22 for the shared
-    SwiGLU's on Command A+'s, 15 for a mixer's in-projection (Nemotron's)
-    (PERF.md 6)."""
-    family, rows = _family(cfg), batch * seq
-    head = rows * cfg.head_dim * jnp.dtype(cfg.dtype).itemsize
-    attention = tuple(zip(ATTN_OFFERED, (
-        head * cfg.n_heads, head * cfg.n_kv_heads, head * cfg.n_kv_heads))
-    ) if _takes_attention_half(cfg, kind) else ()
-    return attention + tuple(family.remat_offers(cfg, kind, rows))
-
-
-def _step_estimate(cfg: "LlamaConfig", params, stacks, passes: int,
-                   rows: int, state: int) -> int:
-    """The bytes one device holds at the peak of a train step over
-    ``params`` (``_stacks``: its stacks of layers and passes) and ``rows``
-    tokens with the parent's list kept, from shapes alone: the state
-    (the step's own count) plus the larger of
-    - the update: the gradients that wait for it (a stack's update runs
-      when its backward scan ends, so the largest stack's and those of the
-      leaves outside the stacks) and adafactor's float32 temporaries over
-      the largest leaf;
-    - a layer's backward: those gradients, what every layer keeps (its
-      input, the family's REMAT_SAVED and, where its first half is an
-      attention call, flash's ``o`` and ``lse``), the logits of the passes
-      before a further pass's, and one layer's products, gradients and
-      float32 forms;
-    - the head: what every layer keeps, the logits and their gradient.
-    A block is counted for what its kind holds (``_halves``; a family
-    whose feed-forward is no expert layer in every block says in which it
-    is: ``routes``)."""
-    family, item = _family(cfg), jnp.dtype(cfg.dtype).itemsize
-    routes = getattr(family, "routes", lambda cfg, kind: True)
-    expert_rows = getattr(family, "expert_rows", lambda cfg, rows: 0)(
-        cfg, rows)
-    heads = rows * cfg.n_heads * cfg.head_dim
-
-    def products(kind, stack):
-        # a layer's matrices [L, in, out] times the rows, an expert's
-        # [L, E, in, out] times the rows its experts get; beside them the
-        # lanes of the rows in expert order (a block with a feed-forward
-        # half) and of the query heads (one with an attention half), or
-        # what the family says a mixer's backward holds
-        first, second = _halves(cfg, kind)
-        total = 0
-        for w in jax.tree.leaves(stack):
-            if w.ndim == 3:
-                total += rows * w.shape[2] * item
-            elif w.ndim == 4:
-                total += expert_rows * w.shape[3] * item
-        return LAYER_BACKWARD * total + LANE_BYTES * (
-            expert_rows * cfg.d_model * (second and routes(cfg, kind))
-            + heads * (first == "attention")) + (
-                family.mixer_backward_bytes(cfg, kind, rows)
-                if first == "mixer" else 0)
-
-    def keeps(kind):
-        flash = heads * item + rows * cfg.n_heads * 4 \
-            if _halves(cfg, kind)[0] == "attention" else 0
-        return rows * cfg.d_model * item + flash \
-            + family.remat_saved_bytes(cfg, kind, rows)
-
-    saved = sum(n * keeps(kind) for kind, n, _ in stacks)
-    in_stacks = [state_bytes(stack) for _, _, stack in stacks]
-    outside = state_bytes(params) - sum(in_stacks)
-    waiting = outside + max(in_stacks)
-    logits = 2 * rows * cfg.vocab_size * (4 if cfg.f32_logits else item)
-    largest = max(x.size for x in jax.tree.leaves(params))
-    return int(state + max(
-        waiting + UPDATE_BYTES * largest,
-        waiting + saved + (passes - 1) * logits
-        + max(products(kind, stack) for kind, _, stack in stacks),
-        saved + passes * logits + outside))
-
-
-def remat_plan(cfg: "LlamaConfig", params, batch: int, seq: int, memory,
-               mesh=None) -> RematPlan:
-    """Which of the names its layers offer the layer checkpoint keeps in
-    each run of layers of a step over ``params`` (arrays or shapes) and
-    [batch, seq] tokens: a pure function of shapes and of ``memory``
-    (parallel.train_step.StepMemory: the device's limit and the state's
-    bytes as the step counts them; None outside a train step). The run is
-    the unit: names are taken in the order offered (q, k and v, then the
-    family's ``REMAT_OFFERED``), each name in the runs that offer it,
-    earliest run first, while the estimate plus what is kept, each run's
-    bytes charged by the run's length (``kept_cost``: 1.0 a byte in a run
-    of one layer, 1.5 in a stack), stays under the limit less its free
-    share (REMAT_FREE); a name that does not fit a run is passed over for
-    the next run and the next name. With no limit
-    (the CPU) or no step nothing more is kept than the parent's list; under
-    a mesh of several devices neither: the activations' share of a device
-    is not counted here."""
-    if memory is None or not memory.limit:
-        return RematPlan((), 0, 0, 0, "no step" if memory is None
-                         else "no limit")
-    if mesh is not None and mesh.size > 1:
-        return RematPlan((), 0, 0, memory.limit, "mesh")
-    stacks, passes = _stacks(params, cfg)
-    offers = [dict(_offers(cfg, kind, batch, seq)) for kind, _, _ in stacks]
-    estimate = _step_estimate(cfg, params, stacks, passes, batch * seq,
-                              memory.state)
-    ceiling = memory.limit * (1 - REMAT_FREE)
-    kept, total, charged = [[] for _ in stacks], 0, 0.0
-    for name in _offered(cfg):
-        for run, (_, n, _) in enumerate(stacks):
-            nbytes = n * offers[run].get(name, 0)
-            cost = kept_cost(n) * nbytes
-            if nbytes and estimate + charged + cost <= ceiling:
-                kept[run].append(name)
-                total += nbytes
-                charged += cost
-    if not total:
-        return RematPlan((), 0, estimate, memory.limit, "no room")
-    return RematPlan(tuple(map(tuple, kept)), total, estimate, memory.limit,
-                     "room", int(charged))
-
-
-def _say_remat_plan(plan: RematPlan, cfg: "LlamaConfig"):
-    """The instant ``remat.plan`` of a trace, once a traced forward under
-    the layer checkpoint: the names kept beyond the parent's list by run
-    (``kept``: every name some run keeps, in the order offered; ``runs``:
-    "name xN, ..." with N the runs that keep it; ``by_run``: the runs'
-    names in the layers' order, "+" between a run's, "-" for none), their
-    bytes, what the rule charged for them (``charged``: a run's bytes at
-    ``kept_cost``; the compiled plan's growth is the chip's answer), the
-    estimate they were added to and the limit."""
-    names = [n for n in _offered(cfg) if any(n in run for run in plan.kept)]
-    tracing.plan("remat.plan", {
-        "kept": ",".join(names), "kept_bytes": plan.kept_bytes,
-        "charged": plan.charged,
-        "runs": ", ".join(f"{n} x{sum(n in run for run in plan.kept)}"
-                          for n in names),
-        "by_run": ",".join("+".join(run) or "-" for run in plan.kept),
-        "estimate": plan.estimate, "limit": plan.limit,
-        "ceiling": int(plan.limit * (1 - REMAT_FREE)), "why": plan.why})
 
 
 def rms_norm(x, scale, eps):
@@ -819,9 +528,9 @@ def _attention_half(x, lp, cfg: LlamaConfig, cos, sin, mesh=None, rules=None,
             q = apply_rope(q, cos, sin)
             k = apply_rope(k, cos, sin)
         # as the attention call takes them: kept across the layer
-        # checkpoint where the step's memory has room (``remat_plan``)
+        # checkpoint where the step's memory has room (``remat.remat_plan``)
         q, k, v = (checkpoint_name(t, name)
-                   for t, name in zip((q, k, v), ATTN_OFFERED))
+                   for t, name in zip((q, k, v), remat.ATTN_OFFERED))
     else:
         def in_heads(i, y, shard, cos, sin):
             # one shard's rows of q (0), k (1) or v (2), as its matmul
@@ -853,13 +562,11 @@ def _residual(x, y, cfg: LlamaConfig):
     return x + y if by is None else x + (y * by).astype(x.dtype)
 
 
-# checkpoint_name tags the dense feed-forward wants kept across the layer
-# checkpoint: none (see _family); and what it offers where the step's memory
-# has room (``remat_plan``): the SwiGLU's two products of x, before the
+# checkpoint_name tags of what the dense feed-forward offers the layer
+# checkpoint where the step's memory has room (``remat.remat_plan``; it wants
+# none kept otherwise): the SwiGLU's two products of x, before the
 # activation, as ``moe.SHARED_OFFERED`` for a shared expert
-REMAT_SAVED = ()
 FFN_OFFERED = ("ffn_gate", "ffn_up")
-REMAT_OFFERED = FFN_OFFERED
 
 
 def remat_saved_bytes(cfg: "LlamaConfig", kind, rows: int) -> int:
@@ -894,22 +601,13 @@ def feed_forward(h, lp, cfg: LlamaConfig, mesh=None, rules=None, tp=None,
     return (jax.nn.silu(gate) * up) @ _dq(lp["w_down"], dt), None
 
 
-def _takes_attention_half(cfg: LlamaConfig, kind) -> bool:
-    """Whether a layer of ``kind`` runs ``_attention_half``: the one kind
-    of a model that names none, a hybrid's "attention" (what follows a "."
-    is its feed-forward's: "attention.dense"), a named kind."""
-    return (getattr(_family(cfg), "attention_half", None) is None
-            and (kind is None or kind.split(".")[0] == "attention"
-                 or kind in dict(cfg.attn_kinds)))
-
-
 def _layer(x, lp, cfg: LlamaConfig, cos, sin, mesh=None, rules=None, tp=None,
            kind=None, carried=None):
     """One transformer block: the attention half, then the family's
     feed-forward half (dense SwiGLU here, the expert layer for a
     MoEConfig). x: [B, S, D]. Returns (x, stats, carried): stats is what
     the halves report (None where neither does: the dense one), ``carried``
-    what a family's attention half hands on to the next layer (``_family``;
+    what a family's attention half hands on to the next layer (``Family``;
     None in, None out). ``kind``: None or
     "attention" for the attention half, or a kind of attention layer the
     config names (``attn_kinds``: the same half with that kind's window,
@@ -926,7 +624,8 @@ def _layer(x, lp, cfg: LlamaConfig, cos, sin, mesh=None, rules=None, tp=None,
     if cfg.parallel_block:
         return _parallel_layer(x, lp, cfg, cos, sin, mesh, rules, tp,
                                kind) + (carried,)
-    own = getattr(_family(cfg), "attention_half", None)
+    family = _family(cfg)
+    own = family.attention_half
     named = kind in dict(cfg.attn_kinds)
     said = None
     # ``first`` None: a block that is its feed-forward alone
@@ -944,12 +643,12 @@ def _layer(x, lp, cfg: LlamaConfig, cos, sin, mesh=None, rules=None, tp=None,
                                 tp=tp, kind=kind)
     elif first == "mixer":
         with jax.named_scope("mixer"):
-            x = _family(cfg).mixer_half(x, lp, cfg, kind, mesh=mesh)
+            x = family.mixer_half(x, lp, cfg, kind, mesh=mesh)
     stats = None
     if second:
         with jax.named_scope("feed_forward"):
             h = _norm(x, lp["ffn_norm"], cfg)
-            y, stats = _family(cfg).feed_forward(
+            y, stats = family.feed_forward(
                 h, lp, cfg, mesh=mesh, rules=rules, tp=tp, kind=kind)
             x = _residual(x, y, cfg)
     if said is not None:
@@ -1121,7 +820,7 @@ def _forward(params, tokens, cfg: LlamaConfig, pos_offset=0, mesh=None,
     tables and the shardings this forward used, the plan).
 
     What a family's attention half hands on to the layers after it
-    (``_family``: ``carried_init``, ``hands_on``) travels beside x through
+    (``Family``: ``carried_init``, ``hands_on``) travels beside x through
     the runs and across the layer checkpoint, by one mechanism for any
     family; a family that hands nothing on carries x alone.
 
@@ -1129,7 +828,7 @@ def _forward(params, tokens, cfg: LlamaConfig, pos_offset=0, mesh=None,
     body, or, for a family with layers of several kinds (``layer_runs``:
     models/hybrid.py), a list of stacks, one a run of adjacent layers of
     one kind: each run is scanned by its kind's body, traced from ONE
-    function a kind and set of names its runs keep (``remat_plan``)
+    function a kind and set of names its runs keep (``remat.remat_plan``)
     whatever the depth. Such a family may also scale the
     embedding (``embedding_multiplier``), do without rotary tables
     (``rope`` False), tie the head to the embedding (no ``lm_head``) and
@@ -1159,15 +858,13 @@ def _forward(params, tokens, cfg: LlamaConfig, pos_offset=0, mesh=None,
                 jax.lax.dynamic_slice_in_dim(t, pos_offset, S, axis=0)
                 for t in _kind_tables(cfg, of, cfg.max_seq_len))
 
-    plan = None
-    if cfg.remat:
-        plan = remat_plan(cfg, params, B, S, step_memory(), mesh)
-        _say_remat_plan(plan, cfg)
+    plan = remat.plan_for_step(cfg, params, B, S, mesh) if cfg.remat \
+        else None
 
     family = _family(cfg)
     # what the attention halves hand from layer to layer beside x (None:
     # nothing, and the scans carry x alone)
-    held = getattr(family, "carried_init", lambda *_: None)(cfg, B, S)
+    held = family.carried_init(cfg, B, S) if family.carried_init else None
 
     @functools.cache
     def body_of(kind, kept):
@@ -1182,7 +879,7 @@ def _forward(params, tokens, cfg: LlamaConfig, pos_offset=0, mesh=None,
                                     carried=held)
             return con(y), held, stats
 
-        return _checkpoint(body, cfg, kept) if cfg.remat else body
+        return remat._checkpoint(body, cfg, kept) if cfg.remat else body
 
     @functools.cache
     def step_of(kind, kept, carries: bool):
@@ -1245,8 +942,9 @@ def _forward(params, tokens, cfg: LlamaConfig, pos_offset=0, mesh=None,
                 stats.append(s)
         with jax.named_scope("layers"):
             stats = _join_stats(stats)
-        _say_layer_plan(runs, body_of.cache_info().currsize, getattr(
-            family, "layer_plan_says", lambda *_: None)(cfg, runs, plan))
+        _say_layer_plan(runs, body_of.cache_info().currsize,
+                        family.layer_plan_says(cfg, runs, plan)
+                        if family.layer_plan_says else None)
     if mesh is not None and rules is not None:
         _say_tp_plan(tp, cfg, B, S)
     if cfg.parallel_block:
@@ -1304,7 +1002,7 @@ def forward_pp(params, tokens, cfg: LlamaConfig, mesh, num_microbatches=None):
             return _layer(x, lp, cfg, cos, sin)[0], None
 
         if cfg.remat:
-            body = _checkpoint(body, cfg)
+            body = remat._checkpoint(body, cfg)
         x, _ = jax.lax.scan(body, x, stage_layers)
         return x
 
@@ -1324,7 +1022,7 @@ def loss_fn(params, batch, cfg: LlamaConfig, mesh=None, rules=None):
     ``further_losses`` (multi-token prediction, models/latent.py) takes
     {"tokens": [B, S+1+n]}: n more ids a sequence, the targets of its
     further passes over the same S positions."""
-    further = getattr(_family(cfg), "further_losses", None)
+    further = _family(cfg).further_losses
     if further is not None:
         if set(batch) != {"tokens"}:
             raise ValueError("a model that predicts further tokens takes "
@@ -1349,7 +1047,7 @@ def loss_fn(params, batch, cfg: LlamaConfig, mesh=None, rules=None):
     else:
         logits, stats, hidden, run, plan = _forward(
             params, inputs, cfg, mesh=mesh, rules=rules)
-    finish = getattr(_family(cfg), "finish_loss", None)
+    finish = _family(cfg).finish_loss
     if finish is not None and stats is None:
         raise ValueError("a model whose loss needs its layers' statistics "
                          "(router losses) does not train under sp or pp")
@@ -1386,3 +1084,10 @@ def cross_entropy(logits, targets, mask=None):
         return nll.mean()
     mask = mask.astype(nll.dtype)
     return (nll * mask).sum() / jnp.maximum(mask.sum(), 1.0)
+
+
+# what the dense model supplies to the shared layer (models/family.py)
+FAMILY = Family(
+    "llama", feed_forward=feed_forward, remat_saved=(),
+    remat_offered=FFN_OFFERED, remat_saved_bytes=remat_saved_bytes,
+    remat_offers=remat_offers)

@@ -27,7 +27,7 @@ weights, sort order and its inverse, group sizes: 36 bytes a token and
 layer at K 8); of what is T*K rows wide the backward recomputes the rows
 in expert order, gate, up and their SwiGLU, and not the down projection
 (``_down_combine``; the numbers: PERF.md section 6, PR 26). Where the step's
-memory has room (``llama.remat_plan``) the shared SwiGLU's two products of x
+memory has room (``remat.remat_plan``) the shared SwiGLU's two products of x
 are kept as well, run of layers by run (SHARED_OFFERED, ``remat_offers``:
 what a layer offers and what each name weighs), and the replay of a run
 that keeps them runs neither a second time.
@@ -74,6 +74,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.models import llama as _ll
+from ray_tpu.models.family import Family
 from ray_tpu.ops.grouped_matmul import GMM_TILING, grouped_matmul, tiles
 from ray_tpu.util import tracing
 
@@ -219,13 +220,12 @@ PRESETS: Dict[str, MoEConfig] = {
             ("full", _ll.AttentionKind(rope=False)))),
 }
 
-# what the layer checkpoint keeps of an expert layer (llama._checkpoint)
+# what the layer checkpoint keeps of an expert layer (remat._checkpoint)
 REMAT_SAVED = ("moe_route",)
-# what it keeps besides where the step's memory has room (llama.remat_plan):
+# what it keeps besides where the step's memory has room (remat.remat_plan):
 # the shared SwiGLU's two products of x, before the activation. silu(gate)
 # x up is not offered: it is elementwise work from the two
 SHARED_OFFERED = ("shared_gate", "shared_up")
-REMAT_OFFERED = SHARED_OFFERED
 # leaves that no gradient reaches and ``post_update`` moves: the optimizer
 # is told to leave them alone (parallel.train_step.hold_out)
 RULE_LEAVES = ("router_bias",)
@@ -963,3 +963,10 @@ def post_update(params, aux, cfg: MoEConfig):
 forward = _ll.forward
 forward_with_stats = _ll.forward_with_stats
 loss_fn = _ll.loss_fn
+
+# what an expert model supplies to the shared layer (models/family.py)
+FAMILY = Family(
+    "moe", feed_forward=feed_forward, remat_saved=REMAT_SAVED,
+    remat_offered=SHARED_OFFERED, remat_saved_bytes=remat_saved_bytes,
+    remat_offers=remat_offers, layer_runs=layer_runs,
+    finish_loss=finish_loss, expert_rows=expert_rows)

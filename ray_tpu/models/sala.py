@@ -85,12 +85,11 @@ from ray_tpu.util import tracing
 
 KINDS = ("sparse", "lightning")
 # the checkpoint_name tag of a sparse layer's set, kept across the layer
-# checkpoint (llama._checkpoint) so that the replay selects nothing
+# checkpoint (remat._checkpoint) so that the replay selects nothing; beyond
+# it, where the step's memory has room (remat.remat_plan), the dense SwiGLU's
+# gate and up, of either kind of layer (``FAMILY``: llama's)
 BLOCK_SET = "block_set"
 REMAT_SAVED = (BLOCK_SET,)
-# beyond it, where the step's memory has room (llama.remat_plan): the dense
-# SwiGLU's gate and up, of either kind of layer
-REMAT_OFFERED = _ll.REMAT_OFFERED
 # queries a block of the selection scores at once
 SELECT_ROWS = 1024
 # steps a chunk of the lightning layers' scan (the sequence where it is
@@ -185,9 +184,6 @@ def remat_saved_bytes(cfg: SalaConfig, kind, rows: int) -> int:
     sequences of the same total hold less)."""
     return rows * cfg.n_kv_heads * (rows // cfg.sparse_block) \
         if kind == "sparse" and rows > cfg.dense_len else 0
-
-
-remat_offers = _ll.remat_offers
 
 
 def param_specs(cfg: SalaConfig) -> Dict[str, Any]:
@@ -504,7 +500,13 @@ def finish_loss(loss, stats, cfg: SalaConfig):
     return loss, aux
 
 
-feed_forward = _ll.feed_forward
 forward = _ll.forward
 forward_with_stats = _ll.forward_with_stats
 loss_fn = _ll.loss_fn
+
+# what the family supplies to the shared layer: the feed-forward and what
+# it offers the layer checkpoint are the dense model's
+FAMILY = _ll.FAMILY.replace(
+    "sala", remat_saved=REMAT_SAVED, remat_saved_bytes=remat_saved_bytes,
+    layer_runs=layer_runs, attention_half=attention_half,
+    finish_loss=finish_loss)

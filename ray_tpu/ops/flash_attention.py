@@ -104,7 +104,7 @@ q.dtype: as large as the layer input a per-layer checkpoint already keeps)
 and the log-sum-exp `lse`, held compact as [B, H, S] f32 (the kernel writes
 it lane-broadcast over 128 lanes, twice the size of `o`; the backward
 broadcasts it back). A `jax.checkpoint` whose policy saves those names
-(models/llama.py::_checkpoint) runs the backward kernels from them; one
+(models/remat.py::_checkpoint) runs the backward kernels from them; one
 that does not runs the forward kernel a second time, a launch over S^2,
 only to rebuild them. q, k and v carry no name: they are rebuilt from the
 layer input by their projections.

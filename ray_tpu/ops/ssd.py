@@ -66,7 +66,7 @@ kernel makes ``exp(a (t - s))`` from two iotas, and no [B, S, H] array, no
 second layout and no gradient of a step or a rate exists on either path.
 
 Across a layer checkpoint nothing of the scan is kept, so nothing of it
-is named for ``llama._checkpoint``: its output is as large as two layer inputs and the states as
+is named for ``remat._checkpoint``: its output is as large as two layer inputs and the states as
 four, so the backward's recomputation of the layer runs the forward call
 again (the numbers: PERF.md section 6, PR 32). What both paths take in
 place of x, dt and A (``_prologue``: u = dt x, the running sums in two

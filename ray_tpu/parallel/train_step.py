@@ -37,7 +37,7 @@ class TrainState(NamedTuple):
 class StepMemory(NamedTuple):
     """What a train step knows of a device's memory while its loss is
     traced, for a model that decides from the bytes left what its layer
-    checkpoint keeps (models/llama.py ``remat_plan``): shapes and the
+    checkpoint keeps (models/remat.py ``remat_plan``): shapes and the
     device's own limit, never what the process happens to hold."""
     limit: int      # the device's ``bytes_limit``; 0 where it states none
     state: int      # a device's share of the parameters and optimizer state

@@ -14,7 +14,7 @@ import sys
 os.environ["JAX_PLATFORMS"] = "cpu"
 # libtpu retries the GCP instance-metadata server for minutes when it is
 # unreachable (sleep loops that even swallow SIGINT) — describing a TPU
-# topology (tests/test_tpu_compile.py) would hang the whole suite.
+# topology (tests/described_tpu.py) would hang the whole suite.
 # Off-GCP there is nothing to fetch; skip the queries outright.
 os.environ.setdefault("TPU_SKIP_MDS_QUERY", "true")
 # Telemetry ships in one batched report per interval (observability/agent.py).
@@ -35,6 +35,11 @@ if "xla_force_host_platform_device_count" not in _flags:
         _flags + " --xla_force_host_platform_device_count=8").strip()
 
 import pytest  # noqa: E402
+
+# the fixtures of the compiles for a described TPU (tests/test_tpu_compile_*):
+# registered here for every file, built only in a file that asks for one
+from described_tpu import (no_persistent_cache, on_chip_branch,  # noqa: E402,F401
+                           one_chip, topo)
 
 
 def pytest_configure(config):

@@ -1,0 +1,142 @@
+"""Is a cell's step program still its parent's? A digest of what is lowered.
+
+    python tests/lowered_cells.py [--repo DIR] [--texts DIR] [cell ...]
+
+lowers the train step of every cell of ``BENCHMARK.json`` (or of those
+named) for a described v5e, by the cell's own files and nothing compiled,
+and prints ``cell sha256[:16]`` of the StableHLO with every location
+stripped: the text's own and those inside the Mosaic calls' bodies (base64
+MLIR bytecode that carries file names and line numbers, decoded and printed
+without them). Run it on an unpacked parent (``git archive <commit> | tar -x
+-C DIR``, then ``--repo DIR``) and on the tree, and ``diff`` the two
+outputs: a refactor that means to change no program changes no line
+(``--texts`` keeps the texts, for the diff when one does). About 70 s for
+the eleven cells; needs libtpu, no chip.
+"""
+
+import argparse
+import base64
+import hashlib
+import importlib
+import json
+import os
+import re
+import sys
+
+# kind of cell -> (config module of the benchmark, its function, family)
+KINDS = {
+    "train": ("model", "llama_config", "llama"),
+    "train_moe": ("model_moe", "moe_config", "moe"),
+    "train_hybrid": ("model_granite", "hybrid_config", "hybrid"),
+    "train_latent": ("model_glm", "latent_config", "latent"),
+    "train_mixed": ("model_mellum", "moe_config", "moe"),
+    "train_parallel": ("model_commanda", "moe_config", "moe"),
+    "train_sparse": ("model_glm52", "latent_config", "latent"),
+    "train_alternating": ("model_nemotron", "hybrid_config", "hybrid"),
+    "train_blockset": ("model_sala", "sala_config", "sala"),
+    "train_shortconv": ("model_lfm2", "hybrid_config", "hybrid"),
+}
+V5E_LIMIT = 16_909_336_064        # what a v5e chip states (here none does)
+
+
+def lowered(name, topo):
+    """The StableHLO of the cell's train step, lowered for ``topo``'s
+    devices with the rule leaves held out and the rule applied, as the
+    cell's kind runs it."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    from benchmark import resolve
+    from ray_tpu.parallel import (MeshSpec, ShardingRules, build_mesh,
+                                  train_step)
+
+    cell = resolve.cell(name)
+    recipe, mix = cell["train"], cell["mix"]
+    module, make, family = KINDS[cell["kind"]]
+    cfg = getattr(importlib.import_module(f"benchmark.{module}"), make)(
+        cell["config"], **{k: recipe[k] for k in (
+            "attn_impl", "gmm_impl", "ssd_impl", "remat", "f32_logits")
+            if k in recipe})
+    fam = importlib.import_module(f"ray_tpu.models.{family}")
+    mesh = build_mesh(MeshSpec(**recipe["mesh"]),
+                      devices=topo.devices[:cell.get("chips", 1)])
+    rules = getattr(ShardingRules, recipe["rules"])()
+    opt, more = optax.adafactor(recipe["lr"]), {}
+    if hasattr(fam, "RULE_LEAVES"):
+        opt = train_step.hold_out(opt, fam.RULE_LEAVES)
+        more = {"post_update": lambda p, aux: fam.post_update(p, aux, cfg)}
+
+    def placed(shapes, shardings):
+        return jax.tree.map(lambda s, sh: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=sh), shapes, shardings)
+
+    init_fn, state_sh = train_step.make_train_state_init(
+        lambda k: fam.init_params(k, cfg), opt, mesh, rules,
+        fam.param_specs(cfg))
+    state = placed(jax.eval_shape(init_fn, jax.random.PRNGKey(0)), state_sh)
+    shape = {"tokens": jax.ShapeDtypeStruct(
+        (mix["batch"], mix["seq"] + getattr(cfg, "n_mtp", 0) + 1), jnp.int32)}
+    batch = placed(shape, train_step.batch_sharding(mesh, rules, shape))
+    return train_step.make_train_step(
+        lambda p, b: fam.loss_fn(p, b, cfg, mesh=mesh, rules=rules), opt,
+        mesh, rules, state_sh, batch_shapes=shape, **more).lower(
+            state, batch).as_text()
+
+
+def without_locations(text):
+    """``text`` with its ``loc(...)`` gone and every Mosaic call's body
+    replaced by the digest of its module printed without debug info."""
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+
+    def body(match):
+        ctx = mlir.make_ir_context()
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            asm = ir.Module.parse(base64.b64decode(match.group(2))) \
+                .operation.get_asm(enable_debug_info=False)
+        return match.group(1) + hashlib.sha256(asm.encode()).hexdigest() \
+            + match.group(3)
+
+    text = re.sub(r" loc\(.*?\)$", "", text, flags=re.M)
+    return re.sub(r'(\\22body\\22: \\22)([A-Za-z0-9+/=]+)(\\22)', body, text)
+
+
+def main():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    ap.add_argument("--repo", default=os.path.join(os.path.dirname(
+        os.path.abspath(__file__)), os.pardir))
+    ap.add_argument("--texts", help="a directory to keep the texts in")
+    ap.add_argument("cells", nargs="*")
+    args = ap.parse_args()
+    repo = os.path.abspath(args.repo)
+    os.chdir(repo)
+    sys.path.insert(0, repo)
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    os.environ.setdefault("TPU_SKIP_MDS_QUERY", "true")
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
+    from jax.experimental import topologies
+
+    from ray_tpu.parallel import train_step
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    jax.default_backend = lambda: "tpu"         # the kernels' chip branch
+    train_step.device_bytes_limit = lambda mesh: V5E_LIMIT
+    with open("BENCHMARK.json") as f:
+        cells = args.cells or [w["name"] for w in json.load(f)["workloads"]]
+    for name in cells:
+        text = without_locations(lowered(name, topo))
+        if args.texts:
+            os.makedirs(args.texts, exist_ok=True)
+            with open(os.path.join(args.texts, name + ".mlir"), "w") as f:
+                f.write(text)
+        print(name, hashlib.sha256(text.encode()).hexdigest()[:16],
+              flush=True)
+
+
+if __name__ == "__main__":
+    main()

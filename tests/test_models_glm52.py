@@ -327,7 +327,8 @@ def _wrong(name, cfg, params):
         # the second full layer's set is handed to nobody: the layer after
         # it attends over the first's
         return cfg, params, mock.patch.object(
-            latent, "hands_on", lambda c, kind: kind == "dense.full")
+            latent, "FAMILY", latent.FAMILY.replace(
+                "latent", hands_on=lambda c, kind: kind == "dense.full"))
     if name == "no relu":
         return cfg, params, mock.patch.object(jax.nn, "relu", lambda x: x)
     if name == "rotary on the last index lanes":

@@ -219,14 +219,15 @@ def test_the_mixers_gradient_rules_against_jax_on_the_plain_formulas(
 def test_the_model_under_the_layer_checkpoint_with_rules_and_without(
         monkeypatch):
     """The whole ``tiny`` model's loss gradient, every layer under
-    ``llama._checkpoint``: the rules against the plain formulas patched in
+    ``remat._checkpoint``: the rules against the plain formulas patched in
     for ``mixer_half``."""
     cfg = tiny(remat=True)
     params, tokens = make(cfg)
     grad = lambda: jax.value_and_grad(                        # noqa: E731
         lambda p: hybrid.loss_fn(p, {"tokens": tokens}, cfg)[0])(params)
     got, g_grads = grad()
-    monkeypatch.setattr(hybrid, "mixer_half", plain_mixer)
+    monkeypatch.setattr(hybrid, "FAMILY", hybrid.FAMILY.replace(
+        "hybrid", mixer_half=plain_mixer))
     want, w_grads = grad()
     assert abs(float(got) - float(want)) < 1e-5
     flat = lambda t: jax.tree.leaves_with_path(t)   # noqa: E731

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import hybrid, llama, reference_lfm2, registry
+from ray_tpu.models import hybrid, llama, reference_lfm2, registry, remat
 
 ROOT = os.path.join(os.path.dirname(os.path.abspath(__file__)), os.pardir)
 CONV = ("mix_norm", "in_proj", "conv_w", "out_proj")
@@ -444,7 +444,7 @@ def test_plan_instants_carry_the_runs_the_dense_layers_and_the_taps(
 
 
 def test_the_plan_counts_a_dense_layer_for_what_it_holds():
-    """``llama.remat_plan`` on the tiny model with a limit that has room:
+    """``remat.remat_plan`` on the tiny model with a limit that has room:
     a dense conv layer offers its SwiGLU's gate and up and its
     in-projection, a sparse conv layer its in-projection alone (no shared
     expert), an attention layer q, k and v; a dense layer keeps no routes
@@ -464,7 +464,7 @@ def test_the_plan_counts_a_dense_layer_for_what_it_holds():
     assert not hybrid.routes(cfg, "conv.dense") and hybrid.routes(cfg, "conv")
     params = jax.eval_shape(lambda: hybrid.init_params(
         jax.random.PRNGKey(0), cfg))
-    plan = llama.remat_plan(cfg, params, 2, 32, StepMemory(10 ** 9, 10 ** 6))
+    plan = remat.remat_plan(cfg, params, 2, 32, StepMemory(10 ** 9, 10 ** 6))
     assert plan.why == "room"
     assert plan.kept == (
         ("ffn_gate", "ffn_up", "mix_proj"), ("mix_proj",),

@@ -476,8 +476,9 @@ def test_dkdv_plan_bytes_at_the_benchmark_shape():
     plan = fa.bwd_dkdv_plan(dtype=jnp.bfloat16, groups=1, vmem_bytes=128 * 2 ** 20,
                             **shape, **mask)
     assert plan["path"] == "resident"
-    # Mosaic planned 14 MiB for this call (tests/test_tpu_compile.py holds
-    # that the limit suffices); a quarter of a v5e's VMEM is the budget
+    # Mosaic planned 14 MiB for this call (tests/test_tpu_compile_dense.py,
+    # ``FLASH_WIDTHS``, holds that the limit suffices); a quarter of a v5e's
+    # VMEM is the budget
     assert 14 * 2 ** 20 <= plan["resident_bytes"] <= 32 * 2 ** 20
     assert plan["resident_bytes"] <= plan["vmem_limit_bytes"] <= 32 * 2 ** 20
     assert plan["hbm_bytes_per_head"] == 9 * 2 ** 20 <= 10e6

@@ -1,5 +1,5 @@
 """What the layer checkpoint keeps is a plan made from bytes
-(``llama.remat_plan``): the names a layer offers, what each weighs, and what
+(``remat.remat_plan``): the names a layer offers, what each weighs, and what
 the step's memory leaves (``parallel.train_step.StepMemory``). Everything
 here runs from shapes or at the tiny presets' sizes, on the CPU, where no
 device states a limit: a test hands the plan one."""
@@ -13,12 +13,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import hybrid, latent, llama, moe, sala
+from ray_tpu.models import hybrid, latent, llama, moe, remat, sala
 from ray_tpu.parallel import train_step
 
 ROOT = os.path.join(os.path.dirname(os.path.abspath(__file__)), os.pardir)
 V5E = 16_909_336_064        # a v5e chip's ``bytes_limit`` (of 16 GiB)
-ALL = llama.ATTN_OFFERED + moe.SHARED_OFFERED
+ALL = remat.ATTN_OFFERED + moe.SHARED_OFFERED
 MIX = (hybrid.MIX_OFFERED,)
 
 # cell -> (the plan its step compiled to with the parent's list kept, bytes
@@ -29,14 +29,14 @@ MIX = (hybrid.MIX_OFFERED,)
 CELLS = {
     "train-commandaplus-ep16-s8192-b1": (9_280_733_184, (ALL,) * 4),
     "train-mellum2-ep4-s16384-b1": (11_246_880_768,
-                                    (llama.ATTN_OFFERED,) * 6),
+                                    (remat.ATTN_OFFERED,) * 6),
     # MEMEM*EMEMEM*EMEMEM*: q, k and v in the three attention blocks, the
     # shared expert's up product in all eight expert blocks, the
     # in-projection's product in all nine mixers (the first five until a
     # run of one layer was charged 1.0 a kept byte, PR 55)
     "train-nemotron3nano-ep8-s8192-b2": (9_556_182_016, tuple(
         {"M": MIX, "E": moe.SHARED_OFFERED[1:],
-         "*": llama.ATTN_OFFERED}[c] for c in "MEMEM*EMEMEM*EMEMEM*")),
+         "*": remat.ATTN_OFFERED}[c] for c in "MEMEM*EMEMEM*EMEMEM*")),
     # DD*ccc*ccc*ccc*ccc*cc*cc, 24 runs of one layer (the plan of PR 54's
     # step, 13,349,467,136 with 2,952,790,016 of names kept, less those):
     # gate, up and the in-projection's product in both dense layers, q, k
@@ -44,7 +44,7 @@ CELLS = {
     # first twelve of the sixteen sparse convolution layers (five at 1.5)
     "train-lfm2-ep4-s16384-b1": (13_349_467_136 - 2_952_790_016, tuple(
         {"D": llama.FFN_OFFERED + MIX, "c": MIX, "-": (),
-         "*": llama.ATTN_OFFERED}[c] for c in "DD*ccc*ccc*ccc*ccc*--*--")),
+         "*": remat.ATTN_OFFERED}[c] for c in "DD*ccc*ccc*ccc*ccc*--*--")),
     # (PR 52's step: the sparse layer, a run of its own, keeps its SwiGLU's
     # gate and up; the three lightning layers' stack has no room for them)
     "train-minicpmsala-l4-s16384-b1": (12_696_442_368,
@@ -75,9 +75,9 @@ def _charge(cfg, params, plan, batch, seq):
     """The sum the rule makes: every run's kept bytes at the cost of the
     run's length (1.0 a byte in a run of one layer, 1.5 in a stack)."""
     total = 0
-    for (kind, n, _), run in zip(llama._stacks(params, cfg)[0], plan.kept):
-        offers = dict(llama._offers(cfg, kind, batch, seq))
-        total += llama.kept_cost(n) * n * sum(offers[name] for name in run)
+    for (kind, n, _), run in zip(remat._stacks(params, cfg)[0], plan.kept):
+        offers = dict(remat._offers(cfg, kind, batch, seq))
+        total += remat.kept_cost(n) * n * sum(offers[name] for name in run)
     return total
 
 
@@ -115,7 +115,7 @@ def cell_plans():
         seq = mix["seq"] + getattr(cfg, "n_mtp", 0)
 
         def plan(limit, mesh=None):
-            return llama.remat_plan(cfg, state.params, mix["batch"], seq,
+            return remat.remat_plan(cfg, state.params, mix["batch"], seq,
                                     train_step.StepMemory(limit, held), mesh)
 
         return (plan(V5E), plan(V5E, mesh),
@@ -153,13 +153,13 @@ def test_a_cell_keeps_the_names_it_has_room_for(name, cell_plans):
     assert meshed.kept == want, meshed
     assert meshed.limit == V5E
     if name == "train-deepseek7b-fsdp2tp2":
-        assert meshed == llama.RematPlan((), 0, 0, V5E, "mesh")
+        assert meshed == remat.RematPlan((), 0, 0, V5E, "mesh")
         return
     assert meshed == alone          # a mesh of one device is none
     assert alone.why == ("room" if want else "no room")
     assert alone.charged == charge(alone)
     if want:
-        assert alone.estimate + alone.charged <= V5E * (1 - llama.REMAT_FREE)
+        assert alone.estimate + alone.charged <= V5E * (1 - remat.REMAT_FREE)
         # every run of one layer at 1.0, Mellum2's three stacks of three
         # window layers (9 of its 12 layers) at 1.5
         assert alone.charged == alone.kept_bytes * (
@@ -198,11 +198,11 @@ def test_the_plan_is_monotone_in_the_limit(name, cell_plans):
         assert less.estimate == more.estimate       # shapes alone
     # 10.1e9, 8.62e9 of it for a plan: under every estimate here
     assert sweep[0].kept == ()
-    dense = llama.ATTN_OFFERED + llama.FFN_OFFERED
+    dense = remat.ATTN_OFFERED + llama.FFN_OFFERED
     offered = {"train-deepseek7b-l8": dense,
                "train-deepseek7b-fsdp2tp2": dense,
-               "train-olmoe1b7b-s4096-b4": llama.ATTN_OFFERED,
-               "train-mellum2-ep4-s16384-b1": llama.ATTN_OFFERED,
+               "train-olmoe1b7b-s4096-b4": remat.ATTN_OFFERED,
+               "train-mellum2-ep4-s16384-b1": remat.ATTN_OFFERED,
                # the latent half offers nothing, nor a dense latent layer
                "train-glm47flash-ep8-s8192-b2": moe.SHARED_OFFERED,
                "train-glm52-ep32-s16384-b1": moe.SHARED_OFFERED,
@@ -211,10 +211,10 @@ def test_the_plan_is_monotone_in_the_limit(name, cell_plans):
                "train-granite4hs-ep8-s8192-b2": ALL + MIX,
                # dense layers' gate and up; no shared expert
                "train-lfm2-ep4-s16384-b1":
-                   llama.ATTN_OFFERED + llama.FFN_OFFERED + MIX,
+                   remat.ATTN_OFFERED + llama.FFN_OFFERED + MIX,
                # two-matrix experts: a shared expert has no gate
                "train-nemotron3nano-ep8-s8192-b2":
-                   llama.ATTN_OFFERED + moe.SHARED_OFFERED[1:] + MIX}
+                   remat.ATTN_OFFERED + moe.SHARED_OFFERED[1:] + MIX}
     # with eight chips' memory every run keeps every name it offers
     kept = {n for run in sweep[-1].kept for n in run}
     assert kept == set(offered.get(name, ALL))
@@ -269,13 +269,13 @@ def _tiny(preset, family=moe):
 _mix = {"tiny": 2 * 128 + 2 * 16 + 8, "tiny-nemotron": 2 * 64 + 2 * 32 + 4}
 OFFERING = {
     (moe, "tiny-commanda"): (ALL, None), (moe, "tiny-mellum"): (
-        llama.ATTN_OFFERED, None),
+        remat.ATTN_OFFERED, None),
     (hybrid, "tiny"): (ALL + MIX, 4 * 16 + 2 * 2 * 16 + 4 * 2 * 48
                        + 3 * _mix["tiny"]),
     (hybrid, "tiny-nemotron"): (
-        llama.ATTN_OFFERED + moe.SHARED_OFFERED[1:] + MIX,
+        remat.ATTN_OFFERED + moe.SHARED_OFFERED[1:] + MIX,
         2 * (4 * 16 + 2 * 2 * 16) + 4 * 40 + 4 * _mix["tiny-nemotron"]),
-    (llama, "tiny"): (llama.ATTN_OFFERED + llama.FFN_OFFERED,
+    (llama, "tiny"): (remat.ATTN_OFFERED + llama.FFN_OFFERED,
                       2 * (4 * 16 + 2 * 2 * 16 + 2 * 128)),
     (sala, "tiny"): (llama.FFN_OFFERED, 5 * 2 * 128),
 }
@@ -306,8 +306,8 @@ def _memory(limit):
     (None, "no step"), (train_step.StepMemory(0, 10**9), "no limit")])
 def test_nothing_more_is_kept_with_no_limit(memory, why):
     cfg, params, batch = _tiny("tiny-commanda")
-    plan = llama.remat_plan(cfg, params, 2, 64, memory)
-    assert plan == llama.RematPlan((), 0, 0, 0, why)
+    plan = remat.remat_plan(cfg, params, 2, 64, memory)
+    assert plan == remat.RematPlan((), 0, 0, 0, why)
 
 
 @pytest.mark.parametrize("family,preset", list(OFFERING), ids=_ids)
@@ -328,7 +328,7 @@ def test_values_and_gradients_are_bit_equal_with_every_name_kept(family,
     got = {}
     for limit in (0, 10**15):
         with _memory(limit), how():
-            plan = llama.remat_plan(cfg, params, 2, 64,
+            plan = remat.remat_plan(cfg, params, 2, 64,
                                     train_step.step_memory())
             (loss, aux), grads = jax.jit(jax.value_and_grad(
                 lambda p: _pair(family.loss_fn(p, batch, cfg)),
@@ -351,16 +351,16 @@ def _limits(cfg, params):
     """Limits from under the estimate to over everything offered, a step
     an offer of a run: [(limit, plan), ...]."""
     def plan(limit):
-        return llama.remat_plan(cfg, params, 2, 64,
+        return remat.remat_plan(cfg, params, 2, 64,
                                 train_step.StepMemory(int(limit), 0))
 
     full = plan(10**15)
     each = sorted({n * b for (kind, n, _), run in zip(
-        llama._stacks(params, cfg)[0], full.kept)
-        for name, b in llama._offers(cfg, kind, 2, 64) if name in run})
-    floor = full.estimate / (1 - llama.REMAT_FREE)
-    step = llama.KEPT_COST_ONE * each[0] / (1 - llama.REMAT_FREE) / 2
-    top = floor + 2 * llama.KEPT_COST_STACK * full.kept_bytes
+        remat._stacks(params, cfg)[0], full.kept)
+        for name, b in remat._offers(cfg, kind, 2, 64) if name in run})
+    floor = full.estimate / (1 - remat.REMAT_FREE)
+    step = remat.KEPT_COST_ONE * each[0] / (1 - remat.REMAT_FREE) / 2
+    top = floor + 2 * remat.KEPT_COST_STACK * full.kept_bytes
     return [(x, plan(x)) for x in np.arange(floor - step, top, step)], full
 
 
@@ -377,7 +377,7 @@ def test_the_plan_is_monotone_in_the_limit_by_run(family, preset):
     assert sweep[0][1].kept == () and sweep[0][1].why == "no room"
     assert sweep[-1][1] == full._replace(limit=sweep[-1][1].limit)
     seen = set()
-    one_cost = len({n for _, n, _ in llama._stacks(params, cfg)[0]}) == 1
+    one_cost = len({n for _, n, _ in remat._stacks(params, cfg)[0]}) == 1
     for (_, less), (limit, more) in zip(sweep, sweep[1:]):
         assert less.charged <= more.charged, (less, more)
         if one_cost:        # then bytes and charge are one order
@@ -385,7 +385,7 @@ def test_the_plan_is_monotone_in_the_limit_by_run(family, preset):
         assert less.estimate == more.estimate
         assert more.charged == _charge(cfg, params, more, 2, 64)
         assert more.estimate + more.charged <= \
-            limit * (1 - llama.REMAT_FREE) + 1
+            limit * (1 - remat.REMAT_FREE) + 1
         assert len(more.kept) in (0, len(full.kept))
         seen.add(more.kept)
     # a step an offer: the plans differ by a run's name at a time
@@ -517,20 +517,20 @@ def test_a_kept_byte_is_charged_by_the_length_of_its_run(name):
         params = jax.eval_shape(
             lambda cfg=cfg: moe.init_params(jax.random.PRNGKey(3), cfg))
         assert [n for _, n in moe.layer_runs(cfg)] == lengths
-        plan = llama.remat_plan(cfg, params, 2, 64,
+        plan = remat.remat_plan(cfg, params, 2, 64,
                                 train_step.StepMemory(10**15, 0))
         assert all(name in run for run in plan.kept), plan
         assert plan.charged == _charge(cfg, params, plan, 2, 64)
-        a_layer = dict(llama._offers(cfg, "window", 2, 64))[name]
-        assert dict(llama._offers(cfg, "full", 2, 64))[name] == a_layer
+        a_layer = dict(remat._offers(cfg, "window", 2, 64))[name]
+        assert dict(remat._offers(cfg, "full", 2, 64))[name] == a_layer
         # every run keeps every name, each at the run's one rate: of the
         # whole charge, this name's is the share of its bytes
         charged[most] = plan.charged * 8 * a_layer / plan.kept_bytes
-    assert charged[1] == 8 * a_layer * llama.KEPT_COST_ONE
-    assert charged[3] == 2 * a_layer * llama.KEPT_COST_ONE \
-        + 6 * a_layer * llama.KEPT_COST_STACK
-    assert (llama.KEPT_COST_ONE, llama.KEPT_COST_STACK) == (1.0, 1.5)
-    assert [llama.kept_cost(n) for n in (1, 2, 3, 30)] == [1.0, 1.5, 1.5, 1.5]
+    assert charged[1] == 8 * a_layer * remat.KEPT_COST_ONE
+    assert charged[3] == 2 * a_layer * remat.KEPT_COST_ONE \
+        + 6 * a_layer * remat.KEPT_COST_STACK
+    assert (remat.KEPT_COST_ONE, remat.KEPT_COST_STACK) == (1.0, 1.5)
+    assert [remat.kept_cost(n) for n in (1, 2, 3, 30)] == [1.0, 1.5, 1.5, 1.5]
 
 
 def test_the_remat_plan_instant_carries_its_fields(monkeypatch):
@@ -560,7 +560,7 @@ def test_the_remat_plan_instant_carries_its_fields(monkeypatch):
     assert set(last) == {"kept", "kept_bytes", "charged", "runs", "by_run",
                          "estimate", "limit", "ceiling", "why"}
     assert last["limit"] == 10**15 and last["ceiling"] == int(
-        10**15 * (1 - llama.REMAT_FREE))
+        10**15 * (1 - remat.REMAT_FREE))
     assert 0 < last["kept_bytes"] < last["estimate"] < last["ceiling"]
     # window, window, window, full, twice over: six of the eight layers lie
     # in a stack of three (1.5 a kept byte), two in a run of one (1.0)
