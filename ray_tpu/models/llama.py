@@ -684,13 +684,12 @@ def _tp_plan(cfg: LlamaConfig, mesh, rules, batch: int, seq: int):
     """How this forward's tensor-parallel matmuls communicate, read from
     what it is given: an ``OverlapPlan`` (parallel/collective_matmul.py:
     the residual stream sharded over the sequence on the tensor axis,
-    half-row permutes under the matmuls) where the rules put ``heads`` and
-    ``mlp`` on one mesh axis of size n > 1 and n divides the sequence, the
-    heads, the KV heads (a shard's columns are split into WHOLE heads) and
-    the feed-forward's width; else None, and the program is the plain one.
-    A family with a feed-forward of its own (models/moe.py) stays plain
-    too: its expert layer is not row-parallel in this sense, and would be
-    handed rows it has to gather first."""
+    half-row permutes under the matmuls; the weights entering as stored,
+    their gradients reduce-scattered over the batch axis under ``embed``
+    by permutes under the gradient products) where the rules put ``heads``
+    and ``mlp`` on one mesh axis of n > 1 shards dividing the sequence,
+    the heads, the KV heads and the feed-forward's width; else None: the
+    plain program, as for a family with a feed-forward of its own."""
     if _family(cfg).feed_forward is not feed_forward:
         return None
     return overlap_plan(mesh, rules, batch, seq,
@@ -698,17 +697,18 @@ def _tp_plan(cfg: LlamaConfig, mesh, rules, batch: int, seq: int):
 
 
 def _say_tp_plan(tp, cfg: LlamaConfig, batch: int, seq: int):
-    """The instant ``tp.overlap_plan`` of a trace, once a traced forward
-    that was given a mesh and rules, after its layers are traced: which
-    path they took and, from the helpers' own calls, at how many sites."""
-    rows = seq // tp.shards if tp else seq
+    """``tp.overlap_plan``, once a traced forward given a mesh and rules."""
+    rows, grads = seq // tp.shards if tp else seq, tp.grad_sites if tp else ()
     tracing.plan("tp.overlap_plan", {
         "path": "overlap" if tp else "plain",
         "shards": tp.shards if tp else 1,
         # a layer's gathers (q/k/v; gate/up) and scatters (wo; w_down)
         "sites": len(tp.sites) if tp else 0, "rows_per_step": rows,
         "bytes_per_permute": batch // tp.batch_shards * rows * cfg.d_model
-        * jnp.dtype(cfg.dtype).itemsize if tp else 0})
+        * jnp.dtype(cfg.dtype).itemsize if tp else 0,
+        # the weights whose gradient the helpers reduce-scatter themselves
+        "grad_shards": tp.grad_shards if grads else 1, "grad_sites": len(
+            grads), "grad_bytes_per_permute": max(grads, default=0)})
 
 
 def _say_layer_plan(runs, bodies: int, more: Optional[dict] = None):

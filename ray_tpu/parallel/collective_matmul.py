@@ -29,20 +29,39 @@ each is ONE permute of half the rows.
 
 All three are ``jax.shard_map``s manual over every mesh axis, as the
 flash kernel's is (models/llama.py ``_flash_sharded``) and for its
-reason, and are differentiated by jax's own transposition: the transpose
-of a gather-side permute is a scatter-side one, so the backward overlaps
-the same way with no backward code here, but for the two row
-permutations (``_join``, ``_split``), each of which names the other as
-its transpose. What was tried on the chip and lost: PERF.md 6, PR 30.
+reason. The tensor axis' side is differentiated by jax's own
+transposition: the transpose of a gather-side permute is a scatter-side
+one, so the backward overlaps the same way, with the two row permutations
+(``_join``, ``_split``) each naming the other as its transpose. What was
+tried on the chip and lost: PERF.md 6, PR 30.
 
-Whether a forward takes them is read from what it is given
-(``overlap_plan``), never set: no config field, no environment variable.
+The BATCH axis' side has backward code of its own (``_products``). A
+weight's ``embed`` dimension is stored sharded over a batch axis (``fsdp``)
+and every shard of that axis sees other tokens, so a weight's gradient is
+a sum over the axis that ends sharded as the weight is stored: a
+reduce-scatter. Left to jax and XLA (the weight entering whole over that
+axis) it is one blocking ``all-reduce-scatter`` fusion a weight after its
+gradient product. Here the weight enters AS STORED and is gathered inside,
+and the gradient leaves as stored: the part of it that belongs to another
+shard is produced first (a product over that shard's part of ``embed``)
+and travels as a ``ppermute`` while the own part's product runs, then the
+two are added (scope ``tp.gradient``): at 2 shards ONE permute of half
+the gradient, at more the ring of ``_scattered``. With no batch axis of
+n > 1 shards under ``embed`` the weights enter whole and the program is
+the one without any of this. What the chip read, and the form that splits
+the whole gradient after its products instead: PERF.md 6, PR 57.
+
+Whether a forward takes them, and which axis lies under ``embed``, is read
+from what it is given (``overlap_plan``), never set: no config field, no
+environment variable.
 """
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
+import operator
 from dataclasses import dataclass, field
 from typing import Any, Callable, List, Optional, Sequence, Tuple
 
@@ -57,22 +76,38 @@ from ray_tpu.parallel.sharding import ShardingRules, mesh_axes
 # program calls none of them), so a trace's permutes and half-row matmuls
 # say which plan issued them
 SCOPE = "tp.overlap"
+# inside it, round the permutes and sums that reduce-scatter a weight's
+# gradient over the batch axis under ``embed``: a trace lists them apart
+# from the tensor axis' (``benchmark/op_scopes.py`` takes the innermost)
+GRAD_SCOPE = "tp.gradient"
 
 
 @dataclass(frozen=True)
 class OverlapPlan:
     """Where the tensor axis lies. ``batch``: the mesh axes (of size > 1)
-    that shard the batch dimension, possibly none. ``sites``: the gathers
-    and scatters traced under this plan so far, one name each."""
+    that shard the batch dimension, possibly none. ``grad_axis``: the one
+    of them that the weights' ``embed`` dimension is stored over (in
+    ``grad_shards`` > 1 shards), or None: the axis a weight's gradient is
+    reduce-scattered over. ``sites``: the gathers and scatters traced
+    under this plan so far, one name each; ``grad_sites``: the weights
+    whose gradient travels by the helpers' own permutes, the bytes of one
+    permute each."""
     mesh: Any
     axis: str
     shards: int
     batch: Tuple[str, ...]
+    grad_axis: Optional[str] = None
     sites: List[str] = field(default_factory=list, compare=False, repr=False)
+    grad_sites: List[int] = field(default_factory=list, compare=False,
+                                  repr=False)
 
     @property
     def batch_shards(self) -> int:
         return math.prod(int(self.mesh.shape[a]) for a in self.batch)
+
+    @property
+    def grad_shards(self) -> int:
+        return int(self.mesh.shape[self.grad_axis]) if self.grad_axis else 1
 
     def rows(self) -> P:
         """[batch, seq over the tensor axis, features]."""
@@ -87,6 +122,27 @@ class OverlapPlan:
 
     def gathered_sharding(self) -> NamedSharding:
         return NamedSharding(self.mesh, P(self.batch or None, None, None))
+
+    def stored(self, embed: int, *ws) -> bool:
+        """Whether ``ws``, whose ``embed`` dimension is ``embed`` long,
+        enter their helper as they are stored, ``embed`` over
+        ``grad_axis``, and their gradients leave so; else they enter whole
+        over the batch axes: no such axis, or an ``embed`` its shards do
+        not divide. Counts the sites."""
+        if self.grad_axis is None or embed % self.grad_shards:
+            return False
+        self.grad_sites.extend(
+            w.size // (self.shards * self.grad_shards) * w.dtype.itemsize
+            for w in ws)
+        return True
+
+    def weight(self, at: int, stored: bool) -> P:
+        """A weight's spec: its ``embed`` dimension (``at``) over
+        ``grad_axis`` where it enters as stored, the other over the
+        tensor axis."""
+        spec = [self.axis, self.axis]
+        spec[at] = self.grad_axis if stored else None
+        return P(*spec)
 
 
 def overlap_plan(mesh, rules: Optional[ShardingRules], batch: int, seq: int,
@@ -105,7 +161,9 @@ def overlap_plan(mesh, rules: Optional[ShardingRules], batch: int, seq: int,
         return None
     axis = heads[0]
     n = int(mesh.shape[axis])
-    plan = OverlapPlan(mesh, axis, n, mesh_axes("batch", rules, mesh))
+    over, embed = (mesh_axes(a, rules, mesh) for a in ("batch", "embed"))
+    plan = OverlapPlan(mesh, axis, n, over, embed[0] if len(
+        embed) == 1 and embed[0] in over else None)
     if axis in plan.batch or mesh_axes("seq", rules, mesh) or seq % n \
             or batch % plan.batch_shards or any(u % n for u in units):
         return None
@@ -161,25 +219,107 @@ _join.defvjp(lambda parts, plan, first: (_join(parts, plan, first), None),
              lambda plan, first, _, g: (_split(g, plan, first),))
 
 
-def _scattered(rows, w, plan: OverlapPlan):
-    """Reduce-scatter of ``rows[t] @ w`` around the ring. ``rows[t]``:
-    this shard's columns of the rows of shard i - 1 - t (t = n - 1: its
-    own). Each product is added to what arrived from shard i - 1 and sent
-    on to i + 1; the own rows' product is made while the last transfer
-    runs."""
-    n = plan.shards
+def _ring_sum(parts, axis: str, n: int, scope: Optional[str] = None):
+    """Reduce-scatter round the ring of ``axis``: ``parts`` yields n
+    products one after the other, the one that belongs to shard
+    i - 1 - t at step t (t = n - 1: the own). Each is added to what
+    arrived from shard i - 1 and sent on to i + 1; the own product is
+    made while the last transfer runs. ``scope`` names the sums and
+    permutes, not the products."""
+    within = contextlib.nullcontext if scope is None else \
+        functools.partial(jax.named_scope, scope)
     acc = None
-    for t in range(n):
-        part = rows[t] @ w
-        if acc is not None:
-            # both sides of the sum stand in memory before it is taken:
-            # fused into the matmul's own output the sum would make the
-            # matmul wait for the transfer it is there to cover
-            part, acc = jax.lax.optimization_barrier((part, acc))
-            part = part + acc
-        acc = jax.lax.ppermute(part, plan.axis, _ring(n)) \
-            if t + 1 < n else part
+    for t, part in enumerate(parts):
+        with within():
+            if acc is not None:
+                # both sides of the sum stand in memory before it is
+                # taken: fused into the matmul's own output the sum would
+                # make the matmul wait for the transfer it is there to
+                # cover
+                part, acc = jax.lax.optimization_barrier((part, acc))
+                part = part + acc
+            acc = jax.lax.ppermute(part, axis, _ring(n)) \
+                if t + 1 < n else part
     return acc
+
+
+def _scattered(rows, w, plan: OverlapPlan, at: Optional[int] = None):
+    """Reduce-scatter of ``rows[t] @ w`` around the tensor axis' ring.
+    ``rows[t]``: this shard's columns of the rows of shard i - 1 - t
+    (t = n - 1: its own)."""
+    return _ring_sum((part for part, in _matmuls(rows, (w,), plan, at)),
+                     plan.axis, plan.shards)
+
+
+def _matmuls(rows, ws, plan: OverlapPlan, at: Optional[int]):
+    """``[r @ w for w in ws]`` for each ``r`` of ``rows`` in turn, which
+    may be made as they are asked for (``_travelling``). ``at`` None: the
+    weights are here whole over the batch axes. Else they are the stored
+    shards, ``embed`` (dimension ``at``) over ``plan.grad_axis``, and all
+    of a weight's products stand under ONE derivative rule
+    (``_products``), which wants the rows together."""
+    if at is None:
+        for r in rows:
+            yield [r @ w for w in ws]
+    else:
+        rows = tuple(rows)
+        yield from zip(*(_products(rows, w, plan, at) for w in ws))
+
+
+def _whole(w, plan: OverlapPlan, at: int):
+    """A stored shard's weight, ``embed`` gathered: XLA's all-gather, as
+    it made of a weight that entered whole."""
+    return jax.lax.all_gather(w, plan.grad_axis, axis=at, tiled=True)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _products(rows, w, plan: OverlapPlan, at: int):
+    """``tuple(r @ W for r in rows)``: ``rows`` [b, s, K] each, ``w`` this
+    device's stored shard of W [K, M], W's dimension ``at`` (its
+    ``embed``) over ``plan.grad_axis``, gathered here.
+
+    Every shard of that axis has other tokens, so W's gradient is the sum
+    of theirs, and it leaves as ``w`` came: a reduce-scatter, which the
+    backward rule makes of products and permutes as ``_scattered`` makes
+    the tensor axis'. The part of the gradient that belongs to shard
+    j - 1 - u is produced at step u, over that shard's part of ``embed``
+    (rows of dW: a slice of ``rows``' features; columns: of the
+    cotangents'), added to what arrived and sent on; the own part's
+    products run under the last transfer. At 2 shards: ONE permute of
+    half the gradient."""
+    return _products_fwd(rows, w, plan, at)[0]
+
+
+def _products_fwd(rows, w, plan, at):
+    W = _whole(w, plan, at)
+    return tuple(r @ W for r in rows), (rows, W)
+
+
+def _products_bwd(plan, at, kept, dys):
+    rows, W = kept
+    f, j = plan.grad_shards, jax.lax.axis_index(plan.grad_axis)
+    width = W.shape[at] // f
+
+    def part(u):
+        # rows^T dy over the tokens, at shard j - 1 - u's part of embed
+        def cut(x):
+            return jax.lax.dynamic_slice_in_dim(
+                x, (j + 2 * f - 1 - u) % f * width, width, axis=2)
+
+        return functools.reduce(operator.add, (
+            jax.lax.dot_general(cut(r) if at == 0 else r,
+                                dy if at == 0 else cut(dy),
+                                (((0, 1), (0, 1)), ((), ()))).astype(W.dtype)
+            for r, dy in zip(rows, dys)))
+
+    drows = tuple(jax.lax.dot_general(
+        dy, W, (((2,), (1,)), ((), ()))).astype(r.dtype)
+        for r, dy in zip(rows, dys))
+    return drows, _ring_sum(map(part, range(f)), plan.grad_axis, f,
+                            GRAD_SCOPE)
+
+
+_products.defvjp(_products_fwd, _products_bwd)
 
 
 def allgather_matmul(h, ws: Sequence[Any], plan: OverlapPlan,
@@ -194,12 +334,13 @@ def allgather_matmul(h, ws: Sequence[Any], plan: OverlapPlan,
     ``extras`` reach it whole on every shard. It may split the last
     dimension: [b, s, N] -> [b, s, H, d]."""
     plan.sites.append("gather")
+    stored = plan.stored(h.shape[-1], *ws)
 
     def shard(h, extras, *ws):
         i, n = jax.lax.axis_index(plan.axis), plan.shards
         parts = []
-        for t, rows in enumerate(_travelling(h, plan)):
-            ys = [rows @ w for w in ws]
+        for t, ys in enumerate(_matmuls(_travelling(h, plan), ws, plan,
+                                        0 if stored else None)):
             if then is not None:
                 ys = [then(k, y, (i + n - t) % n, *extras)
                       for k, y in enumerate(ys)]
@@ -210,7 +351,8 @@ def allgather_matmul(h, ws: Sequence[Any], plan: OverlapPlan,
     with jax.named_scope(SCOPE):
         return jax.shard_map(
             shard, mesh=plan.mesh,
-            in_specs=(plan.rows(), P()) + (P(None, plan.axis),) * len(ws),
+            in_specs=(plan.rows(), P())
+            + (plan.weight(0, stored),) * len(ws),
             out_specs=(plan.columns(),) * len(ws), check_vma=False)(
                 h, tuple(extras), *ws)
 
@@ -220,14 +362,15 @@ def matmul_reduce_scatter(a, w, plan: OverlapPlan):
     ``a @ w`` summed over the shards, [B, S, D] with S over the tensor
     axis."""
     plan.sites.append("scatter")
+    stored = plan.stored(w.shape[1], w)
 
     def shard(a, w):
-        return _scattered(_split(a, plan, 1), w, plan)
+        return _scattered(_split(a, plan, 1), w, plan, 1 if stored else None)
 
     with jax.named_scope(SCOPE):
         return jax.shard_map(
             shard, mesh=plan.mesh,
-            in_specs=(plan.columns(), P(plan.axis, None)),
+            in_specs=(plan.columns(), plan.weight(1, stored)),
             out_specs=plan.rows(), check_vma=False)(a, w)
 
 
@@ -239,15 +382,18 @@ def gather_apply_scatter(h, ws: Sequence[Any], fn: Callable, w_out,
     gathered [B, S, N] is never assembled. The own rows arrive first and
     leave last: ``fn`` of them waits while the others pass."""
     plan.sites.extend(("gather", "scatter"))
+    stored = plan.stored(h.shape[-1], w_out, *ws)
 
     def shard(h, w_out, *ws):
-        mid = [fn(*[rows @ w for w in ws]) for rows in _travelling(h, plan)]
+        mid = [fn(*ys) for ys in _matmuls(_travelling(h, plan), ws, plan,
+                                          0 if stored else None)]
         # mid[t]: the rows of shard i - t; the scatter wants i - 1, ..., i
-        return _scattered(mid[1:] + mid[:1], w_out, plan)
+        return _scattered(mid[1:] + mid[:1], w_out, plan,
+                          1 if stored else None)
 
     with jax.named_scope(SCOPE):
         return jax.shard_map(
             shard, mesh=plan.mesh,
-            in_specs=(plan.rows(), P(plan.axis, None))
-            + (P(None, plan.axis),) * len(ws),
+            in_specs=(plan.rows(), plan.weight(1, stored))
+            + (plan.weight(0, stored),) * len(ws),
             out_specs=plan.rows(), check_vma=False)(h, w_out, *ws)

@@ -69,9 +69,14 @@ class ShardingRules:
         all-gather, both as half-row permutes that run under the matmuls
         (``parallel/collective_matmul.py``), instead of one blocking
         all-reduce of the whole activation. Inside attention the sequence
-        is whole again and the heads are sharded. ``kv_heads`` is not on
-        tp: wk/wv and their optimizer state are replicated over it and
-        their gradients gathered once a step (ROADMAP.md S1)."""
+        is whole again and the heads are sharded. The same helpers take a
+        layer's weights as stored, ``embed`` over fsdp, and reduce-scatter
+        their gradients over fsdp themselves: one permute of the other
+        shard's half under the own half's gradient products, instead of
+        XLA's blocking fusion after them. ``kv_heads`` is not on tp: wk/wv
+        and their optimizer state are replicated over it and their
+        gradients, scattered over fsdp by then, gathered over tp once a
+        step (ROADMAP.md S1)."""
         return cls.fsdp().with_(mlp="tp", heads="tp", vocab="tp")
 
     @classmethod

@@ -441,9 +441,15 @@ def test_tp_overlap_plan_instant_says_which_path():
     assert plans == [
         {"path": "overlap", "shards": 2, "sites": 4, "rows_per_step": 16,
          # two rows of the batch a device x 16 rows x 64 wide x f32
-         "bytes_per_permute": 2 * 16 * CFG.d_model * 4},
+         "bytes_per_permute": 2 * 16 * CFG.d_model * 4,
+         # a layer's seven weights leave their helper as they are stored,
+         # embed over fsdp; the largest part that travels is a quarter
+         # (fsdp 2 x tp 2) of w_gate, f32
+         "grad_shards": 2, "grad_sites": 7,
+         "grad_bytes_per_permute": CFG.d_model * CFG.d_ff // 4 * 4},
         {"path": "plain", "shards": 1, "sites": 0, "rows_per_step": 31,
-         "bytes_per_permute": 0}]
+         "bytes_per_permute": 0, "grad_shards": 1, "grad_sites": 0,
+         "grad_bytes_per_permute": 0}]
 
 
 def _primitives(jaxpr, into=None):

@@ -163,6 +163,10 @@ def test_no_matmul_and_no_kernel_call_outside_a_scope(case, tmp_path,
     tp = {a["path"] for a in plans.get("tp.overlap_plan", [])}
     assert ("overlap" in tp) == (case == "dense-fsdp2tp2"), tp
     want |= {"tp.overlap"} if "overlap" in tp else set()
+    # and where weights' gradients travel by the helpers' own permutes,
+    # those stand under a scope of their own
+    want |= {"tp.gradient"} if any(
+        a["grad_sites"] for a in plans.get("tp.overlap_plan", [])) else set()
     if hasattr(cfg, "gmm_impl"):
         want |= {f"gmm.{cfg.gmm_impl}", f"tgmm.{cfg.gmm_impl}"}
     assert want and kernels == want, (kernels, want, plans)

@@ -181,10 +181,11 @@ COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
 
 def _loop_permutes(text, rows_shape):
     """[(loop body, permute, matmul fusions between its start and its
-    done)] for every collective-permute of ``rows_shape`` in the layer
-    loops of a compiled step, having checked that the loops hold no
-    blocking all-reduce of an activation and that nothing joins or splits
-    the rows as an op of its own (a copy of every part)."""
+    done)] for every collective-permute of ``rows_shape`` (a shape, or a
+    scope on the permute's ``op_name``) in the layer loops of a compiled
+    step, having checked that the loops hold no blocking all-reduce of an
+    activation and that nothing joins or splits the rows as an op of its
+    own (a copy of every part)."""
     import re
 
     bodies = _while_bodies(text)
@@ -251,6 +252,32 @@ def test_four_chip_step_overlaps_its_tensor_parallel_traffic(topo,
                               "bf16[4,512,2560]")
     assert len(permutes) == 11, permutes
     assert all(matmuls >= 1 for _, _, matmuls in permutes), permutes
+
+
+def test_four_chip_step_scatters_its_weight_gradients_by_permutes(
+        topo, on_chip_branch):
+    """Under ``fsdp`` beside the tensor axis a layer's seven weights enter
+    the helpers as they are stored and their gradients leave so: the
+    backward loop's body holds no ``all-reduce-scatter`` fusion (XLA's
+    blocking form of jax's psum and the slice after it; the head's and the
+    embedding's, outside the helpers and the loop, stay), each gradient
+    travels as ONE permute of the half that belongs to the other shard
+    (``tp.gradient``), the scheduler put a matmul fusion between every
+    start and its done, and the halves in flight fit (the four-chip cell's
+    own plan: 14,573,366,784 bytes of a v5e's 16,909,336,064; PERF.md 6)."""
+    compiled = _step_2b7(topo, 4)
+    text = compiled.as_text()
+    bodies = _while_bodies(text)
+    assert not [ln.split(" = ")[0] for lines in bodies.values()
+                for ln in lines if "all-reduce-scatter" in ln]
+    assert "all-reduce-scatter" in text                   # the head's
+    permutes = _loop_permutes(text, "/tp.gradient/")
+    assert len(permutes) == 7, permutes
+    assert len({body for body, _, _ in permutes}) == 1    # the backward's
+    assert all(matmuls >= 1 for _, _, matmuls in permutes), permutes
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        + mem.output_size_in_bytes - mem.alias_size_in_bytes < 14.8e9, mem
 
 
 def test_2b7_train_step_fits_one_chip(topo, on_chip_branch):
