@@ -47,6 +47,11 @@ def _refuse_stated(cfg: LlamaConfig):
     if "conv" in getattr(cfg, "layer_types", ()):
         stated.append("short-convolution layers (their state, the last "
                       "taps - 1 rows, has no place in a KV cache)")
+    if hasattr(cfg, "kda_head_dim"):
+        stated.append("Kimi-Delta-Attention layers (a KDA half is TRAINED, "
+                      "not served: its state, [dk, dv] float32 a head with "
+                      "the convolutions' last taps - 1 rows, has no place "
+                      "in a KV cache)")
     if getattr(cfg, "n_dense", 0) and hasattr(cfg, "conv_taps"):
         stated.append("leading dense layers among expert layers")
     if cfg.norm != "rms":

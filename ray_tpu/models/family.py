@@ -3,7 +3,8 @@
 The embedding, the loop over the layers, the layer checkpoint, the head and
 the loss are written once (models/llama.py, models/remat.py, and for serving
 models/cached.py). A family (llama's dense model, models/moe.py,
-models/hybrid.py, models/latent.py, models/sala.py) is the module that
+models/hybrid.py, models/latent.py, models/sala.py, models/ling.py) is the
+module that
 defines a config class and, at its end, builds ``FAMILY``: a ``Family`` that
 names every member the shared code reads. A family that takes a member from
 another builds FROM that family's record (``moe.FAMILY.replace("hybrid",
@@ -84,7 +85,9 @@ class Family:
     hands_on: Optional[Callable] = None
     # (cfg, runs, plan) -> what ``hybrid.layer_plan`` says more of the layers
     layer_plan_says: Optional[Callable] = None
-    # (cfg, kind) -> (a first half, the feed-forward): what a block holds
+    # (cfg, kind) -> (a first half, the feed-forward): what a block holds.
+    # The first may be "attention" or "mixer" itself, where a family has
+    # both an ``attention_half`` and a ``mixer_half`` and the kind decides
     halves: Callable = _both_halves
     # (cfg, kind) -> whether the block's feed-forward is an expert layer's
     routes: Callable = _every_block_routes
@@ -146,6 +149,8 @@ def _halves(cfg, kind):
     first, second = family.halves(cfg, kind)
     if not first:
         return None, second
+    if first in ("attention", "mixer"):     # the family said which
+        return first, second
     attends = family.attention_half is not None \
         or _takes_attention_half(cfg, kind)
     return "attention" if attends else "mixer", second

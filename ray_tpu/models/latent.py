@@ -30,8 +30,17 @@ interleaved pairs (2i, 2i+1) of the ``qk_rope_dim`` lanes. This is the
 EXPANDED form, the one training and prefill run: K goes to the kernel as
 [B, H, S, qk_nope_dim + qk_rope_dim] with the shared rotary key written
 into every head's last lanes (instant ``mla.plan``). The kernel takes q, k
-and v of one width, so ``v_dim`` has to equal ``qk_nope_dim +
-qk_rope_dim`` (GLM-4.7-Flash: 192 + 64 = 256).
+and v of one width (GLM-4.7-Flash: ``v_dim`` 256 = 192 + 64). Value heads
+NARROWER than the query/key heads (Ling 3.0: 128 beside 128 + 64) reach it
+padded: ``wkv_b``'s v columns are followed by zero columns up to the
+kernel's width, zero columns of v give zero columns of o, and the output's
+first ``v_dim`` lanes go on to ``wo`` (``plan``'s ``v_zero_lanes``). Exact,
+and a third of P V's width is wasted; a value width of the kernels' own is
+what would save it. Without a query latent (``q_rank`` 0: the source's
+``q_lora_rank`` null) q is ONE projection ``wq`` of the normed input and
+there is no ``wq_a``, ``q_a_norm`` or ``wq_b``. With ``attn_gate`` every
+head's output is scaled by one sigmoid gate of the normed input before
+``wo`` (``w_attn_gate`` [D, H]: Ling's ``head_wise`` granularity).
 
 The stored weights keep the published order of columns: ``wq_b`` a head's
 [q_nope | q_rope] H times, the rotary lanes as interleaved pairs; ``wkv_a``
@@ -151,13 +160,16 @@ class LatentConfig(_moe.MoEConfig):
     # a check of the sets and of their way through the layers, not for a
     # step
     index_report_sets: bool = False
+    # one sigmoid gate a head on the attention's output, before ``wo``
+    attn_gate: bool = False
 
     def __post_init__(self):
-        if self.v_dim != self.qk_nope_dim + self.qk_rope_dim:
+        if self.v_dim > self.qk_nope_dim + self.qk_rope_dim:
             raise NotImplementedError(
-                f"value heads of {self.v_dim} beside query/key heads of "
+                f"value heads of {self.v_dim} WIDER than query/key heads of "
                 f"{self.qk_nope_dim} + {self.qk_rope_dim}: the attention "
-                "kernels take q, k and v of one width")
+                "kernels take q, k and v of one width, and only v is padded "
+                "up to it")
         if self.n_mtp not in (0, 1) or not 0 <= self.n_dense < self.n_layers:
             raise ValueError(f"n_mtp {self.n_mtp}, n_dense {self.n_dense} of "
                              f"{self.n_layers} layers")
@@ -173,6 +185,10 @@ class LatentConfig(_moe.MoEConfig):
             if self.index_dim < self.qk_rope_dim:
                 raise ValueError(f"index_dim {self.index_dim} under the "
                                  f"rotary's {self.qk_rope_dim} lanes")
+            if not self.q_rank:
+                raise NotImplementedError(
+                    "an indexer without a query latent: its queries are a "
+                    "projection of c_q")
 
     @property
     def head_dim(self) -> int:
@@ -300,24 +316,30 @@ def hands_on(cfg: LatentConfig, kind) -> bool:
     return _selects(kind)
 
 
-def _mla_specs():
+def _mla_specs(cfg: LatentConfig):
     L = ("layers",)
-    return {"wq_a": L + ("embed", None), "q_a_norm": L + (None,),
-            "wq_b": L + (None, "heads"), "wkv_a": L + ("embed", None),
+    q = {"wq_a": L + ("embed", None), "q_a_norm": L + (None,),
+         "wq_b": L + (None, "heads")} if cfg.q_rank \
+        else {"wq": L + ("embed", "heads")}
+    gate = {"w_attn_gate": L + ("embed", None)} if cfg.attn_gate else {}
+    return {**q, "wkv_a": L + ("embed", None),
             "kv_a_norm": L + (None,), "wkv_b": L + (None, "heads"),
-            "wo": L + ("heads", "embed")}
+            "wo": L + ("heads", "embed"), **gate}
 
 
 def _mla_params(key, cfg: LatentConfig, n: int):
     pd, D, H = cfg.param_dtype, cfg.d_model, cfg.n_heads
-    ks = jax.random.split(key, 5)
+    ks = jax.random.split(key, 6)
 
     def dense(k, shape):
         return jax.random.normal(k, (n,) + shape, pd) * shape[0] ** -0.5
 
-    return {"wq_a": dense(ks[0], (D, cfg.q_rank)),
-            "q_a_norm": jnp.ones((n, cfg.q_rank), pd),
-            "wq_b": dense(ks[1], (cfg.q_rank, H * cfg.head_dim)),
+    q = {"wq_a": dense(ks[0], (D, cfg.q_rank)),
+         "q_a_norm": jnp.ones((n, cfg.q_rank), pd),
+         "wq_b": dense(ks[1], (cfg.q_rank, H * cfg.head_dim))} \
+        if cfg.q_rank else {"wq": dense(ks[0], (D, H * cfg.head_dim))}
+    gate = {"w_attn_gate": dense(ks[5], (D, H))} if cfg.attn_gate else {}
+    return {**q, **gate,
             "wkv_a": dense(ks[2], (D, cfg.kv_rank + cfg.qk_rope_dim)),
             "kv_a_norm": jnp.ones((n, cfg.kv_rank), pd),
             "wkv_b": dense(ks[3], (cfg.kv_rank,
@@ -364,8 +386,8 @@ def _stack_specs(kind: str, run: LatentConfig):
     lay = dict(base.param_specs(run)["layers"])
     for w in _PROJECTIONS:
         del lay[w]
-    return {**lay, **_mla_specs(), **(_index_specs() if _selects(kind)
-                                      else {})}
+    return {**lay, **_mla_specs(run), **(_index_specs() if _selects(kind)
+                                         else {})}
 
 
 def _stack_params(key, kind: str, run: LatentConfig):
@@ -417,7 +439,9 @@ def init_params(key, cfg: LatentConfig) -> Dict[str, Any]:
 
 def num_params(cfg: LatentConfig) -> int:
     D, H = cfg.d_model, cfg.n_heads
-    mla = (D * cfg.q_rank + cfg.q_rank + cfg.q_rank * H * cfg.head_dim
+    mla = ((D * cfg.q_rank + cfg.q_rank + cfg.q_rank * H * cfg.head_dim
+            if cfg.q_rank else D * H * cfg.head_dim)
+           + D * H * cfg.attn_gate
            + D * (cfg.kv_rank + cfg.qk_rope_dim) + cfg.kv_rank
            + cfg.kv_rank * H * (cfg.qk_nope_dim + cfg.v_dim)
            + H * cfg.v_dim * D)
@@ -467,7 +491,11 @@ def plan(cfg: LatentConfig, B: int, S: int) -> dict:
     sum over heads)."""
     H, R, e = cfg.n_heads, cfg.qk_rope_dim, jnp.dtype(cfg.dtype).itemsize
     rows, heads = B * S, B * S * cfg.n_heads * cfg.head_dim
-    return {"S": S, "heads": H, "heads_held": H, "qk_nope": cfg.qk_nope_dim,
+    # v's zero lanes up to the kernel's width, said only where there are any
+    padded = {"v_zero_lanes": cfg.head_dim - cfg.v_dim} \
+        if cfg.v_dim < cfg.head_dim else {}
+    return {**padded,
+            "S": S, "heads": H, "heads_held": H, "qk_nope": cfg.qk_nope_dim,
             "qk_rope": R,
             "v_dim": cfg.v_dim, "q_rank": cfg.q_rank, "kv_rank": cfg.kv_rank,
             "form": "expanded", "k_bytes": heads * e, "rope": "projected",
@@ -727,7 +755,8 @@ def attention_half(x, lp, cfg: LatentConfig, cos, sin, mesh=None, rules=None,
     R, rk, f32 = cfg.qk_rope_dim, cfg.kv_rank, jnp.float32
     tracing.plan("mla.plan", plan(cfg, B, S))
     w = lambda name: _ll._dq(lp[name], dt)                     # noqa: E731
-    wq_b = w("wq_b").reshape(cfg.q_rank, H, dn + R)
+    # without a query latent q is one projection of the normed input
+    wq_b = (w("wq_b") if cfg.q_rank else w("wq")).reshape(-1, H, dn + R)
     wq_s = _swapped(wq_b[..., dn:])                            # [q_rank, H, R]
     wkv_a = w("wkv_a")
     wkv_a = jnp.concatenate([wkv_a, _swapped(wkv_a[:, rk:])], axis=-1)
@@ -735,7 +764,8 @@ def attention_half(x, lp, cfg: LatentConfig, cos, sin, mesh=None, rules=None,
     wk = jnp.pad(wkv_b[..., :dn], ((0, 0), (0, 0), (0, R)))
     one, turn = _rotary_tables(cos, sin, dn)                   # [S, dn + R]
     h = _ll.rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    c_q = _ll.rms_norm(h @ w("wq_a"), lp["q_a_norm"], cfg.norm_eps)
+    c_q = _ll.rms_norm(h @ w("wq_a"), lp["q_a_norm"], cfg.norm_eps) \
+        if cfg.q_rank else h
     q = jnp.einsum("bsr,rhd->bhsd", c_q, wq_b)
     q_s = jnp.pad(jnp.einsum("bsr,rhd->bhsd", c_q, wq_s),
                   ((0, 0), (0, 0), (0, 0), (dn, 0)))
@@ -747,7 +777,10 @@ def attention_half(x, lp, cfg: LatentConfig, cos, sin, mesh=None, rules=None,
     c_kv = _ll.rms_norm(c_kv[..., :rk], lp["kv_a_norm"], cfg.norm_eps)
     k = jnp.einsum("bsr,rhd->bhsd", c_kv, wk) \
         + jnp.pad(k_r, ((0, 0), (0, 0), (dn, 0)))[:, None]
-    v = jnp.einsum("bsr,rhd->bhsd", c_kv, wkv_b[..., dn:])
+    wv = wkv_b[..., dn:]
+    if dv < dn + R:     # zero columns up to the kernel's one width
+        wv = jnp.pad(wv, ((0, 0), (0, 0), (0, dn + R - dv)))
+    v = jnp.einsum("bsr,rhd->bhsd", c_kv, wv)
     # the kernel's own transposes, so XLA writes no copy for them
     q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
     said = None
@@ -757,6 +790,10 @@ def attention_half(x, lp, cfg: LatentConfig, cos, sin, mesh=None, rules=None,
     else:
         out, carried, said = _attend_set(q, k, v, h, c_q, lp, cfg, cos, sin,
                                          carried, kind)
+    if dv < dn + R:
+        out = out[..., :dv]
+    if cfg.attn_gate:
+        out = out * jax.nn.sigmoid(h @ w("w_attn_gate"))[..., None]
     return _ll._residual(
         x, jnp.einsum("bshd,hde->bse", out, w("wo").reshape(H, dv, -1)),
         cfg), carried, said

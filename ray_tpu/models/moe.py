@@ -116,6 +116,17 @@ class MoEConfig(_ll.LlamaConfig):
     router_bias: bool = True
     # multiplies the K weights (DeepSeek's ``routed_scaling_factor``)
     route_scale: float = 1.0
+    # group-limited selection (DeepSeek-V3's ``noaux_tc``, Ling 3.0's): the
+    # experts lie in ``n_group`` groups of adjacent experts, a token keeps
+    # the ``topk_group`` groups whose two largest choice scores sum highest
+    # and its K experts come from those alone (``kept_groups``); 1 and 1
+    # limit nothing and the program is the one without
+    n_group: int = 1
+    topk_group: int = 1
+    # every token's kept groups [T, n_group] among an expert layer's
+    # statistics ("groups"): for a comparison that tells a near tie of
+    # groups from one of experts, not for a step
+    report_groups: bool = False
     # what one step moves a biased router's bias by (``post_update``)
     bias_rate: float = 0.001
     # what an expert, routed or shared, is: "swiglu", silu(x W_gate) x
@@ -454,10 +465,34 @@ def _down_combine_bwd(impl, res, dy):
 _down_combine.defvjp(_down_combine_fwd, _down_combine_bwd)
 
 
+def kept_groups(choice, cfg: MoEConfig):
+    """Choice scores [T, E] (score plus bias) -> bool [T, n_group]: the
+    ``topk_group`` groups a token keeps, a group's score the sum of its two
+    largest choice scores, equal scores to the lower group. The ONE place
+    the group limit lives: ``route`` masks with it and hands the mask on,
+    the expert layer counts with that (``group_kept``), the references
+    state the rule themselves."""
+    T, E = choice.shape
+    best = jax.lax.top_k(choice.reshape(T, cfg.n_group, E // cfg.n_group),
+                         2)[0].sum(axis=-1)                       # [T, G]
+    _, kept = jax.lax.top_k(best, cfg.topk_group)
+    return jnp.any(kept[:, :, None] == jnp.arange(cfg.n_group), axis=1)
+
+
 def route(logits, cfg: MoEConfig, bias=None):
     """Router logits [T, E] float32 (and, for the sigmoid router, its bias
     [E] float32) -> (weights [T, K] float32, experts [T, K] int32, every
-    expert's score [T, E]: softmax probabilities, or sigmoids)."""
+    expert's score [T, E]: softmax probabilities, or sigmoids; the groups
+    every token kept, bool [T, n_group], or None without a group limit).
+    With ``n_group`` > 1 the biased sigmoid router chooses inside the
+    groups a token keeps (``kept_groups``): an expert of another group
+    scores -inf for the choice, whatever its score and bias."""
+    if cfg.n_group > 1 and (cfg.router_score != "sigmoid" or bias is None):
+        raise NotImplementedError(
+            f"n_group {cfg.n_group} on a {cfg.router_score} router "
+            f"{'without' if bias is None else 'with'} a bias: the group "
+            "limit is the biased sigmoid router's")
+    kept = None
     if cfg.router_score == "sigmoid":
         probs = jax.nn.sigmoid(logits)
         if bias is None:        # a router without one (``router_bias``)
@@ -465,9 +500,13 @@ def route(logits, cfg: MoEConfig, bias=None):
         else:
             # the bias chooses and does not weigh; nothing is learned
             # through it
-            _, experts = jax.lax.top_k(
-                probs + jax.lax.stop_gradient(bias.astype(jnp.float32)),
-                cfg.top_k)
+            choice = probs + jax.lax.stop_gradient(bias.astype(jnp.float32))
+            if cfg.n_group > 1:
+                kept = kept_groups(choice, cfg)
+                choice = jnp.where(jnp.repeat(
+                    kept, cfg.n_experts // cfg.n_group, axis=1), choice,
+                    -jnp.inf)
+            _, experts = jax.lax.top_k(choice, cfg.top_k)
             weights = jnp.take_along_axis(probs, experts, axis=-1)
     elif cfg.router_score == "softmax":
         probs = jax.nn.softmax(logits, axis=-1)
@@ -478,7 +517,7 @@ def route(logits, cfg: MoEConfig, bias=None):
         weights = weights / weights.sum(axis=-1, keepdims=True)
     if cfg.route_scale != 1.0:
         weights = weights * cfg.route_scale
-    return weights, experts, probs
+    return weights, experts, probs, kept
 
 
 # Rows computed AT A TIME for a share of the experts: this margin (a
@@ -793,12 +832,22 @@ def feed_forward(h, lp, cfg: MoEConfig, mesh=None, rules=None, tp=None,
     with jax.named_scope("router"):
         logits = jnp.dot(x, _ll._dq(lp["router"], dt),
                          preferred_element_type=jnp.float32)        # [T, E]
-        weights, experts, probs = route(logits, cfg, lp.get("router_bias"))
+        weights, experts, probs, kept = route(logits, cfg,
+                                              lp.get("router_bias"))
         flat = experts.reshape(T * K)
     if cfg.experts_held is not None:
         y, stats = _held_experts(x, weights, experts, lp, cfg)
         with jax.named_scope("router"):
             stats = {"counts": _count(flat, E), **stats}
+            if kept is not None:
+                # the share of the tokens whose kept groups include the
+                # one the held experts lie in (they lie in one: the config
+                # says which, ``experts_held``'s first)
+                held_group = cfg.experts_held[1] // (E // cfg.n_group)
+                stats["group_kept"] = kept[:, held_group].mean(
+                    dtype=jnp.float32)
+                if cfg.report_groups:
+                    stats["groups"] = kept
         return _finish(y, stats, x, lp, cfg, logits, experts, probs, (B, S, D))
     with jax.named_scope("dispatch"):
         order = jnp.argsort(flat, stable=True).astype(jnp.int32)  # row -> slot

@@ -29,6 +29,9 @@ _FAMILIES = {
     # MiniCPM-SALA's model_type: sala.py preset "tiny" (block-set sparse
     # attention layers beside Lightning linear-attention layers)
     "minicpm_sala": "ray_tpu.models.sala",
+    # Ling 3.0's model_type: ling.py preset "tiny" (Kimi-Delta-Attention
+    # layers beside latent attention, group-limited sigmoid-routed experts)
+    "bailing_hybrid": "ray_tpu.models.ling",
     "vit": "ray_tpu.models.vit",
 }
 

@@ -1,0 +1,558 @@
+"""Chunked gated delta rule (the recurrence of Kimi Delta Attention,
+arXiv:2510.26692) for TPU in Pallas, with its backward.
+
+For every head with a [dk, dv] state S, step by step over a sequence:
+
+    S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} + beta_t k_t v_t^T
+    o_t = S_t^T q_t
+
+(q_t, k_t [dk]; v_t [dv]; g_t [dk] <= 0 the log of a decay a KEY CHANNEL;
+beta_t in (0, 1) a head). The state is first decayed channel by channel,
+then what it holds along k_t is erased by beta_t and beta_t k_t v_t^T is
+written: with u_t = beta_t (v_t - (Diag(exp(g_t)) S_{t-1})^T k_t) it reads
+S_t = Diag(exp(g_t)) S_{t-1} + k_t u_t^T. ``ops/ssd.py`` computes neither
+term: its decay is one scalar a head and it erases nothing.
+
+Written in chunks of C steps (the WY form of the delta rule), ``G_t`` the
+running sum of g inside the chunk, S the state entering it:
+
+    Akk[t, s] = sum_c k_tc k_sc exp(G_tc - G_sc)     s <  t
+    Aqk[t, s] = sum_c q_tc k_sc exp(G_tc - G_sc)     s <= t
+    X  = (I + Diag(beta) Akk)^-1                     unit lower triangular
+    V' = X Diag(beta) (V - (K * exp(G)) S)           the u_t of the chunk
+    O  = (Q * exp(G)) S + Aqk V'
+    S' = Diag(exp(G_C)) S + (K * exp(G_C - G))^T V'
+
+The decay between two steps is a VECTOR over the key channels, so there is
+no [C, C] decay block a head to multiply ``K K^T`` by: exp(G_tc - G_sc) has
+to be split into a factor of t and a factor of s, and exp(-G_s) alone
+overflows float32 after 18 steps at g = -5. The pair products are made a
+SUB-BLOCK of ``SUB`` = 16 rows at a time, both factors taken from the
+running sum r at the sub-block's MIDDLE (its row 7): rows carry exp(G_t -
+r), columns exp(r - G_s). At g >= -5 a step both lie within exp(+-40) =
+2.4e17 for every pair inside the sub-block (8 x 5 = 40 < 88, the most
+float32's exp takes; a sum over 128 channels of products of two such
+factors stays under 1e37), and a column BEFORE the sub-block carries a
+factor <= 1 that may underflow to 0 where the true product is under
+exp(-47). Taken from the sub-block's START the factors would reach exp(-80)
+and exp(80): finite, but the low parts of a float32 product at 1e-36 are
+denormal and flushed, and the last row of a sub-block held at the bound
+read 0.6% off. That is what the caller's bound on g buys (``lower_bound``,
+-5 a step and channel: Ling's ``kda_lower_bound`` with ``kda_safe_gate``);
+``SUB`` / 2 x |lower_bound| must stay under 88 and the op refuses a bound
+that does not. Columns after the sub-block are masked out of the result
+and their exponent is clamped.
+
+The inverse of the unit lower triangular [C, C] matrix is made by block
+forward substitution with products only (a TPU has no triangular solve):
+from blocks of one (the identity) the diagonal blocks double, inv([[P1,
+0], [A21, P2]]) = [[P1, 0], [-P2 A21 P1, P2]], which for all blocks at
+once is P <- P - P M P with M the lower-left halves; log2(C) rounds of two
+float32 products. No power of A is ever formed (the product form (I - A)(I
++ A^2)(I + A^4).. is exact too, but its powers grow like binomials where
+adjacent keys are alike).
+
+Two paths, chosen by the caller the way ``attn_impl`` chooses "xla" or
+"flash":
+
+- ``"xla"``: a ``lax.scan`` over the chunks, each chunk plain einsums over
+  the [C, C, dk] decay array and ``solve_triangular``; the chunk's body is
+  checkpointed, so the backward keeps the chunks' incoming states and
+  rebuilds the rest. The CPU tests, a mesh, small sizes. Gradients are
+  jax's own.
+- ``"pallas"``: one Mosaic call forward and one backward, grid (batch, head
+  block, chunk). The [C, C] blocks, the inverse and the state live in
+  VMEM; the chunks of a sequence are walked in order (the backward last to
+  first) with the state between them carried in VMEM scratch (float32,
+  stored [dv, dk] so that the channel decay runs along the lanes), and the
+  only state that reaches HBM is what the backward needs: each chunk's
+  incoming state ([B, S / C, H x dv, dk] float32), written by the forward
+  rule and read once. The backward rebuilds G, the pair blocks, the
+  inverse and V' from the chunk's inputs and that state. Running sums are
+  products with a 0/1 triangle in float32 at ``HIGHEST``, as are the pair
+  blocks and the inverse; products that contract over the state or the
+  steps take the inputs' type (bfloat16 in a model; float32 inputs are
+  multiplied at ``HIGHEST`` too) and accumulate in float32. A Mosaic call cannot be partitioned by GSPMD; the caller refuses
+  a mesh of several devices. Interpret mode off the TPU.
+
+Sequences that are no multiple of the chunk are padded at their end with
+steps that change nothing (k = 0, beta = 0, g = 0) and the outputs cut.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ray_tpu.util import tracing
+
+CHUNK = 64                 # steps a chunk (the source names none)
+SUB = 16                   # rows a sub-block of the pair products
+HEADS_PER_BLOCK = 2        # heads one kernel instance walks
+_EXP_MOST = 88.0           # exp of more overflows float32
+# checkpoint_name tags of what the kernel path's forward rule hands its
+# backward beside the inputs: the output and the chunks' incoming states. A
+# layer checkpoint that keeps BOTH runs no forward call in its replay
+RESIDUALS = ("kda_out", "kda_states")
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _use_interpret() -> bool:
+    return jax.default_backend() != "tpu"
+
+
+def _clamp(lower_bound: float, sub: int = SUB) -> float:
+    """The most a pair's factor's exponent reaches inside a sub-block of
+    ``sub`` rows whose factors are taken from its middle row."""
+    most = sub // 2 * -lower_bound
+    if not lower_bound < 0 or most >= _EXP_MOST:
+        raise ValueError(
+            f"gated_delta_rule: a gate down to {lower_bound} a step over "
+            f"sub-blocks of {sub} steps: exp({most:g}) overflows float32 "
+            f"(want sub / 2 x |lower_bound| < {_EXP_MOST:g})")
+    return float(most)
+
+
+# --- the plain path ---------------------------------------------------------
+
+
+def _chunk_xla(state, q, k, v, g, beta):
+    """One chunk for every batch and head: state [B, H, dk, dv]; q, k, g
+    [B, H, C, dk]; v [B, H, C, dv]; beta [B, H, C], all float32 ->
+    (the state leaving the chunk, o [B, H, C, dv])."""
+    c = q.shape[2]
+    cum = jnp.cumsum(g, axis=2)
+    seen = jnp.tril(jnp.ones((c, c), bool))
+    before = jnp.tril(jnp.ones((c, c), bool), -1)
+    decay = jnp.exp(jnp.where(
+        seen[:, :, None], cum[:, :, :, None, :] - cum[:, :, None, :, :],
+        -jnp.inf))                                        # [B, H, t, s, dk]
+    akk = jnp.where(before, jnp.einsum("bhtc,bhsc,bhtsc->bhts", k, k, decay),
+                    0.0)
+    aqk = jnp.einsum("bhtc,bhsc,bhtsc->bhts", q, k, decay)
+    grown = jnp.exp(cum)
+    rest = v - jnp.einsum("bhtc,bhcv->bhtv", k * grown, state)
+    unit = jnp.eye(c, dtype=g.dtype) + beta[..., None] * akk
+    new = jax.scipy.linalg.solve_triangular(
+        unit, beta[..., None] * rest, lower=True, unit_diagonal=True)
+    o = jnp.einsum("bhtc,bhcv->bhtv", q * grown, state) \
+        + jnp.einsum("bhts,bhsv->bhtv", aqk, new)
+    last = cum[:, :, -1:, :]
+    state = jnp.exp(last)[:, :, 0, :, None] * state + jnp.einsum(
+        "bhtc,bhtv->bhcv", k * jnp.exp(last - cum), new)
+    return state, o
+
+
+def _scan_xla(q, k, v, g, beta, state, chunk: int):
+    """q, k, g [B, S, H, dk], v [B, S, H, dv], beta [B, S, H], state
+    [B, H, dk, dv] -> o [B, S, H, dv], all float32."""
+    B, S, H, _ = q.shape
+
+    def chunks(a):          # [B, S, H, ...] -> [S / C, B, H, C, ...]
+        a = a.reshape(B, S // chunk, chunk, *a.shape[2:])
+        return jnp.moveaxis(jnp.moveaxis(a, 3, 2), 1, 0)
+
+    body = jax.checkpoint(lambda s, x: _chunk_xla(s, *x))
+    _, o = jax.lax.scan(body, state, tuple(map(chunks, (q, k, v, g, beta))))
+    return jnp.moveaxis(jnp.moveaxis(o, 0, 1), 2, 3).reshape(B, S, H, -1)
+
+
+# --- the kernels ------------------------------------------------------------
+
+_NN = (((1,), (0,)), ((), ()))     # a @ b
+_NT = (((1,), (1,)), ((), ()))     # a @ b^T
+_TN = (((0,), (0,)), ((), ()))     # a^T @ b
+
+
+def _dot(a, b, dims, mm):
+    """A product in the inputs' type ``mm``, accumulated in float32. For
+    float32 inputs at full precision: the MXU's default for float32
+    operands is ONE bfloat16 pass, which is bfloat16 inputs' product."""
+    return jax.lax.dot_general(
+        a.astype(mm), b.astype(mm), dims,
+        precision=_HIGHEST if mm == jnp.float32 else None,
+        preferred_element_type=jnp.float32)
+
+
+def _dot32(a, b, dims=_NN):
+    """A float32 product at full precision (sums of gates, the pair
+    blocks, the inverse)."""
+    return jax.lax.dot_general(a.astype(jnp.float32), b.astype(jnp.float32),
+                               dims, precision=_HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
+def _rows_cols(c: int):
+    return (jax.lax.broadcasted_iota(jnp.int32, (c, 1), 0),
+            jax.lax.broadcasted_iota(jnp.int32, (1, c), 1))
+
+
+def _column(tile, i: int):
+    """Column ``i`` of tile [C, n] as [C, 1]: a masked lane reduction."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, tile.shape[1]), 1)
+    return jnp.sum(jnp.where(lane == i, tile, 0.0), axis=1, keepdims=True)
+
+
+def _running_sum(g):
+    """g [C, dk] float32 -> its running sum down the rows, a product with
+    the lower 0/1 triangle."""
+    t, s = _rows_cols(g.shape[0])
+    return _dot32(jnp.where(t >= s, 1.0, 0.0), g)
+
+
+def _sub_factors(cum, lo: int, sub: int, clamp: float):
+    """The two factors of exp(G_t - G_s) for the rows ``lo .. lo + sub`` of
+    a chunk: (rows' exp(G_t - r) [sub, dk], columns' exp(min(r - G_s,
+    clamp)) [C, dk]) with r the running sum at the sub-block's middle."""
+    mid = lo + sub // 2 - 1
+    middle = cum[mid:mid + 1]
+    return (jnp.exp(cum[lo:lo + sub] - middle),
+            jnp.exp(jnp.minimum(middle - cum, clamp)))
+
+
+def _pair_blocks(q, k, cum, sub: int, clamp: float):
+    """(Aqk [C, C] with s <= t, Akk [C, C] with s < t) from float32 q, k
+    and the running sums cum [C, dk], a sub-block of rows at a time."""
+    c = k.shape[0]
+    qk, kk = [], []
+    for lo in range(0, c, sub):
+        rows, cols = _sub_factors(cum, lo, sub, clamp)
+        both = jnp.concatenate([q[lo:lo + sub] * rows, k[lo:lo + sub] * rows])
+        block = _dot32(both, k * cols, _NT)                  # [2 sub, C]
+        qk.append(block[:sub])
+        kk.append(block[sub:])
+    t, s = _rows_cols(c)
+    return (jnp.where(t >= s, jnp.concatenate(qk), 0.0),
+            jnp.where(t > s, jnp.concatenate(kk), 0.0))
+
+
+def _pair_grads(q, k, cum, d_qk, d_kk, sub: int, clamp: float):
+    """The way back through ``_pair_blocks``: d_qk and d_kk [C, C] (zero
+    where the blocks are masked) -> (dq, dk, dcum) [C, dk] float32."""
+    c = k.shape[0]
+    dq, dk_rows, dcum_rows = [], [], []
+    dk_cols = jnp.zeros_like(k)
+    for lo in range(0, c, sub):
+        rows, cols = _sub_factors(cum, lo, sub, clamp)
+        qs, ks = q[lo:lo + sub], k[lo:lo + sub]
+        d_both = jnp.concatenate([d_qk[lo:lo + sub], d_kk[lo:lo + sub]])
+        back = _dot32(d_both, k * cols) * jnp.concatenate([rows, rows])
+        dq.append(back[:sub])
+        dk_rows.append(back[sub:])
+        dcum_rows.append(qs * back[:sub] + ks * back[sub:])
+        both = jnp.concatenate([qs * rows, ks * rows])
+        dk_cols = dk_cols + _dot32(d_both, both, _TN) * cols
+    return (jnp.concatenate(dq), jnp.concatenate(dk_rows) + dk_cols,
+            jnp.concatenate(dcum_rows) - k * dk_cols)
+
+
+def _unit_lower_inverse(a):
+    """(I + a)^-1 for a [C, C] strictly lower triangular, float32: block
+    forward substitution, the diagonal blocks doubling (module docstring)."""
+    c = a.shape[0]
+    t, s = _rows_cols(c)
+    p = jnp.where(t == s, 1.0, 0.0)
+    bits = 0
+    while (1 << bits) < c:
+        # the lower-left half of every diagonal block of 2^(bits + 1)
+        m = jnp.where(((t >> (bits + 1)) == (s >> (bits + 1)))
+                      & (((t >> bits) & 1) == 1) & (((s >> bits) & 1) == 0),
+                      a, 0.0)
+        p = p - _dot32(_dot32(p, m), p)
+        bits += 1
+    return p
+
+
+def _chunk_forward(q, k, v, g, beta, st, mm, sub: int, clamp: float):
+    """What both kernels compute of one head's chunk: q, k, g [C, dk]
+    float32, v [C, dv] float32, beta [C, 1], st [dv, dk] the entering
+    state TRANSPOSED, float32 -> a dict of the chunk's forms."""
+    c = q.shape[0]
+    cum = _running_sum(g)
+    aqk, akk = _pair_blocks(q, k, cum, sub, clamp)
+    x = _unit_lower_inverse(beta * akk)
+    grown = jnp.exp(cum)
+    last = cum[c - 1:c]                                      # [1, dk]
+    to_end = jnp.exp(last - cum)
+    kg, qg, ke = k * grown, q * grown, k * to_end
+    rest = v - _dot(kg, st, _NT, mm)                         # [C, dv]
+    new = _dot(x, beta * rest, _NN, mm)                      # V'
+    return dict(cum=cum, aqk=aqk, akk=akk, x=x, grown=grown, last=last,
+                to_end=to_end, kg=kg, qg=qg, ke=ke, rest=rest, new=new)
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, s0_ref, o_ref, sin_ref,
+                s_scr, *, heads: int, sub: int, clamp: float):
+    """One instance per (batch, head block, chunk), chunks in order. q_ref,
+    k_ref [1, C, heads x dk]; v_ref [1, C, heads x dv]; g_ref as q_ref,
+    float32; beta_ref [1, 1, C, heads] float32; s0_ref [1, heads x dv, dk]
+    the sequence's initial state; o_ref as v_ref; sin_ref [1, 1, heads x
+    dv, dk] float32; s_scr [heads x dv, dk] float32, the state entering the
+    chunk."""
+    f32 = jnp.float32
+    mm = q_ref.dtype
+    dk, dv = q_ref.shape[2] // heads, v_ref.shape[2] // heads
+
+    @pl.when(pl.program_id(2) == 0)
+    def _start():
+        s_scr[...] = s0_ref[0]
+
+    sin_ref[0, 0] = s_scr[...]
+    betas = beta_ref[0, 0]
+    for i in range(heads):
+        kl, vl = slice(i * dk, (i + 1) * dk), slice(i * dv, (i + 1) * dv)
+        st = s_scr[vl, :]
+        f = _chunk_forward(
+            q_ref[0, :, kl].astype(f32), k_ref[0, :, kl].astype(f32),
+            v_ref[0, :, vl].astype(f32), g_ref[0, :, kl],
+            _column(betas, i), st, mm, sub, clamp)
+        o = _dot(f["qg"], st, _NT, mm) + _dot(f["aqk"], f["new"], _NN, mm)
+        o_ref[0, :, vl] = o.astype(o_ref.dtype)
+        s_scr[vl, :] = st * jnp.exp(f["last"]) \
+            + _dot(f["new"], f["ke"], _TN, mm)
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, sin_ref, do_ref,
+                dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, ds0_ref, ds_scr,
+                *, heads: int, sub: int, clamp: float):
+    """The mirror image, chunks last to first; ds_scr [heads x dv, dk] is
+    the gradient of the (transposed) state LEAVING the chunk, and after the
+    first chunk that of the initial state (ds0_ref [1, heads x dv, dk])."""
+    f32 = jnp.float32
+    mm = q_ref.dtype
+    c = q_ref.shape[1]
+    dk, dv = q_ref.shape[2] // heads, v_ref.shape[2] // heads
+
+    @pl.when(pl.program_id(2) == 0)
+    def _start():
+        ds_scr[...] = jnp.zeros_like(ds_scr)
+
+    t, s = _rows_cols(c)
+    betas = beta_ref[0, 0]
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (1, heads), 1)
+    dbetas = jnp.zeros(betas.shape, f32)
+    for i in range(heads):
+        kl, vl = slice(i * dk, (i + 1) * dk), slice(i * dv, (i + 1) * dv)
+        q, k = q_ref[0, :, kl].astype(f32), k_ref[0, :, kl].astype(f32)
+        v = v_ref[0, :, vl].astype(f32)
+        beta = _column(betas, i)
+        st = sin_ref[0, 0, vl, :]
+        dst = ds_scr[vl, :]
+        do = do_ref[0, :, vl]
+        f = _chunk_forward(q, k, v, g_ref[0, :, kl], beta, st, mm, sub, clamp)
+        # o = qg st^T + aqk new; the state leaving: st exp(last) + new^T ke
+        d_new = _dot(f["aqk"], do, _TN, mm) + _dot(f["ke"], dst, _NT, mm)
+        d_aqk = jnp.where(t >= s, _dot(do, f["new"], _NT, mm), 0.0)
+        d_qg = _dot(do, st, _NN, mm)
+        d_ke = _dot(f["new"], dst, _NN, mm)
+        decay = jnp.exp(f["last"])
+        d_last = jnp.sum(st * dst, axis=0, keepdims=True) * decay
+        d_st = dst * decay + _dot(do, f["qg"], _TN, mm)
+        # new = x (beta rest), x = (I + beta akk)^-1
+        d_y = _dot(f["x"], d_new, _TN, mm)
+        d_a = jnp.where(t > s, -_dot(d_y, f["new"], _NT, mm), 0.0)
+        d_rest = beta * d_y
+        d_beta = jnp.sum(d_y * f["rest"], axis=1, keepdims=True) \
+            + jnp.sum(d_a * f["akk"], axis=1, keepdims=True)
+        # rest = v - kg st^T
+        d_kg = -_dot(d_rest, st, _NN, mm)
+        d_st = d_st - _dot(d_rest, f["kg"], _TN, mm)
+        dq, dk_, dcum = _pair_grads(q, k, f["cum"], d_aqk, beta * d_a, sub,
+                                    clamp)
+        through = d_ke * f["ke"]
+        dcum = dcum + d_qg * f["qg"] + d_kg * f["kg"] - through
+        d_last = d_last + jnp.sum(through, axis=0, keepdims=True)
+        dcum = dcum + jnp.where(t == c - 1, d_last, 0.0)
+        dq_ref[0, :, kl] = (dq + d_qg * f["grown"]).astype(dq_ref.dtype)
+        dk_ref[0, :, kl] = (dk_ + d_kg * f["grown"]
+                            + d_ke * f["to_end"]).astype(dk_ref.dtype)
+        dv_ref[0, :, vl] = d_rest.astype(dv_ref.dtype)
+        # the transpose of a running sum: the running sum from the end
+        dg_ref[0, :, kl] = _dot32(jnp.where(s >= t, 1.0, 0.0), dcum)
+        dbetas = jnp.where(lanes == i, d_beta, dbetas)
+        ds_scr[vl, :] = d_st
+    dbeta_ref[0, 0] = dbetas
+    ds0_ref[0] = ds_scr[...]
+
+
+# --- the block plan and the calls -------------------------------------------
+
+
+def plan(*, B: int, S: int, H: int, dk: int, dv: int, chunk: int, dtype,
+         impl: str, lower_bound: float = -5.0) -> dict:
+    """The op's block plan (also the attributes of ``kda.plan``): the path,
+    the chunk and the sub-block of the pair products, how many heads an
+    instance walks, the VMEM one instance of the backward call holds (its
+    blocks twice, Mosaic double-buffers; the state scratch; the float32
+    forms of a head: a dozen [C, C], a dozen [C, dk], half a dozen [C,
+    dv], three [dv, dk]), the bytes of the chunks' incoming states the
+    forward rule keeps for the backward, and the HBM bytes the two calls
+    move for one head and sequence."""
+    heads = min(HEADS_PER_BLOCK, H)
+    while H % heads:
+        heads -= 1
+    item = jnp.dtype(dtype).itemsize
+    c = chunk
+    steps = -(-S // c) * c
+    blocks = (heads * c * (2 * dk + 2 * dv) * item        # q, k, v, do
+              + heads * c * (2 * dk + dv) * item          # dq, dk, dv
+              + 2 * heads * c * dk * 4                    # g, dg
+              + 2 * c * 128 * 4                           # beta, dbeta
+              + 2 * heads * dv * dk * 4)                  # state in, ds0
+    forms = 12 * c * c * 4 + 12 * c * dk * 4 + 6 * c * dv * 4 \
+        + 3 * dv * dk * 4
+    states = B * (steps // c) * H * dv * dk * 4
+    hbm = steps * ((2 * dk + dv) * item + dk * 4 + 4) * 2 \
+        + steps * dv * item * 2 + 2 * (steps // c) * dv * dk * 4
+    on = impl == "pallas"
+    return {"path": impl, "S": S, "chunk": c, "sub_block": SUB,
+            "heads_per_block": heads, "lower_bound": lower_bound,
+            "vmem_bytes": 2 * blocks + heads * dv * dk * 4 + forms if on
+            else 0,
+            "state_bytes_kept": states,
+            "hbm_bytes_per_head": hbm if on else 0}
+
+
+def _specs(c: int, heads: int, dk: int, dv: int):
+    keys = pl.BlockSpec((1, c, heads * dk), lambda b, h, n: (b, n, h))
+    values = pl.BlockSpec((1, c, heads * dv), lambda b, h, n: (b, n, h))
+    beta = pl.BlockSpec((1, 1, c, heads), lambda b, h, n: (b, h, n, 0))
+    first = pl.BlockSpec((1, heads * dv, dk), lambda b, h, n: (b, h, 0))
+    states = pl.BlockSpec((1, 1, heads * dv, dk),
+                          lambda b, h, n: (b, n, h, 0))
+    return keys, values, beta, states, first
+
+
+def _backwards(spec, last: int):
+    """The same block, its chunk index walked last to first."""
+    index = spec.index_map
+    return pl.BlockSpec(spec.block_shape,
+                        lambda b, h, n: index(b, h, last - n))
+
+
+def _forward_call(q, k, v, g, beta, s0, *, chunk, heads, dk, dv, clamp):
+    B, S, _ = q.shape
+    H = q.shape[2] // dk
+    keys, values, betas, states, first = _specs(chunk, heads, dk, dv)
+    call = pl.pallas_call(
+        functools.partial(_fwd_kernel, heads=heads, sub=min(SUB, chunk),
+                          clamp=clamp),
+        grid=(B, H // heads, S // chunk),
+        in_specs=[keys, keys, values, keys, betas, first],
+        out_specs=[values, states],
+        out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype),
+                   jax.ShapeDtypeStruct((B, S // chunk, H * dv, dk),
+                                        jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((heads * dv, dk), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=_use_interpret(),
+    )
+    with jax.named_scope("kda.fwd.pallas"):         # kda.plan's path
+        return call(q, k, v, g, beta, s0)
+
+
+def _backward_call(q, k, v, g, beta, s_in, do, *, chunk, heads, dk, dv,
+                   clamp):
+    B, S, _ = q.shape
+    H = q.shape[2] // dk
+    *walked, first = _specs(chunk, heads, dk, dv)
+    keys, values, betas, states = (
+        _backwards(spec, S // chunk - 1) for spec in walked)
+    call = pl.pallas_call(
+        functools.partial(_bwd_kernel, heads=heads, sub=min(SUB, chunk),
+                          clamp=clamp),
+        grid=(B, H // heads, S // chunk),
+        in_specs=[keys, keys, values, keys, betas, states, values],
+        out_specs=[keys, keys, values, keys, betas, first],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype),
+                   jax.ShapeDtypeStruct(g.shape, jnp.float32),
+                   jax.ShapeDtypeStruct(beta.shape, jnp.float32),
+                   jax.ShapeDtypeStruct((B, H * dv, dk), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((heads * dv, dk), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=_use_interpret(),
+    )
+    with jax.named_scope("kda.bwd.pallas"):
+        return call(q, k, v, g, beta, s_in, do)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))
+def _scan_pallas(q, k, v, g, beta, s0, chunk, heads, dk, dv, clamp):
+    return _forward_call(q, k, v, g, beta, s0, chunk=chunk, heads=heads,
+                         dk=dk, dv=dv, clamp=clamp)[0]
+
+
+def _scan_pallas_fwd(q, k, v, g, beta, s0, chunk, heads, dk, dv, clamp):
+    o, s_in = _forward_call(q, k, v, g, beta, s0, chunk=chunk, heads=heads,
+                            dk=dk, dv=dv, clamp=clamp)
+    o, s_in = (checkpoint_name(a, name) for a, name in zip((o, s_in),
+                                                           RESIDUALS))
+    return o, (q, k, v, g, beta, s_in)
+
+
+def _scan_pallas_bwd(chunk, heads, dk, dv, clamp, res, do):
+    q, k, v, g, beta, s_in = res
+    return _backward_call(q, k, v, g, beta, s_in, do.astype(v.dtype),
+                          chunk=chunk, heads=heads, dk=dk, dv=dv, clamp=clamp)
+
+
+_scan_pallas.defvjp(_scan_pallas_fwd, _scan_pallas_bwd)
+
+
+def gated_delta_rule(q, k, v, g, beta, *, initial_state=None,
+                     chunk: int = CHUNK, impl: str = "xla",
+                     lower_bound: float = -5.0):
+    """q, k [B, S, H, dk], v [B, S, H, dv], g [B, S, H, dk] float32 (the
+    log of the decay a step and key channel, in [lower_bound, 0]), beta
+    [B, S, H] float32, initial_state [B, H, dk, dv] float32 or None (zero)
+    -> o [B, S, H, dv] in v's type: the recurrence of the module docstring.
+    Differentiable in all six on both paths. q and k arrive as the model
+    made them (normed, q scaled): the op scales nothing."""
+    B, S, H, dk = q.shape
+    dv = v.shape[-1]
+    if impl not in ("xla", "pallas"):
+        raise ValueError(f"gated_delta_rule impl must be 'xla' or 'pallas', "
+                         f"got {impl!r}")
+    # a sequence shorter than a chunk is ONE chunk, the least power of two
+    # that holds it
+    chunk = min(chunk, max(SUB, 1 << (S - 1).bit_length()))
+    if chunk % SUB or chunk & (chunk - 1):
+        raise ValueError(f"gated_delta_rule: a chunk of {chunk} steps; want "
+                         f"a power of two and a multiple of {SUB}")
+    clamp = _clamp(lower_bound)
+    said = plan(B=B, S=S, H=H, dk=dk, dv=dv, chunk=chunk, dtype=q.dtype,
+                impl=impl, lower_bound=lower_bound)
+    tracing.plan("kda.plan", said)
+    f32 = jnp.float32
+    g, beta = g.astype(f32), beta.astype(f32)
+    state = jnp.zeros((B, H, dk, dv), f32) if initial_state is None \
+        else initial_state.astype(f32)
+    pad = -S % chunk
+    if pad:     # steps that change nothing: k 0, beta 0, g 0
+        q, k, v, g, beta = (jnp.pad(a, [(0, 0), (0, pad)] + [(0, 0)] * (
+            a.ndim - 2)) for a in (q, k, v, g, beta))
+    if impl == "xla":
+        with jax.named_scope("kda.fwd.xla"):    # jax transposes it itself
+            o = _scan_xla(q.astype(f32), k.astype(f32), v.astype(f32), g,
+                          beta, state, chunk)
+        return o[:, :S].astype(v.dtype)
+    heads = said["heads_per_block"]
+    if (dk % 128 or dv % 128) and not _use_interpret():
+        raise ValueError(f"gated_delta_rule: heads of {dk} keys and {dv} "
+                         "values; the kernel takes whole lane tiles of 128")
+    steps = S + pad
+    flat = lambda a: a.reshape(B, steps, -1)                   # noqa: E731
+    by_block = beta.reshape(B, steps, H // heads, heads).transpose(0, 2, 1, 3)
+    s0 = state.transpose(0, 1, 3, 2).reshape(B, H * dv, dk)
+    o = _scan_pallas(flat(q), flat(k), flat(v), flat(g), by_block, s0, chunk,
+                     heads, dk, dv, clamp)
+    return o.reshape(B, steps, H, dv)[:, :S]

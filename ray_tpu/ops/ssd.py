@@ -74,6 +74,12 @@ layouts) has a gradient rule of its own that keeps x, dt and A and no
 more; a head's dt reaches its P lanes, and ``du x`` is summed over them,
 as a product with a 0/1 matrix (``_over_lanes``, ``_per_head``).
 
+What this op does NOT compute: a term that ERASES what the state holds
+along a key (the delta rule's ``I - beta k k^T``) and a decay that is a
+VECTOR over the state's channels; a head's decay here is one scalar a step
+(``exp(dt_t A)``) or one constant. Kimi Delta Attention's recurrence, which
+has both, is ``ops/delta_rule.py`` (``gated_delta_rule``).
+
 S must be a multiple of the chunk (pad upstream).
 """
 

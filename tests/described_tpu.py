@@ -215,6 +215,7 @@ _CELL_STEPS = {
                                          "hybrid"),
     "train-minicpmsala-l4-s16384-b1": ("model_sala", "sala_config", "sala"),
     "train-lfm2-ep4-s16384-b1": ("model_lfm2", "hybrid_config", "hybrid"),
+    "train-ling3flash-ep32-s16384-b1": ("model_ling", "ling_config", "ling"),
 }
 
 
@@ -239,7 +240,8 @@ def _compile_cell_step(name, topo, monkeypatch):
     module, make, family = _CELL_STEPS[name]
     cfg = getattr(importlib.import_module(f"benchmark.{module}"), make)(
         cell["config"], **{k: recipe[k] for k in (
-            "attn_impl", "gmm_impl", "ssd_impl", "remat", "f32_logits")
+            "attn_impl", "gmm_impl", "ssd_impl", "kda_impl", "remat",
+            "f32_logits")
             if k in recipe})
     fam = importlib.import_module(f"ray_tpu.models.{family}")
     said = []

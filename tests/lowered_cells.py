@@ -35,6 +35,7 @@ KINDS = {
     "train_alternating": ("model_nemotron", "hybrid_config", "hybrid"),
     "train_blockset": ("model_sala", "sala_config", "sala"),
     "train_shortconv": ("model_lfm2", "hybrid_config", "hybrid"),
+    "train_kda": ("model_ling", "ling_config", "ling"),
 }
 V5E_LIMIT = 16_909_336_064        # what a v5e chip states (here none does)
 
@@ -56,7 +57,8 @@ def lowered(name, topo):
     module, make, family = KINDS[cell["kind"]]
     cfg = getattr(importlib.import_module(f"benchmark.{module}"), make)(
         cell["config"], **{k: recipe[k] for k in (
-            "attn_impl", "gmm_impl", "ssd_impl", "remat", "f32_logits")
+            "attn_impl", "gmm_impl", "ssd_impl", "kda_impl", "remat",
+            "f32_logits")
             if k in recipe})
     fam = importlib.import_module(f"ray_tpu.models.{family}")
     mesh = build_mesh(MeshSpec(**recipe["mesh"]),

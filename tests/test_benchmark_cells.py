@@ -36,7 +36,9 @@ RECIPE_KEYS = {"train": ("attn_impl", "remat", "f32_logits"),
                "train_blockset": ("attn_impl", "ssd_impl", "remat",
                                   "f32_logits"),
                "train_shortconv": ("attn_impl", "gmm_impl", "remat",
-                                   "f32_logits")}
+                                   "f32_logits"),
+               "train_kda": ("attn_impl", "gmm_impl", "kda_impl", "remat",
+                             "f32_logits")}
 # published config.json key -> the program's field
 WIDTHS = {"hidden_size": "d_model", "num_hidden_layers": "n_layers",
           "num_attention_heads": "n_heads",
@@ -62,8 +64,9 @@ def test_cell_program_config_builds_at_its_published_widths(name):
     import jax.numpy as jnp
 
     from benchmark import (model, model_commanda, model_glm, model_glm52,
-                           model_granite, model_lfm2, model_mellum,
-                           model_moe, model_nemotron, model_sala, resolve)
+                           model_granite, model_lfm2, model_ling,
+                           model_mellum, model_moe, model_nemotron,
+                           model_sala, resolve)
 
     cell = resolve.cell(name)
     kind, conf, recipe = cell["kind"], cell["config"], cell["train"]
@@ -76,7 +79,8 @@ def test_cell_program_config_builds_at_its_published_widths(name):
              "train_sparse": model_glm52.latent_config,
              "train_alternating": model_nemotron.hybrid_config,
              "train_blockset": model_sala.sala_config,
-             "train_shortconv": model_lfm2.hybrid_config}[kind]
+             "train_shortconv": model_lfm2.hybrid_config,
+             "train_kda": model_ling.ling_config}[kind]
     passed = {k: recipe[k] for k in RECIPE_KEYS[kind] if k in recipe}
     cfg = build(conf, **passed)
 
@@ -90,7 +94,8 @@ def test_cell_program_config_builds_at_its_published_widths(name):
               "train_sparse": model_glm52.HF_TO_FIELD,
               "train_alternating": model_nemotron.HF_TO_FIELD,
               "train_blockset": model_sala.HF_TO_FIELD,
-              "train_shortconv": model_lfm2.HF_TO_FIELD}[kind]
+              "train_shortconv": model_lfm2.HF_TO_FIELD,
+              "train_kda": model_ling.HF_TO_FIELD}[kind]
     for key, field in widths.items():
         assert getattr(cfg, field) == conf[key], (name, key)
     if kind == "train_parallel":
@@ -175,6 +180,36 @@ def test_cell_program_config_builds_at_its_published_widths(name):
         from ray_tpu.models import hybrid
         assert "lm_head" not in hybrid.param_specs(cfg)
         assert sum(n for _, n in hybrid.layer_runs(cfg)) \
+            == conf["num_hidden_layers"]
+    if kind == "train_kda":
+        # both halves' widths, the taps, the gate's bound, the period, the
+        # groups and both feed-forward widths are the published keys' (the
+        # map above); no query latent; the router's width, the group served
+        # and the experts held the deployment's, inside that group
+        assert {"head_dim", "kv_lora_rank", "qk_nope_head_dim",
+                "qk_rope_head_dim", "v_head_dim", "short_conv_kernel_size",
+                "kda_lower_bound", "layer_group_size", "n_group",
+                "topk_group", "moe_intermediate_size", "intermediate_size",
+                "routed_scaling_factor"} <= set(widths)
+        dep = conf["deployment"]
+        assert conf["q_lora_rank"] is None and cfg.q_rank == 0
+        assert cfg.n_experts == dep["router_experts"]
+        assert cfg.experts_held == (conf["num_experts"], dep["experts_first"])
+        per_group = cfg.n_experts // cfg.n_group
+        assert dep["experts_first"] // per_group == dep["group_held"] == (
+            dep["experts_first"] + conf["num_experts"] - 1) // per_group
+        period = conf["layer_group_size"]
+        assert cfg.kinds == tuple(
+            ("mla" if (i + 1) % period == 0 else "kda")
+            + (".dense" if i < conf["first_k_dense_replace"] else "")
+            for i in range(conf["num_hidden_layers"]))
+        assert cfg.v_dim < cfg.head_dim == conf["qk_nope_head_dim"] \
+            + conf["qk_rope_head_dim"] and cfg.attn_gate and not cfg.n_mtp
+        assert cfg.shared_d_ff == conf["moe_shared_expert_intermediate_size"]
+        assert (cfg.router_score, cfg.norm_topk, cfg.rope_dim) == (
+            "sigmoid", True, conf["rotary_dim"])
+        from ray_tpu.models import ling
+        assert sum(n for _, n in ling.layer_runs(cfg)) \
             == conf["num_hidden_layers"]
     if kind == "train_blockset":
         # the heads, the stated head width, the SwiGLU's width and the

@@ -285,9 +285,12 @@ def test_a_compile_says_its_program_and_what_the_cache_did(
     assert miss["attrs"]["seconds"] > 0
     assert hit["attrs"]["cache"] == "hit" and hit["attrs"]["retrieval_s"] > 0
     # the Python trace and the lowering of so small a function are under
-    # the floor, with tracing on too
+    # the floor, with tracing on too: a stage is kept only where it took
+    # the floor or longer (on a loaded machine the lowering of even this
+    # function has: six workers compiling whole steps beside it, PR 58)
     assert [s["name"] for seen in mine for s in seen
-            if s["name"] != "xla.compile"] == []
+            if s["name"] != "xla.compile"
+            and s["dur"] < compile_cache.KEPT_S] == []
 
 
 def test_a_compile_outside_the_cache_says_off(recorder):

@@ -333,7 +333,7 @@ def test_one_layer_one_attention_half_and_the_plans(monkeypatch):
 def test_the_router_without_a_bias():
     cfg = tiny()
     logits = jax.random.normal(jax.random.PRNGKey(0), (16, 8))
-    w, e, s = moe.route(logits, cfg)
+    w, e, s, _ = moe.route(logits, cfg)
     np.testing.assert_allclose(s, jax.nn.sigmoid(logits), atol=1e-6)
     top = jnp.sort(s, -1)[:, -2:].sum(-1)
     np.testing.assert_allclose(w.sum(-1), 1.0, atol=1e-6)
@@ -342,7 +342,7 @@ def test_the_router_without_a_bias():
     # with a bias leaf (GLM's) the bias chooses and does not weigh
     biased = cfg.replace(router_bias=True)
     bias = jnp.zeros(8).at[3].set(10.0)
-    _, e2, _ = moe.route(logits, biased, bias)
+    _, e2, _, _ = moe.route(logits, biased, bias)
     assert bool(jnp.all(jnp.any(e2 == 3, axis=-1)))
     assert "router_bias" in moe.init_params(
         jax.random.PRNGKey(0), biased)["layers"][0]

@@ -267,7 +267,7 @@ def test_two_matrix_experts_with_a_squared_relu(held):
     with jax.default_matmul_precision("highest"):
         y, stats = moe.feed_forward(x, lp, cfg)
         logits = x[0] @ lp["router"]
-        weights, experts, _ = moe.route(logits, cfg)
+        weights, experts, _, _ = moe.route(logits, cfg)
         want = jnp.square(jax.nn.relu(x[0] @ lp["ws_up"])) @ lp["ws_down"]
         first = held[1] if held else 0
         for e in range(cfg.n_held):

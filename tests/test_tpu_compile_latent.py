@@ -407,6 +407,37 @@ def test_ssd_scan_compiles_at_lightning_widths(chunk, one_chip,
     assert plan["vmem_bytes"] <= 16 * 2 ** 20, plan
 
 
+def test_delta_rule_calls_compile_at_the_ling_cells_shape(one_chip,
+                                                          on_chip_branch):
+    """The chunked gated delta rule (32 heads of 128 keys and values over
+    16,384 steps, a gate a key channel in float32, chunks of 64) lowers for
+    a v5e at the Ling-3.0-flash cell's shape: two Mosaic calls, the chunks'
+    incoming states the only state among their results, and what one
+    instance holds in VMEM under the plan's count."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import delta_rule as dr
+
+    B, S, H, d = 1, 16384, 32, 128
+    x = _sds((B, S, H, d), jnp.bfloat16, one_chip)
+    g = _sds((B, S, H, d), jnp.float32, one_chip)
+    beta = _sds((B, S, H), jnp.float32, one_chip)
+
+    def loss(q, k, v, g, beta):
+        return dr.gated_delta_rule(q, k, v, g, beta, chunk=64,
+                                   impl="pallas").astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        x, x, x, g, beta).compile().as_text()
+    assert text.count("tpu_custom_call") == 2, text[:2000]
+    assert f"f32[{B},{S // 64},{H * d},{d}]" in text
+    plan = dr.plan(B=B, S=S, H=H, dk=d, dv=d, chunk=64, dtype=jnp.bfloat16,
+                   impl="pallas")
+    assert plan["state_bytes_kept"] == 536_870_912
+    assert plan["vmem_bytes"] <= 16 * 2 ** 20, plan
+
+
 # The flash calls of the cells that do NOT stream, and the four calls of the
 # attention over a set at the GLM-5.2 cell's shape: sha256[:16] of the jaxpr
 # of the call and its gradient (the kernels' bodies are in it), taken at PR
