@@ -47,10 +47,39 @@ The inverse of the unit lower triangular [C, C] matrix is made by block
 forward substitution with products only (a TPU has no triangular solve):
 from blocks of one (the identity) the diagonal blocks double, inv([[P1,
 0], [A21, P2]]) = [[P1, 0], [-P2 A21 P1, P2]], which for all blocks at
-once is P <- P - P M P with M the lower-left halves; log2(C) rounds of two
-float32 products. No power of A is ever formed (the product form (I - A)(I
-+ A^2)(I + A^4).. is exact too, but its powers grow like binomials where
-adjacent keys are alike).
+once is P <- P - P M P with M the lower-left halves: log2(C) rounds. No
+power of A is ever formed (the product form (I - A)(I + A^2)(I + A^4).. is
+exact too, but its powers grow like binomials where adjacent keys are
+alike). On a v5e the two calls' time is the CHAIN of these rounds, each
+waiting on the last, far more than the size of their products (about 0.2
+us a [128, 128] float32 product at ``HIGHEST`` in the chain, 0.14 us a
+[64, 64] one, 0.05 us a pair product, which waits on nothing: PERF.md 6,
+PR 59), so the rounds are cut to the fewest and widest products that are
+the same mathematics at the same precision:
+
+- the first round's P is the identity, so P - P M P is I - M: no product;
+- the second and third round (from blocks of 2 and of 4) are
+  ``_shifted_round``: P has one subdiagonal, then three, so M P and P (M P)
+  are a lane shift and a row shift a subdiagonal with a float32
+  multiply-add each on the vector unit (exact float32 products, where
+  ``HIGHEST`` is six bfloat16 passes); from blocks of 8 the seven shifts a
+  side cost more than the two products they spare;
+- the other log2(C) - 3 rounds are two float32 products each, as wide as
+  the MXU's tile: the heads of a kernel instance stand two (at C = 64)
+  side by side in ONE block-diagonal [128, 128] matrix whose rounds stop
+  at the chunk, so a round is two products for both heads, and what lies
+  between the heads stays exactly 0;
+- from blocks of 8 rows (a sublane tile) a round multiplies the rows of
+  the odd blocks alone: P M P is zero in the others, so half the rows go
+  through the two products, bit for bit the same.
+
+A head and chunk of 64 steps then costs 7 float32 products forward (4 of
+the pair blocks, half of 6 of the inverse) where a [64, 64] inverse a head
+with all six rounds cost 17, and 15 backward (8 more on the way back
+through the pair blocks) for 25. A chunk of 128 with a head an inverse
+counts the same products a step and timed 6 ms a layer and step behind
+(twice the pair products' columns, and the float32 reading of dg twice as
+far from the plain path: the running sum reaches twice as far).
 
 Two paths, chosen by the caller the way ``attn_impl`` chooses "xla" or
 "flash":
@@ -61,19 +90,27 @@ Two paths, chosen by the caller the way ``attn_impl`` chooses "xla" or
   rebuilds the rest. The CPU tests, a mesh, small sizes. Gradients are
   jax's own.
 - ``"pallas"``: one Mosaic call forward and one backward, grid (batch, head
-  block, chunk). The [C, C] blocks, the inverse and the state live in
-  VMEM; the chunks of a sequence are walked in order (the backward last to
-  first) with the state between them carried in VMEM scratch (float32,
-  stored [dv, dk] so that the channel decay runs along the lanes), and the
-  only state that reaches HBM is what the backward needs: each chunk's
-  incoming state ([B, S / C, H x dv, dk] float32), written by the forward
-  rule and read once. The backward rebuilds G, the pair blocks, the
-  inverse and V' from the chunk's inputs and that state. Running sums are
-  products with a 0/1 triangle in float32 at ``HIGHEST``, as are the pair
-  blocks and the inverse; products that contract over the state or the
-  steps take the inputs' type (bfloat16 in a model; float32 inputs are
-  multiplied at ``HIGHEST`` too) and accumulate in float32. A Mosaic call cannot be partitioned by GSPMD; the caller refuses
-  a mesh of several devices. Interpret mode off the TPU.
+  block, chunk), a block ``HEADS_PER_BLOCK`` heads (four or eight a block
+  time 2 and 3 ms a layer and step better, and the step program, which
+  compiles each of its 21 calls, 8 and 23 s longer). The [C, C]
+  blocks, the inverse and the state live in VMEM; the chunks of a sequence
+  are walked in order (the backward last to first) with the state between
+  them carried in VMEM scratch (float32, stored [dv, dk] so that the
+  channel decay runs along the lanes), and the only state that reaches HBM
+  is what the backward needs: each chunk's incoming state ([B, S / C, H x
+  dv, dk] float32), written by the forward rule and read once. The
+  backward rebuilds G, the pair blocks, the inverse and V' from the
+  chunk's inputs and that state. The running sums (G down the rows, dg's
+  up them) are log2(C) shifted adds of float32 rows on the vector unit, no
+  product: the sum heads the chunk's chain of dependent products, and as
+  a product with the 0/1 triangle it timed 5 ms a layer and step behind
+  (by three passes, which is exact: the triangle has no middle and low
+  part in bfloat16; 6 ms by the six of ``HIGHEST``). The pair blocks and
+  the inverse are float32 products at ``HIGHEST``; products that contract
+  over the state or the steps take the inputs' type (bfloat16 in a model;
+  float32 inputs are multiplied at ``HIGHEST`` too) and accumulate in
+  float32. A Mosaic call cannot be partitioned by GSPMD; the caller
+  refuses a mesh of several devices. Interpret mode off the TPU.
 
 Sequences that are no multiple of the chunk are padded at their end with
 steps that change nothing (k = 0, beta = 0, g = 0) and the outputs cut.
@@ -94,6 +131,11 @@ from ray_tpu.util import tracing
 CHUNK = 64                 # steps a chunk (the source names none)
 SUB = 16                   # rows a sub-block of the pair products
 HEADS_PER_BLOCK = 2        # heads one kernel instance walks
+_SIDE = 128                # the MXU's tile: the side an inverse fills
+SHIFTED_BLOCKS = 4         # the inverse's rounds from blocks of up to so many
+#                            rows are shifted multiply-adds, no product
+HALF_ROWS = 8              # from blocks of so many rows (a sublane tile) a
+#                            round multiplies the odd blocks' rows alone
 _EXP_MOST = 88.0           # exp of more overflows float32
 # checkpoint_name tags of what the kernel path's forward rule hands its
 # backward beside the inputs: the output and the chunks' incoming states. A
@@ -169,19 +211,31 @@ _NT = (((1,), (1,)), ((), ()))     # a @ b^T
 _TN = (((0,), (0,)), ((), ()))     # a^T @ b
 
 
+_TALLIES: list = []     # open counts [float32 products, MXU passes]: plan()
+
+
+def _count(f32: int, passes: int):
+    """Counts a product the kernels' code emits while ``plan()`` traces."""
+    for tally in _TALLIES:
+        tally[0] += f32
+        tally[1] += passes
+
+
 def _dot(a, b, dims, mm):
     """A product in the inputs' type ``mm``, accumulated in float32. For
     float32 inputs at full precision: the MXU's default for float32
     operands is ONE bfloat16 pass, which is bfloat16 inputs' product."""
-    return jax.lax.dot_general(
-        a.astype(mm), b.astype(mm), dims,
-        precision=_HIGHEST if mm == jnp.float32 else None,
-        preferred_element_type=jnp.float32)
+    if mm == jnp.float32:
+        return _dot32(a, b, dims)
+    _count(0, 1)
+    return jax.lax.dot_general(a.astype(mm), b.astype(mm), dims,
+                               preferred_element_type=jnp.float32)
 
 
 def _dot32(a, b, dims=_NN):
-    """A float32 product at full precision (sums of gates, the pair
-    blocks, the inverse)."""
+    """A float32 product at full precision (the pair blocks, the inverse):
+    six bfloat16 passes of the MXU."""
+    _count(1, 6)
     return jax.lax.dot_general(a.astype(jnp.float32), b.astype(jnp.float32),
                                dims, precision=_HIGHEST,
                                preferred_element_type=jnp.float32)
@@ -198,11 +252,21 @@ def _column(tile, i: int):
     return jnp.sum(jnp.where(lane == i, tile, 0.0), axis=1, keepdims=True)
 
 
-def _running_sum(g):
-    """g [C, dk] float32 -> its running sum down the rows, a product with
-    the lower 0/1 triangle."""
-    t, s = _rows_cols(g.shape[0])
-    return _dot32(jnp.where(t >= s, 1.0, 0.0), g)
+def _running_sum(x, from_end: bool = False):
+    """x [C, n] float32 -> its running sum down the rows (up them,
+    ``from_end``) by log2(C) shifted adds on the vector unit: after the
+    round of shift 2^j a row holds the sum of the 2^(j + 1) rows that end
+    (start) at it. No product (module docstring)."""
+    c = x.shape[0]
+    t = jax.lax.broadcasted_iota(jnp.int32, (c, 1), 0)
+    shift = 1
+    while shift < c:
+        if from_end:
+            x = x + jnp.where(t < c - shift, pltpu.roll(x, c - shift, 0), 0.0)
+        else:
+            x = x + jnp.where(t >= shift, pltpu.roll(x, shift, 0), 0.0)
+        shift *= 2
+    return x
 
 
 def _sub_factors(cum, lo: int, sub: int, clamp: float):
@@ -251,131 +315,215 @@ def _pair_grads(q, k, cum, d_qk, d_kk, sub: int, clamp: float):
             jnp.concatenate(dcum_rows) - k * dk_cols)
 
 
-def _unit_lower_inverse(a):
-    """(I + a)^-1 for a [C, C] strictly lower triangular, float32: block
-    forward substitution, the diagonal blocks doubling (module docstring)."""
-    c = a.shape[0]
-    t, s = _rows_cols(c)
-    p = jnp.where(t == s, 1.0, 0.0)
-    bits = 0
-    while (1 << bits) < c:
-        # the lower-left half of every diagonal block of 2^(bits + 1)
-        m = jnp.where(((t >> (bits + 1)) == (s >> (bits + 1)))
-                      & (((t >> bits) & 1) == 1) & (((s >> bits) & 1) == 0),
-                      a, 0.0)
-        p = p - _dot32(_dot32(p, m), p)
-        bits += 1
+def _shifted_round(p, m, b: int):
+    """P M P for P unit lower triangular with diagonal blocks of ``b`` rows
+    (so b - 1 subdiagonals), float32, without a product: (M P)[t, s] = M[t,
+    s] + sum_d M[t, s + d] P[s + d, s] and (P X)[t, s] = X[t, s] + sum_d
+    P[t, t - d] X[t - d, s], a lane shift and a row shift a subdiagonal on
+    the vector unit. A shift that wraps round meets a subdiagonal's zero."""
+    n = p.shape[0]
+    t, s = _rows_cols(n)
+    diagonals = [jnp.where(t - s == d, p, 0.0) for d in range(1, b)]
+    mp = m
+    for d, sub in enumerate(diagonals, 1):
+        mp = mp + pltpu.roll(m, n - d, 1) * jnp.sum(sub, axis=0,
+                                                    keepdims=True)
+    out = mp
+    for d, sub in enumerate(diagonals, 1):
+        out = out + jnp.sum(sub, axis=1, keepdims=True) * pltpu.roll(mp, d, 0)
+    return out
+
+
+def _unit_lower_inverse(a, block: int | None = None):
+    """(I + a)^-1, float32, by block forward substitution with the diagonal
+    blocks doubling (module docstring). a [n, n] is strictly lower
+    triangular, or block diagonal with such blocks of ``block`` rows (the
+    heads of a kernel instance side by side): the rounds stop at the
+    block, and what lies between the blocks stays exactly 0."""
+    n = a.shape[0]
+    t, s = _rows_cols(n)
+
+    def lower_left(b):      # of every diagonal block of 2 b rows: t and s
+        # agree above bit b, which t has and s has not
+        return jnp.where(((t ^ s) < 2 * b) & ((t & ~s & b) != 0), a, 0.0)
+
+    # the first round's P is the identity: P - P M P is I - M, no product
+    p = jnp.where(t == s, 1.0, 0.0) - lower_left(1)
+    b = 2
+    while b < (block or n):
+        m = lower_left(b)
+        if b <= SHIFTED_BLOCKS:
+            p = p - _shifted_round(p, m, b)
+        elif b < HALF_ROWS:
+            p = p - _dot32(_dot32(p, m), p)
+        else:
+            # P M P is zero but in the rows of the odd blocks: half the
+            # rows go through the two products (whole sublane tiles)
+            odd = jnp.concatenate([p[i:i + b] for i in range(b, n, 2 * b)])
+            odd = odd - _dot32(_dot32(odd, m), p)
+            p = jnp.concatenate([
+                rows for j, i in enumerate(range(0, n, 2 * b))
+                for rows in (p[i:i + b], odd[j * b:(j + 1) * b])])
+        b *= 2
     return p
 
 
-def _chunk_forward(q, k, v, g, beta, st, mm, sub: int, clamp: float):
-    """What both kernels compute of one head's chunk: q, k, g [C, dk]
-    float32, v [C, dv] float32, beta [C, 1], st [dv, dk] the entering
-    state TRANSPOSED, float32 -> a dict of the chunk's forms."""
-    c = q.shape[0]
-    cum = _running_sum(g)
-    aqk, akk = _pair_blocks(q, k, cum, sub, clamp)
-    x = _unit_lower_inverse(beta * akk)
-    grown = jnp.exp(cum)
-    last = cum[c - 1:c]                                      # [1, dk]
-    to_end = jnp.exp(last - cum)
-    kg, qg, ke = k * grown, q * grown, k * to_end
-    rest = v - _dot(kg, st, _NT, mm)                         # [C, dv]
-    new = _dot(x, beta * rest, _NN, mm)                      # V'
-    return dict(cum=cum, aqk=aqk, akk=akk, x=x, grown=grown, last=last,
-                to_end=to_end, kg=kg, qg=qg, ke=ke, rest=rest, new=new)
+def _unit_lower_inverses(blocks):
+    """[(I + a)^-1 for a in blocks]: the heads that share one inverse
+    (``plan()``'s ``inverse_side`` over the chunk), as ONE block-diagonal
+    matrix whose rounds are one product for all of them."""
+    c = blocks[0].shape[0]
+    if len(blocks) == 1:
+        return [_unit_lower_inverse(blocks[0])]
+    zero = jnp.zeros_like(blocks[0])
+    x = _unit_lower_inverse(jnp.concatenate([
+        jnp.concatenate([a if j == i else zero for j in range(len(blocks))],
+                        axis=1) for i, a in enumerate(blocks)]), c)
+    return [x[i * c:(i + 1) * c, i * c:(i + 1) * c]
+            for i in range(len(blocks))]
+
+
+def _head(i: int, heads: int, q, k, v, g, betas, state):
+    """Head ``i`` of a block's arrays, float32: (q, k [C, dk], v [C, dv], g
+    [C, dk], beta [C, 1], st [dv, dk] the entering state TRANSPOSED)."""
+    f32 = jnp.float32
+    dk, dv = q.shape[1] // heads, v.shape[1] // heads
+    kl, vl = slice(i * dk, (i + 1) * dk), slice(i * dv, (i + 1) * dv)
+    return (q[:, kl].astype(f32), k[:, kl].astype(f32), v[:, vl].astype(f32),
+            g[:, kl], _column(betas, i), state[vl])
+
+
+def _chunk_forms(heads, mm, sub: int, clamp: float):
+    """What both kernels compute of one chunk for the heads that share an
+    inverse: a list of ``_head``'s tuples -> a dict of the chunk's forms a
+    head."""
+    forms = []
+    for q, k, v, g, beta, st in heads:
+        cum = _running_sum(g)
+        aqk, akk = _pair_blocks(q, k, cum, sub, clamp)
+        forms.append(dict(cum=cum, aqk=aqk, akk=akk))
+    inverses = _unit_lower_inverses(
+        [beta * f["akk"] for (*_, beta, _), f in zip(heads, forms)])
+    for (q, k, v, g, beta, st), f, x in zip(heads, forms, inverses):
+        cum = f["cum"]
+        grown = jnp.exp(cum)
+        last = cum[-1:]                                      # [1, dk]
+        to_end = jnp.exp(last - cum)
+        kg, qg, ke = k * grown, q * grown, k * to_end
+        rest = v - _dot(kg, st, _NT, mm)                     # [C, dv]
+        new = _dot(x, beta * rest, _NN, mm)                  # V'
+        f.update(x=x, grown=grown, last=last, to_end=to_end, kg=kg, qg=qg,
+                 ke=ke, rest=rest, new=new)
+    return forms
+
+
+def _block_forward(q, k, v, g, betas, state, *, heads: int, per: int, mm,
+                   sub: int, clamp: float):
+    """One chunk of a block of ``heads`` heads, ``per`` of them an inverse:
+    q, k [C, heads x dk] and v [C, heads x dv] in the inputs' type, g as q
+    float32, betas [C, heads], state [heads x dv, dk] float32 entering ->
+    (o [C, heads x dv] float32, the state leaving)."""
+    o, left = [], []
+    for first in range(0, heads, per):
+        group = [_head(i, heads, q, k, v, g, betas, state)
+                 for i in range(first, first + per)]
+        for (*_, st), f in zip(group, _chunk_forms(group, mm, sub, clamp)):
+            o.append(_dot(f["qg"], st, _NT, mm)
+                     + _dot(f["aqk"], f["new"], _NN, mm))
+            left.append(st * jnp.exp(f["last"])
+                        + _dot(f["new"], f["ke"], _TN, mm))
+    return jnp.concatenate(o, axis=1), jnp.concatenate(left)
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, s0_ref, o_ref, sin_ref,
-                s_scr, *, heads: int, sub: int, clamp: float):
+                s_scr, **form):
     """One instance per (batch, head block, chunk), chunks in order. q_ref,
     k_ref [1, C, heads x dk]; v_ref [1, C, heads x dv]; g_ref as q_ref,
     float32; beta_ref [1, 1, C, heads] float32; s0_ref [1, heads x dv, dk]
     the sequence's initial state; o_ref as v_ref; sin_ref [1, 1, heads x
     dv, dk] float32; s_scr [heads x dv, dk] float32, the state entering the
     chunk."""
-    f32 = jnp.float32
-    mm = q_ref.dtype
-    dk, dv = q_ref.shape[2] // heads, v_ref.shape[2] // heads
-
     @pl.when(pl.program_id(2) == 0)
     def _start():
         s_scr[...] = s0_ref[0]
 
     sin_ref[0, 0] = s_scr[...]
-    betas = beta_ref[0, 0]
-    for i in range(heads):
-        kl, vl = slice(i * dk, (i + 1) * dk), slice(i * dv, (i + 1) * dv)
-        st = s_scr[vl, :]
-        f = _chunk_forward(
-            q_ref[0, :, kl].astype(f32), k_ref[0, :, kl].astype(f32),
-            v_ref[0, :, vl].astype(f32), g_ref[0, :, kl],
-            _column(betas, i), st, mm, sub, clamp)
-        o = _dot(f["qg"], st, _NT, mm) + _dot(f["aqk"], f["new"], _NN, mm)
-        o_ref[0, :, vl] = o.astype(o_ref.dtype)
-        s_scr[vl, :] = st * jnp.exp(f["last"]) \
-            + _dot(f["new"], f["ke"], _TN, mm)
+    o, s_scr[...] = _block_forward(
+        q_ref[0], k_ref[0], v_ref[0], g_ref[0], beta_ref[0, 0], s_scr[...],
+        mm=q_ref.dtype, **form)
+    o_ref[0] = o.astype(o_ref.dtype)
+
+
+def _block_backward(q, k, v, g, betas, state, do, d_state, *, heads: int,
+                    per: int, mm, sub: int, clamp: float):
+    """The way back through ``_block_forward``: its inputs (state the one
+    ENTERING the chunk), do [C, heads x dv] and d_state [heads x dv, dk]
+    the gradient of the state leaving -> (dq, dk, dv, dg as their inputs,
+    float32; dbetas [C, heads]; the gradient of the state entering)."""
+    c, dv = q.shape[0], v.shape[1] // heads
+    t, s = _rows_cols(c)
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (1, heads), 1)
+    dbetas = jnp.zeros(betas.shape, jnp.float32)
+    dqs, dks, dvs, dgs, d_entering = [], [], [], [], []
+    for first in range(0, heads, per):
+        among = range(first, first + per)
+        group = [_head(i, heads, q, k, v, g, betas, state) for i in among]
+        forms = _chunk_forms(group, mm, sub, clamp)
+        for i, (qi, ki, _, _, beta, st), f in zip(among, group, forms):
+            vl = slice(i * dv, (i + 1) * dv)
+            dst, do_i = d_state[vl], do[:, vl]
+            # o = qg st^T + aqk new; the state leaving: st exp(last) + new^T ke
+            d_new = _dot(f["aqk"], do_i, _TN, mm) + _dot(f["ke"], dst, _NT, mm)
+            d_aqk = jnp.where(t >= s, _dot(do_i, f["new"], _NT, mm), 0.0)
+            d_qg = _dot(do_i, st, _NN, mm)
+            d_ke = _dot(f["new"], dst, _NN, mm)
+            decay = jnp.exp(f["last"])
+            d_last = jnp.sum(st * dst, axis=0, keepdims=True) * decay
+            d_st = dst * decay + _dot(do_i, f["qg"], _TN, mm)
+            # new = x (beta rest), x = (I + beta akk)^-1
+            d_y = _dot(f["x"], d_new, _TN, mm)
+            d_a = jnp.where(t > s, -_dot(d_y, f["new"], _NT, mm), 0.0)
+            d_rest = beta * d_y
+            d_beta = jnp.sum(d_y * f["rest"], axis=1, keepdims=True) \
+                + jnp.sum(d_a * f["akk"], axis=1, keepdims=True)
+            # rest = v - kg st^T
+            d_kg = -_dot(d_rest, st, _NN, mm)
+            d_st = d_st - _dot(d_rest, f["kg"], _TN, mm)
+            dq, dk_, dcum = _pair_grads(qi, ki, f["cum"], d_aqk, beta * d_a,
+                                        sub, clamp)
+            through = d_ke * f["ke"]
+            dcum = dcum + d_qg * f["qg"] + d_kg * f["kg"] - through
+            d_last = d_last + jnp.sum(through, axis=0, keepdims=True)
+            dcum = dcum + jnp.where(t == c - 1, d_last, 0.0)
+            dqs.append(dq + d_qg * f["grown"])
+            dks.append(dk_ + d_kg * f["grown"] + d_ke * f["to_end"])
+            dvs.append(d_rest)
+            # the transpose of a running sum: the running sum from the end
+            dgs.append(_running_sum(dcum, from_end=True))
+            dbetas = jnp.where(lanes == i, d_beta, dbetas)
+            d_entering.append(d_st)
+    wide = lambda parts: jnp.concatenate(parts, axis=1)        # noqa: E731
+    return (wide(dqs), wide(dks), wide(dvs), wide(dgs), dbetas,
+            jnp.concatenate(d_entering))
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, sin_ref, do_ref,
                 dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, ds0_ref, ds_scr,
-                *, heads: int, sub: int, clamp: float):
+                **form):
     """The mirror image, chunks last to first; ds_scr [heads x dv, dk] is
     the gradient of the (transposed) state LEAVING the chunk, and after the
     first chunk that of the initial state (ds0_ref [1, heads x dv, dk])."""
-    f32 = jnp.float32
-    mm = q_ref.dtype
-    c = q_ref.shape[1]
-    dk, dv = q_ref.shape[2] // heads, v_ref.shape[2] // heads
-
     @pl.when(pl.program_id(2) == 0)
     def _start():
         ds_scr[...] = jnp.zeros_like(ds_scr)
 
-    t, s = _rows_cols(c)
-    betas = beta_ref[0, 0]
-    lanes = jax.lax.broadcasted_iota(jnp.int32, (1, heads), 1)
-    dbetas = jnp.zeros(betas.shape, f32)
-    for i in range(heads):
-        kl, vl = slice(i * dk, (i + 1) * dk), slice(i * dv, (i + 1) * dv)
-        q, k = q_ref[0, :, kl].astype(f32), k_ref[0, :, kl].astype(f32)
-        v = v_ref[0, :, vl].astype(f32)
-        beta = _column(betas, i)
-        st = sin_ref[0, 0, vl, :]
-        dst = ds_scr[vl, :]
-        do = do_ref[0, :, vl]
-        f = _chunk_forward(q, k, v, g_ref[0, :, kl], beta, st, mm, sub, clamp)
-        # o = qg st^T + aqk new; the state leaving: st exp(last) + new^T ke
-        d_new = _dot(f["aqk"], do, _TN, mm) + _dot(f["ke"], dst, _NT, mm)
-        d_aqk = jnp.where(t >= s, _dot(do, f["new"], _NT, mm), 0.0)
-        d_qg = _dot(do, st, _NN, mm)
-        d_ke = _dot(f["new"], dst, _NN, mm)
-        decay = jnp.exp(f["last"])
-        d_last = jnp.sum(st * dst, axis=0, keepdims=True) * decay
-        d_st = dst * decay + _dot(do, f["qg"], _TN, mm)
-        # new = x (beta rest), x = (I + beta akk)^-1
-        d_y = _dot(f["x"], d_new, _TN, mm)
-        d_a = jnp.where(t > s, -_dot(d_y, f["new"], _NT, mm), 0.0)
-        d_rest = beta * d_y
-        d_beta = jnp.sum(d_y * f["rest"], axis=1, keepdims=True) \
-            + jnp.sum(d_a * f["akk"], axis=1, keepdims=True)
-        # rest = v - kg st^T
-        d_kg = -_dot(d_rest, st, _NN, mm)
-        d_st = d_st - _dot(d_rest, f["kg"], _TN, mm)
-        dq, dk_, dcum = _pair_grads(q, k, f["cum"], d_aqk, beta * d_a, sub,
-                                    clamp)
-        through = d_ke * f["ke"]
-        dcum = dcum + d_qg * f["qg"] + d_kg * f["kg"] - through
-        d_last = d_last + jnp.sum(through, axis=0, keepdims=True)
-        dcum = dcum + jnp.where(t == c - 1, d_last, 0.0)
-        dq_ref[0, :, kl] = (dq + d_qg * f["grown"]).astype(dq_ref.dtype)
-        dk_ref[0, :, kl] = (dk_ + d_kg * f["grown"]
-                            + d_ke * f["to_end"]).astype(dk_ref.dtype)
-        dv_ref[0, :, vl] = d_rest.astype(dv_ref.dtype)
-        # the transpose of a running sum: the running sum from the end
-        dg_ref[0, :, kl] = _dot32(jnp.where(s >= t, 1.0, 0.0), dcum)
-        dbetas = jnp.where(lanes == i, d_beta, dbetas)
-        ds_scr[vl, :] = d_st
+    dq, dk, dv, dg, dbetas, ds_scr[...] = _block_backward(
+        q_ref[0], k_ref[0], v_ref[0], g_ref[0], beta_ref[0, 0], sin_ref[0, 0],
+        do_ref[0], ds_scr[...], mm=q_ref.dtype, **form)
+    dq_ref[0] = dq.astype(dq_ref.dtype)
+    dk_ref[0] = dk.astype(dk_ref.dtype)
+    dv_ref[0] = dv.astype(dv_ref.dtype)
+    dg_ref[0] = dg
     dbeta_ref[0, 0] = dbetas
     ds0_ref[0] = ds_scr[...]
 
@@ -383,39 +531,80 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, sin_ref, do_ref,
 # --- the block plan and the calls -------------------------------------------
 
 
-def plan(*, B: int, S: int, H: int, dk: int, dv: int, chunk: int, dtype,
-         impl: str, lower_bound: float = -5.0) -> dict:
-    """The op's block plan (also the attributes of ``kda.plan``): the path,
-    the chunk and the sub-block of the pair products, how many heads an
-    instance walks, the VMEM one instance of the backward call holds (its
-    blocks twice, Mosaic double-buffers; the state scratch; the float32
-    forms of a head: a dozen [C, C], a dozen [C, dk], half a dozen [C,
-    dv], three [dv, dk]), the bytes of the chunks' incoming states the
-    forward rule keeps for the backward, and the HBM bytes the two calls
-    move for one head and sequence."""
+@functools.lru_cache(maxsize=None)
+def _products(c: int, heads: int, per: int, dk: int, dv: int, dtype):
+    """(float32 products, MXU passes) a head and chunk of the forward and
+    of the backward call, counted by tracing one block's chunk through the
+    code the kernels run."""
+    f32 = jnp.float32
+    form = dict(heads=heads, per=per, mm=jnp.dtype(dtype), sub=min(SUB, c),
+                clamp=40.0)
+    wide = lambda n, t: jax.ShapeDtypeStruct((c, heads * n), t)  # noqa: E731
+    block = (wide(dk, dtype), wide(dk, dtype), wide(dv, dtype), wide(dk, f32),
+             wide(1, f32), jax.ShapeDtypeStruct((heads * dv, dk), f32))
+    counts = []
+    for rule, more in ((_block_forward, ()),
+                       (_block_backward, (wide(dv, dtype), block[-1]))):
+        tally = [0, 0]
+        _TALLIES.append(tally)
+        try:
+            jax.eval_shape(functools.partial(rule, **form), *block, *more)
+        finally:
+            _TALLIES.remove(tally)
+        counts.append((tally[0] // heads, tally[1] // heads))
+    return counts
+
+
+def plan(*, B: int, S: int, H: int, dk: int, dv: int, dtype, impl: str,
+         chunk: int = CHUNK, lower_bound: float = -5.0) -> dict:
+    """The op's block plan (also the attributes of ``kda.plan``), from the
+    shapes alone: the chunk (a sequence shorter than ``chunk`` is ONE
+    chunk, the least power of two that holds it), the sub-block of the pair
+    products, how many heads an instance walks and how many of them share
+    one inverse
+    (``inverse_side`` over the chunk: as many as fit the MXU's tile of
+    ``_SIDE`` side by side), the float32 products and the MXU
+    passes a head and chunk of each call (``_products``: counted from the
+    kernels' own code), the VMEM one instance of the backward call holds
+    (its blocks twice, Mosaic double-buffers; the state scratch; the
+    float32 forms of the heads of an inverse: a dozen of its side squared,
+    a dozen [C, dk] and half a dozen [C, dv] a head, three [dv, dk]), the
+    bytes of the chunks' incoming states the forward rule keeps for the
+    backward, and the HBM bytes the two calls move for one head and
+    sequence."""
+    on = impl == "pallas"
+    c = min(chunk, max(SUB, 1 << (S - 1).bit_length()))
     heads = min(HEADS_PER_BLOCK, H)
     while H % heads:
         heads -= 1
+    per = max(1, min(heads, _SIDE // c))
+    while heads % per:
+        per -= 1
     item = jnp.dtype(dtype).itemsize
-    c = chunk
     steps = -(-S // c) * c
     blocks = (heads * c * (2 * dk + 2 * dv) * item        # q, k, v, do
               + heads * c * (2 * dk + dv) * item          # dq, dk, dv
               + 2 * heads * c * dk * 4                    # g, dg
               + 2 * c * 128 * 4                           # beta, dbeta
               + 2 * heads * dv * dk * 4)                  # state in, ds0
-    forms = 12 * c * c * 4 + 12 * c * dk * 4 + 6 * c * dv * 4 \
+    forms = 12 * (per * c) ** 2 * 4 + per * (12 * c * dk + 6 * c * dv) * 4 \
         + 3 * dv * dk * 4
     states = B * (steps // c) * H * dv * dk * 4
     hbm = steps * ((2 * dk + dv) * item + dk * 4 + 4) * 2 \
         + steps * dv * item * 2 + 2 * (steps // c) * dv * dk * 4
-    on = impl == "pallas"
-    return {"path": impl, "S": S, "chunk": c, "sub_block": SUB,
+    said = {"path": impl, "S": S, "chunk": c, "sub_block": SUB,
             "heads_per_block": heads, "lower_bound": lower_bound,
-            "vmem_bytes": 2 * blocks + heads * dv * dk * 4 + forms if on
-            else 0,
-            "state_bytes_kept": states,
-            "hbm_bytes_per_head": hbm if on else 0}
+            "vmem_bytes": 0, "state_bytes_kept": states,
+            "hbm_bytes_per_head": 0}
+    if on:
+        (f32_fwd, fwd), (f32_bwd, bwd) = _products(
+            c, heads, per, dk, dv, jnp.dtype(dtype))
+        said.update(
+            inverse_side=per * c, f32_products_fwd=f32_fwd,
+            f32_products_bwd=f32_bwd, mxu_passes_fwd=fwd, mxu_passes_bwd=bwd,
+            vmem_bytes=2 * blocks + heads * dv * dk * 4 + forms,
+            hbm_bytes_per_head=hbm)
+    return said
 
 
 def _specs(c: int, heads: int, dk: int, dv: int):
@@ -435,39 +624,41 @@ def _backwards(spec, last: int):
                         lambda b, h, n: index(b, h, last - n))
 
 
-def _forward_call(q, k, v, g, beta, s0, *, chunk, heads, dk, dv, clamp):
+def _call(kernel, B, H, S, *, chunk, heads, per, dk, dv, clamp, **io):
+    return pl.pallas_call(
+        functools.partial(kernel, heads=heads, per=per, sub=min(SUB, chunk),
+                          clamp=clamp),
+        grid=(B, H // heads, S // chunk),
+        scratch_shapes=[pltpu.VMEM((heads * dv, dk), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=_use_interpret(), **io)
+
+
+def _forward_call(q, k, v, g, beta, s0, *, chunk, heads, dk, dv, **form):
     B, S, _ = q.shape
     H = q.shape[2] // dk
     keys, values, betas, states, first = _specs(chunk, heads, dk, dv)
-    call = pl.pallas_call(
-        functools.partial(_fwd_kernel, heads=heads, sub=min(SUB, chunk),
-                          clamp=clamp),
-        grid=(B, H // heads, S // chunk),
+    call = _call(
+        _fwd_kernel, B, H, S, chunk=chunk, heads=heads, dk=dk, dv=dv, **form,
         in_specs=[keys, keys, values, keys, betas, first],
         out_specs=[values, states],
         out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype),
                    jax.ShapeDtypeStruct((B, S // chunk, H * dv, dk),
-                                        jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((heads * dv, dk), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_use_interpret(),
-    )
+                                        jnp.float32)])
     with jax.named_scope("kda.fwd.pallas"):         # kda.plan's path
         return call(q, k, v, g, beta, s0)
 
 
 def _backward_call(q, k, v, g, beta, s_in, do, *, chunk, heads, dk, dv,
-                   clamp):
+                   **form):
     B, S, _ = q.shape
     H = q.shape[2] // dk
     *walked, first = _specs(chunk, heads, dk, dv)
     keys, values, betas, states = (
         _backwards(spec, S // chunk - 1) for spec in walked)
-    call = pl.pallas_call(
-        functools.partial(_bwd_kernel, heads=heads, sub=min(SUB, chunk),
-                          clamp=clamp),
-        grid=(B, H // heads, S // chunk),
+    call = _call(
+        _bwd_kernel, B, H, S, chunk=chunk, heads=heads, dk=dk, dv=dv, **form,
         in_specs=[keys, keys, values, keys, betas, states, values],
         out_specs=[keys, keys, values, keys, betas, first],
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
@@ -475,34 +666,28 @@ def _backward_call(q, k, v, g, beta, s_in, do, *, chunk, heads, dk, dv,
                    jax.ShapeDtypeStruct(v.shape, v.dtype),
                    jax.ShapeDtypeStruct(g.shape, jnp.float32),
                    jax.ShapeDtypeStruct(beta.shape, jnp.float32),
-                   jax.ShapeDtypeStruct((B, H * dv, dk), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((heads * dv, dk), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_use_interpret(),
-    )
+                   jax.ShapeDtypeStruct((B, H * dv, dk), jnp.float32)])
     with jax.named_scope("kda.bwd.pallas"):
         return call(q, k, v, g, beta, s_in, do)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))
-def _scan_pallas(q, k, v, g, beta, s0, chunk, heads, dk, dv, clamp):
-    return _forward_call(q, k, v, g, beta, s0, chunk=chunk, heads=heads,
-                         dk=dk, dv=dv, clamp=clamp)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _scan_pallas(q, k, v, g, beta, s0, form):
+    """``form``: the calls' static arguments, a tuple of pairs."""
+    return _forward_call(q, k, v, g, beta, s0, **dict(form))[0]
 
 
-def _scan_pallas_fwd(q, k, v, g, beta, s0, chunk, heads, dk, dv, clamp):
-    o, s_in = _forward_call(q, k, v, g, beta, s0, chunk=chunk, heads=heads,
-                            dk=dk, dv=dv, clamp=clamp)
+def _scan_pallas_fwd(q, k, v, g, beta, s0, form):
+    o, s_in = _forward_call(q, k, v, g, beta, s0, **dict(form))
     o, s_in = (checkpoint_name(a, name) for a, name in zip((o, s_in),
                                                            RESIDUALS))
     return o, (q, k, v, g, beta, s_in)
 
 
-def _scan_pallas_bwd(chunk, heads, dk, dv, clamp, res, do):
+def _scan_pallas_bwd(form, res, do):
     q, k, v, g, beta, s_in = res
     return _backward_call(q, k, v, g, beta, s_in, do.astype(v.dtype),
-                          chunk=chunk, heads=heads, dk=dk, dv=dv, clamp=clamp)
+                          **dict(form))
 
 
 _scan_pallas.defvjp(_scan_pallas_fwd, _scan_pallas_bwd)
@@ -522,9 +707,6 @@ def gated_delta_rule(q, k, v, g, beta, *, initial_state=None,
     if impl not in ("xla", "pallas"):
         raise ValueError(f"gated_delta_rule impl must be 'xla' or 'pallas', "
                          f"got {impl!r}")
-    # a sequence shorter than a chunk is ONE chunk, the least power of two
-    # that holds it
-    chunk = min(chunk, max(SUB, 1 << (S - 1).bit_length()))
     if chunk % SUB or chunk & (chunk - 1):
         raise ValueError(f"gated_delta_rule: a chunk of {chunk} steps; want "
                          f"a power of two and a multiple of {SUB}")
@@ -532,6 +714,7 @@ def gated_delta_rule(q, k, v, g, beta, *, initial_state=None,
     said = plan(B=B, S=S, H=H, dk=dk, dv=dv, chunk=chunk, dtype=q.dtype,
                 impl=impl, lower_bound=lower_bound)
     tracing.plan("kda.plan", said)
+    chunk = said["chunk"]
     f32 = jnp.float32
     g, beta = g.astype(f32), beta.astype(f32)
     state = jnp.zeros((B, H, dk, dv), f32) if initial_state is None \
@@ -553,6 +736,8 @@ def gated_delta_rule(q, k, v, g, beta, *, initial_state=None,
     flat = lambda a: a.reshape(B, steps, -1)                   # noqa: E731
     by_block = beta.reshape(B, steps, H // heads, heads).transpose(0, 2, 1, 3)
     s0 = state.transpose(0, 1, 3, 2).reshape(B, H * dv, dk)
-    o = _scan_pallas(flat(q), flat(k), flat(v), flat(g), by_block, s0, chunk,
-                     heads, dk, dv, clamp)
+    form = (("chunk", chunk), ("heads", heads),
+            ("per", said["inverse_side"] // chunk), ("dk", dk), ("dv", dv),
+            ("clamp", clamp))
+    o = _scan_pallas(flat(q), flat(k), flat(v), flat(g), by_block, s0, form)
     return o.reshape(B, steps, H, dv)[:, :S]

@@ -1,7 +1,9 @@
 """The chunked gated delta rule (ops/delta_rule.py) against the recurrence
 advanced a step at a time: values and all six gradients on both paths, over
-lengths that are and are not whole chunks; the gate at its bound; the
-inverse by block forward substitution; the plan and the refusals."""
+lengths that are and are not whole chunks, two heads an inverse and one;
+the gate at its bound; the inverse by block forward substitution at every
+side the plan chooses; the running sums by shifted adds and by three
+passes; the plan and the refusals."""
 
 import jax
 import jax.numpy as jnp
@@ -33,15 +35,18 @@ def make(S, B=2, H=2, dk=16, dv=16, seed=0, lower=-5.0):
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
-@pytest.mark.parametrize("S", [64, 100, 16])
-def test_values_and_six_gradients_are_the_recurrences(impl, S):
-    """A chunk of 32: two whole chunks, three and a part, half a chunk; q,
-    k, v, g, beta and the initial state."""
+@pytest.mark.parametrize("S, chunk", [(64, 32), (100, 32), (16, 32),
+                                      (128, 64), (256, 128)])
+def test_values_and_six_gradients_are_the_recurrences(impl, S, chunk):
+    """A chunk of 32: two whole chunks, three and a part, half a chunk; two
+    whole chunks of the op's own 64 (on the kernel path the block's two
+    heads in ONE inverse of side 128) and two of 128 (a head an inverse);
+    q, k, v, g, beta and the initial state."""
     args, weight = make(S)
 
     def op(q, k, v, g, beta, state):
         return dr.gated_delta_rule(q, k, v, g, beta, initial_state=state,
-                                   chunk=32, impl=impl)
+                                   chunk=chunk, impl=impl)
 
     with jax.default_matmul_precision("highest"):
         want = recurrence(*args)
@@ -57,19 +62,22 @@ def test_values_and_six_gradients_are_the_recurrences(impl, S):
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
-def test_the_gate_at_its_bound_stays_finite(impl):
-    """g = -5 every step and channel for four chunks of 32: exp(-G) alone
-    would overflow after 18 steps; the sub-blocks' factors, taken from the
-    sub-block's middle, neither overflow nor fall among the denormals, and
-    the values and gradients are the recurrence's."""
-    (q, k, v, g, beta, state), weight = make(128)
+@pytest.mark.parametrize("S, chunk", [(128, 32), (128, 64), (256, 128)])
+def test_the_gate_at_its_bound_stays_finite(impl, S, chunk):
+    """g = -5 every step and channel for four chunks of 32, two of the
+    op's own 64 and two of 128: exp(-G) alone would overflow after 18
+    steps; the
+    sub-blocks' factors, taken from the sub-block's middle, neither
+    overflow nor fall among the denormals, and the values and gradients are
+    the recurrence's."""
+    (q, k, v, g, beta, state), weight = make(S)
     g = jnp.full_like(g, -5.0)
 
     def loss(fn):
         return lambda q, k, v, g: (fn(q, k, v, g) * weight).sum()
 
     op = lambda q, k, v, g: dr.gated_delta_rule(                # noqa: E731
-        q, k, v, g, beta, initial_state=state, chunk=32, impl=impl)
+        q, k, v, g, beta, initial_state=state, chunk=chunk, impl=impl)
     ref = lambda q, k, v, g: recurrence(q, k, v, g, beta, state)  # noqa
     with jax.default_matmul_precision("highest"):
         got = jax.jit(op)(q, k, v, g)
@@ -95,26 +103,130 @@ def test_a_bound_the_sub_blocks_cannot_hold_is_refused():
         dr.gated_delta_rule(q, k, v, g, beta, impl="mosaic")
 
 
-def test_the_inverse_by_block_substitution_is_the_inverse():
+def in_a_kernel(fn, *arrays):
+    """``fn(*arrays)`` inside a Mosaic call in interpret mode: the sublane
+    and lane shifts of the kernels' helpers live in kernels only. fn
+    returns one array or a list of arrays of one shape."""
+    from jax.experimental import pallas as pl
+
+    shapes = jax.eval_shape(fn, *arrays)
+
+    def kernel(*refs):
+        ins, outs = refs[:len(arrays)], refs[len(arrays):]
+        got = fn(*(r[...] for r in ins))
+        for o, x in zip(outs, got if isinstance(got, list) else [got]):
+            o[...] = x
+    return pl.pallas_call(kernel, interpret=True, out_shape=shapes)(*arrays)
+
+
+@pytest.mark.parametrize("side, heads", [(64, 1), (128, 1), (64, 2),
+                                         (32, 4)])
+def test_the_inverse_by_block_substitution_is_the_inverse(side, heads):
     """Adjacent rows alike and beta 1, where the powers of A grow like
-    binomials: the block form holds."""
-    c = 64
-    a = jnp.tril(jnp.ones((c, c)), -1) * 0.97
-    got = dr._unit_lower_inverse(a)
-    np.testing.assert_allclose(got @ (jnp.eye(c) + a), jnp.eye(c), atol=2e-5)
-    a = jnp.tril(jax.random.normal(jax.random.PRNGKey(0), (c, c)), -1) * 0.3
-    np.testing.assert_allclose(dr._unit_lower_inverse(a),
-                               jnp.linalg.inv(jnp.eye(c) + a), rtol=2e-3,
-                               atol=2e-3)
+    binomials: the block form holds, at a chunk of 64 and of 128 alone and
+    for the heads of a block side by side in ONE matrix (two chunks of 64
+    in a side of 128, four of 32): each head's own inverse to the bit, and
+    exactly 0 between the heads."""
+    alike = jnp.tril(jnp.ones((side, side)), -1) * 0.97
+    keys = jax.random.split(jax.random.PRNGKey(0), heads)
+    blocks = [alike] + [jnp.tril(jax.random.normal(k, (side, side)), -1) * 0.3
+                        for k in keys[1:]]
+    if heads == 1:
+        blocks.append(jnp.tril(jax.random.normal(keys[0], (side, side)), -1)
+                      * 0.3)
+        got = [in_a_kernel(dr._unit_lower_inverse, a) for a in blocks]
+    else:
+        got = in_a_kernel(lambda *a: dr._unit_lower_inverses(list(a)),
+                          *blocks)
+        whole = in_a_kernel(
+            lambda a: dr._unit_lower_inverse(a, side),
+            jax.scipy.linalg.block_diag(*blocks))
+        for x, a in zip(got, blocks):
+            np.testing.assert_array_equal(
+                x, in_a_kernel(dr._unit_lower_inverse, a))
+        between = jax.scipy.linalg.block_diag(
+            *[jnp.ones((side, side))] * heads) == 0
+        assert not bool(jnp.any(jnp.where(between, whole, 0.0) != 0.0))
+    eye = jnp.eye(side)
+    np.testing.assert_allclose(got[0] @ (eye + alike), eye, atol=4e-5)
+    np.testing.assert_allclose(got[1], jnp.linalg.inv(eye + blocks[1]),
+                               rtol=2e-3, atol=2e-3)
+
+
+@pytest.mark.parametrize("shifted, half", [(1, 1 << 20), (2, 1 << 20),
+                                           (1, 8), (2, 8), (8, 16)])
+def test_every_form_of_a_round_is_the_round(monkeypatch, shifted, half):
+    """A round as two float32 products of all rows (the parent's, from the
+    second round on), by shifted multiply-adds on the vector unit where the
+    blocks are small, and through the products with the odd blocks' rows
+    alone: the same inverse as the op's own mix of them, two heads of 64 in
+    one matrix."""
+    keys = jax.random.split(jax.random.PRNGKey(3), 2)
+    blocks = [jnp.tril(jax.random.normal(k, (64, 64)), -1) * 0.3
+              for k in keys]
+    inverses = lambda *a: dr._unit_lower_inverses(list(a))      # noqa: E731
+    want = in_a_kernel(inverses, *blocks)
+    monkeypatch.setattr(dr, "SHIFTED_BLOCKS", shifted)
+    monkeypatch.setattr(dr, "HALF_ROWS", half)
+    for x, y in zip(in_a_kernel(inverses, *blocks), want):
+        np.testing.assert_allclose(x, y, rtol=2e-5, atol=2e-6)
+
+
+@pytest.mark.parametrize("form", ["shifted adds", "three passes"])
+def test_a_running_sum_is_the_float32_product_with_the_triangle(form):
+    """128 steps of gates at the bound and near it, down the rows and up
+    them, against the 0/1 triangle's product at ``HIGHEST`` (the parent's
+    form) to 1e-6: the op's log2(C) shifted adds, and the form that lost
+    to them on the chip (``tests/delta_rule_forms.py``): a 0/1 operand is
+    exact in bfloat16, so one pass for each of the other operand's three
+    bfloat16 parts, which sum to it exactly, is the float32 product."""
+    import delta_rule_forms as forms
+
+    g = -5.0 + 1e-3 * jax.random.uniform(jax.random.PRNGKey(1), (128, 16))
+    g = g.at[:, 0].set(-5.0)
+    assert bool((sum(p.astype(jnp.float32) for p in forms.three_parts(g))
+                 == g).all())
+    running = dr._running_sum if form == "shifted adds" \
+        else forms.three_pass_sum
+    for from_end in (False, True):
+        want = forms.six_pass_sum(g, from_end)
+        got = in_a_kernel(lambda x: running(x, from_end), g)
+        np.testing.assert_allclose(got, want, rtol=1e-6)
+        exact = np.cumsum(np.asarray(g, np.float64)[::-1 if from_end else 1],
+                          axis=0)[::-1 if from_end else 1]
+        np.testing.assert_allclose(got, exact, rtol=1e-6)
 
 
 def test_the_plan_counts_the_states_and_the_vmem():
-    plan = dr.plan(B=1, S=16384, H=32, dk=128, dv=128, chunk=64,
-                   dtype=jnp.bfloat16, impl="pallas")
+    plan = dr.plan(B=1, S=16384, H=32, dk=128, dv=128, dtype=jnp.bfloat16,
+                   impl="pallas")
     assert plan["path"] == "pallas" and plan["sub_block"] == 16
     assert plan["heads_per_block"] == 2 and plan["chunk"] == 64
     # 256 chunks x 32 heads x [128, 128] float32
     assert plan["state_bytes_kept"] == 256 * 32 * 128 * 128 * 4
     assert 0 < plan["vmem_bytes"] <= 16 * 2 ** 20
-    assert dr.plan(B=1, S=100, H=2, dk=16, dv=16, chunk=32,
-                   dtype=jnp.float32, impl="xla")["vmem_bytes"] == 0
+    # two heads in ONE inverse of [128, 128]: a head and chunk 4 sub-blocks'
+    # pair products and half of 3 rounds of two (the first round is no
+    # product, the second and third shifted multiply-adds); the backward
+    # makes them again and goes back through the pair blocks with 8. The
+    # parent's count was 17 and 25
+    assert plan["inverse_side"] == 128
+    assert plan["f32_products_fwd"] == 4 + 3
+    assert plan["f32_products_bwd"] == 4 + 3 + 8
+    # the running sums are no product; the five others are one pass each
+    assert plan["mxu_passes_fwd"] == 6 * 7 + 5
+    # a chunk of 128 is a head an inverse: 8 pair products, 4 rounds of two
+    whole = dr.plan(B=1, S=16384, H=32, dk=128, dv=128, chunk=128,
+                    dtype=jnp.bfloat16, impl="pallas")
+    assert whole["inverse_side"] == 128 \
+        and whole["f32_products_fwd"] == 8 + 8 \
+        and whole["f32_products_bwd"] == 8 + 8 + 16
+    assert 2 * whole["state_bytes_kept"] == plan["state_bytes_kept"]
+    # an odd head count walks a head a block: its inverse is its own
+    odd = dr.plan(B=1, S=64, H=3, dk=128, dv=128, dtype=jnp.bfloat16,
+                  impl="pallas")
+    assert odd["heads_per_block"] == 1 and odd["chunk"] == 64 \
+        and odd["inverse_side"] == 64 and odd["f32_products_fwd"] == 4 + 6
+    plain = dr.plan(B=1, S=100, H=2, dk=16, dv=16, chunk=32,
+                    dtype=jnp.float32, impl="xla")
+    assert plain["vmem_bytes"] == 0 and "inverse_side" not in plain

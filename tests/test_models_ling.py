@@ -493,9 +493,16 @@ def test_plan_instants_say_the_path_the_chunk_and_the_states(monkeypatch):
     jax.jit(lambda p: ling.loss_fn(p, {"tokens": tokens}, cfg)[0]).lower(
         params)
     plans = [a for n, a in said if n == "kda.plan"]
-    # the op's chunk of 64: two chunks hold 100 steps
+    # the op's chunk of 64: two chunks hold 100 steps; the block's two
+    # heads in ONE inverse of side 128, so a head and chunk costs 4 pair
+    # products and half of 3 rounds of two forward, 8 more on the way back,
+    # and in this float32 model the 5 and 12 products over the state and
+    # the steps are float32 too
     assert plans and all(p["path"] == "pallas" and p["chunk"] == 64
                          and p["sub_block"] == 16 for p in plans)
+    assert all(p["heads_per_block"] == 2 and p["inverse_side"] == 128
+               and p["f32_products_fwd"] == 7 + 5
+               and p["f32_products_bwd"] == 15 + 12 for p in plans)
     assert plans[0]["state_bytes_kept"] == 2 * 2 * 2 * 16 * 16 * 4
     halves = [a for n, a in said if n == "kda.half_plan"]
     assert halves and halves[0]["taps"] == 4 \

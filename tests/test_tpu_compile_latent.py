@@ -410,10 +410,15 @@ def test_ssd_scan_compiles_at_lightning_widths(chunk, one_chip,
 def test_delta_rule_calls_compile_at_the_ling_cells_shape(one_chip,
                                                           on_chip_branch):
     """The chunked gated delta rule (32 heads of 128 keys and values over
-    16,384 steps, a gate a key channel in float32, chunks of 64) lowers for
-    a v5e at the Ling-3.0-flash cell's shape: two Mosaic calls, the chunks'
-    incoming states the only state among their results, and what one
-    instance holds in VMEM under the plan's count."""
+    16,384 steps, a gate a key channel in float32, chunks of 64, the
+    block's heads two to an inverse of side 128) lowers for a v5e at the
+    Ling-3.0-flash cell's shape: exactly two Mosaic calls with the operands
+    and results ``benchmark/readers/ling_kernel_roofline.py`` tells them by
+    (forward 6 -> 2, backward 7 -> 6, q, k, v first at [1, 16384, 4096]),
+    the chunks' incoming states the only state among their results, and
+    what one instance holds in VMEM under the plan's count."""
+    import re
+
     import jax
     import jax.numpy as jnp
 
@@ -425,15 +430,30 @@ def test_delta_rule_calls_compile_at_the_ling_cells_shape(one_chip,
     beta = _sds((B, S, H), jnp.float32, one_chip)
 
     def loss(q, k, v, g, beta):
-        return dr.gated_delta_rule(q, k, v, g, beta, chunk=64,
+        return dr.gated_delta_rule(q, k, v, g, beta,
                                    impl="pallas").astype(jnp.float32).sum()
 
-    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
-        x, x, x, g, beta).compile().as_text()
-    assert text.count("tpu_custom_call") == 2, text[:2000]
-    assert f"f32[{B},{S // 64},{H * d},{d}]" in text
-    plan = dr.plan(B=B, S=S, H=H, dk=d, dv=d, chunk=64, dtype=jnp.bfloat16,
+    both = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4)))
+    text = both.lower(x, x, x, g, beta).compile().as_text()
+    # part (e) of the cell's ``correct`` runs the calls on float32 q, k, v
+    # too: twice the blocks in VMEM, and they fit
+    both.lower(g, g, g, g, beta).compile()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 2, text[:2000]
+    wide = f"[{B},{S},{H * d}]"
+    read = []
+    for line in calls:
+        results, operands = re.search(
+            r"= (.*?) custom-call\((.*?)\), custom_call_target", line).groups()
+        shapes = re.findall(r"\w+(\[[\d,]*\])", line.split(
+            "operand_layout_constraints={")[1].split("}}")[0])
+        assert shapes[:3] == [wide] * 3, shapes
+        read.append((operands.count("%"), results.count("[")))
+    assert sorted(read) == [(6, 2), (7, 6)], read
+    plan = dr.plan(B=B, S=S, H=H, dk=d, dv=d, dtype=jnp.bfloat16,
                    impl="pallas")
+    assert plan["chunk"] == 64 and plan["inverse_side"] == 128
+    assert f"f32[{B},{S // 64},{H * d},{d}]" in text
     assert plan["state_bytes_kept"] == 536_870_912
     assert plan["vmem_bytes"] <= 16 * 2 ** 20, plan
 
