@@ -465,6 +465,76 @@ def _down_combine_bwd(impl, res, dy):
 _down_combine.defvjp(_down_combine_fwd, _down_combine_bwd)
 
 
+# The router keeps k of n scores by k ROUNDS of a masked maximum while k is
+# at most this, and by ``lax.top_k`` above it. A round is a maximum and a
+# minimum along the row (elementwise work and two lane reductions over the
+# [T, n] scores); ``lax.top_k`` on a TPU sorts the n lanes of every row, and
+# its gradient (and ``take_along_axis``'s) is a scatter-add. On the chip at
+# [16384, n], selection, picked scores and their gradient, ms (PERF.md 6, PR
+# 60): n 512, k 8 rounds 0.75 against the sort's 2.89, k 16 2.18 | 4.12, k
+# 24 4.35 | 5.41, k 32 7.46 | 6.62; n 128, k 16 1.99 | 2.62, k 24 3.69 |
+# 3.76, k 32 5.86 | 4.91; n 64 the rounds win at every k to 48 (0.78 |
+# 5.42). The rounds grow faster than k (the picked scores are k more masked
+# sums), the sort about with k: they cross between 24 and 32 where they
+# cross at all, and 16 is under every crossing and above every router's k
+# (4 to 10). A selection of another order (``sala.py``'s 64 blocks of 256,
+# the indexer's 2,048 keys) is no router's and calls ``lax.top_k`` itself.
+ROUND_MAX_K = 16
+
+
+def _rounds(scores, k: int):
+    """k rounds over the rows of scores [..., n] -> (values [..., k], lanes
+    [..., k] int32, taken [..., n] bool: the k lanes chosen). A round takes
+    the row's maximum over the lanes not yet taken and the FIRST such lane
+    that holds it, and strikes that lane in ``taken`` (not with a -inf
+    written into the scores: a row may hold -inf already, a masked group's
+    experts, and a lane is taken once)."""
+    n = scores.shape[-1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, scores.shape, scores.ndim - 1)
+    taken = jnp.zeros(scores.shape, bool)
+    values, lanes = [], []
+    for _ in range(k):
+        top = jnp.max(jnp.where(taken, -jnp.inf, scores), axis=-1,
+                      keepdims=True)
+        first = jnp.min(jnp.where((scores == top) & ~taken, lane, n),
+                        axis=-1, keepdims=True)
+        taken = taken | (lane == first)
+        values.append(top), lanes.append(first)
+    return (jnp.concatenate(values, axis=-1),
+            jnp.concatenate(lanes, axis=-1), taken)
+
+
+def top_lanes(scores, k: int):
+    """The k largest of every row of scores [..., n] -> (values [..., k],
+    lanes [..., k] int32), in ``lax.top_k``'s own order: values falling,
+    equal values to the lower lane. Up to ROUND_MAX_K by rounds, above it
+    ``lax.top_k`` itself."""
+    return jax.lax.top_k(scores, k) if k > ROUND_MAX_K \
+        else _rounds(scores, k)[:2]
+
+
+def at_lanes(scores, lanes):
+    """scores [T, n] at lanes [T, k] -> [T, k]: ``take_along_axis``, up to
+    ROUND_MAX_K lanes as k masked sums, whose gradient is a select (d
+    scores = sum_k where(lane == lanes_k, d out_k, 0)) where a gather's is
+    a scatter-add that a TPU walks row by row."""
+    if lanes.shape[-1] > ROUND_MAX_K:
+        return jnp.take_along_axis(scores, lanes, axis=-1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, scores.shape, scores.ndim - 1)
+    return jnp.stack([jnp.sum(jnp.where(lane == lanes[..., k:k + 1], scores,
+                                        0), axis=-1)
+                      for k in range(lanes.shape[-1])], axis=-1)
+
+
+def route_rounds(cfg: MoEConfig) -> int:
+    """How many rounds of a masked maximum one token's selection takes (a
+    group's two largest, the ``topk_group`` groups, the K experts); 0:
+    the experts come from ``lax.top_k`` (``ROUND_MAX_K``)."""
+    if cfg.top_k > ROUND_MAX_K:
+        return 0
+    return cfg.top_k + (2 + cfg.topk_group if cfg.n_group > 1 else 0)
+
+
 def kept_groups(choice, cfg: MoEConfig):
     """Choice scores [T, E] (score plus bias) -> bool [T, n_group]: the
     ``topk_group`` groups a token keeps, a group's score the sum of its two
@@ -473,10 +543,9 @@ def kept_groups(choice, cfg: MoEConfig):
     the expert layer counts with that (``group_kept``), the references
     state the rule themselves."""
     T, E = choice.shape
-    best = jax.lax.top_k(choice.reshape(T, cfg.n_group, E // cfg.n_group),
-                         2)[0].sum(axis=-1)                       # [T, G]
-    _, kept = jax.lax.top_k(best, cfg.topk_group)
-    return jnp.any(kept[:, :, None] == jnp.arange(cfg.n_group), axis=1)
+    best = top_lanes(choice.reshape(T, cfg.n_group, E // cfg.n_group),
+                     2)[0].sum(axis=-1)                           # [T, G]
+    return _rounds(best, cfg.topk_group)[2]
 
 
 def route(logits, cfg: MoEConfig, bias=None):
@@ -486,7 +555,9 @@ def route(logits, cfg: MoEConfig, bias=None):
     every token kept, bool [T, n_group], or None without a group limit).
     With ``n_group`` > 1 the biased sigmoid router chooses inside the
     groups a token keeps (``kept_groups``): an expert of another group
-    scores -inf for the choice, whatever its score and bias."""
+    scores -inf for the choice, whatever its score and bias. The K experts
+    are ``top_lanes`` of the choice scores (no gradient passes the
+    choice), the weights the scores ``at_lanes``."""
     if cfg.n_group > 1 and (cfg.router_score != "sigmoid" or bias is None):
         raise NotImplementedError(
             f"n_group {cfg.n_group} on a {cfg.router_score} router "
@@ -495,24 +566,21 @@ def route(logits, cfg: MoEConfig, bias=None):
     kept = None
     if cfg.router_score == "sigmoid":
         probs = jax.nn.sigmoid(logits)
-        if bias is None:        # a router without one (``router_bias``)
-            weights, experts = jax.lax.top_k(probs, cfg.top_k)
-        else:
-            # the bias chooses and does not weigh; nothing is learned
-            # through it
-            choice = probs + jax.lax.stop_gradient(bias.astype(jnp.float32))
-            if cfg.n_group > 1:
-                kept = kept_groups(choice, cfg)
-                choice = jnp.where(jnp.repeat(
-                    kept, cfg.n_experts // cfg.n_group, axis=1), choice,
-                    -jnp.inf)
-            _, experts = jax.lax.top_k(choice, cfg.top_k)
-            weights = jnp.take_along_axis(probs, experts, axis=-1)
     elif cfg.router_score == "softmax":
         probs = jax.nn.softmax(logits, axis=-1)
-        weights, experts = jax.lax.top_k(probs, cfg.top_k)
     else:
         raise ValueError(f"unknown router_score {cfg.router_score!r}")
+    choice = jax.lax.stop_gradient(probs)
+    if bias is not None:
+        # the bias chooses and does not weigh; nothing is learned through it
+        choice = choice + jax.lax.stop_gradient(bias.astype(jnp.float32))
+        if cfg.n_group > 1:
+            kept = kept_groups(choice, cfg)
+            choice = jnp.where(jnp.repeat(
+                kept, cfg.n_experts // cfg.n_group, axis=1), choice,
+                -jnp.inf)
+    _, experts = top_lanes(choice, cfg.top_k)
+    weights = at_lanes(probs, experts)
     if cfg.norm_topk:
         weights = weights / weights.sum(axis=-1, keepdims=True)
     if cfg.route_scale != 1.0:
@@ -792,14 +860,18 @@ def expert_plan(cfg: MoEConfig, tokens: int) -> dict:
     are tiled (also the attributes of ``moe.expert_plan``, once a traced
     body): the activation and its matrices (3: gate, up, down; 2: up,
     down), the widths as stored (``padded_width`` 0: the config's own),
-    the experts held, the rows of a pass, and the (tm, tk, tn) the Mosaic
+    the experts held, the rows of a pass, the (tm, tk, tn) the Mosaic
     calls take for the up and the down product and for their weight
-    gradients (``ops/grouped_matmul.py`` ``tiles``; "" on the xla path)."""
+    gradients (``ops/grouped_matmul.py`` ``tiles``; "" on the xla path),
+    and how the router selects: ``route_form`` "rounds" with the rounds a
+    token's selection takes, or "top_k" with 0 (``route_rounds``)."""
     rows, item = expert_rows(cfg, tokens), jnp.dtype(cfg.dtype).itemsize
+    rounds = route_rounds(cfg)
     said = {"act": cfg.expert_act, "matrices": len(_matrices(cfg)) + 1,
             "width": cfg.d_ff, "shared_width": cfg.shared_width,
             "padded_width": 0, "held": cfg.n_held, "rows": rows,
-            "path": cfg.gmm_impl}
+            "path": cfg.gmm_impl, "route_rounds": rounds,
+            "route_form": "rounds" if rounds else "top_k"}
     for name, (k, n) in (("up", (cfg.d_model, cfg.d_ff)),
                          ("down", (cfg.d_ff, cfg.d_model))):
         gmm, tgmm = tiles(rows, k, n, item) if cfg.gmm_impl == "pallas" \

@@ -472,12 +472,14 @@ def test_the_plan_counts_a_dense_layer_for_what_it_holds():
         ("attn_q", "attn_k", "attn_v"), ("mix_proj",))
 
 
-# sha256 (16 hex) of the gradient's jaxpr, addresses masked, at the parent
-# commit of PR 54 (f0cf476), B 2 x S 32
-PARENTS_PROGRAMS = {("tiny", False): "4934224b712006ca",
-                    ("tiny", True): "7b723369c3d331f9",
-                    ("tiny-nemotron", False): "a96c779da023bb95",
-                    ("tiny-nemotron", True): "cf02102d36b480ad"}
+# sha256 (16 hex) of the gradient's jaxpr, addresses masked, B 2 x S 32: at
+# the parent commit of PR 54 (f0cf476) with the router's selection as PR 60
+# made it (``moe.top_lanes``' rounds for ``lax.top_k``: every expert layer's
+# program changed there and nothing else of these; pinned again at PR 60)
+PARENTS_PROGRAMS = {("tiny", False): "16f9f5efa6cd238f",
+                    ("tiny", True): "a718de20c6b412f1",
+                    ("tiny-nemotron", False): "3781eaf9da8114a4",
+                    ("tiny-nemotron", True): "8ba87644f2f51576"}
 
 
 @pytest.mark.parametrize("preset,remat", sorted(PARENTS_PROGRAMS))

@@ -483,14 +483,19 @@ def _primitives(jaxpr, into=None):
 # kept, so the equations lower to nothing. PR 53 replaced the three dense
 # ones: ``feed_forward`` names gate's and up's products, two ``name``
 # equations a traced body, 8 -> 12, 8 -> 12 and 3 -> 5; every other
-# primitive's count is the parent's and the two ``moe`` digests stood.)
+# primitive's count is the parent's and the two ``moe`` digests stood. PR 60
+# replaced the two ``moe`` ones: the router selects by rounds
+# (``moe.top_lanes``), ``top_k`` 2 -> 0 and 1 -> 0, ``scatter-add`` 4 -> 3
+# both, ``reduce_min`` 0 -> 4 and 0 -> 2, ``reduce_max`` 7 -> 11 and 5 -> 7
+# with the selects, compares and masks of the rounds; the three dense
+# digests stood.)
 JAXPRS_FROM = "0.9.0"
 ONE_DEVICE_JAXPRS = {
     ("dense", 2, "flash", True, "bfloat16"): "3d9cb84650f54cfb",
     ("dense", 4, "flash", True, "bfloat16"): "c35af5bbf0866773",
     ("dense", 2, "xla", False, "float32"): "d7b97d42f0c9a0f5",
-    ("moe", 2, "flash", True, "bfloat16"): "3700376b7b68b19e",
-    ("moe", 2, "xla", False, "float32"): "1981221167aeb9de",
+    ("moe", 2, "flash", True, "bfloat16"): "14c9cd882adc4359",
+    ("moe", 2, "xla", False, "float32"): "3717cbc5fa5e0444",
 }
 
 

@@ -345,7 +345,8 @@ def test_plan_instants_carry_the_groups_the_runs_and_the_experts(monkeypatch):
         "act": "relu2", "matrices": 2, "width": 24, "shared_width": 40,
         "padded_width": 0, "held": 2, "rows": 128, "path": "pallas",
         "gmm_up": "128x48x24", "tgmm_up": "128x48x24",
-        "gmm_down": "128x24x48", "tgmm_down": "128x24x48"}
+        "gmm_down": "128x24x48", "tgmm_down": "128x24x48",
+        "route_form": "rounds", "route_rounds": cfg.top_k}
     # the cell's: 2,688 in three tiles of 896, 1,856 whole or 1,024 + 832
     wide = moe.expert_plan(cfg.replace(
         d_model=2688, d_ff=1856, n_experts=128, top_k=6,

@@ -126,6 +126,62 @@ def test_the_held_experts_walks_at_the_cells_widths(widths, one_chip,
     assert not copies, copies
 
 
+def test_the_ling_routed_layer_selects_with_no_sort_and_no_scatter(
+        one_chip, on_chip_branch):
+    """One routed layer at the Ling cell's widths (16,384 tokens of 2,560,
+    a router 512 wide, 8 experts a token inside 4 of 8 groups, 16 experts
+    of 768 held from the 192nd on, the shared expert), forward, replay
+    under ``jax.checkpoint`` and backward, compiled for the chip: no
+    instruction issued under the scope ``router`` is a sort, a top-k call,
+    a gather or a scatter (the selection is ``moe.top_lanes``' rounds, the
+    weights ``at_lanes``' masked sums, whose gradient is a select), and
+    the sorts the layer keeps are ``dispatch``'s, of the assignments, and
+    the ones XLA makes of ``combine``'s scatter-adds."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import moe
+
+    bf = jnp.bfloat16
+    T, D, F, E, held = 16384, 2560, 768, 512, 16
+    cfg = moe.MoEConfig(
+        vocab_size=256, d_model=D, n_layers=1, n_heads=8, n_kv_heads=8,
+        d_ff=F, n_experts=E, top_k=8, experts_held=(held, 192),
+        shared_d_ff=F, router_score="sigmoid", n_group=8, topk_group=4,
+        norm_topk=True, route_scale=2.5, dtype=bf, param_dtype=bf,
+        gmm_impl="pallas")
+    assert moe.expert_plan(cfg, T)["route_rounds"] == 14
+    lp = {"router": _sds((D, E), bf, one_chip),
+          "router_bias": _sds((E,), jnp.float32, one_chip),
+          "we_gate": _sds((held, D, F), bf, one_chip),
+          "we_up": _sds((held, D, F), bf, one_chip),
+          "we_down": _sds((held, F, D), bf, one_chip),
+          "ws_gate": _sds((D, F), bf, one_chip),
+          "ws_up": _sds((D, F), bf, one_chip),
+          "ws_down": _sds((F, D), bf, one_chip)}
+
+    def loss(lp, x):
+        y, stats = jax.checkpoint(
+            lambda lp, x: moe.feed_forward(x, lp, cfg))(lp, x)
+        return jnp.sum(y.astype(jnp.float32) ** 2) + stats["balance"]
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        lp, _sds((1, T, D), bf, one_chip)).compile().as_text()
+    walks = re.compile(r" (sort|scatter|gather)\(|TopK|top_k", re.I)
+    issued = [ln for ln in text.splitlines() if re.search(
+        r'op_name="[^"]*[/(]router[/)]', ln)]
+    assert len(issued) > 20, len(issued)
+    bad = [ln.strip()[:200] for ln in issued
+           if walks.search(ln.split("metadata=")[0])]
+    assert not bad, bad[:3]
+    sorts = [ln for ln in text.splitlines() if re.search(r" sort\(", ln)]
+    names = [re.search(r'op_name="([^"]*)"', ln).group(1) for ln in sorts]
+    assert names and all(re.search(r"[/(](dispatch|combine)[/)]", n)
+                         for n in names), names
+
+
 # (rows, experts, model width, one expert's width) of a cell's grouped
 # matmuls: OLMoE-1B-7B's 131,072 routed rows over 64 experts of 2048 x 1024;
 # GLM-4.7-Flash's one pass of 16,384 rows over the 8 experts held, 2048 x
