@@ -3,8 +3,8 @@
 The embedding, the loop over the layers, the layer checkpoint, the head and
 the loss are written once (models/llama.py, models/remat.py, and for serving
 models/cached.py). A family (llama's dense model, models/moe.py,
-models/hybrid.py, models/latent.py, models/sala.py, models/ling.py) is the
-module that
+models/hybrid.py, models/latent.py, models/sala.py, models/ling.py,
+models/solar.py) is the module that
 defines a config class and, at its end, builds ``FAMILY``: a ``Family`` that
 names every member the shared code reads. A family that takes a member from
 another builds FROM that family's record (``moe.FAMILY.replace("hybrid",
@@ -66,8 +66,8 @@ class Family:
     # what it reports): every layer's attention half, where it is not three
     # projections of the hidden state
     attention_half: Optional[Callable] = None
-    # (x, lp, cfg, kind, mesh=) -> x: the first half of a layer whose kind
-    # is no attention
+    # (x, lp, cfg, kind, mesh=) -> x, or (x, what it reports): the first
+    # half of a layer whose kind is no attention
     mixer_half: Optional[Callable] = None
     # (cfg, kind, rows) -> bytes a mixer's backward holds beside its
     # matrices' products (the step's estimate)

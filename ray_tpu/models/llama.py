@@ -549,6 +549,14 @@ def _attention_half(x, lp, cfg: LlamaConfig, cos, sin, mesh=None, rules=None,
     attn = _attention(q, k, v, cfg, causal=True, mesh=mesh, rules=rules,
                       kind=kind)
     attn = attn.reshape(B, S, H * HD)
+    if "w_attn_gate" in lp:
+        # a sigmoid gate a CHANNEL of the attention's output, before ``wo``
+        # (models/solar.py: the leaf says so, no config field)
+        assert tp is None, kind
+        with jax.named_scope("gate"):
+            attn = attn * jax.nn.sigmoid(
+                (h @ _dq(lp["w_attn_gate"], dt)).astype(jnp.float32)
+            ).astype(dt)
     if tp is not None:
         out = matmul_reduce_scatter(attn, _dq(lp["wo"], dt), tp)
     else:
@@ -613,7 +621,8 @@ def _layer(x, lp, cfg: LlamaConfig, cos, sin, mesh=None, rules=None, tp=None,
     config names (``attn_kinds``: the same half with that kind's window,
     ``cos`` and ``sin`` its tables, under a scope of the kind's name); any
     other kind of layer takes its first half from the family's
-    ``mixer_half`` (x, lp, cfg, kind -> x). A family with an
+    ``mixer_half`` (x, lp, cfg, kind -> x, or (x, what it reports: joined
+    to the layer's statistics)). A family with an
     ``attention_half`` of its own (x, lp, cfg, cos, sin -> x) supplies
     every layer's: (x, lp, cfg, cos, sin, carried, kind -> x, carried,
     what it reports). ``kind`` goes on to the feed-forward. A config with
@@ -644,6 +653,8 @@ def _layer(x, lp, cfg: LlamaConfig, cos, sin, mesh=None, rules=None, tp=None,
     elif first == "mixer":
         with jax.named_scope("mixer"):
             x = family.mixer_half(x, lp, cfg, kind, mesh=mesh)
+            if isinstance(x, tuple):    # a mixer that reports of itself
+                x, said = x
     stats = None
     if second:
         with jax.named_scope("feed_forward"):

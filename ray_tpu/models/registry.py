@@ -32,6 +32,10 @@ _FAMILIES = {
     # Ling 3.0's model_type: ling.py preset "tiny" (Kimi-Delta-Attention
     # layers beside latent attention, group-limited sigmoid-routed experts)
     "bailing_hybrid": "ray_tpu.models.ling",
+    # Solar Open2's model_type: solar.py preset "tiny" (Kimi-Delta-Attention
+    # layers with an unbounded gate beside gated grouped-query attention
+    # with no position table, sigmoid-routed experts in every layer)
+    "solar_open2": "ray_tpu.models.solar",
     "vit": "ray_tpu.models.vit",
 }
 

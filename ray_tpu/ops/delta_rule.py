@@ -7,7 +7,10 @@ For every head with a [dk, dv] state S, step by step over a sequence:
     o_t = S_t^T q_t
 
 (q_t, k_t [dk]; v_t [dv]; g_t [dk] <= 0 the log of a decay a KEY CHANNEL;
-beta_t in (0, 1) a head). The state is first decayed channel by channel,
+beta_t in (0, 2) a head: over 1 the eigenvalue 1 - beta_t of I - beta_t k_t
+k_t^T along a unit k_t is negative, Kimi Linear's ``allow_neg_eigval``, and
+the state stays bounded while it lies in (-1, 1)). The state is first
+decayed channel by channel,
 then what it holds along k_t is erased by beta_t and beta_t k_t v_t^T is
 written: with u_t = beta_t (v_t - (Diag(exp(g_t)) S_{t-1})^T k_t) it reads
 S_t = Diag(exp(g_t)) S_{t-1} + k_t u_t^T. ``ops/ssd.py`` computes neither
@@ -43,6 +46,33 @@ read 0.6% off. That is what the caller's bound on g buys (``lower_bound``,
 that does not. Columns after the sub-block are masked out of the result
 and their exponent is clamped.
 
+A caller that states NO bound (``lower_bound`` None: Kimi Linear's first
+gate, g = -exp(A_log) softplus(.), a step of which can decay a channel by
+exp(-100)) gets a cut that needs none (``_pair_blocks_free``), because no
+factor of it passes 1. The pairs s < t of a chunk are cut in HALVES: for t
+in the later half and s in the earlier one exp(G_t - G_s) is split at the
+earlier half's last row m, exp(G_t - G_m) exp(G_m - G_s), both exponents
+sums of g <= 0; each half is cut again the same way, down to blocks of one
+row, and the diagonal of Aqk is q_t . k_t itself. A pair belongs to the one
+level at which the highest differing bit of t and s lies, so a level is ONE
+product for the whole chunk ([2 C, dk] x [dk, C], masked to the level's
+pairs): log2(C) = 6 products a head and chunk of 64 where the bounded cut
+has 4 smaller ones, and 12 more on the way back where it has 8. A factor
+can underflow, and where either does the true product is under float32's
+least: a channel that dies in one step reads as nothing, not as NaN or inf,
+forward and backward (the gradient at an underflowed pair is the pair's
+value, 0). The sums the factors are made of (``_aligned_sums``: the running
+sum inside aligned blocks of 1, 2, 4 .. rows, and each block's even half's
+total) are made bottom up by adds, so a sum over few rows is the sum of few
+terms and not the difference of two running sums of the chunk (at -60 a
+step a chunk's running sum reaches -3,800, where a float32 ulp is 2e-4);
+the chunk's running sum is the last level. On a v5e the two calls at [1,
+16384, 64, 128] bfloat16 take 33.6 ms forward and 90.7 ms forward and
+backward on this cut against 23.6 and 59.3 on the bounded one over the
+same (bounded) inputs (16.9 / 45.5 against 11.9 / 29.8 at 32 heads: PERF.md
+6, PR 61), so a caller that CAN state a bound keeps the cut it had, with
+the program it had.
+
 The inverse of the unit lower triangular [C, C] matrix is made by block
 forward substitution with products only (a TPU has no triangular solve):
 from blocks of one (the identity) the diagonal blocks double, inv([[P1,
@@ -50,7 +80,17 @@ from blocks of one (the identity) the diagonal blocks double, inv([[P1,
 once is P <- P - P M P with M the lower-left halves: log2(C) rounds. No
 power of A is ever formed (the product form (I - A)(I + A^2)(I + A^4).. is
 exact too, but its powers grow like binomials where adjacent keys are
-alike). On a v5e the two calls' time is the CHAIN of these rounds, each
+alike). The rounds are the same products for beta up to 2; what grows is
+their float32 rounding where adjacent keys are alike: with EVERY key the
+same, no decay and beta 1.99 (I + Diag(beta) Akk is 1.99 in every entry
+under the diagonal, its inverse alternates at 1.99 x (-0.99)^n, and a
+round's sums of 32 terms of size 4 cancel to it) an entry of the inverse of
+side 64 reads 2.5e-5 off (6e-8 at beta 0.97, 6e-8 at 1.5) and the output's
+relative L2 distance to the step-by-step recurrence in float64 is 2.5e-5 on
+the CPU and 1.8e-5 on the chip (S 256; PR 61), where ``solve_triangular``
+reads 2e-6; on a model's seeded inputs with beta = 2 sigmoid(.) the calls
+read 5e-7 to 2.4e-6 off the plain path in float32 (part (e) of the Solar
+cell, [1, 16384, 64, 128]). On a v5e the two calls' time is the CHAIN of these rounds, each
 waiting on the last, far more than the size of their products (about 0.2
 us a [128, 128] float32 product at ``HIGHEST`` in the chain, 0.14 us a
 [64, 64] one, 0.05 us a pair product, which waits on nothing: PERF.md 6,
@@ -315,6 +355,97 @@ def _pair_grads(q, k, cum, d_qk, d_kk, sub: int, clamp: float):
             jnp.concatenate(dcum_rows) - k * dk_cols)
 
 
+def _aligned_sums(g):
+    """g [C, dk] float32 -> ([(P_h, E_h) for h = 1, 2, 4 .. C / 2], the
+    running sum G down the whole chunk): the sums the cut that needs no
+    bound on g splits exp(G_t - G_s) at. P_h[t] is the sum of g over the
+    rows up to and including t of t's ALIGNED block of h rows; E_h[t] the
+    total of the even half of t's aligned block of 2 h rows (P_h at that
+    half's last row), the same in every row of the block. Made bottom up,
+    P_2h = P_h + E_h in the odd halves, so a sum over few rows is the sum
+    of few terms and not the difference of two long running sums; G is
+    P_C. Blocks inside a sublane tile are gathered by row shifts and
+    selects, whole tiles by a row broadcast."""
+    c = g.shape[0]
+    t = jax.lax.broadcasted_iota(jnp.int32, (c, 1), 0)
+    p, h, levels = g, 1, []
+    while h < c:
+        if 2 * h < 8:
+            at = t & (2 * h - 1)
+            e = jnp.where(at == h - 1, p, 0.0)
+            for j in range(2 * h):
+                if j != h - 1:  # row t + (h - 1 - j), inside t's block
+                    e = jnp.where(at == j,
+                                  pltpu.roll(p, (j - (h - 1)) % c, 0), e)
+        else:
+            e = jnp.concatenate([
+                jnp.broadcast_to(p[lo + h - 1:lo + h], (2 * h, p.shape[1]))
+                for lo in range(0, c, 2 * h)])
+        levels.append((p, e))
+        p = p + jnp.where((t & h) != 0, e, 0.0)
+        h *= 2
+    return levels, p
+
+
+def _level(t, s, h: int):
+    """The pairs s < t a cut of blocks of 2 h rows splits: t and s agree
+    above bit h, which t has and s has not."""
+    return ((t ^ s) < 2 * h) & ((t & ~s & h) != 0)
+
+
+def _level_factors(p, e):
+    """The two factors of exp(G_t - G_s) for t in the odd half and s in
+    the even half of an aligned block: (rows' exp(G_t - G_m), columns'
+    exp(G_m - G_s)), m the even half's last row. Both exponents are sums
+    of g <= 0 (the columns' clamped at 0 in the odd halves, which the
+    level masks out), so neither factor passes 1 whatever the gate: they
+    can underflow, and where either does the true product is under
+    float32's least."""
+    return jnp.exp(p), jnp.exp(jnp.minimum(e - p, 0.0))
+
+
+def _pair_blocks_free(q, k, levels):
+    """``_pair_blocks`` for a gate with no bound: the pairs s < t of a
+    chunk are cut in halves, the quarter between the halves split at the
+    halves' boundary (``_level_factors``), and the halves cut again down
+    to blocks of one row: log2(C) products for the whole chunk, each
+    masked to its level's pairs. The diagonal of Aqk is q_t . k_t."""
+    c = k.shape[0]
+    t, s = _rows_cols(c)
+    aqk = jnp.where(t == s, jnp.sum(q * k, axis=1, keepdims=True), 0.0)
+    akk = jnp.zeros((c, c), jnp.float32)
+    for i, (p, e) in enumerate(levels):
+        rows, cols = _level_factors(p, e)
+        block = _dot32(jnp.concatenate([q * rows, k * rows]), k * cols, _NT)
+        level = _level(t, s, 1 << i)
+        aqk = jnp.where(level, block[:c], aqk)
+        akk = jnp.where(level, block[c:], akk)
+    return aqk, akk
+
+
+def _pair_grads_free(q, k, levels, d_qk, d_kk):
+    """The way back through ``_pair_blocks_free``: as ``_pair_grads``. A
+    level's split point cancels in every pair's exponent, so dcum is the
+    rows' part less the columns' part; at a pair whose factor underflowed
+    the gradient is the pair's value, 0."""
+    c = k.shape[0]
+    t, s = _rows_cols(c)
+    diagonal = jnp.sum(jnp.where(t == s, d_qk, 0.0), axis=1, keepdims=True)
+    dq, dk_, dcum = diagonal * k, diagonal * q, jnp.zeros_like(k)
+    for i, (p, e) in enumerate(levels):
+        rows, cols = _level_factors(p, e)
+        level = _level(t, s, 1 << i)
+        d_both = jnp.concatenate([jnp.where(level, d_qk, 0.0),
+                                  jnp.where(level, d_kk, 0.0)])
+        back = _dot32(d_both, k * cols) * jnp.concatenate([rows, rows])
+        both = jnp.concatenate([q * rows, k * rows])
+        dk_cols = _dot32(d_both, both, _TN) * cols
+        dq = dq + back[:c]
+        dk_ = dk_ + back[c:] + dk_cols
+        dcum = dcum + q * back[:c] + k * back[c:] - k * dk_cols
+    return dq, dk_, dcum
+
+
 def _shifted_round(p, m, b: int):
     """P M P for P unit lower triangular with diagonal blocks of ``b`` rows
     (so b - 1 subdiagonals), float32, without a product: (M P)[t, s] = M[t,
@@ -399,9 +530,13 @@ def _chunk_forms(heads, mm, sub: int, clamp: float):
     head."""
     forms = []
     for q, k, v, g, beta, st in heads:
-        cum = _running_sum(g)
-        aqk, akk = _pair_blocks(q, k, cum, sub, clamp)
-        forms.append(dict(cum=cum, aqk=aqk, akk=akk))
+        if clamp is None:       # no bound on g: the cut in halves
+            levels, cum = _aligned_sums(g)
+            aqk, akk = _pair_blocks_free(q, k, levels)
+        else:
+            levels, cum = None, _running_sum(g)
+            aqk, akk = _pair_blocks(q, k, cum, sub, clamp)
+        forms.append(dict(cum=cum, aqk=aqk, akk=akk, levels=levels))
     inverses = _unit_lower_inverses(
         [beta * f["akk"] for (*_, beta, _), f in zip(heads, forms)])
     for (q, k, v, g, beta, st), f, x in zip(heads, forms, inverses):
@@ -489,8 +624,12 @@ def _block_backward(q, k, v, g, betas, state, do, d_state, *, heads: int,
             # rest = v - kg st^T
             d_kg = -_dot(d_rest, st, _NN, mm)
             d_st = d_st - _dot(d_rest, f["kg"], _TN, mm)
-            dq, dk_, dcum = _pair_grads(qi, ki, f["cum"], d_aqk, beta * d_a,
-                                        sub, clamp)
+            if clamp is None:
+                dq, dk_, dcum = _pair_grads_free(qi, ki, f["levels"], d_aqk,
+                                                 beta * d_a)
+            else:
+                dq, dk_, dcum = _pair_grads(qi, ki, f["cum"], d_aqk,
+                                            beta * d_a, sub, clamp)
             through = d_ke * f["ke"]
             dcum = dcum + d_qg * f["qg"] + d_kg * f["kg"] - through
             d_last = d_last + jnp.sum(through, axis=0, keepdims=True)
@@ -532,13 +671,14 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, sin_ref, do_ref,
 
 
 @functools.lru_cache(maxsize=None)
-def _products(c: int, heads: int, per: int, dk: int, dv: int, dtype):
+def _products(c: int, heads: int, per: int, dk: int, dv: int, dtype,
+              bounded: bool = True):
     """(float32 products, MXU passes) a head and chunk of the forward and
     of the backward call, counted by tracing one block's chunk through the
-    code the kernels run."""
+    code the kernels run (``bounded``: with the cut a bound on g allows)."""
     f32 = jnp.float32
     form = dict(heads=heads, per=per, mm=jnp.dtype(dtype), sub=min(SUB, c),
-                clamp=40.0)
+                clamp=40.0 if bounded else None)
     wide = lambda n, t: jax.ShapeDtypeStruct((c, heads * n), t)  # noqa: E731
     block = (wide(dk, dtype), wide(dk, dtype), wide(dv, dtype), wide(dk, f32),
              wide(1, f32), jax.ShapeDtypeStruct((heads * dv, dk), f32))
@@ -556,7 +696,7 @@ def _products(c: int, heads: int, per: int, dk: int, dv: int, dtype):
 
 
 def plan(*, B: int, S: int, H: int, dk: int, dv: int, dtype, impl: str,
-         chunk: int = CHUNK, lower_bound: float = -5.0) -> dict:
+         chunk: int = CHUNK, lower_bound: float | None = -5.0) -> dict:
     """The op's block plan (also the attributes of ``kda.plan``), from the
     shapes alone: the chunk (a sequence shorter than ``chunk`` is ONE
     chunk, the least power of two that holds it), the sub-block of the pair
@@ -568,7 +708,11 @@ def plan(*, B: int, S: int, H: int, dk: int, dv: int, dtype, impl: str,
     kernels' own code), the VMEM one instance of the backward call holds
     (its blocks twice, Mosaic double-buffers; the state scratch; the
     float32 forms of the heads of an inverse: a dozen of its side squared,
-    a dozen [C, dk] and half a dozen [C, dv] a head, three [dv, dk]), the
+    a dozen [C, dk] and half a dozen [C, dv] a head, three [dv, dk]; the
+    cut that needs no bound two [C, dk] more a level), which cut of the
+    pair products ran (``cut`` "bounded": sub-blocks split at their middle
+    row; "halving": ``lower_bound`` None, blocks of ``cut_sizes`` rows each
+    split at its halves' boundary), the
     bytes of the chunks' incoming states the forward rule keeps for the
     backward, and the HBM bytes the two calls move for one head and
     sequence."""
@@ -587,18 +731,21 @@ def plan(*, B: int, S: int, H: int, dk: int, dv: int, dtype, impl: str,
               + 2 * heads * c * dk * 4                    # g, dg
               + 2 * c * 128 * 4                           # beta, dbeta
               + 2 * heads * dv * dk * 4)                  # state in, ds0
-    forms = 12 * (per * c) ** 2 * 4 + per * (12 * c * dk + 6 * c * dv) * 4 \
-        + 3 * dv * dk * 4
+    bounded = lower_bound is not None
+    sizes = [] if bounded else [2 << i for i in range(c.bit_length() - 1)]
+    forms = 12 * (per * c) ** 2 * 4 + per * (
+        (12 + 2 * len(sizes)) * c * dk + 6 * c * dv) * 4 + 3 * dv * dk * 4
     states = B * (steps // c) * H * dv * dk * 4
     hbm = steps * ((2 * dk + dv) * item + dk * 4 + 4) * 2 \
         + steps * dv * item * 2 + 2 * (steps // c) * dv * dk * 4
     said = {"path": impl, "S": S, "chunk": c, "sub_block": SUB,
             "heads_per_block": heads, "lower_bound": lower_bound,
+            "cut": "bounded" if bounded else "halving", "cut_sizes": sizes,
             "vmem_bytes": 0, "state_bytes_kept": states,
             "hbm_bytes_per_head": 0}
     if on:
         (f32_fwd, fwd), (f32_bwd, bwd) = _products(
-            c, heads, per, dk, dv, jnp.dtype(dtype))
+            c, heads, per, dk, dv, jnp.dtype(dtype), bounded)
         said.update(
             inverse_side=per * c, f32_products_fwd=f32_fwd,
             f32_products_bwd=f32_bwd, mxu_passes_fwd=fwd, mxu_passes_bwd=bwd,
@@ -695,10 +842,12 @@ _scan_pallas.defvjp(_scan_pallas_fwd, _scan_pallas_bwd)
 
 def gated_delta_rule(q, k, v, g, beta, *, initial_state=None,
                      chunk: int = CHUNK, impl: str = "xla",
-                     lower_bound: float = -5.0):
+                     lower_bound: float | None = -5.0):
     """q, k [B, S, H, dk], v [B, S, H, dv], g [B, S, H, dk] float32 (the
-    log of the decay a step and key channel, in [lower_bound, 0]), beta
-    [B, S, H] float32, initial_state [B, H, dk, dv] float32 or None (zero)
+    log of the decay a step and key channel, in [lower_bound, 0]; any g <=
+    0 where the caller states no bound, ``lower_bound`` None: the kernels
+    then cut the pair products in halves, module docstring), beta
+    [B, S, H] float32 in (0, 2), initial_state [B, H, dk, dv] float32 or None (zero)
     -> o [B, S, H, dv] in v's type: the recurrence of the module docstring.
     Differentiable in all six on both paths. q and k arrive as the model
     made them (normed, q scaled): the op scales nothing."""
@@ -710,7 +859,7 @@ def gated_delta_rule(q, k, v, g, beta, *, initial_state=None,
     if chunk % SUB or chunk & (chunk - 1):
         raise ValueError(f"gated_delta_rule: a chunk of {chunk} steps; want "
                          f"a power of two and a multiple of {SUB}")
-    clamp = _clamp(lower_bound)
+    clamp = None if lower_bound is None else _clamp(lower_bound)
     said = plan(B=B, S=S, H=H, dk=dk, dv=dv, chunk=chunk, dtype=q.dtype,
                 impl=impl, lower_bound=lower_bound)
     tracing.plan("kda.plan", said)

@@ -1,6 +1,6 @@
 """Is a cell's step program still its parent's? A digest of what is lowered.
 
-    python tests/lowered_cells.py [--repo DIR] [--texts DIR] [cell ...]
+    python tests/lowered_cells.py [--repo DIR] [--texts DIR] [--check] [cell ...]
 
 lowers the train step of every cell of ``BENCHMARK.json`` (or of those
 named) for a described v5e, by the cell's own files and nothing compiled,
@@ -10,8 +10,9 @@ MLIR bytecode that carries file names and line numbers, decoded and printed
 without them). Run it on an unpacked parent (``git archive <commit> | tar -x
 -C DIR``, then ``--repo DIR``) and on the tree, and ``diff`` the two
 outputs: a refactor that means to change no program changes no line
-(``--texts`` keeps the texts, for the diff when one does). About 70 s for
-the eleven cells; needs libtpu, no chip.
+(``--texts`` keeps the texts, for the diff when one does; ``--check`` holds
+the cells to ``PINNED``, the digests PR 61 found on its parent). About 90 s
+for the thirteen cells; needs libtpu, no chip.
 """
 
 import argparse
@@ -36,8 +37,35 @@ KINDS = {
     "train_blockset": ("model_sala", "sala_config", "sala"),
     "train_shortconv": ("model_lfm2", "hybrid_config", "hybrid"),
     "train_kda": ("model_ling", "ling_config", "ling"),
+    "train_solar": ("model_solar", "solar_config", "solar"),
 }
 V5E_LIMIT = 16_909_336_064        # what a v5e chip states (here none does)
+# the digests of the twelve cells PR 61 found, taken on its PARENT (77455d7,
+# jax PINNED_FROM) and equal on its tree: PR 61 added a second cut to
+# ``ops/delta_rule.py``, a mixer that may report and a gate's leaf to
+# ``llama._layer`` and ``_attention_half``, and left every program alone.
+# A cell's text names its functions by what the process lowered before it,
+# so these are the digests of ONE run over every cell in the manifest's order
+# (``--check``, which takes no cell names, holds such a run to them); a cell
+# lowered alone reads otherwise (``@_where_2099``, ``@closed_call_1357``: the
+# numbers count what was traced before). The bounded cut's own program is
+# held by tests/test_delta_rule.py (a jaxpr's digest at a tiny shape). A PR
+# that MEANS to change a cell's program replaces its digest, and says so
+PINNED_FROM = "0.9.0"
+PINNED = {
+    "train-deepseek7b-l8": "2e7bf97854587e81",
+    "train-deepseek7b-fsdp2tp2": "c918d8d005f83a3f",
+    "train-olmoe1b7b-s4096-b4": "823cfed5f3a8c7fa",
+    "train-granite4hs-ep8-s8192-b2": "04f181234a94ae93",
+    "train-glm47flash-ep8-s8192-b2": "a968ebda9000750d",
+    "train-mellum2-ep4-s16384-b1": "9b9f8d8d30c8722d",
+    "train-commandaplus-ep16-s8192-b1": "dff8f54b1acc93fa",
+    "train-glm52-ep32-s16384-b1": "ca4de7c508591ea3",
+    "train-nemotron3nano-ep8-s8192-b2": "05378a4b5d8d9ebc",
+    "train-minicpmsala-l4-s16384-b1": "9e8db484700b5162",
+    "train-lfm2-ep4-s16384-b1": "d6d3bc666242dc82",
+    "train-ling3flash-ep32-s16384-b1": "c626e69b9c15ab7d",
+}
 
 
 def lowered(name, topo):
@@ -110,8 +138,12 @@ def main():
     ap.add_argument("--repo", default=os.path.join(os.path.dirname(
         os.path.abspath(__file__)), os.pardir))
     ap.add_argument("--texts", help="a directory to keep the texts in")
+    ap.add_argument("--check", action="store_true",
+                    help="exit 1 where a cell's digest is not PINNED's")
     ap.add_argument("cells", nargs="*")
     args = ap.parse_args()
+    if args.check and args.cells:
+        ap.error("--check holds a run over EVERY cell to PINNED")
     repo = os.path.abspath(args.repo)
     os.chdir(repo)
     sys.path.insert(0, repo)
@@ -130,14 +162,19 @@ def main():
     train_step.device_bytes_limit = lambda mesh: V5E_LIMIT
     with open("BENCHMARK.json") as f:
         cells = args.cells or [w["name"] for w in json.load(f)["workloads"]]
+    moved = []
     for name in cells:
         text = without_locations(lowered(name, topo))
         if args.texts:
             os.makedirs(args.texts, exist_ok=True)
             with open(os.path.join(args.texts, name + ".mlir"), "w") as f:
                 f.write(text)
-        print(name, hashlib.sha256(text.encode()).hexdigest()[:16],
-              flush=True)
+        digest = hashlib.sha256(text.encode()).hexdigest()[:16]
+        print(name, digest, flush=True)
+        if args.check and PINNED.get(name, digest) != digest:
+            moved.append(name)
+    if moved:
+        sys.exit(f"not the pinned program (jax {PINNED_FROM}): {moved}")
 
 
 if __name__ == "__main__":

@@ -38,7 +38,9 @@ RECIPE_KEYS = {"train": ("attn_impl", "remat", "f32_logits"),
                "train_shortconv": ("attn_impl", "gmm_impl", "remat",
                                    "f32_logits"),
                "train_kda": ("attn_impl", "gmm_impl", "kda_impl", "remat",
-                             "f32_logits")}
+                             "f32_logits"),
+               "train_solar": ("attn_impl", "gmm_impl", "kda_impl", "remat",
+                               "f32_logits")}
 # published config.json key -> the program's field
 WIDTHS = {"hidden_size": "d_model", "num_hidden_layers": "n_layers",
           "num_attention_heads": "n_heads",
@@ -66,7 +68,7 @@ def test_cell_program_config_builds_at_its_published_widths(name):
     from benchmark import (model, model_commanda, model_glm, model_glm52,
                            model_granite, model_lfm2, model_ling,
                            model_mellum, model_moe, model_nemotron,
-                           model_sala, resolve)
+                           model_sala, model_solar, resolve)
 
     cell = resolve.cell(name)
     kind, conf, recipe = cell["kind"], cell["config"], cell["train"]
@@ -80,7 +82,8 @@ def test_cell_program_config_builds_at_its_published_widths(name):
              "train_alternating": model_nemotron.hybrid_config,
              "train_blockset": model_sala.sala_config,
              "train_shortconv": model_lfm2.hybrid_config,
-             "train_kda": model_ling.ling_config}[kind]
+             "train_kda": model_ling.ling_config,
+             "train_solar": model_solar.solar_config}[kind]
     passed = {k: recipe[k] for k in RECIPE_KEYS[kind] if k in recipe}
     cfg = build(conf, **passed)
 
@@ -95,7 +98,8 @@ def test_cell_program_config_builds_at_its_published_widths(name):
               "train_alternating": model_nemotron.HF_TO_FIELD,
               "train_blockset": model_sala.HF_TO_FIELD,
               "train_shortconv": model_lfm2.HF_TO_FIELD,
-              "train_kda": model_ling.HF_TO_FIELD}[kind]
+              "train_kda": model_ling.HF_TO_FIELD,
+              "train_solar": model_solar.HF_TO_FIELD}[kind]
     for key, field in widths.items():
         assert getattr(cfg, field) == conf[key], (name, key)
     if kind == "train_parallel":
@@ -210,6 +214,34 @@ def test_cell_program_config_builds_at_its_published_widths(name):
             "sigmoid", True, conf["rotary_dim"])
         from ray_tpu.models import ling
         assert sum(n for _, n in ling.layer_runs(cfg)) \
+            == conf["num_hidden_layers"]
+    if kind == "train_solar":
+        # the grouped-query half's heads and stated width, the expert's
+        # width, the router's scale and the rank of the pairs are the
+        # published keys' (the map above); a KDA half's heads, width and
+        # taps the nested group's; which layers attend the file's list; no
+        # table, no bound on the gate; the router's width and the experts
+        # held the deployment's
+        assert {"head_dim", "num_key_value_heads", "moe_intermediate_size",
+                "routed_scaling_factor", "kda_gate_rank"} <= set(widths)
+        linear, dep = conf["linear_attn_config"], conf["deployment"]
+        assert (cfg.kda_heads, cfg.kda_head_dim, cfg.conv_taps) == (
+            linear["num_heads"], linear["head_dim"],
+            linear["short_conv_kernel_size"])
+        assert linear["num_kv_heads"] is None and not conf["use_rope"]
+        assert cfg.n_experts == dep["router_experts"]
+        assert cfg.experts_held == (conf["n_routed_experts"],
+                                    dep["experts_first"])
+        assert cfg.kinds == tuple(
+            "gqa" if i in conf["gqa_layers"] else "kda"
+            for i in range(conf["num_hidden_layers"]))
+        assert not cfg.rope and not dict(cfg.attn_kinds)["gqa"].rope
+        assert not hasattr(cfg, "kda_lower_bound")
+        assert cfg.head_dim == conf["head_dim"] and cfg.n_group == 1
+        assert cfg.shared_d_ff == conf["n_shared_experts"] * cfg.d_ff
+        assert (cfg.router_score, cfg.norm_topk) == ("sigmoid", True)
+        from ray_tpu.models import solar
+        assert sum(n for _, n in solar.layer_runs(cfg)) \
             == conf["num_hidden_layers"]
     if kind == "train_blockset":
         # the heads, the stated head width, the SwiGLU's width and the

@@ -230,3 +230,171 @@ def test_the_plan_counts_the_states_and_the_vmem():
     plain = dr.plan(B=1, S=100, H=2, dk=16, dv=16, chunk=32,
                     dtype=jnp.float32, impl="xla")
     assert plain["vmem_bytes"] == 0 and "inverse_side" not in plain
+
+
+# --- the cut that needs no bound on the gate (lower_bound None) -------------
+
+
+def free_gate(case: str, S: int, B=1, H=2, dk=16, dv=16, seed=0):
+    """``make`` with a gate no bound holds and beta in (0, 2): ``drawn``
+    g uniform in (-60, 0) on half the (step, channel) pairs and 0 on the
+    others; ``zero`` g = 0 everywhere; ``dies`` every channel of head 0
+    decays by exp(-200) at step 5 and a channel of head 1 by exp(-1000) at
+    every step; ``alike`` beta 1.99, every key the same, no decay."""
+    (q, k, v, g, beta, state), weight = make(S, B=B, H=H, dk=dk, dv=dv,
+                                             seed=seed)
+    ks = jax.random.split(jax.random.PRNGKey(seed + 100), 3)
+    beta = 2.0 * jax.nn.sigmoid(jax.random.normal(ks[0], beta.shape))
+    if case == "drawn":
+        g = -60.0 * jax.random.uniform(ks[1], g.shape) \
+            * (jax.random.uniform(ks[2], g.shape) < 0.5)
+    elif case == "zero":
+        g = jnp.zeros_like(g)
+    elif case == "dies":
+        g = -jax.random.uniform(ks[1], g.shape)
+        g = g.at[:, 5, 0, :].set(-200.0).at[:, :, 1, 3].set(-1000.0)
+    elif case == "alike":
+        g = jnp.zeros_like(g)
+        k = jnp.broadcast_to(k[:, :1], k.shape)
+        beta = jnp.full_like(beta, 1.99)
+    return (q, k, v, g, beta, state), weight
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("case, S, chunk", [
+    ("drawn", 100, 32), ("drawn", 128, 64),
+    ("zero", 64, 32), ("dies", 64, 32), ("dies", 128, 64),
+    ("alike", 64, 32), ("alike", 128, 64)])
+def test_any_gate_with_no_stated_bound_is_the_recurrences(impl, case, S,
+                                                          chunk):
+    """``lower_bound`` None: the pair products cut in halves, each half
+    split at its boundary, so that no factor passes 1. Gates down to -60 a
+    step, no decay at all, channels that die in one step (nothing, not NaN
+    or inf, forward and backward, dg included), beta 1.99 with every key
+    alike (the inverse's entries alternate at +-2), a length that is no
+    whole chunk: the output and all six gradients are the step-by-step
+    recurrence's."""
+    args, weight = free_gate(case, S)
+
+    def op(q, k, v, g, beta, state):
+        return dr.gated_delta_rule(q, k, v, g, beta, initial_state=state,
+                                   chunk=chunk, impl=impl, lower_bound=None)
+
+    with jax.default_matmul_precision("highest"):
+        want = recurrence(*args)
+        want_grads = jax.grad(lambda *a: (recurrence(*a) * weight).sum(),
+                              argnums=range(6))(*args)
+        got = jax.jit(op)(*args)
+        grads = jax.jit(jax.grad(lambda *a: (op(*a) * weight).sum(),
+                                 argnums=range(6)))(*args)
+    assert bool(jnp.isfinite(got).all())
+    # every key alike at beta 1.99 and no decay: I + Diag(beta) Akk is 1.99
+    # in every entry under the diagonal, its inverse alternates at 1.99 x
+    # (-0.99)^n, and the rounds' sums of 32 terms of size 4 cancel to it:
+    # the inverse by products reads 2.5e-5 off in an entry (6e-8 at beta
+    # 0.97) and the output 1.8e-4 of 4 (relative L2 2.5e-5; the plain
+    # path's solve 2e-6), the worst case there is
+    loose = {"pallas": 100.0, "xla": 4.0}[impl] if case == "alike" else 1.0
+    np.testing.assert_allclose(got, want, atol=5e-6 * loose)
+    for name, a, b in zip("q k v g beta state".split(), grads, want_grads):
+        assert bool(jnp.isfinite(a).all()), name
+        np.testing.assert_allclose(
+            a, b, atol=3e-5 * loose * float(jnp.abs(b).max()), err_msg=name)
+
+
+def test_the_aligned_sums_are_sums_of_few_terms():
+    """``_aligned_sums``: P_h the running sum inside aligned blocks of h
+    rows, E_h the even half's total of a block of 2 h, made bottom up so
+    that a sum over few rows never is the difference of two long sums: at
+    gates of -60 beside gates of -1e-3 the sums over 1, 2 and 4 rows are
+    float32's own (exact to an ulp of the block's total, where the chunk's
+    running sum at -3,000 has an ulp of 2e-4); the last level's sum is the
+    chunk's running sum."""
+    c, dk = 64, 16
+    g = jnp.where(jax.random.uniform(jax.random.PRNGKey(0), (c, dk)) < 0.5,
+                  -60.0, -1e-3)
+    got = in_a_kernel(
+        lambda x: [a for p, e in dr._aligned_sums(x)[0] for a in (p, e)]
+        + [dr._aligned_sums(x)[1]], g)
+    exact = np.asarray(g, np.float64)
+    for i in range(6):
+        h = 1 << i
+        blocks = exact.reshape(c // h, h, dk)
+        want_p = np.cumsum(blocks, axis=1).reshape(c, dk)
+        totals = blocks.sum(axis=1)                       # [c / h, dk]
+        want_e = np.repeat(totals[0::2], 2 * h, axis=0)
+        np.testing.assert_allclose(got[2 * i], want_p, rtol=3e-7, atol=1e-9)
+        np.testing.assert_allclose(got[2 * i + 1], want_e, rtol=3e-7,
+                                   atol=1e-9)
+    np.testing.assert_allclose(got[-1], np.cumsum(exact, axis=0), rtol=3e-7)
+
+
+def test_no_factor_of_the_cut_in_halves_passes_one():
+    """Both factors of every level lie in [0, 1] whatever the gate (a
+    channel at -1000 a step underflows to 0, never an inf or a NaN), and
+    their product at the level's pairs is exp(G_t - G_s)."""
+    c, dk = 32, 8
+    g = -jax.random.uniform(jax.random.PRNGKey(2), (c, dk)) * 3.0
+    g = g.at[:, 0].set(-1000.0).at[7, 1].set(-300.0)
+    factors = in_a_kernel(
+        lambda x: [f for p, e in dr._aligned_sums(x)[0]
+                   for f in dr._level_factors(p, e)], g)
+    cum = np.cumsum(np.asarray(g, np.float64), axis=0)
+    for i in range(5):
+        rows, cols = (np.asarray(f, np.float64) for f in factors[2 * i:][:2])
+        assert (rows >= 0).all() and (rows <= 1).all()
+        assert (cols >= 0).all() and (cols <= 1).all()
+        h = 1 << i
+        for t in range(c):
+            for s in range(t):
+                if (t ^ s) < 2 * h and t & ~s & h:
+                    np.testing.assert_allclose(
+                        rows[t] * cols[s], np.exp(cum[t] - cum[s]),
+                        rtol=1e-4, atol=1e-37)
+
+
+# sha256[:16] of the jaxpr of the kernel path and its five gradients (the
+# kernels' bodies are in it) for a caller that STATES its bound, at Ling's
+# tiny shape ([2, 128, 2, 16] float32, chunks of 64), taken at PR 61's
+# PARENT (77455d7, jax 0.9.0): the cut a bound allows is the program it was
+# (the Ling cell's lowered step says the same at the cell's size,
+# tests/lowered_cells.py). A digest that moves with a change that MEANS to
+# change the bounded cut is replaced, and says so.
+BOUNDED_FROM = "0.9.0"
+BOUNDED_JAXPR = "fd1329bd10fe131f"
+
+
+@pytest.mark.parametrize("stated", [{}, {"lower_bound": -5.0}])
+def test_a_caller_that_states_its_bound_gets_the_parents_program(stated):
+    import hashlib
+    import re
+
+    if jax.__version__ != BOUNDED_FROM:
+        pytest.skip(f"the digest was taken under jax {BOUNDED_FROM}")
+    x = jax.ShapeDtypeStruct((2, 128, 2, 16), jnp.float32)
+    beta = jax.ShapeDtypeStruct((2, 128, 2), jnp.float32)
+    loss = lambda q, k, v, g, b: dr.gated_delta_rule(          # noqa: E731
+        q, k, v, g, b, impl="pallas", chunk=64, **stated).sum()
+    text = str(jax.make_jaxpr(jax.grad(loss, argnums=range(5)))(
+        x, x, x, x, beta))
+    text = re.sub(r"0x[0-9a-f]+", "", text)
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == BOUNDED_JAXPR
+
+
+def test_the_plan_says_which_cut_ran():
+    """``lower_bound`` None: the cut in halves, its block sizes, its
+    float32 products a head and chunk (6 levels where the bounded cut has
+    4 sub-blocks; 12 more on the way back where it has 8) and two [C, dk]
+    forms a level more in VMEM; a stated bound: the parent's plan."""
+    shape = dict(B=1, S=16384, H=64, dk=128, dv=128, dtype=jnp.bfloat16,
+                 impl="pallas")
+    free = dr.plan(**shape, lower_bound=None)
+    assert free["cut"] == "halving" and free["lower_bound"] is None
+    assert free["cut_sizes"] == [2, 4, 8, 16, 32, 64]
+    assert free["f32_products_fwd"] == 6 + 3
+    assert free["f32_products_bwd"] == 6 + 3 + 12
+    assert free["state_bytes_kept"] == 256 * 64 * 128 * 128 * 4
+    bounded = dr.plan(**shape)
+    assert bounded["cut"] == "bounded" and bounded["cut_sizes"] == []
+    assert bounded["f32_products_fwd"] == 4 + 3
+    assert bounded["vmem_bytes"] < free["vmem_bytes"] <= 16 * 2 ** 20

@@ -19,12 +19,13 @@ import lowered_cells
 from benchmark import resolve
 from ray_tpu.models import moe
 
-# the nine cells of BENCHMARK.json whose layers route
+# the ten cells of BENCHMARK.json whose layers route
 CELLS = ("train-olmoe1b7b-s4096-b4", "train-granite4hs-ep8-s8192-b2",
          "train-glm47flash-ep8-s8192-b2", "train-mellum2-ep4-s16384-b1",
          "train-commandaplus-ep16-s8192-b1", "train-glm52-ep32-s16384-b1",
          "train-nemotron3nano-ep8-s8192-b2", "train-lfm2-ep4-s16384-b1",
-         "train-ling3flash-ep32-s16384-b1")
+         "train-ling3flash-ep32-s16384-b1",
+         "train-solaropen2-ep32-s16384-b1")
 # (E, K) of each, as benchmark/configs/ has them (``deployment.router_
 # experts``, ``num_experts_per_tok``): a cell whose router changes shows here
 SHAPES = {"train-olmoe1b7b-s4096-b4": (64, 8),
@@ -35,7 +36,8 @@ SHAPES = {"train-olmoe1b7b-s4096-b4": (64, 8),
           "train-glm52-ep32-s16384-b1": (256, 8),
           "train-nemotron3nano-ep8-s8192-b2": (128, 6),
           "train-lfm2-ep4-s16384-b1": (32, 4),
-          "train-ling3flash-ep32-s16384-b1": (512, 8)}
+          "train-ling3flash-ep32-s16384-b1": (512, 8),
+          "train-solaropen2-ep32-s16384-b1": (320, 8)}
 # router form -> the fields that make it
 FORMS = {"softmax": dict(router_score="softmax", router_bias=False),
          "sigmoid": dict(router_score="sigmoid", router_bias=False),
@@ -262,7 +264,8 @@ def test_a_cells_router_lowers_to_no_sort_no_top_k_and_no_scatter(cell):
 
 @pytest.mark.parametrize("cell,rounds", [
     ("train-ling3flash-ep32-s16384-b1", 14), ("train-lfm2-ep4-s16384-b1", 4),
-    ("train-nemotron3nano-ep8-s8192-b2", 6)])
+    ("train-nemotron3nano-ep8-s8192-b2", 6),
+    ("train-solaropen2-ep32-s16384-b1", 8)])
 def test_the_expert_plan_says_the_selections_form_and_rounds(cell, rounds):
     cfg, tokens = cell_config(cell)
     said = moe.expert_plan(cfg, tokens)

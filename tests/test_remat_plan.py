@@ -638,6 +638,7 @@ def test_the_manifest_lists_the_expert_cells_for_remat_kept_gb():
     assert entry["workloads"][:len(named)] == named and len(named) == 5
     assert set(entry["workloads"][5:]) <= {
         "train-glm52-ep32-s16384-b1", "train-nemotron3nano-ep8-s8192-b2",
-        "train-lfm2-ep4-s16384-b1", "train-ling3flash-ep32-s16384-b1"}
+        "train-lfm2-ep4-s16384-b1", "train-ling3flash-ep32-s16384-b1",
+        "train-solaropen2-ep32-s16384-b1"}
     assert (spec["reader"], spec["span"], spec["attr"]) == (
         "host_span", "train.report", "moe_remat_kept_gb")

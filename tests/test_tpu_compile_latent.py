@@ -458,6 +458,58 @@ def test_delta_rule_calls_compile_at_the_ling_cells_shape(one_chip,
     assert plan["vmem_bytes"] <= 16 * 2 ** 20, plan
 
 
+def test_delta_rule_calls_compile_at_the_solar_cells_shape(one_chip,
+                                                           on_chip_branch):
+    """The delta rule told NO bound on its gate (the cut of the pair
+    products in halves: six levels a chunk, both factors of a level at
+    most 1) at the Solar-Open2 cell's shape, 64 heads of 128 over 16,384
+    steps: exactly two Mosaic calls with the signatures the bounded cut has
+    (forward 6 -> 2, backward 7 -> 6, q, k, v first at [1, 16384, 8192]:
+    ``benchmark/readers/solar_kernel_roofline.py`` tells them by these),
+    in bfloat16 and in float32 (part (e) of the cell's ``correct``), the
+    chunks' incoming states the only state among their results (1.07 GB),
+    and what one instance holds in VMEM under the plan's count."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import delta_rule as dr
+
+    B, S, H, d = 1, 16384, 64, 128
+    x = _sds((B, S, H, d), jnp.bfloat16, one_chip)
+    g = _sds((B, S, H, d), jnp.float32, one_chip)
+    beta = _sds((B, S, H), jnp.float32, one_chip)
+
+    def loss(q, k, v, g, beta):
+        return dr.gated_delta_rule(q, k, v, g, beta, impl="pallas",
+                                   lower_bound=None).astype(jnp.float32).sum()
+
+    both = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4)))
+    text = both.lower(x, x, x, g, beta).compile().as_text()
+    both.lower(g, g, g, g, beta).compile()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 2, text[:2000]
+    wide = f"[{B},{S},{H * d}]"
+    read = []
+    for line in calls:
+        results, operands = re.search(
+            r"= (.*?) custom-call\((.*?)\), custom_call_target", line).groups()
+        shapes = re.findall(r"\w+(\[[\d,]*\])", line.split(
+            "operand_layout_constraints={")[1].split("}}")[0])
+        assert shapes[:3] == [wide] * 3, shapes
+        read.append((operands.count("%"), results.count("[")))
+    assert sorted(read) == [(6, 2), (7, 6)], read
+    plan = dr.plan(B=B, S=S, H=H, dk=d, dv=d, dtype=jnp.bfloat16,
+                   impl="pallas", lower_bound=None)
+    assert plan["cut"] == "halving" and plan["chunk"] == 64 \
+        and plan["inverse_side"] == 128
+    assert f"f32[{B},{S // 64},{H * d},{d}]" in text
+    assert plan["state_bytes_kept"] == 1_073_741_824
+    assert plan["hbm_bytes_per_head"] * H == 5_377_097_728
+    assert plan["vmem_bytes"] <= 16 * 2 ** 20, plan
+
+
 # The flash calls of the cells that do NOT stream, and the four calls of the
 # attention over a set at the GLM-5.2 cell's shape: sha256[:16] of the jaxpr
 # of the call and its gradient (the kernels' bodies are in it), taken at PR
