@@ -18,13 +18,13 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
-import functools
 import math
 from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.core.compile_cache import StoredProgram
 from ray_tpu.parallel.sharding import ShardingRules, named_sharding, tree_shardings
 
 
@@ -144,7 +144,8 @@ def make_train_state_init(init_params_fn: Callable, optimizer, mesh,
                           rules: ShardingRules, param_logical):
     """Returns (init_fn, state_shardings). init_fn(key) -> TrainState, with
     every array created directly into its shard (jit out_shardings) — no
-    host-side full materialization."""
+    host-side full materialization. A warm process loads the program and
+    does not trace it (``make_train_step``)."""
     key_shape = jax.eval_shape(lambda k: k, jax.random.PRNGKey(0))
     params_shapes = jax.eval_shape(init_params_fn, key_shape)
     param_sh = tree_shardings(mesh, param_logical, rules)
@@ -152,13 +153,13 @@ def make_train_state_init(init_params_fn: Callable, optimizer, mesh,
     opt_sh = opt_state_shardings(opt_shapes, params_shapes, param_sh, mesh)
     state_sh = TrainState(param_sh, opt_sh, _replicated(mesh))
 
-    @functools.partial(jax.jit, out_shardings=state_sh)
     def init_fn(key) -> TrainState:
         params = init_params_fn(key)
         return TrainState(params, optimizer.init(params),
                           jnp.zeros((), jnp.int32))
 
-    return init_fn, state_sh
+    return StoredProgram(init_fn, mesh.devices.flat,
+                         out_shardings=state_sh), state_sh
 
 
 def hold_out(optimizer, names: Tuple[str, ...]):
@@ -203,7 +204,17 @@ def make_train_step(loss_fn: Callable, optimizer, mesh, rules: ShardingRules,
     While the loss is traced the step's memory is bound (``step_memory``:
     the device's limit and a device's share of ``state``, parameters AND
     the optimizer's leaves), so the model knows the optimizer's bytes
-    without guessing the optimizer."""
+    without guessing the optimizer.
+
+    Where jax's persistent cache is on, the first call and
+    ``.lower(state, batch).compile()`` look for the executable under a key
+    of ``_step`` BY VALUE (its code and what it closes over: ``loss_fn``,
+    ``optimizer``, ``post_update``, the mesh), the shardings, donation,
+    the arguments' shapes, the device's limit and the source
+    (core/compile_cache.py ``StoredProgram``): a warm process loads its
+    step and does not trace it, and the plans the trace said are said
+    again. A loss that must RUN as the step is first called (it counts,
+    it appends) is no such loss: keep the cache off around it."""
     batch_sh = (batch_sharding(mesh, rules, batch_shapes)
                 if batch_shapes is not None else None)
 
@@ -235,11 +246,10 @@ def make_train_step(loss_fn: Callable, optimizer, mesh, rules: ShardingRules,
     kwargs = {}
     if donate:
         kwargs["donate_argnums"] = (0,)
-    return jax.jit(
-        _step,
+    return StoredProgram(
+        _step, mesh.devices.flat, observes=lambda: device_bytes_limit(mesh),
         in_shardings=(state_shardings, batch_sh),
-        out_shardings=(state_shardings, _replicated(mesh)),
-        **kwargs)
+        out_shardings=(state_shardings, _replicated(mesh)), **kwargs)
 
 
 def optax_global_norm(tree):

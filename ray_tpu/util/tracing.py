@@ -188,12 +188,33 @@ def instant(name: str, attributes: Optional[Dict[str, Any]] = None, *,
     return rec
 
 
+_plans_said: contextvars.ContextVar[Optional[list]] = \
+    contextvars.ContextVar("ray_tpu_plans_said", default=None)
+
+
 def plan(name: str, attributes: Dict[str, Any]) -> Optional[dict]:
     """What a kernel or a model chose while a program is traced (a
     `*.plan` / `*_plan` instant): once a traced body, so kept with
     tracing off, and on the job's timeline though the lowering ran
-    before any profile."""
+    before any profile. Inside `plans_said` it is also written down, for
+    a program that is later loaded and not traced (core/compile_cache.py
+    raises it again as it loads)."""
+    said = _plans_said.get()
+    if said is not None:
+        said.append((name, dict(attributes)))
     return instant(name, attributes, always=True)
+
+
+@contextlib.contextmanager
+def plans_said():
+    """Yields the list that takes (name, attributes) of every `plan`
+    raised inside the block, in order."""
+    said: list = []
+    token = _plans_said.set(said)
+    try:
+        yield said
+    finally:
+        _plans_said.reset(token)
 
 
 def emit_span(name: str, ts: float, dur: float,

@@ -293,6 +293,61 @@ def test_a_compile_says_its_program_and_what_the_cache_did(
             and s["dur"] < compile_cache.KEPT_S] == []
 
 
+def test_a_warm_set_up_reads_the_stored_load_and_no_trace_of_the_step(
+        recorder, fresh_cache, monkeypatch):
+    """ISSUE 62: a step loaded from the program store raises the
+    ``xla.compile`` instant a load from jax's cache raises, so the budget
+    reads it under ``program_load_s``; its trace and lowering are not
+    there to read."""
+    import program_store_toy as toy
+    from benchmark.readers import job_timeline
+
+    tracing.enable()
+    monkeypatch.setattr(compile_cache, "KEPT_S", 0.0)   # every stage kept
+    assert compile_cache.listen() is True
+
+    def set_up():
+        import jax
+
+        del recorder.spans[:]
+        with tracing.span("core.init"):
+            pass
+        with tracing.span("train.fit"), tracing.span("train.loop",
+                                                     {"rank": 0}):
+            init_fn, step, _, batch = toy.build(shapes=False)
+            step(init_fn(jax.random.PRNGKey(7)), batch)
+            tracing.instant("train.first_report")
+        return [{"name": s["name"], "start": s["ts"],
+                 "end": s["ts"] + s.get("dur", 0.0), "attrs": s["attrs"],
+                 "worker": "w"} for s in sorted(recorder.spans,
+                                                key=lambda s: s["ts"])]
+
+    def of_the_step(records, name):
+        return [r for r in records if r["name"] == name
+                and r["attrs"].get("program") in ("_step", "jit(_step)")]
+
+    cold, warm = set_up(), set_up()
+    assert of_the_step(cold, "xla.trace") and of_the_step(cold, "xla.lower")
+    assert [r["attrs"]["cache"] for r in of_the_step(cold, "xla.compile")] \
+        == ["miss"]
+    assert not of_the_step(warm, "xla.trace")
+    assert not of_the_step(warm, "xla.lower")
+    load, = of_the_step(warm, "xla.compile")
+    stored, = [r["attrs"] for r in warm if r["name"] == "program.store"
+               and r["attrs"]["program"] == "_step"]
+    assert stored["hit"] and load["attrs"]["cache"] == "hit"
+    assert load["attrs"]["seconds"] == stored["seconds"] > 0
+    budgets = [job_timeline.budget(records) for records in (cold, warm)]
+    loads = sum(r["attrs"]["seconds"] for r in warm
+                if r["name"] == "xla.compile"
+                and r["attrs"]["cache"] == "hit")
+    assert budgets[1]["program_load_s"] == pytest.approx(loads)
+    assert budgets[1]["program_load_s"] >= stored["seconds"]
+    assert budgets[0]["program_load_s"] == 0.0
+    # the state program's and the step's stages are the cold set-up's
+    assert budgets[1]["trace_lower_s"] < budgets[0]["trace_lower_s"]
+
+
 def test_a_compile_outside_the_cache_says_off(recorder):
     import jax
     import jax.numpy as jnp

@@ -568,10 +568,12 @@ def test_the_remat_plan_instant_carries_its_fields(monkeypatch):
 
 
 def test_the_step_hands_the_model_its_state_bytes_and_the_devices_limit(
-        monkeypatch):
+        monkeypatch, no_persistent_cache):
     """``make_train_step`` binds the device's limit and a device's share of
     params AND optimizer state round the loss; the kept bytes leave the
-    step as ``moe_remat_kept_gb`` and reach the ``train.report`` span."""
+    step as ``moe_remat_kept_gb`` and reach the ``train.report`` span.
+    (The cache off: the loss here writes down what it saw AS IT IS
+    TRACED, and a step loaded from the program store traces nothing.)"""
     import optax
 
     from ray_tpu.parallel import MeshSpec, ShardingRules, build_mesh

@@ -98,8 +98,10 @@ def _digest(step, state, batch) -> str:
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
-def test_no_matmul_and_no_kernel_call_outside_a_scope(case, tmp_path,
-                                                      monkeypatch):
+def test_no_matmul_and_no_kernel_call_outside_a_scope(
+        case, tmp_path, monkeypatch, no_persistent_cache):
+    # (the cache off: the plans are read from a profile around the
+    # LOWERING, and a step the program store holds is lowered by nobody)
     recipe, cfg, step, state, batch = _step(case)
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
