@@ -52,6 +52,12 @@ def _refuse_stated(cfg: LlamaConfig):
                       "not served: its state, [dk, dv] float32 a head with "
                       "the convolutions' last taps - 1 rows, has no place "
                       "in a KV cache)")
+    if hasattr(cfg, "ssm_out_multiplier"):
+        stated.append("a mixer beside the attention half (a block of two "
+                      "first halves holds two kinds of state side by side, "
+                      "keys and values AND a mixer's [H, P, N] state with "
+                      "its convolution's last rows: a KV cache has a place "
+                      "for the first alone)")
     if getattr(cfg, "n_dense", 0) and hasattr(cfg, "conv_taps"):
         stated.append("leading dense layers among expert layers")
     if cfg.norm != "rms":

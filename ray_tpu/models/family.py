@@ -4,7 +4,7 @@ The embedding, the loop over the layers, the layer checkpoint, the head and
 the loss are written once (models/llama.py, models/remat.py, and for serving
 models/cached.py). A family (llama's dense model, models/moe.py,
 models/hybrid.py, models/latent.py, models/sala.py, models/ling.py,
-models/solar.py) is the module that
+models/solar.py, models/falcon.py) is the module that
 defines a config class and, at its end, builds ``FAMILY``: a ``Family`` that
 names every member the shared code reads. A family that takes a member from
 another builds FROM that family's record (``moe.FAMILY.replace("hybrid",
@@ -87,7 +87,10 @@ class Family:
     layer_plan_says: Optional[Callable] = None
     # (cfg, kind) -> (a first half, the feed-forward): what a block holds.
     # The first may be "attention" or "mixer" itself, where a family has
-    # both an ``attention_half`` and a ``mixer_half`` and the kind decides
+    # both an ``attention_half`` and a ``mixer_half`` and the kind decides,
+    # or "both": ``llama._attention_half`` and the ``mixer_half`` read ONE
+    # norm side by side (the config states ``attention_in_multiplier``,
+    # ``attention_out_multiplier`` and ``ssm_out_multiplier``)
     halves: Callable = _both_halves
     # (cfg, kind) -> whether the block's feed-forward is an expert layer's
     routes: Callable = _every_block_routes
@@ -134,22 +137,31 @@ def _takes_attention_half(cfg, kind) -> bool:
     """Whether a layer of ``kind`` runs ``llama._attention_half``: the one
     kind of a model that names none, a hybrid's "attention" (what follows a
     "." is its feed-forward's: "attention.dense"), a named kind."""
-    return (_family(cfg).attention_half is None
+    family = _family(cfg)
+    return (family.attention_half is None
             and (kind is None or kind.split(".")[0] == "attention"
-                 or kind in dict(cfg.attn_kinds)))
+                 or kind in dict(cfg.attn_kinds)
+                 or family.halves(cfg, kind)[0] == "both"))
+
+
+def _runs_half(first, half: str) -> bool:
+    """Whether a block whose first half is ``first`` (``_halves``) runs the
+    ``half`` ("attention" | "mixer"): itself, or one of "both"."""
+    return first in (half, "both")
 
 
 def _halves(cfg, kind):
     """What a block of ``kind`` holds, as ``llama._layer`` runs it: (its
     first half: "attention" (``llama._attention_half`` or the family's own:
     a call of an attention kernel, whose ``o`` and ``lse`` the checkpoint
-    keeps), "mixer" (the family's ``mixer_half``) or None (a block that is
-    its feed-forward alone); whether it runs the feed-forward half)."""
+    keeps), "mixer" (the family's ``mixer_half``), "both" (the two side by
+    side from one norm) or None (a block that is its feed-forward alone);
+    whether it runs the feed-forward half)."""
     family = _family(cfg)
     first, second = family.halves(cfg, kind)
     if not first:
         return None, second
-    if first in ("attention", "mixer"):     # the family said which
+    if first in ("attention", "mixer", "both"):     # the family said which
         return first, second
     attends = family.attention_half is not None \
         or _takes_attention_half(cfg, kind)

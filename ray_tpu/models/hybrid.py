@@ -101,6 +101,7 @@ from typing import Any, Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.models import llama as _ll
@@ -364,15 +365,21 @@ def _of_kind(lay: dict, kind: str, cfg: HybridConfig, mixer: dict,
     return out
 
 
-def param_specs(cfg: HybridConfig) -> Dict[str, Any]:
+def _mixer_specs() -> Dict[str, Any]:
+    """The logical axes of a mixer's leaves (every kind's)."""
     L = ("layers",)
-    mixer = {
+    return {
         "mix_norm": L + ("embed_nr",),
         "in_proj": L + ("embed", "mlp"),
         "conv_w": L + (None, "mlp"), "conv_b": L + ("mlp",),
         "dt_bias": L + (None,), "a_log": L + (None,),
         "d_skip": L + (None,), "gate_norm": L + ("mlp",),
         "out_proj": L + ("mlp", "embed")}
+
+
+def param_specs(cfg: HybridConfig) -> Dict[str, Any]:
+    L = ("layers",)
+    mixer = _mixer_specs()
     # a scale of ONE head's width, shared by the heads (models/sala.py's)
     heads = {"q_norm": L + (None,), "k_norm": L + (None,)} \
         if cfg.qk_head_norm else {}
@@ -730,44 +737,86 @@ def plan(cfg: HybridConfig, B: int, S: int) -> dict:
         "hbm_bytes_bwd": 11 * wide + (4 * wide + 4 * steps) + 6 * conv}
 
 
-def mixer_half(x, lp, cfg: HybridConfig, kind: str, mesh=None):
+def _segment_multipliers(cfg):
+    """[z | xBC | dt]'s multiplier a column, where the config states one a
+    SEGMENT (``ssm_multipliers``: z, x, B, C, dt; models/falcon.py) and one
+    ahead of the projection (``ssm_in_multiplier``, folded in: a constant
+    times a constant); None for a config that states neither."""
+    per = getattr(cfg, "ssm_multipliers", None)
+    if per is None:
+        return None
+    inner, n = cfg.mamba_inner, cfg.mamba_state * cfg.mamba_groups
+    by = np.repeat(np.asarray(per, np.float64),
+                   (inner, inner, n, n, cfg.mamba_heads))
+    return jnp.asarray(by * cfg.ssm_in_multiplier, jnp.float32)
+
+
+def scan_inputs(u, lp, cfg: HybridConfig):
+    """The mixer's normed input u [B, S, D] -> (z [B, S, H P], x
+    [B, S, H P] after the convolution, what ``ssd_scan`` takes: x
+    [B, S, H, P], the steps dt [B, S, H] float32 after their softplus, A
+    [H] float32, B and C [B, S, N] or, in groups, [B, S, G, N])."""
+    B, S, _ = u.shape
+    N, G = cfg.mamba_state, cfg.mamba_groups
+    inner, conv_dim, _ = _mamba_sizes(cfg)
+    dt_, f32 = cfg.dtype, jnp.float32
+    with jax.named_scope("in_proj"):
+        zxbcdt = u @ _ll._dq(lp["in_proj"], dt_)
+    by = _segment_multipliers(cfg)
+    if by is not None:      # the product's epilogue, float32 constants
+        with jax.named_scope("segments"):
+            zxbcdt = (zxbcdt.astype(f32) * by).astype(dt_)
+    # kept across the layer checkpoint where the step's memory has room
+    zxbcdt = checkpoint_name(zxbcdt, MIX_OFFERED)
+    z, step = zxbcdt[..., :inner], zxbcdt[..., inner + conv_dim:]
+    # the heads' channels and B | C, convolved apart: x is an array of its
+    # own in the passes below (a slice of the joint one splits them in two)
+    with jax.named_scope("conv"):
+        xs = _conv_silu(zxbcdt[..., inner:2 * inner],
+                        lp["conv_w"][:, :inner], lp["conv_b"][:inner])
+        bc = _conv_silu(zxbcdt[..., 2 * inner:inner + conv_dim],
+                        lp["conv_w"][:, inner:], lp["conv_b"][inner:])
+    bm, cm = bc[..., :G * N], bc[..., G * N:]
+    if G > 1:                       # a group's B and C: [B, S, G, N]
+        bm, cm = bm.reshape(B, S, G, N), cm.reshape(B, S, G, N)
+    with jax.named_scope("scan"):
+        step = jax.nn.softplus(step.astype(f32) + lp["dt_bias"].astype(f32))
+        heads = xs.reshape(B, S, cfg.mamba_heads, cfg.mamba_head_dim)
+        a = -jnp.exp(lp["a_log"].astype(f32))
+    return z, xs, (heads, step, a, bm, cm)
+
+
+def mixer_half(x, lp, cfg: HybridConfig, kind: str, mesh=None, normed=None):
     """A block's mixer by its kind: the Mamba-2 half, x [B, S, D] -> x +
     its mixer's output (the module docstring has the equations), or the
-    gated short convolution (``short_conv_half``)."""
+    gated short convolution (``short_conv_half``). A block of two first
+    halves (``llama._layer``) hands its one normed input as ``normed``: the
+    half then norms nothing and adds nothing, its result is the mixer's
+    output alone."""
     if _first(kind) == "conv":
+        assert normed is None, kind
         return short_conv_half(x, lp, cfg)
-    assert _first(kind) == "mamba", kind
+    assert _first(kind) == "mamba" or normed is not None, kind
     if cfg.ssd_impl == "pallas" and mesh is not None and mesh.size > 1:
         raise NotImplementedError(
             "ssd_impl='pallas' runs on one device: GSPMD cannot partition "
             "the Mosaic scan, and the mixer has no shard_map of its own yet "
             f"(mesh {dict(mesh.shape)}); use ssd_impl='xla' on a mesh")
     B, S, _ = x.shape
-    H, P, N = cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_state
-    G = cfg.mamba_groups
-    inner, conv_dim, _ = _mamba_sizes(cfg)
-    dt_, f32 = cfg.dtype, jnp.float32
+    G, inner, dt_ = cfg.mamba_groups, cfg.mamba_inner, cfg.dtype
     tracing.plan("mixer.plan", plan(cfg, B, S))
-    u = _ll.rms_norm(x, lp["mix_norm"], cfg.norm_eps)
-    # kept across the layer checkpoint where the step's memory has room
-    zxbcdt = checkpoint_name(u @ _ll._dq(lp["in_proj"], dt_), MIX_OFFERED)
-    z, step = zxbcdt[..., :inner], zxbcdt[..., inner + conv_dim:]
-    # the heads' channels and B | C, convolved apart: x is an array of its
-    # own in the passes below (a slice of the joint one splits them in two)
-    xs = _conv_silu(zxbcdt[..., inner:2 * inner], lp["conv_w"][:, :inner],
-                    lp["conv_b"][:inner])
-    bc = _conv_silu(zxbcdt[..., 2 * inner:inner + conv_dim],
-                    lp["conv_w"][:, inner:], lp["conv_b"][inner:])
-    bm, cm = bc[..., :G * N], bc[..., G * N:]
-    if G > 1:                       # a group's B and C: [B, S, G, N]
-        bm, cm = bm.reshape(B, S, G, N), cm.reshape(B, S, G, N)
-    step = jax.nn.softplus(step.astype(f32) + lp["dt_bias"].astype(f32))
-    y = ssd_scan(xs.reshape(B, S, H, P), step,
-                 -jnp.exp(lp["a_log"].astype(f32)), bm, cm,
-                 chunk=min(cfg.mamba_chunk, S), impl=cfg.ssd_impl)
-    y = _gated_norm(y.reshape(B, S, inner), xs, z, lp["d_skip"],
-                    lp["gate_norm"], cfg.norm_eps, G)
-    return _ll._residual(x, y @ _ll._dq(lp["out_proj"], dt_), cfg)
+    u = _ll.rms_norm(x, lp["mix_norm"], cfg.norm_eps) if normed is None \
+        else normed
+    z, xs, scan = scan_inputs(u, lp, cfg)
+    # the prologue and the kernel calls (``ssd.fwd.pallas``)
+    with jax.named_scope("scan"):
+        y = ssd_scan(*scan, chunk=min(cfg.mamba_chunk, S), impl=cfg.ssd_impl)
+    with jax.named_scope("gated_norm"):
+        y = _gated_norm(y.reshape(B, S, inner), xs, z, lp["d_skip"],
+                        lp["gate_norm"], cfg.norm_eps, G)
+    with jax.named_scope("out_proj"):
+        y = y @ _ll._dq(lp["out_proj"], dt_)
+    return y if normed is not None else _ll._residual(x, y, cfg)
 
 
 def feed_forward(h, lp, cfg: HybridConfig, mesh=None, rules=None, tp=None,

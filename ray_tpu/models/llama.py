@@ -627,7 +627,10 @@ def _layer(x, lp, cfg: LlamaConfig, cos, sin, mesh=None, rules=None, tp=None,
     every layer's: (x, lp, cfg, cos, sin, carried, kind -> x, carried,
     what it reports). ``kind`` goes on to the feed-forward. A config with
     ``parallel_block`` runs the same two halves side by side from one norm
-    (``_parallel_layer``). A family whose blocks hold ONE half says which
+    (``_parallel_layer``). A family whose blocks run TWO first halves, the
+    attention half and its mixer side by side from one norm, ahead of the
+    serial feed-forward, says "both" (``_two_first_halves``). A family
+    whose blocks hold ONE half says which
     (``halves``: cfg, kind -> (a first half, the feed-forward)); a block
     without the feed-forward reports nothing."""
     if cfg.parallel_block:
@@ -655,6 +658,9 @@ def _layer(x, lp, cfg: LlamaConfig, cos, sin, mesh=None, rules=None, tp=None,
             x = family.mixer_half(x, lp, cfg, kind, mesh=mesh)
             if isinstance(x, tuple):    # a mixer that reports of itself
                 x, said = x
+    elif first == "both":
+        assert tp is None, kind
+        x = _two_first_halves(x, lp, cfg, cos, sin, mesh, rules, kind)
     stats = None
     if second:
         with jax.named_scope("feed_forward"):
@@ -667,6 +673,30 @@ def _layer(x, lp, cfg: LlamaConfig, cos, sin, mesh=None, rules=None, tp=None,
     return x, stats, carried
 
 
+def _two_first_halves(x, lp, cfg: LlamaConfig, cos, sin, mesh, rules, kind):
+    """The first half of a block that has TWO (a family whose ``halves``
+    says "both"; models/falcon.py): ONE norm (``attn_norm``) feeds the
+    attention half and the family's mixer side by side, neither reads the
+    other's result, and x + attention_out_multiplier attention(
+    attention_in_multiplier n) + ssm_out_multiplier mixer(n) goes on to the
+    serial feed-forward. The norm, the multipliers and the sum are the
+    block's own (scope ``block``); the halves are the serial block's code
+    under its scopes, each given its normed input."""
+    dt = x.dtype
+    with jax.named_scope("block"):
+        n = _norm(x, lp["attn_norm"], cfg)
+        h = n if cfg.attention_in_multiplier == 1 \
+            else (n * cfg.attention_in_multiplier).astype(dt)
+    with jax.named_scope("attention"):
+        a = _attention_half(x, lp, cfg, cos, sin, mesh=mesh, rules=rules,
+                            kind=kind, normed=h)
+    with jax.named_scope("mixer"):
+        m = _family(cfg).mixer_half(x, lp, cfg, kind, mesh=mesh, normed=n)
+    with jax.named_scope("block"):
+        return x + (a * cfg.attention_out_multiplier).astype(dt) \
+            + (m * cfg.ssm_out_multiplier).astype(dt)
+
+
 def _parallel_layer(x, lp, cfg: LlamaConfig, cos, sin, mesh, rules, tp, kind):
     """``_layer`` for a parallel block (``cfg.parallel_block``): ONE norm
     (``attn_norm``) feeds the attention half and the family's feed-forward
@@ -676,7 +706,11 @@ def _parallel_layer(x, lp, cfg: LlamaConfig, cos, sin, mesh, rules, tp, kind):
     the two halves are the serial block's code under its scopes."""
     if not _takes_attention_half(cfg, kind):
         raise NotImplementedError(
-            f"a parallel block whose first half is no attention ({kind!r})")
+            f"a parallel block whose first half is no attention ({kind!r}): "
+            "parallel_block runs attention beside the FEED-FORWARD; for a "
+            "mixer BESIDE attention ahead of a serial feed-forward the "
+            "family's halves says 'both' (llama._two_first_halves, "
+            "models/falcon.py)")
     with jax.named_scope("block"):
         n = _norm(x, lp["attn_norm"], cfg)
     with jax.named_scope("attention"), jax.named_scope(kind) \
@@ -810,7 +844,9 @@ def forward_with_stats(params, tokens, cfg: LlamaConfig, pos_offset=0,
 
 def _logits(params, x, cfg: LlamaConfig):
     """The head over normed x [B, S, D]: the ``lm_head`` or, tied, the
-    embedding; divided where the config says; float32 or as computed."""
+    embedding; divided (``logits_scaling``) or multiplied
+    (``lm_head_multiplier``) where the config says; float32 or as
+    computed."""
     dt = cfg.dtype
     if "lm_head" in params:
         logits = x @ _dq(params["lm_head"], dt)
@@ -818,6 +854,9 @@ def _logits(params, x, cfg: LlamaConfig):
         logits = jnp.einsum("bsd,vd->bsv", x, _dq(params["embed"], dt))
     if cfg.logits_scaling is not None:
         logits = logits / cfg.logits_scaling
+    by = getattr(cfg, "lm_head_multiplier", None)   # models/falcon.py
+    if by is not None:
+        logits = logits * by
     return logits.astype(jnp.float32) if cfg.f32_logits else logits
 
 
@@ -952,7 +991,8 @@ def _forward(params, tokens, cfg: LlamaConfig, pos_offset=0, mesh=None,
             if s is not None:       # a run of dense layers reports nothing
                 stats.append(s)
         with jax.named_scope("layers"):
-            stats = _join_stats(stats)
+            # a dense model in runs (models/falcon.py) reports nothing
+            stats = _join_stats(stats) if stats else None
         _say_layer_plan(runs, body_of.cache_info().currsize,
                         family.layer_plan_says(cfg, runs, plan)
                         if family.layer_plan_says else None)

@@ -36,6 +36,10 @@ _FAMILIES = {
     # layers with an unbounded gate beside gated grouped-query attention
     # with no position table, sigmoid-routed experts in every layer)
     "solar_open2": "ray_tpu.models.solar",
+    # Falcon-H1's model_type: falcon.py preset "tiny" (a block of two first
+    # halves: a Mamba-2 mixer and grouped-query attention read one norm
+    # side by side, each under its muP multipliers, ahead of a dense SwiGLU)
+    "falcon_h1": "ray_tpu.models.falcon",
     "vit": "ray_tpu.models.vit",
 }
 

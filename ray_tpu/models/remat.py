@@ -26,7 +26,8 @@ from typing import NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.family import _family, _halves, _takes_attention_half
+from ray_tpu.models.family import (_family, _halves, _runs_half,
+                                   _takes_attention_half)
 from ray_tpu.parallel.train_step import state_bytes, step_memory
 from ray_tpu.util import tracing
 
@@ -162,7 +163,8 @@ def _step_estimate(cfg: "LlamaConfig", params, stacks, passes: int,
         # [L, E, in, out] times the rows its experts get; beside them the
         # lanes of the rows in expert order (a block with a feed-forward
         # half) and of the query heads (one with an attention half), or
-        # what the family says a mixer's backward holds
+        # what the family says a mixer's backward holds (a block of two
+        # first halves holds both at once)
         first, second = _halves(cfg, kind)
         total = 0
         for w in jax.tree.leaves(stack):
@@ -170,15 +172,24 @@ def _step_estimate(cfg: "LlamaConfig", params, stacks, passes: int,
                 total += rows * w.shape[2] * item
             elif w.ndim == 4:
                 total += expert_rows * w.shape[3] * item
-        return LAYER_BACKWARD * total + LANE_BYTES * (
+        whole = LAYER_BACKWARD * total + LANE_BYTES * (
             expert_rows * cfg.d_model * (second and routes(cfg, kind))
-            + heads * (first == "attention")) + (
+            + heads * _runs_half(first, "attention")) + (
                 family.mixer_backward_bytes(cfg, kind, rows)
-                if first == "mixer" else 0)
+                if _runs_half(first, "mixer") else 0)
+        if first != "both":
+            return whole
+        # two first halves ahead of a dense SwiGLU: the backward stands at
+        # the larger of the SwiGLU's products and the halves', not at their
+        # sum (the compiled plan of such a step reads 10.38e9 where the sum
+        # read 14.12e9 and the larger reads 10.97e9; PERF.md 6, PR 63)
+        ffn = LAYER_BACKWARD * sum(rows * stack[w].shape[2] * item
+                                   for w in ("w_gate", "w_up", "w_down"))
+        return max(ffn, whole - ffn)
 
     def keeps(kind):
         flash = heads * item + rows * cfg.n_heads * 4 \
-            if _halves(cfg, kind)[0] == "attention" else 0
+            if _runs_half(_halves(cfg, kind)[0], "attention") else 0
         return rows * cfg.d_model * item + flash \
             + family.remat_saved_bytes(cfg, kind, rows)
 

@@ -65,6 +65,18 @@ to sum. Its decay is a CONSTANT a head (``dt`` None: every step is 1, so
 kernel makes ``exp(a (t - s))`` from two iotas, and no [B, S, H] array, no
 second layout and no gradient of a step or a rate exists on either path.
 
+A head as wide as a lane tile WITH steps (P a multiple of 128, ``dt`` a
+head and step, G groups of adjacent heads, any N: a Falcon-H1 mixer at 32
+heads of 128, a state of 256, two groups) takes the third pair
+(``_fwd_kernel_tile``, ``_bwd_kernel_tile``; ``layout`` "tile"): the pairs
+calls' blocks and arguments (a block of heads inside ONE group reads the
+group's B and C and computes ``C B^T`` once; ``cum`` in both layouts, its
+gradient in both) walked a HEAD at a time, no halves chosen by lane, every
+product MXU-wide: ``M_h @ u_h`` [Q, Q] x [Q, P], the state's term ``C``
+[Q, N] x ``h_in^T`` [N, P], the update ``u^T`` [P, Q] x ``B`` [Q, N]. N is
+no lane of u or y, so it need not be P. How many heads a block holds is what
+the backward's blocks leave of ``TILE_VMEM`` (``plan``).
+
 Across a layer checkpoint nothing of the scan is kept, so nothing of it
 is named for ``remat._checkpoint``: its output is as large as two layer inputs and the states as
 four, so the backward's recomputation of the layer runs the forward call
@@ -97,6 +109,11 @@ from ray_tpu.util import tracing
 NEG_INF = -1e30
 HEADS_PER_BLOCK = 16       # heads one kernel instance walks, in pairs
 WIDE_HEADS_PER_BLOCK = 4   # heads of a lane tile each, with B and C of their own
+LANE_TILE = 128            # a head of a multiple of it with steps: "tile"
+# what one instance of the tile layout's backward call may hold (``plan``'s
+# ``vmem_bytes``): under Mosaic's 16 MiB a call, with room for the values
+# of an unrolled head that the count leaves out
+TILE_VMEM = 12 * 2 ** 20
 
 
 def _use_interpret() -> bool:
@@ -167,7 +184,14 @@ _TN = (((0,), (0,)), ((), ()))     # a^T @ b
 
 
 def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
-    return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+    """A product accumulated in float32. Float32 operands at full
+    precision: the MXU's default for them is ONE bfloat16 pass, which is
+    bfloat16 operands' product (a float32 run of the kernels is what
+    holds the algorithm itself to the plain path on the chip)."""
+    full = a.dtype == b.dtype == jnp.float32
+    return jax.lax.dot_general(
+        a, b, dims, preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST if full else None)
 
 
 def _fwd_kernel(u_ref, b_ref, c_ref, col_ref, row_ref, y_ref, hin_ref, h_scr,
@@ -298,6 +322,103 @@ def _bwd_kernel(u_ref, b_ref, c_ref, col_ref, row_ref, hin_ref, dy_ref,
     dcol_ref[0, 0] = dcols
 
 
+# --- a head as wide as a lane tile, with steps, in groups -------------------
+
+
+def _fwd_kernel_tile(u_ref, b_ref, c_ref, col_ref, row_ref, y_ref, hin_ref,
+                     h_scr, *, heads: int, width: int):
+    """``_fwd_kernel`` for heads of whole lane tiles: the same blocks (a
+    block's heads are of one group, b_ref and c_ref [1, Q, N] the group's),
+    one head a step of the loop and no halves chosen by lane."""
+    q = u_ref.shape[1]
+    mm = u_ref.dtype
+
+    @pl.when(pl.program_id(2) == 0)
+    def _start():
+        h_scr[...] = jnp.zeros_like(h_scr)
+
+    bm, cm = b_ref[0], c_ref[0]
+    g = _dot(cm, bm, _NT)                                    # [Q, Q]
+    seen = jax.lax.broadcasted_iota(jnp.int32, (q, 1), 0) \
+        >= jax.lax.broadcasted_iota(jnp.int32, (1, q), 1)
+    cols = col_ref[0]
+    head0 = pl.program_id(1) * heads
+    hin_ref[0, 0] = h_scr[...]
+    for i in range(heads):
+        lanes = slice(i * width, (i + 1) * width)
+        u, h = u_ref[0, :, lanes], h_scr[lanes, :]           # [Q, P], [P, N]
+        col, row = _column(cols, head0 + i), row_ref[0, i:i + 1, :]
+        last = _last(row)
+        y = _dot((g * _decay_block(col, row, seen)).astype(mm), u) \
+            + _dot(cm, h.astype(mm), _NT) * jnp.exp(col)
+        y_ref[0, :, lanes] = y.astype(y_ref.dtype)
+        local = _dot((u.astype(jnp.float32) * jnp.exp(last - col)).astype(mm),
+                     bm, _TN)
+        h_scr[lanes, :] = h * jnp.exp(last) + local
+
+
+def _bwd_kernel_tile(u_ref, b_ref, c_ref, col_ref, row_ref, hin_ref, dy_ref,
+                     du_ref, db_ref, dc_ref, dcol_ref, drow_ref, dh_scr,
+                     *, heads: int, width: int):
+    """``_bwd_kernel`` for heads of whole lane tiles, chunks last to first:
+    the same blocks and results, one head a step of the loop."""
+    q = u_ref.shape[1]
+    mm = u_ref.dtype
+    f32 = jnp.float32
+
+    @pl.when(pl.program_id(2) == 0)
+    def _start():
+        dh_scr[...] = jnp.zeros_like(dh_scr)
+
+    bm, cm = b_ref[0], c_ref[0]
+    g = _dot(cm, bm, _NT)
+    seen = jax.lax.broadcasted_iota(jnp.int32, (q, 1), 0) \
+        >= jax.lax.broadcasted_iota(jnp.int32, (1, q), 1)
+    is_last = jax.lax.broadcasted_iota(jnp.int32, (q, 1), 0) == q - 1
+    cols = col_ref[0]
+    all_lanes = jax.lax.broadcasted_iota(jnp.int32, (1, cols.shape[1]), 1)
+    head0 = pl.program_id(1) * heads
+    dg = jnp.zeros((q, q), f32)
+    db = jnp.zeros(bm.shape, f32)
+    dc = jnp.zeros(cm.shape, f32)
+    dcols = jnp.zeros(cols.shape, f32)
+    for i in range(heads):
+        lanes = slice(i * width, (i + 1) * width)
+        u, dy = u_ref[0, :, lanes], dy_ref[0, :, lanes]      # [Q, P]
+        h = hin_ref[0, 0, lanes, :]                          # [P, N] f32
+        dh = dh_scr[lanes, :]
+        col, row = _column(cols, head0 + i), row_ref[0, i:i + 1, :]
+        last = _last(row)
+        uf, hm, dhm = u.astype(f32), h.astype(mm), dh.astype(mm)
+        decay = _decay_block(col, row, seen)
+        m = g * decay
+        dm = _dot(dy, u, _NT)                                # [Q(t), Q(s)]
+        w = dm * m
+        dg = dg + dm * decay
+        drow_ref[0, i:i + 1, :] = -jnp.sum(w, axis=0, keepdims=True)
+        # what came before the chunk: y_t has exp(cum_t) h_in C_t
+        dye = dy.astype(f32) * jnp.exp(col)                  # [Q, P]
+        came = _dot(cm, hm, _NT)                             # [Q, P]
+        dc = dc + _dot(dye.astype(mm), hm)
+        # the chunk's end state: h_out has exp(last - cum_s) u_s B_s^T
+        to_end = jnp.exp(last - col)                         # [Q, 1]
+        sent = _dot(bm, dhm, _NT) * to_end                   # [Q, P]
+        db = db + _dot((uf * to_end).astype(mm), dhm)
+        du_ref[0, :, lanes] = (_dot(m.astype(mm), dy, _TN)   # M^T dy
+                               + sent).astype(du_ref.dtype)
+        whole = jnp.exp(last)
+        through = jnp.sum(uf * sent, axis=1, keepdims=True)
+        d = jnp.sum(w, axis=1, keepdims=True) + jnp.sum(
+            dye * came, axis=1, keepdims=True) - through
+        d = d + jnp.where(is_last, jnp.sum(through)
+                          + jnp.sum(dh * h * whole), 0.0)
+        dcols = jnp.where(all_lanes == head0 + i, d, dcols)
+        dh_scr[lanes, :] = _dot(dye.astype(mm), cm, _TN) + dh * whole
+    dc_ref[0, 0] = dc + _dot(dg.astype(mm), bm)
+    db_ref[0, 0] = db + _dot(dg.astype(mm), cm, _TN)
+    dcol_ref[0, 0] = dcols
+
+
 # --- a head as wide as a lane tile, keys of its own, a constant decay -------
 
 
@@ -383,6 +504,21 @@ def _bwd_kernel_wide(a_ref, u_ref, b_ref, c_ref, hin_ref, dy_ref, du_ref,
 # --- the block plan and the calls -------------------------------------------
 
 
+def _stepped_blocks(q, heads, H, P, N, item):
+    """Bytes of the backward call's blocks in the two layouts with steps
+    (pairs and tile: the same blocks)."""
+    return (3 * q * heads * P * item              # u, dy, du
+            + 2 * q * N * item + 2 * q * N * 4    # B, C; dB, dC
+            + 2 * q * H * 4 + 2 * heads * q * 4   # cum and its gradient
+            + heads * P * N * 4)                  # h_in
+
+
+def _vmem(blocks, q, heads, P, N):
+    """An instance of the backward call: its blocks twice (Mosaic
+    double-buffers), the state scratch, a head's [Q, Q] float32 values."""
+    return 2 * blocks + heads * P * N * 4 + 8 * q * q * 4
+
+
 def plan(*, S: int, H: int, P: int, N: int, chunk: int, dtype,
          impl: str, G: int = 1, steady: bool = False) -> dict:
     """The scan's block plan (also the attributes of ``ssd.plan``): how
@@ -392,36 +528,37 @@ def plan(*, S: int, H: int, P: int, N: int, chunk: int, dtype,
     two calls move for one head and sequence. A block's heads are of one
     of the G groups; ``steady`` (a constant decay a head, no steps) with
     a group a head takes the wide calls (``layout`` "wide": whole heads
-    with their own B and C, on the chip a head whole lane tiles; else
-    "pairs")."""
+    with their own B and C, on the chip a head whole lane tiles); heads of
+    whole lane tiles with steps take the tile calls ("tile": as many heads
+    a block, 16 at most, as ``TILE_VMEM`` holds); else "pairs"."""
     if H % G:
         raise ValueError(f"ssd_scan: {H} heads in {G} groups")
     wide = steady and G == H
-    heads = min(WIDE_HEADS_PER_BLOCK if wide else HEADS_PER_BLOCK,
-                H if wide else H // G)
-    while (H if wide else H // G) % heads:
-        heads -= 1
+    tile = not steady and P % LANE_TILE == 0
     item = jnp.dtype(dtype).itemsize
     q = chunk
+    heads = min(WIDE_HEADS_PER_BLOCK if wide else HEADS_PER_BLOCK,
+                H if wide else H // G)
+    while (H if wide else H // G) % heads or (
+            tile and heads > 1 and _vmem(_stepped_blocks(
+                q, heads, H, P, N, item), q, heads, P, N) > TILE_VMEM):
+        heads -= 1
     if wide:
         blocks = (3 * q * heads * P * item              # u, dy, du
                   + 4 * q * heads * N * item            # B, C; dB, dC
                   + heads * P * N * 4)                  # h_in
         hbm = S * (5 * P + 6 * N) * item + 2 * (S // q) * P * N * 4
     else:
-        blocks = (3 * q * heads * P * item              # u, dy, du
-                  + 2 * q * N * item + 2 * q * N * 4    # B, C; dB, dC
-                  + 2 * q * H * 4 + 2 * heads * q * 4   # cum and its gradient
-                  + heads * P * N * 4)                  # h_in
+        blocks = _stepped_blocks(q, heads, H, P, N, item)
         # a head's rows of u and y, forward; u, dy and du, backward; h_in
         # written and read
         hbm = S * P * item * 5 + 2 * (S // q) * P * N * 4
-    vmem = 2 * blocks + heads * P * N * 4 + 8 * q * q * 4
     return {"S": S, "chunk": q, "heads_per_block": heads, "path": impl,
             "groups": G, "heads_per_group": H // G,
-            "layout": "wide" if wide else "pairs",
-            "decay": "steady" if steady else "stepped",
-            "vmem_bytes": vmem if impl == "pallas" else 0,
+            "layout": "wide" if wide else "tile" if tile else "pairs",
+            "decay": "steady" if steady else "stepped", "state": N,
+            "vmem_bytes": _vmem(blocks, q, heads, P, N)
+            if impl == "pallas" else 0,
             "hbm_bytes_per_head": hbm if impl == "pallas" else 0}
 
 
@@ -449,6 +586,13 @@ def _backwards(spec, last):
     return pl.BlockSpec(spec.block_shape, flipped)
 
 
+def _kernels(width: int):
+    """(forward, backward) of the stepped calls: a head a lane tile, or
+    two heads a lane tile walked in pairs."""
+    return (_fwd_kernel_tile, _bwd_kernel_tile) if width % LANE_TILE == 0 \
+        else (_fwd_kernel, _bwd_kernel)
+
+
 def _forward_call(u, bm, cm, col, row, *, chunk: int, heads: int, width: int,
                   groups: int):
     B, S, HP = u.shape
@@ -456,7 +600,7 @@ def _forward_call(u, bm, cm, col, row, *, chunk: int, heads: int, width: int,
     wide, shared, colspec, rowspec, state = _specs(B, S, H, width, N, chunk,
                                                    heads, groups)
     call = pl.pallas_call(
-        functools.partial(_fwd_kernel, heads=heads, width=width),
+        functools.partial(_kernels(width)[0], heads=heads, width=width),
         grid=(B, H // heads, S // chunk),
         in_specs=[wide, shared, shared, colspec, rowspec],
         out_specs=[wide, state],
@@ -483,7 +627,7 @@ def _backward_call(u, bm, cm, col, row, h_in, dy, *, chunk: int, heads: int,
     dcol = rev(pl.BlockSpec((1, 1, chunk, H), lambda b, h, c: (b, h, c, 0)))
     f32 = jnp.float32
     call = pl.pallas_call(
-        functools.partial(_bwd_kernel, heads=heads, width=width),
+        functools.partial(_kernels(width)[1], heads=heads, width=width),
         grid=(B, blocks, n_chunks),
         in_specs=[wide, shared, shared, colspec, rowspec, state, wide],
         out_specs=[wide, part, part, dcol, rowspec],
@@ -688,10 +832,11 @@ def ssd_scan(x, dt, a, bm, cm, *, chunk: int = 256, impl: str = "xla"):
     model, a head's decay ``exp(a)`` a step (differentiable in x, bm and
     cm; nothing [B, S, H] is made).
 
-    The Pallas path takes two layouts (``plan``): heads of 64 with steps,
-    an even number of them a group (in pairs, a lane tile a pair), and
-    heads of a multiple of 128 with a B and a C each and a constant decay
-    (``dt`` None, G == H)."""
+    The Pallas path takes three layouts (``plan``): "pairs", heads of 64
+    with steps, an even number of them a group (a lane tile a pair);
+    "tile", heads of a multiple of 128 with steps, in groups, a state of
+    any size; and "wide", heads of a multiple of 128 with a B and a C each
+    and a constant decay (``dt`` None, G == H)."""
     B, S, H, P = x.shape
     G = 1 if bm.ndim == 3 else bm.shape[2]
     if bm.ndim == 4 and G == 1:         # one group, stated: the same call
@@ -706,10 +851,12 @@ def ssd_scan(x, dt, a, bm, cm, *, chunk: int = 256, impl: str = "xla"):
              impl=impl, G=G, steady=dt is None)
     tracing.plan("ssd.plan", p)
     f32 = jnp.float32
-    takes = ("the kernel takes heads of 64 with steps, an even number of "
-             "them a group (a block of 2 to 16 heads of one group), or heads "
-             "of a multiple of 128 with a B and a C each (G == H) and a "
-             "constant decay (dt None)")
+    takes = ("the kernel takes three layouts: 'pairs', heads of 64 with "
+             "steps, an even number of them a group (a block of 2 to 16 "
+             "heads of one group); 'tile', heads of a multiple of 128 with "
+             "steps, in groups (a block of 8 or 16 heads of one group, or "
+             "all the heads); 'wide', heads of a multiple of 128 with a B "
+             "and a C each (G == H) and a constant decay (dt None)")
     if dt is None:
         a = jax.lax.stop_gradient(a.astype(f32))
         if impl == "pallas":
@@ -740,7 +887,11 @@ def ssd_scan(x, dt, a, bm, cm, *, chunk: int = 256, impl: str = "xla"):
                     by_group(u5), bg, cg, by_group(cum))
         return y.reshape(B, S, H, P).astype(x.dtype)
     heads = p["heads_per_block"]
-    if heads % 2:
+    # pairs walk two heads a lane tile; a tile block of cum's rows
+    # [heads, Q] is whole sublane tiles or the whole array
+    fits = heads % 2 == 0 if p["layout"] == "pairs" else (
+        heads % 8 == 0 or heads == H or _use_interpret())
+    if not fits:
         raise ValueError(f"ssd_scan: {H} heads of {P} in {G} groups give a "
                          f"block of {heads}; {takes}")
     flat = lambda a: a.astype(x.dtype) if a.ndim == 3 else \

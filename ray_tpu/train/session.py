@@ -114,10 +114,13 @@ def report(metrics: Dict[str, Any], *, state: Any = None) -> None:
     attrs = {"has_state": state is not None}
     if isinstance(metrics.get("step"), int):
         attrs["step"] = metrics["step"]
-    # an expert model's routing statistics (models/moe.py finish_loss) and
-    # a learned selection's (models/latent.py finish_loss)
+    # an expert model's routing statistics (models/moe.py finish_loss), a
+    # learned selection's (models/latent.py finish_loss) and what a loop
+    # reads of whether a mechanism is alive under its constants
+    # (``alive_``: the softmax scores' deviation, a decay's spread)
     attrs.update({k: v for k, v in metrics.items()
-                  if k.startswith(("moe_", "index_")) and isinstance(v, float)})
+                  if k.startswith(("moe_", "index_", "alive_"))
+                  and isinstance(v, float)})
     with _tracing.span("train.report", attrs):
         _report(ctx, metrics, state)
 

@@ -6,7 +6,8 @@ compile goes into the file of its family: ``tests/test_tpu_compile_dense.py``
 (the dense model's steps, the engine's programs, every flash call; Granite
 rides there), ``_latent.py`` (the GLM cells, MiniCPM-SALA: attention over
 latents and sets), ``_moe.py`` (Mellum2, Command A+, the held experts'
-walks, the grouped matmuls), ``_nemotron.py`` and ``_lfm2.py`` (a cell each).
+walks, the grouped matmuls), ``_nemotron.py``, ``_lfm2.py`` and
+``_falcon.py`` (a cell each).
 
 What put each case where it is (PR 56; PERF.md 6). A whole-step compile
 keeps about three cores busy, the driver runs six workers on eight cores,
@@ -216,6 +217,8 @@ _CELL_STEPS = {
     "train-minicpmsala-l4-s16384-b1": ("model_sala", "sala_config", "sala"),
     "train-lfm2-ep4-s16384-b1": ("model_lfm2", "hybrid_config", "hybrid"),
     "train-ling3flash-ep32-s16384-b1": ("model_ling", "ling_config", "ling"),
+    "train-falconh1-l4-s16384-b1": ("model_falconh1", "falcon_config",
+                                    "falcon"),
 }
 
 

@@ -11,8 +11,8 @@ without them). Run it on an unpacked parent (``git archive <commit> | tar -x
 -C DIR``, then ``--repo DIR``) and on the tree, and ``diff`` the two
 outputs: a refactor that means to change no program changes no line
 (``--texts`` keeps the texts, for the diff when one does; ``--check`` holds
-the cells to ``PINNED``, the digests PR 61 found on its parent). About 90 s
-for the thirteen cells; needs libtpu, no chip.
+the cells to ``PINNED``, the digests PR 61 and PR 63 found on their parents). About 90 s
+for the fourteen cells; needs libtpu, no chip.
 """
 
 import argparse
@@ -38,6 +38,7 @@ KINDS = {
     "train_shortconv": ("model_lfm2", "hybrid_config", "hybrid"),
     "train_kda": ("model_ling", "ling_config", "ling"),
     "train_solar": ("model_solar", "solar_config", "solar"),
+    "train_falconh1": ("model_falconh1", "falcon_config", "falcon"),
 }
 V5E_LIMIT = 16_909_336_064        # what a v5e chip states (here none does)
 # the digests of the twelve cells PR 61 found, taken on its PARENT (77455d7,
@@ -65,6 +66,12 @@ PINNED = {
     "train-minicpmsala-l4-s16384-b1": "9e8db484700b5162",
     "train-lfm2-ep4-s16384-b1": "d6d3bc666242dc82",
     "train-ling3flash-ep32-s16384-b1": "c626e69b9c15ab7d",
+    # PR 63's: the Solar cell's as its parent (9d3ba29) lowers it, equal on
+    # its tree (a third layout in ``ops/ssd.py``, a third form of block in
+    # ``llama._layer``, scopes inside ``hybrid.mixer_half``: every program
+    # left alone), and the cell PR 63 added, as its tree lowers it
+    "train-solaropen2-ep32-s16384-b1": "9796d8cbe0bcd100",
+    "train-falconh1-l4-s16384-b1": "c6b4cd2a1f4e29c9",
 }
 
 

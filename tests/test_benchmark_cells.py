@@ -40,7 +40,9 @@ RECIPE_KEYS = {"train": ("attn_impl", "remat", "f32_logits"),
                "train_kda": ("attn_impl", "gmm_impl", "kda_impl", "remat",
                              "f32_logits"),
                "train_solar": ("attn_impl", "gmm_impl", "kda_impl", "remat",
-                               "f32_logits")}
+                               "f32_logits"),
+               "train_falconh1": ("attn_impl", "ssd_impl", "remat",
+                                  "f32_logits")}
 # published config.json key -> the program's field
 WIDTHS = {"hidden_size": "d_model", "num_hidden_layers": "n_layers",
           "num_attention_heads": "n_heads",
@@ -68,7 +70,7 @@ def test_cell_program_config_builds_at_its_published_widths(name):
     from benchmark import (model, model_commanda, model_glm, model_glm52,
                            model_granite, model_lfm2, model_ling,
                            model_mellum, model_moe, model_nemotron,
-                           model_sala, model_solar, resolve)
+                           model_falconh1, model_sala, model_solar, resolve)
 
     cell = resolve.cell(name)
     kind, conf, recipe = cell["kind"], cell["config"], cell["train"]
@@ -83,7 +85,8 @@ def test_cell_program_config_builds_at_its_published_widths(name):
              "train_blockset": model_sala.sala_config,
              "train_shortconv": model_lfm2.hybrid_config,
              "train_kda": model_ling.ling_config,
-             "train_solar": model_solar.solar_config}[kind]
+             "train_solar": model_solar.solar_config,
+             "train_falconh1": model_falconh1.falcon_config}[kind]
     passed = {k: recipe[k] for k in RECIPE_KEYS[kind] if k in recipe}
     cfg = build(conf, **passed)
 
@@ -99,7 +102,8 @@ def test_cell_program_config_builds_at_its_published_widths(name):
               "train_blockset": model_sala.HF_TO_FIELD,
               "train_shortconv": model_lfm2.HF_TO_FIELD,
               "train_kda": model_ling.HF_TO_FIELD,
-              "train_solar": model_solar.HF_TO_FIELD}[kind]
+              "train_solar": model_solar.HF_TO_FIELD,
+              "train_falconh1": model_falconh1.HF_TO_FIELD}[kind]
     for key, field in widths.items():
         assert getattr(cfg, field) == conf[key], (name, key)
     if kind == "train_parallel":
@@ -215,6 +219,26 @@ def test_cell_program_config_builds_at_its_published_widths(name):
         from ray_tpu.models import ling
         assert sum(n for _, n in ling.layer_runs(cfg)) \
             == conf["num_hidden_layers"]
+    if kind == "train_falconh1":
+        # every head, group, state, chunk and multiplier is the published
+        # key's (the map above and the two tuples); the mixer's inner width
+        # is mamba_d_ssm, not mamba_expand x hidden; every layer is of the
+        # one kind, a block of two first halves
+        assert {"head_dim", "mamba_d_state", "mamba_n_groups",
+                "mamba_chunk_size", "key_multiplier",
+                "lm_head_multiplier"} <= set(widths)
+        assert cfg.ssm_multipliers == tuple(conf["ssm_multipliers"])
+        assert cfg.mlp_multipliers == tuple(conf["mlp_multipliers"])
+        assert cfg.mamba_inner == conf["mamba_d_ssm"] \
+            != conf["mamba_expand"] * conf["hidden_size"]
+        assert cfg.attn_scale == conf["key_multiplier"] \
+            * conf["head_dim"] ** -0.5 and cfg.rope
+        assert cfg.n_heads // cfg.n_kv_heads == 5
+        from ray_tpu.models import falcon
+        from ray_tpu.models.family import _halves
+        runs = falcon.layer_runs(cfg)
+        assert sum(n for _, n in runs) == conf["num_hidden_layers"]
+        assert all(_halves(cfg, k) == ("both", True) for k, _ in runs)
     if kind == "train_solar":
         # the grouped-query half's heads and stated width, the expert's
         # width, the router's scale and the rank of the pairs are the

@@ -214,3 +214,77 @@ def test_ssd_plan_instant_once_a_trace(monkeypatch):
     plans = [a for n, a in seen if n == "ssd.plan"]   # a compile is one too
     assert len(plans) == 1
     assert plans[0]["chunk"] == 32 and plans[0]["heads_per_block"] == 4
+
+
+# --- heads of a lane tile with steps, in groups: the "tile" layout ----------
+
+# (H, G, N, chunk, dtype): P = 128 and S = 64 in all; G in (1, 2, H), N in
+# (P, 2 P), two chunks, both types
+TILE = {"one group, N = P, float32": (4, 1, 128, 16, "float32"),
+        "two groups, N = 2 P, float32": (4, 2, 256, 32, "float32"),
+        "a group a head, N = 2 P, float32": (2, 2, 256, 16, "float32"),
+        "two groups, N = 2 P, bfloat16": (4, 2, 256, 32, "bfloat16"),
+        "one group, N = P, bfloat16": (4, 1, 128, 16, "bfloat16"),
+        "a group a head, N = P, bfloat16": (2, 2, 128, 32, "bfloat16")}
+
+
+@pytest.mark.parametrize("case", sorted(TILE))
+def test_tile_layout_against_the_plain_path_and_the_recurrence(case):
+    """Heads of 128 with steps (``layout`` "tile") in interpret mode: the
+    value and all five gradients against the "xla" path on the same inputs
+    and against the token-by-token recurrence in float32."""
+    H, G, N, chunk, dtype = TILE[case]
+    B, S, P = 1, 64, ssd.LANE_TILE
+    plan = ssd.plan(S=S, H=H, P=P, N=N, chunk=chunk, dtype=dtype,
+                    impl="pallas", G=G)
+    assert (plan["layout"], plan["decay"], plan["state"]) \
+        == ("tile", "stepped", N)
+    x, dt, a, bm, cm = group_inputs(5, B, S, H, P, N, G)
+    x = x * 0.25                            # sums over 128 lanes stay O(1)
+    probe = jax.random.normal(jax.random.PRNGKey(7), (B, S, H, P))
+    low = jnp.dtype(dtype)
+    cast = lambda x, dt, a, bm, cm: (                        # noqa: E731
+        x.astype(low), dt, a, bm.astype(low), cm.astype(low))
+
+    def through(fn, args):
+        return jax.jit(jax.value_and_grad(
+            lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * probe),
+            argnums=(0, 1, 2, 3, 4)))(*args)
+
+    args = cast(x, dt, a, bm, cm)
+    exact = tuple(t.astype(jnp.float32) for t in args)   # the rounded inputs
+    (v, got), (vx, plain), (vr, want) = (
+        through(lambda *a: ssd.ssd_scan(*a, chunk=chunk, impl="pallas"), args),
+        through(lambda *a: ssd.ssd_scan(*a, chunk=chunk, impl="xla"), args),
+        through(grouped(recurrence, G), exact))
+    tol = 2e-3 if dtype == "float32" else 3e-2
+    scale = float(jnp.sqrt(jnp.mean(probe ** 2)) * S * H * P) ** 0.5
+    assert abs(float(v) - float(vr)) < tol * scale * 10, (v, vr)
+    assert abs(float(vx) - float(vr)) < tol * scale * 10, (vx, vr)
+    for name, g, px, w in zip(("x", "dt", "a", "B", "C"), got, plain, want):
+        assert g.shape == w.shape and g.dtype == px.dtype, name
+        norm = float(jnp.linalg.norm(w.astype(jnp.float32)))
+        for which, other in (("recurrence", w), ("plain path", px)):
+            err = float(jnp.linalg.norm(
+                g.astype(jnp.float32) - other.astype(jnp.float32)))
+            assert err <= tol * norm, (name, which, err / norm)
+
+
+def test_tile_plan_at_a_falcon_mixer_and_the_three_layouts_named():
+    """32 heads of 128 in 2 groups, a state of 256, chunk 128: a block of a
+    group's heads under ``TILE_VMEM``; the refusal names the layouts."""
+    p = ssd.plan(S=16384, H=32, P=128, N=256, chunk=128, dtype=jnp.bfloat16,
+                 impl="pallas", G=2)
+    assert (p["layout"], p["decay"], p["groups"], p["heads_per_group"],
+            p["state"]) == ("tile", "stepped", 2, 16, 256)
+    assert p["heads_per_block"] in (8, 16)
+    assert p["vmem_bytes"] <= ssd.TILE_VMEM < 16 * 2 ** 20
+    # the pairs and wide plans are what they were
+    assert ssd.plan(S=8192, H=64, P=64, N=128, chunk=128, dtype=jnp.bfloat16,
+                    impl="pallas", G=8)["layout"] == "pairs"
+    assert ssd.plan(S=16384, H=32, P=128, N=128, chunk=256,
+                    dtype=jnp.bfloat16, impl="pallas", G=32,
+                    steady=True)["layout"] == "wide"
+    x, dt, a, bm, cm = group_inputs(0, 1, 32, 3, 16, 8, 1)
+    with pytest.raises(ValueError, match="'pairs'.*'tile'.*'wide'"):
+        ssd.ssd_scan(x, dt, a, bm, cm, chunk=16, impl="pallas")

@@ -50,7 +50,8 @@ def test_nemotron_step_plans_under_the_figure_its_file_states(
     assert not replayed, replayed[:2]
     assert "checkpoint/feed_forward/shared/dot_general" in text
     # (PR 48's program held nine: one a mixer's body; PR 53's four)
-    made = [ln for ln in lines if "/mixer/dot_general" in ln
+    # (under the scope ``in_proj`` since PR 63 named the mixer's parts)
+    made = [ln for ln in lines if "/mixer/in_proj/dot_general" in ln
             and " convolution(" in ln and "= bf16[2,8192,10304]" in ln]
     assert len(made) == 9, len(made)
     again = [ln for ln in made if "rematted_computation/" in ln]
