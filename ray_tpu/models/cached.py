@@ -21,6 +21,12 @@ from ray_tpu.models.llama import (LlamaConfig, _dq, _embed, _family, _norm,
                                   _project, _residual, _rope_tables)
 
 
+# the pairing of ``_rope_tables``, the only tables a cached forward turns by
+# (a config of several attention kinds, where another may be stated, is
+# refused: ``_refuse_stated``)
+PAIRS = "halves"
+
+
 class KVCache(NamedTuple):
     k: jax.Array        # [L, B, max_seq, KV, HD]
     v: jax.Array
@@ -154,16 +160,8 @@ def _turn_by_row(cfg: LlamaConfig, pos):
     if pos.ndim == 2:
         pos = jnp.minimum(pos, cfg.max_seq_len - 1)
     cos, sin = (t[pos] if pos.ndim == 2 else t[pos][:, None, :]
-                for t in tables)                      # [B, T, HD/2]
-
-    def turn(x):   # [B, T, N, HD]
-        x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-        c = cos[:, :, None, :]
-        s = sin[:, :, None, :]
-        return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s],
-                               axis=-1).astype(x.dtype)
-
-    return turn
+                for t in tables)                      # [B, T, HD]
+    return lambda x: llama.apply_rope(x, cos, sin, PAIRS)  # [B, T, N, HD]
 
 
 def _view_mask(qpos, prefix_len, tail_len, S: int, cfg: LlamaConfig):
@@ -220,7 +218,7 @@ def prefill(params, tokens, lengths, cfg: LlamaConfig):
     tokens overwrite pad slots)."""
     x = _embed(params, tokens, cfg.dtype)
     cos, sin = _rope_tables(cfg.rope_theta, tokens.shape[1], cfg.head_dim)
-    turn = lambda t: llama.apply_rope(t, cos, sin)           # noqa: E731
+    turn = lambda t: llama.apply_rope(t, cos, sin, PAIRS)    # noqa: E731
 
     # nothing stored: the chunk attends over itself from the origin (the
     # flash kernel at P >= 128) and k and v leave as the scan's ys
@@ -246,7 +244,7 @@ def forward_with_cache(params, tokens, cache: KVCache, cfg: LlamaConfig,
     cos, sin = (jax.lax.dynamic_slice_in_dim(t, offset, S, axis=0)
                 for t in _rope_tables(cfg.rope_theta, cfg.max_seq_len,
                                       cfg.head_dim))
-    turn = lambda t: llama.apply_rope(t, cos, sin)           # noqa: E731
+    turn = lambda t: llama.apply_rope(t, cos, sin, PAIRS)    # noqa: E731
 
     def attend(q, k, v, ck, cv):
         ck = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype),

@@ -66,6 +66,10 @@ class Family:
     # what it reports): every layer's attention half, where it is not three
     # projections of the hidden state
     attention_half: Optional[Callable] = None
+    # (cfg, an ``AttentionKind``, seq_len) -> (cos, sin): the tables an
+    # ``attention_half`` of the family's own is handed, where they are not
+    # ``llama._kind_tables``' (None: a pair's angle on both its lanes)
+    rotary_tables: Optional[Callable] = None
     # (x, lp, cfg, kind, mesh=) -> x, or (x, what it reports): the first
     # half of a layer whose kind is no attention
     mixer_half: Optional[Callable] = None

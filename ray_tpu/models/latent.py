@@ -950,6 +950,7 @@ FAMILY = _moe.FAMILY.replace(
     "latent", feed_forward=feed_forward, remat_saved=REMAT_SAVED,
     remat_saved_bytes=remat_saved_bytes, remat_offers=remat_offers,
     layer_runs=layer_runs, attention_half=attention_half,
+    rotary_tables=_ll._kind_pair_tables,
     further_losses=further_losses, finish_loss=finish_loss,
     carried_init=carried_init, hands_on=hands_on,
     further_stacks=further_stacks)

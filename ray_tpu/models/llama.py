@@ -32,6 +32,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.models import remat
@@ -69,7 +70,9 @@ class AttentionKind:
     # q and k go to the kernel as projected) though the model has rotary
     rope: bool = True
     # which lanes of a head one angle turns: "halves" (i, i + head_dim / 2)
-    # (transformers' rotate_half) or "neighbours" (2i, 2i + 1) (GPT-J's)
+    # (transformers' rotate_half) or "neighbours" (2i, 2i + 1) (GPT-J's):
+    # the signed swap ``apply_rope`` multiplies by and the lanes the tables
+    # carry a pair's angle on (``_pair_swap``, ``_on_lanes``)
     pairs: str = "halves"
 
 
@@ -332,7 +335,9 @@ def rope_inv_freq(theta: float, head_dim: int, yarn: Optional[Yarn] = None):
 
 
 @functools.partial(jax.jit, static_argnums=(1, 2), inline=True)
-def _rope_tables(theta: float, seq_len: int, head_dim: int):
+def _pair_tables(theta: float, seq_len: int, head_dim: int):
+    """cos and sin of the plain rotary, one column a PAIR of lanes: float32
+    [seq_len, head_dim / 2] (``_on_lanes`` lays them on a head's lanes)."""
     freqs = rope_inv_freq(theta, head_dim)
     t = jnp.arange(seq_len, dtype=jnp.float32)
     angles = jnp.outer(t, freqs)                     # [S, HD/2]
@@ -341,7 +346,7 @@ def _rope_tables(theta: float, seq_len: int, head_dim: int):
 
 @functools.partial(jax.jit, static_argnums=(0, 1, 2, 3), inline=True)
 def _yarn_tables(theta: float, seq_len: int, head_dim: int, yarn: Yarn):
-    """``_rope_tables`` for a kind of layer under YaRN: the stretched
+    """``_pair_tables`` for a kind of layer under YaRN: the stretched
     frequencies, cos and sin both times the attention factor."""
     freqs = rope_inv_freq(theta, head_dim, yarn)
     angles = jnp.outer(jnp.arange(seq_len, dtype=jnp.float32), freqs)
@@ -349,20 +354,44 @@ def _yarn_tables(theta: float, seq_len: int, head_dim: int, yarn: Yarn):
     return jnp.cos(angles) * by, jnp.sin(angles) * by
 
 
-def _kind_tables(cfg: "LlamaConfig", of: AttentionKind, seq_len: int):
-    """cos and sin of the layers of one kind: [seq_len, rope_dim / 2], or,
-    where neighbouring lanes are paired, [seq_len, rope_dim] with a pair's
-    angle on both its lanes (``apply_rope``)."""
+# which lanes of a head one angle turns (``AttentionKind.pairs``)
+PAIRINGS = ("halves", "neighbours")
+
+
+def _on_lanes(tables, pairs: str):
+    """cos and sin one column a pair, [S, HD / 2] -> [S, HD] with a pair's
+    angle on BOTH its lanes, as ``apply_rope`` takes them: ``halves`` (lanes
+    i and i + HD / 2) cos | cos, ``neighbours`` (lanes 2i and 2i + 1) every
+    column twice."""
+    if pairs not in PAIRINGS:
+        raise ValueError(f"unknown rotary pairing {pairs!r}")
+    if pairs == "halves":
+        return tuple(jnp.concatenate([t, t], axis=-1) for t in tables)
+    return tuple(jnp.repeat(t, 2, axis=-1) for t in tables)
+
+
+def _rope_tables(theta: float, seq_len: int, head_dim: int):
+    """cos and sin of a model whose layers are of ONE kind and pair a
+    head's halves: float32 [seq_len, head_dim] (``apply_rope``)."""
+    return _on_lanes(_pair_tables(theta, seq_len, head_dim), "halves")
+
+
+def _kind_pair_tables(cfg: "LlamaConfig", of: AttentionKind, seq_len: int):
+    """cos and sin of the layers of one kind, one column a pair:
+    [seq_len, rope_dim / 2]. What a family's own attention half takes where
+    it lays them on its lanes itself (``Family.rotary_tables``:
+    models/latent.py, (1 | cos) over a head's nope and rotary lanes)."""
     theta = cfg.rope_theta if of.rope_theta is None else of.rope_theta
     if of.yarn is None:
-        tables = _rope_tables(theta, seq_len, cfg.rope_dim)
-    else:
-        tables = _yarn_tables(float(theta), seq_len, cfg.rope_dim, of.yarn)
-    if of.pairs == "halves":
-        return tables
-    if of.pairs != "neighbours":
-        raise ValueError(f"unknown rotary pairing {of.pairs!r}")
-    return tuple(jnp.repeat(t, 2, axis=-1) for t in tables)
+        return _pair_tables(theta, seq_len, cfg.rope_dim)
+    return _yarn_tables(float(theta), seq_len, cfg.rope_dim, of.yarn)
+
+
+def _kind_tables(cfg: "LlamaConfig", of: AttentionKind, seq_len: int):
+    """cos and sin of the layers of one kind as ``apply_rope`` takes them:
+    [seq_len, rope_dim] with a pair's angle on both its lanes, the pairs
+    the kind's (``_on_lanes``)."""
+    return _on_lanes(_kind_pair_tables(cfg, of, seq_len), of.pairs)
 
 
 def attention_kind(cfg: LlamaConfig, kind=None) -> AttentionKind:
@@ -372,24 +401,79 @@ def attention_kind(cfg: LlamaConfig, kind=None) -> AttentionKind:
         kind, AttentionKind(window=cfg.sliding_window))
 
 
-def apply_rope(x, cos, sin):
-    """x: [B, S, N, HD]; cos/sin: [S, HD/2] (already offset for decode),
-    lanes (i, i + HD/2) turned together; or [S, HD] (``_kind_tables`` for
-    a kind that pairs neighbours): lanes (2i, 2i + 1) turned together."""
-    if cos.shape[-1] == x.shape[-1]:
-        # the pair's other lane by two shifts along the lanes and a select
-        # (a reshape to [.., HD/2, 2] would put 2 on the lane axis)
-        f = x.astype(jnp.float32)
-        even = jnp.arange(x.shape[-1]) % 2 == 0
-        other = jnp.where(even, -jnp.roll(f, -1, axis=-1),
-                          jnp.roll(f, 1, axis=-1))
-        return (f * cos[None, :, None, :]
-                + other * sin[None, :, None, :]).astype(x.dtype)
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    c = cos[None, :, None, :]
-    s = sin[None, :, None, :]
-    return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s],
-                           axis=-1).astype(x.dtype)
+@functools.cache
+def _pair_swap(head_dim: int, pairs: str, dtype):
+    """P [HD, HD] of 0 and +-1: x P is a pair's OTHER lane, signed as the
+    rotary takes it. ``halves``: (x P)[i] = -x[i + HD/2], (x P)[i + HD/2] =
+    x[i]; ``neighbours``: (x P)[2i] = -x[2i + 1], (x P)[2i + 1] = x[2i]. A
+    constant of the program, made from the pairing and the head's width
+    alone; P^T = -P turns back."""
+    if pairs not in PAIRINGS:
+        raise ValueError(f"unknown rotary pairing {pairs!r}")
+    lane = np.arange(head_dim)
+    if pairs == "halves":
+        other, first = (lane + head_dim // 2) % head_dim, lane < head_dim // 2
+    else:
+        other, first = lane ^ 1, lane % 2 == 0
+    swap = np.zeros((head_dim, head_dim), np.float32)
+    swap[other, lane] = np.where(first, -1.0, 1.0)
+    return swap.astype(dtype)
+
+
+def _turn(x, cos, sin, swap):
+    """x cos + (x swap) sin in float32, as x's type: ONE product on the
+    matrix unit with the two multiplies and the add as its epilogue. The
+    product has one non-zero term a lane, so it is exact (``highest``
+    keeps a float32 x whole on the chip; bfloat16 passes once either
+    way). x is read as an array of its own (``optimization_barrier``): the
+    compiler otherwise lets the layout of whoever made x reach the product
+    (rows on the lanes where a per-head norm came first: the product with
+    its operands swapped, then float32 relayout copies of both terms)."""
+    x = jax.lax.optimization_barrier(x)
+    other = jnp.einsum("...d,de->...e", x, jnp.asarray(swap),
+                       precision=jax.lax.Precision.HIGHEST,
+                       preferred_element_type=jnp.float32)
+    return (x.astype(jnp.float32) * cos[..., None, :]
+            + other * sin[..., None, :]).astype(x.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _rotary(x, cos, sin, pairs):
+    return _turn(x, cos, sin, _pair_swap(x.shape[-1], pairs, x.dtype))
+
+
+def _rotary_fwd(x, cos, sin, pairs):
+    return _rotary(x, cos, sin, pairs), (cos, sin)
+
+
+def _rotary_bwd(pairs, tables, dy):
+    # a rotation's gradient is the rotation back: dy cos + (dy P^T) sin,
+    # the same one product (jax's own transposition of ``_turn`` would
+    # round dy sin to x's type ahead of a product of mixed types)
+    return _turn(dy, *tables, _pair_swap(dy.shape[-1], pairs, dy.dtype).T), \
+        None, None
+
+
+_rotary.defvjp(_rotary_fwd, _rotary_bwd)
+
+
+def apply_rope(x, cos, sin, pairs: str = "halves"):
+    """The rotary of x [B, S, N, HD] by cos and sin float32 [S, HD] (already
+    offset for decode; [B, S, HD] where every row has positions of its own)
+    with a pair's angle on BOTH its lanes (``_on_lanes``; ``pairs`` says
+    which lanes those are): x cos + (x P) sin, P the pairing's signed swap
+    (``_pair_swap``). The tables are constants of the step: no gradient
+    reaches them. Nothing tells halves' tables from neighbours' (both are
+    [S, HD]), so every caller in ray_tpu/ says ``pairs``; the default
+    stands for the three-argument call of ``benchmark/kinds/
+    train_falconh1.py`` alone, which takes ``_rope_tables``' halves."""
+    if cos.shape[-1] != x.shape[-1]:
+        raise ValueError(
+            f"rotary tables of {cos.shape[-1]} lanes for heads of "
+            f"{x.shape[-1]}: apply_rope takes a pair's angle on both its "
+            "lanes (llama._on_lanes)")
+    with jax.named_scope("rotary"):
+        return _rotary(x, cos, sin, pairs)
 
 
 def _attention_xla(q, k, v, causal: bool, q_offset=0, window=None,
@@ -517,6 +601,7 @@ def _attention_half(x, lp, cfg: LlamaConfig, cos, sin, mesh=None, rules=None,
     B, S, D = x.shape
     H, KV, HD = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     dt = cfg.dtype
+    pairs = attention_kind(cfg, kind).pairs
 
     h = _norm(x, lp["attn_norm"], cfg) if normed is None else normed
 
@@ -525,8 +610,8 @@ def _attention_half(x, lp, cfg: LlamaConfig, cos, sin, mesh=None, rules=None,
         k = _project(h, lp, cfg, "wk", KV, "k_norm")
         v = _project(h, lp, cfg, "wv", KV)
         if cos is not None:
-            q = apply_rope(q, cos, sin)
-            k = apply_rope(k, cos, sin)
+            q = apply_rope(q, cos, sin, pairs)
+            k = apply_rope(k, cos, sin, pairs)
         # as the attention call takes them: kept across the layer
         # checkpoint where the step's memory has room (``remat.remat_plan``)
         q, k, v = (checkpoint_name(t, name)
@@ -540,7 +625,7 @@ def _attention_half(x, lp, cfg: LlamaConfig, cos, sin, mesh=None, rules=None,
                 return y
             at = lambda t: jax.lax.dynamic_slice_in_dim(       # noqa: E731
                 t, shard * y.shape[1], y.shape[1])
-            return apply_rope(y, at(cos), at(sin))
+            return apply_rope(y, at(cos), at(sin), pairs)
 
         q, k, v = allgather_matmul(
             h, [_dq(lp[w], dt) for w in ("wq", "wk", "wv")], tp,
@@ -892,6 +977,7 @@ def _forward(params, tokens, cfg: LlamaConfig, pos_offset=0, mesh=None,
         if cfg.embedding_multiplier is not None:
             x = (x * cfg.embedding_multiplier).astype(dt)
     x = con(x)
+    family = _family(cfg)
 
     @functools.cache
     def tables_of(kind):
@@ -901,17 +987,17 @@ def _forward(params, tokens, cfg: LlamaConfig, pos_offset=0, mesh=None,
             _say_kind_plan(cfg, kind, of, S)
         if not (cfg.rope and of.rope):
             return None, None
+        make = family.rotary_tables or _kind_tables
         with jax.named_scope("attention"):  # the tables are its rotary's
             if isinstance(pos_offset, int) and pos_offset == 0:
-                return _kind_tables(cfg, of, S)
+                return make(cfg, of, S)
             return tuple(
                 jax.lax.dynamic_slice_in_dim(t, pos_offset, S, axis=0)
-                for t in _kind_tables(cfg, of, cfg.max_seq_len))
+                for t in make(cfg, of, cfg.max_seq_len))
 
     plan = remat.plan_for_step(cfg, params, B, S, mesh) if cfg.remat \
         else None
 
-    family = _family(cfg)
     # what the attention halves hand from layer to layer beside x (None:
     # nothing, and the scans carry x alone)
     held = family.carried_init(cfg, B, S) if family.carried_init else None

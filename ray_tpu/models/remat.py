@@ -76,6 +76,19 @@ UPDATE_BYTES = 16
 # no room and keep nothing; the Mellum2 step has no such pass).
 KEPT_COST_ONE = 1.0
 KEPT_COST_STACK = 1.5
+# What no charge a byte holds: q in a run of ONE layer that stands among
+# stacks. Such a layer's body lies open in the program between the stacks'
+# loops, the compiler is free to place the pieces of its replay, and with
+# its q kept it holds the forward call's log-sum-exp on all 128 lanes until
+# the backward cuts it (268 MB a layer where 2 are asked for) and relayouts
+# of the kept q beside it: +2.88e9 of plan for 0.40e9 of q in the Mellum2
+# step's three full layers, 7.1 a byte, where the q of its three stacks
+# costs 1.83 (+2.22e9 for 1.21e9) and k and v everywhere 12.0e9 in all
+# (the plans compiled for a described v5e, PERF.md 6, PR 64: 17.21e9 with q
+# in every run, over what the chip states). A step whose runs are ALL of one
+# layer reads 0.61 to 1.02 with q among its names (above). So such a run
+# keeps the other names and leaves q to its replay.
+LEFT_BY_ONE_AMONG_STACKS = ("attn_q",)
 
 
 def kept_cost(n: int) -> float:
@@ -219,7 +232,8 @@ def remat_plan(cfg: "LlamaConfig", params, batch: int, seq: int, memory,
     bytes charged by the run's length (``kept_cost``: 1.0 a byte in a run
     of one layer, 1.5 in a stack), stays under the limit less its free
     share (REMAT_FREE); a name that does not fit a run is passed over for
-    the next run and the next name. With no limit
+    the next run and the next name, and a run of one layer among stacks
+    leaves q to its replay (LEFT_BY_ONE_AMONG_STACKS). With no limit
     (the CPU) or no step nothing more is kept than the parent's list; under
     a mesh of several devices neither: the activations' share of a device
     is not counted here."""
@@ -233,9 +247,12 @@ def remat_plan(cfg: "LlamaConfig", params, batch: int, seq: int, memory,
     estimate = _step_estimate(cfg, params, stacks, passes, batch * seq,
                               memory.state)
     ceiling = memory.limit * (1 - REMAT_FREE)
+    among_stacks = any(n > 1 for _, n, _ in stacks)
     kept, total, charged = [[] for _ in stacks], 0, 0.0
     for name in _offered(cfg):
         for run, (_, n, _) in enumerate(stacks):
+            if n == 1 and among_stacks and name in LEFT_BY_ONE_AMONG_STACKS:
+                continue
             nbytes = n * offers[run].get(name, 0)
             cost = kept_cost(n) * nbytes
             if nbytes and estimate + charged + cost <= ceiling:

@@ -461,8 +461,10 @@ def _lightning_half(h, lp, cfg: SalaConfig, cos, sin, mesh):
     LH, hd = cfg.lightning_heads, cfg.head_dim
     if cfg.ssd_impl == "pallas":
         _one_device(mesh, "ssd_impl='pallas'")
-    q = _ll.apply_rope(_ll._project(h, lp, cfg, "wq", LH, "q_norm"), cos, sin)
-    k = _ll.apply_rope(_ll._project(h, lp, cfg, "wk", LH, "k_norm"), cos, sin)
+    pairs = _ll.attention_kind(cfg, "lightning").pairs
+    q, k = (_ll.apply_rope(_ll._project(h, lp, cfg, w, LH, norm), cos, sin,
+                           pairs)
+            for w, norm in (("wq", "q_norm"), ("wk", "k_norm")))
     v = _ll._project(h, lp, cfg, "wv", LH)
     with jax.named_scope("scan"):
         o = ssd_scan(v, None, -slopes(LH), k,

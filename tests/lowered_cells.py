@@ -51,27 +51,35 @@ V5E_LIMIT = 16_909_336_064        # what a v5e chip states (here none does)
 # lowered alone reads otherwise (``@_where_2099``, ``@closed_call_1357``: the
 # numbers count what was traced before). The bounded cut's own program is
 # held by tests/test_delta_rule.py (a jaxpr's digest at a tiny shape). A PR
-# that MEANS to change a cell's program replaces its digest, and says so
+# that MEANS to change a cell's program replaces its digest, and says so.
+# PR 64 replaced the eight cells' whose layers turn tables through
+# ``llama.apply_rope`` (one product with a signed swap where lanes were split
+# and joined: l8, four chips, OLMoE, Mellum2, Command A+, MiniCPM-SALA, LFM2,
+# Falcon-H1); the six that turn none or turn them inside their own
+# projections (Granite, Nemotron, Solar, the GLM cells, Ling) kept theirs.
+# The Mellum2 cell's is its step with q left to the replay in its three full
+# layers (``remat.LEFT_BY_ONE_AMONG_STACKS``, the same PR: no other cell's
+# plan has a run of one layer among stacks that has room for its q)
 PINNED_FROM = "0.9.0"
 PINNED = {
-    "train-deepseek7b-l8": "2e7bf97854587e81",
-    "train-deepseek7b-fsdp2tp2": "c918d8d005f83a3f",
-    "train-olmoe1b7b-s4096-b4": "823cfed5f3a8c7fa",
+    "train-deepseek7b-l8": "46227b7e9f0a4a2b",
+    "train-deepseek7b-fsdp2tp2": "3febe514848b1e40",
+    "train-olmoe1b7b-s4096-b4": "32f89764f69ea8d4",
     "train-granite4hs-ep8-s8192-b2": "04f181234a94ae93",
     "train-glm47flash-ep8-s8192-b2": "a968ebda9000750d",
-    "train-mellum2-ep4-s16384-b1": "9b9f8d8d30c8722d",
-    "train-commandaplus-ep16-s8192-b1": "dff8f54b1acc93fa",
+    "train-mellum2-ep4-s16384-b1": "d1070b522e0041cd",
+    "train-commandaplus-ep16-s8192-b1": "a68cf63e6a72f622",
     "train-glm52-ep32-s16384-b1": "ca4de7c508591ea3",
     "train-nemotron3nano-ep8-s8192-b2": "05378a4b5d8d9ebc",
-    "train-minicpmsala-l4-s16384-b1": "9e8db484700b5162",
-    "train-lfm2-ep4-s16384-b1": "d6d3bc666242dc82",
+    "train-minicpmsala-l4-s16384-b1": "5429377a63fca4a6",
+    "train-lfm2-ep4-s16384-b1": "2e7e7378c9879804",
     "train-ling3flash-ep32-s16384-b1": "c626e69b9c15ab7d",
     # PR 63's: the Solar cell's as its parent (9d3ba29) lowers it, equal on
     # its tree (a third layout in ``ops/ssd.py``, a third form of block in
     # ``llama._layer``, scopes inside ``hybrid.mixer_half``: every program
     # left alone), and the cell PR 63 added, as its tree lowers it
     "train-solaropen2-ep32-s16384-b1": "9796d8cbe0bcd100",
-    "train-falconh1-l4-s16384-b1": "c6b4cd2a1f4e29c9",
+    "train-falconh1-l4-s16384-b1": "83c658e5ea66a12f",
 }
 
 

@@ -92,7 +92,7 @@ def test_the_rotary_on_neighbouring_lanes_by_hand():
     cos, sin = llama._kind_tables(cfg, window, 8)
     assert cos.shape == (8, 16)       # a pair's angle on both its lanes
     x = jax.random.normal(jax.random.PRNGKey(0), (1, 8, 2, 16))
-    got = np.asarray(llama.apply_rope(x, cos, sin))
+    got = np.asarray(llama.apply_rope(x, cos, sin, window.pairs))
     pos, i = 5, 3                                     # lanes 6 and 7
     angle = pos * 10000.0 ** (-2 * i / 16)
     a, b = float(x[0, pos, 1, 6]), float(x[0, pos, 1, 7])
@@ -102,10 +102,12 @@ def test_the_rotary_on_neighbouring_lanes_by_hand():
         b * np.cos(angle) + a * np.sin(angle), abs=1e-5)
     np.testing.assert_allclose(
         got[0], reference_commanda._rope(x[0], 10000.0), atol=1e-5)
-    # the halves' pairing is another function of the same table
+    # the halves' pairing is another function of the same angles: cos | cos
     half = llama._kind_tables(cfg, dataclasses.replace(window, pairs="halves"),
                               8)
-    assert half[0].shape == (8, 8)
+    assert half[0].shape == (8, 16)
+    np.testing.assert_array_equal(half[0][:, :8], half[0][:, 8:])
+    np.testing.assert_array_equal(half[0][:, :8], cos[:, ::2])
     assert float(jnp.max(jnp.abs(llama.apply_rope(x, *half) - got))) > 0.1
     with pytest.raises(ValueError, match="pairing"):
         llama._kind_tables(cfg, dataclasses.replace(window, pairs="odd"), 8)

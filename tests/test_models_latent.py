@@ -224,7 +224,7 @@ def test_latent_attention_through_flash_at_a_head_of_256(fwd_dq, dkdv,
     S = 128
     x = jax.random.normal(jax.random.PRNGKey(4), (1, S, cfg.d_model))
     probe = jax.random.normal(jax.random.PRNGKey(5), x.shape)
-    cos, sin = llama._rope_tables(cfg.rope_theta, S, cfg.rope_dim)
+    cos, sin = llama._pair_tables(cfg.rope_theta, S, cfg.rope_dim)
 
     def mine(x, lp):
         y = latent.attention_half(x, lp, cfg, cos, sin)[0]
@@ -344,7 +344,7 @@ def test_the_attention_half_is_the_published_form(widths, dtype, tol):
     B, S = 2, 48
     x = jax.random.normal(jax.random.PRNGKey(5), (B, S, cfg.d_model), dt)
     probe = jax.random.normal(jax.random.PRNGKey(6), x.shape, jnp.float32)
-    cos, sin = llama._rope_tables(cfg.rope_theta, S, cfg.rope_dim)
+    cos, sin = llama._pair_tables(cfg.rope_theta, S, cfg.rope_dim)
 
     def run(half):
         def f(x, lp):

@@ -259,7 +259,7 @@ def test_the_shares_add_up_to_the_uncut_layer(kind):
     lp = jax.tree.map(lambda w: w[0], params["layers"][at])
     x = jax.random.normal(jax.random.PRNGKey(5), (1, 64, cfg.d_model))
     rc = ref_cfg(cfg)
-    cos, sin = llama._rope_tables(cfg.rope_theta, 64, cfg.rope_dim)
+    cos, sin = llama._pair_tables(cfg.rope_theta, 64, cfg.rope_dim)
 
     def program(c, weights):        # one program a share: jitted, not eager
         return jax.jit(lambda x, w: llama._layer(x, w, c, cos, sin,
@@ -418,7 +418,7 @@ def test_the_latent_half_takes_narrower_values_and_no_query_latent():
     params, _ = make(cfg, seed=2)
     lp = jax.tree.map(lambda w: w[0], params["layers"][2])
     x = jax.random.normal(jax.random.PRNGKey(7), (1, 128, cfg.d_model))
-    cos, sin = llama._rope_tables(cfg.rope_theta, 128, cfg.rope_dim)
+    cos, sin = llama._pair_tables(cfg.rope_theta, 128, cfg.rope_dim)
     rc = ref_cfg(cfg)
     with jax.default_matmul_precision("highest"):
         want = reference_ling.first_half(x[0], lp, rc)

@@ -15,6 +15,95 @@ from ray_tpu.parallel.train_step import (make_train_state_init,  # noqa: E402
 CFG = llama.PRESETS["tiny"].replace(remat=False, dtype=jnp.float32)
 
 
+def _rope_halves_by_hand(x, cos, sin):
+    """The body ``llama.apply_rope`` had before PR 64 for transformers'
+    ``rotate_half``: the lanes split at HD / 2 and joined again; cos and sin
+    one column a pair, [S, HD / 2]."""
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    c, s = cos[None, :, None, :], sin[None, :, None, :]
+    return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s],
+                           axis=-1).astype(x.dtype)
+
+
+def _rope_neighbours_by_hand(x, cos, sin):
+    """The body it had for GPT-J's pairs: the pair's other lane by two
+    shifts along the lanes and a select; cos and sin [S, HD], every column
+    of the pairs' tables twice."""
+    f = x.astype(jnp.float32)
+    even = jnp.arange(x.shape[-1]) % 2 == 0
+    other = jnp.where(even, -jnp.roll(f, -1, axis=-1),
+                      jnp.roll(f, 1, axis=-1))
+    return (f * cos[None, :, None, :]
+            + other * sin[None, :, None, :]).astype(x.dtype)
+
+
+@pytest.mark.parametrize("offset", [0, 7])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("head_dim", [16, 64, 128, 256])
+@pytest.mark.parametrize("pairs", llama.PAIRINGS)
+def test_apply_rope_is_the_split_and_the_shifted_bodies_bit_for_bit(
+        pairs, head_dim, dtype, offset):
+    """One product with the pairing's signed swap and two multiplies give
+    the values of the two bodies the function had (kept here as plain
+    references), bit for bit, whatever the head's width, the type and the
+    rows of the tables (``offset``: a decode step's slice); the gradient,
+    the rotation back, is theirs bit for bit in float32 and within one
+    bfloat16 ulp in bfloat16. Op by op, as the references run: a compiler
+    may contract either form's multiply-adds its own way."""
+    dt, S = getattr(jnp, dtype), 24
+    kx, kw = jax.random.split(jax.random.PRNGKey(head_dim + offset))
+    x = jax.random.normal(kx, (2, S, 3, head_dim), jnp.float32).astype(dt)
+    w = jax.random.normal(kw, x.shape, jnp.float32)
+    by_pair = tuple(t[offset:offset + S] for t in llama._pair_tables(
+        10000.0, S + offset, head_dim))
+    cos, sin = llama._on_lanes(by_pair, pairs)
+    assert cos.shape == sin.shape == (S, head_dim)
+    if pairs == "halves":
+        by_hand = lambda x: _rope_halves_by_hand(x, *by_pair)  # noqa: E731
+    else:
+        by_hand = lambda x: _rope_neighbours_by_hand(x, cos, sin)  # noqa: E731
+    turn = lambda x: llama.apply_rope(x, cos, sin, pairs)      # noqa: E731
+    want, got = by_hand(x), turn(x)
+    assert got.dtype == want.dtype == dt
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+    assert float(jnp.max(jnp.abs(got.astype(jnp.float32)
+                                 - x.astype(jnp.float32)))) > 0.5
+    g_want, g_got = (
+        np.asarray(jax.grad(lambda x: jnp.sum(
+            f(x).astype(jnp.float32) * w))(x), np.float32)
+        for f in (by_hand, turn))
+    if dtype == "float32":
+        np.testing.assert_array_equal(g_got, g_want)
+    else:       # bfloat16 keeps 8 bits: an ulp is 2^-7 of the power of two
+        ulp = 2.0 ** (np.floor(np.log2(np.maximum(np.abs(g_want), 1e-30)))
+                      - 7)
+        assert np.all(np.abs(g_got - g_want) <= ulp)
+
+
+def test_apply_rope_refuses_tables_of_one_column_a_pair():
+    x = jnp.ones((1, 4, 2, 16))
+    cos, sin = llama._pair_tables(10000.0, 4, 16)
+    with pytest.raises(ValueError, match="both its lanes"):
+        llama.apply_rope(x, cos, sin)
+    for refused in (lambda: llama._on_lanes((cos, sin), "odd"),
+                    lambda: llama.apply_rope(
+                        x, *llama._rope_tables(10000.0, 4, 16), "odd")):
+        with pytest.raises(ValueError, match="pairing"):
+            refused()
+
+
+def test_apply_rope_takes_tables_of_every_rows_own_positions():
+    """models/cached.py gathers the tables at each row's positions:
+    [B, S, HD], a row's own tables turning that row."""
+    x = jax.random.normal(jax.random.PRNGKey(0), (1, 4, 2, 16))
+    wide = llama._rope_tables(10000.0, 4, 16)
+    rows = tuple(jnp.stack([t, t]) for t in wide)
+    np.testing.assert_array_equal(
+        llama.apply_rope(jnp.concatenate([x, x]), *rows)[1],
+        llama.apply_rope(x, *wide)[0])
+
+
 def test_forward_shapes():
     params = llama.init_params(jax.random.PRNGKey(0), CFG)
     tokens = jnp.zeros((2, 16), jnp.int32)
@@ -488,14 +577,22 @@ def _primitives(jaxpr, into=None):
 # (``moe.top_lanes``), ``top_k`` 2 -> 0 and 1 -> 0, ``scatter-add`` 4 -> 3
 # both, ``reduce_min`` 0 -> 4 and 0 -> 2, ``reduce_max`` 7 -> 11 and 5 -> 7
 # with the selects, compares and masks of the rounds; the three dense
-# digests stood.)
+# digests stood. PR 64 replaced all five: ``apply_rope`` is one product with
+# a signed swap under a ``custom_vjp`` (``split`` 6 -> 0, 4 -> 0, 7 -> 1 and
+# 5 -> 1, ``concatenate`` 6 -> 2, 4 -> 2, 9 -> 5 and 6 -> 4: what is left is
+# the tables' cos | cos and sin | sin, once a step; ``custom_vjp_call`` + 6
+# with a checkpoint's replay and + 4 without, ``dot_general`` likewise,
+# ``optimization_barrier`` 0 -> 6 and 0 -> 4: x read as an array of its own,
+# a product; mul, sub, neg and add_any fall with the
+# halves' four products a tensor); every other primitive's count is the
+# parent's.)
 JAXPRS_FROM = "0.9.0"
 ONE_DEVICE_JAXPRS = {
-    ("dense", 2, "flash", True, "bfloat16"): "3d9cb84650f54cfb",
-    ("dense", 4, "flash", True, "bfloat16"): "c35af5bbf0866773",
-    ("dense", 2, "xla", False, "float32"): "d7b97d42f0c9a0f5",
-    ("moe", 2, "flash", True, "bfloat16"): "14c9cd882adc4359",
-    ("moe", 2, "xla", False, "float32"): "3717cbc5fa5e0444",
+    ("dense", 2, "flash", True, "bfloat16"): "5e9496703650cc7c",
+    ("dense", 4, "flash", True, "bfloat16"): "673d72968a7a01d9",
+    ("dense", 2, "xla", False, "float32"): "a86f1c23162edebc",
+    ("moe", 2, "flash", True, "bfloat16"): "c0dba656fbe24a74",
+    ("moe", 2, "xla", False, "float32"): "263d461e4de10de6",
 }
 
 

@@ -366,7 +366,11 @@ def test_what_the_layer_checkpoint_is_told():
     plan = remat.remat_plan(cfg, params, 1, rows, StepMemory(
         limit=16_909_336_064, state=2 * 290_000))
     assert plan.why == "room" and len(plan.kept) == 3
-    assert "attn_q" in plan.kept[0] and "kda_gate" in plan.kept[1]
+    # (the attention layer, a run of one beside the KDA layers' stack,
+    # keeps k and v and leaves q: ``remat.LEFT_BY_ONE_AMONG_STACKS``)
+    assert [n for _, n, _ in remat._stacks(params, cfg)[0]][:2] == [1, 3]
+    assert plan.kept[0][:2] == ("attn_k", "attn_v") \
+        and "kda_gate" in plan.kept[1]
 
 
 def test_plan_instants_say_the_cut_the_chunk_and_the_states(monkeypatch):

@@ -28,8 +28,11 @@ MIX = (hybrid.MIX_OFFERED,)
 # any)
 CELLS = {
     "train-commandaplus-ep16-s8192-b1": (9_280_733_184, (ALL,) * 4),
-    "train-mellum2-ep4-s16384-b1": (11_246_880_768,
-                                    (remat.ATTN_OFFERED,) * 6),
+    # www f www f www f: q, k and v in the three stacks of window layers, k
+    # and v in the full layers, runs of one among stacks that leave q to
+    # their replay (PR 64: with q kept there too the step planned 17.21e9)
+    "train-mellum2-ep4-s16384-b1": (11_246_880_768, (
+        remat.ATTN_OFFERED, remat.ATTN_OFFERED[1:]) * 3),
     # MEMEM*EMEMEM*EMEMEM*: q, k and v in the three attention blocks, the
     # shared expert's up product in all eight expert blocks, the
     # in-projection's product in all nine mixers (the first five until a
@@ -171,9 +174,9 @@ def test_a_cell_keeps_the_names_it_has_room_for(name, cell_plans):
     if want:
         assert alone.estimate + alone.charged <= V5E * (1 - remat.REMAT_FREE)
         # every run of one layer at 1.0, Mellum2's three stacks of three
-        # window layers (9 of its 12 layers) at 1.5
+        # window layers (9 of its 12 layers, and all of its q) at 1.5
         assert alone.charged == alone.kept_bytes * (
-            11 / 8 if "mellum2" in name else 1)
+            47 / 32 if "mellum2" in name else 1)
     if name == "train-commandaplus-ep16-s8192-b1":
         # gate and up of four layers of 8,192 x 16,384, q and k, v of 32
         # and 2 heads of 128
@@ -191,7 +194,9 @@ def test_a_cell_keeps_the_names_it_has_room_for(name, cell_plans):
         assert alone.kept_bytes == 16384 * 2 * (
             2 * 2 * 7168 + 6 * 48 * 64 + 14 * 6144) == 4_362_076_160
     if name == "train-mellum2-ep4-s16384-b1":
-        assert alone.kept_bytes == 2_013_265_920        # as at PR 51
+        # 16,384 rows: q, k, v of 32, 4 and 4 heads of 128 nine times, k
+        # and v three times (2,013,265,920 with q in the full layers, PR 51)
+        assert alone.kept_bytes == 16384 * 2 * 128 * (9 * 40 + 3 * 8)
     if name == "train-minicpmsala-l4-s16384-b1":
         assert alone.kept_bytes == 2 * 16384 * 16384 * 2    # as at PR 53
     if name == "train-falconh1-l4-s16384-b1":
@@ -223,7 +228,9 @@ def test_the_plan_is_monotone_in_the_limit(name, cell_plans):
                "train-glm52-ep32-s16384-b1": moe.SHARED_OFFERED,
                # a family's own attention halves offer no q, k, v
                "train-minicpmsala-l4-s16384-b1": llama.FFN_OFFERED,
-               "train-granite4hs-ep8-s8192-b2": ALL + MIX,
+               # (its four attention blocks are runs of one among the
+               # mixers' stacks: they leave q to the replay)
+               "train-granite4hs-ep8-s8192-b2": ALL[1:] + MIX,
                # dense layers' gate and up; no shared expert
                "train-lfm2-ep4-s16384-b1":
                    remat.ATTN_OFFERED + llama.FFN_OFFERED + MIX,
@@ -300,6 +307,15 @@ OFFERING = {
 }
 
 
+def _q_left(cfg, params, batch=2, seq=64):
+    """The layers whose q a plan with every room still leaves to the
+    replay: runs of one layer among stacks (LEFT_BY_ONE_AMONG_STACKS)."""
+    stacks, _ = remat._stacks(params, cfg)
+    return sum("attn_q" in dict(remat._offers(cfg, kind, batch, seq))
+               for kind, n, _ in stacks if n == 1) \
+        if any(n > 1 for _, n, _ in stacks) else 0
+
+
 def _ids(v):
     return getattr(v, "__name__", v if isinstance(v, str) else "").split(
         ".")[-1]
@@ -355,8 +371,16 @@ def test_values_and_gradients_are_bit_equal_with_every_name_kept(family,
         got[limit] = (loss, grads, plan, aux)
     assert got[0][2].kept == () and got[0][2].why == "no limit"
     full = got[10**15][2]
-    assert {n for run in full.kept for n in run} == set(names), full
-    assert full.kept_bytes == 2 * 64 * lanes * 4
+    # (tiny-commanda's and tiny-mellum's two full layers and the hybrid's
+    # one attention block are runs of one among stacks: their q is left,
+    # and the hybrid has no other)
+    left = _q_left(cfg, params)
+    assert left == {"tiny-commanda": 2, "tiny-mellum": 2}.get(
+        preset, int(family is hybrid and preset == "tiny"))
+    assert {n for run in full.kept for n in run} == set(names) - (
+        {"attn_q"} if family is hybrid and left else set()), full
+    assert full.kept_bytes == 2 * 64 * (
+        lanes - left * cfg.n_heads * cfg.head_dim) * 4
     if got[0][3] is not None:       # an expert family reports the bytes
         assert float(got[0][3]["moe_remat_kept_gb"]) == 0.0
         assert float(got[10**15][3]["moe_remat_kept_gb"]) == pytest.approx(
@@ -520,7 +544,9 @@ def test_the_kept_program_computes_no_kept_product_twice(family, preset,
     # one backward scan a stack
     held = sum(w in st for st in stacks for w in weights)
     assert held >= len(weights)
-    assert count == {0: held, 10**15: 0}
+    # (a run of one layer among stacks replays its q whatever the room)
+    assert count == {0: held, 10**15: _q_left(cfg, params)
+                     if "wq" in weights else 0}
 
 
 @pytest.mark.parametrize("name", ALL)
@@ -528,7 +554,8 @@ def test_a_kept_byte_is_charged_by_the_length_of_its_run(name):
     """Two configs that differ in ``run_layers`` alone (every layer a run
     of its own against window, window, window | full twice over) keep the
     same bytes of ``name`` in the same eight layers and are charged 1.0 a
-    byte in the runs of one against 1.5 in the stacks of three."""
+    byte in the runs of one against 1.5 in the stacks of three; but q,
+    which a run of one layer among stacks leaves to its replay."""
     base = _tiny("tiny-commanda")[0]
     charged = {}
     for most, lengths in ((1, [1] * 8), (3, [3, 1, 3, 1])):
@@ -538,16 +565,20 @@ def test_a_kept_byte_is_charged_by_the_length_of_its_run(name):
         assert [n for _, n in moe.layer_runs(cfg)] == lengths
         plan = remat.remat_plan(cfg, params, 2, 64,
                                 train_step.StepMemory(10**15, 0))
-        assert all(name in run for run in plan.kept), plan
+        left = name in remat.LEFT_BY_ONE_AMONG_STACKS and most > 1
+        assert [name in run for run in plan.kept] == [
+            n > 1 or not left for n in lengths], plan
         assert plan.charged == _charge(cfg, params, plan, 2, 64)
         a_layer = dict(remat._offers(cfg, "window", 2, 64))[name]
         assert dict(remat._offers(cfg, "full", 2, 64))[name] == a_layer
-        # every run keeps every name, each at the run's one rate: of the
-        # whole charge, this name's is the share of its bytes
-        charged[most] = plan.charged * 8 * a_layer / plan.kept_bytes
+        # this name's charge: the plan's less the plan's without it
+        rest = plan._replace(kept=tuple(
+            tuple(n for n in run if n != name) for run in plan.kept))
+        charged[most] = plan.charged - _charge(cfg, params, rest, 2, 64)
     assert charged[1] == 8 * a_layer * remat.KEPT_COST_ONE
-    assert charged[3] == 2 * a_layer * remat.KEPT_COST_ONE \
-        + 6 * a_layer * remat.KEPT_COST_STACK
+    assert charged[3] == 6 * a_layer * remat.KEPT_COST_STACK + (
+        0 if left else 2 * a_layer * remat.KEPT_COST_ONE)
+    assert remat.LEFT_BY_ONE_AMONG_STACKS == ("attn_q",)
     assert (remat.KEPT_COST_ONE, remat.KEPT_COST_STACK) == (1.0, 1.5)
     assert [remat.kept_cost(n) for n in (1, 2, 3, 30)] == [1.0, 1.5, 1.5, 1.5]
 
@@ -573,17 +604,25 @@ def test_the_remat_plan_instant_carries_its_fields(monkeypatch):
                         "ceiling": 0, "why": "no step"}
     last = plans[2]
     assert last["kept"] == ",".join(ALL)
-    # four runs of layers (window, full, window, full): each keeps all five
-    assert last["runs"] == ", ".join(f"{n} x4" for n in ALL)
-    assert last["by_run"] == ",".join(["+".join(ALL)] * 4)
+    # four runs of layers (window, full, window, full): each keeps all
+    # five, but the full layers, runs of one among stacks, their q
+    assert last["runs"] == ", ".join(
+        f"{n} x{2 if n == 'attn_q' else 4}" for n in ALL)
+    assert last["by_run"] == ",".join(
+        ["+".join(ALL), "+".join(ALL[1:])] * 2)
     assert set(last) == {"kept", "kept_bytes", "charged", "runs", "by_run",
                          "estimate", "limit", "ceiling", "why"}
     assert last["limit"] == 10**15 and last["ceiling"] == int(
         10**15 * (1 - remat.REMAT_FREE))
     assert 0 < last["kept_bytes"] < last["estimate"] < last["ceiling"]
     # window, window, window, full, twice over: six of the eight layers lie
-    # in a stack of three (1.5 a kept byte), two in a run of one (1.0)
-    assert last["charged"] == last["kept_bytes"] * 11 // 8
+    # in a stack of three (1.5 a kept byte), two in a run of one (1.0,
+    # without their q)
+    layer = dict(remat._offers(cfg, "full", 2, 64))
+    assert dict(remat._offers(cfg, "window", 2, 64)) == layer
+    ones = 2 * (sum(layer.values()) - layer["attn_q"])
+    assert last["kept_bytes"] == 6 * sum(layer.values()) + ones
+    assert last["charged"] == 6 * sum(layer.values()) * 3 // 2 + ones
 
 
 def test_the_step_hands_the_model_its_state_bytes_and_the_devices_limit(

@@ -179,6 +179,29 @@ COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
                "collective-permute")
 
 
+def _rotary_lines(text, head_dim=80):
+    """The lines of a compiled 2b7 step (heads of 80) issued under the
+    scope ``rotary``, having checked that the rotary is one product a
+    tensor with the pairing's signed swap (``llama.apply_rope``): in every
+    pass a convolution under that scope, and NO array anywhere in the step
+    whose last axis is half a head (at heads of 128 the split / concatenate
+    form handed four such halves of q and k from one fusion and the
+    backward copied them in float32: PERF.md 5, l8; at these heads of 80
+    XLA kept the halves inside its fusions, so here the products' scope is
+    what tells the forms apart)."""
+    import re
+
+    halves = sorted(set(re.findall(
+        rf"\w+\[(?:\d+,)+{head_dim // 2}\]", text)))
+    assert not halves, halves
+    lines = [ln for ln in text.splitlines() if "/rotary/" in ln]
+    products = [ln for ln in lines if " convolution(" in ln]
+    for under in ("jvp(layers)", "rematted_computation",
+                  "transpose(jvp(layers))"):
+        assert [ln for ln in products if under in ln], under
+    return lines
+
+
 def _loop_permutes(text, rows_shape):
     """[(loop body, permute, matmul fusions between its start and its
     done)] for every collective-permute of ``rows_shape`` (a shape, or a
@@ -239,6 +262,11 @@ def test_2b7_fsdp_tp_flash_step_compiles_on_four_chips(topo, on_chip_branch):
     assert text.count("tpu_custom_call") == 3
     assert "all-gather" in text and ("reduce-scatter" in text
                                      or "all-reduce" in text)
+    # the rotary contracts a head's lanes, which no axis shards: it brought
+    # no collective (the permutes' counts below hold what the step has)
+    assert not [ln for ln in _rotary_lines(text)
+                if any(c + "(" in ln or c + "-start(" in ln
+                       for c in COLLECTIVES)]
 
 
 def test_four_chip_step_overlaps_its_tensor_parallel_traffic(topo,
@@ -287,7 +315,9 @@ def test_2b7_train_step_fits_one_chip(topo, on_chip_branch):
     assert need < V5E_HBM, mem
     # forward, dq, dkdv: the checkpoint keeps the kernel's output, so the
     # compiled backward holds no second forward call
-    assert compiled.as_text().count("tpu_custom_call") == 3
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 3
+    assert _rotary_lines(text)
 
 
 def test_one_chip_step_has_no_collective(topo, on_chip_branch):
@@ -295,6 +325,7 @@ def test_one_chip_step_has_no_collective(topo, on_chip_branch):
     text = _step_2b7(topo, 1).as_text()
     assert not [c for c in COLLECTIVES
                 if c + "(" in text or c + "-start(" in text]
+    assert _rotary_lines(text)
 
 
 def test_llama7b_fsdp_fits_v5e8_hbm(topo, no_persistent_cache):

@@ -98,7 +98,7 @@ def _glm_attention_block(one_chip):
     lp = {k: _sds(stack[k].shape[1:], bf, one_chip) for k in (
         "attn_norm", "wq_a", "q_a_norm", "wq_b", "wkv_a", "kv_a_norm",
         "wkv_b", "wo")}
-    cos, sin = llama._rope_tables(cfg.rope_theta, S, cfg.rope_dim)
+    cos, sin = llama._pair_tables(cfg.rope_theta, S, cfg.rope_dim)
 
     def half(x, lp):
         with jax.named_scope("attention"):      # as llama._layer opens it
@@ -312,8 +312,10 @@ def test_sala_step_fits_with_its_set_kept_and_selects_once(
     keeps its SwiGLU's gate and up (1.07e9 bytes: the estimate reads
     12.39e9 of the 14.37e9 the rule leaves; the lightning layers' stack of
     three would need 2.42e9 a name), the plan stays under that ceiling
-    (13,769,958,400 when this was written, 12,696,442,368 with nothing
-    kept), XLA rematerializes nothing of its own, the layer
+    (12,512,892,928 since PR 64 took the split rotary's float32 halves out
+    of the lightning layers' body, 11,439,376,896 with nothing kept;
+    13,769,958,400 before, 12,696,442,368 then with nothing kept), XLA
+    rematerializes nothing of its own, the layer
     checkpoint keeps the sparse layer's set so that the replay selects
     nothing (the selection's top-k is in the program once), and every kind
     of Mosaic call is there."""
@@ -323,7 +325,7 @@ def test_sala_step_fits_with_its_set_kept_and_selects_once(
             for p in said] == [
         ("ffn_gate,ffn_up", "ffn_gate+ffn_up,-", 2 * 16384 * 16384 * 2,
          "room")]
-    assert 12.7e9 < plan < 14.37e9, plan
+    assert 12.0e9 < plan < 14.37e9, plan
     text = compiled.as_text()
     assert text.count(".remat") == 0
     for scope in ("sparse.fwd.blocks", "sparse.dq.blocks",

@@ -17,15 +17,22 @@ from described_tpu import _compile_cell_step, _sds, _while_bodies
 def test_mellum2_step_keeps_q_beside_k_and_v(topo, on_chip_branch,
                                              monkeypatch):
     """The Mellum2 cell's step with passes of 49,152 rows: the estimate
-    (11.17e9) leaves room for q beside k and v (2.01e9 bytes over twelve
+    (11.17e9) leaves room for q beside k and v in the three stacks of
+    window layers and for k and v in the three full layers, runs of one
+    among stacks that leave q to their replay (1.61e9 bytes over twelve
     layers; at 65,536 rows it kept k and v alone and planned
-    12,806,373,888), the plan stays under 15.0e9 (14,549,401,600 when this
-    was written; with q kept at the OLD pass it compiled to 15.10e9, PR
-    43) and XLA rematerializes nothing of its own."""
+    12,806,373,888), the plan stays under 15.0e9 (14,212,359,168 when this
+    was written; with q kept in the full layers too 14,549,401,600 until
+    PR 64 and 17,207,286,272 with its rotary, over what the chip states:
+    ``remat.LEFT_BY_ONE_AMONG_STACKS``; k and v alone 11,997,049,856; with
+    q kept at the OLD pass it compiled to 15.10e9, PR 43) and XLA
+    rematerializes nothing of its own."""
     compiled, plan, said = _compile_cell_step(
         "train-mellum2-ep4-s16384-b1", topo, monkeypatch)
-    assert [(p["kept"], p["kept_bytes"], p["why"]) for p in said] == [
-        ("attn_q,attn_k,attn_v", 2_013_265_920, "room")]
+    assert [(p["kept"], p["kept_bytes"], p["by_run"], p["why"])
+            for p in said] == [
+        ("attn_q,attn_k,attn_v", 1_610_612_736,
+         ",".join(["attn_q+attn_k+attn_v", "attn_k+attn_v"] * 3), "room")]
     assert 11.0e9 < plan <= 15.0e9, plan
     assert compiled.as_text().count(".remat") == 0
 
