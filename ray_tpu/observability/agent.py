@@ -8,6 +8,12 @@ thread ships them to the GCS as ONE `telemetry_report` RPC per
 `kv_put` and the ad-hoc flush-every-100-events threshold the runtime
 used to have.
 
+Spans and instants are buffered apart from the task states, as the GCS
+stores them apart (`core/gcs.py` `span_events`), each under
+`task_event_buffer_size`: a burst of task states before a report cannot
+push out what a job keeps of itself (`train.first_report`, a compile, a
+loop's `train.loop_summary`).
+
 Failure never drops telemetry silently: on a failed report the events
 re-buffer (bounded by `task_event_buffer_size`, oldest dropped AND
 counted) and metric deltas carry over into the next report; the drop
@@ -40,7 +46,8 @@ class TelemetryAgent:
         self._rt = runtime
         self._lock = threading.Lock()       # guards buffers + drop counters
         self._ship_lock = threading.Lock()  # serializes report build/send
-        self._events: List[dict] = []       # task events + spans, in order
+        self._events: List[dict] = []       # task states, in order
+        self._spans: List[dict] = []        # spans and instants, in order
         self._edges: List[dict] = []
         self._carry: List[dict] = []        # metric deltas from failed ships
         self._susp_carry: List[dict] = []   # rpc-timeout suspicions, same
@@ -61,12 +68,14 @@ class TelemetryAgent:
             fl.record(ev)
         cap = self._cap()
         with self._lock:
-            self._events.append(ev)
-            overflow = len(self._events) - cap
+            buf = (self._spans if ev.get("kind") in ("span", "instant")
+                   else self._events)
+            buf.append(ev)
+            overflow = len(buf) - cap
             if overflow > 0:
-                del self._events[:overflow]
+                del buf[:overflow]
                 self.events_dropped += overflow
-            high_water = len(self._events) >= max(cap // 2, 1)
+            high_water = len(buf) >= max(cap // 2, 1)
         if high_water:
             # ship early instead of waiting out the interval — bounded
             # memory beats strict batching under a burst
@@ -151,6 +160,7 @@ class TelemetryAgent:
         with self._ship_lock:
             with self._lock:
                 events, self._events = self._events, []
+                spans, self._spans = self._spans, []
                 edges, self._edges = self._edges, []
                 carry, self._carry = self._carry, []
                 d_ev = self.events_dropped - self._events_dropped_shipped
@@ -189,10 +199,10 @@ class TelemetryAgent:
             # (gray-failure detection needs cross-observer evidence).
             suspicions = self._susp_carry + _rpc.drain_timeout_suspicions()
             self._susp_carry = []
-            if not (events or edges or metric_deltas or self_deltas
+            if not (events or spans or edges or metric_deltas or self_deltas
                     or beacons or mem or suspicions):
                 return True
-            report = {"events": events, "edges": edges,
+            report = {"events": events + spans, "edges": edges,
                       "metrics": metric_deltas + self_deltas,
                       "beacons": beacons,
                       "worker": self._rt.worker_id.hex()[:12],
@@ -208,12 +218,8 @@ class TelemetryAgent:
                 with self._lock:
                     self.reports_dropped += 1
                     # re-buffer in original order, oldest dropped first
-                    merged = events + self._events
-                    cap = self._cap()
-                    if len(merged) > cap:
-                        self.events_dropped += len(merged) - cap
-                        merged = merged[-cap:]
-                    self._events = merged
+                    self._events = self._rebuffered(events + self._events)
+                    self._spans = self._rebuffered(spans + self._spans)
                     self._edges = (edges + self._edges)[-_EDGE_BUFFER_CAP:]
                     self._carry = metric_deltas + self._carry
                     self._susp_carry = (suspicions + self._susp_carry)[-256:]
@@ -233,6 +239,15 @@ class TelemetryAgent:
                     fl.dump("stall:" + ",".join(map(str, stalled)),
                             extra={"stalled": stalled, "beacons": beacons})
             return True
+
+    def _rebuffered(self, merged: List[dict]) -> List[dict]:
+        """`merged` cut to the newest `_cap()`, the rest counted as
+        dropped (under `_lock`)."""
+        over = len(merged) - self._cap()
+        if over <= 0:
+            return merged
+        self.events_dropped += over
+        return merged[over:]
 
     # ------------------------------------------------------- node resolution
 

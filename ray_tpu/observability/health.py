@@ -48,6 +48,15 @@ Design here:
   watcher on time = the device or the runtime under jax did not
   answer. Both record into the flight ring and dump it.
 
+* **Every standstill, and whose it was** — while a loop is under way
+  the watcher sleeps 10 ms, and a wake more than 20 ms late is counted
+  (`counters()`) by its cause, which a third clock tells: the process's
+  CPU time over the gap. The process ran while the watcher could not
+  (a native call held the interpreter's lock): `process`. The process
+  got no CPU: `host` (the machine, the VM or the scheduler). Under a
+  second that is all a late wake costs: four counters and an instant
+  `stall::late_wake` that only a running profile or tracing records.
+
 This module is import-light (stdlib only at module scope) because the
 GCS imports it; `quantile_from_buckets` is pulled lazily inside the
 straggler check.
@@ -156,18 +165,27 @@ def snapshot_beacons() -> List[dict]:
 def _reset_for_tests() -> None:
     with _beacons_lock:
         _beacons.clear()
-    _counters.update(host_freezes=0, host_freeze_s=0.0)
+    _counters.update(dict.fromkeys(_counters, 0))
 
 
 # --------------------------------------------------------------------------
 # process side: which side stood still
 # --------------------------------------------------------------------------
 
-_counters: Dict[str, float] = {"host_freezes": 0, "host_freeze_s": 0.0}
+# `host_freezes` / `host_freeze_s`: wakes more than `LATE_S` late, whatever
+# the cause. The other four: every wake more than `LATE_WAKE_S` late (the
+# freezes among them), by cause.
+_counters: Dict[str, float] = {
+    "host_freezes": 0, "host_freeze_s": 0.0,
+    "host_late_ms": 0.0, "host_late_count": 0,
+    "process_late_ms": 0.0, "process_late_count": 0}
+LATE_KEYS = ("host_late_ms", "host_late_count",
+             "process_late_ms", "process_late_count")
 
 
 def counters() -> Dict[str, float]:
-    """Stalls this process has seen (`LLMServer.stats()` shows them)."""
+    """Stalls and late wakes this process has seen (`LLMServer.stats()`
+    shows the freezes, a loop's `train.loop_summary` the late wakes)."""
     return dict(_counters)
 
 
@@ -188,47 +206,81 @@ def _stall(name: str, attrs: Dict[str, Any], reason: str) -> None:
         logger.exception("could not dump for %s", name)
 
 
-def _record_instant(name: str, attrs: Dict[str, Any]) -> None:
-    """The instant, kept with tracing off. Never raises."""
+def _record_instant(name: str, attrs: Dict[str, Any],
+                    always: bool = True) -> None:
+    """The instant, kept with tracing off unless `always` is false.
+    Never raises."""
     try:
         from ray_tpu.util import tracing
 
-        tracing.instant(name, attrs, always=True)
+        tracing.instant(name, attrs, always=always)
     except Exception:  # noqa: BLE001 - a diagnostic must not add a fault
         logger.exception("could not record %s", name)
 
 
+def _loop_under_way() -> bool:
+    """A loop of this process is at work: an armed beacon that has
+    ticked."""
+    with _beacons_lock:
+        return any(b.busy and b.count for b in _beacons.values())
+
+
 class FreezeWatcher:
-    """Sleeps `PERIOD_S`; a wake-up more than `LATE_S` late by the
-    monotonic clock means that this process did not run for that long:
-    its host or VM froze, it was stopped, or every core was taken."""
+    """Sleeps `PERIOD_S` (`BUSY_PERIOD_S` while a loop of the process is
+    under way) and reads two clocks round the sleep. A wake-up more than
+    `LATE_WAKE_S` late by the monotonic clock is counted by its cause:
+    `process` where the process's CPU time over the gap is at least half
+    the lateness (the process ran and this thread could not: a native
+    call held the interpreter's lock), else `host` (the process got no
+    CPU: its host or VM froze, it was stopped, or every core was taken).
+    More than `LATE_S` late is a freeze: kept, and with a loop under way
+    a stall."""
 
     PERIOD_S, LATE_S = 0.1, 1.0
+    BUSY_PERIOD_S, LATE_WAKE_S = 0.01, 0.02
 
     def __init__(self, clock: Callable[[], float] = time.monotonic,
-                 sleep: Callable[[float], None] = time.sleep):
-        self._clock, self._sleep = clock, sleep
+                 sleep: Callable[[float], None] = time.sleep,
+                 cpu_clock: Callable[[], float] = time.process_time,
+                 counters: Optional[Dict[str, float]] = None):
+        self._clock, self._sleep, self._cpu = clock, sleep, cpu_clock
+        # the process's own (`counters()`) unless a test counts apart
+        # from the process's running watcher
+        self._counters = _counters if counters is None else counters
         self._thread: Optional[threading.Thread] = None
         self._start_lock = threading.Lock()
 
     def run_once(self) -> Optional[float]:
         """One sleep; returns how late it woke if that is a freeze."""
-        t0 = self._clock()
-        self._sleep(self.PERIOD_S)
-        late = self._clock() - t0 - self.PERIOD_S
-        if late <= self.LATE_S:
+        period = self.BUSY_PERIOD_S if _loop_under_way() else self.PERIOD_S
+        t0, cpu0 = self._clock(), self._cpu()
+        self._sleep(period)
+        late = self._clock() - t0 - period
+        if late <= self.LATE_WAKE_S:
             return None
-        _counters["host_freezes"] += 1
-        _counters["host_freeze_s"] += late
-        # A stall only where a loop of this process is under way: an
-        # armed beacon that has ticked. Opening a TPU freezes every
-        # process of its host for seconds at every job's start, before
-        # any loop's first step: routine, counted, logged, and on the
-        # job's timeline (`armed` false) as seconds of set-up that were
-        # the machine's; no warning and no dump.
-        with _beacons_lock:
-            at_work = any(b.busy and b.count for b in _beacons.values())
-        attrs = {"late_s": round(late, 3), "armed": at_work}
+        cpu = self._cpu() - cpu0
+        cause = "process" if cpu >= 0.5 * late else "host"
+        self._counters[cause + "_late_ms"] += late * 1e3
+        self._counters[cause + "_late_count"] += 1
+        if late <= self.LATE_S:
+            # counted, and on a running profile's host plane beside the
+            # device's idle gap; nothing logged, kept or dumped
+            _record_instant("stall::late_wake", {
+                "late_ms": round(late * 1e3, 3),
+                "cpu_ms": round(cpu * 1e3, 3), "cause": cause},
+                always=False)
+            return None
+        self._counters["host_freezes"] += 1
+        self._counters["host_freeze_s"] += late
+        # A stall only where a loop of this process is under way.
+        # Opening a TPU freezes every process of its host for seconds at
+        # every job's start, before any loop's first step: routine,
+        # counted, logged, and on the job's timeline (`armed` false) as
+        # seconds of set-up that were the machine's; no warning and no
+        # dump.
+        at_work = _loop_under_way()
+        attrs = {"late_s": round(late, 3), "armed": at_work,
+                 "cpu_s": round(cpu, 3), "cause": cause}
         if at_work:
             _stall("stall::host_freeze", attrs, f"host_freeze:{late:.1f}s")
         else:

@@ -8,9 +8,10 @@ by the TrainWorker actor before the user loop runs.
 
 from __future__ import annotations
 
+import statistics
 import threading
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 from ray_tpu.core import compile_cache as _compile_cache
 from ray_tpu.observability import health as _health
@@ -21,6 +22,64 @@ from ray_tpu.util import tracing as _tracing
 # report() cadence, and big-model steps plus a collective checkpoint
 # save can legitimately take minutes.
 _STEP_DEADLINE_S = 600.0
+
+
+class LoopFigures:
+    """What a loop's reports add up to, kept as they come and said once
+    as the loop ends (`train.loop_summary`). From one report's end to the
+    next one's start the loop WAITS (the batch, the step, the fetch: the
+    user's code and the device); then the report runs; the two are one
+    interval of the loop. Bounded: a count, the longest wait and, for
+    the medians, at most `CAP` pairs spread evenly over the loop (when
+    the sample is full every second pair goes and from then on only
+    every second of those that would have been taken is)."""
+
+    CAP = 512
+
+    def __init__(self) -> None:
+        self.reports = 0
+        self.wait_max_s, self.wait_max_step = 0.0, None
+        self._pairs: List[Tuple[float, float]] = []   # (wait_s, report_s)
+        self._stride = 1
+        self._late0: Optional[Dict[str, float]] = None
+
+    def observe(self, wait_s: Optional[float], report_s: float,
+                step: int) -> None:
+        """One report: `wait_s` is None for the loop's first, which
+        follows set-up and no report."""
+        self.reports += 1
+        if wait_s is None:
+            # late wakes are the loop's from here on
+            self._late0 = _health.counters()
+            return
+        if wait_s > self.wait_max_s:
+            self.wait_max_s, self.wait_max_step = wait_s, step
+        if (self.reports - 2) % self._stride == 0:
+            self._pairs.append((wait_s, report_s))
+            if len(self._pairs) > self.CAP:
+                del self._pairs[1::2]
+                self._stride *= 2
+
+    def summary(self) -> Optional[Dict[str, Any]]:
+        """The attributes of `train.loop_summary`; None for a loop that
+        never reported."""
+        if self._late0 is None:
+            return None
+        out: Dict[str, Any] = {"steps": self.reports}
+        if self._pairs:
+            def median_ms(values) -> float:
+                return round(statistics.median(values) * 1e3, 4)
+
+            out.update(
+                interval_median_ms=median_ms(w + r for w, r in self._pairs),
+                wait_median_ms=median_ms(w for w, _ in self._pairs),
+                wait_max_ms=round(self.wait_max_s * 1e3, 4),
+                wait_max_step=self.wait_max_step,
+                report_median_ms=median_ms(r for _, r in self._pairs))
+        now = _health.counters()
+        out.update({k: round(now[k] - self._late0[k], 3)
+                    for k in _health.LATE_KEYS})
+        return out
 
 
 class TrainContext:
@@ -55,6 +114,7 @@ class TrainContext:
         # it a wait for the device: far above its median is a stall
         self.step_watch = _health.WaitWatch("train.report interval")
         self.last_report: Optional[float] = None   # perf_counter
+        self.figures = LoopFigures()
 
 
 _ctx: Optional[TrainContext] = None
@@ -129,13 +189,14 @@ def _report(ctx: TrainContext, metrics: Dict[str, Any], state: Any) -> None:
     _compile_cache.listen()            # the loop has imported jax by now
     now = time.perf_counter()
     step = metrics.get("step", len(ctx.reports))
-    if ctx.last_report is not None:
-        ctx.step_watch.observe(now - ctx.last_report, step=step)
+    step_no = step if isinstance(step, int) else len(ctx.reports)
+    waited = None if ctx.last_report is None else now - ctx.last_report
+    if waited is not None:
+        ctx.step_watch.observe(waited, step=step)
     else:
         # where set-up ends on the job's timeline: kept, once a context
-        _tracing.instant("train.first_report", {
-            "step": step if isinstance(step, int) else len(ctx.reports)},
-            always=True)
+        _tracing.instant("train.first_report", {"step": step_no},
+                         always=True)
     entry = dict(metrics)
     entry["_ts"] = time.time()
     entry["_rank"] = ctx.world_rank
@@ -149,6 +210,7 @@ def _report(ctx: TrainContext, metrics: Dict[str, Any], state: Any) -> None:
         ctx.reports.append(entry)
     _health.beacon(f"train:r{ctx.world_rank}", _step_deadline(ctx)).tick()
     ctx.last_report = time.perf_counter()   # a save is not a device wait
+    ctx.figures.observe(waited, ctx.last_report - now, step_no)
 
 
 def _save_checkpoint(ctx: TrainContext, state: Any, entry: dict

@@ -75,8 +75,11 @@ class TrainWorker:
             self._open_chips()
             with tracing.span("train.loop", {"rank": self.rank},
                               always=True):
-                self.result = (loop_fn(config) if _accepts_arg(loop_fn)
-                               else loop_fn())
+                try:
+                    self.result = (loop_fn(config) if _accepts_arg(loop_fn)
+                                   else loop_fn())
+                finally:
+                    self._say_loop_summary()
             return self.result
         except BaseException as e:
             import traceback
@@ -96,6 +99,17 @@ class TrainWorker:
             except Exception:
                 pass
             self._flush_telemetry()
+
+    def _say_loop_summary(self) -> None:
+        """The loop's own account of its steps, once as it ends (failed
+        or not) and kept with tracing off: what `session._report` added
+        up (`LoopFigures`) and the late wakes the process's watcher
+        counted from the first report on. A loop that never reported
+        says nothing."""
+        summary = self.ctx.figures.summary() if self.ctx is not None else None
+        if summary is not None:
+            tracing.instant("train.loop_summary",
+                            {"rank": self.rank, **summary}, always=True)
 
     def _open_chips(self) -> None:
         """Where this worker was given chips: the backend's opening, which

@@ -27,8 +27,9 @@ jax itself: a parent that only spawns workers stays off the chip.
 
 What happens once a job is KEPT with tracing off (`always=True`, `plan`):
 the cluster's start, the worker group's, a worker's set-up and loop, the
-chips' opening, the first report, every compile, trace and lowering of
-0.05 s or more, the plans a step was lowered with, a freeze of the host.
+chips' opening, the first report, the loop's own summary as it ends,
+every compile, trace and lowering of 0.05 s or more, the plans a step was
+lowered with, a freeze of the host.
 They ride the same channel to the same store, and `JaxTrainer.fit` writes
 them out as it ends (`<run_dir>/timeline.json`, a Chrome trace), so what
 the program did before any profiler started outlives the cluster. What a

@@ -164,7 +164,8 @@ def test_fit_leaves_the_jobs_timeline(tracing_on, tmp_path, monkeypatch):
     for e in events:
         first.setdefault(e["name"], e)
     order = ["core.init", "train.fit", "train.group_start",
-             "train.worker_setup", "train.loop", "train.first_report"]
+             "train.worker_setup", "train.loop", "train.first_report",
+             "train.loop_summary"]
     assert [n for n in first if n in order] == order      # sorted by start
 
     def ends(e):
@@ -182,6 +183,7 @@ def test_fit_leaves_the_jobs_timeline(tracing_on, tmp_path, monkeypatch):
     assert ends(first["train.group_start"]) <= first["train.loop"]["ts"]
     assert inside("train.loop", "train.fit")
     assert inside("train.first_report", "train.loop")
+    assert inside("train.loop_summary", "train.loop")
     assert first["core.init"]["args"]["attrs"] == {"nodes": 1, "num_cpus": 4.0}
     assert first["train.fit"]["args"]["attrs"] == {
         "workers": 1, "chips_per_worker": 0}
@@ -190,6 +192,16 @@ def test_fit_leaves_the_jobs_timeline(tracing_on, tmp_path, monkeypatch):
     worker = first["train.loop"]["args"]["worker"]
     assert worker and first["train.worker_setup"]["args"]["worker"] == worker
     assert first["train.first_report"]["args"]["worker"] == worker
+    # the loop's own account, once, kept whatever the setting
+    summary, = (e["args"] for e in events if e["name"] == "train.loop_summary")
+    assert summary["worker"] == worker
+    assert sorted(summary["attrs"]) == [
+        "host_late_count", "host_late_ms", "interval_median_ms",
+        "process_late_count", "process_late_ms", "rank",
+        "report_median_ms", "steps", "wait_max_ms", "wait_max_step",
+        "wait_median_ms"]
+    assert (summary["attrs"]["rank"], summary["attrs"]["steps"],
+            summary["attrs"]["wait_max_step"]) == (0, 2, 1)
     assert first["train.fit"]["args"]["worker"] is None
     assert "train.chips_open" not in first       # a worker without chips
     reports = [e for e in events if e["name"] == "train.report"]

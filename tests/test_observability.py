@@ -124,10 +124,10 @@ def test_failed_report_rebuffers_and_counts_drops(ray_start_regular,
     agent.flush(wait=True)  # fails against the dead GCS
     assert agent.reports_dropped > rd0
     with agent._ship_lock, agent._lock:  # no ship in flight -> stable view
-        assert len(agent._events) <= 50  # bounded re-buffer
+        assert len(agent._spans) <= 50  # bounded re-buffer
         assert agent.events_dropped - dropped0 >= 70  # 120 into 50 slots
         assert any(e.get("name") == "obs_drop_ev119"
-                   for e in agent._events)  # newest survive
+                   for e in agent._spans)  # newest survive
 
     monkeypatch.setattr(rt, "gcs_call", orig)  # GCS recovers
     agent.flush(wait=True)

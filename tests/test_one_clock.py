@@ -252,16 +252,40 @@ def test_server_stats_count_compiles_and_freezes(engine):
 
 
 class _Clock:
-    """A clock that jumps by `late` across every sleep."""
+    """A clock that jumps by `late` across every sleep, beside a CPU
+    clock of the process that runs for `busy` of every sleep."""
 
-    def __init__(self, late):
+    def __init__(self, late, busy=0.0):
         self.now, self.late = 100.0, late
+        self.cpu_now, self.busy, self.slept = 7.0, busy, []
 
     def __call__(self):
         return self.now
 
+    def cpu(self):
+        return self.cpu_now
+
     def sleep(self, s):
+        self.slept.append(s)
         self.now += s + self.late
+        self.cpu_now += self.busy
+
+    def watcher(self, counters=None):
+        return health.FreezeWatcher(clock=self, sleep=self.sleep,
+                                    cpu_clock=self.cpu, counters=counters)
+
+
+def _no_counts():
+    """Counters of a test's own: the process's running watcher (an
+    earlier test's cluster started it) counts into the module's."""
+    return dict.fromkeys(health.counters(), 0)
+
+
+def _loop_at_work():
+    loop = health.beacon("test:loop", 30.0)
+    loop.arm()
+    loop.tick()
+    return loop
 
 
 @pytest.mark.parametrize("jump,frozen", [(3.0, True), (0.5, False)])
@@ -315,8 +339,8 @@ def test_a_freeze_before_any_loop_runs_is_counted_and_no_stall(
     worker before its first report), nothing warns and nothing is dumped;
     the instant says `armed` false (the job's timeline counts it as
     set-up that was the machine's)."""
-    clock = _Clock(12.0)
-    w = health.FreezeWatcher(clock=clock, sleep=clock.sleep)
+    clock = _Clock(12.0, busy=0.25)
+    w = clock.watcher()
     with caplog.at_level("INFO", logger="ray_tpu.health"):
         assert w.run_once() == pytest.approx(12.0)
         loop = health.beacon("test:loop", 30.0)
@@ -324,8 +348,13 @@ def test_a_freeze_before_any_loop_runs_is_counted_and_no_stall(
         assert w.run_once() == pytest.approx(12.0)
     assert health.counters()["host_freezes"] == 2
     assert health.counters()["host_freeze_s"] == pytest.approx(24.0)
+    # a freeze is a late wake too, and the process's CPU clock says whose
+    assert health.counters()["host_late_count"] == 2
+    assert health.counters()["host_late_ms"] == pytest.approx(24000.0)
+    assert health.counters()["process_late_count"] == 0
     assert [(s["name"], s["attrs"]) for s in recorder.spans] == [
-        ("stall::host_freeze", {"late_s": 12.0, "armed": False})] * 2
+        ("stall::host_freeze", {"late_s": 12.0, "armed": False,
+                                "cpu_s": 0.25, "cause": "host"})] * 2
     assert recorder.flight.dumps_written == 0
     assert [r.levelname for r in caplog.records] == ["INFO", "INFO"]
     # under way: a stall, dumped under the recorder's rate limit
@@ -341,13 +370,115 @@ def test_an_unarmed_freeze_is_kept_with_tracing_off(recorder):
     """`FreezeWatcher.run_once` with no armed beacon and tracing off: the
     chip's opening is on the job's timeline as `stall::host_freeze`."""
     tracing.disable()
-    clock = _Clock(6.5)
-    late = health.FreezeWatcher(clock=clock, sleep=clock.sleep).run_once()
+    clock = _Clock(6.5, busy=6.0)     # a native call held the lock
+    late = clock.watcher().run_once()
     assert late == pytest.approx(6.5)
     said, = recorder.spans
     assert (said["kind"], said["name"]) == ("instant", "stall::host_freeze")
-    assert said["attrs"] == {"late_s": 6.5, "armed": False}
+    assert said["attrs"] == {"late_s": 6.5, "armed": False,
+                             "cpu_s": 6.0, "cause": "process"}
     assert recorder.flight.dumps_written == 0
+
+
+def test_a_late_wake_under_a_second_is_counted_and_nothing_else(
+        recorder, caplog):
+    """0.3 s late with a loop under way: four counters move; no WARNING,
+    no kept record, no dump, and the freeze counters stand."""
+    tracing.disable()
+    _loop_at_work()
+    clock, counts = _Clock(0.3), _no_counts()
+    with caplog.at_level("INFO", logger="ray_tpu.health"):
+        assert clock.watcher(counts).run_once() is None
+    assert counts == {**_no_counts(), "host_late_count": 1,
+                      "host_late_ms": pytest.approx(300.0)}
+    assert recorder.spans == [] and len(recorder.flight._ring) == 0
+    assert recorder.flight.dumps_written == 0
+    assert caplog.records == []
+
+
+@pytest.mark.parametrize("busy_share,cause", [
+    (1.0, "process"), (0.5, "process"), (0.1, "host"), (0.0, "host")])
+def test_the_cpu_clock_says_whose_standstill_it_was(
+        busy_share, cause, recorder):
+    """The process ran for `busy_share` of the lateness while its watcher
+    could not wake: from half up the process held it, else the host."""
+    late = 0.4
+    clock, counts = _Clock(late, busy=busy_share * late), _no_counts()
+    clock.watcher(counts).run_once()
+    other = "host" if cause == "process" else "process"
+    assert counts[cause + "_late_count"] == 1
+    assert counts[cause + "_late_ms"] == pytest.approx(400.0)
+    assert counts[other + "_late_count"] == 0 and counts[other + "_late_ms"] == 0
+
+
+def test_a_late_wake_is_an_instant_for_tracing_and_the_profile(
+        recorder, tmp_path):
+    """Plain, not kept: recorded with tracing on, and under a profile an
+    event of /host:CPU with its scalars (on the watcher's own thread's
+    line, wherever that runs)."""
+    import jax  # noqa: F401 - the annotation exists once jax is imported
+
+    tracing.enable()
+    clock = _Clock(0.05, busy=0.04)
+    with _Profile(tmp_path / "trace"):
+        clock.watcher(_no_counts()).run_once()
+    mine = [s for s in recorder.spans if s["name"] == "stall::late_wake"
+            and s["attrs"]["late_ms"] == 50.0]
+    assert [(s["kind"], s["attrs"]) for s in mine] == [
+        ("instant", {"late_ms": 50.0, "cpu_ms": 40.0, "cause": "process"})]
+    events = [stats for name, stats, _, _ in _host_events(tmp_path / "trace")
+              if name == "stall::late_wake" and stats.get("late_ms") == 50.0]
+    assert len(events) == 1 and events[0]["cpu_ms"] == 40.0
+    assert events[0]["cause"] == "process"
+
+
+def test_the_watcher_sleeps_10_ms_only_while_a_loop_is_under_way(recorder):
+    clock = _Clock(0.0)
+    w = clock.watcher(_no_counts())
+    w.run_once()                               # no beacon
+    loop = health.beacon("test:loop", 30.0)
+    loop.arm()
+    w.run_once()                               # armed, not ticked yet
+    loop.tick()
+    w.run_once()                               # under way
+    loop.disarm()
+    w.run_once()                               # idle again
+    assert clock.slept == [0.1, 0.1, 0.01, 0.1]
+    assert (w.PERIOD_S, w.BUSY_PERIOD_S, w.LATE_WAKE_S, w.LATE_S) == (
+        0.1, 0.01, 0.02, 1.0)
+
+
+def test_a_wake_on_time_counts_nothing(recorder):
+    """Up to 20 ms late is the scheduler's everyday."""
+    _loop_at_work()
+    clock, counts = _Clock(0.019), _no_counts()
+    assert clock.watcher(counts).run_once() is None
+    assert counts == _no_counts()
+
+
+def test_a_freeze_under_way_still_warns_dumps_and_says_whose(
+        recorder, caplog):
+    """3 s late with a loop under way: the parent's counters, WARNING and
+    dump, the kept instant now with `cpu_s` and `cause`."""
+    tracing.disable()
+    _loop_at_work()
+    clock = _Clock(3.0, busy=2.9)
+    with caplog.at_level("INFO", logger="ray_tpu.health"):
+        assert clock.watcher().run_once() == pytest.approx(3.0)
+    assert health.counters()["host_freezes"] == 1
+    assert health.counters()["host_freeze_s"] == pytest.approx(3.0)
+    assert health.counters()["process_late_count"] == 1
+    said, = recorder.spans
+    assert (said["kind"], said["name"]) == ("instant", "stall::host_freeze")
+    assert said["attrs"] == {"late_s": 3.0, "armed": True, "cpu_s": 2.9,
+                             "cause": "process"}
+    assert [r.levelname for r in caplog.records] == ["WARNING"]
+    assert "stall::host_freeze" in caplog.records[0].getMessage()
+    assert recorder.flight.dumps_written == 1
+    dump, = flight_mod.list_dumps(recorder.cfg.flight_recorder_dir)
+    doc = flight_mod.load_dump(dump)
+    assert doc["reason"] == "host_freeze:3.0s"
+    assert doc["extra"]["cause"] == "process"
 
 
 def test_dumps_go_beside_the_session_directories(monkeypatch, tmp_path):
