@@ -54,10 +54,19 @@ in the later half and s in the earlier one exp(G_t - G_s) is split at the
 earlier half's last row m, exp(G_t - G_m) exp(G_m - G_s), both exponents
 sums of g <= 0; each half is cut again the same way, down to blocks of one
 row, and the diagonal of Aqk is q_t . k_t itself. A pair belongs to the one
-level at which the highest differing bit of t and s lies, so a level is ONE
-product for the whole chunk ([2 C, dk] x [dk, C], masked to the level's
-pairs): log2(C) = 6 products a head and chunk of 64 where the bounded cut
-has 4 smaller ones, and 12 more on the way back where it has 8. A factor
+level at which the highest differing bit of t and s lies. A level keeps t
+in the ODD halves of its blocks and s in the even ones, so from blocks of
+``VECTOR_ROWS`` = 8 rows (a sublane tile) a level is ONE product of the odd
+halves' rows of q and k alone, gathered as whole tiles, against every
+column ([C, dk] x [dk, C], masked to the level's pairs): log2(C) - 3 = 3
+products a head and chunk of 64 where the bounded cut has 4, and 6 more on
+the way back where it has 8 (the level's gradient blocks are zero outside
+those rows). The levels of 1, 2 and 4 rows are the seven subdiagonals
+INSIDE a sublane tile and take no product (``_inside_blocks``): the pair d
+steps apart is sum_c q_tc k_(t-d)c exp(g summed over the d rows between),
+a row shift, exact float32 multiplies and a lane sum a subdiagonal on the
+vector unit, its ONE factor exp of a sum of at most seven g <= 0 made by
+adds (``_shifted_sums``). A factor
 can underflow, and where either does the true product is under float32's
 least: a channel that dies in one step reads as nothing, not as NaN or inf,
 forward and backward (the gradient at an underflowed pair is the pair's
@@ -67,11 +76,18 @@ total) are made bottom up by adds, so a sum over few rows is the sum of few
 terms and not the difference of two running sums of the chunk (at -60 a
 step a chunk's running sum reaches -3,800, where a float32 ulp is 2e-4);
 the chunk's running sum is the last level. On a v5e the two calls at [1,
-16384, 64, 128] bfloat16 take 33.6 ms forward and 90.7 ms forward and
-backward on this cut against 23.6 and 59.3 on the bounded one over the
-same (bounded) inputs (16.9 / 45.5 against 11.9 / 29.8 at 32 heads: PERF.md
-6, PR 61), so a caller that CAN state a bound keeps the cut it had, with
-the program it had.
+16384, 64, 128] bfloat16, every array an argument of the timed program,
+take 21.6 ms forward and 36.0 backward on this cut against 18.7 and 31.5
+on the bounded one over the same inputs (10.9 / 18.1 against 9.5 / 15.9 at
+32 heads over bounded inputs; PR 61's form, six whole products a level of
+every row with every column and twelve on the way back, took 28.8 and
+53.0: PERF.md 6, PR 66, where every form that was timed is listed; they
+are kept in ``tests/delta_rule_cut_forms.py``), so a caller that CAN state
+a bound keeps the cut it had, with the program it had. What the cut's time
+followed was neither the count of its products nor the rows they stream
+(``plan()``'s ``mxu_rows_*``; the form with the fewest rows was not the
+fastest) but the work of the vector unit round them: the factors, the
+three bfloat16 parts of every float32 operand, the masks and selects.
 
 The inverse of the unit lower triangular [C, C] matrix is made by block
 forward substitution with products only (a TPU has no triangular solve):
@@ -176,6 +192,10 @@ SHIFTED_BLOCKS = 4         # the inverse's rounds from blocks of up to so many
 #                            rows are shifted multiply-adds, no product
 HALF_ROWS = 8              # from blocks of so many rows (a sublane tile) a
 #                            round multiplies the odd blocks' rows alone
+VECTOR_ROWS = 8            # the cut in halves: the pairs inside aligned blocks
+#                            of so many rows (the levels under it) are shifted
+#                            multiply-adds, a level from it up one product of
+#                            the odd halves' rows
 _EXP_MOST = 88.0           # exp of more overflows float32
 # checkpoint_name tags of what the kernel path's forward rule hands its
 # backward beside the inputs: the output and the chunks' incoming states. A
@@ -251,14 +271,18 @@ _NT = (((1,), (1,)), ((), ()))     # a @ b^T
 _TN = (((0,), (0,)), ((), ()))     # a^T @ b
 
 
-_TALLIES: list = []     # open counts [float32 products, MXU passes]: plan()
+_TALLIES: list = []     # open counts [float32 products, MXU passes, rows
+#                         streamed x passes]: plan()
 
 
-def _count(f32: int, passes: int):
-    """Counts a product the kernels' code emits while ``plan()`` traces."""
+def _count(f32: int, passes: int, a, dims):
+    """Counts a product the kernels' code emits while ``plan()`` traces:
+    ``passes`` bfloat16 passes of the MXU, each streaming the rows of ``a``
+    (its free dimension under ``dims``) against the other operand."""
     for tally in _TALLIES:
         tally[0] += f32
         tally[1] += passes
+        tally[2] += passes * a.shape[1 - dims[0][0][0]]
 
 
 def _dot(a, b, dims, mm):
@@ -267,7 +291,7 @@ def _dot(a, b, dims, mm):
     operands is ONE bfloat16 pass, which is bfloat16 inputs' product."""
     if mm == jnp.float32:
         return _dot32(a, b, dims)
-    _count(0, 1)
+    _count(0, 1, a, dims)
     return jax.lax.dot_general(a.astype(mm), b.astype(mm), dims,
                                preferred_element_type=jnp.float32)
 
@@ -275,7 +299,7 @@ def _dot(a, b, dims, mm):
 def _dot32(a, b, dims=_NN):
     """A float32 product at full precision (the pair blocks, the inverse):
     six bfloat16 passes of the MXU."""
-    _count(1, 6)
+    _count(1, 6, a, dims)
     return jax.lax.dot_general(a.astype(jnp.float32), b.astype(jnp.float32),
                                dims, precision=_HIGHEST,
                                preferred_element_type=jnp.float32)
@@ -404,46 +428,142 @@ def _level_factors(p, e):
     return jnp.exp(p), jnp.exp(jnp.minimum(e - p, 0.0))
 
 
+def _halves(x, h: int, odd: bool):
+    """The rows of the odd (even) halves of x's aligned blocks of 2 h rows,
+    one under the other: whole sublane tiles from h = 8."""
+    return jnp.concatenate([x[lo:lo + h] for lo in range(h if odd else 0,
+                                                         x.shape[0], 2 * h)])
+
+
+def _spread(x, h: int, odd: bool):
+    """``_halves`` undone: x's rows back in the odd (even) halves, zeros in
+    the others."""
+    zero = jnp.zeros((h, x.shape[1]), x.dtype)
+    pieces = [x[lo:lo + h] for lo in range(0, x.shape[0], h)]
+    return jnp.concatenate([half for piece in pieces for half in (
+        (zero, piece) if odd else (piece, zero))])
+
+
+def _shifted_sums(g, rows: int):
+    """[the sum of g over the d rows that end at each row, d = 1 .. rows -
+    1]: the exponents of the pairs d steps apart, each made of the last by
+    ONE add (a sum of d terms, not a difference of running sums). A row's
+    sum that reaches over the chunk's first row wraps round: its pair lies
+    in no block."""
+    sums = [g]
+    for d in range(1, rows - 1):
+        sums.append(sums[-1] + pltpu.roll(g, d, 0))
+    return sums
+
+
+def _steps_apart(c: int, rows: int):
+    """[C, C]: t - s at the pairs s < t inside aligned blocks of ``rows``
+    rows, 0 elsewhere."""
+    t, s = _rows_cols(c)
+    return jnp.where(((t ^ s) < rows) & (t > s), t - s, 0)
+
+
+def _inside_blocks(q, k, g, rows: int):
+    """(Aqk, Akk) at the pairs s < t inside aligned blocks of ``rows`` <= 8
+    rows (the levels of 1 .. rows / 2 rows together: the block's rows - 1
+    subdiagonals), zero elsewhere, without a product: the pair d steps
+    apart is sum_c q_tc k_(t-d)c exp(g summed over the d rows between), a
+    row shift, exact float32 multiplies and a lane sum a subdiagonal on the
+    vector unit. The one factor is exp of a sum of g <= 0."""
+    c = k.shape[0]
+    apart = _steps_apart(c, rows)
+    aqk = akk = jnp.zeros((c, c), jnp.float32)
+    for d, run in enumerate(_shifted_sums(g, rows), 1):
+        w = pltpu.roll(k, d, 0) * jnp.exp(run)
+        aqk = jnp.where(apart == d, jnp.sum(q * w, axis=1, keepdims=True),
+                        aqk)
+        akk = jnp.where(apart == d, jnp.sum(k * w, axis=1, keepdims=True),
+                        akk)
+    return aqk, akk
+
+
+def _inside_blocks_grads(q, k, g, d_qk, d_kk, rows: int):
+    """The way back through ``_inside_blocks``: (dq, k's gradient as a row,
+    k's gradient as a column) [C, dk] of the pairs inside the blocks."""
+    c = k.shape[0]
+    apart = _steps_apart(c, rows)
+    dq = dk_rows = dk_cols = jnp.zeros_like(k)
+    for d, run in enumerate(_shifted_sums(g, rows), 1):
+        a = jnp.sum(jnp.where(apart == d, d_qk, 0.0), axis=1, keepdims=True)
+        b = jnp.sum(jnp.where(apart == d, d_kk, 0.0), axis=1, keepdims=True)
+        decay = jnp.exp(run)
+        w = pltpu.roll(k, d, 0) * decay                      # of k_(t-d)
+        dq = dq + a * w
+        dk_rows = dk_rows + b * w
+        # k_(t-d)'s gradient is made at row t and moved up d rows
+        dk_cols = dk_cols + pltpu.roll((a * q + b * k) * decay, c - d, 0)
+    return dq, dk_rows, dk_cols
+
+
+def _product_levels(levels):
+    """(h, P_h, E_h) of the levels that are a product: those of
+    ``VECTOR_ROWS`` rows and more."""
+    return [(1 << i, p, e) for i, (p, e) in enumerate(levels)
+            if 1 << i >= VECTOR_ROWS]
+
+
+def _level_operands(q, k, p, e, h: int):
+    """The two operands of a level of h >= 8 rows and their factors: ([q;
+    k] of the odd halves' rows times exp(G_t - G_m) [C, dk], k times
+    exp(G_m - G_s) [C, dk], the rows' factor [C / 2, dk], the columns' [C,
+    dk])."""
+    rows, cols = _level_factors(p, e)
+    rows = _halves(rows, h, True)
+    return (jnp.concatenate([_halves(q, h, True) * rows,
+                             _halves(k, h, True) * rows]), k * cols, rows,
+            cols)
+
+
 def _pair_blocks_free(q, k, levels):
     """``_pair_blocks`` for a gate with no bound: the pairs s < t of a
     chunk are cut in halves, the quarter between the halves split at the
     halves' boundary (``_level_factors``), and the halves cut again down
-    to blocks of one row: log2(C) products for the whole chunk, each
-    masked to its level's pairs. The diagonal of Aqk is q_t . k_t."""
+    to blocks of one row. A level of ``VECTOR_ROWS`` rows or more is ONE
+    product, the odd halves' rows of q and k (whole sublane tiles, the only
+    rows the level keeps) against every column, masked to the level's
+    pairs; the levels under it are ``_inside_blocks``. The diagonal of Aqk
+    is q_t . k_t."""
     c = k.shape[0]
     t, s = _rows_cols(c)
-    aqk = jnp.where(t == s, jnp.sum(q * k, axis=1, keepdims=True), 0.0)
-    akk = jnp.zeros((c, c), jnp.float32)
-    for i, (p, e) in enumerate(levels):
-        rows, cols = _level_factors(p, e)
-        block = _dot32(jnp.concatenate([q * rows, k * rows]), k * cols, _NT)
-        level = _level(t, s, 1 << i)
-        aqk = jnp.where(level, block[:c], aqk)
-        akk = jnp.where(level, block[c:], akk)
+    aqk, akk = _inside_blocks(q, k, levels[0][0], VECTOR_ROWS)
+    aqk = jnp.where(t == s, jnp.sum(q * k, axis=1, keepdims=True), aqk)
+    for h, p, e in _product_levels(levels):
+        both, columns, _, _ = _level_operands(q, k, p, e, h)
+        block = _dot32(both, columns, _NT)                   # [C, C]
+        level = _level(t, s, h)
+        aqk = jnp.where(level, _spread(block[:c // 2], h, True), aqk)
+        akk = jnp.where(level, _spread(block[c // 2:], h, True), akk)
     return aqk, akk
 
 
 def _pair_grads_free(q, k, levels, d_qk, d_kk):
     """The way back through ``_pair_blocks_free``: as ``_pair_grads``. A
-    level's split point cancels in every pair's exponent, so dcum is the
-    rows' part less the columns' part; at a pair whose factor underflowed
-    the gradient is the pair's value, 0."""
+    level's gradient blocks are zero but in the odd halves' rows, which
+    alone go through its two products. A level's split point cancels in
+    every pair's exponent, so dcum is the rows' part (q and k times their
+    gradients as rows) less the columns' part; at a pair whose factor
+    underflowed the gradient is the pair's value, 0."""
     c = k.shape[0]
     t, s = _rows_cols(c)
+    dq, dk_rows, dk_cols = _inside_blocks_grads(q, k, levels[0][0], d_qk,
+                                                d_kk, VECTOR_ROWS)
+    for h, p, e in _product_levels(levels):
+        both, columns, rows, cols = _level_operands(q, k, p, e, h)
+        level = _level(t, s, h)
+        d_both = jnp.concatenate([
+            _halves(jnp.where(level, d, 0.0), h, True) for d in (d_qk, d_kk)])
+        back = _dot32(d_both, columns) * jnp.concatenate([rows, rows])
+        dq = dq + _spread(back[:c // 2], h, True)
+        dk_rows = dk_rows + _spread(back[c // 2:], h, True)
+        dk_cols = dk_cols + _dot32(d_both, both, _TN) * cols
     diagonal = jnp.sum(jnp.where(t == s, d_qk, 0.0), axis=1, keepdims=True)
-    dq, dk_, dcum = diagonal * k, diagonal * q, jnp.zeros_like(k)
-    for i, (p, e) in enumerate(levels):
-        rows, cols = _level_factors(p, e)
-        level = _level(t, s, 1 << i)
-        d_both = jnp.concatenate([jnp.where(level, d_qk, 0.0),
-                                  jnp.where(level, d_kk, 0.0)])
-        back = _dot32(d_both, k * cols) * jnp.concatenate([rows, rows])
-        both = jnp.concatenate([q * rows, k * rows])
-        dk_cols = _dot32(d_both, both, _TN) * cols
-        dq = dq + back[:c]
-        dk_ = dk_ + back[c:] + dk_cols
-        dcum = dcum + q * back[:c] + k * back[c:] - k * dk_cols
-    return dq, dk_, dcum
+    return (dq + diagonal * k, dk_rows + dk_cols + diagonal * q,
+            q * dq + k * (dk_rows - dk_cols))
 
 
 def _shifted_round(p, m, b: int):
@@ -673,9 +793,10 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, sin_ref, do_ref,
 @functools.lru_cache(maxsize=None)
 def _products(c: int, heads: int, per: int, dk: int, dv: int, dtype,
               bounded: bool = True):
-    """(float32 products, MXU passes) a head and chunk of the forward and
-    of the backward call, counted by tracing one block's chunk through the
-    code the kernels run (``bounded``: with the cut a bound on g allows)."""
+    """(float32 products, MXU passes, rows streamed through the MXU over
+    those passes) a head and chunk of the forward and of the backward call,
+    counted by tracing one block's chunk through the code the kernels run
+    (``bounded``: with the cut a bound on g allows)."""
     f32 = jnp.float32
     form = dict(heads=heads, per=per, mm=jnp.dtype(dtype), sub=min(SUB, c),
                 clamp=40.0 if bounded else None)
@@ -685,13 +806,13 @@ def _products(c: int, heads: int, per: int, dk: int, dv: int, dtype,
     counts = []
     for rule, more in ((_block_forward, ()),
                        (_block_backward, (wide(dv, dtype), block[-1]))):
-        tally = [0, 0]
+        tally = [0, 0, 0]
         _TALLIES.append(tally)
         try:
             jax.eval_shape(functools.partial(rule, **form), *block, *more)
         finally:
             _TALLIES.remove(tally)
-        counts.append((tally[0] // heads, tally[1] // heads))
+        counts.append(tuple(n // heads for n in tally))
     return counts
 
 
@@ -703,16 +824,19 @@ def plan(*, B: int, S: int, H: int, dk: int, dv: int, dtype, impl: str,
     products, how many heads an instance walks and how many of them share
     one inverse
     (``inverse_side`` over the chunk: as many as fit the MXU's tile of
-    ``_SIDE`` side by side), the float32 products and the MXU
-    passes a head and chunk of each call (``_products``: counted from the
-    kernels' own code), the VMEM one instance of the backward call holds
+    ``_SIDE`` side by side), the float32 products, the MXU passes and the
+    rows those passes stream (``mxu_rows_*``: a product's free rows times
+    its passes) a head and chunk of each call (``_products``: counted from
+    the kernels' own code), the VMEM one instance of the backward call holds
     (its blocks twice, Mosaic double-buffers; the state scratch; the
     float32 forms of the heads of an inverse: a dozen of its side squared,
     a dozen [C, dk] and half a dozen [C, dv] a head, three [dv, dk]; the
-    cut that needs no bound two [C, dk] more a level), which cut of the
+    cut that needs no bound two [C, dk] more for each level that is a
+    product), which cut of the
     pair products ran (``cut`` "bounded": sub-blocks split at their middle
     row; "halving": ``lower_bound`` None, blocks of ``cut_sizes`` rows each
-    split at its halves' boundary), the
+    split at its halves' boundary, the ``vector_levels`` smallest made on
+    the vector unit without a product), the
     bytes of the chunks' incoming states the forward rule keeps for the
     backward, and the HBM bytes the two calls move for one head and
     sequence."""
@@ -733,22 +857,26 @@ def plan(*, B: int, S: int, H: int, dk: int, dv: int, dtype, impl: str,
               + 2 * heads * dv * dk * 4)                  # state in, ds0
     bounded = lower_bound is not None
     sizes = [] if bounded else [2 << i for i in range(c.bit_length() - 1)]
+    on_vector = sum(size <= VECTOR_ROWS for size in sizes)
     forms = 12 * (per * c) ** 2 * 4 + per * (
-        (12 + 2 * len(sizes)) * c * dk + 6 * c * dv) * 4 + 3 * dv * dk * 4
+        (12 + 2 * (len(sizes) - on_vector)) * c * dk + 6 * c * dv) * 4 \
+        + 3 * dv * dk * 4
     states = B * (steps // c) * H * dv * dk * 4
     hbm = steps * ((2 * dk + dv) * item + dk * 4 + 4) * 2 \
         + steps * dv * item * 2 + 2 * (steps // c) * dv * dk * 4
     said = {"path": impl, "S": S, "chunk": c, "sub_block": SUB,
             "heads_per_block": heads, "lower_bound": lower_bound,
             "cut": "bounded" if bounded else "halving", "cut_sizes": sizes,
+            "vector_levels": on_vector,
             "vmem_bytes": 0, "state_bytes_kept": states,
             "hbm_bytes_per_head": 0}
     if on:
-        (f32_fwd, fwd), (f32_bwd, bwd) = _products(
+        (f32_fwd, fwd, rows_fwd), (f32_bwd, bwd, rows_bwd) = _products(
             c, heads, per, dk, dv, jnp.dtype(dtype), bounded)
         said.update(
             inverse_side=per * c, f32_products_fwd=f32_fwd,
             f32_products_bwd=f32_bwd, mxu_passes_fwd=fwd, mxu_passes_bwd=bwd,
+            mxu_rows_fwd=rows_fwd, mxu_rows_bwd=rows_bwd,
             vmem_bytes=2 * blocks + heads * dv * dk * 4 + forms,
             hbm_bytes_per_head=hbm)
     return said

@@ -78,7 +78,12 @@ PINNED = {
     # its tree (a third layout in ``ops/ssd.py``, a third form of block in
     # ``llama._layer``, scopes inside ``hybrid.mixer_half``: every program
     # left alone), and the cell PR 63 added, as its tree lowers it
-    "train-solaropen2-ep32-s16384-b1": "9796d8cbe0bcd100",
+    # PR 66 replaced the Solar cell's: the delta rule's cut for a gate with
+    # no bound (``ops/delta_rule.py`` ``_pair_blocks_free``,
+    # ``_pair_grads_free``) makes its small levels on the vector unit and
+    # streams the odd halves' rows alone; the thirteen others kept theirs
+    # (the Ling cell calls the op with its bound and keeps the bounded cut)
+    "train-solaropen2-ep32-s16384-b1": "6120360e671a58bd",
     "train-falconh1-l4-s16384-b1": "83c658e5ea66a12f",
 }
 

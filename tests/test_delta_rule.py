@@ -10,6 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import delta_rule_cut_forms as cut_forms
 from ray_tpu.models import reference_ling
 from ray_tpu.ops import delta_rule as dr
 
@@ -215,6 +216,18 @@ def test_the_plan_counts_the_states_and_the_vmem():
     assert plan["f32_products_bwd"] == 4 + 3 + 8
     # the running sums are no product; the five others are one pass each
     assert plan["mxu_passes_fwd"] == 6 * 7 + 5
+    # the rows those passes stream, a head: 4 pair products of [2 x 16, dk]
+    # at six passes; the inverse's 3 rounds of two products of the odd
+    # blocks' 64 of 128 rows, for two heads; four bfloat16 products of C
+    # rows and the state's update of dv
+    assert plan["mxu_rows_fwd"] == 4 * 32 * 6 + 3 * 2 * 64 * 6 // 2 \
+        + 4 * 64 + 128
+    # the backward makes the float32 products again, goes back through a
+    # sub-block by [32, C] x [C, dk] and [C, 32] x [32, dk], and has twelve
+    # bfloat16 products, two of them of dv rows
+    assert plan["mxu_rows_bwd"] == 768 + 1152 + 4 * (32 + 64) * 6 \
+        + 10 * 64 + 2 * 128
+    assert plan["vector_levels"] == 0
     # a chunk of 128 is a head an inverse: 8 pair products, 4 rounds of two
     whole = dr.plan(B=1, S=16384, H=32, dk=128, dv=128, chunk=128,
                     dtype=jnp.bfloat16, impl="pallas")
@@ -264,7 +277,9 @@ def free_gate(case: str, S: int, B=1, H=2, dk=16, dv=16, seed=0):
 @pytest.mark.parametrize("case, S, chunk", [
     ("drawn", 100, 32), ("drawn", 128, 64),
     ("zero", 64, 32), ("dies", 64, 32), ("dies", 128, 64),
-    ("alike", 64, 32), ("alike", 128, 64)])
+    ("alike", 64, 32), ("alike", 128, 64),
+    ("drawn", 40, 16), ("dies", 32, 16), ("zero", 256, 128),
+    ("dies", 256, 128)])
 def test_any_gate_with_no_stated_bound_is_the_recurrences(impl, case, S,
                                                           chunk):
     """``lower_bound`` None: the pair products cut in halves, each half
@@ -273,7 +288,9 @@ def test_any_gate_with_no_stated_bound_is_the_recurrences(impl, case, S,
     or inf, forward and backward, dg included), beta 1.99 with every key
     alike (the inverse's entries alternate at +-2), a length that is no
     whole chunk: the output and all six gradients are the step-by-step
-    recurrence's."""
+    recurrence's. Chunks of 16, 32, 64 and 128: one, two, three and four
+    levels that are a product of the odd halves' rows above the three made
+    on the vector unit (PR 66)."""
     args, weight = free_gate(case, S)
 
     def op(q, k, v, g, beta, state):
@@ -327,6 +344,14 @@ def test_the_aligned_sums_are_sums_of_few_terms():
         np.testing.assert_allclose(got[2 * i + 1], want_e, rtol=3e-7,
                                    atol=1e-9)
     np.testing.assert_allclose(got[-1], np.cumsum(exact, axis=0), rtol=3e-7)
+    # the levels made on the vector unit (PR 66) take their exponents from
+    # ``_shifted_sums``: the sum of the d rows that end at a row, each made
+    # of the last by one add, for every pair inside a block of 8 rows
+    shifted = in_a_kernel(lambda x: dr._shifted_sums(x, 8), g)
+    assert len(shifted) == 7
+    for d, got_d in enumerate(shifted, 1):
+        want = sum(exact[d - 1 - j:c - j] for j in range(d))
+        np.testing.assert_allclose(got_d[d - 1:], want, rtol=3e-7, atol=1e-9)
 
 
 def test_no_factor_of_the_cut_in_halves_passes_one():
@@ -351,6 +376,61 @@ def test_no_factor_of_the_cut_in_halves_passes_one():
                     np.testing.assert_allclose(
                         rows[t] * cols[s], np.exp(cum[t] - cum[s]),
                         rtol=1e-4, atol=1e-37)
+    # a pair inside a block of 8 rows has ONE factor (PR 66): exp of the sum
+    # of g over the rows between, in [0, 1] too
+    for d, run in enumerate(in_a_kernel(lambda x: dr._shifted_sums(x, 8), g),
+                            1):
+        factor = np.exp(np.asarray(run, np.float64))
+        in_float32 = np.asarray(jnp.exp(run))
+        assert (in_float32 >= 0).all() and (in_float32 <= 1).all()
+        for t in range(d, c):
+            if t % 8 >= d:
+                np.testing.assert_allclose(
+                    factor[t], np.exp(cum[t] - cum[t - d]), rtol=1e-4,
+                    atol=1e-37)
+
+
+CUTS = [("the op's own", dr._pair_blocks_free, dr._pair_grads_free)] \
+    + cut_forms.FORMS
+
+
+@pytest.mark.parametrize("c", [16, 32, 64, 128])
+@pytest.mark.parametrize("form", CUTS, ids=[name for name, _, _ in CUTS])
+def test_every_form_of_a_level_is_the_level(form, c):
+    """The cut in halves as the op makes it (the levels of 1, 2 and 4 rows
+    by shifted multiply-adds, a level from 8 rows up one product of the odd
+    halves' rows) and every form that was timed against it
+    (``tests/delta_rule_cut_forms.py``: the narrow side streamed and turned,
+    the even halves' columns alone, one, two or three levels on the vector
+    unit) against PR 61's: ONE product of every row with every column a
+    level, masked to the level's pairs; forward and the way back, at chunks
+    of 16, 32, 64 and 128 (one to four levels that are a product). On the
+    chip a product's entry does not depend on which other rows stream with
+    it; the CPU's may, so float32 rounding of the same sums is what is
+    held."""
+    name, forward, back = form
+    dk = 16
+    ks = jax.random.split(jax.random.PRNGKey(c), 6)
+    q, k = (jax.random.normal(key, (c, dk)) * 0.3 for key in ks[:2])
+    g = -20.0 * jax.random.uniform(ks[2], (c, dk)) \
+        * (jax.random.uniform(ks[3], (c, dk)) < 0.5)
+    t, s = np.arange(c)[:, None], np.arange(c)[None]
+    d_qk = jnp.where(t >= s, jax.random.normal(ks[4], (c, c)), 0.0)
+    d_kk = jnp.where(t > s, jax.random.normal(ks[5], (c, c)), 0.0)
+
+    def both_ways(forward, back):
+        return (in_a_kernel(lambda q, k, g: list(forward(
+                    q, k, dr._aligned_sums(g)[0])), q, k, g)
+                + in_a_kernel(lambda q, k, g, a, b: list(back(
+                    q, k, dr._aligned_sums(g)[0], a, b)), q, k, g, d_qk,
+                    d_kk))
+
+    want = both_ways(cut_forms.whole_products, cut_forms.whole_grads)
+    got = both_ways(forward, back or cut_forms.whole_grads)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, rtol=2e-6,
+                                   atol=2e-6 * float(jnp.abs(b).max()),
+                                   err_msg=name)
 
 
 # sha256[:16] of the jaxpr of the kernel path and its five gradients (the
@@ -382,19 +462,37 @@ def test_a_caller_that_states_its_bound_gets_the_parents_program(stated):
 
 
 def test_the_plan_says_which_cut_ran():
-    """``lower_bound`` None: the cut in halves, its block sizes, its
-    float32 products a head and chunk (6 levels where the bounded cut has
-    4 sub-blocks; 12 more on the way back where it has 8) and two [C, dk]
-    forms a level more in VMEM; a stated bound: the parent's plan."""
+    """``lower_bound`` None: the cut in halves, its block sizes, how many of
+    its levels are made on the vector unit, its float32 products a head and
+    chunk (3 levels of the odd halves' rows where the bounded cut has 4
+    sub-blocks and PR 61's form had 6 whole levels; 6 more on the way back
+    where they have 8 and 12), the rows its passes stream, and two [C, dk]
+    forms more in VMEM for each level that is a product; a stated bound:
+    the parent's plan."""
     shape = dict(B=1, S=16384, H=64, dk=128, dv=128, dtype=jnp.bfloat16,
                  impl="pallas")
     free = dr.plan(**shape, lower_bound=None)
     assert free["cut"] == "halving" and free["lower_bound"] is None
     assert free["cut_sizes"] == [2, 4, 8, 16, 32, 64]
-    assert free["f32_products_fwd"] == 6 + 3
-    assert free["f32_products_bwd"] == 6 + 3 + 12
+    assert free["vector_levels"] == 3
+    assert free["f32_products_fwd"] == 3 + 3
+    assert free["f32_products_bwd"] == 3 + 3 + 6
+    # a level streams [q; k] of its odd halves, 2 x 32 rows; PR 61's form
+    # streamed 6 levels of 2 x 64: 768 x 6 = 4,608 in the pair blocks alone
+    assert free["mxu_rows_fwd"] == 3 * 64 * 6 + 1152 + 384 < 768 * 6
+    assert free["mxu_rows_bwd"] == 2 * 1152 + 3 * (64 + 64) * 6 \
+        + 10 * 64 + 2 * 128
     assert free["state_bytes_kept"] == 256 * 64 * 128 * 128 * 4
     bounded = dr.plan(**shape)
     assert bounded["cut"] == "bounded" and bounded["cut_sizes"] == []
+    assert bounded["vector_levels"] == 0
     assert bounded["f32_products_fwd"] == 4 + 3
+    assert bounded["mxu_rows_fwd"] == 4 * 32 * 6 + 1152 + 384
     assert bounded["vmem_bytes"] < free["vmem_bytes"] <= 16 * 2 ** 20
+    assert free["vmem_bytes"] - bounded["vmem_bytes"] \
+        == 2 * 3 * 2 * 64 * 128 * 4      # two heads, 3 levels, p and e
+    # a chunk of 16 has ONE level that is a product, a chunk of 128 four
+    for chunk, levels in ((16, 1), (32, 2), (128, 4)):
+        said = dr.plan(**shape, chunk=chunk, lower_bound=None)
+        assert said["vector_levels"] == 3
+        assert len(said["cut_sizes"]) - 3 == levels

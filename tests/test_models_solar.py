@@ -385,15 +385,17 @@ def test_plan_instants_say_the_cut_the_chunk_and_the_states(monkeypatch):
         params)
     plans = [a for n, a in said if n == "kda.plan"]
     # the cut in halves: 6 levels of a chunk of 64 where a stated bound
-    # gives 4 sub-blocks; in this float32 model the 5 and 12 products over
-    # the state and the steps are float32 too
+    # gives 4 sub-blocks, the 3 smallest on the vector unit and the 3 others
+    # a product each (PR 66), 6 more on the way back; in this float32 model
+    # the 5 and 12 products over the state and the steps are float32 too
     assert plans and all(
         p["path"] == "pallas" and p["chunk"] == 64 and p["cut"] == "halving"
         and p["lower_bound"] is None
         and p["cut_sizes"] == [2, 4, 8, 16, 32, 64] for p in plans)
     assert all(p["heads_per_block"] == 2 and p["inverse_side"] == 128
-               and p["f32_products_fwd"] == 9 + 5
-               and p["f32_products_bwd"] == 21 + 12 for p in plans)
+               and p["vector_levels"] == 3
+               and p["f32_products_fwd"] == 6 + 5
+               and p["f32_products_bwd"] == 12 + 12 for p in plans)
     halves = [a for n, a in said if n == "kda.half_plan"]
     assert halves and halves[0]["taps"] == 4 \
         and halves[0]["lower_bound"] == "none" \

@@ -463,8 +463,10 @@ def test_delta_rule_calls_compile_at_the_ling_cells_shape(one_chip,
 def test_delta_rule_calls_compile_at_the_solar_cells_shape(one_chip,
                                                            on_chip_branch):
     """The delta rule told NO bound on its gate (the cut of the pair
-    products in halves: six levels a chunk, both factors of a level at
-    most 1) at the Solar-Open2 cell's shape, 64 heads of 128 over 16,384
+    products in halves: six levels a chunk, no factor over 1; since PR 66
+    the three smallest by shifted multiply-adds on the vector unit, seven
+    unrolled subdiagonals each way, the three others a product of the odd
+    halves' rows gathered as whole tiles) at the Solar-Open2 cell's shape, 64 heads of 128 over 16,384
     steps: exactly two Mosaic calls with the signatures the bounded cut has
     (forward 6 -> 2, backward 7 -> 6, q, k, v first at [1, 16384, 8192]:
     ``benchmark/readers/solar_kernel_roofline.py`` tells them by these),
@@ -505,7 +507,8 @@ def test_delta_rule_calls_compile_at_the_solar_cells_shape(one_chip,
     plan = dr.plan(B=B, S=S, H=H, dk=d, dv=d, dtype=jnp.bfloat16,
                    impl="pallas", lower_bound=None)
     assert plan["cut"] == "halving" and plan["chunk"] == 64 \
-        and plan["inverse_side"] == 128
+        and plan["inverse_side"] == 128 and plan["vector_levels"] == 3
+    assert plan["mxu_rows_fwd"] < 768 * 6 and plan["mxu_rows_bwd"] < 13568
     assert f"f32[{B},{S // 64},{H * d},{d}]" in text
     assert plan["state_bytes_kept"] == 1_073_741_824
     assert plan["hbm_bytes_per_head"] * H == 5_377_097_728
